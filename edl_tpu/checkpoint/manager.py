@@ -219,7 +219,7 @@ class CheckpointManager:
         # not train time (async saves return early by design).
         # child_span: inside a live operation (a drain's emergency save,
         # a restage) the save stitches to it; standalone it roots its own
-        # ckpt_save trace — the operation-root taxonomy of DESIGN.md
+        # ckpt_save trace — the operation-root classification of DESIGN.md
         # "Distributed tracing"
         status_doc = status.to_dict()
         try:

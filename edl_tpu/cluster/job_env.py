@@ -10,14 +10,19 @@ checkpoint path — and the worker-side env the process manager injects
 
 TPU topology: instead of ``get_cuda_device_count`` (reference
 utils.py:98-120), the local device count comes from ``EDL_DEVICES_PER_PROC``
-when set (CPU-simulated meshes in tests) else lazily from ``jax`` on first
-use — control-plane processes that never ask never import jax.
+when set (CPU-simulated meshes in tests), else from :func:`probe_devices`
+— a throwaway child process. A TPU chip belongs to one process at a
+time, so a control-plane process that initialised a JAX backend would own
+the chip its workers need: nothing in this module imports jax.
 """
 
 from __future__ import annotations
 
+import json
 import os
-from typing import List, Optional, Tuple
+import subprocess
+import sys
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from edl_tpu.utils.log import get_logger
 
@@ -58,13 +63,68 @@ def job_identity(
     )
 
 
-def local_device_count() -> int:
-    override = os.environ.get("EDL_DEVICES_PER_PROC")
-    if override:
-        return int(override)
-    import jax  # deliberate lazy import
+class LocalDevices(NamedTuple):
+    """What one process finds when it initialises jax on this host."""
 
-    return jax.local_device_count()
+    count: int
+    platform: str   # jax.default_backend(): "tpu", "cpu", ...
+    kind: str       # jax.devices()[0].device_kind ("" when not asked)
+
+
+_PROBE = (
+    "import json, jax; print(json.dumps({'platform': jax.default_backend(), "
+    "'count': jax.local_device_count(), "
+    "'kind': jax.local_devices()[0].device_kind}))"
+)
+
+
+def probe_devices(
+    env: Optional[Dict[str, str]] = None, timeout: float = 300.0
+) -> LocalDevices:
+    """The devices a worker spawned with ``env`` would see.
+
+    ``EDL_DEVICES_PER_PROC`` answers without a process (the platform is
+    then whatever ``JAX_PLATFORMS`` names, possibly ""). Otherwise a child
+    interpreter initialises the backend, prints the answer and EXITS —
+    releasing the chips — before this returns, so call it before anything
+    that needs a device is spawned. A child that cannot reach a device is
+    an error here, not a default: the workers would fail the same way."""
+    env = dict(os.environ if env is None else env)
+    override = env.get("EDL_DEVICES_PER_PROC")
+    if override:
+        return LocalDevices(
+            int(override), env.get("JAX_PLATFORMS", "").strip().lower(), ""
+        )
+    try:
+        out = subprocess.run(
+            [sys.executable, "-c", _PROBE], env=env, timeout=timeout,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        )
+    except subprocess.TimeoutExpired:
+        raise RuntimeError(
+            "device probe did not answer in %.0fs — is another process "
+            "holding the accelerator?" % timeout
+        ) from None
+    if out.returncode != 0:
+        raise RuntimeError(
+            "device probe failed (exit %d): %s"
+            % (out.returncode, out.stderr.strip()[-2000:])
+        )
+    found = json.loads(out.stdout.strip().splitlines()[-1])
+    return LocalDevices(
+        int(found["count"]), str(found["platform"]), str(found["kind"])
+    )
+
+
+def default_compile_cache_dir() -> str:
+    """The one compile-cache home when nobody placed it: inside the
+    checkout (git-ignored), the same for every job id, user and run. The
+    directory's path is part of every cache key, so a cache that moves
+    never hits."""
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    return os.path.join(checkout, ".cache", "xla")
 
 
 class JobEnv:
@@ -96,21 +156,18 @@ class JobEnv:
         # Persistent XLA compilation cache shared by every worker the job
         # ever spawns. Stop-resume elasticity restarts all JAX processes
         # per resize; without this each stage recompiles from scratch and
-        # spawn->first-step dominates resize downtime. Job-scoped default
-        # (stable across restarts on the host); "none" disables.
-        if compile_cache_dir is None:
+        # spawn->first-step dominates resize downtime. Where the operator
+        # placed JAX's cache (JAX_COMPILATION_CACHE_DIR) that IS the cache
+        # — jax reads the variable itself, workers inherit it, and the
+        # exchange and the ladder must scan the same place. Otherwise the
+        # flag/env, else one fixed default; "none" disables.
+        placed = env.get("JAX_COMPILATION_CACHE_DIR", "")
+        if placed:
+            compile_cache_dir = placed
+        elif compile_cache_dir is None:
             compile_cache_dir = env.get("EDL_COMPILE_CACHE_DIR", "")
         if not compile_cache_dir:
-            import tempfile
-
-            # Per-user root: on a multi-tenant host another user owning a
-            # shared /tmp/edl_xla_cache would make makedirs fail at startup,
-            # and loading serialized executables from a world-writable dir
-            # is a cache-poisoning surface.
-            uid = os.getuid() if hasattr(os, "getuid") else 0
-            compile_cache_dir = os.path.join(
-                tempfile.gettempdir(), "edl_xla_cache-%d" % uid, self.job_id
-            )
+            compile_cache_dir = default_compile_cache_dir()
         self.compile_cache_dir = (
             "" if compile_cache_dir == "none" else compile_cache_dir
         )
@@ -171,7 +228,9 @@ class WorkerEnv:
         # per pod by the launcher from EDL_CKPT_LOCAL_BASE; empty = the
         # classic single-tier layout where ckpt_path is the only dir
         self.ckpt_local_dir = env.get("EDL_CKPT_LOCAL_DIR", "")
-        self.compile_cache_dir = env.get("EDL_COMPILE_CACHE_DIR", "")
+        self.compile_cache_dir = env.get(
+            "JAX_COMPILATION_CACHE_DIR", ""
+        ) or env.get("EDL_COMPILE_CACHE_DIR", "")
         # the elastic window, worker-visible (the AOT resize ladder
         # derives its neighbor worlds from it). Absent or malformed =
         # a window pinned to the current world — the ladder is a no-op.

@@ -64,7 +64,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from edl_tpu.chaos.plane import arm_from_env as _chaos_arm
 from edl_tpu.chaos.plane import fault_point as _fault_point
-from edl_tpu.cluster.job_env import JobEnv, local_device_count
+from edl_tpu.cluster.job_env import JobEnv, probe_devices
 from edl_tpu.cluster.model import Cluster, Pod, Worker, new_uuid
 from edl_tpu.discovery.registry import Registration, Registry
 from edl_tpu.launch import process as procs_mod
@@ -192,7 +192,7 @@ class ElasticLauncher:
         self.stall_floor = float(
             os.environ.get("EDL_STALL_FLOOR", 0) or max(5.0, 2.0 * ttl)
         )
-        self.prewarm = prewarm
+        self.prewarm = prewarm or os.environ.get("EDL_PREWARM") == "1"
         self.warmer = None  # created on first adopted stage
         # the elastic window rides the worker env contract so the AOT
         # resize ladder (train/aot.py) can enumerate its neighbor worlds
@@ -228,18 +228,21 @@ class ElasticLauncher:
         # accumulate into a spurious abandonment
         self._hot_fallbacks = 0
         self._hot_fallback_ts = 0.0
+        # what a worker of this job would find: asked of a throwaway child
+        # that has exited before anything below spawns a process that needs
+        # the devices — this process never initialises a jax backend
+        spawn_env = procs_mod.base_worker_env()
+        spawn_env.update(self.extra_worker_env)
+        found = probe_devices(spawn_env)
+        self.local_devices, self.platform = found.count, found.platform
+        self._refuse_what_one_owner_per_chip_forbids()
         self.standby_pool = None
         from edl_tpu.launch.standby import StandbyPool, standby_enabled
 
         if standby_enabled(standby):
-            spawn_env = procs_mod.base_worker_env(self.extra_worker_env)
-            spawn_env.update(self.extra_worker_env)
             # eager backend init is only safe when the elastic window pins
             # the world to one worker (see launch/standby.py docstring)
-            eager = (
-                job_env.max_nodes * job_env.nproc_per_node == 1
-                or os.environ.get("EDL_STANDBY_EAGER") == "1"
-            )
+            eager = job_env.max_nodes * job_env.nproc_per_node == 1
             self.standby_pool = StandbyPool(
                 spawn_env, count=job_env.nproc_per_node, eager=eager
             )
@@ -352,9 +355,33 @@ class ElasticLauncher:
 
     # -- setup -------------------------------------------------------------
 
+    def _refuse_what_one_owner_per_chip_forbids(self) -> None:
+        """A TPU chip belongs to one process at a time, and nothing in the
+        tree tells a process WHICH chips are its own: every process that
+        initialises the backend reaches for all of them. Configurations
+        that need a second device-holding process beside the live worker
+        are refused here, at start, instead of hanging later."""
+        if self.platform != "tpu":
+            return
+        if self.job_env.nproc_per_node > 1:
+            raise ValueError(
+                "--nproc_per_node %d on a TPU host: each worker process "
+                "would claim every local chip and all but the first fail "
+                "at backend init. Run one worker per host — it owns the "
+                "host's %d chip(s) under one mesh."
+                % (self.job_env.nproc_per_node, self.local_devices)
+            )
+        if self.prewarm:
+            raise ValueError(
+                "--prewarm on a TPU host: a shadow stage is a second set of "
+                "worker processes, and the live stage owns the chips they "
+                "would need. The in-worker AOT ladder (train/aot.py) "
+                "compiles neighbour worlds without a second process."
+            )
+
     def _make_pod(self) -> Pod:
         nproc = self.job_env.nproc_per_node
-        devices = max(1, local_device_count() // max(1, nproc))
+        devices = max(1, self.local_devices // max(1, nproc))
         addr = get_host_ip()
         ports = find_free_ports(nproc)
         workers = [
@@ -1190,6 +1217,7 @@ class ElasticLauncher:
                 self.training_args,
                 self.extra_worker_env,
                 self.prewarm,
+                self.platform,
             ) or False
         if self.warmer:
             self.warmer.note_world(published.world_size)
@@ -1608,7 +1636,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         "--compile_cache_dir",
         default=None,
         help="persistent XLA compilation cache shared across resizes "
-        "(default: a job-scoped tmp dir; 'none' disables)",
+        "(JAX_COMPILATION_CACHE_DIR, when set, is the cache and wins; "
+        "default: <checkout>/.cache/xla; 'none' disables)",
     )
     parser.add_argument("--ttl", type=float, default=10.0, help="liveness lease TTL (s)")
     parser.add_argument(

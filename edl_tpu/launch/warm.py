@@ -26,9 +26,9 @@ Shadow stages need devices. On CPU meshes (tests, the resize bench,
 ``xla_force_host_platform_device_count`` simulations) devices are virtual
 and free, so shadow stages are exact: same HLO, same process count, same
 device assignment → same cache key. On real TPU the chips are owned by
-the live stage, so shadow stages cannot run; warming is CPU-gated
-(``EDL_PREWARM_FORCE=1`` overrides for single-host multi-chip setups
-where spare chips exist).
+the live stage and nothing assigns spare chips to a second process, so
+shadow stages cannot run there: warming runs on the CPU platform only,
+and the launcher refuses ``--prewarm`` on a TPU host at start.
 
 Worker-side contract: the warm processes run the SAME training script
 with ``EDL_WARM_ONLY=1``; :func:`edl_tpu.train.context.warm_only` reads
@@ -71,15 +71,6 @@ def anticipated_world_sizes(job_env: JobEnv) -> List[int]:
         {p * job_env.nproc_per_node
          for p in range(job_env.min_nodes, job_env.max_nodes + 1)}
     )
-
-
-def _platform_allows_shadow(extra_worker_env: Dict[str, str]) -> bool:
-    if os.environ.get("EDL_PREWARM_FORCE") == "1":
-        return True
-    platform = extra_worker_env.get(
-        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
-    )
-    return platform.strip().lower() == "cpu"
 
 
 class CacheWarmer:
@@ -490,12 +481,14 @@ def make_warmer_if_enabled(
     training_args: Sequence[str],
     extra_worker_env: Dict[str, str],
     prewarm: bool,
+    platform: str,
 ) -> Optional[CacheWarmer]:
     """Launcher hook: a :class:`CacheWarmer` when prewarming makes sense.
 
     Enabled by the ``--prewarm`` flag or ``EDL_PREWARM=1``; requires a
-    compile cache dir, more than one anticipated size, and a platform
-    where shadow stages can run (CPU, or ``EDL_PREWARM_FORCE=1``).
+    compile cache dir, more than one anticipated size, and the CPU
+    ``platform`` (what the launcher's device probe found), where shadow
+    stages' devices are virtual and free.
     """
     if not (prewarm or os.environ.get("EDL_PREWARM") == "1"):
         return None
@@ -504,11 +497,10 @@ def make_warmer_if_enabled(
         return None
     if len(anticipated_world_sizes(job_env)) <= 1:
         return None
-    if not _platform_allows_shadow(extra_worker_env):
+    if platform != "cpu":
         logger.info(
-            "prewarm skipped: shadow stages need free devices (CPU meshes); "
-            "on TPU the live stage owns the chips (EDL_PREWARM_FORCE=1 to "
-            "override on hosts with spare chips)"
+            "prewarm skipped on platform %r: shadow stages need free "
+            "devices (CPU meshes)", platform,
         )
         return None
     return CacheWarmer(

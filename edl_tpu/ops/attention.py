@@ -264,14 +264,11 @@ def _grid_pipeline_kwargs() -> dict:
     innermost accumulation walk is sequential ('arbitrary')."""
     from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        return {
-            "compiler_params": pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        }
-    except (AttributeError, TypeError):
-        return {}
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
+    }
 
 
 def _bwd_delta(g: jax.Array, o: jax.Array, b: int, h: int, tq: int, d: int):

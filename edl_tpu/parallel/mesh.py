@@ -246,13 +246,11 @@ def sharded_seq_attention(
     the sp == 1 passthrough (and both must agree numerically)."""
     from jax.sharding import PartitionSpec as P
 
-    from edl_tpu.parallel.compat import shard_map
-
     if mesh.shape[sp_axis] == 1:
         return local_fn(q, k, v)
     batch = dp_axis if dp_axis in mesh.axis_names else None
     spec = P(batch, None, sp_axis, None)
-    return shard_map(
+    return jax.shard_map(
         per_shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False,
     )(q, k, v)

@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from edl_tpu.parallel.compat import shard_map
-
 
 def pipeline_efficiency(num_microbatches: int, pp: int) -> float:
     """GPipe ideal utilization: M busy ticks out of M + PP - 1 total."""
@@ -263,7 +261,7 @@ def pipeline_apply(
     fn = partial(
         _pipeline_shard, stage_fn, first_fn, last_fn, num_microbatches, axis
     )
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(param_specs, P(), P(), data_spec, data_spec),

@@ -36,8 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from edl_tpu.parallel.compat import shard_map
-
 
 def _schedule(t, r, pp: int, num_micro: int):
     """Decode rank ``r``'s op at tick ``t``: (has_f, m_f, has_b, m_b)."""
@@ -283,7 +281,7 @@ def pipeline_1f1b_loss_and_grads(
         _1f1b_shard, body_fn, first_fn, last_loss_fn, num_microbatches,
         axis, batch_axis, batch_scale,
     )
-    loss, d_body, d_first, d_last = shard_map(
+    loss, d_body, d_first, d_last = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(

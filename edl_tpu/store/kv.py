@@ -371,6 +371,15 @@ class StoreState:
             lease.deadline = now + lease.ttl
         return len(self._leases)
 
+    def extend_lease_deadlines(self, seconds: float) -> int:
+        """Push every lease's deadline out by ``seconds``; returns how many
+        moved. For a server that was itself not running for that long: no
+        keepalive could have reached it meanwhile, so the silence says
+        nothing about the owners."""
+        for lease in self._leases.values():
+            lease.deadline += seconds
+        return len(self._leases)
+
     # -- durability (snapshot + journal replay) ----------------------------
     #
     # The reference survives control-plane restarts because etcd is an

@@ -78,6 +78,13 @@ _FP_REPL_STREAM = _fault_point(
 )
 
 _LEASE_SWEEP_INTERVAL = 0.2
+# the serve loop woke this much later than it asked to: the process (or the
+# whole host) was not running. Measured on a one-chip v5e VM: every TPU
+# runtime initialisation — the launcher's device probe, each worker's
+# backend start — froze ALL processes for 5-7 s, and a launcher-embedded
+# store woke, swept, and expired its own launcher's 10 s leases before the
+# equally frozen keepalive thread could speak (worker SIGKILLed, restage).
+_STALL_FORGIVEN_ABOVE = 1.0
 _COMPACT_EVERY = 10_000  # journal entries between snapshots
 # semi-sync replication: how long a client ack may be held waiting for
 # every live standby to apply+journal the write before the primary
@@ -734,7 +741,20 @@ class StoreServer:
                     timeout = min(timeout, max(
                         0.0, self._sync_q[0].deadline - time.monotonic()
                     ))
-                for key, _ in self._sel.select(timeout):
+                asked = time.monotonic()
+                ready = self._sel.select(timeout)
+                overslept = time.monotonic() - asked - timeout
+                if overslept > _STALL_FORGIVEN_ABOVE:
+                    # no keepalive could arrive while nothing ran: the
+                    # owners get the lost time back before any sweep
+                    n = self._state.extend_lease_deadlines(overslept)
+                    self._m_lease_resets.inc(n, cause="stall")
+                    logger.warning(
+                        "serve loop stalled %.1fs (process not scheduled); "
+                        "%d lease deadline(s) extended by as much",
+                        overslept, n,
+                    )
+                for key, _ in ready:
                     if key.data == "wake":
                         try:
                             self._wake_r.recv(4096)

@@ -1,59 +1,47 @@
-from edl_tpu.train.context import (
-    current_env,
-    enable_compilation_cache,
-    init,
-    warm_only,
-    worker_barrier,
-)
-from edl_tpu.train.compression import topk_compression
-from edl_tpu.train.loop import ElasticTrainer
-from edl_tpu.train.schedules import (
-    piecewise_decay,
-    scaled_schedule_factory,
-    warmup_cosine,
-)
-from edl_tpu.train.metrics import (
-    AUCState,
-    auc_compute,
-    auc_init,
-    auc_merge,
-    auc_update,
-)
-from edl_tpu.train.step import (
-    TrainState,
-    create_state,
-    cross_entropy_loss,
-    make_cross_entropy_loss,
-    make_eval_step,
-    make_kd_loss,
-    make_masked_train_step,
-    make_train_step,
-    mse_loss,
-)
+"""Training plane. Names resolve on first use (PEP 562), so importing a
+jax-free submodule — the launcher takes ``CacheExchange`` from
+``edl_tpu.train.aot`` — does not import jax through this package: a
+control-plane process must never load it (see cluster/job_env.py)."""
 
-__all__ = [
-    "init",
-    "enable_compilation_cache",
-    "current_env",
-    "ElasticTrainer",
-    "topk_compression",
-    "piecewise_decay",
-    "warmup_cosine",
-    "scaled_schedule_factory",
-    "warm_only",
-    "worker_barrier",
-    "TrainState",
-    "create_state",
-    "make_train_step",
-    "make_masked_train_step",
-    "make_eval_step",
-    "cross_entropy_loss",
-    "make_cross_entropy_loss",
-    "make_kd_loss",
-    "mse_loss",
-    "AUCState",
-    "auc_init",
-    "auc_update",
-    "auc_compute",
-    "auc_merge",
-]
+import importlib
+
+_HOME = {
+    "current_env": "context",
+    "enable_compilation_cache": "context",
+    "init": "context",
+    "warm_only": "context",
+    "worker_barrier": "context",
+    "topk_compression": "compression",
+    "ElasticTrainer": "loop",
+    "piecewise_decay": "schedules",
+    "scaled_schedule_factory": "schedules",
+    "warmup_cosine": "schedules",
+    "AUCState": "metrics",
+    "auc_compute": "metrics",
+    "auc_init": "metrics",
+    "auc_merge": "metrics",
+    "auc_update": "metrics",
+    "TrainState": "step",
+    "create_state": "step",
+    "cross_entropy_loss": "step",
+    "make_cross_entropy_loss": "step",
+    "make_eval_step": "step",
+    "make_kd_loss": "step",
+    "make_masked_train_step": "step",
+    "make_train_step": "step",
+    "mse_loss": "step",
+}
+
+__all__ = sorted(_HOME)
+
+
+def __getattr__(name):
+    if name not in _HOME:
+        raise AttributeError(
+            "module %r has no attribute %r" % (__name__, name)
+        )
+    value = getattr(
+        importlib.import_module("%s.%s" % (__name__, _HOME[name])), name
+    )
+    globals()[name] = value
+    return value

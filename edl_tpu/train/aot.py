@@ -14,18 +14,18 @@ never be hit by an (N-1)-process incarnation even when the program, the
 compile options and the program's own devices are identical — measured:
 the same world-1 step gets a different key in every topology it is
 compiled from. The patch re-keys the accelerator-config component to
-the *program's* device kinds + platform (JAX's own documented fallback
-for backends without serializable topology), making the key a pure
-function of (HLO, compile options, device kinds, platform) — and strips
-the per-fusion-autotune-cache *path* (derived from the local cache dir,
-so it differs per pod) from the compile options before they are hashed,
-the same way JAX strips xla_gpu_cuda_data_dir. Proven on
+the *program's* device kinds (JAX's own documented fallback for backends
+without serializable topology), making the key a pure function of (HLO,
+compile options, device kinds, platform). The key's other host-bound
+part — the per-fusion-autotune-cache *path* jax arms under the local
+cache dir, which rides the compile options into the hash — is switched
+off with a public option (``enable_compilation_cache``). Proven on
 the CPU rig: a world-1 entry compiled from inside a 2-process world is
-hit byte-for-byte by a real world-1 job. Scoped like the all-rank-write
-patch in train/context.py: guarded against private-API drift, env
-opt-out, CPU-only by default (``EDL_CACHE_PORTABLE_KEYS=all`` extends
-it to TPU — queued for on-chip confirmation in run_tpu_suite round 7;
-topology-keyed entries are the conservative default where real ICI
+hit byte-for-byte by a real world-1 job. Written against the private
+seams of the pinned jax 0.9.0 (signatures asserted in
+tests/test_chip_smoke.py), env opt-out, CPU-only by default
+(``EDL_CACHE_PORTABLE_KEYS=all`` extends it to TPU, which no chip run has
+tried; topology-keyed entries are the conservative default where real ICI
 topology differences could matter).
 
 **The AOT ladder** (:class:`AotLadder`). Once a stage reaches steady
@@ -144,80 +144,35 @@ def enable_portable_cache_keys() -> bool:
 
     Idempotent; returns True when the patch is (already) active. Opt out
     with ``EDL_CACHE_PORTABLE_KEYS=0``; ``=all`` extends beyond CPU.
-    Guarded like ``_enable_all_rank_cache_writes``: private-API drift
-    degrades to the stock topology-keyed behavior with a warning.
+    Replaces ``jax._src.cache_key._hash_accelerator_config`` — a private
+    seam of the pinned jax 0.9.0, whose ``(hash_obj, accelerators)``
+    signature tests/test_chip_smoke.py asserts. (The key's other
+    host-bound part, a filesystem path in the compile options, is switched
+    off through a public option in ``enable_compilation_cache``.)
     """
     mode = os.environ.get("EDL_CACHE_PORTABLE_KEYS", "cpu").lower()
     if mode in ("0", "off", "none"):
         return False
-    try:
-        from jax._src import cache_key as _ck
+    from jax._src import cache_key as _ck
 
-        current = getattr(_ck, "_hash_accelerator_config", None)
-        if current is None:
-            logger.warning(
-                "jax._src.cache_key._hash_accelerator_config not found; "
-                "cache keys stay topology-bound"
-            )
-            return False
-        if not getattr(current, "_edl_portable", False):
-            hash_devices = _ck._hash_devices
-            hash_platform = _ck._hash_platform
-
-            def _portable(hash_obj, accelerators, backend, _orig=current):
-                platform = getattr(backend, "platform", "")
-                if mode != "all" and platform != "cpu":
-                    return _orig(hash_obj, accelerators, backend)
-                # the program's own devices + platform — JAX's documented
-                # fallback for backends without serializable topology. The
-                # device COUNT and KINDS still key (a 4-device program never
-                # collides with a 2-device one); what no longer keys is the
-                # process topology the compile happened to run inside.
-                hash_devices(hash_obj, accelerators)
-                hash_platform(hash_obj, backend)
-
-            _portable._edl_portable = True
-            _ck._hash_accelerator_config = _portable
-
-        # second host-bound leak: jax arms XLA's per-fusion autotune
-        # cache UNDER the compilation cache dir, and the resulting
-        # debug-option (a local filesystem path) rides the serialized
-        # compile options into the key — so two pods with different
-        # cache dir paths can never share an entry. Clear it for keying
-        # exactly like jax clears xla_gpu_cuda_data_dir (a path is not a
-        # compile input); the real option still reaches the compiler.
-        orig_opts = getattr(_ck, "_hash_serialized_compile_options", None)
-        if orig_opts is not None and not getattr(
-            orig_opts, "_edl_portable", False
-        ):
-            import copy as _copy
-
-            def _portable_opts(
-                hash_obj, compile_options_obj, *args, _orig=orig_opts, **kw
-            ):
-                try:
-                    stripped = _copy.deepcopy(compile_options_obj)
-                    dbg = stripped.executable_build_options.debug_options
-                    for field in (
-                        "xla_gpu_per_fusion_autotune_cache_dir",
-                        "xla_gpu_experimental_autotune_cache_dir",
-                    ):
-                        if getattr(dbg, field, ""):
-                            setattr(dbg, field, "")
-                    compile_options_obj = stripped
-                except Exception:  # noqa: BLE001 — proto drift: hash as-is
-                    pass
-                return _orig(hash_obj, compile_options_obj, *args, **kw)
-
-            _portable_opts._edl_portable = True
-            _ck._hash_serialized_compile_options = _portable_opts
+    current = _ck._hash_accelerator_config
+    if getattr(current, "_edl_portable", False):
         return True
-    except Exception as exc:  # noqa: BLE001 — private API drift: degrade
-        logger.warning(
-            "could not enable portable cache keys (%s); resize ladder "
-            "entries will only be hit by same-topology incarnations", exc
-        )
-        return False
+
+    def _portable(hash_obj, accelerators, _orig=current):
+        if mode != "all" and accelerators.flat[0].platform != "cpu":
+            return _orig(hash_obj, accelerators)
+        # the program's own device kinds — JAX's documented fallback for
+        # backends without serializable topology (the platform and its
+        # version are a key component of their own). The device COUNT
+        # and KINDS still key (a 4-device program never collides with a
+        # 2-device one); what no longer keys is the process topology the
+        # compile happened to run inside.
+        _ck._hash_devices(hash_obj, accelerators)
+
+    _portable._edl_portable = True
+    _ck._hash_accelerator_config = _portable
+    return True
 
 
 # -- cache hit/miss instrumentation -------------------------------------------
@@ -230,44 +185,39 @@ def instrument_compilation_cache() -> bool:
     """Count persistent-cache hits/misses/writes at the jit seam.
 
     Wraps ``compilation_cache.get_executable_and_time`` /
-    ``put_executable_and_time`` so resize_bench and the monitor can tell
-    "cache load" from "real compile" without parsing logs, and times the
-    miss→write interval into ``edl_train_restage_compile_seconds`` (the
-    actual XLA compile the miss forced). Idempotent, drift-guarded,
-    opt-out with ``EDL_CACHE_EVENTS=0``.
+    ``put_executable_and_time`` (private seams of the pinned jax 0.9.0;
+    signatures asserted in tests/test_chip_smoke.py) so resize_bench, the
+    monitor and chip_smoke.py can tell "cache load" from "real compile"
+    without parsing logs, and times the miss→write interval into
+    ``edl_train_restage_compile_seconds`` (the actual XLA compile the miss
+    forced). Idempotent; opt-out with ``EDL_CACHE_EVENTS=0``.
     """
     if os.environ.get("EDL_CACHE_EVENTS", "1") == "0":
         return False
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax._src import compilation_cache as _cc
 
-        orig_get = _cc.get_executable_and_time
-        orig_put = _cc.put_executable_and_time
-        if getattr(orig_get, "_edl_events", False):
-            return True
+    orig_get = _cc.get_executable_and_time
+    orig_put = _cc.put_executable_and_time
+    if getattr(orig_get, "_edl_events", False):
+        return True
 
-        def get_wrapper(cache_key, compile_options, backend,
-                        _orig=orig_get, **kw):
-            if getattr(_in_ladder, "active", False):
-                return _orig(cache_key, compile_options, backend, **kw)
-            executable, compile_time = _orig(
-                cache_key, compile_options, backend, **kw
-            )
-            if executable is None:
-                _M_CACHE_EVENTS.inc(kind="miss")
-                with _miss_lock:
-                    _miss_started[cache_key] = time.monotonic()
-            else:
-                _M_CACHE_EVENTS.inc(kind="hit")
-            return executable, compile_time
+    def get_wrapper(cache_key, compile_options, backend, executable_devices):
+        found = orig_get(
+            cache_key, compile_options, backend, executable_devices
+        )
+        if getattr(_in_ladder, "active", False):
+            return found
+        if found[0] is None:
+            _M_CACHE_EVENTS.inc(kind="miss")
+            with _miss_lock:
+                _miss_started[cache_key] = time.monotonic()
+        else:
+            _M_CACHE_EVENTS.inc(kind="hit")
+        return found
 
-        def put_wrapper(cache_key, module_name, executable, backend,
-                        compile_time, _orig=orig_put, **kw):
-            if getattr(_in_ladder, "active", False):
-                return _orig(
-                    cache_key, module_name, executable, backend,
-                    compile_time, **kw
-                )
+    def put_wrapper(cache_key, module_name, executable, backend,
+                    compile_time):
+        if not getattr(_in_ladder, "active", False):
             with _miss_lock:
                 t0 = _miss_started.pop(cache_key, None)
             if t0 is not None:
@@ -279,19 +229,15 @@ def instrument_compilation_cache() -> bool:
                         module_name, time.monotonic() - t0,
                     )
             _M_CACHE_EVENTS.inc(kind="write")
-            return _orig(
-                cache_key, module_name, executable, backend, compile_time,
-                **kw
-            )
+        return orig_put(
+            cache_key, module_name, executable, backend, compile_time
+        )
 
-        get_wrapper._edl_events = True
-        put_wrapper._edl_events = True
-        _cc.get_executable_and_time = get_wrapper
-        _cc.put_executable_and_time = put_wrapper
-        return True
-    except Exception as exc:  # noqa: BLE001
-        logger.warning("cache-event instrumentation unavailable: %s", exc)
-        return False
+    get_wrapper._edl_events = True
+    put_wrapper._edl_events = True
+    _cc.get_executable_and_time = get_wrapper
+    _cc.put_executable_and_time = put_wrapper
+    return True
 
 
 def cache_event_counts() -> Dict[str, int]:

@@ -51,54 +51,55 @@ def hot_restage_enabled() -> bool:
 
 
 def enable_compilation_cache(path: str) -> None:
-    """Point XLA's persistent compilation cache at ``path``.
+    """Arm XLA's persistent compilation cache at ``path``.
 
     The resize-cost lever: stop-resume elasticity restarts every JAX
     process per stage, and without a persistent cache each incarnation
-    recompiles the train step from scratch — 10s of seconds of the
-    measured spawn→first-step downtime. With a job-scoped cache dir the
-    SECOND visit to any world size loads the executable instead of
-    compiling it (cache keys include topology, so each world size
-    compiles once per host, ever). Thresholds drop to zero so even small
-    test/CPU computations cache. Must run before the first computation;
-    safe to call again with the same path.
+    recompiles the train step from scratch. With one cache dir shared by
+    every incarnation the SECOND visit to any world size loads the
+    executable instead of compiling it. Thresholds drop to zero so even
+    small test/CPU computations cache. Must run before the first
+    computation; safe to call again.
 
-    An unusable path (permissions, read-only fs) degrades to no cache with
-    a warning instead of killing the worker: the cache is a performance
-    lever, never a correctness requirement.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache and ``path`` is
+    ignored: jax read the variable at import, and nothing here sets a
+    directory. A directory that cannot be used is an ERROR, never a quiet
+    uncached run — a job that wants none says so (``--compile_cache_dir
+    none``). No ownership or mode test: the default lives inside the
+    checkout (whoever can write there can already change the code), and
+    any other directory was placed by the operator.
     """
     import jax
 
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if placed:
+        path = placed
     try:
-        # 0700 + ownership check: XLA deserializes executables from this
-        # dir, so a pre-created world-writable path on a shared /tmp is a
-        # code-injection surface, not just a perf artifact
         os.makedirs(path, mode=0o700, exist_ok=True)
-        st = os.lstat(path)
-        uid = os.getuid() if hasattr(os, "getuid") else st.st_uid
-        if st.st_uid != uid or (st.st_mode & 0o022):
-            logger.warning(
-                "compilation cache dir %s not exclusively ours "
-                "(owner uid %d, mode %o); continuing uncached",
-                path,
-                st.st_uid,
-                st.st_mode & 0o777,
-            )
-            return
         probe = os.path.join(path, ".edl_probe_%d" % os.getpid())
         with open(probe, "w"):
             pass
         os.unlink(probe)
     except OSError as exc:
-        logger.warning(
-            "compilation cache dir %s unusable (%s); continuing uncached",
-            path,
-            exc,
+        raise RuntimeError(
+            "compilation cache dir %s is unusable (%s): place the cache "
+            "with JAX_COMPILATION_CACHE_DIR, or run uncached on purpose "
+            "with --compile_cache_dir none" % (path, exc)
+        ) from exc
+    if not placed:
+        jax.config.update("jax_compilation_cache_dir", path)
+    elif jax.config.jax_compilation_cache_dir != placed:
+        raise RuntimeError(
+            "JAX_COMPILATION_CACHE_DIR=%s was set after jax was imported "
+            "(jax caches in %r): set it in the environment the process "
+            "starts with" % (placed, jax.config.jax_compilation_cache_dir)
         )
-        return
-    jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax would otherwise arm XLA's GPU autotune cache UNDER the cache
+    # dir, and that path rides the compile options into every cache key —
+    # two pods with different cache paths could never share an entry
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
     if os.environ.get("EDL_CACHE_ALL_RANKS", "1") == "1":
         _enable_all_rank_cache_writes()
     # AOT resize plane (train/aot.py): topology-independent cache keys —
@@ -121,88 +122,53 @@ def _enable_all_rank_cache_writes() -> None:
     ever writes theirs: every elastic restage pays a full recompile on
     every non-zero rank, forever. On a host-local (or per-process-keyed)
     cache dir the contention rationale doesn't apply — distinct keys
-    mean distinct files. This wraps ``jax._src.compiler._cache_write``
-    to drop only that gate; if JAX's internals change shape, it logs
-    and leaves the default behavior (``EDL_CACHE_ALL_RANKS=0`` opts
-    out).
+    mean distinct files. This replaces ``jax._src.compiler._cache_write``
+    (a private seam of the pinned jax 0.9.0, signature asserted in
+    tests/test_chip_smoke.py) with a copy that drops only that gate;
+    ``EDL_CACHE_ALL_RANKS=0`` opts out.
     """
-    try:
-        from jax._src import compiler as _compiler
+    import functools
+    import types
 
-        orig = getattr(_compiler, "_cache_write", None)
-        if orig is None or getattr(orig, "_edl_all_ranks", False):
-            if orig is None:
-                logger.warning(
-                    "jax._src.compiler._cache_write not found; cache "
-                    "writes stay rank-0-only"
-                )
-            return
+    from jax._src import compiler as _compiler
 
-        real_distributed = _compiler.distributed
-
-        class _GSView:
-            """global_state view reporting process_id 0 (write-gate only)."""
-
-            def __init__(self, gs):
-                self._gs = gs
-
-            process_id = 0
-
-            def __getattr__(self, name):
-                return getattr(self._gs, name)
-
-        class _DistView:
-            @property
-            def global_state(self):
-                return _GSView(real_distributed.global_state)
-
-            def __getattr__(self, name):
-                return getattr(real_distributed, name)
-
-        import functools
-        import types
-
-        # A COPY of the function whose `distributed` global resolves to
-        # the view: no runtime module mutation, no cross-thread effect on
-        # other compiler-module code.
-        patched = types.FunctionType(
-            orig.__code__,
-            {**orig.__globals__, "distributed": _DistView()},
-            orig.__name__,
-            orig.__defaults__,
-            orig.__closure__,
-        )
-        patched = functools.wraps(orig)(patched)
-        patched._edl_all_ranks = True
-        _compiler._cache_write = patched
-    except Exception as exc:  # private API drift: degrade, don't break
-        logger.warning(
-            "could not enable all-rank cache writes (%s); cache writes "
-            "stay rank-0-only",
-            exc,
-        )
-
-
-def _enable_cpu_collectives() -> None:
-    """Arm Gloo CPU collectives before ``jax.distributed.initialize``.
-
-    jax 0.4.37's CPU backend refuses to compile multi-process SPMD
-    programs ("Multiprocess computations aren't implemented on the CPU
-    backend") unless a collectives implementation is configured BEFORE
-    the backend comes up — the default is none, so every multi-worker
-    CPU world (the whole resize-bench/chaos rig) would die at its first
-    cross-process compile. Guarded: older/newer jax without the option
-    keeps its own default; ``EDL_CPU_COLLECTIVES`` overrides ("0" to
-    skip, else the implementation name)."""
-    choice = os.environ.get("EDL_CPU_COLLECTIVES", "gloo")
-    if choice in ("0", "off", "none") or os.environ.get("JAX_PLATFORMS", "") != "cpu":
+    orig = _compiler._cache_write
+    if getattr(orig, "_edl_all_ranks", False):
         return
-    import jax
+    real_distributed = _compiler.distributed
 
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", choice)
-    except Exception as exc:  # noqa: BLE001 — option drift: use jax's default
-        logger.debug("cpu collectives %r not configurable: %s", choice, exc)
+    class _GSView:
+        """global_state view reporting process_id 0 (write-gate only)."""
+
+        def __init__(self, gs):
+            self._gs = gs
+
+        process_id = 0
+
+        def __getattr__(self, name):
+            return getattr(self._gs, name)
+
+    class _DistView:
+        @property
+        def global_state(self):
+            return _GSView(real_distributed.global_state)
+
+        def __getattr__(self, name):
+            return getattr(real_distributed, name)
+
+    # A COPY of the function whose `distributed` global resolves to the
+    # view: no runtime module mutation, no cross-thread effect on other
+    # compiler-module code.
+    patched = types.FunctionType(
+        orig.__code__,
+        {**orig.__globals__, "distributed": _DistView()},
+        orig.__name__,
+        orig.__defaults__,
+        orig.__closure__,
+    )
+    patched = functools.wraps(orig)(patched)
+    patched._edl_all_ranks = True
+    _compiler._cache_write = patched
 
 
 _cache_pulled = False
@@ -362,7 +328,6 @@ def init(env: Optional[WorkerEnv] = None) -> WorkerEnv:
     if env.world_size > 1 and env.coordinator:
         import jax
 
-        _enable_cpu_collectives()
         logger.info(
             "worker %d/%d joining stage %s (coordinator %s)",
             env.global_rank,

@@ -53,9 +53,6 @@ def synthetic_batch(rng, batch, vocab):
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=200)
     parser.add_argument("--batch", type=int, default=256)

@@ -114,9 +114,6 @@ def run_student(args):
 
 def main():
     import jax
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--role", choices=("teacher", "student"), required=True)
     parser.add_argument("--store", required=True)

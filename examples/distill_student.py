@@ -38,9 +38,6 @@ def distill_loss(logits, targets):
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--store", required=True)
     parser.add_argument("--job_id", default="distill")

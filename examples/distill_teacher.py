@@ -28,9 +28,6 @@ from edl_tpu.train import create_state
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--store", required=True)
     parser.add_argument("--job_id", default="distill")

@@ -56,9 +56,6 @@ def token_batches(loader, batch, seq):
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--data_dir", default=None)
     parser.add_argument("--epochs", type=int, default=2)

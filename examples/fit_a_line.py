@@ -45,9 +45,6 @@ def records(epoch):
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--epochs", type=int, default=10)
     parser.add_argument("--batch", type=int, default=128)

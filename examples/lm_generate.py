@@ -27,10 +27,6 @@ def main():
     parser.add_argument("--period", type=int, default=4)
     args = parser.parse_args()
 
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
-
     import jax
     import jax.numpy as jnp
     import optax

@@ -39,9 +39,6 @@ def lm_loss(logits, labels):
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=20)
     parser.add_argument("--batch", type=int, default=8)

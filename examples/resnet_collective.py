@@ -53,9 +53,6 @@ adjusts = AdjustRegistry()
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     parser = argparse.ArgumentParser()
     parser.add_argument("--epochs", type=int, default=3)
     parser.add_argument("--steps_per_epoch", type=int, default=10)

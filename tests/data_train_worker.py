@@ -116,9 +116,6 @@ def featurize(record: bytes):
 
 
 def run_train():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
     import jax
     import jax.numpy as jnp
     import numpy as np

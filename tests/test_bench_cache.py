@@ -1,141 +1,6 @@
-"""bench.py stale-replay refusal: a cached TPU measurement may only be
-replayed while the perf-relevant code (models/train/ops/bench) is unchanged
-since it was taken — otherwise the honest answer is _tpu_unavailable.
-
-Round-2 verdict weak #5: BENCH_r02.json silently replayed a measurement
-taken 16 hours (and many perf commits) earlier.
-"""
-
-import json
-import os
-import subprocess
-import time
-
-import pytest
-
-
-def _git(repo, *args):
-    out = subprocess.run(
-        ["git", *args], cwd=repo, capture_output=True, text=True, timeout=30
-    )
-    assert out.returncode == 0, out.stderr
-    return out.stdout.strip()
-
-
-@pytest.fixture()
-def bench():
-    import importlib.util
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(root, "bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture()
-def repo(tmp_path):
-    """A tiny git repo with the perf-path layout bench.py watches."""
-    repo = tmp_path / "r"
-    (repo / "edl_tpu" / "models").mkdir(parents=True)
-    (repo / "edl_tpu" / "train").mkdir(parents=True)
-    _git(tmp_path, "init", "-q", str(repo))
-    _git(repo, "config", "user.email", "t@t")
-    _git(repo, "config", "user.name", "t")
-    (repo / "edl_tpu" / "models" / "m.py").write_text("A = 1\n")
-    (repo / "README.md").write_text("readme\n")
-    _git(repo, "add", "-A")
-    _git(repo, "commit", "-qm", "base")
-    return repo
-
-
-def _cache_file(tmp_path, sha, age_s=60.0):
-    path = tmp_path / "cache.json"
-    path.write_text(
-        json.dumps(
-            {
-                "metric": "resnet50_vd_train_throughput_tpu",
-                "value": 1000.0,
-                "measured_at": time.time() - age_s,
-                "measured_sha": sha,
-            }
-        )
-    )
-    return str(path)
-
-
-def test_replays_when_perf_paths_untouched(bench, repo, tmp_path):
-    sha = _git(repo, "rev-parse", "HEAD")
-    # doc-only commit after the measurement: still a faithful replay
-    (repo / "README.md").write_text("changed\n")
-    _git(repo, "add", "-A")
-    _git(repo, "commit", "-qm", "docs")
-    cached = bench._load_result_cache(
-        _cache_file(tmp_path, sha), repo_dir=str(repo)
-    )
-    assert cached is not None and cached["value"] == 1000.0
-
-
-def test_refuses_replay_across_perf_commit(bench, repo, tmp_path):
-    sha = _git(repo, "rev-parse", "HEAD")
-    (repo / "edl_tpu" / "models" / "m.py").write_text("A = 2\n")
-    _git(repo, "add", "-A")
-    _git(repo, "commit", "-qm", "model change")
-    assert bench._load_result_cache(
-        _cache_file(tmp_path, sha), repo_dir=str(repo)
-    ) is None
-
-
-def test_refuses_replay_with_uncommitted_perf_change(bench, repo, tmp_path):
-    sha = _git(repo, "rev-parse", "HEAD")
-    (repo / "edl_tpu" / "train").mkdir(exist_ok=True)
-    tracked = repo / "edl_tpu" / "models" / "m.py"
-    tracked.write_text("A = 3\n")  # dirty working tree, no commit
-    assert bench._load_result_cache(
-        _cache_file(tmp_path, sha), repo_dir=str(repo)
-    ) is None
-
-
-def test_refuses_unstamped_or_unknown_sha(bench, repo, tmp_path):
-    assert bench._load_result_cache(
-        _cache_file(tmp_path, sha=None), repo_dir=str(repo)
-    ) is None
-    assert bench._load_result_cache(
-        _cache_file(tmp_path, sha="f" * 40), repo_dir=str(repo)
-    ) is None
-
-
-def test_still_refuses_stale_by_age(bench, repo, tmp_path):
-    sha = _git(repo, "rev-parse", "HEAD")
-    assert bench._load_result_cache(
-        _cache_file(tmp_path, sha, age_s=49 * 3600), repo_dir=str(repo)
-    ) is None
-
-
-def test_store_stamps_sha(bench, tmp_path, monkeypatch):
-    target = tmp_path / "c.json"
-    monkeypatch.setattr(bench, "_RESULT_CACHE", str(target))
-    monkeypatch.setattr(bench, "_perf_paths_uncommitted", lambda *a: False)
-    bench._store_result_cache(
-        {"metric": "resnet50_vd_train_throughput_tpu", "value": 1.0}
-    )
-    stamped = json.loads(target.read_text())
-    assert stamped["measured_sha"] == bench._git_sha()
-    assert stamped["measured_at"] == pytest.approx(time.time(), abs=30)
-
-
-def test_store_refuses_dirty_tree(bench, tmp_path, monkeypatch):
-    """A measurement taken with uncommitted perf-path edits must not be
-    cached: HEAD would not identify the measured code."""
-    target = tmp_path / "c.json"
-    monkeypatch.setattr(bench, "_RESULT_CACHE", str(target))
-    monkeypatch.setattr(bench, "_perf_paths_uncommitted", lambda *a: True)
-    bench._store_result_cache(
-        {"metric": "resnet50_vd_train_throughput_tpu", "value": 1.0}
-    )
-    assert not target.exists()
+"""bench.py keeps no result cache and replays nothing (with no chip it
+exits non-zero and prints no number); what stays testable off the chip is
+the shared cost model it re-exports."""
 
 
 def test_roofline_from_xla_cost_model():

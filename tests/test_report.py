@@ -479,8 +479,8 @@ class TestReportCLI:
 class TestImportLegacy:
     def test_import_real_checked_in_history(self, tmp_path):
         """The satellite: the repo's own bench_results/ (+ repo-root
-        BENCH_r*.json) normalize into index rows — BENCH_r04 arrives
-        stale, BENCH_r05's honest 0.0 arrives excluded."""
+        BENCH_r*.json) normalize into index rows — BENCH_r05's honest
+        0.0 arrives excluded."""
         root = str(tmp_path / "runs")
         rc, out = run_cli([
             "--runs", root, "--import-legacy",
@@ -490,7 +490,6 @@ class TestImportLegacy:
         summary = json.loads(out)
         assert summary["value"] >= 20
         rows = {r["source"]: r for r in run_archive.read_index(root)}
-        assert rows["BENCH_r04.json"]["stale"] is True
         r05 = rows["BENCH_r05.json"]
         assert r05["excluded"] is True
         # the honest 0.0 is IN the trend under the real metric name

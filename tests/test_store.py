@@ -211,6 +211,53 @@ def test_lease_keeper_keeps_alive(server, client):
     assert client.get("/hb/k") is None
 
 
+_FROZEN_TOGETHER = """
+import sys, time
+sys.path.insert(0, %r)
+from edl_tpu.store import LeaseKeeper, StoreClient, StoreServer
+srv = StoreServer(host="127.0.0.1", port=0).start()
+client = StoreClient(srv.endpoint, timeout=5.0)
+lease = client.lease_grant(ttl=1.5)
+client.put("/hb/k", b"v", lease=lease)
+lost = []
+keeper = LeaseKeeper(client, lease, ttl=1.5, on_lost=lambda: lost.append(1))
+print("READY", flush=True)
+sys.stdin.readline()            # the test froze and thawed us meanwhile
+time.sleep(1.0)                 # let a late sweep / a late keepalive land
+print("LOST" if lost or client.get("/hb/k") is None else "KEPT", flush=True)
+"""
+
+
+def test_frozen_process_does_not_expire_its_own_leases():
+    """The launcher's shape: store server and lease owner in ONE process.
+    Freeze the whole process past the TTL (a TPU runtime start does that to
+    a whole VM): the serve loop must give the owners the lost time back
+    instead of waking, sweeping, and expiring leases whose keepalive
+    thread was exactly as frozen."""
+    import os
+    import signal
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-c", _FROZEN_TOGETHER % root],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
+    )
+    try:
+        assert proc.stdout.readline().strip() == "READY"
+        time.sleep(0.6)  # mid-interval: the next keepalive is not yet due
+        proc.send_signal(signal.SIGSTOP)
+        time.sleep(3.0)  # two TTLs
+        proc.send_signal(signal.SIGCONT)
+        proc.stdin.write("go\n")
+        proc.stdin.flush()
+        assert proc.stdout.readline().strip() == "KEPT"
+    finally:
+        proc.kill()
+        proc.wait()
+
+
 def test_watch_backlog_replay(server, client):
     client.put("/w/a", b"1")
     client.put("/w/b", b"2")
@@ -854,6 +901,18 @@ class TestEpochState:
         clock.now += 4.9  # past the ORIGINAL deadline, inside the fresh one
         assert st.expire_leases() == []
         assert st.lease_keepalive(l1)
+
+
+def test_extend_lease_deadlines_moves_every_lease():
+    clock = FakeClock()
+    st = StoreState(clock=clock)
+    lease = st.lease_grant(5.0)
+    clock.now += 4.9
+    assert st.extend_lease_deadlines(7.0) == 1
+    clock.now += 7.0  # past the original deadline, inside the extended one
+    assert st.expire_leases() == []
+    clock.now += 0.2
+    assert st.expire_leases_with_ids()[1] == [lease]
 
 
 def test_salvage_wal_any_truncation_yields_valid_prefix():

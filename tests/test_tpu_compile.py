@@ -1,0 +1,140 @@
+"""The flash kernels against the real TPU compiler, without a TPU.
+
+CPU interpret mode does not check Mosaic tiling, VMEM budgets or
+``dimension_semantics``: a kernel can pass every interpret-mode test and
+still be refused by the chip's compiler. libtpu compiles for a *described*
+``v5e:2x2`` topology with no chip attached, so each kernel of the training
+path is lowered with ``interpret=False`` at real widths and compiled here.
+
+A compile that passes is a compile, not a chip run: nothing executes, so
+these cases say nothing about values or time (``chip_smoke.py`` does).
+The public entry points (``attention``, ``flash_attention``) ask
+``jax.default_backend()``, see the CPU and take interpret mode or the dense
+reference — the cases call the ``*_forward`` / ``*_backward_kernels``
+functions directly.
+"""
+
+import importlib
+import os
+import re
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+
+A = importlib.import_module("edl_tpu.ops.attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip("TPU topology cannot be described here: %s" % exc)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A described-device executable is written to the persistent cache but
+    cannot be read back without a chip (the next compile warns and compiles
+    again) — keep these compiles out of whatever cache an earlier test
+    armed in this process."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+# (b, h, h_kv, t, d), bf16, causal
+LM = (16, 16, 16, 2048, 64)        # chip_smoke's lm phase: b16 x seq 2048
+HD128 = (2, 8, 8, 4096, 128)
+GQA = (2, 16, 4, 2048, 64)
+AT_MAX = (1, 16, 16, A._flash_max_seq(), 64)
+PAST_MAX = (1, 16, 16, A._flash_max_seq() + 512, 64)
+LONG = (1, 16, 16, 8192, 64)       # chip_smoke's flash2 comparison shape
+
+FWD_NAME = {"flash": "_flash_kernel", "flash2": "_flash2_kernel"}
+BWD_NAMES = {
+    "flash": ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"),
+    "flash2": ("_flash2_bwd_dq_kernel", "_flash2_bwd_dkv_kernel"),
+}
+
+CASES = [
+    pytest.param(family, direction, shape, id="%s-%s-%s" % (family, direction, name))
+    for name, shape, families in (
+        ("lm", LM, ("flash", "flash2")),
+        ("hd128", HD128, ("flash", "flash2")),
+        ("gqa", GQA, ("flash", "flash2")),
+        # the whole-KV kernel at the dispatch boundary and just past it:
+        # the dispatch remaps to flash2 there because an earlier compiler
+        # refused whole-KV past 4096 — this records what today's says
+        ("at_max_seq", AT_MAX, ("flash",)),
+        ("past_max_seq", PAST_MAX, ("flash",)),
+        ("seq8192", LONG, ("flash2",)),
+    )
+    for family in families
+    for direction in ("fwd", "bwd")
+]
+
+
+def _kernel_names(lowered_text):
+    return re.findall(r'kernel_name = "(\w+)"', lowered_text)
+
+
+@pytest.mark.parametrize("family,direction,shape", CASES)
+def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
+    b, h, h_kv, t, d = shape
+    scale = d ** -0.5
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    if family == "flash":
+        fwd_blocks, bwd_blocks = A._kernel_blocks(t)
+        forward, backward = A._flash_forward, A._flash_backward_kernels
+    else:
+        fwd_blocks, bwd_blocks = A._FLASH2_BLOCKS_FWD, A._FLASH2_BLOCKS_BWD
+        forward, backward = A._flash2_forward, A._flash2_backward_kernels
+    if direction == "fwd":
+        bq, bk = fwd_blocks
+        fn = lambda q, k, v: forward(q, k, v, True, scale, bq, bk, False)
+        args = (q, kv, kv)
+        want = [FWD_NAME[family]]
+    else:
+        bq, bk = (A._fit_block(blk, t) for blk in bwd_blocks)
+        fn = lambda q, k, v, g, lse, delta: backward(
+            q, k, v, g, lse, delta, True, scale, bq, bk, False
+        )
+        row = sds((b * h, t), jnp.float32)
+        args = (q, kv, kv, q, row, row)
+        want = list(BWD_NAMES[family])
+
+    lowered = jax.jit(fn).lower(*args)
+    # the kernel itself was lowered — not the ragged-shape dense fallback
+    assert _kernel_names(lowered.as_text()) == want
+    compiled = lowered.compile()  # raises what the chip's compiler would
+    assert compiled.as_text().count("tpu_custom_call") == len(want)
+
+
+def test_grid_pipeline_kwargs_carry_dimension_semantics():
+    """jax 0.9.0 has ``pltpu.CompilerParams(dimension_semantics=...)``: the
+    flash2 family must never run without it (the old guard dropped it
+    silently on an API mismatch)."""
+    params = A._grid_pipeline_kwargs()["compiler_params"]
+    assert tuple(str(s) for s in params.dimension_semantics) == (
+        "parallel", "parallel", "arbitrary",
+    )

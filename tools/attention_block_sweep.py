@@ -53,10 +53,6 @@ def main():
     )
     args = p.parse_args()
 
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
-
     import jax
     import jax.numpy as jnp
 

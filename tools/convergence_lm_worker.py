@@ -39,10 +39,6 @@ SEQ = int(os.environ.get("TEST_SEQ", "48"))
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
-
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -30,10 +30,6 @@ EPOCH_PAUSE = float(os.environ.get("TEST_EPOCH_PAUSE", "0"))
 
 
 def main():
-    from edl_tpu.utils.platform import maybe_pin_cpu
-
-    maybe_pin_cpu()
-
     import numpy as np
     import optax
     from sklearn.datasets import load_digits

@@ -150,7 +150,7 @@ def _local_drill(steps: int, out_dir: Optional[str]) -> Dict:
     try:
         for _ in range(steps + 4):
             w, loss = toy_step(w, x)
-            float(jax.device_get(loss))  # honest per-step sync (bench.py note)
+            float(jax.device_get(loss))  # per-step sync: count finished steps
             telemetry.observe_step()
             controller.on_step()
     finally:

@@ -23,8 +23,8 @@ nonzero on any ``regressed`` verdict — ``tools/verify.sh`` and
 ``run_tpu_suite`` run it as the perf gate. ``--import-legacy``
 normalizes the checked-in ``bench_results/`` history (and the repo-root
 ``BENCH_r*.json`` round summaries beside it) into index rows so trend
-lines start from real history — BENCH_r04 arrives flagged stale and
-BENCH_r05's honest 0.0 arrives excluded-from-baseline.
+lines start from real history — BENCH_r05's honest 0.0 arrives
+excluded-from-baseline.
 """
 
 from __future__ import annotations
