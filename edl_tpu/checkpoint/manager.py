@@ -226,8 +226,14 @@ class CheckpointManager:
             # resize continuity sentinel: the manifest carries a
             # {step, loss, param_norm} numerics fingerprint — restore
             # re-derives the norm (quarantining mismatches) and the
-            # restaged worker's probe asserts loss continuity against it
-            status_doc = obs_numerics.stamp_fingerprint(status_doc, state, step)
+            # restaged worker's probe asserts loss continuity against it.
+            # Its own span: the stamp fetches every parameter (a device
+            # sync), which the ckpt_save span below leaves out while
+            # edl_ckpt_save_seconds counts it
+            with obs_trace.span("ckpt_stamp", step=step, epoch=status.epoch):
+                status_doc = obs_numerics.stamp_fingerprint(
+                    status_doc, state, step
+                )
         except Exception as exc:  # noqa: BLE001 — the stamp must never fail a save
             logger.warning("numerics fingerprint stamp failed: %s", exc)
         with obs_trace.child_span("ckpt_save", step=str(step)):

@@ -25,7 +25,22 @@ from typing import Any, Callable, Iterable, Iterator, Optional, Tuple
 
 import numpy as np
 
+from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.obs import trace as obs_trace
+
 __all__ = ["batched", "prefetch_to_device", "shuffled"]
+
+# counted where the step loop takes a batch off the queue, so that their
+# ratio is made at the one place the loop can starve
+_M_BATCHES = obs_metrics.counter(
+    "edl_data_prefetch_batches_total",
+    "device batches the consumer took off the prefetch queue",
+)
+_M_STARVED = obs_metrics.counter(
+    "edl_data_prefetch_starved_total",
+    "batches the consumer had to wait for: it found the prefetch queue "
+    "empty (an epoch's first batch always counts)",
+)
 
 
 def shuffled(records: Iterable[Any], buffer_size: int, seed: int) -> Iterator[Any]:
@@ -100,6 +115,7 @@ def prefetch_to_device(
     batches: Iterable[Any],
     depth: int = 2,
     sharding=None,
+    epoch: Optional[int] = None,
 ) -> Iterator[Any]:
     """Iterate ``batches`` with ``depth`` device transfers in flight.
 
@@ -111,6 +127,12 @@ def prefetch_to_device(
     call site. Staging HBM is bounded at ``depth + 1`` device batches:
     the queue holds at most ``depth`` and the feeder stages the next
     batch before blocking on the queue reservation.
+
+    The feeder thread leaves three spans a batch, numbered ``batch`` as
+    the consumer will see them and labelled with ``epoch`` when the
+    caller gives one: ``feed_next`` (the source iterator), ``feed_put``
+    (staging on the device) and ``feed_queue`` (blocked on a full queue:
+    the healthy state, since then the consumer is the slower side).
     """
     import jax
 
@@ -119,6 +141,8 @@ def prefetch_to_device(
     q: queue.Queue = queue.Queue(maxsize=depth)
     err: collections.deque = collections.deque(maxlen=1)
     stop = threading.Event()  # consumer gone: unblock + stop the feeder
+    label = {} if epoch is None else {"epoch": epoch}
+    tracer = obs_trace.get_tracer()
 
     def put(batch):
         if sharding is None:
@@ -133,16 +157,26 @@ def prefetch_to_device(
 
     def feeder():
         try:
-            for b in batches:
-                staged = put(b)
-                while not stop.is_set():
+            source = iter(batches)
+            k = 0
+            while True:
+                with tracer.span("feed_next", batch=k, **label):
                     try:
-                        q.put(staged, timeout=0.1)
+                        b = next(source)
+                    except StopIteration:
                         break
-                    except queue.Full:
-                        continue
+                with tracer.span("feed_put", batch=k, **label):
+                    staged = put(b)
+                with tracer.span("feed_queue", batch=k, **label):
+                    while not stop.is_set():
+                        try:
+                            q.put(staged, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
                 if stop.is_set():
                     return  # abandoned mid-epoch: drop staged batches
+                k += 1
         except BaseException as exc:  # re-raised consumer-side
             err.append(exc)
         finally:
@@ -157,11 +191,15 @@ def prefetch_to_device(
     t.start()
     try:
         while True:
+            starved = q.empty()
             item = q.get()
             if item is _Stop:
                 if err:
                     raise err.popleft()
                 return
+            _M_BATCHES.inc()
+            if starved:
+                _M_STARVED.inc()
             yield item
     finally:
         # runs on break/exception/GeneratorExit too: without it the

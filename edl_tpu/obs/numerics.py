@@ -47,6 +47,7 @@ import json
 import math
 import os
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -55,6 +56,7 @@ import numpy as np
 
 from edl_tpu.obs import events as obs_events
 from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.utils.log import get_logger
 
 logger = get_logger("obs.numerics")
@@ -300,16 +302,26 @@ class NumericsProbe:
 
     # -- step ingestion ---------------------------------------------------
 
-    def on_step(self, step: int, bundle: Optional[Dict[str, Any]]) -> None:
+    def on_step(
+        self,
+        step: int,
+        bundle: Optional[Dict[str, Any]],
+        epoch: Optional[int] = None,
+    ) -> Optional[Tuple[int, float]]:
         """Buffer this step's device bundle; publish on the throttle
         cadence. Publishing fetches the *previous* buffered bundle —
         already retired by a full step of device work — except on the
         very first call, which publishes synchronously so the plane is
         armed with real data the moment training produces any (a
         registered-but-never-set gauge would render 0.0 and trip the
-        grad-stall rule during a long first-step compile)."""
+        grad-stall rule during a long first-step compile).
+
+        A call that fetched returns ``(step, monotonic time)``: the step
+        whose bundle came back, and the moment it did — the step loop's
+        one proof that a numbered step has retired on the device.
+        ``epoch`` only labels the ``numerics_fetch`` span."""
         if self._closed or bundle is None:
-            return
+            return None
         self._calls += 1
         prev = self._held
         self._held = (int(step), bundle)
@@ -317,9 +329,10 @@ class NumericsProbe:
         with _LATEST_LOCK:
             _LATEST = self._held
         if self._calls == 1:
-            self._publish(int(step), bundle)
-        elif self._calls % self.every == 0 and prev is not None:
-            self._publish(prev[0], prev[1])
+            return self._publish(int(step), bundle, epoch)
+        if self._calls % self.every == 0 and prev is not None:
+            return self._publish(prev[0], prev[1], epoch)
+        return None
 
     def close(self) -> None:
         """Flush the held bundle (the final step's numbers must not be
@@ -348,15 +361,21 @@ class NumericsProbe:
             self._gauges[name] = g
         return g
 
-    def _publish(self, step: int, bundle: Dict[str, Any]) -> None:
+    def _publish(
+        self, step: int, bundle: Dict[str, Any], epoch: Optional[int] = None
+    ) -> Optional[Tuple[int, float]]:
         if step == self._last_pub_step:
-            return
+            return None
         self._last_pub_step = step
+        label = {} if epoch is None else {"epoch": epoch}
         try:
-            vals = jax.device_get(bundle)
+            # the step loop's one wait for the device
+            with obs_trace.span("numerics_fetch", step=step, **label):
+                vals = jax.device_get(bundle)
         except Exception as exc:  # noqa: BLE001 — a deleted buffer must not kill the loop
             logger.warning("numerics fetch failed at step %d: %s", step, exc)
-            return
+            return None
+        fetched = (step, time.monotonic())
         self.published += 1
         loss = float(vals["loss"])
         grad_norm = float(vals["grad_norm"])
@@ -405,6 +424,7 @@ class NumericsProbe:
             gns=gns,
             divergence=divergence,
         )
+        return fetched
 
     def _update_gns(self, vals) -> Optional[float]:
         half_sq = vals.get("half_sq")

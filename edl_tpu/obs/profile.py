@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import tempfile
 import threading
 import time
@@ -168,13 +169,115 @@ def step_cost(step_fn, *args, **kwargs) -> Dict:
     """XLA's cost analysis for one call of a jitted ``step_fn`` at the
     given arguments — via ``Lowered.cost_analysis()``, i.e. a jax trace
     but NO XLA compile (the compile already happened, or will, through
-    the jit cache). Returns {} on any failure: the cost model is
-    telemetry, never a correctness dependency."""
+    the jit cache). Accepts an already-``Lowered`` object directly (the
+    train loop lowers once for this and the memory plan). Returns {} on
+    any failure: the cost model is telemetry, never a correctness
+    dependency."""
     try:
-        return normalize_cost(step_fn.lower(*args, **kwargs).cost_analysis())
+        lowered = (
+            step_fn if hasattr(step_fn, "cost_analysis")
+            else step_fn.lower(*args, **kwargs)
+        )
+        return normalize_cost(lowered.cost_analysis())
     except Exception as exc:  # noqa: BLE001 — backend/API drift degrades to no cost
         logger.debug("step cost extraction failed: %s", exc)
         return {}
+
+
+# -- the step program's phases ------------------------------------------------
+
+PHASES = ("forward", "backward", "optimizer", "numerics", "other")
+
+# `%fusion.12 = f32[8]{0} fusion(...), calls=%fused_computation.12,
+#  metadata={op_name="jit(step)/optimizer/add" ...}`
+_HLO_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+
+# the running stage's compiled step and, once asked for, its table
+_step_executable = None
+_step_phases: Optional[Dict[str, str]] = None
+
+
+def phase_of(op_name: str) -> str:
+    """The phase a jax ``op_name`` belongs to, by the scopes
+    ``train/step.py`` enters: jax writes the forward pass as
+    ``jvp(forward)`` and what it derives from it (the backward pass,
+    recomputation included) as ``transpose(jvp(forward))``; the
+    half-batch averaging that finishes the gradient counts as backward."""
+    parts = op_name.split(";", 1)[0].split("/")
+    if "numerics" in parts:
+        return "numerics"
+    if "optimizer" in parts:
+        return "optimizer"
+    if "transpose(jvp(forward))" in parts or "grad_mean" in parts:
+        return "backward"
+    if "jvp(forward)" in parts or "forward" in parts:
+        return "forward"
+    return "other"
+
+
+def phases_of_hlo(text: str) -> Dict[str, str]:
+    """``{instruction name: phase}`` from an optimised HLO module's text:
+    every instruction by its own ``op_name``, and one that has none (a
+    fusion, a call) by that of the root of the computation it calls. {}
+    when nothing maps to ``forward``: the executable then predates the
+    scopes (a compile cache older than them handed it back), and a table
+    of ``other`` would pass for a measurement."""
+    own: Dict[str, str] = {}      # instruction -> op_name
+    calls: Dict[str, str] = {}    # instruction without one -> computation
+    roots: Dict[str, str] = {}    # computation -> its root instruction
+    computation = None
+    for line in text.splitlines():
+        started = _HLO_COMPUTATION.match(line)
+        if started:
+            computation = started.group(1)
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if not found:
+            continue
+        name = found.group(2)
+        if found.group(1) and computation is not None:
+            roots[computation] = name
+        op_name = _HLO_OP_NAME.search(line)
+        if op_name:
+            own[name] = op_name.group(1)
+        else:
+            called = _HLO_CALLS.search(line)
+            if called:
+                calls[name] = called.group(1)
+    for name, computation in calls.items():
+        root = roots.get(computation)
+        if root in own:
+            own[name] = own[root]
+    table = {name: phase_of(op_name) for name, op_name in own.items()}
+    return table if "forward" in table.values() else {}
+
+
+def set_step_executable(compiled) -> None:
+    """Keep the running stage's compiled step (the one ``Compiled`` the
+    train loop makes for the memory plan) for :func:`step_phases`."""
+    global _step_executable, _step_phases
+    _step_executable, _step_phases = compiled, None
+
+
+def step_phases() -> Dict[str, str]:
+    """``{HLO instruction name: "forward" | "backward" | "optimizer" |
+    "numerics" | "other"}`` for the step executable of the running
+    stage. A device trace names an event by its HLO instruction
+    (``fusion.124``), not by its scope: this is the join. Parsed from
+    the compiled step's text on first call, never inside ``fit``; {}
+    without a step, or where its names are missing (see
+    :func:`phases_of_hlo`)."""
+    global _step_phases
+    if _step_phases is None and _step_executable is not None:
+        try:
+            _step_phases = phases_of_hlo(_step_executable.as_text())
+        except Exception as exc:  # noqa: BLE001 — telemetry: a backend without text reads as no table
+            logger.warning("step phases unavailable: %s", exc)
+            _step_phases = {}
+    return dict(_step_phases or {})
 
 
 def roofline(cost, device_kind: str, peak: float, mfu: Optional[float] = None) -> Dict:

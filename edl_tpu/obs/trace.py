@@ -5,7 +5,12 @@ Replaces the stderr-only ``_RealTimeline`` one-shot profiler
 tracing plane:
 
 - ``span()`` is a context manager over ``time.monotonic()`` (wall-clock
-  NTP steps can't produce negative or bogus durations);
+  NTP steps can't produce negative or bogus durations). In a process
+  that holds jax the same ``with`` is also a
+  ``jax.profiler.TraceAnnotation("edl:<name>")``: one call, two sinks,
+  so a span taken while any profile is open sits on the profiler's own
+  clock beside the device's lines (``record()``, after the fact, stays
+  ring-only);
 - completed spans land in a ring buffer (``maxlen`` bounded — tracing a
   million-step job costs a fixed few MB, never OOM);
 - export is Chrome trace-event JSON (``chrome://tracing`` / Perfetto).
@@ -62,6 +67,7 @@ import contextvars
 import hashlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -282,10 +288,23 @@ def reset_context() -> None:
     _ctx.set(None)
 
 
-class _SpanHandle:
-    """Context manager minted by :meth:`SpanTracer.span`."""
+#: prefix of a program span's name inside a ``jax.profiler`` trace
+PROFILER_PREFIX = "edl:"
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+
+class _SpanHandle:
+    """Context manager minted by :meth:`SpanTracer.span`.
+
+    One call, two sinks: the span lands in the ring as ever and, in a
+    process that already holds jax, the same ``with`` enters
+    ``jax.profiler.TraceAnnotation("edl:" + name)``, so that whenever a
+    profile is open (anyone's) the span is a host event in the
+    ``.xplane.pb`` on the profiler's own clock, beside the device's
+    lines. Nothing imports jax for this: the store, the launcher and
+    the RPC servers stay ring-only.
+    """
+
+    __slots__ = ("_tracer", "name", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Dict) -> None:
         self._tracer = tracer
@@ -293,15 +312,24 @@ class _SpanHandle:
         self.args = args
 
     def __enter__(self) -> "_SpanHandle":
+        # getattr: another thread may be half way through `import jax`
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._annotation = None
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(
+                PROFILER_PREFIX + self.name
+            )
+            self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        dur = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args = dict(self.args, error=exc_type.__name__)
-        self._tracer.record(
-            self.name, self._t0, time.monotonic() - self._t0, **self.args
-        )
+        self._tracer.record(self.name, self._t0, dur, **self.args)
 
 
 class SpanTracer:
@@ -328,7 +356,8 @@ class SpanTracer:
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **args) -> _SpanHandle:
-        """``with tracer.span("train_step", step=i): ...``"""
+        """``with tracer.span("data_wait", step=i): ...`` — into the ring
+        and, where jax is loaded, into any open profile (:class:`_SpanHandle`)."""
         return _SpanHandle(self, name, args)
 
     def record(self, name: str, t0_mono: float, dur_s: float, **args) -> None:

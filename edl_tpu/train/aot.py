@@ -139,40 +139,55 @@ _in_ladder = threading.local()
 
 # -- portable cache keys ------------------------------------------------------
 
-def enable_portable_cache_keys() -> bool:
-    """Make persistent-cache keys topology-independent (see module doc).
+#: the scopes ``train/step.py`` names inside the step program, and a
+#: count to bump when one moves. jax keys a program after stripping its
+#: debug info, so a scope changes no key, and a cache written before the
+#: scopes were there hands back an executable whose ``op_name``s lack
+#: them (``obs/profile.py:step_phases`` would then find nothing). Mixed
+#: into every key, this makes such entries miss once.
+#: ``tests/test_timeline.py`` holds it to the scopes the step enters.
+STEP_SCOPES_KEY = "forward,grad_mean,optimizer,numerics/1"
 
-    Idempotent; returns True when the patch is (already) active. Opt out
-    with ``EDL_CACHE_PORTABLE_KEYS=0``; ``=all`` extends beyond CPU.
+
+def enable_portable_cache_keys() -> bool:
+    """Make persistent-cache keys topology-independent (see module doc),
+    and mix ``STEP_SCOPES_KEY`` into every key.
+
+    Idempotent; returns True when keys are portable. Opt out of that
+    with ``EDL_CACHE_PORTABLE_KEYS=0``; ``=all`` extends it beyond CPU
+    (read at every key: the scopes' constant is mixed in either way).
     Replaces ``jax._src.cache_key._hash_accelerator_config`` — a private
     seam of the pinned jax 0.9.0, whose ``(hash_obj, accelerators)``
     signature tests/test_chip_smoke.py asserts. (The key's other
     host-bound part, a filesystem path in the compile options, is switched
     off through a public option in ``enable_compilation_cache``.)
     """
-    mode = os.environ.get("EDL_CACHE_PORTABLE_KEYS", "cpu").lower()
-    if mode in ("0", "off", "none"):
-        return False
     from jax._src import cache_key as _ck
 
+    def mode() -> str:
+        return os.environ.get("EDL_CACHE_PORTABLE_KEYS", "cpu").lower()
+
     current = _ck._hash_accelerator_config
-    if getattr(current, "_edl_portable", False):
-        return True
+    if not getattr(current, "_edl_portable", False):
 
-    def _portable(hash_obj, accelerators, _orig=current):
-        if mode != "all" and accelerators.flat[0].platform != "cpu":
-            return _orig(hash_obj, accelerators)
-        # the program's own device kinds — JAX's documented fallback for
-        # backends without serializable topology (the platform and its
-        # version are a key component of their own). The device COUNT
-        # and KINDS still key (a 4-device program never collides with a
-        # 2-device one); what no longer keys is the process topology the
-        # compile happened to run inside.
-        _ck._hash_devices(hash_obj, accelerators)
+        def _portable(hash_obj, accelerators, _orig=current):
+            hash_obj.update(STEP_SCOPES_KEY.encode())
+            now = mode()
+            if now in ("0", "off", "none") or (
+                now != "all" and accelerators.flat[0].platform != "cpu"
+            ):
+                return _orig(hash_obj, accelerators)
+            # the program's own device kinds — JAX's documented fallback for
+            # backends without serializable topology (the platform and its
+            # version are a key component of their own). The device COUNT
+            # and KINDS still key (a 4-device program never collides with a
+            # 2-device one); what no longer keys is the process topology the
+            # compile happened to run inside.
+            _ck._hash_devices(hash_obj, accelerators)
 
-    _portable._edl_portable = True
-    _ck._hash_accelerator_config = _portable
-    return True
+        _portable._edl_portable = True
+        _ck._hash_accelerator_config = _portable
+    return mode() not in ("0", "off", "none")
 
 
 # -- cache hit/miss instrumentation -------------------------------------------

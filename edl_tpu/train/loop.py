@@ -47,7 +47,10 @@ from edl_tpu.obs import trace as obs_trace
 
 _M_STEP_SECONDS = obs_metrics.histogram(
     "edl_train_step_seconds",
-    "train step wall time, dispatch-to-dispatch (includes input wait)",
+    "seconds a train step, as the device paced it: wall time between two "
+    "moments the host knew a numbered step had retired, over the steps "
+    "between them (one observation a stretch; with the numerics plane off, "
+    "the dispatch-to-dispatch interval of every step)",
 )
 _M_STEPS = obs_metrics.counter(
     "edl_train_steps_total", "train steps dispatched"
@@ -77,6 +80,63 @@ DataFn = Callable[[int], Iterable]  # epoch -> records or ready batches
 _M_DRAINS = obs_metrics.counter(
     "edl_train_drains_total", "graceful worker drains (preemption notices honored)"
 )
+
+
+def _lower_step(step, state, device_batch, compile: bool):
+    """``(Lowered, Compiled)`` of the step that has just run: one more jax
+    trace and, with ``compile``, a compile-cache hit. Either is None where
+    it fails: what reads them is telemetry, never a correctness
+    dependency."""
+    lowered = compiled = None
+    try:
+        lowered = step.lower(state, device_batch)
+        if compile:
+            compiled = lowered.compile()
+    except Exception as exc:  # noqa: BLE001 — backend/API drift degrades to no plan
+        print(
+            "elastic-trainer: the step could not be lowered and compiled "
+            "again (%s); its cost model, memory plan or phase table is "
+            "missing" % exc,
+            file=sys.stderr,
+        )
+    return lowered, compiled
+
+
+class RetireClock:
+    """Seconds a step from the moments the host knows a numbered step has
+    retired on the device.
+
+    The step loop runs ahead of the device, so its own dispatch-to-dispatch
+    intervals are a few ms seven times in eight and eight steps long the
+    eighth. What it does know is each moment a wait for the device returned
+    (the numerics plane's fetch of step ``k``'s bundle, the end-of-epoch
+    sync): step ``k`` has retired by then, and the wait returned because
+    it did. Between two such marks the device ran ``Δsteps`` whole steps
+    in ``Δt``, whatever the host did in between. Marks chain inside one
+    epoch: an epoch boundary holds host work (callback, save, a new feed)
+    that no step paced. Steps count through the stage, as the probe and
+    the heartbeat count them.
+    """
+
+    def __init__(self, tracer: obs_trace.SpanTracer) -> None:
+        self._tracer = tracer
+        self._last: Optional[tuple] = None  # (step, monotonic time)
+
+    def start_epoch(self) -> None:
+        self._last = None
+
+    def mark(self, step: int, t: float, epoch: int) -> Optional[float]:
+        """Step ``step`` had retired at ``t``. Returns seconds a step since
+        the previous mark (None for an epoch's first), observes it into
+        ``edl_train_step_seconds`` and leaves a ``step_retired`` instant."""
+        last, self._last = self._last, (step, t)
+        derived = {}
+        if last is not None and step > last[0]:
+            steps = step - last[0]
+            derived = {"steps": steps, "seconds_per_step": (t - last[1]) / steps}
+            _M_STEP_SECONDS.observe(derived["seconds_per_step"])
+        self._tracer.instant("step_retired", step=step, epoch=epoch, **derived)
+        return derived.get("seconds_per_step")
 
 
 class _RestageRequested(Exception):
@@ -441,6 +501,7 @@ class ElasticTrainer:
                             file=sys.stderr,
                         )
                 tracer = obs_trace.get_tracer()
+                retired = RetireClock(tracer)
                 first_step_done = False
                 steps_done = 0  # stage-cumulative, drives the heartbeat
                 last_flight = 0.0  # throttled flight-recorder step marker
@@ -462,13 +523,18 @@ class ElasticTrainer:
                     # interval after it is the step's (train) — the split
                     # the goodput ledger exists to make
                     batch_iter = iter(prefetch_to_device(
-                        batches, depth=self._depth, sharding=sharding
+                        batches, depth=self._depth, sharding=sharding,
+                        epoch=epoch,
                     ))
+                    retired.start_epoch()
                     while True:
                         if first_step_done:
                             obs_goodput.enter("data_wait")
                         try:
-                            device_batch = next(batch_iter)
+                            with tracer.span(
+                                "data_wait", epoch=epoch, step=step_idx
+                            ):
+                                device_batch = next(batch_iter)
                         except StopIteration:
                             break
                         if first_step_done:
@@ -484,30 +550,42 @@ class ElasticTrainer:
                             # the in-flight step's work is simply dropped
                             # (same loss as a stop-resume kill)
                             raise _RestageRequested()
-                        if mem_plane is not None:
-                            # RESOURCE_EXHAUSTED leaves a forensics
-                            # bundle (census + device memory profile +
-                            # the plan + an fsync'd `oom` instant)
-                            # before propagating into drain/restage
-                            with mem_plane.oom_guard(
-                                step=steps_done, epoch=epoch
-                            ):
+                        # host cost of one dispatch: long when the
+                        # runtime's queue is full
+                        with tracer.span(
+                            "step_dispatch", epoch=epoch, step=step_idx
+                        ):
+                            if mem_plane is not None:
+                                # RESOURCE_EXHAUSTED leaves a forensics
+                                # bundle (census + device memory profile +
+                                # the plan + an fsync'd `oom` instant)
+                                # before propagating into drain/restage
+                                with mem_plane.oom_guard(
+                                    step=steps_done, epoch=epoch
+                                ):
+                                    state, metrics = step(state, device_batch)
+                            else:
                                 state, metrics = step(state, device_batch)
-                        else:
-                            state, metrics = step(state, device_batch)
                         # pop BEFORE any aggregation/printing: the bundle
                         # is device arrays for the probe, not a scalar
                         # metric. No host sync here — the probe fetches
                         # on its own throttle.
                         bundle = metrics.pop(obs_numerics.METRICS_KEY, None)
                         if probe is not None:
-                            probe.on_step(steps_done, bundle)
-                        # dispatch-to-dispatch wall time: jax dispatch is
-                        # async, but the state dependency chain makes the
-                        # steady-state interval track real step time
+                            fetched = probe.on_step(
+                                steps_done, bundle, epoch=epoch
+                            )
+                            if fetched is not None:
+                                retired.mark(*fetched, epoch=epoch)
+                        # dispatch to dispatch: the loop runs ahead of the
+                        # device, so this is the host's interval, not the
+                        # step's (RetireClock has that)
                         t_now = time.monotonic()
                         dt = t_now - t_prev
-                        _M_STEP_SECONDS.observe(dt)
+                        if probe is None:
+                            # no wait for the device inside an epoch, so
+                            # the runtime's own queue paces the dispatches
+                            _M_STEP_SECONDS.observe(dt)
                         _M_STEPS.inc()
                         if not first_step_done:
                             # restage trace: the first completed step is
@@ -529,24 +607,24 @@ class ElasticTrainer:
                             _M_FIRST_STEP.set(dt)
                             first_step_done = True
                             obs_goodput.enter("train", cause="first_step")
-                            # arm the MFU/roofline gauges with XLA's own
-                            # cost analysis for this step shape — a jax
-                            # trace, no second XLA compile (the compiled
-                            # executable already sits in the jit cache)
-                            step_telemetry.set_cost(
-                                obs_profile.step_cost(
-                                    step, state, device_batch
-                                )
+                            # one more jax trace of the step, shared by
+                            # what reads the program: XLA's cost analysis
+                            # arms the MFU/roofline gauges; its compile (a
+                            # persistent-cache hit, no second XLA compile)
+                            # gives the memory plane THIS stage's plan and
+                            # obs_profile.step_phases() the names
+                            lowered, compiled = _lower_step(
+                                step, state, device_batch,
+                                compile=mem_plane is not None,
                             )
-                            if mem_plane is not None:
-                                # compile-time memory plan for THIS
-                                # stage's executable: a jax trace + a
-                                # jit/persistent-cache hit, no second
-                                # XLA compile (mirrors step_cost)
+                            step_telemetry.set_cost(
+                                obs_profile.step_cost(lowered)
+                            )
+                            if compiled is not None:
                                 mem_plane.harvest(
-                                    step, state, device_batch,
-                                    world=env.world_size,
+                                    compiled, world=env.world_size
                                 )
+                                obs_profile.set_step_executable(compiled)
                             # steady state reached: speculatively compile
                             # the N±1/N±2 neighbor worlds into the
                             # persistent cache on a low-priority thread
@@ -599,7 +677,15 @@ class ElasticTrainer:
                         # not input wait
                         obs_goodput.enter("train")
                     if metrics:
-                        jax.block_until_ready(metrics)
+                        # the device drains: every step of the epoch has
+                        # retired when this returns
+                        with tracer.span(
+                            "epoch_sync", epoch=epoch, step=step_idx - 1
+                        ):
+                            jax.block_until_ready(metrics)
+                        retired.mark(
+                            steps_done - 1, time.monotonic(), epoch=epoch
+                        )
                     if env.is_rank0 and self._log and metrics:
                         print(
                             "epoch %d %s"
@@ -624,7 +710,8 @@ class ElasticTrainer:
                         epoch=epoch, steps=step_idx,
                     )
                     if on_epoch_end is not None:
-                        on_epoch_end(epoch, metrics)
+                        with tracer.span("epoch_end_hook", epoch=epoch):
+                            on_epoch_end(epoch, metrics)
                     if mngr is not None:
                         mngr.save(
                             state,

@@ -155,6 +155,16 @@ def make_train_step(
     identical to the full-batch gradient for mean-reduced loss heads
     over equal halves, same FLOP count, one jit — and the two half
     norms feed the gradient-noise-scale estimator for free.
+
+    The step program names its phases (``jax.named_scope``: metadata
+    only, the HLO and its fusions are what they were): ``forward`` is
+    the model and the loss head, so that jax writes ``jvp(forward)`` and
+    ``transpose(jvp(forward))`` — forward and backward, recomputation
+    under the latter — into every operation's ``op_name``; ``grad_mean``
+    the half-batch averaging; ``optimizer`` the update; ``numerics`` the
+    bundle. ``obs/profile.py:step_phases`` reads them back from the
+    compiled step; ``train/aot.py:STEP_SCOPES_KEY`` lists them for the
+    compile cache's key (bump it when a scope moves).
     """
     kwargs = dict(apply_kwargs or {})
     # env read at BUILD time, outside the traced step (jit purity): the
@@ -164,6 +174,7 @@ def make_train_step(
     def step(state: TrainState, batch):
         x, y = batch
 
+        @jax.named_scope("forward")
         def loss_fn(params, bx, by):
             variables = {"params": params}
             mutable = []
@@ -218,9 +229,14 @@ def make_train_step(
             x2, y2 = jax.tree_util.tree_map(lambda a: a[h:], (x, y))
             (l1, (m1, _)), g1 = grad_fn(state.params, x1, y1)
             (l2, (m2, _)), g2 = grad_fn(state.params, x2, y2)
-            loss = (l1 + l2) / 2.0
-            grads = jax.tree_util.tree_map(lambda a, c: (a + c) / 2.0, g1, g2)
-            metrics = jax.tree_util.tree_map(lambda a, c: (a + c) / 2.0, m1, m2)
+            with jax.named_scope("grad_mean"):
+                loss = (l1 + l2) / 2.0
+                grads = jax.tree_util.tree_map(
+                    lambda a, c: (a + c) / 2.0, g1, g2
+                )
+                metrics = jax.tree_util.tree_map(
+                    lambda a, c: (a + c) / 2.0, m1, m2
+                )
             new_stats = None
             halves = (g1, g2)
         else:
@@ -228,13 +244,15 @@ def make_train_step(
         updates = {}
         if new_stats is not None:
             updates["batch_stats"] = new_stats
-        new_state = state.apply_gradients(grads, **updates)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads, **updates)
         metrics = {"loss": loss, **metrics}
         if numerics:
-            metrics[obs_numerics.METRICS_KEY] = obs_numerics.device_bundle(
-                loss, grads, state.params, new_state.params,
-                halves=halves, batch=batch_size,
-            )
+            with jax.named_scope("numerics"):
+                metrics[obs_numerics.METRICS_KEY] = obs_numerics.device_bundle(
+                    loss, grads, state.params, new_state.params,
+                    halves=halves, batch=batch_size,
+                )
         return new_state, metrics
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
@@ -293,6 +311,7 @@ def make_masked_train_step(
     def step(state: TrainState, batch, mask):
         x, y = batch
 
+        @jax.named_scope("forward")
         def loss_fn(params):
             variables = {"params": params}
             if state.batch_stats is not None:
@@ -310,7 +329,8 @@ def make_masked_train_step(
         (loss, (metrics, n_valid)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params)
-        new_state = state.apply_gradients(grads)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads)
         return new_state, {"loss": loss, **metrics}, n_valid
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
