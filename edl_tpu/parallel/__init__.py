@@ -2,6 +2,7 @@ from edl_tpu.parallel.mesh import (
     batch_sharding,
     device_put_global,
     device_put_local_rows,
+    fsdp_shardings,
     make_hybrid_mesh,
     make_mesh,
     replicated,
@@ -33,6 +34,7 @@ from edl_tpu.parallel.sharding_rules import (
 __all__ = [
     "device_put_global",
     "device_put_local_rows",
+    "fsdp_shardings",
     "make_hybrid_mesh",
     "make_mesh",
     "batch_sharding",

@@ -216,17 +216,23 @@ def _fsdp_spec(shape: Sequence[int], axis_size: int, axis: str) -> P:
     return P()
 
 
-def shard_params_fsdp(mesh: Mesh, params, axis: str = "fsdp"):
-    """ZeRO-style parameter sharding: each tensor's largest divisible dim is
-    split over the fsdp axis (the TPU-idiomatic replacement for the
-    reference's parameter-server role split, SURVEY §2 C-PS row)."""
+def fsdp_shardings(mesh: Mesh, tree, axis: str = "fsdp"):
+    """ZeRO-style shardings, one a leaf of ``tree`` (arrays or shapes):
+    each tensor's largest divisible dim is split over the fsdp axis (the
+    TPU-idiomatic replacement for the reference's parameter-server role
+    split, SURVEY §2 C-PS row)."""
     axis_size = mesh.shape[axis]
+    return jax.tree.map(
+        lambda x: NamedSharding(mesh, _fsdp_spec(x.shape, axis_size, axis)),
+        tree,
+    )
 
-    def place(x):
-        spec = _fsdp_spec(x.shape, axis_size, axis)
-        return device_put_global(x, NamedSharding(mesh, spec))
 
-    return jax.tree.map(place, params)
+def shard_params_fsdp(mesh: Mesh, params, axis: str = "fsdp"):
+    """Place an existing host or device tree under ``fsdp_shardings``."""
+    return jax.tree.map(
+        device_put_global, params, fsdp_shardings(mesh, params, axis)
+    )
 
 
 def sharded_seq_attention(

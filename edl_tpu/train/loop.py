@@ -65,11 +65,10 @@ _M_FIRST_STEP = obs_metrics.gauge(
 from edl_tpu.data import batched, prefetch_to_device
 from edl_tpu.parallel import (
     batch_sharding,
-    device_put_global,
+    fsdp_shardings,
     make_mesh,
     replicated,
     shard_batch,
-    shard_params_fsdp,
 )
 from edl_tpu.train.context import init, warm_only, worker_barrier
 from edl_tpu.train.step import TrainState, create_state, make_train_step
@@ -80,6 +79,25 @@ DataFn = Callable[[int], Iterable]  # epoch -> records or ready batches
 _M_DRAINS = obs_metrics.counter(
     "edl_train_drains_total", "graceful worker drains (preemption notices honored)"
 )
+
+
+def _state_shardings(mesh, fsdp: bool):
+    """Where ``create_state``'s outputs are born: every leaf replicated
+    over ``mesh``, or under ``fsdp`` each ``params`` / ``opt_state`` leaf
+    split by ``fsdp_shardings`` and ``step`` / ``batch_stats`` replicated
+    (a function of the abstract state: the split reads the shapes, which
+    costs ``create_state`` a second trace; one sharding for every leaf
+    needs none)."""
+    rep = replicated(mesh)
+    if not fsdp:
+        return rep
+    return lambda abstract: abstract.replace(
+        step=rep,
+        params=fsdp_shardings(mesh, abstract.params),
+        opt_state=fsdp_shardings(mesh, abstract.opt_state),
+        # tree.map over None is None: no-op without stats
+        batch_stats=jax.tree.map(lambda _: rep, abstract.batch_stats),
+    )
 
 
 def _lower_step(step, state, device_batch, compile: bool):
@@ -407,35 +425,28 @@ class ElasticTrainer:
                     if self._adjusts is not None
                     else {}
                 )
-                state = create_state(
-                    self._model,
-                    jax.random.PRNGKey(self._seed),
-                    self._sample_input,
-                    self._make_tx(overrides),
-                    **self._init_kwargs,
-                )
-                # every leaf must land on the mesh (a leaf left committed
-                # to device 0 — e.g. the .step scalar — clashes with
-                # mesh-placed args at jit time and checkpoint restore)
-                rep = replicated(mesh)
-                if self._fsdp:
-                    # params/opt_state shard DIRECTLY from host: replicating
-                    # first would put the full model on every device — the
-                    # memory peak fsdp exists to avoid
-                    state = state.replace(
-                        params=shard_params_fsdp(mesh, state.params),
-                        opt_state=shard_params_fsdp(mesh, state.opt_state),
-                        step=device_put_global(state.step, rep),
-                        # tree.map over None is None: no-op without stats
-                        batch_stats=jax.tree.map(
-                            lambda x: device_put_global(x, rep),
-                            state.batch_stats,
-                        ),
+                # one jitted program whose outputs are born on the mesh:
+                # no leaf is ever committed to device 0 alone (it would
+                # clash with mesh-placed args at jit time and checkpoint
+                # restore), under fsdp the full model is on no device,
+                # and on a mesh that spans processes every process runs
+                # the same program
+                with obs_trace.get_tracer().span("state_init") as init_span:
+                    state = jax.block_until_ready(
+                        create_state(
+                            self._model,
+                            jax.random.PRNGKey(self._seed),
+                            self._sample_input,
+                            self._make_tx(overrides),
+                            shardings=_state_shardings(mesh, self._fsdp),
+                            **self._init_kwargs,
+                        )
                     )
-                else:
-                    state = jax.tree.map(
-                        lambda x: device_put_global(x, rep), state
-                    )
+                    leaves = jax.tree.leaves(state)
+                    init_span.args = {
+                        "leaves": len(leaves),
+                        "bytes": sum(x.nbytes for x in leaves),
+                    }
                 start_epoch = 0
                 if mngr is not None:
                     state, status = mngr.restore(state)

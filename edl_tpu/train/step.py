@@ -52,18 +52,45 @@ def create_state(
     rng: jax.Array,
     sample_input,
     tx: optax.GradientTransformation,
+    shardings: Any = None,
     **init_kwargs,
 ) -> TrainState:
-    variables = model.init(rng, sample_input, **init_kwargs)
-    params = variables["params"]
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        apply_fn=model.apply,
-        params=params,
-        tx=tx,
-        opt_state=tx.init(params),
-        batch_stats=variables.get("batch_stats"),
-    )
+    """Build the train state as the output of ONE jitted program.
+
+    ``model.init``, ``tx.init`` and the step counter are traced once and
+    compiled with ``shardings`` as the program's output shardings, so
+    every leaf is born where it lives: no op-by-op init, no forward pass
+    on device 0 (it is dead code under jit), no copy of the state from
+    one device to the mesh afterwards. ``shardings`` is a pytree prefix
+    of the ``TrainState`` (one ``Sharding`` for every leaf, or one per
+    leaf), or a function from the abstract state (``jax.eval_shape``'s
+    tree) to such a prefix; ``None`` leaves placement to jax.
+
+    ``sample_input`` gives shapes and dtypes only: the trace sees zeros
+    of them, so no sample bytes are baked into the program or reach the
+    device. ``rng`` is an argument of the program, not a constant in it:
+    one compile serves every seed. The values do not depend on
+    ``shardings`` (jax's threefry is partitionable).
+    """
+
+    def init_state(rng):
+        zeros = jax.tree.map(
+            lambda x: jnp.zeros(jnp.shape(x), jnp.result_type(x)), sample_input
+        )
+        variables = model.init(rng, zeros, **init_kwargs)
+        params = variables["params"]
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            apply_fn=model.apply,
+            params=params,
+            tx=tx,
+            opt_state=tx.init(params),
+            batch_stats=variables.get("batch_stats"),
+        )
+
+    if callable(shardings):
+        shardings = shardings(jax.eval_shape(init_state, rng))
+    return jax.jit(init_state, out_shardings=shardings)(rng)
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> Tuple[jax.Array, Dict]:

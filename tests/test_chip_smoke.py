@@ -58,8 +58,10 @@ def _json_lines(text):
 
 @pytest.fixture(scope="module")
 def cache_dir(tmp_path_factory):
-    """One placed cache for the rehearsals: the mesh phase then loads the
-    op-by-op init programs the train phase compiled."""
+    """One placed cache for the rehearsals. A phase writes a handful of
+    programs (the key's two, ``jit_init_state``, ``jit_step``, no longer
+    one an init operation): the resumed launch loads the cold one's, and
+    the mesh phase, on its own mesh, shares only the key's."""
     return str(tmp_path_factory.mktemp("xla"))
 
 
