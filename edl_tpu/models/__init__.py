@@ -1,6 +1,6 @@
 from edl_tpu.models.ctr import CTR_EMBEDDING_RULES, DeepFM, binary_cross_entropy_loss
 from edl_tpu.models.mlp import MLP, LinearRegression
-from edl_tpu.models.moe import MOE_EP_RULES, SwitchMoE
+from edl_tpu.models.moe import MOE_EP_RULES, DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.resnet import (
     ResNet,
     ResNet50_vd,
@@ -26,5 +26,7 @@ __all__ = [
     "CTR_EMBEDDING_RULES",
     "binary_cross_entropy_loss",
     "SwitchMoE",
+    "DroplessMoE",
+    "MoESpec",
     "MOE_EP_RULES",
 ]

@@ -1,29 +1,197 @@
-"""Mixture-of-Experts layers: expert parallelism over the ``ep`` mesh axis.
+"""Mixture-of-Experts layers.
 
 Net-new versus the reference (no MoE/expert parallelism anywhere in its
-tree — SURVEY §2 parallelism inventory), built the TPU-compiler way: the
-classic dispatch/combine **einsum formulation** (Mesh-TensorFlow / GShard
-lineage) instead of manual all-to-all calls. Expert weights carry a
-leading ``[E, ...]`` axis sharded over ``ep``; tokens are dp-sharded;
-the dispatch einsum contracts token and expert axes, so GSPMD inserts
-the all-to-alls over ICI itself — no hand-written collectives, static
-shapes throughout (capacity-bounded routing, drops past capacity).
+tree — SURVEY §2 parallelism inventory). Two layers live here:
 
-Switch-style top-1 routing (Fedus et al.) by default, or GShard-style
-top-2 (``top_k=2``: renormalized combine weights, choice-major capacity
-queues so 1st choices claim slots before any 2nd choice), with the
-standard auxiliary load-balancing loss surfaced through flax's ``sow``
-into the ``"losses"`` collection — ``make_train_step(aux_losses=True)``
-adds them to the objective.
+- :class:`DroplessMoE` — the expert layer of today's open MoE models
+  (OLMoE, and with ``norm_topk_prob`` the Moonlight / Trinity lineage):
+  softmax router in float32, top-k of many small SiLU-gated experts,
+  **no capacity and no dropped token**. Tokens are sorted by expert and
+  the three projections run as grouped matrix multiplications over the
+  ragged groups (``edl_tpu.ops.grouped_matmul``); shapes are static
+  whatever the imbalance. Sows the load-balancing and router-z losses
+  into ``"losses"`` and the busiest expert's relative load into
+  ``"metrics"``; ``create_state`` sees both collections in
+  ``model.init``'s result, so the train step adds and reports them with
+  no flag from the caller. :class:`MoESpec` describes it to
+  ``TransformerLM``.
+- :class:`SwitchMoE` — the older capacity-bounded layer in the
+  dispatch/combine **einsum formulation** (Mesh-TensorFlow / GShard
+  lineage): a one-hot ``[B, S, E, C]`` dispatch tensor, tokens dropped
+  past capacity, ungated GELU experts, Switch top-1 or GShard top-2
+  routing. Its einsums contract token and expert axes, so with expert
+  weights sharded over ``ep`` GSPMD inserts the all-to-alls itself. It
+  describes no published model and is kept for its expert-parallel
+  tests until ROADMAP D6 removes it.
+
+Both keep expert weights with a leading ``[E, ...]`` axis so that
+``MOE_EP_RULES`` can shard them over ``ep``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import dataclasses
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from edl_tpu.ops.grouped_matmul import grouped_matmul
+
+
+@dataclasses.dataclass(frozen=True)
+class MoESpec:
+    """The expert layer of a ``TransformerLM``, as one hashable field:
+    every block's feed-forward is a :class:`DroplessMoE` of this shape.
+    (A layer pattern — a model's leading dense layers — belongs here when
+    a configuration needs one.)"""
+
+    num_experts: int
+    top_k: int
+    d_ff: int                      # width of ONE expert
+    norm_topk_prob: bool = False   # renormalise the k weights to sum 1
+    aux_weight: float = 1e-2       # alpha of the load-balancing loss
+    z_weight: float = 1e-3         # beta of the router z-loss
+
+
+def _rows_sorted(tokens, order, inverse, k):
+    """``tokens[order // k]``: row ``r`` of the result is the token of the
+    ``r``-th (token, choice) pair in expert order. Its gradient is taken
+    as a gather too (``inverse`` undoes ``order``; a token's ``k`` copies
+    are then neighbours and summed), not as the scatter-add jax would
+    derive."""
+
+    @jax.custom_vjp
+    def take(tokens, order, inverse):
+        return tokens[order // k]
+
+    def fwd(tokens, order, inverse):
+        return take(tokens, order, inverse), inverse
+
+    def bwd(inverse, grad):
+        n = grad.shape[0] // k
+        back = grad[inverse].reshape(n, k, grad.shape[-1])
+        return jnp.sum(back, axis=1, dtype=jnp.float32).astype(grad.dtype), None, None
+
+    take.defvjp(fwd, bwd)
+    return take(tokens, order, inverse)
+
+
+def _rows_unsorted(rows, order, inverse):
+    """``rows[inverse]``: expert order back to (token, choice) order, with
+    the gradient as the gather ``grad[order]``."""
+
+    @jax.custom_vjp
+    def take(rows, order, inverse):
+        return rows[inverse]
+
+    def fwd(rows, order, inverse):
+        return take(rows, order, inverse), order
+
+    def bwd(order, grad):
+        return grad[order], None, None
+
+    take.defvjp(fwd, bwd)
+    return take(rows, order, inverse)
+
+
+class DroplessMoE(nn.Module):
+    """Dropless top-k mixture of SiLU-gated experts.
+
+    Per token ``x`` (``[B, S, D]`` in, ``[B, S, D]`` out)::
+
+        p      = softmax(W_r x)                    float32, over E
+        w, e   = top_k(p)                          w as it is, or w / sum(w)
+        y      = sum_j w_j * W_down[e_j] (silu(W_gate[e_j] x) * W_up[e_j] x)
+
+    The N*k (token, choice) pairs are sorted by expert; ``gate``, ``up``
+    and ``down`` (``[E, D, F]``, ``[E, D, F]``, ``[E, F, D]``, float32
+    parameters, computed in ``dtype``) are three grouped matrix
+    multiplications over the E ragged groups. Sown:
+
+    - ``"losses"/load_balance`` = ``aux_weight * E * sum_i f_i * P_i`` with
+      ``P_i`` the mean of ``p_i`` over the tokens and ``f_i`` the share of
+      the N*k **assignments** that went to expert ``i`` (so ``sum f = 1``
+      and a uniform router gives ``aux_weight``). Implementations that
+      count ``f_i`` as a share of the N tokens (Hugging Face's
+      ``load_balancing_loss_func``) are larger by the factor k.
+    - ``"losses"/router_z`` = ``z_weight * mean(logsumexp(W_r x)^2)``.
+    - ``"metrics"/moe_load_max`` = the busiest expert's assignments over
+      the mean (1.0 is perfect balance, E is one expert taking all).
+    - ``"intermediates"/top_idx`` and ``/router_logits`` = the chosen
+      experts ``[N, k]`` and ``W_r x`` ``[N, E]`` (only when a caller
+      asks for the collection: a check against a reference).
+
+    Device-side names: ``moe_route`` (router, top-k, sort, losses),
+    ``moe_experts`` (gather and the three grouped matmuls),
+    ``moe_combine`` (un-sort, weights, sum over k).
+    """
+
+    num_experts: int
+    top_k: int
+    d_ff: int
+    norm_topk_prob: bool = False
+    aux_weight: float = 1e-2
+    z_weight: float = 1e-3
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, s, d = x.shape
+        e, k, f = self.num_experts, self.top_k, self.d_ff
+        n = b * s
+        tokens = x.reshape(n, d)
+
+        with jax.named_scope("moe_route"):
+            logits = nn.Dense(
+                e, use_bias=False, dtype=jnp.float32, name="router",
+                precision=jax.lax.Precision.HIGHEST,
+            )(tokens.astype(jnp.float32))               # [N, E]
+            probs = jax.nn.softmax(logits, axis=-1)
+            weights, top_idx = jax.lax.top_k(probs, k)  # [N, k]
+            if self.norm_topk_prob:
+                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            flat = top_idx.reshape(n * k)
+            order = jnp.argsort(flat)                   # stable: by expert, then pair
+            inverse = jnp.argsort(order)
+            group_sizes = jnp.sum(
+                jax.nn.one_hot(top_idx, e, dtype=jnp.int32), axis=(0, 1)
+            )                                           # [E], sums to N*k
+            share = group_sizes.astype(jnp.float32) / (n * k)
+            self.sow(
+                "losses", "load_balance",
+                self.aux_weight * e * jnp.sum(share * jnp.mean(probs, axis=0)),
+            )
+            self.sow(
+                "losses", "router_z",
+                self.z_weight
+                * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
+            )
+            self.sow("metrics", "moe_load_max", jnp.max(share) * e)
+            self.sow("intermediates", "top_idx", top_idx)
+            self.sow("intermediates", "router_logits", logits)
+
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
+        w_gate = self.param("gate", init, (e, d, f), jnp.float32)
+        w_up = self.param("up", init, (e, d, f), jnp.float32)
+        w_down = self.param("down", init, (e, f, d), jnp.float32)
+
+        with jax.named_scope("moe_experts"):
+            rows = _rows_sorted(tokens.astype(self.dtype), order, inverse, k)
+            gate = grouped_matmul(rows, w_gate.astype(self.dtype), group_sizes)
+            up = grouped_matmul(rows, w_up.astype(self.dtype), group_sizes)
+            out = grouped_matmul(
+                nn.silu(gate) * up, w_down.astype(self.dtype), group_sizes
+            )                                           # [N*k, D], expert order
+
+        with jax.named_scope("moe_combine"):
+            out = _rows_unsorted(out, order, inverse).reshape(n, k, d)
+            y = jnp.einsum(
+                "nkd,nk->nd", out.astype(jnp.float32), weights,
+            )
+        return y.reshape(b, s, d).astype(x.dtype)
 
 
 class SwitchMoE(nn.Module):
@@ -107,10 +275,9 @@ class SwitchMoE(nn.Module):
         return out.astype(x.dtype)
 
 
-from jax.sharding import PartitionSpec as P  # noqa: E402
-
 # Expert-parallel sharding rules: expert banks split their leading [E] axis
-# over ``ep``; the router stays replicated.
+# over ``ep``; the router stays replicated. ``w[io]`` are SwitchMoE's two
+# banks, ``gate``/``up``/``down`` DroplessMoE's three.
 MOE_EP_RULES = [
-    (r".*/moe/w[io]", P("ep", None, None)),
+    (r".*/moe/(w[io]|gate|up|down)$", P("ep", None, None)),
 ]

@@ -19,6 +19,7 @@ TPU-first:
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -26,6 +27,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.ops.attention import attention
 
 AttentionFn = Callable[..., jax.Array]  # (q, k, v, causal=...) -> out
@@ -99,6 +101,11 @@ class Attention(nn.Module):
     num_kv_heads: Optional[int] = None
     decode: bool = False       # autoregressive mode: KV cache in "cache"
     max_decode_len: int = 2048
+    # OLMoE's QK-norm: an RMSNorm with a learned scale over the WHOLE
+    # projected q (all heads together), and one over the whole projected
+    # k, before the split into heads means anything and before RoPE
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, positions):
@@ -117,6 +124,10 @@ class Attention(nn.Module):
         q = dense(features=(self.num_heads, head_dim), name="q")(x)
         k = dense(features=(kv_heads, head_dim), name="k")(x)
         v = dense(features=(kv_heads, head_dim), name="v")(x)
+        if self.qk_norm:
+            flat = q.shape[:2] + (-1,)
+            q = RMSNorm(self.norm_eps, name="q_norm")(q.reshape(flat)).reshape(q.shape)
+            k = RMSNorm(self.norm_eps, name="k_norm")(k.reshape(flat)).reshape(k.shape)
         q = rope(q, positions)
         k = rope(k, positions)
         if self.decode:
@@ -218,18 +229,24 @@ class Block(nn.Module):
     num_kv_heads: Optional[int] = None
     decode: bool = False
     max_decode_len: int = 2048
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    moe: Optional[MoESpec] = None  # dropless expert FFN instead of SwiGLU
 
     @nn.compact
     def __call__(self, x, positions):
         x = x + Attention(
             self.num_heads, self.dtype, self.attention_fn,
             num_kv_heads=self.num_kv_heads, decode=self.decode,
-            max_decode_len=self.max_decode_len, name="attn",
-        )(RMSNorm(name="ln1")(x), positions)
-        h = RMSNorm(name="ln2")(x)
-        if self.num_experts > 0:
-            from edl_tpu.models.moe import SwitchMoE
-
+            max_decode_len=self.max_decode_len, qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps, name="attn",
+        )(RMSNorm(self.norm_eps, name="ln1")(x), positions)
+        h = RMSNorm(self.norm_eps, name="ln2")(x)
+        if self.moe is not None:
+            ff = DroplessMoE(
+                **dataclasses.asdict(self.moe), dtype=self.dtype, name="moe"
+            )(h)
+        elif self.num_experts > 0:
             ff = SwitchMoE(
                 num_experts=self.num_experts, d_ff=self.d_ff,
                 dtype=self.dtype, name="moe",
@@ -306,6 +323,11 @@ class TransformerLM(nn.Module):
     num_kv_heads: Optional[int] = None  # < num_heads = GQA; 1 = MQA
     decode: bool = False                # KV-cached autoregressive mode
     max_decode_len: int = 2048
+    norm_eps: float = 1e-6              # every RMSNorm's epsilon
+    qk_norm: bool = False               # RMSNorm over projected q and k
+    # every block's FFN as a dropless top-k expert layer (models/moe.py);
+    # the older Switch pair above stays for SwitchMoE until ROADMAP D6
+    moe: Optional[MoESpec] = None
 
     @nn.compact
     def __call__(self, tokens, positions=None):
@@ -332,8 +354,9 @@ class TransformerLM(nn.Module):
             x = block(
                 self.num_heads, self.d_ff, self.dtype, self.attention_fn,
                 moe, self.num_kv_heads, self.decode, self.max_decode_len,
+                self.norm_eps, self.qk_norm, self.moe,
                 name="layer_%d" % i,
             )(x, positions)
-        x = RMSNorm(name="ln_f")(x)
+        x = RMSNorm(self.norm_eps, name="ln_f")(x)
         logits = LMHead(self.vocab_size, name="lm_head")(x)
         return logits

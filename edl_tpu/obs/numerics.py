@@ -170,6 +170,18 @@ def device_bundle(
     return bundle
 
 
+def publish_sown(values: Dict[str, Any]) -> None:
+    """``edl_train_<name>`` gauges for the step metrics a model sows (an
+    expert layer's ``aux_loss`` and ``moe_load_max``): called with host
+    values the train loop already holds — the probe's throttled fetch, the
+    epoch's end — never with a device array that would have to be waited
+    for."""
+    for name, value in values.items():
+        obs_metrics.gauge(
+            "edl_train_" + name, "sown by the model: %s, last fetched step" % name
+        ).set(float(value))
+
+
 def gns_estimates(big_sq: float, small_sq: float, batch: float) -> Tuple[float, float]:
     """One-step unbiased estimators from McCandlish et al. appendix A:
     given ``|G_big|^2`` at batch ``B`` and the mean half-batch
@@ -409,6 +421,7 @@ class NumericsProbe:
                 "nonfinite", fsync=True, step=step, count=nonfinite, loss=loss
             )
 
+        publish_sown(vals.get("sown", {}))
         gns = self._update_gns(vals)
         divergence = self._update_divergence(step, param_norm)
         self._check_spike(step, loss)

@@ -62,7 +62,7 @@ import tempfile
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, Iterable, Optional, Tuple
+from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 from edl_tpu.obs import events as obs_events
 from edl_tpu.obs import metrics as obs_metrics
@@ -195,9 +195,10 @@ _HLO_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
 
-# the running stage's compiled step and, once asked for, its table
+# the running stage's compiled step and, once asked for, its tables
 _step_executable = None
 _step_phases: Optional[Dict[str, str]] = None
+_step_scopes: Dict[Tuple[str, ...], Dict[str, str]] = {}
 
 
 def phase_of(op_name: str) -> str:
@@ -218,13 +219,10 @@ def phase_of(op_name: str) -> str:
     return "other"
 
 
-def phases_of_hlo(text: str) -> Dict[str, str]:
-    """``{instruction name: phase}`` from an optimised HLO module's text:
-    every instruction by its own ``op_name``, and one that has none (a
-    fusion, a call) by that of the root of the computation it calls. {}
-    when nothing maps to ``forward``: the executable then predates the
-    scopes (a compile cache older than them handed it back), and a table
-    of ``other`` would pass for a measurement."""
+def op_names_of_hlo(text: str) -> Dict[str, str]:
+    """``{instruction name: jax op_name}`` from an optimised HLO module's
+    text: every instruction by its own ``op_name``, and one that has none
+    (a fusion, a call) by that of the root of the computation it calls."""
     own: Dict[str, str] = {}      # instruction -> op_name
     calls: Dict[str, str] = {}    # instruction without one -> computation
     roots: Dict[str, str] = {}    # computation -> its root instruction
@@ -251,8 +249,40 @@ def phases_of_hlo(text: str) -> Dict[str, str]:
         root = roots.get(computation)
         if root in own:
             own[name] = own[root]
-    table = {name: phase_of(op_name) for name, op_name in own.items()}
+    return own
+
+
+def phases_of_hlo(text: str) -> Dict[str, str]:
+    """``{instruction name: phase}`` from an optimised HLO module's text
+    (:func:`op_names_of_hlo`, then :func:`phase_of`). {} when nothing maps
+    to ``forward``: the executable then predates the scopes (a compile
+    cache older than them handed it back), and a table of ``other`` would
+    pass for a measurement."""
+    table = {
+        name: phase_of(op_name)
+        for name, op_name in op_names_of_hlo(text).items()
+    }
     return table if "forward" in table.values() else {}
+
+
+# the expert layer's device-side names (models/moe.py:DroplessMoE)
+MOE_SCOPES = ("moe_route", "moe_experts", "moe_combine")
+
+
+def scopes_of_hlo(text: str, scopes: Sequence[str]) -> Dict[str, str]:
+    """``{instruction name: scope}`` for the instructions whose op_name
+    has one of ``scopes`` (``jax.named_scope`` names) as a component, in
+    the forward pass, its recomputation or its transpose alike; the
+    innermost of them where scopes nest. Instructions under none are left
+    out."""
+    wanted = set(scopes)
+    table: Dict[str, str] = {}
+    for name, op_name in op_names_of_hlo(text).items():
+        for part in reversed(op_name.split(";", 1)[0].split("/")):
+            if part in wanted:
+                table[name] = part
+                break
+    return table
 
 
 def set_step_executable(compiled) -> None:
@@ -260,6 +290,7 @@ def set_step_executable(compiled) -> None:
     train loop makes for the memory plan) for :func:`step_phases`."""
     global _step_executable, _step_phases
     _step_executable, _step_phases = compiled, None
+    _step_scopes.clear()
 
 
 def step_phases() -> Dict[str, str]:
@@ -278,6 +309,21 @@ def step_phases() -> Dict[str, str]:
             logger.warning("step phases unavailable: %s", exc)
             _step_phases = {}
     return dict(_step_phases or {})
+
+
+def step_scopes(scopes: Sequence[str] = MOE_SCOPES) -> Dict[str, str]:
+    """:func:`scopes_of_hlo` for the step executable of the running stage:
+    which of a device trace's events ran under the expert layer's
+    ``moe_route`` / ``moe_experts`` / ``moe_combine``. {} without a step or
+    for a model that enters none of ``scopes``."""
+    key = tuple(scopes)
+    if key not in _step_scopes and _step_executable is not None:
+        try:
+            _step_scopes[key] = scopes_of_hlo(_step_executable.as_text(), key)
+        except Exception as exc:  # noqa: BLE001 — telemetry: a backend without text reads as no table
+            logger.warning("step scopes unavailable: %s", exc)
+            _step_scopes[key] = {}
+    return dict(_step_scopes.get(key, {}))
 
 
 def roofline(cost, device_kind: str, peak: float, mfu: Optional[float] = None) -> Dict:

@@ -1,0 +1,140 @@
+"""Grouped matrix multiplication over ragged groups of rows.
+
+``grouped_matmul(lhs [M, K], rhs [G, K, N], group_sizes [G]) -> [M, N]``:
+the rows of ``lhs`` are sorted by group, group ``g`` owns the next
+``group_sizes[g]`` rows and multiplies them by ``rhs[g]``. The shapes are
+static whatever the sizes are (a group may be empty, none need be a
+multiple of a tile), which is what a dropless mixture-of-experts layer
+needs: no capacity, no padding to the busiest expert.
+
+Two implementations, one contract (value, ``d lhs``, ``d rhs``):
+
+- ``"pallas"``: jax's own Megablox kernels
+  (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` for the value
+  and ``d lhs``, ``tgmm`` for ``d rhs``), tiled for the v5e. Taken on a
+  TPU backend.
+- ``"ragged_dot"``: ``jax.lax.ragged_dot`` and its own transposes. Taken
+  everywhere else (on the CPU it is the only one that is not an
+  interpreter).
+
+Which one runs is decided from ``jax.default_backend()`` when the caller
+names none; the argument is for tests and for the probe that times both
+on the chip (``benchmark/tools/grouped_matmul_probe.py``).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+# (rows, contracting, columns) tiles of the three Megablox kernels (value, row
+# gradient, weight gradient; for the last "rows" is the contracted dimension).
+# Measured on the v5e at the OLMoE cell's shapes (PERF.md section 6, PR 25):
+# the fastest of those that fit VMEM with all three kernels. The rows of a
+# tile belong to one group or are masked, so the row tile bounds the waste at
+# a group's edge (64 edges of at most 512 rows in 131,072 at the OLMoE cell's
+# shape); the other two keep a whole [K, N] slab of one expert in VMEM across
+# that expert's row tiles.
+TILING = (512, 1024, 1024)
+
+IMPLEMENTATIONS = ("pallas", "ragged_dot")
+
+
+def default_implementation() -> str:
+    return "pallas" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
+def _fit(tiling: Tuple[int, int, int], m: int, k: int, n: int):
+    """``tiling`` cut to the problem: no tile larger than its dimension
+    (Megablox masks a ragged last tile of K and N, not one of M, so M is
+    padded by the caller to a whole number of row tiles)."""
+    tm, tk, tn = tiling
+    return min(tm, m), min(tk, k), min(tn, n)
+
+
+def _megablox():
+    # the package's ``gmm`` attribute is its custom-vjp function, which
+    # fixes one tiling for all three kernels; the module has the kernels
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm"
+    )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pallas(lhs, rhs, group_sizes, interpret):
+    m, k = lhs.shape
+    return _megablox().gmm(
+        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+        tiling=_fit(TILING, m, k, rhs.shape[2]), interpret=interpret,
+    )
+
+
+def _pallas_fwd(lhs, rhs, group_sizes, interpret):
+    return _pallas(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+
+
+def _pallas_bwd(interpret, residuals, grad):
+    lhs, rhs, group_sizes = residuals
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    backend = _megablox()
+    grad = grad.astype(lhs.dtype)
+    d_lhs = backend.gmm(
+        grad, rhs, group_sizes, preferred_element_type=lhs.dtype,
+        tiling=_fit(TILING, m, n, k), transpose_rhs=True,
+        interpret=interpret,
+    )
+    d_rhs = backend.tgmm(
+        lhs.swapaxes(0, 1), grad, group_sizes,
+        preferred_element_type=rhs.dtype,
+        tiling=_fit(TILING, m, k, n), num_actual_groups=rhs.shape[0],
+        interpret=interpret,
+    )
+    return d_lhs, d_rhs, None
+
+
+_pallas.defvjp(_pallas_fwd, _pallas_bwd)
+
+
+def grouped_matmul(
+    lhs: jax.Array,
+    rhs: jax.Array,
+    group_sizes: jax.Array,
+    implementation: Optional[str] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """``out[r] = lhs[r] @ rhs[group of row r]`` in ``lhs.dtype``, with
+    float32 accumulation. ``group_sizes`` (int32, ``[G]``) sums to ``M``;
+    rows past the sum, if any, come back as zeros. Differentiable in
+    ``lhs`` and ``rhs``. ``interpret`` runs the Pallas kernels in the
+    interpreter (tests on the CPU)."""
+    if lhs.ndim != 2 or rhs.ndim != 3 or lhs.shape[1] != rhs.shape[1]:
+        raise ValueError(
+            "grouped_matmul: lhs %s and rhs %s are not [M, K] and [G, K, N]"
+            % (lhs.shape, rhs.shape)
+        )
+    if implementation is None:
+        implementation = default_implementation()
+    group_sizes = group_sizes.astype(jnp.int32)
+    rhs = rhs.astype(lhs.dtype)
+    if implementation == "ragged_dot":
+        return jax.lax.ragged_dot(
+            lhs, rhs, group_sizes, preferred_element_type=jnp.float32
+        ).astype(lhs.dtype)
+    if implementation != "pallas":
+        raise ValueError(
+            "grouped_matmul: implementation %r is none of %r"
+            % (implementation, IMPLEMENTATIONS)
+        )
+    m = lhs.shape[0]
+    tm = min(TILING[0], -(-m // 8) * 8)  # a row tile is whole sublanes
+    pad = -m % tm
+    if pad:  # rows that belong to no group, cut off again below
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = _pallas(lhs, rhs, group_sizes, interpret)
+    return out[:m] if pad else out

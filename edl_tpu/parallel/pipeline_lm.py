@@ -49,7 +49,7 @@ class LMStageParams(NamedTuple):
 
 
 def _check_model(model: TransformerLM, pp: int) -> int:
-    if model.num_experts > 0:
+    if model.num_experts > 0 or model.moe is not None:
         raise ValueError(
             "pipeline parallelism requires homogeneous (dense) blocks; "
             "MoE layers change the per-layer param structure"
@@ -97,10 +97,11 @@ def merge_lm_params(model: TransformerLM, split: LMStageParams):
 def _make_fns(model: TransformerLM):
     block = Block(
         model.num_heads, model.d_ff, model.dtype, model.attention_fn,
-        num_kv_heads=model.num_kv_heads,
+        num_kv_heads=model.num_kv_heads, norm_eps=model.norm_eps,
+        qk_norm=model.qk_norm,
     )
     embed_mod = nn.Embed(model.vocab_size, model.d_model, dtype=model.dtype)
-    norm = RMSNorm()
+    norm = RMSNorm(model.norm_eps)
     head_mod = LMHead(model.vocab_size)
 
     def apply_block(bp, h, positions):

@@ -697,6 +697,12 @@ class ElasticTrainer:
                         retired.mark(
                             steps_done - 1, time.monotonic(), epoch=epoch
                         )
+                        # what the model sows (aux_loss, moe_load_max), as
+                        # gauges: the values have just been waited for
+                        obs_numerics.publish_sown({
+                            name: np.asarray(metrics[name])
+                            for name in state.sown if name in metrics
+                        })
                     if env.is_rank0 and self._log and metrics:
                         print(
                             "epoch %d %s"

@@ -33,6 +33,11 @@ class TrainState(struct.PyTreeNode):
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
     opt_state: optax.OptState
     batch_stats: Optional[core.FrozenDict] = None
+    # step metrics that come from what the model sows at every call
+    # (``sown_metric_names``), as ``model.init`` showed them. Static: the
+    # values are a step's by-products, never state, so no leaf here or in
+    # a checkpoint holds them
+    sown: Tuple[str, ...] = struct.field(pytree_node=False, default=())
 
     def apply_gradients(self, grads, **updates) -> "TrainState":
         param_updates, new_opt_state = self.tx.update(
@@ -45,6 +50,19 @@ class TrainState(struct.PyTreeNode):
             opt_state=new_opt_state,
             **updates,
         )
+
+
+AUX_LOSS = "aux_loss"
+
+
+def sown_metric_names(variables) -> Tuple[str, ...]:
+    """The step metrics a model's sown collections give, from
+    ``model.init``'s result: ``"aux_loss"`` if it sows into ``"losses"``
+    (every leaf there is added to the objective, their sum reported), and
+    the name of every leaf it sows into ``"metrics"`` (reported as the
+    mean over the modules that sowed that name)."""
+    names = [AUX_LOSS] if "losses" in variables else []
+    return tuple(names + list(_sown_metrics(variables.get("metrics", {}))))
 
 
 def create_state(
@@ -86,6 +104,7 @@ def create_state(
             tx=tx,
             opt_state=tx.init(params),
             batch_stats=variables.get("batch_stats"),
+            sown=sown_metric_names(variables),
         )
 
     if callable(shardings):
@@ -160,28 +179,41 @@ def make_train_step(
     loss_head: Callable[[jax.Array, jax.Array], Tuple[jax.Array, Dict]],
     apply_kwargs: Optional[Dict[str, Any]] = None,
     donate: bool = True,
-    aux_losses: bool = False,
+    aux_losses: Optional[bool] = None,
     numerics: bool = False,
 ):
     """Build ``step(state, (x, y)) -> (state, metrics)``.
 
     ``apply_kwargs`` are forwarded to the model (e.g. ``{"train": True}``
-    for models with batch norm / dropout). ``aux_losses=True`` collects
-    everything the model ``sow``-ed into the ``"losses"`` collection
-    (e.g. MoE load-balancing terms) and adds it to the objective;
-    the summed extra term is reported as ``metrics["aux_loss"]``.
+    for models with batch norm / dropout).
+
+    A model that ``sow``s into ``"losses"`` (e.g. an expert layer's
+    load-balancing and router-z terms) has every such leaf added to the
+    objective, their sum reported as ``metrics["aux_loss"]``; what it
+    sows into ``"metrics"`` is reported under its own name. The step
+    learns this from ``state.sown``, which ``create_state`` fills from
+    ``model.init``'s result: no caller sets a flag, and a model that sows
+    nothing traces the step it always traced. ``aux_losses`` is an
+    override for a hand-built state: ``True`` collects ``"losses"``
+    whatever ``state.sown`` says, ``False`` collects nothing. Under the
+    numerics plane the sown metrics also ride the bundle (``"sown"``), so
+    the probe's throttled fetch publishes them as gauges.
 
     ``numerics=True`` fuses the numerics-plane bundle (obs/numerics)
     into the step: metrics gains a reserved ``METRICS_KEY`` entry of
     on-device scalars the caller must pop and hand to
     ``NumericsProbe.on_step`` (never aggregate it). When the batch is
     statically splittable — every leaf batched with the same even
-    leading dim, no batch_stats, no aux_losses — and
+    leading dim, no batch_stats, nothing sown — and
     ``EDL_NUMERICS_GNS`` is not ``0``, the gradient is computed as the
     mean of two half-batch gradients instead of one full-batch pass:
     identical to the full-batch gradient for mean-reduced loss heads
     over equal halves, same FLOP count, one jit — and the two half
-    norms feed the gradient-noise-scale estimator for free.
+    norms feed the gradient-noise-scale estimator for free. A model with
+    sown losses is never split: a load-balancing term is a product of two
+    batch means (assignments routed x mean router probability), so the
+    mean of two half-batch gradients is NOT the full-batch gradient and
+    the split's contract does not hold.
 
     The step program names its phases (``jax.named_scope``: metadata
     only, the HLO and its fusions are what they were): ``forward`` is
@@ -200,6 +232,13 @@ def make_train_step(
 
     def step(state: TrainState, batch):
         x, y = batch
+        if aux_losses is None:
+            sown = tuple(state.sown)
+        else:
+            sown = (AUX_LOSS,) if aux_losses else ()
+        collections = ["losses"] if AUX_LOSS in sown else []
+        if any(name != AUX_LOSS for name in sown):
+            collections.append("metrics")
 
         @jax.named_scope("forward")
         def loss_fn(params, bx, by):
@@ -208,8 +247,7 @@ def make_train_step(
             if state.batch_stats is not None:
                 variables["batch_stats"] = state.batch_stats
                 mutable.append("batch_stats")
-            if aux_losses:
-                mutable.append("losses")
+            mutable.extend(collections)
             if mutable:
                 outputs, mutated = state.apply_fn(
                     variables, bx, mutable=mutable, **kwargs
@@ -219,7 +257,9 @@ def make_train_step(
                 outputs = state.apply_fn(variables, bx, **kwargs)
                 mutated, new_stats = {}, None
             loss, metrics = loss_head(outputs, by)
-            if aux_losses:
+            if "metrics" in collections:
+                metrics = {**metrics, **_sown_metrics(mutated.get("metrics", {}))}
+            if "losses" in collections:
                 # always emit the metric so callers see a stable structure
                 aux = sum(
                     (
@@ -229,14 +269,14 @@ def make_train_step(
                     start=jnp.zeros((), jnp.float32),
                 )
                 loss = loss + aux
-                metrics = {**metrics, "aux_loss": aux}
+                metrics = {**metrics, AUX_LOSS: aux}
             return loss, (metrics, new_stats)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         # the half-batch split is decided STATICALLY at trace time from
         # concrete leaf shapes: no runtime branch reaches the schedule
         batch_size = None
-        if want_gns and state.batch_stats is None and not aux_losses:
+        if want_gns and state.batch_stats is None and not sown:
             leaves = jax.tree_util.tree_leaves(batch)
             dims = set()
             splittable = bool(leaves)
@@ -280,9 +320,23 @@ def make_train_step(
                     loss, grads, state.params, new_state.params,
                     halves=halves, batch=batch_size,
                 )
+                if sown:
+                    metrics[obs_numerics.METRICS_KEY]["sown"] = {
+                        name: metrics[name] for name in sown
+                    }
         return new_state, metrics
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
+
+
+def _sown_metrics(collection) -> Dict[str, jax.Array]:
+    """``{name: mean over the modules that sowed it}`` from a ``"metrics"``
+    collection (``{module path...: {name: (value,)}}``)."""
+    by_name: Dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(collection):
+        names = [k.key for k in path if hasattr(k, "key")]
+        by_name.setdefault(names[-1], []).append(jnp.asarray(leaf, jnp.float32))
+    return {name: jnp.mean(jnp.stack(v)) for name, v in by_name.items()}
 
 
 def _masked_reduce(loss_head, outputs, y, mask, context: str):

@@ -1,0 +1,353 @@
+"""The OLMoE path end to end on the CPU: the dropless expert layer against a
+dense mixture, the grouped matmul against a loop over groups, the auxiliary
+terms against hand arithmetic, an OLMoE-shaped ``TransformerLM`` against the
+benchmark's plain reference, and the trainer finding what the model sows with
+no flag from its caller."""
+
+import hashlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmark.reference import moe_lm as reference
+from edl_tpu.checkpoint import CheckpointManager
+from edl_tpu.models import MOE_EP_RULES, DroplessMoE, MoESpec, TransformerLM
+from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.obs import profile as obs_profile
+from edl_tpu.ops import grouped_matmul
+from edl_tpu.parallel import make_mesh, replicated, shard_batch
+from edl_tpu.parallel.sharding_rules import spec_for_path
+from edl_tpu.train import (
+    ElasticTrainer,
+    create_state,
+    cross_entropy_loss,
+    make_train_step,
+)
+
+D = 16
+
+
+def dense_mixture(params, x, k, norm):
+    """Every expert on every token, masked by the routing weights."""
+    tokens = x.reshape(-1, x.shape[-1])
+    e = params["gate"].shape[0]
+    probs = jax.nn.softmax(tokens @ params["router"]["kernel"])
+    weights, chosen = jax.lax.top_k(probs, k)
+    if norm:
+        weights = weights / weights.sum(-1, keepdims=True)
+    mask = jnp.zeros_like(probs).at[
+        jnp.arange(tokens.shape[0])[:, None], chosen
+    ].set(weights)
+    y = jnp.zeros_like(tokens)
+    for i in range(e):
+        hidden = jax.nn.silu(tokens @ params["gate"][i]) * (tokens @ params["up"][i])
+        y = y + mask[:, i:i + 1] * (hidden @ params["down"][i])
+    return y.reshape(x.shape)
+
+
+@pytest.mark.parametrize("norm", [False, True], ids=["raw", "norm_topk_prob"])
+@pytest.mark.parametrize("k,e", [(1, 4), (2, 4), (1, 8), (2, 8), (8, 8)])
+def test_layer_equals_a_dense_mixture(k, e, norm):
+    layer = DroplessMoE(num_experts=e, top_k=k, d_ff=24, norm_topk_prob=norm,
+                        dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(k * 10 + e), (2, 13, D))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    np.testing.assert_allclose(
+        layer.apply({"params": params}, x), dense_mixture(params, x, k, norm),
+        atol=2e-6,
+    )
+
+    def objective(fn):
+        return lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
+
+    got = jax.grad(objective(lambda p, x: layer.apply({"params": p}, x)), (0, 1))(params, x)
+    want = jax.grad(objective(lambda p, x: dense_mixture(p, x, k, norm)), (0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_no_token_is_dropped_when_one_expert_takes_them_all(k):
+    e = 8
+    layer = DroplessMoE(num_experts=e, top_k=k, d_ff=24, dtype=jnp.float32)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (2, 32, D))) + 0.1
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    # positive inputs and a router whose first k columns tower over the rest
+    router = jnp.zeros((D, e)).at[:, :k].set(
+        5.0 * (k - jnp.arange(k, dtype=jnp.float32))
+    )
+    params = {**params, "router": {"kernel": router}}
+    y, sown = layer.apply({"params": params}, x, mutable=["metrics", "intermediates"])
+    chosen = sown["intermediates"]["top_idx"][0]
+    assert set(np.unique(chosen)) == set(range(k))     # all 64 tokens, k experts
+    assert float(sown["metrics"]["moe_load_max"][0]) == pytest.approx(e / k)
+    np.testing.assert_allclose(y, dense_mixture(params, x, k, False), atol=2e-6)
+    assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0  # every token got an answer
+
+
+def loop_over_groups(lhs, rhs, sizes):
+    out, start = [], 0
+    for g, n in enumerate(sizes):
+        out.append(lhs[start:start + n] @ rhs[g])
+        start += n
+    return jnp.concatenate(out)
+
+
+@pytest.mark.parametrize("implementation", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize(
+    "sizes", [[5, 0, 17, 15], [0, 0, 37, 0], [1, 2, 3, 130]],
+    ids=["an_empty_group", "one_group_has_all", "no_multiple_of_a_tile"],
+)
+def test_grouped_matmul_equals_a_loop_over_groups(implementation, sizes):
+    m, k, n = sum(sizes), 24, 40
+    keys = jax.random.split(jax.random.PRNGKey(m), 3)
+    lhs = jax.random.normal(keys[0], (m, k))
+    rhs = jax.random.normal(keys[1], (len(sizes), k, n))
+    w = jax.random.normal(keys[2], (m, n))
+
+    def fn(a, b):
+        return grouped_matmul(
+            a, b, jnp.asarray(sizes), implementation,
+            interpret=implementation == "pallas",
+        )
+
+    got = jax.value_and_grad(lambda a, b: jnp.sum(fn(a, b) * w), (0, 1))(lhs, rhs)
+    want = jax.value_and_grad(
+        lambda a, b: jnp.sum(loop_over_groups(a, b, sizes) * w), (0, 1)
+    )(lhs, rhs)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
+
+
+HAND_LOGITS = np.log(np.array(
+    [[4.0, 2.0, 1.0, 1.0], [1.0, 5.0, 1.0, 1.0], [2.0, 2.0, 3.0, 1.0]], np.float32
+))
+
+
+@pytest.mark.parametrize("term", ["load_balance", "router_z"])
+def test_auxiliary_terms_by_hand(term):
+    """Three tokens, four experts, top-2. Logits are logs of small integers,
+    so every number below is a fraction one can check on paper."""
+    alpha, beta = 0.5, 0.25
+    layer = DroplessMoE(num_experts=4, top_k=2, d_ff=8, aux_weight=alpha,
+                        z_weight=beta, dtype=jnp.float32)
+    x = jnp.eye(3, D)[None]                       # token t is unit vector t
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    router = jnp.zeros((D, 4)).at[:3].set(HAND_LOGITS)
+    params = {**params, "router": {"kernel": router}}
+    _, sown = layer.apply({"params": params}, x, mutable=["losses"])
+    # p = [4 2 1 1]/8, [1 5 1 1]/8, [2 2 3 1]/8: top-2 are {0,1}, {1, one of
+    # 0/2/3 at 1/8 (the first: 0)}, {2, one of 0/1 at 2/8 (the first: 0)}
+    # assignments: expert 0 x3, expert 1 x2, expert 2 x1 of 6; P = column means
+    share = np.array([3, 2, 1, 0]) / 6.0
+    mean_p = np.array([7, 9, 5, 3]) / 24.0
+    want = {
+        "load_balance": alpha * 4 * float(share @ mean_p),
+        # every row sums to 8 before the log: logsumexp = log 8
+        "router_z": beta * float(np.log(8.0) ** 2),
+    }
+    assert float(sown["losses"][term][0]) == pytest.approx(want[term], rel=1e-5)
+
+
+TOY = {
+    "hidden_size": 32, "intermediate_size": 24, "norm_topk_prob": False,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "num_experts": 8,
+    "num_experts_per_tok": 2, "rms_norm_eps": 1e-5, "rope_theta": 10000,
+    "vocab_size": 64,
+    "train": {"load_balance_coef": 0.01, "router_z_coef": 0.001},
+}
+
+
+def toy_lm(layers, dtype=jnp.float32, remat=False):
+    return TransformerLM(
+        vocab_size=64, d_model=32, num_heads=4, num_kv_heads=4,
+        num_layers=layers, d_ff=24, dtype=dtype, remat=remat, norm_eps=1e-5,
+        qk_norm=True,
+        moe=MoESpec(num_experts=8, top_k=2, d_ff=24, aux_weight=0.01 / layers,
+                    z_weight=0.001 / layers),
+    )
+
+
+def lm_loss(logits, y):
+    return cross_entropy_loss(logits.reshape(-1, logits.shape[-1]), y.reshape(-1))
+
+
+def toy_batch(seed=0, b=4, t=16):
+    tokens = np.random.default_rng(seed).integers(0, 64, (b, t + 1)).astype(np.int32)
+    return tokens[:, :-1], tokens[:, 1:]
+
+
+@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
+@pytest.mark.parametrize("layers", [1, 2])
+def test_olmoe_shaped_lm_equals_the_plain_reference(layers, what):
+    lm = toy_lm(layers)
+    config = dict(TOY, num_hidden_layers=layers)
+    x, y = toy_batch()
+    params = lm.init(jax.random.PRNGKey(3), x)["params"]
+    # scales away from 1 so that a norm applied in the wrong place shows
+    params = jax.tree.map(
+        lambda p: p * 1.5 if p.ndim == 1 else p, params
+    )
+
+    def program(params):
+        logits, sown = lm.apply({"params": params}, x, mutable=["losses"])
+        extra = sum(jnp.sum(v) for v in jax.tree.leaves(sown["losses"]))
+        return lm_loss(logits, y)[0] + extra, logits
+
+    with jax.default_matmul_precision("highest"):
+        if what == "logits":
+            np.testing.assert_allclose(
+                program(params)[1], reference.forward(config, params, x)[0], atol=2e-5
+            )
+        elif what == "loss":
+            assert float(program(params)[0]) == pytest.approx(
+                float(reference.loss(config, params, x, y)), rel=1e-6
+            )
+        else:
+            got = jax.grad(lambda p: program(p)[0])(params)
+            want = jax.grad(lambda p: reference.loss(config, p, x, y))(params)
+            flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(want):
+                np.testing.assert_allclose(
+                    flat_got[path], leaf, atol=2e-6, err_msg=str(path)
+                )
+            router = got["layer_0"]["moe"]["router"]["kernel"]
+            assert float(jnp.max(jnp.abs(router))) > 0
+
+
+def zero_loss(logits, y):
+    return 0.0 * jnp.sum(logits), {}
+
+
+def test_fit_adds_what_the_model_sows_with_no_flag(tmp_path):
+    """A loss head that is zero: whatever moves a parameter comes from the
+    sown terms, which no argument of the trainer asked for."""
+    lm = toy_lm(1, remat=True)
+    x, y = toy_batch(b=8)                         # the default mesh is dp over 8
+    seen = {}
+    trainer = ElasticTrainer(
+        lm, optax.sgd(1.0), zero_loss, sample_input=np.zeros_like(x),
+        ckpt_dir=str(tmp_path / "ckpt"), seed=5, log=False,
+    )
+    state = trainer.fit(
+        lambda epoch: iter([(x, y)] * 3), epochs=1,
+        on_epoch_end=lambda epoch, metrics: seen.update(metrics),
+    )
+    assert state.sown == ("aux_loss", "moe_load_max")
+    assert float(seen["aux_loss"]) > 0 and float(seen["moe_load_max"]) >= 1.0
+    assert float(seen["loss"]) == pytest.approx(float(seen["aux_loss"]))
+    fresh = create_state(lm, jax.random.PRNGKey(5), x, optax.sgd(1.0))
+    moved = jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))), state.params, fresh.params
+    )
+    assert moved["layer_0"]["moe"]["router"]["kernel"] > 0   # the auxiliary terms alone
+    assert moved["layer_0"]["moe"]["down"] == 0              # no path from them to an expert
+    # the gauges the benchmark's reader and a dashboard see, set at the epoch's end
+    registry = obs_metrics.default_registry().snapshot()
+    assert registry["edl_train_moe_load_max"][""] == pytest.approx(
+        float(seen["moe_load_max"])
+    )
+    assert registry["edl_train_aux_loss"][""] == pytest.approx(float(seen["aux_loss"]))
+    # what was sown is a by-product of a step: no leaf of the state or of the
+    # checkpoint holds it
+    for tree in (state, fresh):
+        for path, _ in jax.tree_util.tree_leaves_with_path(tree):
+            assert "losses" not in str(path) and "metrics" not in str(path)
+    manager = CheckpointManager(str(tmp_path / "ckpt"))
+    try:
+        restored, status = manager.restore(fresh)
+    finally:
+        manager.close()
+    assert status is not None and int(restored.step) == 3
+    assert restored.sown == state.sown
+    assert len(jax.tree.leaves(restored)) == len(jax.tree.leaves(fresh))
+    np.testing.assert_array_equal(
+        restored.params["layer_0"]["moe"]["router"]["kernel"],
+        state.params["layer_0"]["moe"]["router"]["kernel"],
+    )
+    saved = [
+        os.path.join(base, name)
+        for base, _, names in os.walk(tmp_path / "ckpt") for name in names
+    ]
+    assert saved and not any("losses" in path for path in saved)
+
+
+def test_a_model_with_sown_losses_is_never_split():
+    lm = toy_lm(1)
+    x, y = toy_batch()
+    state = create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
+    _, metrics = make_train_step(lm_loss, numerics=True)(state, (x, y))
+    bundle = metrics["_numerics"]
+    assert "half_sq" not in bundle                       # no half-batch pass
+    assert set(bundle["sown"]) == {"aux_loss", "moe_load_max"}
+    # the override for a hand-built state still works both ways
+    _, off = make_train_step(lm_loss, aux_losses=False)(
+        create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3)), (x, y)
+    )
+    assert "aux_loss" not in off and "moe_load_max" not in off
+
+
+def test_two_dp_devices_agree_with_one():
+    lm = toy_lm(2)
+    x, y = toy_batch(b=4)
+    step = make_train_step(lm_loss, donate=False)
+    state = create_state(lm, jax.random.PRNGKey(0), x, optax.sgd(0.1))
+    one_state, one = step(state, (x, y))
+    with make_mesh({"dp": 2}, devices=jax.devices()[:2]) as mesh:
+        placed = create_state(
+            lm, jax.random.PRNGKey(0), x, optax.sgd(0.1), shardings=replicated(mesh)
+        )
+        two_state, two = step(placed, shard_batch(mesh, (x, y)))
+    for name in ("loss", "aux_loss", "moe_load_max"):
+        assert float(two[name]) == pytest.approx(float(one[name]), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(two_state.params), jax.tree.leaves(one_state.params)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_ep_rules_name_the_dropless_banks():
+    params = toy_lm(1).init(jax.random.PRNGKey(0), toy_batch()[0])["params"]
+    for bank in ("gate", "up", "down"):
+        assert spec_for_path("layer_0/moe/" + bank, MOE_EP_RULES)[0] == "ep"
+        assert params["layer_0"]["moe"][bank].shape[0] == 8
+    assert spec_for_path("layer_0/moe/router/kernel", MOE_EP_RULES) != ("ep", None, None)
+
+
+@pytest.mark.parametrize("scope", obs_profile.MOE_SCOPES)
+def test_the_compiled_step_names_the_expert_layers_scopes(scope):
+    lm = toy_lm(1, dtype=jnp.bfloat16, remat=True)
+    x, y = toy_batch()
+    state = create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
+    compiled = make_train_step(lm_loss, numerics=True).lower(state, (x, y)).compile()
+    table = obs_profile.scopes_of_hlo(compiled.as_text(), obs_profile.MOE_SCOPES)
+    assert scope in set(table.values())
+    phases = obs_profile.phases_of_hlo(compiled.as_text())
+    both = {phases[name] for name, s in table.items() if s == scope and name in phases}
+    assert "forward" in both or "backward" in both
+
+
+# sha256 of the lowered step of a dense TransformerLM at default arguments,
+# taken on the commit before the expert layer (e3b7f7a, jax 0.9.0, CPU): the
+# new fields at their defaults leave the dense LM's program what it was
+DENSE_STEP = {
+    True: "5d70944e413b062e6b4700a64e5ca301fedb229a788ed9923bfd2733b59412af",
+    False: "77d4ea6a0505738109c11f99a634f8fd4b59b23ae244947578b303e19117961a",
+}
+
+
+@pytest.mark.parametrize("numerics", [True, False], ids=["numerics", "bare"])
+def test_the_dense_lm_lowers_to_the_step_it_was(numerics):
+    lm = TransformerLM(vocab_size=128, d_model=64, num_heads=4, num_kv_heads=2,
+                       num_layers=2, d_ff=160, remat=True)
+    tokens = np.zeros((4, 32), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(3e-4))
+    )
+    assert state.sown == ()
+    text = make_train_step(lm_loss, numerics=numerics).lower(
+        state, (tokens, tokens)
+    ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == DENSE_STEP[numerics]
