@@ -278,6 +278,40 @@ def _remat_policy(name: Optional[str]):
     raise ValueError("unknown remat_policy %r" % (name,))
 
 
+@jax.custom_vjp
+def _head_matmul(x, w):
+    """fp32 ``x @ w`` of two operands in one dtype: see ``LMHead``."""
+    return jax.lax.dot_general(
+        x, w, (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _head_matmul_fwd(x, w):
+    return _head_matmul(x, w), (x, w)
+
+
+def _head_matmul_bwd(residuals, ct):
+    x, w = residuals
+    # the barrier makes the logits' gradient one array that both matmuls
+    # read: without it XLA clones its producer (softmax - one_hot over the
+    # fp32 logits, exp and all) into each of them
+    g = jax.lax.optimization_barrier(ct.astype(x.dtype))
+    rows = tuple(range(x.ndim - 1))
+    dx = jax.lax.dot_general(
+        g, w, (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dw = jax.lax.dot_general(
+        x, g, ((rows, rows), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+_head_matmul.defvjp(_head_matmul_fwd, _head_matmul_bwd)
+
+
 class LMHead(nn.Module):
     """Vocabulary projection with fp32 logits from input-dtype operands.
 
@@ -286,8 +320,12 @@ class LMHead(nn.Module):
     bf16 rate, and at vocab 32k the head is one of the largest matmuls
     in the model. Here the multiply runs in the activation dtype (bf16
     in training) with fp32 ACCUMULATION via preferred_element_type, so
-    the softmax still sees fp32 logits. Param path/shape match the old
-    nn.Dense exactly (``lm_head/kernel``) — checkpoints stay loadable.
+    the softmax still sees fp32 logits. The backward keeps the rule: the
+    logits' cotangent is cast to the activation dtype ONCE, as one array,
+    and dx and dW are both matmuls over it with fp32 accumulation, each
+    handed back in its operand's dtype, as autodiff hands a cast operand
+    its cotangent. Param path/shape match the old nn.Dense exactly
+    (``lm_head/kernel``) — checkpoints stay loadable.
     """
 
     vocab_size: int
@@ -298,11 +336,7 @@ class LMHead(nn.Module):
             "kernel", nn.initializers.lecun_normal(),
             (x.shape[-1], self.vocab_size),
         )
-        return jax.lax.dot_general(
-            x, kernel.astype(x.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        return _head_matmul(x, kernel.astype(x.dtype))
 
 
 class TransformerLM(nn.Module):
