@@ -161,7 +161,6 @@ class ElasticLauncher:
         ttl: float = 10.0,
         poll_interval: float = 0.2,
         extra_worker_env: Optional[Dict[str, str]] = None,
-        prewarm: bool = False,
         standby: bool = False,
         hot_restage: bool = False,
         fail_grace: Optional[float] = None,
@@ -192,8 +191,6 @@ class ElasticLauncher:
         self.stall_floor = float(
             os.environ.get("EDL_STALL_FLOOR", 0) or max(5.0, 2.0 * ttl)
         )
-        self.prewarm = prewarm or os.environ.get("EDL_PREWARM") == "1"
-        self.warmer = None  # created on first adopted stage
         # the elastic window rides the worker env contract so the AOT
         # resize ladder (train/aot.py) can enumerate its neighbor worlds
         self.extra_worker_env.setdefault(
@@ -273,6 +270,7 @@ class ElasticLauncher:
         self.completed = False
         self._complete_published = False
         self._handled_token = ""
+        self._token_seen = 0.0  # monotonic: when _handled_token arrived
         self._mem_gate_last: Optional[int] = None  # last recorded fit cap
         # health plane: a preemption notice (SIGTERM/SIGUSR1) flips the
         # event from the signal handler; the loop turns it into a drain
@@ -370,13 +368,6 @@ class ElasticLauncher:
                 "at backend init. Run one worker per host — it owns the "
                 "host's %d chip(s) under one mesh."
                 % (self.job_env.nproc_per_node, self.local_devices)
-            )
-        if self.prewarm:
-            raise ValueError(
-                "--prewarm on a TPU host: a shadow stage is a second set of "
-                "worker processes, and the live stage owns the chips they "
-                "would need. The in-worker AOT ladder (train/aot.py) "
-                "compiles neighbour worlds without a second process."
             )
 
     def _make_pod(self) -> Pod:
@@ -1029,6 +1020,7 @@ class ElasticLauncher:
         if token == self._handled_token:
             return
         self._handled_token = token
+        self._token_seen = time.monotonic()
         if self._draining:
             # my workers are mid-emergency-checkpoint: killing them for the
             # new generation (which excludes this pod anyway) would throw
@@ -1119,6 +1111,19 @@ class ElasticLauncher:
             return  # don't crash-loop the generation that just failed
         if published.stage != self._drain_token():
             return  # stale publish; a newer drain is already in flight
+        if published.stage == self._handled_token:
+            # since the token this pod had no workers and waited for a
+            # leader to publish: after a leader's death that is a lease
+            # running out and a replacement booting, which no process
+            # that is alive can trace but this one
+            with obs_trace.use(
+                obs_trace.op_context("restage", published.stage)
+            ):
+                self._tracer.record(
+                    "await_stage", self._token_seen,
+                    time.monotonic() - self._token_seen,
+                    op="restage", pod=self.pod.pod_id[:8],
+                )
         self.running = published
         self._note_membership(published)
         self._m_spawns.inc()
@@ -1193,34 +1198,14 @@ class ElasticLauncher:
             self._wake()
 
     def _note_membership(self, published: Cluster) -> None:
-        """Per-generation upkeep of the pod-scoped planes: the warmer
-        learns the new world size, and the checkpoint replica holder
-        GCs replicas superseded by the new membership."""
-        self._note_stage_for_warmer(published)
+        """Per-generation upkeep of the pod-scoped planes: the
+        checkpoint replica holder GCs replicas superseded by the new
+        membership."""
         if self.ckpt_replicas is not None:
             try:
                 self.ckpt_replicas.note_membership(published.pod_ids())
             except Exception as exc:  # noqa: BLE001 — GC is best-effort
                 logger.warning("ckpt replica gc failed: %s", exc)
-
-    def _note_stage_for_warmer(self, published: Cluster) -> None:
-        """Kick proactive compile-cache warming for the OTHER world sizes
-        the elastic window allows (see launch/warm.py) — the grow
-        transition should land on a warm cache the first time."""
-        if self.warmer is None:
-            from edl_tpu.launch.warm import make_warmer_if_enabled
-
-            self.warmer = make_warmer_if_enabled(
-                self.job_env,
-                self.pod.pod_id,
-                self.training_script,
-                self.training_args,
-                self.extra_worker_env,
-                self.prewarm,
-                self.platform,
-            ) or False
-        if self.warmer:
-            self.warmer.note_world(published.world_size)
 
     def _kill_workers(self) -> None:
         if self.procs:
@@ -1338,8 +1323,6 @@ class ElasticLauncher:
             self._kill_workers()
             if self.standby_pool is not None:
                 self.standby_pool.stop()
-            if self.warmer:
-                self.warmer.stop()
             if self.cache_exchange is not None:
                 self.cache_exchange.stop()
             if self._ckpt_peers_reg is not None:
@@ -1657,13 +1640,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         "EDL_DRAIN_BUDGET or 10). SIGTERM/SIGUSR1 starts the drain.",
     )
     parser.add_argument(
-        "--prewarm",
-        action="store_true",
-        help="warm the compile cache for the other world sizes in the "
-        "elastic window via background shadow stages (CPU meshes; see "
-        "edl_tpu/launch/warm.py). EDL_PREWARM=1 also enables.",
-    )
-    parser.add_argument(
         "--standby",
         action="store_true",
         help="keep pre-imported hot-standby worker shells so restages "
@@ -1784,7 +1760,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             args.training_script,
             args.training_args,
             ttl=args.ttl,
-            prewarm=args.prewarm,
             standby=args.standby,
             hot_restage=args.hot_restage,
             fail_grace=args.fail_grace,

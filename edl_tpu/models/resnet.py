@@ -196,8 +196,7 @@ class ResNeXt(nn.Module):
     The distillation benchmark's TEACHER is ResNeXt101_32x16d_wsl
     (reference README.md:68-72, example/distill/resnet50 — served via
     Paddle Serving); here it is an in-framework Flax model served by
-    ``edl_tpu.distill.serving.JaxPredictBackend`` or fused into a
-    co-located student step (tools/colocated_distill.py).
+    ``edl_tpu.distill.serving.JaxPredictBackend``.
     """
 
     stage_sizes: Sequence[int]

@@ -28,8 +28,8 @@ Three planes, one package:
   store's ``alerts/{rule}`` keyspace (daemon:
   ``python -m tools.edl_monitord``);
 - :mod:`edl_tpu.obs.profile` — the profiling plane: the roofline/peak
-  cost model (shared with ``bench.py``), live windowed-MFU / roofline /
-  HBM gauges per train stage, store-driven on-demand ``jax.profiler``
+  cost model, live windowed-MFU / roofline / HBM gauges per train
+  stage, store-driven on-demand ``jax.profiler``
   capture windows publishing ``profile/result/{pod}``, and the
   monitor's alert-triggered auto-capture action (CLI:
   ``python -m tools.edl_profile``);

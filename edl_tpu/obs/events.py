@@ -216,11 +216,6 @@ def get_recorder(component: Optional[str] = None) -> Optional[FlightRecorder]:
     with _lock:
         if _recorder is None and not _checked:
             directory = os.environ.get(ENV_DIR, "").strip()
-            # cache-warming shadow stages inherit the job env but must
-            # not pollute the job's black box (same rule as the obs
-            # keyspace in train/context._mount_obs)
-            if os.environ.get("EDL_WARM_ONLY") == "1":
-                directory = ""
             if directory:
                 from edl_tpu.obs.trace import _default_component
 

@@ -6,10 +6,9 @@ on-device profile of the window that fired"). Three pieces, all worker-
 side unless noted:
 
 **Cost model** (pure functions, no jax import). The peak-FLOPs and
-HBM-bandwidth tables and the :func:`roofline` estimator used to live in
-``bench.py`` — offline, once per benchmark run. They live here now and
-``bench.py`` / ``tools/lm_profile.py`` import them back, so the live
-plane and the offline bench can never disagree about what a chip can do.
+HBM-bandwidth tables and the :func:`roofline` estimator behind the live
+gauges below. (The benchmark keeps its own peaks, keyed by the exact
+``device_kind``: ``benchmark/peaks.json``.)
 
 **Live telemetry** (:class:`StepTelemetry`). At stage start the training
 loop extracts XLA's own FLOPs / bytes-accessed estimate for one step
@@ -74,7 +73,7 @@ PROFILE_SERVICE = "profile"
 REQUEST_NAME = "request"
 RESULT_PREFIX = "result/"
 
-# -- the cost model (factored out of bench.py) --------------------------------
+# -- the cost model -----------------------------------------------------------
 
 # peak dense bf16 FLOP/s per chip, by jax device_kind substring
 PEAK_BF16_FLOPS = [

@@ -1241,7 +1241,7 @@ def attention(
     scale: float | None = None,
 ) -> jax.Array:
     """Attention through the measured dispatch table — the default entry
-    point for every model in the tree (TransformerLM, lm_bench, the LM
+    point for every model in the tree (TransformerLM, the LM
     examples). Forward and backward implementations are chosen
     independently per sequence length; off-TPU it is exactly the dense
     reference. ``flash_attention`` / ``attention_reference`` remain for

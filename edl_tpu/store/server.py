@@ -388,6 +388,7 @@ class StoreServer:
             # membership slot 0: clients refresh their ordered endpoint
             # list from here; standbys register via their repl_sync
             self._has_state = True
+            self._fence_self_if_a_peer_is_newer()
             self._publish_endpoint(0, self._advertise)
         else:
             # a restarted standby recovering real local state may promote
@@ -999,6 +1000,26 @@ class StoreServer:
             if ep not in out:
                 out.append(ep)
         return out
+
+    def _fence_self_if_a_peer_is_newer(self) -> None:
+        """A primary that recovered state naming other members asks each
+        for its epoch BEFORE it serves a request: a standby that promoted
+        while this store was dead answers with the higher one, and this
+        store starts fenced. The promoted primary's fence campaign only
+        passes once a ``_FENCE_INTERVAL``; without this a client that had
+        not yet met the new primary could reconnect here in between, have
+        writes acknowledged from stale state and lose them."""
+        for ep in self._known_endpoints():
+            if ep == self._advertise:
+                continue
+            # no sender: a question, not a claim in an equal-epoch tie
+            resp = replica_mod.send_fence(ep, self._state.epoch, timeout=0.5)
+            peer_epoch = int(resp.get("e", 0)) if resp is not None else 0
+            if peer_epoch > self._state.epoch:
+                self._fence_self(
+                    peer_epoch, "%s answered with a newer epoch at boot" % ep
+                )
+                return
 
     def _publish_endpoint(
         self, slot: int, endpoint: str, role: Optional[str] = None

@@ -9,7 +9,6 @@ _HOME = {
     "current_env": "context",
     "enable_compilation_cache": "context",
     "init": "context",
-    "warm_only": "context",
     "worker_barrier": "context",
     "topk_compression": "compression",
     "ElasticTrainer": "loop",

@@ -36,11 +36,11 @@ inside the elastic window, nearest first — via
 scaled to each target world, populating the persistent cache every
 incarnation already points at. Only *shrink* shapes are compilable
 in-process (a grow mesh needs devices this process cannot see; those
-ride the launcher's shadow-stage warmer and the exchange below), and
-only by a worker whose local device sits in the target sub-mesh. Sizes
-are claimed through the store (leased while compiling, permanent
-``done:`` on success — warm.py's dedupe idiom) so co-hosted pods never
-compile the same shape twice. Ladder time is attributed to the new
+ride the exchange below), and only by a worker whose local device sits
+in the target sub-mesh. Sizes are claimed through the store (a key
+leased while compiling, rewritten to a permanent ``done:`` on success,
+released on failure) so co-hosted pods never compile the same shape
+twice. Ladder time is attributed to the new
 ``aot_compile`` goodput state on its own flight-recorder lane
 (component ``aot``) — never the ``train`` lane.
 
@@ -359,8 +359,8 @@ class AotLadder:
         self._mu = threading.Lock()
         self._client = client  # edl: guarded-by(self._mu)
         self._owns_client = client is None
-        # let the live stage settle before stealing cycles from it (the
-        # same measured lesson as warm.py's EDL_PREWARM_DELAY)
+        # let the live stage settle before stealing cycles from it: a
+        # compile that starts beside the stage's own first jit slows both
         if delay is None:
             delay = float(os.environ.get("EDL_AOT_DELAY", "1.0"))
         self._delay = delay
@@ -403,7 +403,7 @@ class AotLadder:
             except Exception:  # noqa: BLE001
                 pass
 
-    # -- store claims (warm.py's dedupe idiom) -----------------------------
+    # -- store claims (leased while compiling, ``done:`` on success) -------
 
     def _store(self):
         with self._mu:
@@ -514,8 +514,7 @@ class AotLadder:
             ndev = world * per_proc
             if ndev > len(devices):
                 # grow rung: the mesh needs devices this process cannot
-                # see — warm.py shadow stages and the cache exchange own
-                # this side of the ladder
+                # see — the cache exchange owns this side of the ladder
                 _M_AOT.inc(outcome="skipped_grow")
                 obs_events.record(
                     "aot", component="aot", world=world,

@@ -185,7 +185,6 @@ def _pull_cache_entries(env: WorkerEnv) -> None:
     global _cache_pulled
     if (
         _cache_pulled
-        or warm_only()
         or os.environ.get("EDL_CACHE_PULLED") == "1"
         or os.environ.get("EDL_CACHE_EXCHANGE", "1") == "0"
         or not env.store_endpoint
@@ -204,17 +203,6 @@ def _pull_cache_entries(env: WorkerEnv) -> None:
         )
     except Exception as exc:  # noqa: BLE001
         logger.warning("compile-cache pull failed: %s", exc)
-
-
-def warm_only() -> bool:
-    """True inside a cache-warming shadow stage (``EDL_WARM_ONLY=1``,
-    spawned by :mod:`edl_tpu.launch.warm`): the training script should run
-    exactly one train step — enough to populate the persistent compile
-    cache for this world size — then exit 0 without checkpoint writes or
-    store traffic. ``ElasticTrainer.fit`` honors this automatically;
-    hand-rolled loops check it themselves (tools/resize_bench_worker.py).
-    """
-    return os.environ.get("EDL_WARM_ONLY") == "1"
 
 
 _boot_recorded = False
@@ -254,8 +242,6 @@ def _mount_obs(env: WorkerEnv) -> None:
     (stage, rank) changes — a hot restage can move this process to a new
     rank. Never raises: obs must not break worker bootstrap."""
     global _obs_registered
-    if warm_only():
-        return  # shadow stages must not pollute the job's obs keyspace
     try:
         from edl_tpu.obs import http as obs_http
 
@@ -301,25 +287,24 @@ def init(env: Optional[WorkerEnv] = None) -> WorkerEnv:
     env = env or WorkerEnv()
     _env = env
     _mount_obs(env)
-    if not warm_only():
-        # goodput: from process start (stop-resume respawn) or in-process
-        # re-init until training resumes, the wall-clock is restage cost
-        from edl_tpu.obs import goodput as obs_goodput
+    # goodput: from process start (stop-resume respawn) or in-process
+    # re-init until training resumes, the wall-clock is restage cost
+    from edl_tpu.obs import goodput as obs_goodput
 
-        obs_goodput.enter("restage", cause="init")
-        if env.stage:
-            # distributed tracing: this worker's whole restage window —
-            # boot, cache pull, jax.distributed join, restore, first jit
-            # — stitches into the stage's restage trace (trace id derives
-            # from the stage token, the key every participant shares).
-            # Idempotent for the same stage; the step loop ends the op at
-            # the first completed step.
-            from edl_tpu.obs import trace as obs_trace
+    obs_goodput.enter("restage", cause="init")
+    if env.stage:
+        # distributed tracing: this worker's whole restage window —
+        # boot, cache pull, jax.distributed join, restore, first jit
+        # — stitches into the stage's restage trace (trace id derives
+        # from the stage token, the key every participant shares).
+        # Idempotent for the same stage; the step loop ends the op at
+        # the first completed step.
+        from edl_tpu.obs import trace as obs_trace
 
-            obs_trace.begin_process_op(
-                "restage", env.stage, rank=str(env.global_rank)
-            )
-            _record_boot_span(obs_trace)
+        obs_trace.begin_process_op(
+            "restage", env.stage, rank=str(env.global_rank)
+        )
+        _record_boot_span(obs_trace)
     if env.compile_cache_dir:
         enable_compilation_cache(env.compile_cache_dir)
         _pull_cache_entries(env)

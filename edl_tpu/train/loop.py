@@ -70,7 +70,7 @@ from edl_tpu.parallel import (
     replicated,
     shard_batch,
 )
-from edl_tpu.train.context import init, warm_only, worker_barrier
+from edl_tpu.train.context import init, worker_barrier
 from edl_tpu.train.step import TrainState, create_state, make_train_step
 
 DataFn = Callable[[int], Iterable]  # epoch -> records or ready batches
@@ -245,11 +245,7 @@ class ElasticTrainer:
         if not ctx.hot_restage_enabled():
             return self._fit_stage(data_fn, epochs, on_epoch_end, None)
         env = init()
-        monitor = (
-            ctx.StageMonitor(env)
-            if env.store_endpoint and not warm_only()
-            else None
-        )
+        monitor = ctx.StageMonitor(env) if env.store_endpoint else None
         try:
             while True:
                 try:
@@ -359,19 +355,16 @@ class ElasticTrainer:
         env = init()
         t_setup = time.monotonic()  # train_setup trace segment starts here
         mesh = make_mesh(self._mesh_axes)
-        # cache-warming shadow stage: compile + one step, no checkpoint
-        # manager at all (a warm stage must never touch the job's ckpt dir)
-        warm = warm_only()
         mngr = (
             CheckpointManager(self._ckpt_dir, async_save=self._async_save)
-            if self._ckpt_dir and not warm
+            if self._ckpt_dir
             else None
         )
         # health plane: drain-notice watch + step heartbeats. Best-effort
         # by design — a job without a store (or a store that is down right
         # now) trains exactly as before, it just cannot drain gracefully.
         health = None
-        if env.store_endpoint and env.job_id and not warm:
+        if env.store_endpoint and env.job_id:
             try:
                 health = ctx.HealthMonitor(env)
             except Exception as exc:  # noqa: BLE001
@@ -384,31 +377,26 @@ class ElasticTrainer:
         capture: Optional[obs_profile.CaptureController] = None
         ladder = None  # AOT resize ladder, armed after the first step
         # memory plane: compile-time plan + census/watermarks + OOM
-        # forensics, per stage (a warm shadow stage compiles and exits —
-        # its plan would be the same executable's, published twice)
+        # forensics, per stage
         mem_plane: Optional[obs_memory.MemoryPlane] = None
-        if not warm:
-            try:
-                mem_plane = obs_memory.MemoryPlane(
-                    stage=env.stage, rank=env.global_rank,
-                    client=(
-                        health.store_client if health is not None else None
-                    ),
-                    job_id=env.job_id or "",
-                    expect_donation=True,  # make_train_step donates state
-                )
-            except Exception as exc:  # noqa: BLE001 — memory plane is telemetry
-                print(
-                    "elastic-trainer: memory plane unavailable (%s); "
-                    "continuing without it" % exc,
-                    file=sys.stderr,
-                )
-        # numerics plane: fused bundle + throttled host export. The warm
-        # shadow stage never publishes (its two steps are compile bait,
-        # not training). Shares the health plane's store client for the
-        # cross-replica digest exchange when one exists.
+        try:
+            mem_plane = obs_memory.MemoryPlane(
+                stage=env.stage, rank=env.global_rank,
+                client=health.store_client if health is not None else None,
+                job_id=env.job_id or "",
+                expect_donation=True,  # make_train_step donates state
+            )
+        except Exception as exc:  # noqa: BLE001 — memory plane is telemetry
+            print(
+                "elastic-trainer: memory plane unavailable (%s); "
+                "continuing without it" % exc,
+                file=sys.stderr,
+            )
+        # numerics plane: fused bundle + throttled host export. Shares
+        # the health plane's store client for the cross-replica digest
+        # exchange when one exists.
         probe = None
-        if not warm and obs_numerics.enabled():
+        if obs_numerics.enabled():
             probe = obs_numerics.NumericsProbe(
                 rank=env.global_rank,
                 client=health.store_client if health is not None else None,
@@ -469,9 +457,6 @@ class ElasticTrainer:
                                     ),
                                 )
                             )
-                # the warm shadow stage compiles WITH the bundle fused
-                # (enabled(), not probe) — its cache entry must be the
-                # computation the real stage will look up
                 step = make_train_step(
                     self._loss, self._apply_kwargs,
                     numerics=obs_numerics.enabled(),
@@ -495,22 +480,21 @@ class ElasticTrainer:
                 # profiles batches 100-105, train_with_fleet.py:524-534)
                 # — now riding the same controller as store requests.
                 step_telemetry = obs_profile.StepTelemetry()
-                if not warm:
-                    try:
-                        capture = obs_profile.CaptureController(
-                            env, telemetry=step_telemetry
+                try:
+                    capture = obs_profile.CaptureController(
+                        env, telemetry=step_telemetry
+                    )
+                    profile_dir = os.environ.get("EDL_PROFILE_DIR")
+                    if profile_dir:
+                        capture.arm_local(
+                            profile_dir, start_after=10, steps=5
                         )
-                        profile_dir = os.environ.get("EDL_PROFILE_DIR")
-                        if profile_dir:
-                            capture.arm_local(
-                                profile_dir, start_after=10, steps=5
-                            )
-                    except Exception as exc:  # noqa: BLE001 — profiling is best-effort
-                        print(
-                            "elastic-trainer: capture plane unavailable "
-                            "(%s); continuing without it" % exc,
-                            file=sys.stderr,
-                        )
+                except Exception as exc:  # noqa: BLE001 — profiling is best-effort
+                    print(
+                        "elastic-trainer: capture plane unavailable "
+                        "(%s); continuing without it" % exc,
+                        file=sys.stderr,
+                    )
                 tracer = obs_trace.get_tracer()
                 retired = RetireClock(tracer)
                 first_step_done = False
@@ -641,7 +625,7 @@ class ElasticTrainer:
                             # persistent cache on a low-priority thread
                             # (train/aot.py) so the NEXT resize re-jits
                             # from a cache load instead of a compile
-                            if not warm and env.compile_cache_dir:
+                            if env.compile_cache_dir:
                                 ladder = self._start_ladder(
                                     env, step, state, device_batch,
                                     mem_plane=mem_plane,
@@ -671,18 +655,6 @@ class ElasticTrainer:
                             capture.on_step(
                                 sync=lambda m=metrics: jax.block_until_ready(m)
                             )
-                        if warm and step_idx >= 2:
-                            # two steps, not one: step 1 caches the
-                            # host-placed-state compile, step 2 the
-                            # steady-state (mesh-sharded inputs) one
-                            jax.block_until_ready(metrics)
-                            if env.is_rank0 and self._log:
-                                print(
-                                    "warm-only stage (world=%d): step "
-                                    "compiled and cached; exiting"
-                                    % env.world_size
-                                )
-                            sys.exit(0)
                     if first_step_done:
                         # the epoch-end device sync below is step work,
                         # not input wait

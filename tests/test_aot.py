@@ -676,3 +676,45 @@ class TestJoinFromPeerCache:
             client.close()
         assert b["rx"] == 0, b
         assert b["counts"]["miss"] >= 1 and b["counts"]["write"] >= 1, b
+
+
+# -- every rank writes the persistent cache ------------------------------------
+
+class TestAllRankCacheWrites:
+    def test_patch_applies_and_is_idempotent(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("EDL_CACHE_ALL_RANKS", "1")
+        from edl_tpu.train.context import enable_compilation_cache
+
+        enable_compilation_cache(str(tmp_path / "c"))
+        from jax._src import compiler as _compiler
+
+        assert getattr(_compiler._cache_write, "_edl_all_ranks", False)
+        before = _compiler._cache_write
+        enable_compilation_cache(str(tmp_path / "c"))
+        assert _compiler._cache_write is before  # no double-wrap
+
+    def test_patched_write_ignores_process_id(self, tmp_path, monkeypatch):
+        """The wrapped _cache_write must not take the rank-0-only early
+        return: with a fake nonzero process_id it should proceed into the
+        write path (observed via the compilation_cache call)."""
+        monkeypatch.setenv("EDL_CACHE_ALL_RANKS", "1")
+        from edl_tpu.train.context import enable_compilation_cache
+
+        enable_compilation_cache(str(tmp_path / "c"))
+        from jax._src import compiler as _compiler
+        from jax._src import compilation_cache as _cc
+
+        calls = []
+        monkeypatch.setattr(
+            _cc, "put_executable_and_time",
+            lambda *a, **kw: calls.append(a),
+        )
+        real_gs = _compiler.distributed.global_state
+        monkeypatch.setattr(real_gs, "process_id", 3, raising=False)
+        try:
+            _compiler._cache_write(
+                "k", 1.0, "jit_x", object(), object(), []
+            )
+        except Exception:
+            pass  # fake executable may explode later in the write path
+        assert calls, "write path never reached despite process_id=3"

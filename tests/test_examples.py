@@ -104,47 +104,6 @@ def test_elastic_text_lm_standalone(tmp_path):
 
 
 @pytest.mark.slow
-def test_colocated_distill_tool():
-    """tools/colocated_distill.py cpu_debug path: fused teacher+student
-    step runs and reports a sane retention ratio."""
-    import json
-
-    env = dict(
-        os.environ,
-        PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        JAX_PLATFORMS="cpu",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "colocated_distill.py")],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert proc.returncode == 0, proc.stderr[-1500:]
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "colocated_distill_retention_cpu_debug"
-    assert 0.0 < rec["value"] <= 1.2
-    assert rec["coloc_img_s"] < rec["pure_img_s"] * 1.2
-
-
-@pytest.mark.slow
-def test_lm_bench_tool_cpu_debug():
-    import json
-
-    env = dict(
-        os.environ,
-        PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        JAX_PLATFORMS="cpu",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "lm_bench.py")],
-        capture_output=True, text=True, timeout=900, env=env,
-    )
-    assert proc.returncode == 0, proc.stderr[-1200:]
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "transformer_lm_train_tokens_per_s_cpu_debug"
-    assert rec["value"] > 0 and rec["loss"] > 0
-
-
-@pytest.mark.slow
 def test_attention_bench_tool_cpu():
     import json
 

@@ -1531,7 +1531,7 @@ class TestCli:
             "lock-order", "blocking-under-lock", "wire-protocol",
             "donation",
         } <= names
-        # per-pass one-line summaries (archived by run_tpu_suite)
+        # per-pass one-line summaries
         for p in doc["passes"]:
             assert p["status"] == "pass" and p["new"] == 0
             assert p["line"].startswith("%s: PASS" % p["name"])
@@ -1683,8 +1683,8 @@ class TestCli:
             assert "cannot regenerate" in out.stderr
 
     def test_compact_json_is_single_line_with_pass_lines(self, tmp_path):
-        """The run_tpu_suite archive format: one line of JSON, one
-        pass/fail summary line per pass."""
+        """``--json --compact``: one line of JSON, one pass/fail summary
+        line per pass."""
         (tmp_path / "pkg").mkdir()
         (tmp_path / "pkg" / "w.py").write_text(textwrap.dedent(_LOCK_RED))
         out = _cli(["--root", str(tmp_path), "pkg", "--json", "--compact",

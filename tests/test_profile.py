@@ -100,6 +100,18 @@ class TestCostModel:
         assert memory["roofline_mfu_ceiling"] == 0.5  # ai/ridge = 5/10
         assert memory["mfu_of_ceiling"] == 0.5        # 0.25 of a 0.5 ceiling
 
+    @pytest.mark.parametrize("flops,bound,ceiling", [
+        (1e12, "memory", 0.416),  # 100 FLOPs/byte under the ridge below
+        (1e13, "compute", 1.0),
+    ])
+    def test_roofline_on_the_v5e_rows(self, flops, bound, ceiling):
+        # the tables' own v5e ridge: 197e12 / 819e9 = 240.5 FLOPs/byte
+        got = roofline(
+            {"flops": flops, "bytes accessed": 1e10}, "TPU v5e", 197e12
+        )
+        assert got["bound"] == bound
+        assert got["roofline_mfu_ceiling"] == ceiling
+
     def test_roofline_empty_on_missing_inputs(self):
         assert roofline({}, "TPU v4", peak=275e12) == {}
         assert roofline({"flops": 1.0}, "TPU v4", peak=275e12) == {}
@@ -125,14 +137,6 @@ class TestCostModel:
 
     def test_step_cost_failure_degrades_to_empty(self):
         assert step_cost(lambda: None) == {}  # not jitted: no .lower
-
-    def test_bench_and_tools_import_the_shared_model(self):
-        # the dedupe satellite: one table, no drift
-        import bench
-
-        assert bench.roofline is roofline
-        assert bench.PEAK_BF16_FLOPS is obs_profile.PEAK_BF16_FLOPS
-        assert bench._peak_flops is peak_flops
 
 
 # -- memory_stats guard -------------------------------------------------------

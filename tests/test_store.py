@@ -768,6 +768,34 @@ class TestWarmStandby:
         finally:
             standby.stop()
 
+    def test_stale_primary_restarts_fenced_before_its_first_request(
+        self, tmp_path
+    ):
+        """A primary restarted on stale state beside a standby that
+        promoted meanwhile is fenced when its constructor returns, not a
+        fence-campaign pass later: it asks the members its own state
+        names for their epoch before it serves."""
+        primary, standby = self._pair(tmp_path)
+        pport = primary.port
+        try:
+            c = StoreClient(
+                "%s,%s" % (primary.endpoint, standby.endpoint), timeout=5.0
+            )
+            c.put("/s/k", b"v")
+            time.sleep(0.3)
+            c.close()
+            primary.kill()
+            self._wait_promoted(standby)
+            old = StoreServer(
+                host="127.0.0.1", port=pport, data_dir=str(tmp_path / "p")
+            )
+            try:
+                assert old._fenced_by == standby._state.epoch
+            finally:
+                old.start().stop()
+        finally:
+            standby.stop()
+
     def test_equal_epoch_fence_tie_breaks_deterministically(self, tmp_path):
         """Two standbys promoted concurrently land on the SAME epoch;
         strictly-greater comparisons can't resolve that, so the fence

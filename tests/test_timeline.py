@@ -6,10 +6,12 @@ step time the device paced (``train/loop.py:RetireClock``)."""
 import contextlib
 import glob
 import os
+import queue
 import subprocess
 import sys
 import threading
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,7 @@ import numpy as np
 import optax
 import pytest
 
+from edl_tpu.data import prefetch as data_prefetch
 from edl_tpu.models import MLP
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.obs import profile as obs_profile
@@ -92,11 +95,47 @@ def _records(epoch, n=192, d=8):
         yield x, (x @ w).astype(np.float32)
 
 
+class _AskedQueue(queue.Queue):
+    """The prefetch queue of one epoch; ``asked`` is set once the consumer
+    has looked into it (``prefetch_to_device`` reads ``empty()`` before each
+    ``get()``: that reading is what the starvation counter counts)."""
+
+    made = []
+
+    def __init__(self, maxsize=0):
+        super().__init__(maxsize)
+        self.asked = threading.Event()
+        _AskedQueue.made.append(self)
+
+    def empty(self):
+        self.asked.set()
+        return super().empty()
+
+
+def _records_after_the_consumer_asked(epoch):
+    """``_records``, held back until the loop has asked this epoch's queue
+    for its first batch: that batch is then waited for in every
+    interleaving of the loop and the feeder."""
+    assert _AskedQueue.made[-1].asked.wait(timeout=60)
+    yield from _records(epoch)
+
+
 @pytest.fixture(scope="module")
 def fit_events(tmp_path_factory):
     """The ring after two epochs of 24 steps of a toy model, with a
     callback and a save after each, and how far the step-time histogram
     and the prefetch counters moved."""
+    with pytest.MonkeyPatch.context() as patch:
+        # the name `queue` as prefetch.py sees it, not the module itself:
+        # other planes make queues of their own during a fit
+        patch.setattr(data_prefetch, "queue", types.SimpleNamespace(
+            Queue=_AskedQueue, Full=queue.Full, Empty=queue.Empty,
+        ))
+        yield _fit_events(tmp_path_factory)
+    _AskedQueue.made.clear()
+
+
+def _fit_events(tmp_path_factory):
     tracer = obs_trace.get_tracer()
     tracer.clear()
     registry = obs_metrics.default_registry()
@@ -107,7 +146,10 @@ def fit_events(tmp_path_factory):
         ckpt_dir=str(tmp_path_factory.mktemp("ckpt")), log=False,
     )
     loop_tid = threading.get_ident() & 0x7FFFFFFF
-    trainer.fit(_records, epochs=2, on_epoch_end=lambda e, m: None)
+    trainer.fit(
+        _records_after_the_consumer_asked, epochs=2,
+        on_epoch_end=lambda e, m: None,
+    )
     after = registry.snapshot()
 
     def grown(name, key=""):

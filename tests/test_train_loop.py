@@ -241,3 +241,100 @@ class TestEvaluate:
             ),
         )
         assert "loss" in out and np.isfinite(out["loss"])
+
+
+# -- what _fit_stage does around the steps --------------------------------------
+
+
+def _trainer(**kwargs):
+    kwargs.setdefault("log", False)
+    return ElasticTrainer(
+        MLP(hidden=(16,), features=1), optax.sgd(0.05), mse_loss,
+        sample_input=np.zeros((8, 8), np.float32), batch_size=8, **kwargs
+    )
+
+
+def test_every_plane_is_closed_when_the_loader_raises_mid_epoch(
+    tmp_path, monkeypatch
+):
+    """Pins: an exception out of ``data_fn``'s iterator reaches ``fit``'s
+    caller, and on the way the stage closes each plane it built exactly
+    once: checkpoint manager, memory plane, numerics probe, capture
+    controller, step telemetry."""
+    from edl_tpu.obs import memory, numerics, profile
+    from edl_tpu.train import loop
+
+    closed = []
+    for owner in (
+        loop.CheckpointManager, memory.MemoryPlane, numerics.NumericsProbe,
+        profile.CaptureController, profile.StepTelemetry,
+    ):
+        def close(self, _real=owner.close, _name=owner.__name__):
+            closed.append(_name)
+            _real(self)
+
+        monkeypatch.setattr(owner, "close", close)
+
+    def torn(epoch):
+        yield from _records(epoch, n=20)
+        raise RuntimeError("the loader broke")
+
+    with pytest.raises(RuntimeError, match="the loader broke"):
+        _trainer(ckpt_dir=str(tmp_path / "ckpt")).fit(torn, epochs=1)
+    assert sorted(closed) == [
+        "CaptureController", "CheckpointManager", "MemoryPlane",
+        "NumericsProbe", "StepTelemetry",
+    ]
+
+
+def test_an_epoch_short_of_one_batch_trains_nothing_and_says_so(capsys):
+    """Pins: with fewer records than ``batch_size`` the epoch runs no step,
+    prints why, and still calls ``on_epoch_end`` with empty metrics."""
+    ended = []
+    state = _trainer(log=True).fit(
+        lambda epoch: _records(epoch, n=5), epochs=2,
+        on_epoch_end=lambda e, m: ended.append((e, dict(m))),
+    )
+    assert int(state.step) == 0
+    assert ended == [(0, {}), (1, {})]
+    out = capsys.readouterr().out
+    assert out.count("produced no full batches") == 2
+
+
+def test_epoch_end_metrics_never_carry_the_numerics_bundle():
+    """Pins: the step's fused numerics bundle (device arrays under
+    ``METRICS_KEY``) is taken out for the probe before ``on_epoch_end``
+    sees the metrics, while the plane is on."""
+    from edl_tpu.obs import numerics
+
+    assert numerics.enabled()
+    seen = []
+    _trainer().fit(
+        lambda epoch: _records(epoch, n=32), epochs=2,
+        on_epoch_end=lambda e, m: seen.append(set(m)),
+    )
+    assert len(seen) == 2
+    assert all("loss" in keys for keys in seen)
+    assert all(numerics.METRICS_KEY not in keys for keys in seen)
+
+
+def test_without_store_or_ckpt_dir_fit_builds_no_monitor_and_no_manager(
+    monkeypatch,
+):
+    """Pins: with no store endpoint and no ``ckpt_dir`` the stage constructs
+    neither a health monitor nor a checkpoint manager, and ``fit`` still
+    trains and returns the state."""
+    from edl_tpu.train import context, loop
+
+    monkeypatch.delenv("EDL_STORE_ENDPOINT", raising=False)
+
+    built = []
+    monkeypatch.setattr(
+        context, "HealthMonitor", lambda *a, **k: built.append("monitor")
+    )
+    monkeypatch.setattr(
+        loop, "CheckpointManager", lambda *a, **k: built.append("manager")
+    )
+    state = _trainer().fit(lambda epoch: _records(epoch, n=32), epochs=1)
+    assert built == []
+    assert int(state.step) == 4

@@ -21,12 +21,6 @@ marker = os.path.join(out_dir, "run.%s.%s.%s" % (stage, rank, world))
 with open(marker, "w") as f:
     f.write(coordinator)
 
-if os.environ.get("EDL_WARM_ONLY") == "1":
-    # cache-warming shadow stage: a real worker exits right after its
-    # first (cache-populating) step — model that promptly
-    time.sleep(0.2)
-    sys.exit(0)
-
 limit = float(os.environ.get("TEST_EXIT_AFTER", "1e9"))
 deadline = time.time() + limit
 while time.time() < deadline:

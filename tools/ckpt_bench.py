@@ -199,8 +199,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     result["ts"] = time.strftime("%Y-%m-%dT%H:%M:%S")
     # run archive (EDL_RUN_ARCHIVE): peer/durable restore timings become
     # indexed rollups so tier-ladder regressions gate via edl_report;
-    # the emitted doc carries its bundle name so downstream archivers
-    # (run_tpu_suite) skip the already-indexed run
+    # the emitted doc carries its bundle name
     from edl_tpu.obs import archive as run_archive
 
     bundle = run_archive.maybe_archive_bench("ckpt_bench", result, backend="cpu")

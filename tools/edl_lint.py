@@ -307,8 +307,7 @@ def main(argv=None) -> int:
                     "status": (
                         "fail" if new_by_pass.get(name, 0) else "pass"
                     ),
-                    # one-line per-pass summary, archived by
-                    # run_tpu_suite alongside the bench payloads
+                    # one-line per-pass summary
                     "line": "%s: %s — %d finding(s), %d new" % (
                         name,
                         "FAIL" if new_by_pass.get(name, 0) else "PASS",

@@ -154,7 +154,7 @@ def analyze(data: dict) -> dict:
 
 
 def run(schedule, interval, batch_per_worker=None, ttl=1.5,
-        nproc_per_node=1, tail=None, platform="cpu", prewarm=False,
+        nproc_per_node=1, tail=None, platform="cpu",
         standby=True, aot=True) -> dict:
     store = StoreServer(port=0).start()
     job_id = "resize-bench-%d" % int(time.time())
@@ -180,8 +180,7 @@ def run(schedule, interval, batch_per_worker=None, ttl=1.5,
         extra_env["EDL_AOT"] = "0"
         extra_env["EDL_CACHE_EXCHANGE"] = "0"
     elif platform == "cpu":
-        # single-core-rig tuning, same serialization floor as the
-        # prewarm block below: at nice 10 the ladder thread loses CPU
+        # single-core-rig tuning: at nice 10 the ladder thread loses CPU
         # arbitration to the co-hosted training workers and its
         # speculative compile races the schedule's next resize (measured:
         # the kill lands mid-compile ~half the time at --interval 18).
@@ -195,19 +194,6 @@ def run(schedule, interval, batch_per_worker=None, ttl=1.5,
         # pod's worker skips the python+jax cold start, and on a
         # single-worker window the shell pre-claims the freed chip
         extra_env["EDL_STANDBY"] = "1"
-    if prewarm:
-        # launcher-side shadow-stage warming (launch/warm.py): grow
-        # transitions should land on a warm cache the FIRST time.
-        # Single-core-rig tuning (see MEMORY: every CPU ratio here is a
-        # serialization floor): nice 0 so the warm compile outraces the
-        # schedule's resize, budget 1 so only the largest grow is warmed
-        # and no shadow stage overlaps a transition, delay 25 s so the
-        # live stage's own cold compile finishes first. On real hosts
-        # the defaults (nice 10, budget 4, delay 15) ride spare cores.
-        extra_env["EDL_PREWARM"] = "1"
-        extra_env["EDL_PREWARM_NICE"] = "0"
-        extra_env["EDL_PREWARM_MAX"] = "1"
-        extra_env["EDL_PREWARM_DELAY"] = "25"
     worker_args = []
     if batch_per_worker:
         worker_args += ["--batch_per_worker", str(batch_per_worker)]
@@ -241,7 +227,6 @@ def run(schedule, interval, batch_per_worker=None, ttl=1.5,
             file=sys.stderr,
         )
     report["schedule"] = list(schedule)
-    report["prewarm"] = bool(prewarm)
     report["standby"] = bool(standby)
     report["aot"] = bool(aot)
     report["platform"] = platform  # cpu numbers prove the machinery; the
@@ -251,10 +236,8 @@ def run(schedule, interval, batch_per_worker=None, ttl=1.5,
         # A/B flags live in the KIND: a --no-aot control lane must trend
         # against other control runs, never share a rolling baseline
         # with its treatment sibling (the same rule edl_report's legacy
-        # import applies to the checked-in _control/_prewarm artifacts)
+        # import applies to the checked-in A/B artifacts)
         kind = "resize_bench"
-        if prewarm:
-            kind += "_prewarm"
         if not standby:
             kind += "_nostandby"
         if not aot:
@@ -298,11 +281,6 @@ def main():
         help="cpu = pinned local mesh; tpu = let workers grab the real chip",
     )
     parser.add_argument(
-        "--prewarm", action="store_true",
-        help="enable launcher-side compile-cache warming for anticipated "
-        "world sizes (launch/warm.py)",
-    )
-    parser.add_argument(
         "--no-standby", action="store_true",
         help="disable the hot-standby worker shells (the cold-spawn "
         "control measurement; standby is on by default)",
@@ -322,7 +300,6 @@ def main():
         ttl=args.ttl,
         nproc_per_node=args.nproc_per_node,
         platform=args.platform,
-        prewarm=args.prewarm,
         standby=not args.no_standby,
         aot=not args.no_aot,
     )

@@ -70,11 +70,9 @@ def main():
     step = make_train_step(cross_entropy_loss, apply_kwargs)
     meter = WorkerMeter(env, batch_per_step=batch_per_worker)
 
-    from edl_tpu.train import warm_only
     from edl_tpu.train import aot
     from edl_tpu.utils.telemetry import record_cache_stats, record_event
 
-    warm = warm_only()
     ladder = None
     with mesh:
         from edl_tpu.parallel import device_put_global, replicated
@@ -86,16 +84,15 @@ def main():
         rep = replicated(mesh)
         state = jax.tree.map(lambda s: device_put_global(s, rep), state)
         batch = shard_batch(mesh, (x, y))
-        if not warm:
-            # 'ready' splits the restage lane for analyze(): publish ->
-            # ready is process+import+init+state build ("restore"),
-            # ready -> first_step is the jit (compile or cache load)
-            client = meter._store()
-            if client is not None:
-                record_event(
-                    client, env.job_id, env.stage, "ready",
-                    "w%d" % env.global_rank,
-                )
+        # 'ready' splits the restage lane for analyze(): publish ->
+        # ready is process+import+init+state build ("restore"),
+        # ready -> first_step is the jit (compile or cache load)
+        client = meter._store()
+        if client is not None:
+            record_event(
+                client, env.job_id, env.stage, "ready",
+                "w%d" % env.global_rank,
+            )
         import time as _time
 
         from edl_tpu.obs import events as obs_events
@@ -105,7 +102,7 @@ def main():
         if os.environ.get("EDL_DEBUG_STEP_HLO") == "1":
             # cache-debug probe: identical shas across two workers mean
             # their step executables share persistent-cache keys up to
-            # compile options (used to validate shadow-stage warming)
+            # compile options
             import hashlib
             text = step.lower(state, batch).as_text()
             print("step-hlo sha=%s len=%d world=%d" % (
@@ -117,22 +114,21 @@ def main():
             # a per-step fetch: the metered sps must count finished
             # steps, not dispatched ones
             float(jax.device_get(metrics["loss"]))
-            if not warm:
-                # goodput: the first step closes the restage interval
-                # context.init opened (init -> first step IS the restage
-                # lane this bench measures); the throttled heartbeat
-                # bounds a SIGKILLed incarnation's open train interval
-                # to <= 1 s (loop.py's idiom) — so an archived bench
-                # run's flight segments attribute wall-clock like a real
-                # job's and edl_report --diff names the restage lane,
-                # not "down"
-                if k == 0:
-                    obs_goodput.enter("train", cause="first_step")
-                now = _time.monotonic()
-                if now - last_flight >= 1.0:
-                    last_flight = now
-                    obs_events.record("train_heartbeat", step=k)
-            if k == 0 and not warm:
+            # goodput: the first step closes the restage interval
+            # context.init opened (init -> first step IS the restage
+            # lane this bench measures); the throttled heartbeat
+            # bounds a SIGKILLed incarnation's open train interval
+            # to <= 1 s (loop.py's idiom) — so an archived bench
+            # run's flight segments attribute wall-clock like a real
+            # job's and edl_report --diff names the restage lane,
+            # not "down"
+            if k == 0:
+                obs_goodput.enter("train", cause="first_step")
+            now = _time.monotonic()
+            if now - last_flight >= 1.0:
+                last_flight = now
+                obs_events.record("train_heartbeat", step=k)
+            if k == 0:
                 # first step done: publish this stage's cache ledger
                 # (hit = loaded a speculated/peer-compiled executable,
                 # miss+write = paid a real compile) and arm the AOT
@@ -154,19 +150,10 @@ def main():
                         ).start()
                     except Exception as exc:  # noqa: BLE001
                         print("aot ladder unavailable: %s" % exc)
-            if warm and k >= 1:
-                # shadow stage spawned by launch/warm.py: exit after TWO
-                # steps, not one — step 1 compiles with host-placed state,
-                # step 2 with the mesh-sharded state it produced (the
-                # steady-state executable); both must land in the cache
-                print("warm-only: step cached for world=%d" % env.world_size)
-                sys.exit(0)
-            if not warm:
-                meter.step()
+            meter.step()
             k += 1
     meter.close()
-    if not warm:
-        obs_goodput.close(cause="bench_done")
+    obs_goodput.close(cause="bench_done")
     if ladder is not None:
         ladder.close()
     if env.is_rank0:

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # One-shot verification gate (referenced from README):
 #
-#   1. tier-1 pytest            (ROADMAP.md's exact lane: CPU rigs, not slow)
+#   1. tier-1 pytest            (the driver's command: CPU, not slow, -n 6)
 #   2. edl-lint --changed       (static analysis over the working diff)
 #   3. edl_report --check       (regression sentinel over the run archive,
 #                                only when an archive index exists —
@@ -13,10 +13,15 @@ cd "$(dirname "$0")/.."
 
 rc=0
 
+# the driver's command (/root/TESTS_LAST_RUN.json "commands"), flag for
+# flag: six workers, a file to a worker, 1470 s. The driver also sets
+# ALLOW_MULTIPLE_LIBTPU_LOAD=1 for its own runs on the CPU; it is passed
+# through when the caller sets it and never set here (on a TPU host that
+# lock is what keeps two processes off one chip).
 echo "== tier-1 pytest" >&2
-if ! timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+if ! timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly; then
+    -p xdist -n 6 --dist loadfile -p no:randomly; then
   echo "== tier-1 pytest RED" >&2
   rc=1
 fi
