@@ -12,7 +12,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.interpreters import partial_eval as pe
 
+from benchmark.families.transformer_lm import LOGITS_REL_TOL
 from benchmark.reference import moe_lm as reference
 from edl_tpu.checkpoint import CheckpointManager
 from edl_tpu.models import MOE_EP_RULES, DroplessMoE, MoESpec, TransformerLM
@@ -89,6 +91,29 @@ def test_no_token_is_dropped_when_one_expert_takes_them_all(k):
     assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0  # every token got an answer
 
 
+def test_router_gradient_of_a_bfloat16_layer_equals_the_float32_mixture():
+    """The router's kernel is reached through the routing weights alone, and
+    those enter inside the experts' activation: 8 of 64 experts, the banks
+    and the rows in bfloat16, held to the benchmark's LM-against-reference
+    tolerance (largest difference over largest magnitude)."""
+    k, e = 8, 64
+    layer = DroplessMoE(num_experts=e, top_k=k, d_ff=24, dtype=jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 32, D))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+
+    def router_grad(fn):
+        def objective(kernel):
+            p = {**params, "router": {"kernel": kernel}}
+            return jnp.sum(jnp.sin(fn(p, x)))
+        return jax.grad(objective)(params["router"]["kernel"])
+
+    got = router_grad(lambda p, x: layer.apply({"params": p}, x))
+    want = router_grad(lambda p, x: dense_mixture(p, x, k, False))
+    assert got.dtype == jnp.float32 and float(jnp.max(jnp.abs(want))) > 0
+    worst = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert worst <= LOGITS_REL_TOL, worst
+
+
 def loop_over_groups(lhs, rhs, sizes):
     out, start = [], 0
     for g, n in enumerate(sizes):
@@ -162,13 +187,13 @@ TOY = {
 }
 
 
-def toy_lm(layers, dtype=jnp.float32, remat=False):
+def toy_lm(layers, dtype=jnp.float32, remat=False, top_k=2, norm=False):
     return TransformerLM(
         vocab_size=64, d_model=32, num_heads=4, num_kv_heads=4,
         num_layers=layers, d_ff=24, dtype=dtype, remat=remat, norm_eps=1e-5,
         qk_norm=True,
-        moe=MoESpec(num_experts=8, top_k=2, d_ff=24, aux_weight=0.01 / layers,
-                    z_weight=0.001 / layers),
+        moe=MoESpec(num_experts=8, top_k=top_k, d_ff=24, norm_topk_prob=norm,
+                    aux_weight=0.01 / layers, z_weight=0.001 / layers),
     )
 
 
@@ -327,6 +352,49 @@ def test_the_compiled_step_names_the_expert_layers_scopes(scope):
     phases = obs_profile.phases_of_hlo(compiled.as_text())
     both = {phases[name] for name, s in table.items() if s == scope and name in phases}
     assert "forward" in both or "backward" in both
+
+
+def equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters, each
+    with the equations that enclose it, outermost first."""
+    for eqn in jaxpr.eqns:
+        yield inside, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub, inside + (eqn,))
+
+
+@pytest.mark.parametrize("norm", [False, True], ids=["raw", "norm_topk_prob"])
+@pytest.mark.parametrize("k", [1, 8])
+def test_the_backward_reruns_no_down_projection_and_unsorts_no_rows(k, norm):
+    """``jax.grad`` of a remat-wrapped (``save_flash``) one-layer LM, after
+    dead-code elimination: 3 grouped matmuls forward, 2 recomputed (gate and
+    up; the down projection's result is no residual of anything), 6 for the
+    gradients. Under the ``remat2`` equation the combine leaves one gather,
+    out of ``dy`` ``[N, D]``: its forward (a ``custom_vjp_call`` of N*k rows)
+    is not run again."""
+    lm = toy_lm(1, dtype=jnp.bfloat16, remat=True, top_k=k, norm=norm)
+    assert lm.remat_policy == "save_flash"
+    x, y = toy_batch()
+    params = lm.init(jax.random.PRNGKey(0), x)["params"]
+
+    def loss(params):
+        logits, sown = lm.apply({"params": params}, x, mutable=["losses"])
+        extra = sum(jnp.sum(v) for v in jax.tree.leaves(sown["losses"]))
+        return lm_loss(logits, y)[0] + extra
+
+    traced = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    live, _ = pe.dce_jaxpr(traced, [True] * len(traced.outvars))
+    grouped, combine_gathers = 0, set()
+    for inside, eqn in equations(live):
+        grouped += eqn.primitive.name == "ragged_dot_general"
+        if eqn.primitive.name != "gather":
+            continue
+        scopes = "/".join(str(e.source_info.name_stack) for e in inside + (eqn,))
+        names = [e.primitive.name for e in inside]
+        if "remat2" in names and "moe_combine" in scopes:
+            combine_gathers.add(("custom_vjp_call" in names, eqn.invars[0].aval.shape))
+    assert grouped == 11
+    assert combine_gathers == {(False, (x.size, 32))}
 
 
 # sha256 of the lowered step of a dense TransformerLM at default arguments
