@@ -1,5 +1,6 @@
 from edl_tpu.models.ctr import CTR_EMBEDDING_RULES, DeepFM, binary_cross_entropy_loss
 from edl_tpu.models.mlp import MLP, LinearRegression
+from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import MOE_EP_RULES, DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.resnet import (
     ResNet,
@@ -9,7 +10,7 @@ from edl_tpu.models.resnet import (
     ResNeXt101_32x16d,
 )
 from edl_tpu.models.decode import greedy_generate, init_cache
-from edl_tpu.models.transformer import TransformerLM
+from edl_tpu.models.transformer import ArchSpec, TransformerLM
 
 __all__ = [
     "MLP",
@@ -29,4 +30,7 @@ __all__ = [
     "DroplessMoE",
     "MoESpec",
     "MOE_EP_RULES",
+    "ArchSpec",
+    "Mamba2Mixer",
+    "MambaSpec",
 ]

@@ -1,0 +1,127 @@
+"""Mamba-2 mixer: the sequence layer of a state-space / attention hybrid.
+
+The layer of Dao and Gu (arXiv:2405.21060) as Hugging Face's
+``GraniteMoeHybridMambaLayer`` lays it out, for normalised input ``n``
+``[B, T, d_model]``::
+
+    [z | xBC | dt] = W_in n              widths d_inner | d_inner + 2 G N | H
+    xBC  = silu(causal depthwise conv_{d_conv}(xBC) + b_conv)
+    x, B, C = split(xBC)                  x: H heads of P; B, C: G groups of N
+    dt   = softplus(dt + dt_bias)         per head
+    y    = ssd_scan(x, dt, -exp(A_log), B, C, D)        (ops/ssd.py)
+    out  = W_out RMSNorm(y * silu(z))     over all d_inner, learned scale
+
+The device time of its four parts carries the names ``ssm_proj`` (both
+projections), ``ssm_conv``, ``ssm_scan`` and ``ssm_gate``
+(``jax.named_scope``; ``obs/profile.py:step_scopes`` joins them to a trace).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from edl_tpu.ops.ssd import ssd_scan
+
+SSM_SCOPES = ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate")
+
+
+@dataclasses.dataclass(frozen=True)
+class MambaSpec:
+    """The shape of a :class:`Mamba2Mixer`, as one hashable field."""
+
+    num_heads: int         # H; d_inner = num_heads * head_dim
+    head_dim: int          # P
+    d_state: int           # N
+    n_groups: int = 1      # G: B and C are shared by H / G heads
+    d_conv: int = 4
+    chunk: int = 256       # steps a chunk of the scan
+    conv_bias: bool = True
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log U(1, 16)``: Mamba-2's own, a spread of decay rates."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32, low=1e-3, high=1e-1):
+    """The inverse softplus of a step drawn log-uniformly in [low, high]."""
+    dt = jnp.exp(
+        jax.random.uniform(key, shape, dtype) * (math.log(high) - math.log(low))
+        + math.log(low)
+    )
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def causal_conv(x, kernel, bias=None):
+    """Depthwise causal convolution along T as ``d_conv`` shifted products,
+    float32: ``y_t = sum_k kernel[k] * x_{t - (d_conv - 1) + k} (+ bias)``,
+    zeros before the start. x ``[B, T, C]``; kernel ``[d_conv, C]``."""
+    taps, t = kernel.shape[0], x.shape[1]
+    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    y = sum(kernel[k] * x[:, k:k + t] for k in range(taps))
+    return y if bias is None else y + bias
+
+
+class Mamba2Mixer(nn.Module):
+    spec: MambaSpec
+    dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        s = self.spec
+        batch, t, d_model = x.shape
+        d_inner, gn = s.num_heads * s.head_dim, s.n_groups * s.d_state
+        conv_dim = d_inner + 2 * gn
+        f32 = jnp.float32
+        dense = lambda width, name: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=self.dtype, name=name
+        )
+
+        with jax.named_scope("ssm_proj"):
+            zxbcdt = dense(d_inner + conv_dim + s.num_heads, "in_proj")(x)
+        z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
+
+        with jax.named_scope("ssm_conv"):
+            bound = s.d_conv ** -0.5  # torch's Conv1d default, fan-in d_conv
+            kernel = self.param(
+                "conv_kernel",
+                lambda key, shape: jax.random.uniform(key, shape, f32, -bound, bound),
+                (s.d_conv, conv_dim),
+            )
+            bias = (
+                self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
+                if s.conv_bias else None
+            )
+            xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(self.dtype)
+        xs, b, c = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
+
+        a_log = self.param("A_log", _a_log_init, (s.num_heads,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (s.num_heads,))
+        skip = self.param("D", nn.initializers.ones, (s.num_heads,))
+        with jax.named_scope("ssm_scan"):
+            y = ssd_scan(
+                xs.reshape(batch, t, s.num_heads, s.head_dim),
+                jax.nn.softplus(dt.astype(f32) + dt_bias),
+                -jnp.exp(a_log),
+                b.reshape(batch, t, s.n_groups, s.d_state),
+                c.reshape(batch, t, s.n_groups, s.d_state),
+                skip, chunk=s.chunk,
+            )
+
+        with jax.named_scope("ssm_gate"):
+            scale = self.param("norm", nn.initializers.ones, (d_inner,))
+            gated = y.reshape(batch, t, d_inner).astype(f32) * nn.silu(z.astype(f32))
+            gated = gated * jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True) + self.norm_eps
+            )
+            gated = (gated * scale).astype(self.dtype)
+
+        with jax.named_scope("ssm_proj"):
+            return dense(d_model, "out_proj")(gated)

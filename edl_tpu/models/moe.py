@@ -45,8 +45,9 @@ from edl_tpu.ops.grouped_matmul import grouped_matmul
 class MoESpec:
     """The expert layer of a ``TransformerLM``, as one hashable field:
     every block's feed-forward is a :class:`DroplessMoE` of this shape.
-    (A layer pattern — a model's leading dense layers — belongs here when
-    a configuration needs one.)"""
+    (Which blocks mix by attention and which by a state-space layer is
+    ``models/transformer.py:ArchSpec.layer_types``; a model's leading
+    dense feed-forward layers would join it there.)"""
 
     num_experts: int
     top_k: int

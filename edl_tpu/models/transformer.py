@@ -4,8 +4,12 @@ Net-new model family versus the reference (its largest workload is
 ResNet50/ERNIE fine-tune; SURVEY §5 notes long-context is absent), built
 TPU-first:
 
-- pre-norm blocks with RMSNorm, RoPE positions, SwiGLU MLP — all
-  large-matmul-dominated so the MXU stays busy; bf16 compute, fp32 params;
+- pre-norm blocks with RMSNorm, RoPE positions (a choice: ``ArchSpec.rope``),
+  SwiGLU MLP — all large-matmul-dominated so the MXU stays busy; bf16
+  compute, fp32 params;
+- a block's sequence mixer is attention or, by ``ArchSpec.layer_types``, a
+  Mamba-2 state-space layer (``models/mamba.py``): one ``TransformerLM``
+  runs dense, expert and hybrid configurations;
 - attention is pluggable: the Pallas flash kernel locally, or ring
   attention over the ``sp`` mesh axis for sequences longer than one
   device's HBM (``edl_tpu.parallel.ring``);
@@ -21,12 +25,13 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.ops.attention import attention
 
@@ -44,6 +49,37 @@ def _supports_gqa(fn) -> bool:
     return getattr(fn, "supports_gqa", False)
 
 NEG_INF_DECODE = -1e30  # mask value for cache positions past the index
+
+
+@dataclasses.dataclass(frozen=True)
+class ArchSpec:
+    """What a ``TransformerLM`` does differently from the dense default,
+    as one hashable field; every default is the dense model's.
+
+    ``layer_types`` names each block's sequence mixer, ``"attention"`` or
+    ``"mamba"`` (then ``mamba`` gives the layer's shape); its length is the
+    model's depth. The three multipliers are Granite's: the embedding's
+    output times ``embedding_multiplier``, each residual branch (mixer and
+    feed-forward) times ``residual_multiplier``, the logits divided by
+    ``logits_scaling``. ``tie_embeddings`` projects onto the vocabulary
+    with the embedding's own matrix, whose gradient is then the sum of
+    both uses."""
+
+    layer_types: Optional[Tuple[str, ...]] = None
+    mamba: Optional[MambaSpec] = None
+    head_dim: Optional[int] = None      # None: d_model / num_heads
+    rope: bool = True                   # False: no position term at all
+    attn_scale: Optional[float] = None  # None: head_dim ** -0.5
+    tie_embeddings: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
+
+def _times(x, factor: float):
+    """``x * factor``, and ``x`` itself at 1: the dense model's program
+    holds no multiplication it never asked for."""
+    return x if factor == 1.0 else x * factor
 
 
 class RMSNorm(nn.Module):
@@ -79,7 +115,11 @@ class Attention(nn.Module):
     ``num_kv_heads`` < ``num_heads`` is GQA (Ainslie et al. 2023): K/V
     project to fewer heads, cutting KV projection params and FLOPs by
     ``num_heads/num_kv_heads``; ``num_kv_heads=1`` is MQA; ``None``
-    (default) is classic MHA. The default dispatch's Pallas kernels are
+    (default) is classic MHA. ``head_dim`` defaults to ``d_model /
+    num_heads`` and may be given for a model whose heads do not tile
+    its width; ``rope=False`` applies no rotation (a position-free
+    layer); ``scale`` replaces the scores' ``head_dim ** -0.5``.
+    The default dispatch's Pallas kernels are
     GQA-AWARE (ops/attention.py: grouped k/v read via index mapping, no
     materialized repeat, dk/dv folded back to the grouped width), so on
     the flash/flash2 routes training keeps the grouped activation bytes
@@ -106,11 +146,14 @@ class Attention(nn.Module):
     # k, before the split into heads means anything and before RoPE
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    head_dim: Optional[int] = None
+    rope: bool = True
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x, positions):
         d_model = x.shape[-1]
-        head_dim = d_model // self.num_heads
+        head_dim = self.head_dim or d_model // self.num_heads
         kv_heads = (
             self.num_kv_heads if self.num_kv_heads is not None
             else self.num_heads
@@ -128,8 +171,9 @@ class Attention(nn.Module):
             flat = q.shape[:2] + (-1,)
             q = RMSNorm(self.norm_eps, name="q_norm")(q.reshape(flat)).reshape(q.shape)
             k = RMSNorm(self.norm_eps, name="k_norm")(k.reshape(flat)).reshape(k.shape)
-        q = rope(q, positions)
-        k = rope(k, positions)
+        if self.rope:
+            q = rope(q, positions)
+            k = rope(k, positions)
         if self.decode:
             out = self._decode_step(q, k, v, kv_heads, head_dim)
         else:
@@ -147,7 +191,8 @@ class Attention(nn.Module):
                 group = self.num_heads // kv_heads
                 k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
             attn = self.attention_fn or attention
-            out = attn(q, k, v, causal=True)
+            scaled = {} if self.scale is None else {"scale": self.scale}
+            out = attn(q, k, v, causal=True, **scaled)
             out = jnp.swapaxes(out, 1, 2)
         return nn.DenseGeneral(
             features=x.shape[-1], axis=(-2, -1), use_bias=False,
@@ -192,7 +237,7 @@ class Attention(nn.Module):
         qg = q.astype(jnp.float32).reshape(b, t, kv_heads, group, head_dim)
         scores = jnp.einsum(
             "btkgd,blkd->bkgtl",
-            qg * (head_dim ** -0.5),
+            qg * (self.scale or head_dim ** -0.5),
             cache_k.value.astype(jnp.float32),
         )
         # query at offset o (position i+o) sees cache slots l <= i+o
@@ -232,15 +277,30 @@ class Block(nn.Module):
     norm_eps: float = 1e-6
     qk_norm: bool = False
     moe: Optional[MoESpec] = None  # dropless expert FFN instead of SwiGLU
+    arch: ArchSpec = ArchSpec()
+    mixer: str = "attention"       # this block's entry of arch.layer_types
 
     @nn.compact
     def __call__(self, x, positions):
-        x = x + Attention(
-            self.num_heads, self.dtype, self.attention_fn,
-            num_kv_heads=self.num_kv_heads, decode=self.decode,
-            max_decode_len=self.max_decode_len, qk_norm=self.qk_norm,
-            norm_eps=self.norm_eps, name="attn",
-        )(RMSNorm(self.norm_eps, name="ln1")(x), positions)
+        arch = self.arch
+        h = RMSNorm(self.norm_eps, name="ln1")(x)
+        if self.mixer == "mamba":
+            if self.decode:
+                raise NotImplementedError("a Mamba-2 block has no decode cache")
+            mixed = Mamba2Mixer(
+                arch.mamba, self.dtype, self.norm_eps, name="mamba"
+            )(h)
+        elif self.mixer == "attention":
+            mixed = Attention(
+                self.num_heads, self.dtype, self.attention_fn,
+                num_kv_heads=self.num_kv_heads, decode=self.decode,
+                max_decode_len=self.max_decode_len, qk_norm=self.qk_norm,
+                norm_eps=self.norm_eps, head_dim=arch.head_dim,
+                rope=arch.rope, scale=arch.attn_scale, name="attn",
+            )(h, positions)
+        else:
+            raise ValueError("unknown layer type %r" % (self.mixer,))
+        x = x + _times(mixed, arch.residual_multiplier)
         h = RMSNorm(self.norm_eps, name="ln2")(x)
         if self.moe is not None:
             ff = DroplessMoE(
@@ -253,7 +313,7 @@ class Block(nn.Module):
             )(h)
         else:
             ff = SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
-        return x + ff
+        return x + _times(ff, arch.residual_multiplier)
 
 
 def _remat_policy(name: Optional[str]):
@@ -362,13 +422,24 @@ class TransformerLM(nn.Module):
     # every block's FFN as a dropless top-k expert layer (models/moe.py);
     # the older Switch pair above stays for SwitchMoE until ROADMAP D6
     moe: Optional[MoESpec] = None
+    # the layer pattern, the attention's head size / positions / score
+    # scale, a tied head and Granite's multipliers; None: the dense model
+    arch: Optional[ArchSpec] = None
 
     @nn.compact
     def __call__(self, tokens, positions=None):
-        x = nn.Embed(
+        arch = self.arch or ArchSpec()
+        layer_types = arch.layer_types or ("attention",) * self.num_layers
+        if len(layer_types) != self.num_layers:
+            raise ValueError(
+                "%d layer_types for num_layers %d"
+                % (len(layer_types), self.num_layers)
+            )
+        embed = nn.Embed(
             self.vocab_size, self.d_model,
             dtype=self.dtype, name="embed",
-        )(tokens)
+        )
+        x = _times(embed(tokens), arch.embedding_multiplier)
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1])[None, :], tokens.shape
@@ -388,9 +459,12 @@ class TransformerLM(nn.Module):
             x = block(
                 self.num_heads, self.d_ff, self.dtype, self.attention_fn,
                 moe, self.num_kv_heads, self.decode, self.max_decode_len,
-                self.norm_eps, self.qk_norm, self.moe,
+                self.norm_eps, self.qk_norm, self.moe, arch, layer_types[i],
                 name="layer_%d" % i,
             )(x, positions)
         x = RMSNorm(self.norm_eps, name="ln_f")(x)
-        logits = LMHead(self.vocab_size, name="lm_head")(x)
-        return logits
+        if arch.tie_embeddings:
+            logits = _head_matmul(x, embed.embedding.astype(x.dtype).T)
+        else:
+            logits = LMHead(self.vocab_size, name="lm_head")(x)
+        return _times(logits, 1.0 / arch.logits_scaling)

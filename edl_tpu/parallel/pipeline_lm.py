@@ -54,6 +54,11 @@ def _check_model(model: TransformerLM, pp: int) -> int:
             "pipeline parallelism requires homogeneous (dense) blocks; "
             "MoE layers change the per-layer param structure"
         )
+    if model.arch is not None:
+        raise ValueError(
+            "pipeline parallelism builds the dense block and an untied "
+            "head; a model with an ArchSpec is not split into stages"
+        )
     if model.num_layers % pp:
         raise ValueError(
             "num_layers %d not divisible by pp %d" % (model.num_layers, pp)

@@ -65,6 +65,7 @@ GQA = (2, 16, 4, 2048, 64)
 AT_MAX = (1, 16, 16, A._flash_max_seq(), 64)
 PAST_MAX = (1, 16, 16, A._flash_max_seq() + 512, 64)
 LONG = (1, 16, 16, 8192, 64)       # chip_smoke's flash2 comparison shape
+GRANITE = (1, 32, 8, 8192, 64)     # granite_4_0_h_micro.steady's attention layer
 
 FWD_NAME = {"flash": "_flash_kernel", "flash2": "_flash2_kernel"}
 BWD_NAMES = {
@@ -84,6 +85,7 @@ CASES = [
         ("at_max_seq", AT_MAX, ("flash",)),
         ("past_max_seq", PAST_MAX, ("flash",)),
         ("seq8192", LONG, ("flash2",)),
+        ("granite", GRANITE, ("flash2",)),
     )
     for family in families
     for direction in ("fwd", "bwd")
@@ -128,6 +130,29 @@ def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
     assert _kernel_names(lowered.as_text()) == want
     compiled = lowered.compile()  # raises what the chip's compiler would
     assert compiled.as_text().count("tpu_custom_call") == len(want)
+
+
+def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip):
+    """The chunked scan with jax's own backward at one sequence of 8192, 64
+    heads of 64 over a state of 128, chunk 256: plain XLA, so what the chip's
+    compiler can refuse is the memory. The float32 decay matrices of one pass
+    are 537 MB; value and gradients together must stay a small part of the
+    16 GB the step shares."""
+    from edl_tpu.ops import ssd_scan
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, h, p, n = 8192, 64, 64, 128
+    args = (sds((1, t, h, p)), sds((1, t, h), jnp.float32), sds((h,), jnp.float32),
+            sds((1, t, 1, n)), sds((1, t, 1, n)), sds((h,), jnp.float32))
+
+    def value_and_grads(w, *a):
+        out, vjp = jax.vjp(lambda *a: ssd_scan(*a, chunk=256), *a)
+        return (out, *vjp(w))
+
+    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, p)), *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
 def test_grid_pipeline_kwargs_carry_dimension_semantics():
