@@ -5,11 +5,17 @@ The layer of Dao and Gu (arXiv:2405.21060) as Hugging Face's
 ``[B, T, d_model]``::
 
     [z | xBC | dt] = W_in n              widths d_inner | d_inner + 2 G N | H
-    xBC  = silu(causal depthwise conv_{d_conv}(xBC) + b_conv)
+    xBC  = silu(causal depthwise conv_{d_conv}(xBC) + b_conv)    (ops/causal_conv.py)
     x, B, C = split(xBC)                  x: H heads of P; B, C: G groups of N
     dt   = softplus(dt + dt_bias)         per head
     y    = ssd_scan(x, dt, -exp(A_log), B, C, D)        (ops/ssd.py)
     out  = W_out RMSNorm(y * silu(z))     over all d_inner, learned scale
+
+The convolution, its bias and its SiLU are one operation,
+``ops/causal_conv.py:causal_conv_silu``: on a TPU a pair of Pallas kernels that
+read ``xBC`` in place out of the in projection's output and write it once, both
+in the model's dtype, while the float32 taps, sum, bias and SiLU stay in VMEM
+and registers; elsewhere the plain float32 form, ``causal_conv`` there.
 
 The device time of its four parts carries the names ``ssm_proj`` (both
 projections), ``ssm_conv``, ``ssm_scan`` and ``ssm_gate``
@@ -26,6 +32,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.ops.causal_conv import causal_conv_silu
 from edl_tpu.ops.ssd import ssd_scan
 
 SSM_SCOPES = ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate")
@@ -58,16 +65,6 @@ def _dt_bias_init(key, shape, dtype=jnp.float32, low=1e-3, high=1e-1):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def causal_conv(x, kernel, bias=None):
-    """Depthwise causal convolution along T as ``d_conv`` shifted products,
-    float32: ``y_t = sum_k kernel[k] * x_{t - (d_conv - 1) + k} (+ bias)``,
-    zeros before the start. x ``[B, T, C]``; kernel ``[d_conv, C]``."""
-    taps, t = kernel.shape[0], x.shape[1]
-    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    y = sum(kernel[k] * x[:, k:k + t] for k in range(taps))
-    return y if bias is None else y + bias
-
-
 class Mamba2Mixer(nn.Module):
     spec: MambaSpec
     dtype: Any = jnp.bfloat16
@@ -86,7 +83,7 @@ class Mamba2Mixer(nn.Module):
 
         with jax.named_scope("ssm_proj"):
             zxbcdt = dense(d_inner + conv_dim + s.num_heads, "in_proj")(x)
-        z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
+        z, dt = zxbcdt[..., :d_inner], zxbcdt[..., d_inner + conv_dim:]
 
         with jax.named_scope("ssm_conv"):
             bound = s.d_conv ** -0.5  # torch's Conv1d default, fan-in d_conv
@@ -99,7 +96,7 @@ class Mamba2Mixer(nn.Module):
                 self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
                 if s.conv_bias else None
             )
-            xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(self.dtype)
+            xbc = causal_conv_silu(zxbcdt, kernel, bias, offset=d_inner)
         xs, b, c = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
 
         a_log = self.param("A_log", _a_log_init, (s.num_heads,))

@@ -155,6 +155,85 @@ def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+def test_causal_conv_kernels_compile_for_v5e_at_granites_widths(one_chip):
+    """Both kernels of ``ops/causal_conv.py`` at one sequence of 8192 and the
+    4352 channels of ``xBC``, read in place out of the in projection's 8512
+    (time along the lanes, as the kernels take it): two custom calls and no
+    float32 ``[8192, 4352]`` array (143 MB) among the temporaries, which are
+    the partial sums and little else."""
+    cc = importlib.import_module("edl_tpu.ops.causal_conv")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, wide, c, offset = 8192, 8512, 4352, 4096
+    blocks = cc._blocks(sds((1, t, wide)), sds((4, c), jnp.float32), offset)
+    assert blocks == (64, 8192)
+
+    def value_and_grads(xt, w, b, dy):
+        y = cc._forward(xt, w, b, offset, blocks, False)
+        return (y, *cc._backward(xt, w, b, dy, offset, blocks, False))
+
+    lowered = jax.jit(value_and_grads).lower(
+        sds((1, wide, t)), sds((c, 4), jnp.float32), sds((c, 1), jnp.float32),
+        sds((1, c, t)),
+    )
+    assert _kernel_names(lowered.as_text()) == ["causal_conv_fwd", "causal_conv_bwd"]
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_a_hybrid_step_on_the_tpu_path_runs_the_conv_kernels_under_ssm_conv(one_chip):
+    """A toy hybrid of a shape the kernels take (256 channels of ``xBC`` at
+    column 128, 128 steps), lowered as the chip lowers it (``jax.default_backend``
+    steered to ``tpu`` here, for this compile only): each Mamba-2 layer holds the forward kernel
+    twice (the value and its recomputation under remat) and the backward
+    kernel once, and the compiled step names all of them under ``ssm_conv``,
+    where the trace's ``ssm_conv_ms`` finds them."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, MambaSpec, TransformerLM
+    from edl_tpu.models.mamba import SSM_SCOPES
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers = ("mamba", "mamba")
+    lm = TransformerLM(
+        vocab_size=64, d_model=48, num_heads=4, num_kv_heads=2, num_layers=len(layers),
+        d_ff=40, dtype=jnp.bfloat16, remat=True, norm_eps=1e-5,
+        arch=ArchSpec(
+            layer_types=layers, head_dim=16, rope=False, tie_embeddings=True,
+            mamba=MambaSpec(num_heads=8, head_dim=16, d_state=32, n_groups=2, chunk=8),
+        ),
+    )
+    tokens = np.zeros((1, 128), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    # the lowered text holds each kernel once or twice (the wrappers are
+    # jitted: one function a kernel and a place in the pass), the compiled
+    # step one custom call a use
+    assert set(_kernel_names(lowered.as_text())) == {"causal_conv_fwd", "causal_conv_bwd"}
+    table = obs_profile.scopes_of_hlo(lowered.compile().as_text(), SSM_SCOPES)
+    calls = sorted(name.split(".")[0] for name in table if name.startswith("causal_conv_"))
+    assert calls == ["causal_conv_bwd"] * len(layers) + ["causal_conv_fwd"] * 2 * len(layers)
+    assert {table[name] for name in table if name.startswith("causal_conv_")} == {"ssm_conv"}
+
+
 def test_grid_pipeline_kwargs_carry_dimension_semantics():
     """jax 0.9.0 has ``pltpu.CompilerParams(dimension_semantics=...)``: the
     flash2 family must never run without it (the old guard dropped it
