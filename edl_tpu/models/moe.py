@@ -3,18 +3,21 @@
 Net-new versus the reference (no MoE/expert parallelism anywhere in its
 tree — SURVEY §2 parallelism inventory). Two layers live here:
 
-- :class:`DroplessMoE` — the expert layer of today's open MoE models
-  (OLMoE, and with ``norm_topk_prob`` the Moonlight / Trinity lineage):
-  softmax router in float32, top-k of many small SiLU-gated experts,
-  **no capacity and no dropped token**. Tokens are sorted by expert and
-  the three projections run as grouped matrix multiplications over the
-  ragged groups (``edl_tpu.ops.grouped_matmul``); shapes are static
-  whatever the imbalance. Sows the load-balancing and router-z losses
-  into ``"losses"`` and the busiest expert's relative load into
-  ``"metrics"``; ``create_state`` sees both collections in
-  ``model.init``'s result, so the train step adds and reports them with
-  no flag from the caller. :class:`MoESpec` describes it to
-  ``TransformerLM``.
+- :class:`DroplessMoE` — the expert layer of today's open MoE models:
+  a float32 router with softmax scores (OLMoE) or sigmoid scores, a
+  balancing bias, normalised and scaled weights and a shared expert
+  (the DeepSeek-V3 line as Trinity-Mini's ``afmoe`` code writes it),
+  top-k of many small SiLU-gated experts, **no capacity and no dropped
+  token**, and optionally **one chip's share of the experts** (``held``:
+  it routes over all of them and computes what its own give). Tokens are
+  sorted by expert and the three projections run as grouped matrix
+  multiplications over the ragged groups
+  (``edl_tpu.ops.grouped_matmul``); shapes are static whatever the
+  imbalance. Sows the load-balancing and router-z losses into
+  ``"losses"`` (where it has any) and the loads into ``"metrics"``;
+  ``create_state`` sees the collections in ``model.init``'s result, so
+  the train step adds and reports them with no flag from the caller.
+  :class:`MoESpec` describes it to ``TransformerLM``.
 - :class:`SwitchMoE` — the older capacity-bounded layer in the
   dispatch/combine **einsum formulation** (Mesh-TensorFlow / GShard
   lineage): a one-hot ``[B, S, E, C]`` dispatch tensor, tokens dropped
@@ -31,7 +34,8 @@ Both keep expert weights with a leading ``[E, ...]`` axis so that
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from functools import partial
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -43,47 +47,67 @@ from edl_tpu.ops.grouped_matmul import grouped_matmul
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
-    """The expert layer of a ``TransformerLM``, as one hashable field:
-    every block's feed-forward is a :class:`DroplessMoE` of this shape.
-    (Which blocks mix by attention and which by a state-space layer is
-    ``models/transformer.py:ArchSpec.layer_types``; a model's leading
-    dense feed-forward layers would join it there.)"""
+    """The expert layer of a ``TransformerLM``, as one hashable field: the
+    feed-forward of every block past the leading
+    ``models/transformer.py:ArchSpec.dense_layers`` is a
+    :class:`DroplessMoE` of this shape, whose fields these are. (Which
+    blocks mix by attention, over which window, and which by a state-space
+    layer is ``ArchSpec.layer_types``.) The defaults are OLMoE's layer:
+    softmax scores, every expert held here, no bias, no shared expert."""
 
     num_experts: int
     top_k: int
     d_ff: int                      # width of ONE expert
     norm_topk_prob: bool = False   # renormalise the k weights to sum 1
-    aux_weight: float = 1e-2       # alpha of the load-balancing loss
-    z_weight: float = 1e-3         # beta of the router z-loss
+    aux_weight: float = 1e-2       # alpha of the load-balancing loss; 0: none
+    z_weight: float = 1e-3         # beta of the router z-loss; 0: none
+    score_func: str = "softmax"    # or "sigmoid": each expert on its own
+    route_scale: float = 1.0       # the k weights times this, at the end
+    bias_rate: float = 0.0         # > 0: a balancing bias under the choice
+    shared_d_ff: int = 0           # > 0: a shared expert of this width
+    held: Optional[Tuple[int, int]] = None  # (first, count) held here; None: all
 
 
-def _sum_unsorted(rows, inverse, k):
+def _sum_unsorted(rows, inverse, k, live=None):
     """``sum_j rows[inverse[n * k + j]]`` in float32: expert order back to
     (token, choice) order, where a token's ``k`` rows are neighbours, and
-    their sum."""
-    n = rows.shape[0] // k
-    back = rows[inverse].reshape(n, k, rows.shape[-1])
+    their sum. With ``live`` only the pairs sorted before it count (the
+    others' experts are held elsewhere and their rows are nobody's)."""
+    n = inverse.shape[0] // k
+    if live is None:
+        back = rows[inverse]
+    else:
+        back = jnp.where(
+            (inverse < live)[:, None],
+            rows[jnp.minimum(inverse, rows.shape[0] - 1)], 0,
+        )
+    back = back.reshape(n, k, rows.shape[-1])
     return jnp.sum(back, axis=1, dtype=jnp.float32)
 
 
-def _rows_sorted(tokens, order, inverse, k):
+def _rows_sorted(tokens, order, inverse, k, live=None):
     """``tokens[order // k]``: row ``r`` of the result is the token of the
-    ``r``-th (token, choice) pair in expert order. Its gradient is
-    :func:`_rows_combined`'s forward (a gather and a sum over ``k``
-    neighbours), not the scatter-add jax would derive."""
+    ``r``-th (token, choice) pair in expert order (``order`` may be that
+    order's first rows only). Its gradient is :func:`_rows_combined`'s
+    forward (a gather and a sum over ``k`` neighbours), not the
+    scatter-add jax would derive."""
 
     @jax.custom_vjp
-    def take(tokens, order, inverse):
+    def take(tokens, order, inverse, live):
         return tokens[order // k]
 
-    def fwd(tokens, order, inverse):
-        return take(tokens, order, inverse), inverse
+    def fwd(tokens, order, inverse, live):
+        return take(tokens, order, inverse, live), (inverse, live)
 
-    def bwd(inverse, grad):
-        return _sum_unsorted(grad, inverse, k).astype(grad.dtype), None, None
+    def bwd(residuals, grad):
+        inverse, live = residuals
+        return (
+            _sum_unsorted(grad, inverse, k, live).astype(grad.dtype),
+            None, None, None,
+        )
 
     take.defvjp(fwd, bwd)
-    return take(tokens, order, inverse)
+    return take(tokens, order, inverse, live)
 
 
 def _scalars_sorted(values, order, inverse):
@@ -106,23 +130,23 @@ def _scalars_sorted(values, order, inverse):
     return take(values, order, inverse)
 
 
-def _rows_combined(rows, order, inverse, k):
+def _rows_combined(rows, order, inverse, k, live=None):
     """``y[n] = sum_j rows[inverse[n * k + j]]`` in float32: the transpose
     of :func:`_rows_sorted`. Its gradient is that function's forward,
     ``grad[order // k]``, so it keeps ``order`` and no row."""
 
     @jax.custom_vjp
-    def combine(rows, order, inverse):
-        return _sum_unsorted(rows, inverse, k)
+    def combine(rows, order, inverse, live):
+        return _sum_unsorted(rows, inverse, k, live)
 
-    def fwd(rows, order, inverse):
-        return combine(rows, order, inverse), order
+    def fwd(rows, order, inverse, live):
+        return combine(rows, order, inverse, live), order
 
     def bwd(order, grad):
-        return grad.astype(rows.dtype)[order // k], None, None
+        return grad.astype(rows.dtype)[order // k], None, None, None
 
     combine.defvjp(fwd, bwd)
-    return combine(rows, order, inverse)
+    return combine(rows, order, inverse, live)
 
 
 class DroplessMoE(nn.Module):
@@ -130,9 +154,47 @@ class DroplessMoE(nn.Module):
 
     Per token ``x`` (``[B, S, D]`` in, ``[B, S, D]`` out)::
 
-        p      = softmax(W_r x)                    float32, over E
-        w, e   = top_k(p)                          w as it is, or w / sum(w)
+        s      = softmax(W_r x)  or  sigmoid(W_r x)     float32, over E
+        e      = top_k(s + b)                      b: the bias, if any
+        w      = s[e], then w / (sum(w) + 1e-20) and w * route_scale, as asked
         y      = sum_j W_down[e_j] (w_j * silu(W_gate[e_j] x) * W_up[e_j] x)
+                 + shared(x)                       if there is a shared expert
+
+    With the defaults this is OLMoE's layer; with sigmoid scores, the bias,
+    normalised and scaled weights and a shared expert it is the layer of
+    the DeepSeek-V3 line as Trinity's ``afmoe`` code writes it.
+
+    **The bias** (``bias_rate > 0``) enters the choice and not the weight,
+    carries no gradient and is moved by the step's own counts ``c_i`` of
+    assignments (Wang et al., arXiv:2408.15664)::
+
+        delta = bias_rate * sign(mean(c) - c);   b <- b + delta - mean(delta)
+
+    It lives in ``"batch_stats"`` (``router_bias``, ``[E]`` float32, zeros
+    at first): what a step computes from its own batch and keeps without a
+    gradient, as BatchNorm's moments are. The forward uses the ``b`` it was
+    given and, where the collection is mutable (the train step), leaves the
+    moved one behind, so the bias rides ``TrainState`` and every checkpoint
+    with no word about it in ``train/``.
+
+    **The share** (``held = (first, count)``): the layer holds experts
+    ``first .. first + count - 1`` of the ``num_experts`` and nothing of the
+    others; its banks are ``[count, ...]``. The router, the bias, the top-k
+    and the weights are over all ``num_experts``. The pairs whose expert is
+    held sort to the front by expert, the others behind them; the three
+    grouped matmuls run over the ``count`` groups (Megablox visits no tile
+    past the groups' sum), and only the first ``live`` rows are summed back.
+    A pair whose expert is held elsewhere adds nothing here: ``y`` is this
+    chip's part of the routed result, plus the shared expert's. **The work
+    follows the rows held, not N * k**, as far as static shapes allow: the
+    gathers, the activation and the combine run over an expert-ordered
+    buffer of twice the balanced share (``2 * N * k * count / E`` rows,
+    16,384 of 65,536 at Trinity-Mini's share) whenever the step's ``live``
+    rows fit it, and over the whole ``N * k`` when they do not (one
+    ``lax.cond`` on ``live``, both sizes compiled): every pair whose expert
+    is held is computed, whatever the imbalance, and ``moe_rows_dropped``
+    says so. This is what expert parallelism asks of a layer; on one chip
+    it runs without its exchange.
 
     The routing weight multiplies the expert's activation, not its output:
     the down projection is linear, so the value is the same, and the
@@ -151,21 +213,30 @@ class DroplessMoE(nn.Module):
     multiplications over the E ragged groups. Sown:
 
     - ``"losses"/load_balance`` = ``aux_weight * E * sum_i f_i * P_i`` with
-      ``P_i`` the mean of ``p_i`` over the tokens and ``f_i`` the share of
+      ``P_i`` the mean of ``s_i`` over the tokens and ``f_i`` the share of
       the N*k **assignments** that went to expert ``i`` (so ``sum f = 1``
       and a uniform router gives ``aux_weight``). Implementations that
       count ``f_i`` as a share of the N tokens (Hugging Face's
       ``load_balancing_loss_func``) are larger by the factor k.
     - ``"losses"/router_z`` = ``z_weight * mean(logsumexp(W_r x)^2)``.
+      Neither is sown at weight 0: the objective then has no auxiliary
+      term and the trainer reports none.
     - ``"metrics"/moe_load_max`` = the busiest expert's assignments over
       the mean (1.0 is perfect balance, E is one expert taking all).
-    - ``"intermediates"/top_idx`` and ``/router_logits`` = the chosen
-      experts ``[N, k]`` and ``W_r x`` ``[N, E]`` (only when a caller
-      asks for the collection: a check against a reference).
+    - with a share, ``"metrics"/moe_rows_held`` = the share of the N*k
+      assignments that fell on held experts (``count / E`` when balanced),
+      ``/moe_held_load_max`` = the busiest held expert's rows over the mean
+      ``N * k / E``, ``/moe_rows_dropped`` = assignments to held experts
+      less rows the grouped matmuls cover (0: the buffer holds them all).
+    - ``"intermediates"/top_idx``, ``/router_logits`` and ``/router_in`` =
+      the chosen experts ``[N, k]``, ``W_r x`` ``[N, E]`` and the router's
+      own float32 operand ``x`` ``[N, D]`` (only when a caller asks for the
+      collection: a check against a reference).
 
-    Device-side names: ``moe_route`` (router, top-k, sort, losses),
-    ``moe_experts`` (gather, the routing weights and the three grouped
-    matmuls), ``moe_combine`` (un-sort and sum over k).
+    Device-side names: ``moe_route`` (router, scores, bias, top-k, sort,
+    losses), ``moe_experts`` (gather, the routing weights and the three
+    grouped matmuls), ``moe_combine`` (un-sort and sum over k),
+    ``moe_shared`` (the shared expert).
     """
 
     num_experts: int
@@ -174,6 +245,11 @@ class DroplessMoE(nn.Module):
     norm_topk_prob: bool = False
     aux_weight: float = 1e-2
     z_weight: float = 1e-3
+    score_func: str = "softmax"
+    route_scale: float = 1.0
+    bias_rate: float = 0.0
+    shared_d_ff: int = 0
+    held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -182,55 +258,147 @@ class DroplessMoE(nn.Module):
         e, k, f = self.num_experts, self.top_k, self.d_ff
         n = b * s
         tokens = x.reshape(n, d)
+        first, count = self.held or (0, e)
+        if first < 0 or count < 1 or first + count > e:
+            raise ValueError("held %r is no part of %d experts" % (self.held, e))
+        # rows of the expert-ordered buffer a step usually needs: twice the
+        # held experts' balanced share of the N * k pairs, in whole sublanes
+        buffer = min(n * k, -(-2 * n * k * count // (8 * e)) * 8)
 
         with jax.named_scope("moe_route"):
+            router_in = tokens.astype(jnp.float32)
             logits = nn.Dense(
                 e, use_bias=False, dtype=jnp.float32, name="router",
                 precision=jax.lax.Precision.HIGHEST,
-            )(tokens.astype(jnp.float32))               # [N, E]
-            probs = jax.nn.softmax(logits, axis=-1)
-            weights, top_idx = jax.lax.top_k(probs, k)  # [N, k]
+            )(router_in)                                # [N, E]
+            if self.score_func == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)
+            elif self.score_func == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+            else:
+                raise ValueError("unknown score_func %r" % (self.score_func,))
+            if self.bias_rate > 0:
+                bias = self.variable(
+                    "batch_stats", "router_bias", jnp.zeros, (e,), jnp.float32
+                )
+                _, top_idx = jax.lax.top_k(probs + bias.value, k)
+                weights = jnp.take_along_axis(probs, top_idx, axis=-1)
+            else:
+                weights, top_idx = jax.lax.top_k(probs, k)  # [N, k]
             if self.norm_topk_prob:
-                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-            flat = top_idx.reshape(n * k)
+                weights = weights / (
+                    jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
+                )
+            if self.route_scale != 1.0:
+                weights = weights * self.route_scale
+            if self.held is None:
+                flat = top_idx.reshape(n * k)
+            else:
+                # held experts by their place in the bank, every other pair
+                # behind them in one group that is nobody's
+                here = (top_idx >= first) & (top_idx < first + count)
+                flat = jnp.where(here, top_idx - first, count).reshape(n * k)
             order = jnp.argsort(flat)                   # stable: by expert, then pair
             inverse = jnp.argsort(order)
-            group_sizes = jnp.sum(
+            counts = jnp.sum(
                 jax.nn.one_hot(top_idx, e, dtype=jnp.int32), axis=(0, 1)
             )                                           # [E], sums to N*k
-            share = group_sizes.astype(jnp.float32) / (n * k)
-            self.sow(
-                "losses", "load_balance",
-                self.aux_weight * e * jnp.sum(share * jnp.mean(probs, axis=0)),
-            )
-            self.sow(
-                "losses", "router_z",
-                self.z_weight
-                * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
-            )
+            if (
+                self.bias_rate > 0 and not self.is_initializing()
+                and self.is_mutable_collection("batch_stats")
+            ):
+                load = counts.astype(jnp.float32)
+                delta = self.bias_rate * jnp.sign(jnp.mean(load) - load)
+                bias.value = bias.value + delta - jnp.mean(delta)
+            if self.held is None:
+                group_sizes, live = counts, None
+            else:
+                group_sizes = counts[first:first + count]
+                live = jnp.sum(group_sizes)
+                self.sow("metrics", "moe_rows_held", live / (n * k))
+                self.sow(
+                    "metrics", "moe_held_load_max",
+                    jnp.max(group_sizes) * (e / (n * k)),
+                )
+                covered = jnp.where(live <= buffer, buffer, n * k)
+                self.sow(
+                    "metrics", "moe_rows_dropped",
+                    jnp.sum(here) - jnp.minimum(live, covered),
+                )
+            share = counts.astype(jnp.float32) / (n * k)
+            if self.aux_weight:
+                self.sow(
+                    "losses", "load_balance",
+                    self.aux_weight * e * jnp.sum(share * jnp.mean(probs, axis=0)),
+                )
+            if self.z_weight:
+                self.sow(
+                    "losses", "router_z",
+                    self.z_weight
+                    * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
+                )
             self.sow("metrics", "moe_load_max", jnp.max(share) * e)
             self.sow("intermediates", "top_idx", top_idx)
             self.sow("intermediates", "router_logits", logits)
+            # the float32 operand itself: XLA may keep the norm's float32
+            # result under it and never round to ``dtype`` (excess precision)
+            self.sow("intermediates", "router_in", router_in)
 
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
-        w_gate = self.param("gate", init, (e, d, f), jnp.float32)
-        w_up = self.param("up", init, (e, d, f), jnp.float32)
-        w_down = self.param("down", init, (e, f, d), jnp.float32)
+        w_gate = self.param("gate", init, (count, d, f), jnp.float32)
+        w_up = self.param("up", init, (count, d, f), jnp.float32)
+        w_down = self.param("down", init, (count, f, d), jnp.float32)
 
-        with jax.named_scope("moe_experts"):
-            rows = _rows_sorted(tokens.astype(self.dtype), order, inverse, k)
-            w_sorted = _scalars_sorted(weights.reshape(n * k), order, inverse)
-            gate = grouped_matmul(rows, w_gate.astype(self.dtype), group_sizes)
-            up = grouped_matmul(rows, w_up.astype(self.dtype), group_sizes)
-            hidden = (
-                nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-                * w_sorted[:, None]
-            ).astype(self.dtype)                        # rounded once
-            out = grouped_matmul(hidden, w_down.astype(self.dtype), group_sizes)
+        def routed(m, tokens, weights, w_gate, w_up, w_down):
+            """The held experts' part of the layer from the first ``m`` rows
+            in expert order: every row when all experts are held, else a
+            buffer that holds the ``live`` rows of held experts."""
+            first_rows = order if m == n * k else order[:m]
+            with jax.named_scope("moe_experts"):
+                rows = _rows_sorted(
+                    tokens.astype(self.dtype), first_rows, inverse, k, live
+                )
+                w_sorted = _scalars_sorted(weights.reshape(n * k), order, inverse)
+                if m < n * k:
+                    w_sorted = w_sorted[:m]
+                gate = grouped_matmul(rows, w_gate.astype(self.dtype), group_sizes)
+                up = grouped_matmul(rows, w_up.astype(self.dtype), group_sizes)
+                if live is not None:
+                    # Megablox writes no row past the groups' sum, forward or
+                    # backward: what lies there is whatever the memory held.
+                    # Zeros instead, on the way in and (the select's
+                    # transpose) on the way back, or one NaN there reaches
+                    # the router through the weights' gradient
+                    nobodys = (jnp.arange(m) >= live)[:, None]
+                    gate, up = (jnp.where(nobodys, 0, a) for a in (gate, up))
+                hidden = (
+                    nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+                    * w_sorted[:, None]
+                ).astype(self.dtype)                    # rounded once
+                if live is not None:
+                    hidden = jnp.where(nobodys, 0, hidden)  # for its cotangent's rows
+                out = grouped_matmul(hidden, w_down.astype(self.dtype), group_sizes)
+            with jax.named_scope("moe_combine"):
+                return _rows_combined(out, first_rows, inverse, k, live)  # [N, D], float32
 
-        with jax.named_scope("moe_combine"):
-            y = _rows_combined(out, order, inverse, k)  # [N, D], float32
-        return y.reshape(b, s, d).astype(x.dtype)
+        operands = (tokens, weights, w_gate, w_up, w_down)
+        if buffer < n * k:
+            # the whole N * k only for a step whose held rows outgrow the
+            # buffer; it keeps nothing for its backward (which computes it
+            # again), so the step's memory is the usual path's
+            y = jax.lax.cond(
+                live <= buffer, partial(routed, buffer),
+                jax.checkpoint(partial(routed, n * k)), *operands,
+            )
+        else:
+            y = routed(n * k, *operands)
+        y = y.reshape(b, s, d).astype(x.dtype)
+        if self.shared_d_ff:
+            from edl_tpu.models.transformer import SwiGLU  # imports this module
+
+            with jax.named_scope("moe_shared"):
+                y = y + SwiGLU(self.shared_d_ff, self.dtype, name="shared")(x)
+        return y
 
 
 class SwitchMoE(nn.Module):

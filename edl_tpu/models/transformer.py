@@ -7,9 +7,12 @@ TPU-first:
 - pre-norm blocks with RMSNorm, RoPE positions (a choice: ``ArchSpec.rope``),
   SwiGLU MLP — all large-matmul-dominated so the MXU stays busy; bf16
   compute, fp32 params;
-- a block's sequence mixer is attention or, by ``ArchSpec.layer_types``, a
-  Mamba-2 state-space layer (``models/mamba.py``): one ``TransformerLM``
-  runs dense, expert and hybrid configurations;
+- a block's sequence mixer is, by ``ArchSpec.layer_types``, full causal
+  attention, attention over a sliding window, or a Mamba-2 state-space
+  layer (``models/mamba.py``); its feed-forward a SwiGLU or an expert
+  layer (``models/moe.py``), the leading ``ArchSpec.dense_layers`` blocks
+  of an expert model dense: one ``TransformerLM`` runs dense, expert,
+  hybrid and mixed-window configurations;
 - attention is pluggable: the Pallas flash kernel locally, or ring
   attention over the ``sp`` mesh axis for sequences longer than one
   device's HBM (``edl_tpu.parallel.ring``);
@@ -23,9 +26,10 @@ TPU-first:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -56,9 +60,19 @@ class ArchSpec:
     """What a ``TransformerLM`` does differently from the dense default,
     as one hashable field; every default is the dense model's.
 
-    ``layer_types`` names each block's sequence mixer, ``"attention"`` or
+    ``layer_types`` names each block's sequence mixer: ``"attention"``
+    (causal over the whole sequence), ``"sliding_attention"`` (causal over
+    the ``sliding_window`` newest keys, the query's own among them) or
     ``"mamba"`` (then ``mamba`` gives the layer's shape); its length is the
-    model's depth. The three multipliers are Granite's: the embedding's
+    model's depth. ``rope`` rotates q and k in every attention layer
+    (``True``), in none (``False``: no position term at all) or in the
+    windowed layers only (``"sliding"``: the full layers then see order
+    through the causal mask alone). ``dense_layers`` leading blocks of a
+    model with an expert layer (``TransformerLM.moe``) keep the dense
+    SwiGLU of ``d_ff``. ``post_norms`` puts an RMSNorm after each branch as
+    well as before it (``x + N(branch(N(x)))``); ``attn_gate`` multiplies
+    the heads' outputs by ``sigmoid(x W_g)``, elementwise, before the out
+    projection. The three multipliers are Granite's: the embedding's
     output times ``embedding_multiplier``, each residual branch (mixer and
     feed-forward) times ``residual_multiplier``, the logits divided by
     ``logits_scaling``. ``tie_embeddings`` projects onto the vocabulary
@@ -68,12 +82,21 @@ class ArchSpec:
     layer_types: Optional[Tuple[str, ...]] = None
     mamba: Optional[MambaSpec] = None
     head_dim: Optional[int] = None      # None: d_model / num_heads
-    rope: bool = True                   # False: no position term at all
+    rope: Union[bool, str] = True       # True, False or "sliding"
     attn_scale: Optional[float] = None  # None: head_dim ** -0.5
     tie_embeddings: bool = False
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    sliding_window: Optional[int] = None
+    dense_layers: int = 0
+    post_norms: bool = False
+    attn_gate: bool = False
+
+
+def _scope(name: Optional[str]):
+    """``jax.named_scope(name)``, and nothing at all without a name."""
+    return jax.named_scope(name) if name else contextlib.nullcontext()
 
 
 def _times(x, factor: float):
@@ -133,6 +156,19 @@ class Attention(nn.Module):
     With tensor parallelism the grouped projections replicate when
     ``num_kv_heads`` doesn't divide ``tp`` (see ``shard_params_by_rules``)
     while q/o keep their Megatron split.
+
+    ``qk_norm`` is one field with two forms, both an RMSNorm with a learned
+    scale on q and one on k, before RoPE: ``True`` (OLMoE's) normalises the
+    WHOLE projected vector, all heads together, with a scale as wide;
+    ``"head"`` normalises each head's ``head_dim`` values with one scale of
+    ``head_dim`` shared by the heads. ``window`` restricts a query to its
+    ``window`` newest keys, itself included (``ops.attention.attention``'s
+    argument; the cached decode path has none and refuses it). ``gate``
+    adds the projection ``g`` of q's width and returns
+    ``(heads' outputs * sigmoid(g)) W_o``. ``kernel_scope`` names the device
+    scope of the attention call alone (a mixed-window model tells its two
+    kinds of layer apart by it); the QK norms and the gate then sit under
+    ``attn_gate``.
     """
 
     num_heads: int
@@ -141,14 +177,14 @@ class Attention(nn.Module):
     num_kv_heads: Optional[int] = None
     decode: bool = False       # autoregressive mode: KV cache in "cache"
     max_decode_len: int = 2048
-    # OLMoE's QK-norm: an RMSNorm with a learned scale over the WHOLE
-    # projected q (all heads together), and one over the whole projected
-    # k, before the split into heads means anything and before RoPE
-    qk_norm: bool = False
+    qk_norm: Union[bool, str] = False  # True: whole width; "head": per head
     norm_eps: float = 1e-6
     head_dim: Optional[int] = None
     rope: bool = True
     scale: Optional[float] = None
+    window: Optional[int] = None
+    gate: bool = False
+    kernel_scope: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -167,14 +203,22 @@ class Attention(nn.Module):
         q = dense(features=(self.num_heads, head_dim), name="q")(x)
         k = dense(features=(kv_heads, head_dim), name="k")(x)
         v = dense(features=(kv_heads, head_dim), name="v")(x)
-        if self.qk_norm:
+        if self.qk_norm == "head":
+            with _scope(self.kernel_scope and "attn_gate"):
+                q = RMSNorm(self.norm_eps, name="q_norm")(q)
+                k = RMSNorm(self.norm_eps, name="k_norm")(k)
+        elif self.qk_norm is True:
             flat = q.shape[:2] + (-1,)
             q = RMSNorm(self.norm_eps, name="q_norm")(q.reshape(flat)).reshape(q.shape)
             k = RMSNorm(self.norm_eps, name="k_norm")(k.reshape(flat)).reshape(k.shape)
+        elif self.qk_norm:
+            raise ValueError("unknown qk_norm %r" % (self.qk_norm,))
         if self.rope:
             q = rope(q, positions)
             k = rope(k, positions)
         if self.decode:
+            if self.window is not None:
+                raise NotImplementedError("the decode cache takes no window")
             out = self._decode_step(q, k, v, kv_heads, head_dim)
         else:
             # [B, T, H, D] -> [B, H, T, D]
@@ -191,9 +235,16 @@ class Attention(nn.Module):
                 group = self.num_heads // kv_heads
                 k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
             attn = self.attention_fn or attention
-            scaled = {} if self.scale is None else {"scale": self.scale}
-            out = attn(q, k, v, causal=True, **scaled)
+            extra = {} if self.scale is None else {"scale": self.scale}
+            if self.window is not None:
+                extra["window"] = self.window
+            with _scope(self.kernel_scope):
+                out = attn(q, k, v, causal=True, **extra)
             out = jnp.swapaxes(out, 1, 2)
+        if self.gate:
+            with _scope(self.kernel_scope and "attn_gate"):
+                g = dense(features=(self.num_heads, head_dim), name="g")(x)
+                out = out * nn.sigmoid(g)
         return nn.DenseGeneral(
             features=x.shape[-1], axis=(-2, -1), use_bias=False,
             dtype=self.dtype, name="o",
@@ -275,7 +326,7 @@ class Block(nn.Module):
     decode: bool = False
     max_decode_len: int = 2048
     norm_eps: float = 1e-6
-    qk_norm: bool = False
+    qk_norm: Union[bool, str] = False
     moe: Optional[MoESpec] = None  # dropless expert FFN instead of SwiGLU
     arch: ArchSpec = ArchSpec()
     mixer: str = "attention"       # this block's entry of arch.layer_types
@@ -290,16 +341,28 @@ class Block(nn.Module):
             mixed = Mamba2Mixer(
                 arch.mamba, self.dtype, self.norm_eps, name="mamba"
             )(h)
-        elif self.mixer == "attention":
+        elif self.mixer in ("attention", "sliding_attention"):
+            sliding = self.mixer == "sliding_attention"
+            if sliding and arch.sliding_window is None:
+                raise ValueError("a sliding_attention layer needs sliding_window")
             mixed = Attention(
                 self.num_heads, self.dtype, self.attention_fn,
                 num_kv_heads=self.num_kv_heads, decode=self.decode,
                 max_decode_len=self.max_decode_len, qk_norm=self.qk_norm,
                 norm_eps=self.norm_eps, head_dim=arch.head_dim,
-                rope=arch.rope, scale=arch.attn_scale, name="attn",
+                rope=sliding if arch.rope == "sliding" else arch.rope,
+                scale=arch.attn_scale,
+                window=arch.sliding_window if sliding else None,
+                gate=arch.attn_gate,
+                # a model of both kinds tells their device time apart
+                kernel_scope=None if arch.sliding_window is None
+                else ("attn_window" if sliding else "attn_full"),
+                name="attn",
             )(h, positions)
         else:
             raise ValueError("unknown layer type %r" % (self.mixer,))
+        if arch.post_norms:
+            mixed = RMSNorm(self.norm_eps, name="ln1_post")(mixed)
         x = x + _times(mixed, arch.residual_multiplier)
         h = RMSNorm(self.norm_eps, name="ln2")(x)
         if self.moe is not None:
@@ -313,6 +376,8 @@ class Block(nn.Module):
             )(h)
         else:
             ff = SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
+        if arch.post_norms:
+            ff = RMSNorm(self.norm_eps, name="ln2_post")(ff)
         return x + _times(ff, arch.residual_multiplier)
 
 
@@ -418,12 +483,17 @@ class TransformerLM(nn.Module):
     decode: bool = False                # KV-cached autoregressive mode
     max_decode_len: int = 2048
     norm_eps: float = 1e-6              # every RMSNorm's epsilon
-    qk_norm: bool = False               # RMSNorm over projected q and k
-    # every block's FFN as a dropless top-k expert layer (models/moe.py);
-    # the older Switch pair above stays for SwitchMoE until ROADMAP D6
+    # RMSNorm over projected q and k: True over the whole width (OLMoE),
+    # "head" over each head's own values (see Attention)
+    qk_norm: Union[bool, str] = False
+    # every block's FFN (past arch.dense_layers) as a dropless top-k expert
+    # layer (models/moe.py); the older Switch pair above stays for
+    # SwitchMoE until ROADMAP D6
     moe: Optional[MoESpec] = None
-    # the layer pattern, the attention's head size / positions / score
-    # scale, a tied head and Granite's multipliers; None: the dense model
+    # the layer pattern (mixers, windows, leading dense layers), the
+    # attention's head size / positions / score scale / gate, norms after
+    # the branches, a tied head and Granite's multipliers; None: the dense
+    # model
     arch: Optional[ArchSpec] = None
 
     @nn.compact
@@ -459,8 +529,9 @@ class TransformerLM(nn.Module):
             x = block(
                 self.num_heads, self.d_ff, self.dtype, self.attention_fn,
                 moe, self.num_kv_heads, self.decode, self.max_decode_len,
-                self.norm_eps, self.qk_norm, self.moe, arch, layer_types[i],
-                name="layer_%d" % i,
+                self.norm_eps, self.qk_norm,
+                None if i < arch.dense_layers else self.moe, arch,
+                layer_types[i], name="layer_%d" % i,
             )(x, positions)
         x = RMSNorm(self.norm_eps, name="ln_f")(x)
         if arch.tie_embeddings:

@@ -312,8 +312,11 @@ def step_phases() -> Dict[str, str]:
 
 def step_scopes(scopes: Sequence[str] = MOE_SCOPES) -> Dict[str, str]:
     """:func:`scopes_of_hlo` for the step executable of the running stage:
-    which of a device trace's events ran under the expert layer's
-    ``moe_route`` / ``moe_experts`` / ``moe_combine``. {} without a step or
+    which of a device trace's events ran under which of ``scopes`` — by
+    default the expert layer's ``moe_route`` / ``moe_experts`` /
+    ``moe_combine``; the models also name ``moe_shared``, the Mamba-2
+    mixer's ``ssm_*`` and, in a model of windowed and full attention layers,
+    ``attn_window`` / ``attn_full`` / ``attn_gate``. {} without a step or
     for a model that enters none of ``scopes``."""
     key = tuple(scopes)
     if key not in _step_scopes and _step_executable is not None:

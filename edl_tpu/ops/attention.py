@@ -1,14 +1,29 @@
-"""Attention: jnp reference + Pallas flash-attention TPU kernel.
+"""Attention: jnp reference + two families of Pallas flash-attention TPU
+kernels, behind one measured dispatch (:func:`attention`).
 
-The flash kernel streams KV blocks through VMEM with the online-softmax
+A flash kernel streams KV blocks through VMEM with the online-softmax
 recurrence (running row-max ``m``, denominator ``l``, numerator ``acc``),
 so the [Tq, Tk] score matrix never materializes in HBM — the standard
 memory-bandwidth win on TPU where HBM, not FLOPs, bounds attention.
 
-Layout: ``[batch, heads, seq, head_dim]``. The kernel grid is
-``(batch*heads, q_blocks)``; each program owns one q block and loops over
-kv blocks with ``lax.fori_loop``. Causal masking compares global q/k
-positions from ``broadcasted_iota`` (TPU needs ≥2D iota).
+Layout: ``[batch, heads, seq, head_dim]``. The whole-KV family
+(``"flash"``: ``_flash_kernel`` and its two backward kernels) has the grid
+``(batch*heads, q_blocks)``; each program owns one q block, holds the
+whole K and V of its head in VMEM and loops over kv blocks with
+``lax.fori_loop``. The grid-pipelined family (``"flash2"``:
+``_flash2_kernel`` and its two) puts the kv blocks (for dk/dv the q blocks)
+on a third, innermost grid dimension, so that they are copied block by
+block behind the compute; it is the one that runs past
+:func:`_flash_max_seq` and the one that takes a **window**
+(``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
+``i - W < j <= i``): its grid then walks only the blocks a block can see,
+starting at the first of them, so blocks wholly outside the window are
+neither copied nor computed, and every live tile is masked on both
+edges. The whole-KV family refuses a window and the dispatch sends a
+windowed call to flash2 at every length; the dense reference takes it as
+a mask. ``window=None`` traces the kernels it always traced. Causal
+masking compares global q/k positions from ``broadcasted_iota`` (TPU
+needs ≥2D iota).
 
 ``flash_attention`` is differentiable via ``jax.custom_vjp`` with REAL
 flash backward kernels: the forward saves per-row logsumexp (``lse``),
@@ -32,14 +47,32 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _dense_causal_mask(scores: jax.Array) -> jax.Array:
+def _dense_causal_mask(scores: jax.Array, window: int | None = None) -> jax.Array:
     """End-aligned causal mask for a dense [..., Tq, Tk] score tensor:
     ``qpos = arange(Tq) + (Tk - Tq)`` so sequence ENDS line up (the one
-    convention every path in this module must share)."""
+    convention every path in this module must share). With ``window`` a
+    query sees the ``window`` newest of those keys, itself included:
+    ``qpos - window < kpos <= qpos``."""
     tq, tk = scores.shape[-2], scores.shape[-1]
     qpos = jnp.arange(tq)[:, None] + (tk - tq)
     kpos = jnp.arange(tk)[None, :]
-    return jnp.where(qpos >= kpos, scores, NEG_INF)
+    return jnp.where(_sees(qpos, kpos, window), scores, NEG_INF)
+
+
+def _sees(qpos, kpos, window):
+    """Whether the query at ``qpos`` sees the key at ``kpos``: causal, and
+    inside the window if there is one."""
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
+
+
+def _check_window(window, causal: bool):
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            "a window (%r) is the newest keys of a causal mask: it needs "
+            "causal=True and at least one key" % (window,)
+        )
 
 
 def attention_reference(
@@ -48,9 +81,12 @@ def attention_reference(
     v: jax.Array,
     causal: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Plain softmax attention; [B, H, T, D] in, [B, H, Tq, D] out."""
-    return attention_reference_with_lse(q, k, v, causal=causal, scale=scale)[0]
+    return attention_reference_with_lse(
+        q, k, v, causal=causal, scale=scale, window=window
+    )[0]
 
 
 def _gqa_group(q: jax.Array, k: jax.Array) -> int:
@@ -90,11 +126,13 @@ def attention_reference_with_lse(
     v: jax.Array,
     causal: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ):
     """Reference attention that also returns per-row logsumexp of the
     scaled scores ``[B, H, Tq]`` — the residual blockwise/ring merging
     needs. Grouped k/v (GQA) broadcast in-graph; their VJP folds dk/dv
     back to the grouped width automatically."""
+    _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     k, v = _broadcast_kv(q, k, v)
@@ -102,7 +140,7 @@ def attention_reference_with_lse(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
     if causal:
-        scores = _dense_causal_mask(scores)
+        scores = _dense_causal_mask(scores, window)
     lse = jax.scipy.special.logsumexp(scores, axis=-1)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
@@ -144,9 +182,10 @@ def _dot_tn(a, b):
     )
 
 
-def _causal_mask(s, qi, q_block, j, block_k, q_offset):
+def _causal_mask(s, qi, q_block, j, block_k, q_offset, window=None):
     """Mask one [block_q, block_k] score tile; ``q_offset = tk - tq``
-    aligns sequence *ends*, matching ``attention_reference``."""
+    aligns sequence *ends*, matching ``attention_reference``; ``window``
+    as in :func:`_dense_causal_mask`."""
     block_q = s.shape[0]
     qpos = (
         jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -157,7 +196,56 @@ def _causal_mask(s, qi, q_block, j, block_k, q_offset):
         jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         + j * block_k
     )
-    return jnp.where(qpos >= kpos, s, NEG_INF)
+    return jnp.where(_sees(qpos, kpos, window), s, NEG_INF)
+
+
+# A window in the grid-pipelined kernels. The innermost grid dimension is
+# then not every block of the other side but the ``steps`` a block can see
+# at most; step ``s`` of q block ``qi`` is kv block ``first(qi) + s`` (of
+# kv block ``ki``: q block ``first(ki) + s``), so blocks wholly outside
+# ``(i - W, i]`` are neither copied nor computed. A step past the last
+# live block repeats that block's index (no new copy) and is skipped.
+
+
+def _first_kv_block(qi, q_block, block_k, q_offset, window, xp=jnp):
+    """The kv block holding the oldest key the first row of q block
+    ``qi`` sees. (``xp``: numpy for the static count of steps.)"""
+    return xp.maximum(qi * q_block + q_offset - (window - 1), 0) // block_k
+
+
+def _last_kv_block(qi, q_block, block_k, q_offset):
+    """The kv block holding the newest key q block ``qi`` sees (its last
+    row's own position)."""
+    return ((qi + 1) * q_block + q_offset - 1) // block_k
+
+
+def _first_q_block(ki, k_block, block_q, q_offset, xp=jnp):
+    """The q block holding the first row that sees kv block ``ki``."""
+    return xp.maximum(ki * k_block - q_offset, 0) // block_q
+
+
+def _last_q_block(ki, k_block, block_q, q_offset, window, num_q, xp=jnp):
+    """The q block holding the last row that sees kv block ``ki``: the one
+    ``window - 1`` past the block's newest key, or the sequence's end."""
+    last_row = (ki + 1) * k_block - 1 + (window - 1) - q_offset
+    return xp.minimum(last_row // block_q, num_q - 1)
+
+
+def _window_steps(window, block_q, block_k, num_q, num_k, q_offset):
+    """``(kv steps a q block, q steps a kv block)``: the most blocks of the
+    other side any block sees, from the static shapes."""
+    import numpy as np
+
+    qi, ki = np.arange(num_q), np.arange(num_k)
+    kv = (
+        _last_kv_block(qi, block_q, block_k, q_offset)
+        - _first_kv_block(qi, block_q, block_k, q_offset, window, np) + 1
+    )
+    q = (
+        _last_q_block(ki, block_k, block_q, q_offset, window, num_q, np)
+        - _first_q_block(ki, block_k, block_q, q_offset, np) + 1
+    )
+    return int(kv.max()), int(q.max())
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -208,7 +296,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
 def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                    acc_scr, *, causal: bool, scale: float, q_block: int,
-                   block_k: int, num_k: int, q_offset: int):
+                   block_k: int, num_k: int, q_offset: int,
+                   window: int | None = None):
     """Grid-pipelined forward: the KV loop lives in the GRID (innermost
     dimension), so Pallas double-buffers each KV block's HBM→VMEM copy
     behind the previous block's compute — where :func:`_flash_kernel`
@@ -216,13 +305,16 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     (no copy/compute overlap, and a VMEM footprint that scales with the
     full sequence). Online-softmax state (m, l, acc) carries across the
     innermost grid steps in VMEM scratch, initialized at j==0 and
-    finalized into (o, lse) at j==num_k-1."""
+    finalized into (o, lse) at j==num_k-1. Under a ``window`` the grid's
+    ``num_k`` steps start at the q block's first visible kv block."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    j = pl.program_id(2)
+    step = j = pl.program_id(2)
+    if window is not None:
+        j = step + _first_kv_block(qi, q_block, block_k, q_offset, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -242,7 +334,7 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         v = v_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, qi, q_block, j, block_k, q_offset)
+            s = _causal_mask(s, qi, q_block, j, block_k, q_offset, window)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -251,7 +343,7 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + _dot_nn(p.astype(v.dtype), v)
 
-    @pl.when(j == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -283,7 +375,7 @@ def _bwd_delta(g: jax.Array, o: jax.Array, b: int, h: int, tq: int, d: int):
 
 def _flash2_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
+    block_q: int, block_k: int, interpret: bool, window: int | None = None,
 ):
     """(o, lse) via the grid-pipelined kernel; same ragged fallback
     contract as :func:`_flash_forward` (``lse is None`` = dense path)."""
@@ -295,13 +387,26 @@ def _flash2_forward(
     block_q = _fit_block(block_q, tq)
     block_k = _fit_block(block_k, tk)
     if tq % block_q or tk % block_k or (causal and tq > tk):
-        return attention_reference(q, k, v, causal=causal, scale=scale), None
+        return attention_reference(
+            q, k, v, causal=causal, scale=scale, window=window
+        ), None
 
     g = _gqa_group(q, k)
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * (h // g), tk, d)
     vf = v.reshape(b * (h // g), tk, d)
     num_k = tk // block_k
+    kv_map = lambda i, qi, j, g=g: (i // g, j, 0)  # noqa: E731
+    if window is not None:
+        last, off = num_k - 1, tk - tq
+        num_k, _ = _window_steps(
+            window, block_q, block_k, tq // block_q, num_k, off
+        )
+
+        def kv_map(i, qi, j, g=g):
+            first = _first_kv_block(qi, block_q, block_k, off, window)
+            return (i // g, jnp.minimum(first + j, last), 0)
+
     grid = (b * h, tq // block_q, num_k)
     kwargs = _grid_pipeline_kwargs()
     out, lse = pl.pallas_call(
@@ -313,6 +418,7 @@ def _flash2_forward(
             block_k=block_k,
             num_k=num_k,
             q_offset=tk - tq,
+            window=window,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
@@ -321,12 +427,8 @@ def _flash2_forward(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec(
-                (1, block_k, d), lambda i, qi, j, g=g: (i // g, j, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, d), lambda i, qi, j, g=g: (i // g, j, 0)
-            ),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
@@ -427,16 +529,19 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dq_ref, dq_scr, *, causal: bool, scale: float,
                           q_block: int, block_k: int, num_k: int,
-                          q_offset: int):
+                          q_offset: int, window: int | None = None):
     """Grid-pipelined dq: KV blocks ride the innermost grid dimension
     (double-buffered DMA), dq accumulates in VMEM scratch across steps —
-    the backward twin of :func:`_flash2_kernel`'s structure."""
+    the backward twin of :func:`_flash2_kernel`'s structure, ``window``
+    included."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    j = pl.program_id(2)
+    step = j = pl.program_id(2)
+    if window is not None:
+        j = step + _first_kv_block(qi, q_block, block_k, q_offset, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -454,13 +559,13 @@ def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, qi, q_block, j, block_k, q_offset)
+            s = _causal_mask(s, qi, q_block, j, block_k, q_offset, window)
         p = jnp.exp(s - lse)
         dp = _dot_nt(do, v)
         ds = p * (dp - delta)
         dq_scr[:] = dq_scr[:] + _dot_nn(ds.astype(k.dtype), k)
 
-    @pl.when(j == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
@@ -468,21 +573,30 @@ def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                            scale: float, block_q: int, k_block: int,
-                           num_q: int, q_offset: int):
+                           num_q: int, q_offset: int,
+                           window: int | None = None, total_q: int = 0):
     """Grid-pipelined dk/dv: Q/dO/lse/delta blocks ride the innermost
-    grid dimension, dk/dv accumulate in scratch per KV block."""
+    grid dimension, dk/dv accumulate in scratch per KV block. Under a
+    ``window`` the ``num_q`` steps start at the first q block that sees
+    this kv block and are live up to the last one that does (of the
+    sequence's ``total_q``)."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    j = pl.program_id(2)
+    step = j = pl.program_id(2)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     live = True
-    if causal:
+    if window is not None:
+        j = step + _first_q_block(ki, k_block, block_q, q_offset)
+        live = j <= _last_q_block(
+            ki, k_block, block_q, q_offset, window, total_q
+        )
+    elif causal:
         # q blocks entirely before this kv block's first column are dead
         live = j >= jnp.maximum(0, (ki * k_block - q_offset) // block_q)
 
@@ -496,14 +610,14 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, j, block_q, ki, k_block, q_offset)
+            s = _causal_mask(s, j, block_q, ki, k_block, q_offset, window)
         p = jnp.exp(s - lse)
         dv_scr[:] = dv_scr[:] + _dot_tn(p.astype(do.dtype), do)
         dp = _dot_nt(do, v)
         ds = p * (dp - delta)
         dk_scr[:] = dk_scr[:] + _dot_tn(ds.astype(q.dtype), q)
 
-    @pl.when(j == num_q - 1)
+    @pl.when(step == num_q - 1)
     def _finalize():
         # scale applied to s, not pre-folded into q (see
         # _flash_bwd_dkv_kernel): dk takes its one factor here
@@ -513,20 +627,21 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash2_backward(
     q, k, v, o, lse, g, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
+    block_q: int, block_k: int, interpret: bool, window: int | None = None,
 ):
     """(dq, dk, dv) via the grid-pipelined backward kernels;
     ``lse`` in kernel layout [B*H, Tq] like :func:`_flash_backward`."""
     b, h, tq, d = q.shape
     delta = _bwd_delta(g, o, b, h, tq, d)
     return _flash2_backward_kernels(
-        q, k, v, g, lse, delta, causal, scale, block_q, block_k, interpret
+        q, k, v, g, lse, delta, causal, scale, block_q, block_k, interpret,
+        window,
     )
 
 
 def _flash2_backward_kernels(
     q, k, v, g, lse, delta, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
+    block_q: int, block_k: int, interpret: bool, window: int | None = None,
 ):
     """The two grid-pipelined backward pallas calls; ``lse``/``delta``
     are [B*H, Tq] (external residuals welcome — ring attention's
@@ -552,23 +667,35 @@ def _flash2_backward_kernels(
     num_k = tk // block_k
     num_q = tq // block_q
     kwargs = _grid_pipeline_kwargs()
-    common = dict(causal=causal, scale=scale, q_offset=tk - tq)
+    off = tk - tq
+    common = dict(causal=causal, scale=scale, q_offset=off, window=window)
+    kv_steps, q_steps = num_k, num_q
+    kv_map = lambda i, qi, j, g=grp: (i // g, j, 0)  # noqa: E731
+    q_map = lambda i, ki, j: (i, j, 0)  # noqa: E731
+    if window is not None:
+        kv_steps, q_steps = _window_steps(
+            window, block_q, block_k, num_q, num_k, off
+        )
+
+        def kv_map(i, qi, j, g=grp):
+            first = _first_kv_block(qi, block_q, block_k, off, window)
+            return (i // g, jnp.minimum(first + j, num_k - 1), 0)
+
+        def q_map(i, ki, j):
+            first = _first_q_block(ki, block_k, block_q, off)
+            return (i, jnp.minimum(first + j, num_q - 1), 0)
 
     dq = pl.pallas_call(
         functools.partial(
             _flash2_bwd_dq_kernel,
-            q_block=block_q, block_k=block_k, num_k=num_k, **common,
+            q_block=block_q, block_k=block_k, num_k=kv_steps, **common,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-        grid=(b * h, num_q, num_k),
+        grid=(b * h, num_q, kv_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec(
-                (1, block_k, d), lambda i, qi, j, g=grp: (i // g, j, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, d), lambda i, qi, j, g=grp: (i // g, j, 0)
-            ),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
@@ -584,24 +711,25 @@ def _flash2_backward_kernels(
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash2_bwd_dkv_kernel,
-            block_q=block_q, k_block=block_k, num_q=num_q, **common,
+            block_q=block_q, k_block=block_k, num_q=q_steps,
+            total_q=num_q, **common,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
         ],
-        grid=(b * h, num_k, num_q),
+        grid=(b * h, num_k, q_steps),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, ki, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec(
                 (1, block_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)
             ),
             pl.BlockSpec(
                 (1, block_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)
             ),
-            pl.BlockSpec((1, block_q, d), lambda i, ki, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, ki, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, ki, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_q, 1), q_map),
+            pl.BlockSpec((1, block_q, 1), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda i, ki, j: (i, ki, 0)),
@@ -957,16 +1085,20 @@ def flash_attention(
     scale: float | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention; falls back to the reference on ragged shapes.
 
     Default blocks come from the measured per-seq table (``_BLOCK_TABLE``,
     v5e on-chip bq x bk sweep): e.g. bq=512 halves the forward at seq
     2048 vs the old fixed 128. Explicit block args win — including past
-    the whole-KV compile limit, where they reach the flash2 kernels."""
+    the whole-KV compile limit, where they reach the flash2 kernels.
+    A ``window`` is served by the flash2 kernels at every length (see
+    :func:`_select_impls`)."""
+    _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if max(q.shape[2], k.shape[2]) > _flash_max_seq():
+    if window is not None or max(q.shape[2], k.shape[2]) > _flash_max_seq():
         # whole-KV kernel does not compile past this length: serve the
         # same contract through the grid-pipelined kernels, filling any
         # unspecified block from flash2's own measured defaults
@@ -980,7 +1112,7 @@ def flash_attention(
         )
         return _auto(
             q, k, v, causal, scale, "flash2", "flash2",
-            fwd_blocks, bwd_blocks,
+            fwd_blocks, bwd_blocks, window,
         )
     if block_q is None or block_k is None:
         (fbq, fbk), _ = _kernel_blocks(q.shape[2])
@@ -1149,22 +1281,27 @@ def _lookup(rows, tq: int) -> str | None:
     return None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _auto(q, k, v, causal, scale, fwd_impl, bwd_impl,
-          fwd_blocks=None, bwd_blocks=None):
+          fwd_blocks=None, bwd_blocks=None, window=None):
     """``fwd_blocks``/``bwd_blocks`` are optional (bq, bk) overrides for
     the kernel impls (hashable tuples — they ride nondiff_argnums);
-    ``None`` means the measured defaults for that impl."""
+    ``None`` means the measured defaults for that impl. ``window`` is
+    taken by ``"ref"`` and ``"flash2"`` (:func:`_select_impls` gives a
+    windowed call no other)."""
     return _auto_fwd(
-        q, k, v, causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks
+        q, k, v, causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
+        window,
     )[0]
 
 
 def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
-              fwd_blocks=None, bwd_blocks=None):
+              fwd_blocks=None, bwd_blocks=None, window=None):
+    if window is not None and "flash" in (fwd_impl, bwd_impl):
+        raise ValueError("the whole-KV flash kernels take no window")
     if fwd_impl == "ref":
         out, lse = attention_reference_with_lse(
-            q, k, v, causal=causal, scale=scale
+            q, k, v, causal=causal, scale=scale, window=window
         )
         b, h, tq, _ = q.shape
         # kernel layout, so a flash backward can consume a dense forward's
@@ -1173,7 +1310,7 @@ def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
     elif fwd_impl == "flash2":
         f2q, f2k = fwd_blocks or _FLASH2_BLOCKS_FWD
         out, lse = _flash2_forward(
-            q, k, v, causal, scale, f2q, f2k, _interpret()
+            q, k, v, causal, scale, f2q, f2k, _interpret(), window
         )
     else:
         fbq, fbk = fwd_blocks or _kernel_blocks(q.shape[2])[0]
@@ -1203,7 +1340,7 @@ def _name_residuals(q, k, v, out, lse):
 
 
 def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
-              residuals, g):
+              window, residuals, g):
     q, k, v, o, lse = residuals
     if bwd_impl in ("flash", "flash2") and lse is not None:
         tq, tk = q.shape[2], k.shape[2]
@@ -1215,15 +1352,17 @@ def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
         )
         bq, bk = _fit_block(bbq, tq), _fit_block(bbk, tk)
         if not (tq % bq or tk % bk or (causal and tq > tk)):
-            backward = (
-                _flash2_backward if bwd_impl == "flash2" else _flash_backward
-            )
-            return backward(
+            if bwd_impl == "flash2":
+                return _flash2_backward(
+                    q, k, v, o, lse, g, causal, scale, bq, bk, _interpret(),
+                    window,
+                )
+            return _flash_backward(
                 q, k, v, o, lse, g, causal, scale, bq, bk, _interpret()
             )
     _, vjp = jax.vjp(
         lambda q, k, v: attention_reference(
-            q, k, v, causal=causal, scale=scale
+            q, k, v, causal=causal, scale=scale, window=window
         ),
         q, k, v,
     )
@@ -1239,24 +1378,32 @@ def attention(
     v: jax.Array,
     causal: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Attention through the measured dispatch table — the default entry
     point for every model in the tree (TransformerLM, the LM
     examples). Forward and backward implementations are chosen
     independently per sequence length; off-TPU it is exactly the dense
     reference. ``flash_attention`` / ``attention_reference`` remain for
-    callers that want a specific implementation."""
+    callers that want a specific implementation. ``window`` (with
+    ``causal``): a query sees its ``window`` newest keys, itself
+    included; both routes take it, the dense one as a mask, the kernel
+    one through flash2 alone (:func:`_select_impls`)."""
+    _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if jax.default_backend() != "tpu":
         # native autodiff, NOT _auto("ref","ref"): the custom_vjp would
         # recompute the whole forward in every backward, where plain
         # differentiation reuses the saved activations
-        return attention_reference(q, k, v, causal=causal, scale=scale)
+        return attention_reference(
+            q, k, v, causal=causal, scale=scale, window=window
+        )
     tq, tk = q.shape[2], k.shape[2]
     table = _dispatch_table()
     if (
         tq == tk
+        and window is None            # nor can it take a window
         and q.shape[1] == k.shape[1]  # builtin can't read grouped k/v
         and _lookup(table["whole"], tq) == "builtin"
     ):
@@ -1268,12 +1415,15 @@ def attention(
         # end-aligned — the conventions agree exactly when lengths match
         return _builtin_flash(q, k, v, causal=causal, sm_scale=scale)
     fwd_impl, bwd_impl = _select_impls(
-        table, q.shape[0], q.shape[1], tq, tk
+        table, q.shape[0], q.shape[1], tq, tk, window is not None
     )
-    return _auto(q, k, v, causal, scale, fwd_impl, bwd_impl)
+    return _auto(
+        q, k, v, causal, scale, fwd_impl, bwd_impl, None, None, window
+    )
 
 
-def _select_impls(table, b: int, h: int, tq: int, tk: int):
+def _select_impls(table, b: int, h: int, tq: int, tk: int,
+                  windowed: bool = False):
     """Table lookup + memory guard -> ``(fwd_impl, bwd_impl)``.
 
     The table is calibrated at one [b, h] point, but the dense forward
@@ -1288,7 +1438,11 @@ def _select_impls(table, b: int, h: int, tq: int, tk: int):
         # the reference forward — guard both directions
         fwd_impl = "flash" if fwd_impl == "ref" else fwd_impl
         bwd_impl = "flash" if bwd_impl == "ref" else bwd_impl
-    if max(tq, tk) > _flash_max_seq():
+    if windowed or max(tq, tk) > _flash_max_seq():
+        # a window: only flash2's grid can leave blocks out (the whole-KV
+        # kernels copy every key into VMEM before they look at one), so
+        # the whole-KV family REFUSES a window and a windowed call is
+        # flash2's at every length. Past the limit,
         # measured on v5e (jax 0.9): the whole-KV-in-VMEM flash kernel
         # fails to COMPILE beyond 4096 (every block config crashed the
         # TPU compiler), while the grid-pipelined flash2 — constant VMEM

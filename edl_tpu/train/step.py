@@ -25,7 +25,12 @@ from edl_tpu.obs import numerics as obs_numerics
 
 
 class TrainState(struct.PyTreeNode):
-    """Model + optimizer state (flax-style, with batch_stats for BN)."""
+    """Model + optimizer state (flax-style). ``batch_stats`` is the model's
+    collection of that name: what a step computes from its own batch and
+    keeps without a gradient — BatchNorm's running moments, an expert
+    router's balancing bias (``models/moe.py``). The step hands it to the
+    model as mutable and stores what comes back; it is saved, restored and
+    replicated with the rest."""
 
     step: jnp.ndarray
     apply_fn: Callable = struct.field(pytree_node=False)

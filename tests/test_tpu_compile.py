@@ -132,6 +132,44 @@ def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
     assert compiled.as_text().count("tpu_custom_call") == len(want)
 
 
+TRINITY = (1, 32, 4, 8192, 128)    # trinity_mini.steady's attention layers
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("window", [2048, 1000], ids=["w2048", "w1000"])
+def test_windowed_kernel_compiles_for_v5e(one_chip, direction, window):
+    """The flash2 kernels under a window at Trinity-Mini's shape (GQA 32:4 x
+    128, T = 8192, the published 2048 and a window no block divides): index
+    maps that start at a block's first visible block and clamp at the last
+    are Mosaic's to accept, not interpret mode's. The grid's innermost
+    dimension is the steps a block can see, not every block."""
+    b, h, h_kv, t, d = TRINITY
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    if direction == "fwd":
+        bq, bk = A._FLASH2_BLOCKS_FWD
+        fn = lambda q, k, v: A._flash2_forward(
+            q, k, v, True, d ** -0.5, bq, bk, False, window
+        )
+        args, want = (q, kv, kv), [FWD_NAME["flash2"]]
+    else:
+        bq, bk = A._FLASH2_BLOCKS_BWD
+        fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
+            q, k, v, g, lse, delta, True, d ** -0.5, bq, bk, False, window
+        )
+        row = sds((b * h, t), jnp.float32)
+        args, want = (q, kv, kv, q, row, row), list(BWD_NAMES["flash2"])
+    kv_steps, q_steps = A._window_steps(window, bq, bk, t // bq, t // bk, 0)
+    assert kv_steps < t // bk and q_steps < t // bq
+    lowered = jax.jit(fn).lower(*args)
+    assert _kernel_names(lowered.as_text()) == want
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == len(want)
+
+
 def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip):
     """The chunked scan with jax's own backward at one sequence of 8192, 64
     heads of 64 over a state of 128, chunk 256: plain XLA, so what the chip's
