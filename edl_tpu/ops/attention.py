@@ -16,14 +16,15 @@ on a third, innermost grid dimension, so that they are copied block by
 block behind the compute; it is the one that runs past
 :func:`_flash_max_seq` and the one that takes a **window**
 (``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
-``i - W < j <= i``): its grid then walks only the blocks a block can see,
-starting at the first of them, so blocks wholly outside the window are
-neither copied nor computed, and every live tile is masked on both
+``i - W < j <= i``). Under a mask its innermost steps are **spans** of the
+other side that start where a block's first visible key (or row) lies, at
+an element and not at a block under a window; a step the mask leaves
+nothing for holds the nearest live span again, so what a block cannot see
+is neither copied nor computed, and every live tile is masked on both
 edges. The whole-KV family refuses a window and the dispatch sends a
 windowed call to flash2 at every length; the dense reference takes it as
-a mask. ``window=None`` traces the kernels it always traced. Causal
-masking compares global q/k positions from ``broadcasted_iota`` (TPU
-needs ≥2D iota).
+a mask. Causal masking compares global q/k positions from
+``broadcasted_iota`` (TPU needs ≥2D iota).
 
 ``flash_attention`` is differentiable via ``jax.custom_vjp`` with REAL
 flash backward kernels: the forward saves per-row logsumexp (``lse``),
@@ -39,6 +40,7 @@ jnp reference end-to-end (forward and backward agree by construction).
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -182,70 +184,224 @@ def _dot_tn(a, b):
     )
 
 
-def _causal_mask(s, qi, q_block, j, block_k, q_offset, window=None):
-    """Mask one [block_q, block_k] score tile; ``q_offset = tk - tq``
-    aligns sequence *ends*, matching ``attention_reference``; ``window``
-    as in :func:`_dense_causal_mask`."""
-    block_q = s.shape[0]
-    qpos = (
-        jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        + qi * q_block
-        + q_offset
-    )
-    kpos = (
-        jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        + j * block_k
-    )
+def _causal_mask(s, q_lo, k_lo, window=None):
+    """Mask one [rows, keys] score tile whose first row sits at position
+    ``q_lo`` (its index plus ``q_offset = tk - tq``, which aligns sequence
+    *ends*, matching ``attention_reference``) and whose first key is
+    ``k_lo``; ``window`` as in :func:`_dense_causal_mask`."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_lo
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_lo
     return jnp.where(_sees(qpos, kpos, window), s, NEG_INF)
 
 
-# A window in the grid-pipelined kernels. The innermost grid dimension is
-# then not every block of the other side but the ``steps`` a block can see
-# at most; step ``s`` of q block ``qi`` is kv block ``first(qi) + s`` (of
-# kv block ``ki``: q block ``first(ki) + s``), so blocks wholly outside
-# ``(i - W, i]`` are neither copied nor computed. A step past the last
-# live block repeats that block's index (no new copy) and is skipped.
+def _tile_class(q_lo, nq, k_lo, nk, window):
+    """``(dead, interior)`` of the tile whose ``nq`` rows start at position
+    ``q_lo`` and whose ``nk`` keys start at ``k_lo``: dead when no row sees
+    any key, interior when every row sees every key, an edge (the diagonal
+    or the window's old side crosses it) otherwise. Scalars in a kernel,
+    numpy arrays in :func:`tile_census`."""
+    q_hi = q_lo + nq - 1
+    k_hi = k_lo + nk - 1
+    dead = q_hi < k_lo
+    interior = q_lo >= k_hi
+    if window is not None:
+        dead = dead | (q_lo - k_hi >= window)
+        interior = interior & (q_hi - k_lo < window)
+    return dead, interior
 
 
-def _first_kv_block(qi, q_block, block_k, q_offset, window, xp=jnp):
-    """The kv block holding the oldest key the first row of q block
-    ``qi`` sees. (``xp``: numpy for the static count of steps.)"""
-    return xp.maximum(qi * q_block + q_offset - (window - 1), 0) // block_k
+# Spans: the innermost grid dimension of the grid-pipelined kernels under a
+# mask. A q block walks ``steps`` spans of ``block_k`` keys from the first
+# key its first row sees (for dk/dv a kv block walks spans of ``block_q``
+# rows from the first row that sees it). Without a window the first span
+# starts at 0 and the steps are every block (the last q block sees them
+# all). Under a window a span starts where the window does, rounded down to
+# ``_SPAN_ALIGN`` and not to a block, so a q block of 256 rows under a
+# window of 2048 walks 2304 keys and not the 3072 of three aligned blocks
+# of 1024: the span's start is an element offset (``pl.Element``), which
+# is all the copy needs. A step outside the spans the mask leaves live
+# (past the diagonal; before the first row that sees a kv block) holds the
+# nearest live span again: Pallas sees the index stand still and copies
+# nothing, and the kernel, which knows where the step would have been,
+# skips it.
+
+_SPAN_ALIGN = 128
 
 
-def _last_kv_block(qi, q_block, block_k, q_offset):
-    """The kv block holding the newest key q block ``qi`` sees (its last
-    row's own position)."""
-    return ((qi + 1) * q_block + q_offset - 1) // block_k
+class _lax:
+    """The span arithmetic's three operations as lax's flat primitives
+    (numpy's names, so :func:`tile_census` runs the same code on arrays).
+    jnp's are jitted helpers, a nested call each for Mosaic to lower in
+    every index map of every call site at every start; every operand
+    here is non-negative, so ``lax.div`` floors."""
+
+    minimum = staticmethod(jax.lax.min)
+    maximum = staticmethod(jax.lax.max)
+    floor_divide = staticmethod(jax.lax.div)
 
 
-def _first_q_block(ki, k_block, block_q, q_offset, xp=jnp):
-    """The q block holding the first row that sees kv block ``ki``."""
-    return xp.maximum(ki * k_block - q_offset, 0) // block_q
+def _kv_range(qi, block_q, q_offset, window, xp=_lax):
+    """``(lo, hi)``: the oldest and the newest key q block ``qi`` sees."""
+    q_lo = qi * block_q + q_offset
+    lo = 0 * qi if window is None else xp.maximum(q_lo - (window - 1), 0)
+    return lo, q_lo + block_q - 1
 
 
-def _last_q_block(ki, k_block, block_q, q_offset, window, num_q, xp=jnp):
-    """The q block holding the last row that sees kv block ``ki``: the one
-    ``window - 1`` past the block's newest key, or the sequence's end."""
-    last_row = (ki + 1) * k_block - 1 + (window - 1) - q_offset
-    return xp.minimum(last_row // block_q, num_q - 1)
+def _q_range(ki, block_k, q_offset, window, tq, xp=_lax):
+    """``(lo, hi)``: the first and the last row that sees kv block ``ki``
+    (``hi < lo``: keys older than every row's window, which none sees)."""
+    lo = xp.maximum(ki * block_k - q_offset, 0)
+    if window is None:
+        return lo, 0 * ki + tq - 1
+    return lo, xp.minimum(
+        (ki + 1) * block_k - 1 + (window - 1) - q_offset, tq - 1
+    )
 
 
-def _window_steps(window, block_q, block_k, num_q, num_k, q_offset):
-    """``(kv steps a q block, q steps a kv block)``: the most blocks of the
-    other side any block sees, from the static shapes."""
+def _spans(seen, block, steps, total, window, xp=_lax):
+    """``(start, first, last)``: where the ``steps`` spans of ``block``
+    begin so that they cover what a block of the other side has ``seen``
+    (``(lo, hi)`` of :func:`_kv_range` or :func:`_q_range`, of ``total``
+    keys or rows), and the first and the last live one of them."""
+    lo, hi = seen
+    align = block if window is None else _SPAN_ALIGN
+    start = xp.minimum(xp.floor_divide(lo, align) * align, total - steps * block)
+    return (
+        start, xp.floor_divide(lo - start, block),
+        xp.floor_divide(xp.maximum(hi, lo) - start, block),
+    )
+
+
+def _span_need(seen):
+    """The most keys (rows) any block walks: from where its first span
+    starts to the last one it sees (:func:`_spans` under a window)."""
+    lo, hi = seen
+    return int((hi.clip(lo) + 1 - lo // _SPAN_ALIGN * _SPAN_ALIGN).max())
+
+
+def _kv_need(window, block_q, tq, tk):
     import numpy as np
 
-    qi, ki = np.arange(num_q), np.arange(num_k)
-    kv = (
-        _last_kv_block(qi, block_q, block_k, q_offset)
-        - _first_kv_block(qi, block_q, block_k, q_offset, window, np) + 1
+    blocks = np.arange(tq // block_q)
+    return _span_need(_kv_range(blocks, block_q, tk - tq, window, np))
+
+
+def _q_need(window, block_k, tq, tk):
+    import numpy as np
+
+    blocks = np.arange(tk // block_k)
+    return _span_need(_q_range(blocks, block_k, tk - tq, window, tq, np))
+
+
+def _span_steps(window, block_q, block_k, tq, tk):
+    """``(kv steps a q block, q steps a kv block)``: the most spans of the
+    other side any block needs, from the static shapes. (Each is exact
+    where its own side's block fits: :func:`_spans_fit`.)"""
+    if window is None:
+        return tk // block_k, tq // block_q
+    return (
+        min(-(-_kv_need(window, block_q, tq, tk) // block_k), tk // block_k),
+        min(-(-_q_need(window, block_k, tq, tk) // block_q), tq // block_q),
     )
-    q = (
-        _last_q_block(ki, block_k, block_q, q_offset, window, num_q, np)
-        - _first_q_block(ki, block_k, block_q, q_offset, np) + 1
+
+
+def _flash2_maps(causal, window, block_q, block_k, tq, tk, group):
+    """``((kv steps, kv index map), (q steps, q index map))`` of the
+    grid-pipelined kernels: where the span of the other side that the
+    innermost grid step ``s`` of a block holds begins, as a block index
+    without a window and as an element offset under one."""
+    from jax.experimental import pallas as pl
+
+    num_q, num_k, off = tq // block_q, tk // block_k, tk - tq
+    if not causal:
+        return (
+            (num_k, lambda i, qi, s: (i // group, s, 0)),
+            (num_q, lambda i, ki, s: (i, s, 0)),
+        )
+    kv_steps, q_steps = _span_steps(window, block_q, block_k, tq, tk)
+
+    def held(seen, s, block, steps, total):
+        """Where step ``s`` holds its span: a block index without a window,
+        an element under one (with what divides it, which Mosaic has to be
+        told: the copy starts on a whole tile)."""
+        start, first, last = _spans(seen, block, steps, total, window)
+        begin = start + _lax.minimum(_lax.maximum(s, first), last) * block
+        if window is None:
+            return _lax.floor_divide(begin, block)
+        slack = total - steps * block
+        return pl.multiple_of(begin, math.gcd(_SPAN_ALIGN, block, slack))
+
+    def kv_map(i, qi, s):
+        seen = _kv_range(qi, block_q, off, window)
+        return (i // group, held(seen, s, block_k, kv_steps, tk), 0)
+
+    def q_map(i, ki, s):
+        seen = _q_range(ki, block_k, off, window, tq)
+        return (i, held(seen, s, block_q, q_steps, tq), 0)
+
+    return (kv_steps, kv_map), (q_steps, q_map)
+
+
+def _span_spec(block, width, index_map, window):
+    """BlockSpec of one span of ``block`` rows (or keys) of ``width``.
+    Under a window ``index_map`` gives the element a span starts at, and
+    Mosaic takes element offsets in every dimension or in none."""
+    from jax.experimental import pallas as pl
+
+    if window is None:
+        return pl.BlockSpec((1, block, width), index_map)
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(block), pl.Element(width)), index_map
     )
-    return int(kv.max()), int(q.max())
+
+
+def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
+    """Shares of the ``tq x tk`` score rectangle, by area, that a
+    grid-pipelined kernel with these blocks finds ``dead`` (never walked,
+    or stepped over: neither copied nor computed), ``interior`` and
+    ``edge`` (both computed under the mask; the keys an edge tile masks are
+    the waste). ``side``: ``"kv"`` for the forward and dq, which walk spans
+    of keys a q block, ``"q"`` for dk/dv."""
+    import numpy as np
+
+    if not causal:
+        return {"dead": 0.0, "interior": 1.0, "edge": 0.0}
+    off = tk - tq
+    kv_steps, q_steps = _span_steps(window, block_q, block_k, tq, tk)
+    if side == "kv":
+        block = np.arange(tq // block_q)[:, None]
+        seen = _kv_range(block, block_q, off, window, np)
+        start = _spans(seen, block_k, kv_steps, tk, window, np)[0]
+        q_lo = block * block_q + off
+        k_lo = start + np.arange(kv_steps)[None, :] * block_k
+    else:
+        block = np.arange(tk // block_k)[:, None]
+        seen = _q_range(block, block_k, off, window, tq, np)
+        start = _spans(seen, block_q, q_steps, tq, window, np)[0]
+        q_lo = start + np.arange(q_steps)[None, :] * block_q + off
+        k_lo = block * block_k
+    dead, interior = _tile_class(q_lo, block_q, k_lo, block_k, window)
+    tile = block_q * block_k / (tq * tk)
+    interior, edge = interior.sum() * tile, (~dead & ~interior).sum() * tile
+    return {
+        "dead": float(1.0 - interior - edge), "interior": float(interior),
+        "edge": float(edge),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side):
+    """One ``attn_tiles`` instant in the span ring for each shape a
+    grid-pipelined kernel is traced at: what the mask makes of its tiles."""
+    from edl_tpu.obs import trace as obs_trace
+
+    shares = tile_census(tq, tk, block_q, block_k, causal, window, side)
+    live = shares["interior"] + shares["edge"]
+    obs_trace.get_tracer().instant(
+        "attn_tiles", kernel=kernel, tq=tq, tk=tk, block_q=block_q,
+        block_k=block_k, window=window,
+        masked_share=shares["edge"] / live if live else 0.0, **shares,
+    )
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -276,7 +432,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot_nt(q, k_blk) * scale
         if causal:
-            s = _causal_mask(s, qi, q_block, j, block_k, q_offset)
+            s = _causal_mask(s, qi * q_block + q_offset, j * block_k)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -294,25 +450,66 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     lse_ref[0] = m + jnp.log(l)
 
 
+# The grid-pipelined forward's online-softmax state. ``m`` and ``l`` are
+# kept a lane tile wide (``_state_lanes``): ``m`` the row maximum in every
+# lane, ``l`` the sum of the keys that fell on each lane, summed across
+# lanes once, at the end. A row sum across lanes is a pass through the
+# cross-lane unit an update, and a ``[block_q, 1]`` array a relayout each
+# time it meets a tile: that forward was bound by its updates, about as
+# much an update as 700 keys whatever its width, not by its keys (PERF.md,
+# PR 32). The whole-KV forward, 128 rows a program, read no faster for it
+# and keeps its ``[block_q, 1]`` carry.
+
+
+def _state_lanes(block_k: int) -> int:
+    return math.gcd(128, block_k)
+
+
+def _softmax_update(s, m, l, acc, v):
+    """``(m, l, acc)`` after the masked scores ``s`` [rows, keys] of one
+    more tile over its values ``v`` [keys, d]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, d = m.shape[1], acc.shape[1]
+    tiles = s.shape[1] // lanes
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    corr = jnp.exp(m - m_new)
+    p = jnp.exp(s - pltpu.repeat(m_new, tiles, axis=1))
+    part = p[:, :lanes]
+    for t in range(1, tiles):
+        part = part + p[:, t * lanes:(t + 1) * lanes]
+    l = l * corr + part
+    if d != lanes:
+        corr = corr[:, :d] if d < lanes else corr[:, :1]
+    return m_new, l, acc * corr + _dot_nn(p.astype(v.dtype), v)
+
+
 def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                    acc_scr, *, causal: bool, scale: float, q_block: int,
                    block_k: int, num_k: int, q_offset: int,
-                   window: int | None = None):
+                   window: int | None = None, seq_k: int = 0):
     """Grid-pipelined forward: the KV loop lives in the GRID (innermost
     dimension), so Pallas double-buffers each KV block's HBM→VMEM copy
     behind the previous block's compute — where :func:`_flash_kernel`
     holds the WHOLE KV in VMEM and walks it with a serial ``fori_loop``
     (no copy/compute overlap, and a VMEM footprint that scales with the
     full sequence). Online-softmax state (m, l, acc) carries across the
-    innermost grid steps in VMEM scratch, initialized at j==0 and
-    finalized into (o, lse) at j==num_k-1. Under a ``window`` the grid's
-    ``num_k`` steps start at the q block's first visible kv block."""
+    innermost grid steps in VMEM scratch, initialized at step 0 and
+    finalized into (o, lse) at step num_k-1. Under a mask the ``num_k``
+    steps are the q block's spans of ``seq_k`` keys (:func:`_kv_range`, :func:`_spans`)."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    step = j = pl.program_id(2)
-    if window is not None:
-        j = step + _first_kv_block(qi, q_block, block_k, q_offset, window)
+    step = pl.program_id(2)
+    q_lo = qi * q_block + q_offset
+    k_lo = step * block_k
+    live = True
+    if causal:
+        seen = _kv_range(qi, q_block, q_offset, window)
+        k_lo += _spans(seen, block_k, num_k, seq_k, window)[0]
+        # a dead tile (past the diagonal, before the window) skips the
+        # FLOPs; its step held a live span again, so nothing was copied
+        live = ~_tile_class(q_lo, q_block, k_lo, block_k, window)[0]
 
     @pl.when(step == 0)
     def _init():
@@ -320,34 +517,21 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # fully-masked (q_block, k_block) tiles skip the FLOPs (their DMA
-    # already happened; the win of the in-kernel loop's block skipping is
-    # traded for pipelining)
-    live = True
-    if causal:
-        live = j * block_k <= (qi + 1) * q_block + q_offset - 1
-
     @pl.when(live)
     def _update():
-        q = q_ref[0]
-        k = k_ref[0]
         v = v_ref[0]
-        s = _dot_nt(q, k) * scale
+        s = _dot_nt(q_ref[0], k_ref[0]) * scale
         if causal:
-            s = _causal_mask(s, qi, q_block, j, block_k, q_offset, window)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + _dot_nn(p.astype(v.dtype), v)
+            s = _causal_mask(s, q_lo, k_lo, window)
+        m_scr[:], l_scr[:], acc_scr[:] = _softmax_update(
+            s, m_scr[:], l_scr[:], acc_scr[:], v
+        )
 
     @pl.when(step == num_k - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:], 1e-30)
+        l = jnp.maximum(jnp.sum(l_scr[:], axis=-1, keepdims=True), 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l)  # [bq, 1] (see _flash_kernel)
+        lse_ref[0] = m_scr[:, :1] + jnp.log(l)  # [bq, 1] (see _flash_kernel)
 
 
 def _grid_pipeline_kwargs() -> dict:
@@ -384,9 +568,10 @@ def _flash2_forward(
 
     b, h, tq, d = q.shape
     tk = k.shape[2]
-    block_q = _fit_block(block_q, tq)
-    block_k = _fit_block(block_k, tk)
-    if tq % block_q or tk % block_k or (causal and tq > tk):
+    block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
+    if not _spans_fit(block_q, block_k, tq, tk, window, "kv") or (
+        causal and tq > tk
+    ):
         return attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
         ), None
@@ -395,18 +580,11 @@ def _flash2_forward(
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * (h // g), tk, d)
     vf = v.reshape(b * (h // g), tk, d)
-    num_k = tk // block_k
-    kv_map = lambda i, qi, j, g=g: (i // g, j, 0)  # noqa: E731
-    if window is not None:
-        last, off = num_k - 1, tk - tq
-        num_k, _ = _window_steps(
-            window, block_q, block_k, tq // block_q, num_k, off
-        )
-
-        def kv_map(i, qi, j, g=g):
-            first = _first_kv_block(qi, block_q, block_k, off, window)
-            return (i // g, jnp.minimum(first + j, last), 0)
-
+    (num_k, kv_map), _ = _flash2_maps(
+        causal, window, block_q, block_k, tq, tk, g
+    )
+    _note_tiles("flash2_fwd", tq, tk, block_q, block_k, causal, window, "kv")
+    kv_spec = _span_spec(block_k, d, kv_map, window)
     grid = (b * h, tq // block_q, num_k)
     kwargs = _grid_pipeline_kwargs()
     out, lse = pl.pallas_call(
@@ -419,6 +597,7 @@ def _flash2_forward(
             num_k=num_k,
             q_offset=tk - tq,
             window=window,
+            seq_k=tk,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
@@ -427,16 +606,16 @@ def _flash2_forward(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
+            pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
@@ -470,7 +649,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot_nt(q, k_blk) * scale
         if causal:
-            s = _causal_mask(s, qi, q_block, j, block_k, q_offset)
+            s = _causal_mask(s, qi * q_block + q_offset, j * block_k)
         p = jnp.exp(s - lse)                            # [bq, bk]
         dp = _dot_nt(do, v_blk)
         ds = p * (dp - delta)
@@ -508,7 +687,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, pl.ds(j * block_q, block_q)]
         s = _dot_nt(q_blk, k_blk) * scale
         if causal:
-            s = _causal_mask(s, j, block_q, ki, k_block, q_offset)
+            s = _causal_mask(s, j * block_q + q_offset, ki * k_block)
         p = jnp.exp(s - lse)                            # [bq, bk]
         dv = dv + _dot_tn(p.astype(do.dtype), do)
         dp = _dot_nt(do, v_blk)
@@ -529,25 +708,27 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dq_ref, dq_scr, *, causal: bool, scale: float,
                           q_block: int, block_k: int, num_k: int,
-                          q_offset: int, window: int | None = None):
+                          q_offset: int, window: int | None = None,
+                          seq_k: int = 0):
     """Grid-pipelined dq: KV blocks ride the innermost grid dimension
     (double-buffered DMA), dq accumulates in VMEM scratch across steps —
-    the backward twin of :func:`_flash2_kernel`'s structure, ``window``
-    included."""
+    the backward twin of :func:`_flash2_kernel`'s structure, the spans
+    under a mask included."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    step = j = pl.program_id(2)
-    if window is not None:
-        j = step + _first_kv_block(qi, q_block, block_k, q_offset, window)
+    step = pl.program_id(2)
+    q_lo = qi * q_block + q_offset
+    k_lo = step * block_k
+    live = True
+    if causal:
+        seen = _kv_range(qi, q_block, q_offset, window)
+        k_lo += _spans(seen, block_k, num_k, seq_k, window)[0]
+        live = ~_tile_class(q_lo, q_block, k_lo, block_k, window)[0]
 
     @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    live = True
-    if causal:
-        live = j * block_k <= (qi + 1) * q_block + q_offset - 1
 
     @pl.when(live)
     def _update():
@@ -559,7 +740,7 @@ def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, qi, q_block, j, block_k, q_offset, window)
+            s = _causal_mask(s, q_lo, k_lo, window)
         p = jnp.exp(s - lse)
         dp = _dot_nt(do, v)
         ds = p * (dp - delta)
@@ -574,31 +755,29 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                            scale: float, block_q: int, k_block: int,
                            num_q: int, q_offset: int,
-                           window: int | None = None, total_q: int = 0):
+                           window: int | None = None, seq_q: int = 0):
     """Grid-pipelined dk/dv: Q/dO/lse/delta blocks ride the innermost
     grid dimension, dk/dv accumulate in scratch per KV block. Under a
-    ``window`` the ``num_q`` steps start at the first q block that sees
-    this kv block and are live up to the last one that does (of the
-    sequence's ``total_q``)."""
+    mask the ``num_q`` steps are the kv block's spans of the ``seq_q``
+    rows (:func:`_q_range`, :func:`_spans`): from the first row that sees it."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    step = j = pl.program_id(2)
+    step = pl.program_id(2)
+    k_lo = ki * k_block
+    q_lo = step * block_q + q_offset
+    live = True
+    if causal:
+        seen = _q_range(ki, k_block, q_offset, window, seq_q)
+        q_lo += _spans(seen, block_q, num_q, seq_q, window)[0]
+        # q rows entirely before this kv block's first column, or past the
+        # window of its last, are dead
+        live = ~_tile_class(q_lo, block_q, k_lo, k_block, window)[0]
 
     @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    live = True
-    if window is not None:
-        j = step + _first_q_block(ki, k_block, block_q, q_offset)
-        live = j <= _last_q_block(
-            ki, k_block, block_q, q_offset, window, total_q
-        )
-    elif causal:
-        # q blocks entirely before this kv block's first column are dead
-        live = j >= jnp.maximum(0, (ki * k_block - q_offset) // block_q)
 
     @pl.when(live)
     def _update():
@@ -610,7 +789,7 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, j, block_q, ki, k_block, q_offset, window)
+            s = _causal_mask(s, q_lo, k_lo, window)
         p = jnp.exp(s - lse)
         dv_scr[:] = dv_scr[:] + _dot_tn(p.astype(do.dtype), do)
         dp = _dot_nt(do, v)
@@ -628,6 +807,7 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash2_backward(
     q, k, v, o, lse, g, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
+    dkv_blocks: tuple[int, int] | None = None,
 ):
     """(dq, dk, dv) via the grid-pipelined backward kernels;
     ``lse`` in kernel layout [B*H, Tq] like :func:`_flash_backward`."""
@@ -635,18 +815,19 @@ def _flash2_backward(
     delta = _bwd_delta(g, o, b, h, tq, d)
     return _flash2_backward_kernels(
         q, k, v, g, lse, delta, causal, scale, block_q, block_k, interpret,
-        window,
+        window, dkv_blocks,
     )
 
 
 def _flash2_backward_kernels(
     q, k, v, g, lse, delta, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
+    dkv_blocks: tuple[int, int] | None = None,
 ):
     """The two grid-pipelined backward pallas calls; ``lse``/``delta``
     are [B*H, Tq] (external residuals welcome — ring attention's
     per-rotation block grads route here past the whole-KV compile
-    limit)."""
+    limit). ``dkv_blocks``: the dk/dv kernel's own, where they differ."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -654,8 +835,10 @@ def _flash2_backward_kernels(
     tk = k.shape[2]
     grp = _gqa_group(q, k)
     h_kv = h // grp
-    block_q = _fit_block(block_q, tq)
-    block_k = _fit_block(block_k, tk)
+    kv_q, kv_k = _fit_blocks(
+        *(dkv_blocks or (block_q, block_k)), tq, tk, window, "q"
+    )
+    block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
 
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * h_kv, tk, d)
@@ -664,38 +847,27 @@ def _flash2_backward_kernels(
     # pallas layout: trailing singleton keeps the block sublane 8-aligned
     lse3 = lse[..., None]
     delta3 = delta[..., None]
-    num_k = tk // block_k
-    num_q = tq // block_q
     kwargs = _grid_pipeline_kwargs()
-    off = tk - tq
-    common = dict(causal=causal, scale=scale, q_offset=off, window=window)
-    kv_steps, q_steps = num_k, num_q
-    kv_map = lambda i, qi, j, g=grp: (i // g, j, 0)  # noqa: E731
-    q_map = lambda i, ki, j: (i, j, 0)  # noqa: E731
-    if window is not None:
-        kv_steps, q_steps = _window_steps(
-            window, block_q, block_k, num_q, num_k, off
-        )
-
-        def kv_map(i, qi, j, g=grp):
-            first = _first_kv_block(qi, block_q, block_k, off, window)
-            return (i // g, jnp.minimum(first + j, num_k - 1), 0)
-
-        def q_map(i, ki, j):
-            first = _first_q_block(ki, block_k, block_q, off)
-            return (i, jnp.minimum(first + j, num_q - 1), 0)
-
+    common = dict(causal=causal, scale=scale, q_offset=tk - tq, window=window)
+    (kv_steps, kv_map), _ = _flash2_maps(
+        causal, window, block_q, block_k, tq, tk, grp
+    )
+    _, (q_steps, q_map) = _flash2_maps(causal, window, kv_q, kv_k, tq, tk, grp)
+    _note_tiles("flash2_dq", tq, tk, block_q, block_k, causal, window, "kv")
+    _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q")
+    kv_spec = _span_spec(block_k, d, kv_map, window)
     dq = pl.pallas_call(
         functools.partial(
             _flash2_bwd_dq_kernel,
-            q_block=block_q, block_k=block_k, num_k=kv_steps, **common,
+            q_block=block_q, block_k=block_k, num_k=kv_steps, seq_k=tk,
+            **common,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-        grid=(b * h, num_q, kv_steps),
+        grid=(b * h, tq // block_q, kv_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            kv_spec,
+            kv_spec,
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
@@ -711,33 +883,28 @@ def _flash2_backward_kernels(
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash2_bwd_dkv_kernel,
-            block_q=block_q, k_block=block_k, num_q=q_steps,
-            total_q=num_q, **common,
+            block_q=kv_q, k_block=kv_k, num_q=q_steps, seq_q=tq, **common,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
         ],
-        grid=(b * h, num_k, q_steps),
+        grid=(b * h, tk // kv_k, q_steps),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec(
-                (1, block_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)
-            ),
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
+            _span_spec(kv_q, d, q_map, window),
+            pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)),
+            pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)),
+            _span_spec(kv_q, d, q_map, window),
+            _span_spec(kv_q, 1, q_map, window),
+            _span_spec(kv_q, 1, q_map, window),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, ki, j: (i, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, ki, j: (i, ki, 0)),
+            pl.BlockSpec((1, kv_k, d), lambda i, ki, j: (i, ki, 0)),
+            pl.BlockSpec((1, kv_k, d), lambda i, ki, j: (i, ki, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((kv_k, d), jnp.float32),
+            pltpu.VMEM((kv_k, d), jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
@@ -780,6 +947,73 @@ def _kernel_blocks(tq: int):
 # (128, 512) flash defaults left 2.4x fwd / 2.6x fwd+bwd on the table.
 _FLASH2_BLOCKS_FWD = (256, 1024)
 _FLASH2_BLOCKS_BWD = (512, 1024)
+
+
+def _spans_fit(block_q, block_k, tq, tk, window, side):
+    """Whether a grid-pipelined kernel can tile the shapes with these
+    blocks. Each block has to divide its side, except under a window the
+    block of the side the kernel walks in spans (``side``: ``"kv"`` keys,
+    ``"q"`` rows): spans start at elements, so whole sublanes will do, as
+    long as the spans a block needs fit into the side."""
+    q_divides, k_divides = tq % block_q == 0, tk % block_k == 0
+    if window is None or (q_divides and k_divides):
+        return q_divides and k_divides
+    if side == "kv" and q_divides:
+        block, need, total = block_k, _kv_need(window, block_q, tq, tk), tk
+    elif side == "q" and k_divides:
+        block, need, total = block_q, _q_need(window, block_k, tq, tk), tq
+    else:
+        return False
+    return block % 8 == 0 and -(-need // block) * block <= total
+
+
+def _fit_blocks(block_q, block_k, tq, tk, window, side):
+    """``(block_q, block_k)`` fitted to the shapes: as given where
+    :func:`_spans_fit` takes them, else through :func:`_fit_block`."""
+    bq, bk = _fit_block(block_q, tq), _fit_block(block_k, tk)
+    if side == "kv" and _spans_fit(bq, block_k, tq, tk, window, side):
+        return bq, block_k
+    if side == "q" and _spans_fit(block_q, bk, tq, tk, window, side):
+        return block_q, bk
+    return bq, bk
+
+
+# A windowed call's blocks, from the window and the shapes (v5e sweep at
+# GQA 32:4 x 128, T = 8192, window 2048: bench_results/README.md). The
+# side a kernel does not walk takes the sweep's block; the walked side is
+# cut into the fewest equal spans no longer than the sweep's that cover
+# what such a block needs (its own rows or keys and the window), in whole
+# lane tiles: the forward there takes one update of 2560 keys a 512 rows,
+# dq one of 2304 keys a 256 rows, dk/dv two of 1280 rows a 512 keys. An
+# online-softmax update costs the forward about as much as 700 keys do,
+# whatever its width, so the forward wants few; dk/dv read faster in two
+# spans than in one.
+_WINDOW_BLOCKS = {"fwd": (512, 2560), "dq": (256, 2560), "dkv": (1280, 512)}
+
+
+def _flash2_blocks(kind, tq, tk, window, given=None):
+    """``(block_q, block_k)`` for the grid-pipelined kernel ``kind``
+    (``"fwd"``, ``"dq"``, ``"dkv"``), fitted to the shapes: what the
+    caller ``given`` (a pair, either of it ``None``) wins, then a windowed
+    call's blocks from the window and the shapes, else the full-causal
+    sweep's."""
+    side = "q" if kind == "dkv" else "kv"
+    bq, bk = _FLASH2_BLOCKS_FWD if kind == "fwd" else _FLASH2_BLOCKS_BWD
+    if window is not None:
+        wq, wk = _WINDOW_BLOCKS[kind]
+        if kind == "dkv":
+            wk = _fit_block(wk, tk)
+            need, longest = _q_need(window, wk, tq, tk), wq
+        else:
+            wq = _fit_block(wq, tq)
+            need, longest = _kv_need(window, wq, tq, tk), wk
+        span = -(-need // -(-need // longest))   # fewest equal steps
+        span = -(-span // 128) * 128             # in whole lane tiles
+        wq, wk = (span, wk) if kind == "dkv" else (wq, span)
+        if _spans_fit(wq, wk, tq, tk, window, side):
+            bq, bk = wq, wk
+    given = given or (None, None)
+    return _fit_blocks(given[0] or bq, given[1] or bk, tq, tk, window, side)
 
 
 def _fit_block(block: int, t: int) -> int:
@@ -1100,19 +1334,12 @@ def flash_attention(
         scale = q.shape[-1] ** -0.5
     if window is not None or max(q.shape[2], k.shape[2]) > _flash_max_seq():
         # whole-KV kernel does not compile past this length: serve the
-        # same contract through the grid-pipelined kernels, filling any
-        # unspecified block from flash2's own measured defaults
-        fwd_blocks = (
-            block_q or _FLASH2_BLOCKS_FWD[0],
-            block_k or _FLASH2_BLOCKS_FWD[1],
-        )
-        bwd_blocks = (
-            block_q or _FLASH2_BLOCKS_BWD[0],
-            block_k or _FLASH2_BLOCKS_BWD[1],
-        )
+        # same contract through the grid-pipelined kernels, which fill any
+        # unspecified block from their own measured defaults
+        # (_flash2_blocks)
+        blocks = (block_q, block_k)
         return _auto(
-            q, k, v, causal, scale, "flash2", "flash2",
-            fwd_blocks, bwd_blocks, window,
+            q, k, v, causal, scale, "flash2", "flash2", blocks, blocks, window
         )
     if block_q is None or block_k is None:
         (fbq, fbk), _ = _kernel_blocks(q.shape[2])
@@ -1308,7 +1535,9 @@ def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
         # residuals (both are the logsumexp of the same scaled scores)
         lse = lse.reshape(b * h, tq)
     elif fwd_impl == "flash2":
-        f2q, f2k = fwd_blocks or _FLASH2_BLOCKS_FWD
+        f2q, f2k = _flash2_blocks(
+            "fwd", q.shape[2], k.shape[2], window, fwd_blocks
+        )
         out, lse = _flash2_forward(
             q, k, v, causal, scale, f2q, f2k, _interpret(), window
         )
@@ -1342,21 +1571,24 @@ def _name_residuals(q, k, v, out, lse):
 def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
               window, residuals, g):
     q, k, v, o, lse = residuals
-    if bwd_impl in ("flash", "flash2") and lse is not None:
-        tq, tk = q.shape[2], k.shape[2]
-        # separate sweeps: _BLOCK_TABLE is the whole-KV kernel's,
-        # _FLASH2_BLOCKS_BWD the grid-pipelined one's
-        bbq, bbk = bwd_blocks or (
-            _FLASH2_BLOCKS_BWD if bwd_impl == "flash2"
-            else _kernel_blocks(tq)[1]
-        )
+    tq, tk = q.shape[2], k.shape[2]
+    kernels = lse is not None and not (causal and tq > tk)
+    # separate sweeps: _BLOCK_TABLE is the whole-KV kernels',
+    # _flash2_blocks the grid-pipelined ones'
+    if kernels and bwd_impl == "flash2":
+        dq_blocks = _flash2_blocks("dq", tq, tk, window, bwd_blocks)
+        dkv_blocks = _flash2_blocks("dkv", tq, tk, window, bwd_blocks)
+        if _spans_fit(*dq_blocks, tq, tk, window, "kv") and _spans_fit(
+            *dkv_blocks, tq, tk, window, "q"
+        ):
+            return _flash2_backward(
+                q, k, v, o, lse, g, causal, scale, *dq_blocks, _interpret(),
+                window, dkv_blocks,
+            )
+    if kernels and bwd_impl == "flash":
+        bbq, bbk = bwd_blocks or _kernel_blocks(tq)[1]
         bq, bk = _fit_block(bbq, tq), _fit_block(bbk, tk)
-        if not (tq % bq or tk % bk or (causal and tq > tk)):
-            if bwd_impl == "flash2":
-                return _flash2_backward(
-                    q, k, v, o, lse, g, causal, scale, bq, bk, _interpret(),
-                    window,
-                )
+        if not (tq % bq or tk % bk):
             return _flash_backward(
                 q, k, v, o, lse, g, causal, scale, bq, bk, _interpret()
             )

@@ -7,6 +7,7 @@ import contextlib
 import glob
 import os
 import queue
+import re
 import subprocess
 import sys
 import threading
@@ -352,7 +353,12 @@ def test_the_step_is_bit_equal_with_and_without_the_scopes(monkeypatch):
 
     monkeypatch.setattr(jax, "named_scope", no_scope)
     without = make_train_step(mse_loss, numerics=True, donate=False)
-    assert "forward" not in without.lower(state, batch).as_text(debug_info=True)
+    # the scope as a path component ("jit(step)/jvp(forward)/..."), not the
+    # bare word: jax caches a jitted jnp helper's jaxpr with its first
+    # caller's source names, and another test file's ("_flash_forward")
+    # ride into this text when both run in one worker
+    lowered = without.lower(state, batch).as_text(debug_info=True)
+    assert not re.search(r"[/(]forward[/)]", lowered)
     new_b, metrics_b = without(state, batch)
     for a, b in zip(jax.tree.leaves((new_a, metrics_a)),
                     jax.tree.leaves((new_b, metrics_b))):

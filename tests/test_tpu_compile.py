@@ -66,6 +66,9 @@ AT_MAX = (1, 16, 16, A._flash_max_seq(), 64)
 PAST_MAX = (1, 16, 16, A._flash_max_seq() + 512, 64)
 LONG = (1, 16, 16, 8192, 64)       # chip_smoke's flash2 comparison shape
 GRANITE = (1, 32, 8, 8192, 64)     # granite_4_0_h_micro.steady's attention layer
+TRINITY = (1, 32, 4, 8192, 128)    # trinity_mini.steady's attention layers
+MISTRAL = (2, 32, 8, 4096, 128)    # mistral_7b.steady's, a half batch a call
+OLMOE = (4, 16, 16, 4096, 128)     # olmoe_1b_7b.steady's
 
 FWD_NAME = {"flash": "_flash_kernel", "flash2": "_flash2_kernel"}
 BWD_NAMES = {
@@ -86,6 +89,12 @@ CASES = [
         ("past_max_seq", PAST_MAX, ("flash",)),
         ("seq8192", LONG, ("flash2",)),
         ("granite", GRANITE, ("flash2",)),
+        # the other three LM cells' shapes, as their steps call the kernels
+        ("trinity_full", TRINITY, ("flash2",)),
+        # (the packaged dispatch gives both a flash2 forward at T = 4096 and
+        # the whole-KV backward)
+        ("mistral", MISTRAL, ("flash", "flash2")),
+        ("olmoe", OLMOE, ("flash", "flash2")),
     )
     for family in families
     for direction in ("fwd", "bwd")
@@ -132,38 +141,40 @@ def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
     assert compiled.as_text().count("tpu_custom_call") == len(want)
 
 
-TRINITY = (1, 32, 4, 8192, 128)    # trinity_mini.steady's attention layers
-
-
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("window", [2048, 1000], ids=["w2048", "w1000"])
 def test_windowed_kernel_compiles_for_v5e(one_chip, direction, window):
     """The flash2 kernels under a window at Trinity-Mini's shape (GQA 32:4 x
-    128, T = 8192, the published 2048 and a window no block divides): index
-    maps that start at a block's first visible block and clamp at the last
-    are Mosaic's to accept, not interpret mode's. The grid's innermost
-    dimension is the steps a block can see, not every block."""
+    128, T = 8192, the published 2048 and a window nothing divides), with the
+    blocks the dispatch gives a windowed call: spans that start at an element
+    (``pl.Element`` in every dimension, an offset Mosaic is told is a whole
+    tile's multiple) are Mosaic's to accept, not interpret mode's. The grid's
+    innermost dimension is the spans a block needs, not every block: one
+    forward update a q block at the published window."""
     b, h, h_kv, t, d = TRINITY
 
     def sds(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    fwd, dq, dkv = (A._flash2_blocks(kind, t, t, window) for kind in ("fwd", "dq", "dkv"))
+    if window == 2048:
+        assert (fwd, dq, dkv) == ((512, 2560), (256, 2304), (1280, 512))
+        assert A._span_steps(window, *fwd, t, t)[0] == 1
     if direction == "fwd":
-        bq, bk = A._FLASH2_BLOCKS_FWD
         fn = lambda q, k, v: A._flash2_forward(
-            q, k, v, True, d ** -0.5, bq, bk, False, window
+            q, k, v, True, d ** -0.5, *fwd, False, window
         )
         args, want = (q, kv, kv), [FWD_NAME["flash2"]]
     else:
-        bq, bk = A._FLASH2_BLOCKS_BWD
         fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
-            q, k, v, g, lse, delta, True, d ** -0.5, bq, bk, False, window
+            q, k, v, g, lse, delta, True, d ** -0.5, *dq, False, window, dkv
         )
         row = sds((b * h, t), jnp.float32)
         args, want = (q, kv, kv, q, row, row), list(BWD_NAMES["flash2"])
-    kv_steps, q_steps = A._window_steps(window, bq, bk, t // bq, t // bk, 0)
-    assert kv_steps < t // bk and q_steps < t // bq
+    kv_steps, _ = A._span_steps(window, *dq, t, t)
+    _, q_steps = A._span_steps(window, *dkv, t, t)
+    assert kv_steps * dq[1] < t / 2 and q_steps * dkv[0] < t / 2
     lowered = jax.jit(fn).lower(*args)
     assert _kernel_names(lowered.as_text()) == want
     compiled = lowered.compile()

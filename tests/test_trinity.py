@@ -415,5 +415,5 @@ def test_a_windowed_call_never_reaches_the_whole_kv_kernels():
     with pytest.raises(ValueError, match="causal"):
         A.attention(q, q, q, causal=False, window=8)
     # fewer steps than blocks: what lies outside the window is not walked
-    assert A._window_steps(2048, 256, 1024, 32, 8, 0) == (3, 12)
-    assert A._window_steps(2048, 512, 1024, 16, 8, 0) == (3, 6)
+    assert A._span_steps(2048, 256, 1024, 8192, 8192) == (3, 12)
+    assert A._span_steps(2048, 512, 1024, 8192, 8192) == (3, 6)
