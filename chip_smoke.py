@@ -98,13 +98,12 @@ def emit(**doc):
 # -- parent-side plumbing -----------------------------------------------------
 
 
-def _child_env(extra=None):
+def _child_env():
     """The caller's environment, minus the test rigs' device-count pin: the
     smoke takes the defaults a user on a TPU host gets."""
     env = dict(os.environ)
     env.pop("EDL_DEVICES_PER_PROC", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.update(extra or {})
     return env
 
 
@@ -179,7 +178,6 @@ def _check_worker_log(text):
     bad = _DEGRADED.findall(text)
     if bad:
         raise PhaseFailed("trainer degraded: %r" % bad[:3])
-    return re.findall(r"cache miss compiled: (\S+)", text)
 
 
 def _role_cmd(role, cfg, report, *extra):
@@ -202,10 +200,7 @@ def _launch(cfg, tag, role, nodes_range, timeout, *role_args):
         *_role_cmd(role, cfg, report, *role_args),
     ]
     launcher_log = os.path.join(log_dir, "launcher.log")
-    code, seconds = _run(
-        cmd, launcher_log, timeout,
-        _child_env({"EDL_CACHE_EVENTS_DEBUG": "1"}),
-    )
+    code, seconds = _run(cmd, launcher_log, timeout, _child_env())
     worker_log = os.path.join(log_dir, "workerlog.0")
     rep = _load_report(report, code, launcher_log, worker_log)
     spawns = _read(launcher_log).count("spawned worker")
@@ -236,7 +231,8 @@ def phase_train(cfg, left):
         cfg, "train-cold", "train", "1:1", min(480, left()),
         "--epochs", str(c["epochs"]), "--ckpt", ckpt,
     )
-    misses = _check_worker_log(log)
+    _check_worker_log(log)
+    misses = rep.pop("missed_modules")
     if rep["cache"]["hit"] + rep["cache"]["miss"] == 0:
         raise PhaseFailed("cache counters are all zero: not instrumented")
     if rep["step"] != c["epochs"] * c["steps"]:
@@ -249,7 +245,8 @@ def phase_train(cfg, left):
         cfg, "train-resumed", "train", "1:1", min(300, left()),
         "--epochs", str(c["epochs"] + 1), "--ckpt", ckpt,
     )
-    misses = _check_worker_log(log)
+    _check_worker_log(log)
+    misses = rep.pop("missed_modules")
     if "resumed at epoch %d" % c["epochs"] not in log:
         raise PhaseFailed("the second launch did not resume at epoch %d"
                           % c["epochs"])
@@ -275,7 +272,8 @@ def phase_ladder(cfg, left):
         cfg, "train-ladder", "train", "1:2", min(300, left()),
         "--epochs", str(c["epochs"] + 2), "--ckpt", _ckpt_dir(), "--ladder",
     )
-    misses = _check_worker_log(log)
+    _check_worker_log(log)
+    misses = rep.pop("missed_modules")
     if rep["step"] != (c["epochs"] + 2) * c["steps"]:
         raise PhaseFailed("state.step %d after the ladder launch" % rep["step"])
     if not any(rep["ladder"].values()):
@@ -506,6 +504,7 @@ def role_train(cfg, args):
         "losses": [round(v, 4) for v in losses],
         "step": int(state.step),
         "cache": aot.cache_event_counts(),
+        "missed_modules": aot.missed_modules(),
         "cache_dir": jax.config.jax_compilation_cache_dir,
         "ladder": _ladder(),
         "peak_flops": {"device_kind": kind, "flops": peak},

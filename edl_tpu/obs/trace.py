@@ -389,6 +389,17 @@ class SpanTracer:
         with self._lock:
             self._events.append(ev)
 
+    def record_wall(
+        self, name: str, start_wall: float, end_wall: float, **args
+    ) -> None:
+        """Record a span somebody else timed on the wall clock (jax's own
+        compile events carry ``time.time()`` at both ends): mapped through
+        the tracer's anchor, so it lands where ``span()`` would have put it."""
+        self.record(
+            name, self._anchor_mono + (start_wall - self._anchor_wall),
+            end_wall - start_wall, **args,
+        )
+
     def instant(self, name: str, ts_wall: Optional[float] = None, **args) -> None:
         """Zero-duration marker (drain triggered, stage published, ...).
 

@@ -46,6 +46,8 @@ import os
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.obs import trace as obs_trace
+
 NEG_INF = -1e30
 
 
@@ -393,8 +395,6 @@ def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
 def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side):
     """One ``attn_tiles`` instant in the span ring for each shape a
     grid-pipelined kernel is traced at: what the mask makes of its tiles."""
-    from edl_tpu.obs import trace as obs_trace
-
     shares = tile_census(tq, tk, block_q, block_k, causal, window, side)
     live = shares["interior"] + shares["edge"]
     obs_trace.get_tracer().instant(
@@ -587,7 +587,7 @@ def _flash2_forward(
     kv_spec = _span_spec(block_k, d, kv_map, window)
     grid = (b * h, tq // block_q, num_k)
     kwargs = _grid_pipeline_kwargs()
-    out, lse = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _flash2_kernel,
             causal=causal,
@@ -620,7 +620,9 @@ def _flash2_forward(
         ],
         interpret=interpret,
         **kwargs,
-    )(qf, kf, vf)
+    )
+    with obs_trace.span("kernel_trace", kernel="flash2_fwd"):
+        out, lse = kernel(qf, kf, vf)
     return out.reshape(b, h, tq, d), lse[..., 0]
 
 
@@ -856,7 +858,7 @@ def _flash2_backward_kernels(
     _note_tiles("flash2_dq", tq, tk, block_q, block_k, causal, window, "kv")
     _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q")
     kv_spec = _span_spec(block_k, d, kv_map, window)
-    dq = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _flash2_bwd_dq_kernel,
             q_block=block_q, block_k=block_k, num_k=kv_steps, seq_k=tk,
@@ -876,11 +878,13 @@ def _flash2_backward_kernels(
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(qf, kf, vf, gf, lse3, delta3)
+    )
+    with obs_trace.span("kernel_trace", kernel="flash2_dq"):
+        dq = kernel(qf, kf, vf, gf, lse3, delta3)
 
     # dk/dv at full q-head width, folded to the grouped width outside
     # (see _flash_backward_kernels)
-    dk, dv = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _flash2_bwd_dkv_kernel,
             block_q=kv_q, k_block=kv_k, num_q=q_steps, seq_q=tq, **common,
@@ -908,7 +912,9 @@ def _flash2_backward_kernels(
         ],
         interpret=interpret,
         **kwargs,
-    )(qf, kf, vf, gf, lse3, delta3)
+    )
+    with obs_trace.span("kernel_trace", kernel="flash2_dkv"):
+        dk, dv = kernel(qf, kf, vf, gf, lse3, delta3)
 
     dk, dv = _fold_dkv(
         dk.reshape(b, h, tk, d), dv.reshape(b, h, tk, d),
@@ -1050,7 +1056,7 @@ def _flash_forward(
     kf = k.reshape(b * (h // g), tk, d)
     vf = v.reshape(b * (h // g), tk, d)
     grid = (b * h, tq // block_q)
-    out, lse = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _flash_kernel,
             block_k=block_k,
@@ -1077,7 +1083,9 @@ def _flash_forward(
             pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
         ],
         interpret=interpret,
-    )(qf, kf, vf)
+    )
+    with obs_trace.span("kernel_trace", kernel="flash_fwd"):
+        out, lse = kernel(qf, kf, vf)
     return out.reshape(b, h, tq, d), lse[..., 0]
 
 
@@ -1180,7 +1188,7 @@ def _flash_backward_kernels(
     delta3 = delta[..., None]
 
     common = dict(causal=causal, scale=scale, q_offset=tk - tq)
-    dq = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel,
             block_k=block_k, q_block=block_q, seq_k=tk, **common,
@@ -1197,12 +1205,14 @@ def _flash_backward_kernels(
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         interpret=interpret,
-    )(qf, kf, vf, gf, lse3, delta3)
+    )
+    with obs_trace.span("kernel_trace", kernel="flash_dq"):
+        dq = kernel(qf, kf, vf, gf, lse3, delta3)
 
     # dk/dv come out at FULL q-head width (each program owns one q head's
     # contribution) and fold to the grouped width outside — the kernels
     # still never read a repeated K/V
-    dk, dv = pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel,
             block_q=block_q, k_block=block_k, seq_q=tq, **common,
@@ -1225,7 +1235,9 @@ def _flash_backward_kernels(
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
         ],
         interpret=interpret,
-    )(qf, kf, vf, gf, lse3, delta3)
+    )
+    with obs_trace.span("kernel_trace", kernel="flash_dkv"):
+        dk, dv = kernel(qf, kf, vf, gf, lse3, delta3)
 
     dk, dv = _fold_dkv(
         dk.reshape(b, h, tk, d), dv.reshape(b, h, tk, d),

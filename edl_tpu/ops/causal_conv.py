@@ -47,6 +47,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.obs import trace as obs_trace
+
 _HALO = 128             # steps of a halo view: one lane tile
 _BLOCK_T = 8192         # steps of a tile, at most: the wider, the less a channel's
                         # fixed work (its taps spread over the lanes, its halo) weighs
@@ -241,7 +243,7 @@ def _forward(xt, w, b, offset, blocks, interpret):
     batch, _, t = xt.shape
     c, taps = w.shape
     first_c, per_t = offset // block_c, block_t // _HALO
-    return pl.pallas_call(
+    kernel = pl.pallas_call(
         causal_conv_fwd,
         name="causal_conv_fwd",
         out_shape=jax.ShapeDtypeStruct((batch, c, t), xt.dtype),
@@ -259,7 +261,9 @@ def _forward(xt, w, b, offset, blocks, interpret):
         scratch_shapes=[pltpu.VMEM((_ROWS, _HALO + block_t), jnp.float32)],
         compiler_params=_independent_tiles(),
         interpret=interpret,
-    )(xt, xt, w, b)
+    )
+    with obs_trace.span("kernel_trace", kernel="causal_conv_fwd"):
+        return kernel(xt, xt, w, b)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6))
@@ -275,7 +279,7 @@ def _backward(xt, w, b, dy, offset, blocks, interpret):
     num_t = t // block_t
     before = lambda i: jnp.maximum(i * per_t - 1, 0)  # noqa: E731
     after = lambda i: jnp.minimum((i + 1) * per_t, t // _HALO - 1)  # noqa: E731
-    dx, sums = pl.pallas_call(
+    kernel = pl.pallas_call(
         causal_conv_bwd,
         name="causal_conv_bwd",
         out_shape=[
@@ -302,7 +306,9 @@ def _backward(xt, w, b, dy, offset, blocks, interpret):
         ],
         compiler_params=_independent_tiles(),
         interpret=interpret,
-    )(xt, xt, xt, dy, dy, w, b)
+    )
+    with obs_trace.span("kernel_trace", kernel="causal_conv_bwd"):
+        dx, sums = kernel(xt, xt, xt, dy, dy, w, b)
     return dx, sums.sum(axis=(0, 1))
 
 

@@ -30,6 +30,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.obs import trace as obs_trace
+
 # (rows, contracting, columns) tiles of the three Megablox kernels (value, row
 # gradient, weight gradient; for the last "rows" is the contracted dimension).
 # Measured on the v5e at the OLMoE cell's shapes (PERF.md section 6, PR 25):
@@ -68,10 +70,11 @@ def _megablox():
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _pallas(lhs, rhs, group_sizes, interpret):
     m, k = lhs.shape
-    return _megablox().gmm(
-        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-        tiling=_fit(TILING, m, k, rhs.shape[2]), interpret=interpret,
-    )
+    with obs_trace.span("kernel_trace", kernel="gmm"):
+        return _megablox().gmm(
+            lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+            tiling=_fit(TILING, m, k, rhs.shape[2]), interpret=interpret,
+        )
 
 
 def _pallas_fwd(lhs, rhs, group_sizes, interpret):
@@ -84,17 +87,19 @@ def _pallas_bwd(interpret, residuals, grad):
     n = rhs.shape[2]
     backend = _megablox()
     grad = grad.astype(lhs.dtype)
-    d_lhs = backend.gmm(
-        grad, rhs, group_sizes, preferred_element_type=lhs.dtype,
-        tiling=_fit(TILING, m, n, k), transpose_rhs=True,
-        interpret=interpret,
-    )
-    d_rhs = backend.tgmm(
-        lhs.swapaxes(0, 1), grad, group_sizes,
-        preferred_element_type=rhs.dtype,
-        tiling=_fit(TILING, m, k, n), num_actual_groups=rhs.shape[0],
-        interpret=interpret,
-    )
+    with obs_trace.span("kernel_trace", kernel="gmm_dlhs"):
+        d_lhs = backend.gmm(
+            grad, rhs, group_sizes, preferred_element_type=lhs.dtype,
+            tiling=_fit(TILING, m, n, k), transpose_rhs=True,
+            interpret=interpret,
+        )
+    with obs_trace.span("kernel_trace", kernel="tgmm"):
+        d_rhs = backend.tgmm(
+            lhs.swapaxes(0, 1), grad, group_sizes,
+            preferred_element_type=rhs.dtype,
+            tiling=_fit(TILING, m, k, n), num_actual_groups=rhs.shape[0],
+            interpret=interpret,
+        )
     return d_lhs, d_rhs, None
 
 

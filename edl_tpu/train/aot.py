@@ -65,7 +65,11 @@ the instrumented persistent-cache read/write seam),
 ``edl_train_restage_compile_seconds`` (real compile time paid between a
 cache miss and its write — the number speculation exists to zero), and
 ``aot``/``exchange`` flight records so edl-timeline shows the
-speculation paying off.
+speculation paying off. In the span ring: ``cache_load`` (the persistent
+cache's read, with ``module``, ``hit``, ``ladder``) and jax's own
+``jit_trace`` / ``jit_lower`` / ``jit_compile`` events
+(:func:`instrument_compile_spans`), which is how a worker's first step
+reads as trace, lowering and load on a restage's critical path.
 """
 
 from __future__ import annotations
@@ -217,10 +221,19 @@ def instrument_compilation_cache() -> bool:
         return True
 
     def get_wrapper(cache_key, compile_options, backend, executable_devices):
-        found = orig_get(
-            cache_key, compile_options, backend, executable_devices
-        )
-        if getattr(_in_ladder, "active", False):
+        # the read where it happens (file read + deserialise, and on a chip
+        # the executable's load): jit_compile less this is key hashing on a
+        # hit and XLA on a miss. The key is "<module>-<hash>".
+        in_ladder = getattr(_in_ladder, "active", False)
+        with obs_trace.span(
+            "cache_load", module=cache_key.rpartition("-")[0],
+            ladder=in_ladder,
+        ) as load:
+            found = orig_get(
+                cache_key, compile_options, backend, executable_devices
+            )
+            load.args["hit"] = found[0] is not None
+        if in_ladder:
             return found
         if found[0] is None:
             _M_CACHE_EVENTS.inc(kind="miss")
@@ -237,12 +250,6 @@ def instrument_compilation_cache() -> bool:
                 t0 = _miss_started.pop(cache_key, None)
             if t0 is not None:
                 _M_RESTAGE_COMPILE.observe(time.monotonic() - t0)
-                if os.environ.get("EDL_CACHE_EVENTS_DEBUG") == "1":
-                    # names the executables speculation failed to cover
-                    logger.info(
-                        "cache miss compiled: %s (%.2fs)",
-                        module_name, time.monotonic() - t0,
-                    )
             _M_CACHE_EVENTS.inc(kind="write")
         return orig_put(
             cache_key, module_name, executable, backend, compile_time
@@ -255,12 +262,69 @@ def instrument_compilation_cache() -> bool:
     return True
 
 
+# -- jax's own compile spans --------------------------------------------------
+
+#: jax's compile events (``jax._src.dispatch.log_elapsed_time``, pinned jax
+#: 0.9.0) -> the ring's span names. Each fires once a traced, lowered or
+#: backend-compiled function with ``fun_name``; a backend compile is a
+#: persistent-cache load on a hit (``cache_load`` lies inside it).
+JAX_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower",
+    "/jax/core/compile/backend_compile_duration": "jit_compile",
+}
+
+
+#: a start traces thousands of inner ``jit``s (``jnp``'s own helpers) for well
+#: under a millisecond each, all of them inside the trace of whatever called
+#: them: left out, so that one start cannot push a worker's boot and restore
+#: spans out of the ring (16,384 events). Lowerings and compiles are a few
+#: dozen a start and all kept: a recompile is never dropped.
+JIT_TRACE_FLOOR_S = 1e-3
+
+
+def _on_jax_time_span(event, start_time, end_time, fun_name="", **_):
+    name = JAX_COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    if name == "jit_trace" and end_time - start_time < JIT_TRACE_FLOOR_S:
+        return
+    obs_trace.get_tracer().record_wall(
+        name, start_time, end_time, fun=str(fun_name)
+    )
+
+
+def instrument_compile_spans() -> None:
+    """jax's trace / lower / compile events into the span ring, as
+    ``jit_trace`` / ``jit_lower`` / ``jit_compile`` with ``fun``: one
+    listener a process (idempotent), cache or no cache. They nest by time
+    under whatever span is open (``state_init``, ``first_step``,
+    ``step_relower``) and stitch into the open ``restage`` operation; a
+    ``jit_compile`` after the first step is a recompile."""
+    from jax import monitoring
+    from jax._src import monitoring as _monitoring
+
+    if _on_jax_time_span not in _monitoring.get_event_time_span_listeners():
+        monitoring.register_event_time_span_listener(_on_jax_time_span)
+
+
 def cache_event_counts() -> Dict[str, int]:
     """Snapshot of {hit, miss, write} counts this process has seen."""
     return {
         kind: int(_M_CACHE_EVENTS.value(kind=kind))
         for kind in ("hit", "miss", "write")
     }
+
+
+def missed_modules() -> List[str]:
+    """The modules the foreground jit looked for in the persistent cache
+    and did not find, oldest first, from the ring's ``cache_load`` spans:
+    what speculation (or the last run) failed to cover."""
+    return [
+        e["args"]["module"] for e in obs_trace.get_tracer().to_events()
+        if e.get("name") == "cache_load"
+        and e["args"].get("hit") is False and not e["args"].get("ladder")
+    ]
 
 
 # -- the AOT ladder -----------------------------------------------------------

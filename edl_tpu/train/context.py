@@ -110,6 +110,7 @@ def enable_compilation_cache(path: str) -> None:
 
     _aot.enable_portable_cache_keys()
     _aot.instrument_compilation_cache()
+    _aot.instrument_compile_spans()
 
 
 def _enable_all_rank_cache_writes() -> None:
@@ -308,6 +309,12 @@ def init(env: Optional[WorkerEnv] = None) -> WorkerEnv:
     if env.compile_cache_dir:
         enable_compilation_cache(env.compile_cache_dir)
         _pull_cache_entries(env)
+    else:
+        # an uncached worker's start is traced all the same: jax's trace,
+        # lower and compile events as spans under the restage operation
+        from edl_tpu.train import aot as _aot
+
+        _aot.instrument_compile_spans()
     if _distributed_up:
         return env
     if env.world_size > 1 and env.coordinator:

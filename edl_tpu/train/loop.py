@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import os
 import sys
+import threading
 import time
 from typing import Any, Callable, Dict, Iterable, Optional
 
@@ -118,6 +119,35 @@ def _lower_step(step, state, device_batch, compile: bool):
             file=sys.stderr,
         )
     return lowered, compiled
+
+
+def _record_step_launch(tracer: obs_trace.SpanTracer) -> None:
+    """``step_launch``: what the step's first call does after jax's last
+    compile event inside it has ended and before it returns — the loaded
+    executable's argument handlers, its upload to each chip, the first
+    enqueue (1.3 s on four chips, 0.1–0.6 s on one). jax reports no event
+    for it, so it is read off the ring: from the end of the last
+    ``jit_compile`` inside this thread's newest ``step_dispatch`` to that
+    dispatch's end. Nothing where the call compiled nothing."""
+    tid = threading.get_ident() & 0x7FFFFFFF
+    mine = [
+        e for e in tracer.to_events()
+        if e.get("tid") == tid and e.get("ph") == "X"
+    ]
+    dispatch = next(
+        (e for e in reversed(mine) if e["name"] == "step_dispatch"), None
+    )
+    if dispatch is None:
+        return
+    compiled = [
+        e["ts"] + e["dur"] for e in mine
+        if e["name"] == "jit_compile" and e["ts"] >= dispatch["ts"]
+    ]
+    if compiled:
+        tracer.record_wall(
+            "step_launch", max(compiled) / 1e6,
+            (dispatch["ts"] + dispatch["dur"]) / 1e6,
+        )
 
 
 class RetireClock:
@@ -588,6 +618,7 @@ class ElasticTrainer:
                             # + compile or cache load), recorded while
                             # the op context is still live so it stitches
                             # — then the restage window ends
+                            _record_step_launch(tracer)
                             tracer.record(
                                 "first_step", t_prev, dt, epoch=epoch
                             )
@@ -607,19 +638,24 @@ class ElasticTrainer:
                             # arms the MFU/roofline gauges; its compile (a
                             # persistent-cache hit, no second XLA compile)
                             # gives the memory plane THIS stage's plan and
-                            # obs_profile.step_phases() the names
-                            lowered, compiled = _lower_step(
-                                step, state, device_batch,
-                                compile=mem_plane is not None,
-                            )
-                            step_telemetry.set_cost(
-                                obs_profile.step_cost(lowered)
-                            )
-                            if compiled is not None:
-                                mem_plane.harvest(
-                                    compiled, world=env.world_size
+                            # obs_profile.step_phases() the names. Its own
+                            # span: set-up time after `first_step` ends
+                            # (milliseconds on the chip: jax's in-process
+                            # caches hand trace, lowering and executable back)
+                            with tracer.span("step_relower") as relower:
+                                lowered, compiled = _lower_step(
+                                    step, state, device_batch,
+                                    compile=mem_plane is not None,
                                 )
-                                obs_profile.set_step_executable(compiled)
+                                step_telemetry.set_cost(
+                                    obs_profile.step_cost(lowered)
+                                )
+                                if compiled is not None:
+                                    mem_plane.harvest(
+                                        compiled, world=env.world_size
+                                    )
+                                    obs_profile.set_step_executable(compiled)
+                                relower.args["compiled"] = compiled is not None
                             # steady state reached: speculatively compile
                             # the N±1/N±2 neighbor worlds into the
                             # persistent cache on a low-priority thread

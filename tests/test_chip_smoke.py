@@ -124,7 +124,7 @@ def test_train_phase_resumes_on_a_cache_hit(rehearsal, capsys):
     assert device == {"platform": "cpu", "kind": "cpu", "count": 1}
     cold, resumed = lines
     assert cold["launch"] == "cold" and cold["cache"]["miss"] > 0
-    assert "jit_step" in cold["missed_modules"]  # the debug names work
+    assert "jit_step" in cold["missed_modules"]  # the cache_load spans name them
     assert resumed["launch"] == "resumed"
     assert resumed["cache"]["hit"] > 0 and resumed["cache"]["miss"] == 0
     assert resumed["missed_modules"] == {}
@@ -177,10 +177,22 @@ def test_a_degraded_trainer_line_fails_the_phase(line):
         chip_smoke._check_worker_log("epoch 0 loss 1.0\n%s\n" % line)
 
 
-def test_missed_modules_are_named():
-    log = "x cache miss compiled: jit_step (41.20s)\ny\n" \
-          "z cache miss compiled: jit_add (0.10s)\n"
-    assert chip_smoke._check_worker_log(log) == ["jit_step", "jit_add"]
+def test_missed_modules_are_named(monkeypatch):
+    """What a worker reports as ``missed_modules``: the foreground's
+    ``cache_load`` spans that found nothing, not the ladder's."""
+    import time
+
+    from edl_tpu.obs import trace as obs_trace
+    from edl_tpu.train import aot
+
+    tracer = obs_trace.SpanTracer("test")
+    for module, hit, ladder in (("jit_step", False, False), ("jit_mul", True, False),
+                                ("jit_step", False, True), ("jit_add", False, False)):
+        tracer.record("cache_load", time.monotonic(), 0.1, module=module,
+                      hit=hit, ladder=ladder)
+    monkeypatch.setattr(obs_trace, "get_tracer", lambda *a: tracer)
+    assert aot.missed_modules() == ["jit_step", "jit_add"]
+    assert chip_smoke._check_worker_log("epoch 0 loss 1.0\n") is None
 
 
 # -- one process per chip: the control plane stays off jax --------------------
