@@ -11,10 +11,10 @@ Layout: ``[batch, heads, seq, head_dim]``. The whole-KV family
 ``(batch*heads, q_blocks)``; each program owns one q block, holds the
 whole K and V of its head in VMEM and loops over kv blocks with
 ``lax.fori_loop``. The grid-pipelined family (``"flash2"``:
-``_flash2_kernel`` and its two) puts the kv blocks (for dk/dv the q blocks)
-on a third, innermost grid dimension, so that they are copied block by
-block behind the compute; it is the one that runs past
-:func:`_flash_max_seq` and the one that takes a **window**
+``_flash2_kernel`` forward, ``_flash2_bwd_kernel`` backward) puts the kv
+blocks (backward: the q blocks) on a third, innermost grid dimension, so
+that they are copied block by block behind the compute; it is the one that
+runs past :func:`_flash_max_seq` and the one that takes a **window**
 (``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
 ``i - W < j <= i``). Under a mask its innermost steps are **spans** of the
 other side that start where a block's first visible key (or row) lies, at
@@ -29,12 +29,19 @@ a mask. Causal masking compares global q/k positions from
 ``flash_attention`` is differentiable via ``jax.custom_vjp`` with REAL
 flash backward kernels: the forward saves per-row logsumexp (``lse``),
 the backward recomputes probabilities blockwise as ``exp(s - lse)`` (no
-online-softmax rescan needed) and runs two Pallas kernels — one gridded
-over q blocks producing ``dq``, one over kv blocks producing ``dk``/``dv``
-— so the backward, where training time actually goes, also never
-materializes the [Tq, Tk] score matrix. Causal runs skip fully-masked
-blocks via dynamic ``fori_loop`` bounds. Ragged shapes fall back to the
-jnp reference end-to-end (forward and backward agree by construction).
+online-softmax rescan needed), so the backward, where training time
+actually goes, also never materializes the [Tq, Tk] score matrix. The
+whole-KV family runs two Pallas kernels — one gridded over q blocks
+producing ``dq``, one over kv blocks producing ``dk``/``dv`` — and skips
+fully-masked blocks via dynamic ``fori_loop`` bounds. The grid-pipelined
+family runs **one**: a kv block's walk over its rows computes a tile's
+``s``, ``p``, ``dp`` and ``ds`` once and adds to all three gradients (five
+matmuls a tile, not seven), with the head's whole ``dq`` accumulated in
+VMEM; a head whose accumulator the chip's VMEM cannot hold
+(:func:`_fused_bwd_vmem`) keeps that family's older pair,
+``_flash2_bwd_dq_kernel`` and ``_flash2_bwd_dkv_kernel``. Ragged shapes
+fall back to the jnp reference end-to-end (forward and backward agree by
+construction).
 """
 
 from __future__ import annotations
@@ -186,13 +193,14 @@ def _dot_tn(a, b):
     )
 
 
-def _causal_mask(s, q_lo, k_lo, window=None):
-    """Mask one [rows, keys] score tile whose first row sits at position
-    ``q_lo`` (its index plus ``q_offset = tk - tq``, which aligns sequence
-    *ends*, matching ``attention_reference``) and whose first key is
-    ``k_lo``; ``window`` as in :func:`_dense_causal_mask`."""
-    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_lo
-    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_lo
+def _causal_mask(s, q_lo, k_lo, window=None, keys_first=False):
+    """Mask one [rows, keys] score tile (``keys_first``: [keys, rows])
+    whose first row sits at position ``q_lo`` (its index plus ``q_offset =
+    tk - tq``, which aligns sequence *ends*, matching
+    ``attention_reference``) and whose first key is ``k_lo``; ``window`` as
+    in :func:`_dense_causal_mask`."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(keys_first)) + q_lo
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(not keys_first)) + k_lo
     return jnp.where(_sees(qpos, kpos, window), s, NEG_INF)
 
 
@@ -392,15 +400,17 @@ def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
 
 
 @functools.lru_cache(maxsize=None)
-def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side):
+def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side,
+                **more):
     """One ``attn_tiles`` instant in the span ring for each shape a
-    grid-pipelined kernel is traced at: what the mask makes of its tiles."""
+    grid-pipelined kernel is traced at: what the mask makes of its tiles
+    (and what ``more`` the kernel has to say of itself)."""
     shares = tile_census(tq, tk, block_q, block_k, causal, window, side)
     live = shares["interior"] + shares["edge"]
     obs_trace.get_tracer().instant(
         "attn_tiles", kernel=kernel, tq=tq, tk=tk, block_q=block_q,
         block_k=block_k, window=window,
-        masked_share=shares["edge"] / live if live else 0.0, **shares,
+        masked_share=shares["edge"] / live if live else 0.0, **shares, **more,
     )
 
 
@@ -806,6 +816,112 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _flash2_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                       causal: bool, scale: float, block_q: int, k_block: int,
+                       num_q: int, num_k: int, q_offset: int, row_align: int,
+                       window: int | None = None, seq_q: int = 0):
+    """The whole grid-pipelined backward in one walk, dk/dv's: a kv block's
+    spans of rows ride the innermost grid dimension, and a live tile's
+    ``s``, ``p``, ``dp`` and ``ds`` are computed once for all three
+    gradients (five matmuls and one ``exp`` a tile where the two kernels
+    above spend seven and two, and q, k, v, dO, lse, delta are read once).
+    The tile is **transposed**, ``[keys, rows]``: ``dv += pT dO`` and ``dk +=
+    dsT q`` are then plain products (only dq's contracts over the first
+    axis), and ``lse`` / ``delta`` come as ``[1, rows]`` along the lanes, a
+    sublane broadcast in the tile and an eighth of a ``[rows, 1]`` block's
+    bytes in HBM. dk/dv accumulate in scratch a kv block as above; dq
+    accumulates in a float32 scratch of the head's **whole** ``[seq_q, d]``,
+    each tile into its rows, zeroed at the head's first step and written at
+    its last: the kv blocks of a head therefore run in order
+    (``"arbitrary"``)."""
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(1)
+    step = pl.program_id(2)
+    k_lo = ki * k_block
+    row = step * block_q
+    live = True
+    if causal:
+        seen = _q_range(ki, k_block, q_offset, window, seq_q)
+        row += _spans(seen, block_q, num_q, seq_q, window)[0]
+        live = ~_tile_class(row + q_offset, block_q, k_lo, k_block, window)[0]
+
+    @pl.when((ki == 0) & (step == 0))
+    def _init_head():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(step == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(live)
+    def _update():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0]                                # [1, bq]
+        delta = delta_ref[0]
+        s = _dot_nt(k, q) * scale                       # [bk, bq]
+        if causal:
+            s = _causal_mask(s, row + q_offset, k_lo, window, keys_first=True)
+        p = jnp.exp(s - lse)
+        dv_scr[:] = dv_scr[:] + _dot_nn(p.astype(do.dtype), do)
+        dp = _dot_nt(v, do)
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dk_scr[:] = dk_scr[:] + _dot_nn(ds, q)
+        rows = pl.ds(pl.multiple_of(row, row_align), block_q)
+        dq_scr[rows, :] = dq_scr[rows, :] + _dot_tn(ds, k)
+
+    @pl.when(step == num_q - 1)
+    def _finalize():
+        # scale as in the two kernels above: once, on the way out
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when((ki == num_k - 1) & (step == num_q - 1))
+    def _finalize_head():
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+
+
+# The fused backward holds a head's dq on the chip. What it asks of VMEM,
+# from the shapes: the float32 accumulator and the two buffers of its
+# output block, dk/dv's scratch, two buffers of every other block (a
+# ``[1, rows]`` block is eight sublanes deep there), and a tile's float32
+# intermediates (s, p, dp, ds, their casts and transposes: eight tiles'
+# worth has taken every shape the rehearsals tried). A call takes the fused
+# kernel where that is at most half the core's VMEM, and the kernel's limit
+# is that figure (Mosaic's default, 16 MiB, holds no 8192 x 128 head).
+_VMEM_V5E = 128 << 20
+
+
+def _vmem_capacity() -> int:
+    """Bytes of VMEM a TensorCore has: the chip's own where there is one,
+    the v5e's (the chip the blocks were swept on) in interpret mode and in
+    a compile for a described chip, which see the CPU."""
+    if jax.default_backend() != "tpu":
+        return _VMEM_V5E
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def _fused_bwd_vmem(tq, d, block_q, block_k, itemsize):
+    """``(bytes of the dq accumulator, bytes of VMEM the fused backward
+    needs)`` at these shapes."""
+    acc = tq * d * 4
+    blocks = 2 * (block_q + 2 * block_k) * d * itemsize + 2 * 8 * block_q * 4
+    need = (
+        acc + 2 * tq * d * itemsize      # dq: the accumulator, its output
+        + 2 * block_k * d * 4            # dk/dv scratch
+        + 2 * blocks                     # every other block, twice
+        + 8 * block_q * block_k * 4      # a tile's intermediates
+    )
+    return acc, need
+
+
 def _flash2_backward(
     q, k, v, o, lse, g, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
@@ -826,10 +942,14 @@ def _flash2_backward_kernels(
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
     dkv_blocks: tuple[int, int] | None = None,
 ):
-    """The two grid-pipelined backward pallas calls; ``lse``/``delta``
-    are [B*H, Tq] (external residuals welcome — ring attention's
-    per-rotation block grads route here past the whole-KV compile
-    limit). ``dkv_blocks``: the dk/dv kernel's own, where they differ."""
+    """The grid-pipelined backward; ``lse``/``delta`` are [B*H, Tq]
+    (external residuals welcome — ring attention's per-rotation block
+    grads route here past the whole-KV compile limit). One pallas call,
+    :func:`_flash2_bwd_kernel`, where a head's dq accumulator fits the
+    chip's VMEM (:func:`_fused_bwd_vmem`) and a span of rows is whole lane
+    tiles; the two older ones, dq and then dk/dv, where not. ``dkv_blocks``: those of the kernels that
+    walk rows a kv block (the fused one, and dk/dv), where they differ
+    from dq's ``block_q`` and ``block_k``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -840,81 +960,119 @@ def _flash2_backward_kernels(
     kv_q, kv_k = _fit_blocks(
         *(dkv_blocks or (block_q, block_k)), tq, tk, window, "q"
     )
-    block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
 
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * h_kv, tk, d)
     vf = v.reshape(b * h_kv, tk, d)
     gf = g.reshape(b * h, tq, d)
-    # pallas layout: trailing singleton keeps the block sublane 8-aligned
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
-    kwargs = _grid_pipeline_kwargs()
     common = dict(causal=causal, scale=scale, q_offset=tk - tq, window=window)
-    (kv_steps, kv_map), _ = _flash2_maps(
-        causal, window, block_q, block_k, tq, tk, grp
-    )
     _, (q_steps, q_map) = _flash2_maps(causal, window, kv_q, kv_k, tq, tk, grp)
-    _note_tiles("flash2_dq", tq, tk, block_q, block_k, causal, window, "kv")
-    _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q")
-    kv_spec = _span_spec(block_k, d, kv_map, window)
-    kernel = pl.pallas_call(
-        functools.partial(
-            _flash2_bwd_dq_kernel,
-            q_block=block_q, block_k=block_k, num_k=kv_steps, seq_k=tk,
-            **common,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-        grid=(b * h, tq // block_q, kv_steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        **kwargs,
-    )
-    with obs_trace.span("kernel_trace", kernel="flash2_dq"):
-        dq = kernel(qf, kf, vf, gf, lse3, delta3)
+    # the kernels that walk rows a kv block: the rows in spans, k and v a
+    # grouped block, dk/dv at full q-head width, folded to the grouped
+    # width outside (see _flash_backward_kernels)
+    rows_spec = _span_spec(kv_q, d, q_map, window)
+    kv_block = pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0))
+    dkv_shape = [
+        jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
+        jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
+    ]
+    dkv_specs = [pl.BlockSpec((1, kv_k, d), lambda i, ki, j: (i, ki, 0))] * 2
+    dkv_scratch = [pltpu.VMEM((kv_k, d), jnp.float32)] * 2
 
-    # dk/dv at full q-head width, folded to the grouped width outside
-    # (see _flash_backward_kernels)
-    kernel = pl.pallas_call(
-        functools.partial(
-            _flash2_bwd_dkv_kernel,
-            block_q=kv_q, k_block=kv_k, num_q=q_steps, seq_q=tq, **common,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
-        ],
-        grid=(b * h, tk // kv_k, q_steps),
-        in_specs=[
-            _span_spec(kv_q, d, q_map, window),
-            pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)),
-            pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0)),
-            _span_spec(kv_q, d, q_map, window),
-            _span_spec(kv_q, 1, q_map, window),
-            _span_spec(kv_q, 1, q_map, window),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, kv_k, d), lambda i, ki, j: (i, ki, 0)),
-            pl.BlockSpec((1, kv_k, d), lambda i, ki, j: (i, ki, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((kv_k, d), jnp.float32),
-            pltpu.VMEM((kv_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-        **kwargs,
-    )
-    with obs_trace.span("kernel_trace", kernel="flash2_dkv"):
-        dk, dv = kernel(qf, kf, vf, gf, lse3, delta3)
+    acc, need = _fused_bwd_vmem(tq, d, kv_q, kv_k, q.dtype.itemsize)
+    # lse and delta ride the lanes there: whole lane tiles a span (Mosaic's
+    # rule, like every tiling rule not the interpreter's)
+    lanes_fit = interpret or kv_q == tq or (kv_q % 128 == 0 and tq % 128 == 0)
+    if need <= _vmem_capacity() // 2 and lanes_fit:
+        _note_tiles(
+            "flash2_bwd", tq, tk, kv_q, kv_k, causal, window, "q",
+            acc_bytes=acc,
+        )
+        # lse and delta along the lanes, a span of them a step
+        lanes = _span_spec(
+            1, kv_q, lambda i, ki, j: (i, 0, q_map(i, ki, j)[1]), window
+        )
+        # what divides the row a span starts at (as in _flash2_maps)
+        row_align = kv_q if window is None else math.gcd(
+            _SPAN_ALIGN, kv_q, tq - q_steps * kv_q
+        )
+        kernel = pl.pallas_call(
+            functools.partial(
+                _flash2_bwd_kernel,
+                block_q=kv_q, k_block=kv_k, num_q=q_steps, num_k=tk // kv_k,
+                seq_q=tq, row_align=row_align, **common,
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, tq, d), q.dtype), *dkv_shape,
+            ],
+            grid=(b * h, tk // kv_k, q_steps),
+            in_specs=[rows_spec, kv_block, kv_block, rows_spec, lanes, lanes],
+            out_specs=[
+                # a head's whole dq: the block stands still over the head's
+                # steps and is written out when the head changes
+                pl.BlockSpec((1, tq, d), lambda i, ki, j: (i, 0, 0)),
+                *dkv_specs,
+            ],
+            scratch_shapes=[pltpu.VMEM((tq, d), jnp.float32), *dkv_scratch],
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=need,
+            ),
+        )
+        with obs_trace.span("kernel_trace", kernel="flash2_bwd"):
+            dq, dk, dv = kernel(
+                qf, kf, vf, gf, lse[:, None, :], delta[:, None, :]
+            )
+    else:
+        block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
+        # pallas layout: trailing singleton keeps the block sublane 8-aligned
+        lse3 = lse[..., None]
+        delta3 = delta[..., None]
+        kwargs = _grid_pipeline_kwargs()
+        (kv_steps, kv_map), _ = _flash2_maps(
+            causal, window, block_q, block_k, tq, tk, grp
+        )
+        _note_tiles("flash2_dq", tq, tk, block_q, block_k, causal, window, "kv")
+        _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q")
+        kv_spec = _span_spec(block_k, d, kv_map, window)
+        q_spec = pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0))
+        row_spec = pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0))
+        kernel = pl.pallas_call(
+            functools.partial(
+                _flash2_bwd_dq_kernel,
+                q_block=block_q, block_k=block_k, num_k=kv_steps, seq_k=tk,
+                **common,
+            ),
+            out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+            grid=(b * h, tq // block_q, kv_steps),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+            **kwargs,
+        )
+        with obs_trace.span("kernel_trace", kernel="flash2_dq"):
+            dq = kernel(qf, kf, vf, gf, lse3, delta3)
+
+        row_spec = _span_spec(kv_q, 1, q_map, window)
+        kernel = pl.pallas_call(
+            functools.partial(
+                _flash2_bwd_dkv_kernel,
+                block_q=kv_q, k_block=kv_k, num_q=q_steps, seq_q=tq, **common,
+            ),
+            out_shape=dkv_shape,
+            grid=(b * h, tk // kv_k, q_steps),
+            in_specs=[
+                rows_spec, kv_block, kv_block, rows_spec, row_spec, row_spec,
+            ],
+            out_specs=dkv_specs,
+            scratch_shapes=dkv_scratch,
+            interpret=interpret,
+            **kwargs,
+        )
+        with obs_trace.span("kernel_trace", kernel="flash2_dkv"):
+            dk, dv = kernel(qf, kf, vf, gf, lse3, delta3)
 
     dk, dv = _fold_dkv(
         dk.reshape(b, h, tk, d), dv.reshape(b, h, tk, d),
@@ -951,8 +1109,12 @@ def _kernel_blocks(tq: int):
 # bk=1024 is safe for flash2 (KV streams through the grid, constant
 # VMEM) where it crashed the compiler for the whole-KV kernel; the
 # (128, 512) flash defaults left 2.4x fwd / 2.6x fwd+bwd on the table.
+# ``_BWD``: the fused backward's (PR 34's sweep, heads of 128 and 64:
+# bench_results/README.md), and dk/dv's where a head's dq does not fit the
+# chip; ``_DQ``: dq's there.
 _FLASH2_BLOCKS_FWD = (256, 1024)
-_FLASH2_BLOCKS_BWD = (512, 1024)
+_FLASH2_BLOCKS_BWD = (1024, 1024)
+_FLASH2_BLOCKS_DQ = (512, 1024)
 
 
 def _spans_fit(block_q, block_k, tq, tk, window, side):
@@ -990,24 +1152,28 @@ def _fit_blocks(block_q, block_k, tq, tk, window, side):
 # cut into the fewest equal spans no longer than the sweep's that cover
 # what such a block needs (its own rows or keys and the window), in whole
 # lane tiles: the forward there takes one update of 2560 keys a 512 rows,
-# dq one of 2304 keys a 256 rows, dk/dv two of 1280 rows a 512 keys. An
-# online-softmax update costs the forward about as much as 700 keys do,
-# whatever its width, so the forward wants few; dk/dv read faster in two
-# spans than in one.
-_WINDOW_BLOCKS = {"fwd": (512, 2560), "dq": (256, 2560), "dkv": (1280, 512)}
+# dq one of 2304 keys a 256 rows, the backward two of 1280 rows a 512 keys.
+# An online-softmax update costs the forward about as much as 700 keys do,
+# whatever its width, so the forward wants few; the backward reads faster
+# in two spans than in one.
+_WINDOW_BLOCKS = {"fwd": (512, 2560), "dq": (256, 2560), "bwd": (1280, 512)}
 
 
 def _flash2_blocks(kind, tq, tk, window, given=None):
     """``(block_q, block_k)`` for the grid-pipelined kernel ``kind``
-    (``"fwd"``, ``"dq"``, ``"dkv"``), fitted to the shapes: what the
-    caller ``given`` (a pair, either of it ``None``) wins, then a windowed
-    call's blocks from the window and the shapes, else the full-causal
-    sweep's."""
-    side = "q" if kind == "dkv" else "kv"
-    bq, bk = _FLASH2_BLOCKS_FWD if kind == "fwd" else _FLASH2_BLOCKS_BWD
+    (``"fwd"``; ``"bwd"``, the kernels that walk rows a kv block: the fused
+    backward, and dk/dv where a head's dq does not fit the chip; ``"dq"``,
+    dq's there), fitted to the shapes: what the caller ``given`` (a pair,
+    either of it ``None``) wins, then a windowed call's blocks from the
+    window and the shapes, else the full-causal sweep's."""
+    side = "q" if kind == "bwd" else "kv"
+    bq, bk = {
+        "fwd": _FLASH2_BLOCKS_FWD, "dq": _FLASH2_BLOCKS_DQ,
+        "bwd": _FLASH2_BLOCKS_BWD,
+    }[kind]
     if window is not None:
         wq, wk = _WINDOW_BLOCKS[kind]
-        if kind == "dkv":
+        if kind == "bwd":
             wk = _fit_block(wk, tk)
             need, longest = _q_need(window, wk, tq, tk), wq
         else:
@@ -1015,7 +1181,7 @@ def _flash2_blocks(kind, tq, tk, window, given=None):
             need, longest = _kv_need(window, wq, tq, tk), wk
         span = -(-need // -(-need // longest))   # fewest equal steps
         span = -(-span // 128) * 128             # in whole lane tiles
-        wq, wk = (span, wk) if kind == "dkv" else (wq, span)
+        wq, wk = (span, wk) if kind == "bwd" else (wq, span)
         if _spans_fit(wq, wk, tq, tk, window, side):
             bq, bk = wq, wk
     given = given or (None, None)
@@ -1589,7 +1755,7 @@ def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
     # _flash2_blocks the grid-pipelined ones'
     if kernels and bwd_impl == "flash2":
         dq_blocks = _flash2_blocks("dq", tq, tk, window, bwd_blocks)
-        dkv_blocks = _flash2_blocks("dkv", tq, tk, window, bwd_blocks)
+        dkv_blocks = _flash2_blocks("bwd", tq, tk, window, bwd_blocks)
         if _spans_fit(*dq_blocks, tq, tk, window, "kv") and _spans_fit(
             *dkv_blocks, tq, tk, window, "q"
         ):

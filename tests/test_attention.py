@@ -476,13 +476,23 @@ class TestFlash2:
         )
         assert lse is None and o.shape == (1, 1, 32, 8)
 
-    def test_flash2_backward_multi_block_grads(self):
+    @pytest.mark.parametrize("backward", ["fused", "pair"])
+    def test_flash2_backward_multi_block_grads(self, monkeypatch, backward):
         """Force num_k > 1 AND num_q > 1 through the grid-pipelined
-        backward kernels: the scratch accumulation across grid steps is
-        the machinery under test (the _auto tests run at one block)."""
+        backward (one fused kernel; dq and dk/dv where no VMEM holds a
+        head's dq): the scratch accumulation across grid steps is the
+        machinery under test (the _auto tests run at one block)."""
+        import importlib
+
         from edl_tpu.ops.attention import (
             _flash2_backward, _flash2_forward, attention_reference,
         )
+
+        if backward == "pair":
+            monkeypatch.setattr(
+                importlib.import_module("edl_tpu.ops.attention"),
+                "_vmem_capacity", lambda: 0,
+            )
 
         rng = np.random.RandomState(11)
         q = jnp.asarray(rng.randn(2, 2, 64, 16), jnp.float32)

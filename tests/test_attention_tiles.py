@@ -23,6 +23,31 @@ A = importlib.import_module("edl_tpu.ops.attention")
 from edl_tpu.obs import trace as obs_trace  # noqa: E402
 
 NAMES = ("o", "lse", "dq", "dk", "dv")
+# the backward a call takes: one fused kernel where a head's dq accumulator
+# fits the chip's VMEM, the dq and dk/dv kernels where it does not
+BACKWARDS = ("fused", "pair")
+
+
+@pytest.fixture
+def backward(request, monkeypatch):
+    """``"pair"``: a chip with no VMEM to spare, so that no accumulator fits."""
+    if request.param == "pair":
+        monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
+    return request.param
+
+
+def _bwd_kernels_traced(fn):
+    """The ``kernel_trace`` names of the backward kernels ``fn`` enters."""
+    ring = obs_trace.get_tracer()
+    ring.clear()
+    out = fn()
+    names = [
+        e["args"]["kernel"] for e in ring.to_events() if e["name"] == "kernel_trace"
+    ]
+    return out, [n for n in names if n != "flash2_fwd"]
+
+
+TRACED = {"fused": ["flash2_bwd"], "pair": ["flash2_dq", "flash2_dkv"]}
 
 
 def _inputs(h, h_kv, tq, tk, d, seed=0):
@@ -32,7 +57,8 @@ def _inputs(h, h_kv, tq, tk, d, seed=0):
 
 
 def _kernels(q, k, v, g, fwd, dq, dkv, window):
-    """(o, lse, dq, dk, dv) of the three flash2 kernels, interpret mode."""
+    """(o, lse, dq, dk, dv) of the flash2 kernels, interpret mode (``dkv``:
+    the blocks of the fused backward, and of dk/dv in the pair)."""
     b, h, tq, d = q.shape
     scale = d ** -0.5
     o, lse = A._flash2_forward(q, k, v, True, scale, *fwd, True, window)
@@ -86,16 +112,22 @@ WALKS = [
     pytest.param(4, 2, 384, 512, 64, 100, WINDOWED, id="offset128-window100"),
     pytest.param(4, 2, 128, 512, 64, 100, FULL, id="offset384-window100"),
     pytest.param(4, 2, 256, 256, 64, 1000, FULL, id="window-past-the-sequence"),
+    # every head its own keys (group 1), at both head widths
+    pytest.param(2, 2, 256, 256, 128, None, FULL, id="full-mha-d128"),
+    pytest.param(2, 2, 512, 512, 64, 256, WINDOWED, id="window-mha-d64"),
+    pytest.param(8, 2, 384, 512, 128, 256, WINDOWED, id="offset128-window-gqa4-d128"),
 ]
 
 
+@pytest.mark.parametrize("backward", BACKWARDS, indirect=True)
 @pytest.mark.parametrize("h,h_kv,tq,tk,d,window,blocks", WALKS)
 def test_flash2_walk_agrees_with_the_dense_reference(
-    monkeypatch, h, h_kv, tq, tk, d, window, blocks
+    monkeypatch, backward, h, h_kv, tq, tk, d, window, blocks
 ):
-    """Forward, dq and dk/dv over dead steps, clamped spans and spans that
-    start between blocks: a class or a span off by one is a whole wrong
-    tile, far past these tolerances (the file's own)."""
+    """Forward and backward (the fused kernel; dq and dk/dv where a head's
+    dq does not fit) over dead steps, clamped spans and spans that start
+    between blocks: a class or a span off by one is a whole wrong tile, far
+    past these tolerances (the file's own)."""
     monkeypatch.setattr(A, "_SPAN_ALIGN", ALIGN)
     q, k, v, g = _inputs(h, h_kv, tq, tk, d)
     fitted = tuple(
@@ -104,14 +136,18 @@ def test_flash2_walk_agrees_with_the_dense_reference(
     )
     for pair, side in zip(fitted, ("kv", "kv", "q")):
         assert A._spans_fit(*pair, tq, tk, window, side)
-    got = _kernels(q, k, v, g, *fitted, window)
+    got, traced = _bwd_kernels_traced(
+        lambda: _kernels(q, k, v, g, *fitted, window)
+    )
+    assert traced == TRACED[backward]
     want = _reference(q, k, v, g, window)
     for name, a, b in zip(NAMES, got, want):
         tol = 3e-5 if name in ("o", "lse") else 3e-4
         np.testing.assert_allclose(a, b.reshape(a.shape), atol=tol, err_msg=name)
 
 
-def test_a_window_that_reaches_every_key_is_the_causal_kernel_to_the_bit():
+@pytest.mark.parametrize("backward", BACKWARDS, indirect=True)
+def test_a_window_that_reaches_every_key_is_the_causal_kernel_to_the_bit(backward):
     """Spans from key 0 over every block are the walk without a window,
     and a window no key falls out of masks nothing more."""
     q, k, v, g = _inputs(4, 2, 256, 256, 64, seed=3)
@@ -121,11 +157,110 @@ def test_a_window_that_reaches_every_key_is_the_causal_kernel_to_the_bit():
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+# -- the fused backward against the pair, and where it is taken -------------
+
+#          h, h_kv, tq,  tk,  d,   window, (block_q, block_k) of both
+AGAINST = [
+    pytest.param(8, 2, 512, 512, 128, None, (64, 128), id="full-gqa4-d128"),
+    pytest.param(8, 1, 256, 256, 64, None, (64, 128), id="full-gqa8-d64"),
+    pytest.param(2, 2, 256, 256, 128, None, (64, 128), id="full-mha-d128"),
+    pytest.param(8, 2, 512, 512, 128, 256, (160, 64), id="window-gqa4-d128"),
+    pytest.param(8, 1, 512, 512, 64, 257, (160, 64), id="window+1-gqa8-d64"),
+    pytest.param(4, 2, 128, 512, 64, None, (64, 128), id="offset384"),
+    pytest.param(4, 2, 384, 512, 64, 100, (160, 64), id="offset128-window100"),
+]
+
+
+@pytest.mark.parametrize("h,h_kv,tq,tk,d,window,blocks", AGAINST)
+def test_the_fused_backward_against_dq_and_dkv(
+    monkeypatch, h, h_kv, tq, tk, d, window, blocks
+):
+    """One walk, dk/dv's own: with the pair's dk/dv blocks the fused kernel
+    adds the same tiles' products in the same order, from a tile it holds
+    transposed; dq is summed a kv block at a time where the pair sums a q
+    block's keys. All three agree to float32 rounding here (on the chip, in
+    bfloat16, dk and dv came out the pair's to the bit: PR 34's probes)."""
+    monkeypatch.setattr(A, "_SPAN_ALIGN", ALIGN)
+    q, k, v, g = _inputs(h, h_kv, tq, tk, d, seed=5)
+    fwd = A._fit_blocks(32, 128, tq, tk, window, "kv")
+    rows = A._fit_blocks(*blocks, tq, tk, window, "q")
+    run = lambda: _kernels(q, k, v, g, fwd, fwd, rows, window)  # noqa: E731
+    fused, traced = _bwd_kernels_traced(run)
+    assert traced == TRACED["fused"]
+    monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
+    pair, traced = _bwd_kernels_traced(run)
+    assert traced == TRACED["pair"]
+    for name, a, b in zip(NAMES, fused, pair):
+        if name in ("o", "lse"):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            np.testing.assert_allclose(a, b, atol=3e-5, err_msg=name)
+
+
+def test_a_head_whose_dq_does_not_fit_takes_dq_and_dkv(monkeypatch):
+    """The rule reads shapes, not names: the accumulator's ``tq * d * 4``
+    bytes, its output's two buffers, the blocks and a tile's intermediates
+    against half the core's VMEM. The cells' heads take 20-34 MB of a v5e's
+    128; the same call on a core with 16 MiB, or a head of 131,072 rows,
+    keeps the two kernels, and agrees."""
+    acc, need = A._fused_bwd_vmem(8192, 128, 512, 1024, 2)
+    assert acc == 8192 * 128 * 4 and acc + 2 * 8192 * 128 * 2 < need < 32 << 20
+    assert A._fused_bwd_vmem(8192, 128, 1280, 512, 2)[1] < 40 << 20
+    assert A._fused_bwd_vmem(8192, 64, 512, 1024, 2)[1] < 32 << 20
+    assert A._fused_bwd_vmem(4096, 128, 512, 1024, 2)[1] < 32 << 20
+    assert A._fused_bwd_vmem(131072, 128, 512, 1024, 2)[1] > A._VMEM_V5E // 2
+    assert A._vmem_capacity() == A._VMEM_V5E  # off the chip: the v5e's
+    q, k, v, g = _inputs(4, 2, 256, 256, 64, seed=7)
+    blocks = ((32, 128), (64, 128), (64, 128))
+    want = _reference(q, k, v, g, None)
+    acc, need = A._fused_bwd_vmem(256, 64, 64, 128, 4)
+    for capacity, kernels in (
+        (2 * need, TRACED["fused"]), (2 * need - 2, TRACED["pair"]),
+        (16 << 20, TRACED["fused"]), (1 << 20, TRACED["pair"]),
+    ):
+        monkeypatch.setattr(A, "_vmem_capacity", lambda: capacity)
+        got, traced = _bwd_kernels_traced(
+            lambda: _kernels(q, k, v, g, *blocks, None)
+        )
+        assert traced == kernels, capacity
+        for name, a, b in zip(NAMES, got, want):
+            tol = 3e-5 if name in ("o", "lse") else 3e-4
+            np.testing.assert_allclose(a, b.reshape(a.shape), atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("backward", BACKWARDS, indirect=True)
+@pytest.mark.parametrize("h,h_kv,tq,tk", [
+    pytest.param(4, 4, 256, 256, id="mha"),
+    pytest.param(8, 2, 256, 256, id="gqa4"),
+    pytest.param(8, 2, 128, 256, id="gqa4-offset128"),
+])
+def test_block_grads_with_external_residuals_past_the_whole_kv_limit(
+    monkeypatch, backward, h, h_kv, tq, tk
+):
+    """Ring attention's building block: ``lse`` and ``delta`` of the global
+    softmax come from outside, and past the whole-KV compile limit the call
+    is the flash2 backward's, fused or not by the same rule."""
+    monkeypatch.setattr(A, "_flash_max_seq", lambda: 64)
+    q, k, v, g = _inputs(h, h_kv, tq, tk, 64, seed=9)
+    scale = 64 ** -0.5
+    o, lse = A.attention_reference_with_lse(q, k, v, causal=True, scale=scale)
+    delta = jnp.sum(g * o, axis=-1)
+    got, traced = _bwd_kernels_traced(lambda: A.flash_block_grads(
+        q, k, v, g, lse, delta, causal=True, block_q=64, block_k=128
+    ))
+    assert traced == TRACED[backward]
+    want = _reference(q, k, v, g, None)[2:]
+    for name, a, b in zip(NAMES[2:], got, want):
+        np.testing.assert_allclose(np.asarray(a), b, atol=3e-4, err_msg=name)
+
+
 # -- spans: dead steps copy nothing, live pairs are walked once --------------
 
 #        tq,   tk,   window, (block_q, block_k) of fwd/dq, of dkv
 SPANS = [
     pytest.param(8192, 8192, None, (256, 1024), (512, 1024), id="full"),
+    pytest.param(8192, 8192, None, (256, 1024), (1024, 1024), id="full-bwd"),
+    pytest.param(4096, 4096, None, (256, 1024), (1024, 1024), id="full4096-bwd"),
     pytest.param(8192, 8192, 2048, (512, 2560), (1280, 512), id="window2048"),
     pytest.param(8192, 8192, 2048, (256, 2304), (512, 1024), id="window2048-dq"),
     pytest.param(8192, 8192, 1000, (512, 1536), (768, 512), id="window1000"),
@@ -191,21 +326,22 @@ def test_spans_cover_what_the_mask_leaves_and_dead_steps_hold_still(
 
 def test_a_windowed_calls_blocks_come_from_the_window_and_the_shapes():
     """The published window at T = 8192: one forward update of 2560 keys a
-    512 rows, dq one of 2304 a 256 rows, dk/dv two of 1280 rows a 512 keys;
+    512 rows, the backward two spans of 1280 rows a 512 keys (and where a
+    head's dq does not fit the chip, dq one of 2304 keys a 256 rows);
     another window or length moves the spans, not the rule; a window that
     reaches every key takes blocks that divide the sequence; what a caller
     gives wins."""
     blocks = lambda *a: tuple(  # noqa: E731
-        A._flash2_blocks(kind, *a) for kind in ("fwd", "dq", "dkv")
+        A._flash2_blocks(kind, *a) for kind in ("fwd", "dq", "bwd")
     )
     assert blocks(8192, 8192, 2048) == ((512, 2560), (256, 2304), (1280, 512))
     assert blocks(8192, 8192, 1000) == ((512, 1536), (256, 1280), (768, 512))
     assert blocks(32768, 32768, 4096) == ((512, 2304), (256, 2176), (1152, 512))
-    assert blocks(8192, 8192, None) == ((256, 1024), (512, 1024), (512, 1024))
+    assert blocks(8192, 8192, None) == ((256, 1024), (512, 1024), (1024, 1024))
     for bq, bk in blocks(8192, 8192, 8192) + blocks(32, 32, 8):
         assert 8192 % bq == 0 and 8192 % bk == 0
     assert A._flash2_blocks("fwd", 8192, 8192, 2048, (None, 1024)) == (512, 1024)
-    assert A._flash2_blocks("dkv", 8192, 8192, 2048, (256, None)) == (256, 512)
+    assert A._flash2_blocks("bwd", 8192, 8192, 2048, (256, None)) == (256, 512)
     # steps: one, one and two where whole blocks of 1024 took three and six
     assert A._span_steps(2048, 512, 2560, 8192, 8192)[0] == 1
     assert A._span_steps(2048, 256, 2304, 8192, 8192)[0] == 1
@@ -228,9 +364,10 @@ CENSUS = [
     pytest.param(8192, 8192, 256, 1024, None, "kv", id="granite-trinity-full-fwd"),
     pytest.param(8192, 8192, 512, 1024, None, "kv", id="granite-trinity-full-dq"),
     pytest.param(8192, 8192, 512, 1024, None, "q", id="granite-trinity-full-dkv"),
+    pytest.param(8192, 8192, 1024, 1024, None, "q", id="granite-trinity-full-bwd"),
     pytest.param(8192, 8192, 512, 2560, 2048, "kv", id="trinity-window-fwd"),
     pytest.param(8192, 8192, 256, 2304, 2048, "kv", id="trinity-window-dq"),
-    pytest.param(8192, 8192, 1280, 512, 2048, "q", id="trinity-window-dkv"),
+    pytest.param(8192, 8192, 1280, 512, 2048, "q", id="trinity-window-bwd"),
 ]
 
 
@@ -292,12 +429,24 @@ def test_the_census_at_the_cells_shapes():
     assert A.tile_census(64, 64, 16, 16, False) == {
         "dead": 0.0, "interior": 1.0, "edge": 0.0,
     }
+    # the fused backward, a kv block's rows: 1024 x 1024 walks 36 of 64 tiles
+    # without a window, eight of them on the diagonal; under the window two
+    # spans of 1280 rows a 512 keys (the last kv block sees one), nearly all
+    # of them cut by the diagonal or by the window's old side
+    bwd = A.tile_census(8192, 8192, *A._flash2_blocks("bwd", 8192, 8192, None), True, side="q")
+    assert 1 - bwd["dead"] == pytest.approx(36 / 64)
+    assert bwd["edge"] / (1 - bwd["dead"]) == pytest.approx(8 / 36)
+    windowed = A.tile_census(8192, 8192, *A._flash2_blocks("bwd", 8192, 8192, 2048), True, 2048, "q")
+    assert 1 - windowed["dead"] == pytest.approx(30 * 1280 * 512 / 8192 ** 2)
+    assert windowed["interior"] < 0.01 < seen < 1 - windowed["dead"]
 
 
-def test_one_attn_tiles_instant_a_traced_shape():
+@pytest.mark.parametrize("backward", BACKWARDS, indirect=True)
+def test_one_attn_tiles_instant_a_traced_shape(backward):
     """Each flash2 wrapper notes its census once for a shape it is traced
     at: kernel, shapes, blocks, window, the three shares and the masked
-    share of what is walked."""
+    share of what is walked; the fused backward also the bytes of the dq
+    accumulator it holds, and a call that fell back to dq and dk/dv theirs."""
     A._note_tiles.cache_clear()
     ring = obs_trace.get_tracer()
     seen = lambda: [  # noqa: E731
@@ -309,11 +458,17 @@ def test_one_attn_tiles_instant_a_traced_shape():
     for _ in range(2):  # the second trace of the shape notes nothing
         _kernels(q, k, v, g, (16, 32), (32, 32), (32, 48), 40)
     new = seen()[before:]
-    assert [a["kernel"] for a in new] == ["flash2_fwd", "flash2_dq", "flash2_dkv"]
-    for args, (bq, bk), side in zip(new, ((16, 32), (32, 32), (32, 48)), ("kv", "kv", "q")):
-        want = A.tile_census(96, 96, bq, bk, True, 40, side)
-        assert {key: args[key] for key in want} == want
+    want = {
+        "fused": [("flash2_fwd", (16, 32), "kv"), ("flash2_bwd", (32, 48), "q")],
+        "pair": [("flash2_fwd", (16, 32), "kv"), ("flash2_dq", (32, 32), "kv"),
+                 ("flash2_dkv", (32, 48), "q")],
+    }[backward]
+    assert [a["kernel"] for a in new] == [name for name, _, _ in want]
+    for args, (name, (bq, bk), side) in zip(new, want):
+        census = A.tile_census(96, 96, bq, bk, True, 40, side)
+        assert {key: args[key] for key in census} == census
         assert (args["block_q"], args["block_k"], args["window"]) == (bq, bk, 40)
         assert args["masked_share"] == pytest.approx(
-            want["edge"] / (want["edge"] + want["interior"])
+            census["edge"] / (census["edge"] + census["interior"])
         )
+        assert args.get("acc_bytes") == (96 * 8 * 4 if name == "flash2_bwd" else None)

@@ -73,7 +73,9 @@ OLMOE = (4, 16, 16, 4096, 128)     # olmoe_1b_7b.steady's
 FWD_NAME = {"flash": "_flash_kernel", "flash2": "_flash2_kernel"}
 BWD_NAMES = {
     "flash": ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"),
-    "flash2": ("_flash2_bwd_dq_kernel", "_flash2_bwd_dkv_kernel"),
+    # one fused kernel: a head's float32 dq accumulator (4 MB at 8192 x 128)
+    # stays in VMEM under a limit set from the shapes
+    "flash2": ("_flash2_bwd_kernel",),
 }
 
 CASES = [
@@ -157,7 +159,7 @@ def test_windowed_kernel_compiles_for_v5e(one_chip, direction, window):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
-    fwd, dq, dkv = (A._flash2_blocks(kind, t, t, window) for kind in ("fwd", "dq", "dkv"))
+    fwd, dq, dkv = (A._flash2_blocks(kind, t, t, window) for kind in ("fwd", "dq", "bwd"))
     if window == 2048:
         assert (fwd, dq, dkv) == ((512, 2560), (256, 2304), (1280, 512))
         assert A._span_steps(window, *fwd, t, t)[0] == 1
@@ -179,6 +181,39 @@ def test_windowed_kernel_compiles_for_v5e(one_chip, direction, window):
     assert _kernel_names(lowered.as_text()) == want
     compiled = lowered.compile()
     assert compiled.as_text().count("tpu_custom_call") == len(want)
+
+
+@pytest.mark.parametrize("shape,window,capacity", [
+    pytest.param(TRINITY, None, 0, id="trinity_full"),
+    pytest.param(TRINITY, 2048, 0, id="trinity_w2048"),
+    pytest.param(GRANITE, None, 0, id="granite"),
+    # 4160 = 65 x 64: the largest block that divides it is half a lane tile,
+    # which lse and delta cannot ride along the lanes
+    pytest.param((1, 4, 2, 4160, 64), None, None, id="rows_of_half_a_lane_tile"),
+])
+def test_the_two_kernel_backward_compiles_for_v5e(
+    one_chip, monkeypatch, shape, window, capacity
+):
+    """Where a head's dq accumulator does not fit the core's VMEM, or a span
+    of rows is not whole lane tiles, the call keeps dq and dk/dv, with the
+    blocks the dispatch gives them, under Mosaic's default limit."""
+    if capacity is not None:
+        monkeypatch.setattr(A, "_vmem_capacity", lambda: capacity)
+    b, h, h_kv, t, d = shape
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, kv, row = sds((b, h, t, d)), sds((b, h_kv, t, d)), sds((b * h, t), jnp.float32)
+    dq, dkv = (A._flash2_blocks(kind, t, t, window) for kind in ("dq", "bwd"))
+    fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
+        q, k, v, g, lse, delta, True, d ** -0.5, *dq, False, window, dkv
+    )
+    lowered = jax.jit(fn).lower(q, kv, kv, q, row, row)
+    assert _kernel_names(lowered.as_text()) == [
+        "_flash2_bwd_dq_kernel", "_flash2_bwd_dkv_kernel"
+    ]
+    assert lowered.compile().as_text().count("tpu_custom_call") == 2
 
 
 def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip):

@@ -355,10 +355,13 @@ def qkvw():
 
 @pytest.mark.parametrize("heads", list(GROUPS))
 @pytest.mark.parametrize("span", list(WINDOWS))
-@pytest.mark.parametrize(
-    "route", ["dense", "flash", "flash2_forward", "flash2_dq", "flash2_dkv"]
-)
-def test_the_window_in_every_route_against_a_dense_mask(qkvw, route, span, heads):
+@pytest.mark.parametrize("route", [
+    "dense", "flash", "flash2_forward", "flash2_dq", "flash2_dkv",
+    "flash2_pair_dq", "flash2_pair_dkv",
+])
+def test_the_window_in_every_route_against_a_dense_mask(
+    monkeypatch, qkvw, route, span, heads
+):
     q, k, v, w = qkvw[heads]
     window = WINDOWS[span]
     scale = HD ** -0.5
@@ -394,11 +397,15 @@ def test_the_window_in_every_route_against_a_dense_mask(qkvw, route, span, heads
             np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
         return
     b, h = q.shape[:2]
-    dq, dk, dv = A._flash2_backward_kernels(
-        q, k, v, w, lse.reshape(b * h, T), A._bwd_delta(w, got, b, h, T, HD),
-        True, scale, BLOCK, BLOCK, True, window,
-    )
-    if route == "flash2_dq":
+    if "pair" in route:  # a head whose dq no VMEM holds: dq and dk/dv apart
+        monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
+    with mock.patch.object(A, "_flash2_bwd_kernel", wraps=A._flash2_bwd_kernel) as fused:
+        dq, dk, dv = A._flash2_backward_kernels(
+            q, k, v, w, lse.reshape(b * h, T), A._bwd_delta(w, got, b, h, T, HD),
+            True, scale, BLOCK, BLOCK, True, window,
+        )
+    assert fused.called == ("pair" not in route)
+    if route.endswith("dq"):
         _close(dq, want_dq, tol=1e-5)
     else:
         _close(dk, want_dk, tol=1e-5)
