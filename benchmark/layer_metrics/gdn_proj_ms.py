@@ -1,0 +1,16 @@
+"""Device 0's time a traced step under ``gdn_proj`` (the in projection ``[q | k | v | gate | b | a]`` and the out projection; forward,
+recomputation and backward alike), by the program's
+``obs/profile.py:step_scopes()``."""
+
+from benchmark import gdn_timeline
+
+NAME = "gdn_proj_ms"
+UNIT = "ms"
+BETTER = "lower"
+LAYER = "Model + kernels"
+MOVES = "throughput"
+SOURCE = "device_trace"
+
+
+def read(run):
+    return gdn_timeline.scope_ms(run, "gdn_proj")

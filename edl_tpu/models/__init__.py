@@ -1,5 +1,6 @@
 from edl_tpu.models.ctr import CTR_EMBEDDING_RULES, DeepFM, binary_cross_entropy_loss
 from edl_tpu.models.mlp import MLP, LinearRegression
+from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import MOE_EP_RULES, DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.resnet import (
@@ -33,4 +34,6 @@ __all__ = [
     "ArchSpec",
     "Mamba2Mixer",
     "MambaSpec",
+    "GatedDeltaMixer",
+    "GatedDeltaSpec",
 ]

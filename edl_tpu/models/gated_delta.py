@@ -1,0 +1,140 @@
+"""Gated-delta-rule mixer: the linear-attention layer of a hybrid decoder.
+
+The layer of Yang, Kautz and Hatamizadeh (arXiv:2412.06464) as
+``flash-linear-attention``'s ``GatedDeltaNet`` and Hugging Face's Qwen3-Next
+lay it out, for the block's input ``x`` ``[B, T, d_model]``, ``H`` heads of
+``d_k`` (queries, keys) and ``d_v`` (values)::
+
+    [q | k | v | gate | b | a] = W_in x        widths H d_k | H d_k | H d_v | H d_v | H | H
+    [q | k | v] = silu(causal depthwise conv_{d_conv}([q | k | v]))   no bias (ops/causal_conv.py)
+    q = q / sqrt(|q|^2 + 1e-6) * d_k^-1/2;  k = k / sqrt(|k|^2 + 1e-6)         per head, float32
+    beta = sigmoid(b) * (2 if neg_eigval else 1)     per head: I - beta k k^T then has
+                                                     an eigenvalue in (-1, 1), not (0, 1)
+    g    = -exp(A_log) * softplus(a + dt_bias)       per head, the log of the decay
+    o    = gated_delta_rule(q, k, v, g, beta)        (ops/gated_delta.py)
+    o    = RMSNorm_{d_v}(o) * w * silu(gate)         per head, one scale w of d_v for all
+    out  = W_out o
+
+The norm comes first and the gate second, each head for itself: not the
+Mamba-2 mixer's ``RMSNorm(y * silu(z))`` over the whole width. The three short
+convolutions are one over the leading ``2 H d_k + H d_v`` columns of the in
+projection's output, read in place by ``causal_conv_silu``.
+
+The device time of its four parts carries the names ``gdn_proj`` (both
+projections), ``gdn_conv``, ``gdn_scan`` (the L2 norms, ``beta``, ``g`` and the
+chunked rule) and ``gdn_gate`` (``jax.named_scope``;
+``obs/profile.py:step_scopes`` joins them to a trace). Into ``"metrics"`` it
+sows ``gdn_decay_mean`` (the mean of ``exp(g)`` over tokens and heads: how fast
+the state forgets), ``gdn_beta_mean`` and ``gdn_state_absmax`` (the largest
+magnitude in the state after the last step: the health of a rule whose
+eigenvalues may be negative), which the step averages over the layers and the
+loop exports as ``edl_train_<name>`` gauges; into ``"intermediates"`` the
+rule's own inputs.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from edl_tpu.models.mamba import _dt_bias_init
+from edl_tpu.ops.causal_conv import causal_conv_silu
+from edl_tpu.ops.gated_delta import gated_delta_rule
+
+GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_scan", "gdn_gate")
+L2_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaSpec:
+    """The shape of a :class:`GatedDeltaMixer`, as one hashable field."""
+
+    num_heads: int           # H, of keys and of values alike
+    key_dim: int             # d_k
+    value_dim: int           # d_v
+    d_conv: int = 4
+    chunk: int = 64          # steps a chunk of the rule: a power of two
+    neg_eigval: bool = True  # beta in (0, 2) and not (0, 1)
+
+
+def _unit(m):
+    """``m / sqrt(|m|^2 + 1e-6)`` over the last axis."""
+    return m * jax.lax.rsqrt(jnp.sum(m * m, axis=-1, keepdims=True) + L2_EPS)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log U(0, 16)`` (from 1e-6, so that the log is finite): the layer's
+    own, a spread of decay rates."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-6, 16.0))
+
+
+class GatedDeltaMixer(nn.Module):
+    spec: GatedDeltaSpec
+    dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        s = self.spec
+        batch, t, d_model = x.shape
+        h, d_k, d_v = s.num_heads, s.key_dim, s.value_dim
+        conv_dim = 2 * h * d_k + h * d_v
+        f32 = jnp.float32
+        dense = lambda width, name: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=self.dtype, name=name
+        )
+
+        with jax.named_scope("gdn_proj"):
+            proj = dense(conv_dim + h * d_v + 2 * h, "in_proj")(x)
+        gate = proj[..., conv_dim:conv_dim + h * d_v]
+        b, a = jnp.split(proj[..., conv_dim + h * d_v:].astype(f32), 2, axis=-1)
+
+        with jax.named_scope("gdn_conv"):
+            kernel = self.param(
+                "conv_kernel",
+                lambda key, shape: jax.random.uniform(key, shape, f32, -0.5, 0.5),
+                (s.d_conv, conv_dim),
+            )
+            qkv = causal_conv_silu(proj, kernel, None, offset=0)
+
+        a_log = self.param("A_log", _a_log_init, (h,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (h,))
+        scale = self.param("norm", nn.initializers.ones, (d_v,))
+
+        with jax.named_scope("gdn_scan"):
+            q, k, v = jnp.split(qkv, [h * d_k, 2 * h * d_k], axis=-1)
+            q = _unit(q.reshape(batch, t, h, d_k).astype(f32)) * d_k ** -0.5
+            k = _unit(k.reshape(batch, t, h, d_k).astype(f32))
+            q, k = q.astype(self.dtype), k.astype(self.dtype)
+            v = v.reshape(batch, t, h, d_v)
+            beta = jax.nn.sigmoid(b) * (2.0 if s.neg_eigval else 1.0)
+            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+            # what the rule's backward keeps (a chunk's system and inverse,
+            # the 128 states a chunk inherits: 0.8 GB a layer at 8192 steps of
+            # 15 heads) is recomputed when the backward reaches it, and so not
+            # held while the gate, the out projection and the block's
+            # feed-forward are still unwinding
+            rule = jax.checkpoint(functools.partial(
+                gated_delta_rule, chunk=s.chunk, return_final_state=True
+            ))
+            o, state = rule(q, k, v, g, beta)
+        self.sow("metrics", "gdn_decay_mean", jnp.mean(jnp.exp(g)))
+        self.sow("metrics", "gdn_beta_mean", jnp.mean(beta))
+        self.sow("metrics", "gdn_state_absmax", jnp.max(jnp.abs(state)))
+        self.sow("intermediates", "rule_inputs", (q, k, v, g, beta))
+
+        with jax.named_scope("gdn_gate"):
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(o * o, axis=-1, keepdims=True) + self.norm_eps
+            )
+            o = o * scale * nn.silu(gate.reshape(batch, t, h, d_v).astype(f32))
+            o = o.reshape(batch, t, h * d_v).astype(self.dtype)
+
+        with jax.named_scope("gdn_proj"):
+            return dense(d_model, "out_proj")(o)

@@ -4,15 +4,18 @@ Net-new model family versus the reference (its largest workload is
 ResNet50/ERNIE fine-tune; SURVEY §5 notes long-context is absent), built
 TPU-first:
 
-- pre-norm blocks with RMSNorm, RoPE positions (a choice: ``ArchSpec.rope``),
-  SwiGLU MLP — all large-matmul-dominated so the MXU stays busy; bf16
-  compute, fp32 params;
+- blocks with RMSNorm (before each branch, before and after it, or after
+  it only: ``ArchSpec.post_norms``), RoPE positions (a choice:
+  ``ArchSpec.rope``), SwiGLU MLP — all large-matmul-dominated so the MXU
+  stays busy; bf16 compute, fp32 params;
 - a block's sequence mixer is, by ``ArchSpec.layer_types``, full causal
-  attention, attention over a sliding window, or a Mamba-2 state-space
-  layer (``models/mamba.py``); its feed-forward a SwiGLU or an expert
+  attention, attention over a sliding window, a Mamba-2 state-space
+  layer (``models/mamba.py``) or a gated-delta-rule linear-attention layer
+  (``models/gated_delta.py``); its feed-forward a SwiGLU or an expert
   layer (``models/moe.py``), the leading ``ArchSpec.dense_layers`` blocks
   of an expert model dense: one ``TransformerLM`` runs dense, expert,
-  hybrid and mixed-window configurations;
+  hybrid (either recurrent mixer beside attention) and mixed-window
+  configurations;
 - attention is pluggable: the Pallas flash kernel locally, or ring
   attention over the ``sp`` mesh axis for sequences longer than one
   device's HBM (``edl_tpu.parallel.ring``);
@@ -35,6 +38,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.ops.attention import attention
@@ -62,15 +66,19 @@ class ArchSpec:
 
     ``layer_types`` names each block's sequence mixer: ``"attention"``
     (causal over the whole sequence), ``"sliding_attention"`` (causal over
-    the ``sliding_window`` newest keys, the query's own among them) or
-    ``"mamba"`` (then ``mamba`` gives the layer's shape); its length is the
-    model's depth. ``rope`` rotates q and k in every attention layer
-    (``True``), in none (``False``: no position term at all) or in the
+    the ``sliding_window`` newest keys, the query's own among them),
+    ``"mamba"`` (then ``mamba`` gives the layer's shape) or
+    ``"linear_attention"`` (the gated delta rule; then ``gated_delta`` gives
+    the layer's shape); its length is the model's depth. ``rope`` rotates q
+    and k in every attention layer (``True``), in none (``False``: no position term at all) or in the
     windowed layers only (``"sliding"``: the full layers then see order
     through the causal mask alone). ``dense_layers`` leading blocks of a
     model with an expert layer (``TransformerLM.moe``) keep the dense
-    SwiGLU of ``d_ff``. ``post_norms`` puts an RMSNorm after each branch as
-    well as before it (``x + N(branch(N(x)))``); ``attn_gate`` multiplies
+    SwiGLU of ``d_ff``. ``post_norms`` is one field with three forms for
+    where a block's RMSNorms sit: ``False`` before each branch (``x +
+    branch(N(x))``), ``True`` before and after it (``x + N(branch(N(x)))``),
+    ``"only"`` after it and not before (``x + N(branch(x))``: the branch
+    reads the residual stream as it is); ``attn_gate`` multiplies
     the heads' outputs by ``sigmoid(x W_g)``, elementwise, before the out
     projection. The three multipliers are Granite's: the embedding's
     output times ``embedding_multiplier``, each residual branch (mixer and
@@ -81,6 +89,7 @@ class ArchSpec:
 
     layer_types: Optional[Tuple[str, ...]] = None
     mamba: Optional[MambaSpec] = None
+    gated_delta: Optional[GatedDeltaSpec] = None
     head_dim: Optional[int] = None      # None: d_model / num_heads
     rope: Union[bool, str] = True       # True, False or "sliding"
     attn_scale: Optional[float] = None  # None: head_dim ** -0.5
@@ -90,8 +99,11 @@ class ArchSpec:
     logits_scaling: float = 1.0
     sliding_window: Optional[int] = None
     dense_layers: int = 0
-    post_norms: bool = False
+    post_norms: Union[bool, str] = False  # False, True or "only"
     attn_gate: bool = False
+
+
+LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention")
 
 
 def _scope(name: Optional[str]):
@@ -334,12 +346,23 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         arch = self.arch
-        h = RMSNorm(self.norm_eps, name="ln1")(x)
+        if arch.post_norms not in (False, True, "only"):
+            raise ValueError("unknown post_norms %r" % (arch.post_norms,))
+        before, after = arch.post_norms != "only", bool(arch.post_norms)
+        h = RMSNorm(self.norm_eps, name="ln1")(x) if before else x
         if self.mixer == "mamba":
             if self.decode:
                 raise NotImplementedError("a Mamba-2 block has no decode cache")
             mixed = Mamba2Mixer(
                 arch.mamba, self.dtype, self.norm_eps, name="mamba"
+            )(h)
+        elif self.mixer == "linear_attention":
+            if self.decode:
+                raise NotImplementedError(
+                    "a gated-delta-rule block has no decode cache"
+                )
+            mixed = GatedDeltaMixer(
+                arch.gated_delta, self.dtype, self.norm_eps, name="gdn"
             )(h)
         elif self.mixer in ("attention", "sliding_attention"):
             sliding = self.mixer == "sliding_attention"
@@ -360,11 +383,14 @@ class Block(nn.Module):
                 name="attn",
             )(h, positions)
         else:
-            raise ValueError("unknown layer type %r" % (self.mixer,))
-        if arch.post_norms:
+            raise ValueError(
+                "unknown layer type %r: a block's mixer is one of %s"
+                % (self.mixer, ", ".join(LAYER_TYPES))
+            )
+        if after:
             mixed = RMSNorm(self.norm_eps, name="ln1_post")(mixed)
         x = x + _times(mixed, arch.residual_multiplier)
-        h = RMSNorm(self.norm_eps, name="ln2")(x)
+        h = RMSNorm(self.norm_eps, name="ln2")(x) if before else x
         if self.moe is not None:
             ff = DroplessMoE(
                 **dataclasses.asdict(self.moe), dtype=self.dtype, name="moe"
@@ -376,7 +402,7 @@ class Block(nn.Module):
             )(h)
         else:
             ff = SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
-        if arch.post_norms:
+        if after:
             ff = RMSNorm(self.norm_eps, name="ln2_post")(ff)
         return x + _times(ff, arch.residual_multiplier)
 
@@ -491,9 +517,9 @@ class TransformerLM(nn.Module):
     # SwitchMoE until ROADMAP D6
     moe: Optional[MoESpec] = None
     # the layer pattern (mixers, windows, leading dense layers), the
-    # attention's head size / positions / score scale / gate, norms after
-    # the branches, a tied head and Granite's multipliers; None: the dense
-    # model
+    # attention's head size / positions / score scale / gate, where a
+    # block's norms sit, a tied head and Granite's multipliers; None: the
+    # dense model
     arch: Optional[ArchSpec] = None
 
     @nn.compact

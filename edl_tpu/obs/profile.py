@@ -315,9 +315,13 @@ def step_scopes(scopes: Sequence[str] = MOE_SCOPES) -> Dict[str, str]:
     which of a device trace's events ran under which of ``scopes`` — by
     default the expert layer's ``moe_route`` / ``moe_experts`` /
     ``moe_combine``; the models also name ``moe_shared``, the Mamba-2
-    mixer's ``ssm_*`` and, in a model of windowed and full attention layers,
-    ``attn_window`` / ``attn_full`` / ``attn_gate``. {} without a step or
-    for a model that enters none of ``scopes``."""
+    mixer's ``ssm_proj`` / ``ssm_conv`` / ``ssm_scan`` / ``ssm_gate``, the
+    gated-delta-rule mixer's ``gdn_proj`` / ``gdn_conv`` / ``gdn_scan`` /
+    ``gdn_gate`` and, in a model of windowed and full attention layers,
+    ``attn_window`` / ``attn_full`` / ``attn_gate``. A caller asks for the
+    scopes of one mixer or layer at a time; an instruction under two of the
+    asked scopes counts under the innermost. {} without a step or for a
+    model that enters none of ``scopes``."""
     key = tuple(scopes)
     if key not in _step_scopes and _step_executable is not None:
         try:

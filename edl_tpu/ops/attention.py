@@ -900,12 +900,17 @@ _VMEM_V5E = 128 << 20
 def _vmem_capacity() -> int:
     """Bytes of VMEM a TensorCore has: the chip's own where there is one,
     the v5e's (the chip the blocks were swept on) in interpret mode and in
-    a compile for a described chip, which see the CPU."""
+    a compile for a described chip, which see the CPU (also where the
+    caller steers ``jax.default_backend`` to ``tpu`` for that compile, as
+    ``benchmark/tools/compile_for_v5e.py`` does: no chip answers then)."""
     if jax.default_backend() != "tpu":
         return _VMEM_V5E
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.get_tpu_info().vmem_capacity_bytes
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:  # "Unsupported TPU device kind: cpu"
+        return _VMEM_V5E
 
 
 def _fused_bwd_vmem(tq, d, block_q, block_k, itemsize):

@@ -1,8 +1,13 @@
 """Depthwise causal convolution with its bias and SiLU, as one pass.
 
 ``causal_conv_silu(x, kernel, bias)`` is the short convolution in front of a
-Mamba-2 scan (``models/mamba.py``), for ``x`` ``[B, T, C]``, ``kernel``
-``[d_conv, C]`` and ``bias`` ``[C]``::
+recurrent mixer's scan, for ``x`` ``[B, T, C]``, ``kernel`` ``[d_conv, C]`` and
+``bias`` ``[C]``. The Mamba-2 mixer (``models/mamba.py``) calls it with a bias
+on the channels of ``xBC`` in the middle of its in projection's output
+(``offset`` = the width of ``z``); the gated-delta-rule mixer
+(``models/gated_delta.py``) with ``bias=None`` (the kernels then add a vector of
+zeros, whose gradient nobody asks for) on the LEADING channels ``[q | k | v]``
+of its own (``offset=0``, so the channel block is what divides ``C`` alone)::
 
     pre_t = sum_k kernel[k] * x_{t - (d_conv - 1) + k} + bias     zeros before 0
     y_t   = pre_t * sigmoid(pre_t)                                in x's dtype

@@ -69,6 +69,7 @@ GRANITE = (1, 32, 8, 8192, 64)     # granite_4_0_h_micro.steady's attention laye
 TRINITY = (1, 32, 4, 8192, 128)    # trinity_mini.steady's attention layers
 MISTRAL = (2, 32, 8, 4096, 128)    # mistral_7b.steady's, a half batch a call
 OLMOE = (4, 16, 16, 4096, 128)     # olmoe_1b_7b.steady's
+OLMO_HYBRID = (1, 15, 15, 8192, 128)  # olmo_hybrid_7b.steady's full layer: MHA
 
 FWD_NAME = {"flash": "_flash_kernel", "flash2": "_flash2_kernel"}
 BWD_NAMES = {
@@ -97,6 +98,7 @@ CASES = [
         # the whole-KV backward)
         ("mistral", MISTRAL, ("flash", "flash2")),
         ("olmoe", OLMOE, ("flash", "flash2")),
+        ("olmo_hybrid", OLMO_HYBRID, ("flash2",)),
     )
     for family in families
     for direction in ("fwd", "bwd")
@@ -266,6 +268,109 @@ def test_causal_conv_kernels_compile_for_v5e_at_granites_widths(one_chip):
     compiled = lowered.compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
+def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip):
+    """The chunked rule with its backward at one sequence of 8192, the 15
+    heads of 96 / 192 the cell holds, chunks of 64: plain XLA, so what the
+    chip's compiler can refuse is the memory. A chunk's float32 system is
+    31 MB a layer and the 128 states a chunk inherits 142 MB; with the
+    inverse's own backward, value and gradients together stay under 2 GB
+    (all 30 heads: 2.7 GB) of the 5 GB the step has beside its parameters."""
+    from edl_tpu.ops import gated_delta_rule
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, h, d_k, d_v = 8192, 15, 96, 192
+    args = (sds((1, t, h, d_k)), sds((1, t, h, d_k)), sds((1, t, h, d_v)),
+            sds((1, t, h), jnp.float32), sds((1, t, h), jnp.float32))
+
+    def value_and_grads(w, *a):
+        out, vjp = jax.vjp(lambda *a: gated_delta_rule(*a, chunk=64), *a)
+        return (out, *vjp(w))
+
+    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, d_v)), *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+def test_causal_conv_kernels_compile_for_v5e_at_the_hybrids_widths(one_chip):
+    """The kernels' second shape: the 5760 channels of ``[q | k | v]`` (15
+    heads) at the START of the in projection's 8670 (offset 0, so the blocks
+    are what divides the channels alone), no bias (zeros stand in), one
+    sequence of 8192."""
+    cc = importlib.import_module("edl_tpu.ops.causal_conv")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, wide, c, offset = 8192, 8670, 5760, 0
+    blocks = cc._blocks(sds((1, t, wide)), sds((4, c), jnp.float32), offset)
+    assert blocks == (64, 8192)
+
+    def value_and_grads(xt, w, b, dy):
+        y = cc._forward(xt, w, b, offset, blocks, False)
+        return (y, *cc._backward(xt, w, b, dy, offset, blocks, False))
+
+    lowered = jax.jit(value_and_grads).lower(
+        sds((1, wide, t)), sds((c, 4), jnp.float32), sds((c, 1), jnp.float32),
+        sds((1, c, t)),
+    )
+    assert _kernel_names(lowered.as_text()) == ["causal_conv_fwd", "causal_conv_bwd"]
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_a_linear_attention_step_on_the_tpu_path_names_its_kernels_under_gdn_conv(one_chip):
+    """A toy linear-attention hybrid of a shape the kernels take (256 channels
+    of ``[q | k | v]`` at column 0 of 456, 128 steps), lowered as the chip
+    lowers it: each linear layer holds the forward convolution kernel three
+    times (the value, the block's recomputation, the mixer's own) and the
+    backward kernel once under ``gdn_conv``, and the full layer's flash
+    kernels stay outside every ``gdn_*`` scope."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, GatedDeltaSpec, TransformerLM
+    from edl_tpu.models.gated_delta import GDN_SCOPES
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers = ("linear_attention", "attention")
+    lm = TransformerLM(
+        vocab_size=64, d_model=64, num_heads=4, num_kv_heads=4, num_layers=len(layers),
+        d_ff=48, dtype=jnp.bfloat16, remat=True, qk_norm=True,
+        arch=ArchSpec(
+            layer_types=layers, rope=False, post_norms="only",
+            gated_delta=GatedDeltaSpec(num_heads=4, key_dim=16, value_dim=32, chunk=32),
+        ),
+    )
+    tokens = np.zeros((1, 128), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    assert {"causal_conv_fwd", "causal_conv_bwd"} <= set(_kernel_names(lowered.as_text()))
+    text = lowered.compile().as_text()
+    table = obs_profile.scopes_of_hlo(text, GDN_SCOPES)
+    convs = sorted(name.split(".")[0] for name in table if name.startswith("causal_conv_"))
+    assert convs.count("causal_conv_bwd") == 1 and convs.count("causal_conv_fwd") >= 2
+    assert {table[name] for name in table if name.startswith("causal_conv_")} == {"gdn_conv"}
+    assert set(table.values()) == set(GDN_SCOPES)
+    custom = [ln for ln in text.splitlines() if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(custom) > len(convs)                 # the flash kernels are there too
 
 
 def test_a_hybrid_step_on_the_tpu_path_runs_the_conv_kernels_under_ssm_conv(one_chip):
