@@ -30,6 +30,14 @@ magnitude in the state after the last step: the health of a rule whose
 eigenvalues may be negative), which the step averages over the layers and the
 loop exports as ``edl_train_<name>`` gauges; into ``"intermediates"`` the
 rule's own inputs.
+
+For a remat policy around the block the rule's ``o`` and final state bear the
+name ``gdn_out`` and, inside the rule, what its sequential carry leaves the
+name ``gdn_carry`` and every chunk's ``T`` ``gdn_inverse``
+(``ops/gated_delta.py:REMAT_NAMES``): a policy that saves them
+(``TransformerLM.remat_policy`` ``"save_flash"``) runs the carry's loop once
+forward and once in reverse a layer and the solve once; one that saves none
+runs the forward loop and the solve again when the block is recomputed.
 """
 
 from __future__ import annotations
@@ -41,10 +49,11 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from edl_tpu.models.mamba import _dt_bias_init
 from edl_tpu.ops.causal_conv import causal_conv_silu
-from edl_tpu.ops.gated_delta import gated_delta_rule
+from edl_tpu.ops.gated_delta import OUT_NAME, REMAT_NAMES, gated_delta_rule
 
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_scan", "gdn_gate")
 L2_EPS = 1e-6
@@ -114,15 +123,29 @@ class GatedDeltaMixer(nn.Module):
             v = v.reshape(batch, t, h, d_v)
             beta = jax.nn.sigmoid(b) * (2.0 if s.neg_eigval else 1.0)
             g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
-            # what the rule's backward keeps (a chunk's system and inverse,
-            # the 128 states a chunk inherits: 0.8 GB a layer at 8192 steps of
-            # 15 heads) is recomputed when the backward reaches it, and so not
-            # held while the gate, the out projection and the block's
-            # feed-forward are still unwinding
-            rule = jax.checkpoint(functools.partial(
-                gated_delta_rule, chunk=s.chunk, return_final_state=True
-            ))
-            o, state = rule(q, k, v, g, beta)
+            # under the block's remat with a policy that saves the names
+            # (``"save_flash"``), what survives a layer's forward is ``o``
+            # and the final state (``gdn_out``) and, from inside the rule,
+            # the 128 float32 states the chunks inherit and ``V_new``
+            # (``gdn_carry``) and every chunk's ``T`` (``gdn_inverse``): 0.27
+            # GB a layer at 8192 steps of 15 heads. Everything later products
+            # read of the sequential carry is then saved, so the block's
+            # recomputation does not run the loop again and the carry's own
+            # backward reads the states: one loop forward, one in reverse,
+            # one solve a layer. The rest of what the rule's backward keeps
+            # (a chunk's system, ``w``, ``u``, the scores: 0.6 GB a layer) is
+            # recomputed when the backward reaches the rule, and so not held
+            # while the gate, the out projection and the block's feed-forward
+            # are still unwinding
+            rule = jax.checkpoint(
+                functools.partial(
+                    gated_delta_rule, chunk=s.chunk, return_final_state=True
+                ),
+                policy=jax.checkpoint_policies.save_only_these_names(*REMAT_NAMES),
+            )
+            o, state = (
+                checkpoint_name(a, OUT_NAME) for a in rule(q, k, v, g, beta)
+            )
         self.sow("metrics", "gdn_decay_mean", jnp.mean(jnp.exp(g)))
         self.sow("metrics", "gdn_beta_mean", jnp.mean(beta))
         self.sow("metrics", "gdn_state_absmax", jnp.max(jnp.abs(state)))

@@ -42,6 +42,7 @@ from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.ops.attention import attention
+from edl_tpu.ops.gated_delta import REMAT_NAMES as GDN_NAMES
 
 AttentionFn = Callable[..., jax.Array]  # (q, k, v, causal=...) -> out
 
@@ -415,16 +416,22 @@ def _remat_policy(name: Optional[str]):
     consumes them instead of re-running the forward kernel: O(B*T*D)
     extra HBM per layer buys back a full flash forward per layer per
     step. ``"save_flash_qkv"`` additionally skips the q/k/v projection
-    recompute. ``None``/"full" is classic recompute-everything."""
+    recompute. Both also keep what a gated-delta-rule layer's sequential
+    carry leaves (``gdn_carry``: the float32 state every chunk inherits,
+    ``V_new``; ``gdn_out``: the rule's output and final state) and every
+    chunk's inverse (``gdn_inverse``), so that the block's recomputation
+    runs neither the carry's loop nor the solve again
+    (ops/gated_delta.py); a model without such a layer bears none of
+    the names. ``None``/"full" is classic recompute-everything."""
     if name in (None, "full"):
         return None
     if name == "save_flash":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse"
+            "flash_out", "flash_lse", *GDN_NAMES
         )
     if name == "save_flash_qkv":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", "flash_qkv"
+            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES
         )
     raise ValueError("unknown remat_policy %r" % (name,))
 

@@ -31,16 +31,30 @@ arithmetic is that of block forward substitution: no power of ``A`` is formed,
 so keys that repeat inside a chunk (``A`` entries near ``beta``, whose powers
 ``(I - A)(I + A^2)...`` would reach 1e18 before cancelling) cost nothing.
 
-Only the state is carried sequentially (a ``lax.scan`` over the chunks of two
-matmuls a step: ``W S`` and ``K^T V_new``); the outputs of all chunks are then
-computed at once from the states the scan emits.
+Only the state is carried sequentially (``carried_states``: a ``lax.scan`` over
+the chunks of two matmuls a step, ``W S`` and ``K^T V_new``); the outputs of all
+chunks are then computed at once from the states the scan emits.
+
+**What the carry leaves for its backward.** ``carried_states`` has a backward
+of its own: the forward keeps the float32 state every chunk inherits, the
+backward is one reverse ``lax.scan`` that takes ``jax.vjp`` of the same step
+at the saved state. The saved states, ``V_new`` and the final state bear the
+name ``gdn_carry`` (``jax.ad_checkpoint.checkpoint_name``), so a remat policy
+that saves the name (``TransformerLM.remat_policy`` ``"save_flash"``) finds
+all the later products read of the loop and does not run it a second time: the
+compiled gradient holds one forward and one reverse loop a call. jax's own
+transpose of a ``lax.scan`` takes its residuals from inside the loop, so the
+forward loop would run again whatever was saved outside it. ``T``, all its
+own backward keeps, bears the name ``gdn_inverse``: saved, the doubling's
+rounds (the costliest of the chunk-parallel parts) are not repeated either.
 
 Precision: ``g``, its running sums, every ``exp``, ``beta``, ``A``, ``T`` (its
 rounds at ``Precision.HIGHEST``) and the carried state are float32; the other
 matmuls take their operands in ``q``'s dtype with float32 accumulation, as
 ``ssd_scan``'s do. Every ``exp`` is of a difference that is never positive, so
 nothing overflows however fast a head forgets. Plain ``jax.numpy`` / ``lax``:
-the backward is jax's, but for the inverse's (``unit_lower_inverse``).
+the backward is jax's, but for the inverse's (``unit_lower_inverse``) and the
+carry's (``carried_states``), and the carry's is jax's of one step.
 """
 
 from __future__ import annotations
@@ -49,10 +63,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from edl_tpu.obs import trace as obs_trace
 
 SOLVE = "block_doubling"  # how T is had, as the ``gdn_chunks`` instant names it
+# the names a remat policy saves to spare the rule's two costly parts a second
+# run: what ``carried_states`` keeps, every chunk's ``T``, and the rule's
+# outputs (tagged by the caller)
+CARRY_NAME, INVERSE_NAME, OUT_NAME = "gdn_carry", "gdn_inverse", "gdn_out"
+REMAT_NAMES = (CARRY_NAME, INVERSE_NAME, OUT_NAME)
 _exact = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
@@ -80,7 +100,8 @@ def unit_lower_inverse(a):
 
 
 def _unit_lower_inverse_fwd(a):
-    inverse = unit_lower_inverse(a)
+    # by name: 16 KB a chunk a head saved spares a recomputation its rounds
+    inverse = checkpoint_name(unit_lower_inverse(a), INVERSE_NAME)
     return inverse, inverse
 
 
@@ -92,13 +113,82 @@ def _unit_lower_inverse_bwd(inverse, ct):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+def _carry(state, inputs):
+    """One chunk's step of the state, float32 ``[b h k v]``: what the chunk
+    writes (``V_new``, in the operands' dtype) and the state it leaves."""
+    w_n, u_n, k_n, whole_n = inputs
+    dtype, dot = w_n.dtype, dict(preferred_element_type=jnp.float32)
+    new = u_n - jnp.einsum("bhck,bhkv->bhcv", w_n, state.astype(dtype), **dot)
+    new = new.astype(dtype)
+    after = whole_n[..., None, None] * state + jnp.einsum(
+        "bchk,bhcv->bhkv", k_n, new, **dot
+    )
+    return after, new
+
+
+@jax.custom_vjp
+def carried_states(state, w, u, k_out, whole):
+    """The state from chunk to chunk: ``(states, new, final)`` for the initial
+    ``state`` ``[b h k v]`` (float32) and, chunks first, ``w`` ``[n b h c k]``,
+    ``u`` ``[n b h c v]`` (float32), ``k_out`` ``[n b c h k]``, ``whole`` ``[n b
+    h]``. ``states`` ``[n b h k v]`` are the float32 states the chunks inherit
+    (float32 because the gradient of ``whole`` is ``<dS, S>``), ``new`` ``[n b
+    h c v]`` what they write, ``final`` the state after the last."""
+
+    def step(state, inputs):
+        after, new = _carry(state, inputs)
+        return after, (state, new)
+
+    final, (states, new) = jax.lax.scan(step, state, (w, u, k_out, whole))
+    return states, new, final
+
+
+def _carried_states_fwd(state, w, u, k_out, whole):
+    # by name, so that a remat policy can keep all three: whatever the later
+    # products read of the loop has to be saved, or the loop runs again
+    states, new, final = (
+        checkpoint_name(a, CARRY_NAME)
+        for a in carried_states(state, w, u, k_out, whole)
+    )
+    return (states, new, final), (states, w, u, k_out, whole)
+
+
+def _carried_states_bwd(residuals, cotangents):
+    states, *inputs = residuals
+    d_states, d_new, d_final = cotangents
+
+    def step(d_state, at):
+        state, inputs_n, d_entering, d_new_n = at
+        _, pull = jax.vjp(_carry, state, inputs_n)
+        d_before, d_inputs = pull((d_state, d_new_n))
+        return d_before + d_entering, d_inputs
+
+    d_state, d_inputs = jax.lax.scan(
+        step, d_final, (states, tuple(inputs), d_states, d_new), reverse=True
+    )
+    return (d_state, *d_inputs)
+
+
+carried_states.defvjp(_carried_states_fwd, _carried_states_bwd)
+
+
+def saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch=1):
+    """What one call leaves for its backward under a policy that saves
+    ``REMAT_NAMES``: the float32 states the chunks inherit and the final one,
+    every chunk's float32 ``T``, ``V_new`` and ``o`` in the operands' dtype."""
+    states = 4 * (chunks + 1) * heads * d_k * d_v
+    inverses = 4 * chunks * heads * chunk * chunk
+    return batch * (states + inverses + 2 * itemsize * chunks * chunk * heads * d_v)
+
+
 @functools.lru_cache(maxsize=None)
-def _note_chunks(chunk, chunks, heads, d_k, d_v):
+def _note_chunks(chunk, chunks, heads, d_k, d_v, itemsize, batch):
     """One ``gdn_chunks`` instant in the span ring for each shape the rule
     is traced at."""
     obs_trace.get_tracer().instant(
         "gdn_chunks", chunk=chunk, chunks=chunks, heads=heads, d_k=d_k, d_v=d_v,
-        state_bytes=4 * heads * d_k * d_v, solve=SOLVE,
+        state_bytes=4 * heads * d_k * d_v, solve=SOLVE, carry="saved",
+        saved_bytes=saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch),
     )
 
 
@@ -135,8 +225,8 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
             for a in (q, k, v, g, beta)
         )
     nc = (t + pad) // size
-    _note_chunks(size, nc, h, d_k, d_v)
     f32, dtype = jnp.float32, q.dtype
+    _note_chunks(size, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch)
     dot = dict(preferred_element_type=f32)
 
     # everything below: b batch, n chunk, c / s step in a chunk, h head,
@@ -172,24 +262,16 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     whole = jnp.exp(gamma[..., -1])                              # [b n h]
 
     # from chunk to chunk, the state in float32
-    def carry(state, inputs):
-        w_n, u_n, k_n, whole_n = inputs
-        new = u_n - jnp.einsum("bhck,bhkv->bhcv", w_n, state.astype(dtype), **dot)
-        new = new.astype(dtype)
-        after = whole_n[..., None, None] * state + jnp.einsum(
-            "bchk,bhcv->bhkv", k_n, new, **dot
-        )
-        return after, (state.astype(dtype), new)
-
     if initial_state is None:
         state = jnp.zeros((batch, h, d_k, d_v), f32)
     else:
         state = initial_state.astype(f32)
     chunks_first = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
-    state, (entering, new) = jax.lax.scan(
-        carry, state, tuple(chunks_first(a) for a in (w, u, k_out, whole))
+    states, new, state = carried_states(
+        state, *(chunks_first(a) for a in (w, u, k_out, whole))
     )
-    entering = jnp.moveaxis(entering, 0, 1)                      # [b n h k v]
+    # a cast of what is saved: the backward needs no copy of its own
+    entering = jnp.moveaxis(states.astype(dtype), 0, 1)          # [b n h k v]
     new = jnp.moveaxis(new, 0, 1)                                # [b n h c v]
 
     # every chunk's outputs: what it inherits, and what it wrote itself

@@ -5,6 +5,9 @@ norm placement, a QK norm over the whole width, no positions) against the
 benchmark's plain reference, the convolution kernels' no-bias call, and the
 scopes, gauges and instant the model leaves for the tracing."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +16,7 @@ import pytest
 
 from benchmark.reference import gdn_lm as reference
 from edl_tpu.models import ArchSpec, GatedDeltaMixer, GatedDeltaSpec, TransformerLM
+from edl_tpu.models import transformer as transformer_module
 from edl_tpu.models.gated_delta import GDN_SCOPES
 from edl_tpu.models.transformer import Block
 from edl_tpu.obs import profile as obs_profile
@@ -124,6 +128,45 @@ def test_rule_gradients_equal_the_recurrences(wrt, chunk):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+@functools.lru_cache(maxsize=None)
+def carry_gradients(dtype, t):
+    """Gradients of the rule with respect to ``q, k, v, g, beta`` and the
+    initial state, with cotangents on ``o`` and on the final state: through
+    the carry's own backward, and through jax's transpose of the same scan
+    (``carried_states`` without its ``custom_vjp``: the parent's rule)."""
+    args = rule_inputs(seed=1, t=t)
+    args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    args += (jax.random.normal(jax.random.PRNGKey(4), (2, 3, 8, 16)),)
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, t, 3, 16))
+    w_state = jax.random.normal(jax.random.PRNGKey(10), (2, 3, 8, 16))
+
+    def loss(q, k, v, g, beta, initial):
+        o, state = gated_delta_rule(
+            q, k, v, g, beta, chunk=16, initial_state=initial, return_final_state=True
+        )
+        return jnp.sum(o.astype(jnp.float32) * w) + jnp.sum(state * w_state)
+
+    own = jax.jit(jax.grad(loss, range(6)))(*args)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(rule_module, "carried_states", rule_module.carried_states.fun)
+        plain = jax.jit(jax.grad(loss, range(6)))(*args)
+    return own, plain
+
+
+@pytest.mark.parametrize("t", [64, 80], ids=["whole_chunks", "ragged"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("wrt", range(6), ids=["q", "k", "v", "g", "beta", "initial_state"])
+def test_the_carrys_own_backward_equals_jaxs_transpose_of_the_scan(wrt, dtype, t):
+    """One reverse scan of ``jax.vjp`` of the same step at the saved float32
+    states: the same operations at the same operands, so float32 rounding (the
+    order a compiled sum is taken in) is all that may differ, under bfloat16
+    operands as under float32 ones."""
+    own, plain = carry_gradients(dtype, t)
+    got, want = (np.asarray(a[wrt], np.float32) for a in (own, plain))
+    assert own[wrt].dtype == plain[wrt].dtype and np.abs(want).max() > 0
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
 def test_the_final_states_gradient_reaches_the_inputs():
     args = rule_inputs(seed=2, t=40)
     w = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 8, 16))
@@ -224,10 +267,19 @@ def test_each_traced_shape_leaves_one_gdn_chunks_instant():
         gated_delta_rule(*args, chunk=16)
     noted = [e for e in tracer.to_events() if e["name"] == "gdn_chunks"][before:]
     assert len(noted) == 1
+    # batch 2, float32 operands: the states three chunks inherit and the final
+    # one, three chunks' T, then V_new and o over the 48 padded steps
+    saved = 2 * (4 * (3 + 1) * 3 * 8 * 16 + 4 * 3 * 3 * 16 * 16 + 2 * 4 * 48 * 3 * 16)
     assert noted[0]["args"] == {
         "chunk": 16, "chunks": 3, "heads": 3, "d_k": 8, "d_v": 16,
         "state_bytes": 4 * 3 * 8 * 16, "solve": "block_doubling",
+        "carry": "saved", "saved_bytes": saved,
     }
+    # the cell's call: 128 states of [15, 96, 192] and 128 T of [15, 64, 64]
+    # float32, V_new and o bfloat16
+    assert rule_module.saved_bytes(64, 128, 15, 96, 192, 2) == (
+        129 * 15 * 96 * 192 * 4 + 128 * 15 * 64 * 64 * 4 + 2 * 8192 * 15 * 192 * 2
+    )
 
 
 # -- the convolution's no-bias call ----------------------------------------------
@@ -385,6 +437,40 @@ def test_hybrid_lm_equals_the_plain_reference(remat, what):
         np.testing.assert_allclose(
             a, b, rtol=1e-3, atol=1e-5, err_msg=jax.tree_util.keystr(path)
         )
+
+
+def lowered_gradient(lm):
+    x, y = toy_batch()
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0), x)["params"])
+    loss = lambda p: lm_loss(lm.apply({"params": p}, x), y)[0]  # noqa: E731
+    return jax.jit(jax.grad(loss)).lower(params).as_text()
+
+
+@pytest.mark.parametrize("policy, loops, solves", [
+    ("save_flash", 2, 1), ("save_flash_qkv", 2, 1), ("full", 3, 2),
+])
+def test_a_linear_layers_carry_loops_once_forward_and_once_in_reverse(policy, loops, solves):
+    """Two remat blocks around the rule: with the rule's names in the block's
+    policy the gradient holds a forward and a reverse loop a layer and one
+    solve (chunks of 8: three rounds of two products at the highest
+    precision, and two more in the inverse's backward); with nothing saved the
+    block's recomputation runs the forward loop and the solve again."""
+    arch = toy_arch(layer_types=("linear_attention",) * 2)
+    text = lowered_gradient(toy_lm(arch, remat=True).clone(remat_policy=policy))
+    assert len(re.findall(r"stablehlo\.while", text)) == loops * 2
+    exact = len(re.findall(r"precision = \[HIGHEST, HIGHEST\]", text))
+    assert exact == (6 * solves + 2) * 2
+
+
+def test_the_carrys_names_in_the_policy_leave_a_dense_lm_as_it_was(monkeypatch):
+    dense = toy_lm(toy_arch(layer_types=("attention",) * 2, gated_delta=None), remat=True)
+    dense = dense.clone(remat_policy="save_flash")
+    with_names = lowered_gradient(dense)
+    monkeypatch.setattr(
+        transformer_module, "_remat_policy",
+        lambda name: jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse"),
+    )
+    assert lowered_gradient(dense) == with_names
 
 
 PLACEMENTS = {
