@@ -3,6 +3,7 @@ from edl_tpu.models.mlp import MLP, LinearRegression
 from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import MOE_EP_RULES, DroplessMoE, MoESpec, SwitchMoE
+from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
 from edl_tpu.models.resnet import (
     ResNet,
     ResNet50_vd,
@@ -36,4 +37,6 @@ __all__ = [
     "MambaSpec",
     "GatedDeltaMixer",
     "GatedDeltaSpec",
+    "ShortConvMixer",
+    "ShortConvSpec",
 ]

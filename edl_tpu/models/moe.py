@@ -59,6 +59,7 @@ class MoESpec:
     top_k: int
     d_ff: int                      # width of ONE expert
     norm_topk_prob: bool = False   # renormalise the k weights to sum 1
+    norm_topk_eps: float = 1e-20   # ... over (their sum + this)
     aux_weight: float = 1e-2       # alpha of the load-balancing loss; 0: none
     z_weight: float = 1e-3         # beta of the router z-loss; 0: none
     score_func: str = "softmax"    # or "sigmoid": each expert on its own
@@ -156,7 +157,7 @@ class DroplessMoE(nn.Module):
 
         s      = softmax(W_r x)  or  sigmoid(W_r x)     float32, over E
         e      = top_k(s + b)                      b: the bias, if any
-        w      = s[e], then w / (sum(w) + 1e-20) and w * route_scale, as asked
+        w      = s[e], then w / (sum(w) + norm_topk_eps) and w * route_scale, as asked
         y      = sum_j W_down[e_j] (w_j * silu(W_gate[e_j] x) * W_up[e_j] x)
                  + shared(x)                       if there is a shared expert
 
@@ -223,6 +224,9 @@ class DroplessMoE(nn.Module):
       term and the trainer reports none.
     - ``"metrics"/moe_load_max`` = the busiest expert's assignments over
       the mean (1.0 is perfect balance, E is one expert taking all).
+    - with a bias, ``"metrics"/moe_bias_absmax`` = the largest ``|b|`` of
+      the bias the choice was made under (how far the balancing has had to
+      lean; zero at first).
     - with a share, ``"metrics"/moe_rows_held`` = the share of the N*k
       assignments that fell on held experts (``count / E`` when balanced),
       ``/moe_held_load_max`` = the busiest held expert's rows over the mean
@@ -243,6 +247,7 @@ class DroplessMoE(nn.Module):
     top_k: int
     d_ff: int
     norm_topk_prob: bool = False
+    norm_topk_eps: float = 1e-20
     aux_weight: float = 1e-2
     z_weight: float = 1e-3
     score_func: str = "softmax"
@@ -281,13 +286,14 @@ class DroplessMoE(nn.Module):
                 bias = self.variable(
                     "batch_stats", "router_bias", jnp.zeros, (e,), jnp.float32
                 )
+                self.sow("metrics", "moe_bias_absmax", jnp.max(jnp.abs(bias.value)))
                 _, top_idx = jax.lax.top_k(probs + bias.value, k)
                 weights = jnp.take_along_axis(probs, top_idx, axis=-1)
             else:
                 weights, top_idx = jax.lax.top_k(probs, k)  # [N, k]
             if self.norm_topk_prob:
                 weights = weights / (
-                    jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
+                    jnp.sum(weights, axis=-1, keepdims=True) + self.norm_topk_eps
                 )
             if self.route_scale != 1.0:
                 weights = weights * self.route_scale

@@ -10,11 +10,12 @@ TPU-first:
   stays busy; bf16 compute, fp32 params;
 - a block's sequence mixer is, by ``ArchSpec.layer_types``, full causal
   attention, attention over a sliding window, a Mamba-2 state-space
-  layer (``models/mamba.py``) or a gated-delta-rule linear-attention layer
-  (``models/gated_delta.py``); its feed-forward a SwiGLU or an expert
+  layer (``models/mamba.py``), a gated-delta-rule linear-attention layer
+  (``models/gated_delta.py``) or a gated short convolution
+  (``models/short_conv.py``); its feed-forward a SwiGLU or an expert
   layer (``models/moe.py``), the leading ``ArchSpec.dense_layers`` blocks
   of an expert model dense: one ``TransformerLM`` runs dense, expert,
-  hybrid (either recurrent mixer beside attention) and mixed-window
+  hybrid (any of the three cheap mixers beside attention) and mixed-window
   configurations;
 - attention is pluggable: the Pallas flash kernel locally, or ring
   attention over the ``sp`` mesh axis for sequences longer than one
@@ -41,6 +42,7 @@ import jax.numpy as jnp
 from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
+from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
 from edl_tpu.ops.attention import attention
 from edl_tpu.ops.gated_delta import REMAT_NAMES as GDN_NAMES
 
@@ -68,12 +70,15 @@ class ArchSpec:
     ``layer_types`` names each block's sequence mixer: ``"attention"``
     (causal over the whole sequence), ``"sliding_attention"`` (causal over
     the ``sliding_window`` newest keys, the query's own among them),
-    ``"mamba"`` (then ``mamba`` gives the layer's shape) or
+    ``"mamba"`` (then ``mamba`` gives the layer's shape),
     ``"linear_attention"`` (the gated delta rule; then ``gated_delta`` gives
-    the layer's shape); its length is the model's depth. ``rope`` rotates q
-    and k in every attention layer (``True``), in none (``False``: no position term at all) or in the
-    windowed layers only (``"sliding"``: the full layers then see order
-    through the causal mask alone). ``dense_layers`` leading blocks of a
+    the layer's shape) or ``"conv"`` (the gated short convolution; then
+    ``short_conv`` gives its taps); its length is the model's depth. ``rope``
+    rotates q and k in every attention layer (``True``), in none (``False``:
+    no position term at all) or in the windowed layers only (``"sliding"``:
+    the full layers then see order through the causal mask alone); the
+    rotation's base is ``rope_theta``, a configuration's own key, and 10,000
+    where none is given. ``dense_layers`` leading blocks of a
     model with an expert layer (``TransformerLM.moe``) keep the dense
     SwiGLU of ``d_ff``. ``post_norms`` is one field with three forms for
     where a block's RMSNorms sit: ``False`` before each branch (``x +
@@ -91,8 +96,10 @@ class ArchSpec:
     layer_types: Optional[Tuple[str, ...]] = None
     mamba: Optional[MambaSpec] = None
     gated_delta: Optional[GatedDeltaSpec] = None
+    short_conv: Optional[ShortConvSpec] = None
     head_dim: Optional[int] = None      # None: d_model / num_heads
     rope: Union[bool, str] = True       # True, False or "sliding"
+    rope_theta: float = 10000.0         # the rotation's base
     attn_scale: Optional[float] = None  # None: head_dim ** -0.5
     tie_embeddings: bool = False
     embedding_multiplier: float = 1.0
@@ -104,7 +111,8 @@ class ArchSpec:
     attn_gate: bool = False
 
 
-LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention")
+LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
+               "conv")
 
 
 def _scope(name: Optional[str]):
@@ -154,7 +162,9 @@ class Attention(nn.Module):
     (default) is classic MHA. ``head_dim`` defaults to ``d_model /
     num_heads`` and may be given for a model whose heads do not tile
     its width; ``rope=False`` applies no rotation (a position-free
-    layer); ``scale`` replaces the scores' ``head_dim ** -0.5``.
+    layer), and ``rope_theta`` is the rotation's base (a block hands over
+    ``ArchSpec.rope_theta``: the configuration's own, 10,000 by default);
+    ``scale`` replaces the scores' ``head_dim ** -0.5``.
     The default dispatch's Pallas kernels are
     GQA-AWARE (ops/attention.py: grouped k/v read via index mapping, no
     materialized repeat, dk/dv folded back to the grouped width), so on
@@ -194,6 +204,7 @@ class Attention(nn.Module):
     norm_eps: float = 1e-6
     head_dim: Optional[int] = None
     rope: bool = True
+    rope_theta: float = 10000.0
     scale: Optional[float] = None
     window: Optional[int] = None
     gate: bool = False
@@ -227,8 +238,8 @@ class Attention(nn.Module):
         elif self.qk_norm:
             raise ValueError("unknown qk_norm %r" % (self.qk_norm,))
         if self.rope:
-            q = rope(q, positions)
-            k = rope(k, positions)
+            q = rope(q, positions, self.rope_theta)
+            k = rope(k, positions, self.rope_theta)
         if self.decode:
             if self.window is not None:
                 raise NotImplementedError("the decode cache takes no window")
@@ -365,6 +376,14 @@ class Block(nn.Module):
             mixed = GatedDeltaMixer(
                 arch.gated_delta, self.dtype, self.norm_eps, name="gdn"
             )(h)
+        elif self.mixer == "conv":
+            if self.decode:
+                raise NotImplementedError(
+                    "a short-convolution block has no decode cache"
+                )
+            mixed = ShortConvMixer(
+                arch.short_conv or ShortConvSpec(), self.dtype, name="sconv"
+            )(h)
         elif self.mixer in ("attention", "sliding_attention"):
             sliding = self.mixer == "sliding_attention"
             if sliding and arch.sliding_window is None:
@@ -375,6 +394,7 @@ class Block(nn.Module):
                 max_decode_len=self.max_decode_len, qk_norm=self.qk_norm,
                 norm_eps=self.norm_eps, head_dim=arch.head_dim,
                 rope=sliding if arch.rope == "sliding" else arch.rope,
+                rope_theta=arch.rope_theta,
                 scale=arch.attn_scale,
                 window=arch.sliding_window if sliding else None,
                 gate=arch.attn_gate,
@@ -524,7 +544,7 @@ class TransformerLM(nn.Module):
     # SwitchMoE until ROADMAP D6
     moe: Optional[MoESpec] = None
     # the layer pattern (mixers, windows, leading dense layers), the
-    # attention's head size / positions / score scale / gate, where a
+    # attention's head size / positions and their base / score scale / gate, where a
     # block's norms sit, a tied head and Granite's multipliers; None: the
     # dense model
     arch: Optional[ArchSpec] = None

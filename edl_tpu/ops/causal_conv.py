@@ -41,6 +41,16 @@ is, and the result is rounded once. Two implementations, one contract (value,
 Which one runs is decided from ``jax.default_backend()`` and the shapes, as
 ``ops/grouped_matmul.py`` decides; ``interpret`` runs the kernels in the
 Pallas interpreter (tests on the CPU).
+
+A third caller, the gated short-convolution mixer (``models/short_conv.py``),
+takes :func:`gated_causal_conv`: the same depthwise causal taps between two
+elementwise gates, ``C_g * conv(B_g * x)`` over the three thirds ``[B_g | C_g |
+x]`` of its in projection's output, read in place. That form leaves out what
+the one above is built around, the bias and the SiLU (there is no activation at
+all), and is plain ``jax.numpy`` with jax's own backward on every backend: its
+neighbours are matmuls over ``[B, T, C]`` with the channels along the lanes, so
+XLA keeps that layout and makes the gates, the shifted products and their sum
+one fusion forward (PERF.md section 6, PR 37, has the trace that decided it).
 """
 
 from __future__ import annotations
@@ -76,6 +86,36 @@ def causal_conv(x, kernel, bias=None):
 def _plain(x, kernel, bias, offset):
     x = x[..., offset:offset + kernel.shape[1]]
     return jax.nn.silu(causal_conv(x, kernel, bias)).astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_gated(batch, t, channels, taps, itemsize):
+    """One ``sconv_shape`` instant in the span ring for each shape the gated
+    convolution is traced at; ``bytes`` is one forward pass's least traffic
+    (three reads and one write of ``[B, T, C]``)."""
+    obs_trace.get_tracer().instant(
+        "sconv_shape", channels=channels, taps=taps, steps=t, batch=batch,
+        implementation="plain", bytes=4 * batch * t * channels * itemsize,
+    )
+
+
+def gated_causal_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
+    """``C_g * causal_conv(B_g * x~, kernel)`` in ``x``'s dtype, ``[B, T, C]``,
+    for ``x`` ``[B, T, 3 C]`` = ``[B_g | C_g | x~]`` and ``kernel`` ``[taps,
+    C]``: no bias and no activation. The two gates, the taps and their sum are
+    float32 and the result is rounded once. Differentiable in ``x`` and
+    ``kernel``."""
+    taps, c = kernel.shape
+    if x.ndim != 3 or x.shape[2] != 3 * c:
+        raise ValueError(
+            "gated_causal_conv: x %s is not [B, T, 3 * %d]" % (x.shape, c)
+        )
+    _note_gated(x.shape[0], x.shape[1], c, taps, jnp.dtype(x.dtype).itemsize)
+    f32 = jnp.float32
+    b_gate, c_gate, inner = (
+        x[..., i * c:(i + 1) * c].astype(f32) for i in range(3)
+    )
+    return (c_gate * causal_conv(b_gate * inner, kernel)).astype(x.dtype)
 
 
 def _divisor(n: int, most: int, of: int) -> int:

@@ -24,7 +24,7 @@ on the chip (``benchmark/tools/grouped_matmul_probe.py``).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional, Tuple
 
 import jax
@@ -39,8 +39,15 @@ from edl_tpu.obs import trace as obs_trace
 # tile belong to one group or are masked, so the row tile bounds the waste at
 # a group's edge (64 edges of at most 512 rows in 131,072 at the OLMoE cell's
 # shape); the other two keep a whole [K, N] slab of one expert in VMEM across
-# that expert's row tiles.
+# that expert's row tiles. Measured at widths of 1024 and 2048 (OLMoE's and
+# Trinity's experts over a model of 2048), which 1024 divides; elsewhere
+# ``_fit`` takes, for K and for N alike, the largest whole number of lane
+# tiles (128) that divides the dimension and is at most this one's, so that no
+# tile is ragged: at an expert width of 1536, 768 (1024 there is one tile and
+# a masked half, a third of the kernel's work wasted; PERF.md section 6, PR 37,
+# has the probe's numbers for 768 against 512).
 TILING = (512, 1024, 1024)
+_LANES = 128
 
 IMPLEMENTATIONS = ("pallas", "ragged_dot")
 
@@ -49,12 +56,37 @@ def default_implementation() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ragged_dot"
 
 
+def _whole(most: int, dim: int) -> int:
+    """The tile of a dimension ``dim`` of K or N: ``dim`` itself where it is
+    at most ``most``, else the largest multiple of 128 at most ``most`` that
+    divides it, else (none does) ``most`` and a ragged last tile, which
+    Megablox masks."""
+    if dim <= most:
+        return dim
+    for tile in range(most - most % _LANES, 0, -_LANES):
+        if dim % tile == 0:
+            return tile
+    return most
+
+
 def _fit(tiling: Tuple[int, int, int], m: int, k: int, n: int):
-    """``tiling`` cut to the problem: no tile larger than its dimension
+    """``tiling`` cut to the problem: no tile larger than its dimension, and
+    those of K and N dividing theirs where a whole number of lane tiles does
     (Megablox masks a ragged last tile of K and N, not one of M, so M is
     padded by the caller to a whole number of row tiles)."""
     tm, tk, tn = tiling
-    return min(tm, m), min(tk, k), min(tn, n)
+    return min(tm, m), _whole(tk, k), _whole(tn, n)
+
+
+@lru_cache(maxsize=None)
+def _note_tiles(kernel: str, m: int, k: int, n: int, tiling):
+    """One ``gmm_tiles`` instant in the span ring for each shape a Megablox
+    kernel is traced at, with the tiling it was given."""
+    obs_trace.get_tracer().instant(
+        "gmm_tiles", kernel=kernel, rows=m, contracting=k, columns=n,
+        tiling=list(tiling),
+    )
+    return tiling
 
 
 def _megablox():
@@ -70,10 +102,12 @@ def _megablox():
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _pallas(lhs, rhs, group_sizes, interpret):
     m, k = lhs.shape
+    n = rhs.shape[2]
     with obs_trace.span("kernel_trace", kernel="gmm"):
         return _megablox().gmm(
             lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=_fit(TILING, m, k, rhs.shape[2]), interpret=interpret,
+            tiling=_note_tiles("gmm", m, k, n, _fit(TILING, m, k, n)),
+            interpret=interpret,
         )
 
 
@@ -90,14 +124,16 @@ def _pallas_bwd(interpret, residuals, grad):
     with obs_trace.span("kernel_trace", kernel="gmm_dlhs"):
         d_lhs = backend.gmm(
             grad, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=_fit(TILING, m, n, k), transpose_rhs=True,
+            tiling=_note_tiles("gmm_dlhs", m, n, k, _fit(TILING, m, n, k)),
+            transpose_rhs=True,
             interpret=interpret,
         )
     with obs_trace.span("kernel_trace", kernel="tgmm"):
         d_rhs = backend.tgmm(
             lhs.swapaxes(0, 1), grad, group_sizes,
             preferred_element_type=rhs.dtype,
-            tiling=_fit(TILING, m, k, n), num_actual_groups=rhs.shape[0],
+            tiling=_note_tiles("tgmm", m, k, n, _fit(TILING, m, k, n)),
+            num_actual_groups=rhs.shape[0],
             interpret=interpret,
         )
     return d_lhs, d_rhs, None

@@ -302,8 +302,8 @@ def test_a_pattern_of_the_wrong_length_or_kind_is_refused():
                           d_ff=40, arch=toy_arch())
     with pytest.raises(ValueError, match="3 layer_types for num_layers 2"):
         short.init(jax.random.PRNGKey(0), x)
-    with pytest.raises(ValueError, match="unknown layer type 'conv'"):
-        toy_lm(toy_arch(layer_types=("mamba", "conv", "attention"))).init(
+    with pytest.raises(ValueError, match="unknown layer type 'hyena'"):
+        toy_lm(toy_arch(layer_types=("mamba", "hyena", "attention"))).init(
             jax.random.PRNGKey(0), x
         )
     with pytest.raises(ValueError, match="ArchSpec"):

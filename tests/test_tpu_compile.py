@@ -294,6 +294,53 @@ def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+@pytest.mark.parametrize("shape", ["up", "down"])
+def test_megablox_compiles_for_v5e_at_an_expert_width_of_1536(one_chip, shape):
+    """The three Megablox kernels at the held experts' shape of the
+    short-convolution hybrid (8 groups, an 8192-row buffer, 2048 x 1536), on
+    the tiles ``_fit`` gives where 1024 does not divide: 768 over the 1536, in
+    N for gate / up and in K for down. Three custom calls."""
+    gm = importlib.import_module("edl_tpu.ops.grouped_matmul")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    k, n, tiles = {"up": (2048, 1536, (512, 1024, 768)),
+                   "down": (1536, 2048, (512, 768, 1024))}[shape]
+    assert gm._fit(gm.TILING, 8192, k, n) == tiles
+
+    def value_and_grads(lhs, rhs, sizes, dy):
+        out, vjp = jax.vjp(lambda a, b: gm._pallas(a, b, sizes, False), lhs, rhs)
+        return (out, *vjp(dy))
+
+    compiled = jax.jit(value_and_grads).lower(
+        sds((8192, k)), sds((8, k, n)), sds((8,), jnp.int32), sds((8192, n))
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_gated_causal_conv_compiles_for_v5e_at_the_hybrids_widths(one_chip):
+    """The gated short convolution, plain XLA with jax's own backward, at one
+    sequence of 8192 and 2048 channels read out of the in projection's 6144: no
+    kernel, so what the chip's compiler can refuse is the memory. Value and
+    gradients together hold a few float32 ``[8192, 2048]`` arrays (67 MB each)
+    and no copy of the padded input for every tap."""
+    from edl_tpu.ops import gated_causal_conv
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def value_and_grads(x, w, dy):
+        out, vjp = jax.vjp(gated_causal_conv, x, w)
+        return (out, *vjp(dy))
+
+    compiled = jax.jit(value_and_grads).lower(
+        sds((1, 8192, 6144)), sds((3, 2048), jnp.float32), sds((1, 8192, 2048))
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 def test_causal_conv_kernels_compile_for_v5e_at_the_hybrids_widths(one_chip):
     """The kernels' second shape: the 5760 channels of ``[q | k | v]`` (15
     heads) at the START of the in projection's 8670 (offset 0, so the blocks
