@@ -61,7 +61,7 @@ import tempfile
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from edl_tpu.obs import events as obs_events
 from edl_tpu.obs import metrics as obs_metrics
@@ -193,6 +193,7 @@ _HLO_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_MATMUL = re.compile(r"\s(?:convolution|dot)\(")
 
 # the running stage's compiled step and, once asked for, its tables
 _step_executable = None
@@ -251,16 +252,50 @@ def op_names_of_hlo(text: str) -> Dict[str, str]:
     return own
 
 
+def update_passes_of_hlo(text: str) -> List[str]:
+    """The fusions that are the optimizer's pass over a leaf: those whose
+    body holds instructions of the ``optimizer`` scope and no matmul. Such a
+    pass reads ``g, p, m, v`` and writes ``p, m, v``; the half-batch mean and
+    the numerics bundle's norms ride in it, and XLA names the fusion after
+    one of the norms' reduces. A fusion that still holds the matmul that
+    produces its gradient is not one: its time is the matmul's."""
+    updates, matmuls = set(), set()   # computations holding either
+    calls: Dict[str, str] = {}        # instruction -> computation
+    computation = None
+    for line in text.splitlines():
+        started = _HLO_COMPUTATION.match(line)
+        if started:
+            computation = started.group(1)
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if not found:
+            continue
+        called = _HLO_CALLS.search(line)
+        if called:
+            calls[found.group(2)] = called.group(1)
+        if _HLO_MATMUL.search(line):
+            matmuls.add(computation)
+        op_name = _HLO_OP_NAME.search(line)
+        if op_name and phase_of(op_name.group(1)) == "optimizer":
+            updates.add(computation)
+    return [
+        name for name, computation in calls.items()
+        if computation in updates and computation not in matmuls
+    ]
+
+
 def phases_of_hlo(text: str) -> Dict[str, str]:
     """``{instruction name: phase}`` from an optimised HLO module's text
-    (:func:`op_names_of_hlo`, then :func:`phase_of`). {} when nothing maps
-    to ``forward``: the executable then predates the scopes (a compile
-    cache older than them handed it back), and a table of ``other`` would
-    pass for a measurement."""
+    (:func:`op_names_of_hlo`, then :func:`phase_of`; the optimizer's passes,
+    :func:`update_passes_of_hlo`, under ``optimizer`` whatever name they
+    took). {} when nothing maps to ``forward``: the executable then predates
+    the scopes (a compile cache older than them handed it back), and a table
+    of ``other`` would pass for a measurement."""
     table = {
         name: phase_of(op_name)
         for name, op_name in op_names_of_hlo(text).items()
     }
+    table.update((name, "optimizer") for name in update_passes_of_hlo(text))
     return table if "forward" in table.values() else {}
 
 

@@ -13,6 +13,7 @@ equivalent of the reference's AMP + loss-scaling flags
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -22,6 +23,7 @@ import optax
 from flax import core, struct
 
 from edl_tpu.obs import numerics as obs_numerics
+from edl_tpu.obs import trace as obs_trace
 
 
 class TrainState(struct.PyTreeNode):
@@ -58,6 +60,70 @@ class TrainState(struct.PyTreeNode):
 
 
 AUX_LOSS = "aux_loss"
+
+#: Which matrices' weight gradients leave the optimizer's fusion
+#: (``grads_apart``): both dimensions at least MIN_WIDTH, fewer than
+#: MAX_ELEMENTS elements. Set from every operation of the six LM cells listed
+#: once fused and once apart (PERF.md section 6, PR 38). What decides is how
+#: far under its matmul's rate XLA's fused form runs (apart is the matmul at
+#: 85-92% of peak plus a pass of half its time, so it wins under 60%), and
+#: that is not a function of a leaf's size: at hidden 2048 every matrix
+#: measured (``[2048, 6144]`` to ``[2048, 25024]``) runs fused at 64-78% and
+#: loses 0.1-0.5 ms apart, at 3840 and 4096 the fused form reads 31-56% from
+#: ``[2880, 3840]`` (1.94 ms fused, 1.06 + 0.46 apart) to ``[4096, 14336]``
+#: (11.2-14.1 behind a plain first half, 5.1-5.9 + 3.1 apart), and the
+#: vocabulary's matrices from 103 M elements up run fused at 75-92%, so there
+#: the float32 round trip only costs (4.0-4.2 ms each at 131 M).
+GRAD_APART_MIN_WIDTH = 2560
+GRAD_APART_MAX_ELEMENTS = 76 << 20
+
+
+def taken_apart(leaf) -> bool:
+    """Whether ``grads_apart`` takes this gradient leaf: a float matrix
+    (rank 2: an expert bank's gradient leaves a custom call and is apart
+    already) of at least ``GRAD_APART_MIN_WIDTH`` rows and columns and fewer
+    than ``GRAD_APART_MAX_ELEMENTS`` elements. A rule on the leaf's shape
+    and dtype alone."""
+    return (
+        len(leaf.shape) == 2
+        and jnp.issubdtype(leaf.dtype, jnp.floating)
+        and min(leaf.shape) >= GRAD_APART_MIN_WIDTH
+        and leaf.size < GRAD_APART_MAX_ELEMENTS
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _note_apart(leaves, of, nbytes, largest_bytes, min_width, max_elements):
+    """One ``grad_apart`` instant in the span ring for each tree a step is
+    traced over: how many of its leaves ``grads_apart`` took, and their
+    float32 bytes."""
+    obs_trace.get_tracer().instant(
+        "grad_apart", leaves=leaves, of=of, bytes=nbytes,
+        largest_bytes=largest_bytes, min_width=min_width,
+        max_elements=max_elements,
+    )
+
+
+def grads_apart(grads):
+    """``grads`` with every leaf the rule takes (``taken_apart``) behind an
+    ``optimization_barrier`` of its own: the identity, and a fence. Left to
+    itself XLA fuses the update, the half-batch mean and the numerics
+    bundle's norms into the matmul that produces a weight gradient, and
+    where that fused form tiles badly the matmul runs at a third of peak.
+    Behind the barrier dW is written once by a plain matmul and everything
+    that reads it — both of its readers must read THIS tree — is one
+    elementwise-and-reduce pass over ``g, p, m, v`` at the HBM's rate. One
+    barrier a leaf, never one over the tree: that would hold every
+    gradient live at once."""
+    leaves = jax.tree_util.tree_leaves(grads)
+    sizes = [4 * leaf.size for leaf in leaves if taken_apart(leaf)]
+    _note_apart(
+        len(sizes), len(leaves), sum(sizes), max(sizes, default=0),
+        GRAD_APART_MIN_WIDTH, GRAD_APART_MAX_ELEMENTS,
+    )
+    return jax.tree_util.tree_map(
+        lambda g: jax.lax.optimization_barrier(g) if taken_apart(g) else g, grads
+    )
 
 
 def sown_metric_names(variables) -> Tuple[str, ...]:
@@ -302,6 +368,8 @@ def make_train_step(
             (l1, (m1, _)), g1 = grad_fn(state.params, x1, y1)
             (l2, (m2, _)), g2 = grad_fn(state.params, x2, y2)
             with jax.named_scope("grad_mean"):
+                # each half's, not the mean's: ``half_sq`` reads g2 alone
+                g1, g2 = grads_apart(g1), grads_apart(g2)
                 loss = (l1 + l2) / 2.0
                 grads = jax.tree_util.tree_map(
                     lambda a, c: (a + c) / 2.0, g1, g2
@@ -317,6 +385,8 @@ def make_train_step(
         if new_stats is not None:
             updates["batch_stats"] = new_stats
         with jax.named_scope("optimizer"):
+            if halves is None:
+                grads = grads_apart(grads)
             new_state = state.apply_gradients(grads, **updates)
         metrics = {"loss": loss, **metrics}
         if numerics:

@@ -342,6 +342,53 @@ def test_an_executable_older_than_the_scopes_gives_no_table():
     }
 
 
+HLO_WITH_AN_UPDATE_PASS = """
+HloModule jit_step
+
+%fused_pass (g: f32[8,8], p: f32[8,8]) -> (f32[], f32[8,8]) {
+  %g = f32[8,8]{1,0} parameter(0)
+  %p = f32[8,8]{1,0} parameter(1)
+  %sq = f32[8,8]{1,0} multiply(%g, %g), metadata={op_name="jit(step)/numerics/square"}
+  %norm = f32[] reduce(%sq), metadata={op_name="jit(step)/numerics/reduce_sum"}
+  %new = f32[8,8]{1,0} add(%p, %g), metadata={op_name="jit(step)/optimizer/add"}
+  ROOT %tuple = (f32[], f32[8,8]{1,0}) tuple(%norm, %new)
+}
+
+%fused_dw (x: f32[8,8], p: f32[8,8]) -> f32[8,8] {
+  %x = f32[8,8]{1,0} parameter(0)
+  %p = f32[8,8]{1,0} parameter(1)
+  %dw = f32[8,8]{1,0} convolution(%x, %x), metadata={op_name="jit(step)/transpose(jvp(forward))/dot_general"}
+  ROOT %new = f32[8,8]{1,0} add(%p, %dw), metadata={op_name="jit(step)/optimizer/add"}
+}
+
+%fused_forward (x: f32[8,8]) -> f32[8,8] {
+  %x = f32[8,8]{1,0} parameter(0)
+  ROOT %y = f32[8,8]{1,0} add(%x, %x), metadata={op_name="jit(step)/jvp(forward)/add"}
+}
+
+ENTRY %main (a: f32[8,8], b: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0)
+  %b = f32[8,8]{1,0} parameter(1)
+  %fusion.0 = f32[8,8]{1,0} fusion(%a), kind=kLoop, calls=%fused_forward
+  %convert_reduce_fusion = (f32[], f32[8,8]{1,0}) fusion(%a, %b), kind=kLoop, calls=%fused_pass, metadata={op_name="jit(step)/numerics/reduce_sum"}
+  ROOT %fusion.1 = f32[8,8]{1,0} fusion(%a, %b), kind=kOutput, calls=%fused_dw, metadata={op_name="jit(step)/transpose(jvp(forward))/dot_general"}
+}
+"""
+
+
+def test_the_optimizers_pass_counts_as_optimizer_whatever_name_it_took():
+    """A fusion of the update with the bundle's norms is named after a norm's
+    reduce; one that still holds the gradient's matmul stays the matmul's."""
+    assert obs_profile.update_passes_of_hlo(HLO_WITH_AN_UPDATE_PASS) == [
+        "convert_reduce_fusion"
+    ]
+    table = obs_profile.phases_of_hlo(HLO_WITH_AN_UPDATE_PASS)
+    assert table["convert_reduce_fusion"] == "optimizer"
+    assert table["fusion.1"] == "backward"
+    assert table["fusion.0"] == "forward"
+    assert obs_profile.update_passes_of_hlo(HLO_BEFORE_THE_SCOPES) == []
+
+
 def test_the_step_is_bit_equal_with_and_without_the_scopes(monkeypatch):
     state, batch = _toy_step_and_inputs()
     with_scopes = make_train_step(mse_loss, numerics=True, donate=False)

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from flax import linen as nn
 
 from edl_tpu.models import MLP, LinearRegression, ResNet
 from edl_tpu.models.resnet import BasicBlockVd
@@ -518,3 +519,168 @@ class TestMaskedTrainStep:
         )
         with _pytest.raises(ValueError, match="batch_stats"):
             masked(state, (x, np.zeros(4, np.int64)), np.ones(4, bool))
+
+
+# -- large weight gradients leave the optimizer's fusion ----------------------
+
+
+class _Sower(nn.Module):
+    """Two Dense layers; with ``sows`` a term of the objective through
+    ``"losses"`` and a gauge through ``"metrics"``."""
+
+    sows: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        h = jnp.tanh(nn.Dense(16, name="inner")(x))
+        if self.sows:
+            self.sow("losses", "activation_l2", 1e-2 * jnp.mean(h * h))
+            self.sow("metrics", "activation_absmax", jnp.max(jnp.abs(h)))
+        return nn.Dense(1, name="outer")(h)
+
+
+def _apart_state_and_batch(sows, tx=None):
+    rs = np.random.RandomState(3)
+    x = rs.randn(8, 8).astype(np.float32)
+    y = rs.randn(8, 1).astype(np.float32)
+    state = create_state(
+        _Sower(sows=sows), jax.random.PRNGKey(0), x, tx or optax.adamw(1e-2)
+    )
+    return state, (x, y)
+
+
+@pytest.mark.parametrize("sows", [False, True], ids=["no_sown", "sown_losses"])
+@pytest.mark.parametrize("split", [True, False], ids=["split", "unsplit"])
+def test_the_step_is_bit_equal_with_and_without_leaves_taken_apart(
+    monkeypatch, split, sows
+):
+    """The barrier is the identity: parameters, optimizer state, loss and
+    bundle to the bit, whether the rule takes every matrix or none."""
+    from edl_tpu.train import step as step_module
+
+    monkeypatch.setenv("EDL_NUMERICS_GNS", "1" if split else "0")
+    state, batch = _apart_state_and_batch(sows)
+    results, barriers = [], []
+    for min_width in (1, 2**20):
+        monkeypatch.setattr(step_module, "GRAD_APART_MIN_WIDTH", min_width)
+        step = make_train_step(mse_loss, numerics=True, donate=False)
+        barriers.append(
+            step.lower(state, batch).as_text().count("optimization_barrier")
+        )
+        results.append(step(state, batch))
+    halves = 2 if split and not sows else 1  # a model that sows is never split
+    assert barriers == [2 * halves, 0]  # the two Dense kernels; no bias
+    (new_a, metrics_a), (new_b, metrics_b) = results
+    assert ("half_sq" in metrics_a["_numerics"]) == (halves == 2)
+    assert ("aux_loss" in metrics_a) == sows
+    leaves_a, tree_a = jax.tree.flatten((new_a, metrics_a))
+    leaves_b, tree_b = jax.tree.flatten((new_b, metrics_b))
+    assert tree_a == tree_b
+    for a, b in zip(leaves_a, leaves_b):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # and the step moved something
+    assert not np.array_equal(
+        np.asarray(new_a.params["inner"]["kernel"]),
+        np.asarray(state.params["inner"]["kernel"]),
+    )
+
+
+def _shapes(*leaves):
+    return {
+        "leaf%d" % i: jax.ShapeDtypeStruct(shape, dtype)
+        for i, (shape, dtype) in enumerate(leaves)
+    }
+
+
+def _resnet50_vd_params():
+    from edl_tpu.models import ResNet50_vd
+
+    variables = jax.eval_shape(
+        lambda: ResNet50_vd(num_classes=1000).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 224, 224, 3)), train=False
+        )
+    )
+    return variables["params"]
+
+
+@pytest.mark.parametrize("tree,taken", [
+    (_resnet50_vd_params, 0),
+    (lambda: _shapes(((3840, 11008), jnp.float32)), 1),
+    (lambda: _shapes(((11008, 3840), jnp.float32), ((4096, 14336), jnp.bfloat16),
+                     ((3840, 8670), jnp.float32), ((2880, 3840), jnp.float32),
+                     ((3840,), jnp.float32)), 4),
+    (lambda: _shapes(((2048, 8192), jnp.float32),                # Granite's SwiGLU
+                     ((2048, 11776), jnp.float32),               # LFM2's
+                     ((2048, 25024), jnp.float32)), 0),          # Trinity's head
+    (lambda: _shapes(((4096, 32000), jnp.float32),               # Mistral's head
+                     ((32000, 4096), jnp.float32)), 0),
+    (lambda: _shapes(((16, 4096, 4096), jnp.float32),            # an expert bank
+                     ((4096, 32, 128), jnp.float32)), 0),        # a DenseGeneral
+    (lambda: _shapes(((2**30,), jnp.float32), ((40 << 20,), jnp.float32)), 0),
+    (lambda: _shapes(((4096, 14336), jnp.int32)), 0),            # never an integer
+    (lambda: _shapes(((4096, 14336), jnp.bool_)), 0),
+    (lambda: _shapes(((), jnp.float32)), 0),
+], ids=["resnet50_vd", "olmo_swiglu", "matrices_and_a_norm", "hidden_2048",
+        "vocabulary_matrices", "rank_3", "one_d", "integer", "boolean", "scalar"])
+def test_the_rule_on_which_gradient_leaves_are_taken_apart(tree, taken):
+    from edl_tpu.train import step as step_module
+
+    leaves = jax.tree.leaves(tree())
+    assert leaves
+    assert sum(step_module.taken_apart(leaf) for leaf in leaves) == taken
+
+
+@pytest.mark.parametrize("qualifying", [0, 1, 3])
+@pytest.mark.parametrize("split", [True, False], ids=["split", "unsplit"])
+def test_the_lowered_step_holds_one_barrier_a_leaf_taken(
+    monkeypatch, split, qualifying
+):
+    """k leaves taken: k ``optimization_barrier``s in the lowered text, 2k
+    under the half-batch split, none for a tree with none to take."""
+    from edl_tpu.train import step as step_module
+
+    monkeypatch.setenv("EDL_NUMERICS_GNS", "1" if split else "0")
+    # kernels of [8, 16], [16, 16] (one or three of them) and [16, 1], biases
+    monkeypatch.setattr(step_module, "GRAD_APART_MIN_WIDTH", 16)
+    hidden = {0: (), 1: (16, 16), 3: (16, 16, 16, 16)}[qualifying]
+    model = MLP(hidden=hidden, features=1)
+    x = np.zeros((8, 8), np.float32)
+    state = create_state(model, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
+    kernels = [
+        leaf for leaf in jax.tree.leaves(state.params)
+        if step_module.taken_apart(leaf)
+    ]
+    assert len(kernels) == qualifying
+    step = make_train_step(mse_loss, numerics=True)
+    text = step.lower(state, (x, np.zeros((8, 1), np.float32))).as_text()
+    assert text.count("optimization_barrier") == qualifying * (2 if split else 1)
+
+
+def test_tracing_a_step_leaves_one_grad_apart_instant(monkeypatch):
+    from edl_tpu.obs import trace as obs_trace
+    from edl_tpu.train import step as step_module
+
+    monkeypatch.setattr(step_module, "GRAD_APART_MIN_WIDTH", 8)
+    step_module._note_apart.cache_clear()
+    tracer = obs_trace.get_tracer()
+    before = len([e for e in tracer.to_events() if e["name"] == "grad_apart"])
+    state, batch = _apart_state_and_batch(False)
+    for _ in range(2):  # the second trace at the same shapes adds nothing
+        # split: ``grads_apart`` runs once a half, over one and the same tree
+        make_train_step(mse_loss, numerics=True).lower(state, batch)
+    noted = [e for e in tracer.to_events() if e["name"] == "grad_apart"][before:]
+    assert len(noted) == 1
+    # of inner's [8, 16] kernel and bias and outer's [16, 1] and bias: the first
+    assert noted[0]["args"] == {
+        "leaves": 1, "of": 4, "bytes": 4 * 8 * 16, "largest_bytes": 4 * 8 * 16,
+        "min_width": 8, "max_elements": step_module.GRAD_APART_MAX_ELEMENTS,
+    }
+    # a tree with nothing to take says so
+    monkeypatch.setattr(step_module, "GRAD_APART_MIN_WIDTH", 2**20)
+    make_train_step(mse_loss, numerics=True).lower(state, batch)
+    noted = [e for e in tracer.to_events() if e["name"] == "grad_apart"][before:]
+    assert [e["args"]["leaves"] for e in noted] == [1, 0]
+    assert noted[1]["args"] == {
+        "leaves": 0, "of": 4, "bytes": 0, "largest_bytes": 0, "min_width": 2**20,
+        "max_elements": step_module.GRAD_APART_MAX_ELEMENTS,
+    }
