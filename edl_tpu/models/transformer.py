@@ -32,7 +32,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from functools import partial
+import math
+from functools import lru_cache, partial
 from typing import Any, Callable, Optional, Tuple, Union
 
 import flax.linen as nn
@@ -43,6 +44,7 @@ from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.attention import attention
 from edl_tpu.ops.gated_delta import REMAT_NAMES as GDN_NAMES
 
@@ -153,6 +155,54 @@ def rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array
     return out.astype(x.dtype)
 
 
+@lru_cache(maxsize=None)
+def _note_dw_apart(kernel: str, shape: Tuple[int, ...], dtype: str):
+    """One ``dw_apart`` instant in the span ring for each shape a projection
+    of the attention layer has its weight gradient fenced at."""
+    obs_trace.get_tracer().instant(
+        "dw_apart", kernel=kernel, shape=list(shape), dtype=dtype,
+        bytes=math.prod(shape) * jnp.dtype(dtype).itemsize,
+    )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dw_apart(name: str, kernel):
+    """``kernel``, and a fence behind its gradient: forward the identity
+    (it lowers to nothing), backward one ``optimization_barrier`` on the
+    cotangent. ``name`` is the projection's, for the instant."""
+    return kernel
+
+
+def _dw_apart_fwd(name, kernel):
+    return kernel, None
+
+
+def _dw_apart_bwd(name, _, ct):
+    _note_dw_apart(name, tuple(ct.shape), str(ct.dtype))
+    return (jax.lax.optimization_barrier(ct),)
+
+
+_dw_apart.defvjp(_dw_apart_fwd, _dw_apart_bwd)
+
+
+def _heads_dot_general(name: str, x, kernel, dimension_numbers, precision=None):
+    """``nn.DenseGeneral``'s ``dot_general`` for a projection into heads
+    (``[d_model, heads, head_dim]``) or out of them (``[heads, head_dim,
+    d_model]``): ``lax.dot_general`` whose weight gradient is taken out of
+    the optimizer's fusion. Left to itself XLA fuses the update and the
+    numerics bundle's norms into the rank-3 ``dot_general`` that produces
+    this dW, and that fused form ran at 26-51% of peak wherever a cell held
+    a wide one (PERF.md section 6, PRs 38 and 39; the narrow ones are a wash
+    either way). Behind the fence dW is a plain matmul, written once in the
+    compute dtype it is rounded to anyway, and its readers one pass. The
+    layer decides, not ``train/step.py:taken_apart``: no shape tells these
+    leaves from an expert bank ``[E, d, w]``, whose gradient leaves a custom
+    call and is apart already."""
+    return jax.lax.dot_general(
+        x, _dw_apart(name, kernel), dimension_numbers, precision=precision
+    )
+
+
 class Attention(nn.Module):
     """Multi-head / grouped-query attention.
 
@@ -191,7 +241,9 @@ class Attention(nn.Module):
     ``(heads' outputs * sigmoid(g)) W_o``. ``kernel_scope`` names the device
     scope of the attention call alone (a mixed-window model tells its two
     kinds of layer apart by it); the QK norms and the gate then sit under
-    ``attn_gate``.
+    ``attn_gate``. Every projection's weight gradient is written by a matmul
+    of its own, behind a fence (``_heads_dot_general``); the parameters are
+    plain ``nn.DenseGeneral``'s.
     """
 
     num_heads: int
@@ -223,10 +275,16 @@ class Attention(nn.Module):
                 "num_kv_heads (%d) must be a positive divisor of "
                 "num_heads (%d)" % (kv_heads, self.num_heads)
             )
-        dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
-        q = dense(features=(self.num_heads, head_dim), name="q")(x)
-        k = dense(features=(kv_heads, head_dim), name="k")(x)
-        v = dense(features=(kv_heads, head_dim), name="v")(x)
+
+        def dense(name: str, **shape):
+            return nn.DenseGeneral(
+                use_bias=False, dtype=self.dtype, name=name,
+                dot_general=partial(_heads_dot_general, name), **shape,
+            )
+
+        q = dense("q", features=(self.num_heads, head_dim))(x)
+        k = dense("k", features=(kv_heads, head_dim))(x)
+        v = dense("v", features=(kv_heads, head_dim))(x)
         if self.qk_norm == "head":
             with _scope(self.kernel_scope and "attn_gate"):
                 q = RMSNorm(self.norm_eps, name="q_norm")(q)
@@ -267,12 +325,9 @@ class Attention(nn.Module):
             out = jnp.swapaxes(out, 1, 2)
         if self.gate:
             with _scope(self.kernel_scope and "attn_gate"):
-                g = dense(features=(self.num_heads, head_dim), name="g")(x)
+                g = dense("g", features=(self.num_heads, head_dim))(x)
                 out = out * nn.sigmoid(g)
-        return nn.DenseGeneral(
-            features=x.shape[-1], axis=(-2, -1), use_bias=False,
-            dtype=self.dtype, name="o",
-        )(out)
+        return dense("o", features=x.shape[-1], axis=(-2, -1))(out)
 
     def _decode_step(self, q, k, v, kv_heads: int, head_dim: int):
         """Cached autoregressive attention for T >= 1 new tokens: insert

@@ -39,6 +39,21 @@ def store():
     srv.stop()
 
 
+@pytest.fixture()
+def unfence(monkeypatch):
+    """``unfence()`` takes the attention projections' dW fence
+    (``models/transformer.py:_dw_apart``) off for the rest of the test: the
+    layer then lowers as plain ``nn.DenseGeneral`` does. Build fresh functions
+    after it: ``jax.checkpoint`` and ``jax.jit`` keep a traced one."""
+
+    def off():
+        from edl_tpu.models import transformer
+
+        monkeypatch.setattr(transformer, "_dw_apart", lambda name, kernel: kernel)
+
+    return off
+
+
 def incarnations(out_dir):
     """toy_worker marker files -> {stage: {rank: world}}"""
     out = defaultdict(dict)

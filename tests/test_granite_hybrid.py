@@ -343,10 +343,20 @@ OLMOE_STEP = {
     True: "811833c7958dbbe89a17631f372dc63fd56d4908d71b9bde3b4a094b96322af7",
     False: "73177280fe77daaf9570913ee9a4183317afaf70d6939a6941b5b4df3266adcb",
 }
+# the same step behind the attention projections' fence (PR 39): one
+# ``optimization_barrier`` a projection and half-batch; with the fence off
+# the text is still the one above
+OLMOE_STEP_FENCED = {
+    True: "d329de59cd77ec13f863decd379257ea10d58cde3c5a3469c8f9509eb898da57",
+    False: "01af43030cd3bd7c72fc11f9e8ba72a3e7b4003f5cab7ce0eb768267299ad84b",
+}
 
 
+@pytest.mark.parametrize("fenced", [True, False], ids=["fenced", "unfenced"])
 @pytest.mark.parametrize("numerics", [True, False], ids=["numerics", "bare"])
-def test_the_expert_lm_lowers_to_the_step_it_was(numerics):
+def test_the_expert_lm_lowers_to_the_step_it_was(unfence, numerics, fenced):
+    if not fenced:
+        unfence()
     lm = TransformerLM(
         vocab_size=64, d_model=32, num_heads=4, num_kv_heads=4, num_layers=2,
         d_ff=24, dtype=jnp.bfloat16, remat=True, norm_eps=1e-5, qk_norm=True,
@@ -359,7 +369,9 @@ def test_the_expert_lm_lowers_to_the_step_it_was(numerics):
     text = make_train_step(lm_loss, numerics=numerics).lower(
         state, (tokens, tokens)
     ).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == OLMOE_STEP[numerics]
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        OLMOE_STEP_FENCED if fenced else OLMOE_STEP
+    )[numerics]
 
 
 def test_an_arch_spec_at_its_defaults_is_the_dense_model():

@@ -389,6 +389,89 @@ def test_the_optimizers_pass_counts_as_optimizer_whatever_name_it_took():
     assert obs_profile.update_passes_of_hlo(HLO_BEFORE_THE_SCOPES) == []
 
 
+HLO_WITH_A_FENCED_HEAD_PROJECTION = """
+HloModule jit_step
+
+%fused_dq (x: bf16[16,8], dy: bf16[16,4,2]) -> bf16[8,4,2] {
+  %x = bf16[16,8]{1,0} parameter(0)
+  %dy = bf16[16,4,2]{2,1,0} parameter(1)
+  ROOT %dw = bf16[8,4,2]{2,0,1} convolution(%x, %dy), metadata={op_name="jit(step)/transpose(jvp(forward))/TransformerLM/checkpoint/layer_4/attn/q/dot_general"}
+}
+
+%fused_pass (g: bf16[8,4,2], p: f32[8,4,2]) -> (f32[], f32[8,4,2]) {
+  %g = bf16[8,4,2]{2,0,1} parameter(0)
+  %p = f32[8,4,2]{2,0,1} parameter(1)
+  %g32 = f32[8,4,2]{2,0,1} convert(%g), metadata={op_name="jit(step)/transpose(jvp(forward))/TransformerLM/checkpoint/layer_4/attn/q/convert_element_type"}
+  %sq = f32[8,4,2]{2,0,1} multiply(%g32, %g32), metadata={op_name="jit(step)/numerics/square"}
+  %norm = f32[] reduce(%sq), metadata={op_name="jit(step)/numerics/reduce_sum"}
+  %new = f32[8,4,2]{2,0,1} add(%p, %g32), metadata={op_name="jit(step)/optimizer/add"}
+  ROOT %tuple = (f32[], f32[8,4,2]{2,0,1}) tuple(%norm, %new)
+}
+
+%fused_forward (x: bf16[16,8]) -> bf16[16,8] {
+  %x = bf16[16,8]{1,0} parameter(0)
+  ROOT %y = bf16[16,8]{1,0} add(%x, %x), metadata={op_name="jit(step)/jvp(forward)/add"}
+}
+
+ENTRY %main (x: bf16[16,8], dy: bf16[16,4,2], p: f32[8,4,2]) -> f32[8,4,2] {
+  %x = bf16[16,8]{1,0} parameter(0)
+  %dy = bf16[16,4,2]{2,1,0} parameter(1)
+  %p = f32[8,4,2]{2,0,1} parameter(2)
+  %fusion.0 = bf16[16,8]{1,0} fusion(%x), kind=kLoop, calls=%fused_forward
+  %fusion.3132 = bf16[8,4,2]{2,0,1} fusion(%x, %dy), kind=kOutput, calls=%fused_dq, metadata={op_name="jit(step)/transpose(jvp(forward))/TransformerLM/checkpoint/layer_4/attn/q/dot_general"}
+  ROOT %convert_reduce_fusion.46 = (f32[], f32[8,4,2]{2,0,1}) fusion(%fusion.3132, %p), kind=kLoop, calls=%fused_pass, metadata={op_name="jit(step)/numerics/reduce_sum"}
+}
+"""
+
+
+def test_the_pass_behind_a_fenced_head_projection_counts_as_optimizer():
+    """The step as the chip compiles it behind ``models/transformer.py``'s
+    fence (names and op_names of ``trinity_mini``'s own ``q``, shapes cut):
+    the matmul that writes the bfloat16 dW is ``backward``, its reader, which
+    converts it, updates the leaf and carries a norm, is ``optimizer``."""
+    assert obs_profile.update_passes_of_hlo(HLO_WITH_A_FENCED_HEAD_PROJECTION) == [
+        "convert_reduce_fusion.46"
+    ]
+    table = obs_profile.phases_of_hlo(HLO_WITH_A_FENCED_HEAD_PROJECTION)
+    assert table["fusion.3132"] == "backward"
+    assert table["convert_reduce_fusion.46"] == "optimizer"
+    assert table["fusion.0"] == "forward"
+
+
+def test_tracing_a_step_leaves_one_dw_apart_instant_a_shape():
+    """``q`` and ``g`` share a shape and each has an instant of its own, as
+    ``k`` and ``v``, and ``o`` has its; two layers and a second trace add none."""
+    from edl_tpu.models import ArchSpec, TransformerLM
+    from edl_tpu.models import transformer
+    from edl_tpu.train import cross_entropy_loss
+
+    transformer._note_dw_apart.cache_clear()
+    tracer = obs_trace.get_tracer()
+    before = len([e for e in tracer.to_events() if e["name"] == "dw_apart"])
+    lm = TransformerLM(
+        vocab_size=32, d_model=24, num_heads=4, num_kv_heads=2, num_layers=2,
+        d_ff=16, dtype=jnp.bfloat16, remat=True,
+        arch=ArchSpec(head_dim=8, attn_gate=True),
+    )
+    tokens = np.zeros((2, 8), np.int32)
+    state = create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    for _ in range(2):
+        make_train_step(cross_entropy_loss, numerics=True).lower(state, (tokens, tokens))
+    noted = [e for e in tracer.to_events() if e["name"] == "dw_apart"][before:]
+    assert all(e["ph"] == "i" for e in noted)
+    assert sorted((e["args"] for e in noted), key=lambda a: a["kernel"]) == [
+        {"kernel": "g", "shape": [24, 4, 8], "dtype": "bfloat16", "bytes": 1536},
+        {"kernel": "k", "shape": [24, 2, 8], "dtype": "bfloat16", "bytes": 768},
+        {"kernel": "o", "shape": [4, 8, 24], "dtype": "bfloat16", "bytes": 1536},
+        {"kernel": "q", "shape": [24, 4, 8], "dtype": "bfloat16", "bytes": 1536},
+        {"kernel": "v", "shape": [24, 2, 8], "dtype": "bfloat16", "bytes": 768},
+    ]
+    # a forward-only program holds no fence and notes nothing
+    transformer._note_dw_apart.cache_clear()
+    jax.jit(lambda p: lm.apply({"params": p}, tokens)).lower(state.params)
+    assert len([e for e in tracer.to_events() if e["name"] == "dw_apart"]) == before + 5
+
+
 def test_the_step_is_bit_equal_with_and_without_the_scopes(monkeypatch):
     state, batch = _toy_step_and_inputs()
     with_scopes = make_train_step(mse_loss, numerics=True, donate=False)

@@ -470,6 +470,89 @@ def test_a_hybrid_step_on_the_tpu_path_runs_the_conv_kernels_under_ssm_conv(one_
     assert {table[name] for name in table if name.startswith("causal_conv_")} == {"ssm_conv"}
 
 
+def _head_projection_gradients(text):
+    """``{projection: (holds a matmul, holds the optimizer's instructions)}``
+    for the fusions of a compiled step that write an array of an attention
+    projection's kernel shape and are named after its ``dot_general``."""
+    from edl_tpu.obs import profile as obs_profile
+
+    matmuls, updates, computation = set(), set(), None
+    for line in text.splitlines():
+        started = obs_profile._HLO_COMPUTATION.match(line)
+        if started:
+            computation = started.group(1)
+        elif obs_profile._HLO_MATMUL.search(line):
+            matmuls.add(computation)
+        elif 'op_name="jit(step)/optimizer/' in line:
+            updates.add(computation)
+    found = {}
+    for line in text.splitlines():
+        named = re.search(
+            r'op_name="jit\(step\)/transpose\(jvp\(forward\)\)/[^"]*'
+            r'/attn/(?:attn_gate/)?([qkvgo])/dot_general"', line,
+        )
+        called = obs_profile._HLO_CALLS.search(line)
+        written = line.split(" fusion(")[0]
+        if named and called and " fusion(" in line and re.search(
+            r"\[2048,(?:32|4),128\]|\[32,128,2048\]", written
+        ):
+            body = called.group(1)
+            found[named.group(1)] = (body in matmuls, body in updates)
+    return found
+
+
+@pytest.mark.parametrize("fenced", [True, False], ids=["fenced", "left_to_xla"])
+def test_trinitys_head_projections_write_their_gradient_by_a_plain_matmul(
+    one_chip, unfence, fenced
+):
+    """One attention layer at ``trinity_mini``'s widths (2048 wide, GQA 32:4
+    x 128 with the gate, a window of 2048 over 8192 tokens; the SwiGLU and the
+    vocabulary cut to toys), AdamW and the numerics bundle, compiled for the
+    described v5e: the weight gradients of ``q``, ``k``, ``v``, ``g`` and ``o``
+    are fusions that hold the matmul and no instruction of the ``optimizer``
+    scope. Without the fence XLA puts the update into that matmul's fusion,
+    the form the chip ran at 26-34% of peak (PERF.md section 6, PR 38)."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, TransformerLM
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    if not fenced:
+        unfence()
+    lm = TransformerLM(
+        vocab_size=512, d_model=2048, num_heads=32, num_kv_heads=4, num_layers=1,
+        d_ff=256, dtype=jnp.bfloat16, remat=True, qk_norm="head",
+        arch=ArchSpec(
+            layer_types=("sliding_attention",), head_dim=128, rope="sliding",
+            sliding_window=2048, attn_gate=True,
+        ),
+    )
+    tokens = np.zeros((1, 8192), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(4e-4))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = make_train_step(loss, numerics=True).lower(
+            described(state), described((tokens, tokens))
+        ).compile()
+    found = _head_projection_gradients(compiled.as_text())
+    assert set(found) == {"q", "k", "v", "g", "o"}
+    assert all(matmul for matmul, _ in found.values())
+    if fenced:
+        assert not any(update for _, update in found.values())
+    else:  # the check can see the fused form: the wide ones at least
+        assert found["q"][1] and found["g"][1]
+
+
 def test_grid_pipeline_kwargs_carry_dimension_semantics():
     """jax 0.9.0 has ``pltpu.CompilerParams(dimension_semantics=...)``: the
     flash2 family must never run without it (the old guard dropped it

@@ -684,3 +684,181 @@ def test_tracing_a_step_leaves_one_grad_apart_instant(monkeypatch):
         "leaves": 0, "of": 4, "bytes": 0, "largest_bytes": 0, "min_width": 2**20,
         "max_elements": step_module.GRAD_APART_MAX_ELEMENTS,
     }
+
+
+# -- the attention layer's projections fence their own dW ---------------------
+
+
+def _toy_lm(gate, dtype=jnp.float32, **over):
+    from edl_tpu.models.transformer import ArchSpec, TransformerLM
+
+    return TransformerLM(**{
+        "vocab_size": 64, "d_model": 32, "num_heads": 4, "num_kv_heads": 2,
+        "num_layers": 2, "d_ff": 64, "dtype": dtype, "remat": True,
+        "arch": ArchSpec(attn_gate=gate, head_dim=16), **over,
+    })
+
+
+def _toy_lm_state_and_batch(gate, dtype=jnp.float32, **over):
+    tokens = np.random.RandomState(0).randint(0, 64, (4, 16)).astype(np.int32)
+    state = create_state(
+        _toy_lm(gate, dtype, **over), jax.random.PRNGKey(0), tokens,
+        optax.adamw(1e-2),
+    )
+    return state, (tokens, np.roll(tokens, -1, axis=1))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("gate", [False, True], ids=["plain", "gated"])
+@pytest.mark.parametrize("split", [True, False], ids=["split", "unsplit"])
+def test_the_step_is_bit_equal_with_and_without_the_head_projections_fence(
+    monkeypatch, unfence, split, gate, dtype
+):
+    """The fence is the identity: parameters, optimizer state, loss and
+    metrics to the bit. The bundle's norms are sums over the whole tree whose
+    order XLA picks fusion by fusion, so they agree to a float32's rounding."""
+    monkeypatch.setenv("EDL_NUMERICS_GNS", "1" if split else "0")
+    state, batch = _toy_lm_state_and_batch(gate, dtype)
+    results, barriers = [], []
+    for fenced in (True, False):
+        if not fenced:
+            unfence()
+        step = make_train_step(cross_entropy_loss, numerics=True, donate=False)
+        barriers.append(
+            step.lower(state, batch).as_text().count("optimization_barrier")
+        )
+        results.append(step(state, batch))
+    # q, k, v, o and the gate's g in each of two layers, each half's under the split
+    assert barriers[0] - barriers[1] == (5 if gate else 4) * 2 * (2 if split else 1)
+    (new_a, metrics_a), (new_b, metrics_b) = results
+    assert ("half_sq" in metrics_a["_numerics"]) == split
+    bundle_a, bundle_b = metrics_a.pop("_numerics"), metrics_b.pop("_numerics")
+    leaves_a, tree_a = jax.tree.flatten((new_a, metrics_a))
+    leaves_b, tree_b = jax.tree.flatten((new_b, metrics_b))
+    assert tree_a == tree_b
+    for a, b in zip(leaves_a, leaves_b):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert jax.tree.structure(bundle_a) == jax.tree.structure(bundle_b)
+    for a, b in zip(jax.tree.leaves(bundle_a), jax.tree.leaves(bundle_b)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6)
+    # and the step moved the fenced kernels
+    for name in ("q", "k", "v", "o") + (("g",) if gate else ()):
+        assert not np.array_equal(
+            np.asarray(new_a.params["layer_0"]["attn"][name]["kernel"]),
+            np.asarray(state.params["layer_0"]["attn"][name]["kernel"]),
+        )
+
+
+class _PlainHeads(nn.Module):
+    """The attention layer's parameters as plain ``nn.DenseGeneral`` makes
+    them: the tree the fence must leave as it was."""
+
+    gate: bool
+
+    @nn.compact
+    def __call__(self, x):
+        q = nn.DenseGeneral(features=(4, 16), use_bias=False, name="q")(x)
+        for name in ("k", "v"):
+            nn.DenseGeneral(features=(2, 16), use_bias=False, name=name)(x)
+        if self.gate:
+            nn.DenseGeneral(features=(4, 16), use_bias=False, name="g")(x)
+        return nn.DenseGeneral(
+            features=32, axis=(-2, -1), use_bias=False, name="o"
+        )(q)
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["plain", "gated"])
+def test_the_fenced_projections_keep_dense_generals_tree_and_initial_values(gate):
+    from edl_tpu.models.transformer import Attention
+
+    x = jnp.zeros((2, 8, 32), jnp.bfloat16)
+    positions = jnp.broadcast_to(jnp.arange(8)[None, :], (2, 8))
+    got = Attention(
+        num_heads=4, num_kv_heads=2, head_dim=16, gate=gate
+    ).init(jax.random.PRNGKey(7), x, positions)["params"]
+    want = _PlainHeads(gate).init(jax.random.PRNGKey(7), x)["params"]
+    shapes = {"q": (32, 4, 16), "k": (32, 2, 16), "v": (32, 2, 16), "o": (4, 16, 32)}
+    if gate:
+        shapes["g"] = (32, 4, 16)
+    assert {k: v["kernel"].shape for k, v in got.items()} == shapes
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype == jnp.float32
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["plain", "gated"])
+def test_the_lowered_gradient_holds_one_barrier_a_head_projection(unfence, gate):
+    """q, k, v, o (and g) each one; the forward and the decode programs none
+    at all."""
+    from edl_tpu.models.transformer import Attention
+
+    x = jnp.ones((2, 8, 32), jnp.bfloat16)
+    positions = jnp.broadcast_to(jnp.arange(8)[None, :], (2, 8))
+    layer = Attention(num_heads=4, num_kv_heads=2, head_dim=16, gate=gate)
+    params = layer.init(jax.random.PRNGKey(0), x, positions)["params"]
+    decoder = layer.clone(decode=True, max_decode_len=8)
+    cache = decoder.init(jax.random.PRNGKey(0), x, positions)["cache"]
+
+    def barriers(fn, *args):
+        return jax.jit(fn).lower(*args).as_text().count("optimization_barrier")
+
+    def counted():  # fresh functions: ``jax.checkpoint`` keeps a traced one
+        def forward(p):
+            return layer.apply({"params": p}, x, positions)
+
+        def decode(p, c):
+            return decoder.apply(
+                {"params": p, "cache": c}, x[:, :1], positions[:, :1],
+                mutable=["cache"],
+            )
+
+        return {
+            "forward": barriers(forward, params),
+            "decode": barriers(decode, params, cache),
+            "gradient": barriers(
+                jax.grad(lambda p: forward(p).astype(jnp.float32).sum()), params
+            ),
+            "under_remat": barriers(
+                jax.grad(
+                    lambda p: jax.checkpoint(forward)(p).astype(jnp.float32).sum()
+                ),
+                params,
+            ),
+        }
+
+    fenced = counted()
+    unfence()
+    plain = counted()  # ``jax.checkpoint`` brings barriers of its own
+    assert plain["forward"] == plain["decode"] == plain["gradient"] == 0
+    assert {k: fenced[k] - plain[k] for k in fenced} == {
+        "forward": 0, "decode": 0,
+        "gradient": 5 if gate else 4, "under_remat": 5 if gate else 4,
+    }
+
+
+def test_the_gradient_through_the_fence_is_the_plain_gradient_to_the_bit(unfence):
+    """Under ``jax.checkpoint`` and without: every kernel's gradient equals
+    the unfenced layer's."""
+    from edl_tpu.models.transformer import Attention
+
+    rs = np.random.RandomState(1)
+    x = jnp.asarray(rs.randn(2, 8, 32), jnp.bfloat16)
+    positions = jnp.broadcast_to(jnp.arange(8)[None, :], (2, 8))
+    layer = Attention(num_heads=4, num_kv_heads=2, head_dim=16, gate=True)
+    params = layer.init(jax.random.PRNGKey(0), x, positions)["params"]
+
+    def grads(remat):
+        def loss(p):
+            fn = lambda p: layer.apply({"params": p}, x, positions)  # noqa: E731
+            out = (jax.checkpoint(fn) if remat else fn)(p)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.jit(jax.grad(loss))(params)
+
+    fenced = [grads(False), grads(True)]
+    unfence()
+    plain = [grads(False), grads(True)]
+    for got, want in zip(fenced, plain):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))

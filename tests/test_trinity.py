@@ -424,3 +424,72 @@ def test_a_windowed_call_never_reaches_the_whole_kv_kernels():
     # fewer steps than blocks: what lies outside the window is not walked
     assert A._span_steps(2048, 256, 1024, 8192, 8192) == (3, 12)
     assert A._span_steps(2048, 512, 1024, 8192, 8192) == (3, 6)
+
+
+# -- the attention layer's projections fence their weight gradients; banks do not
+
+
+def test_the_toy_twins_step_fences_its_head_projections_and_nothing_else(unfence):
+    """Four attention layers with a gate: twenty barriers more than the
+    unfenced step's (the model sows, so the step is never split), the ring
+    names ``q``, ``k``, ``v``, ``g``, ``o`` and no bank ``[E, d, w]``, and
+    the tree is the unfenced model's."""
+    from edl_tpu.models import transformer
+    from edl_tpu.obs import trace as obs_trace
+
+    job = family.build(TOY, 1, 0)
+    batch = family.host_batches(TOY, 1, 0, n_batches=1)[0]
+    transformer._note_dw_apart.cache_clear()
+    tracer = obs_trace.get_tracer()
+    before = len([e for e in tracer.to_events() if e["name"] == "dw_apart"])
+    barriers, trees = [], []
+    for fenced in (True, False):
+        if not fenced:
+            unfence()
+        state = create_state(
+            job["model"], jax.random.PRNGKey(0), job["sample_input"], job["optimizer"]
+        )
+        trees.append(jax.tree.map(lambda a: (a.shape, a.dtype), state.params))
+        text = make_train_step(job["loss"], numerics=True).lower(state, batch).as_text()
+        barriers.append(text.count("optimization_barrier"))
+    assert barriers[0] - barriers[1] == 5 * TOY["num_hidden_layers"]
+    assert trees[0] == trees[1]
+    banks = [
+        leaf.shape for leaf in jax.tree.leaves(state.params)
+        if leaf.ndim == 3 and leaf.shape[0] == TOY["num_experts"]
+    ]
+    assert banks  # the held experts' banks are rank 3 too
+    noted = [e["args"] for e in tracer.to_events() if e["name"] == "dw_apart"][before:]
+    hidden, heads = TOY["hidden_size"], TOY["num_attention_heads"]
+    kv, head_dim = TOY["num_key_value_heads"], TOY["head_dim"]
+    assert sorted((a["kernel"], tuple(a["shape"])) for a in noted) == [
+        ("g", (hidden, heads, head_dim)), ("k", (hidden, kv, head_dim)),
+        ("o", (heads, head_dim, hidden)), ("q", (hidden, heads, head_dim)),
+        ("v", (hidden, kv, head_dim)),
+    ]
+    assert all(a["dtype"] == "float32" for a in noted)  # the toy's compute dtype
+    assert all(a["bytes"] == 4 * int(np.prod(a["shape"])) for a in noted)
+
+
+def test_the_steps_rule_takes_no_leaf_of_trinitys_own_tree():
+    """``grads_apart`` judges rank 2 only: at the published widths its count
+    stays 0, and the twenty-five projections are the layer's to fence."""
+    from edl_tpu.train import step as step_module
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "trinity_mini.json")) as f:
+        config = json.load(f)
+    job = family.build(config, 1, 0)
+    params = jax.eval_shape(
+        lambda: job["model"].init(jax.random.PRNGKey(0), job["sample_input"])
+    )["params"]
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    assert sum(step_module.taken_apart(leaf) for _, leaf in leaves) == 0
+    heads = {}
+    for path, leaf in leaves:
+        keys = [str(k.key) for k in path]
+        if "attn" in keys and keys[-2] in ("q", "k", "v", "g", "o"):
+            heads.setdefault(keys[-2], set()).add(leaf.shape)
+    assert heads == {
+        "q": {(2048, 32, 128)}, "g": {(2048, 32, 128)},
+        "k": {(2048, 4, 128)}, "v": {(2048, 4, 128)}, "o": {(32, 128, 2048)},
+    }
