@@ -1,5 +1,6 @@
 """Attention: jnp reference + two families of Pallas flash-attention TPU
-kernels, behind one measured dispatch (:func:`attention`).
+kernels, behind one routing function (:func:`_route`, asked by
+:func:`attention`).
 
 A flash kernel streams KV blocks through VMEM with the online-softmax
 recurrence (running row-max ``m``, denominator ``l``, numerator ``acc``),
@@ -14,14 +15,14 @@ whole K and V of its head in VMEM and loops over kv blocks with
 ``_flash2_kernel`` forward, ``_flash2_bwd_kernel`` backward) puts the kv
 blocks (backward: the q blocks) on a third, innermost grid dimension, so
 that they are copied block by block behind the compute; it is the one that
-runs past :func:`_flash_max_seq` and the one that takes a **window**
+runs past ``_WHOLE_KV_MAX_SEQ`` and the one that takes a **window**
 (``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
 ``i - W < j <= i``). Under a mask its innermost steps are **spans** of the
 other side that start where a block's first visible key (or row) lies, at
 an element and not at a block under a window; a step the mask leaves
 nothing for holds the nearest live span again, so what a block cannot see
 is neither copied nor computed, and every live tile is masked on both
-edges. The whole-KV family refuses a window and the dispatch sends a
+edges. The whole-KV family refuses a window and :func:`_route` sends a
 windowed call to flash2 at every length; the dense reference takes it as
 a mask. Causal masking compares global q/k positions from
 ``broadcasted_iota`` (TPU needs ≥2D iota).
@@ -48,7 +49,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -1299,14 +1299,14 @@ def flash_block_grads(
     rotation); shapes the kernels can't tile use the jnp twin.
 
     Default blocks come from the measured tables (whole-KV backward
-    table, or flash2's past the compile limit — the whole-KV kernels do
-    not COMPILE beyond :func:`_flash_max_seq`, see _select_impls);
-    explicit block args always reach the kernel that runs."""
+    table, or flash2's where :func:`_whole_kv_serves` says the whole-KV
+    kernels do not); explicit block args always reach the kernel that
+    runs."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
     tk = k.shape[2]
-    long_seq = max(tq, tk) > _flash_max_seq()
+    long_seq = not _whole_kv_serves(tq, tk)
     if block_q is None or block_k is None:
         dbq, dbk = _FLASH2_BLOCKS_BWD if long_seq else _kernel_blocks(tq)[1]
         block_q = block_q or dbq
@@ -1467,15 +1467,16 @@ def flash_with_lse(
     the primitive blockwise/ring merging builds on. Callers own
     differentiation (ring attention defines its own VJP from
     :func:`flash_block_grads`). Default blocks come from the measured
-    tables (whole-KV kernel, or flash2 past its compile limit);
-    explicit block args always reach the kernel that runs."""
+    tables (whole-KV kernel, or flash2 where :func:`_whole_kv_serves`
+    says it does not); explicit block args always reach the kernel that
+    runs."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
     tk = k.shape[2]
     # resolve kernel + blocks FIRST so the ragged precheck validates the
     # exact blocks the kernel will run with
-    long_seq = max(tq, tk) > _flash_max_seq()
+    long_seq = not _whole_kv_serves(tq, tk)
     if block_q is None or block_k is None:
         dbq, dbk = _FLASH2_BLOCKS_FWD if long_seq else _kernel_blocks(tq)[0]
         block_q = block_q or dbq
@@ -1487,9 +1488,8 @@ def flash_with_lse(
         return attention_reference_with_lse(
             q, k, v, causal=causal, scale=scale
         )
+    # both forwards keep the same residual contract
     forward = _flash2_forward if long_seq else _flash_forward
-    # flash2 past the compile limit: the whole-KV kernel does not
-    # COMPILE there (see _select_impls); same residual contract
     out, lse = forward(q, k, v, causal, scale, bq, bk, _interpret())
     return out, lse.reshape(b, h, tq)
 
@@ -1511,184 +1511,55 @@ def flash_attention(
     2048 vs the old fixed 128. Explicit block args win — including past
     the whole-KV compile limit, where they reach the flash2 kernels.
     A ``window`` is served by the flash2 kernels at every length (see
-    :func:`_select_impls`)."""
+    :func:`_whole_kv_serves`)."""
     _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if window is not None or max(q.shape[2], k.shape[2]) > _flash_max_seq():
-        # whole-KV kernel does not compile past this length: serve the
-        # same contract through the grid-pipelined kernels, which fill any
-        # unspecified block from their own measured defaults
+    tq, tk, windowed = q.shape[2], k.shape[2], window is not None
+    if not _whole_kv_serves(tq, tk, windowed):
+        # the same contract through the grid-pipelined kernels, which fill
+        # any unspecified block from their own measured defaults
         # (_flash2_blocks)
         blocks = (block_q, block_k)
         return _auto(
-            q, k, v, causal, scale, "flash2", "flash2", blocks, blocks, window
+            q, k, v, causal, scale, *_route(tq, tk, windowed), blocks, blocks,
+            window,
         )
     if block_q is None or block_k is None:
-        (fbq, fbk), _ = _kernel_blocks(q.shape[2])
+        (fbq, fbk), _ = _kernel_blocks(tq)
         block_q = block_q or fbq
         block_k = block_k or fbk
     return _flash(q, k, v, causal, scale, block_q, block_k)
 
 
-# -- measured dispatch ------------------------------------------------------
-#
-# Round-2 on-chip numbers (v5e bf16, [4,16,T,64], attention_tpu_r2.jsonl)
-# showed the Pallas kernel LOSING to XLA's dense path forward at T<=2048
-# (1.64 vs 0.97 ms at 1024, 6.18 vs 2.92 at 2048) while WINNING backward
-# (flash bwd ~1.1/1.7 ms vs dense vjp ~1.8/6.8) and forward at 4096
-# (25.0 vs 30.9). Shipping one implementation is a deoptimization
-# somewhere; :func:`attention` instead composes the measured-fastest
-# forward and backward independently — the dense path stays a candidate,
-# so the dispatch is never slower than XLA by construction.
+# -- routing ----------------------------------------------------------------
 
-# (max_seq, impl) rows, first match wins; "whole" rows (when calibrated)
-# route the entire op to jax's builtin TPU flash kernel instead of a
-# fwd/bwd composition.
-_DEFAULT_DISPATCH = {
-    "fwd": ((2048, "ref"), (_INF, "flash")),
-    "bwd": ((_INF, "flash"),),
-    "whole": (),
-}
+# The whole-KV kernels' compile limit: on v5e, jax 0.9, Mosaic refused them
+# past it at every block config, while flash2's VMEM footprint does not grow
+# with the sequence. Feasibility, not speed.
+_WHOLE_KV_MAX_SEQ = 4096
+# Longest ``tq`` whose forward stays whole-KV where both families compile:
+# the July calibration (bench_results/attention_dispatch_r4.json: v5e,
+# [4, 16, T, 64], T 1024-4096). Moved by editing it in a PR the benchmark
+# measures in every cell (ROADMAP S7), not by configuration.
+_WHOLE_KV_FWD_MAX_TQ = 2048
 
 
-# legal impl names per table section: a typo in a calibration artifact must
-# fail fast at load, not silently reroute at the first attention() call
-_VALID_IMPLS = {
-    "fwd": {"ref", "flash", "flash2"},
-    "bwd": {"ref", "flash", "flash2"},
-    "whole": {"builtin", "comp"},
-}
+def _whole_kv_serves(tq: int, tk: int, windowed: bool = False) -> bool:
+    """Whether the whole-KV family can take the call at all: it copies
+    every key of a head into VMEM before it looks at one, so only flash2's
+    grid can leave a window's blocks out, and past ``_WHOLE_KV_MAX_SEQ`` it
+    does not compile."""
+    return not windowed and max(tq, tk) <= _WHOLE_KV_MAX_SEQ
 
 
-# calibration artifact shipped with the package (written by
-# ``tools/attention_bench.py --calibrate`` on real hardware, copied in by
-# the release flow) — the measured default for users who never set
-# EDL_ATTN_DISPATCH
-_PACKAGED_DISPATCH = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "attention_dispatch.json"
-)
-
-
-def _load_table(path: str, base: dict) -> dict:
-    """Parse a calibration artifact into a dispatch table (keys missing
-    from the artifact keep ``base``'s rows), raising on any malformation
-    (unknown impl, non-ascending bounds, bad JSON)."""
-    import json
-
-    with open(path) as f:
-        raw = json.load(f)
-    table = dict(base)
-    for key in ("fwd", "bwd", "whole"):
-        if key not in raw:
-            continue
-        rows = tuple(
-            (_INF if m is None else m, impl) for m, impl in raw[key]
-        )
-        bad = [impl for _, impl in rows if impl not in _VALID_IMPLS[key]]
-        if bad:
-            raise ValueError(
-                "unknown %s impl(s) %r (valid: %s)"
-                % (key, bad, sorted(_VALID_IMPLS[key]))
-            )
-        bounds = [m for m, _ in rows]
-        if any(not isinstance(m, (int, float)) for m in bounds):
-            raise ValueError(
-                "non-numeric %s bound in %r" % (key, raw[key])
-            )
-        if bounds != sorted(bounds):
-            raise ValueError(
-                "%s bounds not ascending: %r" % (key, raw[key])
-            )
-        table[key] = rows
-    return table
-
-
-@functools.lru_cache(maxsize=1)
-def _dispatch_table() -> dict:
-    """The active table, in priority order: a calibration artifact via
-    ``EDL_ATTN_DISPATCH=<json>`` (``tools/attention_bench.py --calibrate``
-    writes one: ``{"fwd": [[2048, "ref"], [null, "flash"]], ...}`` with
-    ``null`` = no upper bound), else the calibration artifact packaged
-    next to this module (``attention_dispatch.json``), else the
-    hard-coded measured default.
-
-    A malformed file or an unknown impl name falls back to the next
-    source WITH a warning — never a silent routing change, never a lazy
-    crash mid-train. An env artifact that omits a key inherits that
-    key's rows from the packaged artifact (not the hard-coded default):
-    each tier refines the one below it."""
-    from edl_tpu.utils.log import get_logger
-
-    logger = get_logger("ops.attention")
-    base = _DEFAULT_DISPATCH
-    base_name = "built-in measured default"
-    if os.path.exists(_PACKAGED_DISPATCH):
-        try:
-            base = _load_table(_PACKAGED_DISPATCH, _DEFAULT_DISPATCH)
-            base_name = "packaged calibration artifact"
-        except (OSError, ValueError, TypeError) as exc:
-            logger.warning(
-                "packaged dispatch artifact %s unusable (%s); the "
-                "built-in measured default table is the base",
-                _PACKAGED_DISPATCH,
-                exc,
-            )
-    path = os.environ.get("EDL_ATTN_DISPATCH", "")
-    if path:
-        try:
-            return _load_table(path, base)
-        except (OSError, ValueError, TypeError) as exc:
-            logger.warning(
-                "EDL_ATTN_DISPATCH=%s unusable (%s); using the %s table",
-                path,
-                exc,
-                base_name,
-            )
-    return base
-
-
-@functools.lru_cache(maxsize=1)
-def _flash_max_seq() -> int:
-    """Longest sequence the whole-KV flash kernel compiles for (v5e,
-    jax 0.9; see _select_impls) — beyond it flash routes to the
-    grid-pipelined flash2. ``EDL_FLASH_MAX_SEQ`` overrides; a malformed
-    or non-positive value warns and keeps the measured default (same
-    contract as EDL_ATTN_DISPATCH: never an import-time crash). Raising
-    it past the measured limit re-exposes the whole-KV compile crash —
-    only do so after a real-chip compile check on the target jax."""
-    raw = os.environ.get("EDL_FLASH_MAX_SEQ", "4096")
-    try:
-        val = int(raw)
-        if val <= 0:
-            raise ValueError("must be positive")
-        return val
-    except ValueError:
-        from edl_tpu.utils.log import get_logger
-
-        get_logger("ops.attention").warning(
-            "EDL_FLASH_MAX_SEQ=%r is not a positive int; using 4096", raw
-        )
-        return 4096
-
-
-@functools.lru_cache(maxsize=1)
-def _dense_score_bytes_limit() -> int:
-    """Max fp32 score-matrix bytes before the dense forward is rerouted
-    to flash regardless of the dispatch table. Default 2 GiB ≈ 1/8 of a
-    v5e chip's 16 GiB HBM (scores are one of several live buffers and
-    appear again transposed in the backward). ``EDL_ATTN_DENSE_LIMIT``
-    overrides (bytes)."""
-    import os
-
-    return int(os.environ.get("EDL_ATTN_DENSE_LIMIT", 2 << 30))
-
-
-def _lookup(rows, tq: int) -> str | None:
-    for max_seq, impl in rows:
-        if tq <= max_seq:
-            return impl
-    return None
+def _route(tq: int, tk: int, windowed: bool) -> tuple[str, str]:
+    """``(fwd_impl, bwd_impl)`` for a call on the TPU, from what the call
+    can observe. The only code that names an implementation: ``"flash"`` is
+    the whole-KV family, ``"flash2"`` the grid-pipelined one."""
+    if not _whole_kv_serves(tq, tk, windowed):
+        return "flash2", "flash2"
+    return ("flash" if tq <= _WHOLE_KV_FWD_MAX_TQ else "flash2"), "flash"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -1697,8 +1568,8 @@ def _auto(q, k, v, causal, scale, fwd_impl, bwd_impl,
     """``fwd_blocks``/``bwd_blocks`` are optional (bq, bk) overrides for
     the kernel impls (hashable tuples — they ride nondiff_argnums);
     ``None`` means the measured defaults for that impl. ``window`` is
-    taken by ``"ref"`` and ``"flash2"`` (:func:`_select_impls` gives a
-    windowed call no other)."""
+    taken by ``"flash2"`` and by the reference's vjp (:func:`_route` gives
+    a windowed call no other)."""
     return _auto_fwd(
         q, k, v, causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
         window,
@@ -1707,17 +1578,14 @@ def _auto(q, k, v, causal, scale, fwd_impl, bwd_impl,
 
 def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
               fwd_blocks=None, bwd_blocks=None, window=None):
-    if window is not None and "flash" in (fwd_impl, bwd_impl):
-        raise ValueError("the whole-KV flash kernels take no window")
-    if fwd_impl == "ref":
-        out, lse = attention_reference_with_lse(
-            q, k, v, causal=causal, scale=scale, window=window
+    if "flash" in (fwd_impl, bwd_impl) and not _whole_kv_serves(
+        q.shape[2], k.shape[2], window is not None
+    ):
+        raise ValueError(
+            "the whole-KV flash kernels take no window and no sequence past "
+            "%d" % _WHOLE_KV_MAX_SEQ
         )
-        b, h, tq, _ = q.shape
-        # kernel layout, so a flash backward can consume a dense forward's
-        # residuals (both are the logsumexp of the same scaled scores)
-        lse = lse.reshape(b * h, tq)
-    elif fwd_impl == "flash2":
+    if fwd_impl == "flash2":
         f2q, f2k = _flash2_blocks(
             "fwd", q.shape[2], k.shape[2], window, fwd_blocks
         )
@@ -1795,75 +1663,25 @@ def attention(
     scale: float | None = None,
     window: int | None = None,
 ) -> jax.Array:
-    """Attention through the measured dispatch table — the default entry
-    point for every model in the tree (TransformerLM, the LM
-    examples). Forward and backward implementations are chosen
-    independently per sequence length; off-TPU it is exactly the dense
-    reference. ``flash_attention`` / ``attention_reference`` remain for
-    callers that want a specific implementation. ``window`` (with
-    ``causal``): a query sees its ``window`` newest keys, itself
-    included; both routes take it, the dense one as a mask, the kernel
-    one through flash2 alone (:func:`_select_impls`)."""
+    """The default entry point for every model in the tree
+    (TransformerLM, the LM examples). On the TPU :func:`_route` picks the
+    forward and the backward kernels from the call's shapes; off the TPU it
+    is exactly the dense reference. ``flash_attention`` /
+    ``attention_reference`` remain for callers that want a specific
+    implementation. ``window`` (with ``causal``): a query sees its
+    ``window`` newest keys, itself included; the reference takes it as a
+    mask, the kernels through flash2 alone."""
     _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if jax.default_backend() != "tpu":
-        # native autodiff, NOT _auto("ref","ref"): the custom_vjp would
-        # recompute the whole forward in every backward, where plain
+        # native autodiff, not a custom_vjp around the reference: that
+        # would recompute the whole forward in every backward, where plain
         # differentiation reuses the saved activations
         return attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
         )
-    tq, tk = q.shape[2], k.shape[2]
-    table = _dispatch_table()
-    if (
-        tq == tk
-        and window is None            # nor can it take a window
-        and q.shape[1] == k.shape[1]  # builtin can't read grouped k/v
-        and _lookup(table["whole"], tq) == "builtin"
-    ):
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention as _builtin_flash,
-        )
-
-        # tq == tk only: the builtin's causal mask is start-aligned, ours
-        # end-aligned — the conventions agree exactly when lengths match
-        return _builtin_flash(q, k, v, causal=causal, sm_scale=scale)
-    fwd_impl, bwd_impl = _select_impls(
-        table, q.shape[0], q.shape[1], tq, tk, window is not None
-    )
+    fwd_impl, bwd_impl = _route(q.shape[2], k.shape[2], window is not None)
     return _auto(
         q, k, v, causal, scale, fwd_impl, bwd_impl, None, None, window
     )
-
-
-def _select_impls(table, b: int, h: int, tq: int, tk: int,
-                  windowed: bool = False):
-    """Table lookup + memory guard -> ``(fwd_impl, bwd_impl)``.
-
-    The table is calibrated at one [b, h] point, but the dense forward
-    materializes the fp32 [Tq, Tk] score matrix per (batch, head) —
-    O(b*h*T^2) HBM, recomputed under remat — while flash streams it.
-    Beyond a bytes threshold the dense "win" trades a few ms for an
-    OOM; route to flash there."""
-    fwd_impl = _lookup(table["fwd"], tq) or "flash"
-    bwd_impl = _lookup(table["bwd"], tq) or "flash"
-    if b * h * tq * tk * 4 > _dense_score_bytes_limit():
-        # dense bwd re-materializes the same score matrix via jax.vjp of
-        # the reference forward — guard both directions
-        fwd_impl = "flash" if fwd_impl == "ref" else fwd_impl
-        bwd_impl = "flash" if bwd_impl == "ref" else bwd_impl
-    if windowed or max(tq, tk) > _flash_max_seq():
-        # a window: only flash2's grid can leave blocks out (the whole-KV
-        # kernels copy every key into VMEM before they look at one), so
-        # the whole-KV family REFUSES a window and a windowed call is
-        # flash2's at every length. Past the limit,
-        # measured on v5e (jax 0.9): the whole-KV-in-VMEM flash kernel
-        # fails to COMPILE beyond 4096 (every block config crashed the
-        # TPU compiler), while the grid-pipelined flash2 — constant VMEM
-        # footprint by construction — compiles and runs at 8192+. This
-        # is feasibility, not speed: the calibrated table can't express
-        # "flash does not exist here".
-        fwd_impl = "flash2" if fwd_impl == "flash" else fwd_impl
-        bwd_impl = "flash2" if bwd_impl == "flash" else bwd_impl
-    return fwd_impl, bwd_impl

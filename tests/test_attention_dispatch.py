@@ -1,10 +1,7 @@
-"""Fast dispatch-table units for ops.attention.attention (no compile-heavy
-kernel work — the composition numerics live in test_attention.py)."""
+"""Fast routing units for ops.attention.attention (no compile-heavy kernel
+work — the composition numerics live in test_attention.py)."""
 
 import importlib
-import importlib.util
-import json
-import os
 
 import jax
 import numpy as np
@@ -27,156 +24,16 @@ class TestDispatchFast:
         ref = attention_reference(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
-    def test_dispatch_table_env_override(self, tmp_path, monkeypatch):
-        import importlib
-        import json
-
-        A = importlib.import_module("edl_tpu.ops.attention")
-
-        path = tmp_path / "table.json"
-        path.write_text(json.dumps({
-            "fwd": [[512, "ref"], [None, "flash"]],
-            "bwd": [[None, "flash"]],
-            "whole": [[None, "builtin"]],
-        }))
-        monkeypatch.setenv("EDL_ATTN_DISPATCH", str(path))
-        A._dispatch_table.cache_clear()
-        try:
-            table = A._dispatch_table()
-            assert A._lookup(table["fwd"], 512) == "ref"
-            assert A._lookup(table["fwd"], 513) == "flash"
-            assert A._lookup(table["whole"], 10_000) == "builtin"
-            assert A._lookup(table["bwd"], 4096) == "flash"
-        finally:
-            A._dispatch_table.cache_clear()
-
-    def test_rows_from_winners(self):
-        mod = _load_bench()
-        rows = mod._rows_from_winners(
-            [(1024, "ref"), (2048, "ref"), (4096, "flash")]
-        )
-        assert rows == [[2048, "ref"], [None, "flash"]]
-        assert mod._rows_from_winners([]) == []
-
-    def test_unknown_impl_falls_back_to_default(self, tmp_path, monkeypatch):
-        A = importlib.import_module("edl_tpu.ops.attention")
-        # isolate the bottom tier: the real packaged artifact (shipped
-        # since r4) would otherwise be the fallback
-        monkeypatch.setattr(A, "_PACKAGED_DISPATCH", str(tmp_path / "none"))
-        path = tmp_path / "table.json"
-        path.write_text(json.dumps({
-            "fwd": [[None, "flsh"]],  # typo: must not silently reroute
-            "bwd": [[None, "flash"]],
-        }))
-        monkeypatch.setenv("EDL_ATTN_DISPATCH", str(path))
-        A._dispatch_table.cache_clear()
-        try:
-            assert A._dispatch_table() == A._DEFAULT_DISPATCH
-        finally:
-            A._dispatch_table.cache_clear()
-
-    def test_malformed_file_falls_back_to_default(self, tmp_path, monkeypatch):
-        A = importlib.import_module("edl_tpu.ops.attention")
-        monkeypatch.setattr(A, "_PACKAGED_DISPATCH", str(tmp_path / "none"))
-        path = tmp_path / "table.json"
-        path.write_text("{not json")
-        monkeypatch.setenv("EDL_ATTN_DISPATCH", str(path))
-        A._dispatch_table.cache_clear()
-        try:
-            assert A._dispatch_table() == A._DEFAULT_DISPATCH
-        finally:
-            A._dispatch_table.cache_clear()
-        monkeypatch.setenv("EDL_ATTN_DISPATCH", str(tmp_path / "missing"))
-        A._dispatch_table.cache_clear()
-        try:
-            assert A._dispatch_table() == A._DEFAULT_DISPATCH
-        finally:
-            A._dispatch_table.cache_clear()
-
-    def test_packaged_artifact_is_default_when_no_env(
-        self, tmp_path, monkeypatch
-    ):
-        """No EDL_ATTN_DISPATCH -> the calibration artifact shipped next
-        to ops/attention.py is the table; a malformed packaged file
-        degrades to the hard-coded default."""
-        A = importlib.import_module("edl_tpu.ops.attention")
-        monkeypatch.delenv("EDL_ATTN_DISPATCH", raising=False)
-        packaged = tmp_path / "attention_dispatch.json"
-        packaged.write_text(json.dumps({
-            "fwd": [[1024, "ref"], [None, "flash2"]],
-            "bwd": [[4096, "flash"], [None, "ref"]],
-        }))
-        monkeypatch.setattr(A, "_PACKAGED_DISPATCH", str(packaged))
-        A._dispatch_table.cache_clear()
-        try:
-            table = A._dispatch_table()
-            assert A._lookup(table["fwd"], 2048) == "flash2"
-            assert A._lookup(table["bwd"], 8192) == "ref"
-        finally:
-            A._dispatch_table.cache_clear()
-        # env var outranks the packaged artifact; keys the env artifact
-        # omits inherit the PACKAGED rows, not the hard-coded default
-        override = tmp_path / "override.json"
-        override.write_text(json.dumps({"fwd": [[None, "flash"]]}))
-        monkeypatch.setenv("EDL_ATTN_DISPATCH", str(override))
-        A._dispatch_table.cache_clear()
-        try:
-            table = A._dispatch_table()
-            assert A._lookup(table["fwd"], 64) == "flash"
-            assert A._lookup(table["bwd"], 8192) == "ref"
-        finally:
-            A._dispatch_table.cache_clear()
-        # malformed packaged file -> hard-coded default, no crash
-        monkeypatch.delenv("EDL_ATTN_DISPATCH")
-        packaged.write_text("{broken")
-        A._dispatch_table.cache_clear()
-        try:
-            assert A._dispatch_table() == A._DEFAULT_DISPATCH
-        finally:
-            A._dispatch_table.cache_clear()
-
-    def test_memory_guard_reroutes_huge_dense_fwd(self, monkeypatch):
-        A = importlib.import_module("edl_tpu.ops.attention")
-        table = {
-            "fwd": ((A._INF, "ref"),),
-            "bwd": ((A._INF, "ref"),),
-            "whole": (),
-        }
-        # under the limit: table wins
-        assert A._select_impls(table, 4, 16, 2048, 2048) == ("ref", "ref")
-        # 32 * 32 * 8192^2 * 4B = 256 GiB of scores: guard reroutes both
-        # directions; at 8192 the flash-compile guard then lands both on
-        # flash2 (the whole-KV kernel does not compile past 4096)
-        assert A._select_impls(table, 32, 32, 8192, 8192) == (
-            "flash2", "flash2"
-        )
-        monkeypatch.setenv("EDL_ATTN_DENSE_LIMIT", str(1 << 60))
-        A._dense_score_bytes_limit.cache_clear()
-        try:
-            assert A._select_impls(table, 32, 32, 8192, 8192) == ("ref", "ref")
-        finally:
-            A._dense_score_bytes_limit.cache_clear()
-
     def test_flash_compile_guard_remaps_long_seq_to_flash2(self):
         A = importlib.import_module("edl_tpu.ops.attention")
-        table = {
-            "fwd": ((A._INF, "flash"),),
-            "bwd": ((A._INF, "flash"),),
-            "whole": (),
-        }
-        # within the compile limit: flash stays
-        assert A._select_impls(table, 4, 16, 4096, 4096) == ("flash", "flash")
-        # past it: flash does not compile -> flash2 both directions
-        assert A._select_impls(table, 4, 16, 8192, 8192) == (
-            "flash2", "flash2"
-        )
-        # an explicit ref routing is left alone (the memory guard owns
-        # that decision)
-        table_ref = {
-            "fwd": ((A._INF, "ref"),), "bwd": ((A._INF, "ref"),),
-            "whole": (),
-        }
-        assert A._select_impls(table_ref, 1, 1, 8192, 8192) == ("ref", "ref")
+        limit = A._WHOLE_KV_MAX_SEQ
+        # within the compile limit the whole-KV family serves; past it on
+        # either side it does not, and both directions are flash2's
+        assert A._whole_kv_serves(limit, limit)
+        assert A._route(limit, limit, False)[1] == "flash"
+        for tq, tk in ((2 * limit, 2 * limit), (64, limit + 1), (limit + 1, 64)):
+            assert not A._whole_kv_serves(tq, tk)
+            assert A._route(tq, tk, False) == ("flash2", "flash2")
 
     def test_public_flash_entry_points_reroute_past_compile_limit(
         self, monkeypatch
@@ -186,25 +43,17 @@ class TestDispatchFast:
         compiler); with the limit shrunk, both must still match the
         reference through the grid-pipelined route."""
         A = importlib.import_module("edl_tpu.ops.attention")
-        monkeypatch.setenv("EDL_FLASH_MAX_SEQ", "64")
-        A._flash_max_seq.cache_clear()
-        try:
-            q, k, v = _qkv(t=128, d=8)
-            out = A.flash_attention(q, k, v, causal=True)
-            ref = A.attention_reference(q, k, v, causal=True)
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref), atol=2e-4
-            )
-            o2, lse = A.flash_with_lse(q, k, v, causal=True)
-            _, lse_ref = A.attention_reference_with_lse(q, k, v, causal=True)
-            np.testing.assert_allclose(
-                np.asarray(o2), np.asarray(ref), atol=2e-4
-            )
-            np.testing.assert_allclose(
-                np.asarray(lse), np.asarray(lse_ref), atol=2e-4
-            )
-        finally:
-            A._flash_max_seq.cache_clear()
+        monkeypatch.setattr(A, "_WHOLE_KV_MAX_SEQ", 64)
+        q, k, v = _qkv(t=128, d=8)
+        out = A.flash_attention(q, k, v, causal=True)
+        ref = A.attention_reference(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4)
+        o2, lse = A.flash_with_lse(q, k, v, causal=True)
+        _, lse_ref = A.attention_reference_with_lse(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(o2), np.asarray(ref), atol=2e-4)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(lse_ref), atol=2e-4
+        )
 
     def test_kernel_blocks_table(self):
         A = importlib.import_module("edl_tpu.ops.attention")
@@ -214,191 +63,56 @@ class TestDispatchFast:
         assert A._kernel_blocks(65536) == ((128, 512), (512, 512))
 
 
-def _load_tool(filename):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        filename[:-3], os.path.join(root, "tools", filename)
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+# What `_route` gives a call on the TPU: (q heads, kv heads, tq, tk, head_dim,
+# window) -> (forward, backward). The first seven are the attention calls of
+# the benchmark's LM configurations (tests/test_tpu_compile.py compiles the
+# same shapes), with the kernels PERF.md section 4 says those cells run; the
+# rest are the rule's edges.
+ROUTES = [
+    pytest.param(32, 8, 4096, 4096, 128, None, ("flash2", "flash"), id="mistral_7b"),
+    pytest.param(16, 16, 4096, 4096, 128, None, ("flash2", "flash"), id="olmoe_1b_7b"),
+    pytest.param(32, 8, 8192, 8192, 64, None, ("flash2", "flash2"),
+                 id="granite_4_0_h_micro"),
+    pytest.param(32, 4, 8192, 8192, 128, 2048, ("flash2", "flash2"),
+                 id="trinity_mini-window"),
+    pytest.param(32, 4, 8192, 8192, 128, None, ("flash2", "flash2"),
+                 id="trinity_mini-full"),
+    pytest.param(15, 15, 8192, 8192, 128, None, ("flash2", "flash2"),
+                 id="olmo_hybrid_7b"),
+    pytest.param(32, 8, 8192, 8192, 64, None, ("flash2", "flash2"), id="lfm2_24b_a2b"),
+    pytest.param(16, 16, 1024, 1024, 64, None, ("flash", "flash"), id="short"),
+    pytest.param(16, 16, 2048, 2048, 64, None, ("flash", "flash"),
+                 id="fwd-crossover"),  # chip_smoke's lm phase
+    pytest.param(16, 16, 2049, 2049, 64, None, ("flash2", "flash"),
+                 id="fwd-crossover+1"),
+    pytest.param(16, 16, 4096, 4096, 64, None, ("flash2", "flash"), id="compile-limit"),
+    pytest.param(16, 16, 4097, 4097, 64, None, ("flash2", "flash2"),
+                 id="compile-limit+1"),
+    pytest.param(16, 16, 1024, 8192, 64, None, ("flash2", "flash2"),
+                 id="short-q-long-kv"),
+    pytest.param(16, 16, 8192, 1024, 64, None, ("flash2", "flash2"),
+                 id="long-q-short-kv"),
+    pytest.param(16, 16, 1024, 4096, 64, None, ("flash", "flash"),
+                 id="short-q-kv-at-limit"),
+    pytest.param(16, 16, 4096, 1024, 64, None, ("flash2", "flash"),
+                 id="q-past-crossover-short-kv"),
+    pytest.param(16, 4, 512, 512, 64, 512, ("flash2", "flash2"), id="window-512"),
+    pytest.param(16, 4, 2048, 2048, 64, 2048, ("flash2", "flash2"),
+                 id="window-at-crossover"),
+]
 
 
-def _load_bench():
-    return _load_tool("attention_bench.py")
-
-
-class TestCalibrationPicksMinima:
-    """The table builder must pick per-row minima from a recorded
-    measurement file — so a calibration artifact can never ship a row the
-    measurements contradict (the r2 artifact implied dense bwd beat flash
-    bwd at 4096 while the then-default said flash everywhere)."""
-
-    def _results(self):
-        # seconds, shaped like the round-2 on-chip artifact
-        # (bench_results/attention_tpu_r2.jsonl, v5e [4,16,T,64] bf16):
-        # dense fwd wins <=2048, flash fwd wins at 4096; and at 4096 the
-        # dense-bwd composition beats the flash-bwd one (the inversion).
-        r = {}
-        fwd = {
-            1024: {"reference": 0.97e-3, "flash": 1.64e-3, "builtin": 1.2e-3,
-                   "comp_flash2_flash": 1.7e-3},
-            4096: {"reference": 30.87e-3, "flash": 25.01e-3, "builtin": 26e-3,
-                   "comp_flash2_flash": 25.5e-3},
-        }
-        fwd_bwd = {
-            1024: {"reference": 2.8e-3, "flash": 2.7e-3, "builtin": 3.0e-3,
-                   "comp_ref_flash": 2.1e-3, "comp_flash_ref": 3.4e-3,
-                   "comp_flash2_flash": 2.9e-3, "comp_flash2_ref": 3.5e-3,
-                   "comp_flash2_flash2": 3.0e-3, "comp_ref_flash2": 2.3e-3,
-                   "comp_flash_flash2": 2.8e-3},
-            4096: {"reference": 57.97e-3, "flash": 60.15e-3, "builtin": 59e-3,
-                   # flash fwd (winner) + ref bwd: 25.01 + 27.1 = 52.1
-                   "comp_flash_ref": 52.1e-3,
-                   "comp_ref_flash": 66.0e-3,
-                   "comp_flash2_flash": 61.0e-3, "comp_flash2_ref": 53.0e-3,
-                   "comp_flash2_flash2": 62.0e-3, "comp_ref_flash2": 67.0e-3,
-                   "comp_flash_flash2": 61.0e-3},
-        }
-        for seq, times in fwd.items():
-            for name, t in times.items():
-                r[(name, "fwd", seq)] = t
-        for seq, times in fwd_bwd.items():
-            for name, t in times.items():
-                r[(name, "fwd_bwd", seq)] = t
-        return r
-
-    def test_minima_and_inversion(self):
-        mod = _load_bench()
-        A = importlib.import_module("edl_tpu.ops.attention")
-        table = mod.build_dispatch_table(self._results(), [1024, 4096], True)
-        # fwd: dense wins at 1024, flash at 4096
-        assert table["fwd"] == [[1024, "ref"], [None, "flash"]]
-        # bwd: flash wins at 1024 (comp_ref_flash fastest with ref fwd);
-        # ref wins at 4096 (comp_flash_ref < flash and < builtin) — the
-        # inversion the r2 numbers implied MUST survive into the table
-        assert table["bwd"] == [[1024, "flash"], [None, "ref"]]
-        # builtin never beats the best composition in this recording
-        assert table["whole"] == [[None, "comp"]]
-        # every impl name in the artifact is loadable (validation gate)
-        for key in ("fwd", "bwd", "whole"):
-            for _, impl in table[key]:
-                assert impl in A._VALID_IMPLS[key]
-
-    def test_joint_pair_beats_greedy_fwd_first(self):
-        """The r4 recalibration regression: flash2 won fwd-only at 1024
-        by 0.05 ms but every flash2 composition lost by ~0.2 ms — the
-        winner must be the jointly-fastest (fwd, bwd) PAIR, not the best
-        bwd for the fwd-only winner."""
-        mod = _load_bench()
-        r = self._results()
-        # make flash2 the fwd-only winner at 1024...
-        r[("comp_flash2_flash", "fwd", 1024)] = 0.90e-3
-        # ...but keep every flash2 composition slower than (ref, flash)
-        # (comp_ref_flash is 2.1e-3 in the base recording)
-        table = mod.build_dispatch_table(r, [1024], False)
-        assert table["fwd"] == [[None, "ref"]]
-        assert table["bwd"] == [[None, "flash"]]
-
-    def test_builtin_row_when_it_wins(self):
-        mod = _load_bench()
-        r = self._results()
-        # make builtin strictly fastest at 4096, both modes
-        r[("builtin", "fwd", 4096)] = 20e-3
-        r[("builtin", "fwd_bwd", 4096)] = 45e-3
-        table = mod.build_dispatch_table(r, [1024, 4096], True)
-        assert table["whole"] == [[1024, "comp"], [None, "builtin"]]
-        # and the calibrated artifact round-trips through the loader
-        A = importlib.import_module("edl_tpu.ops.attention")
-        for key in ("fwd", "bwd", "whole"):
-            for _, impl in table[key]:
-                assert impl in A._VALID_IMPLS[key]
-
-
-def _load_installer():
-    return _load_tool("install_dispatch.py")
-
-
-class TestInstallDispatch:
-    """tools/install_dispatch.py promotes a calibration artifact to the
-    packaged default — refusing artifacts its own measurement file
-    contradicts, so an inverted row can never become the shipped table."""
-
-    def _write_jsonl(self, path, results):
-        rows = []
-        for (name, mode, seq), secs in results.items():
-            rows.append(json.dumps({
-                "metric": "attention_%s_%s" % (name, mode),
-                "seq": seq, "ms": secs * 1e3,
-            }))
-        # summary rows the parser must skip
-        rows.append(json.dumps({
-            "metric": "attention_dispatch_speedup", "seq": 1024, "fwd": 1.0,
-        }))
-        path.write_text("\n".join(rows) + "\n")
-
-    def test_roundtrip_and_contradiction_gate(self, tmp_path, monkeypatch):
-        inst = _load_installer()
-        bench = _load_bench()
-        A = importlib.import_module("edl_tpu.ops.attention")
-        results = TestCalibrationPicksMinima()._results()
-        measured = tmp_path / "measured.jsonl"
-        self._write_jsonl(measured, results)
-        # jsonl -> results dict round-trips (float via ms conversion)
-        got, seqs, has_builtin = inst.results_from_jsonl(str(measured))
-        assert seqs == [1024, 4096] and has_builtin
-        assert got.keys() == results.keys()
-        table = bench.build_dispatch_table(results, seqs, has_builtin)
-        artifact = tmp_path / "dispatch.json"
-        artifact.write_text(json.dumps(table))
-        packaged = tmp_path / "attention_dispatch.json"
-        monkeypatch.setattr(A, "_PACKAGED_DISPATCH", str(packaged))
-        # consistent artifact installs
-        monkeypatch.setattr(
-            "sys.argv",
-            ["x", str(artifact), "--check-against", str(measured)],
-        )
-        assert inst.main() == 0
-        assert json.loads(packaged.read_text()) == table
-        # an inverted bwd row is refused (flash@4096 composes 60.15 ms vs
-        # the measured-best 52.1 ms — far beyond the rounding tolerance)
-        bad = dict(table)
-        bad["bwd"] = [[None, "flash"]]
-        artifact.write_text(json.dumps(bad))
-        packaged.unlink()
-        assert inst.main() == 1
-        assert not packaged.exists()
-        # a near-tie within TOLERANCE is NOT a contradiction: rows carry
-        # ms rounded to 3 decimals, so exact-winner equality would refuse
-        # artifacts the same run produced
-        tied = dict(results)
-        tied[("comp_flash_ref", "fwd_bwd", 4096)] = 52.1e-3
-        tied[("comp_flash2_ref", "fwd_bwd", 4096)] = 52.1004e-3
-        measured2 = tmp_path / "measured_tie.jsonl"
-        self._write_jsonl(measured2, tied)
-        art2 = tmp_path / "dispatch2.json"
-        t2 = dict(table)
-        t2["bwd"] = [[1024, "flash"], [None, "ref"]]
-        art2.write_text(json.dumps(t2))
-        monkeypatch.setattr(
-            "sys.argv",
-            ["x", str(art2), "--check-against", str(measured2), "--dry-run"],
-        )
-        assert inst.main() == 0
-
-    def test_unusable_measurement_file_is_diagnosed(
-        self, tmp_path, monkeypatch, capsys
-    ):
-        inst = _load_installer()
-        bench = _load_bench()
-        results = TestCalibrationPicksMinima()._results()
-        table = bench.build_dispatch_table(results, [1024, 4096], True)
-        artifact = tmp_path / "dispatch.json"
-        artifact.write_text(json.dumps(table))
-        empty = tmp_path / "empty.jsonl"
-        empty.write_text("")
-        monkeypatch.setattr(
-            "sys.argv", ["x", str(artifact), "--check-against", str(empty)],
-        )
-        assert inst.main() == 1
-        assert "no calibration rows" in capsys.readouterr().err
+@pytest.mark.parametrize("h,h_kv,tq,tk,d,window,want", ROUTES)
+def test_route(monkeypatch, h, h_kv, tq, tk, d, window, want):
+    """The routing function's answer, and that `attention()` on the TPU
+    hands exactly that answer to `_auto` whatever the heads are."""
+    A = importlib.import_module("edl_tpu.ops.attention")
+    assert A._route(tq, tk, window is not None) == want
+    assert ("flash" in want) <= A._whole_kv_serves(tq, tk, window is not None)
+    seen = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(A, "_auto", lambda *a: seen.append(a[5:]))
+    q = jax.ShapeDtypeStruct((1, h, tq, d), np.float32)
+    k = jax.ShapeDtypeStruct((1, h_kv, tk, d), np.float32)
+    A.attention(q, k, k, causal=True, window=window)
+    assert seen == [want + (None, None, window)]

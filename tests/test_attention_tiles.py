@@ -240,7 +240,7 @@ def test_block_grads_with_external_residuals_past_the_whole_kv_limit(
     """Ring attention's building block: ``lse`` and ``delta`` of the global
     softmax come from outside, and past the whole-KV compile limit the call
     is the flash2 backward's, fused or not by the same rule."""
-    monkeypatch.setattr(A, "_flash_max_seq", lambda: 64)
+    monkeypatch.setattr(A, "_WHOLE_KV_MAX_SEQ", 64)
     q, k, v, g = _inputs(h, h_kv, tq, tk, 64, seed=9)
     scale = 64 ** -0.5
     o, lse = A.attention_reference_with_lse(q, k, v, causal=True, scale=scale)

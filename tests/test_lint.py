@@ -1429,6 +1429,30 @@ class TestRepoConformance:
         assert "lock_flow" in ctx.cache
         assert "protocol_facts" in ctx.cache
 
+    def test_kernels_and_models_read_no_environment(self):
+        """What runs on the chip follows from the call's shapes: a switch
+        under ops/ or models/ is a PR the benchmark measures in every
+        cell, never a variable read at trace time."""
+        import ast
+
+        from edl_tpu.analysis import repo_context
+
+        chip_path = ("edl_tpu/ops/", "edl_tpu/models/")
+        mods = [
+            m for m in repo_context().modules
+            if m.relpath.startswith(chip_path) and m.tree is not None
+        ]
+        assert len(mods) > 10
+        # any name, not only the literal EDL_* ones the knob catalogue sees
+        reads = [
+            "%s:%d" % (m.relpath, node.lineno)
+            for m in mods
+            for node in ast.walk(m.tree)
+            if isinstance(node, ast.Attribute)
+            and node.attr in ("environ", "getenv")
+        ]
+        assert not reads, reads
+
 
 # -- baseline semantics -------------------------------------------------------
 

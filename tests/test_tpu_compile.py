@@ -62,8 +62,8 @@ def no_persistent_cache():
 LM = (16, 16, 16, 2048, 64)        # chip_smoke's lm phase: b16 x seq 2048
 HD128 = (2, 8, 8, 4096, 128)
 GQA = (2, 16, 4, 2048, 64)
-AT_MAX = (1, 16, 16, A._flash_max_seq(), 64)
-PAST_MAX = (1, 16, 16, A._flash_max_seq() + 512, 64)
+AT_MAX = (1, 16, 16, A._WHOLE_KV_MAX_SEQ, 64)
+PAST_MAX = (1, 16, 16, A._WHOLE_KV_MAX_SEQ + 512, 64)
 LONG = (1, 16, 16, 8192, 64)       # chip_smoke's flash2 comparison shape
 GRANITE = (1, 32, 8, 8192, 64)     # granite_4_0_h_micro.steady's attention layer
 TRINITY = (1, 32, 4, 8192, 128)    # trinity_mini.steady's attention layers

@@ -413,9 +413,8 @@ def test_the_window_in_every_route_against_a_dense_mask(
 
 
 def test_a_windowed_call_never_reaches_the_whole_kv_kernels():
-    table = A._DEFAULT_DISPATCH
     for t in (512, 2048, 4096, 8192):
-        assert "flash" not in A._select_impls(table, 1, 32, t, t, windowed=True)
+        assert "flash" not in A._route(t, t, windowed=True)
     q = jnp.zeros((1, 2, 32, 8))
     with pytest.raises(ValueError, match="take no window"):
         A._auto(q, q, q, True, 1.0, "flash", "flash2", None, None, 8)

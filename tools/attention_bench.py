@@ -62,89 +62,6 @@ def bench_one(fn, args, iters):
     return max(t2 - t1, 1e-9) / iters
 
 
-def build_dispatch_table(results, seqs, has_builtin, meta=None):
-    """Pure winner-selection: recorded timings -> dispatch table.
-
-    ``results`` maps ``(impl_name, mode, seq)`` -> seconds, with the
-    impl names bench ``main()`` produces ("reference", "flash",
-    "comp_<fwd>_<bwd>", optionally "builtin"). Factored out of main()
-    so a CPU test can feed it a recorded measurement file and assert
-    every row is the per-seq minimum — calibration output can never
-    ship an inverted row again (the r2 artifact implied dense bwd beat
-    flash bwd at 4096 while the shipped default said otherwise).
-    """
-    fwd_w, bwd_w, whole_w = [], [], []
-    for seq in seqs:
-        fwd_times = {
-            "ref": results[("reference", "fwd", seq)],
-            "flash": results[("flash", "fwd", seq)],
-            "flash2": results[("comp_flash2_flash", "fwd", seq)],
-        }
-        comp_times = {
-            ("ref", "ref"): results[("reference", "fwd_bwd", seq)],
-            ("flash", "flash"): results[("flash", "fwd_bwd", seq)],
-            ("ref", "flash"): results[("comp_ref_flash", "fwd_bwd", seq)],
-            ("flash", "ref"): results[("comp_flash_ref", "fwd_bwd", seq)],
-            ("flash2", "flash"):
-                results[("comp_flash2_flash", "fwd_bwd", seq)],
-            ("flash2", "ref"):
-                results[("comp_flash2_ref", "fwd_bwd", seq)],
-            ("flash2", "flash2"):
-                results[("comp_flash2_flash2", "fwd_bwd", seq)],
-            ("ref", "flash2"):
-                results[("comp_ref_flash2", "fwd_bwd", seq)],
-            ("flash", "flash2"):
-                results[("comp_flash_flash2", "fwd_bwd", seq)],
-        }
-        # JOINT (fwd, bwd) winner on full fwd+bwd time, fwd-only as the
-        # tiebreak: the table's single fwd row serves training AND
-        # inference, and picking the fwd-only winner first then the best
-        # bwd for it (the old greedy policy) shipped a measured ~21%
-        # TRAINING slowdown at seq 1024 in the r4 recalibration (flash2
-        # won fwd-only by 0.05 ms but its best composition lost by
-        # 0.2 ms). Training is where the time goes; inference-heavy
-        # callers have the KV-cache decode path and EDL_ATTN_DISPATCH.
-        fwd_best, bwd_best = min(
-            comp_times,
-            key=lambda fb: (comp_times[fb], fwd_times[fb[0]]),
-        )
-        fwd_w.append((seq, fwd_best))
-        bwd_w.append((seq, bwd_best))
-        if has_builtin:
-            # EVERY seq gets a whole-row verdict ("comp" = fall through
-            # to the fwd/bwd composition): a sparse winners-only list
-            # would let _rows_from_winners' unbounded last row route
-            # unmeasured/losing lengths to the builtin kernel
-            best_comp = comp_times[(fwd_best, bwd_best)]
-            builtin_wins = (
-                results[("builtin", "fwd", seq)] < fwd_times[fwd_best]
-                and results[("builtin", "fwd_bwd", seq)] < best_comp
-            )
-            whole_w.append((seq, "builtin" if builtin_wins else "comp"))
-    table = {
-        "fwd": _rows_from_winners(fwd_w),
-        "bwd": _rows_from_winners(bwd_w),
-        "whole": _rows_from_winners(whole_w),
-    }
-    if meta:
-        table["_measured"] = meta
-    return table
-
-
-def _rows_from_winners(winners):
-    """[(seq, impl)...] -> threshold rows [[seq, impl], ..., [None, last]]
-    (first match wins; last row unbounded)."""
-    rows = []
-    for seq, impl in sorted(winners):
-        if rows and rows[-1][1] == impl:
-            rows[-1][0] = seq
-        else:
-            rows.append([seq, impl])
-    if rows:
-        rows[-1][0] = None
-    return rows
-
-
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--batch", type=int, default=4)
@@ -152,18 +69,13 @@ def main():
     p.add_argument("--head_dim", type=int, default=64)
     p.add_argument("--seqs", type=int, nargs="+", default=None)
     p.add_argument("--iters", type=int, default=20)
-    p.add_argument(
-        "--calibrate", default=None, metavar="OUT.json",
-        help="also time fwd/bwd compositions and jax's builtin TPU kernel, "
-        "then write a dispatch table (load via EDL_ATTN_DISPATCH)",
-    )
     args = p.parse_args()
 
     import jax
     import jax.numpy as jnp
 
     from edl_tpu.ops.attention import (
-        _auto, attention, attention_reference, flash_attention,
+        attention, attention_reference, flash_attention,
     )
 
     dev = jax.devices()[0]
@@ -172,42 +84,12 @@ def main():
     dtype = jnp.bfloat16 if on_tpu else jnp.float32
     b, h, d = args.batch, args.heads, args.head_dim
 
-    def comp(fwd_impl, bwd_impl):
-        def f(q, k, v, causal=True):
-            return _auto(
-                q, k, v, causal, q.shape[-1] ** -0.5, fwd_impl, bwd_impl
-            )
-        return f
-
     impls = {
         "flash": flash_attention,
         "reference": attention_reference,
-        # the dispatching default every model routes through: its row must
-        # come out >= 1.0x reference at every seq, fwd and fwd_bwd
+        # the default entry point every model routes through
         "auto": attention,
     }
-    if on_tpu:
-        try:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention as _builtin,
-            )
-
-            impls["builtin"] = lambda q, k, v, causal=True: _builtin(
-                q, k, v, causal=causal, sm_scale=q.shape[-1] ** -0.5
-            )
-        except ImportError:
-            pass
-    if args.calibrate:
-        impls["comp_ref_flash"] = comp("ref", "flash")
-        impls["comp_flash_ref"] = comp("flash", "ref")
-        # grid-pipelined fwd AND bwd candidates (all share the residual
-        # contract, so any forward pairs with any backward)
-        impls["comp_flash2_flash"] = comp("flash2", "flash")
-        impls["comp_flash2_ref"] = comp("flash2", "ref")
-        impls["comp_flash2_flash2"] = comp("flash2", "flash2")
-        impls["comp_ref_flash2"] = comp("ref", "flash2")
-        impls["comp_flash_flash2"] = comp("flash", "flash2")
-
     results = {}
     for seq in seqs:
         rng = jax.random.PRNGKey(0)
@@ -230,14 +112,7 @@ def main():
                 g = jax.grad(loss, argnums=(0, 1, 2))(*args)
                 return g[0] + g[1] + g[2]
 
-            modes = (("fwd", fwd, 1.0), ("fwd_bwd", fwd_bwd, 3.5))
-            if name.startswith("comp_") and name != "comp_flash2_flash":
-                # a composition's forward IS its fwd_impl alone; only the
-                # fwd_bwd number is new information — skip the redundant
-                # on-chip timing. Exception: comp_flash2_flash carries the
-                # only fwd measurement of the flash2 kernel.
-                modes = (("fwd_bwd", fwd_bwd, 3.5),)
-            for mode, f, mult in modes:
+            for mode, f, mult in (("fwd", fwd, 1.0), ("fwd_bwd", fwd_bwd, 3.5)):
                 dt = bench_one(f, (q, k, v), args.iters)
                 rec = {
                     "metric": "attention_%s_%s" % (name, mode),
@@ -252,7 +127,7 @@ def main():
                 print(json.dumps(rec))
 
     for seq in seqs:
-        # the acceptance row: dispatch vs XLA dense, both modes
+        # the routed entry point against XLA's dense path, both modes
         print(json.dumps({
             "metric": "attention_dispatch_speedup",
             "seq": seq,
@@ -266,21 +141,6 @@ def main():
             ),
             "platform": "tpu" if on_tpu else "cpu",
         }))
-
-    if args.calibrate:
-        table = build_dispatch_table(
-            results, seqs, "builtin" in impls,
-            meta={
-                "device": dev.device_kind,
-                "shape": [b, h, d],
-                "seqs": seqs,
-            },
-        )
-        with open(args.calibrate, "w") as f:
-            json.dump(table, f, indent=1)
-        print(json.dumps({"metric": "attention_dispatch_table",
-                          "path": args.calibrate, **{
-                              k: table[k] for k in ("fwd", "bwd", "whole")}}))
 
 
 if __name__ == "__main__":

@@ -10,8 +10,8 @@ on new hardware or a new jax release and update the constants in
 Prints one JSON row per (kernel, seq, bq, bk) with fwd and fwd+bwd ms;
 configs that crash the compiler are recorded as rows with "error" (that
 is itself signal — bk=1024 kills the whole-KV kernel at seq >= 4096,
-and every whole-KV config dies at 8192, which is why the dispatch
-remaps flash -> flash2 past ``EDL_FLASH_MAX_SEQ``).
+and every whole-KV config died at 8192, which is why
+``ops/attention.py:_route`` gives flash2 past ``_WHOLE_KV_MAX_SEQ``).
 
 Usage::
 
