@@ -12,7 +12,7 @@ from edl_tpu.models.resnet import (
     ResNeXt101_32x16d,
 )
 from edl_tpu.models.decode import greedy_generate, init_cache
-from edl_tpu.models.transformer import ArchSpec, TransformerLM
+from edl_tpu.models.transformer import ArchSpec, SparseAttentionSpec, TransformerLM
 
 __all__ = [
     "MLP",
@@ -39,4 +39,5 @@ __all__ = [
     "GatedDeltaSpec",
     "ShortConvMixer",
     "ShortConvSpec",
+    "SparseAttentionSpec",
 ]

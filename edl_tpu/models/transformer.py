@@ -11,8 +11,9 @@ TPU-first:
 - a block's sequence mixer is, by ``ArchSpec.layer_types``, full causal
   attention, attention over a sliding window, a Mamba-2 state-space
   layer (``models/mamba.py``), a gated-delta-rule linear-attention layer
-  (``models/gated_delta.py``) or a gated short convolution
-  (``models/short_conv.py``); its feed-forward a SwiGLU or an expert
+  (``models/gated_delta.py``), a gated short convolution
+  (``models/short_conv.py``) or attention over the keys a learned indexer
+  selects (``ops/sparse_attention.py``); its feed-forward a SwiGLU or an expert
   layer (``models/moe.py``), the leading ``ArchSpec.dense_layers`` blocks
   of an expert model dense: one ``TransformerLM`` runs dense, expert,
   hybrid (any of the three cheap mixers beside attention) and mixed-window
@@ -47,6 +48,8 @@ from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.attention import attention
 from edl_tpu.ops.gated_delta import REMAT_NAMES as GDN_NAMES
+from edl_tpu.ops.sparse_attention import REMAT_NAMES as DSA_NAMES
+from edl_tpu.ops.sparse_attention import sparse_attention
 
 AttentionFn = Callable[..., jax.Array]  # (q, k, v, causal=...) -> out
 
@@ -65,6 +68,24 @@ NEG_INF_DECODE = -1e30  # mask value for cache positions past the index
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttentionSpec:
+    """The indexer of a ``"sparse_attention"`` layer (DeepSeek sparse
+    attention, arXiv:2512.02556 section 2): ``index_heads`` query heads of
+    ``index_dim`` against one shared key head score every causal pair, each
+    query attends to its ``topk`` best keys, and the indexer's KL towards the
+    main attention's head-mean probabilities joins the objective at
+    ``loss_weight`` (``ops/sparse_attention.py`` has the equations)."""
+
+    index_heads: int = 16
+    index_dim: int = 64
+    topk: int = 2048
+    loss_weight: float = 1.0
+
+
+DSA_SCOPES = ("dsa_index", "dsa_select", "attn_sparse", "dsa_target")
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchSpec:
     """What a ``TransformerLM`` does differently from the dense default,
     as one hashable field; every default is the dense model's.
@@ -74,8 +95,11 @@ class ArchSpec:
     the ``sliding_window`` newest keys, the query's own among them),
     ``"mamba"`` (then ``mamba`` gives the layer's shape),
     ``"linear_attention"`` (the gated delta rule; then ``gated_delta`` gives
-    the layer's shape) or ``"conv"`` (the gated short convolution; then
-    ``short_conv`` gives its taps); its length is the model's depth. ``rope``
+    the layer's shape), ``"conv"`` (the gated short convolution; then
+    ``short_conv`` gives its taps) or ``"sparse_attention"`` (causal attention
+    over the keys a learned indexer selects for each query; then
+    ``sparse_attention`` gives the indexer's shape); its length is the
+    model's depth. ``rope``
     rotates q and k in every attention layer (``True``), in none (``False``:
     no position term at all) or in the windowed layers only (``"sliding"``:
     the full layers then see order through the causal mask alone); the
@@ -99,6 +123,7 @@ class ArchSpec:
     mamba: Optional[MambaSpec] = None
     gated_delta: Optional[GatedDeltaSpec] = None
     short_conv: Optional[ShortConvSpec] = None
+    sparse_attention: Optional[SparseAttentionSpec] = None
     head_dim: Optional[int] = None      # None: d_model / num_heads
     rope: Union[bool, str] = True       # True, False or "sliding"
     rope_theta: float = 10000.0         # the rotation's base
@@ -114,7 +139,7 @@ class ArchSpec:
 
 
 LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
-               "conv")
+               "conv", "sparse_attention")
 
 
 def _scope(name: Optional[str]):
@@ -244,6 +269,25 @@ class Attention(nn.Module):
     ``attn_gate``. Every projection's weight gradient is written by a matmul
     of its own, behind a fence (``_heads_dot_general``); the parameters are
     plain ``nn.DenseGeneral``'s.
+
+    ``sparse`` makes the layer attend over a learned selection: an indexer on
+    ``stop_gradient(x)`` (``index_q``: ``index_heads`` heads of ``index_dim``;
+    ``index_k``: one head through a LayerNorm with scale and bias; both
+    rotated like q and k; ``index_w``: a float32 weight a head a token, times
+    ``index_heads ** -0.5 * index_dim ** -0.5``) scores every causal pair,
+    ``ops.sparse_attention`` keeps each query's ``topk`` best keys and
+    attends over them, and the indexer's KL towards the heads' mean
+    probabilities is sown into ``"losses"`` (``dsa_index_kl``, at
+    ``loss_weight``): it is the only gradient the indexer's three matrices
+    and the LayerNorm receive, and it reaches nothing else. Sown into
+    ``"metrics"``: ``dsa_index_kl`` (unweighted), ``dsa_selected_share``
+    (selected over causal pairs) and ``dsa_tile_live`` (of the forward
+    kernel's tiles under the causal line, the share that holds a selected
+    pair); into ``"intermediates"``, for a check that asks for the
+    collection: ``selection`` ``[B, T, T]`` (int8), ``index_scores`` ``[B, T,
+    T]`` and ``index_operands`` (the indexer's q ``[B, T, J, Di]``, k ``[B, T,
+    Di]`` and weights ``[B, T, J]``). Device scopes ``dsa_index`` / ``dsa_select`` / ``attn_sparse`` /
+    ``dsa_target``. The decode cache has no selection and refuses it.
     """
 
     num_heads: int
@@ -261,6 +305,7 @@ class Attention(nn.Module):
     window: Optional[int] = None
     gate: bool = False
     kernel_scope: Optional[str] = None
+    sparse: Optional[SparseAttentionSpec] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -299,9 +344,13 @@ class Attention(nn.Module):
             q = rope(q, positions, self.rope_theta)
             k = rope(k, positions, self.rope_theta)
         if self.decode:
-            if self.window is not None:
-                raise NotImplementedError("the decode cache takes no window")
+            if self.window is not None or self.sparse is not None:
+                raise NotImplementedError(
+                    "the decode cache takes no window and no selection"
+                )
             out = self._decode_step(q, k, v, kv_heads, head_dim)
+        elif self.sparse is not None:
+            out = self._selected(x, positions, q, k, v)
         else:
             # [B, T, H, D] -> [B, H, T, D]
             q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
@@ -328,6 +377,45 @@ class Attention(nn.Module):
                 g = dense("g", features=(self.num_heads, head_dim))(x)
                 out = out * nn.sigmoid(g)
         return dense("o", features=x.shape[-1], axis=(-2, -1))(out)
+
+    def _selected(self, x, positions, q, k, v):
+        """Attention over the keys the layer's indexer selects (``sparse``);
+        q, k, v ``[B, T, heads, head_dim]`` as projected, normed and rotated;
+        returns ``[B, T, H, head_dim]``."""
+        spec = self.sparse
+        if self.window is not None or self.attention_fn is not None:
+            raise ValueError("a selection takes no window and no attention_fn")
+        with jax.named_scope("dsa_index"):
+            seen = jax.lax.stop_gradient(x)
+            fp32_out = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
+            index_q = nn.DenseGeneral(
+                (spec.index_heads, spec.index_dim), use_bias=False,
+                dtype=self.dtype, name="index_q",
+            )(seen)
+            index_k = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype, name="index_k_norm")(
+                nn.Dense(spec.index_dim, use_bias=False, dtype=self.dtype, name="index_k")(seen)
+            )[:, :, None, :]
+            if self.rope:
+                index_q = rope(index_q, positions, self.rope_theta)
+                index_k = rope(index_k, positions, self.rope_theta)
+            index_w = nn.Dense(
+                spec.index_heads, use_bias=False, dtype=self.dtype,
+                dot_general=fp32_out, name="index_w",
+            )(seen).astype(jnp.float32) * (spec.index_heads ** -0.5 * spec.index_dim ** -0.5)
+        out, kl, stats, detail = sparse_attention(
+            *(jnp.swapaxes(t, 1, 2) for t in (q, k, v, index_q)), index_k[:, :, 0],
+            index_w, spec.topk, self.scale,
+        )
+        if spec.loss_weight:
+            self.sow("losses", "dsa_index_kl", spec.loss_weight * kl)
+        self.sow("metrics", "dsa_index_kl", kl)
+        self.sow("metrics", "dsa_selected_share", stats["selected_share"])
+        self.sow("metrics", "dsa_tile_live", stats["tile_live"])
+        # only when a caller asks for the collection: a check against a reference
+        self.sow("intermediates", "selection", detail["selection"])
+        self.sow("intermediates", "index_scores", detail["scores"])
+        self.sow("intermediates", "index_operands", (index_q, index_k[:, :, 0], index_w))
+        return jnp.swapaxes(out, 1, 2)
 
     def _decode_step(self, q, k, v, kv_heads: int, head_dim: int):
         """Cached autoregressive attention for T >= 1 new tokens: insert
@@ -439,10 +527,15 @@ class Block(nn.Module):
             mixed = ShortConvMixer(
                 arch.short_conv or ShortConvSpec(), self.dtype, name="sconv"
             )(h)
-        elif self.mixer in ("attention", "sliding_attention"):
+        elif self.mixer in ("attention", "sliding_attention", "sparse_attention"):
             sliding = self.mixer == "sliding_attention"
+            selecting = self.mixer == "sparse_attention"
             if sliding and arch.sliding_window is None:
                 raise ValueError("a sliding_attention layer needs sliding_window")
+            if selecting and self.decode:
+                raise NotImplementedError(
+                    "a sparse-attention block has no decode cache of indexer keys"
+                )
             mixed = Attention(
                 self.num_heads, self.dtype, self.attention_fn,
                 num_kv_heads=self.num_kv_heads, decode=self.decode,
@@ -456,6 +549,8 @@ class Block(nn.Module):
                 # a model of both kinds tells their device time apart
                 kernel_scope=None if arch.sliding_window is None
                 else ("attn_window" if sliding else "attn_full"),
+                sparse=(arch.sparse_attention or SparseAttentionSpec())
+                if selecting else None,
                 name="attn",
             )(h, positions)
         else:
@@ -496,17 +591,21 @@ def _remat_policy(name: Optional[str]):
     ``V_new``; ``gdn_out``: the rule's output and final state) and every
     chunk's inverse (``gdn_inverse``), so that the block's recomputation
     runs neither the carry's loop nor the solve again
-    (ops/gated_delta.py); a model without such a layer bears none of
-    the names. ``None``/"full" is classic recompute-everything."""
+    (ops/gated_delta.py), and the two thresholds a row that a
+    sparse-attention layer's selection found (``dsa_select``: 128 KB a
+    layer; the recomputation then makes the scores and the mask again and
+    not the bisection; ops/sparse_attention.py); a model without such a
+    layer bears none of the names. ``None``/"full" is classic
+    recompute-everything."""
     if name in (None, "full"):
         return None
     if name == "save_flash":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", *GDN_NAMES
+            "flash_out", "flash_lse", *GDN_NAMES, *DSA_NAMES
         )
     if name == "save_flash_qkv":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES
+            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES, *DSA_NAMES
         )
     raise ValueError("unknown remat_policy %r" % (name,))
 

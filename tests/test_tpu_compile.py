@@ -561,3 +561,116 @@ def test_grid_pipeline_kwargs_carry_dimension_semantics():
     assert tuple(str(s) for s in params.dimension_semantics) == (
         "parallel", "parallel", "arbitrary",
     )
+
+
+# -- attention over a learned selection (ops/sparse_attention.py) -------------
+
+SPARSE = (32, 4, 16384, 128, 16, 64, 2048)  # keye_vl_2_0_30b_a3b.steady: H, Hkv, T, D, J, Di, topk
+SPARSE_KERNELS = {
+    "index_fwd": "_index_fwd_kernel", "select": "_select_kernel",
+    "sparse_fwd": "_sparse_fwd_kernel", "sparse_bwd": "_sparse_bwd_kernel",
+    "target": "_target_kernel", "target_grad": "_target_kernel",
+    "index_bwd": "_index_bwd_kernel",
+}
+
+
+@pytest.mark.parametrize("kernel", list(SPARSE_KERNELS))
+def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, kernel):
+    """Each kernel of the selection's path at the step's own shape (one
+    sequence of 16,384, GQA 32:4 x 128, an indexer of 16 heads x 64, the blocks
+    ``_kernel_plan`` gives): the int8 mask's tiles, the select kernel's row
+    block and key scratch (8 MB each under a limit set from the shapes), the
+    fused backward's 8 MB dq accumulator, the target's heads on the innermost
+    grid dimension, the indexer backward's whole-sequence key gradient."""
+    S = importlib.import_module("edl_tpu.ops.sparse_attention")
+    h, h_kv, t, d, j, di, topk = SPARSE
+    plan = S._kernel_plan(t, d, 2)
+    assert plan == {"fwd": (256, 1024), "bwd": (1024, 1024), "index": (512, 512),
+                    "rows": 128, "chunk": 2048}
+    scale = d ** -0.5
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    f32, q, kv = jnp.float32, sds((h, t, d)), sds((h_kv, t, d))
+    rect, mask, rows = sds((t, t), f32), sds((t, t), jnp.int8), sds((h, t), f32)
+    iq, ik, iw = sds((j, t, di)), sds((t, di)), sds((t, j), f32)
+    fn, args = {
+        "index_fwd": (lambda a, b, c: S._index_scores_kernels(a, b, c, *plan["index"], False),
+                      (iq, ik, iw)),
+        "select": (lambda s: S._select_kernels(s, topk, plan["rows"], plan["chunk"], False),
+                   (rect,)),
+        "sparse_fwd": (lambda q, k, v, m: S._sparse_forward(q, k, v, m, scale, *plan["fwd"], False),
+                       (q, kv, kv, mask)),
+        "sparse_bwd": (lambda q, k, v, g, l, dl, m: S._sparse_backward_kernels(
+            q, k, v, g, l, dl, m, scale, *plan["bwd"], False), (q, kv, kv, q, rows, rows, mask)),
+        "target": (lambda q, k, l, m, s, li: S._target_kernels(
+            q, k, l, m, s, li, scale, *plan["index"], False)[0],
+            (q, kv, rows, mask, rect, sds((t,), f32))),
+        "target_grad": (lambda q, k, l, m, s, li: S._target_kernels(
+            q, k, l, m, s, li, scale, *plan["index"], False, jnp.bfloat16),
+            (q, kv, rows, mask, rect, sds((t,), f32))),
+        "index_bwd": (lambda dd, a, b, c: S._index_backward_kernels(
+            dd, a, b, c, *plan["index"], False), (sds((t, t)), iq, ik, iw)),
+    }[kernel]
+    lowered = jax.jit(fn).lower(*args)
+    assert _kernel_names(lowered.as_text()) == [SPARSE_KERNELS[kernel]]
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # nothing but a layout copy of the mask beside the kernel's own operands
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def _sparse_cell():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "keye_vl_2_0_30b_a3b.json")) as f:
+        return root, json.load(f)
+
+
+def test_the_sparse_cells_depth_is_the_one_its_plan_chose():
+    """The rule, on the numbers the file records: the deepest of 6, 5, 4 layers
+    whose compiled step leaves at least 1 GB of the chip's 15.75 (the slow case
+    below compiles them again)."""
+    _, config = _sparse_cell()
+    plan = config["plan"]
+    fits = [
+        tried["num_hidden_layers"] for tried in plan["tried"]
+        if plan["chip_gb"] - tried["total_gb"] >= plan["least_left_gb"]
+    ]
+    assert plan["chosen"] == max(fits) == config["num_hidden_layers"]
+    assert config["published"]["num_hidden_layers"] == 48
+    assert config["train"]["seq_len"] == 16384
+    for tried in plan["tried"]:
+        assert tried["left_gb"] == pytest.approx(plan["chip_gb"] - tried["total_gb"], abs=2e-3)
+        if tried["num_hidden_layers"] > plan["chosen"]:
+            assert tried["left_gb"] < plan["least_left_gb"]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("depth", [6, 5])
+def test_the_sparse_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(depth):
+    """``benchmark/tools/compile_for_v5e.py`` on the cell at the depth the
+    issue asked for first and at the one the rule chose (3 to 4 minutes each):
+    the step compiles with its 30 custom calls a layer, and the plan's total is
+    what the configuration's file records, to 0.1 GB."""
+    import json
+    import subprocess
+    import sys
+
+    root, config = _sparse_cell()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "tools", "compile_for_v5e.py"),
+         "keye_vl_2_0_30b_a3b.steady", "num_hidden_layers=%d" % depth],
+        capture_output=True, text=True, timeout=1500, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    doc = json.loads(out.stdout.strip().splitlines()[-1])
+    recorded = next(
+        t for t in config["plan"]["tried"] if t["num_hidden_layers"] == depth
+    )
+    assert doc["parameters"] == recorded["parameters"]
+    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
+    assert doc["tpu_custom_calls"] == 30 * depth
