@@ -43,9 +43,10 @@ more than a tile of any ``[T, T]`` rectangle per head:
   online-softmax update, VMEM rule and blocks they share) under a mask that is
   an **operand**, not a function of positions. The work is the dense causal
   one: what the selection empties inside a tile is time, not work;
-- ``_target_kernel``: ``p`` (the heads ride the innermost grid dimension and
-  sum in VMEM), the rows' KL and, for the backward, ``dL_I/dI`` in the
-  indexer's compute dtype;
+- ``_target_kernel``: ``p``, the rows' KL and, for the backward, ``dL_I/dI``
+  in the indexer's compute dtype. A grid step is a score tile for all the
+  heads: they loop inside the kernel over strips of the tile's rows, their
+  sum one value a strip, and the mask is read once a tile, at the close;
 - ``_index_bwd_kernel``: ``dL_I/dI`` through the relu to ``index_q``,
   ``index_k`` and ``index_w``, recomputing each head's products.
 """
@@ -84,6 +85,7 @@ REMAT_NAMES = (SELECT_NAME,)
 _INDEX_BLOCKS = (512, 512)
 _SELECT_ROWS = 128
 _SELECT_CHUNK = 2048             # keys a pass of the count reads at a time
+_TARGET_ROWS = 256               # rows of a tile the target sums the heads over at a time
 _INT_MIN = -(2 ** 31)
 
 
@@ -700,49 +702,80 @@ _masked_flash.defvjp(_masked_flash_fwd, _masked_flash_bwd)
 # -- the indexer's target and loss -----------------------------------------
 
 
+def _target_rows(block_q: int) -> int:
+    """Rows of the strip of a score tile the target kernel sums the heads over
+    at a time (every key of the tile)."""
+    return _fit_block(_TARGET_ROWS, block_q)
+
+
 def _target_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, lsi_ref, *refs,
-                   scale: float, heads: int, block_q: int, block_k: int,
-                   num_k: int, with_grad: bool):
+                   scale: float, heads: int, group: int, block_q: int,
+                   block_k: int, num_k: int, strip_rows: int, with_grad: bool):
+    """One grid step is one score tile for every head: ``q_ref [heads,
+    block_q, D]``, ``k_ref [Hkv, block_k, D]``, ``lse_ref [heads, block_q, 1]``
+    (the masked forward's row sums in the shape it writes them: XLA then
+    schedules the kernels around this call as it did before the heads came
+    inside). The tile is walked in strips of ``strip_rows`` rows; in a strip
+    the heads are a static loop whose sum of ``exp(s * scale - lse_h)`` is one
+    value, added bare in the heads' order, and the selection is applied once,
+    to the sum, at the strip's close. An
+    unpicked key's exponent can pass float32 (``lse_h`` is over the selected
+    keys only), so the sum may hold ``inf`` off the selection: the select comes
+    first, before the ``1 / heads``, the ``log`` and the product, and no ``inf
+    * 0`` is formed. ``refs``: the rows' KL, ``dI`` with ``with_grad``, and the
+    KL's lane-folded scratch."""
     from jax.experimental import pallas as pl
 
-    if with_grad:
-        kl_ref, d_ref, p_scr, kl_scr = refs
-    else:
-        kl_ref, p_scr, kl_scr = refs
-    qi, ki, hh = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kl_ref, kl_scr = refs[0], refs[-1]
+    d_ref = refs[1] if with_grad else None
+    qi, ki = pl.program_id(0), pl.program_id(1)
 
-    @pl.when((ki == 0) & (hh == 0))
+    @pl.when(ki == 0)
     def _init_rows():
         kl_scr[...] = jnp.zeros_like(kl_scr)
 
     @pl.when(ki <= _last_live(qi, block_q, block_k))
     def _tile():
-        picked = mask_ref[...].astype(jnp.int32) != 0
-
-        @pl.when(hh == 0)
-        def _init():
-            p_scr[...] = jnp.zeros_like(p_scr)
-
-        s = _dot_nt(q_ref[0], k_ref[0]) * scale
-        p_scr[...] = p_scr[...] + jnp.where(picked, jnp.exp(s - lse_ref[0]), 0.0)
-
-        @pl.when(hh == heads - 1)
-        def _close():
-            target = p_scr[...] * (1.0 / heads)
-            logq = s_ref[...] - lsi_ref[...]
+        def strip(r, carry):
+            rows = pl.ds(pl.multiple_of(r * strip_rows, strip_rows), strip_rows)
+            total = None
+            for h in range(heads):
+                s = _dot_nt(q_ref[h, rows, :], k_ref[h // group]) * scale
+                p = jnp.exp(s - lse_ref[h, rows, :])
+                total = p if total is None else total + p
+            picked = mask_ref[rows, :].astype(jnp.int32) != 0
+            target = jnp.where(picked, total, 0.0) * (1.0 / heads)
+            logq = s_ref[rows, :] - lsi_ref[rows, :]
             live = picked & (target > 0)
             term = jnp.where(
                 live, target * (jnp.log(jnp.where(live, target, 1.0)) - logq), 0.0
             )
-            kl_scr[...] = kl_scr[...] + _fold_lanes(term)
+            kl_scr[rows, :] = kl_scr[rows, :] + _fold_lanes(term)
             if with_grad:
-                d_ref[...] = jnp.where(
+                d_ref[rows, :] = jnp.where(
                     picked, jnp.exp(logq) - target, 0.0
                 ).astype(d_ref.dtype)
+            return carry
 
-    @pl.when((ki == num_k - 1) & (hh == heads - 1))
+        jax.lax.fori_loop(0, block_q // strip_rows, strip, 0)
+
+    @pl.when(ki == num_k - 1)
     def _rows_out():
         kl_ref[...] = jnp.sum(kl_scr[...], axis=-1, keepdims=True)
+
+
+def _target_vmem(h, h_kv, d, itemsize, block_q, block_k, strip_rows, grad_itemsize):
+    """Bytes of VMEM the target kernel asks for: two buffers of every block (a
+    ``[rows, 1]`` block is a lane tile wide: the heads' row sums are 8 MB a
+    buffer at 32 x 512), the KL's scratch, and sixteen of a strip's float32
+    values for Mosaic's own."""
+    keys = max(128, block_k)
+    blocks = (
+        (h * block_q + h_kv * block_k) * d * itemsize
+        + block_q * keys * (1 + 4 + grad_itemsize)
+        + (h + 2) * block_q * 128 * 4
+    )
+    return 2 * blocks + block_q * 128 * 4 + 16 * strip_rows * keys * 4
 
 
 def _target_kernels(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
@@ -750,19 +783,32 @@ def _target_kernels(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
     """The rows' ``KL(p || softmax over the selection of I)`` ``[T]`` and, with
     ``grad_dtype``, ``d (sum of them) / d I`` ``[T, T]`` (``softmax - p`` on
     the selection, 0 off it; tiles past the diagonal unwritten)."""
+    return _target_call(
+        q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
+        _target_rows(block_q), interpret, grad_dtype,
+    )
+
+
+# jitted so that a step traces and lowers the body, 32 heads unrolled, once a
+# mode and not once a call site (fifteen a step at five layers)
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _target_call(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
+                 strip_rows, interpret, grad_dtype):
+    """The grid is the score tiles alone: a row of tiles fetches its q block,
+    every head's, once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     h, t, d = q.shape
-    group = h // k.shape[0]
+    h_kv = k.shape[0]
     num_k = t // block_k
     with_grad = grad_dtype is not None
 
     def held(qi, ki):
         return jax.lax.min(ki, _last_live(qi, block_q, block_k))
 
-    tile = pl.BlockSpec((block_q, block_k), lambda qi, ki, hh: (qi, held(qi, ki)))
-    row = pl.BlockSpec((block_q, 1), lambda qi, ki, hh: (qi, 0))
+    tile = pl.BlockSpec((block_q, block_k), lambda qi, ki: (qi, held(qi, ki)))
+    row = pl.BlockSpec((block_q, 1), lambda qi, ki: (qi, 0))
     out_shape = [jax.ShapeDtypeStruct((t, 1), jnp.float32)]
     out_specs = [row]
     if with_grad:
@@ -770,30 +816,31 @@ def _target_kernels(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
         out_specs.append(tile)
     kernel = pl.pallas_call(
         functools.partial(
-            _target_kernel, scale=scale, heads=h, block_q=block_q,
-            block_k=block_k, num_k=num_k, with_grad=with_grad,
+            _target_kernel, scale=scale, heads=h, group=h // h_kv, block_q=block_q,
+            block_k=block_k, num_k=num_k, strip_rows=strip_rows,
+            with_grad=with_grad,
         ),
         out_shape=out_shape,
-        grid=(t // block_q, num_k, h),
+        grid=(t // block_q, num_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda qi, ki, hh: (hh, qi, 0)),
-            pl.BlockSpec(
-                (1, block_k, d), lambda qi, ki, hh: (hh // group, held(qi, ki), 0)
-            ),
-            pl.BlockSpec((1, block_q, 1), lambda qi, ki, hh: (hh, qi, 0)),
+            pl.BlockSpec((h, block_q, d), lambda qi, ki: (0, qi, 0)),
+            pl.BlockSpec((h_kv, block_k, d), lambda qi, ki: (0, held(qi, ki), 0)),
+            pl.BlockSpec((h, block_q, 1), lambda qi, ki: (0, qi, 0)),
             tile, tile, row,
         ],
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, block_k), jnp.float32),
-            pltpu.VMEM((block_q, min(128, block_k)), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((block_q, min(128, block_k)), jnp.float32)],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_target_vmem(
+                h, h_kv, d, q.dtype.itemsize, block_q, block_k, strip_rows,
+                jnp.dtype(grad_dtype).itemsize if with_grad else 0,
+            ),
         ),
     )
-    with obs_trace.span("kernel_trace", kernel="dsa_target"):
+    # the scope names the call in a device trace (the innermost one counts)
+    with obs_trace.span("kernel_trace", kernel="dsa_target"), jax.named_scope("dsa_target"):
         outs = kernel(q, k, lse[..., None], mask, scores, lse_index[:, None])
     return (outs[0][:, 0], outs[1]) if with_grad else (outs[0][:, 0], None)
 
@@ -850,15 +897,19 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 @functools.lru_cache(maxsize=None)
 def _note_shape(tq, topk, index_heads, index_dim, select, score_bytes,
-                mask_bytes, index_blocks, fwd_blocks, bwd_blocks, select_rows):
+                mask_bytes, index_blocks, fwd_blocks, bwd_blocks, select_rows,
+                target_heads_step):
     """One ``dsa_shape`` instant in the span ring for each shape the
-    selection is traced at."""
+    selection is traced at (``target_*``: the target kernel's tile, the heads
+    a grid step of it takes and the strip their sum is one value over)."""
     obs_trace.get_tracer().instant(
         "dsa_shape", tq=tq, topk=topk, index_heads=index_heads,
         index_dim=index_dim, select=select, score_bytes=score_bytes,
         mask_bytes=mask_bytes, index_blocks=list(index_blocks),
         fwd_blocks=list(fwd_blocks), bwd_blocks=list(bwd_blocks),
-        select_rows=select_rows,
+        select_rows=select_rows, target_blocks=list(index_blocks),
+        target_heads_step=target_heads_step,
+        target_strip=[_target_rows(index_blocks[0]), index_blocks[1]],
     )
 
 
@@ -895,7 +946,7 @@ def _one_kernels(q, k, v, index_q, index_k, index_w, topk, scale, plan, interpre
         stats = _stats(mask, *plan["fwd"])
     _note_shape(
         t, topk, index_q.shape[0], index_q.shape[2], "bisect", t * t * 4, t * t,
-        plan["index"], plan["fwd"], plan["bwd"], plan["rows"],
+        plan["index"], plan["fwd"], plan["bwd"], plan["rows"], q.shape[0],
     )
     with jax.named_scope("attn_sparse"):
         out, lse = _masked_flash(

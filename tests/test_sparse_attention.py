@@ -58,10 +58,10 @@ BLOCKS = ((64, 128), (128, 64))     # (fwd block_q, block_k), (bwd block_q, bloc
 def small_tiles():
     """The kernels' module constants at sizes that give T = 256 several tiles
     each way (the real ones are one tile there)."""
-    was = S._INDEX_BLOCKS, S._SELECT_ROWS, S._SELECT_CHUNK
-    S._INDEX_BLOCKS, S._SELECT_ROWS, S._SELECT_CHUNK = (64, 64), 32, 128
+    was = S._INDEX_BLOCKS, S._SELECT_ROWS, S._SELECT_CHUNK, S._TARGET_ROWS
+    S._INDEX_BLOCKS, S._SELECT_ROWS, S._SELECT_CHUNK, S._TARGET_ROWS = (64, 64), 32, 128, 32
     yield
-    S._INDEX_BLOCKS, S._SELECT_ROWS, S._SELECT_CHUNK = was
+    S._INDEX_BLOCKS, S._SELECT_ROWS, S._SELECT_CHUNK, S._TARGET_ROWS = was
 
 
 def operands(seed=0, tied=False):
@@ -232,6 +232,81 @@ def test_a_traced_shape_leaves_one_dsa_shape_instant(small_tiles):
     assert event["tq"] == T and event["topk"] == TOPK and event["select"] == "bisect"
     assert event["index_heads"] == J and event["index_dim"] == DI
     assert event["score_bytes"] == T * T * 4 and event["mask_bytes"] == T * T
+    # the target's schedule: the tile, every head in one grid step, two strips a tile
+    assert event["target_blocks"] == [64, 64] and event["target_heads_step"] == H
+    assert event["target_strip"] == [32, 64]
+
+
+def _target_case(h, hkv, seed):
+    """Operands of the target kernel as the layer hands them over (the masked
+    forward's own ``lse``), and the reference's ``L_I`` and ``dI``."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(keys[0], (h, T, DH))
+    k, v = (jax.random.normal(key, (hkv, T, DH)) for key in keys[1:3])
+    scores = jax.random.normal(keys[3], (T, T))
+    picked = S.select_reference(scores, TOPK)
+    mask = picked.astype(jnp.int8)
+    scale = DH ** -0.5
+    _, lse = S._sparse_forward(q, k, v, mask, scale, 64, 64, True)
+    with jax.default_matmul_precision("highest"):
+        target = jnp.mean(S._masked_attention_reference(q, k, v, picked, scale)[1], axis=0)
+    loss = lambda s: S.index_kl_reference(s, picked, target)  # noqa: E731
+    want_kl, want_d = jax.value_and_grad(loss)(scores)
+    run = lambda grad_dtype=None: S._target_kernels(  # noqa: E731
+        q, k, lse, mask, scores, S._selected_lse(scores, mask), scale, 64, 64, True,
+        grad_dtype,
+    )
+    return run, np.asarray(picked), want_kl, want_d * T
+
+
+def test_the_targets_two_modes_give_the_same_rows_and_a_gradient_that_sums_to_nothing(small_tiles):
+    """The value call and the ``dI`` call read the rows' KL bit for bit alike;
+    ``dI`` is 0 off the selection and ``softmax - p`` adds up to 0 over a
+    row's selection."""
+    run, picked, _, _ = _target_case(H, HKV, 11)
+    rows, none = run()
+    rows_again, d = run(jnp.float32)
+    assert none is None
+    assert np.asarray(rows).tobytes() == np.asarray(rows_again).tobytes()
+    d = np.where(np.tril(np.ones((T, T), bool)), np.asarray(d), 0.0)  # dead tiles are unwritten
+    assert not d[~picked].any()
+    assert np.abs(d.sum(axis=1)).max() < 1e-5 and np.abs(d).max() > 1e-3
+
+
+@pytest.mark.parametrize("mode", ["value", "grad"])
+@pytest.mark.parametrize(
+    "h, hkv", [(4, 4), (4, 2), (8, 1), (5, 1), (6, 3)],
+    ids=["group1", "group2", "group8", "five_heads", "six_heads_in_pairs"],
+)
+def test_the_target_kernel_against_the_reference_by_group_and_mode(small_tiles, h, hkv, mode):
+    """Every head in one grid step, two strips a tile: GQA groups of 1, 2 and 8
+    heads a key head, and head counts that no power of two divides."""
+    run, picked, want_kl, want_d = _target_case(h, hkv, 12 + h)
+    rows, d = run(jnp.float32 if mode == "grad" else None)
+    assert rows.shape == (T,)
+    _close(jnp.mean(rows), want_kl, tol=1e-5)
+    if mode == "grad":
+        _close(np.where(picked, np.asarray(d), 0.0), want_d, tol=5e-5)
+
+
+def test_an_unpicked_key_whose_exponent_passes_float32_leaves_the_target_finite(small_tiles):
+    """The heads' sum is masked once, at the close: an unpicked causal key
+    whose ``q . k * scale - lse`` passes 89 makes that sum ``inf`` for its
+    head, and the select must come before anything multiplies it."""
+    q, k, v, iq, ik, iw = operands(8)
+    picked = np.asarray(S.sparse_attention_reference(q, k, v, iq, ik, iw, TOPK)[3]["selection"][0]) != 0
+    row = T - 3
+    key = int(np.flatnonzero(~picked[row, :row])[0])
+    along = jnp.zeros((DH,)).at[0].set(30.0)          # 30 * 30 * 32 ** -0.5 = 159
+    q, k = q.at[0, 1, row].set(along), k.at[0, 0, key].set(along)
+    _, lse = S._sparse_forward(q[0], k[0], v[0], jnp.asarray(picked, jnp.int8), DH ** -0.5, 64, 64, True)
+    assert float(q[0, 1, row] @ k[0, 0, key]) * DH ** -0.5 - float(lse[1, row]) > 89
+    args = (q, k, v, iq, ik, iw)
+    (_, (_, kl, _)), grads = _objective("kernels", args)
+    (_, (_, want_kl, _)), want = _objective("plain", args)
+    _close(kl, want_kl, tol=1e-5)
+    for got, ref in zip(grads[3:], want[3:]):          # dI reaches the indexer's three
+        _close(got, ref, tol=5e-5)
 
 
 def test_the_masked_kernels_keep_emitting_attn_tiles(small_tiles):

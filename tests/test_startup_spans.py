@@ -52,6 +52,9 @@ def fit_ring(tmp_path_factory):
 
     was = jax.config.jax_compilation_cache_dir
     enable_compilation_cache(str(tmp_path_factory.mktemp("xla")))
+    # jax opens its cache once: one an earlier test of this worker left open
+    # on another directory would answer for the empty one
+    compilation_cache.reset_cache()
     tracer = obs_trace.get_tracer()
     tracer.clear()
     try:

@@ -580,8 +580,9 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
     sequence of 16,384, GQA 32:4 x 128, an indexer of 16 heads x 64, the blocks
     ``_kernel_plan`` gives): the int8 mask's tiles, the select kernel's row
     block and key scratch (8 MB each under a limit set from the shapes), the
-    fused backward's 8 MB dq accumulator, the target's heads on the innermost
-    grid dimension, the indexer backward's whole-sequence key gradient."""
+    fused backward's 8 MB dq accumulator, the target's q block of all 32 heads
+    (4 MB a buffer) with the heads' loop inside the kernel, the indexer
+    backward's whole-sequence key gradient."""
     S = importlib.import_module("edl_tpu.ops.sparse_attention")
     h, h_kv, t, d, j, di, topk = SPARSE
     plan = S._kernel_plan(t, d, 2)
@@ -619,6 +620,42 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
     assert compiled.as_text().count("tpu_custom_call") == 1
     # nothing but a layout copy of the mask beside the kernel's own operands
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_the_target_takes_every_head_in_a_grid_step_under_a_limit_from_the_shapes(one_chip):
+    """``sparse_attention`` traced at the cell's shape (nothing compiled)
+    leaves the target's schedule on the ``dsa_shape`` instant, and the lowered
+    call asks for the VMEM its blocks need, well under half a core's."""
+    from edl_tpu.obs import trace as obs_trace
+
+    S = importlib.import_module("edl_tpu.ops.sparse_attention")
+    h, h_kv, t, d, j, di, topk = SPARSE
+
+    def sds(dims, dtype=jnp.bfloat16, **kw):
+        return jax.ShapeDtypeStruct(dims, dtype, **kw)
+
+    S._note_shape.cache_clear()
+    ring = obs_trace.get_tracer()
+    before = len([e for e in ring.to_events() if e["name"] == "dsa_shape"])
+    jax.eval_shape(
+        lambda *a: S.sparse_attention(*a, topk, interpret=True),
+        sds((1, h, t, d)), sds((1, h_kv, t, d)), sds((1, h_kv, t, d)),
+        sds((1, j, t, di)), sds((1, t, di)), sds((1, t, j), jnp.float32),
+    )
+    (event,) = [e["args"] for e in ring.to_events() if e["name"] == "dsa_shape"][before:]
+    assert event["target_blocks"] == [512, 512] and event["target_heads_step"] == h
+    assert event["target_strip"] == [256, 512]
+    lowered = jax.jit(
+        lambda q, k, l, m, s, li: S._target_kernels(
+            q, k, l, m, s, li, d ** -0.5, 512, 512, False, jnp.bfloat16)
+    ).lower(
+        sds((h, t, d), sharding=one_chip), sds((h_kv, t, d), sharding=one_chip),
+        sds((h, t), jnp.float32, sharding=one_chip), sds((t, t), jnp.int8, sharding=one_chip),
+        sds((t, t), jnp.float32, sharding=one_chip), sds((t,), jnp.float32, sharding=one_chip),
+    )
+    (limit,) = re.findall(r'memory_space\\22: ?1, \\22offset\\22: 0, \\22size\\22: (\d+)', lowered.as_text())
+    assert int(limit) == S._target_vmem(h, h_kv, d, 2, 512, 512, 256, 2)
+    assert 16 << 20 < int(limit) < A._vmem_capacity() // 2   # q alone is two buffers of 4 MB
 
 
 def _sparse_cell():
