@@ -591,10 +591,10 @@ def _remat_policy(name: Optional[str]):
     ``V_new``; ``gdn_out``: the rule's output and final state) and every
     chunk's inverse (``gdn_inverse``), so that the block's recomputation
     runs neither the carry's loop nor the solve again
-    (ops/gated_delta.py), and the two thresholds a row that a
-    sparse-attention layer's selection found (``dsa_select``: 128 KB a
-    layer; the recomputation then makes the scores and the mask again and
-    not the bisection; ops/sparse_attention.py); a model without such a
+    (ops/gated_delta.py), and the three values a row that a
+    sparse-attention layer's select kernel found (``dsa_select``: 192 KB a
+    layer; the recomputation then makes the scores and the mask again, not
+    the bisection or its row sums; ops/sparse_attention.py); a model without such a
     layer bears none of the names. ``None``/"full" is classic
     recompute-everything."""
     if name in (None, "full"):

@@ -34,8 +34,11 @@ more than a tile of any ``[T, T]`` rectangle per head:
   by bisection over the 32 bits of the scores' order-preserving integer keys
   on a row block held in VMEM (the scores are read once), then, only in a
   block where the threshold is tied, a bisection over the key index for the
-  last tied key a row keeps: two ``[T]`` integers a call (``tau``, ``last``),
-  which bear the ``checkpoint_name`` ``dsa_select``;
+  last tied key a row keeps, then, from the same block, the log-sum-exp of the
+  scores a row keeps (its softmax's row sum in ``L_I``): three ``[T]`` vectors
+  a call (``tau``, ``last``, ``lse``), which bear the ``checkpoint_name``
+  ``dsa_select``, so the backward has all three as residuals and no plain-XLA
+  pass reads the ``[T, T]`` scores for the loss;
 - the selection as an ``int8`` mask ``[T, T]``, one elementwise pass of XLA
   over ``I`` and the two thresholds;
 - ``_sparse_fwd_kernel`` / ``_sparse_bwd_kernel``: the grid-pipelined flash
@@ -78,7 +81,7 @@ from edl_tpu.ops.attention import (
     _vmem_capacity,
 )
 
-SELECT_NAME = "dsa_select"       # checkpoint_name of the selection's thresholds
+SELECT_NAME = "dsa_select"       # checkpoint_name of the select kernel's three rows
 REMAT_NAMES = (SELECT_NAME,)
 # the tile the index scores, the target and the indexer's backward are made in
 # (a configuration's q / kv chunk of 512), and the rows a selection holds
@@ -203,14 +206,15 @@ def _last_live(qi, block_q: int, block_k: int):
     return jax.lax.div(qi * block_q + block_q - 1, block_k)
 
 
-def _fold_lanes(x, lanes: int = 128):
+def _fold_lanes(x, lanes: int = 128, combine=jnp.add):
     """``[rows, n * lanes] -> [rows, lanes]``: the lane tiles summed (plain
-    adds; the one cross-lane sum is left to the caller's last step)."""
+    adds, or ``combine``; the one cross-lane step is left to the caller's
+    last)."""
     if x.shape[1] <= lanes:
         return x
     part = x[:, :lanes]
     for t in range(1, x.shape[1] // lanes):
-        part = part + x[:, t * lanes:(t + 1) * lanes]
+        part = combine(part, x[:, t * lanes:(t + 1) * lanes])
     return part
 
 
@@ -363,16 +367,27 @@ def _index_backward_kernels(d_scores, index_q, index_k, index_w, block_q, block_
 # -- the selection ----------------------------------------------------------
 
 
+def _order_bits(i):
+    """A float32's bits (int32) to the key whose signed order is the floats',
+    and back: its own inverse."""
+    return i ^ ((i >> 31) & 0x7FFFFFFF)
+
+
 def _order_key(x):
     """float32 -> int32 whose signed order is the floats' (``-0.0`` as
     ``+0.0``, which ``lax.top_k`` cannot tell apart either)."""
     x = jnp.where(x == 0.0, 0.0, x)
-    i = jax.lax.bitcast_convert_type(x, jnp.int32)
-    return i ^ ((i >> 31) & 0x7FFFFFFF)
+    return _order_bits(jax.lax.bitcast_convert_type(x, jnp.int32))
 
 
-def _select_kernel(s_ref, tau_ref, last_ref, key_scr, *, topk: int, rows: int,
-                   chunk: int, total: int):
+def _kept(key, cols, tau, last):
+    """Whether a causal key is of the selection: above the row's threshold,
+    or tied with it and no later than the last tied key the row keeps."""
+    return (key > tau) | ((key == tau) & (cols <= last))
+
+
+def _select_kernel(s_ref, tau_ref, last_ref, lse_ref, key_scr, *, topk: int,
+                   rows: int, chunk: int, total: int):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(0)
@@ -388,21 +403,25 @@ def _select_kernel(s_ref, tau_ref, last_ref, key_scr, *, topk: int, rows: int,
     def at(c):
         return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
 
-    def fill(c, carry):
-        key = _order_key(s_ref[:, at(c)])
-        key_scr[:, at(c)] = jnp.where(cols_of(c) <= t, key, _INT_MIN)
-        return carry
+    lanes = min(128, chunk)
 
-    jax.lax.fori_loop(0, chunks, fill, 0)
+    def fill(c, top):
+        # a key past the diagonal holds _INT_MIN, under every finite score's:
+        # no later pass asks for the causal test again
+        key = jnp.where(cols_of(c) <= t, _order_key(s_ref[:, at(c)]), _INT_MIN)
+        key_scr[:, at(c)] = key
+        return jnp.maximum(top, _fold_lanes(key, combine=jnp.maximum))
+
+    top = jax.lax.fori_loop(
+        0, chunks, fill, jnp.full((rows, lanes), _INT_MIN, jnp.int32)
+    )
 
     def count(hit):
         """Keys a row for which ``hit(keys of a chunk, c)`` holds: [rows, 1]."""
         def body(c, acc):
             return acc + _fold_lanes(hit(key_scr[:, at(c)], c).astype(jnp.int32))
 
-        acc = jax.lax.fori_loop(
-            0, chunks, body, jnp.zeros((rows, min(128, chunk)), jnp.int32)
-        )
+        acc = jax.lax.fori_loop(0, chunks, body, jnp.zeros((rows, lanes), jnp.int32))
         return jnp.sum(acc, axis=-1, keepdims=True)
 
     def value_bit(i, found):
@@ -436,10 +455,26 @@ def _select_kernel(s_ref, tau_ref, last_ref, key_scr, *, topk: int, rows: int,
             0, bits, index_bit, jnp.zeros((rows, 1), jnp.int32)
         )
 
+    # the log-sum-exp of the scores a row keeps, about its largest causal
+    # score, which is always kept
+    m = jax.lax.bitcast_convert_type(
+        _order_bits(jnp.max(top, axis=-1, keepdims=True)), jnp.float32
+    )
+    last = last_ref[...]
+
+    def kept_exp(c, acc):
+        keep = _kept(key_scr[:, at(c)], cols_of(c), tau, last)
+        return acc + _fold_lanes(jnp.where(keep, jnp.exp(s_ref[:, at(c)] - m), 0.0))
+
+    acc = jax.lax.fori_loop(0, chunks, kept_exp, jnp.zeros((rows, lanes), jnp.float32))
+    lse_ref[...] = m + jnp.log(jnp.sum(acc, axis=-1, keepdims=True))
+
 
 def _select_kernels(scores, topk: int, rows: int, chunk: int, interpret: bool):
-    """``(tau, last)``, two ``[T]`` int32: row ``t`` keeps key ``s <= t`` iff
-    ``key(I[t, s]) > tau[t]``, or ``== tau[t]`` and ``s <= last[t]``."""
+    """``(tau, last, lse)``, three ``[T]``: row ``t`` keeps key ``s <= t`` iff
+    ``key(I[t, s]) > tau[t]``, or ``== tau[t]`` and ``s <= last[t]`` (int32
+    both); ``lse[t]`` (float32) is ``log sum exp(I[t, s])`` over the keys it
+    keeps."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -450,10 +485,10 @@ def _select_kernels(scores, topk: int, rows: int, chunk: int, interpret: bool):
         functools.partial(
             _select_kernel, topk=topk, rows=rows, chunk=chunk, total=t
         ),
-        out_shape=[out, out],
+        out_shape=[out, out, jax.ShapeDtypeStruct((t, 1), jnp.float32)],
         grid=(t // rows,),
         in_specs=[pl.BlockSpec((rows, t), lambda qi: (qi, 0))],
-        out_specs=[spec, spec],
+        out_specs=[spec, spec, spec],
         scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -462,8 +497,8 @@ def _select_kernels(scores, topk: int, rows: int, chunk: int, interpret: bool):
         ),
     )
     with obs_trace.span("kernel_trace", kernel="dsa_select"):
-        tau, last = kernel(scores)
-    return tau[:, 0], last[:, 0]
+        tau, last, lse = kernel(scores)
+    return tau[:, 0], last[:, 0], lse[:, 0]
 
 
 def selection_mask(scores, tau, last):
@@ -471,8 +506,7 @@ def selection_mask(scores, tau, last):
     thresholds of :func:`_select_kernels`."""
     t = scores.shape[0]
     rows, cols = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
-    key = _order_key(scores)
-    keep = (key > tau[:, None]) | ((key == tau[:, None]) & (cols <= last[:, None]))
+    keep = _kept(_order_key(scores), cols, tau[:, None], last[:, None])
     return (keep & (cols <= rows)).astype(jnp.int8)
 
 
@@ -689,6 +723,10 @@ def _masked_flash_bwd(scale, blocks, interpret, residuals, cotangents):
     q, k, v, mask, out, lse = residuals
     g, _ = cotangents          # lse feeds the detached target alone
     h, t, d = q.shape
+    # one buffer for both of the mask's readers here (this transpose and the
+    # target's dI call): XLA otherwise fuses its pass over the scores into the
+    # transpose too, and makes the mask twice
+    mask = jax.lax.optimization_barrier(mask)
     delta = _bwd_delta(g, out, 1, h, t, d)
     dq, dk, dv = _sparse_backward_kernels(
         q, k, v, g, lse, delta, mask.T, scale, *blocks[1], interpret
@@ -845,38 +883,34 @@ def _target_call(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
     return (outs[0][:, 0], outs[1]) if with_grad else (outs[0][:, 0], None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _index_loss(index_q, index_k, index_w, scores, mask, q, k, lse, scale,
-                blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _index_loss(index_q, index_k, index_w, scores, mask, lse_index, q, k, lse,
+                scale, blocks, interpret):
     """``L_I`` of one sequence from the indexer's three operands; ``scores``
-    (their ``I``), the selection and the main attention's ``q``, ``k``,
-    ``lse`` are constants here."""
+    (their ``I``), the selection, its rows' log-sum-exp of ``I`` (the select
+    kernel's) and the main attention's ``q``, ``k``, ``lse`` are constants
+    here."""
     return _index_loss_fwd(
-        index_q, index_k, index_w, scores, mask, q, k, lse, scale, blocks,
-        interpret,
+        index_q, index_k, index_w, scores, mask, lse_index, q, k, lse, scale,
+        blocks, interpret,
     )[0]
 
 
-def _selected_lse(scores, mask):
-    return jax.scipy.special.logsumexp(
-        jnp.where(mask != 0, scores, -jnp.inf), axis=-1
-    )
-
-
-def _index_loss_fwd(index_q, index_k, index_w, scores, mask, q, k, lse, scale,
-                    blocks, interpret):
+def _index_loss_fwd(index_q, index_k, index_w, scores, mask, lse_index, q, k,
+                    lse, scale, blocks, interpret):
     rows, _ = _target_kernels(
-        q, k, lse, mask, scores, _selected_lse(scores, mask), scale, *blocks,
-        interpret,
+        q, k, lse, mask, scores, lse_index, scale, *blocks, interpret
     )
-    return jnp.mean(rows), (index_q, index_k, index_w, scores, mask, q, k, lse)
+    return jnp.mean(rows), (
+        index_q, index_k, index_w, scores, mask, lse_index, q, k, lse
+    )
 
 
 def _index_loss_bwd(scale, blocks, interpret, residuals, g):
-    index_q, index_k, index_w, scores, mask, q, k, lse = residuals
+    index_q, index_k, index_w, scores, mask, lse_index, q, k, lse = residuals
     _, d_scores = _target_kernels(
-        q, k, lse, mask, scores, _selected_lse(scores, mask), scale, *blocks,
-        interpret, grad_dtype=index_q.dtype,
+        q, k, lse, mask, scores, lse_index, scale, *blocks, interpret,
+        grad_dtype=index_q.dtype,
     )
     with jax.named_scope("dsa_index"):  # the innermost scope counts
         dq, dk, dw = _index_backward_kernels(
@@ -885,7 +919,7 @@ def _index_loss_bwd(scale, blocks, interpret, residuals, g):
     g = g / scores.shape[0]    # the mean over the rows
     return (
         (dq * g).astype(index_q.dtype), (dk * g).astype(index_k.dtype),
-        (dw * g).astype(index_w.dtype), None, None, None, None, None,
+        (dw * g).astype(index_w.dtype), None, None, None, None, None, None,
     )
 
 
@@ -896,15 +930,17 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
 @functools.lru_cache(maxsize=None)
-def _note_shape(tq, topk, index_heads, index_dim, select, score_bytes,
-                mask_bytes, index_blocks, fwd_blocks, bwd_blocks, select_rows,
-                target_heads_step):
+def _note_shape(tq, topk, index_heads, index_dim, select, index_lse,
+                score_bytes, mask_bytes, index_blocks, fwd_blocks, bwd_blocks,
+                select_rows, target_heads_step):
     """One ``dsa_shape`` instant in the span ring for each shape the
-    selection is traced at (``target_*``: the target kernel's tile, the heads
-    a grid step of it takes and the strip their sum is one value over)."""
+    selection is traced at (``index_lse``: where the row sums of the indexer's
+    softmax are made; ``target_*``: the target kernel's tile, the heads a grid
+    step of it takes and the strip their sum is one value over)."""
     obs_trace.get_tracer().instant(
         "dsa_shape", tq=tq, topk=topk, index_heads=index_heads,
-        index_dim=index_dim, select=select, score_bytes=score_bytes,
+        index_dim=index_dim, select=select, index_lse=index_lse,
+        score_bytes=score_bytes,
         mask_bytes=mask_bytes, index_blocks=list(index_blocks),
         fwd_blocks=list(fwd_blocks), bwd_blocks=list(bwd_blocks),
         select_rows=select_rows, target_blocks=list(index_blocks),
@@ -939,14 +975,18 @@ def _one_kernels(q, k, v, index_q, index_k, index_w, topk, scale, plan, interpre
             stop(index_q), stop(index_k), stop(index_w), *plan["index"], interpret
         )
     with jax.named_scope("dsa_select"):
-        tau, last = _select_kernels(scores, topk, plan["rows"], plan["chunk"], interpret)
-        tau = checkpoint_name(tau, SELECT_NAME)
-        last = checkpoint_name(last, SELECT_NAME)
+        tau, last, lse_index = (
+            checkpoint_name(row, SELECT_NAME)
+            for row in _select_kernels(
+                scores, topk, plan["rows"], plan["chunk"], interpret
+            )
+        )
         mask = selection_mask(scores, tau, last)
         stats = _stats(mask, *plan["fwd"])
     _note_shape(
-        t, topk, index_q.shape[0], index_q.shape[2], "bisect", t * t * 4, t * t,
-        plan["index"], plan["fwd"], plan["bwd"], plan["rows"], q.shape[0],
+        t, topk, index_q.shape[0], index_q.shape[2], "bisect", "select",
+        t * t * 4, t * t, plan["index"], plan["fwd"], plan["bwd"], plan["rows"],
+        q.shape[0],
     )
     with jax.named_scope("attn_sparse"):
         out, lse = _masked_flash(
@@ -954,8 +994,8 @@ def _one_kernels(q, k, v, index_q, index_k, index_w, topk, scale, plan, interpre
         )
     with jax.named_scope("dsa_target"):
         kl = _index_loss(
-            index_q, index_k, index_w, scores, mask, stop(q), stop(k), stop(lse),
-            scale, plan["index"], interpret,
+            index_q, index_k, index_w, scores, mask, lse_index, stop(q), stop(k),
+            stop(lse), scale, plan["index"], interpret,
         )
     return out, kl, stats, _detail(mask, scores)
 

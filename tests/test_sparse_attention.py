@@ -93,7 +93,7 @@ def test_selection_keeps_exactly_min_k_t_plus_1_keys_a_row(small_tiles, tied, to
     form and the reference's blocked ``lax.top_k`` mark the same pairs."""
     _, _, _, iq, ik, iw = operands(2, tied)
     scores = S.index_scores_reference(iq[0], ik[0], iw[0])
-    tau, last = S._select_kernels(scores, topk, 32, 128, True)
+    tau, last, _ = S._select_kernels(scores, topk, 32, 128, True)
     got = np.asarray(S.selection_mask(scores, tau, last)) != 0
     want, kth = reference.select(scores, topk)
     np.testing.assert_array_equal(got, np.asarray(want))
@@ -106,14 +106,41 @@ def test_selection_keeps_exactly_min_k_t_plus_1_keys_a_row(small_tiles, tied, to
     assert float(kth[row]) == np.sort(np.asarray(scores[row]))[::-1][min(topk, T) - 1]
 
 
+def _selected_lse(scores, mask):
+    """The plain form of the select kernel's third output: the log-sum-exp of
+    a row's scores over the keys ``mask`` keeps."""
+    return jax.scipy.special.logsumexp(jnp.where(mask != 0, scores, -jnp.inf), axis=-1)
+
+
+@pytest.mark.parametrize("topk", [1, 48, 200, T, 4 * T])
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+def test_the_select_kernel_gives_the_log_sum_exp_of_the_keys_a_row_keeps(small_tiles, tied, topk):
+    """Over exactly the selection (the causal test and the tie rule with it),
+    scores of both signs, and two rows of all zeros, where every key ties at
+    the threshold and the sum is the count of those kept."""
+    _, _, _, iq, ik, iw = operands(2, tied)
+    scores = S.index_scores_reference(iq[0], ik[0], iw[0])
+    scores = scores.at[7].set(0.0).at[200].set(0.0)
+    assert float(scores.min()) < 0 < float(scores.max())
+    _, _, lse = S._select_kernels(scores, topk, 32, 128, True)
+    assert lse.shape == (T,) and lse.dtype == jnp.float32
+    _close(lse, _selected_lse(scores, S.select_reference(scores, topk)), tol=1e-6)
+    for row in (7, 200):
+        assert float(lse[row]) == pytest.approx(np.log(min(topk, row + 1)), rel=1e-6, abs=1e-6)
+
+
 def test_a_tie_at_the_threshold_goes_to_the_lower_key_index(small_tiles):
     scores = jnp.zeros((T, T), jnp.float32).at[:, 5].set(1.0)   # one key above, all others tied
-    tau, last = S._select_kernels(scores, 4, 32, 128, True)
+    tau, last, lse = S._select_kernels(scores, 4, 32, 128, True)
     got = np.asarray(S.selection_mask(scores, tau, last)) != 0
     assert got[100].nonzero()[0].tolist() == [0, 1, 2, 5]
     assert got[3].nonzero()[0].tolist() == [0, 1, 2, 3]
     assert got[4].nonzero()[0].tolist() == [0, 1, 2, 3]         # key 5 is not causal yet
     np.testing.assert_array_equal(got, np.asarray(S.select_reference(scores, 4)))
+    # the kept tied keys alone count: three zeros and the one, not the row's 100 zeros
+    assert float(lse[100]) == pytest.approx(np.log(3 + np.e), rel=1e-6)
+    assert float(lse[4]) == pytest.approx(np.log(4), rel=1e-6)
+    _close(lse, _selected_lse(scores, got), tol=1e-6)
 
 
 def _objective(impl, args, kl_weight=3.0):
@@ -230,6 +257,7 @@ def test_a_traced_shape_leaves_one_dsa_shape_instant(small_tiles):
     assert len(found) == 1
     event = found[0]
     assert event["tq"] == T and event["topk"] == TOPK and event["select"] == "bisect"
+    assert event["index_lse"] == "select"     # the indexer's row sums leave the select kernel
     assert event["index_heads"] == J and event["index_dim"] == DI
     assert event["score_bytes"] == T * T * 4 and event["mask_bytes"] == T * T
     # the target's schedule: the tile, every head in one grid step, two strips a tile
@@ -253,7 +281,7 @@ def _target_case(h, hkv, seed):
     loss = lambda s: S.index_kl_reference(s, picked, target)  # noqa: E731
     want_kl, want_d = jax.value_and_grad(loss)(scores)
     run = lambda grad_dtype=None: S._target_kernels(  # noqa: E731
-        q, k, lse, mask, scores, S._selected_lse(scores, mask), scale, 64, 64, True,
+        q, k, lse, mask, scores, _selected_lse(scores, mask), scale, 64, 64, True,
         grad_dtype,
     )
     return run, np.asarray(picked), want_kl, want_d * T
@@ -655,3 +683,45 @@ def test_the_remat_policy_keeps_the_selections_thresholds():
     for name in ("save_flash", "save_flash_qkv"):
         assert transformer_module._remat_policy(name) is not None
     assert transformer_module._remat_policy("full") is None
+
+
+def _equations(jaxpr, inside=()):
+    """Every equation with the equations it sits under, kernels' bodies left
+    out."""
+    for eqn in jaxpr.eqns:
+        yield inside, eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub, inside + (eqn,))
+
+
+def test_under_the_remat_policy_the_bisection_runs_once_and_no_pass_of_xla_reads_the_scores_for_the_loss(small_tiles):
+    """A sparse layer's value and gradient under ``jax.checkpoint`` with
+    ``save_flash``, after dead-code elimination: the select kernel once (its
+    three rows are saved names, so the recomputation drops it), the target
+    kernel twice (value, then ``dI``), and under ``dsa_target`` no reduction
+    of a ``[T, T]`` operand outside a kernel: the indexer's row sums are the
+    select kernel's."""
+    from jax._src.interpreters import partial_eval as pe
+
+    def layer(*a):
+        o, kl, _, _ = S.sparse_attention(*a, TOPK, interpret=True, blocks=BLOCKS)
+        return jnp.sum(o * jnp.cos(jnp.arange(DH))) + kl
+
+    step = jax.value_and_grad(
+        jax.checkpoint(layer, policy=transformer_module._remat_policy("save_flash")),
+        argnums=tuple(range(6)),
+    )
+    traced = jax.make_jaxpr(step)(*operands(9)).jaxpr
+    live, _ = pe.dce_jaxpr(traced, [True] * len(traced.outvars))
+    kernels, passes = [], []
+    for inside, eqn in _equations(live):
+        scopes = "/".join(str(e.source_info.name_stack) for e in inside + (eqn,))
+        if eqn.primitive.name == "pallas_call":
+            kernels.append(eqn.params["jaxpr"].debug_info.func_name)
+        elif "dsa_target" in scopes and eqn.primitive.name.startswith("reduce_"):
+            passes += [v.aval.shape for v in eqn.invars if v.aval.shape == (T, T)]
+    assert kernels.count("_select_kernel") == 1
+    assert kernels.count("_target_kernel") == 2
+    assert kernels.count("_index_fwd_kernel") == 2      # the scores are made again, as before
+    assert passes == []

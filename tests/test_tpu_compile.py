@@ -622,6 +622,88 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+def test_the_select_kernel_gives_its_three_rows_inside_the_vmem_limit_it_had(one_chip):
+    """At the cell's shape (``[16384, 16384]`` scores, 128 rows a block, 2048
+    keys a pass) the select kernel, which the ``select`` case above compiles,
+    has its third output, the selection's log-sum-exp, beside the two
+    thresholds, and still asks for the 32 MB its row block, its key scratch and
+    their buffers need: the sum reads the block already held and brings no
+    buffer."""
+    S = importlib.import_module("edl_tpu.ops.sparse_attention")
+    t, topk = SPARSE[2], SPARSE[6]
+    lowered = jax.jit(lambda s: S._select_kernels(s, topk, 128, 2048, False)).lower(
+        jax.ShapeDtypeStruct((t, t), jnp.float32, sharding=one_chip)
+    )
+    assert _kernel_names(lowered.as_text()) == ["_select_kernel"]
+    assert [(o.shape, str(o.dtype)) for o in lowered.out_info] == [
+        ((t,), "int32"), ((t,), "int32"), ((t,), "float32"),
+    ]
+    (limit,) = re.findall(r'memory_space\\22: ?1, \\22offset\\22: 0, \\22size\\22: (\d+)', lowered.as_text())
+    assert int(limit) == 4 * 128 * t * 4 == 32 << 20
+
+
+def _plain_readers(entry, shape):
+    """Instructions of a compiled entry computation, kernels aside, that take
+    a value of ``shape`` (``"f32[16384,16384]"``): ``(name, opcode)`` each."""
+    held, readers = set(), []
+    for line in entry.splitlines():
+        if " = " not in line:
+            continue
+        name, rest = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        op = re.match(r"(\(.*?\)|\S+) ([\w-]+)\(", rest)
+        if not op:
+            continue
+        operands = set(re.findall(r"%[\w.-]+", rest[op.end():].split(")")[0]))
+        if operands & held and op.group(2) not in ("custom-call", "get-tuple-element", "bitcast"):
+            readers.append((name, op.group(2)))
+        if rest.startswith(shape):
+            held.add(name)
+    return readers
+
+
+def test_a_sparse_layers_step_reads_the_scores_outside_its_kernels_only_to_make_the_mask(one_chip):
+    """One sparse-attention layer's value and gradient under ``save_flash`` at
+    the cell's shape, compiled for the described v5e: eight kernels (the select
+    kernel once), and of XLA's own operations only two take the 1.07 GB of
+    scores, the mask's elementwise pass forward and again in the
+    recomputation: no row sum for the indexer's loss, and no second mask for
+    the masked backward's transpose (its barrier holds the two readers to one
+    buffer)."""
+    from unittest import mock
+
+    from edl_tpu.models.transformer import _remat_policy
+
+    S = importlib.import_module("edl_tpu.ops.sparse_attention")
+    h, h_kv, t, d, j, di, topk = SPARSE
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def layer(*a):
+        out, kl, _, _ = S.sparse_attention(*a, topk)
+        return jnp.sum(out.astype(jnp.float32)) + kl
+
+    step = jax.jit(jax.value_and_grad(
+        jax.checkpoint(layer, policy=_remat_policy("save_flash")), argnums=tuple(range(6))
+    ))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = step.lower(
+            sds((1, h, t, d)), sds((1, h_kv, t, d)), sds((1, h_kv, t, d)),
+            sds((1, j, t, di)), sds((1, t, di)), sds((1, t, j), jnp.float32),
+        )
+    kernels = _kernel_names(lowered.as_text())
+    assert len(kernels) == 8 and kernels.count("_select_kernel") == 1
+    assert kernels.count("_target_kernel") == 2
+    text = lowered.compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    readers = _plain_readers(entry, "f32[%d,%d]" % (t, t))
+    masks = {
+        line.strip().split(" = ")[0] for line in entry.splitlines()
+        if " fusion(" in line and "s8[%d,%d]" % (t, t) in line.split(" fusion(")[0]
+    }
+    assert len(masks) == 2 and {name for name, _ in readers} == masks
+
+
 def test_the_target_takes_every_head_in_a_grid_step_under_a_limit_from_the_shapes(one_chip):
     """``sparse_attention`` traced at the cell's shape (nothing compiled)
     leaves the target's schedule on the ``dsa_shape`` instant, and the lowered
