@@ -1,6 +1,11 @@
 from edl_tpu.models.ctr import CTR_EMBEDDING_RULES, DeepFM, binary_cross_entropy_loss
 from edl_tpu.models.mlp import MLP, LinearRegression
-from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
+from edl_tpu.models.gated_delta import (
+    GatedDeltaMixer,
+    GatedDeltaSpec,
+    KimiDeltaMixer,
+    KimiDeltaSpec,
+)
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import MOE_EP_RULES, DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
@@ -12,7 +17,13 @@ from edl_tpu.models.resnet import (
     ResNeXt101_32x16d,
 )
 from edl_tpu.models.decode import greedy_generate, init_cache
-from edl_tpu.models.transformer import ArchSpec, SparseAttentionSpec, TransformerLM
+from edl_tpu.models.transformer import (
+    ArchSpec,
+    LatentAttention,
+    LatentAttentionSpec,
+    SparseAttentionSpec,
+    TransformerLM,
+)
 
 __all__ = [
     "MLP",
@@ -40,4 +51,8 @@ __all__ = [
     "ShortConvMixer",
     "ShortConvSpec",
     "SparseAttentionSpec",
+    "KimiDeltaMixer",
+    "KimiDeltaSpec",
+    "LatentAttention",
+    "LatentAttentionSpec",
 ]

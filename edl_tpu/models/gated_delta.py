@@ -38,6 +38,11 @@ name ``gdn_carry`` and every chunk's ``T`` ``gdn_inverse``
 (``TransformerLM.remat_policy`` ``"save_flash"``) runs the carry's loop once
 forward and once in reverse a layer and the solve once; one that saves none
 runs the forward loop and the solve again when the block is recomputed.
+
+:class:`KimiDeltaMixer` is Kimi delta attention, the same rule with a log-decay
+for every key channel under a safe gate (``ops/gated_delta.py:kda_rule``):
+separate projections and convolutions for q, k and v, a sigmoid gate, the
+scopes ``kda_*`` and the same names for a remat policy.
 """
 
 from __future__ import annotations
@@ -53,7 +58,13 @@ from jax.ad_checkpoint import checkpoint_name
 
 from edl_tpu.models.mamba import _dt_bias_init
 from edl_tpu.ops.causal_conv import causal_conv_silu
-from edl_tpu.ops.gated_delta import OUT_NAME, REMAT_NAMES, gated_delta_rule
+from edl_tpu.ops.gated_delta import (
+    MAX_DECAY_A_STEP,
+    OUT_NAME,
+    REMAT_NAMES,
+    gated_delta_rule,
+    kda_rule,
+)
 
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_scan", "gdn_gate")
 L2_EPS = 1e-6
@@ -63,7 +74,8 @@ L2_EPS = 1e-6
 class GatedDeltaSpec:
     """The shape of a :class:`GatedDeltaMixer`, as one hashable field."""
 
-    num_heads: int           # H, of keys and of values alike
+    num_heads: int           # H held HERE, of keys and of values alike (a chip
+                             # with a share of a layer's heads gives its own count)
     key_dim: int             # d_k
     value_dim: int           # d_v
     d_conv: int = 4
@@ -161,3 +173,139 @@ class GatedDeltaMixer(nn.Module):
 
         with jax.named_scope("gdn_proj"):
             return dense(d_model, "out_proj")(o)
+
+
+# -- Kimi delta attention: a decay for every key channel ---------------------
+
+KDA_SCOPES = ("kda_proj", "kda_conv", "kda_scan", "kda_gate")
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiDeltaSpec:
+    """The shape of a :class:`KimiDeltaMixer`, as one hashable field."""
+
+    num_heads: int             # H, of keys and of values alike
+    key_dim: int               # d_k: queries, keys and the decay's channels
+    value_dim: int             # d_v
+    d_conv: int = 4
+    chunk: int = 64            # steps a chunk of the rule: a power of two
+    lower_bound: float = -5.0  # the safe gate: g in (lower_bound, 0) a channel a step
+
+
+def _kda_a_log_init(key, shape, dtype=jnp.float32):
+    """``log U(1, 16)``: the layer's own (``flash-linear-attention``)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class KimiDeltaMixer(nn.Module):
+    """Kimi delta attention (Kimi Linear, arXiv:2510.26692, as
+    ``flash-linear-attention``'s ``KimiDeltaAttention`` lays it out), for the
+    block's input ``x`` ``[B, T, d_model]``, ``H`` heads of ``d_k`` and
+    ``d_v``::
+
+        q, k, v = silu(causal depthwise conv_{d_conv}(x W_{q,k,v}))   three projections, three
+                                                                      convolutions, no bias
+        q = q / sqrt(|q|^2 + 1e-6) * d_k^-1/2;  k = k / sqrt(|k|^2 + 1e-6)      per head, float32
+        beta = sigmoid(x W_b)                                         per head
+        g    = lower_bound * sigmoid(exp(A_log) * (x W_f + dt_bias))  per head AND key channel:
+                                                                      the safe gate, g in (lower_bound, 0)
+        o    = kda_rule(q, k, v, g, beta)                             (ops/gated_delta.py)
+        o    = RMSNorm_{d_v}(o) * w * sigmoid(x W_g)                  per head, one scale w of d_v
+        out  = W_o o
+
+    ``W_f`` and ``W_g`` are full rank (the published ``no_kda_lora``), ``A_log``
+    one a head, ``dt_bias`` one a key channel. With ``lower_bound`` -5 the
+    rule's sub-block of 16 steps sums to under 80, inside float32's exponent;
+    a bound past ``ops/gated_delta.py:MAX_DECAY_A_STEP`` is refused. No
+    position term.
+
+    Device scopes ``kda_proj`` (the six projections in and the one out),
+    ``kda_conv``, ``kda_scan`` (the L2 norms, ``beta``, the gate, the chunked
+    rule) and ``kda_gate``. Sown into ``"metrics"``: ``kda_decay_mean`` (the
+    mean of ``exp(g)``), ``kda_beta_mean`` and ``kda_state_absmax`` (the largest
+    magnitude in the state after the last step); into ``"intermediates"`` the
+    rule's own inputs. What a remat policy saves bears the scalar rule's names
+    (``REMAT_NAMES``): under ``"save_flash"`` the carry's loop runs once forward
+    and once in reverse a layer and the solve once.
+    """
+
+    spec: KimiDeltaSpec
+    dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        s = self.spec
+        batch, t, d_model = x.shape
+        h, d_k, d_v = s.num_heads, s.key_dim, s.value_dim
+        if not -MAX_DECAY_A_STEP <= s.lower_bound < 0:
+            raise ValueError(
+                "KimiDeltaMixer: lower_bound %g outside [-%g, 0): the rule's "
+                "sub-block would leave float32" % (s.lower_bound, MAX_DECAY_A_STEP)
+            )
+        f32 = jnp.float32
+        dense = lambda width, name, **how: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=self.dtype, name=name, **how
+        )
+        taps = lambda name, width: self.param(  # noqa: E731
+            name, lambda key, shape: jax.random.uniform(key, shape, f32, -0.5, 0.5),
+            (s.d_conv, width),
+        )
+
+        with jax.named_scope("kda_proj"):
+            q, k, v = (
+                dense(h * width, name)(x)
+                for name, width in (("q_proj", d_k), ("k_proj", d_k), ("v_proj", d_v))
+            )
+            # the decay's projection leaves its accumulator in float32: rounded
+            # to bfloat16 first, a log-decay near the gate's steepest point
+            # moves by 0.1 (exp(A_log) up to 16 times a slope of 5 / 4)
+            f = dense(
+                h * d_k, "f_proj",
+                dot_general=functools.partial(jax.lax.dot_general, preferred_element_type=f32),
+            )(x).reshape(batch, t, h, d_k)
+            b = dense(h, "b_proj")(x).astype(f32)
+            gate = dense(h * d_v, "g_proj")(x)
+
+        with jax.named_scope("kda_conv"):
+            q, k, v = (
+                causal_conv_silu(m, taps(name, m.shape[-1]), None)
+                for name, m in (("q_conv", q), ("k_conv", k), ("v_conv", v))
+            )
+
+        a_log = self.param("A_log", _kda_a_log_init, (h,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (h, d_k))
+        scale = self.param("norm", nn.initializers.ones, (d_v,))
+
+        with jax.named_scope("kda_scan"):
+            q = _unit(q.reshape(batch, t, h, d_k).astype(f32)) * d_k ** -0.5
+            k = _unit(k.reshape(batch, t, h, d_k).astype(f32))
+            q, k = q.astype(self.dtype), k.astype(self.dtype)
+            v = v.reshape(batch, t, h, d_v)
+            beta = jax.nn.sigmoid(b)
+            g = s.lower_bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None] * (f + dt_bias))
+            # as GatedDeltaMixer's: what survives a layer's forward under a
+            # policy that saves the names is the rule's o, its final state, the
+            # states the chunks inherit, V_new and every chunk's T
+            rule = jax.checkpoint(
+                functools.partial(kda_rule, chunk=s.chunk, return_final_state=True),
+                policy=jax.checkpoint_policies.save_only_these_names(*REMAT_NAMES),
+            )
+            o, state = (
+                checkpoint_name(a, OUT_NAME) for a in rule(q, k, v, g, beta)
+            )
+        self.sow("metrics", "kda_decay_mean", jnp.mean(jnp.exp(g)))
+        self.sow("metrics", "kda_beta_mean", jnp.mean(beta))
+        self.sow("metrics", "kda_state_absmax", jnp.max(jnp.abs(state)))
+        self.sow("intermediates", "rule_inputs", (q, k, v, g, beta))
+
+        with jax.named_scope("kda_gate"):
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(o * o, axis=-1, keepdims=True) + self.norm_eps
+            )
+            o = o * scale * nn.sigmoid(gate.reshape(batch, t, h, d_v).astype(f32))
+            o = o.reshape(batch, t, h * d_v).astype(self.dtype)
+
+        with jax.named_scope("kda_proj"):
+            return dense(d_model, "o_proj")(o)

@@ -67,6 +67,8 @@ class MoESpec:
     bias_rate: float = 0.0         # > 0: a balancing bias under the choice
     shared_d_ff: int = 0           # > 0: a shared expert of this width
     held: Optional[Tuple[int, int]] = None  # (first, count) held here; None: all
+    n_group: int = 1               # > 1: the experts in this many equal groups,
+    topk_group: int = 1            # ... the choice inside the best topk_group
 
 
 def _sum_unsorted(rows, inverse, k, live=None):
@@ -156,7 +158,8 @@ class DroplessMoE(nn.Module):
     Per token ``x`` (``[B, S, D]`` in, ``[B, S, D]`` out)::
 
         s      = softmax(W_r x)  or  sigmoid(W_r x)     float32, over E
-        e      = top_k(s + b)                      b: the bias, if any
+        e      = top_k(s + b)                      b: the bias, if any; with ``n_group > 1``
+                                                   inside a token's best ``topk_group`` groups
         w      = s[e], then w / (sum(w) + norm_topk_eps) and w * route_scale, as asked
         y      = sum_j W_down[e_j] (w_j * silu(W_gate[e_j] x) * W_up[e_j] x)
                  + shared(x)                       if there is a shared expert
@@ -164,6 +167,13 @@ class DroplessMoE(nn.Module):
     With the defaults this is OLMoE's layer; with sigmoid scores, the bias,
     normalised and scaled weights and a shared expert it is the layer of
     the DeepSeek-V3 line as Trinity's ``afmoe`` code writes it.
+
+    **Groups** (``n_group > 1``; DeepSeek-V3, arXiv:2412.19437, section
+    2.1.2's node-limited routing): the ``E`` experts lie in ``n_group`` equal
+    groups in order (a group is a host); a group's score is the sum of its two
+    best ``s + b``, a token keeps its best ``topk_group`` groups and the top-k
+    is taken inside them. The weights are the chosen experts' ``s`` as before.
+    ``n_group = 1`` is the ungrouped layer, instruction for instruction.
 
     **The bias** (``bias_rate > 0``) enters the choice and not the weight,
     carries no gradient and is moved by the step's own counts ``c_i`` of
@@ -224,6 +234,9 @@ class DroplessMoE(nn.Module):
       term and the trainer reports none.
     - ``"metrics"/moe_load_max`` = the busiest expert's assignments over
       the mean (1.0 is perfect balance, E is one expert taking all).
+    - with groups, ``"metrics"/moe_groups_live`` = the groups that hold at
+      least one of a token's ``k`` experts, the mean over tokens
+      (``topk_group`` where the limit binds).
     - with a bias, ``"metrics"/moe_bias_absmax`` = the largest ``|b|`` of
       the bias the choice was made under (how far the balancing has had to
       lean; zero at first).
@@ -255,7 +268,26 @@ class DroplessMoE(nn.Module):
     bias_rate: float = 0.0
     shared_d_ff: int = 0
     held: Optional[Tuple[int, int]] = None
+    n_group: int = 1
+    topk_group: int = 1
     dtype: Any = jnp.bfloat16
+
+    def _inside_kept_groups(self, choice):
+        """``choice`` [N, E] with the experts outside a token's best
+        ``topk_group`` of the ``n_group`` groups at -inf; a group's score is
+        the sum of its two best entries."""
+        n, e = choice.shape
+        groups, kept = self.n_group, self.topk_group
+        if e % groups or not 1 <= kept <= groups or e // groups < 2:
+            raise ValueError(
+                "%d experts in %d groups, the best %d kept" % (e, groups, kept)
+            )
+        if kept * (e // groups) < self.top_k:
+            raise ValueError("top_k %d outgrows %d kept groups" % (self.top_k, kept))
+        best_two, _ = jax.lax.top_k(choice.reshape(n, groups, e // groups), 2)
+        _, best = jax.lax.top_k(jnp.sum(best_two, axis=-1), kept)    # [N, kept]
+        keep = jnp.any(jax.nn.one_hot(best, groups, dtype=bool), axis=1)
+        return jnp.where(jnp.repeat(keep, e // groups, axis=1), choice, -jnp.inf)
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -287,10 +319,21 @@ class DroplessMoE(nn.Module):
                     "batch_stats", "router_bias", jnp.zeros, (e,), jnp.float32
                 )
                 self.sow("metrics", "moe_bias_absmax", jnp.max(jnp.abs(bias.value)))
-                _, top_idx = jax.lax.top_k(probs + bias.value, k)
-                weights = jnp.take_along_axis(probs, top_idx, axis=-1)
+                choice = probs + bias.value
             else:
+                choice = probs
+            if self.n_group > 1:
+                choice = self._inside_kept_groups(choice)
+            if choice is probs:
                 weights, top_idx = jax.lax.top_k(probs, k)  # [N, k]
+            else:  # chosen by one quantity, weighted by the scores
+                _, top_idx = jax.lax.top_k(choice, k)
+                weights = jnp.take_along_axis(probs, top_idx, axis=-1)
+            if self.n_group > 1:
+                hit = jnp.any(jax.nn.one_hot(
+                    top_idx // (e // self.n_group), self.n_group, dtype=bool
+                ), axis=1)
+                self.sow("metrics", "moe_groups_live", jnp.mean(jnp.sum(hit, axis=-1)))
             if self.norm_topk_prob:
                 weights = weights / (
                     jnp.sum(weights, axis=-1, keepdims=True) + self.norm_topk_eps

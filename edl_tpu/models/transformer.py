@@ -11,13 +11,15 @@ TPU-first:
 - a block's sequence mixer is, by ``ArchSpec.layer_types``, full causal
   attention, attention over a sliding window, a Mamba-2 state-space
   layer (``models/mamba.py``), a gated-delta-rule linear-attention layer
-  (``models/gated_delta.py``), a gated short convolution
-  (``models/short_conv.py``) or attention over the keys a learned indexer
-  selects (``ops/sparse_attention.py``); its feed-forward a SwiGLU or an expert
-  layer (``models/moe.py``), the leading ``ArchSpec.dense_layers`` blocks
-  of an expert model dense: one ``TransformerLM`` runs dense, expert,
-  hybrid (any of the three cheap mixers beside attention) and mixed-window
-  configurations;
+  (``models/gated_delta.py``; with a decay a key channel, Kimi delta
+  attention), a gated short convolution (``models/short_conv.py``),
+  attention over the keys a learned indexer selects
+  (``ops/sparse_attention.py``) or latent attention (keys and values out of
+  one low-rank latent a token: :class:`LatentAttention`); its feed-forward a
+  SwiGLU or an expert layer (``models/moe.py``), the leading
+  ``ArchSpec.dense_layers`` blocks of an expert model dense: one
+  ``TransformerLM`` runs dense, expert, hybrid (any of the four cheap mixers
+  beside attention) and mixed-window configurations;
 - attention is pluggable: the Pallas flash kernel locally, or ring
   attention over the ``sp`` mesh axis for sequences longer than one
   device's HBM (``edl_tpu.parallel.ring``);
@@ -41,12 +43,17 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from edl_tpu.models.gated_delta import GatedDeltaMixer, GatedDeltaSpec
+from edl_tpu.models.gated_delta import (
+    GatedDeltaMixer,
+    GatedDeltaSpec,
+    KimiDeltaMixer,
+    KimiDeltaSpec,
+)
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
 from edl_tpu.obs import trace as obs_trace
-from edl_tpu.ops.attention import attention
+from edl_tpu.ops.attention import _flash2_blocks, attention
 from edl_tpu.ops.gated_delta import REMAT_NAMES as GDN_NAMES
 from edl_tpu.ops.sparse_attention import REMAT_NAMES as DSA_NAMES
 from edl_tpu.ops.sparse_attention import sparse_attention
@@ -86,6 +93,26 @@ DSA_SCOPES = ("dsa_index", "dsa_select", "attn_sparse", "dsa_target")
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttentionSpec:
+    """The shape of a ``"latent_attention"`` layer (multi-head latent
+    attention, DeepSeek-V2, arXiv:2405.04434 section 2.1, without a query
+    rank): keys and values come out of one ``kv_lora_rank``-wide latent a
+    token, a head's query and key are ``qk_nope_head_dim`` values without a
+    position and ``qk_rope_head_dim`` rotated ones (the keys' rotated part
+    one vector a token, shared by the heads), its value ``v_head_dim``.
+    ``head_gate`` multiplies each head's output by one ``sigmoid(x W_g)``."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    head_gate: bool = False
+
+
+MLA_SCOPES = ("mla_proj", "attn_mla")
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchSpec:
     """What a ``TransformerLM`` does differently from the dense default,
     as one hashable field; every default is the dense model's.
@@ -96,9 +123,14 @@ class ArchSpec:
     ``"mamba"`` (then ``mamba`` gives the layer's shape),
     ``"linear_attention"`` (the gated delta rule; then ``gated_delta`` gives
     the layer's shape), ``"conv"`` (the gated short convolution; then
-    ``short_conv`` gives its taps) or ``"sparse_attention"`` (causal attention
+    ``short_conv`` gives its taps), ``"sparse_attention"`` (causal attention
     over the keys a learned indexer selects for each query; then
-    ``sparse_attention`` gives the indexer's shape); its length is the
+    ``sparse_attention`` gives the indexer's shape), ``"kda"`` (Kimi delta
+    attention, the delta rule with a decay for every key channel; then
+    ``kda`` gives the layer's shape) or ``"latent_attention"`` (causal
+    attention whose keys and values come out of a low-rank latent; then
+    ``latent_attention`` gives its ranks and head sizes, and ``rope_theta``
+    the base of its rotated part whatever ``rope`` says); its length is the
     model's depth. ``rope``
     rotates q and k in every attention layer (``True``), in none (``False``:
     no position term at all) or in the windowed layers only (``"sliding"``:
@@ -124,6 +156,8 @@ class ArchSpec:
     gated_delta: Optional[GatedDeltaSpec] = None
     short_conv: Optional[ShortConvSpec] = None
     sparse_attention: Optional[SparseAttentionSpec] = None
+    kda: Optional[KimiDeltaSpec] = None
+    latent_attention: Optional[LatentAttentionSpec] = None
     head_dim: Optional[int] = None      # None: d_model / num_heads
     rope: Union[bool, str] = True       # True, False or "sliding"
     rope_theta: float = 10000.0         # the rotation's base
@@ -139,7 +173,7 @@ class ArchSpec:
 
 
 LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
-               "conv", "sparse_attention")
+               "conv", "sparse_attention", "kda", "latent_attention")
 
 
 def _scope(name: Optional[str]):
@@ -471,6 +505,84 @@ class Attention(nn.Module):
         return out.reshape(b, t, self.num_heads, head_dim).astype(self.dtype)
 
 
+@lru_cache(maxsize=None)
+def _note_mla_shape(tq: int, heads: int, spec: LatentAttentionSpec):
+    """One ``mla_shape`` instant in the span ring for each shape a latent
+    attention layer is traced at, with the blocks the grid-pipelined kernels
+    take at the two widths."""
+    d_qk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
+    blocks = {
+        kind: list(_flash2_blocks(kind, tq, tq, None))
+        for kind in ("fwd", "bwd")
+    }
+    obs_trace.get_tracer().instant(
+        "mla_shape", tq=tq, heads=heads, d_qk=d_qk, d_v=spec.v_head_dim,
+        latent=spec.kv_lora_rank, rope_dim=spec.qk_rope_head_dim,
+        fwd_blocks=blocks["fwd"], bwd_blocks=blocks["bwd"],
+    )
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention as it trains (DeepSeek-V2,
+    arXiv:2405.04434, equations 9 to 19 with ``q_lora_rank`` null), for the
+    block's input ``x`` ``[B, T, d_model]`` and ``H`` heads::
+
+        q = x W_q                       [T, H, nope + rope], cut into q_n | q_r
+        [c | k_r] = x W_a               kv_lora_rank + rope: the latent and ONE rotated key a token
+        [k_n | v] = RMSNorm(c) W_b      [T, H, nope + v_head_dim]
+        q_r, k_r rotated at rope_theta; k = [k_n | k_r] with k_r shared by the heads
+        o = softmax(q k^T (nope + rope)^-1/2 + causal mask) v         [T, H, v_head_dim]
+        out = W_o (o * sigmoid(x W_g))  one gate a head (``head_gate``)
+
+    It trains as plain multi-head attention with keys of ``nope + rope`` and
+    values of ``v_head_dim``: ``ops.attention.attention`` takes the two
+    widths through the grid-pipelined kernels. Nothing is absorbed into
+    ``W_q`` or ``W_o`` and no latent is cached: there is no decode path.
+    Device scopes ``mla_proj`` (the latent path: the projections, the
+    latent's norm, the rotation, the gate) and ``attn_mla`` (the attention
+    call alone: ``%attn_mla.N`` in a trace). ``q``, ``kv_b`` and ``o`` take
+    ``_heads_dot_general``'s fence behind their weight gradients."""
+
+    num_heads: int
+    spec: LatentAttentionSpec
+    dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+
+    @nn.compact
+    def __call__(self, x, positions):
+        s, h = self.spec, self.num_heads
+        nope, rot, d_v = s.qk_nope_head_dim, s.qk_rope_head_dim, s.v_head_dim
+        _note_mla_shape(x.shape[1], h, s)
+
+        def heads(name: str, **shape):
+            return nn.DenseGeneral(
+                use_bias=False, dtype=self.dtype, name=name,
+                dot_general=partial(_heads_dot_general, name), **shape,
+            )
+
+        flat = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        with jax.named_scope("mla_proj"):
+            q = heads("q", features=(h, nope + rot))(x)
+            latent = flat(s.kv_lora_rank + rot, name="kv_a")(x)
+            c = RMSNorm(self.norm_eps, name="kv_norm")(latent[..., :s.kv_lora_rank])
+            kv = heads("kv_b", features=(h, nope + d_v))(c)
+            q_r = rope(q[..., nope:], positions, self.rope_theta)
+            k_r = rope(latent[..., None, s.kv_lora_rank:], positions, self.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_r, k_r.shape[:2] + (h, rot))], axis=-1
+            )
+            q, k, v = (jnp.swapaxes(m, 1, 2) for m in (q, k, kv[..., nope:]))
+        with jax.named_scope("attn_mla"):
+            out = attention(q, k, v, causal=True, scale=(nope + rot) ** -0.5)
+        with jax.named_scope("mla_proj"):
+            out = jnp.swapaxes(out, 1, 2)
+            if s.head_gate:
+                out = out * nn.sigmoid(flat(h, name="g")(x))[..., None]
+            return heads("o", features=x.shape[-1], axis=(-2, -1))(out)
+
+
 class SwiGLU(nn.Module):
     d_ff: int
     dtype: Any = jnp.bfloat16
@@ -519,6 +631,23 @@ class Block(nn.Module):
             mixed = GatedDeltaMixer(
                 arch.gated_delta, self.dtype, self.norm_eps, name="gdn"
             )(h)
+        elif self.mixer == "kda":
+            if self.decode:
+                raise NotImplementedError(
+                    "a Kimi-delta-attention block has no decode state"
+                )
+            mixed = KimiDeltaMixer(
+                arch.kda, self.dtype, self.norm_eps, name="kda"
+            )(h)
+        elif self.mixer == "latent_attention":
+            if self.decode:
+                raise NotImplementedError(
+                    "a latent-attention block has no decode cache of latents"
+                )
+            mixed = LatentAttention(
+                self.num_heads, arch.latent_attention, self.dtype, self.norm_eps,
+                arch.rope_theta, name="attn",
+            )(h, positions)
         elif self.mixer == "conv":
             if self.decode:
                 raise NotImplementedError(

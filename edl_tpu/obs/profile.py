@@ -354,7 +354,9 @@ def step_scopes(scopes: Sequence[str] = MOE_SCOPES) -> Dict[str, str]:
     gated-delta-rule mixer's ``gdn_proj`` / ``gdn_conv`` / ``gdn_scan`` /
     ``gdn_gate``, the gated short convolution's ``sconv_proj`` /
     ``sconv_conv``, a sparse-attention layer's ``dsa_index`` / ``dsa_select`` /
-    ``attn_sparse`` / ``dsa_target`` and, in a model of windowed and full
+    ``attn_sparse`` / ``dsa_target``, the Kimi-delta-attention mixer's
+    ``kda_proj`` / ``kda_conv`` / ``kda_scan`` / ``kda_gate``, a latent-attention
+    layer's ``mla_proj`` / ``attn_mla`` and, in a model of windowed and full
     attention layers, ``attn_window`` / ``attn_full`` / ``attn_gate``. A caller asks for the
     scopes of one mixer or layer at a time; an instruction under two of the
     asked scopes counts under the innermost. {} without a step or for a

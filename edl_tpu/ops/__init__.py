@@ -12,11 +12,11 @@ long-context attention + distributed compute first-class here.
 
 from edl_tpu.ops.attention import attention, attention_reference, flash_attention
 from edl_tpu.ops.causal_conv import causal_conv_silu, gated_causal_conv
-from edl_tpu.ops.gated_delta import gated_delta_rule
+from edl_tpu.ops.gated_delta import gated_delta_rule, kda_rule
 from edl_tpu.ops.grouped_matmul import grouped_matmul
 from edl_tpu.ops.sparse_attention import sparse_attention
 from edl_tpu.ops.ssd import ssd_scan
 
 __all__ = ["attention", "attention_reference", "causal_conv_silu", "flash_attention",
-           "gated_causal_conv", "gated_delta_rule", "grouped_matmul", "sparse_attention",
-           "ssd_scan"]
+           "gated_causal_conv", "gated_delta_rule", "grouped_matmul", "kda_rule",
+           "sparse_attention", "ssd_scan"]

@@ -123,11 +123,12 @@ def _broadcast_kv(q, k, v):
 
 
 def _fold_dkv(dk, dv, b, h_kv, group, tk, d):
-    """Sum full-q-head-width dk/dv back to the grouped input width."""
+    """Sum full-q-head-width dk/dv back to the grouped input width (``d``:
+    dk's; dv keeps its own where the values are narrower than the keys)."""
     if group == 1:
         return dk, dv
     dk = dk.reshape(b, h_kv, group, tk, d).sum(axis=2)
-    dv = dv.reshape(b, h_kv, group, tk, d).sum(axis=2)
+    dv = dv.reshape(b, h_kv, group, tk, dv.shape[-1]).sum(axis=2)
     return dk, dv
 
 
@@ -577,7 +578,7 @@ def _flash2_forward(
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, d_v = k.shape[2], v.shape[3]  # values may be narrower than the keys
     block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
     if not _spans_fit(block_q, block_k, tq, tk, window, "kv") or (
         causal and tq > tk
@@ -589,12 +590,13 @@ def _flash2_forward(
     g = _gqa_group(q, k)
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * (h // g), tk, d)
-    vf = v.reshape(b * (h // g), tk, d)
+    vf = v.reshape(b * (h // g), tk, d_v)
     (num_k, kv_map), _ = _flash2_maps(
         causal, window, block_q, block_k, tq, tk, g
     )
     _note_tiles("flash2_fwd", tq, tk, block_q, block_k, causal, window, "kv")
     kv_spec = _span_spec(block_k, d, kv_map, window)
+    v_spec = kv_spec if d_v == d else _span_spec(block_k, d_v, kv_map, window)
     grid = (b * h, tq // block_q, num_k)
     kwargs = _grid_pipeline_kwargs()
     kernel = pl.pallas_call(
@@ -610,30 +612,30 @@ def _flash2_forward(
             seq_k=tk,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, tq, 1), jnp.float32),
         ],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
             kv_spec,
-            kv_spec,
+            v_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda i, qi, j: (i, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
             pltpu.VMEM((block_q, _state_lanes(block_k)), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
     )
     with obs_trace.span("kernel_trace", kernel="flash2_fwd"):
         out, lse = kernel(qf, kf, vf)
-    return out.reshape(b, h, tq, d), lse[..., 0]
+    return out.reshape(b, h, tq, d_v), lse[..., 0]
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -934,8 +936,8 @@ def _flash2_backward(
 ):
     """(dq, dk, dv) via the grid-pipelined backward kernels;
     ``lse`` in kernel layout [B*H, Tq] like :func:`_flash_backward`."""
-    b, h, tq, d = q.shape
-    delta = _bwd_delta(g, o, b, h, tq, d)
+    b, h, tq, _ = q.shape
+    delta = _bwd_delta(g, o, b, h, tq, v.shape[3])
     return _flash2_backward_kernels(
         q, k, v, g, lse, delta, causal, scale, block_q, block_k, interpret,
         window, dkv_blocks,
@@ -959,7 +961,7 @@ def _flash2_backward_kernels(
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, d_v = k.shape[2], v.shape[3]  # v, dO and dv at the values' width
     grp = _gqa_group(q, k)
     h_kv = h // grp
     kv_q, kv_k = _fit_blocks(
@@ -968,8 +970,8 @@ def _flash2_backward_kernels(
 
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * h_kv, tk, d)
-    vf = v.reshape(b * h_kv, tk, d)
-    gf = g.reshape(b * h, tq, d)
+    vf = v.reshape(b * h_kv, tk, d_v)
+    gf = g.reshape(b * h, tq, d_v)
     common = dict(causal=causal, scale=scale, q_offset=tk - tq, window=window)
     _, (q_steps, q_map) = _flash2_maps(causal, window, kv_q, kv_k, tq, tk, grp)
     # the kernels that walk rows a kv block: the rows in spans, k and v a
@@ -977,12 +979,20 @@ def _flash2_backward_kernels(
     # width outside (see _flash_backward_kernels)
     rows_spec = _span_spec(kv_q, d, q_map, window)
     kv_block = pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0))
+    if d_v == d:
+        do_spec, v_block = rows_spec, kv_block
+    else:
+        do_spec = _span_spec(kv_q, d_v, q_map, window)
+        v_block = pl.BlockSpec((1, kv_k, d_v), lambda i, ki, j, g=grp: (i // g, ki, 0))
     dkv_shape = [
         jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-        jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
+        jax.ShapeDtypeStruct((b * h, tk, d_v), v.dtype),
     ]
-    dkv_specs = [pl.BlockSpec((1, kv_k, d), lambda i, ki, j: (i, ki, 0))] * 2
-    dkv_scratch = [pltpu.VMEM((kv_k, d), jnp.float32)] * 2
+    dkv_specs = [
+        pl.BlockSpec((1, kv_k, width), lambda i, ki, j: (i, ki, 0))
+        for width in (d, d_v)
+    ]
+    dkv_scratch = [pltpu.VMEM((kv_k, width), jnp.float32) for width in (d, d_v)]
 
     acc, need = _fused_bwd_vmem(tq, d, kv_q, kv_k, q.dtype.itemsize)
     # lse and delta ride the lanes there: whole lane tiles a span (Mosaic's
@@ -1011,7 +1021,7 @@ def _flash2_backward_kernels(
                 jax.ShapeDtypeStruct((b * h, tq, d), q.dtype), *dkv_shape,
             ],
             grid=(b * h, tk // kv_k, q_steps),
-            in_specs=[rows_spec, kv_block, kv_block, rows_spec, lanes, lanes],
+            in_specs=[rows_spec, kv_block, v_block, do_spec, lanes, lanes],
             out_specs=[
                 # a head's whole dq: the block stands still over the head's
                 # steps and is written out when the head changes
@@ -1042,6 +1052,11 @@ def _flash2_backward_kernels(
         _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q")
         kv_spec = _span_spec(block_k, d, kv_map, window)
         q_spec = pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0))
+        if d_v == d:
+            v_spec, dq_do_spec = kv_spec, q_spec
+        else:
+            v_spec = _span_spec(block_k, d_v, kv_map, window)
+            dq_do_spec = pl.BlockSpec((1, block_q, d_v), lambda i, qi, j: (i, qi, 0))
         row_spec = pl.BlockSpec((1, block_q, 1), lambda i, qi, j: (i, qi, 0))
         kernel = pl.pallas_call(
             functools.partial(
@@ -1051,7 +1066,7 @@ def _flash2_backward_kernels(
             ),
             out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
             grid=(b * h, tq // block_q, kv_steps),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, kv_spec, v_spec, dq_do_spec, row_spec, row_spec],
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             interpret=interpret,
@@ -1069,7 +1084,7 @@ def _flash2_backward_kernels(
             out_shape=dkv_shape,
             grid=(b * h, tk // kv_k, q_steps),
             in_specs=[
-                rows_spec, kv_block, kv_block, rows_spec, row_spec, row_spec,
+                rows_spec, kv_block, v_block, do_spec, row_spec, row_spec,
             ],
             out_specs=dkv_specs,
             scratch_shapes=dkv_scratch,
@@ -1080,7 +1095,7 @@ def _flash2_backward_kernels(
             dk, dv = kernel(qf, kf, vf, gf, lse3, delta3)
 
     dk, dv = _fold_dkv(
-        dk.reshape(b, h, tk, d), dv.reshape(b, h, tk, d),
+        dk.reshape(b, h, tk, d), dv.reshape(b, h, tk, d_v),
         b, h_kv, grp, tk, d,
     )
     return dq.reshape(b, h, tq, d), dk, dv
@@ -1516,14 +1531,15 @@ def flash_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     tq, tk, windowed = q.shape[2], k.shape[2], window is not None
-    if not _whole_kv_serves(tq, tk, windowed):
+    two_widths = v.shape[3] != q.shape[3]
+    if not _whole_kv_serves(tq, tk, windowed, two_widths):
         # the same contract through the grid-pipelined kernels, which fill
         # any unspecified block from their own measured defaults
         # (_flash2_blocks)
         blocks = (block_q, block_k)
         return _auto(
-            q, k, v, causal, scale, *_route(tq, tk, windowed), blocks, blocks,
-            window,
+            q, k, v, causal, scale, *_route(tq, tk, windowed, two_widths),
+            blocks, blocks, window,
         )
     if block_q is None or block_k is None:
         (fbq, fbk), _ = _kernel_blocks(tq)
@@ -1545,19 +1561,23 @@ _WHOLE_KV_MAX_SEQ = 4096
 _WHOLE_KV_FWD_MAX_TQ = 2048
 
 
-def _whole_kv_serves(tq: int, tk: int, windowed: bool = False) -> bool:
+def _whole_kv_serves(tq: int, tk: int, windowed: bool = False,
+                     two_widths: bool = False) -> bool:
     """Whether the whole-KV family can take the call at all: it copies
     every key of a head into VMEM before it looks at one, so only flash2's
-    grid can leave a window's blocks out, and past ``_WHOLE_KV_MAX_SEQ`` it
-    does not compile."""
-    return not windowed and max(tq, tk) <= _WHOLE_KV_MAX_SEQ
+    grid can leave a window's blocks out, past ``_WHOLE_KV_MAX_SEQ`` it
+    does not compile, and its blocks have one width for q, k and v
+    (``two_widths``: values narrower than the keys, as latent attention
+    trains; the grid-pipelined kernels take them)."""
+    return not (windowed or two_widths) and max(tq, tk) <= _WHOLE_KV_MAX_SEQ
 
 
-def _route(tq: int, tk: int, windowed: bool) -> tuple[str, str]:
+def _route(tq: int, tk: int, windowed: bool,
+           two_widths: bool = False) -> tuple[str, str]:
     """``(fwd_impl, bwd_impl)`` for a call on the TPU, from what the call
     can observe. The only code that names an implementation: ``"flash"`` is
     the whole-KV family, ``"flash2"`` the grid-pipelined one."""
-    if not _whole_kv_serves(tq, tk, windowed):
+    if not _whole_kv_serves(tq, tk, windowed, two_widths):
         return "flash2", "flash2"
     return ("flash" if tq <= _WHOLE_KV_FWD_MAX_TQ else "flash2"), "flash"
 
@@ -1579,11 +1599,11 @@ def _auto(q, k, v, causal, scale, fwd_impl, bwd_impl,
 def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
               fwd_blocks=None, bwd_blocks=None, window=None):
     if "flash" in (fwd_impl, bwd_impl) and not _whole_kv_serves(
-        q.shape[2], k.shape[2], window is not None
+        q.shape[2], k.shape[2], window is not None, v.shape[3] != q.shape[3]
     ):
         raise ValueError(
-            "the whole-KV flash kernels take no window and no sequence past "
-            "%d" % _WHOLE_KV_MAX_SEQ
+            "the whole-KV flash kernels take no window, no values narrower "
+            "than the keys and no sequence past %d" % _WHOLE_KV_MAX_SEQ
         )
     if fwd_impl == "flash2":
         f2q, f2k = _flash2_blocks(
@@ -1681,7 +1701,9 @@ def attention(
         return attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
         )
-    fwd_impl, bwd_impl = _route(q.shape[2], k.shape[2], window is not None)
+    fwd_impl, bwd_impl = _route(
+        q.shape[2], k.shape[2], window is not None, v.shape[3] != q.shape[3]
+    )
     return _auto(
         q, k, v, causal, scale, fwd_impl, bwd_impl, None, None, window
     )

@@ -55,6 +55,11 @@ matmuls take their operands in ``q``'s dtype with float32 accumulation, as
 nothing overflows however fast a head forgets. Plain ``jax.numpy`` / ``lax``:
 the backward is jax's, but for the inverse's (``unit_lower_inverse``) and the
 carry's (``carried_states``), and the carry's is jax's of one step.
+
+:func:`kda_rule` is the same rule with a decay for every key channel (Kimi
+delta attention): the decay no longer factors out of the products over the key
+channels, so it rides the operands, a sub-block of steps at a time; the solve,
+the carry (its decay then a vector over ``d_k``) and the names are shared.
 """
 
 from __future__ import annotations
@@ -120,9 +125,10 @@ def _carry(state, inputs):
     dtype, dot = w_n.dtype, dict(preferred_element_type=jnp.float32)
     new = u_n - jnp.einsum("bhck,bhkv->bhcv", w_n, state.astype(dtype), **dot)
     new = new.astype(dtype)
-    after = whole_n[..., None, None] * state + jnp.einsum(
-        "bchk,bhcv->bhkv", k_n, new, **dot
-    )
+    # a decay a head ``[b h]``, or one a key channel ``[b h k]`` (``kda_rule``)
+    per_channel = whole_n.ndim == state.ndim - 1
+    decay = whole_n[..., None] if per_channel else whole_n[..., None, None]
+    after = decay * state + jnp.einsum("bchk,bhcv->bhkv", k_n, new, **dot)
     return after, new
 
 
@@ -131,9 +137,10 @@ def carried_states(state, w, u, k_out, whole):
     """The state from chunk to chunk: ``(states, new, final)`` for the initial
     ``state`` ``[b h k v]`` (float32) and, chunks first, ``w`` ``[n b h c k]``,
     ``u`` ``[n b h c v]`` (float32), ``k_out`` ``[n b c h k]``, ``whole`` ``[n b
-    h]``. ``states`` ``[n b h k v]`` are the float32 states the chunks inherit
-    (float32 because the gradient of ``whole`` is ``<dS, S>``), ``new`` ``[n b
-    h c v]`` what they write, ``final`` the state after the last."""
+    h]`` (``kda_rule``: ``[n b h k]``, a decay a key channel). ``states`` ``[n b
+    h k v]`` are the float32 states the chunks inherit (float32 because the
+    gradient of ``whole`` is ``<dS, S>``), ``new`` ``[n b h c v]`` what they
+    write, ``final`` the state after the last."""
 
     def step(state, inputs):
         after, new = _carry(state, inputs)
@@ -282,6 +289,156 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         "bnhcs,bnhsv->bnchv", (scores * between).astype(dtype), new, **dot
     )
     o = (inherited + own).reshape(batch, t + pad, h, d_v)[:, :t].astype(dtype)
+    if return_final_state:
+        return o, state
+    return o
+
+
+# -- a decay for every key channel (Kimi delta attention) --------------------
+
+# Steps that share one reference point of the exponents. A factor lies within
+# e^+-(SUB_BLOCK / 2 * max|g|) and a masked pair's product under the square of
+# it, which float32 holds up to e^88: ``MAX_DECAY_A_STEP`` is the most a caller
+# may let ``|g|`` reach, and ``KimiDeltaMixer`` holds its gate's bound to it.
+SUB_BLOCK = 16
+MAX_DECAY_A_STEP = 88.0 / SUB_BLOCK
+
+
+@functools.lru_cache(maxsize=None)
+def _note_kda_chunks(chunk, sub_block, chunks, heads, d_k, d_v, itemsize, batch):
+    """One ``kda_chunks`` instant in the span ring for each shape
+    :func:`kda_rule` is traced at."""
+    obs_trace.get_tracer().instant(
+        "kda_chunks", chunk=chunk, sub_block=sub_block, chunks=chunks, heads=heads,
+        d_k=d_k, d_v=d_v, state_bytes=4 * heads * d_k * d_v, solve=SOLVE,
+        saved_bytes=saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch),
+    )
+
+
+def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
+             return_final_state: bool = False):
+    """The delta rule with **a decay for every key channel** (Kimi delta
+    attention, arXiv:2510.26692, equation 1): per head, for a log-decay ``g_t``
+    of ``d_k`` values, none positive::
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    ``q``, ``k``, ``g`` ``[B, T, H, d_k]``; ``v`` ``[B, T, H, d_v]``; ``beta``
+    ``[B, T, H]``; returns ``o`` ``[B, T, H, d_v]`` in ``q``'s dtype (and the
+    final state, float32 ``[B, H, d_k, d_v]``, with ``return_final_state``).
+
+    The chunked form is :func:`gated_delta_rule`'s with the decay inside every
+    contraction over the key channels: with ``Gamma`` the running sum of ``g``
+    in a chunk, ``A_ij = beta_i sum_d k_i[d] k_j[d] exp(Gamma_i[d] -
+    Gamma_j[d])`` and the scores likewise with ``q_i``, so ``exp(Gamma_i -
+    Gamma_j)`` no longer factors out as one ``[C, C]`` matrix a head. It
+    factors a channel: ``(k_i o exp(Gamma_i - R)) . (k_j o exp(R - Gamma_j))``
+    for any reference ``R``, and the rows of each **sub-block** of
+    ``SUB_BLOCK`` steps take ``R`` = the running sum at the sub-block's middle
+    step. A row's exponent and, inside the rows' own sub-block, a column's are
+    then within half a sub-block's decay of zero either way; the columns of
+    earlier sub-blocks have none positive, those of later ones are zeros (the
+    mask's). **The caller keeps ``|g|`` under ``MAX_DECAY_A_STEP``** (the KDA
+    layer's safe gate holds ``g`` in (-5, 0): 40 over half a sub-block, so a
+    factor lies in e^+-40 and the product of a masked pair under e^80, inside
+    float32's e^88); no other exponent here is of a positive argument. ``W``,
+    ``U``, the carry
+    (``carried_states``, its decay a vector over ``d_k``) and the outputs are
+    the scalar rule's with ``exp(Gamma)`` a channel; the solve is
+    ``unit_lower_inverse``; what a remat policy saves bears the same names
+    (``REMAT_NAMES``).
+
+    Precision as the scalar rule's: ``g``, its sums, every ``exp``, ``beta``,
+    the system, its inverse and the carried state in float32; each matmul
+    operand rounded once to ``q``'s dtype, float32 accumulation.
+    """
+    batch, t, h, d_k = q.shape
+    d_v = v.shape[-1]
+    if k.shape != q.shape or g.shape != q.shape or v.shape[:3] != q.shape[:3]:
+        raise ValueError(
+            "kda_rule: q %s, k %s, g %s, v %s" % (q.shape, k.shape, g.shape, v.shape)
+        )
+    if beta.shape != q.shape[:3]:
+        raise ValueError("kda_rule: beta %s for %s" % (beta.shape, q.shape[:3]))
+    if chunk < 1 or chunk & (chunk - 1):
+        raise ValueError("kda_rule: chunk %d is not a power of two" % chunk)
+    sub = min(SUB_BLOCK, chunk)  # both powers of two: it divides the chunk
+    pad = -t % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta)
+        )
+    steps = t + pad
+    size, blocks, nc = chunk, chunk // sub, steps // chunk
+    f32, dtype = jnp.float32, q.dtype
+    _note_kda_chunks(size, sub, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch)
+    dot = dict(preferred_element_type=f32)
+
+    # b batch, n chunk, i sub-block, c / s step (in a chunk, or in a sub-block
+    # behind an i), h head, k key width, v value width
+    q = q.reshape(batch, nc, size, h, d_k)
+    k = k.reshape(batch, nc, size, h, d_k)
+    v = v.reshape(batch, nc, size, h, d_v)
+    beta = beta.astype(f32).reshape(batch, nc, size, h)
+    gamma = jnp.cumsum(g.astype(f32).reshape(batch, nc, size, h, d_k), axis=2)
+
+    # a sub-block's reference: the running sum at its middle step
+    in_blocks = lambda a: a.reshape(batch, nc, blocks, sub, *a.shape[3:])  # noqa: E731
+    ref = in_blocks(gamma)[:, :, :, (sub - 1) // 2]             # [b n i h k]
+    rows = jnp.exp(in_blocks(gamma) - ref[:, :, :, None])        # [b n i c h k]
+    # columns up to the end of the rows' own sub-block, zeros past it
+    reach = jnp.arange(size)[None, :] // sub <= jnp.arange(blocks)[:, None]   # [i s]
+    cols = jnp.exp(jnp.where(
+        reach[:, :, None, None], ref[:, :, :, None] - gamma[:, :, None], -jnp.inf
+    ))                                                           # [b n i s h k]
+    k32, q32 = k.astype(f32), q.astype(f32)
+    k_rows = (in_blocks(k32) * rows).astype(dtype)
+    q_rows = (in_blocks(q32) * rows).astype(dtype)
+    k_cols = (k32[:, :, None] * cols).astype(dtype)
+
+    def against_columns(rows):
+        """``rows`` [b n i c h k] against ``k_cols``, a sub-block at a time:
+        [b n h C S], the sub-blocks' rows one after another."""
+        merged = lambda a: a.reshape(batch, nc * blocks, *a.shape[3:])  # noqa: E731
+        tile = jnp.einsum("bmchk,bmshk->bmhcs", merged(rows), merged(k_cols), **dot)
+        tile = tile.reshape(batch, nc, blocks, h, sub, size)
+        return jnp.moveaxis(tile, 2, 3).reshape(batch, nc, h, size, size)
+
+    kk, scores = against_columns(k_rows), against_columns(q_rows)
+
+    # inside a chunk: the system, its inverse, and what it makes of K and V
+    lower = jnp.tril(jnp.ones((size, size), bool))
+    beta_h = jnp.moveaxis(beta, 2, -1)                           # [b n h c]
+    system = jnp.where(jnp.tril(lower, -1), beta_h[..., None] * kk, 0.0)
+    inverse = unit_lower_inverse(system).astype(dtype)
+    k_in = (k32 * (beta[..., None] * jnp.exp(gamma))).astype(dtype)
+    v_in = (v.astype(f32) * beta[..., None]).astype(dtype)
+    w = jnp.einsum("bnhcs,bnshk->bnhck", inverse, k_in, **dot).astype(dtype)
+    u = jnp.einsum("bnhcs,bnshv->bnhcv", inverse, v_in, **dot)
+    k_out = (k32 * jnp.exp(gamma[:, :, -1:] - gamma)).astype(dtype)
+    whole = jnp.exp(gamma[:, :, -1])                             # [b n h k]
+
+    # from chunk to chunk, the state in float32
+    if initial_state is None:
+        state = jnp.zeros((batch, h, d_k, d_v), f32)
+    else:
+        state = initial_state.astype(f32)
+    chunks_first = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
+    states, new, state = carried_states(
+        state, *(chunks_first(a) for a in (w, u, k_out, whole))
+    )
+    entering = jnp.moveaxis(states.astype(dtype), 0, 1)          # [b n h k v]
+    new = jnp.moveaxis(new, 0, 1)                                # [b n h c v]
+
+    # every chunk's outputs: what it inherits, and what it wrote itself
+    q_in = (q32 * jnp.exp(gamma)).astype(dtype)
+    inherited = jnp.einsum("bnchk,bnhkv->bnchv", q_in, entering, **dot)
+    own = jnp.einsum(
+        "bnhcs,bnhsv->bnchv", jnp.where(lower, scores, 0.0).astype(dtype), new, **dot
+    )
+    o = (inherited + own).reshape(batch, steps, h, d_v)[:, :t].astype(dtype)
     if return_final_state:
         return o, state
     return o

@@ -45,7 +45,12 @@ from edl_tpu.obs import trace as obs_trace
 # tiles (128) that divides the dimension and is at most this one's, so that no
 # tile is ragged: at an expert width of 1536, 768 (1024 there is one tile and
 # a masked half, a third of the kernel's work wasted; PERF.md section 6, PR 37,
-# has the probe's numbers for 768 against 512).
+# has the probe's numbers for 768 against 512). The row tile is of ALL the
+# groups' rows together (``m``), not of one group's: a tile that spans a
+# group's end is walked once for each group in it, so where groups are small
+# beside 512 rows (``ling_3_0_flash_vl.steady``: 128 rows a held expert
+# expected, four groups a tile) most of a tile's rows are masked for each of
+# them; not tuned here (PERF.md section 7).
 TILING = (512, 1024, 1024)
 _LANES = 128
 

@@ -1,0 +1,500 @@
+"""Ling-3.0-flash's three mechanisms at toy sizes on the CPU (the whole model is
+in ``tests/test_kda_model.py``): the chunked Kimi
+delta rule (a decay for every key channel) against the step-by-step recurrence,
+the grid-pipelined flash kernels at two widths (latent attention trains as keys
+of 192 and values of 128) against dense float32 attention, the grouped choice of
+experts against a plain top-k of groups; then the mixers, the shares of an expert
+layer and the whole model against ``benchmark/reference/kda_lm.py``, which
+imports nothing from ``edl_tpu.models``.
+"""
+
+import importlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.families import kda_lm as family
+from benchmark.reference import kda_lm as reference
+from edl_tpu.models import (
+    KimiDeltaMixer,
+    LatentAttention,
+    MoESpec,
+)
+from edl_tpu.models.moe import DroplessMoE
+from edl_tpu.obs import trace as obs_trace
+from edl_tpu.ops import gated_delta_rule, kda_rule
+
+A = importlib.import_module("edl_tpu.ops.attention")
+G = importlib.import_module("edl_tpu.ops.gated_delta")
+T = importlib.import_module("edl_tpu.models.transformer")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+with open(os.path.join(ROOT, "benchmark", "rehearsal", "configs", "ling_3_0_flash_vl.json")) as f:
+    TOY = json.load(f)
+D = TOY["hidden_size"]
+
+
+def _close(got, want, tol=2e-4):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.isfinite(got).all()
+    scale = max(np.max(np.abs(want)), 1e-12)
+    assert np.max(np.abs(got - want)) / scale <= tol
+
+
+def shaken(params, seed=7):
+    """Every vector (a norm's scale, a decay's bias) off its start, so that a
+    misplaced one shows."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 400))
+    return jax.tree.map(
+        lambda a: a * (1 + 0.2 * jax.random.normal(next(keys), a.shape)) if a.ndim <= 2 and a.size < 4096 else a,
+        params,
+    )
+
+
+# -- the rule --------------------------------------------------------------------
+
+
+def rule_inputs(seed=0, b=2, t=96, h=3, d_k=8, d_v=16, low=-5.0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda m: m / jnp.linalg.norm(m, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], (b, t, h, d_k))) * d_k ** -0.5
+    k = unit(jax.random.normal(keys[1], (b, t, h, d_k)))
+    v = jax.random.normal(keys[2], (b, t, h, d_v))
+    g = low * jax.nn.sigmoid(2.0 * jax.random.normal(keys[3], (b, t, h, d_k)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, h)))
+    return q, k, v, g, beta
+
+
+def at_the_bound(args):
+    """Steps 16..63 of every channel at the safe gate's bound: three whole
+    sub-blocks whose exponents reach e^+-80."""
+    q, k, v, g, beta = args
+    steps = (jnp.arange(g.shape[1]) >= 16) & (jnp.arange(g.shape[1]) < 64)
+    return q, k, v, jnp.where(steps[None, :, None, None], -5.0 + 1e-4, g), beta
+
+
+def repeated_keys(args):
+    """One key for every step of a head: the system's entries near beta."""
+    q, k, v, g, beta = args
+    return q, jnp.broadcast_to(k[:, :1], k.shape), v, g, beta
+
+
+CASES = {"drawn": lambda a: a, "at_the_bound": at_the_bound, "repeated_keys": repeated_keys}
+chunked = lambda *a: kda_rule(*a, chunk=32, return_final_state=True)  # noqa: E731
+
+
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("what", ["value", "q", "k", "v", "g", "beta"])
+def test_the_chunked_rule_equals_the_step_by_step_recurrence(case, what):
+    args = CASES[case](rule_inputs())
+    with jax.default_matmul_precision("highest"):
+        if what == "value":
+            (o, state), (want_o, want_state) = chunked(*args), reference.recurrence(*args)
+            assert o.shape == (2, 96, 3, 16) and state.shape == (2, 3, 8, 16)
+            _close(o, want_o, tol=1e-4)
+            _close(state, want_state, tol=1e-4)
+            return
+        weigh = lambda f: lambda *a: (  # noqa: E731 — the output and the state both
+            jnp.sum(jnp.sin(f(*a)[0])) + jnp.sum(f(*a)[1] ** 2)
+        )
+        leaf = ("q", "k", "v", "g", "beta").index(what)
+        got = jax.grad(weigh(chunked), argnums=leaf)(*args)
+        want = jax.grad(weigh(reference.recurrence), argnums=leaf)(*args)
+    # at the bound a sub-block's factors are e^+-80: float32 keeps 1e-7 of each
+    _close(got, want, tol=2e-3 if case == "at_the_bound" else 2e-4)
+
+
+@pytest.mark.parametrize("chunk,sub_block", [(16, 16), (64, 16), (32, 8), (8, 16)])
+def test_the_result_does_not_depend_on_the_chunk_or_the_sub_block(chunk, sub_block, monkeypatch):
+    monkeypatch.setattr(G, "SUB_BLOCK", sub_block)
+    args = rule_inputs(seed=1, t=70)  # a length no chunk divides: padded steps leave the state
+    with jax.default_matmul_precision("highest"):
+        o, state = kda_rule(*args, chunk=chunk, return_final_state=True)
+        want_o, want_state = reference.recurrence(*args)
+    _close(o, want_o, tol=1e-4)
+    _close(state, want_state, tol=1e-4)
+
+
+def test_with_every_channels_decay_equal_it_is_the_scalar_rule():
+    q, k, v, g, beta = rule_inputs(seed=2)
+    one = g[..., :1]
+    with jax.default_matmul_precision("highest"):
+        got = kda_rule(q, k, v, jnp.broadcast_to(one, g.shape), beta, chunk=32)
+        want = gated_delta_rule(q, k, v, one[..., 0], beta, chunk=32)
+    _close(got, want, tol=1e-5)
+
+
+def test_the_rule_carries_an_initial_state_and_rounds_its_operands_once():
+    q, k, v, g, beta = rule_inputs(seed=3)
+    with jax.default_matmul_precision("highest"):
+        _, half = chunked(*(a[:, :64] for a in (q, k, v, g, beta)))
+        rest = kda_rule(*(a[:, 64:] for a in (q, k, v, g, beta)), chunk=32, initial_state=half)
+        whole = kda_rule(q, k, v, g, beta, chunk=32)
+    _close(rest, whole[:, 64:], tol=1e-5)
+    bf16 = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
+    # jitted: the CPU's op-by-op dot takes no bfloat16 operands
+    low = jax.jit(lambda *a: kda_rule(*a, chunk=32))(bf16(q), bf16(k), bf16(v), g, beta)
+    assert low.dtype == jnp.bfloat16
+    _close(low.astype(jnp.float32), whole, tol=0.03)
+
+
+@pytest.mark.parametrize("bad", ["g_shape", "beta_shape", "chunk", "v_shape"])
+def test_the_rule_refuses_shapes_it_cannot_take(bad):
+    q, k, v, g, beta = rule_inputs(t=32)
+    kwargs = {}
+    if bad == "g_shape":
+        g = g[..., 0]
+    elif bad == "beta_shape":
+        beta = beta[..., None]
+    elif bad == "chunk":
+        kwargs["chunk"] = 24
+    else:
+        v = v[:, :-1]
+    with pytest.raises(ValueError, match="kda_rule"):
+        kda_rule(q, k, v, g, beta, **kwargs)
+
+
+def test_the_scalar_rules_carry_is_what_it_was():
+    """``_carry`` tells a decay a head from a decay a channel by its rank: the
+    scalar rule's callers see the product they saw."""
+    state = jnp.ones((1, 2, 4, 3))
+    inputs = (jnp.zeros((1, 2, 5, 4)), jnp.zeros((1, 2, 5, 3)), jnp.zeros((1, 5, 2, 4)))
+    a_head, _ = G._carry(state, (*inputs, jnp.full((1, 2), 0.5)))
+    a_channel, _ = G._carry(state, (*inputs, jnp.full((1, 2, 4), 0.5).at[..., 0].set(0.25)))
+    assert float(a_head[0, 0, 0, 0]) == 0.5 and float(a_channel[0, 0, 0, 0]) == 0.25
+    assert float(a_channel[0, 0, 1, 0]) == 0.5
+
+
+def test_each_traced_shape_leaves_one_kda_chunks_instant():
+    G._note_kda_chunks.cache_clear()
+    tracer = obs_trace.get_tracer()
+    before = len([e for e in tracer.to_events() if e["name"] == "kda_chunks"])
+    args = rule_inputs(t=64)
+    for _ in range(2):
+        jax.jit(lambda *a: kda_rule(*a, chunk=32)).lower(*args)
+    found = [e["args"] for e in tracer.to_events() if e["name"] == "kda_chunks"][before:]
+    assert len(found) == 1
+    assert (found[0]["chunk"], found[0]["sub_block"], found[0]["heads"]) == (32, 16, 3)
+    assert (found[0]["d_k"], found[0]["d_v"]) == (8, 16)
+    assert found[0]["state_bytes"] == 4 * 3 * 8 * 16
+    assert found[0]["saved_bytes"] == G.saved_bytes(32, 2, 3, 8, 16, 4, 2)
+
+
+# -- the flash kernels at two widths ---------------------------------------------
+
+
+def two_width_inputs(b=1, h=2, h_kv=2, t=256, d=48, d_v=32, seed=1):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (
+        jax.random.normal(keys[0], (b, h, t, d)), jax.random.normal(keys[1], (b, h_kv, t, d)),
+        jax.random.normal(keys[2], (b, h_kv, t, d_v)), jax.random.normal(keys[3], (b, h, t, d_v)),
+    )
+
+
+@pytest.mark.parametrize("h_kv", [2, 1], ids=["mha", "gqa"])
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "dq_and_dkv"])
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+def test_flash2_at_two_widths_equals_dense_attention(monkeypatch, what, fused, h_kv):
+    q, k, v, w = two_width_inputs(h_kv=h_kv)
+    if not fused:
+        monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
+    scale = q.shape[-1] ** -0.5
+    kernels = lambda q, k, v: A._auto(  # noqa: E731
+        q, k, v, True, scale, "flash2", "flash2", (64, 128), (128, 64), None
+    )
+    dense = lambda q, k, v: A.attention_reference(q, k, v, causal=True, scale=scale)  # noqa: E731
+    (got, vjp), (want, ref_vjp) = jax.vjp(kernels, q, k, v), jax.vjp(dense, q, k, v)
+    assert got.shape == (1, 2, 256, 32)
+    if what == "out":
+        _close(got, want, tol=1e-5)
+        return
+    index = ("dq", "dk", "dv").index(what)
+    assert vjp(w)[index].shape == (q, k, v)[index].shape
+    _close(vjp(w)[index], ref_vjp(w)[index], tol=1e-5)
+
+
+def test_a_two_width_call_never_reaches_the_whole_kv_kernels():
+    for t in (256, 2048, 4096, 8192):
+        assert A._route(t, t, False, True) == ("flash2", "flash2")
+        assert not A._whole_kv_serves(t, t, False, True)
+    assert A._route(2048, 2048, False) == A._route(2048, 2048, False, False) == ("flash", "flash")
+    q, k, v, _ = two_width_inputs()
+    assert A.flash_attention(q, k, v, causal=True).shape == (1, 2, 256, 32)
+    with pytest.raises(ValueError, match="narrower"):
+        A._auto(q, k, v, True, 1.0, "flash", "flash", None, None, None)
+
+
+# -- the grouped choice ------------------------------------------------------------
+
+E, K, F, GROUPS, KEPT = 64, 8, 24, 8, 4
+LAYER = dict(
+    TOY, num_experts=E, num_experts_per_tok=K, moe_intermediate_size=F,
+    moe_shared_expert_intermediate_size=F, n_group=GROUPS, topk_group=KEPT,
+    share={"router_experts": E, "experts_first": 0},
+)
+
+
+def _layer(held, **overrides):
+    fields = dict(
+        num_experts=E, top_k=K, d_ff=F, norm_topk_prob=True, aux_weight=0.0,
+        z_weight=0.0, score_func="sigmoid", route_scale=2.5, bias_rate=1e-3,
+        shared_d_ff=F, held=held, n_group=GROUPS, topk_group=KEPT, dtype=jnp.float32,
+    )
+    return DroplessMoE(**dict(fields, **overrides))
+
+
+@pytest.fixture(scope="module")
+def whole_layer():
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, D), jnp.float32)
+    variables = _layer(None).init(jax.random.PRNGKey(2), x)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (E,), jnp.float32)
+    return x, variables["params"], {"router_bias": bias - jnp.mean(bias)}
+
+
+def test_the_grouped_choice_is_a_plain_top_k_of_groups(whole_layer):
+    """Written out with numpy: a group's score is the sum of its two best ``s +
+    b``; the best 4 of 8 groups are kept; the 8 best ``s + b`` inside them are
+    the token's experts; the gauge counts the groups they touch."""
+    x, params, stats = whole_layer
+    _, sown = _layer(None).apply(
+        {"params": params, "batch_stats": stats}, x, mutable=["intermediates", "metrics"]
+    )
+    top_idx = np.asarray(sown["intermediates"]["top_idx"][0])
+    logits = np.asarray(sown["intermediates"]["router_logits"][0], np.float64)
+    choice = 1 / (1 + np.exp(-logits)) + np.asarray(stats["router_bias"], np.float64)
+    for n in range(choice.shape[0]):
+        by_group = choice[n].reshape(GROUPS, E // GROUPS)
+        score = np.sort(by_group, axis=-1)[:, -2:].sum(axis=-1)
+        kept = np.argsort(-score)[:KEPT]
+        inside = np.full(E, -np.inf)
+        for group in kept:
+            members = slice(group * (E // GROUPS), (group + 1) * (E // GROUPS))
+            inside[members] = choice[n, members]
+        assert sorted(top_idx[n]) == sorted(np.argsort(-inside)[:K])
+        assert set(top_idx[n] // (E // GROUPS)) <= set(kept)
+    touched = np.mean([len(set(row // (E // GROUPS))) for row in top_idx])
+    assert float(sown["metrics"]["moe_groups_live"][0]) == pytest.approx(touched)
+    assert touched <= KEPT
+    # and the reference's own choice is the same one
+    _, experts, margin, _ = reference.route(LAYER, jnp.asarray(logits, jnp.float32), stats["router_bias"])
+    settled = np.asarray(margin) > 1e-5
+    assert (np.sort(np.asarray(experts), -1) == np.sort(top_idx, -1))[settled].all()
+
+
+@pytest.mark.parametrize("bias_rate", [1e-3, 0.0], ids=["bias", "no_bias"])
+def test_with_one_group_it_is_the_ungrouped_layer_bit_for_bit(whole_layer, bias_rate):
+    x, params, stats = whole_layer
+    variables = {"params": params, **({"batch_stats": stats} if bias_rate else {})}
+    grouped = _layer(None, n_group=1, topk_group=1, bias_rate=bias_rate)
+    plain = DroplessMoE(
+        num_experts=E, top_k=K, d_ff=F, norm_topk_prob=True, aux_weight=0.0, z_weight=0.0,
+        score_func="sigmoid", route_scale=2.5, bias_rate=bias_rate, shared_d_ff=F,
+        dtype=jnp.float32,
+    )
+    a, sown = grouped.apply(variables, x, mutable=["metrics"])
+    b = plain.apply(variables, x, mutable=["metrics"])[0]
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert "moe_groups_live" not in sown["metrics"]
+    lower = lambda layer: jax.jit(  # noqa: E731
+        lambda v, x: layer.apply(v, x, mutable=["metrics"])[0]
+    ).lower(variables, x).as_text()
+    assert lower(grouped) == lower(plain)
+    assert MoESpec(8, 2, 16).n_group == 1 and MoESpec(8, 2, 16).topk_group == 1
+
+
+@pytest.mark.parametrize("fields", [
+    dict(n_group=5), dict(n_group=8, topk_group=9), dict(n_group=32, topk_group=2),
+    dict(n_group=8, topk_group=1, top_k=16),
+])
+def test_groups_that_cannot_hold_the_choice_are_refused(whole_layer, fields):
+    x, params, stats = whole_layer
+    with pytest.raises(ValueError, match="groups"):
+        _layer(None, **fields).apply({"params": params, "batch_stats": stats}, x)
+
+
+@pytest.mark.parametrize("what", ["value", "gradients"])
+def test_the_expert_layer_equals_a_dense_loop(whole_layer, what):
+    x, params, stats = whole_layer
+
+    def program(p, x):
+        return _layer(None).apply({"params": p, "batch_stats": stats}, x)
+
+    def plain(p, x):
+        return reference.mixture(LAYER, p, stats["router_bias"], x.reshape(-1, D))[0].reshape(x.shape)
+
+    with jax.default_matmul_precision("highest"):
+        if what == "value":
+            _close(program(params, x), plain(params, x), tol=1e-5)
+            return
+        weight = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+        got, want = (
+            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(params, x)
+            for f in (program, plain)
+        )
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        _close(a, b, tol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "sizes", [(8,) * 8, (16,) * 4, (24, 40), (64,)], ids=lambda s: "x".join(map(str, s)),
+)
+def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_uncut_layer(whole_layer, sizes):
+    """Every chip routes over all E experts in their groups and computes what
+    its own give; the shared expert is on every chip alike, so it counts once:
+    the sum of the shares' ROUTED parts plus the shared expert's output is the
+    uncut layer of the reference; and the reference given a share agrees chip
+    by chip."""
+    x, params, stats = whole_layer
+    tokens = x.reshape(-1, D)
+    with jax.default_matmul_precision("highest"):
+        uncut, _ = reference.mixture(LAYER, params, stats["router_bias"], tokens)
+        shared = reference.swiglu(params["shared"], tokens)
+        total, first = shared, 0
+        for count in sizes:
+            here = dict(params, **{
+                bank: params[bank][first:first + count] for bank in ("gate", "up", "down")
+            })
+            part, sown = _layer((first, count)).apply(
+                {"params": here, "batch_stats": stats}, x, mutable=["metrics"]
+            )
+            assert float(sown["metrics"]["moe_rows_dropped"][0]) == 0
+            want, _ = reference.mixture(
+                dict(LAYER, num_experts=count,
+                     share={"router_experts": E, "experts_first": first}),
+                here, stats["router_bias"], tokens,
+            )
+            _close(part.reshape(-1, D), want, tol=1e-5)
+            total, first = total + (part.reshape(-1, D) - shared), first + count
+    _close(total, uncut, tol=1e-5)
+
+
+# -- the mixers --------------------------------------------------------------------
+
+SPEC = family.kda_spec(TOY)
+MLA = family.latent_spec(TOY)
+
+
+@pytest.fixture(scope="module")
+def kda_mixer():
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, D), jnp.float32)
+    layer = KimiDeltaMixer(SPEC, jnp.float32, TOY["rms_norm_eps"])
+    return layer, shaken(layer.init(jax.random.PRNGKey(6), x)["params"]), x
+
+
+def test_the_kda_mixer_holds_the_published_parameters(kda_mixer):
+    _, params, _ = kda_mixer
+    h, d = TOY["num_attention_heads"], TOY["head_dim"]
+    assert {k: v["kernel"].shape for k, v in params.items() if k.endswith("_proj")} == {
+        "q_proj": (D, h * d), "k_proj": (D, h * d), "v_proj": (D, h * d),
+        "f_proj": (D, h * d), "g_proj": (D, h * d), "b_proj": (D, h), "o_proj": (h * d, D),
+    }
+    assert params["q_conv"].shape == params["k_conv"].shape == params["v_conv"].shape == (4, h * d)
+    assert params["A_log"].shape == (h,) and params["dt_bias"].shape == (h, d)
+    assert params["norm"].shape == (d,)
+    counted = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+    toy = dict(TOY, hidden_size=D)
+    assert counted == family.kda_mixer_params(toy) + 3 * 4 * h * d + h + h * d + d
+
+
+@pytest.mark.parametrize("what", ["value", "gradients", "inputs"])
+def test_the_kda_mixer_equals_the_reference(kda_mixer, what):
+    layer, params, x = kda_mixer
+    program = lambda p, x: layer.apply({"params": p}, x)  # noqa: E731
+    plain = lambda p, x: reference.kda_mixer(TOY, p, x)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        if what == "value":
+            _close(program(params, x), plain(params, x), tol=1e-4)
+            return
+        if what == "inputs":
+            _, sown = layer.apply({"params": params}, x, mutable=["intermediates", "metrics"])
+            want = reference.rule_inputs(TOY, params, x)
+            for a, b in zip(sown["intermediates"]["rule_inputs"][0], want):
+                _close(a, b, tol=1e-5)
+            g = sown["intermediates"]["rule_inputs"][0][3]
+            assert g.shape == (2, 64, 4, 16) and -5.0 < float(jnp.min(g)) and float(jnp.max(g)) <= 0.0
+            assert float(sown["metrics"]["kda_decay_mean"][0]) == pytest.approx(
+                float(jnp.mean(jnp.exp(want[3]))), rel=1e-5
+            )
+            assert float(sown["metrics"]["kda_state_absmax"][0]) > 0
+            return
+        weight = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+        got, want = (
+            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(params, x)
+            for f in (program, plain)
+        )
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
+        _close(a, b, tol=1e-3)
+
+
+def test_a_future_step_never_reaches_an_earlier_output(kda_mixer):
+    layer, params, x = kda_mixer
+    later = x.at[:, 40:].add(1.0)
+    a, b = layer.apply({"params": params}, x), layer.apply({"params": params}, later)
+    assert np.array_equal(np.asarray(a[:, :40]), np.asarray(b[:, :40]))
+    assert float(jnp.max(jnp.abs(a[:, 40:] - b[:, 40:]))) > 1e-3
+
+
+@pytest.fixture(scope="module")
+def mla_layer():
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 48, D), jnp.float32)
+    layer = LatentAttention(4, MLA, jnp.float32, TOY["rms_norm_eps"], float(TOY["rope_theta"]))
+    positions = jnp.broadcast_to(jnp.arange(48)[None], (2, 48))
+    return layer, shaken(layer.init(jax.random.PRNGKey(10), x, positions)["params"]), x, positions
+
+
+def test_the_latent_layer_holds_the_published_parameters(mla_layer):
+    _, params, _, _ = mla_layer
+    assert {k: v["kernel"].shape for k, v in params.items() if "kernel" in v} == {
+        "q": (D, 4, 24), "kv_a": (D, 32 + 8), "kv_b": (32, 4, 16 + 16), "g": (D, 4),
+        "o": (4, 16, D),
+    }
+    assert params["kv_norm"]["scale"].shape == (32,)
+    counted = sum(int(np.prod(v["kernel"].shape)) for v in params.values() if "kernel" in v)
+    assert counted == family.mla_mixer_params(TOY)
+
+
+@pytest.mark.parametrize("what", ["value", "gradients"])
+def test_the_latent_layer_equals_the_reference(mla_layer, what):
+    layer, params, x, positions = mla_layer
+    program = lambda p, x: layer.apply({"params": p}, x, positions)  # noqa: E731
+    plain = lambda p, x: reference.mla_mixer(TOY, p, x)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        if what == "value":
+            _close(program(params, x), plain(params, x), tol=1e-5)
+            return
+        weight = jax.random.normal(jax.random.PRNGKey(11), x.shape)
+        got, want = (
+            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(params, x)
+            for f in (program, plain)
+        )
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
+        _close(a, b, tol=1e-4)
+
+
+def test_the_latent_layers_rotation_is_at_the_specs_base_and_shared_by_the_heads(mla_layer):
+    layer, params, x, positions = mla_layer
+    other = layer.clone(rope_theta=10000.0)
+    a, b = layer.apply({"params": params}, x, positions), other.apply({"params": params}, x, positions)
+    assert float(jnp.max(jnp.abs(a - b))) > 1e-4
+    shifted = layer.apply({"params": params}, x, positions + 7)  # relative positions only
+    _close(shifted, a, tol=1e-4)
+
+
+def test_each_traced_shape_leaves_one_mla_shape_instant(mla_layer):
+    layer, params, x, positions = mla_layer
+    T._note_mla_shape.cache_clear()
+    tracer = obs_trace.get_tracer()
+    before = len([e for e in tracer.to_events() if e["name"] == "mla_shape"])
+    for _ in range(2):
+        jax.jit(lambda p, x: layer.apply({"params": p}, x, positions)).lower(params, x)
+    found = [e["args"] for e in tracer.to_events() if e["name"] == "mla_shape"][before:]
+    assert len(found) == 1
+    assert (found[0]["tq"], found[0]["heads"], found[0]["d_qk"], found[0]["d_v"]) == (48, 4, 24, 16)
+    assert found[0]["latent"] == 32 and found[0]["rope_dim"] == 8
+    assert found[0]["fwd_blocks"] == list(A._flash2_blocks("fwd", 48, 48, None))

@@ -793,3 +793,142 @@ def test_the_sparse_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorde
     assert doc["parameters"] == recorded["parameters"]
     assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
     assert doc["tpu_custom_calls"] == 30 * depth
+
+
+# -- ling_3_0_flash_vl.steady: two-width flash2, the per-channel delta rule --
+
+MLA = (1, 16, 8192, 192, 128)  # the latent layer as it trains: b, h, t, d_qk, d_v
+
+
+@pytest.mark.parametrize("heads", [16, 32], ids=["held", "published"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash2_compiles_for_v5e_at_keys_of_192_and_values_of_128(one_chip, direction, heads):
+    """The grid-pipelined forward and the fused backward with q and k of 192
+    and v, dO, o and dv of 128 (multi-head latent attention as it trains), at
+    the blocks ``_auto`` gives the call: the kernels themselves lower, and the
+    chip's compiler takes their blocks and the fused backward's VMEM."""
+    b, _, t, d_qk, d_v = MLA
+    scale = d_qk ** -0.5
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, v = sds((b, heads, t, d_qk)), sds((b, heads, t, d_v))
+    assert A._route(t, t, False, True) == ("flash2", "flash2")
+    if direction == "fwd":
+        bq, bk = A._flash2_blocks("fwd", t, t, None)
+        fn = lambda q, k, v: A._flash2_forward(q, k, v, True, scale, bq, bk, False)
+        args, want = (q, q, v), ["_flash2_kernel"]
+    else:
+        bq, bk = A._flash2_blocks("bwd", t, t, None)
+        fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
+            q, k, v, g, lse, delta, True, scale, bq, bk, False
+        )
+        row = sds((b * heads, t), jnp.float32)
+        args, want = (q, q, v, v, row, row), ["_flash2_bwd_kernel"]
+    lowered = jax.jit(fn).lower(*args)
+    assert _kernel_names(lowered.as_text()) == want
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, *args))]
+    if direction == "fwd":
+        assert shapes[0] == (b, heads, t, d_v)
+    else:
+        assert shapes == [(b, heads, t, d_qk), (b, heads, t, d_qk), (b, heads, t, d_v)]
+
+
+def test_kda_rule_compiles_for_v5e_at_the_cells_widths(one_chip):
+    """The per-channel rule with its backward at one sequence of 8192, the 16
+    heads of 128 / 128 the cell holds, chunks of 64 in sub-blocks of 16: plain
+    XLA, so what the chip's compiler can refuse is the memory. The decayed
+    columns are four float32 copies of the keys (268 MB at 16 heads) and their
+    cotangent as many again; value and gradients together stay under 3 GB of
+    the 6 the step has beside its state."""
+    from edl_tpu.ops import kda_rule
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, h, d = 8192, 16, 128
+    args = (sds((1, t, h, d)), sds((1, t, h, d)), sds((1, t, h, d)),
+            sds((1, t, h, d), jnp.float32), sds((1, t, h), jnp.float32))
+
+    def value_and_grads(w, *a):
+        out, vjp = jax.vjp(lambda *a: kda_rule(*a, chunk=64), *a)
+        return (out, *vjp(w))
+
+    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, d)), *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def _kda_cell():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "ling_3_0_flash_vl.json")) as f:
+        return root, json.load(f)
+
+
+def test_the_kda_cells_cut_is_the_one_its_plan_chose():
+    """The rule, on the numbers the file records: the deeper of 7 and 6 layers
+    at all 32 heads whose compiled step leaves at least 1 GB of the chip's
+    15.75; neither does, so depth 6 as one of two chips' share of the heads
+    (16 of 32), whose plan has to leave that much (the slow case below compiles
+    them again). The tool's total counts more than the chip reserves, so the
+    file also records what the chip itself left at depth 6 (``on_chip``): with
+    all the heads the step runs, but with less than 1 GB left and not beside a
+    ballast of 1 GiB; with 16 it leaves the room the rule asks for."""
+    _, config = _kda_cell()
+    plan = config["plan"]
+    left = lambda tried: plan["chip_gb"] - tried["total_gb"]  # noqa: E731
+    whole = [t for t in plan["tried"] if t["num_attention_heads"] == 32]
+    assert sorted(t["num_hidden_layers"] for t in whole) == [6, 7]
+    assert all(left(t) < plan["least_left_gb"] for t in whole)
+    chosen = next(t for t in plan["tried"] if t["num_attention_heads"] == 16)
+    assert left(chosen) >= plan["least_left_gb"]
+    whole_on_chip = next(t for t in whole if t["num_hidden_layers"] == 6)["on_chip"]
+    assert whole_on_chip["ran"] and whole_on_chip["left_gb"] < plan["least_left_gb"]
+    assert "RESOURCE_EXHAUSTED" in whole_on_chip["beside_a_ballast_of_1_gib"]
+    for on_chip in (whole_on_chip, chosen["on_chip"]):      # the chip's own arithmetic
+        reserved = on_chip["state_gb"] + on_chip["program_reserve_gib"] * 2 ** 30 / 1e9
+        assert on_chip["left_gb"] == pytest.approx(on_chip["bytes_limit"] / 1e9 - reserved, abs=0.01)
+    assert chosen["on_chip"]["left_gb"] >= plan["least_left_gb"]
+    assert plan["chosen"] == {"num_hidden_layers": 6, "num_attention_heads": 16}
+    assert (config["num_hidden_layers"], config["num_attention_heads"]) == (6, 16)
+    assert config["published"]["num_attention_heads"] == 32
+    assert config["published"]["num_hidden_layers"] == 42
+    for tried in plan["tried"]:
+        assert tried["left_gb"] == pytest.approx(left(tried), abs=2e-3)
+    # a whole period at the published five to one, as layers 1 to 6 lie
+    kinds = config["layer_types"]
+    assert kinds == config["published"]["layer_types"][1:7]
+    assert kinds.count("full_attention") == 1 and len(kinds) == config["layer_group_size"]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("heads", [32, 16])
+def test_the_kda_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(heads):
+    """``benchmark/tools/compile_for_v5e.py`` on the cell at depth 6 with all
+    the heads and with the share the rule chose (3 to 5 minutes each): the step
+    compiles, and the plan's total is what the configuration's file records,
+    to 0.1 GB."""
+    import json
+    import subprocess
+    import sys
+
+    root, config = _kda_cell()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "tools", "compile_for_v5e.py"),
+         "ling_3_0_flash_vl.steady", "num_attention_heads=%d" % heads,
+         "num_key_value_heads=%d" % heads],
+        capture_output=True, text=True, timeout=1500, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    doc = json.loads(out.stdout.strip().splitlines()[-1])
+    recorded = next(
+        t for t in config["plan"]["tried"]
+        if (t["num_hidden_layers"], t["num_attention_heads"]) == (6, heads)
+    )
+    assert doc["parameters"] == recorded["parameters"]
+    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
