@@ -221,12 +221,19 @@ class KimiDeltaMixer(nn.Module):
 
     Device scopes ``kda_proj`` (the six projections in and the one out),
     ``kda_conv``, ``kda_scan`` (the L2 norms, ``beta``, the gate, the chunked
-    rule) and ``kda_gate``. Sown into ``"metrics"``: ``kda_decay_mean`` (the
+    rule: on a TPU backend at bfloat16 its chunk-local stage is the Pallas
+    kernels ``kda_inverse``, ``kda_operands`` and ``kda_backward``, custom
+    calls of those names in a trace, between them the carry's two loops and the
+    output stage in plain XLA; ``q``, ``k``, ``v`` and ``g`` go to the rule as
+    ``[B, T, H, d]``, whose ``[B, T, H d]`` view the kernels read in place) and
+    ``kda_gate``. Sown into ``"metrics"``: ``kda_decay_mean`` (the
     mean of ``exp(g)``), ``kda_beta_mean`` and ``kda_state_absmax`` (the largest
     magnitude in the state after the last step); into ``"intermediates"`` the
     rule's own inputs. What a remat policy saves bears the scalar rule's names
     (``REMAT_NAMES``): under ``"save_flash"`` the carry's loop runs once forward
-    and once in reverse a layer and the solve once.
+    and once in reverse a layer and the solve (``kda_inverse``) once; the rest
+    of the chunk-local stage (``kda_operands``) runs again when the backward
+    reaches the rule, to remake the carry's operands.
     """
 
     spec: KimiDeltaSpec

@@ -60,11 +60,27 @@ carry's (``carried_states``), and the carry's is jax's of one step.
 delta attention): the decay no longer factors out of the products over the key
 channels, so it rides the operands, a sub-block of steps at a time; the solve,
 the carry (its decay then a vector over ``d_k``) and the names are shared.
+Its **chunk-local stage**, everything between the rule's inputs and the
+carry's operands that depends on one chunk of one head only, has two forms
+under one contract. On a TPU backend, for bfloat16 operands at a chunk of 64
+and widths of whole lane tiles, three Pallas kernels under one
+``jax.custom_vjp`` hold a chunk of every head in VMEM a grid step and never
+write an intermediate to HBM: ``kda_inverse`` (``k``, ``g``, ``beta`` to every
+chunk's float32 ``T``; the solve's rounds run here and nowhere else, and ``T``
+bears ``gdn_inverse``), ``kda_operands`` (the inputs and ``T`` to ``w``, ``u``,
+``k_out``, ``whole``, ``q_in`` and the masked scores, each in its reader's
+layout) and ``kda_backward`` (the inputs, ``T`` and the six cotangents to
+``dq``, ``dk``, ``dv``, ``dg``, ``dbeta``). Everywhere else, :func:`_local_plain`:
+the plain ``jax.numpy`` form with jax's backward, which is also what the
+kernels are tested against. The carry and the output stage are plain XLA under
+both. :func:`gated_delta_rule` itself is plain throughout: a decay a step is
+the kernels' case of ``g`` equal along the lanes, not moved onto them here.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -305,18 +321,482 @@ MAX_DECAY_A_STEP = 88.0 / SUB_BLOCK
 
 
 @functools.lru_cache(maxsize=None)
-def _note_kda_chunks(chunk, sub_block, chunks, heads, d_k, d_v, itemsize, batch):
+def _note_kda_chunks(chunk, sub_block, chunks, heads, d_k, d_v, itemsize, batch, path):
     """One ``kda_chunks`` instant in the span ring for each shape
-    :func:`kda_rule` is traced at."""
+    :func:`kda_rule` is traced at; ``path`` says which form of the chunk-local
+    stage the shape took, ``"kernel"`` or ``"plain"``."""
     obs_trace.get_tracer().instant(
         "kda_chunks", chunk=chunk, sub_block=sub_block, chunks=chunks, heads=heads,
-        d_k=d_k, d_v=d_v, state_bytes=4 * heads * d_k * d_v, solve=SOLVE,
+        d_k=d_k, d_v=d_v, state_bytes=4 * heads * d_k * d_v, solve=SOLVE, path=path,
         saved_bytes=saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch),
     )
 
 
+def _local_plain(q, k, v, g, beta, size, sub):
+    """The chunk-local stage in plain ``jax.numpy``: everything of the rule
+    that depends on one chunk of one head only. ``q``, ``k``, ``g`` ``[B, T, H,
+    d_k]``, ``v`` ``[B, T, H, d_v]``, ``beta`` ``[B, T, H]``, ``T`` a multiple
+    of ``size``; returns the carry's operands chunks first (``w`` ``[n b h c
+    k]``, ``u`` ``[n b h c v]`` float32, ``k_out`` ``[n b c h k]``, ``whole``
+    ``[n b h k]`` float32), the output stage's (``q_in`` ``[b n c h k]``, the
+    causal-masked scores ``[b n h c s]``) and every chunk's float32 ``T`` ``[b
+    n h c s]``."""
+    batch, steps, h, d_k = q.shape
+    blocks, nc = size // sub, steps // size
+    f32, dtype = jnp.float32, q.dtype
+    dot = dict(preferred_element_type=f32)
+
+    # b batch, n chunk, i sub-block, c / s step (in a chunk, or in a sub-block
+    # behind an i), h head, k key width, v value width
+    q = q.reshape(batch, nc, size, h, d_k)
+    k = k.reshape(batch, nc, size, h, d_k)
+    v = v.reshape(batch, nc, size, h, v.shape[-1])
+    beta = beta.astype(f32).reshape(batch, nc, size, h)
+    gamma = jnp.cumsum(g.astype(f32).reshape(batch, nc, size, h, d_k), axis=2)
+
+    # a sub-block's reference: the running sum at its middle step
+    in_blocks = lambda a: a.reshape(batch, nc, blocks, sub, *a.shape[3:])  # noqa: E731
+    ref = in_blocks(gamma)[:, :, :, (sub - 1) // 2]             # [b n i h k]
+    rows = jnp.exp(in_blocks(gamma) - ref[:, :, :, None])        # [b n i c h k]
+    # columns up to the end of the rows' own sub-block, zeros past it
+    reach = jnp.arange(size)[None, :] // sub <= jnp.arange(blocks)[:, None]   # [i s]
+    cols = jnp.exp(jnp.where(
+        reach[:, :, None, None], ref[:, :, :, None] - gamma[:, :, None], -jnp.inf
+    ))                                                           # [b n i s h k]
+    k32, q32 = k.astype(f32), q.astype(f32)
+    k_rows = (in_blocks(k32) * rows).astype(dtype)
+    q_rows = (in_blocks(q32) * rows).astype(dtype)
+    k_cols = (k32[:, :, None] * cols).astype(dtype)
+
+    def against_columns(rows):
+        """``rows`` [b n i c h k] against ``k_cols``, a sub-block at a time:
+        [b n h C S], the sub-blocks' rows one after another."""
+        merged = lambda a: a.reshape(batch, nc * blocks, *a.shape[3:])  # noqa: E731
+        tile = jnp.einsum("bmchk,bmshk->bmhcs", merged(rows), merged(k_cols), **dot)
+        tile = tile.reshape(batch, nc, blocks, h, sub, size)
+        return jnp.moveaxis(tile, 2, 3).reshape(batch, nc, h, size, size)
+
+    kk, scores = against_columns(k_rows), against_columns(q_rows)
+
+    # inside a chunk: the system, its inverse, and what it makes of K and V
+    lower = jnp.tril(jnp.ones((size, size), bool))
+    beta_h = jnp.moveaxis(beta, 2, -1)                           # [b n h c]
+    system = jnp.where(jnp.tril(lower, -1), beta_h[..., None] * kk, 0.0)
+    exact = unit_lower_inverse(system)
+    inverse = exact.astype(dtype)
+    k_in = (k32 * (beta[..., None] * jnp.exp(gamma))).astype(dtype)
+    v_in = (v.astype(f32) * beta[..., None]).astype(dtype)
+    w = jnp.einsum("bnhcs,bnshk->bnhck", inverse, k_in, **dot).astype(dtype)
+    u = jnp.einsum("bnhcs,bnshv->bnhcv", inverse, v_in, **dot)
+    k_out = (k32 * jnp.exp(gamma[:, :, -1:] - gamma)).astype(dtype)
+    whole = jnp.exp(gamma[:, :, -1])                             # [b n h k]
+    q_in = (q32 * jnp.exp(gamma)).astype(dtype)
+    chunks_first = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
+    return (
+        *(chunks_first(a) for a in (w, u, k_out, whole)), q_in,
+        jnp.where(lower, scores, 0.0).astype(dtype), exact,
+    )
+
+
+# -- the chunk-local stage as Pallas kernels ---------------------------------
+#
+# One grid step holds a chunk of every head in VMEM: the blocks are ``chunk``
+# rows of the mixer's own ``[B, T, H d]`` arrays (a free reshape of ``[B, T, H,
+# d]``; a head is ``d`` lanes of a row), so no neighbour transposes, and the
+# heads are walked by a loop inside the body. Per head everything is a ``[C,
+# d]`` or ``[C, C]`` tile: eight float32 registers' worth. A scalar decay a
+# step (``gated_delta_rule``) is the case of ``g`` equal along the lanes.
+
+_KERNEL_CHUNK = 64       # the chunk the kernels are written for
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _running_sum(a, reverse=False):
+    """The running sum of ``a`` ``[C, d]`` down its rows (``reverse``: up), by
+    ``log2(C)`` shifted additions in float32 (Mosaic lowers no ``cumsum``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    size, row = a.shape[0], _iota(a.shape, 0)
+    s = 1
+    while s < size:
+        if reverse:
+            a = a + jnp.where(row < size - s, pltpu.roll(a, size - s, axis=0), 0.0)
+        else:
+            a = a + jnp.where(row >= s, pltpu.roll(a, s, axis=0), 0.0)
+        s *= 2
+    return a
+
+
+def _decays(g):
+    """Of one tile's log-decays ``g`` ``[C, d]``: their running sum ``gamma``,
+    the sub-blocks' references (``[1, d]`` each: ``gamma`` at the sub-block's
+    middle step) and the rows' factors ``exp(gamma - reference)`` ``[C, d]``."""
+    gamma, sub = _running_sum(g), SUB_BLOCK
+    middles = [i * sub + (sub - 1) // 2 for i in range(g.shape[0] // sub)]
+    refs = [gamma[m:m + 1] for m in middles]
+    of_rows = jnp.concatenate(
+        [jnp.broadcast_to(ref, (sub, g.shape[1])) for ref in refs], axis=0
+    )
+    return gamma, refs, jnp.exp(gamma - of_rows)
+
+
+def _columns(gamma, ref, reach):
+    """A sub-block's factors of the columns ``exp(reference - gamma)`` ``[C,
+    d]``: up to the end of the rows' own sub-block (``reach`` steps), zeros
+    past it."""
+    return jnp.exp(jnp.where(_iota(gamma.shape, 0) < reach, ref - gamma, -jnp.inf))
+
+
+def _times_transposed(a, b, **how):
+    """``a b^T``, float32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32, **how
+    )
+
+
+def _transposed_times(a, b, **how):
+    """``a^T b``, float32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32, **how
+    )
+
+
+def _against_columns(decayed_rows, k32, gamma, refs):
+    """``[C, C]``: the decayed rows (``[C, d]``, rounded) against the decayed
+    keys, a sub-block of rows at a time under its own reference. A column past
+    the rows' sub-block meets a row of zeros: the mask's six pairs of sub-blocks
+    cost the matrix unit nothing (its columns are free up to 128) and no ``exp``
+    of a positive argument is taken."""
+    strips, sub = [], SUB_BLOCK
+    for i, ref in enumerate(refs):
+        k_cols = (k32 * _columns(gamma, ref, (i + 1) * sub)).astype(decayed_rows.dtype)
+        strips.append(_times_transposed(decayed_rows[i * sub:(i + 1) * sub], k_cols))
+    return jnp.concatenate(strips, axis=0)
+
+
+def _solve(system):
+    """``unit_lower_inverse``'s rounds on one ``[C, C]`` float32 tile whose
+    strict lower triangle is the system (the rest is masked away here). The
+    first round, ``I - I A_1 I``, is taken without its two products."""
+    row, col = _iota(system.shape, 0), _iota(system.shape, 1)
+    inverse = None
+    for shift in range(system.shape[0].bit_length() - 1):  # blocks of 1 << shift rows
+        joins = ((row >> (shift + 1)) == (col >> (shift + 1))) & ((row >> shift) > (col >> shift))
+        joined = jnp.where(joins, system, 0.0)
+        if inverse is None:
+            inverse = jnp.where(row == col, 1.0, 0.0) - joined
+        else:
+            inverse = inverse - _exact(_exact(inverse, joined), inverse)
+    return inverse
+
+
+def _head_of(h, width):
+    """The lanes of head ``h`` in a row of ``heads * width``."""
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(h * width, 128), width)
+
+
+def _column_of(betas, h):
+    """Head ``h``'s column ``[C, 1]`` of ``betas`` ``[C, H]``."""
+    return jnp.sum(jnp.where(_iota(betas.shape, 1) == h, betas, 0.0), axis=1, keepdims=True)
+
+
+def _over_heads(heads, body, init=0):
+    """``body(pair, half, carry)`` for every head ``2 * pair + half`` in turn,
+    a pair to a round of the loop: the scheduler may interleave the two bodies
+    (Mosaic's own ``unroll`` is all or nothing), and ``half`` is static."""
+
+    def both(pair, carry):
+        return body(pair, 1, body(pair, 0, carry))
+
+    return jax.lax.fori_loop(0, heads // 2, both, init)
+
+
+def _inverse_at(inverse_ref, pair, half):
+    """Where head ``2 * pair + half``'s ``T`` ``[C, C]`` lies in a block ``[1,
+    1, H / 2, C, 2 C]``: a pair of heads side by side along the lanes, so the
+    float32 array every layer saves for its backward has no lane of padding."""
+    size = inverse_ref.shape[3]
+    return 0, 0, pair, slice(None), slice(half * size, (half + 1) * size)
+
+
+def kda_inverse_kernel(k_ref, g_ref, beta_ref, inverse_ref):
+    """Every head's ``T = (I + A)^-1`` of one chunk, float32 ``[H / 2, C, 2
+    C]``: a pair of heads a row."""
+    f32 = jnp.float32
+    heads = beta_ref.shape[2]
+    d_k = k_ref.shape[2] // heads
+    betas = beta_ref[0]
+
+    def head(pair, half, carry):
+        h = 2 * pair + half
+        lanes = _head_of(h, d_k)
+        gamma, refs, rows = _decays(g_ref[0, :, lanes])
+        k32 = k_ref[0, :, lanes].astype(f32)
+        kk = _against_columns((k32 * rows).astype(k_ref.dtype), k32, gamma, refs)
+        inverse_ref[_inverse_at(inverse_ref, pair, half)] = _solve(_column_of(betas, h) * kk)
+        return carry
+
+    _over_heads(heads, head)
+
+
+def kda_operands_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
+                        w_ref, u_ref, k_out_ref, whole_ref, q_in_ref, scores_ref):
+    """From one chunk's inputs and ``T`` to the carry's operands (``w``, ``u``,
+    ``k_out``, ``whole``) and the output stage's (``q_in``, the masked scores),
+    each written where its reader takes it."""
+    from jax.experimental import pallas as pl
+
+    f32, dtype = jnp.float32, q_ref.dtype
+    heads = beta_ref.shape[2]
+    d_k, d_v = k_ref.shape[2] // heads, v_ref.shape[2] // heads
+    betas = beta_ref[0]
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+
+    def head(pair, half, carry):
+        h = 2 * pair + half
+        keys, values = _head_of(h, d_k), _head_of(h, d_v)
+        gamma, refs, rows = _decays(g_ref[0, :, keys])
+        q32, k32 = q_ref[0, :, keys].astype(f32), k_ref[0, :, keys].astype(f32)
+        beta, grown = _column_of(betas, h), jnp.exp(gamma)
+        scores = _against_columns((q32 * rows).astype(dtype), k32, gamma, refs)
+        lower = _iota(scores.shape, 0) >= _iota(scores.shape, 1)
+        scores_ref[0, 0, h] = jnp.where(lower, scores, 0.0).astype(dtype)
+        inverse = inverse_ref[_inverse_at(inverse_ref, pair, half)].astype(dtype)
+        k_in = (k32 * (beta * grown)).astype(dtype)
+        v_in = (v_ref[0, :, values].astype(f32) * beta).astype(dtype)
+        w_ref[0, 0, h] = dot(inverse, k_in).astype(dtype)
+        u_ref[0, 0, h] = dot(inverse, v_in)
+        k_out_ref[0, 0, :, keys] = (k32 * jnp.exp(gamma[-1:] - gamma)).astype(dtype)
+        whole_ref[0, 0, pl.ds(h, 1), :] = jnp.exp(gamma[-1:])
+        q_in_ref[0, :, keys] = (q32 * grown).astype(dtype)
+        return carry
+
+    _over_heads(heads, head)
+
+
+def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
+                        dw_ref, du_ref, dk_out_ref, dwhole_ref, dq_in_ref, dscores_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """The cotangents of one chunk's inputs from those of the six operands:
+    the tile's intermediates are made again in VMEM from the inputs and the
+    saved ``T``, and every step of the way back is local to the tile."""
+    from jax.experimental import pallas as pl
+
+    f32, dtype = jnp.float32, q_ref.dtype
+    heads = beta_ref.shape[2]
+    d_k, d_v = k_ref.shape[2] // heads, v_ref.shape[2] // heads
+    size, sub = q_ref.shape[1], SUB_BLOCK
+    betas = beta_ref[0]
+    down = lambda a: jnp.sum(a, axis=0, keepdims=True)      # noqa: E731 — [1, d]
+    across = lambda a: jnp.sum(a, axis=1, keepdims=True)    # noqa: E731 — [C, 1]
+
+    def head(pair, half, d_betas):
+        h = 2 * pair + half
+        keys, values = _head_of(h, d_k), _head_of(h, d_v)
+        gamma, refs, rows = _decays(g_ref[0, :, keys])
+        q32, k32 = q_ref[0, :, keys].astype(f32), k_ref[0, :, keys].astype(f32)
+        v32 = v_ref[0, :, values].astype(f32)
+        beta, grown = _column_of(betas, h), jnp.exp(gamma)
+        to_end = jnp.exp(gamma[-1:] - gamma)
+        exact = inverse_ref[_inverse_at(inverse_ref, pair, half)]
+        inverse = exact.astype(dtype)
+        k_in = (k32 * (beta * grown)).astype(dtype)
+        v_in = (v32 * beta).astype(dtype)
+
+        # through w = T k_in and u = T v_in
+        dw, du = dw_ref[0, 0, h], du_ref[0, 0, h].astype(dtype)
+        d_inverse = _times_transposed(dw, k_in) + _times_transposed(du, v_in)
+        d_k_in, d_v_in = _transposed_times(inverse, dw), _transposed_times(inverse, du)
+
+        # through the solve, as ``_unit_lower_inverse_bwd``: dA = -T^T dT T^T
+        highest = dict(precision=jax.lax.Precision.HIGHEST)
+        d_system = -_times_transposed(
+            _transposed_times(exact, d_inverse, **highest), exact, **highest
+        )
+        row, col = _iota(d_system.shape, 0), _iota(d_system.shape, 1)
+        d_system = jnp.where(row > col, d_system, 0.0)
+        d_kk = d_system * beta
+        d_scores = jnp.where(row >= col, dscores_ref[0, 0, h].astype(f32), 0.0)
+
+        # through the products of the decayed rows and columns, a sub-block
+        # of rows at a time
+        k_rows, q_rows = (k32 * rows).astype(dtype), (q32 * rows).astype(dtype)
+        d_keys = jnp.zeros_like(k32)
+        d_gamma = jnp.zeros_like(gamma)
+        d_rows, d_beta, d_refs = [], [], []
+        for i, ref in enumerate(refs):
+            at = slice(i * sub, (i + 1) * sub)
+            cols = _columns(gamma, ref, (i + 1) * sub)
+            decayed = k32 * cols
+            k_cols = decayed.astype(dtype)
+            d_beta.append(across(d_system[at] * _times_transposed(k_rows[at], k_cols)))
+            both = jnp.concatenate([d_kk[at], d_scores[at]], axis=0).astype(dtype)
+            d_rows.append(jnp.dot(both, k_cols, preferred_element_type=f32))
+            d_cols = _transposed_times(
+                both, jnp.concatenate([k_rows[at], q_rows[at]], axis=0)
+            )
+            d_keys = d_keys + d_cols * cols
+            d_gamma = d_gamma - d_cols * decayed
+            d_refs.append(down(d_cols * decayed))
+        d_k_rows = jnp.concatenate([a[:sub] for a in d_rows], axis=0)
+        d_q_rows = jnp.concatenate([a[sub:] for a in d_rows], axis=0)
+        through_rows = (d_k_rows * k32 + d_q_rows * q32) * rows
+        d_gamma = d_gamma + through_rows
+        step = _iota(gamma.shape, 0)
+        for i, d_ref in enumerate(d_refs):
+            d_ref = d_ref - down(through_rows[i * sub:(i + 1) * sub])
+            d_gamma = d_gamma + jnp.where(step == i * sub + (sub - 1) // 2, d_ref, 0.0)
+
+        # through the elementwise operands
+        dk_out, dq_in = dk_out_ref[0, 0, :, keys].astype(f32), dq_in_ref[0, :, keys].astype(f32)
+        leaving = dk_out * k32 * to_end
+        d_last = down(leaving) + dwhole_ref[0, 0, pl.ds(h, 1), :] * jnp.exp(gamma[-1:])
+        d_gamma = (
+            d_gamma + d_k_in * k32 * (beta * grown) - leaving + dq_in * q32 * grown
+            + jnp.where(step == size - 1, d_last, 0.0)
+        )
+        dq_ref[0, :, keys] = (d_q_rows * rows + dq_in * grown).astype(dtype)
+        dk_ref[0, :, keys] = (
+            d_keys + d_k_rows * rows + d_k_in * (beta * grown) + dk_out * to_end
+        ).astype(dtype)
+        dv_ref[0, :, values] = (d_v_in * beta).astype(dtype)
+        dg_ref[0, :, keys] = _running_sum(d_gamma, reverse=True)
+        d_beta = (
+            jnp.concatenate(d_beta, axis=0) + across(d_k_in * k32 * grown) + across(d_v_in * v32)
+        )
+        return jnp.where(_iota(d_betas.shape, 1) == h, d_beta, d_betas)
+
+    dbeta_ref[0] = _over_heads(heads, head, jnp.zeros(betas.shape, f32))
+
+
+_INPUTS = ("keys", "keys", "values", "decays", "beta")                 # q k v g beta
+_OPERANDS = ("w", "u", "k_out", "whole", "keys", "scores")             # ..., q_in, scores
+
+
+def _run(kernel, ins, outs, operands, interpret):
+    """One of the three kernels on ``operands``, whose kinds ``ins`` names
+    (``outs`` those of its results): a grid step a chunk, every block a chunk
+    of every head."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, steps, h = operands[ins.index("beta")].shape
+    size, nc = _KERNEL_CHUNK, steps // _KERNEL_CHUNK
+    keys = operands[ins.index("keys")]
+    values = operands[ins.index("values")] if "values" in ins else keys
+    d_k, d_v = keys.shape[2] // h, values.shape[2] // h
+    f32, dtype = jnp.float32, keys.dtype
+    # a kind's shape, dtype, block and the block's place at batch b, chunk n
+    here, first = (lambda b, n: (b, n)), (lambda b, n: (n, b))  # noqa: E731
+    kinds = dict(
+        keys=((batch, steps, h * d_k), dtype, (1, size, h * d_k), here),
+        values=((batch, steps, h * d_v), dtype, (1, size, h * d_v), here),
+        decays=((batch, steps, h * d_k), f32, (1, size, h * d_k), here),
+        beta=((batch, steps, h), f32, (1, size, h), here),
+        # every chunk's T, a pair of heads a row; the scores [b n h c s]
+        inverse=((batch, nc, h // 2, size, 2 * size), f32, (1, 1, h // 2, size, 2 * size), here),
+        scores=((batch, nc, h, size, size), dtype, (1, 1, h, size, size), here),
+        # the carry's operands, chunks first
+        w=((nc, batch, h, size, d_k), dtype, (1, 1, h, size, d_k), first),
+        u=((nc, batch, h, size, d_v), f32, (1, 1, h, size, d_v), first),
+        k_out=((nc, batch, size, h * d_k), dtype, (1, 1, size, h * d_k), first),
+        whole=((nc, batch, h, d_k), f32, (1, 1, h, d_k), first),
+    )
+
+    def spec(kind):
+        _, _, block, place = kinds[kind]
+        return pl.BlockSpec(block, lambda b, n: place(b, n) + (0,) * (len(block) - 2))
+
+    def held(kind):  # a block's bytes in VMEM, its rows padded to whole lane tiles
+        _, dt, block, _ = kinds[kind]
+        return math.prod(block[:-1]) * -(-block[-1] // 128) * 128 * jnp.dtype(dt).itemsize
+
+    name = kernel.__name__.removesuffix("_kernel")
+    call = pl.pallas_call(
+        kernel, name=name, grid=(batch, nc),
+        in_specs=[spec(kind) for kind in ins], out_specs=[spec(kind) for kind in outs],
+        out_shape=[jax.ShapeDtypeStruct(*kinds[kind][:2]) for kind in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            # every block twice (the pipeline's two buffers) and room for the
+            # body's own tiles
+            vmem_limit_bytes=2 * sum(held(kind) for kind in (*ins, *outs)) + (16 << 20),
+        ),
+        interpret=interpret,
+    )
+    with obs_trace.span("kernel_trace", kernel=name):
+        return tuple(call(*operands))
+
+
+# jitted, as ``ops/causal_conv.py``'s: a step traces and lowers each body once
+# (with ``SUB_BLOCK`` as it stands at the first trace of a shape)
+@functools.partial(jax.jit, static_argnums=3)
+def _inverse_call(k, g, beta, interpret):
+    ins = ("keys", "decays", "beta")
+    return _run(kda_inverse_kernel, ins, ("inverse",), (k, g, beta), interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=6)
+def _operands_call(q, k, v, g, beta, inverse, interpret):
+    ins = (*_INPUTS, "inverse")
+    return _run(kda_operands_kernel, ins, _OPERANDS, (q, k, v, g, beta, inverse), interpret)
+
+
+@functools.partial(jax.jit, static_argnums=12)
+def _backward_call(q, k, v, g, beta, inverse, dw, du, dk_out, dwhole, dq_in, dscores, interpret):
+    ins = (*_INPUTS, "inverse", *_OPERANDS)
+    operands = (q, k, v, g, beta, inverse, dw, du, dk_out, dwhole, dq_in, dscores)
+    return _run(kda_backward_kernel, ins, _INPUTS, operands, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _local_kernels(q, k, v, g, beta, interpret):
+    """The chunk-local stage by the kernels, for ``q``, ``k``, ``g`` ``[B, T, H
+    d_k]``, ``v`` ``[B, T, H d_v]`` and ``beta`` ``[B, T, H]`` (float32 ``g``
+    and ``beta``): ``(w, u, k_out, whole, q_in, scores)`` as ``_local_plain``
+    lays them out, ``k_out`` and ``q_in`` with a row's heads side by side."""
+    inverse = _inverse_call(k, g, beta, interpret)
+    return _operands_call(q, k, v, g, beta, inverse, interpret)
+
+
+def _local_kernels_fwd(q, k, v, g, beta, interpret):
+    # T apart from the rest and by name, as ``_unit_lower_inverse_fwd``: a
+    # policy that saves it spares the recomputation the solve, which is most
+    # of the stage's arithmetic, and the backward reads it
+    inverse = checkpoint_name(_inverse_call(k, g, beta, interpret), INVERSE_NAME)
+    operands = _operands_call(q, k, v, g, beta, inverse, interpret)
+    return operands, (q, k, v, g, beta, inverse)
+
+
+def _local_kernels_bwd(interpret, residuals, cotangents):
+    return _backward_call(*residuals, *cotangents, interpret)
+
+
+_local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
+
+
+def _kernels_take(q, k, v, steps, chunk, interpret):
+    """Whether the chunk-local stage of these operands is the kernels': on a
+    TPU backend (or in the interpreter), bfloat16 operands, the kernels' chunk
+    in sub-blocks of whole bfloat16 tiles, widths of whole lane tiles, a
+    length the chunk divides and the heads in pairs."""
+    return bool(
+        (interpret or jax.default_backend() == "tpu")
+        and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+        and chunk == _KERNEL_CHUNK and SUB_BLOCK % 16 == 0 and chunk % SUB_BLOCK == 0
+        and steps % chunk == 0
+        and q.shape[2] % 2 == 0
+        and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+    )
+
+
 def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
-             return_final_state: bool = False):
+             return_final_state: bool = False, interpret: bool = False):
     """The delta rule with **a decay for every key channel** (Kimi delta
     attention, arXiv:2510.26692, equation 1): per head, for a log-decay ``g_t``
     of ``d_k`` values, none positive::
@@ -352,6 +832,31 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     Precision as the scalar rule's: ``g``, its sums, every ``exp``, ``beta``,
     the system, its inverse and the carried state in float32; each matmul
     operand rounded once to ``q``'s dtype, float32 accumulation.
+
+    **Two forms of the chunk-local stage** (from the inputs to the carry's
+    operands ``w``, ``u``, ``k_out``, ``whole`` and the output stage's ``q_in``
+    and masked scores), one contract. Which runs is decided from what the call
+    can see, as ``ops/causal_conv.py`` decides: on a TPU backend, for bfloat16
+    ``q``, ``k``, ``v``, a chunk of 64, ``d_k`` and ``d_v`` multiples of 128,
+    an even ``H`` and a ``T`` the chunk divides, three Pallas kernels under one
+    ``jax.custom_vjp`` (``_local_kernels``); everywhere else (the CPU, float32
+    operands, a ragged ``T``, another chunk) the plain form (``_local_plain``),
+    which is also the kernels' reference. ``interpret`` runs the kernels in the
+    Pallas interpreter (tests on the CPU). The carry (``carried_states``) and
+    the output stage after it are plain XLA either way.
+
+    The kernels hold one chunk of every head in VMEM a grid step, ``chunk``
+    rows of the ``[B, T, H d]`` views of the inputs as the mixer hands them
+    over (a head is ``d`` lanes of a row, so nothing is transposed on the way
+    in), walk the heads in a loop and write each operand in its reader's
+    layout (the carry's chunks first). ``kda_inverse`` makes every chunk's
+    float32 ``T`` from ``k``, ``g`` and ``beta``; it alone runs the solve, and
+    its output bears ``INVERSE_NAME``, so under a policy that saves the name the
+    recomputation of a layer runs ``kda_operands`` (everything else, from the
+    inputs and ``T``) and not the solve. ``kda_backward`` keeps nothing but the
+    inputs and ``T``: it makes the tile's intermediates again in VMEM and
+    returns ``dq``, ``dk``, ``dv``, ``dg``, ``dbeta`` from the six operands'
+    cotangents, the solve's by ``dA = -T^T dT T^T``.
     """
     batch, t, h, d_k = q.shape
     d_v = v.shape[-1]
@@ -364,6 +869,7 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError("kda_rule: chunk %d is not a power of two" % chunk)
     sub = min(SUB_BLOCK, chunk)  # both powers of two: it divides the chunk
+    kernels = _kernels_take(q, k, v, t, chunk, interpret)
     pad = -t % chunk
     if pad:
         q, k, v, g, beta = (
@@ -371,73 +877,37 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
             for a in (q, k, v, g, beta)
         )
     steps = t + pad
-    size, blocks, nc = chunk, chunk // sub, steps // chunk
+    size, nc = chunk, steps // chunk
     f32, dtype = jnp.float32, q.dtype
-    _note_kda_chunks(size, sub, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch)
+    _note_kda_chunks(
+        size, sub, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch,
+        "kernel" if kernels else "plain",
+    )
     dot = dict(preferred_element_type=f32)
 
-    # b batch, n chunk, i sub-block, c / s step (in a chunk, or in a sub-block
-    # behind an i), h head, k key width, v value width
-    q = q.reshape(batch, nc, size, h, d_k)
-    k = k.reshape(batch, nc, size, h, d_k)
-    v = v.reshape(batch, nc, size, h, d_v)
-    beta = beta.astype(f32).reshape(batch, nc, size, h)
-    gamma = jnp.cumsum(g.astype(f32).reshape(batch, nc, size, h, d_k), axis=2)
-
-    # a sub-block's reference: the running sum at its middle step
-    in_blocks = lambda a: a.reshape(batch, nc, blocks, sub, *a.shape[3:])  # noqa: E731
-    ref = in_blocks(gamma)[:, :, :, (sub - 1) // 2]             # [b n i h k]
-    rows = jnp.exp(in_blocks(gamma) - ref[:, :, :, None])        # [b n i c h k]
-    # columns up to the end of the rows' own sub-block, zeros past it
-    reach = jnp.arange(size)[None, :] // sub <= jnp.arange(blocks)[:, None]   # [i s]
-    cols = jnp.exp(jnp.where(
-        reach[:, :, None, None], ref[:, :, :, None] - gamma[:, :, None], -jnp.inf
-    ))                                                           # [b n i s h k]
-    k32, q32 = k.astype(f32), q.astype(f32)
-    k_rows = (in_blocks(k32) * rows).astype(dtype)
-    q_rows = (in_blocks(q32) * rows).astype(dtype)
-    k_cols = (k32[:, :, None] * cols).astype(dtype)
-
-    def against_columns(rows):
-        """``rows`` [b n i c h k] against ``k_cols``, a sub-block at a time:
-        [b n h C S], the sub-blocks' rows one after another."""
-        merged = lambda a: a.reshape(batch, nc * blocks, *a.shape[3:])  # noqa: E731
-        tile = jnp.einsum("bmchk,bmshk->bmhcs", merged(rows), merged(k_cols), **dot)
-        tile = tile.reshape(batch, nc, blocks, h, sub, size)
-        return jnp.moveaxis(tile, 2, 3).reshape(batch, nc, h, size, size)
-
-    kk, scores = against_columns(k_rows), against_columns(q_rows)
-
-    # inside a chunk: the system, its inverse, and what it makes of K and V
-    lower = jnp.tril(jnp.ones((size, size), bool))
-    beta_h = jnp.moveaxis(beta, 2, -1)                           # [b n h c]
-    system = jnp.where(jnp.tril(lower, -1), beta_h[..., None] * kk, 0.0)
-    inverse = unit_lower_inverse(system).astype(dtype)
-    k_in = (k32 * (beta[..., None] * jnp.exp(gamma))).astype(dtype)
-    v_in = (v.astype(f32) * beta[..., None]).astype(dtype)
-    w = jnp.einsum("bnhcs,bnshk->bnhck", inverse, k_in, **dot).astype(dtype)
-    u = jnp.einsum("bnhcs,bnshv->bnhcv", inverse, v_in, **dot)
-    k_out = (k32 * jnp.exp(gamma[:, :, -1:] - gamma)).astype(dtype)
-    whole = jnp.exp(gamma[:, :, -1])                             # [b n h k]
+    # b batch, n chunk, c / s step in a chunk, h head, k key width, v value width
+    if kernels:
+        flat = lambda a: a.reshape(batch, steps, -1)  # noqa: E731 — a row's heads side by side
+        w, u, k_out, whole, q_in, scores = _local_kernels(
+            flat(q), flat(k), flat(v), flat(g.astype(f32)), beta.astype(f32), interpret
+        )
+        k_out = k_out.reshape(nc, batch, size, h, d_k)
+        q_in = q_in.reshape(batch, nc, size, h, d_k)
+    else:
+        w, u, k_out, whole, q_in, scores, _ = _local_plain(q, k, v, g, beta, size, sub)
 
     # from chunk to chunk, the state in float32
     if initial_state is None:
         state = jnp.zeros((batch, h, d_k, d_v), f32)
     else:
         state = initial_state.astype(f32)
-    chunks_first = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
-    states, new, state = carried_states(
-        state, *(chunks_first(a) for a in (w, u, k_out, whole))
-    )
+    states, new, state = carried_states(state, w, u, k_out, whole)
     entering = jnp.moveaxis(states.astype(dtype), 0, 1)          # [b n h k v]
     new = jnp.moveaxis(new, 0, 1)                                # [b n h c v]
 
     # every chunk's outputs: what it inherits, and what it wrote itself
-    q_in = (q32 * jnp.exp(gamma)).astype(dtype)
     inherited = jnp.einsum("bnchk,bnhkv->bnchv", q_in, entering, **dot)
-    own = jnp.einsum(
-        "bnhcs,bnhsv->bnchv", jnp.where(lower, scores, 0.0).astype(dtype), new, **dot
-    )
+    own = jnp.einsum("bnhcs,bnhsv->bnchv", scores, new, **dot)
     o = (inherited + own).reshape(batch, steps, h, d_v)[:, :t].astype(dtype)
     if return_final_state:
         return o, state

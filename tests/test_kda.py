@@ -8,6 +8,7 @@ layer and the whole model against ``benchmark/reference/kda_lm.py``, which
 imports nothing from ``edl_tpu.models``.
 """
 
+import functools
 import importlib
 import json
 import os
@@ -182,6 +183,177 @@ def test_each_traced_shape_leaves_one_kda_chunks_instant():
     assert (found[0]["d_k"], found[0]["d_v"]) == (8, 16)
     assert found[0]["state_bytes"] == 4 * 3 * 8 * 16
     assert found[0]["saved_bytes"] == G.saved_bytes(32, 2, 3, 8, 16, 4, 2)
+    assert found[0]["path"] == "plain" and found[0]["solve"] == G.SOLVE
+
+
+# -- the chunk-local stage's kernels, in the Pallas interpreter --------------------
+
+OPERANDS = ("w", "u", "k_out", "whole", "q_in", "scores", "inverse")
+LEAVES = ("q", "k", "v", "g", "beta")
+
+
+def near_zero(args):
+    """Channels that hardly forget: every factor of a sub-block near one."""
+    q, k, v, g, beta = args
+    return q, k, v, 1e-3 * g, beta
+
+
+def padded(args):
+    """The last 40 steps as ``kda_rule`` pads a ragged length: zero rows,
+    ``g = 0`` and ``beta = 0``, which leave the state as it is."""
+    q, k, v, g, beta = args
+    live = (jnp.arange(g.shape[1]) < g.shape[1] - 40)
+    cut = lambda a: a * live.reshape((1, -1) + (1,) * (a.ndim - 2))  # noqa: E731
+    return tuple(cut(a) for a in (q, k, v, g, beta))
+
+
+KERNEL_CASES = {"drawn": lambda a: a, "at_the_bound": at_the_bound, "near_zero": near_zero,
+                "padded": padded}
+
+
+def kernel_inputs(case, dtype):
+    """One sequence of two chunks, two heads of 128 / 128: the kernels' case."""
+    q, k, v, g, beta = KERNEL_CASES[case](rule_inputs(seed=4, b=1, t=128, h=2, d_k=128, d_v=128))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def by_kernels(q, k, v, g, beta):
+    """The six operands the rule reads by the kernels, in ``_local_plain``'s
+    shapes."""
+    b, t, h, d = q.shape
+    flat = lambda a: a.reshape(b, t, -1)  # noqa: E731
+    w, u, k_out, whole, q_in, scores = G._local_kernels(
+        flat(q), flat(k), flat(v), flat(g), beta, True
+    )
+    return (w, u, k_out.reshape(t // 64, b, 64, h, d), whole,
+            q_in.reshape(b, t // 64, 64, h, d), scores)
+
+
+def inverse_by_kernels(q, k, v, g, beta):
+    b, t = q.shape[:2]
+    pairs = G._inverse_call(k.reshape(b, t, -1), g.reshape(b, t, -1), beta, True)
+    # [b n h/2 c (2 s)]: a pair of heads side by side along the lanes
+    apart = jnp.moveaxis(pairs.reshape(b, t // 64, -1, 64, 2, 64), 4, 3)
+    return apart.reshape(b, t // 64, -1, 64, 64)
+
+
+def weights(like, seed=20):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(like))
+    return tuple(jax.random.normal(key, a.shape).astype(a.dtype) for key, a in zip(keys, like))
+
+
+@functools.lru_cache(maxsize=None)
+def stage_both_ways(case, dtype):
+    """``(values, gradients)`` of the stage by the kernels and by the plain
+    form on the same inputs: the gradients under one random cotangent of the
+    six operands the rule reads (``T`` is the backward's own residual)."""
+    args = kernel_inputs(case, jnp.dtype(dtype))
+    plain = lambda *a: G._local_plain(*a, 64, 16)  # noqa: E731
+    found = []
+    with jax.default_matmul_precision("highest"):
+        for stage, inverse in ((by_kernels, inverse_by_kernels), (plain, lambda *a: plain(*a)[6])):
+            values, pull = jax.jit(lambda *a, stage=stage: jax.vjp(lambda *x: stage(*x)[:6], *a))(*args)
+            grads = jax.jit(pull)(weights(values))
+            found.append((values + (jax.jit(inverse)(*args),), grads))
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+@pytest.mark.parametrize("what", OPERANDS + LEAVES)
+def test_the_kernels_are_the_plain_chunk_local_stage(what, case, dtype):
+    """Float32 operands (which only the tests hand the kernels) hold the
+    arithmetic to the plain form's; bfloat16 the points of rounding: an operand
+    rounded at another point reads a bfloat16 step, 4e-3, or more off."""
+    (got, got_grads), (want, want_grads) = stage_both_ways(case, dtype)
+    if what in OPERANDS:
+        a, b = got[OPERANDS.index(what)], want[OPERANDS.index(what)]
+    else:
+        a, b = got_grads[LEAVES.index(what)], want_grads[LEAVES.index(what)]
+    assert a.shape == b.shape and a.dtype == b.dtype
+    # at the bound a sub-block's factors are e^+-80: float32 keeps 1e-7 of each
+    exact = 2e-3 if case == "at_the_bound" else 1e-4
+    _close(a, b, tol=exact if dtype == "float32" else 1.5e-2)
+
+
+bf16_args = lambda args: tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]  # noqa: E731
+
+
+@functools.lru_cache(maxsize=None)
+def rule_both_ways(case):
+    """``kda_rule`` on bfloat16 operands from an initial state, ``(o, final
+    state)`` and the five gradients under a random cotangent of both, by the
+    kernels (in the interpreter) and by the plain form."""
+    args = bf16_args(KERNEL_CASES[case](rule_inputs(seed=5, b=1, t=192, h=2, d_k=128, d_v=128)))
+    state = 0.1 * jax.random.normal(jax.random.PRNGKey(6), (1, 2, 128, 128))
+    found = []
+    for interpret in (True, False):
+        rule = lambda *a, i=interpret: kda_rule(  # noqa: E731
+            *a, chunk=64, initial_state=state, return_final_state=True, interpret=i
+        )
+        values, pull = jax.jit(lambda *a, rule=rule: jax.vjp(rule, *a))(*args)
+        found.append((values, jax.jit(pull)(weights(values, seed=21))))
+    return found
+
+
+@pytest.mark.parametrize("case", ["drawn", "at_the_bound", "padded"])
+@pytest.mark.parametrize("what", ["o", "state"] + list(LEAVES))
+def test_the_rule_by_the_kernels_is_the_rule_by_the_plain_form(what, case):
+    (got, got_grads), (want, want_grads) = rule_both_ways(case)
+    if what in ("o", "state"):
+        a, b = got[what == "state"], want[what == "state"]
+    else:
+        a, b = got_grads[LEAVES.index(what)], want_grads[LEAVES.index(what)]
+    assert a.shape == b.shape and a.dtype == b.dtype
+    _close(a.astype(jnp.float32), b.astype(jnp.float32), tol=2e-2)
+
+
+@pytest.mark.parametrize("case", ["drawn", "at_the_bound"])
+def test_the_rule_by_the_kernels_equals_the_step_by_step_recurrence(case):
+    """Within the limit the plain form's bfloat16 call is held to above."""
+    args = bf16_args(KERNEL_CASES[case](rule_inputs(seed=3, b=1, t=128, h=2, d_k=128, d_v=128)))
+    o, state = jax.jit(
+        lambda *a: kda_rule(*a, chunk=64, return_final_state=True, interpret=True)
+    )(*args)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_state = reference.recurrence(*(a.astype(jnp.float32) for a in args))
+    assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    _close(o.astype(jnp.float32), want_o, tol=0.03)
+    _close(state, want_state, tol=0.03)
+
+
+@pytest.mark.parametrize("why,path", [
+    ("the_kernels_case", "kernel"), ("no_tpu_and_no_interpreter", "plain"),
+    ("float32_operands", "plain"), ("a_ragged_length", "plain"), ("a_chunk_of_32", "plain"),
+    ("a_width_of_64", "plain"), ("sub_blocks_of_8", "plain"), ("three_heads", "plain"),
+])
+def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path, monkeypatch):
+    """``kda_chunks`` carries ``path``; the CPU's default, float32 operands (the
+    benchmark check's exact call), a length the chunk does not divide, another
+    chunk, a width under a lane tile and an odd count of heads take the plain form."""
+    t, d, h, chunk, interpret, narrow = 128, 128, 2, 64, True, bf16_args
+    if why == "no_tpu_and_no_interpreter":
+        interpret = False
+    elif why == "float32_operands":
+        narrow = lambda args: args  # noqa: E731
+    elif why == "a_ragged_length":
+        t = 100
+    elif why == "a_chunk_of_32":
+        chunk = 32
+    elif why == "a_width_of_64":
+        d = 64
+    elif why == "sub_blocks_of_8":
+        monkeypatch.setattr(G, "SUB_BLOCK", 8)
+    elif why == "three_heads":
+        h = 3
+    args = narrow(rule_inputs(seed=8, b=1, t=t, h=h, d_k=d, d_v=d))
+    G._note_kda_chunks.cache_clear()
+    tracer = obs_trace.get_tracer()
+    before = len([e for e in tracer.to_events() if e["name"] == "kda_chunks"])
+    lowered = jax.jit(lambda *a: kda_rule(*a, chunk=chunk, interpret=interpret)).lower(*args)
+    found = [e["args"] for e in tracer.to_events() if e["name"] == "kda_chunks"][before:]
+    assert [e["path"] for e in found] == [path]
+    assert ("kda_operands" in lowered.as_text(debug_info=True)) == (path == "kernel")
 
 
 # -- the flash kernels at two widths ---------------------------------------------

@@ -837,13 +837,19 @@ def test_flash2_compiles_for_v5e_at_keys_of_192_and_values_of_128(one_chip, dire
         assert shapes == [(b, heads, t, d_qk), (b, heads, t, d_qk), (b, heads, t, d_v)]
 
 
-def test_kda_rule_compiles_for_v5e_at_the_cells_widths(one_chip):
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_kda_rule_compiles_for_v5e_at_the_cells_widths(one_chip, path):
     """The per-channel rule with its backward at one sequence of 8192, the 16
-    heads of 128 / 128 the cell holds, chunks of 64 in sub-blocks of 16: plain
-    XLA, so what the chip's compiler can refuse is the memory. The decayed
-    columns are four float32 copies of the keys (268 MB at 16 heads) and their
-    cotangent as many again; value and gradients together stay under 3 GB of
-    the 6 the step has beside its state."""
+    heads of 128 / 128 the cell holds, chunks of 64 in sub-blocks of 16. Plain
+    XLA (what a CPU backend and a shape the kernels refuse take), what the
+    chip's compiler can refuse is the memory: the decayed columns are four
+    float32 copies of the keys (268 MB at 16 heads) and their cotangent as many
+    again; value and gradients together plan 2.23 GB, under 3 of the 6 the step
+    has beside its state. As the chip lowers it (``jax.default_backend`` steered
+    here, in the test) the chunk-local stage is the three kernels, each once,
+    and no copy of a chunk's decayed keys reaches HBM: 0.71 GB."""
+    from unittest import mock
+
     from edl_tpu.ops import kda_rule
 
     def sds(dims, dtype=jnp.bfloat16):
@@ -857,8 +863,55 @@ def test_kda_rule_compiles_for_v5e_at_the_cells_widths(one_chip):
         out, vjp = jax.vjp(lambda *a: kda_rule(*a, chunk=64), *a)
         return (out, *vjp(w))
 
-    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, d)), *args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    with mock.patch.object(jax, "default_backend", lambda: "tpu" if path == "kernels" else "cpu"):
+        lowered = jax.jit(value_and_grads).lower(sds((1, t, h, d)), *args)
+    kernels = sorted(_kernel_names(lowered.as_text()))
+    compiled = lowered.compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == len(kernels)
+    if path == "kernels":
+        assert kernels == ["kda_backward", "kda_inverse", "kda_operands"] and temp < 1e9
+    else:
+        assert kernels == [] and 1e9 < temp < 3e9
+
+
+@pytest.mark.parametrize("heads", [16, 32], ids=["held", "published"])
+@pytest.mark.parametrize("kernel", ["kda_inverse", "kda_operands", "kda_backward"])
+def test_kda_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, kernel, heads):
+    """The three kernels of the rule's chunk-local stage at one sequence of
+    8192 and heads of 128 / 128, a chunk of every head a grid step (blocks of
+    ``[64, heads * 128]`` rows of the mixer's own arrays, a head's lanes taken
+    by a dynamic slice inside the body's loop, ``T`` a pair of heads a
+    ``[64, 128]`` row, ``[heads, 64, 64]`` tiles of the scores): the tiling, the slices and the VMEM limit set from
+    the shapes are what the chip's compiler can refuse."""
+    G = importlib.import_module("edl_tpu.ops.gated_delta")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, d, f32 = 8192, 128, jnp.float32
+    nc = t // 64
+    rows, decays, beta = sds((1, t, heads * d)), sds((1, t, heads * d), f32), sds((1, t, heads), f32)
+    inverse = sds((1, nc, heads // 2, 64, 128), f32)
+    operands = (sds((nc, 1, heads, 64, d)), sds((nc, 1, heads, 64, d), f32),
+                sds((nc, 1, 64, heads * d)), sds((nc, 1, heads, d), f32), rows,
+                sds((1, nc, heads, 64, 64)))
+    call, args = {
+        "kda_inverse": (lambda *a: G._inverse_call(*a, False), (rows, decays, beta)),
+        "kda_operands": (lambda *a: G._operands_call(*a, False),
+                         (rows, rows, rows, decays, beta, inverse)),
+        "kda_backward": (lambda *a: G._backward_call(*a, False),
+                         (rows, rows, rows, decays, beta, inverse, *operands)),
+    }[kernel]
+    compiled = jax.jit(call).lower(*args).compile()
+    assert kernel in compiled.as_text()
+    out = jax.eval_shape(call, *args)
+    if kernel == "kda_operands":
+        assert [(a.shape, a.dtype) for a in out] == [(a.shape, a.dtype) for a in operands]
+    elif kernel == "kda_backward":
+        assert [(a.shape, a.dtype) for a in out] == [
+            (a.shape, a.dtype) for a in (rows, rows, rows, decays, beta)
+        ]
 
 
 def _kda_cell():
@@ -911,7 +964,10 @@ def test_the_kda_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(h
     """``benchmark/tools/compile_for_v5e.py`` on the cell at depth 6 with all
     the heads and with the share the rule chose (3 to 5 minutes each): the step
     compiles, and the plan's total is what the configuration's file records,
-    to 0.1 GB."""
+    to 0.1 GB, at the 16 heads the cell runs. With all 32 the file keeps PR
+    45's 17.52 GB (a ``benchmark`` issue's to mend, ROADMAP S11(16)); since the
+    rule's chunk-local stage is kernels (PR 46) the plan is 16.53: the four
+    float32 copies of a chunk's keys and their cotangents are never in HBM."""
     import json
     import subprocess
     import sys
@@ -931,4 +987,6 @@ def test_the_kda_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(h
         if (t["num_hidden_layers"], t["num_attention_heads"]) == (6, heads)
     )
     assert doc["parameters"] == recorded["parameters"]
-    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
+    planned = recorded["total_gb"] if heads == 16 else 16.53
+    assert doc["total_gb"] == pytest.approx(planned, abs=0.1)
+    assert planned <= recorded["total_gb"]
