@@ -35,8 +35,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import math
-from functools import lru_cache, partial
+from functools import partial
 from typing import Any, Callable, Optional, Tuple, Union
 
 import flax.linen as nn
@@ -214,16 +213,6 @@ def rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array
     return out.astype(x.dtype)
 
 
-@lru_cache(maxsize=None)
-def _note_dw_apart(kernel: str, shape: Tuple[int, ...], dtype: str):
-    """One ``dw_apart`` instant in the span ring for each shape a projection
-    of the attention layer has its weight gradient fenced at."""
-    obs_trace.get_tracer().instant(
-        "dw_apart", kernel=kernel, shape=list(shape), dtype=dtype,
-        bytes=math.prod(shape) * jnp.dtype(dtype).itemsize,
-    )
-
-
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _dw_apart(name: str, kernel):
     """``kernel``, and a fence behind its gradient: forward the identity
@@ -237,7 +226,11 @@ def _dw_apart_fwd(name, kernel):
 
 
 def _dw_apart_bwd(name, _, ct):
-    _note_dw_apart(name, tuple(ct.shape), str(ct.dtype))
+    # once a shape a projection's weight gradient is fenced at, and stage
+    obs_trace.get_tracer().note_once(
+        "dw_apart", kernel=name, shape=list(ct.shape), dtype=str(ct.dtype),
+        bytes=ct.size * ct.dtype.itemsize,
+    )
     return (jax.lax.optimization_barrier(ct),)
 
 
@@ -505,17 +498,16 @@ class Attention(nn.Module):
         return out.reshape(b, t, self.num_heads, head_dim).astype(self.dtype)
 
 
-@lru_cache(maxsize=None)
 def _note_mla_shape(tq: int, heads: int, spec: LatentAttentionSpec):
     """One ``mla_shape`` instant in the span ring for each shape a latent
-    attention layer is traced at, with the blocks the grid-pipelined kernels
-    take at the two widths."""
+    attention layer is traced at in a stage (``note_once``), with the blocks
+    the grid-pipelined kernels take at the two widths."""
     d_qk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
     blocks = {
         kind: list(_flash2_blocks(kind, tq, tq, None))
         for kind in ("fwd", "bwd")
     }
-    obs_trace.get_tracer().instant(
+    obs_trace.get_tracer().note_once(
         "mla_shape", tq=tq, heads=heads, d_qk=d_qk, d_v=spec.v_head_dim,
         latent=spec.kv_lora_rank, rope_dim=spec.qk_rope_head_dim,
         fwd_blocks=blocks["fwd"], bwd_blocks=blocks["bwd"],

@@ -319,7 +319,7 @@ class NumericsProbe:
         step: int,
         bundle: Optional[Dict[str, Any]],
         epoch: Optional[int] = None,
-    ) -> Optional[Tuple[int, float]]:
+    ) -> Optional[Tuple[int, float, Dict[str, Any]]]:
         """Buffer this step's device bundle; publish on the throttle
         cadence. Publishing fetches the *previous* buffered bundle —
         already retired by a full step of device work — except on the
@@ -328,9 +328,10 @@ class NumericsProbe:
         registered-but-never-set gauge would render 0.0 and trip the
         grad-stall rule during a long first-step compile).
 
-        A call that fetched returns ``(step, monotonic time)``: the step
-        whose bundle came back, and the moment it did — the step loop's
-        one proof that a numbered step has retired on the device.
+        A call that fetched returns ``(step, monotonic time, sown)``: the
+        step whose bundle came back, the moment it did — the step loop's
+        one proof that a numbered step has retired on the device — and what
+        the model sowed in that step, as the same fetch brought it.
         ``epoch`` only labels the ``numerics_fetch`` span."""
         if self._closed or bundle is None:
             return None
@@ -375,7 +376,7 @@ class NumericsProbe:
 
     def _publish(
         self, step: int, bundle: Dict[str, Any], epoch: Optional[int] = None
-    ) -> Optional[Tuple[int, float]]:
+    ) -> Optional[Tuple[int, float, Dict[str, Any]]]:
         if step == self._last_pub_step:
             return None
         self._last_pub_step = step
@@ -387,7 +388,7 @@ class NumericsProbe:
         except Exception as exc:  # noqa: BLE001 — a deleted buffer must not kill the loop
             logger.warning("numerics fetch failed at step %d: %s", step, exc)
             return None
-        fetched = (step, time.monotonic())
+        fetched = (step, time.monotonic(), dict(vals.get("sown", {})))
         self.published += 1
         loss = float(vals["loss"])
         grad_norm = float(vals["grad_norm"])

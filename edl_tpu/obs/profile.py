@@ -37,6 +37,16 @@ Unknown device kinds take ``EDL_PEAK_FLOPS`` (override for new chips);
 pure-CPU backends fall back to a nominal debug peak so the plumbing is
 drivable off-TPU — a CPU "MFU" is a plumbing signal, not a measurement.
 
+**The step program's own account** (:class:`HloProgram`). One parse of the
+running stage's step executable puts every instruction under one part
+(:data:`STEP_PARTS`) and one pass (:data:`PHASES`): :func:`step_phases`,
+:func:`step_scopes` and :func:`step_parts` are the joins of a device
+trace's events to the program's names, and once a stage
+:func:`publish_step_census` counts the instructions that run (matmuls,
+Pallas kernel calls by kernel, loops, collectives) into one
+``step_program`` instant, ``edl_train_step_program_count{what}`` and a
+flight record, on a thread of its own (``train/loop.py``).
+
 **On-demand capture** (:class:`CaptureController`). Workers watch the
 job's ``profile/request`` store key; a request (``edl-profile
 --request``, or the monitor's auto-capture) makes every worker run one
@@ -183,22 +193,89 @@ def step_cost(step_fn, *args, **kwargs) -> Dict:
         return {}
 
 
-# -- the step program's phases ------------------------------------------------
+# -- the step program's phases and parts --------------------------------------
 
 PHASES = ("forward", "backward", "optimizer", "numerics", "other")
+
+# The one table of a step's parts: ``(a component of a jax op_name, the part
+# it reads under)``, and an instruction takes the innermost component that is
+# listed. First the device scopes the models enter (``jax.named_scope``), each
+# a part of its own: the expert layer's (models/moe.py), the Mamba-2 mixer's
+# (models/mamba.py), the gated-delta-rule and Kimi-delta-attention mixers'
+# (models/gated_delta.py), the gated short convolution's (models/short_conv.py),
+# a sparse-attention layer's (ops/sparse_attention.py), a latent-attention
+# layer's and, in a model of windowed and full attention layers, each kind's
+# (models/transformer.py). Then the flax module names, for what has no scope:
+# ``attn`` is an attention layer's projections, rotation and QK norms, a mixer's
+# own name what it does outside its scopes. :func:`part_of` adds the parts that
+# are no component: ``attn_kernel`` (a Pallas call right under ``attn``),
+# ``block`` (a layer's residual adds, under ``layer_<n>`` and no module),
+# ``loss`` (forward or backward, under no module or scope at all), a tied
+# ``head`` (a matmul under the model and no module) and ``other``.
+_STEP_SCOPES = (
+    "moe_route", "moe_experts", "moe_combine", "moe_shared",
+    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate",
+    "gdn_proj", "gdn_conv", "gdn_scan", "gdn_gate",
+    "kda_proj", "kda_conv", "kda_scan", "kda_gate",
+    "sconv_proj", "sconv_conv",
+    "dsa_index", "dsa_select", "dsa_target", "attn_sparse",
+    "attn_window", "attn_full", "attn_gate", "attn_mla", "mla_proj",
+    "embed", "attn", "mlp", "moe", "mamba", "gdn", "kda", "sconv",
+)
+STEP_PARTS = tuple((name, name) for name in _STEP_SCOPES) + (
+    ("ln1", "norm"), ("ln2", "norm"), ("ln1_post", "norm"),
+    ("ln2_post", "norm"), ("ln_f", "norm"), ("lm_head", "head"),
+)
+_PART_OF_COMPONENT = dict(STEP_PARTS)
+#: a ``while`` / ``conditional``: its body's instructions are placed
+#: themselves, so whoever sums by part leaves this one out and counts a loop once
+CONTAINER = "container"
 
 # `%fusion.12 = f32[8]{0} fusion(...), calls=%fused_computation.12,
 #  metadata={op_name="jit(step)/optimizer/add" ...}`
 _HLO_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
-_HLO_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_HLO_COMPUTATION = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
-_HLO_MATMUL = re.compile(r"\s(?:convolution|dot)\(")
+_HLO_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+# a `while`'s, a `conditional`'s, a `call`'s computations: they run as written
+_HLO_CONTROL = re.compile(
+    r"(?:condition|body|to_apply|true_computation|false_computation)=%?([\w.\-]+)"
+)
+_HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_HLO_LAYER = re.compile(r"layer_\d+$")
+_PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
+_MATMULS = ("dot", "convolution")
+# the parts that name no module or scope with a matmul of its own: one found
+# there is in a layer the table does not list (``block``: under a ``layer_<n>``
+# in a module of no listed name) or under no name at all (``loss``, ``other``)
+_UNPLACED = ("other", "block", "loss")
+_CONTAINERS = ("while", "conditional")
+_COLLECTIVES = frozenset(
+    kind + suffix
+    for kind in ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                 "collective-permute")
+    for suffix in ("", "-start")
+)
 
-# the running stage's compiled step and, once asked for, its tables
-_step_executable = None
-_step_phases: Optional[Dict[str, str]] = None
-_step_scopes: Dict[Tuple[str, ...], Dict[str, str]] = {}
+
+class _StepSlot:
+    """The running stage's compiled step and, once asked for, its parse. Whoever
+    asks first makes the parse, outside :data:`_step_lock`; whoever asks
+    meanwhile waits for ``parsed``, not for the lock, so the next stage's
+    :func:`set_step_executable` never waits behind a text of seconds."""
+
+    __slots__ = ("compiled", "program", "claimed", "parsed")
+
+    def __init__(self, compiled) -> None:
+        self.compiled = compiled
+        self.program: Optional["HloProgram"] = None
+        self.claimed = False
+        self.parsed = threading.Event()
+
+
+_step: Optional[_StepSlot] = None
+_step_lock = threading.Lock()  # held to swap or claim the slot, never to parse
 
 
 def phase_of(op_name: str) -> str:
@@ -219,37 +296,229 @@ def phase_of(op_name: str) -> str:
     return "other"
 
 
-def op_names_of_hlo(text: str) -> Dict[str, str]:
-    """``{instruction name: jax op_name}`` from an optimised HLO module's
-    text: every instruction by its own ``op_name``, and one that has none
-    (a fusion, a call) by that of the root of the computation it calls."""
-    own: Dict[str, str] = {}      # instruction -> op_name
-    calls: Dict[str, str] = {}    # instruction without one -> computation
-    roots: Dict[str, str] = {}    # computation -> its root instruction
-    computation = None
-    for line in text.splitlines():
-        started = _HLO_COMPUTATION.match(line)
-        if started:
-            computation = started.group(1)
-            continue
-        found = _HLO_INSTRUCTION.match(line)
-        if not found:
-            continue
-        name = found.group(2)
-        if found.group(1) and computation is not None:
-            roots[computation] = name
-        op_name = _HLO_OP_NAME.search(line)
-        if op_name:
-            own[name] = op_name.group(1)
-        else:
+def part_of(op_name: str, kernel: bool = False) -> str:
+    """The part a jax ``op_name`` belongs to: the innermost of its components
+    that :data:`STEP_PARTS` lists (a Pallas call, ``kernel``, right under
+    ``attn`` reads ``attn_kernel``); failing one, ``block`` under a
+    ``layer_<n>``, ``loss`` in the forward or the backward pass under no
+    name, ``head`` for a matmul under the model alone (a tied head), else
+    ``other``."""
+    components = op_name.split(";", 1)[0].split("/")
+    for component in reversed(components):
+        part = _PART_OF_COMPONENT.get(component)
+        if part is not None:
+            return "attn_kernel" if kernel and part == "attn" else part
+    if any(_HLO_LAYER.match(c) for c in components):
+        return "block"
+    if phase_of(op_name) not in ("forward", "backward"):
+        return "other"
+    # the loss is what the forward pass runs under no name at all: every
+    # component before the primitive is a transform jax wrote (``jit(..)``,
+    # ``jvp(..)``, ``transpose(..)``), none a module or a scope
+    named = {c for c in components[:-1] if "(" not in c}
+    if not named:
+        return "loss"
+    # a matmul right under the model, in no module of its own: a tied head,
+    # the embedding's matrix once more (``TransformerLM(tie_embeddings=)``)
+    tied = len(named) == 1 and components[-1] == "dot_general"
+    return "head" if tied else "other"
+
+
+class HloProgram:
+    """What one pass over an optimised HLO module's text keeps of every
+    instruction, and the tables read off it: the join of a device trace's
+    events (named by HLO instruction) to the program's own names. The pass is
+    a regex over every line, seconds for a step of a hundred thousand
+    instructions: made once an executable, off the step loop's thread."""
+
+    def __init__(self, text: str) -> None:
+        self.own: Dict[str, str] = {}          # instruction -> its own op_name
+        self.opcode: Dict[str, str] = {}       # instruction -> opcode
+        self.home: Dict[str, str] = {}         # instruction -> its computation
+        self.calls: Dict[str, str] = {}        # instruction -> `calls=` computation
+        self.roots: Dict[str, str] = {}        # computation -> root instruction
+        self.kernels: set = set()              # the Pallas custom calls
+        self.matmuls: set = set()              # computations holding a dot / convolution
+        self.updates: set = set()              # computations holding `optimizer` instructions
+        self.run: set = set()                  # computations that run as written
+        computation = None
+        for line in text.splitlines():
+            started = _HLO_COMPUTATION.match(line)
+            if started:
+                computation = started.group(2)
+                if started.group(1):
+                    self.run.add(computation)
+                continue
+            found = _HLO_INSTRUCTION.match(line)
+            if not found:
+                continue
+            name = found.group(2)
+            self.home[name] = computation
+            if found.group(1) and computation is not None:
+                self.roots[computation] = name
+            # the opcode follows the shape; a Pallas call's line is its whole
+            # kernel, so look no further than the head of the line
+            # (the match ends on the blank after ``=``, which the opcode's
+            # pattern starts from)
+            at, until = found.end(), found.end() + 4096
+            opcode = _HLO_OPCODE.search(line, at - 1, until)
+            opcode = self.opcode[name] = opcode.group(1) if opcode else ""
+            if opcode in _MATMULS:
+                self.matmuls.add(computation)
+            op_name = _HLO_OP_NAME.search(line)
+            if op_name:
+                self.own[name] = op_name.group(1)
+                if phase_of(op_name.group(1)) == "optimizer":
+                    self.updates.add(computation)
+            if opcode == "custom-call":
+                if line.find(_PALLAS_TARGET, at, until) >= 0:
+                    self.kernels.add(name)
+                continue
             called = _HLO_CALLS.search(line)
             if called:
-                calls[name] = called.group(1)
-    for name, computation in calls.items():
-        root = roots.get(computation)
-        if root in own:
-            own[name] = own[root]
-    return own
+                self.calls[name] = called.group(1)
+                if opcode != "fusion":  # an async start, a call
+                    self.run.add(called.group(1))
+            if opcode in _CONTAINERS or opcode == "call":
+                self.run.update(_HLO_CONTROL.findall(line))
+                branches = _HLO_BRANCHES.search(line)
+                if branches:
+                    self.run.update(
+                        b.strip().lstrip("%") for b in branches.group(1).split(",")
+                    )
+        self._tables: Dict[object, Dict] = {}
+        #: what the parse cost, where :func:`step_program` made it
+        self.cost: Dict[str, float] = {}
+
+    def _once(self, key, make):
+        if key not in self._tables:
+            self._tables[key] = make()
+        return self._tables[key]
+
+    def op_names(self) -> Dict[str, str]:
+        """``{instruction name: jax op_name}``: every instruction by its own
+        ``op_name``, and one that has none (a fusion, a call) by that of the
+        root of the computation it calls."""
+        def make():
+            names = dict(self.own)
+            for name, computation in self.calls.items():
+                root = self.roots.get(computation)
+                if name not in self.own and root in self.own:
+                    names[name] = self.own[root]
+            return names
+        return self._once("op_names", make)
+
+    def update_passes(self) -> List[str]:
+        """The fusions that are the optimizer's pass over a leaf: those whose
+        body holds instructions of the ``optimizer`` scope and no matmul."""
+        return [
+            name for name, computation in self.calls.items()
+            if computation in self.updates and computation not in self.matmuls
+        ]
+
+    def phases(self) -> Dict[str, str]:
+        def make():
+            table = {
+                name: phase_of(op_name)
+                for name, op_name in self.op_names().items()
+            }
+            table.update((name, "optimizer") for name in self.update_passes())
+            return table if "forward" in table.values() else {}
+        return self._once("phases", make)
+
+    def scopes(self, scopes: Sequence[str]) -> Dict[str, str]:
+        def make():
+            wanted = set(scopes)
+            table: Dict[str, str] = {}
+            for name, op_name in self.op_names().items():
+                for part in reversed(op_name.split(";", 1)[0].split("/")):
+                    if part in wanted:
+                        table[name] = part
+                        break
+            return table
+        return self._once(tuple(scopes), make)
+
+    def parts(self) -> Dict[str, Tuple[str, str]]:
+        """``{instruction: (part, pass)}`` for EVERY instruction: one without
+        a name of its own or of its root reads ``("other", "other")``, a
+        ``while`` / ``conditional`` :data:`CONTAINER` under its own pass. {}
+        where :meth:`phases` is (an executable older than the scopes)."""
+        def make():
+            phases = self.phases()
+            if not phases:
+                return {}
+            names = self.op_names()
+            table = {}
+            for name, opcode in self.opcode.items():
+                op_name = names.get(name)
+                if opcode in _CONTAINERS:
+                    part = CONTAINER
+                elif op_name is None:
+                    part = "other"
+                else:
+                    part = part_of(op_name, name in self.kernels)
+                table[name] = (part, phases.get(name, "other"))
+            return table
+        return self._once("parts", make)
+
+    def census(self) -> Dict:
+        """The instructions that run as written (the entry computation's, a
+        loop's body's, a conditional's branches': not those inside a fusion or
+        a reducer), counted: ``{"totals": {what: count}, "parts": {"part/pass":
+        {what: count}}, "kernels": {"kernel/pass": count}}``. ``what`` is
+        ``instructions``; ``matmuls`` (a ``dot`` or ``convolution``, a fusion
+        that holds one, a Pallas call: each once); ``kernel_calls`` (the
+        Pallas calls, by the name a trace gives them: the instruction's less
+        its number); ``loops`` and ``conditionals``; ``collectives``;
+        ``fused_dw`` (matmul fusions that also hold ``optimizer``
+        instructions); ``unplaced_matmuls`` (matmuls whose part is ``other``,
+        ``block`` or ``loss``: in a module the table does not list, or under
+        no name; one right under the model reads ``head``, a tied head's, and
+        is not seen here).
+        Static: both branches of a conditional count, a loop's body once. A
+        loop or a conditional is counted under the part its own name gives
+        (``kda_scan``'s carry, ``moe_experts``' group search), where
+        :meth:`parts` calls it a container."""
+        parts, names = self.parts(), self.op_names()
+        totals = dict.fromkeys(
+            ("instructions", "matmuls", "kernel_calls", "loops", "conditionals",
+             "collectives", "fused_dw", "unplaced_matmuls"), 0,
+        )
+        by_part: Dict[str, Dict[str, int]] = {}
+        kernels: Dict[str, int] = {}
+        for name, opcode in self.opcode.items():
+            if self.home[name] not in self.run or opcode == "parameter":
+                continue
+            part, phase = parts.get(name, ("other", "other"))
+            if part == CONTAINER:  # counted, not timed: under the part it serves
+                part = part_of(names.get(name, ""))
+            fused = self.calls.get(name) if opcode == "fusion" else None
+            counts = {
+                "instructions": 1,
+                "matmuls": opcode in _MATMULS or fused in self.matmuls
+                or name in self.kernels,
+                "kernel_calls": name in self.kernels,
+                "loops": opcode == "while",
+                "conditionals": opcode == "conditional",
+                "collectives": opcode in _COLLECTIVES,
+                "fused_dw": fused in self.matmuls and fused in self.updates,
+            }
+            counts["unplaced_matmuls"] = counts["matmuls"] and part in _UNPLACED
+            row = by_part.setdefault("%s/%s" % (part, phase), {})
+            for what, n in counts.items():
+                if n:
+                    totals[what] += 1
+                    row[what] = row.get(what, 0) + 1
+            if name in self.kernels:
+                key = "%s/%s" % (re.sub(r"\.\d+$", "", name), phase)
+                kernels[key] = kernels.get(key, 0) + 1
+        return {"totals": totals, "parts": by_part, "kernels": kernels}
+
+
+def op_names_of_hlo(text: str) -> Dict[str, str]:
+    """``{instruction name: jax op_name}`` from an optimised HLO module's
+    text (:meth:`HloProgram.op_names`)."""
+    return HloProgram(text).op_names()
 
 
 def update_passes_of_hlo(text: str) -> List[str]:
@@ -259,29 +528,7 @@ def update_passes_of_hlo(text: str) -> List[str]:
     the numerics bundle's norms ride in it, and XLA names the fusion after
     one of the norms' reduces. A fusion that still holds the matmul that
     produces its gradient is not one: its time is the matmul's."""
-    updates, matmuls = set(), set()   # computations holding either
-    calls: Dict[str, str] = {}        # instruction -> computation
-    computation = None
-    for line in text.splitlines():
-        started = _HLO_COMPUTATION.match(line)
-        if started:
-            computation = started.group(1)
-            continue
-        found = _HLO_INSTRUCTION.match(line)
-        if not found:
-            continue
-        called = _HLO_CALLS.search(line)
-        if called:
-            calls[found.group(2)] = called.group(1)
-        if _HLO_MATMUL.search(line):
-            matmuls.add(computation)
-        op_name = _HLO_OP_NAME.search(line)
-        if op_name and phase_of(op_name.group(1)) == "optimizer":
-            updates.add(computation)
-    return [
-        name for name, computation in calls.items()
-        if computation in updates and computation not in matmuls
-    ]
+    return HloProgram(text).update_passes()
 
 
 def phases_of_hlo(text: str) -> Dict[str, str]:
@@ -291,12 +538,7 @@ def phases_of_hlo(text: str) -> Dict[str, str]:
     took). {} when nothing maps to ``forward``: the executable then predates
     the scopes (a compile cache older than them handed it back), and a table
     of ``other`` would pass for a measurement."""
-    table = {
-        name: phase_of(op_name)
-        for name, op_name in op_names_of_hlo(text).items()
-    }
-    table.update((name, "optimizer") for name in update_passes_of_hlo(text))
-    return table if "forward" in table.values() else {}
+    return HloProgram(text).phases()
 
 
 # the expert layer's device-side names (models/moe.py:DroplessMoE)
@@ -309,66 +551,156 @@ def scopes_of_hlo(text: str, scopes: Sequence[str]) -> Dict[str, str]:
     the forward pass, its recomputation or its transpose alike; the
     innermost of them where scopes nest. Instructions under none are left
     out."""
-    wanted = set(scopes)
-    table: Dict[str, str] = {}
-    for name, op_name in op_names_of_hlo(text).items():
-        for part in reversed(op_name.split(";", 1)[0].split("/")):
-            if part in wanted:
-                table[name] = part
-                break
-    return table
+    return HloProgram(text).scopes(scopes)
+
+
+def parts_of_hlo(text: str) -> Dict[str, Tuple[str, str]]:
+    """``{instruction name: (part, pass)}`` for every instruction of an
+    optimised HLO module's text: ``pass`` is :func:`phases_of_hlo`'s phase (a
+    fusion where its root is, an optimizer's pass under ``optimizer``;
+    ``other`` without a name), ``part`` :func:`part_of` its op_name, so what
+    ``jax.checkpoint`` runs again reads under the part it recomputes and the
+    ``backward`` pass. {} where :func:`phases_of_hlo` is."""
+    return HloProgram(text).parts()
 
 
 def set_step_executable(compiled) -> None:
     """Keep the running stage's compiled step (the one ``Compiled`` the
-    train loop makes for the memory plan) for :func:`step_phases`."""
-    global _step_executable, _step_phases
-    _step_executable, _step_phases = compiled, None
-    _step_scopes.clear()
+    train loop makes for the memory plan) for :func:`step_phases`,
+    :func:`step_scopes` and :func:`step_parts`."""
+    global _step
+    with _step_lock:
+        _step = _StepSlot(compiled) if compiled is not None else None
+
+
+def executable_text(compiled) -> str:
+    """The optimised HLO text of a ``Compiled``, got so that another thread
+    can run meanwhile: ``Compiled.as_text()`` is one call that holds the GIL
+    from the executable's modules to their text (3.4 s for a ResNet-50 step on
+    a v5e, during which the step loop dispatched nothing: my probe, PR 47),
+    where ``hlo_modules()`` alone, nearly all of it, releases the GIL and
+    ``to_string()`` holds it for 0.05 s. The same text, as jax joins it."""
+    executable = compiled.runtime_executable()
+    if hasattr(executable, "hlo_modules"):
+        return "\n\n".join(m.to_string() for m in executable.hlo_modules())
+    return compiled.as_text()
+
+
+def step_program() -> Optional[HloProgram]:
+    """The one parse of the running stage's step executable that the three
+    tables and the census share: made by whoever asks first (the census's
+    thread, in a stage that trains), never inside the step loop. None without
+    a step, or where the backend has no text. The lock is held to claim the
+    parse, not across it: a stage that ends meanwhile swaps the slot and this
+    parse is its own slot's, thrown away with it."""
+    with _step_lock:
+        slot = _step
+        mine = slot is not None and not slot.claimed
+        if mine:
+            slot.claimed = True
+    if slot is None:
+        return None
+    if not mine:
+        slot.parsed.wait()
+        return slot.program
+    try:
+        t0 = time.monotonic()
+        text = executable_text(slot.compiled)
+        t1 = time.monotonic()
+        program = slot.program = HloProgram(text)
+        program.cost = {
+            "text_bytes": len(text), "text_s": t1 - t0,
+            "parse_s": time.monotonic() - t1,
+        }
+    except Exception as exc:  # noqa: BLE001 — telemetry: a backend without text reads as no table
+        logger.warning("step program unavailable: %s", exc)
+        program = slot.program = HloProgram("")
+    finally:
+        slot.parsed.set()  # after the except: a waiter reads what it left
+    return program
 
 
 def step_phases() -> Dict[str, str]:
     """``{HLO instruction name: "forward" | "backward" | "optimizer" |
     "numerics" | "other"}`` for the step executable of the running
     stage. A device trace names an event by its HLO instruction
-    (``fusion.124``), not by its scope: this is the join. Parsed from
-    the compiled step's text on first call, never inside ``fit``; {}
-    without a step, or where its names are missing (see
-    :func:`phases_of_hlo`)."""
-    global _step_phases
-    if _step_phases is None and _step_executable is not None:
-        try:
-            _step_phases = phases_of_hlo(_step_executable.as_text())
-        except Exception as exc:  # noqa: BLE001 — telemetry: a backend without text reads as no table
-            logger.warning("step phases unavailable: %s", exc)
-            _step_phases = {}
-    return dict(_step_phases or {})
+    (``fusion.124``), not by its scope: this is the join. Read off
+    :func:`step_program`; {} without a step, or where its names are
+    missing (see :func:`phases_of_hlo`)."""
+    program = step_program()
+    return dict(program.phases()) if program is not None else {}
 
 
 def step_scopes(scopes: Sequence[str] = MOE_SCOPES) -> Dict[str, str]:
     """:func:`scopes_of_hlo` for the step executable of the running stage:
-    which of a device trace's events ran under which of ``scopes`` — by
-    default the expert layer's ``moe_route`` / ``moe_experts`` /
-    ``moe_combine``; the models also name ``moe_shared``, the Mamba-2
-    mixer's ``ssm_proj`` / ``ssm_conv`` / ``ssm_scan`` / ``ssm_gate``, the
-    gated-delta-rule mixer's ``gdn_proj`` / ``gdn_conv`` / ``gdn_scan`` /
-    ``gdn_gate``, the gated short convolution's ``sconv_proj`` /
-    ``sconv_conv``, a sparse-attention layer's ``dsa_index`` / ``dsa_select`` /
-    ``attn_sparse`` / ``dsa_target``, the Kimi-delta-attention mixer's
-    ``kda_proj`` / ``kda_conv`` / ``kda_scan`` / ``kda_gate``, a latent-attention
-    layer's ``mla_proj`` / ``attn_mla`` and, in a model of windowed and full
-    attention layers, ``attn_window`` / ``attn_full`` / ``attn_gate``. A caller asks for the
-    scopes of one mixer or layer at a time; an instruction under two of the
-    asked scopes counts under the innermost. {} without a step or for a
-    model that enters none of ``scopes``."""
-    key = tuple(scopes)
-    if key not in _step_scopes and _step_executable is not None:
-        try:
-            _step_scopes[key] = scopes_of_hlo(_step_executable.as_text(), key)
-        except Exception as exc:  # noqa: BLE001 — telemetry: a backend without text reads as no table
-            logger.warning("step scopes unavailable: %s", exc)
-            _step_scopes[key] = {}
-    return dict(_step_scopes.get(key, {}))
+    which of a device trace's events ran under which of ``scopes``, by
+    default the expert layer's; every scope a model enters is in
+    :data:`STEP_PARTS`. A caller asks for the scopes of one mixer or layer at
+    a time; an instruction under two of the asked scopes counts under the
+    innermost. {} without a step or for a model that enters none of
+    ``scopes``. (:func:`step_parts` is the same join for the whole step.)"""
+    program = step_program()
+    return dict(program.scopes(scopes)) if program is not None else {}
+
+
+def step_parts() -> Dict[str, Tuple[str, str]]:
+    """:func:`parts_of_hlo` for the step executable of the running stage:
+    every instruction under one part of :data:`STEP_PARTS` (or ``attn_kernel``,
+    ``block``, ``loss``, ``other``, :data:`CONTAINER`) and one pass of
+    :data:`PHASES`, so a device trace's seconds sum by part to the step with
+    a loop counted once. {} without a step."""
+    program = step_program()
+    return dict(program.parts()) if program is not None else {}
+
+
+_M_STEP_PROGRAM = obs_metrics.gauge(
+    "edl_train_step_program_count",
+    "the running stage's compiled step, counted once a stage, by what: "
+    "matmuls, unplaced_matmuls, kernel_calls, loops (HloProgram.census) and "
+    "plain_fallbacks (the stage's call-site shapes that took the plain form "
+    "where a kernel form exists)",
+)
+#: the totals that are gauges, each read by a listed metric of the benchmark
+#: (``step_unplaced_share``, ``step_kernel_calls``, ``step_loops``,
+#: ``step_plain_fallbacks``); the census's other totals are the instant's and
+#: the flight record's alone
+STEP_PROGRAM_GAUGES = (
+    "matmuls", "unplaced_matmuls", "kernel_calls", "loops", "plain_fallbacks",
+)
+
+
+def publish_step_census(
+    tracer, notes: Sequence[tuple] = (), plan: Optional[Dict] = None, **stage
+) -> Optional[Dict]:
+    """The census of the running stage's step (:meth:`HloProgram.census`),
+    out three ways: one ``step_program`` instant in ``tracer``'s ring (the
+    totals as arguments, the by-part and by-kernel tables and the memory
+    ``plan``'s kinds as one argument each), the totals that a metric reads
+    (:data:`STEP_PROGRAM_GAUGES`) as ``edl_train_step_program_count{what}`` and
+    one ``step_program`` flight record. ``notes`` is what the stage had noted
+    when its first step ended (``SpanTracer.notes()``): those with
+    ``path="plain"`` are the ``plain_fallbacks``, listed in the instant with
+    their ``why``. Called on a thread of its own (``train/loop.py``): the text
+    of a large executable and the pass over it take seconds. None without a
+    step or its names."""
+    program = step_program()
+    if program is None or not program.parts():
+        return None
+    t0 = time.monotonic()
+    census = program.census()
+    fallbacks = [
+        dict(args, note=name) for name, args in notes if args.get("path") == "plain"
+    ]
+    totals = dict(census["totals"], plain_fallbacks=len(fallbacks))
+    for what in STEP_PROGRAM_GAUGES:
+        _M_STEP_PROGRAM.set(float(totals[what]), what=what)
+    cost = dict(program.cost, census_s=time.monotonic() - t0)
+    tracer.instant(
+        "step_program", parts=census["parts"], kernels=census["kernels"],
+        fallbacks=fallbacks, plan=dict(plan or {}), **totals, **cost, **stage,
+    )
+    obs_events.record("step_program", **totals, **cost, **stage)
+    return census
 
 
 def roofline(cost, device_kind: str, peak: float, mfu: Optional[float] = None) -> Dict:

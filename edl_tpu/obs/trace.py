@@ -13,6 +13,11 @@ tracing plane:
   ring-only);
 - completed spans land in a ring buffer (``maxlen`` bounded — tracing a
   million-step job costs a fixed few MB, never OOM);
+- ``note_once()`` is an instant the first time a (name, arguments) pair is
+  seen in a stage: what a kernel's call site says of each shape it is
+  traced at (its tiles, its chunks, which form it took and why), once a
+  shape however many layers share it, and again in the next stage
+  (``reset_notes()``; ``clear()`` forgets them with the ring);
 - export is Chrome trace-event JSON (``chrome://tracing`` / Perfetto).
   Timestamps are mapped back to unix-epoch microseconds through a
   (wall, monotonic) anchor captured at tracer creation, so traces from
@@ -351,6 +356,7 @@ class SpanTracer:
         self._anchor_wall = time.time()
         self._anchor_mono = time.monotonic()
         self._events: deque = deque(maxlen=maxlen)
+        self._notes: Dict[str, tuple] = {}  # key -> (name, args), this stage's
         self._lock = threading.Lock()
 
     # -- recording ---------------------------------------------------------
@@ -426,6 +432,33 @@ class SpanTracer:
         with self._lock:
             self._events.append(ev)
 
+    def note_once(self, name: str, **args) -> None:
+        """:meth:`instant`, the first time this ``(name, args)`` pair is seen
+        since :meth:`reset_notes` (the train loop calls that at the start of
+        every stage) or :meth:`clear`: a call site traced at one shape many
+        times (a layer for each of a model's blocks, a second lowering) notes
+        it once, and a stage that traces its step anew notes it again. A site
+        that chooses between a kernel and a plain form adds ``path="kernel"``,
+        or ``path="plain"`` and ``why``, the first condition its dispatch did
+        not meet."""
+        key = "%s %r" % (name, sorted(args.items()))
+        with self._lock:
+            if key in self._notes:
+                return
+            self._notes[key] = (name, args)
+        self.instant(name, **args)
+
+    def notes(self) -> List[tuple]:
+        """``[(name, args)]`` noted since the last reset, in order."""
+        with self._lock:
+            return list(self._notes.values())
+
+    def reset_notes(self) -> None:
+        """Forget what :meth:`note_once` has seen (the ring keeps its
+        instants): the next stage's shapes are noted afresh."""
+        with self._lock:
+            self._notes.clear()
+
     def _to_epoch_us(self, mono: float) -> float:
         return (self._anchor_wall + (mono - self._anchor_mono)) * 1e6
 
@@ -438,6 +471,7 @@ class SpanTracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+            self._notes.clear()
 
     def to_events(self) -> List[dict]:
         """Snapshot as Chrome trace events, process metadata included."""

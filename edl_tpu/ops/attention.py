@@ -400,15 +400,15 @@ def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
     }
 
 
-@functools.lru_cache(maxsize=None)
 def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side,
                 **more):
     """One ``attn_tiles`` instant in the span ring for each shape a
-    grid-pipelined kernel is traced at: what the mask makes of its tiles
-    (and what ``more`` the kernel has to say of itself)."""
+    grid-pipelined kernel is traced at in a stage (``note_once``): what the
+    mask makes of its tiles (and what ``more`` the kernel has to say of
+    itself)."""
     shares = tile_census(tq, tk, block_q, block_k, causal, window, side)
     live = shares["interior"] + shares["edge"]
-    obs_trace.get_tracer().instant(
+    obs_trace.get_tracer().note_once(
         "attn_tiles", kernel=kernel, tq=tq, tk=tk, block_q=block_q,
         block_k=block_k, window=window,
         masked_share=shares["edge"] / live if live else 0.0, **shares, **more,
@@ -1663,6 +1663,12 @@ def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
             return _flash_backward(
                 q, k, v, o, lse, g, causal, scale, bq, bk, _interpret()
             )
+    if bwd_impl in ("flash", "flash2"):  # a kernel was asked for
+        obs_trace.get_tracer().note_once(
+            "attn_route", tq=tq, tk=tk, window=window, side="backward",
+            path="plain",
+            why="lse" if lse is None else "blocks" if kernels else "shape",
+        )
     _, vjp = jax.vjp(
         lambda q, k, v: attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
@@ -1690,20 +1696,29 @@ def attention(
     ``attention_reference`` remain for callers that want a specific
     implementation. ``window`` (with ``causal``): a query sees its
     ``window`` newest keys, itself included; the reference takes it as a
-    mask, the kernels through flash2 alone."""
+    mask, the kernels through flash2 alone. Which of the two a shape took
+    is an ``attn_route`` note (``path``, and ``why`` where it is the plain
+    form: ``backend`` here; ``lse`` / ``shape`` / ``blocks`` where a backward
+    pass on the TPU fell to the reference's)."""
     _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    note = functools.partial(
+        obs_trace.get_tracer().note_once, "attn_route", tq=q.shape[2],
+        tk=k.shape[2], window=window,
+    )
     if jax.default_backend() != "tpu":
         # native autodiff, not a custom_vjp around the reference: that
         # would recompute the whole forward in every backward, where plain
         # differentiation reuses the saved activations
+        note(path="plain", why="backend")
         return attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
         )
     fwd_impl, bwd_impl = _route(
         q.shape[2], k.shape[2], window is not None, v.shape[3] != q.shape[3]
     )
+    note(path="kernel", forward=fwd_impl, backward=bwd_impl)
     return _auto(
         q, k, v, causal, scale, fwd_impl, bwd_impl, None, None, window
     )

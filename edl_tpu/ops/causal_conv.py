@@ -40,7 +40,9 @@ is, and the result is rounded once. Two implementations, one contract (value,
 
 Which one runs is decided from ``jax.default_backend()`` and the shapes, as
 ``ops/grouped_matmul.py`` decides; ``interpret`` runs the kernels in the
-Pallas interpreter (tests on the CPU).
+Pallas interpreter (tests on the CPU). Each shape says which it took in a
+``conv_shape`` note: ``path`` ``kernel`` or ``plain``, and on ``plain`` ``why``
+(``backend``, or ``blocks`` for a shape the kernels do not take).
 
 A third caller, the gated short-convolution mixer (``models/short_conv.py``),
 takes :func:`gated_causal_conv`: the same depthwise causal taps between two
@@ -88,17 +90,6 @@ def _plain(x, kernel, bias, offset):
     return jax.nn.silu(causal_conv(x, kernel, bias)).astype(x.dtype)
 
 
-@functools.lru_cache(maxsize=None)
-def _note_gated(batch, t, channels, taps, itemsize):
-    """One ``sconv_shape`` instant in the span ring for each shape the gated
-    convolution is traced at; ``bytes`` is one forward pass's least traffic
-    (three reads and one write of ``[B, T, C]``)."""
-    obs_trace.get_tracer().instant(
-        "sconv_shape", channels=channels, taps=taps, steps=t, batch=batch,
-        implementation="plain", bytes=4 * batch * t * channels * itemsize,
-    )
-
-
 def gated_causal_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
     """``C_g * causal_conv(B_g * x~, kernel)`` in ``x``'s dtype, ``[B, T, C]``,
     for ``x`` ``[B, T, 3 C]`` = ``[B_g | C_g | x~]`` and ``kernel`` ``[taps,
@@ -110,7 +101,14 @@ def gated_causal_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
         raise ValueError(
             "gated_causal_conv: x %s is not [B, T, 3 * %d]" % (x.shape, c)
         )
-    _note_gated(x.shape[0], x.shape[1], c, taps, jnp.dtype(x.dtype).itemsize)
+    # once a shape and stage; ``bytes`` is one forward pass's least traffic
+    # (three reads and one write of ``[B, T, C]``). Plain XLA is the only
+    # form there is, so no ``path``
+    obs_trace.get_tracer().note_once(
+        "sconv_shape", channels=c, taps=taps, steps=x.shape[1], batch=x.shape[0],
+        implementation="plain",
+        bytes=4 * x.shape[0] * x.shape[1] * c * jnp.dtype(x.dtype).itemsize,
+    )
     f32 = jnp.float32
     b_gate, c_gate, inner = (
         x[..., i * c:(i + 1) * c].astype(f32) for i in range(3)
@@ -396,8 +394,18 @@ def causal_conv_silu(
             % (x.shape, c, offset)
         )
     blocks = _blocks(x, kernel, offset)
-    if blocks is None or not (interpret or jax.default_backend() == "tpu"):
+    kernels = interpret or jax.default_backend() == "tpu"
+    # the first condition the dispatch did not meet, None where it met both
+    why = "backend" if not kernels else "blocks" if blocks is None else None
+    note = functools.partial(
+        obs_trace.get_tracer().note_once, "conv_shape", channels=c,
+        taps=kernel.shape[0], steps=x.shape[1], batch=x.shape[0], offset=offset,
+        dtype=str(x.dtype),
+    )
+    if why:
+        note(path="plain", why=why)
         return _plain(x, kernel, bias, offset)
+    note(path="kernel")
     b = jnp.zeros((c,), jnp.float32) if bias is None else bias.astype(jnp.float32)
     yt = _kernels(
         x.swapaxes(1, 2), kernel.astype(jnp.float32).T, b.reshape(c, 1),

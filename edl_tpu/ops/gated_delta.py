@@ -204,17 +204,6 @@ def saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch=1):
     return batch * (states + inverses + 2 * itemsize * chunks * chunk * heads * d_v)
 
 
-@functools.lru_cache(maxsize=None)
-def _note_chunks(chunk, chunks, heads, d_k, d_v, itemsize, batch):
-    """One ``gdn_chunks`` instant in the span ring for each shape the rule
-    is traced at."""
-    obs_trace.get_tracer().instant(
-        "gdn_chunks", chunk=chunk, chunks=chunks, heads=heads, d_k=d_k, d_v=d_v,
-        state_bytes=4 * heads * d_k * d_v, solve=SOLVE, carry="saved",
-        saved_bytes=saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch),
-    )
-
-
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
                      return_final_state: bool = False):
     """``o`` ``[B, T, H, d_v]`` in ``q``'s dtype (and the final state, float32
@@ -249,7 +238,14 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         )
     nc = (t + pad) // size
     f32, dtype = jnp.float32, q.dtype
-    _note_chunks(size, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch)
+    # once a shape and stage; plain XLA is the only form, so no ``path``
+    obs_trace.get_tracer().note_once(
+        "gdn_chunks", chunk=size, chunks=nc, heads=h, d_k=d_k, d_v=d_v,
+        state_bytes=4 * h * d_k * d_v, solve=SOLVE, carry="saved",
+        saved_bytes=saved_bytes(
+            size, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch
+        ),
+    )
     dot = dict(preferred_element_type=f32)
 
     # everything below: b batch, n chunk, c / s step in a chunk, h head,
@@ -318,18 +314,6 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
 # may let ``|g|`` reach, and ``KimiDeltaMixer`` holds its gate's bound to it.
 SUB_BLOCK = 16
 MAX_DECAY_A_STEP = 88.0 / SUB_BLOCK
-
-
-@functools.lru_cache(maxsize=None)
-def _note_kda_chunks(chunk, sub_block, chunks, heads, d_k, d_v, itemsize, batch, path):
-    """One ``kda_chunks`` instant in the span ring for each shape
-    :func:`kda_rule` is traced at; ``path`` says which form of the chunk-local
-    stage the shape took, ``"kernel"`` or ``"plain"``."""
-    obs_trace.get_tracer().instant(
-        "kda_chunks", chunk=chunk, sub_block=sub_block, chunks=chunks, heads=heads,
-        d_k=d_k, d_v=d_v, state_bytes=4 * heads * d_k * d_v, solve=SOLVE, path=path,
-        saved_bytes=saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch),
-    )
 
 
 def _local_plain(q, k, v, g, beta, size, sub):
@@ -780,19 +764,23 @@ def _local_kernels_bwd(interpret, residuals, cotangents):
 _local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
 
 
-def _kernels_take(q, k, v, steps, chunk, interpret):
-    """Whether the chunk-local stage of these operands is the kernels': on a
-    TPU backend (or in the interpreter), bfloat16 operands, the kernels' chunk
-    in sub-blocks of whole bfloat16 tiles, widths of whole lane tiles, a
-    length the chunk divides and the heads in pairs."""
-    return bool(
-        (interpret or jax.default_backend() == "tpu")
-        and q.dtype == k.dtype == v.dtype == jnp.bfloat16
-        and chunk == _KERNEL_CHUNK and SUB_BLOCK % 16 == 0 and chunk % SUB_BLOCK == 0
-        and steps % chunk == 0
-        and q.shape[2] % 2 == 0
-        and q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+def _kernels_refuse(q, k, v, steps, chunk, interpret):
+    """Why the chunk-local stage of these operands is not the kernels', or
+    None where it is: the first of a TPU backend or the interpreter
+    (``backend``), bfloat16 operands (``dtype``), the kernels' chunk in
+    sub-blocks of whole bfloat16 tiles (``chunk``), a length the chunk divides
+    (``steps``), the heads in pairs (``heads_odd``) and widths of whole lane
+    tiles (``width``) that does not hold."""
+    conditions = (
+        ("backend", interpret or jax.default_backend() == "tpu"),
+        ("dtype", q.dtype == k.dtype == v.dtype == jnp.bfloat16),
+        ("chunk", chunk == _KERNEL_CHUNK and SUB_BLOCK % 16 == 0
+         and chunk % SUB_BLOCK == 0),
+        ("steps", steps % chunk == 0),
+        ("heads_odd", q.shape[2] % 2 == 0),
+        ("width", q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0),
     )
+    return next((why for why, met in conditions if not met), None)
 
 
 def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
@@ -869,7 +857,8 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError("kda_rule: chunk %d is not a power of two" % chunk)
     sub = min(SUB_BLOCK, chunk)  # both powers of two: it divides the chunk
-    kernels = _kernels_take(q, k, v, t, chunk, interpret)
+    why_plain = _kernels_refuse(q, k, v, t, chunk, interpret)
+    kernels = why_plain is None
     pad = -t % chunk
     if pad:
         q, k, v, g, beta = (
@@ -879,10 +868,20 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     steps = t + pad
     size, nc = chunk, steps // chunk
     f32, dtype = jnp.float32, q.dtype
-    _note_kda_chunks(
-        size, sub, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch,
-        "kernel" if kernels else "plain",
+    # once a shape and stage: which form the chunk-local stage took, and why
+    # where it is the plain one
+    note = functools.partial(
+        obs_trace.get_tracer().note_once, "kda_chunks", chunk=size,
+        sub_block=sub, chunks=nc, heads=h, d_k=d_k, d_v=d_v,
+        state_bytes=4 * h * d_k * d_v, solve=SOLVE,
+        saved_bytes=saved_bytes(
+            size, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch
+        ),
     )
+    if kernels:
+        note(path="kernel")
+    else:
+        note(path="plain", why=why_plain)
     dot = dict(preferred_element_type=f32)
 
     # b batch, n chunk, c / s step in a chunk, h head, k key width, v value width

@@ -19,12 +19,14 @@ Two implementations, one contract (value, ``d lhs``, ``d rhs``):
 
 Which one runs is decided from ``jax.default_backend()`` when the caller
 names none; the argument is for tests and for the probe that times both
-on the chip (``benchmark/tools/grouped_matmul_probe.py``).
+on the chip (``benchmark/tools/grouped_matmul_probe.py``). Each shape says
+which it took in its ``gmm_tiles`` note: ``path`` ``kernel`` or ``plain``,
+and on ``plain`` ``why`` (``backend``, or ``asked`` by the caller).
 """
 
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -83,13 +85,13 @@ def _fit(tiling: Tuple[int, int, int], m: int, k: int, n: int):
     return min(tm, m), _whole(tk, k), _whole(tn, n)
 
 
-@lru_cache(maxsize=None)
 def _note_tiles(kernel: str, m: int, k: int, n: int, tiling):
     """One ``gmm_tiles`` instant in the span ring for each shape a Megablox
-    kernel is traced at, with the tiling it was given."""
-    obs_trace.get_tracer().instant(
+    kernel is traced at in a stage (``note_once``), with the tiling it was
+    given."""
+    obs_trace.get_tracer().note_once(
         "gmm_tiles", kernel=kernel, rows=m, contracting=k, columns=n,
-        tiling=list(tiling),
+        tiling=list(tiling), path="kernel",
     )
     return tiling
 
@@ -164,11 +166,16 @@ def grouped_matmul(
             "grouped_matmul: lhs %s and rhs %s are not [M, K] and [G, K, N]"
             % (lhs.shape, rhs.shape)
         )
+    why = "asked"  # the caller named the plain form itself
     if implementation is None:
-        implementation = default_implementation()
+        implementation, why = default_implementation(), "backend"
     group_sizes = group_sizes.astype(jnp.int32)
     rhs = rhs.astype(lhs.dtype)
     if implementation == "ragged_dot":
+        obs_trace.get_tracer().note_once(
+            "gmm_tiles", kernel="ragged_dot", rows=lhs.shape[0],
+            contracting=lhs.shape[1], columns=rhs.shape[2], path="plain", why=why,
+        )
         return jax.lax.ragged_dot(
             lhs, rhs, group_sizes, preferred_element_type=jnp.float32
         ).astype(lhs.dtype)

@@ -929,15 +929,15 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 # -- the whole --------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=None)
 def _note_shape(tq, topk, index_heads, index_dim, select, index_lse,
                 score_bytes, mask_bytes, index_blocks, fwd_blocks, bwd_blocks,
                 select_rows, target_heads_step):
     """One ``dsa_shape`` instant in the span ring for each shape the
-    selection is traced at (``index_lse``: where the row sums of the indexer's
-    softmax are made; ``target_*``: the target kernel's tile, the heads a grid
-    step of it takes and the strip their sum is one value over)."""
-    obs_trace.get_tracer().instant(
+    selection's kernels are traced at in a stage (``note_once``;
+    ``index_lse``: where the row sums of the indexer's softmax are made;
+    ``target_*``: the target kernel's tile, the heads a grid step of it takes
+    and the strip their sum is one value over)."""
+    obs_trace.get_tracer().note_once(
         "dsa_shape", tq=tq, topk=topk, index_heads=index_heads,
         index_dim=index_dim, select=select, index_lse=index_lse,
         score_bytes=score_bytes,
@@ -946,6 +946,7 @@ def _note_shape(tq, topk, index_heads, index_dim, select, index_lse,
         select_rows=select_rows, target_blocks=list(index_blocks),
         target_heads_step=target_heads_step,
         target_strip=[_target_rows(index_blocks[0]), index_blocks[1]],
+        path="kernel",
     )
 
 
@@ -1007,7 +1008,8 @@ def sparse_attention(q, k, v, index_q, index_k, index_w, topk: int, scale=None,
     [B, T, Di]``, ``index_w [B, T, J]``; ``stats`` holds the scalars
     ``selected_share`` and ``tile_live``. On the TPU the kernels run wherever
     they tile ``T``; off it the reference does (``interpret=True``: the
-    kernels, interpreted, as the tests run them). ``blocks``: ``((fwd block_q,
+    kernels, interpreted, as the tests run them); the ``dsa_shape`` note says
+    which (``path``, and on ``plain`` ``why``: ``backend`` or ``blocks``). ``blocks``: ``((fwd block_q,
     block_k), (bwd block_q, block_k))`` in place of flash2's sweep."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     _gqa_group(q, k)
@@ -1016,6 +1018,11 @@ def sparse_attention(q, k, v, index_q, index_k, index_w, topk: int, scale=None,
         q.shape[2], q.shape[3], q.dtype.itemsize, blocks
     )
     if not plan:
+        obs_trace.get_tracer().note_once(
+            "dsa_shape", tq=q.shape[2], topk=topk, index_heads=index_q.shape[1],
+            index_dim=index_q.shape[3], path="plain",
+            why="blocks" if kernels else "backend",
+        )
         return sparse_attention_reference(
             q, k, v, index_q, index_k, index_w, topk, scale
         )

@@ -121,6 +121,30 @@ def _lower_step(step, state, device_batch, compile: bool):
     return lowered, compiled
 
 
+#: seconds a stage that trained to its end waits for its census thread
+CENSUS_JOIN_S = 30.0
+
+
+def _publish_step_census(tracer, notes, plan, env, dropped) -> None:
+    """The census thread's body: ``obs_profile.publish_step_census``, which
+    must never take the stage down. A stage that left early (a resize, an
+    exception) has set ``dropped`` and did not wait: its census is not
+    published into the next stage's ring and gauges."""
+    try:
+        obs_profile.step_program()  # the seconds: the text and the pass over it
+        if dropped.is_set():
+            return
+        obs_profile.publish_step_census(
+            tracer, notes, plan.by_kind() if plan is not None else None,
+            stage=env.stage, world=env.world_size,
+        )
+    except Exception as exc:  # noqa: BLE001 — telemetry, never a correctness dependency
+        print(
+            "elastic-trainer: no census of the compiled step (%s)" % exc,
+            file=sys.stderr,
+        )
+
+
 def _record_step_launch(tracer: obs_trace.SpanTracer) -> None:
     """``step_launch``: what the step's first call does after jax's last
     compile event inside it has ended and before it returns — the loaded
@@ -173,16 +197,25 @@ class RetireClock:
     def start_epoch(self) -> None:
         self._last = None
 
-    def mark(self, step: int, t: float, epoch: int) -> Optional[float]:
+    def mark(
+        self, step: int, t: float, epoch: int,
+        gauges: Optional[Dict[str, float]] = None,
+    ) -> Optional[float]:
         """Step ``step`` had retired at ``t``. Returns seconds a step since
         the previous mark (None for an epoch's first), observes it into
-        ``edl_train_step_seconds`` and leaves a ``step_retired`` instant."""
+        ``edl_train_step_seconds`` and leaves a ``step_retired`` instant.
+        ``gauges`` is what the model sowed in that step (``moe_held_load_max``,
+        ``dsa_tile_live``, ...), as the wait that made the mark brought it
+        back: the instant carries it, so the ring holds mark by mark the
+        seconds a step beside the routing the step ran under."""
         last, self._last = self._last, (step, t)
         derived = {}
         if last is not None and step > last[0]:
             steps = step - last[0]
             derived = {"steps": steps, "seconds_per_step": (t - last[1]) / steps}
             _M_STEP_SECONDS.observe(derived["seconds_per_step"])
+        if gauges:
+            derived["gauges"] = {k: float(v) for k, v in gauges.items()}
         self._tracer.instant("step_retired", step=step, epoch=epoch, **derived)
         return derived.get("seconds_per_step")
 
@@ -384,6 +417,8 @@ class ElasticTrainer:
 
         env = init()
         t_setup = time.monotonic()  # train_setup trace segment starts here
+        # a stage traces its step anew: its shapes are noted anew
+        obs_trace.get_tracer().reset_notes()
         mesh = make_mesh(self._mesh_axes)
         mngr = (
             CheckpointManager(self._ckpt_dir, async_save=self._async_save)
@@ -406,6 +441,8 @@ class ElasticTrainer:
         step_telemetry: Optional[obs_profile.StepTelemetry] = None
         capture: Optional[obs_profile.CaptureController] = None
         ladder = None  # AOT resize ladder, armed after the first step
+        census = None  # the compiled step's census, on its own thread
+        census_dropped = threading.Event()
         # memory plane: compile-time plan + census/watermarks + OOM
         # forensics, per stage
         mem_plane: Optional[obs_memory.MemoryPlane] = None
@@ -601,7 +638,10 @@ class ElasticTrainer:
                                 steps_done, bundle, epoch=epoch
                             )
                             if fetched is not None:
-                                retired.mark(*fetched, epoch=epoch)
+                                retired.mark(
+                                    fetched[0], fetched[1], epoch=epoch,
+                                    gauges=fetched[2],
+                                )
                         # dispatch to dispatch: the loop runs ahead of the
                         # device, so this is the host's interval, not the
                         # step's (RetireClock has that)
@@ -650,12 +690,27 @@ class ElasticTrainer:
                                 step_telemetry.set_cost(
                                     obs_profile.step_cost(lowered)
                                 )
+                                plan = None
                                 if compiled is not None:
-                                    mem_plane.harvest(
+                                    plan = mem_plane.harvest(
                                         compiled, world=env.world_size
                                     )
                                     obs_profile.set_step_executable(compiled)
                                 relower.args["compiled"] = compiled is not None
+                            if compiled is not None:
+                                # the census of the compiled step: its text
+                                # and a pass over it take seconds, so on a
+                                # thread of its own (a stage that trains to
+                                # its end waits for it); the stage's notes as
+                                # they stand now, before the ladder's thread
+                                # traces other worlds
+                                census = threading.Thread(
+                                    target=_publish_step_census,
+                                    args=(tracer, tracer.notes(), plan, env,
+                                          census_dropped),
+                                    name="edl-step-census", daemon=True,
+                                )
+                                census.start()
                             # steady state reached: speculatively compile
                             # the N±1/N±2 neighbor worlds into the
                             # persistent cache on a low-priority thread
@@ -702,15 +757,17 @@ class ElasticTrainer:
                             "epoch_sync", epoch=epoch, step=step_idx - 1
                         ):
                             jax.block_until_ready(metrics)
-                        retired.mark(
-                            steps_done - 1, time.monotonic(), epoch=epoch
-                        )
+                        t_synced = time.monotonic()
                         # what the model sows (aux_loss, moe_load_max), as
                         # gauges: the values have just been waited for
-                        obs_numerics.publish_sown({
+                        sown = {
                             name: np.asarray(metrics[name])
                             for name in state.sown if name in metrics
-                        })
+                        }
+                        retired.mark(
+                            steps_done - 1, t_synced, epoch=epoch, gauges=sown
+                        )
+                        obs_numerics.publish_sown(sown)
                     if env.is_rank0 and self._log and metrics:
                         print(
                             "epoch %d %s"
@@ -745,8 +802,13 @@ class ElasticTrainer:
                 if mngr is not None:
                     mngr.wait()
                 obs_goodput.close(cause="complete")
+                if census is not None:
+                    # bounded, and here alone: a stage that is leaving for a
+                    # resize or on an exception waits for no telemetry
+                    census.join(timeout=CENSUS_JOIN_S)
                 return state
         finally:
+            census_dropped.set()
             if probe is not None:
                 probe.close()
             if ladder is not None:

@@ -13,7 +13,6 @@ equivalent of the reference's AMP + loss-scaling flags
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -92,18 +91,6 @@ def taken_apart(leaf) -> bool:
     )
 
 
-@functools.lru_cache(maxsize=None)
-def _note_apart(leaves, of, nbytes, largest_bytes, min_width, max_elements):
-    """One ``grad_apart`` instant in the span ring for each tree a step is
-    traced over: how many of its leaves ``grads_apart`` took, and their
-    float32 bytes."""
-    obs_trace.get_tracer().instant(
-        "grad_apart", leaves=leaves, of=of, bytes=nbytes,
-        largest_bytes=largest_bytes, min_width=min_width,
-        max_elements=max_elements,
-    )
-
-
 def grads_apart(grads):
     """``grads`` with every leaf the rule takes (``taken_apart``) behind an
     ``optimization_barrier`` of its own: the identity, and a fence. Left to
@@ -117,9 +104,12 @@ def grads_apart(grads):
     gradient live at once."""
     leaves = jax.tree_util.tree_leaves(grads)
     sizes = [4 * leaf.size for leaf in leaves if taken_apart(leaf)]
-    _note_apart(
-        len(sizes), len(leaves), sum(sizes), max(sizes, default=0),
-        GRAD_APART_MIN_WIDTH, GRAD_APART_MAX_ELEMENTS,
+    # once a tree a step is traced over, and stage: how many of its leaves
+    # the rule took, and their float32 bytes
+    obs_trace.get_tracer().note_once(
+        "grad_apart", leaves=len(sizes), of=len(leaves), bytes=sum(sizes),
+        largest_bytes=max(sizes, default=0), min_width=GRAD_APART_MIN_WIDTH,
+        max_elements=GRAD_APART_MAX_ELEMENTS,
     )
     return jax.tree_util.tree_map(
         lambda g: jax.lax.optimization_barrier(g) if taken_apart(g) else g, grads

@@ -447,7 +447,7 @@ def test_one_attn_tiles_instant_a_traced_shape(backward):
     at: kernel, shapes, blocks, window, the three shares and the masked
     share of what is walked; the fused backward also the bytes of the dq
     accumulator it holds, and a call that fell back to dq and dk/dv theirs."""
-    A._note_tiles.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     ring = obs_trace.get_tracer()
     seen = lambda: [  # noqa: E731
         e["args"] for e in ring.to_events()

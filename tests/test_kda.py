@@ -171,7 +171,7 @@ def test_the_scalar_rules_carry_is_what_it_was():
 
 
 def test_each_traced_shape_leaves_one_kda_chunks_instant():
-    G._note_kda_chunks.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "kda_chunks"])
     args = rule_inputs(t=64)
@@ -347,7 +347,7 @@ def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path, mon
     elif why == "three_heads":
         h = 3
     args = narrow(rule_inputs(seed=8, b=1, t=t, h=h, d_k=d, d_v=d))
-    G._note_kda_chunks.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "kda_chunks"])
     lowered = jax.jit(lambda *a: kda_rule(*a, chunk=chunk, interpret=interpret)).lower(*args)
@@ -660,7 +660,7 @@ def test_the_latent_layers_rotation_is_at_the_specs_base_and_shared_by_the_heads
 
 def test_each_traced_shape_leaves_one_mla_shape_instant(mla_layer):
     layer, params, x, positions = mla_layer
-    T._note_mla_shape.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "mla_shape"])
     for _ in range(2):

@@ -406,7 +406,7 @@ def test_megablox_at_width_1536_with_ragged_and_empty_groups(shape, what):
 
 
 def test_each_traced_shape_leaves_one_gmm_tiles_instant_a_kernel():
-    gmm_module._note_tiles.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "gmm_tiles"])
     sizes = jnp.asarray([100, 156], jnp.int32)
@@ -595,7 +595,7 @@ def test_the_compiled_step_names_the_mixers_scopes(scope):
 
 
 def test_each_traced_shape_leaves_one_sconv_shape_instant():
-    conv_module._note_gated.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "sconv_shape"])
     x, w, _ = conv_inputs(jnp.bfloat16, b=1, t=256, c=128)

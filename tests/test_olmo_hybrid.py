@@ -259,7 +259,7 @@ def test_the_rule_refuses_what_it_cannot_compute(fault):
 
 
 def test_each_traced_shape_leaves_one_gdn_chunks_instant():
-    rule_module._note_chunks.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "gdn_chunks"])
     args = rule_inputs(t=40)

@@ -244,7 +244,7 @@ def test_shapes_the_kernels_cannot_tile_take_the_plain_form(monkeypatch):
 
 
 def test_a_traced_shape_leaves_one_dsa_shape_instant(small_tiles):
-    S._note_shape.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     ring = obs_trace.get_tracer()
     seen = lambda: [e["args"] for e in ring.to_events() if e["name"] == "dsa_shape"]  # noqa: E731
     before = len(seen())
@@ -338,7 +338,7 @@ def test_an_unpicked_key_whose_exponent_passes_float32_leaves_the_target_finite(
 
 
 def test_the_masked_kernels_keep_emitting_attn_tiles(small_tiles):
-    S._note_tiles.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     ring = obs_trace.get_tracer()
     seen = lambda: [e["args"] for e in ring.to_events() if e["name"] == "attn_tiles"]  # noqa: E731
     before = len(seen())

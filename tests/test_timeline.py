@@ -442,10 +442,9 @@ def test_tracing_a_step_leaves_one_dw_apart_instant_a_shape():
     """``q`` and ``g`` share a shape and each has an instant of its own, as
     ``k`` and ``v``, and ``o`` has its; two layers and a second trace add none."""
     from edl_tpu.models import ArchSpec, TransformerLM
-    from edl_tpu.models import transformer
     from edl_tpu.train import cross_entropy_loss
 
-    transformer._note_dw_apart.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "dw_apart"])
     lm = TransformerLM(
@@ -467,7 +466,7 @@ def test_tracing_a_step_leaves_one_dw_apart_instant_a_shape():
         {"kernel": "v", "shape": [24, 2, 8], "dtype": "bfloat16", "bytes": 768},
     ]
     # a forward-only program holds no fence and notes nothing
-    transformer._note_dw_apart.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     jax.jit(lambda p: lm.apply({"params": p}, tokens)).lower(state.params)
     assert len([e for e in tracer.to_events() if e["name"] == "dw_apart"]) == before + 5
 

@@ -476,15 +476,8 @@ def _head_projection_gradients(text):
     projection's kernel shape and are named after its ``dot_general``."""
     from edl_tpu.obs import profile as obs_profile
 
-    matmuls, updates, computation = set(), set(), None
-    for line in text.splitlines():
-        started = obs_profile._HLO_COMPUTATION.match(line)
-        if started:
-            computation = started.group(1)
-        elif obs_profile._HLO_MATMUL.search(line):
-            matmuls.add(computation)
-        elif 'op_name="jit(step)/optimizer/' in line:
-            updates.add(computation)
+    program = obs_profile.HloProgram(text)
+    matmuls, updates = program.matmuls, program.updates
     found = {}
     for line in text.splitlines():
         named = re.search(
@@ -716,7 +709,7 @@ def test_the_target_takes_every_head_in_a_grid_step_under_a_limit_from_the_shape
     def sds(dims, dtype=jnp.bfloat16, **kw):
         return jax.ShapeDtypeStruct(dims, dtype, **kw)
 
-    S._note_shape.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     ring = obs_trace.get_tracer()
     before = len([e for e in ring.to_events() if e["name"] == "dsa_shape"])
     jax.eval_shape(

@@ -661,7 +661,7 @@ def test_tracing_a_step_leaves_one_grad_apart_instant(monkeypatch):
     from edl_tpu.train import step as step_module
 
     monkeypatch.setattr(step_module, "GRAD_APART_MIN_WIDTH", 8)
-    step_module._note_apart.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "grad_apart"])
     state, batch = _apart_state_and_batch(False)

@@ -433,12 +433,11 @@ def test_the_toy_twins_step_fences_its_head_projections_and_nothing_else(unfence
     unfenced step's (the model sows, so the step is never split), the ring
     names ``q``, ``k``, ``v``, ``g``, ``o`` and no bank ``[E, d, w]``, and
     the tree is the unfenced model's."""
-    from edl_tpu.models import transformer
     from edl_tpu.obs import trace as obs_trace
 
     job = family.build(TOY, 1, 0)
     batch = family.host_batches(TOY, 1, 0, n_batches=1)[0]
-    transformer._note_dw_apart.cache_clear()
+    obs_trace.get_tracer().reset_notes()
     tracer = obs_trace.get_tracer()
     before = len([e for e in tracer.to_events() if e["name"] == "dw_apart"])
     barriers, trees = [], []
