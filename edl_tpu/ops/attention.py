@@ -15,7 +15,8 @@ whole K and V of its head in VMEM and loops over kv blocks with
 ``_flash2_kernel`` forward, ``_flash2_bwd_kernel`` backward) puts the kv
 blocks (backward: the q blocks) on a third, innermost grid dimension, so
 that they are copied block by block behind the compute; it is the one that
-runs past ``_WHOLE_KV_MAX_SEQ`` and the one that takes a **window**
+runs past ``_WHOLE_KV_FWD_MAX_TQ`` rows (every benchmark cell's calls) and
+the one that takes a **window**
 (``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
 ``i - W < j <= i``). Under a mask its innermost steps are **spans** of the
 other side that start where a block's first visible key (or row) lies, at
@@ -1124,15 +1125,20 @@ def _kernel_blocks(tq: int):
             return fwd, bwd
 
 
-# flash2 (grid-pipelined) blocks — swept separately at seq 8192 (the
-# regime flash2 owns: the whole-KV kernel does not compile there).
-# bk=1024 is safe for flash2 (KV streams through the grid, constant
-# VMEM) where it crashed the compiler for the whole-KV kernel; the
-# (128, 512) flash defaults left 2.4x fwd / 2.6x fwd+bwd on the table.
-# ``_BWD``: the fused backward's (PR 34's sweep, heads of 128 and 64:
-# bench_results/README.md), and dk/dv's where a head's dq does not fit the
-# chip; ``_DQ``: dq's there.
-_FLASH2_BLOCKS_FWD = (256, 1024)
+# flash2 (grid-pipelined) blocks, full-causal. ``_FWD``: PR 48's sweep at the
+# seven shapes the benchmark's cells call it at (T = 4096 to 16384, heads of
+# 64, 128 and 192 / 128, GQA 1:1 to 8:1, and the masked copy in
+# ops/sparse_attention.py; bench_results/README.md, "the forward's blocks"):
+# 1024 rows x 1024 keys read fastest at every one, the kernel alone and in
+# one program with its backward, 16-37% under the 256 x 1024 that the first
+# sweep (seq 8192, bq up to 512) had left. A row block's keys stream through
+# the matrix unit once a block, so fewer, taller blocks copy less and pay
+# fewer online-softmax updates; 2048 keys a step lose to 1024 from 512 rows
+# on. The tile fits Mosaic's default VMEM limit at float32 and at a head of
+# 256 (compiled for a described v5e). ``_BWD``: the fused backward's (PR 34's
+# sweep, heads of 128 and 64, T = 8192 and 4096: same file), and dk/dv's
+# where a head's dq does not fit the chip; ``_DQ``: dq's there.
+_FLASH2_BLOCKS_FWD = (1024, 1024)
 _FLASH2_BLOCKS_BWD = (1024, 1024)
 _FLASH2_BLOCKS_DQ = (512, 1024)
 
@@ -1436,39 +1442,6 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, block_k):
-    out, _ = _flash_forward(
-        q, k, v, causal, scale, block_q, block_k, _interpret()
-    )
-    return out
-
-
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_forward(
-        q, k, v, causal, scale, block_q, block_k, _interpret()
-    )
-    return _name_residuals(q, k, v, out, lse)
-
-
-def _flash_bwd(causal, scale, block_q, block_k, residuals, g):
-    q, k, v, o, lse = residuals
-    if lse is None:  # ragged-shape fallback: differentiate the reference
-        _, vjp = jax.vjp(
-            lambda q, k, v: attention_reference(
-                q, k, v, causal=causal, scale=scale
-            ),
-            q, k, v,
-        )
-        return vjp(g)
-    return _flash_backward(
-        q, k, v, o, lse, g, causal, scale, block_q, block_k, _interpret()
-    )
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
-
-
 def flash_with_lse(
     q: jax.Array,
     k: jax.Array,
@@ -1521,31 +1494,22 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention; falls back to the reference on ragged shapes.
 
-    Default blocks come from the measured per-seq table (``_BLOCK_TABLE``,
-    v5e on-chip bq x bk sweep): e.g. bq=512 halves the forward at seq
-    2048 vs the old fixed 128. Explicit block args win — including past
-    the whole-KV compile limit, where they reach the flash2 kernels.
-    A ``window`` is served by the flash2 kernels at every length (see
-    :func:`_whole_kv_serves`)."""
+    The kernels are the ones :func:`_route` gives the call's shapes, as in
+    :func:`attention` on the TPU (here on every backend: off the TPU they
+    run in the interpreter), so what a caller checks under this name is
+    what a step runs. Default blocks come from each family's measured table
+    (``_BLOCK_TABLE``, :func:`_flash2_blocks`); explicit block args win, in
+    the forward and in the backward, whichever family takes the call."""
     _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    tq, tk, windowed = q.shape[2], k.shape[2], window is not None
-    two_widths = v.shape[3] != q.shape[3]
-    if not _whole_kv_serves(tq, tk, windowed, two_widths):
-        # the same contract through the grid-pipelined kernels, which fill
-        # any unspecified block from their own measured defaults
-        # (_flash2_blocks)
-        blocks = (block_q, block_k)
-        return _auto(
-            q, k, v, causal, scale, *_route(tq, tk, windowed, two_widths),
-            blocks, blocks, window,
-        )
-    if block_q is None or block_k is None:
-        (fbq, fbk), _ = _kernel_blocks(tq)
-        block_q = block_q or fbq
-        block_k = block_k or fbk
-    return _flash(q, k, v, causal, scale, block_q, block_k)
+    blocks = (block_q, block_k)
+    return _auto(
+        q, k, v, causal, scale,
+        *_route(q.shape[2], k.shape[2], window is not None,
+                v.shape[3] != q.shape[3]),
+        blocks, blocks, window,
+    )
 
 
 # -- routing ----------------------------------------------------------------
@@ -1554,10 +1518,15 @@ def flash_attention(
 # past it at every block config, while flash2's VMEM footprint does not grow
 # with the sequence. Feasibility, not speed.
 _WHOLE_KV_MAX_SEQ = 4096
-# Longest ``tq`` whose forward stays whole-KV where both families compile:
-# the July calibration (bench_results/attention_dispatch_r4.json: v5e,
-# [4, 16, T, 64], T 1024-4096). Moved by editing it in a PR the benchmark
-# measures in every cell (ROADMAP S7), not by configuration.
+# Longest ``tq`` the whole-KV family keeps where both families compile: the
+# July calibration's forward crossover (bench_results/attention_dispatch_r4.json:
+# v5e, [4, 16, T, 64], T 1024-4096). What is left of ROADMAP S5(a): above it
+# a call is flash2's, forward and backward (PR 34's probes at the two cells
+# of T = 4096: the fused backward 10.73 ms against the whole-KV pair's 19.40,
+# 5.85 against 10.46); at and under it no benchmark cell runs and no probe
+# has measured the fused backward against the pair, so the crossover stands
+# where July put it. Moved by editing it in a PR the benchmark measures in
+# every cell, not by configuration.
 _WHOLE_KV_FWD_MAX_TQ = 2048
 
 
@@ -1574,12 +1543,27 @@ def _whole_kv_serves(tq: int, tk: int, windowed: bool = False,
 
 def _route(tq: int, tk: int, windowed: bool,
            two_widths: bool = False) -> tuple[str, str]:
-    """``(fwd_impl, bwd_impl)`` for a call on the TPU, from what the call
-    can observe. The only code that names an implementation: ``"flash"`` is
-    the whole-KV family, ``"flash2"`` the grid-pipelined one."""
-    if not _whole_kv_serves(tq, tk, windowed, two_widths):
-        return "flash2", "flash2"
-    return ("flash" if tq <= _WHOLE_KV_FWD_MAX_TQ else "flash2"), "flash"
+    """``(fwd_impl, bwd_impl)`` for a call, from what the call can observe.
+    The only code that names an implementation: ``"flash"`` is the whole-KV
+    family, ``"flash2"`` the grid-pipelined one. A call is of one family,
+    forward and backward: the whole-KV one where it serves and ``tq`` is at
+    most ``_WHOLE_KV_FWD_MAX_TQ``, flash2 everywhere else (which of flash2's
+    backward kernels, the fused one or the pair, is :func:`_fused_bwd_vmem`'s
+    to say)."""
+    if tq <= _WHOLE_KV_FWD_MAX_TQ and _whole_kv_serves(
+        tq, tk, windowed, two_widths
+    ):
+        return "flash", "flash"
+    return "flash2", "flash2"
+
+
+def _whole_kv_blocks(kind: str, tq: int, given=None):
+    """``(block_q, block_k)`` of a whole-KV kernel (``kind`` ``"fwd"`` or
+    ``"bwd"``, as in :func:`_flash2_blocks`): what the caller ``given`` (a
+    pair, either of it ``None``) wins, then ``_BLOCK_TABLE``'s."""
+    table = _kernel_blocks(tq)[kind == "bwd"]
+    given = given or (None, None)
+    return given[0] or table[0], given[1] or table[1]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -1587,7 +1571,8 @@ def _auto(q, k, v, causal, scale, fwd_impl, bwd_impl,
           fwd_blocks=None, bwd_blocks=None, window=None):
     """``fwd_blocks``/``bwd_blocks`` are optional (bq, bk) overrides for
     the kernel impls (hashable tuples — they ride nondiff_argnums);
-    ``None`` means the measured defaults for that impl. ``window`` is
+    ``None``, for a pair or for either of it, means the measured defaults
+    for that impl. ``window`` is
     taken by ``"flash2"`` and by the reference's vjp (:func:`_route` gives
     a windowed call no other)."""
     return _auto_fwd(
@@ -1613,7 +1598,7 @@ def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
             q, k, v, causal, scale, f2q, f2k, _interpret(), window
         )
     else:
-        fbq, fbk = fwd_blocks or _kernel_blocks(q.shape[2])[0]
+        fbq, fbk = _whole_kv_blocks("fwd", q.shape[2], fwd_blocks)
         out, lse = _flash_forward(
             q, k, v, causal, scale, fbq, fbk, _interpret()
         )
@@ -1657,7 +1642,7 @@ def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
                 window, dkv_blocks,
             )
     if kernels and bwd_impl == "flash":
-        bbq, bbk = bwd_blocks or _kernel_blocks(tq)[1]
+        bbq, bbk = _whole_kv_blocks("bwd", tq, bwd_blocks)
         bq, bk = _fit_block(bbq, tq), _fit_block(bbk, tk)
         if not (tq % bq or tk % bk):
             return _flash_backward(

@@ -261,6 +261,10 @@ SPANS = [
     pytest.param(8192, 8192, None, (256, 1024), (512, 1024), id="full"),
     pytest.param(8192, 8192, None, (256, 1024), (1024, 1024), id="full-bwd"),
     pytest.param(4096, 4096, None, (256, 1024), (1024, 1024), id="full4096-bwd"),
+    # the blocks a full-causal call takes since PR 48's forward sweep
+    pytest.param(8192, 8192, None, (1024, 1024), (1024, 1024), id="full-shipped"),
+    pytest.param(4096, 4096, None, (1024, 1024), (1024, 1024), id="full4096-shipped"),
+    pytest.param(16384, 16384, None, (1024, 1024), (1024, 1024), id="full16384-shipped"),
     pytest.param(8192, 8192, 2048, (512, 2560), (1280, 512), id="window2048"),
     pytest.param(8192, 8192, 2048, (256, 2304), (512, 1024), id="window2048-dq"),
     pytest.param(8192, 8192, 1000, (512, 1536), (768, 512), id="window1000"),
@@ -322,6 +326,8 @@ def test_spans_cover_what_the_mask_leaves_and_dead_steps_hold_still(
                     repeats += side == "kv"
     if (tq, window, kv_side) == (8192, None, (256, 1024)):
         assert repeats == 112
+    if (tq, window, kv_side) == (8192, None, (1024, 1024)):
+        assert repeats == 28  # of 64: q block i of eight leaves 7 - i dead
 
 
 def test_a_windowed_calls_blocks_come_from_the_window_and_the_shapes():
@@ -337,7 +343,8 @@ def test_a_windowed_calls_blocks_come_from_the_window_and_the_shapes():
     assert blocks(8192, 8192, 2048) == ((512, 2560), (256, 2304), (1280, 512))
     assert blocks(8192, 8192, 1000) == ((512, 1536), (256, 1280), (768, 512))
     assert blocks(32768, 32768, 4096) == ((512, 2304), (256, 2176), (1152, 512))
-    assert blocks(8192, 8192, None) == ((256, 1024), (512, 1024), (1024, 1024))
+    assert blocks(8192, 8192, None) == ((1024, 1024), (512, 1024), (1024, 1024))
+    assert blocks(4096, 4096, None) == ((1024, 1024), (512, 1024), (1024, 1024))
     for bq, bk in blocks(8192, 8192, 8192) + blocks(32, 32, 8):
         assert 8192 % bq == 0 and 8192 % bk == 0
     assert A._flash2_blocks("fwd", 8192, 8192, 2048, (None, 1024)) == (512, 1024)

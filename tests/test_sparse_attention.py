@@ -232,7 +232,7 @@ def test_shapes_the_kernels_cannot_tile_take_the_plain_form(monkeypatch):
     for name, real in (("_INDEX_BLOCKS", (512, 512)), ("_SELECT_ROWS", 128), ("_SELECT_CHUNK", 2048)):
         monkeypatch.setattr(S, name, real)   # whatever a fixture of this module left
     assert S._kernel_plan(16384, 128, 2) == {
-        "fwd": (256, 1024), "bwd": (1024, 1024), "index": (512, 512),
+        "fwd": (1024, 1024), "bwd": (1024, 1024), "index": (512, 512),
         "rows": 128, "chunk": 2048,
     }
     assert S._kernel_plan(100, 32, 4) is None            # no block divides it

@@ -94,8 +94,8 @@ CASES = [
         ("granite", GRANITE, ("flash2",)),
         # the other three LM cells' shapes, as their steps call the kernels
         ("trinity_full", TRINITY, ("flash2",)),
-        # (the packaged dispatch gives both a flash2 forward at T = 4096 and
-        # the whole-KV backward)
+        # (the route gives both the flash2 pair at T = 4096 since PR 48: the
+        # whole-KV cases record that the family still compiles there)
         ("mistral", MISTRAL, ("flash", "flash2")),
         ("olmoe", OLMOE, ("flash", "flash2")),
         ("olmo_hybrid", OLMO_HYBRID, ("flash2",)),
@@ -143,6 +143,31 @@ def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
     assert _kernel_names(lowered.as_text()) == want
     compiled = lowered.compile()  # raises what the chip's compiler would
     assert compiled.as_text().count("tpu_custom_call") == len(want)
+
+
+@pytest.mark.parametrize("shape", [MISTRAL, OLMOE], ids=["mistral", "olmoe"])
+def test_a_call_at_4096_compiles_for_v5e_as_one_forward_and_one_fused_backward(
+    one_chip, monkeypatch, shape
+):
+    """The two cells of T = 4096 through the entry point their kernel check
+    calls: `flash_attention` asks `_route`, and value and gradients are two
+    custom calls, the flash2 forward and the one fused backward (where the
+    whole-KV dq and dkv stood until PR 48), with the blocks the route's
+    tables give."""
+    b, h, h_kv, t, d = shape
+    monkeypatch.setattr(A, "_interpret", lambda: False)
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def value_and_grads(q, k, v, w):
+        out, vjp = jax.vjp(lambda q, k, v: A.flash_attention(q, k, v, causal=True), q, k, v)
+        return (out, *vjp(w))
+
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    lowered = jax.jit(value_and_grads).lower(q, kv, kv, q)
+    assert _kernel_names(lowered.as_text()) == ["_flash2_kernel", "_flash2_bwd_kernel"]
+    assert lowered.compile().as_text().count("tpu_custom_call") == 2
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -579,7 +604,7 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
     S = importlib.import_module("edl_tpu.ops.sparse_attention")
     h, h_kv, t, d, j, di, topk = SPARSE
     plan = S._kernel_plan(t, d, 2)
-    assert plan == {"fwd": (256, 1024), "bwd": (1024, 1024), "index": (512, 512),
+    assert plan == {"fwd": (1024, 1024), "bwd": (1024, 1024), "index": (512, 512),
                     "rows": 128, "chunk": 2048}
     scale = d ** -0.5
 

@@ -10,8 +10,12 @@ on new hardware or a new jax release and update the constants in
 Prints one JSON row per (kernel, seq, bq, bk) with fwd and fwd+bwd ms;
 configs that crash the compiler are recorded as rows with "error" (that
 is itself signal — bk=1024 kills the whole-KV kernel at seq >= 4096,
-and every whole-KV config died at 8192, which is why
-``ops/attention.py:_route`` gives flash2 past ``_WHOLE_KV_MAX_SEQ``).
+and every whole-KV config died at 8192, which is where
+``_WHOLE_KV_MAX_SEQ`` came from; since PR 48 ``ops/attention.py:_route``
+gives flash2 from ``_WHOLE_KV_FWD_MAX_TQ`` + 1 rows on, and the flash2
+forward's default came from a sweep at the benchmark cells' own shapes,
+GQA, two widths and the masked copy included, which this tool's MHA-only
+shapes do not reach: bench_results/README.md, "the forward's blocks").
 
 Usage::
 
@@ -78,8 +82,9 @@ def main():
 
                 if args.impl == "flash":
                     def fwd(a, bq=bq, bk=bk):
-                        return A._flash(
-                            a[0], a[1], a[2], True, scale, bq, bk
+                        return A._auto(
+                            a[0], a[1], a[2], True, scale, "flash", "flash",
+                            (bq, bk), (bq, bk),
                         )
 
                     def fwd_bwd(a, fwd=fwd):
