@@ -9,7 +9,13 @@ The layer of Dao and Gu (arXiv:2405.21060) as Hugging Face's
     x, B, C = split(xBC)                  x: H heads of P; B, C: G groups of N
     dt   = softplus(dt + dt_bias)         per head
     y    = ssd_scan(x, dt, -exp(A_log), B, C, D)        (ops/ssd.py)
-    out  = W_out RMSNorm(y * silu(z))     over all d_inner, learned scale
+    out  = W_out RMSNorm(y * silu(z))     over each group's d_inner / G channels
+                                          (all d_inner at G = 1), learned scale
+
+``H`` and ``G`` may be one chip's share of the published heads and groups, a
+group's ``H / G`` heads whole: a head's state is its own and the norm a group's
+own, so what the other groups' heads give before ``W_out`` is computed where
+they are held, and concatenates to the whole layer's.
 
 The convolution, its bias and its SiLU are one operation,
 ``ops/causal_conv.py:causal_conv_silu``: on a TPU a pair of Pallas kernels that
@@ -20,6 +26,10 @@ and registers; elsewhere the plain float32 form, ``causal_conv`` there.
 The device time of its four parts carries the names ``ssm_proj`` (both
 projections), ``ssm_conv``, ``ssm_scan`` and ``ssm_gate``
 (``jax.named_scope``; ``obs/profile.py:step_scopes`` joins them to a trace).
+Sown into ``"metrics"``: ``ssm_decay_mean``, the mean of ``exp(dt * A)`` over
+steps and heads (how fast the state forgets: 1 never, 0 at once); into
+``"intermediates"``, for a check that asks for the collection, ``gated``: the
+normed and gated ``[B, T, d_inner]`` that ``W_out`` reads.
 """
 
 from __future__ import annotations
@@ -103,22 +113,27 @@ class Mamba2Mixer(nn.Module):
         dt_bias = self.param("dt_bias", _dt_bias_init, (s.num_heads,))
         skip = self.param("D", nn.initializers.ones, (s.num_heads,))
         with jax.named_scope("ssm_scan"):
+            dt, a = jax.nn.softplus(dt.astype(f32) + dt_bias), -jnp.exp(a_log)
             y = ssd_scan(
-                xs.reshape(batch, t, s.num_heads, s.head_dim),
-                jax.nn.softplus(dt.astype(f32) + dt_bias),
-                -jnp.exp(a_log),
+                xs.reshape(batch, t, s.num_heads, s.head_dim), dt, a,
                 b.reshape(batch, t, s.n_groups, s.d_state),
                 c.reshape(batch, t, s.n_groups, s.d_state),
                 skip, chunk=s.chunk,
             )
+            self.sow("metrics", "ssm_decay_mean", jnp.mean(jnp.exp(dt * a)))
 
         with jax.named_scope("ssm_gate"):
             scale = self.param("norm", nn.initializers.ones, (d_inner,))
             gated = y.reshape(batch, t, d_inner).astype(f32) * nn.silu(z.astype(f32))
+            if s.n_groups > 1:  # a group's channels are normalised among themselves
+                gated = gated.reshape(batch, t, s.n_groups, d_inner // s.n_groups)
             gated = gated * jax.lax.rsqrt(
                 jnp.mean(gated * gated, axis=-1, keepdims=True) + self.norm_eps
             )
+            if s.n_groups > 1:
+                gated = gated.reshape(batch, t, d_inner)
             gated = (gated * scale).astype(self.dtype)
+            self.sow("intermediates", "gated", gated)  # before W_out (a check's)
 
         with jax.named_scope("ssm_proj"):
             return dense(d_model, "out_proj")(gated)

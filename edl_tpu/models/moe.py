@@ -7,10 +7,13 @@ tree — SURVEY §2 parallelism inventory). Two layers live here:
   a float32 router with softmax scores (OLMoE) or sigmoid scores, a
   balancing bias, normalised and scaled weights and a shared expert
   (the DeepSeek-V3 line as Trinity-Mini's ``afmoe`` code writes it),
-  top-k of many small SiLU-gated experts, **no capacity and no dropped
+  top-k of many small experts, SiLU-gated with three matrices each or
+  ungated with two and a squared ReLU (Nemotron-H's), reading and writing
+  the model's width or a narrower latent between two shared projections
+  (LatentMoE), **no capacity and no dropped
   token**, and optionally **one chip's share of the experts** (``held``:
   it routes over all of them and computes what its own give). Tokens are
-  sorted by expert and the three projections run as grouped matrix
+  sorted by expert and an expert's projections run as grouped matrix
   multiplications over the ragged groups
   (``edl_tpu.ops.grouped_matmul``); shapes are static whatever the
   imbalance. Sows the load-balancing and router-z losses into
@@ -42,7 +45,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.grouped_matmul import grouped_matmul
+
+# an expert's activation by name; "relu2" is the squared ReLU of Nemotron-H
+ACTIVATIONS = {"silu": nn.silu, "relu2": lambda a: jnp.square(nn.relu(a))}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,8 +59,10 @@ class MoESpec:
     ``models/transformer.py:ArchSpec.dense_layers`` is a
     :class:`DroplessMoE` of this shape, whose fields these are. (Which
     blocks mix by attention, over which window, and which by a state-space
-    layer is ``ArchSpec.layer_types``.) The defaults are OLMoE's layer:
-    softmax scores, every expert held here, no bias, no shared expert."""
+    layer is ``ArchSpec.layer_types``, and there a block whose one branch
+    is this layer.) The defaults are OLMoE's layer: softmax scores, every
+    expert held here, no bias, no shared expert, SiLU-gated experts of three
+    matrices at the model's width."""
 
     num_experts: int
     top_k: int
@@ -69,6 +78,9 @@ class MoESpec:
     held: Optional[Tuple[int, int]] = None  # (first, count) held here; None: all
     n_group: int = 1               # > 1: the experts in this many equal groups,
     topk_group: int = 1            # ... the choice inside the best topk_group
+    gated: bool = True             # False: two matrices an expert, W_down act(W_up x)
+    activation: str = "silu"       # or "relu2"; a gated expert's is "silu"
+    latent: int = 0                # > 0: the routed experts read and write this width
 
 
 def _sum_unsorted(rows, inverse, k, live=None):
@@ -152,8 +164,23 @@ def _rows_combined(rows, order, inverse, k, live=None):
     return combine(rows, order, inverse, live)
 
 
+class UngatedMLP(nn.Module):
+    """``W_down act(W_up x)``: the two-matrix feed-forward, no gate and no
+    bias (Nemotron-H's, with ``"relu2"``)."""
+
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+    activation: str = "relu2"
+
+    @nn.compact
+    def __call__(self, x):
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        hidden = ACTIVATIONS[self.activation](dense(self.d_ff, name="up")(x))
+        return dense(x.shape[-1], name="down")(hidden)
+
+
 class DroplessMoE(nn.Module):
-    """Dropless top-k mixture of SiLU-gated experts.
+    """Dropless top-k mixture of small experts, SiLU-gated or ungated.
 
     Per token ``x`` (``[B, S, D]`` in, ``[B, S, D]`` out)::
 
@@ -167,6 +194,24 @@ class DroplessMoE(nn.Module):
     With the defaults this is OLMoE's layer; with sigmoid scores, the bias,
     normalised and scaled weights and a shared expert it is the layer of
     the DeepSeek-V3 line as Trinity's ``afmoe`` code writes it.
+
+    **Ungated** (``gated=False``): an expert is two matrices,
+    ``W_down[e] (w * act(W_up[e] x))`` with ``act`` the ``activation``
+    (``"relu2"``: the squared ReLU of Nemotron-H, arXiv:2504.03624), two
+    grouped matmuls a pass where a gated expert has three, and the shared
+    expert, if any, is of the same ungated form (:class:`UngatedMLP`).
+
+    **The latent** (``latent = L > 0``; Nemotron-3's LatentMoE): the routed
+    experts read and write an ``L``-wide latent between two projections that
+    all experts share::
+
+        u = W_latent_down x                        [D, L], before the dispatch
+        r = sum_j W_down[e_j] (w_j * act(W_up[e_j] u))      banks [count, L, F] / [count, F, L]
+        y = W_latent_up r + shared(x)              [L, D], after the combine
+
+    The router, the bias and the shared expert are on the full-width ``x``.
+    ``W_latent_up`` is linear, so a chip's part ``W_latent_up r_held`` of the
+    routed result sums with the other chips' to the whole layer's.
 
     **Groups** (``n_group > 1``; DeepSeek-V3, arXiv:2412.19437, section
     2.1.2's node-limited routing): the ``E`` experts lie in ``n_group`` equal
@@ -220,8 +265,9 @@ class DroplessMoE(nn.Module):
 
     The N*k (token, choice) pairs are sorted by expert; ``gate``, ``up``
     and ``down`` (``[E, D, F]``, ``[E, D, F]``, ``[E, F, D]``, float32
-    parameters, computed in ``dtype``) are three grouped matrix
-    multiplications over the E ragged groups. Sown:
+    parameters, computed in ``dtype``; no ``gate`` where the experts are
+    ungated, ``L`` for ``D`` where they work in a latent) are three (two)
+    grouped matrix multiplications over the E ragged groups. Sown:
 
     - ``"losses"/load_balance`` = ``aux_weight * E * sum_i f_i * P_i`` with
       ``P_i`` the mean of ``s_i`` over the tokens and ``f_i`` the share of
@@ -247,13 +293,18 @@ class DroplessMoE(nn.Module):
       less rows the grouped matmuls cover (0: the buffer holds them all).
     - ``"intermediates"/top_idx``, ``/router_logits`` and ``/router_in`` =
       the chosen experts ``[N, k]``, ``W_r x`` ``[N, E]`` and the router's
-      own float32 operand ``x`` ``[N, D]`` (only when a caller asks for the
-      collection: a check against a reference).
+      own float32 operand ``x`` ``[N, D]``; with a latent ``/routed_latent`` =
+      ``r`` ``[N, L]``, this chip's part of the routed sum before
+      ``W_latent_up`` (only when a caller asks for the collection: a check
+      against a reference).
 
     Device-side names: ``moe_route`` (router, scores, bias, top-k, sort,
-    losses), ``moe_experts`` (gather, the routing weights and the three
-    grouped matmuls), ``moe_combine`` (un-sort and sum over k),
-    ``moe_shared`` (the shared expert).
+    losses), ``moe_latent`` (both of the latent's projections),
+    ``moe_experts`` (gather, the routing weights and the grouped matmuls),
+    ``moe_combine`` (un-sort and sum over k), ``moe_shared`` (the shared
+    expert). Each traced shape leaves one ``moe_shape`` instant in the span
+    ring (``experts``, ``held``, ``top_k``, ``pairs``, ``buffer_rows``,
+    ``latent``, ``width``, ``gated``, ``activation``).
     """
 
     num_experts: int
@@ -270,6 +321,9 @@ class DroplessMoE(nn.Module):
     held: Optional[Tuple[int, int]] = None
     n_group: int = 1
     topk_group: int = 1
+    gated: bool = True
+    activation: str = "silu"
+    latent: int = 0
     dtype: Any = jnp.bfloat16
 
     def _inside_kept_groups(self, choice):
@@ -298,9 +352,20 @@ class DroplessMoE(nn.Module):
         first, count = self.held or (0, e)
         if first < 0 or count < 1 or first + count > e:
             raise ValueError("held %r is no part of %d experts" % (self.held, e))
+        if self.activation not in ACTIVATIONS or (self.gated and self.activation != "silu"):
+            raise ValueError(
+                "activation %r: a gated expert is SiLU-gated, an ungated one "
+                "takes one of %s" % (self.activation, ", ".join(sorted(ACTIVATIONS)))
+            )
+        act = ACTIVATIONS[self.activation]
         # rows of the expert-ordered buffer a step usually needs: twice the
         # held experts' balanced share of the N * k pairs, in whole sublanes
         buffer = min(n * k, -(-2 * n * k * count // (8 * e)) * 8)
+        obs_trace.get_tracer().note_once(
+            "moe_shape", experts=e, held=count, top_k=k, pairs=n * k,
+            buffer_rows=buffer, latent=self.latent, width=f, gated=self.gated,
+            activation=self.activation,
+        )
 
         with jax.named_scope("moe_route"):
             router_in = tokens.astype(jnp.float32)
@@ -393,15 +458,23 @@ class DroplessMoE(nn.Module):
             # result under it and never round to ``dtype`` (excess precision)
             self.sow("intermediates", "router_in", router_in)
 
+        if self.latent:
+            with jax.named_scope("moe_latent"):
+                tokens = nn.Dense(
+                    self.latent, use_bias=False, dtype=self.dtype, name="latent_down"
+                )(tokens)
+        width = tokens.shape[-1]  # what a routed expert reads and writes
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=0)
-        w_gate = self.param("gate", init, (count, d, f), jnp.float32)
-        w_up = self.param("up", init, (count, d, f), jnp.float32)
-        w_down = self.param("down", init, (count, f, d), jnp.float32)
+        banks = tuple(
+            self.param(name, init, (count, width, f), jnp.float32)
+            for name in (("gate", "up") if self.gated else ("up",))
+        ) + (self.param("down", init, (count, f, width), jnp.float32),)
 
-        def routed(m, tokens, weights, w_gate, w_up, w_down):
+        def routed(m, tokens, weights, *banks):
             """The held experts' part of the layer from the first ``m`` rows
             in expert order: every row when all experts are held, else a
-            buffer that holds the ``live`` rows of held experts."""
+            buffer that holds the ``live`` rows of held experts. ``banks``:
+            ``gate`` (a gated expert's), ``up`` and ``down``."""
             first_rows = order if m == n * k else order[:m]
             with jax.named_scope("moe_experts"):
                 rows = _rows_sorted(
@@ -410,8 +483,10 @@ class DroplessMoE(nn.Module):
                 w_sorted = _scalars_sorted(weights.reshape(n * k), order, inverse)
                 if m < n * k:
                     w_sorted = w_sorted[:m]
-                gate = grouped_matmul(rows, w_gate.astype(self.dtype), group_sizes)
-                up = grouped_matmul(rows, w_up.astype(self.dtype), group_sizes)
+                into = [
+                    grouped_matmul(rows, bank.astype(self.dtype), group_sizes)
+                    for bank in banks[:-1]
+                ]
                 if live is not None:
                     # Megablox writes no row past the groups' sum, forward or
                     # backward: what lies there is whatever the memory held.
@@ -419,18 +494,18 @@ class DroplessMoE(nn.Module):
                     # transpose) on the way back, or one NaN there reaches
                     # the router through the weights' gradient
                     nobodys = (jnp.arange(m) >= live)[:, None]
-                    gate, up = (jnp.where(nobodys, 0, a) for a in (gate, up))
-                hidden = (
-                    nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-                    * w_sorted[:, None]
-                ).astype(self.dtype)                    # rounded once
+                    into = [jnp.where(nobodys, 0, a) for a in into]
+                hidden = act(into[0].astype(jnp.float32))
+                if self.gated:
+                    hidden = hidden * into[1].astype(jnp.float32)
+                hidden = (hidden * w_sorted[:, None]).astype(self.dtype)  # rounded once
                 if live is not None:
                     hidden = jnp.where(nobodys, 0, hidden)  # for its cotangent's rows
-                out = grouped_matmul(hidden, w_down.astype(self.dtype), group_sizes)
+                out = grouped_matmul(hidden, banks[-1].astype(self.dtype), group_sizes)
             with jax.named_scope("moe_combine"):
-                return _rows_combined(out, first_rows, inverse, k, live)  # [N, D], float32
+                return _rows_combined(out, first_rows, inverse, k, live)  # [N, width], float32
 
-        operands = (tokens, weights, w_gate, w_up, w_down)
+        operands = (tokens, weights, *banks)
         if buffer < n * k:
             # the whole N * k only for a step whose held rows outgrow the
             # buffer; it keeps nothing for its backward (which computes it
@@ -441,12 +516,23 @@ class DroplessMoE(nn.Module):
             )
         else:
             y = routed(n * k, *operands)
+        if self.latent:
+            # this chip's part of the routed sum, in the latent (a check's)
+            self.sow("intermediates", "routed_latent", y)
+            with jax.named_scope("moe_latent"):
+                y = nn.Dense(d, use_bias=False, dtype=self.dtype, name="latent_up")(
+                    y.astype(self.dtype)
+                )
         y = y.reshape(b, s, d).astype(x.dtype)
         if self.shared_d_ff:
             from edl_tpu.models.transformer import SwiGLU  # imports this module
 
             with jax.named_scope("moe_shared"):
-                y = y + SwiGLU(self.shared_d_ff, self.dtype, name="shared")(x)
+                shared = (
+                    SwiGLU(self.shared_d_ff, self.dtype, name="shared") if self.gated
+                    else UngatedMLP(self.shared_d_ff, self.dtype, self.activation, name="shared")
+                )
+                y = y + shared(x)
         return y
 
 
@@ -532,8 +618,11 @@ class SwitchMoE(nn.Module):
 
 
 # Expert-parallel sharding rules: expert banks split their leading [E] axis
-# over ``ep``; the router stays replicated. ``w[io]`` are SwitchMoE's two
-# banks, ``gate``/``up``/``down`` DroplessMoE's three.
+# over ``ep``; the router stays replicated, and so do the latent's two
+# projections and the shared expert (``moe/latent_*/kernel``,
+# ``moe/shared/*/kernel``: no bank, no rule). ``w[io]`` are SwitchMoE's two
+# banks, ``gate``/``up``/``down`` DroplessMoE's three (``up``/``down`` alone
+# where its experts are ungated).
 MOE_EP_RULES = [
     (r".*/moe/(w[io]|gate|up|down)$", P("ep", None, None)),
 ]

@@ -17,9 +17,11 @@ TPU-first:
   (``ops/sparse_attention.py``) or latent attention (keys and values out of
   one low-rank latent a token: :class:`LatentAttention`); its feed-forward a
   SwiGLU or an expert layer (``models/moe.py``), the leading
-  ``ArchSpec.dense_layers`` blocks of an expert model dense: one
+  ``ArchSpec.dense_layers`` blocks of an expert model dense; or, with
+  ``ArchSpec.one_branch``, blocks of one branch each (Nemotron-H's: a mixer, an
+  expert layer or a dense feed-forward alone under one norm): one
   ``TransformerLM`` runs dense, expert, hybrid (any of the four cheap mixers
-  beside attention) and mixed-window configurations;
+  beside attention), mixed-window and one-branch configurations;
 - attention is pluggable: the Pallas flash kernel locally, or ring
   attention over the ``sp`` mesh axis for sequences longer than one
   device's HBM (``edl_tpu.parallel.ring``);
@@ -130,7 +132,12 @@ class ArchSpec:
     attention whose keys and values come out of a low-rank latent; then
     ``latent_attention`` gives its ranks and head sizes, and ``rope_theta``
     the base of its rotated part whatever ``rope`` says); its length is the
-    model's depth. ``rope``
+    model's depth. ``one_branch`` makes every block ``x + Branch(N(x))`` with
+    ONE branch under one norm (Nemotron-H, arXiv:2504.03624): a mixer's block
+    then carries no feed-forward, and ``layer_types`` may also name a block
+    whose branch is the feed-forward alone, ``"moe"`` (the expert layer of
+    ``TransformerLM.moe``) or ``"mlp"`` (the dense SwiGLU of ``d_ff``);
+    ``dense_layers`` then says nothing, and there is no decode path. ``rope``
     rotates q and k in every attention layer (``True``), in none (``False``:
     no position term at all) or in the windowed layers only (``"sliding"``:
     the full layers then see order through the causal mask alone); the
@@ -169,10 +176,13 @@ class ArchSpec:
     dense_layers: int = 0
     post_norms: Union[bool, str] = False  # False, True or "only"
     attn_gate: bool = False
+    one_branch: bool = False            # True: a block is one branch, no second
 
 
 LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
                "conv", "sparse_attention", "kda", "latent_attention")
+# under ``ArchSpec.one_branch`` also: a block whose branch is the feed-forward
+FEED_FORWARD_TYPES = ("moe", "mlp")
 
 
 def _scope(name: Optional[str]):
@@ -608,8 +618,14 @@ class Block(nn.Module):
         if arch.post_norms not in (False, True, "only"):
             raise ValueError("unknown post_norms %r" % (arch.post_norms,))
         before, after = arch.post_norms != "only", bool(arch.post_norms)
+        if arch.one_branch and self.decode:
+            raise NotImplementedError("a one-branch block has no decode path")
         h = RMSNorm(self.norm_eps, name="ln1")(x) if before else x
-        if self.mixer == "mamba":
+        if arch.one_branch and self.mixer in FEED_FORWARD_TYPES:
+            if self.mixer == "moe" and self.moe is None:
+                raise ValueError('a "moe" block needs TransformerLM.moe')
+            mixed = self._feed_forward(h, dense=self.mixer == "mlp")
+        elif self.mixer == "mamba":
             if self.decode:
                 raise NotImplementedError("a Mamba-2 block has no decode cache")
             mixed = Mamba2Mixer(
@@ -676,27 +692,34 @@ class Block(nn.Module):
             )(h, positions)
         else:
             raise ValueError(
-                "unknown layer type %r: a block's mixer is one of %s"
-                % (self.mixer, ", ".join(LAYER_TYPES))
+                "unknown layer type %r: a block's mixer is one of %s, and under "
+                "one_branch its one branch may be a feed-forward alone, one of %s"
+                % (self.mixer, ", ".join(LAYER_TYPES), ", ".join(FEED_FORWARD_TYPES))
             )
         if after:
             mixed = RMSNorm(self.norm_eps, name="ln1_post")(mixed)
         x = x + _times(mixed, arch.residual_multiplier)
+        if arch.one_branch:
+            return x
         h = RMSNorm(self.norm_eps, name="ln2")(x) if before else x
-        if self.moe is not None:
-            ff = DroplessMoE(
-                **dataclasses.asdict(self.moe), dtype=self.dtype, name="moe"
-            )(h)
-        elif self.num_experts > 0:
-            ff = SwitchMoE(
-                num_experts=self.num_experts, d_ff=self.d_ff,
-                dtype=self.dtype, name="moe",
-            )(h)
-        else:
-            ff = SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
+        ff = self._feed_forward(h)
         if after:
             ff = RMSNorm(self.norm_eps, name="ln2_post")(ff)
         return x + _times(ff, arch.residual_multiplier)
+
+    def _feed_forward(self, h, dense: bool = False):
+        """The block's feed-forward on its normed input: the expert layer if
+        the block has one and ``dense`` does not overrule it, else the SwiGLU."""
+        if self.moe is not None and not dense:
+            return DroplessMoE(
+                **dataclasses.asdict(self.moe), dtype=self.dtype, name="moe"
+            )(h)
+        if self.num_experts > 0 and not dense:
+            return SwitchMoE(
+                num_experts=self.num_experts, d_ff=self.d_ff,
+                dtype=self.dtype, name="moe",
+            )(h)
+        return SwiGLU(self.d_ff, self.dtype, name="mlp")(h)
 
 
 def _remat_policy(name: Optional[str]):

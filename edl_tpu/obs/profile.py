@@ -213,7 +213,7 @@ PHASES = ("forward", "backward", "optimizer", "numerics", "other")
 # ``loss`` (forward or backward, under no module or scope at all), a tied
 # ``head`` (a matmul under the model and no module) and ``other``.
 _STEP_SCOPES = (
-    "moe_route", "moe_experts", "moe_combine", "moe_shared",
+    "moe_route", "moe_experts", "moe_combine", "moe_shared", "moe_latent",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate",
     "gdn_proj", "gdn_conv", "gdn_scan", "gdn_gate",
     "kda_proj", "kda_conv", "kda_scan", "kda_gate",

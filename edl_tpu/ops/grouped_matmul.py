@@ -52,7 +52,12 @@ from edl_tpu.obs import trace as obs_trace
 # group's end is walked once for each group in it, so where groups are small
 # beside 512 rows (``ling_3_0_flash_vl.steady``: 128 rows a held expert
 # expected, four groups a tile) most of a tile's rows are masked for each of
-# them; not tuned here (PERF.md section 7).
+# them; not tuned here (PERF.md section 7). The latent expert layer
+# (``nemotron_3_super_120b_a12b.steady``) reads ``gmm_tiles`` (512, 1024, 896)
+# over 1024 x 2688 and (512, 896, 1024) over 2688 x 1024 (2688 is 21 lane
+# tiles: 896 = 7 of them is the largest whole divisor under 1024), on a buffer
+# of 5632 rows at 352 rows a held expert expected: a row tile of 512 then holds
+# the edge of one or two groups, and is walked once for each; open, not tuned.
 TILING = (512, 1024, 1024)
 _LANES = 128
 

@@ -20,19 +20,23 @@ from edl_tpu.ops import ssd_scan
 from edl_tpu.parallel.pipeline_lm import split_lm_params
 from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
 
-# a toy Granite: 8 Mamba-2 heads of 16 in 2 groups over a state of 16, GQA 4:2
-# at head 16 in a model of width 48, so head_dim is not d_model / heads
+# a toy Granite: 8 Mamba-2 heads of 16 in ONE group, as published, over a state
+# of 16, GQA 4:2 at head 16 in a model of width 48, so head_dim is not d_model /
+# heads. One group, because ``reference/ssm_lm.py`` (Hugging Face's Granite layer)
+# normalises over all d_inner, and a layer of more groups normalises each group's
+# channels among themselves (``tests/test_nemotron_h.py`` holds 2 and 4 groups
+# against ``reference/nemotron_h_lm.py``): with one group both are one function.
 TOY = {
     "hidden_size": 48, "num_attention_heads": 4, "num_key_value_heads": 2,
     "layer_types": ["mamba", "attention", "mamba"], "num_hidden_layers": 3,
-    "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_n_groups": 2,
+    "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_n_groups": 1,
     "mamba_d_state": 16, "mamba_d_conv": 4, "mamba_conv_bias": True,
     "mamba_chunk_size": 8, "attention_multiplier": 1.0 / 64,
     "embedding_multiplier": 12.0, "residual_multiplier": 0.22,
     "logits_scaling": 8.0, "rms_norm_eps": 1e-5, "vocab_size": 64,
     "shared_intermediate_size": 40,
 }
-SPEC = MambaSpec(num_heads=8, head_dim=16, d_state=16, n_groups=2, d_conv=4, chunk=8)
+SPEC = MambaSpec(num_heads=8, head_dim=16, d_state=16, n_groups=1, d_conv=4, chunk=8)
 
 
 def toy_arch(**overrides):
@@ -156,7 +160,7 @@ def test_mixer_equals_the_sequential_reference():
     mixer, params, x = mixer_and_params()
     assert set(params) == {"in_proj", "out_proj", "conv_kernel", "conv_bias",
                            "A_log", "dt_bias", "D", "norm"}
-    assert params["in_proj"]["kernel"].shape == (48, 128 + (128 + 2 * 32) + 8)
+    assert params["in_proj"]["kernel"].shape == (48, 128 + (128 + 2 * 16) + 8)
     np.testing.assert_allclose(
         mixer.apply({"params": params}, x), reference.mamba_mixer(TOY, params, x),
         rtol=2e-5, atol=2e-5,

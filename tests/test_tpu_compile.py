@@ -1008,3 +1008,185 @@ def test_the_kda_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(h
     planned = recorded["total_gb"] if heads == 16 else 16.53
     assert doc["total_gb"] == pytest.approx(planned, abs=0.1)
     assert planned <= recorded["total_gb"]
+
+
+# -- nemotron_3_super_120b_a12b.steady: experts in a latent, Mamba-2 in groups --
+
+@pytest.mark.parametrize("shape", ["up", "down"])
+def test_megablox_compiles_for_v5e_in_a_latent_of_1024_at_a_width_of_2688(one_chip, shape):
+    """The three Megablox kernels at the held experts' shape of the latent
+    expert layer (8 groups, a buffer of 5632 rows: twice the 2816 that 8192
+    tokens x 22 choices x 8 / 512 expect, 352 a group; 1024 x 2688), on the
+    tiles ``_fit`` gives where 1024 does not divide 2688 = 21 lane tiles: 896
+    (seven lane tiles, three of them a row), in N for up and in K for down.
+    Three custom calls."""
+    gm = importlib.import_module("edl_tpu.ops.grouped_matmul")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows = 5632
+    assert rows == min(8192 * 22, -(-2 * 8192 * 22 * 8 // (8 * 512)) * 8)  # the layer's buffer
+    k, n, tiles = {"up": (1024, 2688, (512, 1024, 896)),
+                   "down": (2688, 1024, (512, 896, 1024))}[shape]
+    assert gm._fit(gm.TILING, rows, k, n) == tiles
+
+    def value_and_grads(lhs, rhs, sizes, dy):
+        out, vjp = jax.vjp(lambda a, b: gm._pallas(a, b, sizes, False), lhs, rhs)
+        return (out, *vjp(dy))
+
+    compiled = jax.jit(value_and_grads).lower(
+        sds((rows, k)), sds((8, k, n)), sds((8,), jnp.int32), sds((rows, n))
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_ssd_scan_compiles_for_v5e_in_four_groups_at_a_chunk_of_128(one_chip):
+    """The chunked scan with jax's own backward at one sequence of 8192, the 64
+    heads of 64 the cell holds in 4 groups of 16 over a state of 128, chunk 128
+    (``r = h // g`` = 16: the first cell with more than one group). Plain XLA,
+    so what the chip's compiler can refuse is the memory: at half Granite's
+    chunk the float32 decay matrices of one pass are 268 MB."""
+    from edl_tpu.ops import ssd_scan
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, h, p, g, n = 8192, 64, 64, 4, 128
+    args = (sds((1, t, h, p)), sds((1, t, h), jnp.float32), sds((h,), jnp.float32),
+            sds((1, t, g, n)), sds((1, t, g, n)), sds((h,), jnp.float32))
+
+    def value_and_grads(w, *a):
+        out, vjp = jax.vjp(lambda *a: ssd_scan(*a, chunk=128), *a)
+        return (out, *vjp(w))
+
+    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, p)), *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_a_one_branch_step_on_the_tpu_path_leaves_no_matmul_unplaced(one_chip):
+    """A toy of Nemotron-H's blocks (a Mamba-2 mixer in two groups, the latent
+    expert layer alone, attention alone, a dense feed-forward alone), lowered as
+    the chip lowers it (``jax.default_backend`` steered to ``tpu`` here, for this
+    compile only): ``STEP_PARTS`` places every matmul of the compiled step, the
+    latent's two projections under ``moe_latent``, and an ungated expert's two
+    banks make two ``gmm`` calls a pass where a gated expert's make three."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, MambaSpec, MoESpec, TransformerLM
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers = ("mamba", "moe", "attention", "mlp")
+    lm = TransformerLM(
+        vocab_size=256, d_model=128, num_heads=4, num_kv_heads=1, num_layers=len(layers),
+        d_ff=256, dtype=jnp.bfloat16, remat=True, norm_eps=1e-5,
+        moe=MoESpec(
+            num_experts=16, top_k=4, d_ff=256, norm_topk_prob=True, aux_weight=0.0,
+            z_weight=0.0, score_func="sigmoid", route_scale=5.0, bias_rate=1e-3,
+            shared_d_ff=256, held=(0, 4), gated=False, activation="relu2", latent=128,
+        ),
+        arch=ArchSpec(
+            layer_types=layers, head_dim=32, rope=False, one_branch=True,
+            mamba=MambaSpec(num_heads=8, head_dim=16, d_state=32, n_groups=2, chunk=32),
+        ),
+    )
+    tokens = np.zeros((1, 256), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    program = obs_profile.HloProgram(lowered.compile().as_text())
+    census = program.census()
+    assert census["totals"]["matmuls"] > 0 and census["totals"]["unplaced_matmuls"] == 0
+    parts = {key.split("/")[0] for key in census["parts"]}
+    assert {"moe_latent", "moe_shared", "moe_experts", "ssm_proj", "mlp", "attn", "head"} <= parts
+    # two banks: in each branch of the layer's cond, forward 2 gmm; backward
+    # the recomputed up's 1, 2 gmm_dlhs and 2 tgmm (3 / 3 / 3 where gated)
+    assert census["kernels"]["gmm/forward"] == 2 * 2
+    assert census["kernels"]["tgmm/backward"] == 2 * 2
+
+
+def _latent_cell():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+        root, "benchmark", "configs", "nemotron_3_super_120b_a12b.json"
+    )) as f:
+        return root, json.load(f)
+
+
+def test_the_latent_cells_share_of_the_heads_is_the_one_its_plan_chose():
+    """The rule, on the numbers the file records: the first whole period (depth
+    9, ``MEMEMEM*E``) with the heads as one of TWO chips' share, if its compiled
+    step (the tool) and the chip itself (what ``bytes_limit`` leaves after the
+    state and the block the step reserves, and a run beside a ballast of 1 GiB)
+    leave at least 1 GB of the chip's 15.75; else one of four chips' share. With
+    every head held the period is 1002.7 M parameters, 16.04 GB with its
+    gradients: no chip holds it, and the file records the tool's refusal."""
+    _, config = _latent_cell()
+    plan = config["plan"]
+    left = lambda tried: plan["chip_gb"] - tried["total_gb"]  # noqa: E731
+    by_share = {t["chips_a_heads"]: t for t in plan["tried"]}
+    assert sorted(by_share) == [1, 2]
+    assert left(by_share[1]) < 0 and by_share[1]["parameters"] == 1002718720
+    chosen = by_share[plan["chosen"]["chips_a_heads"]]
+    assert plan["chosen"] == {"num_hidden_layers": 9, "chips_a_heads": 2}
+    assert left(chosen) >= plan["least_left_gb"] and chosen["parameters"] == 765620992
+    on_chip = chosen["on_chip"]
+    assert on_chip["ran"] and on_chip["correct"]
+    reserved = on_chip["state_gb"] + on_chip["program_reserve_gib"] * 2 ** 30 / 1e9
+    assert on_chip["left_gb"] == pytest.approx(on_chip["bytes_limit"] / 1e9 - reserved, abs=0.01)
+    assert on_chip["left_gb"] >= plan["least_left_gb"]
+    assert on_chip["beside_a_ballast_of_1_gib"].startswith("ran, correct")
+    for tried in plan["tried"]:
+        assert tried["left_gb"] == pytest.approx(left(tried), abs=2e-3)
+    share = config["share"]
+    assert share["chips_a_heads"] == 2 and share["chips_a_layer"] == 64
+    published = config["published"]
+    assert (config["mamba_num_heads"], config["n_groups"]) == (
+        published["mamba_num_heads"] // 2, published["n_groups"] // 2)
+    assert (config["num_attention_heads"], config["num_key_value_heads"]) == (
+        published["num_attention_heads"] // 2, published["num_key_value_heads"] // 2)
+    # each group's heads whole, and the published 16 queries a KV head
+    assert config["mamba_num_heads"] // config["n_groups"] == 16
+    assert config["num_attention_heads"] // config["num_key_value_heads"] == 16
+    # the pattern's first whole period, as blocks 0 to 8 lie
+    assert config["hybrid_override_pattern"] == published["hybrid_override_pattern"][:9]
+    assert config["num_hidden_layers"] == 9 and published["num_hidden_layers"] == 88
+
+
+@pytest.mark.slow
+def test_the_latent_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded():
+    """``benchmark/tools/compile_for_v5e.py`` on the cell as it runs (3 to 5
+    minutes): the step compiles, and the plan's total is what the
+    configuration's file records, to 0.1 GB."""
+    import json
+    import subprocess
+    import sys
+
+    root, config = _latent_cell()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "tools", "compile_for_v5e.py"),
+         "nemotron_3_super_120b_a12b.steady"],
+        capture_output=True, text=True, timeout=1500, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    doc = json.loads(out.stdout.strip().splitlines()[-1])
+    recorded = next(t for t in config["plan"]["tried"] if t["chips_a_heads"] == 2)
+    assert doc["parameters"] == recorded["parameters"]
+    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
