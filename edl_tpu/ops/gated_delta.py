@@ -75,6 +75,15 @@ the plain ``jax.numpy`` form with jax's backward, which is also what the
 kernels are tested against. The carry and the output stage are plain XLA under
 both. :func:`gated_delta_rule` itself is plain throughout: a decay a step is
 the kernels' case of ``g`` equal along the lanes, not moved onto them here.
+
+``ops/ssd.py`` (Mamba-2's scan, which has no solve) runs its own chunk-local
+stage as two kernels on the same frame and imports it from here: the call
+builder ``_chunk_call`` (a grid step a chunk, the blocks and the VMEM limit
+from a table of kinds; ``_run`` is this file's table), ``_iota``,
+``_running_sum`` (``axis`` 1: along the lanes), ``_column_of``, ``_over_heads``
+(``width`` heads a round) and the two transposed products. What a backward
+keeps differs: ``kda_backward`` the inputs and ``T``, ``ssd_backward`` the
+inputs alone.
 """
 
 from __future__ import annotations
@@ -398,18 +407,19 @@ def _iota(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
-def _running_sum(a, reverse=False):
-    """The running sum of ``a`` ``[C, d]`` down its rows (``reverse``: up), by
-    ``log2(C)`` shifted additions in float32 (Mosaic lowers no ``cumsum``)."""
+def _running_sum(a, reverse=False, axis=0):
+    """The running sum of ``a`` ``[C, d]`` down its rows (``reverse``: up;
+    ``axis`` 1: along each row), by ``log2(C)`` shifted additions in float32
+    (Mosaic lowers no ``cumsum``)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    size, row = a.shape[0], _iota(a.shape, 0)
+    size, at = a.shape[axis], _iota(a.shape, axis)
     s = 1
     while s < size:
         if reverse:
-            a = a + jnp.where(row < size - s, pltpu.roll(a, size - s, axis=0), 0.0)
+            a = a + jnp.where(at < size - s, pltpu.roll(a, size - s, axis=axis), 0.0)
         else:
-            a = a + jnp.where(row >= s, pltpu.roll(a, s, axis=0), 0.0)
+            a = a + jnp.where(at >= s, pltpu.roll(a, s, axis=axis), 0.0)
         s *= 2
     return a
 
@@ -489,15 +499,18 @@ def _column_of(betas, h):
     return jnp.sum(jnp.where(_iota(betas.shape, 1) == h, betas, 0.0), axis=1, keepdims=True)
 
 
-def _over_heads(heads, body, init=0):
+def _over_heads(heads, body, init=0, width=2):
     """``body(pair, half, carry)`` for every head ``2 * pair + half`` in turn,
     a pair to a round of the loop: the scheduler may interleave the two bodies
-    (Mosaic's own ``unroll`` is all or nothing), and ``half`` is static."""
+    (Mosaic's own ``unroll`` is all or nothing), and ``half`` is static. With
+    ``width``, that many heads a round: head ``width * pair + half``."""
 
-    def both(pair, carry):
-        return body(pair, 1, body(pair, 0, carry))
+    def together(pair, carry):
+        for half in range(width):
+            carry = body(pair, half, carry)
+        return carry
 
-    return jax.lax.fori_loop(0, heads // 2, both, init)
+    return jax.lax.fori_loop(0, heads // width, together, init)
 
 
 def _inverse_at(inverse_ref, pair, half):
@@ -658,6 +671,52 @@ def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
     dbeta_ref[0] = _over_heads(heads, head, jnp.zeros(betas.shape, f32))
 
 
+def _chunk_call(kernel, grid, kinds, ins, outs, operands, interpret, scratch=()):
+    """``kernel`` as one Pallas call over ``grid`` = (batch, chunks), every
+    grid step independent: ``kinds`` gives a kind of operand its shape, dtype,
+    block and the block's place at batch ``b``, chunk ``n`` (its leading block
+    indices; the rest are zeros), ``ins`` and ``outs`` name the kinds of
+    ``operands`` and of the results, ``scratch`` the body's VMEM arrays (shape,
+    dtype). The call bears the kernel's name less ``_kernel``; its VMEM limit
+    is reckoned from the blocks. Shared with ``ops/ssd.py``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(kind):
+        _, _, block, place = kinds[kind]
+
+        def index(b, n):
+            at = place(b, n)
+            return at + (0,) * (len(block) - len(at))
+
+        return pl.BlockSpec(block, index)
+
+    def padded(shape, dt):  # bytes in VMEM, rows padded to whole lane tiles
+        return math.prod(shape[:-1]) * -(-shape[-1] // 128) * 128 * jnp.dtype(dt).itemsize
+
+    def held(kind):
+        _, dt, block, _ = kinds[kind]
+        return padded(block, dt)
+
+    name = kernel.__name__.removesuffix("_kernel")
+    call = pl.pallas_call(
+        kernel, name=name, grid=grid,
+        in_specs=[spec(kind) for kind in ins], out_specs=[spec(kind) for kind in outs],
+        out_shape=[jax.ShapeDtypeStruct(*kinds[kind][:2]) for kind in outs],
+        scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            # every block twice (the pipeline's two buffers), the scratch, and
+            # room for the body's own tiles
+            vmem_limit_bytes=2 * sum(held(kind) for kind in (*ins, *outs))
+            + sum(padded(shape, dt) for shape, dt in scratch) + (16 << 20),
+        ),
+        interpret=interpret,
+    )
+    with obs_trace.span("kernel_trace", kernel=name):
+        return tuple(call(*operands))
+
+
 _INPUTS = ("keys", "keys", "values", "decays", "beta")                 # q k v g beta
 _OPERANDS = ("w", "u", "k_out", "whole", "keys", "scores")             # ..., q_in, scores
 
@@ -666,9 +725,6 @@ def _run(kernel, ins, outs, operands, interpret):
     """One of the three kernels on ``operands``, whose kinds ``ins`` names
     (``outs`` those of its results): a grid step a chunk, every block a chunk
     of every head."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     batch, steps, h = operands[ins.index("beta")].shape
     size, nc = _KERNEL_CHUNK, steps // _KERNEL_CHUNK
     keys = operands[ins.index("keys")]
@@ -691,30 +747,7 @@ def _run(kernel, ins, outs, operands, interpret):
         k_out=((nc, batch, size, h * d_k), dtype, (1, 1, size, h * d_k), first),
         whole=((nc, batch, h, d_k), f32, (1, 1, h, d_k), first),
     )
-
-    def spec(kind):
-        _, _, block, place = kinds[kind]
-        return pl.BlockSpec(block, lambda b, n: place(b, n) + (0,) * (len(block) - 2))
-
-    def held(kind):  # a block's bytes in VMEM, its rows padded to whole lane tiles
-        _, dt, block, _ = kinds[kind]
-        return math.prod(block[:-1]) * -(-block[-1] // 128) * 128 * jnp.dtype(dt).itemsize
-
-    name = kernel.__name__.removesuffix("_kernel")
-    call = pl.pallas_call(
-        kernel, name=name, grid=(batch, nc),
-        in_specs=[spec(kind) for kind in ins], out_specs=[spec(kind) for kind in outs],
-        out_shape=[jax.ShapeDtypeStruct(*kinds[kind][:2]) for kind in outs],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            # every block twice (the pipeline's two buffers) and room for the
-            # body's own tiles
-            vmem_limit_bytes=2 * sum(held(kind) for kind in (*ins, *outs)) + (16 << 20),
-        ),
-        interpret=interpret,
-    )
-    with obs_trace.span("kernel_trace", kernel=name):
-        return tuple(call(*operands))
+    return _chunk_call(kernel, (batch, nc), kinds, ins, outs, operands, interpret)
 
 
 # jitted, as ``ops/causal_conv.py``'s: a step traces and lowers each body once

@@ -10,60 +10,107 @@ is computed a chunk of ``chunk`` steps at a time. Inside a chunk the state
 never materialises: ``Y_diag = (L o C B^T)(dt x)`` with ``L_ij = exp(sum of
 dt_k A over j < k <= i)``, three matmuls. Each chunk's own final state is one
 more matmul, the states are carried from chunk to chunk by a sequential
-``lax.scan`` (``T / chunk`` steps of an elementwise update), and what a chunk
-inherits reaches its outputs through ``Y_off = exp(cumsum) * C S_in``.
+``lax.scan`` (``T / chunk`` steps), and what a chunk inherits reaches its
+outputs through ``Y_off = exp(cumsum) * C S_in``, which the same loop adds to
+``Y_diag`` as it passes the chunk (:func:`_carry_out`).
 
 Precision: the log-decays ``dt A``, their running sums, every ``exp`` of
 them and the carried state are float32; the matmul operands (``C``, ``B``,
 ``L o C B^T``, ``dt x`` and the state a chunk reads) are in ``x``'s dtype with
-float32 accumulation. Plain ``jax.numpy`` / ``lax``: the backward is jax's.
+float32 accumulation. No ``exp`` is of a positive argument.
+
+**The chunk-local stage**, everything between the scan's inputs and the
+carry's operands that depends on one chunk only (``Y_diag + D x``, the chunk's
+own state, ``exp`` of the chunk's whole decay and of its running sum), has two
+forms under one contract, chosen from what the call can see
+(:func:`_kernels_refuse`), as ``ops/gated_delta.py:kda_rule``'s is:
+
+- On a TPU backend, for bfloat16 ``x``, ``B``, ``C``, a chunk of whole lane
+  tiles (a multiple of 128) that divides ``T``, ``P`` a multiple of 16, ``N``
+  of 128 and a multiple of 8 heads a group: two Pallas kernels under one
+  ``jax.custom_vjp`` (:func:`_local_kernels`), ``ssd_forward`` and
+  ``ssd_backward``, a grid step a chunk, every head of the chunk in VMEM. They
+  work on the transposed activations, ``[B, channels, T]`` with time along the
+  lanes, which is how ``causal_conv_silu`` hands ``xBC`` over and how XLA keeps
+  ``z`` and ``dt`` (PERF.md section 6, PR 30): a head is ``P`` whole sublanes, a
+  step's decay a lane, and the ``swapaxes`` around the kernels are bitcasts.
+  ``x``, ``B`` and ``C`` are read out of one array, one under another, and
+  their gradients leave as one array of its shape: the mixer hands
+  :func:`ssd_scan` three slices of what its convolution wrote, and XLA passes
+  that array whole (``tests/test_tpu_compile.py`` holds it to that).
+  The ``[L, L]`` decay matrix of a head lives a ``128 x 128`` block at a time
+  in registers, only the blocks on and under the causal line; the group's
+  scores ``C B^T`` are made once a group in a VMEM scratch, and the products
+  whose one operand a group's heads share (their own states through ``B``)
+  are one product over the heads' rows. The backward keeps **nothing but the
+  inputs**: it makes the tile's intermediates again in VMEM and takes the two
+  ``dM`` reductions (row and column sums of ``d mixing o mixing``) on the
+  tile; a block's recomputation runs ``ssd_forward`` again.
+- Everywhere else (the CPU, float32 operands, a ragged ``T``, other widths),
+  :func:`_local_plain`: plain ``jax.numpy`` with jax's own backward, which is
+  also what the kernels are tested against. ``interpret`` runs the kernels in
+  the Pallas interpreter (tests on the CPU).
+
+The carry and the inherited term are plain XLA under both, one ``lax.scan``
+with a backward of its own (:func:`_carry_out`: a reverse scan that keeps the
+state each chunk inherited and nothing else). The loop rounds each chunk's
+outputs as it passes, and the result is laid out for its reader (time last)
+once, in ``x``'s dtype, behind an ``optimization_barrier``: without it XLA
+hoists the gate's float32 conversion over the layout change and makes two
+float32 passes of it (PERF.md section 6, PR 50). Each traced shape leaves one
+``ssm_chunks`` instant: ``chunk``, ``chunks``, ``heads``, ``groups``,
+``d_head``, ``d_state``, ``state_bytes``, ``path`` ``kernel`` / ``plain`` and,
+on ``plain``, ``why`` (the first of ``backend``, ``dtype``, ``chunk``,
+``steps``, ``heads``, ``width``, ``vmem`` that did not hold).
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.obs import trace as obs_trace
+from edl_tpu.ops.gated_delta import (
+    _chunk_call, _column_of, _iota, _over_heads, _running_sum,
+    _times_transposed, _transposed_times,
+)
 
-def ssd_scan(x, dt, a, b, c, d=None, *, chunk: int = 256, initial_state=None,
-             return_final_state: bool = False):
-    """``y`` ``[B, T, H, P]`` in ``x``'s dtype (and the final state, float32
-    ``[B, H, P, N]``, with ``return_final_state``).
+_BLOCK = 128            # a lane tile: the [L, L] tile is walked in such blocks
+# Heads a round of the kernels' loops, for the scheduler to interleave: 0.58 ms a
+# forward call at Nemotron's shape for 0.97 at 2 (my probe, PR 50). A group's
+# heads come in eights in both cells and in every published Mamba-2 layer.
+_HEADS_A_ROUND = 8
+_VMEM_MOST = 96 << 20   # what the kernels' blocks may hold of a core's 128 MiB
 
-    ``x`` ``[B, T, H, P]``; ``dt`` ``[B, T, H]``, the step sizes, already
-    positive (after the softplus); ``a`` ``[H]``, negative; ``b``, ``c``
-    ``[B, T, G, N]`` with ``G`` dividing ``H`` (a group's ``B`` and ``C`` are
-    shared by its ``H / G`` heads); ``d`` ``[H]`` or None; ``initial_state``
-    ``[B, H, P, N]`` or None for zeros. The result does not depend on
-    ``chunk`` beyond rounding; a ``T`` that ``chunk`` does not divide is
-    padded with steps of size 0, which leave the state as it is.
-    """
+
+def _local_plain(x, dt, a, b, c, d, size):
+    """The chunk-local stage in plain ``jax.numpy``, for ``x`` ``[B, T, H,
+    P]``, ``dt`` ``[B, T, H]`` (float32), ``a``, ``d`` ``[H]`` (float32; ``d``
+    may be None), ``b``, ``c`` ``[B, T, G, N]`` and a ``T`` of whole chunks of
+    ``size``, chunks first, as the carry's loop takes them: ``Y_diag + D x``
+    ``[n, B, H P, L]`` and ``exp`` of the running sum ``[n, B, H, L]`` (a
+    chunk's steps last, as the kernels give them), every chunk's own state
+    ``[n, B, H, P, N]`` and ``exp`` of its whole decay ``[n, B, H]``, all
+    float32."""
     batch, t, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
-    if h % g:
-        raise ValueError("ssd_scan: %d heads in %d groups" % (h, g))
-    r = h // g
-    size = min(chunk, t)
-    pad = -t % size
-    if pad:
-        x, dt, b, c = (
-            jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-            for v in (x, dt, b, c)
-        )
-    nc = (t + pad) // size
+    r, nc = h // g, t // size
     f32, dtype = jnp.float32, x.dtype
     dot = dict(preferred_element_type=f32)
 
     # everything below: b batch, c chunk, l / s step in a chunk, g group,
     # r head in its group, p head width, n state width
-    dt = dt.astype(f32).reshape(batch, nc, size, g, r)
+    dt = dt.reshape(batch, nc, size, g, r)
     x = x.reshape(batch, nc, size, g, r, p)
     b = b.reshape(batch, nc, size, g, n)
     c = c.reshape(batch, nc, size, g, n)
     x32 = x.astype(f32)
     dtx = x32 * dt[..., None]
     # running sum of the log-decay inside each chunk, heads before steps
-    decay = jnp.cumsum(dt * a.astype(f32).reshape(g, r), axis=2)
+    decay = jnp.cumsum(dt * a.reshape(g, r), axis=2)
     decay = jnp.moveaxis(decay, 2, -1)                           # [b c g r l]
 
     # inside a chunk
@@ -73,36 +120,532 @@ def ssd_scan(x, dt, a, b, c, d=None, *, chunk: int = 256, initial_state=None,
         causal, decay[..., :, None] - decay[..., None, :], -jnp.inf
     ))                                                           # [b c g r l s]
     mixing = (between * scores[:, :, :, None]).astype(dtype)
-    y = jnp.einsum("bcgrls,bcsgrp->bclgrp", mixing, dtx.astype(dtype), **dot)
+    y = jnp.einsum("bcgrls,bcsgrp->bcgrpl", mixing, dtx.astype(dtype), **dot)
+    if d is not None:
+        y = y + d.reshape(g, r, 1, 1) * jnp.moveaxis(x32, 2, -1)
 
     # a chunk's own contribution to the state at its end
     to_end = jnp.exp(decay[..., -1:] - decay)                    # [b c g r l]
     weighted = (dtx * jnp.moveaxis(to_end, -1, 2)[..., None]).astype(dtype)
     own = jnp.einsum("bclgn,bclgrp->bcgrpn", b, weighted, **dot)
+    whole = jnp.exp(decay[..., -1])                              # [b c g r]
+    chunks_first = lambda v, *dims: jnp.moveaxis(v, 1, 0).reshape(nc, batch, *dims)  # noqa: E731
+    return (
+        chunks_first(y, h * p, size), chunks_first(own, h, p, n),
+        chunks_first(whole, h), chunks_first(jnp.exp(decay), h, size),
+    )
 
-    # from chunk to chunk, in float32
-    def carry(state, inputs):
-        whole, new = inputs
-        return whole[..., None, None] * state + new, state
 
+# -- the chunk-local stage as Pallas kernels ---------------------------------
+#
+# One grid step holds a chunk of every head in VMEM, time along the lanes:
+# ``xBC`` ``[B, H P + 2 G N, T]`` (a head of ``x`` is ``P`` sublanes, a group's
+# ``B`` or ``C`` ``N``) and ``dt`` ``[B, H, T]``. The tile of a head is held
+# transposed, ``[s, l]``
+# (the step that wrote along the sublanes, the step that reads along the
+# lanes), so ``Y^T = (dt x)^T mixing^T`` and its two transposed products in the
+# backward are plain ones, and a step's decay is a lane of a ``[1, L]`` row; the
+# column ``decay_s`` comes out of the chunk's one ``[L, H]`` transpose.
+
+
+def _at(i):
+    return slice(i * _BLOCK, (i + 1) * _BLOCK)
+
+
+def _between(rows, cols, s, l):
+    """Block ``(s, l)`` of a head's transposed decay tile, ``exp(decay_l -
+    decay_s)`` where ``s <= l`` and zeros elsewhere: ``rows`` the head's decay
+    a block of lanes (``[1, 128]`` each), ``cols`` a block of sublanes (``[128,
+    1]`` each). A block under the diagonal (``s < l``) needs no mask; one above
+    it is never asked for."""
+    diff = rows[l] - cols[s]
+    if s == l:
+        diff = jnp.where(_iota(diff.shape, 0) <= _iota(diff.shape, 1), diff, -jnp.inf)
+    return jnp.exp(diff)
+
+
+def _decays_of(dt_ref, a_ref, d_ref, decay_ref, to_end_ref, cols_ref, skip_ref):
+    """Every head's running sum of ``dt A`` over the chunk ``[H, L]`` (left in
+    ``decay_ref``, a block of lanes at a time; its transpose in ``cols_ref``,
+    ``exp(decay_L - decay)`` in ``to_end_ref``, ``D`` along a lane tile in
+    ``skip_ref``); returns it and its last column ``[H, 1]``."""
+    decay = _running_sum(dt_ref[0] * a_ref[...], axis=1)
+    last = decay[:, decay.shape[1] - 1:]
+    for i in range(decay_ref.shape[0]):  # a block of lanes apart: a row is read by head
+        decay_ref[i] = decay[:, _at(i)]
+    to_end_ref[...] = jnp.exp(last - decay)
+    cols_ref[...] = decay.T
+    skip_ref[...] = jnp.broadcast_to(d_ref[...], skip_ref.shape)
+    return decay, last
+
+
+def _blocks_of(decay_ref, cols_ref, h, blocks):
+    """Head ``h``'s decay as :func:`_between` takes it: a ``[1, 128]`` row and
+    a ``[128, 1]`` column for each block of the chunk's steps."""
+    from jax.experimental import pallas as pl
+
+    rows = [decay_ref[i, pl.ds(h, 1), :] for i in range(blocks)]
+    return rows, [_column_of(cols_ref[_at(i), :], h) for i in range(blocks)]
+
+
+def _rows(i, width, base=0):
+    """Rows ``base + i * width`` to ``base + (i + 1) * width`` of a block: head
+    ``i``'s of ``x`` (``width`` ``P``), group ``i``'s of ``B`` or ``C``
+    (``width`` ``N``, ``base`` where they start in ``xBC``)."""
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(base + i * width, math.gcd(base, width)), width)
+
+
+def _shape_of(xbc_ref, dt_ref, state_ref):
+    """``(heads, P, N, groups, chunk's blocks)`` and where ``B`` and ``C``
+    start among the rows of ``xBC``, from the kernels' blocks."""
+    heads, size = dt_ref.shape[1:]
+    p, n = state_ref.shape[3:]
+    at_b = heads * p
+    at_c = at_b + (xbc_ref.shape[1] - at_b) // 2
+    return heads, p, n, (at_c - at_b) // n, size // _BLOCK, at_b, at_c
+
+
+def ssd_forward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
+                       y_ref, own_ref, whole_ref, grown_ref,
+                       scores_ref, decay_ref, to_end_ref, cols_ref, skip_ref, weighted_ref):
+    """One chunk of every head: ``Y_diag + D x`` (float32, transposed as the
+    inputs are), the chunk's own state, ``exp`` of its whole decay and of the
+    running sum."""
+    from jax.experimental import pallas as pl
+
+    f32, dtype = jnp.float32, xbc_ref.dtype
+    heads, p, n, groups, blocks, at_b, at_c = _shape_of(xbc_ref, dt_ref, own_ref)
+    r = heads // groups
+    width = _HEADS_A_ROUND
+    decay, last = _decays_of(
+        dt_ref, a_ref, d_ref, decay_ref, to_end_ref, cols_ref, skip_ref
+    )
+    grown_ref[0, 0] = jnp.exp(decay)
+    whole_ref[0, 0] = jnp.exp(last)
+
+    def group(g, carry):
+        b_g, c_g = _rows(g, n, at_b), _rows(g, n, at_c)
+        # the scores of the group's heads, [s, l]: once a group
+        scores_ref[...] = _transposed_times(xbc_ref[0, b_g, :], xbc_ref[0, c_g, :])
+
+        def head(pair, half, carry):
+            h = g * r + width * pair + half
+            rows, one = _rows(h, p), pl.ds(h, 1)
+            x32 = xbc_ref[0, rows, :].astype(f32)
+            dtx = x32 * dt_ref[0, one, :]
+            dtxb = dtx.astype(dtype)
+            rows_h, cols_h = _blocks_of(decay_ref, cols_ref, h, blocks)
+            skip = skip_ref[one, :]
+            for l in range(blocks):
+                acc = skip * x32[:, _at(l)]
+                for s in range(l + 1):
+                    mixing = _between(rows_h, cols_h, s, l) * scores_ref[_at(s), _at(l)]
+                    acc = acc + jnp.dot(
+                        dtxb[:, _at(s)], mixing.astype(dtype), preferred_element_type=f32
+                    )
+                y_ref[0, 0, rows, _at(l)] = acc
+            weighted_ref[rows, :] = (dtx * to_end_ref[one, :]).astype(dtype)
+            return carry
+
+        carry = _over_heads(r, head, carry, width)
+
+        # the own states of the group's heads: B is theirs together, so one
+        # product streams all their rows through it, a slab of heads at a time
+        def slab(i, carry):
+            first = g * r // width + i
+            own = _times_transposed(weighted_ref[_rows(first, width * p), :], xbc_ref[0, b_g, :])
+            own_ref[0, 0, pl.ds(first * width, width)] = own.reshape(width, p, n)
+            return carry
+
+        return jax.lax.fori_loop(0, r // width, slab, carry)
+
+    jax.lax.fori_loop(0, groups, group, 0)
+
+
+def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
+                        dy_ref, down_ref, dwhole_ref, dgrown_ref,
+                        dxbc_ref, ddt_ref, da_ref, dd_ref,
+                        scores_ref, decay_ref, to_end_ref, cols_ref, skip_ref,
+                        weighted_ref, dscores_ref, d_weighted_ref, by_row_ref, by_col_ref,
+                        d_end_ref, dt_part_ref, dd_part_ref):
+    """The cotangents of one chunk's inputs from those of the four outputs:
+    the tile's intermediates are made again in VMEM from the inputs, and every
+    step of the way back is local to the tile. ``dA`` and ``dD`` leave as the
+    chunk's own sums ``[H, 1]``."""
+    from jax.experimental import pallas as pl
+
+    f32, dtype = jnp.float32, xbc_ref.dtype
+    heads, p, n, groups, blocks, at_b, at_c = _shape_of(xbc_ref, dt_ref, down_ref)
+    size, r = dt_ref.shape[2], heads // groups
+    width = _HEADS_A_ROUND
+    decay, last = _decays_of(
+        dt_ref, a_ref, d_ref, decay_ref, to_end_ref, cols_ref, skip_ref
+    )
+    down = lambda a: jnp.sum(a, axis=0, keepdims=True)      # noqa: E731 — [1, L]
+    across = lambda a: jnp.sum(a, axis=1, keepdims=True)    # noqa: E731 — [L, 1]
+    add = lambda acc, a: a if acc is None else acc + a      # noqa: E731
+
+    def group(g, carry):
+        b_g, c_g = _rows(g, n, at_b), _rows(g, n, at_c)
+        scores_ref[...] = _transposed_times(xbc_ref[0, b_g, :], xbc_ref[0, c_g, :])
+        dscores_ref[...] = jnp.zeros(dscores_ref.shape, f32)
+
+        # through the own states of the group's heads, B theirs together: one
+        # product streams all their rows through it, a slab of heads at a time
+        def slab(i, carry):
+            first = g * r // width + i
+            d_own = down_ref[0, 0, pl.ds(first * width, width)].reshape(width * p, n)
+            d_weighted_ref[_rows(first, width * p), :] = jnp.dot(
+                d_own.astype(dtype), xbc_ref[0, b_g, :], preferred_element_type=f32
+            )
+            return carry
+
+        jax.lax.fori_loop(0, r // width, slab, carry)
+
+        def head(pair, half, carry):
+            h = g * r + width * pair + half
+            rows, one = _rows(h, p), pl.ds(h, 1)
+            x32 = xbc_ref[0, rows, :].astype(f32)
+            dt_h, to_end = dt_ref[0, one, :], to_end_ref[one, :]
+            dtx = x32 * dt_h
+            dtxb = dtx.astype(dtype)
+            dy = dy_ref[0, 0, rows, :]
+            dyb = dy.astype(dtype)
+            rows_h, cols_h = _blocks_of(decay_ref, cols_ref, h, blocks)
+
+            # through Y^T = (dt x)^T mixing^T, a block of the tile at a time
+            d_dtx, by_row, by_col = [None] * blocks, [None] * blocks, [None] * blocks
+            for l in range(blocks):
+                for s in range(l + 1):
+                    between = _between(rows_h, cols_h, s, l)
+                    mixing = between * scores_ref[_at(s), _at(l)]
+                    d_mixing = _transposed_times(dtxb[:, _at(s)], dyb[:, _at(l)])
+                    # the two dM reductions: the decay's gradient by row and by column
+                    both = d_mixing * mixing
+                    by_row[l] = add(by_row[l], down(both))
+                    by_col[s] = add(by_col[s], across(both))
+                    dscores_ref[_at(s), _at(l)] += d_mixing * between
+                    d_dtx[s] = add(
+                        d_dtx[s], _times_transposed(dyb[:, _at(l)], mixing.astype(dtype))
+                    )
+            d_dtx = jnp.concatenate(d_dtx, axis=1)
+
+            # through the chunk's own state
+            d_weighted = d_weighted_ref[rows, :]
+            d_dtx = d_dtx + d_weighted * to_end
+            d_end_ref[one, :] = down(d_weighted * dtx) * to_end
+            weighted_ref[rows, :] = (dtx * to_end).astype(dtype)
+
+            skip = jnp.concatenate([skip_ref[one, :]] * blocks, axis=1)
+            dxbc_ref[0, rows, :] = (d_dtx * dt_h + skip * dy).astype(dtype)
+            dt_part_ref[one, :] = down(d_dtx * x32)
+            dd_part_ref[one, :] = down(dy * x32)
+            by_row_ref[one, :] = (
+                jnp.concatenate(by_row, axis=1)
+                + dgrown_ref[0, 0, one, :] * jnp.exp(jnp.concatenate(rows_h, axis=1))
+            )
+            by_col = jnp.concatenate(by_col, axis=0)
+            by_col_ref[...] = jnp.where(
+                _iota(by_col_ref.shape, 1) == h, by_col, by_col_ref[...]
+            )
+            return carry
+
+        carry = _over_heads(r, head, carry, width)
+
+        # the group's B and C: through the scores, and B through the own states
+        # of its heads (one product over the heads' rows)
+        b, c = xbc_ref[0, b_g, :], xbc_ref[0, c_g, :]
+        d_own = down_ref[0, 0, pl.ds(g * r, r)].reshape(r * p, n).astype(dtype)
+        db = _transposed_times(d_own, weighted_ref[_rows(g, r * p), :])
+        db_s, dc_l = [None] * blocks, [None] * blocks
+        for l in range(blocks):
+            for s in range(l + 1):
+                d_scores = dscores_ref[_at(s), _at(l)].astype(dtype)
+                dc_l[l] = add(
+                    dc_l[l], jnp.dot(b[:, _at(s)], d_scores, preferred_element_type=f32)
+                )
+                db_s[s] = add(db_s[s], _times_transposed(c[:, _at(l)], d_scores))
+        dxbc_ref[0, b_g, :] = (db + jnp.concatenate(db_s, axis=1)).astype(dtype)
+        dxbc_ref[0, c_g, :] = jnp.concatenate(dc_l, axis=1).astype(dtype)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
+
+    # every head's decay at once: rows less columns, the chunk's end, and back
+    # through the running sum
+    d_end = d_end_ref[...]
+    d_last = across(d_end) + dwhole_ref[0, 0] * jnp.exp(last)
+    d_decay = (
+        by_row_ref[...] - d_end - by_col_ref[...].T
+        + jnp.where(_iota(decay.shape, 1) == size - 1, d_last, 0.0)
+    )
+    d_log = _running_sum(d_decay, reverse=True, axis=1)
+    ddt_ref[0] = d_log * a_ref[...] + dt_part_ref[...]
+    da_ref[0, 0] = across(d_log * dt_ref[0])
+    dd_ref[0, 0] = across(dd_part_ref[...])
+
+
+_INPUTS = ("packed", "steps", "head", "head")                          # xbc dt a d
+_OUTPUTS = ("local", "state", "whole", "grown")                        # y own whole grown
+
+
+def _kinds(xbc, dt, size, p, n):
+    """The kernels' kinds of operand (``ops/gated_delta.py:_chunk_call``): a
+    kind's shape, dtype, block and the block's place at batch ``b``, chunk
+    ``c``; and the scratch both kernels share."""
+    batch, rows, steps = xbc.shape
+    h, nc = dt.shape[1], steps // size
+    f32, dtype = jnp.float32, xbc.dtype
+    lanes = lambda b, c: (b, 0, c)          # noqa: E731 — a chunk's steps of [B, ., T]
+    first = lambda b, c: (c, b)             # noqa: E731 — the carry's: chunks first
+    kinds = dict(
+        # every row of xBC (x, B, C one under another), as the convolution left it
+        packed=((batch, rows, steps), dtype, (1, rows, size), lanes),
+        steps=((batch, h, steps), f32, (1, h, size), lanes),
+        head=((h, 1), f32, (h, 1), lambda b, c: (0, 0)),
+        # the carry's operands, chunks first
+        local=((nc, batch, h * p, size), f32, (1, 1, h * p, size), first),
+        grown=((nc, batch, h, size), f32, (1, 1, h, size), first),
+        state=((nc, batch, h, p, n), f32, (1, 1, h, p, n), first),
+        whole=((nc, batch, h, 1), f32, (1, 1, h, 1), first),
+    )
+    # the group's scores, every head's decay, exp(decay_L - decay), decay^T, D,
+    # dt x decayed to the chunk's end
+    scratch = (((size, size), f32), ((size // _BLOCK, h, _BLOCK), f32), ((h, size), f32),
+               ((size, h), f32), ((h, _BLOCK), f32), ((h * p, size), dtype))
+    return kinds, scratch
+
+
+# jitted, as ``ops/causal_conv.py``'s: a step traces and lowers each body once
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _forward_call(xbc, dt, a, d, size, p, n, interpret):
+    kinds, scratch = _kinds(xbc, dt, size, p, n)
+    grid = (xbc.shape[0], xbc.shape[2] // size)
+    return _chunk_call(
+        ssd_forward_kernel, grid, kinds, _INPUTS, _OUTPUTS, (xbc, dt, a, d),
+        interpret, scratch,
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _backward_call(xbc, dt, a, d, dy, d_own, d_whole, d_grown, size, p, n, interpret):
+    kinds, scratch = _kinds(xbc, dt, size, p, n)
+    grid = (xbc.shape[0], xbc.shape[2] // size)
+    f32, h = jnp.float32, dt.shape[1]
+    by_head = ((h, size), f32)
+    scratch = (
+        *scratch, ((size, size), f32), ((h * p, size), f32), by_head, ((size, h), f32),
+        by_head, by_head, by_head,
+    )
+    outs = ("packed", "steps", "whole", "whole")  # dxbc ddt da dd
+    return _chunk_call(
+        ssd_backward_kernel, grid, kinds, (*_INPUTS, *_OUTPUTS), outs,
+        (xbc, dt, a, d, dy, d_own, d_whole, d_grown), interpret, scratch,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _local_kernels(xbc, dt, a, d, size, p, n, interpret):
+    """The chunk-local stage by the kernels, time along the lanes: ``xbc``
+    ``[B, H P + 2 G N, T]`` (``x``, ``B`` and ``C`` one under another, as the
+    convolution leaves them: nothing is sliced out), ``dt`` ``[B, H, T]``
+    (float32), ``a``, ``d`` ``[H, 1]`` (float32); returns, chunks first,
+    ``Y_diag + D x`` ``[n, B, H P, L]``, the own states ``[n, B, H, P, N]``,
+    ``exp`` of the chunks' whole decays ``[n, B, H, 1]`` and of the running sum
+    ``[n, B, H, L]``, float32."""
+    return _forward_call(xbc, dt, a, d, size, p, n, interpret)
+
+
+def _local_kernels_fwd(xbc, dt, a, d, size, p, n, interpret):
+    # nothing but the inputs: the backward makes the tile again
+    return _forward_call(xbc, dt, a, d, size, p, n, interpret), (xbc, dt, a, d)
+
+
+def _local_kernels_bwd(size, p, n, interpret, inputs, cotangents):
+    dxbc, ddt, da, dd = _backward_call(*inputs, *cotangents, size, p, n, interpret)
+    return dxbc, ddt, da.sum((0, 1)), dd.sum((0, 1))
+
+
+_local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
+
+
+# -- from chunk to chunk -----------------------------------------------------
+
+
+def _inherit(state, c_n, dtype):
+    """What a chunk's steps read of the state it inherits, ``C S`` ``[B, G, R,
+    P, L]`` (float32): ``state`` ``[B, G, R, P, N]``, ``c_n`` ``[B, G, N, L]``."""
+    return jnp.einsum(
+        "bgnl,bgrpn->bgrpl", c_n, state.astype(dtype), preferred_element_type=jnp.float32
+    )
+
+
+# bound here: the family's wrong program (``benchmark/tests/test_ssm_lm.py``, a
+# carried state kept in bfloat16) swaps this module's ``jax.lax.scan`` for one
+# that rounds the forward's carry and knows no ``reverse``
+_scan_back = functools.partial(jax.lax.scan, reverse=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _carry_out(state, whole, own, local, grown, c, dtype):
+    """The state from chunk to chunk, and every chunk's outputs as the loop
+    passes it: ``(y, final)`` for the initial ``state`` ``[B, G, R, P, N]``
+    (float32) and, chunks first, ``whole`` ``[n, B, G, R]``, ``own`` ``[n, B, G,
+    R, P, N]``, ``local`` ``[n, B, G, R, P, L]`` (``Y_diag + D x``), ``grown``
+    ``[n, B, G, R, L]`` (all float32) and ``c`` ``[n, B, G, N, L]``. ``y`` ``[B,
+    G, R, P, n L]`` in ``dtype``, time last: the loop rounds each chunk's
+    outputs as it passes, so what is laid out for the mixer's gate afterwards
+    is the result in ``dtype`` and no float32 ``[T, H P]`` array. One
+    ``lax.scan`` of ``n`` steps, a matmul and an elementwise update each; its
+    backward is one reverse scan that makes ``C S`` again from the state a
+    chunk inherited, which is all the forward keeps."""
+    return _carry_out_fwd(state, whole, own, local, grown, c, dtype)[0]
+
+
+def _carry_out_fwd(state, whole, own, local, grown, c, dtype):
+    def step(state, inputs):
+        whole_n, own_n, local_n, grown_n, c_n = inputs
+        y_n = local_n + _inherit(state, c_n, dtype) * grown_n[..., None, :]
+        return whole_n[..., None, None] * state + own_n, (state, y_n.astype(dtype))
+
+    final, (entering, y) = jax.lax.scan(step, state, (whole, own, local, grown, c))
+    # the chunks' steps one after another, time last. The barrier holds the
+    # reader's float32 conversion behind the layout change: XLA then moves
+    # whole (sublanes, L) tiles once, in ``dtype`` (0.10 ms a call at Granite's
+    # shape), where it made a transposing copy and a retiling of the float32
+    # array (0.82 ms)
+    y = jnp.moveaxis(y, 0, -2)
+    y = jax.lax.optimization_barrier(y.reshape(*y.shape[:-2], -1))
+    return (y, final), (entering, whole, grown, c)
+
+
+def _carry_out_bwd(dtype, residuals, cotangents):
+    entering, whole, grown, c = residuals
+    dy, d_final = cotangents
+    nc, size = grown.shape[0], grown.shape[-1]
+    f32 = jnp.float32
+
+    def step(d_after, inputs):
+        index, state, whole_n, grown_n, c_n = inputs
+        dy_n = jax.lax.dynamic_slice_in_dim(dy, index * size, size, axis=-1).astype(f32)
+        inherited = _inherit(state, c_n, dtype)
+        d_inherited = dy_n * grown_n[..., None, :]
+        d_state = whole_n[..., None, None] * d_after + jnp.einsum(
+            "bgrpl,bgnl->bgrpn", d_inherited, c_n, preferred_element_type=f32
+        )
+        d_c = jnp.einsum(
+            "bgrpl,bgrpn->bgnl", d_inherited, state.astype(dtype), preferred_element_type=f32
+        )
+        return d_state, (
+            jnp.sum(d_after * state, axis=(-1, -2)), d_after, dy_n,
+            jnp.sum(dy_n * inherited, axis=-2), d_c.astype(c.dtype),
+        )
+
+    d_state, (d_whole, d_own, d_local, d_grown, d_c) = _scan_back(
+        step, d_final, (jnp.arange(nc), entering, whole, grown, c)
+    )
+    return d_state, d_whole, d_own, d_local, d_grown, d_c
+
+
+_carry_out.defvjp(_carry_out_fwd, _carry_out_bwd)
+
+
+def _kernels_refuse(dtype, h, p, g, n, steps, chunk, interpret):
+    """Why the chunk-local stage of these operands is not the kernels', or
+    None where it is: the first of a TPU backend or the interpreter
+    (``backend``), bfloat16 operands (``dtype``), a chunk of whole lane tiles
+    (``chunk``) that divides the length (``steps``), a group's heads in eights
+    (``heads``), a head of whole bfloat16 sublane tiles over a state of whole
+    lane tiles (``width``) and blocks a core's VMEM holds (``vmem``) that does
+    not hold."""
+    # x, Y and dY or dx of a chunk, twice each, and the own states
+    blocks = 2 * chunk * h * p * (2 + 4 + 4) + 4 * 4 * h * p * n
+    conditions = (
+        ("backend", interpret or jax.default_backend() == "tpu"),
+        ("dtype", dtype == jnp.bfloat16),
+        ("chunk", chunk % _BLOCK == 0),
+        ("steps", steps % chunk == 0),
+        ("heads", (h // g) % _HEADS_A_ROUND == 0),
+        ("width", p % 16 == 0 and n % 128 == 0),
+        ("vmem", blocks <= _VMEM_MOST),
+    )
+    return next((why for why, met in conditions if not met), None)
+
+
+def ssd_scan(x, dt, a, b, c, d=None, *, chunk: int = 256, initial_state=None,
+             return_final_state: bool = False, interpret: bool = False):
+    """``y`` ``[B, T, H, P]`` in ``x``'s dtype (and the final state, float32
+    ``[B, H, P, N]``, with ``return_final_state``).
+
+    ``x`` ``[B, T, H, P]``; ``dt`` ``[B, T, H]``, the step sizes, already
+    positive (after the softplus); ``a`` ``[H]``, negative; ``b``, ``c``
+    ``[B, T, G, N]`` with ``G`` dividing ``H`` (a group's ``B`` and ``C`` are
+    shared by its ``H / G`` heads); ``d`` ``[H]`` or None; ``initial_state``
+    ``[B, H, P, N]`` or None for zeros. The result does not depend on
+    ``chunk`` beyond rounding; a ``T`` that ``chunk`` does not divide is
+    padded with steps of size 0, which leave the state as it is (and takes the
+    plain form). ``interpret`` runs the kernels in the Pallas interpreter.
+    """
+    batch, t, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    if h % g:
+        raise ValueError("ssd_scan: %d heads in %d groups" % (h, g))
+    r = h // g
+    size = min(chunk, t)
+    why_plain = _kernels_refuse(jnp.result_type(x, b, c), h, p, g, n, t, size, interpret)
+    pad = -t % size
+    if pad:
+        x, dt, b, c = (
+            jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+            for v in (x, dt, b, c)
+        )
+    steps = t + pad
+    nc = steps // size
+    f32, dtype = jnp.float32, x.dtype
+    # once a shape and stage: which form the chunk-local stage took, and why
+    # where it is the plain one
+    note = functools.partial(
+        obs_trace.get_tracer().note_once, "ssm_chunks", chunk=size, chunks=nc,
+        heads=h, groups=g, d_head=p, d_state=n, state_bytes=4 * h * p * n,
+    )
+    dt, a = dt.astype(f32), a.astype(f32)
+    if why_plain is None:
+        note(path="kernel")
+        # x, B and C one under another, time last. The mixer hands over three
+        # slices of the one array its convolution wrote, and XLA passes that
+        # array whole: no slice of it and no concatenation is made (nor of the
+        # gradient, which leaves as one array of its shape)
+        xbc = jnp.concatenate([v.reshape(batch, steps, -1) for v in (x, b, c)], axis=-1)
+        # the carry's C out of the same array: its gradient then joins the
+        # kernels' in one pass over ``d xbc`` (read off ``c`` itself, XLA makes
+        # a slice of 4224 channels and a sum of its own)
+        c = xbc[..., h * p + g * n:].reshape(c.shape)
+        skip = jnp.zeros((h,), f32) if d is None else d.astype(f32)
+        y, own, whole, grown = _local_kernels(
+            xbc.swapaxes(1, 2), dt.swapaxes(1, 2), a.reshape(h, 1), skip.reshape(h, 1),
+            size, p, n, interpret,
+        )
+        whole = whole[..., 0]
+    else:
+        note(path="plain", why=why_plain)
+        y, own, whole, grown = _local_plain(
+            x, dt, a, b, c, None if d is None else d.astype(f32), size
+        )
+
+    # from chunk to chunk, the state in float32, and every chunk's outputs
     if initial_state is None:
         state = jnp.zeros((batch, g, r, p, n), f32)
     else:
         state = initial_state.astype(f32).reshape(batch, g, r, p, n)
-    whole = jnp.exp(decay[..., -1])                              # [b c g r]
-    state, entering = jax.lax.scan(
-        carry, state, (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(own, 1, 0))
+    # a chunk's C with its steps last, as the kernels take it
+    c = jnp.transpose(c.reshape(batch, nc, size, g, n), (1, 0, 3, 4, 2))
+    y, state = _carry_out(
+        state, whole.reshape(nc, batch, g, r), own.reshape(nc, batch, g, r, p, n),
+        y.reshape(nc, batch, g, r, p, size), grown.reshape(nc, batch, g, r, size), c, dtype,
     )
-    entering = jnp.moveaxis(entering, 0, 1)                      # [b c g r p n]
-
-    # what a chunk inherits, seen through C and decayed to each step
-    inherited = jnp.einsum(
-        "bclgn,bcgrpn->bclgrp", c, entering.astype(dtype), **dot
-    )
-    y = y + inherited * jnp.moveaxis(jnp.exp(decay), -1, 2)[..., None]
-    if d is not None:
-        y = y + d.astype(f32).reshape(g, r, 1) * x32
-    y = y.reshape(batch, t + pad, h, p)[:, :t].astype(dtype)
+    y = y.reshape(batch, h * p, steps).swapaxes(1, 2)[:, :t].reshape(batch, t, h, p)
     if return_final_state:
         return y, state.reshape(batch, h, p, n)
     return y

@@ -15,6 +15,7 @@ functions directly.
 """
 
 import importlib
+import math
 import os
 import re
 
@@ -243,12 +244,16 @@ def test_the_two_kernel_backward_compiles_for_v5e(
     assert lowered.compile().as_text().count("tpu_custom_call") == 2
 
 
-def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip):
-    """The chunked scan with jax's own backward at one sequence of 8192, 64
-    heads of 64 over a state of 128, chunk 256: plain XLA, so what the chip's
-    compiler can refuse is the memory. The float32 decay matrices of one pass
-    are 537 MB; value and gradients together must stay a small part of the
-    16 GB the step shares."""
+SSD_CELLS = {"granite": (256, 1), "nemotron": (128, 4)}     # chunk, groups
+
+
+def _ssd_value_and_grads(one_chip, chunk, groups, path):
+    """``ssd_scan``'s value and six gradients at one sequence of 8192, 64 heads
+    of 64 over a state of 128, lowered as a CPU lowers it (``plain``) or as the
+    chip does (``kernels``: ``jax.default_backend`` steered here, in the test):
+    the lowered text's kernel names and the compiled program."""
+    from unittest import mock
+
     from edl_tpu.ops import ssd_scan
 
     def sds(dims, dtype=jnp.bfloat16):
@@ -256,14 +261,52 @@ def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip):
 
     t, h, p, n = 8192, 64, 64, 128
     args = (sds((1, t, h, p)), sds((1, t, h), jnp.float32), sds((h,), jnp.float32),
-            sds((1, t, 1, n)), sds((1, t, 1, n)), sds((h,), jnp.float32))
+            sds((1, t, groups, n)), sds((1, t, groups, n)), sds((h,), jnp.float32))
 
     def value_and_grads(w, *a):
-        out, vjp = jax.vjp(lambda *a: ssd_scan(*a, chunk=256), *a)
+        out, vjp = jax.vjp(lambda *a: ssd_scan(*a, chunk=chunk), *a)
         return (out, *vjp(w))
 
-    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, p)), *args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    with mock.patch.object(jax, "default_backend", lambda: "tpu" if path == "kernels" else "cpu"):
+        lowered = jax.jit(lambda w, *a: value_and_grads(w, *a)).lower(sds((1, t, h, p)), *args)
+    return sorted(_kernel_names(lowered.as_text())), lowered.compile()
+
+
+def _square_tiles(compiled, chunk):
+    """The instructions of a compiled program, outside its Pallas calls, whose
+    result holds a float32 ``[.., L, L]`` array of a million elements or more:
+    the decay matrices and the scores of every chunk (``B`` and ``C`` of a
+    chunk are ``[L, N]`` and ``N`` may be ``L``: bfloat16, or one chunk's
+    ``dC`` inside the carry's loop, 64 K elements)."""
+    square = re.compile(r"f32\[([0-9,]*%d,%d)\]" % (chunk, chunk))
+    found = []
+    for line in compiled.as_text().splitlines():
+        if " = " not in line or "tpu_custom_call" in line:
+            continue
+        shape = square.search(line.split(" = ", 1)[1].split(" ", 1)[0])
+        if shape and math.prod(int(d) for d in shape.group(1).split(",")) >= 1 << 20:
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip, path):
+    """The chunked scan at one sequence of 8192, 64 heads of 64 over a state of
+    128, chunk 256. Plain XLA with jax's own backward (a CPU backend, a shape
+    the kernels refuse), what the chip's compiler can refuse is the memory: the
+    float32 decay matrices of one pass are 537 MB; value and gradients together
+    must stay a small part of the 16 GB the step shares. As the chip lowers it
+    the chunk-local stage is ``ssd_forward`` and ``ssd_backward``, each once,
+    and no ``[256, 256]`` array exists outside them."""
+    kernels, compiled = _ssd_value_and_grads(one_chip, *SSD_CELLS["granite"], path)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == len(kernels)
+    if path == "kernels":
+        assert kernels == ["ssd_backward", "ssd_forward"] and temp < 1.5e9
+        assert _square_tiles(compiled, 256) == []
+    else:
+        assert kernels == [] and temp < 4e9
+        assert _square_tiles(compiled, 256)
 
 
 def test_causal_conv_kernels_compile_for_v5e_at_granites_widths(one_chip):
@@ -493,6 +536,69 @@ def test_a_hybrid_step_on_the_tpu_path_runs_the_conv_kernels_under_ssm_conv(one_
     calls = sorted(name.split(".")[0] for name in table if name.startswith("causal_conv_"))
     assert calls == ["causal_conv_bwd"] * len(layers) + ["causal_conv_fwd"] * 2 * len(layers)
     assert {table[name] for name in table if name.startswith("causal_conv_")} == {"ssm_conv"}
+
+
+def test_a_hybrid_step_on_the_tpu_path_runs_the_scan_kernels_under_ssm_scan(one_chip):
+    """A toy hybrid of a shape the scan's kernels take (16 heads of 16 in two
+    groups over a state of 256, two chunks of 128), lowered as the chip lowers
+    it: each Mamba-2 layer holds ``ssd_forward`` twice (the value and its
+    recomputation under remat) and ``ssd_backward`` once, the compiled step
+    names all of them under ``ssm_scan`` (where ``ssm_scan_ms`` and
+    ``step_kernel_calls`` find them), no matmul is left unplaced, the one
+    ``ssm_chunks`` instant reads ``path="kernel"`` (so ``step_plain_fallbacks``
+    counts none for it), and no float32 ``[128, 128]`` array exists outside
+    the kernels."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, MambaSpec, TransformerLM
+    from edl_tpu.models.mamba import SSM_SCOPES
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.obs import trace as obs_trace
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers = ("mamba", "mamba")
+    lm = TransformerLM(
+        vocab_size=64, d_model=48, num_heads=4, num_kv_heads=2, num_layers=len(layers),
+        d_ff=40, dtype=jnp.bfloat16, remat=True, norm_eps=1e-5,
+        arch=ArchSpec(
+            layer_types=layers, head_dim=16, rope=False, tie_embeddings=True,
+            mamba=MambaSpec(num_heads=16, head_dim=16, d_state=256, n_groups=2, chunk=128),
+        ),
+    )
+    tokens = np.zeros((1, 256), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    tracer = obs_trace.get_tracer()
+    tracer.reset_notes()
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    noted = [args for name, args in tracer.notes() if name == "ssm_chunks"]
+    assert [dict(n)["path"] for n in noted] == ["kernel"]
+    assert {"ssd_forward", "ssd_backward"} <= set(_kernel_names(lowered.as_text()))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    table = obs_profile.scopes_of_hlo(text, SSM_SCOPES)
+    calls = sorted(name.split(".")[0] for name in table if name.startswith("ssd_"))
+    assert calls == ["ssd_backward"] * len(layers) + ["ssd_forward"] * 2 * len(layers)
+    assert {table[name] for name in table if name.startswith("ssd_")} == {"ssm_scan"}
+    census = obs_profile.HloProgram(text).census()
+    assert census["totals"]["unplaced_matmuls"] == 0
+    assert census["kernels"]["ssd_forward/forward"] == len(layers)
+    assert census["kernels"]["ssd_forward/backward"] == len(layers)
+    assert census["kernels"]["ssd_backward/backward"] == len(layers)
+    assert _square_tiles(compiled, 128) == []
 
 
 def _head_projection_gradients(text):
@@ -1041,27 +1147,134 @@ def test_megablox_compiles_for_v5e_in_a_latent_of_1024_at_a_width_of_2688(one_ch
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
-def test_ssd_scan_compiles_for_v5e_in_four_groups_at_a_chunk_of_128(one_chip):
-    """The chunked scan with jax's own backward at one sequence of 8192, the 64
-    heads of 64 the cell holds in 4 groups of 16 over a state of 128, chunk 128
-    (``r = h // g`` = 16: the first cell with more than one group). Plain XLA,
-    so what the chip's compiler can refuse is the memory: at half Granite's
-    chunk the float32 decay matrices of one pass are 268 MB."""
-    from edl_tpu.ops import ssd_scan
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_ssd_scan_compiles_for_v5e_in_four_groups_at_a_chunk_of_128(one_chip, path):
+    """The chunked scan at one sequence of 8192, the 64 heads of 64 the cell
+    holds in 4 groups of 16 over a state of 128, chunk 128 (``r = h // g`` =
+    16: the first cell with more than one group). Plain XLA, what the chip's
+    compiler can refuse is the memory: at half Granite's chunk the float32
+    decay matrices of one pass are 268 MB. As the chip lowers it, the two
+    kernels and no ``[128, 128]`` array outside them."""
+    kernels, compiled = _ssd_value_and_grads(one_chip, *SSD_CELLS["nemotron"], path)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if path == "kernels":
+        assert kernels == ["ssd_backward", "ssd_forward"] and temp < 1.5e9
+        assert _square_tiles(compiled, 128) == []
+    else:
+        assert kernels == [] and temp < 3e9
+
+
+@pytest.mark.parametrize("kernel", ["ssd_forward", "ssd_backward"])
+@pytest.mark.parametrize("cell", list(SSD_CELLS))
+def test_ssd_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, cell, kernel):
+    """The two kernels of the scan's chunk-local stage at one sequence of 8192
+    and 64 heads of 64 over a state of 128, a chunk of every head a grid step,
+    time along the lanes (blocks of ``[64 * 64 + 2 * groups * 128, chunk]``
+    columns of the transposed ``xBC``, a head's 64 sublanes and a group's 128
+    taken by dynamic slices inside the body's loops, a head's decay a ``[1,
+    128]`` row of a scratch a block of lanes apart, the own states of a slab of
+    heads by one product): the
+    tiling, the slices, the transposed products and the VMEM limit set from
+    the shapes are what the chip's compiler can refuse."""
+    S = importlib.import_module("edl_tpu.ops.ssd")
 
     def sds(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    t, h, p, g, n = 8192, 64, 64, 4, 128
-    args = (sds((1, t, h, p)), sds((1, t, h), jnp.float32), sds((h,), jnp.float32),
-            sds((1, t, g, n)), sds((1, t, g, n)), sds((h,), jnp.float32))
+    chunk, groups = SSD_CELLS[cell]
+    t, h, p, n, f32 = 8192, 64, 64, 128, jnp.float32
+    nc = t // chunk
+    inputs = (sds((1, h * p + 2 * groups * n, t)), sds((1, h, t), f32), sds((h, 1), f32),
+              sds((h, 1), f32))
+    outputs = (sds((nc, 1, h * p, chunk), f32), sds((nc, 1, h, p, n), f32),
+               sds((nc, 1, h, 1), f32), sds((nc, 1, h, chunk), f32))
+    call, args = {
+        "ssd_forward": (lambda *a: S._forward_call(*a, chunk, p, n, False), inputs),
+        "ssd_backward": (lambda *a: S._backward_call(*a, chunk, p, n, False),
+                         (*inputs, *outputs)),
+    }[kernel]
+    compiled = jax.jit(call).lower(*args).compile()
+    assert kernel in compiled.as_text()
+    out = jax.eval_shape(call, *args)
+    want = outputs if kernel == "ssd_forward" else (
+        inputs[0], inputs[1], outputs[2], outputs[2])
+    assert [(a.shape, a.dtype) for a in out] == [(a.shape, a.dtype) for a in want]
 
-    def value_and_grads(w, *a):
-        out, vjp = jax.vjp(lambda *a: ssd_scan(*a, chunk=128), *a)
-        return (out, *vjp(w))
 
-    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, p)), *args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+def _large_under(text, scope, elements):
+    """``(opcode or the fusion's name, dtype)`` of the compiled program's entry
+    instructions named under ``scope`` that write ``elements`` values or more
+    themselves: kernels, loops, a loop's empty output buffers and the
+    bookkeeping around them (``get-tuple-element``, ``bitcast``, ``tuple``)
+    left out."""
+    found = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        named = re.search(r'op_name="([^"]*)"', line)
+        if " = " not in line or not named or "/%s/" % scope not in named.group(1) + "/":
+            continue
+        name, rest = line.strip().split(" = ", 1)
+        written = re.match(r"\(?(\w+)\[([0-9,]*)\]", rest)
+        opcode = re.search(r"[\])}] ([a-z\-]+)\(", rest)
+        if not written or not opcode or opcode.group(1) in (
+                "custom-call", "while", "broadcast", "get-tuple-element", "bitcast", "tuple"):
+            continue
+        if math.prod(int(d) for d in written.group(2).split(",") if d) >= elements:
+            kind = opcode.group(1)
+            # a fusion by its name, which XLA makes from what it holds
+            found.append((name.lstrip("%").split(".")[0] if kind == "fusion" else kind,
+                          written.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("cell", list(SSD_CELLS))
+def test_a_mixer_at_the_cells_widths_passes_xbc_whole_and_moves_y_once_in_bfloat16(
+        one_chip, cell):
+    """One ``Mamba2Mixer`` at a cell's widths (8192 steps, 64 heads of 64 over a
+    state of 128), value and gradients under ``jax.checkpoint`` as a block's
+    recomputation runs it, lowered as the chip lowers it. What XLA does around
+    the scan's kernels is worth as much as the kernels (PERF.md section 6, PR
+    50), and an upgrade of XLA could take it back silently: **between the
+    convolution and the scan** the mixer's three slices of ``xBC`` and
+    ``ssd_scan``'s laying them side by side cancel, so under ``ssm_scan`` no
+    array of ``x``'s size is sliced, fused or concatenated, and the backward
+    kernel writes one gradient of ``xBC``'s whole shape; **between the scan and
+    the gate** ``y`` is laid out for its reader by one bfloat16 copy a forward
+    pass (the ``optimization_barrier`` in ``_carry_out_fwd``; without it XLA
+    converts first and copies and retiles ``[8192, 4096]`` float32), and the
+    gate makes no copy of its own."""
+    from unittest import mock
+
+    from edl_tpu.models import Mamba2Mixer, MambaSpec
+
+    (chunk, groups), d_model = SSD_CELLS[cell], {"granite": 2048, "nemotron": 4096}[cell]
+    t, h, p, n = 8192, 64, 64, 128
+    mixer = Mamba2Mixer(
+        MambaSpec(num_heads=h, head_dim=p, d_state=n, n_groups=groups, chunk=chunk),
+        jnp.bfloat16, 1e-5,
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    x = jax.ShapeDtypeStruct((1, t, d_model), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype))["params"]
+    )
+
+    def loss(params, x):
+        out = jax.checkpoint(lambda params, x: mixer.apply({"params": params}, x))(params, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(described(params), described(x))
+    text = lowered.compile().as_text()
+    assert " concatenate(" not in text
+    assert re.search(r"\(bf16\[1,%d,%d\]\S* [^=]*custom-call\(" % (h * p + 2 * groups * n, t), text)
+    moved = _large_under(text, "ssm_scan", t * h * p)   # a copy, or a fusion of one and a bitcast
+    assert [(kind[:4], dtype) for kind, dtype in moved] == [("copy", "bf16")] * 2
+    at_the_gate = _large_under(text, "ssm_gate", t * h * p)
+    assert at_the_gate and not [
+        kind for kind, _ in at_the_gate if "copy" in kind or "transpose" in kind
+    ]
 
 
 def test_a_one_branch_step_on_the_tpu_path_leaves_no_matmul_unplaced(one_chip):
@@ -1172,8 +1385,14 @@ def test_the_latent_cells_share_of_the_heads_is_the_one_its_plan_chose():
 @pytest.mark.slow
 def test_the_latent_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded():
     """``benchmark/tools/compile_for_v5e.py`` on the cell as it runs (3 to 5
-    minutes): the step compiles, and the plan's total is what the
-    configuration's file records, to 0.1 GB."""
+    minutes): the step compiles, and the plan's total is within 0.1 GB of
+    14.20. The configuration's file keeps PR 49's 14.094 (a ``benchmark`` PR's
+    to rewrite: a ``perf_opt`` PR edits nothing under ``benchmark/``); since
+    the scan's chunk-local stage is kernels (PR 50) the plan reads 14.203
+    (temporaries 4.65 GB, code 0.37): the float32 ``Y_diag`` a layer's kernel
+    hands its carry loop (134 MB) is alive beside the loop's stacked outputs,
+    where XLA fused the plain form's into the product's pass. On the chip the
+    step's peak did not rise (``hbm_peak_gb`` 9.56 for 9.59, PERF.md)."""
     import json
     import subprocess
     import sys
@@ -1189,4 +1408,5 @@ def test_the_latent_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorde
     doc = json.loads(out.stdout.strip().splitlines()[-1])
     recorded = next(t for t in config["plan"]["tried"] if t["chips_a_heads"] == 2)
     assert doc["parameters"] == recorded["parameters"]
-    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
+    assert recorded["total_gb"] == pytest.approx(14.09, abs=0.01)
+    assert doc["total_gb"] == pytest.approx(14.20, abs=0.1)
