@@ -40,9 +40,12 @@ forward and once in reverse a layer and the solve once; one that saves none
 runs the forward loop and the solve again when the block is recomputed.
 
 :class:`KimiDeltaMixer` is Kimi delta attention, the same rule with a log-decay
-for every key channel under a safe gate (``ops/gated_delta.py:kda_rule``):
-separate projections and convolutions for q, k and v, a sigmoid gate, the
-scopes ``kda_*`` and the same names for a remat policy.
+for every key channel (``ops/gated_delta.py:kda_rule``): separate projections
+and convolutions for q, k and v, a sigmoid gate, the scopes ``kda_*`` and the
+same names for a remat policy; by its spec the decay's gate is the safe gate
+(a lower bound) or Kimi Linear's own unbounded softplus gate, beta lies in (0,
+1) or (0, 2), and the decay's and the gate's projections are one full matrix
+each or a low-rank pair.
 """
 
 from __future__ import annotations
@@ -59,7 +62,6 @@ from jax.ad_checkpoint import checkpoint_name
 from edl_tpu.models.mamba import _dt_bias_init
 from edl_tpu.ops.causal_conv import causal_conv_silu
 from edl_tpu.ops.gated_delta import (
-    MAX_DECAY_A_STEP,
     OUT_NAME,
     REMAT_NAMES,
     gated_delta_rule,
@@ -189,7 +191,13 @@ class KimiDeltaSpec:
     value_dim: int             # d_v
     d_conv: int = 4
     chunk: int = 64            # steps a chunk of the rule: a power of two
-    lower_bound: float = -5.0  # the safe gate: g in (lower_bound, 0) a channel a step
+    # the safe gate: g in (lower_bound, 0) a channel a step. None: Kimi Linear's
+    # own gate, -exp(A_log) softplus(.), which no bound holds
+    lower_bound: float | None = -5.0
+    neg_eigval: bool = False   # beta in (0, 2) and not (0, 1)
+    # the decay's and the gate's projections as a pair of matrices through this
+    # width (Kimi Linear's own: the head's width). None: one full matrix each
+    gate_rank: int | None = None
 
 
 def _kda_a_log_init(key, shape, dtype=jnp.float32):
@@ -206,18 +214,24 @@ class KimiDeltaMixer(nn.Module):
         q, k, v = silu(causal depthwise conv_{d_conv}(x W_{q,k,v}))   three projections, three
                                                                       convolutions, no bias
         q = q / sqrt(|q|^2 + 1e-6) * d_k^-1/2;  k = k / sqrt(|k|^2 + 1e-6)      per head, float32
-        beta = sigmoid(x W_b)                                         per head
+        beta = sigmoid(x W_b) * (2 if neg_eigval else 1)              per head
         g    = lower_bound * sigmoid(exp(A_log) * (x W_f + dt_bias))  per head AND key channel:
                                                                       the safe gate, g in (lower_bound, 0)
+          or   -exp(A_log) * softplus(x W_f + dt_bias)                lower_bound None: Kimi Linear's
+                                                                      own gate, any g <= 0
         o    = kda_rule(q, k, v, g, beta)                             (ops/gated_delta.py)
         o    = RMSNorm_{d_v}(o) * w * sigmoid(x W_g)                  per head, one scale w of d_v
         out  = W_o o
 
-    ``W_f`` and ``W_g`` are full rank (the published ``no_kda_lora``), ``A_log``
-    one a head, ``dt_bias`` one a key channel. With ``lower_bound`` -5 the
-    rule's sub-block of 16 steps sums to under 80, inside float32's exponent;
-    a bound past ``ops/gated_delta.py:MAX_DECAY_A_STEP`` is refused. No
-    position term.
+    ``A_log`` is one a head, ``dt_bias`` one a key channel. With ``gate_rank``
+    None ``W_f`` and ``W_g`` are one full matrix each (Ling's published
+    ``no_kda_lora``; leaves ``f_proj``, ``g_proj``); with a rank ``r`` each is
+    Kimi Linear's pair ``W_down`` ``[d_model, r]`` then ``W_up`` ``[r, H d]``
+    (leaves ``f_down`` / ``f_up`` without a bias, ``g_down`` / ``g_up`` with a
+    bias on ``g_up``): on a chip that holds a share of the heads the first
+    matrices stay whole and the second are cut by heads. The rule holds for
+    any ``g <= 0``, so either gate runs the same program and only a bound that
+    is none below zero is refused. No position term.
 
     Device scopes ``kda_proj`` (the six projections in and the one out),
     ``kda_conv``, ``kda_scan`` (the L2 norms, ``beta``, the gate, the chunked
@@ -227,9 +241,11 @@ class KimiDeltaMixer(nn.Module):
     output stage in plain XLA; ``q``, ``k``, ``v`` and ``g`` go to the rule as
     ``[B, T, H, d]``, whose ``[B, T, H d]`` view the kernels read in place) and
     ``kda_gate``. Sown into ``"metrics"``: ``kda_decay_mean`` (the
-    mean of ``exp(g)``), ``kda_beta_mean`` and ``kda_state_absmax`` (the largest
-    magnitude in the state after the last step); into ``"intermediates"`` the
-    rule's own inputs. What a remat policy saves bears the scalar rule's names
+    mean of ``exp(g)``), ``kda_beta_mean``, ``kda_log_decay_min`` (the most
+    negative ``g`` of the step: under -5.5 the rule's form before PR 51, a
+    sub-block of 16 steps under one reference, was not finite) and
+    ``kda_state_absmax`` (the largest magnitude in the state after the last
+    step); into ``"intermediates"`` the rule's own inputs. What a remat policy saves bears the scalar rule's names
     (``REMAT_NAMES``): under ``"save_flash"`` the carry's loop runs once forward
     and once in reverse a layer and the solve (``kda_inverse``) once; the rest
     of the chunk-local stage (``kda_operands``) runs again when the backward
@@ -245,10 +261,10 @@ class KimiDeltaMixer(nn.Module):
         s = self.spec
         batch, t, d_model = x.shape
         h, d_k, d_v = s.num_heads, s.key_dim, s.value_dim
-        if not -MAX_DECAY_A_STEP <= s.lower_bound < 0:
+        safe = s.lower_bound is not None
+        if safe and not s.lower_bound < 0:
             raise ValueError(
-                "KimiDeltaMixer: lower_bound %g outside [-%g, 0): the rule's "
-                "sub-block would leave float32" % (s.lower_bound, MAX_DECAY_A_STEP)
+                "KimiDeltaMixer: lower_bound %g is no bound below zero" % s.lower_bound
             )
         f32 = jnp.float32
         dense = lambda width, name, **how: nn.Dense(  # noqa: E731
@@ -267,12 +283,21 @@ class KimiDeltaMixer(nn.Module):
             # the decay's projection leaves its accumulator in float32: rounded
             # to bfloat16 first, a log-decay near the gate's steepest point
             # moves by 0.1 (exp(A_log) up to 16 times a slope of 5 / 4)
-            f = dense(
-                h * d_k, "f_proj",
-                dot_general=functools.partial(jax.lax.dot_general, preferred_element_type=f32),
-            )(x).reshape(batch, t, h, d_k)
+            wide = dict(
+                dot_general=functools.partial(jax.lax.dot_general, preferred_element_type=f32)
+            )
+            if s.gate_rank is None:
+                f = dense(h * d_k, "f_proj", **wide)(x)
+                gate = dense(h * d_v, "g_proj")(x)
+            else:
+                # the first of each pair whole on a chip with a share of the
+                # heads, the second cut by heads; the gate's second with a bias
+                f = dense(h * d_k, "f_up", **wide)(dense(s.gate_rank, "f_down")(x))
+                gate = nn.Dense(h * d_v, dtype=self.dtype, name="g_up")(
+                    dense(s.gate_rank, "g_down")(x)
+                )
+            f = f.reshape(batch, t, h, d_k)
             b = dense(h, "b_proj")(x).astype(f32)
-            gate = dense(h * d_v, "g_proj")(x)
 
         with jax.named_scope("kda_conv"):
             q, k, v = (
@@ -289,13 +314,22 @@ class KimiDeltaMixer(nn.Module):
             k = _unit(k.reshape(batch, t, h, d_k).astype(f32))
             q, k = q.astype(self.dtype), k.astype(self.dtype)
             v = v.reshape(batch, t, h, d_v)
-            beta = jax.nn.sigmoid(b)
-            g = s.lower_bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None] * (f + dt_bias))
+            beta = jax.nn.sigmoid(b) * (2.0 if s.neg_eigval else 1.0)
+            if safe:
+                g = s.lower_bound * jax.nn.sigmoid(jnp.exp(a_log)[:, None] * (f + dt_bias))
+            else:
+                g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(f + dt_bias)
             # as GatedDeltaMixer's: what survives a layer's forward under a
             # policy that saves the names is the rule's o, its final state, the
             # states the chunks inherit, V_new and every chunk's T
             rule = jax.checkpoint(
-                functools.partial(kda_rule, chunk=s.chunk, return_final_state=True),
+                functools.partial(
+                    kda_rule, chunk=s.chunk, return_final_state=True,
+                    caller=dict(
+                        gate="safe" if safe else "softplus", bound=s.lower_bound,
+                        beta_max=2.0 if s.neg_eigval else 1.0, rank=s.gate_rank,
+                    ),
+                ),
                 policy=jax.checkpoint_policies.save_only_these_names(*REMAT_NAMES),
             )
             o, state = (
@@ -303,6 +337,7 @@ class KimiDeltaMixer(nn.Module):
             )
         self.sow("metrics", "kda_decay_mean", jnp.mean(jnp.exp(g)))
         self.sow("metrics", "kda_beta_mean", jnp.mean(beta))
+        self.sow("metrics", "kda_log_decay_min", jnp.min(g))
         self.sow("metrics", "kda_state_absmax", jnp.max(jnp.abs(state)))
         self.sow("intermediates", "rule_inputs", (q, k, v, g, beta))
 

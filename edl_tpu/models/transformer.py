@@ -302,10 +302,10 @@ class Attention(nn.Module):
     adds the projection ``g`` of q's width and returns
     ``(heads' outputs * sigmoid(g)) W_o``. ``kernel_scope`` names the device
     scope of the attention call alone (a mixed-window model tells its two
-    kinds of layer apart by it); the QK norms and the gate then sit under
-    ``attn_gate``. Every projection's weight gradient is written by a matmul
-    of its own, behind a fence (``_heads_dot_general``); the parameters are
-    plain ``nn.DenseGeneral``'s.
+    kinds of layer apart by it); with it, or with a gate, the per-head QK
+    norms and the gate sit under ``attn_gate``. Every projection's weight
+    gradient is written by a matmul of its own, behind a fence
+    (``_heads_dot_general``); the parameters are plain ``nn.DenseGeneral``'s.
 
     ``sparse`` makes the layer attend over a learned selection: an indexer on
     ``stop_gradient(x)`` (``index_q``: ``index_heads`` heads of ``index_dim``;
@@ -367,8 +367,11 @@ class Attention(nn.Module):
         q = dense("q", features=(self.num_heads, head_dim))(x)
         k = dense("k", features=(kv_heads, head_dim))(x)
         v = dense("v", features=(kv_heads, head_dim))(x)
+        # the device scope of what stands beside the kernel: by name in a layer
+        # with a gate, or of a model that tells two kinds of layer apart
+        beside = "attn_gate" if self.gate or self.kernel_scope else None
         if self.qk_norm == "head":
-            with _scope(self.kernel_scope and "attn_gate"):
+            with _scope(beside):
                 q = RMSNorm(self.norm_eps, name="q_norm")(q)
                 k = RMSNorm(self.norm_eps, name="k_norm")(k)
         elif self.qk_norm is True:
@@ -410,7 +413,7 @@ class Attention(nn.Module):
                 out = attn(q, k, v, causal=True, **extra)
             out = jnp.swapaxes(out, 1, 2)
         if self.gate:
-            with _scope(self.kernel_scope and "attn_gate"):
+            with _scope(beside):
                 g = dense("g", features=(self.num_heads, head_dim))(x)
                 out = out * nn.sigmoid(g)
         return dense("o", features=x.shape[-1], axis=(-2, -1))(out)

@@ -58,7 +58,8 @@ carry's (``carried_states``), and the carry's is jax's of one step.
 
 :func:`kda_rule` is the same rule with a decay for every key channel (Kimi
 delta attention): the decay no longer factors out of the products over the key
-channels, so it rides the operands, a sub-block of steps at a time; the solve,
+channels, so it rides the operands, under references that keep every factor
+at or under 1 whatever the decay (the halving form, below); the solve,
 the carry (its decay then a vector over ``d_k``) and the names are shared.
 Its **chunk-local stage**, everything between the rule's inputs and the
 carry's operands that depends on one chunk of one head only, has two forms
@@ -317,15 +318,41 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
 
 # -- a decay for every key channel (Kimi delta attention) --------------------
 
-# Steps that share one reference point of the exponents. A factor lies within
-# e^+-(SUB_BLOCK / 2 * max|g|) and a masked pair's product under the square of
-# it, which float32 holds up to e^88: ``MAX_DECAY_A_STEP`` is the most a caller
-# may let ``|g|`` reach, and ``KimiDeltaMixer`` holds its gate's bound to it.
-SUB_BLOCK = 16
-MAX_DECAY_A_STEP = 88.0 / SUB_BLOCK
+def _halves(size):
+    """The half-sizes of the halving form, largest first: ``size / 2, ..., 1``."""
+    return [1 << b for b in reversed(range(size.bit_length() - 1))]
 
 
-def _local_plain(q, k, v, g, beta, size, sub):
+def _plain_pairs(q32, k32, gamma, dtype):
+    """``sum_d a_i[d] k_j[d] exp(Gamma_i[d] - Gamma_j[d])`` for ``a`` = ``k``
+    and ``a`` = ``q``, ``[b n h c s]`` each (``kk`` below the diagonal, the
+    scores on and below it), **for any** ``Gamma`` **that does not grow along
+    the steps**: the chunk is halved down to single steps, and a pair ``i > j``
+    is had at the one level where ``i`` lies in the upper half and ``j`` in the
+    lower half of the same block, under the reference ``Gamma`` at the lower
+    half's last step. ``exp(Gamma_i - R)`` and ``exp(R - Gamma_j)`` are then
+    both at most 1, whatever the decay; the diagonal has no decay at all.
+    Operands ``[b n c h k]`` float32, rounded to ``dtype`` once decayed."""
+    size = gamma.shape[2]
+    step = jnp.arange(size)
+    dot = dict(preferred_element_type=jnp.float32)
+    kk = scores = 0.0
+    for s in _halves(size):
+        upper = (step & s) != 0
+        at = jnp.where(upper, (step & ~(s - 1)) - 1, step | (s - 1))
+        ref, up = gamma[:, :, at], upper[:, None, None]
+        factor = jnp.exp(jnp.minimum(jnp.where(up, gamma - ref, ref - gamma), 0.0))
+        k_rows = jnp.where(up, k32 * factor, 0.0).astype(dtype)
+        q_rows = jnp.where(up, q32 * factor, 0.0).astype(dtype)
+        k_cols = jnp.where(up, 0.0, k32 * factor).astype(dtype)
+        same = (step[:, None] // (2 * s)) == (step[None, :] // (2 * s))
+        kk += jnp.where(same, jnp.einsum("bnchk,bnshk->bnhcs", k_rows, k_cols, **dot), 0.0)
+        scores += jnp.where(same, jnp.einsum("bnchk,bnshk->bnhcs", q_rows, k_cols, **dot), 0.0)
+    own = jnp.einsum("bnchk,bnchk->bnhc", q32.astype(dtype), k32.astype(dtype), **dot)
+    return kk, scores + own[..., None] * jnp.eye(size, dtype=jnp.float32)
+
+
+def _local_plain(q, k, v, g, beta, size):
     """The chunk-local stage in plain ``jax.numpy``: everything of the rule
     that depends on one chunk of one head only. ``q``, ``k``, ``g`` ``[B, T, H,
     d_k]``, ``v`` ``[B, T, H, d_v]``, ``beta`` ``[B, T, H]``, ``T`` a multiple
@@ -333,43 +360,21 @@ def _local_plain(q, k, v, g, beta, size, sub):
     k]``, ``u`` ``[n b h c v]`` float32, ``k_out`` ``[n b c h k]``, ``whole``
     ``[n b h k]`` float32), the output stage's (``q_in`` ``[b n c h k]``, the
     causal-masked scores ``[b n h c s]``) and every chunk's float32 ``T`` ``[b
-    n h c s]``."""
+    n h c s]``. Holds for any ``g <= 0`` (:func:`_plain_pairs`)."""
     batch, steps, h, d_k = q.shape
-    blocks, nc = size // sub, steps // size
+    nc = steps // size
     f32, dtype = jnp.float32, q.dtype
     dot = dict(preferred_element_type=f32)
 
-    # b batch, n chunk, i sub-block, c / s step (in a chunk, or in a sub-block
-    # behind an i), h head, k key width, v value width
+    # b batch, n chunk, c / s step in a chunk, h head, k key width, v value width
     q = q.reshape(batch, nc, size, h, d_k)
     k = k.reshape(batch, nc, size, h, d_k)
     v = v.reshape(batch, nc, size, h, v.shape[-1])
     beta = beta.astype(f32).reshape(batch, nc, size, h)
     gamma = jnp.cumsum(g.astype(f32).reshape(batch, nc, size, h, d_k), axis=2)
 
-    # a sub-block's reference: the running sum at its middle step
-    in_blocks = lambda a: a.reshape(batch, nc, blocks, sub, *a.shape[3:])  # noqa: E731
-    ref = in_blocks(gamma)[:, :, :, (sub - 1) // 2]             # [b n i h k]
-    rows = jnp.exp(in_blocks(gamma) - ref[:, :, :, None])        # [b n i c h k]
-    # columns up to the end of the rows' own sub-block, zeros past it
-    reach = jnp.arange(size)[None, :] // sub <= jnp.arange(blocks)[:, None]   # [i s]
-    cols = jnp.exp(jnp.where(
-        reach[:, :, None, None], ref[:, :, :, None] - gamma[:, :, None], -jnp.inf
-    ))                                                           # [b n i s h k]
     k32, q32 = k.astype(f32), q.astype(f32)
-    k_rows = (in_blocks(k32) * rows).astype(dtype)
-    q_rows = (in_blocks(q32) * rows).astype(dtype)
-    k_cols = (k32[:, :, None] * cols).astype(dtype)
-
-    def against_columns(rows):
-        """``rows`` [b n i c h k] against ``k_cols``, a sub-block at a time:
-        [b n h C S], the sub-blocks' rows one after another."""
-        merged = lambda a: a.reshape(batch, nc * blocks, *a.shape[3:])  # noqa: E731
-        tile = jnp.einsum("bmchk,bmshk->bmhcs", merged(rows), merged(k_cols), **dot)
-        tile = tile.reshape(batch, nc, blocks, h, sub, size)
-        return jnp.moveaxis(tile, 2, 3).reshape(batch, nc, h, size, size)
-
-    kk, scores = against_columns(k_rows), against_columns(q_rows)
+    kk, scores = _plain_pairs(q32, k32, gamma, dtype)
 
     # inside a chunk: the system, its inverse, and what it makes of K and V
     lower = jnp.tril(jnp.ones((size, size), bool))
@@ -424,26 +429,6 @@ def _running_sum(a, reverse=False, axis=0):
     return a
 
 
-def _decays(g):
-    """Of one tile's log-decays ``g`` ``[C, d]``: their running sum ``gamma``,
-    the sub-blocks' references (``[1, d]`` each: ``gamma`` at the sub-block's
-    middle step) and the rows' factors ``exp(gamma - reference)`` ``[C, d]``."""
-    gamma, sub = _running_sum(g), SUB_BLOCK
-    middles = [i * sub + (sub - 1) // 2 for i in range(g.shape[0] // sub)]
-    refs = [gamma[m:m + 1] for m in middles]
-    of_rows = jnp.concatenate(
-        [jnp.broadcast_to(ref, (sub, g.shape[1])) for ref in refs], axis=0
-    )
-    return gamma, refs, jnp.exp(gamma - of_rows)
-
-
-def _columns(gamma, ref, reach):
-    """A sub-block's factors of the columns ``exp(reference - gamma)`` ``[C,
-    d]``: up to the end of the rows' own sub-block (``reach`` steps), zeros
-    past it."""
-    return jnp.exp(jnp.where(_iota(gamma.shape, 0) < reach, ref - gamma, -jnp.inf))
-
-
 def _times_transposed(a, b, **how):
     """``a b^T``, float32 accumulation."""
     return jax.lax.dot_general(
@@ -458,17 +443,104 @@ def _transposed_times(a, b, **how):
     )
 
 
-def _against_columns(decayed_rows, k32, gamma, refs):
-    """``[C, C]``: the decayed rows (``[C, d]``, rounded) against the decayed
-    keys, a sub-block of rows at a time under its own reference. A column past
-    the rows' sub-block meets a row of zeros: the mask's six pairs of sub-blocks
-    cost the matrix unit nothing (its columns are free up to 128) and no ``exp``
-    of a positive argument is taken."""
-    strips, sub = [], SUB_BLOCK
-    for i, ref in enumerate(refs):
-        k_cols = (k32 * _columns(gamma, ref, (i + 1) * sub)).astype(decayed_rows.dtype)
-        strips.append(_times_transposed(decayed_rows[i * sub:(i + 1) * sub], k_cols))
-    return jnp.concatenate(strips, axis=0)
+def _halved(gamma):
+    """The halving form's factors of one tile, for any ``gamma`` ``[C, d]``
+    that does not grow down the rows: a level a half-size ``s`` (``_halves``),
+    ``(s, upper, factor)`` with ``upper`` the steps in the upper half of their
+    block of ``2 s`` and ``factor`` ``exp(-|gamma - R|)``, ``R`` the running
+    sum at the last step of the block's lower half (:func:`_plain_pairs`).
+    ``R`` comes to every row by rolls: ``last`` holds ``gamma`` at the last step
+    of a row's own block of ``s``, which a lower row reads in place and an
+    upper row ``s`` rows up."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    size, step = gamma.shape[0], _iota(gamma.shape, 0)
+    lasts, last = {}, gamma
+    for s in reversed(_halves(size)):                    # 1, 2, ..., C / 2
+        lasts[s] = last
+        if 2 * s < size:
+            last = jnp.where((step & s) != 0, last, pltpu.roll(last, size - s, axis=0))
+    levels = []
+    for s in _halves(size):
+        upper = (step & s) != 0
+        ref = jnp.where(upper, pltpu.roll(lasts[s], s, axis=0), lasts[s])
+        apart = jnp.where(upper, gamma - ref, ref - gamma)
+        levels.append((s, upper, jnp.exp(jnp.minimum(apart, 0.0))))
+    return levels
+
+
+def _same_block(shape, s):
+    """``[C, C]``: whether row and column lie in one block of ``2 s`` steps."""
+    bits = s.bit_length()
+    return (_iota(shape, 0) >> bits) == (_iota(shape, 1) >> bits)
+
+
+def _halved_pairs(levels, rows32, k32, dtype):
+    """``[C, C]``, below the diagonal: ``sum_d rows_i[d] k_j[d] exp(Gamma_i[d]
+    - Gamma_j[d])`` of one tile by the halving form (``rows32`` the keys
+    themselves for ``kk``, the queries for the scores): a level's upper rows
+    against its lower columns, kept inside the blocks of ``2 s``."""
+    pairs = jnp.zeros((k32.shape[0],) * 2, jnp.float32)
+    for s, upper, factor in levels:
+        rows = jnp.where(upper, rows32 * factor, 0.0).astype(dtype)
+        k_cols = jnp.where(upper, 0.0, k32 * factor).astype(dtype)
+        pairs = pairs + jnp.where(
+            _same_block(pairs.shape, s), _times_transposed(rows, k_cols), 0.0
+        )
+    return pairs
+
+
+def _on_diagonal(tile, column):
+    """``tile`` ``[C, C]`` with ``column`` ``[C, 1]`` on its diagonal."""
+    return jnp.where(_iota(tile.shape, 0) == _iota(tile.shape, 1), column, tile)
+
+
+def _halved_back(levels, k32, q32, d_kk, d_scores, dtype):
+    """Through :func:`_halved_pairs`: from the cotangents of ``kk`` (below the
+    diagonal) and of the scores (on and below it) to ``(dq, dk, dgamma, kk)``,
+    ``kk`` made again for ``dbeta``. A level's two products go back as two more;
+    a factor's cotangent goes to ``gamma`` directly and, with the other sign,
+    to the level's reference, which the rolls of :func:`_halved` carried: they
+    are undone in the opposite order."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    size, step = k32.shape[0], _iota(k32.shape, 0)
+    kk = jnp.zeros((size, size), jnp.float32)
+    d_q = d_k = d_gamma = jnp.zeros_like(k32)
+    to_last = {}
+    for s, upper, factor in levels:
+        k_dec, q_dec = k32 * factor, q32 * factor
+        k_rows = jnp.where(upper, k_dec, 0.0).astype(dtype)
+        q_rows = jnp.where(upper, q_dec, 0.0).astype(dtype)
+        k_cols = jnp.where(upper, 0.0, k_dec).astype(dtype)
+        same = _same_block(kk.shape, s)
+        kk = kk + jnp.where(same, _times_transposed(k_rows, k_cols), 0.0)
+        both = jnp.concatenate(
+            [jnp.where(same, d_kk, 0.0), jnp.where(same, d_scores, 0.0)], axis=0
+        ).astype(dtype)                                                  # [2 C, C]
+        d_rows = jnp.dot(both, k_cols, preferred_element_type=jnp.float32)
+        d_cols = _transposed_times(both, jnp.concatenate([k_rows, q_rows], axis=0))
+        d_k_dec = jnp.where(upper, d_rows[:size], d_cols)
+        d_q_dec = jnp.where(upper, d_rows[size:], 0.0)
+        d_k, d_q = d_k + d_k_dec * factor, d_q + d_q_dec * factor
+        # the exponent: gamma - R in the upper half, R - gamma in the lower
+        d_apart = (d_k_dec * k_dec + d_q_dec * q_dec)
+        direct = jnp.where(upper, d_apart, -d_apart)
+        d_gamma = d_gamma + direct
+        to_last[s] = (
+            jnp.where(upper, 0.0, -direct)
+            + pltpu.roll(jnp.where(upper, -direct, 0.0), size - s, axis=0)
+        )
+    d_last = None
+    for s in _halves(size):                              # C / 2, ..., 1
+        if d_last is None:
+            d_last = to_last[s]
+        else:
+            d_last = to_last[s] + jnp.where(
+                (step & s) != 0, d_last + pltpu.roll(d_last, s, axis=0), 0.0
+            )
+    own = jnp.sum(_on_diagonal(jnp.zeros_like(d_scores), d_scores), axis=1, keepdims=True)
+    return d_q + own * k32, d_k + own * q32, d_gamma + d_last, kk
 
 
 def _solve(system):
@@ -532,9 +604,9 @@ def kda_inverse_kernel(k_ref, g_ref, beta_ref, inverse_ref):
     def head(pair, half, carry):
         h = 2 * pair + half
         lanes = _head_of(h, d_k)
-        gamma, refs, rows = _decays(g_ref[0, :, lanes])
+        levels = _halved(_running_sum(g_ref[0, :, lanes]))
         k32 = k_ref[0, :, lanes].astype(f32)
-        kk = _against_columns((k32 * rows).astype(k_ref.dtype), k32, gamma, refs)
+        kk = _halved_pairs(levels, k32, k32, k_ref.dtype)
         inverse_ref[_inverse_at(inverse_ref, pair, half)] = _solve(_column_of(betas, h) * kk)
         return carry
 
@@ -557,10 +629,14 @@ def kda_operands_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
     def head(pair, half, carry):
         h = 2 * pair + half
         keys, values = _head_of(h, d_k), _head_of(h, d_v)
-        gamma, refs, rows = _decays(g_ref[0, :, keys])
+        gamma = _running_sum(g_ref[0, :, keys])
         q32, k32 = q_ref[0, :, keys].astype(f32), k_ref[0, :, keys].astype(f32)
         beta, grown = _column_of(betas, h), jnp.exp(gamma)
-        scores = _against_columns((q32 * rows).astype(dtype), k32, gamma, refs)
+        # the diagonal, which no decay touches, is q . k a row
+        scores = _on_diagonal(
+            _halved_pairs(_halved(gamma), q32, k32, dtype),
+            jnp.sum(q32 * k32, axis=1, keepdims=True),
+        )
         lower = _iota(scores.shape, 0) >= _iota(scores.shape, 1)
         scores_ref[0, 0, h] = jnp.where(lower, scores, 0.0).astype(dtype)
         inverse = inverse_ref[_inverse_at(inverse_ref, pair, half)].astype(dtype)
@@ -587,7 +663,7 @@ def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
     f32, dtype = jnp.float32, q_ref.dtype
     heads = beta_ref.shape[2]
     d_k, d_v = k_ref.shape[2] // heads, v_ref.shape[2] // heads
-    size, sub = q_ref.shape[1], SUB_BLOCK
+    size = q_ref.shape[1]
     betas = beta_ref[0]
     down = lambda a: jnp.sum(a, axis=0, keepdims=True)      # noqa: E731 — [1, d]
     across = lambda a: jnp.sum(a, axis=1, keepdims=True)    # noqa: E731 — [C, 1]
@@ -595,7 +671,7 @@ def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
     def head(pair, half, d_betas):
         h = 2 * pair + half
         keys, values = _head_of(h, d_k), _head_of(h, d_v)
-        gamma, refs, rows = _decays(g_ref[0, :, keys])
+        gamma = _running_sum(g_ref[0, :, keys])
         q32, k32 = q_ref[0, :, keys].astype(f32), k_ref[0, :, keys].astype(f32)
         v32 = v_ref[0, :, values].astype(f32)
         beta, grown = _column_of(betas, h), jnp.exp(gamma)
@@ -620,34 +696,11 @@ def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
         d_kk = d_system * beta
         d_scores = jnp.where(row >= col, dscores_ref[0, 0, h].astype(f32), 0.0)
 
-        # through the products of the decayed rows and columns, a sub-block
-        # of rows at a time
-        k_rows, q_rows = (k32 * rows).astype(dtype), (q32 * rows).astype(dtype)
-        d_keys = jnp.zeros_like(k32)
-        d_gamma = jnp.zeros_like(gamma)
-        d_rows, d_beta, d_refs = [], [], []
-        for i, ref in enumerate(refs):
-            at = slice(i * sub, (i + 1) * sub)
-            cols = _columns(gamma, ref, (i + 1) * sub)
-            decayed = k32 * cols
-            k_cols = decayed.astype(dtype)
-            d_beta.append(across(d_system[at] * _times_transposed(k_rows[at], k_cols)))
-            both = jnp.concatenate([d_kk[at], d_scores[at]], axis=0).astype(dtype)
-            d_rows.append(jnp.dot(both, k_cols, preferred_element_type=f32))
-            d_cols = _transposed_times(
-                both, jnp.concatenate([k_rows[at], q_rows[at]], axis=0)
-            )
-            d_keys = d_keys + d_cols * cols
-            d_gamma = d_gamma - d_cols * decayed
-            d_refs.append(down(d_cols * decayed))
-        d_k_rows = jnp.concatenate([a[:sub] for a in d_rows], axis=0)
-        d_q_rows = jnp.concatenate([a[sub:] for a in d_rows], axis=0)
-        through_rows = (d_k_rows * k32 + d_q_rows * q32) * rows
-        d_gamma = d_gamma + through_rows
+        d_queries, d_keys, d_gamma, kk = _halved_back(
+            _halved(gamma), k32, q32, d_kk, d_scores, dtype
+        )
+        d_beta = across(d_system * kk)
         step = _iota(gamma.shape, 0)
-        for i, d_ref in enumerate(d_refs):
-            d_ref = d_ref - down(through_rows[i * sub:(i + 1) * sub])
-            d_gamma = d_gamma + jnp.where(step == i * sub + (sub - 1) // 2, d_ref, 0.0)
 
         # through the elementwise operands
         dk_out, dq_in = dk_out_ref[0, 0, :, keys].astype(f32), dq_in_ref[0, :, keys].astype(f32)
@@ -657,15 +710,13 @@ def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
             d_gamma + d_k_in * k32 * (beta * grown) - leaving + dq_in * q32 * grown
             + jnp.where(step == size - 1, d_last, 0.0)
         )
-        dq_ref[0, :, keys] = (d_q_rows * rows + dq_in * grown).astype(dtype)
+        dq_ref[0, :, keys] = (d_queries + dq_in * grown).astype(dtype)
         dk_ref[0, :, keys] = (
-            d_keys + d_k_rows * rows + d_k_in * (beta * grown) + dk_out * to_end
+            d_keys + d_k_in * (beta * grown) + dk_out * to_end
         ).astype(dtype)
         dv_ref[0, :, values] = (d_v_in * beta).astype(dtype)
         dg_ref[0, :, keys] = _running_sum(d_gamma, reverse=True)
-        d_beta = (
-            jnp.concatenate(d_beta, axis=0) + across(d_k_in * k32 * grown) + across(d_v_in * v32)
-        )
+        d_beta = d_beta + across(d_k_in * k32 * grown) + across(d_v_in * v32)
         return jnp.where(_iota(d_betas.shape, 1) == h, d_beta, d_betas)
 
     dbeta_ref[0] = _over_heads(heads, head, jnp.zeros(betas.shape, f32))
@@ -751,7 +802,6 @@ def _run(kernel, ins, outs, operands, interpret):
 
 
 # jitted, as ``ops/causal_conv.py``'s: a step traces and lowers each body once
-# (with ``SUB_BLOCK`` as it stands at the first trace of a shape)
 @functools.partial(jax.jit, static_argnums=3)
 def _inverse_call(k, g, beta, interpret):
     ins = ("keys", "decays", "beta")
@@ -800,15 +850,14 @@ _local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
 def _kernels_refuse(q, k, v, steps, chunk, interpret):
     """Why the chunk-local stage of these operands is not the kernels', or
     None where it is: the first of a TPU backend or the interpreter
-    (``backend``), bfloat16 operands (``dtype``), the kernels' chunk in
-    sub-blocks of whole bfloat16 tiles (``chunk``), a length the chunk divides
-    (``steps``), the heads in pairs (``heads_odd``) and widths of whole lane
-    tiles (``width``) that does not hold."""
+    (``backend``), bfloat16 operands (``dtype``), the kernels' chunk
+    (``chunk``), a length the chunk divides (``steps``), the heads in pairs
+    (``heads_odd``) and widths of whole lane tiles (``width``) that does not
+    hold."""
     conditions = (
         ("backend", interpret or jax.default_backend() == "tpu"),
         ("dtype", q.dtype == k.dtype == v.dtype == jnp.bfloat16),
-        ("chunk", chunk == _KERNEL_CHUNK and SUB_BLOCK % 16 == 0
-         and chunk % SUB_BLOCK == 0),
+        ("chunk", chunk == _KERNEL_CHUNK),
         ("steps", steps % chunk == 0),
         ("heads_odd", q.shape[2] % 2 == 0),
         ("width", q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0),
@@ -817,7 +866,7 @@ def _kernels_refuse(q, k, v, steps, chunk, interpret):
 
 
 def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
-             return_final_state: bool = False, interpret: bool = False):
+             return_final_state: bool = False, interpret: bool = False, caller=None):
     """The delta rule with **a decay for every key channel** (Kimi delta
     attention, arXiv:2510.26692, equation 1): per head, for a log-decay ``g_t``
     of ``d_k`` values, none positive::
@@ -835,20 +884,30 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     Gamma_j[d])`` and the scores likewise with ``q_i``, so ``exp(Gamma_i -
     Gamma_j)`` no longer factors out as one ``[C, C]`` matrix a head. It
     factors a channel: ``(k_i o exp(Gamma_i - R)) . (k_j o exp(R - Gamma_j))``
-    for any reference ``R``, and the rows of each **sub-block** of
-    ``SUB_BLOCK`` steps take ``R`` = the running sum at the sub-block's middle
-    step. A row's exponent and, inside the rows' own sub-block, a column's are
-    then within half a sub-block's decay of zero either way; the columns of
-    earlier sub-blocks have none positive, those of later ones are zeros (the
-    mask's). **The caller keeps ``|g|`` under ``MAX_DECAY_A_STEP``** (the KDA
-    layer's safe gate holds ``g`` in (-5, 0): 40 over half a sub-block, so a
-    factor lies in e^+-40 and the product of a masked pair under e^80, inside
-    float32's e^88); no other exponent here is of a positive argument. ``W``,
-    ``U``, the carry
-    (``carried_states``, its decay a vector over ``d_k``) and the outputs are
-    the scalar rule's with ``exp(Gamma)`` a channel; the solve is
-    ``unit_lower_inverse``; what a remat policy saves bears the same names
-    (``REMAT_NAMES``).
+    for any reference ``R``, and **the rule holds for any finite** ``g <= 0``
+    (Kimi Linear's own gate, ``-exp(A_log) softplus(.)``, which no bound
+    holds, as well as a safe gate's ``(-5, 0)``) by choosing ``R`` so that
+    **no** ``exp`` **of a positive argument is taken anywhere**, forward or
+    backward: the **halving** form. The chunk is halved down to single steps;
+    a pair ``i > j`` is had at the one level where ``i`` lies in the upper and
+    ``j`` in the lower half of the same block, with ``R`` the running sum at
+    the lower half's last step, so ``Gamma_i - R <= 0`` and ``R - Gamma_j <=
+    0`` both; the diagonal (``i = j``: no decay) is ``q_i . k_i`` itself, and
+    a factor that underflows float32 reads 0, as the step-by-step recurrence's
+    would. ``log2(C)`` levels, each one ``exp`` of a ``[C, d]`` tile and one
+    masked ``[C, d] x [d, C]`` product (:func:`_plain_pairs`, :func:`_halved`).
+    (Until PR 51 the rows of a sub-block of 16 steps shared a reference at its
+    middle step, which held only while ``|g|`` stayed under 5.5 a step and was
+    0.66% of ``ling_3_0_flash_vl.steady``'s step cheaper, 5 ``exp`` a tile for 6
+    and 4 strips of 16 rows for 6 whole products: ``bench_results/README.md``
+    has both timed.)
+
+    ``W``, ``U``, the carry (``carried_states``, its decay a vector over
+    ``d_k``) and the outputs are the scalar rule's with ``exp(Gamma)`` a
+    channel; the solve is ``unit_lower_inverse``; what a remat policy saves
+    bears the same names (``REMAT_NAMES``). ``caller`` is what the caller says
+    of itself for the ``kda_chunks`` instant (``KimiDeltaMixer``: ``gate``,
+    ``bound``, ``beta_max``, ``rank``); the rule adds ``pairs`` (``halving``).
 
     Precision as the scalar rule's: ``g``, its sums, every ``exp``, ``beta``,
     the system, its inverse and the carried state in float32; each matmul
@@ -889,7 +948,6 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         raise ValueError("kda_rule: beta %s for %s" % (beta.shape, q.shape[:3]))
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError("kda_rule: chunk %d is not a power of two" % chunk)
-    sub = min(SUB_BLOCK, chunk)  # both powers of two: it divides the chunk
     why_plain = _kernels_refuse(q, k, v, t, chunk, interpret)
     kernels = why_plain is None
     pad = -t % chunk
@@ -905,7 +963,7 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     # where it is the plain one
     note = functools.partial(
         obs_trace.get_tracer().note_once, "kda_chunks", chunk=size,
-        sub_block=sub, chunks=nc, heads=h, d_k=d_k, d_v=d_v,
+        pairs="halving", **(caller or {}), chunks=nc, heads=h, d_k=d_k, d_v=d_v,
         state_bytes=4 * h * d_k * d_v, solve=SOLVE,
         saved_bytes=saved_bytes(
             size, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch
@@ -926,7 +984,7 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         k_out = k_out.reshape(nc, batch, size, h, d_k)
         q_in = q_in.reshape(batch, nc, size, h, d_k)
     else:
-        w, u, k_out, whole, q_in, scores, _ = _local_plain(q, k, v, g, beta, size, sub)
+        w, u, k_out, whole, q_in, scores, _ = _local_plain(q, k, v, g, beta, size)
 
     # from chunk to chunk, the state in float32
     if initial_state is None:

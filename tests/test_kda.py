@@ -71,8 +71,8 @@ def rule_inputs(seed=0, b=2, t=96, h=3, d_k=8, d_v=16, low=-5.0):
 
 
 def at_the_bound(args):
-    """Steps 16..63 of every channel at the safe gate's bound: three whole
-    sub-blocks whose exponents reach e^+-80."""
+    """Steps 16..63 of every channel at the safe gate's bound: forty-eight
+    steps running of e^-5 each."""
     q, k, v, g, beta = args
     steps = (jnp.arange(g.shape[1]) >= 16) & (jnp.arange(g.shape[1]) < 64)
     return q, k, v, jnp.where(steps[None, :, None, None], -5.0 + 1e-4, g), beta
@@ -105,13 +105,12 @@ def test_the_chunked_rule_equals_the_step_by_step_recurrence(case, what):
         leaf = ("q", "k", "v", "g", "beta").index(what)
         got = jax.grad(weigh(chunked), argnums=leaf)(*args)
         want = jax.grad(weigh(reference.recurrence), argnums=leaf)(*args)
-    # at the bound a sub-block's factors are e^+-80: float32 keeps 1e-7 of each
+    # at the bound the terms that survive are e^-5 and less of the rest's
     _close(got, want, tol=2e-3 if case == "at_the_bound" else 2e-4)
 
 
-@pytest.mark.parametrize("chunk,sub_block", [(16, 16), (64, 16), (32, 8), (8, 16)])
-def test_the_result_does_not_depend_on_the_chunk_or_the_sub_block(chunk, sub_block, monkeypatch):
-    monkeypatch.setattr(G, "SUB_BLOCK", sub_block)
+@pytest.mark.parametrize("chunk", [16, 64, 32, 8])
+def test_the_result_does_not_depend_on_the_chunk(chunk):
     args = rule_inputs(seed=1, t=70)  # a length no chunk divides: padded steps leave the state
     with jax.default_matmul_precision("highest"):
         o, state = kda_rule(*args, chunk=chunk, return_final_state=True)
@@ -179,7 +178,8 @@ def test_each_traced_shape_leaves_one_kda_chunks_instant():
         jax.jit(lambda *a: kda_rule(*a, chunk=32)).lower(*args)
     found = [e["args"] for e in tracer.to_events() if e["name"] == "kda_chunks"][before:]
     assert len(found) == 1
-    assert (found[0]["chunk"], found[0]["sub_block"], found[0]["heads"]) == (32, 16, 3)
+    assert (found[0]["chunk"], found[0]["pairs"], found[0]["heads"]) == (32, "halving", 3)
+    assert "sub_block" not in found[0]
     assert (found[0]["d_k"], found[0]["d_v"]) == (8, 16)
     assert found[0]["state_bytes"] == 4 * 3 * 8 * 16
     assert found[0]["saved_bytes"] == G.saved_bytes(32, 2, 3, 8, 16, 4, 2)
@@ -193,7 +193,7 @@ LEAVES = ("q", "k", "v", "g", "beta")
 
 
 def near_zero(args):
-    """Channels that hardly forget: every factor of a sub-block near one."""
+    """Channels that hardly forget: every factor of every level near one."""
     q, k, v, g, beta = args
     return q, k, v, 1e-3 * g, beta
 
@@ -248,7 +248,7 @@ def stage_both_ways(case, dtype):
     form on the same inputs: the gradients under one random cotangent of the
     six operands the rule reads (``T`` is the backward's own residual)."""
     args = kernel_inputs(case, jnp.dtype(dtype))
-    plain = lambda *a: G._local_plain(*a, 64, 16)  # noqa: E731
+    plain = lambda *a: G._local_plain(*a, 64)  # noqa: E731
     found = []
     with jax.default_matmul_precision("highest"):
         for stage, inverse in ((by_kernels, inverse_by_kernels), (plain, lambda *a: plain(*a)[6])):
@@ -271,7 +271,7 @@ def test_the_kernels_are_the_plain_chunk_local_stage(what, case, dtype):
     else:
         a, b = got_grads[LEAVES.index(what)], want_grads[LEAVES.index(what)]
     assert a.shape == b.shape and a.dtype == b.dtype
-    # at the bound a sub-block's factors are e^+-80: float32 keeps 1e-7 of each
+    # at the bound the terms that survive are e^-5 and less of the rest's
     exact = 2e-3 if case == "at_the_bound" else 1e-4
     _close(a, b, tol=exact if dtype == "float32" else 1.5e-2)
 
@@ -325,9 +325,9 @@ def test_the_rule_by_the_kernels_equals_the_step_by_step_recurrence(case):
 @pytest.mark.parametrize("why,path", [
     ("the_kernels_case", "kernel"), ("no_tpu_and_no_interpreter", "plain"),
     ("float32_operands", "plain"), ("a_ragged_length", "plain"), ("a_chunk_of_32", "plain"),
-    ("a_width_of_64", "plain"), ("sub_blocks_of_8", "plain"), ("three_heads", "plain"),
+    ("a_width_of_64", "plain"), ("three_heads", "plain"),
 ])
-def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path, monkeypatch):
+def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path):
     """``kda_chunks`` carries ``path``; the CPU's default, float32 operands (the
     benchmark check's exact call), a length the chunk does not divide, another
     chunk, a width under a lane tile and an odd count of heads take the plain form."""
@@ -342,8 +342,6 @@ def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path, mon
         chunk = 32
     elif why == "a_width_of_64":
         d = 64
-    elif why == "sub_blocks_of_8":
-        monkeypatch.setattr(G, "SUB_BLOCK", 8)
     elif why == "three_heads":
         h = 3
     args = narrow(rule_inputs(seed=8, b=1, t=t, h=h, d_k=d, d_v=d))

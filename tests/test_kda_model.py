@@ -227,14 +227,37 @@ def test_an_unknown_layer_type_names_the_new_ones_among_the_known():
         lm.init(jax.random.PRNGKey(0), np.zeros((1, 4), np.int32))
 
 
-@pytest.mark.parametrize("bound", [-6.0, 0.0])
-def test_a_gate_whose_bound_the_rules_sub_block_cannot_hold_is_refused(bound):
-    """The rule's sub-block of 16 steps keeps its factors in float32 only while
-    ``|g|`` stays under ``ops/gated_delta.py:MAX_DECAY_A_STEP`` (5.5): the mixer
-    holds its gate's bound to the rule's constant."""
+def test_a_bound_that_is_none_below_zero_is_refused():
     lm = TransformerLM(
         vocab_size=64, d_model=32, num_heads=2, num_layers=1, d_ff=32,
-        arch=ArchSpec(layer_types=("kda",), kda=KimiDeltaSpec(2, 8, 8, lower_bound=bound)),
+        arch=ArchSpec(layer_types=("kda",), kda=KimiDeltaSpec(2, 8, 8, lower_bound=0.0)),
     )
     with pytest.raises(ValueError, match="lower_bound"):
         lm.init(jax.random.PRNGKey(0), np.zeros((1, 4), np.int32))
+
+
+def test_a_gate_whose_bound_the_parents_rule_could_not_hold_trains_finite():
+    """A bound of -12 a step is past the 5.5 the rule held until PR 51 (sixteen
+    steps of it leave float32 under a middle reference), and the mixer refused
+    it. The rule now holds for any ``g <= 0``: with the gate driven to its
+    bound on most channels the model's loss and every gradient stay finite
+    through five steps."""
+    lm = TransformerLM(
+        vocab_size=64, d_model=32, num_heads=2, num_layers=2, d_ff=32, remat=True,
+        arch=ArchSpec(layer_types=("kda", "kda"),
+                      kda=KimiDeltaSpec(2, 16, 16, chunk=32, lower_bound=-12.0)),
+    )
+    x = np.random.default_rng(0).integers(0, 64, (2, 65)).astype(np.int32)
+    x, y = x[:, :-1], x[:, 1:]
+    state = create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
+    # the decay's bias far up: the sigmoid at 1, g at the bound
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: a + 30.0 if "dt_bias" in jax.tree_util.keystr(path) else a, state.params
+    )
+    state = state.replace(params=params)
+    step = make_train_step(lm_loss, numerics=True, donate=False)
+    for _ in range(5):
+        state, metrics = step(state, (x, y))
+        assert np.isfinite(float(metrics["loss"]))
+    assert float(metrics["kda_log_decay_min"]) < -11.9
+    assert all(bool(jnp.isfinite(a).all()) for a in jax.tree.leaves(state.params))

@@ -999,11 +999,13 @@ def test_kda_rule_compiles_for_v5e_at_the_cells_widths(one_chip, path):
         assert kernels == [] and 1e9 < temp < 3e9
 
 
-@pytest.mark.parametrize("heads", [16, 32], ids=["held", "published"])
+@pytest.mark.parametrize("heads", [16, 32, 8], ids=["held", "published", "solar"])
 @pytest.mark.parametrize("kernel", ["kda_inverse", "kda_operands", "kda_backward"])
 def test_kda_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, kernel, heads):
     """The three kernels of the rule's chunk-local stage at one sequence of
-    8192 and heads of 128 / 128, a chunk of every head a grid step (blocks of
+    8192 and heads of 128 / 128 (any ``g <= 0``: a level's rolls down the
+    sublanes, six masked products a tile), at Ling's 16 held and 32 published
+    heads and at Solar's 8 held, a chunk of every head a grid step (blocks of
     ``[64, heads * 128]`` rows of the mixer's own arrays, a head's lanes taken
     by a dynamic slice inside the body's loop, ``T`` a pair of heads a
     ``[64, 128]`` row, ``[heads, 64, 64]`` tiles of the scores): the tiling, the slices and the VMEM limit set from
@@ -1410,3 +1412,137 @@ def test_the_latent_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorde
     assert doc["parameters"] == recorded["parameters"]
     assert recorded["total_gb"] == pytest.approx(14.09, abs=0.01)
     assert doc["total_gb"] == pytest.approx(14.20, abs=0.1)
+
+
+# -- Solar-Open2: the rule under Kimi Linear's own gate in a whole step ------------------------------
+
+def _solar_cell():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "solar_open2_250b.json")) as f:
+        return root, json.load(f)
+
+
+def test_a_solar_step_on_the_tpu_path_runs_the_rule_as_kernels_under_kda_scan(one_chip):
+    """A toy of Solar-Open2's first period (a gated attention layer without a
+    position term, three Kimi-delta-attention layers with the softplus gate,
+    beta to 2 and low-rank pairs, an expert layer in every block), at heads of
+    128 so that the kernels take it, lowered as the chip lowers it
+    (``jax.default_backend`` steered to ``tpu`` here, for this compile only):
+    the rule notes ``path="kernel"`` and its caller's gate, the three ``kda_*``
+    custom calls lie under ``kda_scan``, and ``STEP_PARTS`` places every
+    matmul of the compiled step."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, KimiDeltaSpec, MoESpec, TransformerLM
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.obs import trace as obs_trace
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers = ("attention", "kda", "kda", "kda")
+    lm = TransformerLM(
+        vocab_size=256, d_model=128, num_heads=2, num_kv_heads=1, num_layers=len(layers),
+        d_ff=256, dtype=jnp.bfloat16, remat=True, remat_policy="save_flash", norm_eps=1e-5,
+        moe=MoESpec(
+            num_experts=20, top_k=4, d_ff=128, norm_topk_prob=True, aux_weight=0.0,
+            z_weight=0.0, score_func="sigmoid", route_scale=1.0, bias_rate=1e-3,
+            shared_d_ff=128, held=(0, 4),
+        ),
+        arch=ArchSpec(
+            layer_types=layers, head_dim=128, rope=False, attn_gate=True,
+            kda=KimiDeltaSpec(num_heads=2, key_dim=128, value_dim=128, lower_bound=None,
+                              neg_eigval=True, gate_rank=128),
+        ),
+    )
+    tokens = np.zeros((1, 256), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    tracer = obs_trace.get_tracer()
+    tracer.reset_notes()
+    before = len([e for e in tracer.to_events() if e["name"] == "kda_chunks"])
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    noted = [e["args"] for e in tracer.to_events() if e["name"] == "kda_chunks"][before:]
+    assert noted and all(
+        (a["path"], a["pairs"], a["gate"], a["bound"], a["beta_max"], a["rank"])
+        == ("kernel", "halving", "softplus", None, 2.0, 128) for a in noted
+    )
+    text = lowered.compile().as_text()
+    program = obs_profile.HloProgram(text)
+    census = program.census()
+    assert census["totals"]["matmuls"] > 0 and census["totals"]["unplaced_matmuls"] == 0
+    parts = {key.split("/")[0] for key in census["parts"]}
+    assert {"kda_proj", "kda_conv", "kda_scan", "kda_gate", "attn_gate", "moe_shared",
+            "moe_experts", "moe_route", "head"} <= parts
+    kernels = {key.split("/")[0] for key in census["kernels"]}
+    assert {"kda_inverse", "kda_operands", "kda_backward"} <= kernels
+    scopes = obs_profile.scopes_of_hlo(text, ("kda_proj", "kda_conv", "kda_scan", "kda_gate"))
+    calls = {name: scope for name, scope in scopes.items() if name.startswith("kda_")}
+    assert calls and set(calls.values()) == {"kda_scan"}
+
+
+def test_the_solar_cells_sequence_is_the_one_its_plan_chose():
+    """The rule, on the numbers the file records: one sequence of 8192 if the
+    chip itself leaves at least 1 GB of ``bytes_limit`` after the state and the
+    block the step reserves and the cell runs ``correct`` beside a ballast of
+    1 GiB; the tool's total is recorded beside it (it counts more than the chip
+    reserves)."""
+    _, config = _solar_cell()
+    plan = config["plan"]
+    tried = {t["seq_len"]: t for t in plan["tried"]}
+    chosen = tried[plan["chosen"]["seq_len"]]
+    assert chosen["parameters"] == 840874392
+    assert chosen["left_gb"] == pytest.approx(plan["chip_gb"] - chosen["total_gb"], abs=2e-3)
+    on_chip = chosen["on_chip"]
+    assert on_chip["ran"] and on_chip["correct"]
+    reserved = on_chip["state_gb"] + on_chip["program_reserve_gib"] * 2 ** 30 / 1e9
+    assert on_chip["left_gb"] == pytest.approx(on_chip["bytes_limit"] / 1e9 - reserved, abs=0.01)
+    if plan["chosen"]["seq_len"] == 8192:
+        assert on_chip["left_gb"] >= plan["least_left_gb"]
+        assert on_chip["beside_a_ballast_of_1_gib"].startswith("ran, correct")
+    assert config["train"]["seq_len"] == plan["chosen"]["seq_len"]
+    share, published = config["share"], config["published"]
+    assert share["chips_a_layer"] == 40 and share["chips_a_heads"] == 8
+    assert config["n_routed_experts"] * share["chips_a_layer"] == published["n_routed_experts"]
+    assert config["vocab_size"] * share["chips_a_vocabulary"] == published["vocab_size"]
+    assert config["linear_attn_config"]["num_heads"] * 8 == published["linear_attn_config.num_heads"]
+    assert config["num_attention_heads"] * 8 == published["num_attention_heads"]
+    assert config["num_key_value_heads"] * 8 == published["num_key_value_heads"]
+    assert config["gqa_layers"] == [0] and config["num_hidden_layers"] == 4
+
+
+@pytest.mark.slow
+def test_the_solar_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded():
+    """``benchmark/tools/compile_for_v5e.py`` on the cell as it runs (about
+    five minutes): the step compiles, and the plan's total is the file's."""
+    import json
+    import subprocess
+    import sys
+
+    root, config = _solar_cell()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "tools", "compile_for_v5e.py"),
+         "solar_open2_250b.steady"],
+        capture_output=True, text=True, timeout=1800, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    doc = json.loads(out.stdout.strip().splitlines()[-1])
+    recorded = next(
+        t for t in config["plan"]["tried"] if t["seq_len"] == config["train"]["seq_len"]
+    )
+    assert doc["parameters"] == recorded["parameters"]
+    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
