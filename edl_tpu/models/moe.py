@@ -43,10 +43,18 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.grouped_matmul import grouped_matmul
+
+# The ``checkpoint_name``s of what a remat policy may keep of a
+# :class:`DroplessMoE` (its docstring says which tensors, and their bytes);
+# ``models/transformer.py:_remat_policy`` saves both
+ROUTE_NAME = "moe_route"
+HELD_NAME = "moe_held"
+REMAT_NAMES = (ROUTE_NAME, HELD_NAME)
 
 # an expert's activation by name; "relu2" is the squared ReLU of Nemotron-H
 ACTIVATIONS = {"silu": nn.silu, "relu2": lambda a: jnp.square(nn.relu(a))}
@@ -123,6 +131,30 @@ def _rows_sorted(tokens, order, inverse, k, live=None):
 
     take.defvjp(fwd, bwd)
     return take(tokens, order, inverse, live)
+
+
+def _top_k_kept(scores, k):
+    """``lax.top_k(scores, k)`` along the last axis, values and indices both
+    under ``ROUTE_NAME``, with the values' gradient (each one's, back at its
+    index) read off the NAMED indices: jax's own rule reads the primitive's
+    unnamed output, so a recomputation that was handed the indices would run
+    the ``top_k`` again for them."""
+
+    @jax.custom_vjp
+    def top(scores):
+        return tuple(jax.lax.top_k(scores, k))
+
+    def fwd(scores):
+        values, idx = top(scores)
+        idx = checkpoint_name(idx, ROUTE_NAME)
+        return (checkpoint_name(values, ROUTE_NAME), idx), idx
+
+    def bwd(idx, grads):
+        taken = lambda s: jnp.take_along_axis(s, idx, axis=-1)  # noqa: E731
+        return jax.linear_transpose(taken, scores)(grads[0])
+
+    top.defvjp(fwd, bwd)
+    return top(scores)
 
 
 def _scalars_sorted(values, order, inverse):
@@ -298,6 +330,22 @@ class DroplessMoE(nn.Module):
       ``W_latent_up`` (only when a caller asks for the collection: a check
       against a reference).
 
+    **Under a policy that keeps names** (``REMAT_NAMES``; every LM cell's
+    ``"save_flash"`` does): the route's results the rest of the layer reads
+    bear ``moe_route`` (the float32 logits, ``top_idx``, ``order``,
+    ``inverse``, ``counts``, and the chosen experts' scores ``[N, k]`` before
+    they are normalised (the ``top_k``'s own values, or a gather out of the
+    scores that took 0.75 ms a layer of Ling's step when it ran again):
+    4 N E + 16 N k + 4 E bytes, 16.8 MB + 1.0 MB at 8192 tokens, 512 experts
+    and top-8), and the ``gate`` / ``up`` products over the held
+    experts' buffer ``moe_held`` (2 m F bytes each). A block's recomputation
+    handed them makes the scores, the normalised weights and the losses again
+    (elementwise on ``[N, E]`` and ``[N, k]``), gathers the buffer's
+    rows and sorts the weights, and runs no router matmul, no ``top_k``, no
+    ``argsort`` and no grouped matmul of the buffer branch. The whole-``N k``
+    form bears no name (537 MB a product in OLMoE's layer), and the large
+    branch of the ``cond`` goes on keeping nothing.
+
     Device-side names: ``moe_route`` (router, scores, bias, top-k, sort,
     losses), ``moe_latent`` (both of the latent's projections),
     ``moe_experts`` (gather, the routing weights and the grouped matmuls),
@@ -368,11 +416,14 @@ class DroplessMoE(nn.Module):
         )
 
         with jax.named_scope("moe_route"):
+            # by name, for a policy that keeps them (``REMAT_NAMES``): the rest
+            # of the route is elementwise on [N, E] or [N, k] and is made again
+            kept = partial(checkpoint_name, name=ROUTE_NAME)
             router_in = tokens.astype(jnp.float32)
-            logits = nn.Dense(
+            logits = kept(nn.Dense(
                 e, use_bias=False, dtype=jnp.float32, name="router",
                 precision=jax.lax.Precision.HIGHEST,
-            )(router_in)                                # [N, E]
+            )(router_in))                               # [N, E]
             if self.score_func == "softmax":
                 probs = jax.nn.softmax(logits, axis=-1)
             elif self.score_func == "sigmoid":
@@ -390,10 +441,10 @@ class DroplessMoE(nn.Module):
             if self.n_group > 1:
                 choice = self._inside_kept_groups(choice)
             if choice is probs:
-                weights, top_idx = jax.lax.top_k(probs, k)  # [N, k]
+                weights, top_idx = _top_k_kept(probs, k)    # [N, k]
             else:  # chosen by one quantity, weighted by the scores
-                _, top_idx = jax.lax.top_k(choice, k)
-                weights = jnp.take_along_axis(probs, top_idx, axis=-1)
+                top_idx = kept(jax.lax.top_k(choice, k)[1])
+                weights = kept(jnp.take_along_axis(probs, top_idx, axis=-1))
             if self.n_group > 1:
                 hit = jnp.any(jax.nn.one_hot(
                     top_idx // (e // self.n_group), self.n_group, dtype=bool
@@ -412,11 +463,11 @@ class DroplessMoE(nn.Module):
                 # behind them in one group that is nobody's
                 here = (top_idx >= first) & (top_idx < first + count)
                 flat = jnp.where(here, top_idx - first, count).reshape(n * k)
-            order = jnp.argsort(flat)                   # stable: by expert, then pair
-            inverse = jnp.argsort(order)
-            counts = jnp.sum(
+            order = kept(jnp.argsort(flat))             # stable: by expert, then pair
+            inverse = kept(jnp.argsort(order))
+            counts = kept(jnp.sum(
                 jax.nn.one_hot(top_idx, e, dtype=jnp.int32), axis=(0, 1)
-            )                                           # [E], sums to N*k
+            ))                                          # [E], sums to N*k
             if (
                 self.bias_rate > 0 and not self.is_initializing()
                 and self.is_mutable_collection("batch_stats")
@@ -487,6 +538,8 @@ class DroplessMoE(nn.Module):
                     grouped_matmul(rows, bank.astype(self.dtype), group_sizes)
                     for bank in banks[:-1]
                 ]
+                if m < n * k:  # the held experts' buffer alone: [m, F] each
+                    into = [checkpoint_name(a, HELD_NAME) for a in into]
                 if live is not None:
                     # Megablox writes no row past the groups' sum, forward or
                     # backward: what lies there is whatever the memory held.

@@ -52,6 +52,7 @@ from edl_tpu.models.gated_delta import (
 )
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
+from edl_tpu.models.moe import REMAT_NAMES as MOE_NAMES
 from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.attention import _flash2_blocks, attention
@@ -741,18 +742,28 @@ def _remat_policy(name: Optional[str]):
     (ops/gated_delta.py), and the three values a row that a
     sparse-attention layer's select kernel found (``dsa_select``: 192 KB a
     layer; the recomputation then makes the scores and the mask again, not
-    the bisection or its row sums; ops/sparse_attention.py); a model without such a
-    layer bears none of the names. ``None``/"full" is classic
+    the bisection or its row sums; ops/sparse_attention.py), and of an
+    expert layer what its route decided and what its held experts'
+    buffer multiplied (``models/moe.py:REMAT_NAMES``; ``moe_route``: the
+    router's float32 logits ``[N, E]``, 16.8 MB a layer at 8192 tokens and
+    512 experts, and the chosen experts, their scores, the sort's two
+    permutations and the counts, under 2 MB; ``moe_held``: the ``gate`` / ``up`` products
+    over the buffer of ``2 N k count / E`` rows, 6 MB a layer in Ling's
+    cell, 18 in Solar's, 30 in Nemotron's, 50 in LFM2's, 67 in
+    Trinity's, 100 in Keye's; the whole-``N k`` form bears no name), so
+    that the recomputation runs no router matmul, ``top_k``, sort or
+    buffer-sized grouped matmul again; a model without such a layer
+    bears none of the names. ``None``/"full" is classic
     recompute-everything."""
     if name in (None, "full"):
         return None
     if name == "save_flash":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", *GDN_NAMES, *DSA_NAMES
+            "flash_out", "flash_lse", *GDN_NAMES, *DSA_NAMES, *MOE_NAMES
         )
     if name == "save_flash_qkv":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES, *DSA_NAMES
+            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES, *DSA_NAMES, *MOE_NAMES
         )
     raise ValueError("unknown remat_policy %r" % (name,))
 

@@ -1546,3 +1546,130 @@ def test_the_solar_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded
     )
     assert doc["parameters"] == recorded["parameters"]
     assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
+
+
+# -- what a block's recomputation runs again of an expert layer (PR 52) --------
+
+_WHERE = (  # first match wins: where an instruction of an expert layer runs
+    ("large_again", re.compile(r"branch_0_fun/checkpoint/rematted_computation/")),
+    ("recomputed", re.compile(r"/rematted_computation/")),
+    ("backward", re.compile(r"transpose\(")),
+    ("forward", re.compile(r"")),
+)
+
+
+def _expert_layer_census(text):
+    """``{(what, where): count}`` over a compiled step's text: ``what`` the
+    router's matmul, a ``top_k``, a sort of the route's, a fusion of the gather
+    of the chosen scores (however many XLA makes of it), a sort that carries the
+    weights, the three Megablox kernels by the ``cond``'s branch (``buffer`` /
+    ``large``); ``where`` from the instruction's ``op_name``: ``forward``,
+    ``recomputed`` (the block's recomputation), ``large_again`` (the large
+    branch's own, inside its backward), ``backward``."""
+    import collections
+
+    census = collections.Counter()
+    for line in text.splitlines():
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        opcode = re.search(r" (dot|convolution|sort|custom-call|fusion)\(", line[:4096])
+        if not op_name or not opcode or "/moe/" not in op_name.group(1):
+            continue
+        op_name, opcode = op_name.group(1), opcode.group(1)
+        if opcode == "fusion":  # the chosen experts' scores, gathered out of [N, E]
+            if not op_name.endswith("/moe_route/jit(take_along_axis)/gather"):
+                continue
+            what = "scores_gather"
+        elif opcode == "custom-call":
+            kernel = re.search(r"moe_experts/jit\((t?gmm)\)/", op_name)
+            if "tpu_custom_call" not in line or not kernel:
+                continue
+            branch = "large" if "branch_0_fun" in op_name else "buffer"
+            what = "%s_%s" % (kernel.group(1), branch)
+        elif opcode == "sort":
+            what = (
+                "top_k" if op_name.endswith("/top_k")
+                else "route_sort" if "/moe_route/" in op_name else "weights_sort"
+            )
+        elif "/moe_route/router/" in op_name:
+            what = "router"
+        else:
+            continue
+        where = next(name for name, pattern in _WHERE if pattern.search(op_name))
+        census[what, where] += 1
+    return census
+
+
+@pytest.mark.parametrize("policy", ["save_flash", None], ids=["save_flash", "full"])
+@pytest.mark.parametrize("choice", ["plain", "grouped"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
+    one_chip, gated, choice, policy
+):
+    """A toy of one block that holds 8 of 32 experts (a buffer of 2048 of the
+    4096 pairs), lowered as the chip lowers it. Under ``save_flash`` the
+    policy keeps the layer's ``REMAT_NAMES`` and a block's recomputation runs
+    no router matmul, no ``top_k``, no ``argsort``, no gather of the chosen
+    scores and no Megablox call in the buffer branch: a layer's route runs once, and the buffer branch launches
+    ``gmm`` three times forward (two ungated) and three times for ``d lhs``.
+    The large branch keeps nothing and runs ``gate`` / ``up`` again inside its
+    own backward, as it did. With ``remat_policy=None`` nothing is kept and the
+    recomputation runs all of it, the counts before the names."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, MoESpec, TransformerLM
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers, banks = 1, 3 if gated else 2
+    lm = TransformerLM(
+        vocab_size=256, d_model=256, num_heads=2, num_kv_heads=1, num_layers=layers,
+        d_ff=256, dtype=jnp.bfloat16, remat=True, remat_policy=policy, norm_eps=1e-5,
+        moe=MoESpec(
+            num_experts=32, top_k=4, d_ff=256, norm_topk_prob=True, aux_weight=0.0,
+            z_weight=0.0, score_func="sigmoid", route_scale=2.5, bias_rate=1e-3,
+            held=(0, 8), gated=gated, activation="silu" if gated else "relu2",
+            **(dict(n_group=4, topk_group=2) if choice == "grouped" else {}),
+        ),
+        arch=ArchSpec(head_dim=128),
+    )
+    tokens = np.zeros((1, 1024), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    text = lowered.compile().as_text()
+    # no cond hands on a [N k, F] array: the large branch keeps nothing, and
+    # the join of the two branches' residuals would allocate it on every step
+    conds = [line.split(" conditional(")[0] for line in text.splitlines() if " conditional(" in line]
+    assert len(conds) == 3 * layers and not [c for c in conds if "[4096,256]" in c]
+    census = _expert_layer_census(text)
+    gathers = {where for (what, where) in census if what == "scores_gather"}
+    assert gathers == ({"forward"} if policy else {"forward", "recomputed"})
+    census = {key: n for key, n in census.items() if key[0] != "scores_gather"}
+    top_ks = 3 if choice == "grouped" else 1
+    again = 0 if policy == "save_flash" else 1  # what the recomputation repeats
+    want = {
+        ("router", "forward"): 1, ("router", "recomputed"): again, ("router", "backward"): 2,
+        ("top_k", "forward"): top_ks, ("top_k", "recomputed"): top_ks * again,
+        ("route_sort", "forward"): 2, ("route_sort", "recomputed"): 2 * again,
+        # the weights ride a sort into expert order in each branch, and their
+        # gradient one back: elementwise work's company, made again
+        ("weights_sort", "forward"): 2, ("weights_sort", "recomputed"): 1,
+        ("weights_sort", "large_again"): 1, ("weights_sort", "backward"): 2,
+        ("gmm_buffer", "forward"): banks, ("gmm_buffer", "recomputed"): (banks - 1) * again,
+        ("gmm_buffer", "backward"): banks, ("tgmm_buffer", "backward"): banks,
+        ("gmm_large", "forward"): banks, ("gmm_large", "large_again"): banks - 1,
+        ("gmm_large", "backward"): banks, ("tgmm_large", "backward"): banks,
+    }
+    assert dict(census) == {key: layers * n for key, n in want.items() if n}

@@ -325,6 +325,116 @@ def test_rows_past_the_groups_sum_may_hold_anything(whole_layer, monkeypatch, wh
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
 
 
+# -- what a block's recomputation finds saved of the layer ---------------------
+
+
+def _under(names, layer_kwargs, variables, x):
+    """Value, gradients and the moved bias of a remat-wrapped layer whose
+    policy saves ``names``; jitted, so the CPU's fusions are a step's."""
+    import flax.linen as nn
+
+    layer = nn.remat(
+        DroplessMoE, policy=jax.checkpoint_policies.save_only_these_names(*names)
+    )(**layer_kwargs)
+
+    def loss(params, x):
+        y, moved = layer.apply(
+            dict(variables, params=params), x, mutable=["batch_stats", "losses", "metrics"]
+        )
+        aux = sum(jnp.sum(leaf) for leaf in jax.tree.leaves(moved.get("losses", {})))
+        kept = (y, moved.get("batch_stats", {}), moved["metrics"])
+        return jnp.sum(jnp.square(y.astype(jnp.float32))) + aux, kept
+
+    (_, kept), grads = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+    )(variables["params"], x)
+    return kept, grads
+
+
+@pytest.mark.parametrize("load", ["inside_the_buffer", "outgrows_it"])
+@pytest.mark.parametrize("held", [(0, K), None], ids=["held", "whole"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("choice", ["scores", "grouped"])
+def test_a_recomputation_handed_the_route_and_the_products_gives_the_same(
+    choice, dtype, held, load
+):
+    """``save_flash`` keeps the layer's ``REMAT_NAMES``; the tensors it keeps
+    are the ones the recomputation would make, so the value, every gradient,
+    the moved bias and the gauges are those of a policy that keeps none: to
+    the bit on the CPU, float32 and bfloat16 alike, whether the step's rows
+    fit the held experts' buffer or take the whole ``N k`` (a bias sends every
+    token to the first ``K`` experts), with the scores themselves chosen from
+    (the ``top_k`` whose values are the weights) or a bias under the choice
+    and the choice inside the best groups."""
+    from edl_tpu.models import moe
+    from edl_tpu.models.transformer import MOE_NAMES
+
+    assert MOE_NAMES == moe.REMAT_NAMES == ("moe_route", "moe_held")
+    kind = dict(
+        scores=dict(score_func="softmax", bias_rate=0.0, aux_weight=1e-2, z_weight=1e-3),
+        grouped=dict(n_group=4, topk_group=3),
+    )[choice]
+    kwargs = dict(
+        num_experts=E, top_k=K, d_ff=F, norm_topk_prob=True, aux_weight=0.0,
+        z_weight=0.0, score_func="sigmoid", route_scale=2.826, bias_rate=0.001,
+        shared_d_ff=F, held=held, dtype=dtype,
+    )
+    kwargs.update(kind)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 24, D), dtype)
+    variables = DroplessMoE(**kwargs).init(jax.random.PRNGKey(8), x)
+    variables = {k: v for k, v in variables.items() if k in ("params", "batch_stats")}
+    if load == "outgrows_it":
+        if choice == "scores":  # no bias to lean on: the router itself prefers them
+            x = jnp.abs(x)
+            router = variables["params"]["router"]
+            router["kernel"] = jnp.where(jnp.arange(E) < K, 1.0, -1.0) + 0.01 * router["kernel"]
+        else:
+            variables["batch_stats"] = {
+                "router_bias": jnp.where(jnp.arange(E) < K, 10.0, 0.0)
+            }
+    (y, stats, gauges), grads = _under(moe.REMAT_NAMES, kwargs, variables, x)
+    if held is not None:
+        assert float(gauges["moe_rows_dropped"][0]) == 0
+        fits = float(gauges["moe_rows_held"][0]) <= 2 * held[1] / E
+        assert fits == (load == "inside_the_buffer")
+    if choice != "scores":
+        assert not np.array_equal(
+            np.asarray(stats["router_bias"]), np.asarray(variables["batch_stats"]["router_bias"])
+        )
+    want = _under((), kwargs, variables, x)
+    for a, b in zip(
+        jax.tree.leaves(((y, stats, gauges), grads)), jax.tree.leaves(want), strict=True
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in jax.tree.leaves(grads))
+    router = np.asarray(grads[0]["router"]["kernel"])
+    assert np.abs(router).max() > 0
+
+
+def test_a_dense_step_lowers_to_the_same_text_with_and_without_the_layers_names(monkeypatch):
+    """The names exist only inside ``DroplessMoE``: a model without one finds
+    nothing of theirs to save, and its lowered step is the same text whether
+    the policy lists them or not."""
+    from edl_tpu.models import TransformerLM, transformer
+    from edl_tpu.train import cross_entropy_loss
+
+    def lowered():
+        lm = TransformerLM(vocab_size=128, d_model=64, num_heads=4, num_kv_heads=2,
+                           num_layers=2, d_ff=160, remat=True)
+        tokens = np.zeros((2, 32), np.int32)
+        state = jax.eval_shape(
+            lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(3e-4))
+        )
+        loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+            logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+        )
+        return make_train_step(loss, numerics=True).lower(state, (tokens, tokens)).as_text()
+
+    with_names = lowered()
+    monkeypatch.setattr(transformer, "MOE_NAMES", ())
+    assert lowered() == with_names
+
+
 # -- the window, in every route -----------------------------------------------
 
 T, HD, BLOCK = 64, 8, 16
