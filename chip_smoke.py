@@ -568,8 +568,7 @@ def role_lm(cfg, args):
     names = re.findall(r'kernel_name = "(\w+)"', text)
     calls = {
         k: names.count(k)
-        for k in ("_flash_kernel", "_flash_bwd_dq_kernel",
-                  "_flash_bwd_dkv_kernel")
+        for k in ("_flash2_kernel", "_flash2_bwd_kernel")
     }
     if cfg["platform"] == "tpu":
         layers = c["layers"]

@@ -1,49 +1,38 @@
-"""Attention: jnp reference + two families of Pallas flash-attention TPU
-kernels, behind one routing function (:func:`_route`, asked by
-:func:`attention`).
+"""Attention: jnp reference + one family of Pallas flash-attention TPU
+kernels (:func:`attention` runs them on the TPU at every shape they tile).
 
 A flash kernel streams KV blocks through VMEM with the online-softmax
 recurrence (running row-max ``m``, denominator ``l``, numerator ``acc``),
 so the [Tq, Tk] score matrix never materializes in HBM — the standard
 memory-bandwidth win on TPU where HBM, not FLOPs, bounds attention.
 
-Layout: ``[batch, heads, seq, head_dim]``. The whole-KV family
-(``"flash"``: ``_flash_kernel`` and its two backward kernels) has the grid
-``(batch*heads, q_blocks)``; each program owns one q block, holds the
-whole K and V of its head in VMEM and loops over kv blocks with
-``lax.fori_loop``. The grid-pipelined family (``"flash2"``:
-``_flash2_kernel`` forward, ``_flash2_bwd_kernel`` backward) puts the kv
-blocks (backward: the q blocks) on a third, innermost grid dimension, so
-that they are copied block by block behind the compute; it is the one that
-runs past ``_WHOLE_KV_FWD_MAX_TQ`` rows (every benchmark cell's calls) and
-the one that takes a **window**
-(``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
-``i - W < j <= i``). Under a mask its innermost steps are **spans** of the
+Layout: ``[batch, heads, seq, head_dim]``. The kernels are grid-pipelined
+(``flash2``: ``_flash2_kernel`` forward, ``_flash2_bwd_kernel`` backward):
+the grid is ``(batch*heads, q_blocks, kv_blocks)`` (backward: the q blocks
+innermost), so the other side's blocks are copied block by block behind
+the compute and a kernel's VMEM does not grow with the sequence. They take
+a **window** (``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
+``i - W < j <= i``). Under a mask the innermost steps are **spans** of the
 other side that start where a block's first visible key (or row) lies, at
 an element and not at a block under a window; a step the mask leaves
 nothing for holds the nearest live span again, so what a block cannot see
 is neither copied nor computed, and every live tile is masked on both
-edges. The whole-KV family refuses a window and :func:`_route` sends a
-windowed call to flash2 at every length; the dense reference takes it as
-a mask. Causal masking compares global q/k positions from
-``broadcasted_iota`` (TPU needs ≥2D iota).
+edges. The dense reference takes a window as a mask. Causal masking
+compares global q/k positions from ``broadcasted_iota`` (TPU needs ≥2D
+iota).
 
 ``flash_attention`` is differentiable via ``jax.custom_vjp`` with REAL
 flash backward kernels: the forward saves per-row logsumexp (``lse``),
 the backward recomputes probabilities blockwise as ``exp(s - lse)`` (no
 online-softmax rescan needed), so the backward, where training time
-actually goes, also never materializes the [Tq, Tk] score matrix. The
-whole-KV family runs two Pallas kernels — one gridded over q blocks
-producing ``dq``, one over kv blocks producing ``dk``/``dv`` — and skips
-fully-masked blocks via dynamic ``fori_loop`` bounds. The grid-pipelined
-family runs **one**: a kv block's walk over its rows computes a tile's
-``s``, ``p``, ``dp`` and ``ds`` once and adds to all three gradients (five
-matmuls a tile, not seven), with the head's whole ``dq`` accumulated in
-VMEM; a head whose accumulator the chip's VMEM cannot hold
-(:func:`_fused_bwd_vmem`) keeps that family's older pair,
-``_flash2_bwd_dq_kernel`` and ``_flash2_bwd_dkv_kernel``. Ragged shapes
-fall back to the jnp reference end-to-end (forward and backward agree by
-construction).
+actually goes, also never materializes the [Tq, Tk] score matrix. It is
+**one** kernel: a kv block's walk over its rows computes a tile's ``s``,
+``p``, ``dp`` and ``ds`` once and adds to all three gradients (five matmuls
+a tile, not seven), with the head's whole ``dq`` accumulated in VMEM; a
+head whose accumulator the chip's VMEM cannot hold
+(:func:`_fused_bwd_vmem`) keeps the older pair, ``_flash2_bwd_dq_kernel``
+and ``_flash2_bwd_dkv_kernel``. Ragged shapes fall back to the jnp
+reference end-to-end (forward and backward agree by construction).
 """
 
 from __future__ import annotations
@@ -416,52 +405,6 @@ def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side,
     )
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                  causal: bool, scale: float, q_block: int, seq_k: int,
-                  q_offset: int):
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    q = q_ref[0]  # [block_q, d], input dtype (bf16 rides the MXU fast path)
-    block_q = q.shape[0]
-
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[1]), jnp.float32)
-
-    num_kv = seq_k // block_k
-    if causal:
-        # kv blocks past this q block's last row are fully masked
-        upper = jnp.minimum(
-            num_kv, ((qi + 1) * q_block + q_offset + block_k - 1) // block_k
-        )
-    else:
-        upper = num_kv
-
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = _dot_nt(q, k_blk) * scale
-        if causal:
-            s = _causal_mask(s, qi * q_block + q_offset, j * block_k)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + _dot_nn(p.astype(v_blk.dtype), v_blk)
-        return m_new, l, acc
-
-    m, l, acc = jax.lax.fori_loop(0, upper, body, (m, l, acc))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # per-row logsumexp of the SCALED scores: the backward's residual.
-    # lse rides pallas as [B*H, Tq, 1] — a (1, block_q, 1) block keeps the
-    # sublane dim 8-aligned, which the TPU lowering requires (a plain
-    # (1, block_q) block over [B*H, Tq] has sublane 1 and is rejected)
-    lse_ref[0] = m + jnp.log(l)
-
-
 # The grid-pipelined forward's online-softmax state. ``m`` and ``l`` are
 # kept a lane tile wide (``_state_lanes``): ``m`` the row maximum in every
 # lane, ``l`` the sum of the keys that fell on each lane, summed across
@@ -469,8 +412,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 # cross-lane unit an update, and a ``[block_q, 1]`` array a relayout each
 # time it meets a tile: that forward was bound by its updates, about as
 # much an update as 700 keys whatever its width, not by its keys (PERF.md,
-# PR 32). The whole-KV forward, 128 rows a program, read no faster for it
-# and keeps its ``[block_q, 1]`` carry.
+# PR 32).
 
 
 def _state_lanes(block_k: int) -> int:
@@ -502,11 +444,9 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                    window: int | None = None, seq_k: int = 0):
     """Grid-pipelined forward: the KV loop lives in the GRID (innermost
     dimension), so Pallas double-buffers each KV block's HBM→VMEM copy
-    behind the previous block's compute — where :func:`_flash_kernel`
-    holds the WHOLE KV in VMEM and walks it with a serial ``fori_loop``
-    (no copy/compute overlap, and a VMEM footprint that scales with the
-    full sequence). Online-softmax state (m, l, acc) carries across the
-    innermost grid steps in VMEM scratch, initialized at step 0 and
+    behind the previous block's compute, and the VMEM footprint does not
+    scale with the sequence. Online-softmax state (m, l, acc) carries across
+    the innermost grid steps in VMEM scratch, initialized at step 0 and
     finalized into (o, lse) at step num_k-1. Under a mask the ``num_k``
     steps are the q block's spans of ``seq_k`` keys (:func:`_kv_range`, :func:`_spans`)."""
     from jax.experimental import pallas as pl
@@ -543,7 +483,11 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _finalize():
         l = jnp.maximum(jnp.sum(l_scr[:], axis=-1, keepdims=True), 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:, :1] + jnp.log(l)  # [bq, 1] (see _flash_kernel)
+        # per-row logsumexp of the SCALED scores: the backward's residual.
+        # lse rides pallas as [B*H, Tq, 1] — a (1, block_q, 1) block keeps the
+        # sublane dim 8-aligned, which the TPU lowering requires (a plain
+        # (1, block_q) block over [B*H, Tq] has sublane 1 and is rejected)
+        lse_ref[0] = m_scr[:, :1] + jnp.log(l)
 
 
 def _grid_pipeline_kwargs() -> dict:
@@ -573,8 +517,9 @@ def _flash2_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
 ):
-    """(o, lse) via the grid-pipelined kernel; same ragged fallback
-    contract as :func:`_flash_forward` (``lse is None`` = dense path)."""
+    """(o, lse) via the grid-pipelined kernel; ``lse is None`` marks the
+    ragged-shape fallback to the jnp reference (the backward then uses the
+    reference too)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -584,6 +529,10 @@ def _flash2_forward(
     if not _spans_fit(block_q, block_k, tq, tk, window, "kv") or (
         causal and tq > tk
     ):
+        # ragged blocks, or end-aligned causal with MORE queries than keys:
+        # the latter leaves early q rows with zero visible keys, where the
+        # reference degenerates to a uniform softmax — not worth defeating
+        # the kernel's masked-block skipping to reproduce
         return attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
         ), None
@@ -637,87 +586,6 @@ def _flash2_forward(
     with obs_trace.span("kernel_trace", kernel="flash2_fwd"):
         out, lse = kernel(qf, kf, vf)
     return out.reshape(b, h, tq, d_v), lse[..., 0]
-
-
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k: int, causal: bool, scale: float,
-                         q_block: int, seq_k: int, q_offset: int):
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    q = q_ref[0]                                        # [bq, d]
-    do = do_ref[0]                                      # [bq, d]
-    lse = lse_ref[0]                                    # [bq, 1]
-    delta = delta_ref[0]                                # [bq, 1]
-    block_q = q.shape[0]
-
-    num_kv = seq_k // block_k
-    if causal:
-        upper = jnp.minimum(
-            num_kv, ((qi + 1) * q_block + q_offset + block_k - 1) // block_k
-        )
-    else:
-        upper = num_kv
-
-    def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = _dot_nt(q, k_blk) * scale
-        if causal:
-            s = _causal_mask(s, qi * q_block + q_offset, j * block_k)
-        p = jnp.exp(s - lse)                            # [bq, bk]
-        dp = _dot_nt(do, v_blk)
-        ds = p * (dp - delta)
-        return dq + _dot_nn(ds.astype(k_blk.dtype), k_blk)
-
-    dq = jax.lax.fori_loop(
-        0, upper, body, jnp.zeros((block_q, q.shape[1]), jnp.float32)
-    )
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          scale: float, k_block: int, seq_q: int,
-                          q_offset: int):
-    from jax.experimental import pallas as pl
-
-    ki = pl.program_id(1)
-    k_blk = k_ref[0]                                    # [bk, d]
-    v_blk = v_ref[0]                                    # [bk, d]
-    bk, d = k_blk.shape
-
-    num_q = seq_q // block_q
-    if causal:
-        # q rows before this kv block's first column are fully masked
-        lower = jnp.maximum(0, (ki * k_block - q_offset) // block_q)
-    else:
-        lower = 0
-
-    def body(j, carry):
-        dk, dv = carry
-        q_blk = q_ref[0, pl.ds(j * block_q, block_q), :]
-        do = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(j * block_q, block_q)]    # [bq, 1]
-        delta = delta_ref[0, pl.ds(j * block_q, block_q)]
-        s = _dot_nt(q_blk, k_blk) * scale
-        if causal:
-            s = _causal_mask(s, j * block_q + q_offset, ki * k_block)
-        p = jnp.exp(s - lse)                            # [bq, bk]
-        dv = dv + _dot_tn(p.astype(do.dtype), do)
-        dp = _dot_nt(do, v_blk)
-        ds = p * (dp - delta)
-        dk = dk + _dot_tn(ds.astype(q_blk.dtype), q_blk)
-        return dk, dv
-
-    dk, dv = jax.lax.fori_loop(
-        lower, num_q, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)),
-    )
-    # scale was applied to s, not pre-folded into q, so dk takes its one
-    # factor of ``scale`` here
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -813,8 +681,8 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(step == num_q - 1)
     def _finalize():
-        # scale applied to s, not pre-folded into q (see
-        # _flash_bwd_dkv_kernel): dk takes its one factor here
+        # scale was applied to s, not pre-folded into q, so dk takes its
+        # one factor of ``scale`` here
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
@@ -936,7 +804,7 @@ def _flash2_backward(
     dkv_blocks: tuple[int, int] | None = None,
 ):
     """(dq, dk, dv) via the grid-pipelined backward kernels;
-    ``lse`` in kernel layout [B*H, Tq] like :func:`_flash_backward`."""
+    ``lse`` in kernel layout [B*H, Tq]."""
     b, h, tq, _ = q.shape
     delta = _bwd_delta(g, o, b, h, tq, v.shape[3])
     return _flash2_backward_kernels(
@@ -952,7 +820,7 @@ def _flash2_backward_kernels(
 ):
     """The grid-pipelined backward; ``lse``/``delta`` are [B*H, Tq]
     (external residuals welcome — ring attention's per-rotation block
-    grads route here past the whole-KV compile limit). One pallas call,
+    grads come here). One pallas call,
     :func:`_flash2_bwd_kernel`, where a head's dq accumulator fits the
     chip's VMEM (:func:`_fused_bwd_vmem`) and a span of rows is whole lane
     tiles; the two older ones, dq and then dk/dv, where not. ``dkv_blocks``: those of the kernels that
@@ -976,8 +844,9 @@ def _flash2_backward_kernels(
     common = dict(causal=causal, scale=scale, q_offset=tk - tq, window=window)
     _, (q_steps, q_map) = _flash2_maps(causal, window, kv_q, kv_k, tq, tk, grp)
     # the kernels that walk rows a kv block: the rows in spans, k and v a
-    # grouped block, dk/dv at full q-head width, folded to the grouped
-    # width outside (see _flash_backward_kernels)
+    # grouped block (programs in one GQA group share it, so no H-wide repeat
+    # ever materializes in HBM), dk/dv at FULL q-head width (each program
+    # owns one q head's contribution), folded to the grouped width outside
     rows_spec = _span_spec(kv_q, d, q_map, window)
     kv_block = pl.BlockSpec((1, kv_k, d), lambda i, ki, j, g=grp: (i // g, ki, 0))
     if d_v == d:
@@ -1102,29 +971,6 @@ def _flash2_backward_kernels(
     return dq.reshape(b, h, tq, d), dk, dv
 
 
-_INF = float("inf")
-# measured per-seq WHOLE-KV flash kernel blocks — v5e on-chip sweep
-# (bq x bk grid, causal [4,16,T,64] bf16, bench_results/README.md
-# "block sweep"): rows (max_seq, (fwd_bq, fwd_bk), (bwd_bq, bwd_bk)),
-# first match wins (last row unbounded). bk=1024 crashes the TPU
-# compiler at seq>=4096; the 512 column won or tied everywhere it
-# mattered, so only bq varies. flash2 has its own separately-swept
-# blocks (_FLASH2_BLOCKS_* below) — this table is whole-KV-only.
-_BLOCK_TABLE = (
-    (1024, (256, 512), (256, 512)),
-    (2048, (512, 512), (256, 512)),
-    (_INF, (128, 512), (512, 512)),
-)
-
-
-def _kernel_blocks(tq: int):
-    """(fwd_blocks, bwd_blocks) for a sequence length, from the measured
-    table; callers still pass the result through ``_fit_block``."""
-    for max_seq, fwd, bwd in _BLOCK_TABLE:
-        if tq <= max_seq:
-            return fwd, bwd
-
-
 # flash2 (grid-pipelined) blocks, full-causal. ``_FWD``: PR 48's sweep at the
 # seven shapes the benchmark's cells call it at (T = 4096 to 16384, heads of
 # 64, 128 and 192 / 128, GQA 1:1 to 8:1, and the masked copy in
@@ -1224,63 +1070,6 @@ def _fit_block(block: int, t: int) -> int:
     return block
 
 
-def _flash_forward(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
-):
-    """Returns ``(o, lse)``; ``lse is None`` marks the ragged-shape
-    fallback to the jnp reference (backward then uses the reference too)."""
-    from jax.experimental import pallas as pl
-
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    block_q = _fit_block(block_q, tq)
-    block_k = _fit_block(block_k, tk)
-    if tq % block_q or tk % block_k or (causal and tq > tk):
-        # ragged blocks, or end-aligned causal with MORE queries than keys:
-        # the latter leaves early q rows with zero visible keys, where the
-        # reference degenerates to a uniform softmax — not worth defeating
-        # the kernel's masked-block skipping to reproduce
-        return attention_reference(q, k, v, causal=causal, scale=scale), None
-
-    g = _gqa_group(q, k)
-    qf = q.reshape(b * h, tq, d)
-    kf = k.reshape(b * (h // g), tk, d)
-    vf = v.reshape(b * (h // g), tk, d)
-    grid = (b * h, tq // block_q)
-    kernel = pl.pallas_call(
-        functools.partial(
-            _flash_kernel,
-            block_k=block_k,
-            causal=causal,
-            scale=scale,
-            q_block=block_q,
-            seq_k=tk,
-            q_offset=tk - tq,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, tq, 1), jnp.float32),
-        ],
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            # grouped k/v row: programs in one GQA group share it, so no
-            # H-wide repeat ever materializes in HBM
-            pl.BlockSpec((1, tk, d), lambda i, j, g=g: (i // g, 0, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j, g=g: (i // g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
-        interpret=interpret,
-    )
-    with obs_trace.span("kernel_trace", kernel="flash_fwd"):
-        out, lse = kernel(qf, kf, vf)
-    return out.reshape(b, h, tq, d), lse[..., 0]
-
-
 def _block_grads_reference(q, k, v, g, lse, delta, causal, scale):
     """jnp twin of the backward kernels for shapes they can't tile:
     block gradients given EXTERNAL (global) lse and delta."""
@@ -1319,123 +1108,21 @@ def flash_block_grads(
     distributed backward passes (ring attention accumulates these per KV
     rotation); shapes the kernels can't tile use the jnp twin.
 
-    Default blocks come from the measured tables (whole-KV backward
-    table, or flash2's where :func:`_whole_kv_serves` says the whole-KV
-    kernels do not); explicit block args always reach the kernel that
-    runs."""
+    Default blocks are the fused backward's (``_FLASH2_BLOCKS_BWD``);
+    explicit block args always reach the kernel."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
     tk = k.shape[2]
-    long_seq = not _whole_kv_serves(tq, tk)
-    if block_q is None or block_k is None:
-        dbq, dbk = _FLASH2_BLOCKS_BWD if long_seq else _kernel_blocks(tq)[1]
-        block_q = block_q or dbq
-        block_k = block_k or dbk
-    bq = _fit_block(block_q, tq)
-    bk = _fit_block(block_k, tk)
+    bq = _fit_block(block_q or _FLASH2_BLOCKS_BWD[0], tq)
+    bk = _fit_block(block_k or _FLASH2_BLOCKS_BWD[1], tk)
     if tq % bq or tk % bk or (causal and tq > tk):
         return _block_grads_reference(q, k, v, g, lse, delta, causal, scale)
-    kernels = _flash2_backward_kernels if long_seq else _flash_backward_kernels
-    return kernels(
+    return _flash2_backward_kernels(
         q, k, v, g,
         lse.reshape(b * h, tq), delta.reshape(b * h, tq),
         causal, scale, bq, bk, _interpret(),
     )
-
-
-def _flash_backward(
-    q, k, v, o, lse, g, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
-):
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    block_q = _fit_block(block_q, tq)
-    block_k = _fit_block(block_k, tk)
-
-    delta = _bwd_delta(g, o, b, h, tq, d)
-    return _flash_backward_kernels(
-        q, k, v, g, lse, delta, causal, scale, block_q, block_k, interpret
-    )
-
-
-def _flash_backward_kernels(
-    q, k, v, g, lse, delta, causal: bool, scale: float,
-    block_q: int, block_k: int, interpret: bool,
-):
-    """The two backward pallas calls; ``lse``/``delta`` are [B*H, Tq]."""
-    from jax.experimental import pallas as pl
-
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    grp = _gqa_group(q, k)
-    h_kv = h // grp
-
-    qf = q.reshape(b * h, tq, d)
-    kf = k.reshape(b * h_kv, tk, d)
-    vf = v.reshape(b * h_kv, tk, d)
-    gf = g.reshape(b * h, tq, d)
-    # pallas layout: trailing singleton keeps the block sublane 8-aligned
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
-
-    common = dict(causal=causal, scale=scale, q_offset=tk - tq)
-    kernel = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel,
-            block_k=block_k, q_block=block_q, seq_k=tk, **common,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-        grid=(b * h, tq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j, g=grp: (i // g, 0, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j, g=grp: (i // g, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        interpret=interpret,
-    )
-    with obs_trace.span("kernel_trace", kernel="flash_dq"):
-        dq = kernel(qf, kf, vf, gf, lse3, delta3)
-
-    # dk/dv come out at FULL q-head width (each program owns one q head's
-    # contribution) and fold to the grouped width outside — the kernels
-    # still never read a repeated K/V
-    kernel = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel,
-            block_q=block_q, k_block=block_k, seq_q=tq, **common,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
-        ],
-        grid=(b * h, tk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, g=grp: (i // g, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, g=grp: (i // g, j, 0)),
-            pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tq, 1), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tq, 1), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-        ],
-        interpret=interpret,
-    )
-    with obs_trace.span("kernel_trace", kernel="flash_dkv"):
-        dk, dv = kernel(qf, kf, vf, gf, lse3, delta3)
-
-    dk, dv = _fold_dkv(
-        dk.reshape(b, h, tk, d), dv.reshape(b, h, tk, d),
-        b, h_kv, grp, tk, d,
-    )
-    return dq.reshape(b, h, tq, d), dk, dv
 
 
 def _interpret() -> bool:
@@ -1454,31 +1141,22 @@ def flash_with_lse(
     """Forward-only ``(o, lse)`` with ``lse`` as [B, H, Tq] float32 —
     the primitive blockwise/ring merging builds on. Callers own
     differentiation (ring attention defines its own VJP from
-    :func:`flash_block_grads`). Default blocks come from the measured
-    tables (whole-KV kernel, or flash2 where :func:`_whole_kv_serves`
-    says it does not); explicit block args always reach the kernel that
-    runs."""
+    :func:`flash_block_grads`). Default blocks are the forward's
+    (``_FLASH2_BLOCKS_FWD``); explicit block args always reach the kernel."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
     tk = k.shape[2]
-    # resolve kernel + blocks FIRST so the ragged precheck validates the
-    # exact blocks the kernel will run with
-    long_seq = not _whole_kv_serves(tq, tk)
-    if block_q is None or block_k is None:
-        dbq, dbk = _FLASH2_BLOCKS_FWD if long_seq else _kernel_blocks(tq)[0]
-        block_q = block_q or dbq
-        block_k = block_k or dbk
-    bq = _fit_block(block_q, tq)
-    bk = _fit_block(block_k, tk)
+    # resolve the blocks FIRST so the ragged precheck validates the exact
+    # blocks the kernel will run with
+    bq = _fit_block(block_q or _FLASH2_BLOCKS_FWD[0], tq)
+    bk = _fit_block(block_k or _FLASH2_BLOCKS_FWD[1], tk)
     if tq % bq or tk % bk or (causal and tq > tk):
         # ragged: take the reference path directly (one compute, with lse)
         return attention_reference_with_lse(
             q, k, v, causal=causal, scale=scale
         )
-    # both forwards keep the same residual contract
-    forward = _flash2_forward if long_seq else _flash_forward
-    out, lse = forward(q, k, v, causal, scale, bq, bk, _interpret())
+    out, lse = _flash2_forward(q, k, v, causal, scale, bq, bk, _interpret())
     return out, lse.reshape(b, h, tq)
 
 
@@ -1494,114 +1172,33 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention; falls back to the reference on ragged shapes.
 
-    The kernels are the ones :func:`_route` gives the call's shapes, as in
-    :func:`attention` on the TPU (here on every backend: off the TPU they
-    run in the interpreter), so what a caller checks under this name is
-    what a step runs. Default blocks come from each family's measured table
-    (``_BLOCK_TABLE``, :func:`_flash2_blocks`); explicit block args win, in
-    the forward and in the backward, whichever family takes the call."""
+    The kernels are the ones :func:`attention` runs on the TPU (here on
+    every backend: off the TPU they run in the interpreter), so what a
+    caller checks under this name is what a step runs. Default blocks come
+    from :func:`_flash2_blocks`; explicit block args win, in the forward and
+    in the backward."""
     _check_window(window, causal)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     blocks = (block_q, block_k)
-    return _auto(
-        q, k, v, causal, scale,
-        *_route(q.shape[2], k.shape[2], window is not None,
-                v.shape[3] != q.shape[3]),
-        blocks, blocks, window,
-    )
+    return _auto(q, k, v, causal, scale, blocks, blocks, window)
 
 
-# -- routing ----------------------------------------------------------------
-
-# The whole-KV kernels' compile limit: on v5e, jax 0.9, Mosaic refused them
-# past it at every block config, while flash2's VMEM footprint does not grow
-# with the sequence. Feasibility, not speed.
-_WHOLE_KV_MAX_SEQ = 4096
-# Longest ``tq`` the whole-KV family keeps where both families compile: the
-# July calibration's forward crossover (bench_results/attention_dispatch_r4.json:
-# v5e, [4, 16, T, 64], T 1024-4096). What is left of ROADMAP S5(a): above it
-# a call is flash2's, forward and backward (PR 34's probes at the two cells
-# of T = 4096: the fused backward 10.73 ms against the whole-KV pair's 19.40,
-# 5.85 against 10.46); at and under it no benchmark cell runs and no probe
-# has measured the fused backward against the pair, so the crossover stands
-# where July put it. Moved by editing it in a PR the benchmark measures in
-# every cell, not by configuration.
-_WHOLE_KV_FWD_MAX_TQ = 2048
-
-
-def _whole_kv_serves(tq: int, tk: int, windowed: bool = False,
-                     two_widths: bool = False) -> bool:
-    """Whether the whole-KV family can take the call at all: it copies
-    every key of a head into VMEM before it looks at one, so only flash2's
-    grid can leave a window's blocks out, past ``_WHOLE_KV_MAX_SEQ`` it
-    does not compile, and its blocks have one width for q, k and v
-    (``two_widths``: values narrower than the keys, as latent attention
-    trains; the grid-pipelined kernels take them)."""
-    return not (windowed or two_widths) and max(tq, tk) <= _WHOLE_KV_MAX_SEQ
-
-
-def _route(tq: int, tk: int, windowed: bool,
-           two_widths: bool = False) -> tuple[str, str]:
-    """``(fwd_impl, bwd_impl)`` for a call, from what the call can observe.
-    The only code that names an implementation: ``"flash"`` is the whole-KV
-    family, ``"flash2"`` the grid-pipelined one. A call is of one family,
-    forward and backward: the whole-KV one where it serves and ``tq`` is at
-    most ``_WHOLE_KV_FWD_MAX_TQ``, flash2 everywhere else (which of flash2's
-    backward kernels, the fused one or the pair, is :func:`_fused_bwd_vmem`'s
-    to say)."""
-    if tq <= _WHOLE_KV_FWD_MAX_TQ and _whole_kv_serves(
-        tq, tk, windowed, two_widths
-    ):
-        return "flash", "flash"
-    return "flash2", "flash2"
-
-
-def _whole_kv_blocks(kind: str, tq: int, given=None):
-    """``(block_q, block_k)`` of a whole-KV kernel (``kind`` ``"fwd"`` or
-    ``"bwd"``, as in :func:`_flash2_blocks`): what the caller ``given`` (a
-    pair, either of it ``None``) wins, then ``_BLOCK_TABLE``'s."""
-    table = _kernel_blocks(tq)[kind == "bwd"]
-    given = given or (None, None)
-    return given[0] or table[0], given[1] or table[1]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _auto(q, k, v, causal, scale, fwd_impl, bwd_impl,
-          fwd_blocks=None, bwd_blocks=None, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _auto(q, k, v, causal, scale, fwd_blocks=None, bwd_blocks=None,
+          window=None):
     """``fwd_blocks``/``bwd_blocks`` are optional (bq, bk) overrides for
-    the kernel impls (hashable tuples — they ride nondiff_argnums);
-    ``None``, for a pair or for either of it, means the measured defaults
-    for that impl. ``window`` is
-    taken by ``"flash2"`` and by the reference's vjp (:func:`_route` gives
-    a windowed call no other)."""
-    return _auto_fwd(
-        q, k, v, causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
-        window,
-    )[0]
+    the kernels (hashable tuples — they ride nondiff_argnums); ``None``,
+    for a pair or for either of it, means the measured defaults."""
+    return _auto_fwd(q, k, v, causal, scale, fwd_blocks, bwd_blocks, window)[0]
 
 
-def _auto_fwd(q, k, v, causal, scale, fwd_impl, bwd_impl,
-              fwd_blocks=None, bwd_blocks=None, window=None):
-    if "flash" in (fwd_impl, bwd_impl) and not _whole_kv_serves(
-        q.shape[2], k.shape[2], window is not None, v.shape[3] != q.shape[3]
-    ):
-        raise ValueError(
-            "the whole-KV flash kernels take no window, no values narrower "
-            "than the keys and no sequence past %d" % _WHOLE_KV_MAX_SEQ
-        )
-    if fwd_impl == "flash2":
-        f2q, f2k = _flash2_blocks(
-            "fwd", q.shape[2], k.shape[2], window, fwd_blocks
-        )
-        out, lse = _flash2_forward(
-            q, k, v, causal, scale, f2q, f2k, _interpret(), window
-        )
-    else:
-        fbq, fbk = _whole_kv_blocks("fwd", q.shape[2], fwd_blocks)
-        out, lse = _flash_forward(
-            q, k, v, causal, scale, fbq, fbk, _interpret()
-        )
+def _auto_fwd(q, k, v, causal, scale, fwd_blocks=None, bwd_blocks=None,
+              window=None):
+    bq, bk = _flash2_blocks("fwd", q.shape[2], k.shape[2], window, fwd_blocks)
+    out, lse = _flash2_forward(
+        q, k, v, causal, scale, bq, bk, _interpret(), window
+    )
     return _name_residuals(q, k, v, out, lse)
 
 
@@ -1624,14 +1221,11 @@ def _name_residuals(q, k, v, out, lse):
     return out, (q, k, v, out, lse)
 
 
-def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
-              window, residuals, g):
+def _auto_bwd(causal, scale, fwd_blocks, bwd_blocks, window, residuals, g):
     q, k, v, o, lse = residuals
     tq, tk = q.shape[2], k.shape[2]
     kernels = lse is not None and not (causal and tq > tk)
-    # separate sweeps: _BLOCK_TABLE is the whole-KV kernels',
-    # _flash2_blocks the grid-pipelined ones'
-    if kernels and bwd_impl == "flash2":
+    if kernels:
         dq_blocks = _flash2_blocks("dq", tq, tk, window, bwd_blocks)
         dkv_blocks = _flash2_blocks("bwd", tq, tk, window, bwd_blocks)
         if _spans_fit(*dq_blocks, tq, tk, window, "kv") and _spans_fit(
@@ -1641,19 +1235,11 @@ def _auto_bwd(causal, scale, fwd_impl, bwd_impl, fwd_blocks, bwd_blocks,
                 q, k, v, o, lse, g, causal, scale, *dq_blocks, _interpret(),
                 window, dkv_blocks,
             )
-    if kernels and bwd_impl == "flash":
-        bbq, bbk = _whole_kv_blocks("bwd", tq, bwd_blocks)
-        bq, bk = _fit_block(bbq, tq), _fit_block(bbk, tk)
-        if not (tq % bq or tk % bk):
-            return _flash_backward(
-                q, k, v, o, lse, g, causal, scale, bq, bk, _interpret()
-            )
-    if bwd_impl in ("flash", "flash2"):  # a kernel was asked for
-        obs_trace.get_tracer().note_once(
-            "attn_route", tq=tq, tk=tk, window=window, side="backward",
-            path="plain",
-            why="lse" if lse is None else "blocks" if kernels else "shape",
-        )
+    obs_trace.get_tracer().note_once(
+        "attn_route", tq=tq, tk=tk, window=window, side="backward",
+        path="plain",
+        why="lse" if lse is None else "blocks" if kernels else "shape",
+    )
     _, vjp = jax.vjp(
         lambda q, k, v: attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
@@ -1675,13 +1261,12 @@ def attention(
     window: int | None = None,
 ) -> jax.Array:
     """The default entry point for every model in the tree
-    (TransformerLM, the LM examples). On the TPU :func:`_route` picks the
-    forward and the backward kernels from the call's shapes; off the TPU it
-    is exactly the dense reference. ``flash_attention`` /
-    ``attention_reference`` remain for callers that want a specific
-    implementation. ``window`` (with ``causal``): a query sees its
-    ``window`` newest keys, itself included; the reference takes it as a
-    mask, the kernels through flash2 alone. Which of the two a shape took
+    (TransformerLM, the LM examples). On the TPU it is the flash kernels,
+    forward and backward; off the TPU it is exactly the dense reference.
+    ``flash_attention`` / ``attention_reference`` remain for callers that
+    want a specific implementation. ``window`` (with ``causal``): a query
+    sees its ``window`` newest keys, itself included; the reference takes it
+    as a mask, the kernels as spans. Which of the two a shape took
     is an ``attn_route`` note (``path``, and ``why`` where it is the plain
     form: ``backend`` here; ``lse`` / ``shape`` / ``blocks`` where a backward
     pass on the TPU fell to the reference's)."""
@@ -1700,10 +1285,5 @@ def attention(
         return attention_reference(
             q, k, v, causal=causal, scale=scale, window=window
         )
-    fwd_impl, bwd_impl = _route(
-        q.shape[2], k.shape[2], window is not None, v.shape[3] != q.shape[3]
-    )
-    note(path="kernel", forward=fwd_impl, backward=bwd_impl)
-    return _auto(
-        q, k, v, causal, scale, fwd_impl, bwd_impl, None, None, window
-    )
+    note(path="kernel")
+    return _auto(q, k, v, causal, scale, None, None, window)

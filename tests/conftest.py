@@ -54,6 +54,36 @@ def unfence(monkeypatch):
     return off
 
 
+def value_and_gradients(fn, *args, weight=None, argnums=(0, 1)):
+    """``(value, gradients)`` of ``fn(*args)``, the gradients of its sum under
+    the cotangent ``weight`` (ones without one) with respect to ``argnums``,
+    as ONE jitted program. What the cases of a ``[value | gradients | <leaf>]``
+    family compare is computed once a module through this (an eager run pays
+    a compile for every primitive of the model, forward and backward, and
+    once more in every case that runs it again)."""
+    import jax
+    import jax.numpy as jnp
+
+    def scalar(*a):
+        out = fn(*a)
+        return jnp.sum(out if weight is None else out * weight), out
+
+    (_, value), grads = jax.jit(
+        jax.value_and_grad(scalar, argnums=argnums, has_aux=True)
+    )(*args)
+    return value, grads
+
+
+def loss_logits_gradients(fn, params):
+    """``(loss, logits, gradients)`` of ``fn(params) -> (loss, logits)`` as ONE
+    jitted program: what the cases of a ``[logits | loss | gradients]`` family
+    compare, computed once a module (see :func:`value_and_gradients`)."""
+    import jax
+
+    (loss, logits), grads = jax.jit(jax.value_and_grad(fn, has_aux=True))(params)
+    return loss, logits, grads
+
+
 def incarnations(out_dir):
     """toy_worker marker files -> {stage: {rank: world}}"""
     out = defaultdict(dict)

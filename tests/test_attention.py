@@ -399,26 +399,29 @@ def tiny_lm_attn(attn_fn):
 
 
 class TestDispatchedAttention:
-    """The routed entry point (ops.attention.attention): any fwd/bwd
-    composition `_route` can name, and the reference's vjp every backward
-    falls back to, must match the dense reference in values AND grads —
-    either family's lse feeds the other's backward kernels."""
+    """What the routed entry point (ops.attention.attention) runs on the
+    TPU, `_auto`: the kernels' backward, and the reference's vjp a backward
+    falls back to where its blocks do not tile the shape (the forward's lse
+    is then unused), must match the dense reference in values AND grads."""
 
-    @pytest.mark.parametrize("fwd_impl", ["flash", "flash2"])
-    @pytest.mark.parametrize("bwd_impl", ["ref", "flash", "flash2"])
+    @pytest.mark.parametrize("bwd_blocks", [None, (24, 24)], ids=["kernel", "plain"])
     @pytest.mark.parametrize("causal", [False, True])
-    def test_all_compositions_match_reference(self, fwd_impl, bwd_impl, causal):
-        from edl_tpu.ops.attention import _auto
+    def test_value_and_grads_match_reference(self, bwd_blocks, causal):
+        import importlib
 
+        A = importlib.import_module("edl_tpu.ops.attention")
         q, k, v = _qkv(t=32)
         scale = q.shape[-1] ** -0.5
-        out = _auto(q, k, v, causal, scale, fwd_impl, bwd_impl)
+        assert A._spans_fit(
+            *A._flash2_blocks("bwd", 32, 32, None, bwd_blocks), 32, 32, None, "q"
+        ) == (bwd_blocks is None)
+        auto = lambda q, k, v: A._auto(q, k, v, causal, scale, None, bwd_blocks)
+        out = auto(q, k, v)
         ref = attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
         grads = jax.grad(
-            lambda q, k, v: _auto(q, k, v, causal, scale, fwd_impl, bwd_impl).sum(),
-            argnums=(0, 1, 2),
+            lambda q, k, v: auto(q, k, v).sum(), argnums=(0, 1, 2),
         )(q, k, v)
         ref_grads = jax.grad(
             lambda q, k, v: attention_reference(q, k, v, causal=causal).sum(),
@@ -564,17 +567,15 @@ class TestGQAKernels:
                 np.asarray(a), np.asarray(b_), atol=3e-4, rtol=1e-3
             )
 
-    def test_flash2_grouped_long_seq_route(self, monkeypatch):
-        # force the flash2 route (past the whole-KV compile limit)
-        import importlib
-
-        A = importlib.import_module("edl_tpu.ops.attention")
-        monkeypatch.setattr(A, "_WHOLE_KV_MAX_SEQ", 128)
+    def test_flash2_grouped_multi_block(self):
+        # several blocks a side (the measured ones are one block at t = 256):
+        # the i // g index maps under the grid's walk
         q, k, v, w = self._mk(4, 2)
         want_val, want_dq, want_dk, want_dv = self._want(q, k, v, w, True)
 
         def f(q, k, v):
-            return (flash_attention(q, k, v, causal=True) * w).sum()
+            out = flash_attention(q, k, v, causal=True, block_q=64, block_k=128)
+            return (out * w).sum()
 
         got_val, (dq, dk, dv) = jax.value_and_grad(f, argnums=(0, 1, 2))(
             q, k, v

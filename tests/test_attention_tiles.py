@@ -12,6 +12,7 @@ count over the mask. (``tests/test_attention.py`` is marked ``slow`` as a
 module, so tier-1 never runs it: these live here.)
 """
 
+import functools
 import importlib
 
 import jax
@@ -71,15 +72,26 @@ def _kernels(q, k, v, g, fwd, dq, dkv, window):
 
 def _reference(q, k, v, g, window):
     scale = q.shape[-1] ** -0.5
-    o, lse = A.attention_reference_with_lse(
-        q, k, v, causal=True, scale=scale, window=window
-    )
-    _, vjp = jax.vjp(
-        lambda q, k, v: A.attention_reference(
+
+    @jax.jit
+    def dense(q, k, v, g):  # one program, not a compile an operation
+        o, lse = A.attention_reference_with_lse(
             q, k, v, causal=True, scale=scale, window=window
-        ), q, k, v,
-    )
-    return tuple(np.asarray(x) for x in (o, lse.reshape(-1, q.shape[2]), *vjp(g)))
+        )
+        _, vjp = jax.vjp(
+            lambda q, k, v: A.attention_reference(
+                q, k, v, causal=True, scale=scale, window=window
+            ), q, k, v,
+        )
+        return (o, lse.reshape(-1, q.shape[2]), *vjp(g))
+
+    return tuple(np.asarray(x) for x in dense(q, k, v, g))
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_reference(h, h_kv, tq, tk, d, window):
+    """The dense side of a walk, once for both of its backwards."""
+    return _reference(*_inputs(h, h_kv, tq, tk, d), window)
 
 
 def _sees(tq, tk, window):
@@ -140,7 +152,7 @@ def test_flash2_walk_agrees_with_the_dense_reference(
         lambda: _kernels(q, k, v, g, *fitted, window)
     )
     assert traced == TRACED[backward]
-    want = _reference(q, k, v, g, window)
+    want = _walk_reference(h, h_kv, tq, tk, d, window)
     for name, a, b in zip(NAMES, got, want):
         tol = 3e-5 if name in ("o", "lse") else 3e-4
         np.testing.assert_allclose(a, b.reshape(a.shape), atol=tol, err_msg=name)
@@ -234,13 +246,10 @@ def test_a_head_whose_dq_does_not_fit_takes_dq_and_dkv(monkeypatch):
     pytest.param(8, 2, 256, 256, id="gqa4"),
     pytest.param(8, 2, 128, 256, id="gqa4-offset128"),
 ])
-def test_block_grads_with_external_residuals_past_the_whole_kv_limit(
-    monkeypatch, backward, h, h_kv, tq, tk
-):
+def test_block_grads_with_external_residuals(backward, h, h_kv, tq, tk):
     """Ring attention's building block: ``lse`` and ``delta`` of the global
-    softmax come from outside, and past the whole-KV compile limit the call
-    is the flash2 backward's, fused or not by the same rule."""
-    monkeypatch.setattr(A, "_WHOLE_KV_MAX_SEQ", 64)
+    softmax come from outside, and the call is the flash2 backward's, fused
+    or not by the same rule."""
     q, k, v, g = _inputs(h, h_kv, tq, tk, 64, seed=9)
     scale = 64 ** -0.5
     o, lse = A.attention_reference_with_lse(q, k, v, causal=True, scale=scale)

@@ -127,25 +127,24 @@ def test_attention_bench_tool_cpu():
 
 @pytest.mark.slow
 def test_attention_block_sweep_tool_cpu():
-    """Both kernel branches of the block-sweep tool produce fwd AND
-    fwd+bwd rows (flash2's backward is composed explicitly), so the
-    shipped _BLOCK_TABLE/_FLASH2_BLOCKS_* constants stay re-derivable."""
+    """The block-sweep tool produces fwd AND fwd+bwd rows (the backward is
+    composed explicitly), so the shipped _FLASH2_BLOCKS_* constants stay
+    re-derivable."""
     import json
 
-    for impl in ("flash", "flash2"):
-        proc = run_tool(
-            "attention_block_sweep.py",
-            ["--impl", impl, "--seqs", "64", "--batch", "1", "--heads", "1",
-             "--head_dim", "8", "--blocks_q", "32", "--blocks_k", "32",
-             "--iters", "1"],
-        )
-        assert proc.returncode == 0, proc.stderr[-1200:]
-        row = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert row["impl"] == impl and row["seq"] == 64
-        # toy shapes can two-point-cancel to 0.0 ms; structure is the
-        # contract here — both modes measured, no compile error recorded
-        assert "error" not in row
-        assert row["fwd_ms"] >= 0 and row["fwdbwd_ms"] >= 0
+    proc = run_tool(
+        "attention_block_sweep.py",
+        ["--seqs", "64", "--batch", "1", "--heads", "1",
+         "--head_dim", "8", "--blocks_q", "32", "--blocks_k", "32",
+         "--iters", "1"],
+    )
+    assert proc.returncode == 0, proc.stderr[-1200:]
+    row = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert row["seq"] == 64
+    # toy shapes can two-point-cancel to 0.0 ms; structure is the
+    # contract here — both modes measured, no compile error recorded
+    assert "error" not in row
+    assert row["fwd_ms"] >= 0 and row["fwdbwd_ms"] >= 0
 
 
 @pytest.mark.slow

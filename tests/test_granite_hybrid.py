@@ -4,6 +4,7 @@ hybrid ``TransformerLM`` (layer pattern, position-free attention at a given
 score scale, tied and scaled embeddings) against the benchmark's plain
 reference, and the programs the dense and the expert LM lower to, unchanged."""
 
+import functools
 import hashlib
 
 import jax
@@ -11,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients, value_and_gradients
 
 from benchmark.reference import ssm_lm as reference
 from edl_tpu.models import ArchSpec, Mamba2Mixer, MambaSpec, MoESpec, TransformerLM
@@ -150,10 +152,11 @@ def test_scan_in_bfloat16_keeps_its_decays_and_state_in_float32():
 # -- the mixer ---------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
 def mixer_and_params():
     mixer = Mamba2Mixer(SPEC, jnp.float32, 1e-5)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 48))
-    return mixer, shaken(mixer.init(jax.random.PRNGKey(1), x)["params"]), x
+    return mixer, shaken(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"]), x
 
 
 def test_mixer_equals_the_sequential_reference():
@@ -161,26 +164,31 @@ def test_mixer_equals_the_sequential_reference():
     assert set(params) == {"in_proj", "out_proj", "conv_kernel", "conv_bias",
                            "A_log", "dt_bias", "D", "norm"}
     assert params["in_proj"]["kernel"].shape == (48, 128 + (128 + 2 * 16) + 8)
-    np.testing.assert_allclose(
-        mixer.apply({"params": params}, x), reference.mamba_mixer(TOY, params, x),
-        rtol=2e-5, atol=2e-5,
-    )
+    (got, _), (want, _) = mixer_both_ways()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 MIXER_LEAVES = ["A_log", "dt_bias", "D", "conv_bias", "conv_kernel", "norm",
                 "in_proj/kernel", "out_proj/kernel", "input"]
 
 
-@pytest.mark.parametrize("leaf", MIXER_LEAVES)
-def test_mixer_gradient_equals_the_references(leaf):
+@functools.lru_cache(maxsize=None)
+def mixer_both_ways():
+    """``(value, gradients)`` of the mixer and of the sequential reference
+    under one cotangent, once for the cases that each look at one leaf."""
     mixer, params, x = mixer_and_params()
     w = jax.random.normal(jax.random.PRNGKey(7), x.shape)
-    got = jax.grad(
-        lambda p, x: jnp.sum(mixer.apply({"params": p}, x) * w), (0, 1)
-    )(params, x)
-    want = jax.grad(
-        lambda p, x: jnp.sum(reference.mamba_mixer(TOY, p, x) * w), (0, 1)
-    )(params, x)
+    return [
+        value_and_gradients(fn, params, x, weight=w) for fn in (
+            lambda p, x: mixer.apply({"params": p}, x),
+            lambda p, x: reference.mamba_mixer(TOY, p, x),
+        )
+    ]
+
+
+@pytest.mark.parametrize("leaf", MIXER_LEAVES)
+def test_mixer_gradient_equals_the_references(leaf):
+    (_, got), (_, want) = mixer_both_ways()
 
     def pick(grads):
         if leaf == "input":
@@ -196,7 +204,7 @@ def test_mixer_gradient_equals_the_references(leaf):
 
 def test_mixer_initialises_as_mamba2_does():
     mixer = Mamba2Mixer(MambaSpec(num_heads=512, head_dim=2, d_state=4), jnp.float32)
-    p = mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 16)))["params"]
+    p = jax.jit(mixer.init)(jax.random.PRNGKey(0), jnp.zeros((1, 4, 16)))["params"]
     a, dt = jnp.exp(p["A_log"]), jax.nn.softplus(p["dt_bias"])
     assert 1.0 <= float(a.min()) and float(a.max()) <= 16.0
     assert 1e-3 * 0.999 <= float(dt.min()) and float(dt.max()) <= 1e-1 * 1.001
@@ -208,28 +216,39 @@ def test_mixer_initialises_as_mamba2_does():
 # -- the whole model ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_hybrid_lm_equals_the_plain_reference(remat, what):
+@functools.lru_cache(maxsize=None)
+def lm_and_reference(remat):
+    """``(loss, logits, gradients)`` of the toy LM and of the plain reference
+    at one batch, once for the three cases that each look at one of them."""
     lm = toy_lm(remat=remat)
     x, y = toy_batch()
-    params = shaken(lm.init(jax.random.PRNGKey(3), x)["params"])
+    params = shaken(jax.jit(lm.init)(jax.random.PRNGKey(3), x)["params"])
     assert "lm_head" not in params                       # tied: no second matrix
     assert set(params["layer_0"]) == {"ln1", "mamba", "ln2", "mlp"}
     assert set(params["layer_1"]) == {"ln1", "attn", "ln2", "mlp"}
     assert params["layer_1"]["attn"]["q"]["kernel"].shape == (48, 4, 16)
+
+    def program(p):
+        logits = lm.apply({"params": p}, x)
+        return lm_loss(logits, y)[0], logits
+
+    def plain(p):
+        logits = reference.forward(TOY, p, x)
+        return reference.loss(logits, y), logits
+
+    return [loss_logits_gradients(fn, params) for fn in (program, plain)]
+
+
+@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_hybrid_lm_equals_the_plain_reference(remat, what):
+    (loss, logits, got), (want_loss, want_logits, want) = lm_and_reference(remat)
     if what == "logits":
-        np.testing.assert_allclose(
-            lm.apply({"params": params}, x), reference.forward(TOY, params, x),
-            rtol=2e-4, atol=2e-5,
-        )
+        np.testing.assert_allclose(logits, want_logits, rtol=2e-4, atol=2e-5)
         return
-    program = lambda p: lm_loss(lm.apply({"params": p}, x), y)[0]  # noqa: E731
-    plain = lambda p: reference.loss(reference.forward(TOY, p, x), y)  # noqa: E731
     if what == "loss":
-        assert float(program(params)) == pytest.approx(float(plain(params)), rel=1e-5)
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
         return
-    got, want = jax.grad(program)(params), jax.grad(plain)(params)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(
             a, b, rtol=1e-3, atol=1e-6, err_msg=jax.tree_util.keystr(path)
@@ -239,8 +258,8 @@ def test_hybrid_lm_equals_the_plain_reference(remat, what):
 def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses():
     lm = toy_lm()
     x, y = toy_batch()
-    params = shaken(lm.init(jax.random.PRNGKey(3), x)["params"])
-    tied = jax.grad(lambda p: lm_loss(lm.apply({"params": p}, x), y)[0])(params)
+    params = shaken(jax.jit(lm.init)(jax.random.PRNGKey(3), x)["params"])
+    tied = jax.jit(jax.grad(lambda p: lm_loss(lm.apply({"params": p}, x), y)[0]))(params)
 
     def untied(lookup, head):
         """The reference's forward pass with the two uses of E as two
@@ -260,7 +279,7 @@ def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses():
         return reference.loss((h @ head.T) / config["logits_scaling"], y)
 
     e = params["embed"]["embedding"]
-    from_lookup, from_head = jax.grad(untied, (0, 1))(e, e)
+    from_lookup, from_head = jax.jit(jax.grad(untied, (0, 1)))(e, e)
     assert float(jnp.abs(from_lookup).max()) > 0 and float(jnp.abs(from_head).max()) > 0
     np.testing.assert_allclose(
         tied["embed"]["embedding"], from_lookup + from_head, rtol=1e-3, atol=1e-6
@@ -278,25 +297,24 @@ def test_each_multiplier_reaches_the_logits_as_the_reference_says(key, value):
     field = "attn_scale" if key == "attention_multiplier" else key
     lm = toy_lm(toy_arch(**{field: value}))
     x, _ = toy_batch()
-    params = shaken(toy_lm().init(jax.random.PRNGKey(3), x)["params"])
-    got = lm.apply({"params": params}, x)
-    np.testing.assert_allclose(
-        got, reference.forward(dict(TOY, **{key: value}), params, x), rtol=2e-4, atol=2e-5
-    )
-    base = toy_lm().apply({"params": params}, x)
+    params = shaken(jax.jit(toy_lm().init)(jax.random.PRNGKey(3), x)["params"])
+    got = jax.jit(lm.apply)({"params": params}, x)
+    want = jax.jit(lambda p: reference.forward(dict(TOY, **{key: value}), p, x))(params)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    base = jax.jit(toy_lm().apply)({"params": params}, x)
     assert float(jnp.max(jnp.abs(got - base))) > 1e-3    # and it is not ignored
 
 
 def test_attention_without_rope_sees_no_positions_and_with_rope_does():
     x, _ = toy_batch()
-    params = toy_lm().init(jax.random.PRNGKey(3), x)["params"]
+    params = jax.jit(toy_lm().init)(jax.random.PRNGKey(3), x)["params"]
     shifted = jnp.broadcast_to(jnp.arange(x.shape[1])[None] + 100, x.shape)
-    plain, roped = toy_lm(), toy_lm(toy_arch(rope=True))
+    plain, roped = jax.jit(toy_lm().apply), jax.jit(toy_lm(toy_arch(rope=True)).apply)
     np.testing.assert_array_equal(
-        plain.apply({"params": params}, x), plain.apply({"params": params}, x, shifted)
+        plain({"params": params}, x), plain({"params": params}, x, shifted)
     )
     assert float(jnp.max(jnp.abs(
-        roped.apply({"params": params}, x) - plain.apply({"params": params}, x)
+        roped({"params": params}, x) - plain({"params": params}, x)
     ))) > 1e-4
 
 
@@ -327,14 +345,18 @@ def test_the_hybrid_trains_through_the_step_and_is_never_split_at_batch_one():
     assert float(metrics["loss"]) < first and np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.parametrize("scope", SSM_SCOPES)
-def test_the_compiled_step_names_the_mixers_scopes(scope):
+@functools.lru_cache(maxsize=None)
+def compiled_steps_scopes():
     lm = toy_lm(dtype=jnp.bfloat16, remat=True)
     x, y = toy_batch(b=1)
     state = create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
     compiled = make_train_step(lm_loss, numerics=False).lower(state, (x, y)).compile()
-    table = obs_profile.scopes_of_hlo(compiled.as_text(), SSM_SCOPES)
-    assert scope in set(table.values())
+    return set(obs_profile.scopes_of_hlo(compiled.as_text(), SSM_SCOPES).values())
+
+
+@pytest.mark.parametrize("scope", SSM_SCOPES)
+def test_the_compiled_step_names_the_mixers_scopes(scope):
+    assert scope in compiled_steps_scopes()
 
 
 # -- the other two LMs are what they were -------------------------------------
@@ -384,7 +406,7 @@ def test_an_arch_spec_at_its_defaults_is_the_dense_model():
     x, _ = toy_batch()
     dense = TransformerLM(vocab_size=64, d_model=48, num_heads=4, num_kv_heads=2,
                           num_layers=2, d_ff=40, dtype=jnp.float32)
-    params = dense.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(dense.init)(jax.random.PRNGKey(0), x)["params"]
     np.testing.assert_array_equal(
         dense.apply({"params": params}, x),
         dense.clone(arch=ArchSpec()).apply({"params": params}, x),

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import value_and_gradients
 
 from benchmark.families import kda_lm as family
 from benchmark.reference import kda_lm as reference
@@ -88,33 +89,47 @@ CASES = {"drawn": lambda a: a, "at_the_bound": at_the_bound, "repeated_keys": re
 chunked = lambda *a: kda_rule(*a, chunk=32, return_final_state=True)  # noqa: E731
 
 
+def _value_and_five_gradients(rule):
+    """One jitted program for every case (they differ in values alone): ``(o,
+    state)`` and the gradients of a weight on both for the five operands."""
+
+    def weighed(*a):  # the output and the state both
+        o, state = rule(*a)
+        return jnp.sum(jnp.sin(o)) + jnp.sum(state ** 2), (o, state)
+
+    return jax.jit(jax.value_and_grad(weighed, argnums=range(5), has_aux=True))
+
+
+BOTH_RULES = (_value_and_five_gradients(chunked), _value_and_five_gradients(reference.recurrence))
+
+
+@functools.lru_cache(maxsize=None)
+def chunked_and_recurrence(case):
+    args = CASES[case](rule_inputs())
+    with jax.default_matmul_precision("highest"):
+        return [(values, grads) for (_, values), grads in (run(*args) for run in BOTH_RULES)]
+
+
 @pytest.mark.parametrize("case", list(CASES))
 @pytest.mark.parametrize("what", ["value", "q", "k", "v", "g", "beta"])
 def test_the_chunked_rule_equals_the_step_by_step_recurrence(case, what):
-    args = CASES[case](rule_inputs())
-    with jax.default_matmul_precision("highest"):
-        if what == "value":
-            (o, state), (want_o, want_state) = chunked(*args), reference.recurrence(*args)
-            assert o.shape == (2, 96, 3, 16) and state.shape == (2, 3, 8, 16)
-            _close(o, want_o, tol=1e-4)
-            _close(state, want_state, tol=1e-4)
-            return
-        weigh = lambda f: lambda *a: (  # noqa: E731 — the output and the state both
-            jnp.sum(jnp.sin(f(*a)[0])) + jnp.sum(f(*a)[1] ** 2)
-        )
-        leaf = ("q", "k", "v", "g", "beta").index(what)
-        got = jax.grad(weigh(chunked), argnums=leaf)(*args)
-        want = jax.grad(weigh(reference.recurrence), argnums=leaf)(*args)
+    ((o, state), got), ((want_o, want_state), want) = chunked_and_recurrence(case)
+    if what == "value":
+        assert o.shape == (2, 96, 3, 16) and state.shape == (2, 3, 8, 16)
+        _close(o, want_o, tol=1e-4)
+        _close(state, want_state, tol=1e-4)
+        return
+    leaf = ("q", "k", "v", "g", "beta").index(what)
     # at the bound the terms that survive are e^-5 and less of the rest's
-    _close(got, want, tol=2e-3 if case == "at_the_bound" else 2e-4)
+    _close(got[leaf], want[leaf], tol=2e-3 if case == "at_the_bound" else 2e-4)
 
 
 @pytest.mark.parametrize("chunk", [16, 64, 32, 8])
 def test_the_result_does_not_depend_on_the_chunk(chunk):
     args = rule_inputs(seed=1, t=70)  # a length no chunk divides: padded steps leave the state
     with jax.default_matmul_precision("highest"):
-        o, state = kda_rule(*args, chunk=chunk, return_final_state=True)
-        want_o, want_state = reference.recurrence(*args)
+        o, state = jax.jit(lambda *a: kda_rule(*a, chunk=chunk, return_final_state=True))(*args)
+        want_o, want_state = BOTH_RULES[1](*args)[0][1]
     _close(o, want_o, tol=1e-4)
     _close(state, want_state, tol=1e-4)
 
@@ -242,20 +257,33 @@ def weights(like, seed=20):
     return tuple(jax.random.normal(key, a.shape).astype(a.dtype) for key, a in zip(keys, like))
 
 
+def _stage_program(stage, inverse):
+    """One jitted program for every case of a dtype (the cases differ in
+    values alone): the stage's seven operands and its five gradients."""
+
+    @jax.jit
+    def run(*args):
+        values, pull = jax.vjp(lambda *x: stage(*x)[:6], *args)
+        return values + (inverse(*args),), pull(weights(values))
+
+    return run
+
+
+_plain_stage = lambda *a: G._local_plain(*a, 64)  # noqa: E731
+STAGES = (
+    _stage_program(by_kernels, inverse_by_kernels),
+    _stage_program(_plain_stage, lambda *a: _plain_stage(*a)[6]),
+)
+
+
 @functools.lru_cache(maxsize=None)
 def stage_both_ways(case, dtype):
     """``(values, gradients)`` of the stage by the kernels and by the plain
     form on the same inputs: the gradients under one random cotangent of the
     six operands the rule reads (``T`` is the backward's own residual)."""
     args = kernel_inputs(case, jnp.dtype(dtype))
-    plain = lambda *a: G._local_plain(*a, 64)  # noqa: E731
-    found = []
     with jax.default_matmul_precision("highest"):
-        for stage, inverse in ((by_kernels, inverse_by_kernels), (plain, lambda *a: plain(*a)[6])):
-            values, pull = jax.jit(lambda *a, stage=stage: jax.vjp(lambda *x: stage(*x)[:6], *a))(*args)
-            grads = jax.jit(pull)(weights(values))
-            found.append((values + (jax.jit(inverse)(*args),), grads))
-    return found
+        return [run(*args) for run in STAGES]
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -279,6 +307,22 @@ def test_the_kernels_are_the_plain_chunk_local_stage(what, case, dtype):
 bf16_args = lambda args: tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]  # noqa: E731
 
 
+def _rule_program(interpret):
+    """One jitted program for every case (they differ in values alone)."""
+
+    @jax.jit
+    def run(state, *args):
+        values, pull = jax.vjp(lambda *a: kda_rule(
+            *a, chunk=64, initial_state=state, return_final_state=True, interpret=interpret
+        ), *args)
+        return values, pull(weights(values, seed=21))
+
+    return run
+
+
+RULES = (_rule_program(True), _rule_program(False))
+
+
 @functools.lru_cache(maxsize=None)
 def rule_both_ways(case):
     """``kda_rule`` on bfloat16 operands from an initial state, ``(o, final
@@ -286,14 +330,7 @@ def rule_both_ways(case):
     kernels (in the interpreter) and by the plain form."""
     args = bf16_args(KERNEL_CASES[case](rule_inputs(seed=5, b=1, t=192, h=2, d_k=128, d_v=128)))
     state = 0.1 * jax.random.normal(jax.random.PRNGKey(6), (1, 2, 128, 128))
-    found = []
-    for interpret in (True, False):
-        rule = lambda *a, i=interpret: kda_rule(  # noqa: E731
-            *a, chunk=64, initial_state=state, return_final_state=True, interpret=i
-        )
-        values, pull = jax.jit(lambda *a, rule=rule: jax.vjp(rule, *a))(*args)
-        found.append((values, jax.jit(pull)(weights(values, seed=21))))
-    return found
+    return [run(state, *args) for run in RULES]
 
 
 @pytest.mark.parametrize("case", ["drawn", "at_the_bound", "padded"])
@@ -308,13 +345,16 @@ def test_the_rule_by_the_kernels_is_the_rule_by_the_plain_form(what, case):
     _close(a.astype(jnp.float32), b.astype(jnp.float32), tol=2e-2)
 
 
+_rule_from_zeros = jax.jit(
+    lambda *a: kda_rule(*a, chunk=64, return_final_state=True, interpret=True)
+)
+
+
 @pytest.mark.parametrize("case", ["drawn", "at_the_bound"])
 def test_the_rule_by_the_kernels_equals_the_step_by_step_recurrence(case):
     """Within the limit the plain form's bfloat16 call is held to above."""
     args = bf16_args(KERNEL_CASES[case](rule_inputs(seed=3, b=1, t=128, h=2, d_k=128, d_v=128)))
-    o, state = jax.jit(
-        lambda *a: kda_rule(*a, chunk=64, return_final_state=True, interpret=True)
-    )(*args)
+    o, state = _rule_from_zeros(*args)
     with jax.default_matmul_precision("highest"):
         want_o, want_state = reference.recurrence(*(a.astype(jnp.float32) for a in args))
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
@@ -365,37 +405,50 @@ def two_width_inputs(b=1, h=2, h_kv=2, t=256, d=48, d_v=32, seed=1):
     )
 
 
+@functools.lru_cache(maxsize=None)
+def two_widths_both_ways(fused, h_kv):
+    """``(out, dq, dk, dv)`` by the kernels and by the dense form, once for
+    the four cases that each look at one of them."""
+    from unittest import mock
+
+    q, k, v, w = two_width_inputs(h_kv=h_kv)
+    scale = q.shape[-1] ** -0.5
+    kernels = lambda q, k, v: A._auto(  # noqa: E731
+        q, k, v, True, scale, (64, 128), (128, 64), None
+    )
+    dense = lambda q, k, v: A.attention_reference(q, k, v, causal=True, scale=scale)  # noqa: E731
+    # not fused: a head whose dq no VMEM holds, so dq and dk/dv apart
+    capacity = A._VMEM_V5E if fused else 0
+    with mock.patch.object(A, "_vmem_capacity", lambda: capacity), mock.patch.object(
+        A, "_flash2_bwd_kernel", wraps=A._flash2_bwd_kernel
+    ) as fused_kernel:
+        found = [
+            value_and_gradients(fn, q, k, v, weight=w, argnums=(0, 1, 2))
+            for fn in (kernels, dense)
+        ]
+    assert fused_kernel.called == fused
+    return [(out, *grads) for out, grads in found], (q, k, v)
+
+
 @pytest.mark.parametrize("h_kv", [2, 1], ids=["mha", "gqa"])
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "dq_and_dkv"])
 @pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
-def test_flash2_at_two_widths_equals_dense_attention(monkeypatch, what, fused, h_kv):
-    q, k, v, w = two_width_inputs(h_kv=h_kv)
-    if not fused:
-        monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
-    scale = q.shape[-1] ** -0.5
-    kernels = lambda q, k, v: A._auto(  # noqa: E731
-        q, k, v, True, scale, "flash2", "flash2", (64, 128), (128, 64), None
-    )
-    dense = lambda q, k, v: A.attention_reference(q, k, v, causal=True, scale=scale)  # noqa: E731
-    (got, vjp), (want, ref_vjp) = jax.vjp(kernels, q, k, v), jax.vjp(dense, q, k, v)
-    assert got.shape == (1, 2, 256, 32)
-    if what == "out":
-        _close(got, want, tol=1e-5)
-        return
-    index = ("dq", "dk", "dv").index(what)
-    assert vjp(w)[index].shape == (q, k, v)[index].shape
-    _close(vjp(w)[index], ref_vjp(w)[index], tol=1e-5)
+def test_flash2_at_two_widths_equals_dense_attention(what, fused, h_kv):
+    (got, want), operands = two_widths_both_ways(fused, h_kv)
+    index = ("out", "dq", "dk", "dv").index(what)
+    assert got[0].shape == (1, 2, 256, 32)
+    if index:
+        assert got[index].shape == operands[index - 1].shape
+    _close(got[index], want[index], tol=1e-5)
 
 
-def test_a_two_width_call_never_reaches_the_whole_kv_kernels():
-    for t in (256, 2048, 4096, 8192):
-        assert A._route(t, t, False, True) == ("flash2", "flash2")
-        assert not A._whole_kv_serves(t, t, False, True)
-    assert A._route(2048, 2048, False) == A._route(2048, 2048, False, False) == ("flash", "flash")
+def test_a_two_width_call_through_the_public_name():
+    """`flash_attention` at its own blocks (one a side here) takes values
+    narrower than the keys."""
     q, k, v, _ = two_width_inputs()
-    assert A.flash_attention(q, k, v, causal=True).shape == (1, 2, 256, 32)
-    with pytest.raises(ValueError, match="narrower"):
-        A._auto(q, k, v, True, 1.0, "flash", "flash", None, None, None)
+    got = A.flash_attention(q, k, v, causal=True)
+    assert got.shape == (1, 2, 256, 32)
+    _close(got, A.attention_reference(q, k, v, causal=True), tol=1e-5)
 
 
 # -- the grouped choice ------------------------------------------------------------
@@ -420,7 +473,7 @@ def _layer(held, **overrides):
 @pytest.fixture(scope="module")
 def whole_layer():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, D), jnp.float32)
-    variables = _layer(None).init(jax.random.PRNGKey(2), x)
+    variables = jax.jit(_layer(None).init)(jax.random.PRNGKey(2), x)
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (E,), jnp.float32)
     return x, variables["params"], {"router_bias": bias - jnp.mean(bias)}
 
@@ -486,8 +539,8 @@ def test_groups_that_cannot_hold_the_choice_are_refused(whole_layer, fields):
         _layer(None, **fields).apply({"params": params, "batch_stats": stats}, x)
 
 
-@pytest.mark.parametrize("what", ["value", "gradients"])
-def test_the_expert_layer_equals_a_dense_loop(whole_layer, what):
+@pytest.fixture(scope="module")
+def expert_layer_both_ways(whole_layer):
     x, params, stats = whole_layer
 
     def program(p, x):
@@ -496,15 +549,17 @@ def test_the_expert_layer_equals_a_dense_loop(whole_layer, what):
     def plain(p, x):
         return reference.mixture(LAYER, p, stats["router_bias"], x.reshape(-1, D))[0].reshape(x.shape)
 
+    weight = jax.random.normal(jax.random.PRNGKey(4), x.shape)
     with jax.default_matmul_precision("highest"):
-        if what == "value":
-            _close(program(params, x), plain(params, x), tol=1e-5)
-            return
-        weight = jax.random.normal(jax.random.PRNGKey(4), x.shape)
-        got, want = (
-            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(params, x)
-            for f in (program, plain)
-        )
+        return [value_and_gradients(f, params, x, weight=weight) for f in (program, plain)]
+
+
+@pytest.mark.parametrize("what", ["value", "gradients"])
+def test_the_expert_layer_equals_a_dense_loop(expert_layer_both_ways, what):
+    (value, got), (want_value, want) = expert_layer_both_ways
+    if what == "value":
+        _close(value, want_value, tol=1e-5)
+        return
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
         _close(a, b, tol=1e-4)
 
@@ -552,7 +607,7 @@ MLA = family.latent_spec(TOY)
 def kda_mixer():
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, D), jnp.float32)
     layer = KimiDeltaMixer(SPEC, jnp.float32, TOY["rms_norm_eps"])
-    return layer, shaken(layer.init(jax.random.PRNGKey(6), x)["params"]), x
+    return layer, shaken(jax.jit(layer.init)(jax.random.PRNGKey(6), x)["params"]), x
 
 
 def test_the_kda_mixer_holds_the_published_parameters(kda_mixer):
@@ -570,17 +625,28 @@ def test_the_kda_mixer_holds_the_published_parameters(kda_mixer):
     assert counted == family.kda_mixer_params(toy) + 3 * 4 * h * d + h + h * d + d
 
 
-@pytest.mark.parametrize("what", ["value", "gradients", "inputs"])
-def test_the_kda_mixer_equals_the_reference(kda_mixer, what):
+@pytest.fixture(scope="module")
+def kda_mixer_both_ways(kda_mixer):
     layer, params, x = kda_mixer
     program = lambda p, x: layer.apply({"params": p}, x)  # noqa: E731
     plain = lambda p, x: reference.kda_mixer(TOY, p, x)  # noqa: E731
+    weight = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+    with jax.default_matmul_precision("highest"):
+        return [value_and_gradients(f, params, x, weight=weight) for f in (program, plain)]
+
+
+@pytest.mark.parametrize("what", ["value", "gradients", "inputs"])
+def test_the_kda_mixer_equals_the_reference(kda_mixer, kda_mixer_both_ways, what):
+    layer, params, x = kda_mixer
+    (value, got), (want_value, want) = kda_mixer_both_ways
     with jax.default_matmul_precision("highest"):
         if what == "value":
-            _close(program(params, x), plain(params, x), tol=1e-4)
+            _close(value, want_value, tol=1e-4)
             return
         if what == "inputs":
-            _, sown = layer.apply({"params": params}, x, mutable=["intermediates", "metrics"])
+            _, sown = jax.jit(lambda p, x: layer.apply(
+                {"params": p}, x, mutable=["intermediates", "metrics"]
+            ))(params, x)
             want = reference.rule_inputs(TOY, params, x)
             for a, b in zip(sown["intermediates"]["rule_inputs"][0], want):
                 _close(a, b, tol=1e-5)
@@ -591,11 +657,6 @@ def test_the_kda_mixer_equals_the_reference(kda_mixer, what):
             )
             assert float(sown["metrics"]["kda_state_absmax"][0]) > 0
             return
-        weight = jax.random.normal(jax.random.PRNGKey(8), x.shape)
-        got, want = (
-            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(params, x)
-            for f in (program, plain)
-        )
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
         _close(a, b, tol=1e-3)
@@ -604,7 +665,8 @@ def test_the_kda_mixer_equals_the_reference(kda_mixer, what):
 def test_a_future_step_never_reaches_an_earlier_output(kda_mixer):
     layer, params, x = kda_mixer
     later = x.at[:, 40:].add(1.0)
-    a, b = layer.apply({"params": params}, x), layer.apply({"params": params}, later)
+    apply = jax.jit(lambda x: layer.apply({"params": params}, x))
+    a, b = apply(x), apply(later)
     assert np.array_equal(np.asarray(a[:, :40]), np.asarray(b[:, :40]))
     assert float(jnp.max(jnp.abs(a[:, 40:] - b[:, 40:]))) > 1e-3
 
@@ -614,7 +676,7 @@ def mla_layer():
     x = jax.random.normal(jax.random.PRNGKey(9), (2, 48, D), jnp.float32)
     layer = LatentAttention(4, MLA, jnp.float32, TOY["rms_norm_eps"], float(TOY["rope_theta"]))
     positions = jnp.broadcast_to(jnp.arange(48)[None], (2, 48))
-    return layer, shaken(layer.init(jax.random.PRNGKey(10), x, positions)["params"]), x, positions
+    return layer, shaken(jax.jit(layer.init)(jax.random.PRNGKey(10), x, positions)["params"]), x, positions
 
 
 def test_the_latent_layer_holds_the_published_parameters(mla_layer):
@@ -628,20 +690,22 @@ def test_the_latent_layer_holds_the_published_parameters(mla_layer):
     assert counted == family.mla_mixer_params(TOY)
 
 
-@pytest.mark.parametrize("what", ["value", "gradients"])
-def test_the_latent_layer_equals_the_reference(mla_layer, what):
+@pytest.fixture(scope="module")
+def mla_layer_both_ways(mla_layer):
     layer, params, x, positions = mla_layer
     program = lambda p, x: layer.apply({"params": p}, x, positions)  # noqa: E731
     plain = lambda p, x: reference.mla_mixer(TOY, p, x)  # noqa: E731
+    weight = jax.random.normal(jax.random.PRNGKey(11), x.shape)
     with jax.default_matmul_precision("highest"):
-        if what == "value":
-            _close(program(params, x), plain(params, x), tol=1e-5)
-            return
-        weight = jax.random.normal(jax.random.PRNGKey(11), x.shape)
-        got, want = (
-            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(params, x)
-            for f in (program, plain)
-        )
+        return [value_and_gradients(f, params, x, weight=weight) for f in (program, plain)]
+
+
+@pytest.mark.parametrize("what", ["value", "gradients"])
+def test_the_latent_layer_equals_the_reference(mla_layer_both_ways, what):
+    (value, got), (want_value, want) = mla_layer_both_ways
+    if what == "value":
+        _close(value, want_value, tol=1e-5)
+        return
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
         _close(a, b, tol=1e-4)
@@ -650,9 +714,11 @@ def test_the_latent_layer_equals_the_reference(mla_layer, what):
 def test_the_latent_layers_rotation_is_at_the_specs_base_and_shared_by_the_heads(mla_layer):
     layer, params, x, positions = mla_layer
     other = layer.clone(rope_theta=10000.0)
-    a, b = layer.apply({"params": params}, x, positions), other.apply({"params": params}, x, positions)
+    apply = jax.jit(lambda layer, positions: layer.apply({"params": params}, x, positions),
+                    static_argnums=0)
+    a, b = apply(layer, positions), apply(other, positions)
     assert float(jnp.max(jnp.abs(a - b))) > 1e-4
-    shifted = layer.apply({"params": params}, x, positions + 7)  # relative positions only
+    shifted = apply(layer, positions + 7)  # relative positions only
     _close(shifted, a, tol=1e-4)
 
 

@@ -6,6 +6,7 @@ logits, loss and every gradient, a whole step through ``make_train_step``, the
 gauges it exports, the scopes its compiled step names, what remat saves.
 """
 
+import functools
 import json
 import os
 
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients
 
 from benchmark.families import kda_lm as family
 from benchmark.reference import kda_lm as reference
@@ -68,7 +70,7 @@ def lm_loss(logits, targets):
 def toy_variables():
     lm = toy_lm()
     x, y = toy_batch()
-    variables = lm.init(jax.random.PRNGKey(3), x)
+    variables = jax.jit(lm.init)(jax.random.PRNGKey(3), x)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 8))
 
     def some_bias(a):  # as the rule leaves it: its mean at zero
@@ -93,32 +95,51 @@ def test_the_toy_is_the_published_pattern(toy_variables):
 
 
 @pytest.fixture(scope="module")
-def reference_gradients(toy_variables):
+def reference_outputs(toy_variables):
+    """``(loss, logits, gradients)`` of the plain reference at the toy's batch."""
     params, stats, x, y = toy_variables
+
+    def plain(p):
+        return reference.loss(TOY, p, stats, x, y), reference.forward(TOY, p, stats, x)[0]
+
     with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.grad(lambda p: reference.loss(TOY, p, stats, x, y)))(params)
+        return loss_logits_gradients(plain, params)
+
+
+@pytest.fixture(scope="module")
+def reference_gradients(reference_outputs):
+    return reference_outputs[2]
+
+
+@pytest.fixture(scope="module")
+def program_outputs(toy_variables):
+    """``remat -> (loss, logits, gradients)`` of the toy LM, each computed once."""
+    params, stats, x, y = toy_variables
+
+    @functools.lru_cache(maxsize=None)
+    def outputs(remat):
+        lm = toy_lm(remat=remat)
+
+        def program(p):
+            logits = lm.apply({"params": p, "batch_stats": stats}, x)
+            return lm_loss(logits, y)[0], logits
+
+        with jax.default_matmul_precision("highest"):
+            return loss_logits_gradients(program, params)
+
+    return outputs
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-def test_the_lm_equals_the_plain_reference(toy_variables, reference_gradients, remat, what):
-    params, stats, x, y = toy_variables
-    lm = toy_lm(remat=remat)
-    program = lambda p: lm_loss(  # noqa: E731
-        lm.apply({"params": p, "batch_stats": stats}, x), y
-    )[0]
-    plain = lambda p: reference.loss(TOY, p, stats, x, y)  # noqa: E731
-    with jax.default_matmul_precision("highest"):
-        if what == "logits":
-            _close(
-                lm.apply({"params": params, "batch_stats": stats}, x),
-                reference.forward(TOY, params, stats, x)[0],
-            )
-            return
-        if what == "loss":
-            assert float(program(params)) == pytest.approx(float(plain(params)), rel=1e-5)
-            return
-        got, want = jax.jit(jax.grad(program))(params), reference_gradients
+def test_the_lm_equals_the_plain_reference(program_outputs, reference_outputs, remat, what):
+    (loss, logits, got), (want_loss, want_logits, want) = program_outputs(remat), reference_outputs
+    if what == "logits":
+        _close(logits, want_logits)
+        return
+    if what == "loss":
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+        return
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         name = jax.tree_util.keystr(path)
         assert float(jnp.linalg.norm(b)) > 0, name  # the parameter is in the graph

@@ -8,6 +8,7 @@ its measured tiling does not divide, a ``TransformerLM`` of the published
 pattern against the benchmark's plain reference, and the scopes, instants and
 gauge the model leaves for the tracing."""
 
+import functools
 import importlib
 import json
 import os
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients
 
 from benchmark.families import lfm2_lm as family
 from benchmark.reference import lfm2_lm as reference
@@ -148,7 +150,7 @@ def recurrence(params, x, taps):
 def mixer():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 19, D), jnp.float32)
     layer = ShortConvMixer(ShortConvSpec(taps=3), jnp.float32)
-    return layer, x, layer.init(jax.random.PRNGKey(1), x)["params"]
+    return layer, x, jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
 
 
 def test_mixer_equals_the_step_by_step_recurrence(mixer):
@@ -175,7 +177,7 @@ def test_mixer_gradient_equals_the_recurrences(mixer, leaf):
 
 def test_mixer_initialises_its_taps_as_a_depthwise_conv1d_does():
     layer = ShortConvMixer(ShortConvSpec(taps=3), jnp.float32)
-    taps = layer.init(jax.random.PRNGKey(5), jnp.zeros((1, 4, 512)))["params"]["conv_kernel"]
+    taps = jax.jit(layer.init)(jax.random.PRNGKey(5), jnp.zeros((1, 4, 512)))["params"]["conv_kernel"]
     assert float(jnp.max(jnp.abs(taps))) <= 3 ** -0.5
     assert float(jnp.max(jnp.abs(taps))) > 0.95 * 3 ** -0.5 and abs(float(jnp.mean(taps))) < 0.05
 
@@ -221,11 +223,10 @@ def test_the_familys_rotation_check_tells_the_base(base, passes):
 def test_attention_rotates_at_the_specs_base():
     lm = toy_lm()
     x, _ = toy_batch()
-    params = lm.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(lm.init)(jax.random.PRNGKey(0), x)["params"]
     other = lm.clone(arch=toy_arch(rope_theta=10000.0))
-    a, b = lm.apply({"params": params, "batch_stats": zero_bias(lm, x)}, x), other.apply(
-        {"params": params, "batch_stats": zero_bias(lm, x)}, x
-    )
+    variables = {"params": params, "batch_stats": zero_bias(lm, x)}
+    a, b = jax.jit(lm.apply)(variables, x), jax.jit(other.apply)(variables, x)
     assert float(jnp.max(jnp.abs(a - b))) > 1e-3
 
 
@@ -250,7 +251,7 @@ def _layer(held, **overrides):
 @pytest.fixture(scope="module")
 def whole_layer():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, D), jnp.float32)
-    variables = _layer(None).init(jax.random.PRNGKey(2), x)
+    variables = jax.jit(_layer(None).init)(jax.random.PRNGKey(2), x)
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (E,), jnp.float32)
     return x, variables["params"], {"router_bias": bias - jnp.mean(bias)}
 
@@ -291,7 +292,7 @@ def test_the_renormalisations_epsilon_is_the_specs():
     x = jnp.full((1, 4, D), 1.0)
     for eps, total in ((1e-6, None), (1e-20, 1.0)):
         layer = _layer(None, norm_topk_eps=eps, bias_rate=0.0)
-        params = layer.init(jax.random.PRNGKey(0), x)["params"]
+        params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
         params = dict(params, router={"kernel": jnp.full((D, E), -14.0 / D)})
         params = dict(params, down=jnp.broadcast_to(jnp.eye(F, D)[None], (E, F, D)))
         scores = jax.nn.sigmoid(jnp.float32(-14.0))
@@ -368,12 +369,10 @@ def test_the_tiling_follows_the_shape():
     assert fit(gmm_module.TILING, 4096, 1100, 1300) == (512, 1024, 1024)
 
 
-@pytest.mark.parametrize("shape", ["up", "down"])
-@pytest.mark.parametrize("what", ["value", "d_lhs", "d_rhs"])
-def test_megablox_at_width_1536_with_ragged_and_empty_groups(shape, what):
-    """The three kernels in the interpreter at the tiles the rule gives (768
-    over the 1536, in N for gate/up and in K for down), against a float32
-    loop: groups that end inside a row tile, an empty one, rows past the sum."""
+@functools.lru_cache(maxsize=None)
+def megablox_and_a_loop(shape):
+    """``[(value, d_lhs, d_rhs) by the kernels, by a float32 loop], live`` at a
+    shape, once for the three cases that each look at one of them."""
     k, n = (256, 1536) if shape == "up" else (1536, 256)
     sizes = np.array([0, 130, 513, 7, 0, 250], np.int32)            # sums to 900
     m = 1024
@@ -392,14 +391,23 @@ def test_megablox_at_width_1536_with_ragged_and_empty_groups(shape, what):
         out = jnp.einsum("mk,mkn->mn", lhs[:sizes.sum()], rhs[group])
         return jnp.pad(out, ((0, m - sizes.sum()), (0, 0)))
 
+    def both(fn):
+        out, vjp = jax.vjp(fn, lhs, rhs)
+        return (out, *vjp(dy))
+
     with jax.default_matmul_precision("highest"):
-        got, vjp = jax.vjp(kernels, lhs, rhs)
-        want, ref_vjp = jax.vjp(loop, lhs, rhs)
-        if what == "value":
-            _close(got, want, tol=1e-5)
-            return
-        index = ("d_lhs", "d_rhs").index(what)
-        a, b = vjp(dy)[index], ref_vjp(dy)[index]
+        return [jax.jit(lambda fn=fn: both(fn))() for fn in (kernels, loop)], live
+
+
+@pytest.mark.parametrize("shape", ["up", "down"])
+@pytest.mark.parametrize("what", ["value", "d_lhs", "d_rhs"])
+def test_megablox_at_width_1536_with_ragged_and_empty_groups(shape, what):
+    """The three kernels in the interpreter at the tiles the rule gives (768
+    over the 1536, in N for gate/up and in K for down), against a float32
+    loop: groups that end inside a row tile, an empty one, rows past the sum."""
+    (got, want), live = megablox_and_a_loop(shape)
+    index = ("value", "d_lhs", "d_rhs").index(what)
+    a, b = got[index], want[index]
     if what == "d_lhs":
         a = jnp.where(live, a, 0.0)  # rows past the sum hold whatever was there
     _close(a, b, tol=1e-5)
@@ -443,7 +451,7 @@ def toy_batch(seed=0, b=2):
 
 
 def zero_bias(lm, x):
-    return jax.tree.map(jnp.zeros_like, lm.init(jax.random.PRNGKey(0), x)["batch_stats"])
+    return jax.tree.map(jnp.zeros_like, jax.jit(lm.init)(jax.random.PRNGKey(0), x)["batch_stats"])
 
 
 def lm_loss(logits, targets):
@@ -456,7 +464,7 @@ def lm_loss(logits, targets):
 def toy_variables():
     lm = toy_lm()
     x, y = toy_batch()
-    variables = lm.init(jax.random.PRNGKey(3), x)
+    variables = jax.jit(lm.init)(jax.random.PRNGKey(3), x)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 8))
 
     def some_bias(a):  # as the rule leaves it: its mean at zero
@@ -479,26 +487,43 @@ def test_the_toy_is_the_published_pattern(toy_variables):
     assert "lm_head" not in params                                        # a tied head
 
 
+@pytest.fixture(scope="module")
+def lm_and_reference(toy_variables):
+    """``remat -> [(loss, logits, gradients) of the toy LM, of the plain
+    reference]`` at one batch, once for the cases that each look at one."""
+    params, stats, x, y = toy_variables
+
+    def plain(p):
+        return reference.loss(TOY, p, stats, x, y), reference.forward(TOY, p, stats, x)[0]
+
+    @functools.lru_cache(maxsize=None)
+    def outputs(fn):
+        with jax.default_matmul_precision("highest"):
+            return loss_logits_gradients(fn, params)
+
+    @functools.lru_cache(maxsize=None)
+    def program(remat):
+        lm = toy_lm(remat=remat)
+
+        def fn(p):
+            logits = lm.apply({"params": p, "batch_stats": stats}, x)
+            return lm_loss(logits, y)[0], logits
+
+        return fn
+
+    return lambda remat: (outputs(program(remat)), outputs(plain))
+
+
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-def test_the_lm_equals_the_plain_reference(toy_variables, remat, what):
-    params, stats, x, y = toy_variables
-    lm = toy_lm(remat=remat)
-    program = lambda p: lm_loss(  # noqa: E731
-        lm.apply({"params": p, "batch_stats": stats}, x), y
-    )[0]
-    plain = lambda p: reference.loss(TOY, p, stats, x, y)  # noqa: E731
-    with jax.default_matmul_precision("highest"):
-        if what == "logits":
-            _close(
-                lm.apply({"params": params, "batch_stats": stats}, x),
-                reference.forward(TOY, params, stats, x)[0],
-            )
-            return
-        if what == "loss":
-            assert float(program(params)) == pytest.approx(float(plain(params)), rel=1e-5)
-            return
-        got, want = jax.grad(program)(params), jax.grad(plain)(params)
+def test_the_lm_equals_the_plain_reference(lm_and_reference, remat, what):
+    (loss, logits, got), (want_loss, want_logits, want) = lm_and_reference(remat)
+    if what == "logits":
+        _close(logits, want_logits)
+        return
+    if what == "loss":
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+        return
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         name = jax.tree_util.keystr(path)
         assert float(jnp.linalg.norm(b)) > 0, name  # the parameter is in the graph
@@ -584,14 +609,18 @@ def test_the_lm_trains_through_the_step_and_exports_the_bias_gauge():
     assert "edl_train_moe_bias_absmax " in obs_metrics.default_registry().render()
 
 
-@pytest.mark.parametrize("scope", SCONV_SCOPES)
-def test_the_compiled_step_names_the_mixers_scopes(scope):
+@functools.lru_cache(maxsize=None)
+def compiled_steps_scopes():
     lm = toy_lm(remat=True, dtype=jnp.bfloat16)
     x, y = toy_batch(b=1)
     state = create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
     compiled = make_train_step(lm_loss, numerics=False).lower(state, (x, y)).compile()
-    table = obs_profile.scopes_of_hlo(compiled.as_text(), SCONV_SCOPES)
-    assert scope in set(table.values())
+    return set(obs_profile.scopes_of_hlo(compiled.as_text(), SCONV_SCOPES).values())
+
+
+@pytest.mark.parametrize("scope", SCONV_SCOPES)
+def test_the_compiled_step_names_the_mixers_scopes(scope):
+    assert scope in compiled_steps_scopes()
 
 
 def test_each_traced_shape_leaves_one_sconv_shape_instant():

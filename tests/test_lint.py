@@ -1453,6 +1453,34 @@ class TestRepoConformance:
         ]
         assert not reads, reads
 
+    def test_attention_takes_no_argument_that_names_an_implementation(self):
+        """``ops/attention.py`` holds one kernel family: which kernels a call
+        runs follows from its shapes, so no function there has a parameter
+        through which a second family could be chosen."""
+        import ast
+
+        from edl_tpu.analysis import repo_context
+
+        (mod,) = [
+            m for m in repo_context().modules
+            if m.relpath == "edl_tpu/ops/attention.py"
+        ]
+        functions = [
+            node for node in ast.walk(mod.tree)
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda))
+        ]
+        assert len(functions) > 40
+        named = [
+            "%s:%d %s" % (mod.relpath, node.lineno, arg.arg)
+            for node in functions
+            for arg in (
+                node.args.posonlyargs + node.args.args + node.args.kwonlyargs
+                + [a for a in (node.args.vararg, node.args.kwarg) if a]
+            )
+            if arg.arg in ("impl", "implementation") or arg.arg.endswith("_impl")
+        ]
+        assert not named, named
+
 
 # -- baseline semantics -------------------------------------------------------
 

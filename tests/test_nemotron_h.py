@@ -10,6 +10,7 @@ parent's, the sharding rules on the new leaves, and every matmul of the compiled
 step under a part of ``STEP_PARTS``.
 """
 
+import functools
 import hashlib
 import json
 import os
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients, value_and_gradients
 
 from benchmark.families import nemotron_h_lm as family
 from benchmark.reference import nemotron_h_lm as reference
@@ -86,7 +88,7 @@ def lm_loss(logits, targets):
 def toy_variables():
     lm = toy_lm()
     x, y = toy_batch()
-    variables = lm.init(jax.random.PRNGKey(3), x)
+    variables = jax.jit(lm.init)(jax.random.PRNGKey(3), x)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 8))
 
     def some_bias(a):  # as the rule leaves it: its mean at zero
@@ -116,34 +118,51 @@ def test_the_toy_is_blocks_of_one_branch_each(toy_variables):
 
 
 @pytest.fixture(scope="module")
-def reference_gradients(toy_variables):
+def reference_outputs(toy_variables):
+    """``(loss, logits, gradients)`` of the plain reference at the toy's batch."""
     params, stats, x, y = toy_variables
+
+    def plain(p):
+        return reference.loss(TOY, p, stats, x, y), reference.forward(TOY, p, stats, x)[0]
+
     with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.grad(lambda p: reference.loss(TOY, p, stats, x, y)))(params)
+        return loss_logits_gradients(plain, params)
+
+
+@pytest.fixture(scope="module")
+def reference_gradients(reference_outputs):
+    return reference_outputs[2]
+
+
+@pytest.fixture(scope="module")
+def program_outputs(toy_variables):
+    """``remat -> (loss, logits, gradients)`` of the toy LM, each computed once."""
+    params, stats, x, y = toy_variables
+
+    @functools.lru_cache(maxsize=None)
+    def outputs(remat):
+        lm = toy_lm(remat=remat)
+
+        def program(p):
+            logits = lm.apply({"params": p, "batch_stats": stats}, x)
+            return lm_loss(logits, y)[0], logits
+
+        with jax.default_matmul_precision("highest"):
+            return loss_logits_gradients(program, params)
+
+    return outputs
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-def test_the_one_branch_lm_equals_the_plain_reference(
-    toy_variables, reference_gradients, remat, what
-):
-    params, stats, x, y = toy_variables
-    lm = toy_lm(remat=remat)
-    program = lambda p: lm_loss(  # noqa: E731
-        lm.apply({"params": p, "batch_stats": stats}, x), y
-    )[0]
-    plain = lambda p: reference.loss(TOY, p, stats, x, y)  # noqa: E731
-    with jax.default_matmul_precision("highest"):
-        if what == "logits":
-            _close(
-                lm.apply({"params": params, "batch_stats": stats}, x),
-                reference.forward(TOY, params, stats, x)[0],
-            )
-            return
-        if what == "loss":
-            assert float(program(params)) == pytest.approx(float(plain(params)), rel=1e-5)
-            return
-        got, want = jax.jit(jax.grad(program))(params), reference_gradients
+def test_the_one_branch_lm_equals_the_plain_reference(program_outputs, reference_outputs, remat, what):
+    (loss, logits, got), (want_loss, want_logits, want) = program_outputs(remat), reference_outputs
+    if what == "logits":
+        _close(logits, want_logits)
+        return
+    if what == "loss":
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+        return
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         name = jax.tree_util.keystr(path)
         assert float(jnp.linalg.norm(b)) > 0, name  # the parameter is in the graph
@@ -228,7 +247,7 @@ def layer_config(held):
 @pytest.fixture(scope="module")
 def whole_layer():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32), jnp.float32)
-    variables = latent_layer().init(jax.random.PRNGKey(1), x)
+    variables = jax.jit(latent_layer().init)(jax.random.PRNGKey(1), x)
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(2), (E,))
     return variables["params"], {"router_bias": bias - jnp.mean(bias)}, x
 
@@ -252,18 +271,16 @@ def test_the_latent_layer_equals_a_plain_loop_over_its_experts(whole_layer, held
     weigh = jax.random.normal(jax.random.PRNGKey(5), x.shape)
 
     def program(p):
-        return jnp.sum(layer.apply({"params": p, "batch_stats": stats}, x) * weigh)
+        return layer.apply({"params": p, "batch_stats": stats}, x)
 
     def plain(p):
-        y, _ = reference.mixture(config, p, stats["router_bias"], tokens)
-        return jnp.sum(y.reshape(x.shape) * weigh)
+        return reference.mixture(config, p, stats["router_bias"], tokens)[0].reshape(x.shape)
 
     with jax.default_matmul_precision("highest"):
-        _close(
-            layer.apply({"params": params, "batch_stats": stats}, x).reshape(tokens.shape),
-            reference.mixture(config, params, stats["router_bias"], tokens)[0],
+        (value, got), (want_value, want) = (
+            value_and_gradients(fn, params, weight=weigh, argnums=0) for fn in (program, plain)
         )
-        got, want = jax.grad(program)(params), jax.grad(plain)(params)
+    _close(value, want_value)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
         _close(a, b, tol=1e-3)
@@ -319,7 +336,7 @@ def lowered_digest(form):
     ``DroplessMoE``'s value and gradients, in the form ``FORMS[form]``."""
     layer = DroplessMoE(num_experts=8, top_k=2, d_ff=24, dtype=jnp.float32, **FORMS[form])
     x = jnp.zeros((2, 16, 32), jnp.float32)
-    variables = layer.init(jax.random.PRNGKey(0), x)
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
 
     def value_and_gradients(v, x):
         def loss(p):
@@ -387,7 +404,7 @@ def mamba_layer(heads=HEADS, groups=GROUPS):
 @pytest.fixture(scope="module")
 def grouped_mixer():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 48, 32), jnp.float32)
-    params = shaken(mamba_layer().init(jax.random.PRNGKey(1), x)["params"], seed=3)
+    params = shaken(jax.jit(mamba_layer().init)(jax.random.PRNGKey(1), x)["params"], seed=3)
     return params, x
 
 
@@ -438,14 +455,13 @@ def test_the_two_halves_of_the_heads_concatenate_to_the_whole_before_w_out(group
     params, x = grouped_mixer
     gated = lambda left: left["intermediates"]["gated"][0]  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        whole_out, whole = mamba_layer().apply({"params": params}, x, mutable=["intermediates"])
-        halves = [
-            mamba_layer(HEADS // 2, GROUPS // 2).apply(
-                {"params": half_of(params, half)}, x, mutable=["intermediates"]
-            )
-            for half in (0, 1)
-        ]
-        before_w_out = reference.mamba_inner(mamba_config(), params, x)
+        sown = lambda layer: jax.jit(  # noqa: E731
+            lambda p: layer.apply({"params": p}, x, mutable=["intermediates"])
+        )
+        whole_out, whole = sown(mamba_layer())(params)
+        half_layer = sown(mamba_layer(HEADS // 2, GROUPS // 2))
+        halves = [half_layer(half_of(params, half)) for half in (0, 1)]
+        before_w_out = jax.jit(lambda p: reference.mamba_inner(mamba_config(), p, x))(params)
     together = jnp.concatenate([gated(left) for _, left in halves], axis=-1)
     _close(together, gated(whole), tol=1e-5)
     _close(together, before_w_out)
@@ -456,13 +472,12 @@ def test_one_group_is_the_mixer_it_was():
     """``n_groups = 1``: the norm over all ``d_inner``, as ``reference/ssm_lm.py``
     (Granite's) writes it."""
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 40, 32), jnp.float32)
-    params = shaken(mamba_layer(groups=1).init(jax.random.PRNGKey(1), x)["params"])
+    params = shaken(jax.jit(mamba_layer(groups=1).init)(jax.random.PRNGKey(1), x)["params"])
+    config = {"mamba_n_heads": HEADS, "mamba_d_head": P, "mamba_n_groups": 1,
+              "mamba_d_state": N, "mamba_conv_bias": True, "rms_norm_eps": 1e-5}
     with jax.default_matmul_precision("highest"):
-        got = mamba_layer(groups=1).apply({"params": params}, x)
-        want = ssm_reference.mamba_mixer(
-            {"mamba_n_heads": HEADS, "mamba_d_head": P, "mamba_n_groups": 1,
-             "mamba_d_state": N, "mamba_conv_bias": True, "rms_norm_eps": 1e-5}, params, x,
-        )
+        got = jax.jit(mamba_layer(groups=1).apply)({"params": params}, x)
+        want = jax.jit(lambda p: ssm_reference.mamba_mixer(config, p, x))(params)
     _close(got, want)
 
 
@@ -483,12 +498,12 @@ def one_branch_lm(kinds, **changes):
 def test_a_dense_feed_forward_can_be_a_blocks_one_branch():
     lm = one_branch_lm(("attention", "mlp", "mamba", "moe"), dtype=jnp.float32)
     x = np.zeros((1, 16), np.int32)
-    params = lm.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(lm.init)(jax.random.PRNGKey(0), x)["params"]
     assert [set(params["layer_%d" % i]) - {"ln1"} for i in range(4)] == [
         {"attn"}, {"mlp"}, {"mamba"}, {"moe"}
     ]
     assert set(params["layer_1"]["mlp"]) == {"gate", "up", "down"}       # the SwiGLU of d_ff
-    assert lm.apply({"params": params}, x).shape == (1, 16, 64)
+    assert jax.jit(lm.apply)({"params": params}, x).shape == (1, 16, 64)
 
 
 @pytest.mark.parametrize("kind", ["mamba", "attention", "moe", "mlp"])
@@ -513,7 +528,7 @@ def test_the_rules_accept_the_new_leaves():
     the tensor-parallel rules take a one-branch dense block's SwiGLU and the
     attention block and have nothing to say of a Mamba-2 block's leaves."""
     lm = one_branch_lm(("mamba", "moe", "attention", "mlp"))
-    params = lm.init(jax.random.PRNGKey(0), np.zeros((1, 16), np.int32))["params"]
+    params = jax.jit(lm.init)(jax.random.PRNGKey(0), np.zeros((1, 16), np.int32))["params"]
     rules = TRANSFORMER_TP_RULES + MOE_EP_RULES
     from edl_tpu.parallel.mesh import make_mesh
 

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients, value_and_gradients
 
 from benchmark.reference import gdn_lm as reference
 from edl_tpu.models import ArchSpec, GatedDeltaMixer, GatedDeltaSpec, TransformerLM
@@ -118,14 +119,25 @@ def test_rule_equals_the_recurrence(case):
     np.testing.assert_allclose(got_state, want_state, rtol=2e-5, atol=2e-5)
 
 
+@functools.lru_cache(maxsize=None)
+def rule_gradients(chunk):
+    """The five gradients of the rule at a chunk (``None``: the recurrence's),
+    once for the cases that each look at one."""
+    args = rule_inputs(seed=1, t=80)                # 80: neither chunk divides it
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, 80, 3, 16))
+    if chunk is None:
+        fn = lambda *a: jnp.sum(reference.recurrence(*a)[0] * w)  # noqa: E731
+    else:
+        fn = lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk) * w)  # noqa: E731
+    return jax.jit(jax.grad(fn, range(5)))(*args)
+
+
 @pytest.mark.parametrize("chunk", [16, 64])
 @pytest.mark.parametrize("wrt", range(5), ids=["q", "k", "v", "g", "beta"])
 def test_rule_gradients_equal_the_recurrences(wrt, chunk):
-    args = rule_inputs(seed=1, t=80)                # 80: neither chunk divides it
-    w = jax.random.normal(jax.random.PRNGKey(9), (2, 80, 3, 16))
-    got = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk) * w), wrt)(*args)
-    want = jax.grad(lambda *a: jnp.sum(reference.recurrence(*a)[0] * w), wrt)(*args)
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(
+        rule_gradients(chunk)[wrt], rule_gradients(None)[wrt], rtol=2e-4, atol=2e-4
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -311,10 +323,26 @@ def test_causal_conv_silu_without_a_bias_at_offset_zero_in_the_kernels(what):
 # -- the mixer -------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
 def toy_mixer():
     mixer = GatedDeltaMixer(SPEC, jnp.float32, 1e-6)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 48))
-    return mixer, shaken(mixer.init(jax.random.PRNGKey(1), x)["params"]), x
+    return mixer, shaken(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"]), x
+
+
+@functools.lru_cache(maxsize=None)
+def mixer_both_ways():
+    """``(value, gradients)`` of the mixer and of the sequential reference
+    under one cotangent, once for the cases that each look at one leaf."""
+    mixer, params, x = toy_mixer()
+    w = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    return [
+        value_and_gradients(fn, params, weight=w, argnums=0)
+        for fn in (
+            lambda p: mixer.apply({"params": p}, x),
+            lambda p: reference.linear_attention_mixer(TOY, p, x),
+        )
+    ]
 
 
 def test_mixer_equals_the_sequential_reference():
@@ -322,10 +350,8 @@ def test_mixer_equals_the_sequential_reference():
     assert params["in_proj"]["kernel"].shape == (48, 24 + 24 + 48 + 48 + 3 + 3)
     assert params["conv_kernel"].shape == (4, 96) and "conv_bias" not in params
     assert params["norm"].shape == (16,) and params["out_proj"]["kernel"].shape == (48, 48)
-    got = mixer.apply({"params": params}, x)
-    np.testing.assert_allclose(
-        got, reference.linear_attention_mixer(TOY, params, x), rtol=2e-4, atol=2e-5
-    )
+    (got, _), (want, _) = mixer_both_ways()
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 MIXER_LEAVES = ["in_proj", "conv_kernel", "A_log", "dt_bias", "norm", "out_proj"]
@@ -333,12 +359,7 @@ MIXER_LEAVES = ["in_proj", "conv_kernel", "A_log", "dt_bias", "norm", "out_proj"
 
 @pytest.mark.parametrize("leaf", MIXER_LEAVES)
 def test_mixer_gradient_equals_the_references(leaf):
-    mixer, params, x = toy_mixer()
-    w = jax.random.normal(jax.random.PRNGKey(7), x.shape)
-    got = jax.grad(lambda p: jnp.sum(mixer.apply({"params": p}, x) * w))(params)
-    want = jax.grad(
-        lambda p: jnp.sum(reference.linear_attention_mixer(TOY, p, x) * w)
-    )(params)
+    (_, got), (_, want) = mixer_both_ways()
     for a, b in zip(jax.tree.leaves(got[leaf]), jax.tree.leaves(want[leaf])):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5)
 
@@ -353,9 +374,9 @@ def test_two_chips_shares_of_the_heads_add_up_to_the_whole_layer():
     spec = GatedDeltaSpec(num_heads=4, key_dim=8, value_dim=16, chunk=8)
     mixer = GatedDeltaMixer(spec, jnp.float32, 1e-6)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 48))
-    params = shaken(mixer.init(jax.random.PRNGKey(1), x)["params"])
+    params = shaken(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"])
     whole = dict(TOY, linear_num_key_heads=4, linear_num_value_heads=4)
-    want = reference.linear_attention_mixer(whole, params, x)
+    want = jax.jit(lambda p: reference.linear_attention_mixer(whole, p, x))(params)
 
     def share(first, count):
         heads = np.arange(first, first + count)
@@ -376,19 +397,20 @@ def test_two_chips_shares_of_the_heads_add_up_to_the_whole_layer():
         }
 
     half = dict(TOY, linear_num_key_heads=2, linear_num_value_heads=2)
-    parts = [reference.linear_attention_mixer(half, share(first, 2), x) for first in (0, 2)]
+    halved = jax.jit(lambda p: reference.linear_attention_mixer(half, p, x))
+    parts = [halved(share(first, 2)) for first in (0, 2)]
     np.testing.assert_allclose(parts[0] + parts[1], want, rtol=2e-5, atol=2e-5)
     assert float(jnp.max(jnp.abs(parts[0] - want))) > 0.1     # and neither is the whole
     # the program's mixer on a share is the reference's on it
     held = GatedDeltaMixer(GatedDeltaSpec(2, 8, 16, chunk=8), jnp.float32, 1e-6)
     np.testing.assert_allclose(
-        held.apply({"params": share(0, 2)}, x), parts[0], rtol=2e-4, atol=2e-5
+        jax.jit(held.apply)({"params": share(0, 2)}, x), parts[0], rtol=2e-4, atol=2e-5
     )
 
 
 def test_mixer_initialises_as_its_source_does():
     mixer = GatedDeltaMixer(GatedDeltaSpec(64, 8, 16), jnp.float32)
-    params = mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 32)))["params"]
+    params = jax.jit(mixer.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8, 32)))["params"]
     rate = np.exp(np.asarray(params["A_log"]))
     assert 0 < rate.min() < 2 and 14 < rate.max() <= 16          # U(0, 16)
     step = np.log1p(np.exp(np.asarray(params["dt_bias"])))       # softplus
@@ -403,8 +425,10 @@ def test_without_neg_eigval_beta_stays_under_one():
     for neg, top in ((True, 2.0), (False, 1.0)):
         spec = GatedDeltaSpec(3, 8, 16, chunk=8, neg_eigval=neg)
         mixer = GatedDeltaMixer(spec, jnp.float32)
-        params = shaken(mixer.init(jax.random.PRNGKey(1), x)["params"])
-        _, sown = mixer.apply({"params": params}, x, mutable=["intermediates"])
+        params = shaken(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"])
+        _, sown = jax.jit(
+            lambda p, x, mixer=mixer: mixer.apply({"params": p}, x, mutable=["intermediates"])
+        )(params, x)
         beta = sown["intermediates"]["rule_inputs"][0][4]
         assert top / 2 < float(beta.max()) < top and float(beta.min()) > 0
 
@@ -412,27 +436,38 @@ def test_without_neg_eigval_beta_stays_under_one():
 # -- the model -------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-def test_hybrid_lm_equals_the_plain_reference(remat, what):
+@functools.lru_cache(maxsize=None)
+def lm_and_reference(remat):
+    """``(loss, logits, gradients)`` of the toy LM and of the plain reference
+    at one batch, once for the three cases that each look at one of them."""
     lm = toy_lm(remat=remat)
     x, y = toy_batch()
-    params = shaken(lm.init(jax.random.PRNGKey(3), x)["params"])
+    params = shaken(jax.jit(lm.init)(jax.random.PRNGKey(3), x)["params"])
     assert set(params["layer_0"]) == {"gdn", "mlp", "ln1_post", "ln2_post"}
     assert set(params["layer_1"]) == {"attn", "mlp", "ln1_post", "ln2_post"}
     assert params["layer_1"]["attn"]["q_norm"]["scale"].shape == (48,)
-    program = lambda p: lm_loss(lm.apply({"params": p}, x), y)[0]  # noqa: E731
-    plain = lambda p: reference.loss(reference.forward(TOY, p, x), y)  # noqa: E731
+
+    def program(p):
+        logits = lm.apply({"params": p}, x)
+        return lm_loss(logits, y)[0], logits
+
+    def plain(p):
+        logits = reference.forward(TOY, p, x)
+        return reference.loss(logits, y), logits
+
+    return [loss_logits_gradients(fn, params) for fn in (program, plain)]
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
+def test_hybrid_lm_equals_the_plain_reference(remat, what):
+    (loss, logits, got), (want_loss, want_logits, want) = lm_and_reference(remat)
     if what == "logits":
-        np.testing.assert_allclose(
-            lm.apply({"params": params}, x), reference.forward(TOY, params, x),
-            rtol=2e-4, atol=2e-5,
-        )
+        np.testing.assert_allclose(logits, want_logits, rtol=2e-4, atol=2e-5)
         return
     if what == "loss":
-        assert float(program(params)) == pytest.approx(float(plain(params)), rel=1e-5)
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
         return
-    got, want = jax.grad(program)(params), jax.grad(plain)(params)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(
             a, b, rtol=1e-3, atol=1e-5, err_msg=jax.tree_util.keystr(path)
@@ -490,7 +525,7 @@ def test_a_blocks_norms_sit_where_the_one_field_says(placement):
     block = Block(4, 40, jnp.float32, arch=arch, mixer="linear_attention")
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 24, 48)) * 3.0
     positions = jnp.arange(24)[None]
-    params = shaken(block.init(jax.random.PRNGKey(1), x, positions)["params"])
+    params = shaken(jax.jit(block.init)(jax.random.PRNGKey(1), x, positions)["params"])
     assert {k for k in params if k.startswith("ln")} == names
     norm = lambda name: lambda v: reference._rms_norm(  # noqa: E731
         v, params[name]["scale"], 1e-6
@@ -550,11 +585,15 @@ def test_the_hybrid_trains_through_the_step_and_exports_its_three_gauges():
         assert "edl_train_%s " % name in text
 
 
-@pytest.mark.parametrize("scope", GDN_SCOPES)
-def test_the_compiled_step_names_the_mixers_scopes(scope):
+@functools.lru_cache(maxsize=None)
+def compiled_steps_scopes():
     lm = toy_lm(dtype=jnp.bfloat16, remat=True)
     x, y = toy_batch(b=1)
     state = create_state(lm, jax.random.PRNGKey(0), x, optax.adamw(1e-3))
     compiled = make_train_step(lm_loss, numerics=False).lower(state, (x, y)).compile()
-    table = obs_profile.scopes_of_hlo(compiled.as_text(), GDN_SCOPES)
-    assert scope in set(table.values())
+    return set(obs_profile.scopes_of_hlo(compiled.as_text(), GDN_SCOPES).values())
+
+
+@pytest.mark.parametrize("scope", GDN_SCOPES)
+def test_the_compiled_step_names_the_mixers_scopes(scope):
+    assert scope in compiled_steps_scopes()

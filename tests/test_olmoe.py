@@ -4,6 +4,7 @@ terms against hand arithmetic, an OLMoE-shaped ``TransformerLM`` against the
 benchmark's plain reference, and the trainer finding what the model sows with
 no flag from its caller."""
 
+import functools
 import hashlib
 import os
 
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients
 from jax.interpreters import partial_eval as pe
 
 from benchmark.families.transformer_lm import LOGITS_REL_TOL
@@ -57,17 +59,19 @@ def test_layer_equals_a_dense_mixture(k, e, norm):
     layer = DroplessMoE(num_experts=e, top_k=k, d_ff=24, norm_topk_prob=norm,
                         dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(k * 10 + e), (2, 13, D))
-    params = layer.init(jax.random.PRNGKey(1), x)["params"]
-    np.testing.assert_allclose(
-        layer.apply({"params": params}, x), dense_mixture(params, x, k, norm),
-        atol=2e-6,
-    )
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
 
-    def objective(fn):
-        return lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
+    def value_and_grads(fn):  # one jitted program a side
+        def objective(p, x):
+            y = fn(p, x)
+            return jnp.sum(jnp.sin(y)), y
 
-    got = jax.grad(objective(lambda p, x: layer.apply({"params": p}, x)), (0, 1))(params, x)
-    want = jax.grad(objective(lambda p, x: dense_mixture(p, x, k, norm)), (0, 1))(params, x)
+        (_, y), grads = jax.jit(jax.value_and_grad(objective, (0, 1), has_aux=True))(params, x)
+        return y, grads
+
+    value, got = value_and_grads(lambda p, x: layer.apply({"params": p}, x))
+    want_value, want = value_and_grads(lambda p, x: dense_mixture(p, x, k, norm))
+    np.testing.assert_allclose(value, want_value, atol=2e-6)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
 
@@ -77,17 +81,20 @@ def test_no_token_is_dropped_when_one_expert_takes_them_all(k):
     e = 8
     layer = DroplessMoE(num_experts=e, top_k=k, d_ff=24, dtype=jnp.float32)
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (2, 32, D))) + 0.1
-    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
     # positive inputs and a router whose first k columns tower over the rest
     router = jnp.zeros((D, e)).at[:, :k].set(
         5.0 * (k - jnp.arange(k, dtype=jnp.float32))
     )
     params = {**params, "router": {"kernel": router}}
-    y, sown = layer.apply({"params": params}, x, mutable=["metrics", "intermediates"])
+    y, sown = jax.jit(lambda p: layer.apply(
+        {"params": p}, x, mutable=["metrics", "intermediates"]
+    ))(params)
     chosen = sown["intermediates"]["top_idx"][0]
     assert set(np.unique(chosen)) == set(range(k))     # all 64 tokens, k experts
     assert float(sown["metrics"]["moe_load_max"][0]) == pytest.approx(e / k)
-    np.testing.assert_allclose(y, dense_mixture(params, x, k, False), atol=2e-6)
+    want = jax.jit(lambda p: dense_mixture(p, x, k, False))(params)
+    np.testing.assert_allclose(y, want, atol=2e-6)
     assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0  # every token got an answer
 
 
@@ -99,13 +106,13 @@ def test_router_gradient_of_a_bfloat16_layer_equals_the_float32_mixture():
     k, e = 8, 64
     layer = DroplessMoE(num_experts=e, top_k=k, d_ff=24, dtype=jnp.bfloat16)
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 32, D))
-    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)["params"]
 
     def router_grad(fn):
         def objective(kernel):
             p = {**params, "router": {"kernel": kernel}}
             return jnp.sum(jnp.sin(fn(p, x)))
-        return jax.grad(objective)(params["router"]["kernel"])
+        return jax.jit(jax.grad(objective))(params["router"]["kernel"])
 
     got = router_grad(lambda p, x: layer.apply({"params": p}, x))
     want = router_grad(lambda p, x: dense_mixture(p, x, k, False))
@@ -161,7 +168,7 @@ def test_auxiliary_terms_by_hand(term):
     layer = DroplessMoE(num_experts=4, top_k=2, d_ff=8, aux_weight=alpha,
                         z_weight=beta, dtype=jnp.float32)
     x = jnp.eye(3, D)[None]                       # token t is unit vector t
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
     router = jnp.zeros((D, 4)).at[:3].set(HAND_LOGITS)
     params = {**params, "router": {"kernel": router}}
     _, sown = layer.apply({"params": params}, x, mutable=["losses"])
@@ -206,13 +213,14 @@ def toy_batch(seed=0, b=4, t=16):
     return tokens[:, :-1], tokens[:, 1:]
 
 
-@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-@pytest.mark.parametrize("layers", [1, 2])
-def test_olmoe_shaped_lm_equals_the_plain_reference(layers, what):
+@functools.lru_cache(maxsize=None)
+def program_and_reference(layers):
+    """``(loss, logits, gradients)`` of the toy LM and of the plain reference
+    at one batch, computed once for the cases that each look at one of them."""
     lm = toy_lm(layers)
     config = dict(TOY, num_hidden_layers=layers)
     x, y = toy_batch()
-    params = lm.init(jax.random.PRNGKey(3), x)["params"]
+    params = jax.jit(lm.init)(jax.random.PRNGKey(3), x)["params"]
     # scales away from 1 so that a norm applied in the wrong place shows
     params = jax.tree.map(
         lambda p: p * 1.5 if p.ndim == 1 else p, params
@@ -223,25 +231,29 @@ def test_olmoe_shaped_lm_equals_the_plain_reference(layers, what):
         extra = sum(jnp.sum(v) for v in jax.tree.leaves(sown["losses"]))
         return lm_loss(logits, y)[0] + extra, logits
 
+    def plain(params):
+        return reference.loss(config, params, x, y), reference.forward(config, params, x)[0]
+
     with jax.default_matmul_precision("highest"):
-        if what == "logits":
+        return [loss_logits_gradients(fn, params) for fn in (program, plain)]
+
+
+@pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
+@pytest.mark.parametrize("layers", [1, 2])
+def test_olmoe_shaped_lm_equals_the_plain_reference(layers, what):
+    (loss, logits, got), (want_loss, want_logits, want) = program_and_reference(layers)
+    if what == "logits":
+        np.testing.assert_allclose(logits, want_logits, atol=2e-5)
+    elif what == "loss":
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    else:
+        flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(want):
             np.testing.assert_allclose(
-                program(params)[1], reference.forward(config, params, x)[0], atol=2e-5
+                flat_got[path], leaf, atol=2e-6, err_msg=str(path)
             )
-        elif what == "loss":
-            assert float(program(params)[0]) == pytest.approx(
-                float(reference.loss(config, params, x, y)), rel=1e-6
-            )
-        else:
-            got = jax.grad(lambda p: program(p)[0])(params)
-            want = jax.grad(lambda p: reference.loss(config, p, x, y))(params)
-            flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
-            for path, leaf in jax.tree_util.tree_leaves_with_path(want):
-                np.testing.assert_allclose(
-                    flat_got[path], leaf, atol=2e-6, err_msg=str(path)
-                )
-            router = got["layer_0"]["moe"]["router"]["kernel"]
-            assert float(jnp.max(jnp.abs(router))) > 0
+        router = got["layer_0"]["moe"]["router"]["kernel"]
+        assert float(jnp.max(jnp.abs(router))) > 0
 
 
 def zero_loss(logits, y):
@@ -334,7 +346,7 @@ def test_two_dp_devices_agree_with_one():
 
 
 def test_ep_rules_name_the_dropless_banks():
-    params = toy_lm(1).init(jax.random.PRNGKey(0), toy_batch()[0])["params"]
+    params = jax.jit(toy_lm(1).init)(jax.random.PRNGKey(0), toy_batch()[0])["params"]
     for bank in ("gate", "up", "down"):
         assert spec_for_path("layer_0/moe/" + bank, MOE_EP_RULES)[0] == "ep"
         assert params["layer_0"]["moe"][bank].shape[0] == 8
@@ -375,7 +387,7 @@ def test_the_backward_reruns_no_down_projection_and_unsorts_no_rows(k, norm):
     lm = toy_lm(1, dtype=jnp.bfloat16, remat=True, top_k=k, norm=norm)
     assert lm.remat_policy == "save_flash"
     x, y = toy_batch()
-    params = lm.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(lm.init)(jax.random.PRNGKey(0), x)["params"]
 
     def loss(params):
         logits, sown = lm.apply({"params": params}, x, mutable=["losses"])

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import loss_logits_gradients, value_and_gradients
 
 from benchmark.families import solar_lm as family
 from benchmark.reference import solar_lm as reference
@@ -253,7 +254,7 @@ def solar_mixer(heads=H, dtype=jnp.float32):
 @pytest.fixture(scope="module")
 def whole_mixer():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 96, DM), jnp.float32)
-    params = solar_mixer().init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(solar_mixer().init)(jax.random.PRNGKey(1), x)["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(2), 40))
     shake = lambda a: a + 0.3 * jax.random.normal(next(keys), a.shape)  # noqa: E731
     params = dict(params, norm=shake(params["norm"]), dt_bias=shake(params["dt_bias"]) + 2.0,
@@ -273,17 +274,35 @@ def test_the_mixers_leaves_are_the_published_forms(whole_mixer):
     assert params["dt_bias"].shape == (H, DK) and params["A_log"].shape == (H,)
 
 
-@pytest.mark.parametrize("what", ["value", "gradients", "rule_inputs"])
-def test_the_mixer_equals_the_references_layer(whole_mixer, what):
+@pytest.fixture(scope="module")
+def mixer_both_ways(whole_mixer):
+    """``(value, gradients)`` of the mixer and of the reference's layer under
+    one cotangent, once for the cases that each look at one of them."""
     params, x = whole_mixer
     mixer = solar_mixer()
     weigh = jax.random.normal(jax.random.PRNGKey(5), x.shape)
     with jax.default_matmul_precision("highest"):
+        return [
+            value_and_gradients(fn, params, weight=weigh, argnums=0) for fn in (
+                lambda p: mixer.apply({"params": p}, x),
+                lambda p: reference.kda_mixer(MIXER_CONFIG, p, x),
+            )
+        ]
+
+
+@pytest.mark.parametrize("what", ["value", "gradients", "rule_inputs"])
+def test_the_mixer_equals_the_references_layer(whole_mixer, mixer_both_ways, what):
+    params, x = whole_mixer
+    mixer = solar_mixer()
+    (value, got), (want_value, want) = mixer_both_ways
+    with jax.default_matmul_precision("highest"):
         if what == "value":
-            _close(mixer.apply({"params": params}, x), reference.kda_mixer(MIXER_CONFIG, params, x))
+            _close(value, want_value)
             return
         if what == "rule_inputs":
-            _, left = mixer.apply({"params": params}, x, mutable=["intermediates", "metrics"])
+            _, left = jax.jit(lambda p: mixer.apply(
+                {"params": p}, x, mutable=["intermediates", "metrics"]
+            ))(params)
             got = left["intermediates"]["rule_inputs"][0]
             want = reference.rule_inputs(MIXER_CONFIG, params, x)[:5]
             for a, b in zip(got, want):
@@ -292,10 +311,6 @@ def test_the_mixer_equals_the_references_layer(whole_mixer, what):
             assert float(jnp.max(beta)) > 1.0 and float(jnp.min(g)) < -5.5  # past both old limits
             assert float(left["metrics"]["kda_log_decay_min"][0]) == float(jnp.min(g))
             return
-        got = jax.grad(lambda p: jnp.sum(mixer.apply({"params": p}, x) * weigh))(params)
-        want = jax.grad(
-            lambda p: jnp.sum(reference.kda_mixer(MIXER_CONFIG, p, x) * weigh)
-        )(params)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
         _close(a, b, tol=1e-3)
@@ -311,7 +326,7 @@ def test_another_published_form_is_another_function(whole_mixer, form):
                                    lower_bound=None, neg_eigval=True, gate_rank=DK), **changes})
     other = KimiDeltaMixer(spec, jnp.float32, 1e-5)
     if form == "full_rank":
-        fresh = other.init(jax.random.PRNGKey(1), x)["params"]
+        fresh = jax.jit(other.init)(jax.random.PRNGKey(1), x)["params"]
         assert "f_proj" in fresh and "f_down" not in fresh
         assert fresh["f_proj"]["kernel"].shape == (DM, H * DK) and set(fresh["g_proj"]) == {"kernel"}
         return
@@ -344,12 +359,11 @@ def test_the_shares_of_a_linear_mixers_heads_add_up_to_the_uncut_layer():
     heads = 8
     config = dict(MIXER_CONFIG, linear_attn_config=dict(MIXER_CONFIG["linear_attn_config"], num_heads=heads))
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 64, DM), jnp.float32)
-    params = solar_mixer(heads).init(jax.random.PRNGKey(4), x)["params"]
+    params = jax.jit(solar_mixer(heads).init)(jax.random.PRNGKey(4), x)["params"]
+    share = jax.jit(lambda p: solar_mixer(1).apply({"params": p}, x))
     with jax.default_matmul_precision("highest"):
-        want = reference.kda_mixer(config, params, x)
-        parts = [
-            solar_mixer(1).apply({"params": heads_of(params, i, 1)}, x) for i in range(heads)
-        ]
+        want = jax.jit(lambda p: reference.kda_mixer(config, p, x))(params)
+        parts = [share(heads_of(params, i, 1)) for i in range(heads)]
     _close(sum(parts), want)
     assert float(jnp.max(jnp.abs(parts[0]))) > 0
 
@@ -364,11 +378,12 @@ def test_the_shares_of_the_softmax_layers_heads_add_up_to_the_uncut_layer():
         num_heads=q, num_kv_heads=kv, head_dim=hd, dtype=jnp.float32, rope=False, gate=True,
     )
     positions = jnp.arange(x.shape[1])[None]                   # read by no layer: no rotation
-    params = layer(hq, hkv).init(jax.random.PRNGKey(6), x, positions)["params"]
+    params = jax.jit(layer(hq, hkv).init)(jax.random.PRNGKey(6), x, positions)["params"]
     group = hq // hkv
+    share = jax.jit(lambda p: layer(group, 1).apply({"params": p}, x, positions))
     with jax.default_matmul_precision("highest"):
         want = reference.gqa_mixer(config, params, x)
-        _close(layer(hq, hkv).apply({"params": params}, x, positions), want)
+        _close(jax.jit(layer(hq, hkv).apply)({"params": params}, x, positions), want)
         parts = []
         for i in range(hkv):
             qs = slice(i * group, (i + 1) * group)
@@ -379,7 +394,7 @@ def test_the_shares_of_the_softmax_layers_heads_add_up_to_the_uncut_layer():
                 "v": {"kernel": params["v"]["kernel"][:, i:i + 1]},
                 "o": {"kernel": params["o"]["kernel"][qs]},
             }
-            parts.append(layer(group, 1).apply({"params": cut}, x, positions))
+            parts.append(share(cut))
     _close(sum(parts), want)
 
 
@@ -409,24 +424,26 @@ def test_the_forty_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     top-k and the renormalisation at the whole width on every chip; what they
     put out, the shared expert counted once, is the reference's uncut layer."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32), jnp.float32)
-    params = expert_layer().init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(expert_layer().init)(jax.random.PRNGKey(1), x)["params"]
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(2), (E,))
     stats = {"router_bias": bias - jnp.mean(bias)}
     tokens = x.reshape(-1, x.shape[-1])
+    # ``held`` is a field of the layer: a share is its own program, forty of
+    # them, each jitted (the reference's forty run eagerly on one set of shapes)
+    program = jax.jit(lambda first, p: expert_layer((first, 1)).apply(
+        {"params": p, "batch_stats": stats}, x
+    ), static_argnums=0)
+    plain = lambda first, p: reference.mixture(  # noqa: E731
+        layer_config((first, 1)), p, stats["router_bias"], tokens
+    )[0]
     with jax.default_matmul_precision("highest"):
         want, info = reference.mixture(layer_config(None), params, stats["router_bias"], tokens)
         shared = reference.swiglu(params["shared"], tokens)
         outputs = []
         for first in range(E):
             banks = {name: params[name][first:first + 1] for name in ("gate", "up", "down")}
-            y = expert_layer((first, 1)).apply(
-                {"params": {**params, **banks}, "batch_stats": stats}, x
-            )
-            outputs.append(y.reshape(tokens.shape))
-            part, _ = reference.mixture(
-                layer_config((first, 1)), {**params, **banks}, stats["router_bias"], tokens
-            )
-            _close(outputs[-1], part)
+            outputs.append(program(first, {**params, **banks}).reshape(tokens.shape))
+            _close(outputs[-1], plain(first, {**params, **banks}))
     _close(sum(outputs) - (E - 1) * shared, want)
     assert int(jnp.sum(info["counts"])) == tokens.shape[0] * K
 
@@ -459,7 +476,7 @@ def lm_loss(logits, targets):
 def toy_variables():
     lm = toy_lm()
     x, y = toy_batch()
-    variables = lm.init(jax.random.PRNGKey(3), x)
+    variables = jax.jit(lm.init)(jax.random.PRNGKey(3), x)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 8))
 
     def some_bias(a):  # as the rule leaves it: its mean at zero
@@ -484,32 +501,51 @@ def test_the_toy_is_the_first_period_with_an_expert_layer_in_every_block(toy_var
 
 
 @pytest.fixture(scope="module")
-def reference_gradients(toy_variables):
+def reference_outputs(toy_variables):
+    """``(loss, logits, gradients)`` of the plain reference at the toy's batch."""
     params, stats, x, y = toy_variables
+
+    def plain(p):
+        return reference.loss(TOY, p, stats, x, y), reference.forward(TOY, p, stats, x)[0]
+
     with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.grad(lambda p: reference.loss(TOY, p, stats, x, y)))(params)
+        return loss_logits_gradients(plain, params)
+
+
+@pytest.fixture(scope="module")
+def reference_gradients(reference_outputs):
+    return reference_outputs[2]
+
+
+@pytest.fixture(scope="module")
+def program_outputs(toy_variables):
+    """``remat -> (loss, logits, gradients)`` of the toy LM, each computed once."""
+    params, stats, x, y = toy_variables
+
+    @functools.lru_cache(maxsize=None)
+    def outputs(remat):
+        lm = toy_lm(remat=remat)
+
+        def program(p):
+            logits = lm.apply({"params": p, "batch_stats": stats}, x)
+            return lm_loss(logits, y)[0], logits
+
+        with jax.default_matmul_precision("highest"):
+            return loss_logits_gradients(program, params)
+
+    return outputs
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("what", ["logits", "loss", "gradients"])
-def test_the_toy_lm_equals_the_plain_reference(toy_variables, reference_gradients, remat, what):
-    params, stats, x, y = toy_variables
-    lm = toy_lm(remat=remat)
-    program = lambda p: lm_loss(  # noqa: E731
-        lm.apply({"params": p, "batch_stats": stats}, x), y
-    )[0]
-    with jax.default_matmul_precision("highest"):
-        if what == "logits":
-            _close(
-                lm.apply({"params": params, "batch_stats": stats}, x),
-                reference.forward(TOY, params, stats, x)[0],
-            )
-            return
-        if what == "loss":
-            want = reference.loss(TOY, params, stats, x, y)
-            assert float(program(params)) == pytest.approx(float(want), rel=1e-5)
-            return
-        got, want = jax.jit(jax.grad(program))(params), reference_gradients
+def test_the_toy_lm_equals_the_plain_reference(program_outputs, reference_outputs, remat, what):
+    (loss, logits, got), (want_loss, want_logits, want) = program_outputs(remat), reference_outputs
+    if what == "logits":
+        _close(logits, want_logits)
+        return
+    if what == "loss":
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+        return
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         assert float(jnp.linalg.norm(b)) > 0, jax.tree_util.keystr(path)
         _close(a, b, tol=1e-3)

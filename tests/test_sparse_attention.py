@@ -392,7 +392,7 @@ def toy():
         jax.random.PRNGKey(11), (2, TOY["train"]["seq_len"] + 1), 0, TOY["vocab_size"]
     )
     model = toy_model()
-    params = shaken(model.init(jax.random.PRNGKey(12), tokens[:, :-1])["params"])
+    params = shaken(jax.jit(model.init)(jax.random.PRNGKey(12), tokens[:, :-1])["params"])
     return model, params, tokens[:, :-1], tokens[:, 1:]
 
 
@@ -415,7 +415,7 @@ def _indexer_loss(model, params, tokens):
 
 def test_the_language_loss_gives_the_indexers_parameters_a_zero_gradient(toy):
     model, params, tokens, targets = toy
-    grads = jax.grad(lambda p: _language_loss(model, p, tokens, targets))(params)
+    grads = jax.jit(jax.grad(lambda p: _language_loss(model, p, tokens, targets)))(params)
     seen = 0
     for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         if _is_indexers(path):
@@ -428,7 +428,7 @@ def test_the_language_loss_gives_the_indexers_parameters_a_zero_gradient(toy):
 
 def test_the_indexers_loss_gives_every_other_parameter_a_zero_gradient(toy):
     model, params, tokens, _ = toy
-    grads = jax.grad(lambda p: _indexer_loss(model, p, tokens))(params)
+    grads = jax.jit(jax.grad(lambda p: _indexer_loss(model, p, tokens)))(params)
     for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         name = jax.tree_util.keystr(path)
         if _is_indexers(path):
@@ -445,11 +445,11 @@ def test_a_selection_of_every_causal_key_is_the_attention_layer(toy):
         if name.startswith("layer_") else layer
         for name, layer in params.items()
     }
-    want = toy_model("attention").apply({"params": dense_params}, tokens)
+    want = jax.jit(toy_model("attention").apply)({"params": dense_params}, tokens)
     for topk in (t, 4 * t):
-        got = toy_model(topk=topk).apply({"params": params}, tokens)
+        got = jax.jit(toy_model(topk=topk).apply)({"params": params}, tokens)
         _close(got, want, tol=1e-6)
-    short = toy_model(topk=t // 4).apply({"params": params}, tokens)
+    short = jax.jit(toy_model(topk=t // 4).apply)({"params": params}, tokens)
     assert float(jnp.max(jnp.abs(short - want))) > 1e-3   # a real selection differs
 
 
@@ -473,19 +473,21 @@ def trained(toy):
     ).replace(params=params)
     job = family.build(TOY, tokens.shape[0], 0)
     new_state, metrics = make_train_step(job["loss"], donate=False)(state, (tokens, targets))
-    logits, left = model.apply(
-        {"params": params}, tokens, mutable=["intermediates", "losses", "metrics"]
-    )
+    logits, left = jax.jit(lambda p: model.apply(
+        {"params": p}, tokens, mutable=["intermediates", "losses", "metrics"]
+    ))(params)
     layers = range(TOY["num_hidden_layers"])
     chosen = jnp.stack([left["intermediates"]["layer_%d" % i]["moe"]["top_idx"][0] for i in layers])
     selections = jnp.stack([
         left["intermediates"]["layer_%d" % i]["attn"]["selection"][0] != 0 for i in layers
     ])
     with jax.default_matmul_precision("highest"):
-        want_logits, losses, info = reference.forward(TOY, params, tokens, chosen, selections)
-        want_loss, want_grads = jax.value_and_grad(
-            lambda p: reference.loss(TOY, p, tokens, targets, chosen, selections)
+        want_logits, losses, info = jax.jit(
+            lambda p: reference.forward(TOY, p, tokens, chosen, selections)
         )(params)
+        want_loss, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: reference.loss(TOY, p, tokens, targets, chosen, selections)
+        ))(params)
     # sgd at rate 1: the step's gradient is the parameters' change
     grads = jax.tree.map(lambda a, b: a - b, params, new_state.params)
     return {
@@ -598,7 +600,7 @@ def test_the_shares_of_a_softmax_layer_add_up_and_count_the_auxiliary_loss_once(
     experts' counts, is the same number on every chip (the objective of the
     deployment counts it once, not once a chip)."""
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, D), jnp.float32)
-    params = _layer(None).init(jax.random.PRNGKey(2), x)["params"]
+    params = jax.jit(_layer(None).init)(jax.random.PRNGKey(2), x)["params"]
     with jax.default_matmul_precision("highest"):
         uncut, balance, _ = reference.mixture(
             dict(LAYER, share={"router_experts": E, "experts_first": 0}),
@@ -645,7 +647,7 @@ def test_an_attention_layer_of_the_older_kinds_lowers_without_the_selection(kind
         arch=ArchSpec(layer_types=(kind,), sliding_window=8),
     )
     tokens = jnp.zeros((1, 16), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     assert "index_q" not in params["params"]["layer_0"]["attn"]
     assert "losses" not in params and "metrics" not in params
     text = jax.jit(model.apply).lower(params, tokens).as_text()
@@ -667,15 +669,18 @@ def test_the_familys_start_changes_the_embedding_tables_first_values_alone(rms):
         if f.name not in ("parent", "name")
     })
     tokens = jnp.zeros((1, 16), jnp.int32)
-    got = started.init(jax.random.PRNGKey(0), tokens)["params"]
-    want = plain.init(jax.random.PRNGKey(0), tokens)["params"]
+    got = jax.jit(started.init)(jax.random.PRNGKey(0), tokens)["params"]
+    want = jax.jit(plain.init)(jax.random.PRNGKey(0), tokens)["params"]
     table = got["embed"].pop("embedding")
     drawn = want["embed"].pop("embedding")
     _close(table, drawn * rms * TOY["hidden_size"] ** 0.5, tol=1e-6)
     assert float(jnp.sqrt(jnp.mean(table ** 2))) == pytest.approx(rms, rel=0.05)
     assert jax.tree.all(jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)), got, want))
     got["embed"]["embedding"] = table
-    _close(started.apply({"params": got}, tokens), plain.apply({"params": got}, tokens), tol=0)
+    _close(
+        jax.jit(started.apply)({"params": got}, tokens),
+        jax.jit(plain.apply)({"params": got}, tokens), tol=0,
+    )
 
 
 def test_the_remat_policy_keeps_the_selections_thresholds():

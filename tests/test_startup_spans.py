@@ -308,11 +308,11 @@ def _experts():
 
 
 @pytest.mark.parametrize("make,kernels", [
-    (lambda: _attention(None), ["flash_fwd", "flash_dq", "flash_dkv"]),
+    (lambda: _attention(None), ["flash2_fwd", "flash2_bwd"]),
     (lambda: _attention(64), ["flash2_fwd", "flash2_bwd"]),
     (_conv, ["causal_conv_fwd", "causal_conv_bwd"]),
     (_experts, ["gmm", "gmm_dlhs", "tgmm"]),
-], ids=["flash", "flash2", "causal_conv", "megablox"])
+], ids=["flash2", "flash2_window", "causal_conv", "megablox"])
 def test_kernel_trace_once_a_shape_and_never_from_the_compiled_function(make, kernels):
     aot.instrument_compile_spans()
     tracer = obs_trace.get_tracer()

@@ -379,7 +379,6 @@ def test_attention_says_which_form_it_took():
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         assert _new_notes("attn_route", call) == [{
             "tq": 256, "tk": 256, "window": None, "path": "kernel",
-            "forward": "flash", "backward": "flash",
         }]
 
 

@@ -484,7 +484,7 @@ def test_the_step_is_bit_equal_with_and_without_the_scopes(monkeypatch):
     without = make_train_step(mse_loss, numerics=True, donate=False)
     # the scope as a path component ("jit(step)/jvp(forward)/..."), not the
     # bare word: jax caches a jitted jnp helper's jaxpr with its first
-    # caller's source names, and another test file's ("_flash_forward")
+    # caller's source names, and another test file's ("_flash2_forward")
     # ride into this text when both run in one worker
     lowered = without.lower(state, batch).as_text(debug_info=True)
     assert not re.search(r"[/(]forward[/)]", lowered)

@@ -63,45 +63,32 @@ def no_persistent_cache():
 LM = (16, 16, 16, 2048, 64)        # chip_smoke's lm phase: b16 x seq 2048
 HD128 = (2, 8, 8, 4096, 128)
 GQA = (2, 16, 4, 2048, 64)
-AT_MAX = (1, 16, 16, A._WHOLE_KV_MAX_SEQ, 64)
-PAST_MAX = (1, 16, 16, A._WHOLE_KV_MAX_SEQ + 512, 64)
-LONG = (1, 16, 16, 8192, 64)       # chip_smoke's flash2 comparison shape
+LONG = (1, 16, 16, 8192, 64)       # chip_smoke's long comparison shape
 GRANITE = (1, 32, 8, 8192, 64)     # granite_4_0_h_micro.steady's attention layer
 TRINITY = (1, 32, 4, 8192, 128)    # trinity_mini.steady's attention layers
 MISTRAL = (2, 32, 8, 4096, 128)    # mistral_7b.steady's, a half batch a call
 OLMOE = (4, 16, 16, 4096, 128)     # olmoe_1b_7b.steady's
 OLMO_HYBRID = (1, 15, 15, 8192, 128)  # olmo_hybrid_7b.steady's full layer: MHA
 
-FWD_NAME = {"flash": "_flash_kernel", "flash2": "_flash2_kernel"}
-BWD_NAMES = {
-    "flash": ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"),
-    # one fused kernel: a head's float32 dq accumulator (4 MB at 8192 x 128)
-    # stays in VMEM under a limit set from the shapes
-    "flash2": ("_flash2_bwd_kernel",),
-}
+FWD_NAME = "_flash2_kernel"
+# one fused kernel: a head's float32 dq accumulator (4 MB at 8192 x 128)
+# stays in VMEM under a limit set from the shapes
+BWD_NAMES = ("_flash2_bwd_kernel",)
 
 CASES = [
-    pytest.param(family, direction, shape, id="%s-%s-%s" % (family, direction, name))
-    for name, shape, families in (
-        ("lm", LM, ("flash", "flash2")),
-        ("hd128", HD128, ("flash", "flash2")),
-        ("gqa", GQA, ("flash", "flash2")),
-        # the whole-KV kernel at the dispatch boundary and just past it:
-        # the dispatch remaps to flash2 there because an earlier compiler
-        # refused whole-KV past 4096 — this records what today's says
-        ("at_max_seq", AT_MAX, ("flash",)),
-        ("past_max_seq", PAST_MAX, ("flash",)),
-        ("seq8192", LONG, ("flash2",)),
-        ("granite", GRANITE, ("flash2",)),
-        # the other three LM cells' shapes, as their steps call the kernels
-        ("trinity_full", TRINITY, ("flash2",)),
-        # (the route gives both the flash2 pair at T = 4096 since PR 48: the
-        # whole-KV cases record that the family still compiles there)
-        ("mistral", MISTRAL, ("flash", "flash2")),
-        ("olmoe", OLMOE, ("flash", "flash2")),
-        ("olmo_hybrid", OLMO_HYBRID, ("flash2",)),
+    pytest.param(direction, shape, id="flash2-%s-%s" % (direction, name))
+    for name, shape in (
+        ("lm", LM),
+        ("hd128", HD128),
+        ("gqa", GQA),
+        ("seq8192", LONG),
+        ("granite", GRANITE),
+        # the other LM cells' shapes, as their steps call the kernels
+        ("trinity_full", TRINITY),
+        ("mistral", MISTRAL),
+        ("olmoe", OLMOE),
+        ("olmo_hybrid", OLMO_HYBRID),
     )
-    for family in families
     for direction in ("fwd", "bwd")
 ]
 
@@ -110,8 +97,8 @@ def _kernel_names(lowered_text):
     return re.findall(r'kernel_name = "(\w+)"', lowered_text)
 
 
-@pytest.mark.parametrize("family,direction,shape", CASES)
-def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
+@pytest.mark.parametrize("direction,shape", CASES)
+def test_kernel_compiles_for_v5e(one_chip, direction, shape):
     b, h, h_kv, t, d = shape
     scale = d ** -0.5
 
@@ -119,25 +106,19 @@ def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
-    if family == "flash":
-        fwd_blocks, bwd_blocks = A._kernel_blocks(t)
-        forward, backward = A._flash_forward, A._flash_backward_kernels
-    else:
-        fwd_blocks, bwd_blocks = A._FLASH2_BLOCKS_FWD, A._FLASH2_BLOCKS_BWD
-        forward, backward = A._flash2_forward, A._flash2_backward_kernels
     if direction == "fwd":
-        bq, bk = fwd_blocks
-        fn = lambda q, k, v: forward(q, k, v, True, scale, bq, bk, False)
+        bq, bk = A._FLASH2_BLOCKS_FWD
+        fn = lambda q, k, v: A._flash2_forward(q, k, v, True, scale, bq, bk, False)
         args = (q, kv, kv)
-        want = [FWD_NAME[family]]
+        want = [FWD_NAME]
     else:
-        bq, bk = (A._fit_block(blk, t) for blk in bwd_blocks)
-        fn = lambda q, k, v, g, lse, delta: backward(
+        bq, bk = (A._fit_block(blk, t) for blk in A._FLASH2_BLOCKS_BWD)
+        fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
             q, k, v, g, lse, delta, True, scale, bq, bk, False
         )
         row = sds((b * h, t), jnp.float32)
         args = (q, kv, kv, q, row, row)
-        want = list(BWD_NAMES[family])
+        want = list(BWD_NAMES)
 
     lowered = jax.jit(fn).lower(*args)
     # the kernel itself was lowered — not the ragged-shape dense fallback
@@ -146,15 +127,31 @@ def test_kernel_compiles_for_v5e(one_chip, family, direction, shape):
     assert compiled.as_text().count("tpu_custom_call") == len(want)
 
 
-@pytest.mark.parametrize("shape", [MISTRAL, OLMOE], ids=["mistral", "olmoe"])
-def test_a_call_at_4096_compiles_for_v5e_as_one_forward_and_one_fused_backward(
-    one_chip, monkeypatch, shape
+# (shape, tk where it is not tq, causal) of a call through `flash_attention`
+CALLS = [
+    pytest.param(MISTRAL, None, True, id="mistral"),
+    pytest.param(OLMOE, None, True, id="olmoe"),
+    # what the whole-KV kernels served until PR 53 (tq <= 2048, tk <= 4096):
+    # the compiler's word that the one family takes every shape the two did
+    pytest.param(LM, None, True, id="lm"),
+    pytest.param((4, 16, 16, 1024, 64), None, True, id="t1024-d64"),
+    pytest.param((4, 32, 8, 1024, 128), None, True, id="t1024-gqa8-d128"),
+    pytest.param((4, 16, 16, 512, 64), None, True, id="t512-d64"),
+    pytest.param((4, 32, 8, 512, 128), None, True, id="t512-gqa8-d128"),
+    pytest.param((2, 32, 8, 2048, 128), None, True, id="t2048-gqa8-d128"),
+    pytest.param((4, 16, 16, 1024, 64), None, False, id="t1024-not-causal"),
+    pytest.param((4, 16, 16, 1024, 64), 4096, True, id="tq1024-tk4096"),
+]
+
+
+@pytest.mark.parametrize("shape,tk,causal", CALLS)
+def test_a_call_compiles_for_v5e_as_one_forward_and_one_fused_backward(
+    one_chip, monkeypatch, shape, tk, causal
 ):
-    """The two cells of T = 4096 through the entry point their kernel check
-    calls: `flash_attention` asks `_route`, and value and gradients are two
-    custom calls, the flash2 forward and the one fused backward (where the
-    whole-KV dq and dkv stood until PR 48), with the blocks the route's
-    tables give."""
+    """A call through the entry point the cells' kernel check calls:
+    `flash_attention` is `attention()`'s kernels, and value and gradients
+    are two custom calls, the flash2 forward and the one fused backward,
+    with the blocks `_flash2_blocks` gives the shape."""
     b, h, h_kv, t, d = shape
     monkeypatch.setattr(A, "_interpret", lambda: False)
 
@@ -162,10 +159,10 @@ def test_a_call_at_4096_compiles_for_v5e_as_one_forward_and_one_fused_backward(
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def value_and_grads(q, k, v, w):
-        out, vjp = jax.vjp(lambda q, k, v: A.flash_attention(q, k, v, causal=True), q, k, v)
+        out, vjp = jax.vjp(lambda q, k, v: A.flash_attention(q, k, v, causal=causal), q, k, v)
         return (out, *vjp(w))
 
-    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, tk or t, d))
     lowered = jax.jit(value_and_grads).lower(q, kv, kv, q)
     assert _kernel_names(lowered.as_text()) == ["_flash2_kernel", "_flash2_bwd_kernel"]
     assert lowered.compile().as_text().count("tpu_custom_call") == 2
@@ -195,13 +192,13 @@ def test_windowed_kernel_compiles_for_v5e(one_chip, direction, window):
         fn = lambda q, k, v: A._flash2_forward(
             q, k, v, True, d ** -0.5, *fwd, False, window
         )
-        args, want = (q, kv, kv), [FWD_NAME["flash2"]]
+        args, want = (q, kv, kv), [FWD_NAME]
     else:
         fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
             q, k, v, g, lse, delta, True, d ** -0.5, *dq, False, window, dkv
         )
         row = sds((b * h, t), jnp.float32)
-        args, want = (q, kv, kv, q, row, row), list(BWD_NAMES["flash2"])
+        args, want = (q, kv, kv, q, row, row), list(BWD_NAMES)
     kv_steps, _ = A._span_steps(window, *dq, t, t)
     _, q_steps = A._span_steps(window, *dkv, t, t)
     assert kv_steps * dq[1] < t / 2 and q_steps * dkv[0] < t / 2
@@ -938,7 +935,6 @@ def test_flash2_compiles_for_v5e_at_keys_of_192_and_values_of_128(one_chip, dire
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     q, v = sds((b, heads, t, d_qk)), sds((b, heads, t, d_v))
-    assert A._route(t, t, False, True) == ("flash2", "flash2")
     if direction == "fwd":
         bq, bk = A._flash2_blocks("fwd", t, t, None)
         fn = lambda q, k, v: A._flash2_forward(q, k, v, True, scale, bq, bk, False)

@@ -5,6 +5,7 @@ parameter's gradient, the bias after three steps), the share tied to the model
 ``DroplessMoE`` bit for bit under ``held = (0, E)``, and the window in every
 route of ``ops/attention.py`` against a dense mask written here."""
 
+import functools
 import importlib
 import json
 import os
@@ -48,11 +49,11 @@ def trained():
     step = make_train_step(job["loss"], donate=False)
     pool = family.host_batches(TOY, 1, 0, n_batches=4)
     biases, counts = [state.batch_stats], []
+    sown = jax.jit(lambda variables, tokens: state.apply_fn(
+        variables, tokens, mutable=["intermediates"]
+    ))
     for batch in pool[:3]:
-        _, left = state.apply_fn(
-            {"params": state.params, "batch_stats": state.batch_stats}, batch[0],
-            mutable=["intermediates"],
-        )
+        _, left = sown({"params": state.params, "batch_stats": state.batch_stats}, batch[0])
         counts.append({
             name: np.bincount(
                 np.asarray(layer["moe"]["top_idx"][0]).reshape(-1),
@@ -70,13 +71,15 @@ def trained():
         return job["loss"](logits, targets)[0], logits
 
     with jax.default_matmul_precision("highest"):
-        (got_loss, got_logits), got_grads = jax.value_and_grad(
+        (got_loss, got_logits), got_grads = jax.jit(jax.value_and_grad(
             program_loss, has_aux=True
+        ))(state.params)
+        want_logits, info = jax.jit(
+            lambda p: reference.forward(TOY, p, state.batch_stats, tokens)
         )(state.params)
-        want_logits, info = reference.forward(TOY, state.params, state.batch_stats, tokens)
-        want_loss, want_grads = jax.value_and_grad(
+        want_loss, want_grads = jax.jit(jax.value_and_grad(
             lambda p: reference.loss(TOY, p, state.batch_stats, tokens, targets)
-        )(state.params)
+        ))(state.params)
     return {
         "state": state, "biases": biases, "counts": counts, "info": info,
         "got": {"logits": got_logits, "loss": got_loss, "grads": got_grads},
@@ -181,7 +184,7 @@ def _layer(held, **over):
 @pytest.fixture(scope="module")
 def whole_layer():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, D), jnp.float32)
-    variables = _layer(None).init(jax.random.PRNGKey(2), x)
+    variables = jax.jit(_layer(None).init)(jax.random.PRNGKey(2), x)
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (E,), jnp.float32)
     return x, variables["params"], {"router_bias": bias - jnp.mean(bias)}
 
@@ -245,9 +248,9 @@ def test_holding_every_expert_is_todays_layer_bit_for_bit(what, norm_topk_prob):
     )
     today, share = _layer(None, **kind), _layer((0, E), **kind)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 40, D), jnp.bfloat16)
-    params = today.init(jax.random.PRNGKey(5), x)["params"]
+    params = jax.jit(today.init)(jax.random.PRNGKey(5), x)["params"]
     assert jax.tree.structure(params) == jax.tree.structure(
-        share.init(jax.random.PRNGKey(5), x)["params"]
+        jax.eval_shape(share.init, jax.random.PRNGKey(5), x)["params"]
     )
 
     def run(layer):
@@ -381,7 +384,7 @@ def test_a_recomputation_handed_the_route_and_the_products_gives_the_same(
     )
     kwargs.update(kind)
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 24, D), dtype)
-    variables = DroplessMoE(**kwargs).init(jax.random.PRNGKey(8), x)
+    variables = jax.jit(DroplessMoE(**kwargs).init)(jax.random.PRNGKey(8), x)
     variables = {k: v for k, v in variables.items() if k in ("params", "batch_stats")}
     if load == "outgrows_it":
         if choice == "scores":  # no bias to lean on: the router itself prefers them
@@ -463,6 +466,61 @@ def qkvw():
     return {name: make(*heads) for name, heads in GROUPS.items()}
 
 
+@pytest.fixture(scope="module")
+def dense_route(qkvw):
+    """``(span, heads) -> (out, dq, dk, dv)`` of the dense route under the
+    window, one jitted program, once for the seven routes that read it."""
+    @functools.lru_cache(maxsize=None)
+    def outputs(span, heads):
+        q, k, v, w = qkvw[heads]
+
+        @jax.jit
+        def run(q, k, v, w):
+            out, vjp = jax.vjp(lambda q, k, v: A.attention_reference(
+                q, k, v, causal=True, window=WINDOWS[span]), q, k, v)
+            return (out, *vjp(w))
+
+        return run(q, k, v, w)
+
+    return outputs
+
+
+@pytest.fixture(scope="module")
+def flash2_route(qkvw):
+    """The kernels' forward of a ``(span, heads)`` once for the five routes
+    that start from it, and a backward call once for the two gradients'
+    routes that read it (an interpreted Pallas call is a compile a call)."""
+
+    class Route:
+        @staticmethod
+        @functools.lru_cache(maxsize=None)
+        def forward(span, heads):
+            q, k, v, _ = qkvw[heads]
+            return A._flash2_forward(
+                q, k, v, True, HD ** -0.5, BLOCK, BLOCK, True, WINDOWS[span]
+            )
+
+        @staticmethod
+        @functools.lru_cache(maxsize=None)
+        def backward(span, heads, pair):
+            """``((dq, dk, dv), whether the fused kernel ran)``; ``pair``: a
+            head whose dq no VMEM holds, so dq and dk/dv apart."""
+            q, k, v, w = qkvw[heads]
+            got, lse = Route.forward(span, heads)
+            b, h = q.shape[:2]
+            capacity = 0 if pair else A._VMEM_V5E
+            with mock.patch.object(A, "_vmem_capacity", lambda: capacity), mock.patch.object(
+                A, "_flash2_bwd_kernel", wraps=A._flash2_bwd_kernel
+            ) as fused:
+                grads = A._flash2_backward_kernels(
+                    q, k, v, w, lse.reshape(b * h, T), A._bwd_delta(w, got, b, h, T, HD),
+                    True, HD ** -0.5, BLOCK, BLOCK, True, WINDOWS[span],
+                )
+            return grads, fused.called
+
+    return Route
+
+
 @pytest.mark.parametrize("heads", list(GROUPS))
 @pytest.mark.parametrize("span", list(WINDOWS))
 @pytest.mark.parametrize("route", [
@@ -470,51 +528,39 @@ def qkvw():
     "flash2_pair_dq", "flash2_pair_dkv",
 ])
 def test_the_window_in_every_route_against_a_dense_mask(
-    monkeypatch, qkvw, route, span, heads
+    qkvw, dense_route, flash2_route, route, span, heads
 ):
     q, k, v, w = qkvw[heads]
     window = WINDOWS[span]
     scale = HD ** -0.5
-
-    def dense(q, k, v):
-        return A.attention_reference(q, k, v, causal=True, window=window)
-
     want = _dense_mask_attention(q, k, v, window)
+    # the gradients' truth: jax's own, through the dense route checked first
+    out, want_dq, want_dk, want_dv = dense_route(span, heads)
     if route == "dense":
-        _close(dense(q, k, v), want, tol=1e-5)
+        _close(out, want, tol=1e-5)
         return
-    # the gradients' truth: jax's own, through the dense route just checked
-    out, vjp = jax.vjp(dense, q, k, v)
-    want_dq, want_dk, want_dv = vjp(w)
-    if route == "flash":  # refuses the window and hands the call to flash2
+    if route == "flash":  # the public name, its custom vjp and the window
         fn = lambda q, k, v: A.flash_attention(  # noqa: E731
             q, k, v, causal=True, block_q=BLOCK, block_k=BLOCK, window=window
         )
-        with mock.patch.object(A, "_flash_forward") as whole_kv, mock.patch.object(
+        with mock.patch.object(
             A, "_flash2_forward", wraps=A._flash2_forward
         ) as pipelined:
             got, vjp = jax.vjp(fn, q, k, v)
-        assert pipelined.call_count == 1 and not whole_kv.called
+        assert pipelined.call_count == 1
         _close(got, want, tol=1e-5)
         for a, b in zip(vjp(w), (want_dq, want_dk, want_dv)):
             _close(a, b, tol=1e-5)
         return
-    got, lse = A._flash2_forward(q, k, v, True, scale, BLOCK, BLOCK, True, window)
+    got, lse = flash2_route.forward(span, heads)
     if route == "flash2_forward":
         _close(got, want, tol=1e-5)
         if window >= T:  # the causal kernel, to the bit
             plain, _ = A._flash2_forward(q, k, v, True, scale, BLOCK, BLOCK, True)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
         return
-    b, h = q.shape[:2]
-    if "pair" in route:  # a head whose dq no VMEM holds: dq and dk/dv apart
-        monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
-    with mock.patch.object(A, "_flash2_bwd_kernel", wraps=A._flash2_bwd_kernel) as fused:
-        dq, dk, dv = A._flash2_backward_kernels(
-            q, k, v, w, lse.reshape(b * h, T), A._bwd_delta(w, got, b, h, T, HD),
-            True, scale, BLOCK, BLOCK, True, window,
-        )
-    assert fused.called == ("pair" not in route)
+    (dq, dk, dv), fused = flash2_route.backward(span, heads, "pair" in route)
+    assert fused == ("pair" not in route)
     if route.endswith("dq"):
         _close(dq, want_dq, tol=1e-5)
     else:
@@ -522,12 +568,12 @@ def test_the_window_in_every_route_against_a_dense_mask(
         _close(dv, want_dv, tol=1e-5)
 
 
-def test_a_windowed_call_never_reaches_the_whole_kv_kernels():
-    for t in (512, 2048, 4096, 8192):
-        assert "flash" not in A._route(t, t, windowed=True)
+def test_a_window_needs_a_causal_mask_and_a_key():
     q = jnp.zeros((1, 2, 32, 8))
-    with pytest.raises(ValueError, match="take no window"):
-        A._auto(q, q, q, True, 1.0, "flash", "flash2", None, None, 8)
+    for causal, window in ((False, 8), (True, 0)):
+        for fn in (A.attention, A.flash_attention, A.attention_reference):
+            with pytest.raises(ValueError, match="a window"):
+                fn(q, q, q, causal=causal, window=window)
     with pytest.raises(ValueError, match="causal"):
         A.attention(q, q, q, causal=False, window=8)
     # fewer steps than blocks: what lies outside the window is not walked

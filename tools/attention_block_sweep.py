@@ -1,26 +1,21 @@
 """On-chip block-size sweep for the Pallas attention kernels.
 
-The shipped defaults (``_BLOCK_TABLE`` for the whole-KV flash kernel,
-``_FLASH2_BLOCKS_*`` for the grid-pipelined flash2) came from exactly
-this measurement (r4, v5e — `bench_results/attention_blocks_r4.jsonl`):
-the original fixed (128, 512) blocks left 1.7-2.6x on the table. Re-run
-on new hardware or a new jax release and update the constants in
-``edl_tpu/ops/attention.py`` when the winners move.
+The first shipped ``_FLASH2_BLOCKS_*`` came from exactly this measurement
+(r4, v5e): fixed (128, 512) blocks left 1.7-2.6x on the table. Re-run on
+new hardware or a new jax release and update the constants in
+``edl_tpu/ops/attention.py`` when the winners move. The forward's default
+since PR 48 came from a sweep at the benchmark cells' own shapes, GQA, two
+widths and the masked copy included, which this tool's MHA-only shapes do
+not reach (bench_results/README.md, "the forward's blocks").
 
-Prints one JSON row per (kernel, seq, bq, bk) with fwd and fwd+bwd ms;
-configs that crash the compiler are recorded as rows with "error" (that
-is itself signal — bk=1024 kills the whole-KV kernel at seq >= 4096,
-and every whole-KV config died at 8192, which is where
-``_WHOLE_KV_MAX_SEQ`` came from; since PR 48 ``ops/attention.py:_route``
-gives flash2 from ``_WHOLE_KV_FWD_MAX_TQ`` + 1 rows on, and the flash2
-forward's default came from a sweep at the benchmark cells' own shapes,
-GQA, two widths and the masked copy included, which this tool's MHA-only
-shapes do not reach: bench_results/README.md, "the forward's blocks").
+Prints one JSON row per (seq, bq, bk) with fwd and fwd+bwd ms; configs
+that crash the compiler are recorded as rows with "error" (that is itself
+signal).
 
 Usage::
 
     python tools/attention_block_sweep.py [--seqs 1024 2048 4096]
-        [--impl flash|flash2] [--iters 10]
+        [--iters 10]
 """
 
 from __future__ import annotations
@@ -47,7 +42,6 @@ def main():
     p.add_argument("--heads", type=int, default=16)
     p.add_argument("--head_dim", type=int, default=64)
     p.add_argument("--seqs", type=int, nargs="+", default=[1024, 2048, 4096])
-    p.add_argument("--impl", choices=("flash", "flash2"), default="flash")
     p.add_argument("--iters", type=int, default=10)
     p.add_argument(
         "--blocks_q", type=int, nargs="+", default=[128, 256, 512]
@@ -80,47 +74,31 @@ def main():
                 if bq > seq or bk > seq:
                     continue
 
-                if args.impl == "flash":
-                    def fwd(a, bq=bq, bk=bk):
-                        return A._auto(
-                            a[0], a[1], a[2], True, scale, "flash", "flash",
-                            (bq, bk), (bq, bk),
-                        )
+                def fwd(a, bq=bq, bk=bk):
+                    o, _ = A._flash2_forward(
+                        a[0], a[1], a[2], True, scale, bq, bk,
+                        A._interpret(),
+                    )
+                    return o
 
-                    def fwd_bwd(a, fwd=fwd):
-                        def loss(q, k, v):
-                            return jnp.sum(
-                                fwd((q, k, v)).astype(jnp.float32)
-                            )
+                def fwd_bwd(a, bq=bq, bk=bk):
+                    # explicit fwd + backward kernels at the SAME blocks
+                    # — how _FLASH2_BLOCKS_BWD was (and can again be)
+                    # derived
+                    qq, kk_, vv = a
+                    o, lse = A._flash2_forward(
+                        qq, kk_, vv, True, scale, bq, bk,
+                        A._interpret(),
+                    )
+                    g = jnp.ones_like(o)
+                    dq, dk, dv = A._flash2_backward(
+                        qq, kk_, vv, o,
+                        lse.reshape(b * h, qq.shape[2]), g, True,
+                        scale, bq, bk, A._interpret(),
+                    )
+                    return dq + dk + dv
 
-                        g = jax.grad(loss, argnums=(0, 1, 2))(*a)
-                        return g[0] + g[1] + g[2]
-                else:
-                    def fwd(a, bq=bq, bk=bk):
-                        o, _ = A._flash2_forward(
-                            a[0], a[1], a[2], True, scale, bq, bk,
-                            A._interpret(),
-                        )
-                        return o
-
-                    def fwd_bwd(a, bq=bq, bk=bk):
-                        # explicit fwd + flash2 backward kernels at the
-                        # SAME blocks — how _FLASH2_BLOCKS_BWD was (and
-                        # can again be) derived
-                        qq, kk_, vv = a
-                        o, lse = A._flash2_forward(
-                            qq, kk_, vv, True, scale, bq, bk,
-                            A._interpret(),
-                        )
-                        g = jnp.ones_like(o)
-                        dq, dk, dv = A._flash2_backward(
-                            qq, kk_, vv, o,
-                            lse.reshape(b * h, qq.shape[2]), g, True,
-                            scale, bq, bk, A._interpret(),
-                        )
-                        return dq + dk + dv
-
-                row = {"impl": args.impl, "seq": seq, "bq": bq, "bk": bk}
+                row = {"seq": seq, "bq": bq, "bk": bk}
                 try:
                     row["fwd_ms"] = round(
                         bench_one(fwd, (q, k, v), args.iters) * 1e3, 3
