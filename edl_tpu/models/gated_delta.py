@@ -38,6 +38,9 @@ name ``gdn_carry`` and every chunk's ``T`` ``gdn_inverse``
 (``TransformerLM.remat_policy`` ``"save_flash"``) runs the carry's loop once
 forward and once in reverse a layer and the solve once; one that saves none
 runs the forward loop and the solve again when the block is recomputed.
+The in projection's output bears the name ``mixer_in``
+(``models/mamba.py:projected``, whole and before the slices): the same policy
+hands it to the recomputation, which then runs no ``in_proj`` matmul again.
 
 :class:`KimiDeltaMixer` is Kimi delta attention, the same rule with a log-decay
 for every key channel (``ops/gated_delta.py:kda_rule``): separate projections
@@ -59,7 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from edl_tpu.models.mamba import _dt_bias_init
+from edl_tpu.models.mamba import _dt_bias_init, projected
 from edl_tpu.ops.causal_conv import causal_conv_silu
 from edl_tpu.ops.gated_delta import (
     OUT_NAME,
@@ -113,7 +116,7 @@ class GatedDeltaMixer(nn.Module):
         )
 
         with jax.named_scope("gdn_proj"):
-            proj = dense(conv_dim + h * d_v + 2 * h, "in_proj")(x)
+            proj, = projected("gdn", dense(conv_dim + h * d_v + 2 * h, "in_proj")(x))
         gate = proj[..., conv_dim:conv_dim + h * d_v]
         b, a = jnp.split(proj[..., conv_dim + h * d_v:].astype(f32), 2, axis=-1)
 
@@ -249,7 +252,11 @@ class KimiDeltaMixer(nn.Module):
     (``REMAT_NAMES``): under ``"save_flash"`` the carry's loop runs once forward
     and once in reverse a layer and the solve (``kda_inverse``) once; the rest
     of the chunk-local stage (``kda_operands``) runs again when the backward
-    reaches the rule, to remake the carry's operands.
+    reaches the rule, to remake the carry's operands. The six in projections'
+    outputs bear ``mixer_in`` (``models/mamba.py:projected``; of a low-rank pair
+    the second matrix's, ``f`` in float32 as it is): the same policy hands them
+    to the block's recomputation, which multiplies only a pair's first
+    ``gate_rank`` columns again.
     """
 
     spec: KimiDeltaSpec
@@ -296,8 +303,12 @@ class KimiDeltaMixer(nn.Module):
                 gate = nn.Dense(h * d_v, dtype=self.dtype, name="g_up")(
                     dense(s.gate_rank, "g_down")(x)
                 )
+            b = dense(h, "b_proj")(x)
+            # what the six hand on, by name (of a low-rank pair the second
+            # matrix's output; ``f`` in the float32 it is in)
+            q, k, v, f, gate, b = projected("kda", q, k, v, f, gate, b)
             f = f.reshape(batch, t, h, d_k)
-            b = dense(h, "b_proj")(x).astype(f32)
+            b = b.astype(f32)
 
         with jax.named_scope("kda_conv"):
             q, k, v = (

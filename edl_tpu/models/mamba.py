@@ -38,6 +38,13 @@ Sown into ``"metrics"``: ``ssm_decay_mean``, the mean of ``exp(dt * A)`` over
 steps and heads (how fast the state forgets: 1 never, 0 at once); into
 ``"intermediates"``, for a check that asks for the collection, ``gated``: the
 normed and gated ``[B, T, d_inner]`` that ``W_out`` reads.
+
+For a remat policy around the block the in projection's output ``[z | xBC |
+dt]`` bears the name ``mixer_in`` (:func:`projected`, whole and before the
+slices): a policy that saves it (``TransformerLM.remat_policy``
+``"save_flash"``) hands the block's recomputation the array the forward wrote,
+so the convolution, the gate and ``dt`` read it again and no ``in_proj`` matmul
+runs a second time; one that saves none multiplies again.
 """
 
 from __future__ import annotations
@@ -49,11 +56,33 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.causal_conv import causal_conv_silu
 from edl_tpu.ops.ssd import ssd_scan
 
 SSM_SCOPES = ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate")
+
+# The ``checkpoint_name`` of what a recurrent mixer's in projections hand on
+# (this mixer's and ``models/gated_delta.py``'s two; ``models/short_conv.py``
+# says why its own bears none); ``models/transformer.py:_remat_policy`` saves it
+IN_NAME = "mixer_in"
+REMAT_NAMES = (IN_NAME,)
+
+
+def projected(mixer: str, *arrays):
+    """``arrays``, the outputs of a mixer's in projections, each under
+    ``IN_NAME`` for a remat policy that keeps names: the convolution's backward
+    wants its input and the gate's wants ``z``, and a block's recomputation
+    that is handed them runs no in projection again. Once a (mixer, shape) and
+    stage a ``mixer_saved`` instant: ``mixer``, the ``arrays``' count and the
+    ``bytes`` one layer leaves under the name."""
+    obs_trace.get_tracer().note_once(
+        "mixer_saved", mixer=mixer, arrays=len(arrays),
+        bytes=sum(a.size * a.dtype.itemsize for a in arrays),
+    )
+    return tuple(checkpoint_name(a, IN_NAME) for a in arrays)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +129,9 @@ class Mamba2Mixer(nn.Module):
         )
 
         with jax.named_scope("ssm_proj"):
-            zxbcdt = dense(d_inner + conv_dim + s.num_heads, "in_proj")(x)
+            zxbcdt, = projected(
+                "mamba2", dense(d_inner + conv_dim + s.num_heads, "in_proj")(x)
+            )
         z, dt = zxbcdt[..., :d_inner], zxbcdt[..., d_inner + conv_dim:]
 
         with jax.named_scope("ssm_conv"):

@@ -18,6 +18,14 @@ model's dtype.
 The device time of its two parts carries the names ``sconv_proj`` (both
 projections) and ``sconv_conv`` (the gates and the taps) (``jax.named_scope``;
 ``obs/profile.py:step_scopes`` joins them to a trace).
+
+The in projection's output bears no ``checkpoint_name`` (the recurrent mixers'
+bears ``mixer_in``, ``models/mamba.py:projected``): kept under ``"save_flash"``
+it took the seven recomputed ``in_proj`` matmuls out of
+``lfm2_24b_a2b.steady``'s step (7.9 ms) and the step was 0.6 ms slower for 0.7
+GB more, because the matmuls of the mixers' backward then found their operands
+in HBM where XLA had prefetched them under the recomputed matmul (PERF.md
+section 6, PR 54).
 """
 
 from __future__ import annotations

@@ -51,6 +51,7 @@ from edl_tpu.models.gated_delta import (
     KimiDeltaSpec,
 )
 from edl_tpu.models.mamba import Mamba2Mixer, MambaSpec
+from edl_tpu.models.mamba import REMAT_NAMES as MIXER_NAMES
 from edl_tpu.models.moe import DroplessMoE, MoESpec, SwitchMoE
 from edl_tpu.models.moe import REMAT_NAMES as MOE_NAMES
 from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
@@ -752,18 +753,30 @@ def _remat_policy(name: Optional[str]):
     cell, 18 in Solar's, 30 in Nemotron's, 50 in LFM2's, 67 in
     Trinity's, 100 in Keye's; the whole-``N k`` form bears no name), so
     that the recomputation runs no router matmul, ``top_k``, sort or
-    buffer-sized grouped matmul again; a model without such a layer
+    buffer-sized grouped matmul again, and what a recurrent mixer's in
+    projections hand on (``models/mamba.py:REMAT_NAMES``; ``mixer_in``:
+    ``Mamba2Mixer``'s ``[z | xBC | dt]`` and ``GatedDeltaMixer``'s ``proj``,
+    each whole and before its slices, and ``KimiDeltaMixer``'s ``q``,
+    ``k``, ``v`` before their convolutions, its float32 ``f``, ``gate`` and
+    ``b``, of a low-rank pair the second matrix's output; one sequence of
+    8192 leaves 152 MB a layer in Nemotron's cell, 139 in Granite's, 142
+    in OLMo-hybrid's, 201 in Ling's, 101 in Solar's; ``ShortConvMixer``'s
+    bears no name: the chip read LFM2's step no faster for it), so that
+    the convolution, the gate and the rule's inputs are remade from the
+    kept array and the recomputation runs no in projection's matmul
+    again; a model without such a layer
     bears none of the names. ``None``/"full" is classic
     recompute-everything."""
     if name in (None, "full"):
         return None
     if name == "save_flash":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", *GDN_NAMES, *DSA_NAMES, *MOE_NAMES
+            "flash_out", "flash_lse", *GDN_NAMES, *DSA_NAMES, *MOE_NAMES, *MIXER_NAMES
         )
     if name == "save_flash_qkv":
         return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES, *DSA_NAMES, *MOE_NAMES
+            "flash_out", "flash_lse", "flash_qkv", *GDN_NAMES, *DSA_NAMES, *MOE_NAMES,
+            *MIXER_NAMES,
         )
     raise ValueError("unknown remat_policy %r" % (name,))
 
