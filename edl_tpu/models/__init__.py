@@ -21,6 +21,7 @@ from edl_tpu.models.transformer import (
     ArchSpec,
     LatentAttention,
     LatentAttentionSpec,
+    MTPSpec,
     SparseAttentionSpec,
     TransformerLM,
 )
@@ -55,4 +56,5 @@ __all__ = [
     "KimiDeltaSpec",
     "LatentAttention",
     "LatentAttentionSpec",
+    "MTPSpec",
 ]

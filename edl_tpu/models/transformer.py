@@ -98,21 +98,40 @@ DSA_SCOPES = ("dsa_index", "dsa_select", "attn_sparse", "dsa_target")
 @dataclasses.dataclass(frozen=True)
 class LatentAttentionSpec:
     """The shape of a ``"latent_attention"`` layer (multi-head latent
-    attention, DeepSeek-V2, arXiv:2405.04434 section 2.1, without a query
-    rank): keys and values come out of one ``kv_lora_rank``-wide latent a
-    token, a head's query and key are ``qk_nope_head_dim`` values without a
-    position and ``qk_rope_head_dim`` rotated ones (the keys' rotated part
-    one vector a token, shared by the heads), its value ``v_head_dim``.
-    ``head_gate`` multiplies each head's output by one ``sigmoid(x W_g)``."""
+    attention, DeepSeek-V2, arXiv:2405.04434 section 2.1): keys and values
+    come out of one ``kv_lora_rank``-wide latent a token, a head's query and
+    key are ``qk_nope_head_dim`` values without a position and
+    ``qk_rope_head_dim`` rotated ones (the keys' rotated part one vector a
+    token, shared by the heads), its value ``v_head_dim``. With a
+    ``q_lora_rank`` the queries come out of a latent of that width too, under
+    a norm of its own (``None``: one full-rank projection). ``head_gate``
+    multiplies each head's output by one ``sigmoid(x W_g)``."""
 
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     head_gate: bool = False
+    q_lora_rank: Optional[int] = None
 
 
 MLA_SCOPES = ("mla_proj", "attn_mla")
+
+
+@dataclasses.dataclass(frozen=True)
+class MTPSpec:
+    """A multi-token-prediction module after the last norm (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2, equations 21 to 25): ``depth`` modules (one
+    is what runs; another value raises), each predicting one token further
+    than the main head, its cross-entropy joining the objective at
+    ``loss_weight`` (the paper's lambda). ``TransformerLM`` has the module."""
+
+    depth: int = 1
+    loss_weight: float = 0.3
+
+
+# ``mtp`` holds the whole module, the other two lie inside it
+MTP_SCOPES = ("mtp", "mtp_join", "mtp_head")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,6 +198,7 @@ class ArchSpec:
     post_norms: Union[bool, str] = False  # False, True or "only"
     attn_gate: bool = False
     one_branch: bool = False            # True: a block is one branch, no second
+    mtp: Optional[MTPSpec] = None       # a multi-token-prediction module
 
 
 LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
@@ -525,16 +545,18 @@ def _note_mla_shape(tq: int, heads: int, spec: LatentAttentionSpec):
     obs_trace.get_tracer().note_once(
         "mla_shape", tq=tq, heads=heads, d_qk=d_qk, d_v=spec.v_head_dim,
         latent=spec.kv_lora_rank, rope_dim=spec.qk_rope_head_dim,
+        q_rank=spec.q_lora_rank,
         fwd_blocks=blocks["fwd"], bwd_blocks=blocks["bwd"],
     )
 
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention as it trains (DeepSeek-V2,
-    arXiv:2405.04434, equations 9 to 19 with ``q_lora_rank`` null), for the
-    block's input ``x`` ``[B, T, d_model]`` and ``H`` heads::
+    arXiv:2405.04434, equations 9 to 19), for the block's input ``x``
+    ``[B, T, d_model]`` and ``H`` heads::
 
-        q = x W_q                       [T, H, nope + rope], cut into q_n | q_r
+        q = x W_q                       [T, H, nope + rope], cut into q_n | q_r;
+            with ``q_lora_rank``: q = RMSNorm(x W_qa) W_qb
         [c | k_r] = x W_a               kv_lora_rank + rope: the latent and ONE rotated key a token
         [k_n | v] = RMSNorm(c) W_b      [T, H, nope + v_head_dim]
         q_r, k_r rotated at rope_theta; k = [k_n | k_r] with k_r shared by the heads
@@ -547,8 +569,11 @@ class LatentAttention(nn.Module):
     ``W_q`` or ``W_o`` and no latent is cached: there is no decode path.
     Device scopes ``mla_proj`` (the latent path: the projections, the
     latent's norm, the rotation, the gate) and ``attn_mla`` (the attention
-    call alone: ``%attn_mla.N`` in a trace). ``q``, ``kv_b`` and ``o`` take
-    ``_heads_dot_general``'s fence behind their weight gradients."""
+    call alone: ``%attn_mla.N`` in a trace). ``q`` (``q_b`` under a query
+    rank, after ``q_a`` and ``q_norm``), ``kv_b`` and ``o`` take
+    ``_heads_dot_general``'s fence behind their weight gradients. ``q`` as
+    projected, before its rotation, is sown into ``"intermediates"`` as
+    ``queries`` (``q`` is the full-rank projection's own name)."""
 
     num_heads: int
     spec: LatentAttentionSpec
@@ -570,7 +595,14 @@ class LatentAttention(nn.Module):
 
         flat = partial(nn.Dense, use_bias=False, dtype=self.dtype)
         with jax.named_scope("mla_proj"):
-            q = heads("q", features=(h, nope + rot))(x)
+            if s.q_lora_rank is None:
+                q = heads("q", features=(h, nope + rot))(x)
+            else:
+                c_q = RMSNorm(self.norm_eps, name="q_norm")(
+                    flat(s.q_lora_rank, name="q_a")(x)
+                )
+                q = heads("q_b", features=(h, nope + rot))(c_q)
+            self.sow("intermediates", "queries", q)
             latent = flat(s.kv_lora_rank + rot, name="kv_a")(x)
             c = RMSNorm(self.norm_eps, name="kv_norm")(latent[..., :s.kv_lora_rank])
             kv = heads("kv_b", features=(h, nope + d_v))(c)
@@ -842,7 +874,43 @@ class LMHead(nn.Module):
         return _head_matmul(x, kernel.astype(x.dtype))
 
 
+def _scored_cross_entropy(logits, labels, scored):
+    """Mean softmax cross-entropy over the positions ``scored`` marks, in the
+    arithmetic of ``train/step.py:cross_entropy_loss`` (which ``models/`` may
+    not import): ``-sum(one_hot * log_softmax(logits))`` a position."""
+    one_hot = jax.nn.one_hot(labels, logits.shape[-1])
+    ce = -jnp.sum(one_hot * jax.nn.log_softmax(logits, axis=-1), axis=-1)
+    return jnp.sum(ce * scored) / jnp.maximum(jnp.sum(scored), 1)
+
+
 class TransformerLM(nn.Module):
+    """The decoder: an embedding, ``num_layers`` blocks, a last norm and a
+    head over the vocabulary; its output is the logits ``[B, T, vocab]``.
+
+    With ``ArchSpec.mtp`` (and not in ``decode``) a multi-token-prediction
+    module of depth 1 runs behind the last norm, built from the model's own
+    parts (DeepSeek-V3, arXiv:2412.19437, equations 21 to 25), for the normed
+    last stream ``hbar`` (what the head reads) and the model's own ``tokens``::
+
+        u_i = [N_e(Emb(t_{i+1})) ; N_h(hbar_i)] W_eh      the embedding half first
+        g   = Block(u)                  one more block: the last layer's mixer, the
+                                        model's feed-forward, positions as given
+        P_i = softmax(Head(N_m(g_i)))   Emb and Head are the model's own parameters
+        L   = mean_i -log P_i[t_{i+2}]  over i = 0 .. T-3
+
+    It runs over all T positions, so that its attention call has the trunk's
+    shape (the ids shifted by one and by two, padded with id 0 at the end) and
+    the last two positions are not scored: neither has a target among the
+    model's input, and being last under a causal mixer neither reaches a
+    scored one. ``loss_weight * L`` is sown into ``"losses"`` (the step adds
+    it to the objective), ``L`` into ``"metrics"`` as ``mtp_loss`` and the
+    module's logits into ``"intermediates"``; the model's output stays the main
+    logits. Parameters ``mtp_enorm``, ``mtp_hnorm``, ``mtp_eh_proj``,
+    ``mtp_block`` and ``mtp_norm`` beside the model's; device scopes ``mtp``
+    (the whole module; the block's own scopes lie inside it), ``mtp_join``
+    (the two norms, the concatenation, ``W_eh``) and ``mtp_head`` (the last
+    norm, the head's second use, the cross-entropy)."""
+
     vocab_size: int = 32000
     d_model: int = 512
     num_heads: int = 8
@@ -898,15 +966,15 @@ class TransformerLM(nn.Module):
                 Block, static_argnums=(),
                 policy=_remat_policy(self.remat_policy),
             )
+
+        def switch_width(i: int) -> int:  # the older Switch pair's pattern
+            routed = self.num_experts > 0 and (i + 1) % self.moe_every == 0
+            return self.num_experts if routed else 0
+
         for i in range(self.num_layers):
-            moe = (
-                self.num_experts
-                if self.num_experts > 0 and (i + 1) % self.moe_every == 0
-                else 0
-            )
             x = block(
                 self.num_heads, self.d_ff, self.dtype, self.attention_fn,
-                moe, self.num_kv_heads, self.decode, self.max_decode_len,
+                switch_width(i), self.num_kv_heads, self.decode, self.max_decode_len,
                 self.norm_eps, self.qk_norm,
                 None if i < arch.dense_layers else self.moe, arch,
                 layer_types[i], name="layer_%d" % i,
@@ -915,5 +983,54 @@ class TransformerLM(nn.Module):
         if arch.tie_embeddings:
             logits = _head_matmul(x, embed.embedding.astype(x.dtype).T)
         else:
-            logits = LMHead(self.vocab_size, name="lm_head")(x)
+            head = LMHead(self.vocab_size, name="lm_head")
+            logits = head(x)
+        if arch.mtp is not None and not self.decode:
+            if arch.mtp.depth != 1:
+                raise ValueError(
+                    "a multi-token module of depth %r: one is what runs" % (arch.mtp.depth,)
+                )
+            t = tokens.shape[1]
+            # position i joins token i+1 and is scored against token i+2
+            ahead = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+            targets = jnp.pad(tokens[:, 2:], ((0, 0), (0, min(t, 2))))
+            scored = jnp.broadcast_to(jnp.arange(t) < t - 2, tokens.shape)
+            obs_trace.get_tracer().note_once(
+                "mtp_shape", depth=arch.mtp.depth, tq=t, scored=max(t - 2, 0),
+                loss_weight=arch.mtp.loss_weight, vocab=self.vocab_size,
+                mixer=layer_types[-1], logit_bytes=4 * tokens.size * self.vocab_size,
+            )
+            with jax.named_scope("mtp"):
+                with jax.named_scope("mtp_join"):
+                    joined = jnp.concatenate([
+                        RMSNorm(self.norm_eps, name="mtp_enorm")(
+                            _times(embed(ahead), arch.embedding_multiplier)
+                        ),
+                        RMSNorm(self.norm_eps, name="mtp_hnorm")(x),
+                    ], axis=-1)
+                    u = nn.Dense(
+                        self.d_model, use_bias=False, dtype=self.dtype, name="mtp_eh_proj"
+                    )(joined)
+                g = block(
+                    self.num_heads, self.d_ff, self.dtype, self.attention_fn,
+                    switch_width(self.num_layers), self.num_kv_heads, False,
+                    self.max_decode_len,
+                    self.norm_eps, self.qk_norm, self.moe, arch,
+                    layer_types[-1], name="mtp_block",
+                )(u, positions)
+                with jax.named_scope("mtp_head"):
+                    g = RMSNorm(self.norm_eps, name="mtp_norm")(g)
+                    # the kernel, not the module once more: under the module's
+                    # name the second use would read as the main head's
+                    w = (
+                        embed.embedding.T if arch.tie_embeddings
+                        else head.variables["params"]["kernel"]
+                    )
+                    ahead_logits = _times(
+                        _head_matmul(g, w.astype(g.dtype)), 1.0 / arch.logits_scaling
+                    )
+                    mtp_loss = _scored_cross_entropy(ahead_logits, targets, scored)
+            self.sow("intermediates", "mtp_logits", ahead_logits)
+            self.sow("losses", "mtp_loss", arch.mtp.loss_weight * mtp_loss)
+            self.sow("metrics", "mtp_loss", mtp_loss)
         return _times(logits, 1.0 / arch.logits_scaling)

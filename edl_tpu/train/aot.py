@@ -150,7 +150,7 @@ _in_ladder = threading.local()
 #: them (``obs/profile.py:step_phases`` would then find nothing). Mixed
 #: into every key, this makes such entries miss once.
 #: ``tests/test_timeline.py`` holds it to the scopes the step enters.
-STEP_SCOPES_KEY = "forward,grad_mean,optimizer,numerics/1"
+STEP_SCOPES_KEY = "forward,grad_mean,optimizer,numerics/2"
 
 
 def enable_portable_cache_keys() -> bool:

@@ -1755,3 +1755,159 @@ def test_a_mixers_step_on_the_tpu_path_projects_once(one_chip, monkeypatch, mixe
     assert dict(again) == want
     # forward once and the backward's two, whatever the policy keeps
     assert {leaf: all_passes[leaf] - again[leaf] for leaf in leaves} == dict.fromkeys(leaves, 3)
+
+
+# -- GLM-4.7-Flash: latent attention at 20 heads of 256 / 256, a multi-token module (PR 55) ------
+
+MLA_256 = (1, 20, 8192, 256, 256)  # glm_4_7_flash.steady's six calls: b, h, t, d_qk, d_v
+
+
+def _glm_cell():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "glm_4_7_flash.json")) as f:
+        return root, json.load(f)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash2_compiles_for_v5e_at_twenty_heads_of_256(one_chip, direction):
+    """The grid-pipelined forward and the FUSED backward with q, k, v, dO, o
+    and every gradient 256 wide at T = 8192 (a head's float32 dq accumulator is
+    8.4 MB): the chip's compiler takes the blocks ``_flash2_blocks`` gives and
+    the VMEM limit ``_fused_bwd_vmem`` sets from the shapes."""
+    b, heads, t, d_qk, d_v = MLA_256
+    scale = d_qk ** -0.5
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, v = sds((b, heads, t, d_qk)), sds((b, heads, t, d_v))
+    if direction == "fwd":
+        bq, bk = A._flash2_blocks("fwd", t, t, None)
+        fn = lambda q, k, v: A._flash2_forward(q, k, v, True, scale, bq, bk, False)
+        args, want = (q, q, v), ["_flash2_kernel"]
+    else:
+        bq, bk = A._flash2_blocks("bwd", t, t, None)
+        fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
+            q, k, v, g, lse, delta, True, scale, bq, bk, False
+        )
+        row = sds((b * heads, t), jnp.float32)
+        args, want = (q, q, v, v, row, row), ["_flash2_bwd_kernel"]
+    lowered = jax.jit(fn).lower(*args)
+    assert _kernel_names(lowered.as_text()) == want
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, *args))]
+    if direction == "fwd":
+        assert shapes[0] == (b, heads, t, d_v)
+    else:
+        assert shapes == [(b, heads, t, d_qk), (b, heads, t, d_qk), (b, heads, t, d_v)]
+
+
+def test_the_glm_cells_plan_fits_the_chip_at_the_guides_floor():
+    """The numbers the file records: the tool's plan of the whole step (a
+    described v5e) under the chip's 15.75 GB, the cut at the guide's floor (a
+    leading dense layer and four that follow, 8 experts a layer, an eighth of
+    the vocabulary), and what the chip itself read."""
+    _, config = _glm_cell()
+    plan = config["plan"]
+    (tried,) = plan["tried"]
+    assert tried["parameters"] == 706518528
+    assert tried["total_gb"] == pytest.approx(13.32, abs=0.01) and tried["total_gb"] < plan["chip_gb"]
+    assert tried["left_gb"] == pytest.approx(plan["chip_gb"] - tried["total_gb"], abs=2e-3)
+    assert tried["on_chip"]["ran"] and tried["on_chip"]["correct"]
+    assert config["train"]["seq_len"] == plan["chosen"]["seq_len"] == tried["seq_len"] == 8192
+    share, published = config["share"], config["published"]
+    assert config["n_routed_experts"] * share["chips_a_layer"] == published["n_routed_experts"]
+    assert config["vocab_size"] * share["chips_a_vocabulary"] == published["vocab_size"]
+    assert config["num_hidden_layers"] - config["first_k_dense_replace"] == 4
+    assert config["num_nextn_predict_layers"] == 1
+
+
+@pytest.mark.slow
+def test_the_glm_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded():
+    """``benchmark/tools/compile_for_v5e.py`` on the cell as it runs (about
+    three minutes): the step compiles with its six attention calls' kernels,
+    and the plan's total is within 0.1 GB of the recorded one."""
+    import json
+    import subprocess
+    import sys
+
+    root, config = _glm_cell()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "tools", "compile_for_v5e.py"),
+         "glm_4_7_flash.steady"],
+        capture_output=True, text=True, timeout=1500, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    doc = json.loads(out.stdout.strip().splitlines()[-1])
+    (recorded,) = config["plan"]["tried"]
+    assert doc["parameters"] == recorded["parameters"]
+    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
+    assert doc["tpu_custom_calls"] == recorded["tpu_custom_calls"]
+
+
+def test_a_multi_token_step_on_the_tpu_path_places_the_modules_matmuls(one_chip):
+    """A toy of the cell's model (latent attention with a query rank, a dense
+    layer, expert layers, the multi-token module), lowered as the chip lowers it:
+    ``STEP_PARTS`` places every matmul of the compiled step, the module's joined
+    projection under ``mtp_join`` and the head's second use under ``mtp_head``
+    (not ``head``: the main head's alone), and asked for the module's scopes
+    alone ``mtp`` takes its block's attention kernels with everything else the
+    module runs, so a trace tells them from the trunk's."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, LatentAttentionSpec, MoESpec, MTPSpec, TransformerLM
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    lm = TransformerLM(
+        vocab_size=256, d_model=128, num_heads=4, num_layers=2, d_ff=256,
+        dtype=jnp.bfloat16, remat=True, norm_eps=1e-5,
+        moe=MoESpec(
+            num_experts=16, top_k=4, d_ff=128, norm_topk_prob=True, aux_weight=0.0,
+            z_weight=0.0, score_func="sigmoid", route_scale=1.8, bias_rate=1e-3,
+            shared_d_ff=128, held=(0, 4),
+        ),
+        arch=ArchSpec(
+            layer_types=("latent_attention",) * 2, dense_layers=1, rope_theta=1e6,
+            latent_attention=LatentAttentionSpec(
+                kv_lora_rank=64, qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=128,
+                q_lora_rank=96,
+            ),
+            mtp=MTPSpec(),
+        ),
+    )
+    tokens = np.zeros((1, 512), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    text = lowered.compile().as_text()
+    program = obs_profile.HloProgram(text)
+    census = program.census()
+    assert census["totals"]["matmuls"] > 0 and census["totals"]["unplaced_matmuls"] == 0
+    parts = {key.split("/")[0] for key in census["parts"]}
+    assert {"mtp_join", "mtp_head", "head", "mla_proj", "moe_shared", "moe_experts", "mlp"} <= parts
+    # three attention calls (two layers and the module's block): a forward and a
+    # fused backward each, the module's under ``mtp`` when its scopes are asked alone
+    calls = {name: scope for name, scope in program.scopes(("attn_mla",)).items()
+             if name in program.kernels}
+    assert len(calls) == 3 * 2
+    under_module = {name for name, scope in program.scopes(("mtp", "mtp_join", "mtp_head")).items()
+                    if name in program.kernels and name in calls}
+    assert len(under_module) == 2
