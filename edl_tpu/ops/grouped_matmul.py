@@ -26,40 +26,57 @@ and on ``plain`` ``why`` (``backend``, or ``asked`` by the caller).
 
 from __future__ import annotations
 
+import math
 from functools import partial
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from edl_tpu.obs import trace as obs_trace
 
-# (rows, contracting, columns) tiles of the three Megablox kernels (value, row
-# gradient, weight gradient; for the last "rows" is the contracted dimension).
-# Measured on the v5e at the OLMoE cell's shapes (PERF.md section 6, PR 25):
-# the fastest of those that fit VMEM with all three kernels. The rows of a
-# tile belong to one group or are masked, so the row tile bounds the waste at
-# a group's edge (64 edges of at most 512 rows in 131,072 at the OLMoE cell's
-# shape); the other two keep a whole [K, N] slab of one expert in VMEM across
-# that expert's row tiles. Measured at widths of 1024 and 2048 (OLMoE's and
-# Trinity's experts over a model of 2048), which 1024 divides; elsewhere
-# ``_fit`` takes, for K and for N alike, the largest whole number of lane
-# tiles (128) that divides the dimension and is at most this one's, so that no
-# tile is ragged: at an expert width of 1536, 768 (1024 there is one tile and
-# a masked half, a third of the kernel's work wasted; PERF.md section 6, PR 37,
-# has the probe's numbers for 768 against 512). The row tile is of ALL the
-# groups' rows together (``m``), not of one group's: a tile that spans a
-# group's end is walked once for each group in it, so where groups are small
-# beside 512 rows (``ling_3_0_flash_vl.steady``: 128 rows a held expert
-# expected, four groups a tile) most of a tile's rows are masked for each of
-# them; not tuned here (PERF.md section 7). The latent expert layer
-# (``nemotron_3_super_120b_a12b.steady``) reads ``gmm_tiles`` (512, 1024, 896)
-# over 1024 x 2688 and (512, 896, 1024) over 2688 x 1024 (2688 is 21 lane
-# tiles: 896 = 7 of them is the largest whole divisor under 1024), on a buffer
-# of 5632 rows at 352 rows a held expert expected: a row tile of 512 then holds
-# the edge of one or two groups, and is walked once for each; open, not tuned.
-TILING = (512, 1024, 1024)
+# How Megablox's three kernels are tiled, (rows, contracting, columns) each
+# (``gmm`` for the value, ``gmm`` over the transposed bank for ``d lhs``,
+# ``tgmm`` for ``d rhs``, whose "contracting" and "columns" are the two sides
+# of a bank and whose contracted dimension is the rows). Fitted on the v5e at
+# the expert cells' held-share shapes: ``bench_results/gmm_tile_sweep.py``,
+# its table in PERF.md section 6 (PR 57).
+#
+# - **The row tile.** A tile's rows belong to one group or are masked, and a
+#   tile that holds a group's end is walked once for every group in it: a call
+#   walks about ``live + (G - 1) * tm`` rows, so where a held expert gets a few
+#   hundred rows a tile of 512 is mostly other groups' masked rows. ``gmm``
+#   takes 256 at every length of group measured (it loads its ``[K, tn]`` slab
+#   into the MXU whatever the rows are, and 128 rows do not cover that), 128
+#   where a full call's mean group ``m // G`` is shorter than 256; ``tgmm``,
+#   whose step costs in proportion to its rows (its masks and its transpose
+#   are float32 passes over the row tiles), takes 128 until ``m // G`` reaches
+#   2048 and 256 from there.
+# - **The contracting tile is the whole K wherever that fits** (``gmm``'s grid
+#   is (column tiles, row visits, K tiles), K innermost: with one K tile the
+#   bank's ``[K, tn]`` block keeps its index across a group's consecutive row
+#   tiles and is fetched once a group and column tile; with two it changes at
+#   every grid step and crosses HBM again at every visit). The column tile is
+#   cut, to a whole number of lane tiles that divides N, before K is split.
+#   What fits: two buffers each of the ``[tm, K]`` rows, the ``[K, tn]`` slab
+#   and the ``[tm, tn]`` output, and the float32 accumulator, within
+#   ``_VMEM_COUNTED``: the 16 MiB a kernel is given with no
+#   ``vmem_limit_bytes`` (Megablox passes none) less the 2 MiB Mosaic was
+#   seen to add (0 to 1.8 MiB, by bisecting ``xla_tpu_scoped_vmem_limit_kib``
+#   on a compile for a described v5e). ``tgmm`` contracts the rows and has
+#   nothing to hold.
+# - ``TILING`` is the most each tile is: a row tile, the contracting tile of a
+#   ``gmm`` whose K cannot be whole (and of ``tgmm``'s bank), a column tile.
+#   ``_whole`` takes, for K and for N alike, the largest whole number of lane
+#   tiles (128) that divides the dimension and is at most the tile asked for,
+#   so that no tile is ragged (at an expert width of 1536, 768; at 2688 = 21
+#   lane tiles, 896). No cell has a K that must be split; where one is, and
+#   groups are long, 512 rows were faster than 256 (PR 25, this sweep: 14% at
+#   OLMoE's shape), which this rule does not take up.
+TILING = (256, 1024, 1024)
 _LANES = 128
+_VMEM_COUNTED = (16 - 2) * 2**20
+_KERNELS = ("gmm", "gmm_dlhs", "tgmm")
 
 IMPLEMENTATIONS = ("pallas", "ragged_dot")
 
@@ -81,22 +98,66 @@ def _whole(most: int, dim: int) -> int:
     return most
 
 
-def _fit(tiling: Tuple[int, int, int], m: int, k: int, n: int):
-    """``tiling`` cut to the problem: no tile larger than its dimension, and
-    those of K and N dividing theirs where a whole number of lane tiles does
-    (Megablox masks a ragged last tile of K and N, not one of M, so M is
-    padded by the caller to a whole number of row tiles)."""
-    tm, tk, tn = tiling
-    return min(tm, m), _whole(tk, k), _whole(tn, n)
+def _row_tile(kernel: str, m: int, groups: int) -> int:
+    """The row tile of a call of ``m`` rows in ``groups`` groups: whole lane
+    tiles (the kernels' masks and ``tgmm``'s transposed product are cut in
+    128s), or the whole of a small ``m`` in whole sublanes."""
+    if m <= _LANES:
+        return -(-m // 8) * 8
+    rows = m // groups // (8 if kernel == "tgmm" else 1)
+    return min(max(rows // _LANES, 1) * _LANES, TILING[0])
 
 
-def _note_tiles(kernel: str, m: int, k: int, n: int, tiling):
+def _column_tiles(most: int, n: int):
+    """The column tiles of N to try beside a whole K, widest first:
+    ``_whole``'s, then every smaller whole number of lane tiles that divides
+    N."""
+    first = _whole(most, n)
+    yield first
+    for tile in range((first - 1) // _LANES * _LANES, 0, -_LANES):
+        if n % tile == 0:
+            yield tile
+
+
+def _working_set(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """Bytes of VMEM a ``gmm`` step is counted to hold at a tiling."""
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def _fit(kernel: str, m: int, groups: int, k: int, n: int, itemsize: int):
+    """The tiling of one of the three kernels for ``[m, k]`` rows in
+    ``groups`` groups over banks ``[groups, k, n]``: arithmetic on the shapes
+    alone. No tile is larger than its dimension, and those of K and N divide
+    theirs where a whole number of lane tiles does (Megablox masks a ragged
+    last tile of K and N, not one of M, so M is padded by the caller to whole
+    row tiles)."""
+    tm = _row_tile(kernel, m, groups)
+    if kernel == "tgmm":
+        return tm, _whole(TILING[1], k), _whole(TILING[2], n)
+    if kernel == "gmm_dlhs":  # [m, n] x [n, k]: contracts the bank's columns
+        k, n = n, k
+    for tn in _column_tiles(TILING[2], n):
+        if _working_set(tm, k, tn, itemsize) <= _VMEM_COUNTED:
+            return tm, k, tn
+    return tm, _whole(TILING[1], k), _whole(TILING[2], n)
+
+
+def _tilings(m: int, groups: int, k: int, n: int, itemsize: int):
+    """The three kernels' tilings for one call, in ``_KERNELS``' order."""
+    return tuple(_fit(kernel, m, groups, k, n, itemsize) for kernel in _KERNELS)
+
+
+def _note_tiles(kernel: str, m: int, groups: int, k: int, n: int, tiling):
     """One ``gmm_tiles`` instant in the span ring for each shape a Megablox
     kernel is traced at in a stage (``note_once``), with the tiling it was
-    given."""
+    given and what that was chosen from: the groups, a full call's mean rows a
+    group, and the most grid steps along the rows the call can take (a row
+    tile is visited once, and once more for each group that starts inside
+    one)."""
     obs_trace.get_tracer().note_once(
         "gmm_tiles", kernel=kernel, rows=m, contracting=k, columns=n,
-        tiling=list(tiling), path="kernel",
+        tiling=list(tiling), path="kernel", groups=groups,
+        rows_a_group=m // groups, visits_bound=-(-m // tiling[0]) + groups - 1,
     )
     return tiling
 
@@ -111,32 +172,34 @@ def _megablox():
     )
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _pallas(lhs, rhs, group_sizes, interpret):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _pallas(lhs, rhs, group_sizes, tilings, interpret):
+    """``tilings``: the three kernels' (``_tilings``), of which the rows of
+    ``lhs`` are whole tiles."""
     m, k = lhs.shape
-    n = rhs.shape[2]
+    groups, _, n = rhs.shape
     with obs_trace.span("kernel_trace", kernel="gmm"):
         return _megablox().gmm(
             lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=_note_tiles("gmm", m, k, n, _fit(TILING, m, k, n)),
+            tiling=_note_tiles("gmm", m, groups, k, n, tilings[0]),
             interpret=interpret,
         )
 
 
-def _pallas_fwd(lhs, rhs, group_sizes, interpret):
-    return _pallas(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+def _pallas_fwd(lhs, rhs, group_sizes, tilings, interpret):
+    return _pallas(lhs, rhs, group_sizes, tilings, interpret), (lhs, rhs, group_sizes)
 
 
-def _pallas_bwd(interpret, residuals, grad):
+def _pallas_bwd(tilings, interpret, residuals, grad):
     lhs, rhs, group_sizes = residuals
     m, k = lhs.shape
-    n = rhs.shape[2]
+    groups, _, n = rhs.shape
     backend = _megablox()
     grad = grad.astype(lhs.dtype)
     with obs_trace.span("kernel_trace", kernel="gmm_dlhs"):
         d_lhs = backend.gmm(
             grad, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=_note_tiles("gmm_dlhs", m, n, k, _fit(TILING, m, n, k)),
+            tiling=_note_tiles("gmm_dlhs", m, groups, n, k, tilings[1]),
             transpose_rhs=True,
             interpret=interpret,
         )
@@ -144,8 +207,8 @@ def _pallas_bwd(interpret, residuals, grad):
         d_rhs = backend.tgmm(
             lhs.swapaxes(0, 1), grad, group_sizes,
             preferred_element_type=rhs.dtype,
-            tiling=_note_tiles("tgmm", m, k, n, _fit(TILING, m, k, n)),
-            num_actual_groups=rhs.shape[0],
+            tiling=_note_tiles("tgmm", m, groups, k, n, tilings[2]),
+            num_actual_groups=groups,
             interpret=interpret,
         )
     return d_lhs, d_rhs, None
@@ -189,10 +252,11 @@ def grouped_matmul(
             "grouped_matmul: implementation %r is none of %r"
             % (implementation, IMPLEMENTATIONS)
         )
-    m = lhs.shape[0]
-    tm = min(TILING[0], -(-m // 8) * 8)  # a row tile is whole sublanes
-    pad = -m % tm
+    m, k = lhs.shape
+    groups, _, n = rhs.shape
+    tilings = _tilings(m, groups, k, n, lhs.dtype.itemsize)
+    pad = -m % math.lcm(*(tiling[0] for tiling in tilings))
     if pad:  # rows that belong to no group, cut off again below
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    out = _pallas(lhs, rhs, group_sizes, interpret)
+    out = _pallas(lhs, rhs, group_sizes, tilings, interpret)
     return out[:m] if pad else out

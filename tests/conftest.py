@@ -29,6 +29,20 @@ import pytest
 
 TOY_WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy_worker.py")
 
+# The expert layer's call shapes in the benchmark's expert cells: (held groups,
+# the rows of the held experts' buffer as ``models/moe.py`` sizes it, the whole
+# N * k, the width an expert reads, its own width).
+EXPERT_CELL_SHAPES = {
+    "olmoe_1b_7b": (64, 131072, 131072, 2048, 1024),  # all 64 held: no buffer
+    "trinity_mini": (16, 16384, 65536, 2048, 1024),
+    "lfm2_24b_a2b": (8, 8192, 32768, 2048, 1536),  # 1024 does not divide 1536
+    "keye_vl_2_0_30b_a3b": (16, 32768, 131072, 2048, 768),
+    "ling_3_0_flash_vl": (8, 2048, 65536, 2560, 768),
+    "nemotron_3_super_120b_a12b": (8, 5632, 180224, 1024, 2688),  # 2688 = 21 lane tiles
+    "solar_open2_250b": (8, 3280, 65536, 4096, 1280),  # no row tile divides 3280
+    "glm_4_7_flash": (8, 8192, 32768, 2048, 1536),
+}
+
 
 @pytest.fixture()
 def store():

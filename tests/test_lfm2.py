@@ -354,21 +354,6 @@ def test_the_layer_sows_how_far_its_bias_leans(whole_layer):
 # -- the grouped matmul at a width its measured tiling does not divide ---------
 
 
-def test_the_tiling_follows_the_shape():
-    fit = gmm_module._fit
-    assert gmm_module.TILING == (512, 1024, 1024)
-    # at the widths it was measured at, what it was
-    assert fit(gmm_module.TILING, 65536, 2048, 1024) == (512, 1024, 1024)
-    assert fit(gmm_module.TILING, 65536, 1024, 2048) == (512, 1024, 1024)
-    assert fit(gmm_module.TILING, 131072, 2048, 2048) == (512, 1024, 1024)
-    assert fit(gmm_module.TILING, 128, 64, 96) == (128, 64, 96)      # the toys': no larger
-    # 1536 is one and a half tiles of 1024: 768 divides it, in K and in N
-    assert fit(gmm_module.TILING, 4096, 2048, 1536) == (512, 1024, 768)
-    assert fit(gmm_module.TILING, 4096, 1536, 2048) == (512, 768, 1024)
-    # no whole number of lane tiles divides: the ragged last tile, as before
-    assert fit(gmm_module.TILING, 4096, 1100, 1300) == (512, 1024, 1024)
-
-
 @functools.lru_cache(maxsize=None)
 def megablox_and_a_loop(shape):
     """``[(value, d_lhs, d_rhs) by the kernels, by a float32 loop], live`` at a
@@ -402,8 +387,9 @@ def megablox_and_a_loop(shape):
 @pytest.mark.parametrize("shape", ["up", "down"])
 @pytest.mark.parametrize("what", ["value", "d_lhs", "d_rhs"])
 def test_megablox_at_width_1536_with_ragged_and_empty_groups(shape, what):
-    """The three kernels in the interpreter at the tiles the rule gives (768
-    over the 1536, in N for gate/up and in K for down), against a float32
+    """The three kernels in the interpreter at the tiles the rule gives (rows
+    of 128; 768 over the 1536 in N for gate/up, the 1536 whole in K for down;
+    ``tests/test_gmm_tiles.py`` holds the rule itself), against a float32
     loop: groups that end inside a row tile, an empty one, rows past the sum."""
     (got, want), live = megablox_and_a_loop(shape)
     index = ("value", "d_lhs", "d_rhs").index(what)
@@ -428,9 +414,11 @@ def test_each_traced_shape_leaves_one_gmm_tiles_instant_a_kernel():
     found = [e["args"] for e in tracer.to_events() if e["name"] == "gmm_tiles"][before:]
     assert sorted(e["kernel"] for e in found) == ["gmm", "gmm_dlhs", "tgmm"]
     by_kernel = {e["kernel"]: e for e in found}
-    assert by_kernel["gmm"]["tiling"] == [256, 128, 768]
-    assert by_kernel["gmm_dlhs"]["tiling"] == [256, 768, 128]
-    assert by_kernel["tgmm"]["tiling"] == [256, 128, 768]
+    assert by_kernel["gmm"]["tiling"] == [128, 128, 768]
+    assert by_kernel["gmm_dlhs"]["tiling"] == [128, 1536, 128]  # the contracted width whole
+    assert by_kernel["tgmm"]["tiling"] == [128, 128, 768]
+    for e in found:  # what the row tile was chosen from, and what it bounds
+        assert (e["groups"], e["rows_a_group"], e["visits_bound"]) == (2, 128, 2 + 2 - 1)
 
 
 # -- the model -------------------------------------------------------------------

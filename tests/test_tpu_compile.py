@@ -25,6 +25,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
 import jax
 import jax.numpy as jnp
+from conftest import EXPERT_CELL_SHAPES
 
 A = importlib.import_module("edl_tpu.ops.attention")
 
@@ -359,27 +360,31 @@ def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
-@pytest.mark.parametrize("shape", ["up", "down"])
-def test_megablox_compiles_for_v5e_at_an_expert_width_of_1536(one_chip, shape):
-    """The three Megablox kernels at the held experts' shape of the
-    short-convolution hybrid (8 groups, an 8192-row buffer, 2048 x 1536), on
-    the tiles ``_fit`` gives where 1024 does not divide: 768 over the 1536, in
-    N for gate / up and in K for down. Three custom calls."""
+@pytest.mark.parametrize("bank", ["up", "down"])
+@pytest.mark.parametrize("cell", [c for c in EXPERT_CELL_SHAPES if c != "glm_4_7_flash"])  # LFM2's shapes
+def test_megablox_compiles_for_v5e_on_the_tiles_of_a_cells_held_share(one_chip, cell, bank):
+    """The three Megablox kernels at a cell's held-share shape on the tiles
+    ``_fit`` gives (PR 57: rows of 256 and 128, the contracting dimension
+    whole), under the 16 MiB of scoped VMEM a kernel has by default: Megablox
+    asks for no more, and what Mosaic adds to the counted working set is not
+    in the count. Three custom calls."""
     gm = importlib.import_module("edl_tpu.ops.grouped_matmul")
 
     def sds(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    k, n, tiles = {"up": (2048, 1536, (512, 1024, 768)),
-                   "down": (1536, 2048, (512, 768, 1024))}[shape]
-    assert gm._fit(gm.TILING, 8192, k, n) == tiles
+    groups, rows, _, d, f = EXPERT_CELL_SHAPES[cell]
+    k, n = (d, f) if bank == "up" else (f, d)
+    tilings = gm._tilings(rows, groups, k, n, 2)
+    rows += -rows % math.lcm(*(t[0] for t in tilings))  # as ``grouped_matmul`` pads them
+    assert [t[1] for t in tilings[:2]] == [k, n]  # K whole, forward and for d lhs
 
     def value_and_grads(lhs, rhs, sizes, dy):
-        out, vjp = jax.vjp(lambda a, b: gm._pallas(a, b, sizes, False), lhs, rhs)
+        out, vjp = jax.vjp(lambda a, b: gm._pallas(a, b, sizes, tilings, False), lhs, rhs)
         return (out, *vjp(dy))
 
     compiled = jax.jit(value_and_grads).lower(
-        sds((8192, k)), sds((8, k, n)), sds((8,), jnp.int32), sds((8192, n))
+        sds((rows, k)), sds((groups, k, n)), sds((groups,), jnp.int32), sds((rows, n))
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
 
@@ -1122,35 +1127,6 @@ def test_the_kda_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(h
 
 # -- nemotron_3_super_120b_a12b.steady: experts in a latent, Mamba-2 in groups --
 
-@pytest.mark.parametrize("shape", ["up", "down"])
-def test_megablox_compiles_for_v5e_in_a_latent_of_1024_at_a_width_of_2688(one_chip, shape):
-    """The three Megablox kernels at the held experts' shape of the latent
-    expert layer (8 groups, a buffer of 5632 rows: twice the 2816 that 8192
-    tokens x 22 choices x 8 / 512 expect, 352 a group; 1024 x 2688), on the
-    tiles ``_fit`` gives where 1024 does not divide 2688 = 21 lane tiles: 896
-    (seven lane tiles, three of them a row), in N for up and in K for down.
-    Three custom calls."""
-    gm = importlib.import_module("edl_tpu.ops.grouped_matmul")
-
-    def sds(dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    rows = 5632
-    assert rows == min(8192 * 22, -(-2 * 8192 * 22 * 8 // (8 * 512)) * 8)  # the layer's buffer
-    k, n, tiles = {"up": (1024, 2688, (512, 1024, 896)),
-                   "down": (2688, 1024, (512, 896, 1024))}[shape]
-    assert gm._fit(gm.TILING, rows, k, n) == tiles
-
-    def value_and_grads(lhs, rhs, sizes, dy):
-        out, vjp = jax.vjp(lambda a, b: gm._pallas(a, b, sizes, False), lhs, rhs)
-        return (out, *vjp(dy))
-
-    compiled = jax.jit(value_and_grads).lower(
-        sds((rows, k)), sds((8, k, n)), sds((8,), jnp.int32), sds((rows, n))
-    ).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
-
-
 @pytest.mark.parametrize("path", ["plain", "kernels"])
 def test_ssd_scan_compiles_for_v5e_in_four_groups_at_a_chunk_of_128(one_chip, path):
     """The chunked scan at one sequence of 8192, the 64 heads of 64 the cell
@@ -1629,6 +1605,7 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
     import optax
 
     from edl_tpu.models import ArchSpec, MoESpec, TransformerLM
+    from edl_tpu.obs import trace as obs_trace
     from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
 
     layers, banks = 1, 3 if gated else 2
@@ -1653,11 +1630,18 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
     loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
         logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
     )
+    obs_trace.get_tracer().reset_notes()
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         lowered = make_train_step(loss, numerics=False).lower(
             described(state), described((tokens, tokens))
         )
-    text = lowered.compile().as_text()
+    text = lowered.compile().as_text()  # under the default scoped VMEM, on the rule's tiles
+    tiles = [a for name, a in obs_trace.get_tracer().notes() if name == "gmm_tiles"]
+    assert {a["kernel"] for a in tiles} == {"gmm", "gmm_dlhs", "tgmm"}
+    for a in tiles:  # 8 held groups in a buffer of 2048 or the whole 4096
+        assert a["groups"] == 8 and a["rows_a_group"] == a["rows"] // 8 in (256, 512)
+        assert a["tiling"][0] == (128 if a["kernel"] == "tgmm" else 256)
+        assert a["visits_bound"] == a["rows"] // a["tiling"][0] + 7
     # no cond hands on a [N k, F] array: the large branch keeps nothing, and
     # the join of the two branches' residuals would allocate it on every step
     conds = [line.split(" conditional(")[0] for line in text.splitlines() if " conditional(" in line]
