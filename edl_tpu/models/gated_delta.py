@@ -22,8 +22,14 @@ projection's output, read in place by ``causal_conv_silu``.
 
 The device time of its four parts carries the names ``gdn_proj`` (both
 projections), ``gdn_conv``, ``gdn_scan`` (the L2 norms, ``beta``, ``g`` and the
-chunked rule) and ``gdn_gate`` (``jax.named_scope``;
-``obs/profile.py:step_scopes`` joins them to a trace). Into ``"metrics"`` it
+chunked rule: on a TPU backend at bfloat16, a chunk of 64, a ``T`` of whole
+lane tiles and widths in 16s its chunk-local stage is the Pallas kernels
+``gdn_inverse``, ``gdn_operands`` and ``gdn_backward``, custom calls of those
+names in a trace, between them the carry's two loops and the output stage in
+plain XLA; ``q``, ``k`` and ``v`` go to the rule as ``[B, T, H, d]``, whose
+``[B, H d, T]`` view, the steps minor as the convolution wrote them, the
+kernels read in place; everywhere else the plain form) and ``gdn_gate``
+(``jax.named_scope``; ``obs/profile.py:step_scopes`` joins them to a trace). Into ``"metrics"`` it
 sows ``gdn_decay_mean`` (the mean of ``exp(g)`` over tokens and heads: how fast
 the state forgets), ``gdn_beta_mean`` and ``gdn_state_absmax`` (the largest
 magnitude in the state after the last step: the health of a rule whose
@@ -36,8 +42,10 @@ name ``gdn_out`` and, inside the rule, what its sequential carry leaves the
 name ``gdn_carry`` and every chunk's ``T`` ``gdn_inverse``
 (``ops/gated_delta.py:REMAT_NAMES``): a policy that saves them
 (``TransformerLM.remat_policy`` ``"save_flash"``) runs the carry's loop once
-forward and once in reverse a layer and the solve once; one that saves none
-runs the forward loop and the solve again when the block is recomputed.
+forward and once in reverse a layer and the solve (``gdn_inverse``) once, and
+the rest of the chunk-local stage (``gdn_operands``) again when the backward
+reaches the rule; one that saves none runs the forward loop and the solve
+again when the block is recomputed.
 The in projection's output bears the name ``mixer_in``
 (``models/mamba.py:projected``, whole and before the slices): the same policy
 hands it to the recomputation, which then runs no ``in_proj`` matmul again.

@@ -215,7 +215,7 @@ def saved_bytes(chunk, chunks, heads, d_k, d_v, itemsize, batch=1):
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
-                     return_final_state: bool = False):
+                     return_final_state: bool = False, interpret: bool = False):
     """``o`` ``[B, T, H, d_v]`` in ``q``'s dtype (and the final state, float32
     ``[B, H, d_k, d_v]``, with ``return_final_state``).
 
@@ -226,6 +226,24 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     not depend on it beyond rounding; a ``T`` it does not divide is padded with
     steps of ``g = 0``, ``beta = 0`` and zero rows, which leave the state as it
     is.
+
+    **Two forms of the chunk-local stage** (from the inputs to the carry's
+    operands ``w``, ``u``, ``k_out``, ``whole`` and the output stage's ``q_in``
+    and masked scores), one contract, decided from what the call can see as
+    :func:`kda_rule`'s is: on a TPU backend, for bfloat16 ``q``, ``k``, ``v``,
+    a chunk of 64, a ``T`` of whole lane tiles (128 steps, two chunks: a grid
+    step) and widths that are multiples of 16, the three Pallas kernels
+    ``gdn_inverse``, ``gdn_operands`` and ``gdn_backward`` under one
+    ``jax.custom_vjp`` (:func:`_scalar_kernels`); everywhere else the plain
+    form (:func:`_scalar_plain`), which is also the kernels' reference.
+    ``interpret`` runs the kernels in the Pallas interpreter (tests on the
+    CPU). The kernels hold **the steps along the lanes** (``[B, H d, T]``, the
+    view of ``[B, T, H, d]`` that the mixer's convolution writes and XLA keeps
+    through the norms), so a head is ``d`` sublanes of a block wherever it
+    starts: any count of heads, an odd last one by itself after the loop over
+    pairs, and the cell's 15 of 96 / 192 as they are, nothing padded and
+    nothing copied. The carry (``carried_states``) and the output stage are
+    plain XLA either way.
     """
     batch, t, h, d_k = q.shape
     d_v = v.shape[-1]
@@ -239,6 +257,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         )
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError("gated_delta_rule: chunk %d is not a power of two" % chunk)
+    why_plain = _kernels_refuse(q, k, v, t, chunk, interpret, along_lanes=True)
     size = chunk
     pad = -t % size
     if pad:
@@ -246,28 +265,62 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
             jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
             for a in (q, k, v, g, beta)
         )
-    nc = (t + pad) // size
+    steps = t + pad
+    nc = steps // size
     f32, dtype = jnp.float32, q.dtype
-    # once a shape and stage; plain XLA is the only form, so no ``path``
+    # once a shape and stage: which form the chunk-local stage took, and why
+    # where it is the plain one
     obs_trace.get_tracer().note_once(
         "gdn_chunks", chunk=size, chunks=nc, heads=h, d_k=d_k, d_v=d_v,
         state_bytes=4 * h * d_k * d_v, solve=SOLVE, carry="saved",
         saved_bytes=saved_bytes(
             size, nc, h, d_k, d_v, jnp.dtype(dtype).itemsize, batch
         ),
+        **(dict(path="kernel") if why_plain is None else dict(path="plain", why=why_plain)),
     )
+    if why_plain is None:
+        # the steps along the lanes, a head's channels one under another: as
+        # the mixer's convolution left them, so a view and no copy
+        lanes = lambda a: jnp.swapaxes(a.reshape(batch, steps, -1), 1, 2)  # noqa: E731
+        w, u, k_out, whole, q_in, scores = _scalar_kernels(
+            lanes(q), lanes(k), lanes(v), lanes(g.astype(f32)), lanes(beta.astype(f32)),
+            interpret,
+        )
+        # a tile a head as the kernels wrote them: the readers' order of axes
+        k_out, q_in = jnp.swapaxes(k_out, 2, 3), jnp.swapaxes(q_in, 2, 3)
+        whole = whole.reshape(nc, batch, h)
+    else:
+        w, u, k_out, whole, q_in, scores, _ = _scalar_plain(q, k, v, g, beta, size)
+    o, state = _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t)
+    if return_final_state:
+        return o, state
+    return o
+
+
+def _scalar_plain(q, k, v, g, beta, size):
+    """The scalar rule's chunk-local stage in plain ``jax.numpy``: everything
+    of the rule that depends on one chunk of one head only. ``q``, ``k`` ``[B,
+    T, H, d_k]``, ``v`` ``[B, T, H, d_v]``, ``g``, ``beta`` ``[B, T, H]``, ``T``
+    a multiple of ``size``; returns the carry's operands chunks first (``w``
+    ``[n b h c k]``, ``u`` ``[n b h c v]`` float32, ``k_out`` ``[n b c h k]``,
+    ``whole`` ``[n b h]`` float32), the output stage's (``q_in`` ``[b n c h
+    k]``, the causal-masked decayed scores ``[b n h c s]``) and every chunk's
+    float32 ``T`` ``[b n h c s]``."""
+    batch, steps, h, d_k = q.shape
+    nc = steps // size
+    f32, dtype = jnp.float32, q.dtype
     dot = dict(preferred_element_type=f32)
 
     # everything below: b batch, n chunk, c / s step in a chunk, h head,
     # k key width, v value width
     q = q.reshape(batch, nc, size, h, d_k)
     k = k.reshape(batch, nc, size, h, d_k)
-    v = v.reshape(batch, nc, size, h, d_v)
-    steps = lambda a: jnp.moveaxis(  # noqa: E731 — heads before steps
+    v = v.reshape(batch, nc, size, h, v.shape[-1])
+    by_head = lambda a: jnp.moveaxis(  # noqa: E731 — heads before steps
         a.astype(f32).reshape(batch, nc, size, h), 2, -1
     )
-    beta = steps(beta)                                           # [b n h c]
-    gamma = jnp.cumsum(steps(g), axis=-1)
+    beta = by_head(beta)                                         # [b n h c]
+    gamma = jnp.cumsum(by_head(g), axis=-1)
     by_step = lambda a: jnp.moveaxis(a, -1, 2)[..., None]  # noqa: E731 — [b n c h 1]
 
     lower = jnp.tril(jnp.ones((size, size), bool))
@@ -277,11 +330,9 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
 
     # inside a chunk: the system, its inverse, and what it makes of K and V
     kk = jnp.einsum("bnchk,bnshk->bnhcs", k, k, **dot)
-    system = jnp.where(
-        jnp.tril(jnp.ones((size, size), bool), -1),
-        beta[..., None] * kk * between, 0.0,
-    )
-    inverse = unit_lower_inverse(system).astype(dtype)
+    system = jnp.where(jnp.tril(lower, -1), beta[..., None] * kk * between, 0.0)
+    exact = unit_lower_inverse(system)
+    inverse = exact.astype(dtype)
     k_in = (k.astype(f32) * by_step(beta * jnp.exp(gamma))).astype(dtype)
     v_in = (v.astype(f32) * by_step(beta)).astype(dtype)
     w = jnp.einsum("bnhcs,bnshk->bnhck", inverse, k_in, **dot).astype(dtype)
@@ -289,31 +340,37 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     to_end = jnp.exp(gamma[..., -1:] - gamma)                    # [b n h c]
     k_out = (k.astype(f32) * by_step(to_end)).astype(dtype)
     whole = jnp.exp(gamma[..., -1])                              # [b n h]
+    q_in = (q.astype(f32) * by_step(jnp.exp(gamma))).astype(dtype)
+    scores = jnp.einsum("bnchk,bnshk->bnhcs", q, k, **dot)
+    chunks_first = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
+    return (
+        *(chunks_first(a) for a in (w, u, k_out, whole)), q_in,
+        (scores * between).astype(dtype), exact,
+    )
 
-    # from chunk to chunk, the state in float32
+
+def _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t):
+    """From the chunk-local stage's six operands (as :func:`_scalar_plain` and
+    :func:`_local_plain` lay them out) to the first ``t`` steps' ``o`` ``[B, t,
+    H, d_v]`` in the operands' dtype and the final state: the state from chunk
+    to chunk in float32 (``carried_states``), then every chunk's outputs at
+    once, what it inherits and what it wrote itself."""
+    nc, batch, h, size, d_k = w.shape
+    d_v = u.shape[-1]
+    f32, dtype = jnp.float32, w.dtype
+    dot = dict(preferred_element_type=f32)
     if initial_state is None:
         state = jnp.zeros((batch, h, d_k, d_v), f32)
     else:
         state = initial_state.astype(f32)
-    chunks_first = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
-    states, new, state = carried_states(
-        state, *(chunks_first(a) for a in (w, u, k_out, whole))
-    )
+    states, new, state = carried_states(state, w, u, k_out, whole)
     # a cast of what is saved: the backward needs no copy of its own
     entering = jnp.moveaxis(states.astype(dtype), 0, 1)          # [b n h k v]
     new = jnp.moveaxis(new, 0, 1)                                # [b n h c v]
-
-    # every chunk's outputs: what it inherits, and what it wrote itself
-    q_in = (q.astype(f32) * by_step(jnp.exp(gamma))).astype(dtype)
     inherited = jnp.einsum("bnchk,bnhkv->bnchv", q_in, entering, **dot)
-    scores = jnp.einsum("bnchk,bnshk->bnhcs", q, k, **dot)
-    own = jnp.einsum(
-        "bnhcs,bnhsv->bnchv", (scores * between).astype(dtype), new, **dot
-    )
-    o = (inherited + own).reshape(batch, t + pad, h, d_v)[:, :t].astype(dtype)
-    if return_final_state:
-        return o, state
-    return o
+    own = jnp.einsum("bnhcs,bnhsv->bnchv", scores, new, **dot)
+    o = (inherited + own).reshape(batch, nc * size, h, d_v)[:, :t].astype(dtype)
+    return o, state
 
 
 # -- a decay for every key channel (Kimi delta attention) --------------------
@@ -402,8 +459,8 @@ def _local_plain(q, k, v, g, beta, size):
 # rows of the mixer's own ``[B, T, H d]`` arrays (a free reshape of ``[B, T, H,
 # d]``; a head is ``d`` lanes of a row), so no neighbour transposes, and the
 # heads are walked by a loop inside the body. Per head everything is a ``[C,
-# d]`` or ``[C, C]`` tile: eight float32 registers' worth. A scalar decay a
-# step (``gated_delta_rule``) is the case of ``g`` equal along the lanes.
+# d]`` or ``[C, C]`` tile: eight float32 registers' worth. (A scalar decay a
+# step, ``gated_delta_rule``, has kernels of its own further down.)
 
 _KERNEL_CHUNK = 64       # the chunk the kernels are written for
 
@@ -412,17 +469,20 @@ def _iota(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
-def _running_sum(a, reverse=False, axis=0):
+def _running_sum(a, reverse=False, axis=0, period=None):
     """The running sum of ``a`` ``[C, d]`` down its rows (``reverse``: up;
-    ``axis`` 1: along each row), by ``log2(C)`` shifted additions in float32
-    (Mosaic lowers no ``cumsum``)."""
+    ``axis`` 1: along each row; ``period``: from anew every so many, a power of
+    two), by ``log2(C)`` shifted additions in float32 (Mosaic lowers no
+    ``cumsum``)."""
     from jax.experimental.pallas import tpu as pltpu
 
     size, at = a.shape[axis], _iota(a.shape, axis)
+    if period:
+        at = at & (period - 1)
     s = 1
-    while s < size:
+    while s < (period or size):
         if reverse:
-            a = a + jnp.where(at < size - s, pltpu.roll(a, size - s, axis=axis), 0.0)
+            a = a + jnp.where(at < (period or size) - s, pltpu.roll(a, size - s, axis=axis), 0.0)
         else:
             a = a + jnp.where(at >= s, pltpu.roll(a, s, axis=axis), 0.0)
         s *= 2
@@ -543,13 +603,15 @@ def _halved_back(levels, k32, q32, d_kk, d_scores, dtype):
     return d_q + own * k32, d_k + own * q32, d_gamma + d_last, kk
 
 
-def _solve(system):
+def _solve(system, rounds=None):
     """``unit_lower_inverse``'s rounds on one ``[C, C]`` float32 tile whose
     strict lower triangle is the system (the rest is masked away here). The
-    first round, ``I - I A_1 I``, is taken without its two products."""
+    first round, ``I - I A_1 I``, is taken without its two products. Fewer
+    ``rounds`` leave diagonal blocks of ``1 << rounds`` rows inverted, each
+    for itself: all there is to a tile that is zero outside them."""
     row, col = _iota(system.shape, 0), _iota(system.shape, 1)
     inverse = None
-    for shift in range(system.shape[0].bit_length() - 1):  # blocks of 1 << shift rows
+    for shift in range(rounds or system.shape[0].bit_length() - 1):  # blocks of 1 << shift rows
         joins = ((row >> (shift + 1)) == (col >> (shift + 1))) & ((row >> shift) > (col >> shift))
         joined = jnp.where(joins, system, 0.0)
         if inverse is None:
@@ -575,14 +637,19 @@ def _over_heads(heads, body, init=0, width=2):
     """``body(pair, half, carry)`` for every head ``2 * pair + half`` in turn,
     a pair to a round of the loop: the scheduler may interleave the two bodies
     (Mosaic's own ``unroll`` is all or nothing), and ``half`` is static. With
-    ``width``, that many heads a round: head ``width * pair + half``."""
+    ``width``, that many heads a round: head ``width * pair + half``. Heads
+    that fill no round (an odd last one) come after the loop, ``pair`` then a
+    number and not the loop's index."""
 
     def together(pair, carry):
         for half in range(width):
             carry = body(pair, half, carry)
         return carry
 
-    return jax.lax.fori_loop(0, heads // width, together, init)
+    carry = jax.lax.fori_loop(0, heads // width, together, init)
+    for half in range(heads % width):
+        carry = body(heads // width, half, carry)
+    return carry
 
 
 def _inverse_at(inverse_ref, pair, half):
@@ -847,22 +914,334 @@ def _local_kernels_bwd(interpret, residuals, cotangents):
 _local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
 
 
-def _kernels_refuse(q, k, v, steps, chunk, interpret):
+def _kernels_refuse(q, k, v, steps, chunk, interpret, along_lanes=False):
     """Why the chunk-local stage of these operands is not the kernels', or
     None where it is: the first of a TPU backend or the interpreter
     (``backend``), bfloat16 operands (``dtype``), the kernels' chunk
-    (``chunk``), a length the chunk divides (``steps``), the heads in pairs
-    (``heads_odd``) and widths of whole lane tiles (``width``) that does not
-    hold."""
+    (``chunk``), a length of whole grid steps (``steps``: a chunk; two, a lane
+    tile, for the scalar rule's kernels, which hold the steps ``along_lanes``)
+    and a head that tiles (``heads_odd``, ``width``) that does not hold. With
+    the heads along the lanes (``kda_rule``) they come in pairs of whole lane
+    tiles; along the sublanes a head is any multiple of a packed bfloat16
+    tile's 16 rows, and any count of them."""
+    tile = 16 if along_lanes else 128
     conditions = (
         ("backend", interpret or jax.default_backend() == "tpu"),
         ("dtype", q.dtype == k.dtype == v.dtype == jnp.bfloat16),
         ("chunk", chunk == _KERNEL_CHUNK),
-        ("steps", steps % chunk == 0),
-        ("heads_odd", q.shape[2] % 2 == 0),
-        ("width", q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0),
+        ("steps", steps % (_LANE_STEPS if along_lanes else chunk) == 0),
+        ("heads_odd", along_lanes or q.shape[2] % 2 == 0),
+        ("width", q.shape[-1] % tile == 0 and v.shape[-1] % tile == 0),
     )
     return next((why for why, met in conditions if not met), None)
+
+
+# -- a decay a step: the scalar rule's chunk-local stage as kernels ----------
+#
+# The same frame and the same three roles, **time along the lanes**: the
+# mixer's convolution leaves ``[q | k | v]`` with the steps minor, XLA keeps
+# them so through the norms, and the kernels read them so (``[B, H d, T]``; a
+# head is ``d`` sublanes at ``h d``, whatever ``d`` a multiple of 16 and
+# however many heads: no lane tile to fit, nothing padded, nothing
+# transposed on the way in or on the way back). A grid step is a lane tile
+# of steps, two chunks of 64, of every head; the two are worked as one
+# ``[128, 128]`` tile that is zero outside its two diagonal blocks, so every
+# product is the MXU's own size. With one ``gamma`` a step and head,
+# ``exp(gamma_i - gamma_j)`` factors out of the contraction over the key
+# channels: a tile is one ``K K^T`` and one ``Q K^T`` under a mask of ``exp``
+# of a difference that is never positive, where the halving form pays six
+# ``exp`` and six masked products. ``g`` and ``beta`` are ``[H, 128]`` blocks:
+# the running sum of every head at once.
+
+_LANE_STEPS = 2 * _KERNEL_CHUNK   # steps a grid step: a lane tile, two chunks
+
+
+def _rows_of(h, width):
+    """The sublanes of head ``h`` in a block of ``heads * width`` rows; ``h``
+    the loop's index or, in an odd tail, a number."""
+    from jax.experimental import pallas as pl
+
+    start = h * width
+    return pl.ds(start if isinstance(h, int) else pl.multiple_of(start, math.gcd(width, 128)), width)
+
+
+def _row_of(rows, h):
+    """Head ``h``'s row ``[1, L]`` of ``rows`` ``[H, L]``."""
+    return jnp.sum(jnp.where(_iota(rows.shape, 0) == h, rows, 0.0), axis=0, keepdims=True)
+
+
+def _turned(vector):
+    """A row ``[1, L]`` as the column ``[L, 1]``, a column as the row: off the
+    diagonal of a tile, exactly."""
+    size = max(vector.shape)
+    on = _iota((size, size), 0) == _iota((size, size), 1)
+    return jnp.sum(jnp.where(on, vector, 0.0), axis=int(vector.shape[0] == 1), keepdims=True)
+
+
+def _same_chunk(shape):
+    """``[L, L]``: whether row and column are steps of one chunk."""
+    bits = _KERNEL_CHUNK.bit_length() - 1
+    return (_iota(shape, 0) >> bits) == (_iota(shape, 1) >> bits)
+
+
+def _decay_between(gamma):
+    """``[L, L]``: ``exp(gamma_i - gamma_j)`` for a step ``i`` on or after
+    ``j`` in ``j``'s chunk, zeros elsewhere, for a head's running sums
+    ``gamma`` ``[1, L]`` (each chunk's from its own first step)."""
+    shape = (gamma.shape[1],) * 2
+    live = _same_chunk(shape) & (_iota(shape, 0) >= _iota(shape, 1))
+    return jnp.exp(jnp.where(live, _turned(gamma) - gamma, -jnp.inf))
+
+
+def _folded(tile):
+    """A ``[2 C, 2 C]`` tile that is zero outside its two diagonal blocks as
+    ``[C, 2 C]``, the blocks side by side: no lane of padding in what a layer
+    saves. :func:`_unfolded` is the way back."""
+    return tile[:_KERNEL_CHUNK] + tile[_KERNEL_CHUNK:]
+
+
+def _unfolded(blocks):
+    tile = jnp.concatenate([blocks, blocks], axis=0)
+    return jnp.where(_same_chunk(tile.shape), tile, 0.0)
+
+
+def _decays(g_ref):
+    """Every head's running sum of ``g`` over each chunk and what the operands
+    take of it, ``[H, L]`` each: ``gamma``, ``exp(gamma)``, ``exp(gamma_C -
+    gamma)``; and ``exp(gamma_C)`` of the two chunks, ``[H, 1]`` each."""
+    gammas = _running_sum(g_ref[0], axis=1, period=_KERNEL_CHUNK)
+    ends = [gammas[:, c * _KERNEL_CHUNK - 1:c * _KERNEL_CHUNK] for c in (1, 2)]
+    last = jnp.where(_iota(gammas.shape, 1) < _KERNEL_CHUNK, *ends)
+    return gammas, jnp.exp(gammas), jnp.exp(last - gammas), [jnp.exp(end) for end in ends]
+
+
+def gdn_inverse_kernel(k_ref, g_ref, beta_ref, inverse_ref):
+    """Every head's ``T = (I + A)^-1`` of two chunks, float32 ``[H, C, 2 C]``:
+    a head's two side by side."""
+    heads = beta_ref.shape[1]
+    d_k = k_ref.shape[1] // heads
+    gammas = _running_sum(g_ref[0], axis=1, period=_KERNEL_CHUNK)
+    betas = beta_ref[0]
+    rounds = _KERNEL_CHUNK.bit_length() - 1  # no round joins the two chunks
+
+    def head(pair, half, carry):
+        h = 2 * pair + half
+        k = k_ref[0, _rows_of(h, d_k), :]                               # [d_k, L]
+        system = _turned(_row_of(betas, h)) * _transposed_times(k, k) * _decay_between(
+            _row_of(gammas, h)
+        )
+        inverse_ref[0, 0, h] = _folded(_solve(system, rounds))
+        return carry
+
+    _over_heads(heads, head)
+
+
+def gdn_operands_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
+                        w_ref, u_ref, k_out_ref, whole_ref, q_in_ref, scores_ref):
+    """From two chunks' inputs and ``T`` to the carry's operands (``w``, ``u``,
+    ``k_out``, ``whole``) and the output stage's (``q_in``, the masked decayed
+    scores), a ``[C, d]`` tile a head and chunk each: steps along the sublanes
+    there, as their readers take them."""
+    f32, dtype = jnp.float32, q_ref.dtype
+    heads, size = beta_ref.shape[1], _KERNEL_CHUNK
+    d_k, d_v = k_ref.shape[1] // heads, v_ref.shape[1] // heads
+    gammas, growns, to_ends, wholes = _decays(g_ref)
+    betas = beta_ref[0]
+    whole_ref[0, 0], whole_ref[1, 0] = wholes
+    square = (_LANE_STEPS,) * 2
+    # a tile's transpose through the MXU: exact for what is rounded already
+    eye = (_iota(square, 0) == _iota(square, 1)).astype(dtype)
+
+    def head(pair, half, carry):
+        h = 2 * pair + half
+        keys, values = _rows_of(h, d_k), _rows_of(h, d_v)
+        q, k = q_ref[0, keys, :], k_ref[0, keys, :]                     # [d_k, L]
+        k32 = k.astype(f32)
+        beta, grown = _row_of(betas, h), _row_of(growns, h)             # [1, L]
+        between = _decay_between(_row_of(gammas, h))
+        scores = (_transposed_times(q, k) * between).astype(dtype)      # [L, L]
+        inverse = _unfolded(inverse_ref[0, 0, h]).astype(dtype)
+        k_in = (k32 * (beta * grown)).astype(dtype)
+        v_in = (v_ref[0, values, :].astype(f32) * beta).astype(dtype)
+        w = _times_transposed(inverse, k_in).astype(dtype)              # [L, d_k]
+        u = _times_transposed(inverse, v_in)                            # [L, d_v]
+        k_out = _times_transposed(eye, (k32 * _row_of(to_ends, h)).astype(dtype)).astype(dtype)
+        q_in = _times_transposed(eye, (q.astype(f32) * grown).astype(dtype)).astype(dtype)
+        for c in range(_LANE_STEPS // size):  # a chunk's rows, and its block of the scores
+            at = slice(c * size, (c + 1) * size)
+            w_ref[c, 0, h], u_ref[c, 0, h], k_out_ref[c, 0, h] = w[at], u[at], k_out[at]
+            q_in_ref[0, c, h], scores_ref[0, c, h] = q_in[at], scores[at, at]
+        return carry
+
+    _over_heads(heads, head)
+
+
+def gdn_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
+                        dw_ref, du_ref, dk_out_ref, dwhole_ref, dq_in_ref, dscores_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """The cotangents of two chunks' inputs from those of the six operands:
+    the tile's intermediates are made again in VMEM from the inputs and the
+    saved ``T``. A head leaves its row of ``dbeta`` and of the running sum's
+    cotangent; ``dg`` is every head's reverse running sum at the end."""
+    f32, dtype = jnp.float32, q_ref.dtype
+    heads, size, steps = beta_ref.shape[1], _KERNEL_CHUNK, _LANE_STEPS
+    d_k, d_v = k_ref.shape[1] // heads, v_ref.shape[1] // heads
+    gammas, growns, to_ends, wholes = _decays(g_ref)
+    betas = beta_ref[0]
+    down = lambda a: jnp.sum(a, axis=0, keepdims=True)      # noqa: E731 — [1, L]
+    across = lambda a: jnp.sum(a, axis=1, keepdims=True)    # noqa: E731 — [L, 1]
+    highest = dict(precision=jax.lax.Precision.HIGHEST)
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+    row, col = _iota((steps, steps), 0), _iota((steps, steps), 1)
+    same = _same_chunk((steps, steps))
+    eye = (row == col).astype(dtype)
+
+    def head(pair, half, carry):
+        h = 2 * pair + half
+        keys, values = _rows_of(h, d_k), _rows_of(h, d_v)
+        q, k = q_ref[0, keys, :], k_ref[0, keys, :]                     # [d_k, L]
+        q32, k32, v32 = q.astype(f32), k.astype(f32), v_ref[0, values, :].astype(f32)
+        beta, grown, to_end = (_row_of(a, h) for a in (betas, growns, to_ends))
+        between = _decay_between(_row_of(gammas, h))
+        exact = _unfolded(inverse_ref[0, 0, h])
+        inverse = exact.astype(dtype)
+        k_in = (k32 * (beta * grown)).astype(dtype)
+        v_in = (v32 * beta).astype(dtype)
+        # the two chunks' cotangent tiles one under another, [L, d]
+        both_chunks = lambda tile: jnp.concatenate([tile(0), tile(1)], axis=0)  # noqa: E731
+        dw = both_chunks(lambda c: dw_ref[c, 0, h])
+        du = both_chunks(lambda c: du_ref[c, 0, h]).astype(dtype)
+
+        # through w = T k_in and u = T v_in
+        d_inverse = jnp.where(same, dot(dw, k_in) + dot(du, v_in), 0.0)
+        d_k_in, d_v_in = _transposed_times(dw, inverse), _transposed_times(du, inverse)
+
+        # through the solve, as ``_unit_lower_inverse_bwd``: dA = -T^T dT T^T
+        d_system = -_times_transposed(
+            _transposed_times(exact, d_inverse, **highest), exact, **highest
+        )
+        d_system = jnp.where(same & (row > col), d_system, 0.0)
+        d_scores = both_chunks(lambda c: jnp.concatenate([dscores_ref[0, c, h]] * 2, axis=1))
+        d_scores = jnp.where(same & (row >= col), d_scores.astype(f32), 0.0)
+
+        # through A = beta (K K^T) o between and the scores (Q K^T) o between
+        kk, qk = _transposed_times(k, k), _transposed_times(q, k)
+        by_beta = d_system * _turned(beta)
+        d_between = (by_beta * kk + d_scores * qk) * between
+        both = jnp.concatenate([by_beta * between, d_scores * between], axis=0).astype(dtype)
+        d_rows = _times_transposed(k, both)                  # [d_k, 2 L]: K^T dA^T | K^T dS^T
+        d_cols = dot(jnp.concatenate([k, q], axis=1), both)  # [d_k, L]: K^T dA + Q^T dS
+
+        # through the elementwise operands
+        dk_out = _transposed_times(both_chunks(lambda c: dk_out_ref[c, 0, h]), eye)
+        dq_in = _transposed_times(both_chunks(lambda c: dq_in_ref[0, c, h]), eye)
+        leaving, fed = down(dk_out * k32) * to_end, down(d_k_in * k32)
+        # gamma_i gathers a row of between's cotangent, gamma_j gives a column
+        d_gamma = (
+            _turned(across(d_between)) - down(d_between) + fed * (beta * grown) - leaving
+            + down(dq_in * q32) * grown
+        )
+        d_beta = _turned(across(d_system * kk * between)) + fed * grown + down(d_v_in * v32)
+        dq_ref[0, keys, :] = (d_rows[:, steps:] + dq_in * grown).astype(dtype)
+        dk_ref[0, keys, :] = (
+            d_rows[:, :steps] + d_cols + d_k_in * (beta * grown) + dk_out * to_end
+        ).astype(dtype)
+        dv_ref[0, values, :] = (d_v_in * beta).astype(dtype)
+        mine = _iota(betas.shape, 0) == h
+        return tuple(
+            jnp.where(mine, mine_row, rows)
+            for mine_row, rows in zip((d_beta, d_gamma, leaving), carry)
+        )
+
+    d_betas, d_gammas, leavings = _over_heads(heads, head, (jnp.zeros(betas.shape, f32),) * 3)
+    # each chunk's last step: what left through k_out and through whole
+    step = _iota(betas.shape, 1)
+    for c, whole in enumerate(wholes):
+        mine = (step >> (size.bit_length() - 1)) == c
+        d_last = across(jnp.where(mine, leavings, 0.0)) + dwhole_ref[c, 0] * whole
+        d_gammas = d_gammas + jnp.where(step == (c + 1) * size - 1, d_last, 0.0)
+    dg_ref[0] = _running_sum(d_gammas, reverse=True, axis=1, period=size)
+    dbeta_ref[0] = d_betas
+
+
+_SCALAR_INPUTS = ("keys", "keys", "values", "steps", "steps")            # q k v g beta
+_SCALAR_OPERANDS = ("w", "u", "w", "whole", "q_in", "scores")            # ..., k_out, ...
+
+
+def _scalar_run(kernel, ins, outs, operands, interpret):
+    """One of the scalar rule's three kernels on ``operands``, whose kinds
+    ``ins`` names (``outs`` those of its results): a grid step a lane tile of
+    steps (two chunks), every block those of every head."""
+    batch, h, steps = operands[ins.index("steps")].shape
+    size, nc, lanes = _KERNEL_CHUNK, steps // _KERNEL_CHUNK, _LANE_STEPS
+    keys = operands[ins.index("keys")]
+    values = operands[ins.index("values")] if "values" in ins else keys
+    d_k, d_v = keys.shape[1] // h, values.shape[1] // h
+    f32, dtype = jnp.float32, keys.dtype
+    here, first = (lambda b, n: (b, n)), (lambda b, n: (n, b))  # noqa: E731
+    along = lambda rows, dt: (  # noqa: E731 — [B, rows, T], a lane tile of steps
+        (batch, rows, steps), dt, (1, rows, lanes), lambda b, n: (b, 0, n)
+    )
+    kinds = dict(
+        keys=along(h * d_k, dtype), values=along(h * d_v, dtype), steps=along(h, f32),
+        # every two chunks' T side by side; the scores [b n h c s]
+        inverse=((batch, nc // 2, h, size, lanes), f32, (1, 1, h, size, lanes), here),
+        scores=((batch, nc, h, size, size), dtype, (1, 2, h, size, size), here),
+        q_in=((batch, nc, h, size, d_k), dtype, (1, 2, h, size, d_k), here),
+        # the carry's operands, chunks first (k_out as w)
+        w=((nc, batch, h, size, d_k), dtype, (2, 1, h, size, d_k), first),
+        u=((nc, batch, h, size, d_v), f32, (2, 1, h, size, d_v), first),
+        whole=((nc, batch, h, 1), f32, (2, 1, h, 1), first),
+    )
+    return _chunk_call(kernel, (batch, nc // 2), kinds, ins, outs, operands, interpret)
+
+
+# jitted, as ``ops/causal_conv.py``'s: a step traces and lowers each body once
+@functools.partial(jax.jit, static_argnums=3)
+def _scalar_inverse_call(k, g, beta, interpret):
+    ins = ("keys", "steps", "steps")
+    return _scalar_run(gdn_inverse_kernel, ins, ("inverse",), (k, g, beta), interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=6)
+def _scalar_operands_call(q, k, v, g, beta, inverse, interpret):
+    ins = (*_SCALAR_INPUTS, "inverse")
+    return _scalar_run(
+        gdn_operands_kernel, ins, _SCALAR_OPERANDS, (q, k, v, g, beta, inverse), interpret
+    )
+
+
+@functools.partial(jax.jit, static_argnums=12)
+def _scalar_backward_call(q, k, v, g, beta, inverse, dw, du, dk_out, dwhole, dq_in, dscores,
+                          interpret):
+    ins = (*_SCALAR_INPUTS, "inverse", *_SCALAR_OPERANDS)
+    operands = (q, k, v, g, beta, inverse, dw, du, dk_out, dwhole, dq_in, dscores)
+    return _scalar_run(gdn_backward_kernel, ins, _SCALAR_INPUTS, operands, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _scalar_kernels(q, k, v, g, beta, interpret):
+    """The scalar rule's chunk-local stage by the kernels, time along the
+    lanes: ``q``, ``k`` ``[B, H d_k, T]``, ``v`` ``[B, H d_v, T]`` and float32
+    ``g``, ``beta`` ``[B, H, T]``; ``(w, u, k_out, whole, q_in, scores)`` as
+    ``_scalar_plain`` lays them out, but ``k_out`` ``[n b h c k]`` and ``q_in``
+    ``[b n h c k]`` a tile a head and ``whole`` ``[n b h 1]``."""
+    inverse = _scalar_inverse_call(k, g, beta, interpret)
+    return _scalar_operands_call(q, k, v, g, beta, inverse, interpret)
+
+
+def _scalar_kernels_fwd(q, k, v, g, beta, interpret):
+    # T apart from the rest and by name, as ``_local_kernels_fwd``
+    inverse = checkpoint_name(_scalar_inverse_call(k, g, beta, interpret), INVERSE_NAME)
+    operands = _scalar_operands_call(q, k, v, g, beta, inverse, interpret)
+    return operands, (q, k, v, g, beta, inverse)
+
+
+def _scalar_kernels_bwd(interpret, residuals, cotangents):
+    return _scalar_backward_call(*residuals, *cotangents, interpret)
+
+
+_scalar_kernels.defvjp(_scalar_kernels_fwd, _scalar_kernels_bwd)
 
 
 def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
@@ -973,7 +1352,6 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         note(path="kernel")
     else:
         note(path="plain", why=why_plain)
-    dot = dict(preferred_element_type=f32)
 
     # b batch, n chunk, c / s step in a chunk, h head, k key width, v value width
     if kernels:
@@ -986,19 +1364,7 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     else:
         w, u, k_out, whole, q_in, scores, _ = _local_plain(q, k, v, g, beta, size)
 
-    # from chunk to chunk, the state in float32
-    if initial_state is None:
-        state = jnp.zeros((batch, h, d_k, d_v), f32)
-    else:
-        state = initial_state.astype(f32)
-    states, new, state = carried_states(state, w, u, k_out, whole)
-    entering = jnp.moveaxis(states.astype(dtype), 0, 1)          # [b n h k v]
-    new = jnp.moveaxis(new, 0, 1)                                # [b n h c v]
-
-    # every chunk's outputs: what it inherits, and what it wrote itself
-    inherited = jnp.einsum("bnchk,bnhkv->bnchv", q_in, entering, **dot)
-    own = jnp.einsum("bnhcs,bnhsv->bnchv", scores, new, **dot)
-    o = (inherited + own).reshape(batch, steps, h, d_v)[:, :t].astype(dtype)
+    o, state = _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t)
     if return_final_state:
         return o, state
     return o

@@ -285,7 +285,7 @@ def test_each_traced_shape_leaves_one_gdn_chunks_instant():
     assert noted[0]["args"] == {
         "chunk": 16, "chunks": 3, "heads": 3, "d_k": 8, "d_v": 16,
         "state_bytes": 4 * 3 * 8 * 16, "solve": "block_doubling",
-        "carry": "saved", "saved_bytes": saved,
+        "carry": "saved", "saved_bytes": saved, "path": "plain", "why": "backend",
     }
     # the cell's call: 128 states of [15, 96, 192] and 128 T of [15, 64, 64]
     # float32, V_new and o bfloat16

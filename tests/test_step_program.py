@@ -402,7 +402,6 @@ def test_sparse_attention_says_which_form_it_took(interpret, t, path, why):
 
 def test_an_op_with_one_form_notes_no_path():
     from edl_tpu.ops.causal_conv import gated_causal_conv
-    from edl_tpu.ops.gated_delta import gated_delta_rule
 
     x = jax.ShapeDtypeStruct((1, 64, 3 * 16), jnp.bfloat16)
     w = jax.ShapeDtypeStruct((3, 16), jnp.float32)
@@ -410,12 +409,28 @@ def test_an_op_with_one_form_notes_no_path():
         "sconv_shape", lambda: jax.eval_shape(gated_causal_conv, x, w)
     )
     assert len(noted) == 1 and "path" not in noted[0]
-    wide = jax.ShapeDtypeStruct((1, 64, 2, 16), jnp.float32)
-    thin = jax.ShapeDtypeStruct((1, 64, 2), jnp.float32)
+
+
+@pytest.mark.parametrize("case,path,why", [
+    (dict(interpret=True), "kernel", None), (dict(), "plain", "backend"),
+    (dict(interpret=True, dtype=jnp.float32), "plain", "dtype"),
+    (dict(interpret=True, chunk=32), "plain", "chunk"),
+])
+def test_the_scalar_delta_rule_says_which_form_it_took(case, path, why):
+    """Two forms since PR 58 (it noted no ``path`` while plain XLA was its
+    only one): any count of heads is the kernels', of any width in 16s."""
+    from edl_tpu.ops.gated_delta import gated_delta_rule
+
+    wide = jax.ShapeDtypeStruct((1, 128, 3, 16), case.get("dtype", jnp.bfloat16))
+    thin = jax.ShapeDtypeStruct((1, 128, 3), jnp.float32)
     noted = _new_notes("gdn_chunks", lambda: jax.eval_shape(
-        gated_delta_rule, wide, wide, wide, thin, thin
+        lambda *a: gated_delta_rule(
+            *a, chunk=case.get("chunk", 64), interpret=case.get("interpret", False)
+        ), wide, wide, wide, thin, thin
     ))
-    assert len(noted) == 1 and "path" not in noted[0]
+    assert len(noted) == 1
+    assert noted[0]["path"] == path and noted[0].get("why") == why
+    assert (noted[0]["heads"], noted[0]["d_k"], noted[0]["d_v"]) == (3, 16, 16)
 
 
 # -- the census of a stage, and the window's steps as a series ----------------------

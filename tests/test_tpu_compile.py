@@ -336,13 +336,19 @@ def test_causal_conv_kernels_compile_for_v5e_at_granites_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
-def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip):
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip, path):
     """The chunked rule with its backward at one sequence of 8192, the 15
-    heads of 96 / 192 the cell holds, chunks of 64: plain XLA, so what the
-    chip's compiler can refuse is the memory. A chunk's float32 system is
-    31 MB a layer and the 128 states a chunk inherits 142 MB; with the
-    inverse's own backward, value and gradients together stay under 2 GB
-    (all 30 heads: 2.7 GB) of the 5 GB the step has beside its parameters."""
+    heads of 96 / 192 the cell holds, chunks of 64. Plain XLA (what a CPU
+    backend and a shape the kernels refuse take), what the chip's compiler can
+    refuse is the memory: a chunk's float32 system is 31 MB a layer and the
+    128 states a chunk inherits 142 MB; with the inverse's own backward, value
+    and gradients together stay under 2 GB (all 30 heads: 2.7 GB) of the 5 GB
+    the step has beside its parameters. As the chip lowers it
+    (``jax.default_backend`` steered here, in the test) the chunk-local stage
+    is the three kernels, each once, and plans less."""
+    from unittest import mock
+
     from edl_tpu.ops import gated_delta_rule
 
     def sds(dims, dtype=jnp.bfloat16):
@@ -356,8 +362,60 @@ def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip):
         out, vjp = jax.vjp(lambda *a: gated_delta_rule(*a, chunk=64), *a)
         return (out, *vjp(w))
 
-    compiled = jax.jit(value_and_grads).lower(sds((1, t, h, d_v)), *args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    with mock.patch.object(jax, "default_backend", lambda: "tpu" if path == "kernels" else "cpu"):
+        lowered = jax.jit(lambda *a: value_and_grads(*a)).lower(sds((1, t, h, d_v)), *args)
+    kernels = sorted(_kernel_names(lowered.as_text()))
+    compiled = lowered.compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == len(kernels)
+    if path == "kernels":
+        assert kernels == ["gdn_backward", "gdn_inverse", "gdn_operands"] and temp < 1.2e9
+    else:
+        assert kernels == [] and 1.2e9 < temp < 2e9
+
+
+@pytest.mark.parametrize("heads", [15, 30], ids=["held", "published"])
+@pytest.mark.parametrize("kernel", ["gdn_inverse", "gdn_operands", "gdn_backward"])
+def test_gdn_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, kernel, heads):
+    """The three kernels of the scalar rule's chunk-local stage at one
+    sequence of 8192 and heads of 96 / 192, the cell's 15 held (an odd count:
+    seven rounds of the loop over pairs and a last head by itself) and the
+    published 30: the steps along the lanes (blocks of ``[heads * d, 128]``,
+    two chunks a grid step, a head's rows taken by a dynamic slice of 96 or 192
+    sublanes inside the body's loop), ``T`` a head's two chunks a ``[64, 128]``
+    row, ``[heads, 64, d]`` tiles for the carry's operands at the true widths:
+    the slices, the products over a contracting dimension of 96 and the VMEM
+    limit set from the shapes are what the chip's compiler can refuse."""
+    G = importlib.import_module("edl_tpu.ops.gated_delta")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, d_k, d_v, f32 = 8192, 96, 192, jnp.float32
+    nc = t // 64
+    keys, values, steps = sds((1, heads * d_k, t)), sds((1, heads * d_v, t)), sds((1, heads, t), f32)
+    inverse = sds((1, nc // 2, heads, 64, 128), f32)
+    tiles = sds((nc, 1, heads, 64, d_k))
+    operands = (tiles, sds((nc, 1, heads, 64, d_v), f32), tiles, sds((nc, 1, heads, 1), f32),
+                sds((1, nc, heads, 64, d_k)), sds((1, nc, heads, 64, 64)))
+    call, args = {
+        "gdn_inverse": (lambda *a: G._scalar_inverse_call(*a, False), (keys, steps, steps)),
+        "gdn_operands": (lambda *a: G._scalar_operands_call(*a, False),
+                         (keys, keys, values, steps, steps, inverse)),
+        "gdn_backward": (lambda *a: G._scalar_backward_call(*a, False),
+                         (keys, keys, values, steps, steps, inverse, *operands)),
+    }[kernel]
+    compiled = jax.jit(lambda *a: call(*a)).lower(*args).compile()
+    assert kernel in compiled.as_text()
+    out = jax.eval_shape(call, *args)
+    if kernel == "gdn_inverse":
+        assert (out.shape, out.dtype) == (inverse.shape, inverse.dtype)
+    elif kernel == "gdn_operands":
+        assert [(a.shape, a.dtype) for a in out] == [(a.shape, a.dtype) for a in operands]
+    else:
+        assert [(a.shape, a.dtype) for a in out] == [
+            (a.shape, a.dtype) for a in (keys, keys, values, steps, steps)
+        ]
 
 
 @pytest.mark.parametrize("bank", ["up", "down"])
@@ -488,6 +546,70 @@ def test_a_linear_attention_step_on_the_tpu_path_names_its_kernels_under_gdn_con
     assert set(table.values()) == set(GDN_SCOPES)
     custom = [ln for ln in text.splitlines() if " custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(custom) > len(convs)                 # the flash kernels are there too
+
+
+def test_a_linear_attention_step_on_the_tpu_path_runs_the_rule_as_kernels_under_gdn_scan(one_chip):
+    """A toy linear-attention hybrid at the kernels' chunk of 64 (three heads
+    of 16 / 32: an odd count, widths under a lane tile; 128 steps, one grid
+    step), under the cell's ``save_flash``, lowered as the chip lowers it: the
+    rule notes ``path="kernel"``; each linear layer holds ``gdn_inverse`` once,
+    forward (``gdn_inverse`` by name is what the recomputation reads),
+    ``gdn_operands`` forward and once more where the backward reaches the rule
+    under the mixer's checkpoint, and ``gdn_backward`` once; all of them under
+    ``gdn_scan``; the carry still loops once forward and once in reverse a
+    layer; and ``STEP_PARTS`` places every matmul of the compiled step."""
+    from unittest import mock
+
+    import numpy as np
+    import optax
+
+    from edl_tpu.models import ArchSpec, GatedDeltaSpec, TransformerLM
+    from edl_tpu.models.gated_delta import GDN_SCOPES
+    from edl_tpu.obs import profile as obs_profile
+    from edl_tpu.obs import trace as obs_trace
+    from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
+
+    layers = ("linear_attention", "linear_attention", "attention")
+    lm = TransformerLM(
+        vocab_size=64, d_model=64, num_heads=4, num_kv_heads=4, num_layers=len(layers),
+        d_ff=48, dtype=jnp.bfloat16, remat=True, remat_policy="save_flash", qk_norm=True,
+        arch=ArchSpec(
+            layer_types=layers, rope=False, post_norms="only",
+            gated_delta=GatedDeltaSpec(num_heads=3, key_dim=16, value_dim=32, chunk=64),
+        ),
+    )
+    tokens = np.zeros((1, 128), np.int32)
+    state = jax.eval_shape(
+        lambda: create_state(lm, jax.random.PRNGKey(0), tokens, optax.adamw(1e-3))
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    loss = lambda logits, y: cross_entropy_loss(  # noqa: E731
+        logits.reshape(-1, logits.shape[-1]), y.reshape(-1)
+    )
+    tracer = obs_trace.get_tracer()
+    tracer.reset_notes()
+    before = len([e for e in tracer.to_events() if e["name"] == "gdn_chunks"])
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = make_train_step(loss, numerics=False).lower(
+            described(state), described((tokens, tokens))
+        )
+    noted = [e["args"] for e in tracer.to_events() if e["name"] == "gdn_chunks"][before:]
+    assert noted and all(a["path"] == "kernel" and "why" not in a for a in noted)
+    text = lowered.compile().as_text()
+    census = obs_profile.HloProgram(text).census()
+    assert census["totals"]["matmuls"] > 0 and census["totals"]["unplaced_matmuls"] == 0
+    linear = layers.count("linear_attention")
+    calls = {key: n for key, n in census["kernels"].items() if key.startswith("gdn_")}
+    assert calls == {
+        "gdn_inverse/forward": linear, "gdn_operands/forward": linear,
+        "gdn_operands/backward": linear, "gdn_backward/backward": linear,
+    }
+    assert census["totals"]["loops"] == 2 * linear
+    scopes = obs_profile.scopes_of_hlo(text, GDN_SCOPES)
+    rule = {name: scope for name, scope in scopes.items() if name.startswith("gdn_")}
+    assert len(rule) == 4 * linear and set(rule.values()) == {"gdn_scan"}
 
 
 def test_a_hybrid_step_on_the_tpu_path_runs_the_conv_kernels_under_ssm_conv(one_chip):
