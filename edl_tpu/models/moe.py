@@ -7,8 +7,9 @@ tree — SURVEY §2 parallelism inventory). Two layers live here:
   a float32 router with softmax scores (OLMoE) or sigmoid scores, a
   balancing bias, normalised and scaled weights and a shared expert
   (the DeepSeek-V3 line as Trinity-Mini's ``afmoe`` code writes it),
-  top-k of many small experts, SiLU-gated with three matrices each or
-  ungated with two and a squared ReLU (Nemotron-H's), reading and writing
+  top-k of many small experts, gated with three matrices each (SiLU, or
+  SmallThinker's ReLU) or ungated with two and a squared ReLU (Nemotron-H's),
+  routed from what they read or from the block's own input, reading and writing
   the model's width or a narrower latent between two shared projections
   (LatentMoE), **no capacity and no dropped
   token**, and optionally **one chip's share of the experts** (``held``:
@@ -56,8 +57,15 @@ ROUTE_NAME = "moe_route"
 HELD_NAME = "moe_held"
 REMAT_NAMES = (ROUTE_NAME, HELD_NAME)
 
-# an expert's activation by name; "relu2" is the squared ReLU of Nemotron-H
-ACTIVATIONS = {"silu": nn.silu, "relu2": lambda a: jnp.square(nn.relu(a))}
+# an expert's activation by name; "relu2" is the squared ReLU of Nemotron-H,
+# "relu" the gate of SmallThinker's experts (a gated expert's is one of GATES)
+ACTIVATIONS = {
+    "silu": nn.silu, "relu": nn.relu, "relu2": lambda a: jnp.square(nn.relu(a)),
+}
+GATES = ("silu", "relu")
+# what the router reads: the feed-forward's own normed input, or the block's
+# input as the block received it, before its first norm and its mixer
+ROUTE_FROM = ("ff_input", "block_input")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,7 +78,7 @@ class MoESpec:
     layer is ``ArchSpec.layer_types``, and there a block whose one branch
     is this layer.) The defaults are OLMoE's layer: softmax scores, every
     expert held here, no bias, no shared expert, SiLU-gated experts of three
-    matrices at the model's width."""
+    matrices at the model's width, routed from what they read."""
 
     num_experts: int
     top_k: int
@@ -87,8 +95,9 @@ class MoESpec:
     n_group: int = 1               # > 1: the experts in this many equal groups,
     topk_group: int = 1            # ... the choice inside the best topk_group
     gated: bool = True             # False: two matrices an expert, W_down act(W_up x)
-    activation: str = "silu"       # or "relu2"; a gated expert's is "silu"
+    activation: str = "silu"       # a gated expert's: "silu" or "relu"; ungated also "relu2"
     latent: int = 0                # > 0: the routed experts read and write this width
+    route_from: str = "ff_input"   # or "block_input": the router reads the block's input
 
 
 def _sum_unsorted(rows, inverse, k, live=None):
@@ -196,6 +205,23 @@ def _rows_combined(rows, order, inverse, k, live=None):
     return combine(rows, order, inverse, live)
 
 
+@jax.custom_vjp
+def _as_stored(x):
+    """``x`` behind an ``optimization_barrier``, forward only: the array as it
+    stands in memory, rounded to its dtype once. A block's input is the sum of
+    two bfloat16 arrays, and XLA makes such a sum again inside each fusion that
+    reads it, rounding it to bfloat16 in one and (excess precision) not in
+    another: the router's matmul and the sown ``router_in`` then read numbers
+    2^-9 apart (on the chip 1.3e-3 of the largest logit, a third of what a
+    bfloat16 router reads; PERF.md section 6, PR 59). The block's checkpoint
+    keeps this array anyway; the chip read the barrier at 0.29 ms a layer. The
+    cotangent goes through as it is."""
+    return jax.lax.optimization_barrier(x)
+
+
+_as_stored.defvjp(lambda x: (_as_stored(x), None), lambda _, ct: (ct,))
+
+
 class UngatedMLP(nn.Module):
     """``W_down act(W_up x)``: the two-matrix feed-forward, no gate and no
     bias (Nemotron-H's, with ``"relu2"``)."""
@@ -212,7 +238,7 @@ class UngatedMLP(nn.Module):
 
 
 class DroplessMoE(nn.Module):
-    """Dropless top-k mixture of small experts, SiLU-gated or ungated.
+    """Dropless top-k mixture of small experts, gated (SiLU or ReLU) or ungated.
 
     Per token ``x`` (``[B, S, D]`` in, ``[B, S, D]`` out)::
 
@@ -226,6 +252,22 @@ class DroplessMoE(nn.Module):
     With the defaults this is OLMoE's layer; with sigmoid scores, the bias,
     normalised and scaled weights and a shared expert it is the layer of
     the DeepSeek-V3 line as Trinity's ``afmoe`` code writes it.
+
+    **The route's own operand** (``route_from = "block_input"``, and then
+    ``__call__(x, route_x)``; SmallThinker, arXiv:2507.20984: a router placed
+    before attention): ``W_r`` reads ``route_x`` (the residual stream as the
+    block received it, un-normed) where everything above says ``W_r x``;
+    logits, scores, choice, weights, sort, losses and gauges follow from it,
+    and the experts (the latent, the shared expert) read ``x`` as before. The
+    route's gradient reaches ``route_x`` and not ``x``. The parameter tree is
+    the same. ``"ff_input"`` takes no second operand and is the layer above.
+
+    **A ReLU gate** (``activation = "relu"`` on a gated expert):
+    ``W_down[e] (w * relu(W_gate[e] x) * W_up[e] x)``, and
+    ``"metrics"/moe_gate_dead`` = the share of the gate's pre-activations, over
+    the rows of held experts, that the ReLU zeroes (0.5 at a fresh start; the
+    columns a later kernel could skip). A shared expert under it is asked by no
+    model and raises, as ``"relu2"`` on a gated expert does.
 
     **Ungated** (``gated=False``): an expert is two matrices,
     ``W_down[e] (w * act(W_up[e] x))`` with ``act`` the ``activation``
@@ -352,7 +394,7 @@ class DroplessMoE(nn.Module):
     ``moe_combine`` (un-sort and sum over k), ``moe_shared`` (the shared
     expert). Each traced shape leaves one ``moe_shape`` instant in the span
     ring (``experts``, ``held``, ``top_k``, ``pairs``, ``buffer_rows``,
-    ``latent``, ``width``, ``gated``, ``activation``).
+    ``latent``, ``width``, ``gated``, ``activation``, ``route_from``).
     """
 
     num_experts: int
@@ -372,6 +414,7 @@ class DroplessMoE(nn.Module):
     gated: bool = True
     activation: str = "silu"
     latent: int = 0
+    route_from: str = "ff_input"
     dtype: Any = jnp.bfloat16
 
     def _inside_kept_groups(self, choice):
@@ -392,7 +435,7 @@ class DroplessMoE(nn.Module):
         return jnp.where(jnp.repeat(keep, e // groups, axis=1), choice, -jnp.inf)
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, route_x: Optional[jax.Array] = None) -> jax.Array:
         b, s, d = x.shape
         e, k, f = self.num_experts, self.top_k, self.d_ff
         n = b * s
@@ -400,10 +443,23 @@ class DroplessMoE(nn.Module):
         first, count = self.held or (0, e)
         if first < 0 or count < 1 or first + count > e:
             raise ValueError("held %r is no part of %d experts" % (self.held, e))
-        if self.activation not in ACTIVATIONS or (self.gated and self.activation != "silu"):
+        if self.activation not in ACTIVATIONS or (self.gated and self.activation not in GATES):
             raise ValueError(
-                "activation %r: a gated expert is SiLU-gated, an ungated one "
-                "takes one of %s" % (self.activation, ", ".join(sorted(ACTIVATIONS)))
+                "activation %r: a gated expert's gate is one of %s, an ungated one "
+                "takes one of %s"
+                % (self.activation, ", ".join(GATES), ", ".join(sorted(ACTIVATIONS)))
+            )
+        relu_gate = self.gated and self.activation == "relu"
+        if relu_gate and self.shared_d_ff:
+            raise ValueError("a shared expert under a ReLU gate: no model asks for one")
+        if self.route_from not in ROUTE_FROM:
+            raise ValueError(
+                "route_from %r: one of %s" % (self.route_from, ", ".join(ROUTE_FROM))
+            )
+        if (self.route_from == "block_input") != (route_x is not None):
+            raise ValueError(
+                "route_from %r and %s second operand"
+                % (self.route_from, "no" if route_x is None else "a")
             )
         act = ACTIVATIONS[self.activation]
         # rows of the expert-ordered buffer a step usually needs: twice the
@@ -412,14 +468,16 @@ class DroplessMoE(nn.Module):
         obs_trace.get_tracer().note_once(
             "moe_shape", experts=e, held=count, top_k=k, pairs=n * k,
             buffer_rows=buffer, latent=self.latent, width=f, gated=self.gated,
-            activation=self.activation,
+            activation=self.activation, route_from=self.route_from,
         )
 
         with jax.named_scope("moe_route"):
             # by name, for a policy that keeps them (``REMAT_NAMES``): the rest
             # of the route is elementwise on [N, E] or [N, k] and is made again
             kept = partial(checkpoint_name, name=ROUTE_NAME)
-            router_in = tokens.astype(jnp.float32)
+            router_in = (
+                tokens if route_x is None else _as_stored(route_x).reshape(n, d)
+            ).astype(jnp.float32)
             logits = kept(nn.Dense(
                 e, use_bias=False, dtype=jnp.float32, name="router",
                 precision=jax.lax.Precision.HIGHEST,
@@ -525,7 +583,8 @@ class DroplessMoE(nn.Module):
             """The held experts' part of the layer from the first ``m`` rows
             in expert order: every row when all experts are held, else a
             buffer that holds the ``live`` rows of held experts. ``banks``:
-            ``gate`` (a gated expert's), ``up`` and ``down``."""
+            ``gate`` (a gated expert's), ``up`` and ``down``. Beside it, under
+            a ReLU gate, the share of the held rows' gate values it zeroes."""
             first_rows = order if m == n * k else order[:m]
             with jax.named_scope("moe_experts"):
                 rows = _rows_sorted(
@@ -549,6 +608,11 @@ class DroplessMoE(nn.Module):
                     nobodys = (jnp.arange(m) >= live)[:, None]
                     into = [jnp.where(nobodys, 0, a) for a in into]
                 hidden = act(into[0].astype(jnp.float32))
+                dead = None
+                if relu_gate:  # nobody's rows are zeros: they count as not alive
+                    alive = jnp.sum(hidden > 0, dtype=jnp.float32)
+                    held_rows = m if live is None else jnp.maximum(live, 1)
+                    dead = 1.0 - alive / (held_rows * f)
                 if self.gated:
                     hidden = hidden * into[1].astype(jnp.float32)
                 hidden = (hidden * w_sorted[:, None]).astype(self.dtype)  # rounded once
@@ -556,19 +620,22 @@ class DroplessMoE(nn.Module):
                     hidden = jnp.where(nobodys, 0, hidden)  # for its cotangent's rows
                 out = grouped_matmul(hidden, banks[-1].astype(self.dtype), group_sizes)
             with jax.named_scope("moe_combine"):
-                return _rows_combined(out, first_rows, inverse, k, live)  # [N, width], float32
+                # [N, width], float32
+                return _rows_combined(out, first_rows, inverse, k, live), dead
 
         operands = (tokens, weights, *banks)
         if buffer < n * k:
             # the whole N * k only for a step whose held rows outgrow the
             # buffer; it keeps nothing for its backward (which computes it
             # again), so the step's memory is the usual path's
-            y = jax.lax.cond(
+            y, dead = jax.lax.cond(
                 live <= buffer, partial(routed, buffer),
                 jax.checkpoint(partial(routed, n * k)), *operands,
             )
         else:
-            y = routed(n * k, *operands)
+            y, dead = routed(n * k, *operands)
+        if dead is not None:
+            self.sow("metrics", "moe_gate_dead", dead)
         if self.latent:
             # this chip's part of the routed sum, in the latent (a check's)
             self.sow("intermediates", "routed_latent", y)
@@ -604,7 +671,7 @@ class SwitchMoE(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, route_x: Optional[jax.Array] = None) -> jax.Array:
         b, s, d = x.shape
         e, k = self.num_experts, self.top_k
         capacity = max(1, int(self.capacity_factor * k * s / e))

@@ -657,6 +657,15 @@ class Block(nn.Module):
         before, after = arch.post_norms != "only", bool(arch.post_norms)
         if arch.one_branch and self.decode:
             raise NotImplementedError("a one-branch block has no decode path")
+        # an expert layer routed before the mixer reads the stream as it came in
+        routed_early = self.moe is not None and self.moe.route_from == "block_input"
+        block_input = x if routed_early else None
+        if routed_early and (arch.one_branch or not before):
+            raise ValueError(
+                'route_from "block_input" wants a block of two branches with a norm '
+                "before each: with one_branch or post_norms \"only\" the router's "
+                "operand is the experts' own"
+            )
         h = RMSNorm(self.norm_eps, name="ln1")(x) if before else x
         if arch.one_branch and self.mixer in FEED_FORWARD_TYPES:
             if self.mixer == "moe" and self.moe is None:
@@ -739,18 +748,19 @@ class Block(nn.Module):
         if arch.one_branch:
             return x
         h = RMSNorm(self.norm_eps, name="ln2")(x) if before else x
-        ff = self._feed_forward(h)
+        ff = self._feed_forward(h, route_x=block_input)
         if after:
             ff = RMSNorm(self.norm_eps, name="ln2_post")(ff)
         return x + _times(ff, arch.residual_multiplier)
 
-    def _feed_forward(self, h, dense: bool = False):
+    def _feed_forward(self, h, dense: bool = False, route_x=None):
         """The block's feed-forward on its normed input: the expert layer if
-        the block has one and ``dense`` does not overrule it, else the SwiGLU."""
+        the block has one and ``dense`` does not overrule it, else the SwiGLU.
+        ``route_x``: the expert layer's second operand (``MoESpec.route_from``)."""
         if self.moe is not None and not dense:
             return DroplessMoE(
                 **dataclasses.asdict(self.moe), dtype=self.dtype, name="moe"
-            )(h)
+            )(h, route_x)
         if self.num_experts > 0 and not dense:
             return SwitchMoE(
                 num_experts=self.num_experts, d_ff=self.d_ff,

@@ -365,7 +365,7 @@ def test_with_the_defaults_the_layer_lowers_as_the_parents(form):
 
 def test_an_activation_the_form_does_not_take_is_refused():
     x = jnp.zeros((1, 8, 16))
-    with pytest.raises(ValueError, match="a gated expert is SiLU-gated"):
+    with pytest.raises(ValueError, match="a gated expert's gate is one of silu, relu"):
         latent_layer(gated=True).init(jax.random.PRNGKey(0), x)
     with pytest.raises(ValueError, match="relu2, silu"):
         latent_layer(activation="gelu").init(jax.random.PRNGKey(0), x)
@@ -382,6 +382,7 @@ def test_each_traced_shape_leaves_one_moe_shape_instant():
     assert found == [{
         "experts": E, "held": 4, "top_k": K, "pairs": 64 * K, "buffer_rows": 160,
         "latent": LATENT, "width": F, "gated": False, "activation": "relu2",
+        "route_from": "ff_input",
     }]
 
 

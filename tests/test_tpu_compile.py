@@ -2017,3 +2017,106 @@ def test_a_multi_token_step_on_the_tpu_path_places_the_modules_matmuls(one_chip)
     under_module = {name for name, scope in program.scopes(("mtp", "mtp_join", "mtp_head")).items()
                     if name in program.kernels and name in calls}
     assert len(under_module) == 2
+
+
+# -- SmallThinker: 16,384 positions, a group of seven query heads ---------------
+
+SMALLTHINKER = (1, 28, 4, 16384, 128)  # smallthinker_21b_a3b.steady's attention layers
+
+
+def _smallthinker_cell():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "smallthinker_21b_a3b.json")) as f:
+        return root, json.load(f)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "w4096"])
+def test_flash2_compiles_for_v5e_at_sixteen_thousand_positions_and_a_group_of_seven(
+    one_chip, direction, window
+):
+    """The grid-pipelined forward and the FUSED backward at T = 16,384, 28 query
+    heads over 4 key heads of 128 (``i // 7`` in the kernels' index maps, dk and
+    dv folded from seven heads), over the whole sequence and under the
+    published window of 4096, with the blocks the dispatch gives: twice the
+    longest unmasked sequence any other cell has, and a head's float32 dq
+    accumulator at 8.4 MB under the limit ``_fused_bwd_vmem`` sets."""
+    b, h, h_kv, t, d = SMALLTHINKER
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    fwd, dq, dkv = (A._flash2_blocks(kind, t, t, window) for kind in ("fwd", "dq", "bwd"))
+    if direction == "fwd":
+        fn = lambda q, k, v: A._flash2_forward(
+            q, k, v, True, d ** -0.5, *fwd, False, window
+        )
+        args, want = (q, kv, kv), [FWD_NAME]
+    else:
+        fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
+            q, k, v, g, lse, delta, True, d ** -0.5, *dq, False, window, dkv
+        )
+        row = sds((b * h, t), jnp.float32)
+        args, want = (q, kv, kv, q, row, row), list(BWD_NAMES)
+    lowered = jax.jit(fn).lower(*args)
+    assert _kernel_names(lowered.as_text()) == want
+    assert lowered.compile().as_text().count("tpu_custom_call") == len(want)
+    shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, *args))]
+    if direction == "bwd":  # dq at the query heads' width, dk and dv folded to the key heads'
+        assert shapes == [(b, h, t, d), (b, h_kv, t, d), (b, h_kv, t, d)]
+
+
+def test_the_smallthinker_cells_rung_is_the_first_its_ladder_leaves_room_for():
+    """The numbers the file records: the ladder's rungs in order (depth 8 with
+    one sequence, depth 4 with two, depth 4 with one), each with the tool's plan
+    of the whole step (a described v5e), and the chosen one the first that
+    leaves at least 1 GB of the chip's 15.75 and ran on the chip."""
+    _, config = _smallthinker_cell()
+    plan = config["plan"]
+    rungs = [(t["num_hidden_layers"], t["batch_per_chip"]) for t in plan["tried"]]
+    assert rungs == [(8, 1), (4, 2), (4, 1)]
+    fits = [t for t in plan["tried"] if t["left_gb"] >= 1.0 and t["on_chip"]["ran"]]
+    chosen = plan["chosen"]
+    assert (fits[0]["num_hidden_layers"], fits[0]["batch_per_chip"]) == (
+        chosen["num_hidden_layers"], chosen["batch_per_chip"]
+    ) == (config["num_hidden_layers"], config["train"]["batch_per_chip"])
+    for tried in plan["tried"]:
+        assert tried["seq_len"] == config["train"]["seq_len"] == 16384
+        assert tried["left_gb"] == pytest.approx(plan["chip_gb"] - tried["total_gb"], abs=2e-3)
+    assert fits[0]["parameters"] == chosen["parameters"]
+    assert fits[0]["on_chip"]["correct"] and 4.0 <= fits[0]["on_chip"]["hbm_peak_gb"] < 15.75
+    share, published = config["share"], config["published"]
+    assert config["moe_num_primary_experts"] * share["chips_a_layer"] == 64
+    assert config["vocab_size"] * share["chips_a_vocabulary"] == published["vocab_size"]
+
+
+@pytest.mark.slow
+def test_the_smallthinker_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded():
+    """``benchmark/tools/compile_for_v5e.py`` on the cell as it runs (several
+    minutes): the step compiles with its attention layers' kernels and its
+    expert layers', and the plan's total is within 0.1 GB of the recorded one."""
+    import json
+    import subprocess
+    import sys
+
+    root, config = _smallthinker_cell()
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "tools", "compile_for_v5e.py"),
+         "smallthinker_21b_a3b.steady"],
+        capture_output=True, text=True, timeout=1500, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    doc = json.loads(out.stdout.strip().splitlines()[-1])
+    chosen = config["plan"]["chosen"]
+    recorded = next(
+        t for t in config["plan"]["tried"]
+        if (t["num_hidden_layers"], t["batch_per_chip"])
+        == (chosen["num_hidden_layers"], chosen["batch_per_chip"])
+    )
+    assert doc["parameters"] == recorded["parameters"]
+    assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
+    assert doc["tpu_custom_calls"] == recorded["tpu_custom_calls"]
