@@ -48,7 +48,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from edl_tpu.obs import trace as obs_trace
-from edl_tpu.ops.grouped_matmul import grouped_matmul
+from edl_tpu.ops.grouped_matmul import (
+    SEGMENT_TILE, grouped_matmul, rows_summed_by_segment,
+)
 
 # The ``checkpoint_name``s of what a remat policy may keep of a
 # :class:`DroplessMoE` (its docstring says which tensors, and their bytes);
@@ -100,43 +102,50 @@ class MoESpec:
     route_from: str = "ff_input"   # or "block_input": the router reads the block's input
 
 
-def _sum_unsorted(rows, inverse, k, live=None):
-    """``sum_j rows[inverse[n * k + j]]`` in float32: expert order back to
-    (token, choice) order, where a token's ``k`` rows are neighbours, and
-    their sum. With ``live`` only the pairs sorted before it count (the
-    others' experts are held elsewhere and their rows are nobody's)."""
+def _sum_unsorted(rows, order, inverse, k, live=None, dtype=jnp.float32):
+    """``sum_j rows[inverse[n * k + j]]``, summed in float32 and rounded once to
+    ``dtype``: expert order back to (token, choice) order, where a token's
+    ``k`` rows are neighbours, and their sum. With ``live`` only the pairs
+    sorted before it count (the others' experts are held elsewhere and their
+    rows are nobody's).
+
+    Handed a held share's buffer (``live`` and fewer rows than pairs; ``order``
+    then names the pair of each of its rows) it reads those ``m`` rows alone,
+    as a sum by token (``ops/grouped_matmul.py:rows_summed_by_segment``): the
+    same value, with no array of ``N k`` rows gathered, written or read."""
     n = inverse.shape[0] // k
+    m = rows.shape[0]
+    if live is not None and m < inverse.shape[0]:
+        token = jnp.where(jnp.arange(m) < live, order // k, n)  # n: nobody's
+        return rows_summed_by_segment(rows, token, n, dtype)
     if live is None:
         back = rows[inverse]
     else:
         back = jnp.where(
             (inverse < live)[:, None],
-            rows[jnp.minimum(inverse, rows.shape[0] - 1)], 0,
+            rows[jnp.minimum(inverse, m - 1)], 0,
         )
     back = back.reshape(n, k, rows.shape[-1])
-    return jnp.sum(back, axis=1, dtype=jnp.float32)
+    return jnp.sum(back, axis=1, dtype=jnp.float32).astype(dtype)
 
 
 def _rows_sorted(tokens, order, inverse, k, live=None):
     """``tokens[order // k]``: row ``r`` of the result is the token of the
     ``r``-th (token, choice) pair in expert order (``order`` may be that
     order's first rows only). Its gradient is :func:`_rows_combined`'s
-    forward (a gather and a sum over ``k`` neighbours), not the
-    scatter-add jax would derive."""
+    forward (a gather and a sum over ``k`` neighbours, or over a buffer
+    its rows' sum by token), not the scatter-add jax would derive."""
 
     @jax.custom_vjp
     def take(tokens, order, inverse, live):
         return tokens[order // k]
 
     def fwd(tokens, order, inverse, live):
-        return take(tokens, order, inverse, live), (inverse, live)
+        return take(tokens, order, inverse, live), (order, inverse, live)
 
     def bwd(residuals, grad):
-        inverse, live = residuals
-        return (
-            _sum_unsorted(grad, inverse, k, live).astype(grad.dtype),
-            None, None, None,
-        )
+        order, inverse, live = residuals
+        return _sum_unsorted(grad, order, inverse, k, live, grad.dtype), None, None, None
 
     take.defvjp(fwd, bwd)
     return take(tokens, order, inverse, live)
@@ -188,12 +197,13 @@ def _scalars_sorted(values, order, inverse):
 
 def _rows_combined(rows, order, inverse, k, live=None):
     """``y[n] = sum_j rows[inverse[n * k + j]]`` in float32: the transpose
-    of :func:`_rows_sorted`. Its gradient is that function's forward,
+    of :func:`_rows_sorted` (:func:`_sum_unsorted`: over a buffer, its rows'
+    sum by token). Its gradient is that function's forward,
     ``grad[order // k]``, so it keeps ``order`` and no row."""
 
     @jax.custom_vjp
     def combine(rows, order, inverse, live):
-        return _sum_unsorted(rows, inverse, k, live)
+        return _sum_unsorted(rows, order, inverse, k, live)
 
     def fwd(rows, order, inverse, live):
         return combine(rows, order, inverse, live), order
@@ -323,8 +333,14 @@ class DroplessMoE(nn.Module):
     rows fit it, and over the whole ``N * k`` when they do not (one
     ``lax.cond`` on ``live``, both sizes compiled): every pair whose expert
     is held is computed, whatever the imbalance, and ``moe_rows_dropped``
-    says so. This is what expert parallelism asks of a layer; on one chip
-    it runs without its exchange.
+    says so. On the buffer the combine, and its transpose as the gather's
+    gradient, is a sum by token over the buffer's ``m`` rows (the rows sorted
+    by token, a tile of 128 tokens one ragged group of a ``tgmm`` on the TPU:
+    ``ops/grouped_matmul.py:rows_summed_by_segment``); with every pair's row
+    at hand (the large branch, or every expert held) it is the gather of all
+    ``N * k`` and a sum over ``k`` neighbours, as it was. The shapes say which;
+    ``"metrics"/moe_buffer_taken`` says how often. This is what expert
+    parallelism asks of a layer; on one chip it runs without its exchange.
 
     The routing weight multiplies the expert's activation, not its output:
     the down projection is linear, so the value is the same, and the
@@ -364,7 +380,10 @@ class DroplessMoE(nn.Module):
       assignments that fell on held experts (``count / E`` when balanced),
       ``/moe_held_load_max`` = the busiest held expert's rows over the mean
       ``N * k / E``, ``/moe_rows_dropped`` = assignments to held experts
-      less rows the grouped matmuls cover (0: the buffer holds them all).
+      less rows the grouped matmuls cover (0: the buffer holds them all),
+      and where the share has a buffer smaller than ``N * k``
+      ``/moe_buffer_taken`` = 1.0 in a step whose ``live`` rows fit it (the
+      buffer branch ran), else 0.0; the step averages the layers'.
     - ``"intermediates"/top_idx``, ``/router_logits`` and ``/router_in`` =
       the chosen experts ``[N, k]``, ``W_r x`` ``[N, E]`` and the router's
       own float32 operand ``x`` ``[N, D]``; with a latent ``/routed_latent`` =
@@ -394,7 +413,10 @@ class DroplessMoE(nn.Module):
     ``moe_combine`` (un-sort and sum over k), ``moe_shared`` (the shared
     expert). Each traced shape leaves one ``moe_shape`` instant in the span
     ring (``experts``, ``held``, ``top_k``, ``pairs``, ``buffer_rows``,
-    ``latent``, ``width``, ``gated``, ``activation``, ``route_from``).
+    ``latent``, ``width``, ``gated``, ``activation``, ``route_from``,
+    ``combine_rows``: the rows a combine pass gathers on the layer's usual
+    path, and ``combine_tile``: the tokens a group of the buffer's sum, 0
+    where there is no buffer).
     """
 
     num_experts: int
@@ -469,6 +491,8 @@ class DroplessMoE(nn.Module):
             "moe_shape", experts=e, held=count, top_k=k, pairs=n * k,
             buffer_rows=buffer, latent=self.latent, width=f, gated=self.gated,
             activation=self.activation, route_from=self.route_from,
+            # the rows a combine pass gathers, and the tokens a group of its sum
+            combine_rows=buffer, combine_tile=SEGMENT_TILE if buffer < n * k else 0,
         )
 
         with jax.named_scope("moe_route"):
@@ -628,6 +652,7 @@ class DroplessMoE(nn.Module):
             # the whole N * k only for a step whose held rows outgrow the
             # buffer; it keeps nothing for its backward (which computes it
             # again), so the step's memory is the usual path's
+            self.sow("metrics", "moe_buffer_taken", (live <= buffer).astype(jnp.float32))
             y, dead = jax.lax.cond(
                 live <= buffer, partial(routed, buffer),
                 jax.checkpoint(partial(routed, n * k)), *operands,

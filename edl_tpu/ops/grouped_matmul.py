@@ -22,6 +22,16 @@ names none; the argument is for tests and for the probe that times both
 on the chip (``benchmark/tools/grouped_matmul_probe.py``). Each shape says
 which it took in its ``gmm_tiles`` note: ``path`` ``kernel`` or ``plain``,
 and on ``plain`` ``why`` (``backend``, or ``asked`` by the caller).
+
+``rows_summed_by_segment(rows [M, D], segments [M], S) -> [S, D]`` is the
+same kernel family put to a sum: ``out[s]`` is the float32 sum of the rows
+whose segment is ``s``. On a TPU the rows are sorted by segment and the
+segments taken ``SEGMENT_TILE`` at a time: a tile's rows are one ragged
+group, and Megablox's ``tgmm`` of the rows' one-hot place in their tile
+(exact in bfloat16) against the rows is the tile's ``[SEGMENT_TILE, D]``
+sums. Everywhere else ``jax.ops.segment_sum``. A held share's expert layer
+un-sorts its buffer with it (``models/moe.py:_sum_unsorted``), so that pass
+follows the buffer's rows and not the ``N k`` pairs.
 """
 
 from __future__ import annotations
@@ -40,7 +50,7 @@ from edl_tpu.obs import trace as obs_trace
 # ``tgmm`` for ``d rhs``, whose "contracting" and "columns" are the two sides
 # of a bank and whose contracted dimension is the rows). Fitted on the v5e at
 # the expert cells' held-share shapes: ``bench_results/gmm_tile_sweep.py``,
-# its table in PERF.md section 6 (PR 57).
+# its table in bench_results/README.md (PR 57).
 #
 # - **The row tile.** A tile's rows belong to one group or are masked, and a
 #   tile that holds a group's end is walked once for every group in it: a call
@@ -77,6 +87,11 @@ TILING = (256, 1024, 1024)
 _LANES = 128
 _VMEM_COUNTED = (16 - 2) * 2**20
 _KERNELS = ("gmm", "gmm_dlhs", "tgmm")
+# Segments a group of ``rows_summed_by_segment``'s ``tgmm``: the one-hot's
+# width, so a lane tile (256 and 512 read the same at the held-share cells'
+# buffers on the v5e, and the whole width as one column tile 1-16% faster than
+# ``_whole``'s: ``bench_results/segment_sum_probe.py``, PERF.md section 6, PR 60).
+SEGMENT_TILE = 128
 
 IMPLEMENTATIONS = ("pallas", "ragged_dot")
 
@@ -140,6 +155,21 @@ def _fit(kernel: str, m: int, groups: int, k: int, n: int, itemsize: int):
         if _working_set(tm, k, tn, itemsize) <= _VMEM_COUNTED:
             return tm, k, tn
     return tm, _whole(TILING[1], k), _whole(TILING[2], n)
+
+
+def _fit_segments(m: int, groups: int, d: int, itemsize: int):
+    """The tiling of ``rows_summed_by_segment``'s ``tgmm`` for ``[m, d]`` rows
+    in ``groups`` tiles of ``SEGMENT_TILE`` segments: ``tgmm``'s row tile, the
+    one-hot whole, and the widest column tile (the whole width first, so the
+    one-hot is read once) whose counted working set fits: two buffers each of
+    the rows' and the one-hot's blocks and of the float32 output's, its
+    accumulator, and the float32 copies the kernel's masks make of both."""
+    tm, tile = _row_tile("tgmm", m, groups), SEGMENT_TILE
+    for tn in _column_tiles(d, d):
+        held = (2 * itemsize + 4) * tm * (tn + tile) + 3 * 4 * tile * tn
+        if held <= _VMEM_COUNTED:
+            return tm, tile, tn
+    return tm, tile, _whole(_LANES, d)
 
 
 def _tilings(m: int, groups: int, k: int, n: int, itemsize: int):
@@ -260,3 +290,75 @@ def grouped_matmul(
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = _pallas(lhs, rhs, group_sizes, tilings, interpret)
     return out[:m] if pad else out
+
+
+def rows_summed_by_segment(
+    rows: jax.Array,
+    segments: jax.Array,
+    num_segments: int,
+    dtype=jnp.float32,
+    implementation: Optional[str] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """``out[s] = sum of rows[r] over the r with segments[r] == s``:
+    ``[num_segments, D]``, each row read in its own dtype, summed in float32
+    and rounded once to ``dtype``. ``segments`` (int32,
+    ``[M]``, in any order) may name no segment (``num_segments`` or more): such
+    a row is nobody's and counts as zeros whatever it holds, and a segment no
+    row names comes back as zeros. The work follows ``M``: one sort of ``M``
+    keys, one gather of ``M`` rows and a ``tgmm`` over them on a TPU (bfloat16
+    rows; the kernel has no derivative, and its callers are ``custom_vjp``
+    rules), ``jax.ops.segment_sum`` everywhere else."""
+    if rows.ndim != 2 or segments.shape != rows.shape[:1]:
+        raise ValueError(
+            "rows_summed_by_segment: rows %s and segments %s are not [M, D] and [M]"
+            % (rows.shape, segments.shape)
+        )
+    m, d = rows.shape
+    why = "asked"
+    if implementation is None:
+        implementation, why = default_implementation(), "backend"
+        if implementation == "pallas" and rows.dtype != jnp.bfloat16:
+            # the MXU would round wider rows to bfloat16 before it sums them
+            implementation, why = "ragged_dot", "dtype"
+    segments = segments.astype(jnp.int32)
+    nobodys = (segments < 0) | (segments >= num_segments)
+    if implementation == "ragged_dot":  # the plain form, as ``grouped_matmul`` names it
+        obs_trace.get_tracer().note_once(
+            "gmm_tiles", kernel="segment_sum", rows=m, contracting=num_segments,
+            columns=d, path="plain", why=why,
+        )
+        return jax.ops.segment_sum(  # an index past the segments is dropped
+            rows.astype(jnp.float32), jnp.where(nobodys, num_segments, segments),
+            num_segments,
+        ).astype(dtype)
+    if implementation != "pallas":
+        raise ValueError(
+            "rows_summed_by_segment: implementation %r is none of %r"
+            % (implementation, IMPLEMENTATIONS)
+        )
+    tile = SEGMENT_TILE
+    groups = -(-num_segments // tile)
+    tiling = _fit_segments(m, groups, d, rows.dtype.itemsize)
+    with jax.named_scope("segment_sum"):
+        # nobody's rows sort behind every group, and so do the rows that fill
+        # the last row tile: entries of the gather's index, not a pad of its
+        # result
+        keys = jnp.concatenate([
+            jnp.where(nobodys, groups * tile, segments),
+            jnp.full((-m % tiling[0],), groups * tile, jnp.int32),
+        ])
+        keys, by_segment = jax.lax.sort(
+            (keys, jnp.minimum(jnp.arange(keys.shape[0], dtype=jnp.int32), m - 1)),
+            num_keys=1,
+        )
+        sizes = jnp.sum(jax.nn.one_hot(keys // tile, groups, dtype=jnp.int32), axis=0)
+        place = (keys[None, :] % tile == jnp.arange(tile)[:, None]).astype(rows.dtype)
+        with obs_trace.span("kernel_trace", kernel="segment_sum"):
+            out = _megablox().tgmm(
+                place, rows[by_segment], sizes, preferred_element_type=dtype,
+                tiling=_note_tiles("segment_sum", keys.shape[0], groups, tile, d, tiling),
+                interpret=interpret,
+            )
+    out = out.reshape(groups * tile, d)
+    return out if groups * tile == num_segments else out[:num_segments]

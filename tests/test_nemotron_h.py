@@ -382,7 +382,7 @@ def test_each_traced_shape_leaves_one_moe_shape_instant():
     assert found == [{
         "experts": E, "held": 4, "top_k": K, "pairs": 64 * K, "buffer_rows": 160,
         "latent": LATENT, "width": F, "gated": False, "activation": "relu2",
-        "route_from": "ff_input",
+        "route_from": "ff_input", "combine_rows": 160, "combine_tile": 128,
     }]
 
 

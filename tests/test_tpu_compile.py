@@ -447,6 +447,49 @@ def test_megablox_compiles_for_v5e_on_the_tiles_of_a_cells_held_share(one_chip, 
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+# a held share's buffer summed by token: (buffer rows m, the width its experts
+# write, tokens) of the two cells PR 60 claims in, and Solar's ragged buffer
+SEGMENT_SUM_SHAPES = {
+    "smallthinker_21b_a3b": (24576, 2560, 16384),
+    "nemotron_3_super_120b_a12b": (5632, 1024, 8192),
+    "solar_open2_250b": (3280, 4096, 8192),  # no row tile divides 3280
+}
+
+
+@pytest.mark.parametrize("cell", list(SEGMENT_SUM_SHAPES))
+def test_the_buffers_sum_by_token_compiles_for_v5e_at_a_cells_shape(one_chip, cell):
+    """``rows_summed_by_segment`` on the TPU's path at a cell's buffer: one sort
+    of the buffer's keys, one gather of its rows (into whole row tiles: no pad
+    of the result), one ``tgmm`` over the tiles of 128 tokens ``_fit_segments`` tiles,
+    under the default scoped VMEM, and no array of more rows than the buffer's
+    at the layer's width but the float32 result."""
+    gm = importlib.import_module("edl_tpu.ops.grouped_matmul")
+    rows, width, tokens = SEGMENT_SUM_SHAPES[cell]
+    groups = tokens // gm.SEGMENT_TILE
+    tiling = gm._fit_segments(rows, groups, width, 2)
+    assert tiling == (128, 128, width)  # the whole width: the one-hot is read once
+
+    def summed(buffer, token):
+        return gm.rows_summed_by_segment(buffer, token, tokens, implementation="pallas")
+
+    compiled = jax.jit(summed).lower(
+        jax.ShapeDtypeStruct((rows, width), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "tgmm" in text
+    entry = text[text.index("ENTRY "):]
+    assert len(re.findall(r" sort\(", entry)) == 1
+    assert not re.search(r"= bf16\[\d+,\d+\]\S* pad\(", entry)  # the keys alone are padded
+    padded = rows + -rows % tiling[0]
+    wide = {
+        (int(r), dtype) for dtype, r in re.findall(r"(bf16|f32)\[(\d+),%d\]" % width, entry)
+    }
+    assert wide == {(rows, "bf16"), (padded, "bf16"), (tokens, "f32")}
+    out = jax.eval_shape(summed, jnp.zeros((rows, width), jnp.bfloat16), jnp.zeros((rows,), jnp.int32))
+    assert (out.shape, out.dtype) == ((tokens, width), jnp.float32)
+
+
 def test_gated_causal_conv_compiles_for_v5e_at_the_hybrids_widths(one_chip):
     """The gated short convolution, plain XLA with jax's own backward, at one
     sequence of 8192 and 2048 channels read out of the in projection's 6144: no
@@ -1431,7 +1474,10 @@ def test_a_one_branch_step_on_the_tpu_path_leaves_no_matmul_unplaced(one_chip):
     # two banks: in each branch of the layer's cond, forward 2 gmm; backward
     # the recomputed up's 1, 2 gmm_dlhs and 2 tgmm (3 / 3 / 3 where gated)
     assert census["kernels"]["gmm/forward"] == 2 * 2
-    assert census["kernels"]["tgmm/backward"] == 2 * 2
+    # and, since PR 60, the buffer branch's sum by token: the combine forward
+    # and recomputed (nothing is kept of this block), and the gather's gradient
+    assert census["kernels"]["tgmm/forward"] == 1
+    assert census["kernels"]["tgmm/backward"] == 2 * 2 + 2
 
 
 def _latent_cell():
@@ -1670,7 +1716,9 @@ def _expert_layer_census(text):
     router's matmul, a ``top_k``, a sort of the route's, a fusion of the gather
     of the chosen scores (however many XLA makes of it), a sort that carries the
     weights, the three Megablox kernels by the ``cond``'s branch (``buffer`` /
-    ``large``); ``where`` from the instruction's ``op_name``: ``forward``,
+    ``large``), the sort and the ``tgmm`` of the buffer's sum by token
+    (``segment_sort``, ``segment_sum``); ``where`` from the instruction's
+    ``op_name``: ``forward``,
     ``recomputed`` (the block's recomputation), ``large_again`` (the large
     branch's own, inside its backward), ``backward``."""
     import collections
@@ -1687,15 +1735,16 @@ def _expert_layer_census(text):
                 continue
             what = "scores_gather"
         elif opcode == "custom-call":
-            kernel = re.search(r"moe_experts/jit\((t?gmm)\)/", op_name)
+            kernel = re.search(r"moe_experts/jit\((t?gmm)\)/|/(segment_sum)/jit\(tgmm\)/", op_name)
             if "tpu_custom_call" not in line or not kernel:
                 continue
             branch = "large" if "branch_0_fun" in op_name else "buffer"
-            what = "%s_%s" % (kernel.group(1), branch)
+            what = "%s_%s" % (kernel.group(1) or kernel.group(2), branch)
         elif opcode == "sort":
             what = (
                 "top_k" if op_name.endswith("/top_k")
-                else "route_sort" if "/moe_route/" in op_name else "weights_sort"
+                else "route_sort" if "/moe_route/" in op_name
+                else "segment_sort" if "/segment_sum/" in op_name else "weights_sort"
             )
         elif "/moe_route/router/" in op_name:
             what = "router"
@@ -1720,7 +1769,14 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
     ``gmm`` three times forward (two ungated) and three times for ``d lhs``.
     The large branch keeps nothing and runs ``gate`` / ``up`` again inside its
     own backward, as it did. With ``remat_policy=None`` nothing is kept and the
-    recomputation runs all of it, the counts before the names."""
+    recomputation runs all of it, the counts before the names.
+
+    Since PR 60 the buffer branch sums its rows by token (one sort of the
+    buffer's 2048 keys and one ``tgmm`` over eight tiles of 128 tokens, forward
+    under ``moe_combine`` and once more as ``_rows_sorted``'s gradient): under
+    ``/moe/`` and outside the large branch no instruction, forward or backward,
+    has an operand or a result of the 4096 pairs' rows at the layer's width;
+    the large branch gathers them as it did."""
     from unittest import mock
 
     import numpy as np
@@ -1759,7 +1815,11 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
         )
     text = lowered.compile().as_text()  # under the default scoped VMEM, on the rule's tiles
     tiles = [a for name, a in obs_trace.get_tracer().notes() if name == "gmm_tiles"]
-    assert {a["kernel"] for a in tiles} == {"gmm", "gmm_dlhs", "tgmm"}
+    assert {a["kernel"] for a in tiles} == {"gmm", "gmm_dlhs", "tgmm", "segment_sum"}
+    (by_token,) = [a for a in tiles if a["kernel"] == "segment_sum"]
+    assert (by_token["rows"], by_token["groups"], by_token["contracting"]) == (2048, 8, 128)
+    assert by_token["tiling"] == [128, 128, 256] and by_token["path"] == "kernel"
+    tiles = [a for a in tiles if a is not by_token]
     for a in tiles:  # 8 held groups in a buffer of 2048 or the whole 4096
         assert a["groups"] == 8 and a["rows_a_group"] == a["rows"] // 8 in (256, 512)
         assert a["tiling"][0] == (128 if a["kernel"] == "tgmm" else 256)
@@ -1786,8 +1846,28 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
         ("gmm_buffer", "backward"): banks, ("tgmm_buffer", "backward"): banks,
         ("gmm_large", "forward"): banks, ("gmm_large", "large_again"): banks - 1,
         ("gmm_large", "backward"): banks, ("tgmm_large", "backward"): banks,
+        # the buffer's rows summed by token: the combine (whose value no
+        # recomputation needs) and ``_rows_sorted``'s gradient
+        ("segment_sort", "forward"): 1, ("segment_sort", "backward"): 1,
+        ("segment_sum_buffer", "forward"): 1, ("segment_sum_buffer", "backward"): 1,
     }
     assert dict(census) == {key: layers * n for key, n in want.items() if n}
+    pairs_rows = re.compile(r"\[4096,256\]|\[1024,4,256\]")  # every pair's row, flat or by token
+    under_moe = [
+        (re.search(r'op_name="([^"]*)"', line).group(1), line.split(", metadata=")[0])
+        for line in text.splitlines() if "/moe/" in line and 'op_name="' in line
+    ]
+    assert not [
+        head for op_name, head in under_moe
+        if "branch_0_fun" not in op_name and "/moe/" in op_name and pairs_rows.search(head)
+    ]
+    every_pairs = {
+        (scope, "backward" if "transpose(" in op_name else "forward")
+        for op_name, head in under_moe for scope in ("moe_combine", "moe_experts")
+        if "branch_0_fun" in op_name and op_name.endswith("/%s/gather" % scope)
+        and " gather(" in head and "bf16[4096,256]" in head
+    }
+    assert every_pairs >= {("moe_combine", "forward"), ("moe_experts", "backward")}
 
 
 # -- what a block's recomputation runs again of a mixer's projections (PR 54) --
