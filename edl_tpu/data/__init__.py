@@ -17,9 +17,13 @@ WIP/non-functional — SURVEY §2 C21: undefined names, excluded from ctest):
 - ``prefetch``   — fixed-shape batching (pad+mask, XLA static shapes) and
   host->device prefetch with bounded in-flight transfers (net-new: the
   reference has no device-feed stage at all).
+- ``block_diffusion`` — the forward (noising) process of a block-diffusion
+  training step, on the host, as a function of explicit randomness and of a
+  batch's place in the data order.
 """
 
 from edl_tpu.data.dataset import FileListDataset, FileSplitter, TxtFileSplitter
+from edl_tpu.data.block_diffusion import noise_draws, noised, noised_batch
 from edl_tpu.data.checkpoint import DataCheckpoint
 from edl_tpu.data.dispatcher import (
     DISPATCH_SERVICE,
@@ -45,6 +49,9 @@ __all__ = [
     "DataTask",
     "ElasticDataLoader",
     "batched",
+    "noise_draws",
+    "noised",
+    "noised_batch",
     "prefetch_to_device",
     "shuffled",
 ]

@@ -19,6 +19,7 @@ from edl_tpu.models.resnet import (
 from edl_tpu.models.decode import greedy_generate, init_cache
 from edl_tpu.models.transformer import (
     ArchSpec,
+    BlockDiffusionSpec,
     LatentAttention,
     LatentAttentionSpec,
     MTPSpec,
@@ -45,6 +46,7 @@ __all__ = [
     "MoESpec",
     "MOE_EP_RULES",
     "ArchSpec",
+    "BlockDiffusionSpec",
     "Mamba2Mixer",
     "MambaSpec",
     "GatedDeltaMixer",

@@ -135,6 +135,28 @@ MTP_SCOPES = ("mtp", "mtp_join", "mtp_head")
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusionSpec:
+    """The block-diffusion training step (BD3-LM, arXiv:2503.09573; SDAR,
+    arXiv:2510.06303): ``TransformerLM`` then takes ``[B, 2 L]`` ids, a clean
+    sequence ``x_0`` and beside it its noised copy ``x_t`` (positions of
+    ``x_0`` replaced by ``mask_id``, block by block of ``block`` positions:
+    ``data/block_diffusion.py`` makes the pair on the host), runs both through
+    every layer under the block-diffusion mask (``ops.attention``: a noised
+    position sees the clean text of strictly earlier blocks and its own
+    block's noised positions) at positions that repeat across the halves, and
+    scores the noised half alone. ``train/step.py:make_block_diffusion_loss``
+    is its loss head. ``mask_id`` is a row of the embedding like any other;
+    the model reads only ``block``."""
+
+    block: int = 4
+    mask_id: int = 0
+
+
+# the device scope of the attention call under ``ArchSpec.block_diffusion``
+BLOCK_DIFFUSION_SCOPE = "attn_block_diffusion"
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchSpec:
     """What a ``TransformerLM`` does differently from the dense default,
     as one hashable field; every default is the dense model's.
@@ -176,7 +198,10 @@ class ArchSpec:
     feed-forward) times ``residual_multiplier``, the logits divided by
     ``logits_scaling``. ``tie_embeddings`` projects onto the vocabulary
     with the embedding's own matrix, whose gradient is then the sum of
-    both uses."""
+    both uses. ``block_diffusion`` makes the model the denoiser of a
+    block-diffusion step (``BlockDiffusionSpec``): every layer is then
+    ``"attention"`` over a clean and a noised copy side by side, and there is
+    no decode path, window, selection or multi-token module with it."""
 
     layer_types: Optional[Tuple[str, ...]] = None
     mamba: Optional[MambaSpec] = None
@@ -199,6 +224,7 @@ class ArchSpec:
     attn_gate: bool = False
     one_branch: bool = False            # True: a block is one branch, no second
     mtp: Optional[MTPSpec] = None       # a multi-token-prediction module
+    block_diffusion: Optional[BlockDiffusionSpec] = None  # the training step's
 
 
 LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
@@ -347,6 +373,12 @@ class Attention(nn.Module):
     T]`` and ``index_operands`` (the indexer's q ``[B, T, J, Di]``, k ``[B, T,
     Di]`` and weights ``[B, T, J]``). Device scopes ``dsa_index`` / ``dsa_select`` / ``attn_sparse`` /
     ``dsa_target``. The decode cache has no selection and refuses it.
+
+    ``block_diffusion`` (a block length ``B``) makes the call's mask the third
+    kind ``ops.attention.attention`` knows: ``x`` is then ``2 L`` positions, a
+    clean sequence and its noised copy, and the mask ``block_diffusion=(L,
+    B)``. No decode cache, window, selection or custom ``attention_fn`` goes
+    with it; each raises by name.
     """
 
     num_heads: int
@@ -365,10 +397,28 @@ class Attention(nn.Module):
     gate: bool = False
     kernel_scope: Optional[str] = None
     sparse: Optional[SparseAttentionSpec] = None
+    block_diffusion: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, positions):
         d_model = x.shape[-1]
+        if self.block_diffusion is not None:
+            for name, given in (
+                ("decode", self.decode), ("window", self.window is not None),
+                ("sparse", self.sparse is not None),
+                ("attention_fn", self.attention_fn is not None),
+            ):
+                if given:
+                    raise NotImplementedError(
+                        "Attention: block_diffusion with %s: the block-diffusion "
+                        "mask is a training step's, over a clean and a noised "
+                        "copy in one call of ops.attention.attention" % name
+                    )
+            if x.shape[1] % 2:
+                raise ValueError(
+                    "Attention: block_diffusion over %d positions: a clean and "
+                    "a noised copy are 2 L" % x.shape[1]
+                )
         head_dim = self.head_dim or d_model // self.num_heads
         kv_heads = (
             self.num_kv_heads if self.num_kv_heads is not None
@@ -431,6 +481,8 @@ class Attention(nn.Module):
             extra = {} if self.scale is None else {"scale": self.scale}
             if self.window is not None:
                 extra["window"] = self.window
+            if self.block_diffusion is not None:
+                extra["block_diffusion"] = (x.shape[1] // 2, self.block_diffusion)
             with _scope(self.kernel_scope):
                 out = attn(q, k, v, causal=True, **extra)
             out = jnp.swapaxes(out, 1, 2)
@@ -713,6 +765,7 @@ class Block(nn.Module):
         elif self.mixer in ("attention", "sliding_attention", "sparse_attention"):
             sliding = self.mixer == "sliding_attention"
             selecting = self.mixer == "sparse_attention"
+            diffusing = arch.block_diffusion is not None
             if sliding and arch.sliding_window is None:
                 raise ValueError("a sliding_attention layer needs sliding_window")
             if selecting and self.decode:
@@ -730,10 +783,12 @@ class Block(nn.Module):
                 window=arch.sliding_window if sliding else None,
                 gate=arch.attn_gate,
                 # a model of both kinds tells their device time apart
-                kernel_scope=None if arch.sliding_window is None
+                kernel_scope=BLOCK_DIFFUSION_SCOPE if diffusing
+                else None if arch.sliding_window is None
                 else ("attn_window" if sliding else "attn_full"),
                 sparse=(arch.sparse_attention or SparseAttentionSpec())
                 if selecting else None,
+                block_diffusion=arch.block_diffusion.block if diffusing else None,
                 name="attn",
             )(h, positions)
         else:
@@ -966,6 +1021,13 @@ class TransformerLM(nn.Module):
             dtype=self.dtype, name="embed",
         )
         x = _times(embed(tokens), arch.embedding_multiplier)
+        if arch.block_diffusion is not None:
+            length = self._block_diffusion_length(arch, layer_types, tokens)
+            if positions is None:
+                # position i of either copy is position i of the sequence
+                positions = jnp.broadcast_to(
+                    (jnp.arange(2 * length) % length)[None, :], tokens.shape
+                )
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1])[None, :], tokens.shape
@@ -989,6 +1051,10 @@ class TransformerLM(nn.Module):
                 None if i < arch.dense_layers else self.moe, arch,
                 layer_types[i], name="layer_%d" % i,
             )(x, positions)
+        if arch.block_diffusion is not None:
+            # the noised half alone is scored: the clean half was keys and
+            # values, and its last layer's output is never read
+            x = x[:, length:]
         x = RMSNorm(self.norm_eps, name="ln_f")(x)
         if arch.tie_embeddings:
             logits = _head_matmul(x, embed.embedding.astype(x.dtype).T)
@@ -1044,3 +1110,42 @@ class TransformerLM(nn.Module):
             self.sow("losses", "mtp_loss", arch.mtp.loss_weight * mtp_loss)
             self.sow("metrics", "mtp_loss", mtp_loss)
         return _times(logits, 1.0 / arch.logits_scaling)
+
+    def _block_diffusion_length(self, arch, layer_types, tokens) -> int:
+        """``L`` of a block-diffusion call's ``[B, 2 L]`` ids, after refusing
+        by name what does not go with the spec; one ``block_diffusion_shape``
+        instant a shape and stage."""
+        spec = arch.block_diffusion
+        refused = [
+            name for name, given in (
+                ("decode=True", self.decode),
+                ("a window (sliding_window)", arch.sliding_window is not None),
+                ("sparse_attention", arch.sparse_attention is not None),
+                ("mtp", arch.mtp is not None),
+                ("an attention_fn", self.attention_fn is not None),
+                ("a mixer that is not attention (layer_types %s)"
+                 % sorted(set(layer_types) - {"attention"}),
+                 set(layer_types) != {"attention"}),
+                ("one_branch", arch.one_branch),
+            ) if given
+        ]
+        if refused:
+            raise NotImplementedError(
+                "TransformerLM: block_diffusion with %s: the step runs a clean "
+                "and a noised copy through attention layers under one mask and "
+                "scores the noised half" % ", ".join(refused)
+            )
+        length, odd = divmod(tokens.shape[1], 2)
+        if odd or spec.block < 1 or length % spec.block:
+            raise ValueError(
+                "TransformerLM: block_diffusion wants [B, 2 L] ids, x_0 then "
+                "x_t, with L whole blocks of %d: got %d positions"
+                % (spec.block, tokens.shape[1])
+            )
+        obs_trace.get_tracer().note_once(
+            "block_diffusion_shape", length=length, block=spec.block,
+            mask_id=spec.mask_id, positions=tokens.shape[1],
+            head_rows=tokens.shape[0] * length,
+            logit_bytes=4 * tokens.shape[0] * length * self.vocab_size,
+        )
+        return length

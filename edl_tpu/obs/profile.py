@@ -205,8 +205,9 @@ PHASES = ("forward", "backward", "optimizer", "numerics", "other")
 # (models/gated_delta.py), the gated short convolution's (models/short_conv.py),
 # a sparse-attention layer's (ops/sparse_attention.py), a latent-attention
 # layer's, a multi-token-prediction module's (``mtp`` is what its block does
-# outside the block's own scopes) and, in a model of windowed and full attention
-# layers, each kind's (models/transformer.py). Then the flax module names, for what has no scope:
+# outside the block's own scopes), in a model of windowed and full attention
+# layers each kind's, and a block-diffusion step's attention call
+# (models/transformer.py). Then the flax module names, for what has no scope:
 # ``attn`` is an attention layer's projections, rotation and QK norms, a mixer's
 # own name what it does outside its scopes. :func:`part_of` adds the parts that
 # are no component: ``attn_kernel`` (a Pallas call right under ``attn``),
@@ -221,6 +222,7 @@ _STEP_SCOPES = (
     "sconv_proj", "sconv_conv",
     "dsa_index", "dsa_select", "dsa_target", "attn_sparse",
     "attn_window", "attn_full", "attn_gate", "attn_mla", "mla_proj",
+    "attn_block_diffusion",
     "mtp", "mtp_join", "mtp_head",
     "embed", "attn", "mlp", "moe", "mamba", "gdn", "kda", "sconv",
 )

@@ -10,9 +10,14 @@ Layout: ``[batch, heads, seq, head_dim]``. The kernels are grid-pipelined
 (``flash2``: ``_flash2_kernel`` forward, ``_flash2_bwd_kernel`` backward):
 the grid is ``(batch*heads, q_blocks, kv_blocks)`` (backward: the q blocks
 innermost), so the other side's blocks are copied block by block behind
-the compute and a kernel's VMEM does not grow with the sequence. They take
-a **window** (``window=W`` with ``causal``: query ``i`` sees keys ``j`` with
-``i - W < j <= i``). Under a mask the innermost steps are **spans** of the
+the compute and a kernel's VMEM does not grow with the sequence. A mask is
+one of **three kinds**: causal (``causal``: query ``i`` sees keys ``j <= i``,
+sequence ends aligned), a **window** (``window=W`` with ``causal``: ``i - W <
+j <= i``), or **block diffusion** (``block_diffusion=(L, B)`` with ``causal``,
+over ``2 L`` positions, a clean copy of a sequence and then its noised copy,
+in blocks of ``B``: :func:`_sees` has the rule; a block then sees up to two
+runs of the other side's blocks that do not touch, and its innermost steps
+walk the one and jump to the other). Under a mask the innermost steps are **spans** of the
 other side that start where a block's first visible key (or row) lies, at
 an element and not at a block under a window; a step the mask leaves
 nothing for holds the nearest live span again, so what a block cannot see
@@ -48,32 +53,88 @@ from edl_tpu.obs import trace as obs_trace
 NEG_INF = -1e30
 
 
-def _dense_causal_mask(scores: jax.Array, window: int | None = None) -> jax.Array:
+def _dense_causal_mask(
+    scores: jax.Array, window: int | None = None, bd=None
+) -> jax.Array:
     """End-aligned causal mask for a dense [..., Tq, Tk] score tensor:
     ``qpos = arange(Tq) + (Tk - Tq)`` so sequence ENDS line up (the one
     convention every path in this module must share). With ``window`` a
     query sees the ``window`` newest of those keys, itself included:
-    ``qpos - window < kpos <= qpos``."""
+    ``qpos - window < kpos <= qpos``; with ``bd`` the block-diffusion rule
+    of :func:`_sees`."""
     tq, tk = scores.shape[-2], scores.shape[-1]
     qpos = jnp.arange(tq)[:, None] + (tk - tq)
     kpos = jnp.arange(tk)[None, :]
-    return jnp.where(_sees(qpos, kpos, window), scores, NEG_INF)
+    return jnp.where(_sees(qpos, kpos, window, bd), scores, NEG_INF)
 
 
-def _sees(qpos, kpos, window):
-    """Whether the query at ``qpos`` sees the key at ``kpos``: causal, and
-    inside the window if there is one."""
+def _sees(qpos, kpos, window, bd=None):
+    """Whether the query at ``qpos`` sees the key at ``kpos``. One of three
+    kinds: causal; causal inside a ``window``; or block diffusion, ``bd = (L,
+    B)`` over ``2 L`` positions, the clean sequence in ``[0, L)`` and its
+    noised copy in ``[L, 2 L)``, position ``i`` of either half in block ``(i
+    mod L) // B``: a clean query sees the clean keys of its own and of earlier
+    blocks (causal by whole blocks) and no noised key; a noised query sees the
+    clean keys of STRICTLY earlier blocks (never its own block's clean tokens,
+    which are its answers) and the noised keys of its own block, both ways."""
+    if bd is not None:
+        length, block = bd
+        q_noised, k_noised = qpos >= length, kpos >= length
+        return _bd_visible(
+            jnp.where(q_noised, qpos - length, qpos),
+            jnp.where(k_noised, kpos - length, kpos), q_noised, k_noised, block,
+        )
     if window is None:
         return qpos >= kpos
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _check_window(window, causal: bool):
+_FAR = 1 << 30  # below every position's distance, and above
+
+
+def _bd_visible(q, k, q_noised, k_noised, block, where=jnp.where):
+    """:func:`_sees`'s block-diffusion rule on positions inside their halves
+    (``q``, ``k``: ``i mod L``) and the halves themselves (arrays in the dense
+    reference; in a kernel a tile lies in one half of each side, so they are
+    scalars and the rule is two comparisons of the tile). With ``t`` the
+    key's distance from the first position of the query's block, the key is
+    seen iff ``lo <= t < hi``: clean keys from any distance below up to the
+    query's own block (a clean query: ``hi = B``) or short of it (a noised
+    one: ``hi = 0``), noised keys inside the block (``0 <= t < B``) and by a
+    noised query alone."""
+    start = q & -block if block & (block - 1) == 0 else q // block * block
+    t = k - start
+    hi = where(k_noised, where(q_noised, block, -_FAR), where(q_noised, 0, block))
+    lo = where(k_noised, 0, -_FAR)
+    return (t >= lo) & (t < hi)
+
+
+def _check_window(window, causal: bool, block_diffusion=None, tq=None, tk=None):
+    """The mask's kind from the arguments every entry point takes: refuses a
+    window that is not a causal mask's, a block-diffusion mask over another
+    shape than its own, and both at once."""
+    if window is not None and block_diffusion is not None:
+        raise ValueError(
+            "a window (%r) and block_diffusion (%r) together: a mask is causal, "
+            "a window or block diffusion, one kind a call"
+            % (window, block_diffusion)
+        )
     if window is not None and (not causal or window < 1):
         raise ValueError(
             "a window (%r) is the newest keys of a causal mask: it needs "
             "causal=True and at least one key" % (window,)
         )
+    if block_diffusion is not None:
+        length, block = block_diffusion
+        if not causal or block < 1 or length < block or length % block or not (
+            tq == tk == 2 * length
+        ):
+            raise ValueError(
+                "block_diffusion=(L, B) (%r) is causal by blocks of B over 2 L "
+                "positions, a clean copy and a noised one: it needs causal=True, "
+                "B dividing L and 2 L queries and keys (%r, %r)"
+                % (block_diffusion, tq, tk)
+            )
 
 
 def attention_reference(
@@ -83,10 +144,12 @@ def attention_reference(
     causal: bool = False,
     scale: float | None = None,
     window: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
     """Plain softmax attention; [B, H, T, D] in, [B, H, Tq, D] out."""
     return attention_reference_with_lse(
-        q, k, v, causal=causal, scale=scale, window=window
+        q, k, v, causal=causal, scale=scale, window=window,
+        block_diffusion=block_diffusion,
     )[0]
 
 
@@ -129,12 +192,14 @@ def attention_reference_with_lse(
     causal: bool = False,
     scale: float | None = None,
     window: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ):
     """Reference attention that also returns per-row logsumexp of the
     scaled scores ``[B, H, Tq]`` — the residual blockwise/ring merging
     needs. Grouped k/v (GQA) broadcast in-graph; their VJP folds dk/dv
-    back to the grouped width automatically."""
-    _check_window(window, causal)
+    back to the grouped width automatically. The mask, of whichever kind,
+    is a dense boolean array here."""
+    _check_window(window, causal, block_diffusion, q.shape[2], k.shape[2])
     if scale is None:
         scale = q.shape[-1] ** -0.5
     k, v = _broadcast_kv(q, k, v)
@@ -142,7 +207,7 @@ def attention_reference_with_lse(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
     if causal:
-        scores = _dense_causal_mask(scores, window)
+        scores = _dense_causal_mask(scores, window, block_diffusion)
     lse = jax.scipy.special.logsumexp(scores, axis=-1)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
@@ -184,25 +249,60 @@ def _dot_tn(a, b):
     )
 
 
-def _causal_mask(s, q_lo, k_lo, window=None, keys_first=False):
+def _causal_mask(s, q_lo, k_lo, window=None, keys_first=False, bd=None):
     """Mask one [rows, keys] score tile (``keys_first``: [keys, rows])
     whose first row sits at position ``q_lo`` (its index plus ``q_offset =
     tk - tq``, which aligns sequence *ends*, matching
-    ``attention_reference``) and whose first key is ``k_lo``; ``window`` as
-    in :func:`_dense_causal_mask`."""
-    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(keys_first)) + q_lo
-    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(not keys_first)) + k_lo
-    return jnp.where(_sees(qpos, kpos, window), s, NEG_INF)
+    ``attention_reference``) and whose first key is ``k_lo``; ``window`` and
+    ``bd`` as in :func:`_dense_causal_mask`. A block-diffusion tile lies in
+    one half of each side (the blocks divide ``L``), so which halves is read
+    off its corner and the positions count from their half's start."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(keys_first))
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(not keys_first))
+    if bd is not None:
+        length, block = bd
+        q_noised, k_noised = q_lo >= length, k_lo >= length
+        seen = _bd_visible(
+            qpos + (q_lo - _select(q_noised, length, 0)),
+            kpos + (k_lo - _select(k_noised, length, 0)),
+            q_noised, k_noised, block, where=_select,
+        )
+        return jnp.where(seen, s, NEG_INF)
+    return jnp.where(_sees(qpos + q_lo, kpos + k_lo, window), s, NEG_INF)
 
 
-def _tile_class(q_lo, nq, k_lo, nk, window):
+def _select(flag, a, b):
+    """``jnp.where`` of two whole numbers on a kernel's scalar flag."""
+    return jax.lax.select(flag, jnp.int32(a), jnp.int32(b))
+
+
+def _tile_class(q_lo, nq, k_lo, nk, window, bd=None):
     """``(dead, interior)`` of the tile whose ``nq`` rows start at position
     ``q_lo`` and whose ``nk`` keys start at ``k_lo``: dead when no row sees
-    any key, interior when every row sees every key, an edge (the diagonal
-    or the window's old side crosses it) otherwise. Scalars in a kernel,
-    numpy arrays in :func:`tile_census`."""
+    any key, interior when every row sees every key, an edge (the diagonal,
+    the window's old side or a block-diffusion boundary crosses it)
+    otherwise. Scalars in a kernel, numpy arrays in :func:`tile_census`."""
     q_hi = q_lo + nq - 1
     k_hi = k_lo + nk - 1
+    if bd is not None:
+        # a tile in one half of each side; rows and keys by their blocks
+        length, block = bd
+        q_noised, k_noised = q_lo >= length, k_lo >= length
+        first, last = (q_lo % length) // block, (q_hi % length) // block
+        k_first, k_last = (k_lo % length) // block, (k_hi % length) // block
+        own = q_noised & k_noised        # noised rows, noised keys: own block
+        clean = ~q_noised & ~k_noised    # causal by blocks
+        earlier = q_noised & ~k_noised   # strictly earlier blocks
+        dead = (
+            (~q_noised & k_noised) | (clean & (k_first > last))
+            | (earlier & (k_first >= last))
+            | (own & ((k_first > last) | (k_last < first)))
+        )
+        interior = (
+            (clean & (k_last <= first)) | (earlier & (k_last < first))
+            | (own & (k_first == last) & (k_last == first))
+        )
+        return dead, interior
     dead = q_hi < k_lo
     interior = q_lo >= k_hi
     if window is not None:
@@ -273,6 +373,77 @@ def _spans(seen, block, steps, total, window, xp=_lax):
     )
 
 
+# Block diffusion: a block of either side sees up to TWO runs of the other
+# side's blocks, which do not touch. A q block of the clean half walks the
+# clean keys up to its own last row (a causal walk); one of the noised half
+# walks the clean keys of the blocks before its last row's and then jumps to
+# the noised keys of its own rows. A kv block of clean keys is walked by the
+# clean rows from its first key on and by the noised rows of later blocks; one
+# of noised keys by its own noised rows alone. The innermost grid steps count
+# through the first run and then the second; the steps left over hold the last
+# live block again (nothing is copied) and the kernel skips them. Blocks
+# divide ``L``, so a tile lies in one half of each side, and they are whole
+# blocks of ``B``, at least two.
+
+
+def _bd_seen(i, own, bd, side, xp=_lax):
+    """``(a_lo, a_hi, b_lo, b_hi, b_on)``: the two runs of keys (``side``
+    ``"kv"``) that q block ``i`` of ``own`` rows sees, or of rows (``"q"``)
+    that see kv block ``i`` of ``own`` keys, first and last element of each;
+    ``b_on`` is 1 where there is a second run and 0 where not."""
+    length, block = bd
+    noised = xp.floor_divide(i * own, length)      # 0: the clean half, 1
+    lo = i * own - noised * length                 # from its half's start
+    if side == "kv":
+        return (
+            0 * i, lo + own - 1 - noised * block,
+            length + lo, length + lo + own - 1, noised,
+        )
+    return (
+        i * own, length + noised * (lo + own) - 1,
+        length + lo + block, 0 * i + 2 * length - 1, 1 - noised,
+    )
+
+
+def _bd_runs(seen, other, xp=_lax):
+    """``(a_first, a_count, b_first, b_count)``: :func:`_bd_seen`'s two runs
+    in blocks of ``other``."""
+    a_lo, a_hi, b_lo, b_hi, b_on = seen
+    a_first, b_first = xp.floor_divide(a_lo, other), xp.floor_divide(b_lo, other)
+    return (
+        a_first, xp.floor_divide(a_hi, other) - a_first + 1,
+        b_first, b_on * (xp.floor_divide(b_hi, other) - b_first + 1),
+    )
+
+
+def _bd_block(runs, s, xp=_lax):
+    """``(block, live)``: the block of the other side that step ``s`` holds
+    (the last live one again past the runs' end) and whether the step is one
+    of the runs' at all."""
+    a_first, a_count, b_first, b_count = runs
+    t = xp.minimum(s, a_count + b_count - 1)
+    jumped = xp.minimum(xp.maximum(t - a_count + 1, 0), 1)
+    return (
+        a_first + t + jumped * (b_first - a_first - a_count),
+        s < a_count + b_count,
+    )
+
+
+def _bd_steps(bd, block_q, block_k):
+    """``(kv steps a q block, q steps a kv block)`` under block diffusion:
+    the longest walk of either side."""
+    import numpy as np
+
+    length = bd[0]
+
+    def most(own, other, side):
+        blocks = np.arange(2 * length // own)
+        runs = _bd_runs(_bd_seen(blocks, own, bd, side, np), other, np)
+        return int((runs[1] + runs[3]).max())
+
+    return most(block_q, block_k, "kv"), most(block_k, block_q, "q")
+
+
 def _span_need(seen):
     """The most keys (rows) any block walks: from where its first span
     starts to the last one it sees (:func:`_spans` under a window)."""
@@ -306,11 +477,13 @@ def _span_steps(window, block_q, block_k, tq, tk):
     )
 
 
-def _flash2_maps(causal, window, block_q, block_k, tq, tk, group):
+def _flash2_maps(causal, window, block_q, block_k, tq, tk, group, bd=None):
     """``((kv steps, kv index map), (q steps, q index map))`` of the
     grid-pipelined kernels: where the span of the other side that the
     innermost grid step ``s`` of a block holds begins, as a block index
-    without a window and as an element offset under one."""
+    without a window and as an element offset under one. Under block
+    diffusion (``bd``) a block index that jumps from the first run to the
+    second (:func:`_bd_block`)."""
     from jax.experimental import pallas as pl
 
     num_q, num_k, off = tq // block_q, tk // block_k, tk - tq
@@ -318,6 +491,19 @@ def _flash2_maps(causal, window, block_q, block_k, tq, tk, group):
         return (
             (num_k, lambda i, qi, s: (i // group, s, 0)),
             (num_q, lambda i, ki, s: (i, s, 0)),
+        )
+    if bd is not None:
+        kv_steps, q_steps = _bd_steps(bd, block_q, block_k)
+
+        def jumping(own, other, side, head):
+            def index_map(i, block, s):
+                runs = _bd_runs(_bd_seen(block, own, bd, side), other)
+                return (head(i), _bd_block(runs, s)[0], 0)
+            return index_map
+
+        return (
+            (kv_steps, jumping(block_q, block_k, "kv", lambda i: i // group)),
+            (q_steps, jumping(block_k, block_q, "q", lambda i: i)),
         )
     kv_steps, q_steps = _span_steps(window, block_q, block_k, tq, tk)
 
@@ -356,7 +542,8 @@ def _span_spec(block, width, index_map, window):
     )
 
 
-def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
+def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv",
+                bd=None):
     """Shares of the ``tq x tk`` score rectangle, by area, that a
     grid-pipelined kernel with these blocks finds ``dead`` (never walked,
     or stepped over: neither copied nor computed), ``interior`` and
@@ -367,6 +554,23 @@ def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
 
     if not causal:
         return {"dead": 0.0, "interior": 1.0, "edge": 0.0}
+    if bd is not None:
+        own, other = (block_q, block_k) if side == "kv" else (block_k, block_q)
+        steps = _bd_steps(bd, block_q, block_k)[side != "kv"]
+        block = np.arange(2 * bd[0] // own)[:, None]
+        runs = _bd_runs(_bd_seen(block, own, bd, side, np), other, np)
+        held, live = _bd_block(runs, np.arange(steps)[None, :], np)
+        q_lo, k_lo = block * own, held * other
+        if side != "kv":
+            q_lo, k_lo = k_lo, q_lo
+        dead, interior = _tile_class(q_lo, block_q, k_lo, block_k, None, bd)
+        tile = block_q * block_k / (tq * tk)
+        interior = (live & interior).sum() * tile
+        edge = (live & ~dead).sum() * tile - interior
+        return {
+            "dead": float(1.0 - interior - edge), "interior": float(interior),
+            "edge": float(edge),
+        }
     off = tk - tq
     kv_steps, q_steps = _span_steps(window, block_q, block_k, tq, tk)
     if side == "kv":
@@ -391,13 +595,21 @@ def tile_census(tq, tk, block_q, block_k, causal, window=None, side="kv"):
 
 
 def _note_tiles(kernel, tq, tk, block_q, block_k, causal, window, side,
-                **more):
+                bd=None, **more):
     """One ``attn_tiles`` instant in the span ring for each shape a
     grid-pipelined kernel is traced at in a stage (``note_once``): what the
     mask makes of its tiles (and what ``more`` the kernel has to say of
-    itself)."""
-    shares = tile_census(tq, tk, block_q, block_k, causal, window, side)
+    itself). Under block diffusion also the mask's kind, its ``(L, B)``, the
+    share of the rectangle's pairs that are ``visible`` and the ``path``:
+    ``kernel`` from a kernel's own trace, ``plain`` (with ``why``) from
+    :func:`_note_planned_tiles`."""
+    shares = tile_census(tq, tk, block_q, block_k, causal, window, side, bd)
     live = shares["interior"] + shares["edge"]
+    if bd is not None:
+        more = {
+            "path": "kernel", **more, "mask": "block_diffusion", "length": bd[0],
+            "block": bd[1], "visible": bd[0] * (bd[0] + bd[1]) / (tq * tk),
+        }
     obs_trace.get_tracer().note_once(
         "attn_tiles", kernel=kernel, tq=tq, tk=tk, block_q=block_q,
         block_k=block_k, window=window,
@@ -441,7 +653,7 @@ def _softmax_update(s, m, l, acc, v):
 def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                    acc_scr, *, causal: bool, scale: float, q_block: int,
                    block_k: int, num_k: int, q_offset: int,
-                   window: int | None = None, seq_k: int = 0):
+                   window: int | None = None, seq_k: int = 0, bd=None):
     """Grid-pipelined forward: the KV loop lives in the GRID (innermost
     dimension), so Pallas double-buffers each KV block's HBM→VMEM copy
     behind the previous block's compute, and the VMEM footprint does not
@@ -456,7 +668,9 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     q_lo = qi * q_block + q_offset
     k_lo = step * block_k
     live = True
-    if causal:
+    if bd is not None:
+        k_lo, live = _bd_tile(qi, step, q_block, block_k, bd, "kv")
+    elif causal:
         seen = _kv_range(qi, q_block, q_offset, window)
         k_lo += _spans(seen, block_k, num_k, seq_k, window)[0]
         # a dead tile (past the diagonal, before the window) skips the
@@ -474,7 +688,7 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         v = v_ref[0]
         s = _dot_nt(q_ref[0], k_ref[0]) * scale
         if causal:
-            s = _causal_mask(s, q_lo, k_lo, window)
+            s = _causal_mask(s, q_lo, k_lo, window, bd=bd)
         m_scr[:], l_scr[:], acc_scr[:] = _softmax_update(
             s, m_scr[:], l_scr[:], acc_scr[:], v
         )
@@ -488,6 +702,13 @@ def _flash2_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         # sublane dim 8-aligned, which the TPU lowering requires (a plain
         # (1, block_q) block over [B*H, Tq] has sublane 1 and is rejected)
         lse_ref[0] = m_scr[:, :1] + jnp.log(l)
+
+
+def _bd_tile(i, step, own, other, bd, side):
+    """``(first element of the other side's block that step ``step`` of block
+    ``i`` holds, whether the step is live)`` in a block-diffusion kernel."""
+    held, live = _bd_block(_bd_runs(_bd_seen(i, own, bd, side), other), step)
+    return held * other, live
 
 
 def _grid_pipeline_kwargs() -> dict:
@@ -516,6 +737,7 @@ def _bwd_delta(g: jax.Array, o: jax.Array, b: int, h: int, tq: int, d: int):
 def _flash2_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
+    bd=None,
 ):
     """(o, lse) via the grid-pipelined kernel; ``lse is None`` marks the
     ragged-shape fallback to the jnp reference (the backward then uses the
@@ -525,8 +747,8 @@ def _flash2_forward(
 
     b, h, tq, d = q.shape
     tk, d_v = k.shape[2], v.shape[3]  # values may be narrower than the keys
-    block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
-    if not _spans_fit(block_q, block_k, tq, tk, window, "kv") or (
+    block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv", bd)
+    if not _spans_fit(block_q, block_k, tq, tk, window, "kv", bd) or (
         causal and tq > tk
     ):
         # ragged blocks, or end-aligned causal with MORE queries than keys:
@@ -534,7 +756,8 @@ def _flash2_forward(
         # reference degenerates to a uniform softmax — not worth defeating
         # the kernel's masked-block skipping to reproduce
         return attention_reference(
-            q, k, v, causal=causal, scale=scale, window=window
+            q, k, v, causal=causal, scale=scale, window=window,
+            block_diffusion=bd,
         ), None
 
     g = _gqa_group(q, k)
@@ -542,9 +765,9 @@ def _flash2_forward(
     kf = k.reshape(b * (h // g), tk, d)
     vf = v.reshape(b * (h // g), tk, d_v)
     (num_k, kv_map), _ = _flash2_maps(
-        causal, window, block_q, block_k, tq, tk, g
+        causal, window, block_q, block_k, tq, tk, g, bd
     )
-    _note_tiles("flash2_fwd", tq, tk, block_q, block_k, causal, window, "kv")
+    _note_tiles("flash2_fwd", tq, tk, block_q, block_k, causal, window, "kv", bd)
     kv_spec = _span_spec(block_k, d, kv_map, window)
     v_spec = kv_spec if d_v == d else _span_spec(block_k, d_v, kv_map, window)
     grid = (b * h, tq // block_q, num_k)
@@ -560,6 +783,7 @@ def _flash2_forward(
             q_offset=tk - tq,
             window=window,
             seq_k=tk,
+            bd=bd,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq, d_v), q.dtype),
@@ -592,7 +816,7 @@ def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dq_ref, dq_scr, *, causal: bool, scale: float,
                           q_block: int, block_k: int, num_k: int,
                           q_offset: int, window: int | None = None,
-                          seq_k: int = 0):
+                          seq_k: int = 0, bd=None):
     """Grid-pipelined dq: KV blocks ride the innermost grid dimension
     (double-buffered DMA), dq accumulates in VMEM scratch across steps —
     the backward twin of :func:`_flash2_kernel`'s structure, the spans
@@ -604,7 +828,9 @@ def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_lo = qi * q_block + q_offset
     k_lo = step * block_k
     live = True
-    if causal:
+    if bd is not None:
+        k_lo, live = _bd_tile(qi, step, q_block, block_k, bd, "kv")
+    elif causal:
         seen = _kv_range(qi, q_block, q_offset, window)
         k_lo += _spans(seen, block_k, num_k, seq_k, window)[0]
         live = ~_tile_class(q_lo, q_block, k_lo, block_k, window)[0]
@@ -623,7 +849,7 @@ def _flash2_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, q_lo, k_lo, window)
+            s = _causal_mask(s, q_lo, k_lo, window, bd=bd)
         p = jnp.exp(s - lse)
         dp = _dot_nt(do, v)
         ds = p * (dp - delta)
@@ -638,7 +864,8 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                            scale: float, block_q: int, k_block: int,
                            num_q: int, q_offset: int,
-                           window: int | None = None, seq_q: int = 0):
+                           window: int | None = None, seq_q: int = 0,
+                           bd=None):
     """Grid-pipelined dk/dv: Q/dO/lse/delta blocks ride the innermost
     grid dimension, dk/dv accumulate in scratch per KV block. Under a
     mask the ``num_q`` steps are the kv block's spans of the ``seq_q``
@@ -650,7 +877,9 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_lo = ki * k_block
     q_lo = step * block_q + q_offset
     live = True
-    if causal:
+    if bd is not None:
+        q_lo, live = _bd_tile(ki, step, k_block, block_q, bd, "q")
+    elif causal:
         seen = _q_range(ki, k_block, q_offset, window, seq_q)
         q_lo += _spans(seen, block_q, num_q, seq_q, window)[0]
         # q rows entirely before this kv block's first column, or past the
@@ -672,7 +901,7 @@ def _flash2_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(q, k) * scale
         if causal:
-            s = _causal_mask(s, q_lo, k_lo, window)
+            s = _causal_mask(s, q_lo, k_lo, window, bd=bd)
         p = jnp.exp(s - lse)
         dv_scr[:] = dv_scr[:] + _dot_tn(p.astype(do.dtype), do)
         dp = _dot_nt(do, v)
@@ -691,7 +920,7 @@ def _flash2_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
                        causal: bool, scale: float, block_q: int, k_block: int,
                        num_q: int, num_k: int, q_offset: int, row_align: int,
-                       window: int | None = None, seq_q: int = 0):
+                       window: int | None = None, seq_q: int = 0, bd=None):
     """The whole grid-pipelined backward in one walk, dk/dv's: a kv block's
     spans of rows ride the innermost grid dimension, and a live tile's
     ``s``, ``p``, ``dp`` and ``ds`` are computed once for all three
@@ -713,7 +942,9 @@ def _flash2_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_lo = ki * k_block
     row = step * block_q
     live = True
-    if causal:
+    if bd is not None:
+        row, live = _bd_tile(ki, step, k_block, block_q, bd, "q")
+    elif causal:
         seen = _q_range(ki, k_block, q_offset, window, seq_q)
         row += _spans(seen, block_q, num_q, seq_q, window)[0]
         live = ~_tile_class(row + q_offset, block_q, k_lo, k_block, window)[0]
@@ -737,7 +968,9 @@ def _flash2_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0]
         s = _dot_nt(k, q) * scale                       # [bk, bq]
         if causal:
-            s = _causal_mask(s, row + q_offset, k_lo, window, keys_first=True)
+            s = _causal_mask(
+                s, row + q_offset, k_lo, window, keys_first=True, bd=bd
+            )
         p = jnp.exp(s - lse)
         dv_scr[:] = dv_scr[:] + _dot_nn(p.astype(do.dtype), do)
         dp = _dot_nt(v, do)
@@ -801,7 +1034,7 @@ def _fused_bwd_vmem(tq, d, block_q, block_k, itemsize):
 def _flash2_backward(
     q, k, v, o, lse, g, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
-    dkv_blocks: tuple[int, int] | None = None,
+    dkv_blocks: tuple[int, int] | None = None, bd=None,
 ):
     """(dq, dk, dv) via the grid-pipelined backward kernels;
     ``lse`` in kernel layout [B*H, Tq]."""
@@ -809,14 +1042,14 @@ def _flash2_backward(
     delta = _bwd_delta(g, o, b, h, tq, v.shape[3])
     return _flash2_backward_kernels(
         q, k, v, g, lse, delta, causal, scale, block_q, block_k, interpret,
-        window, dkv_blocks,
+        window, dkv_blocks, bd,
     )
 
 
 def _flash2_backward_kernels(
     q, k, v, g, lse, delta, causal: bool, scale: float,
     block_q: int, block_k: int, interpret: bool, window: int | None = None,
-    dkv_blocks: tuple[int, int] | None = None,
+    dkv_blocks: tuple[int, int] | None = None, bd=None,
 ):
     """The grid-pipelined backward; ``lse``/``delta`` are [B*H, Tq]
     (external residuals welcome — ring attention's per-rotation block
@@ -834,15 +1067,19 @@ def _flash2_backward_kernels(
     grp = _gqa_group(q, k)
     h_kv = h // grp
     kv_q, kv_k = _fit_blocks(
-        *(dkv_blocks or (block_q, block_k)), tq, tk, window, "q"
+        *(dkv_blocks or (block_q, block_k)), tq, tk, window, "q", bd
     )
 
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * h_kv, tk, d)
     vf = v.reshape(b * h_kv, tk, d_v)
     gf = g.reshape(b * h, tq, d_v)
-    common = dict(causal=causal, scale=scale, q_offset=tk - tq, window=window)
-    _, (q_steps, q_map) = _flash2_maps(causal, window, kv_q, kv_k, tq, tk, grp)
+    common = dict(
+        causal=causal, scale=scale, q_offset=tk - tq, window=window, bd=bd
+    )
+    _, (q_steps, q_map) = _flash2_maps(
+        causal, window, kv_q, kv_k, tq, tk, grp, bd
+    )
     # the kernels that walk rows a kv block: the rows in spans, k and v a
     # grouped block (programs in one GQA group share it, so no H-wide repeat
     # ever materializes in HBM), dk/dv at FULL q-head width (each program
@@ -870,7 +1107,7 @@ def _flash2_backward_kernels(
     lanes_fit = interpret or kv_q == tq or (kv_q % 128 == 0 and tq % 128 == 0)
     if need <= _vmem_capacity() // 2 and lanes_fit:
         _note_tiles(
-            "flash2_bwd", tq, tk, kv_q, kv_k, causal, window, "q",
+            "flash2_bwd", tq, tk, kv_q, kv_k, causal, window, "q", bd,
             acc_bytes=acc,
         )
         # lse and delta along the lanes, a span of them a step
@@ -910,16 +1147,18 @@ def _flash2_backward_kernels(
                 qf, kf, vf, gf, lse[:, None, :], delta[:, None, :]
             )
     else:
-        block_q, block_k = _fit_blocks(block_q, block_k, tq, tk, window, "kv")
+        block_q, block_k = _fit_blocks(
+            block_q, block_k, tq, tk, window, "kv", bd
+        )
         # pallas layout: trailing singleton keeps the block sublane 8-aligned
         lse3 = lse[..., None]
         delta3 = delta[..., None]
         kwargs = _grid_pipeline_kwargs()
         (kv_steps, kv_map), _ = _flash2_maps(
-            causal, window, block_q, block_k, tq, tk, grp
+            causal, window, block_q, block_k, tq, tk, grp, bd
         )
-        _note_tiles("flash2_dq", tq, tk, block_q, block_k, causal, window, "kv")
-        _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q")
+        _note_tiles("flash2_dq", tq, tk, block_q, block_k, causal, window, "kv", bd)
+        _note_tiles("flash2_dkv", tq, tk, kv_q, kv_k, causal, window, "q", bd)
         kv_spec = _span_spec(block_k, d, kv_map, window)
         q_spec = pl.BlockSpec((1, block_q, d), lambda i, qi, j: (i, qi, 0))
         if d_v == d:
@@ -989,12 +1228,20 @@ _FLASH2_BLOCKS_BWD = (1024, 1024)
 _FLASH2_BLOCKS_DQ = (512, 1024)
 
 
-def _spans_fit(block_q, block_k, tq, tk, window, side):
+def _spans_fit(block_q, block_k, tq, tk, window, side, bd=None):
     """Whether a grid-pipelined kernel can tile the shapes with these
     blocks. Each block has to divide its side, except under a window the
     block of the side the kernel walks in spans (``side``: ``"kv"`` keys,
     ``"q"`` rows): spans start at elements, so whole sublanes will do, as
-    long as the spans a block needs fit into the side."""
+    long as the spans a block needs fit into the side. Under block diffusion
+    each block divides a half (``L``) and is whole blocks of ``B``, two at
+    least (a tile then lies in one half of each side, and a block's first run
+    is never empty)."""
+    if bd is not None:
+        return all(
+            bd[0] % block == 0 and block % bd[1] == 0 and block >= 2 * bd[1]
+            for block in (block_q, block_k)
+        )
     q_divides, k_divides = tq % block_q == 0, tk % block_k == 0
     if window is None or (q_divides and k_divides):
         return q_divides and k_divides
@@ -1007,9 +1254,12 @@ def _spans_fit(block_q, block_k, tq, tk, window, side):
     return block % 8 == 0 and -(-need // block) * block <= total
 
 
-def _fit_blocks(block_q, block_k, tq, tk, window, side):
+def _fit_blocks(block_q, block_k, tq, tk, window, side, bd=None):
     """``(block_q, block_k)`` fitted to the shapes: as given where
-    :func:`_spans_fit` takes them, else through :func:`_fit_block`."""
+    :func:`_spans_fit` takes them, else through :func:`_fit_block` (under
+    block diffusion to a half, which a block may not straddle)."""
+    if bd is not None:
+        return _fit_block(block_q, bd[0]), _fit_block(block_k, bd[0])
     bq, bk = _fit_block(block_q, tq), _fit_block(block_k, tk)
     if side == "kv" and _spans_fit(bq, block_k, tq, tk, window, side):
         return bq, block_k
@@ -1031,13 +1281,14 @@ def _fit_blocks(block_q, block_k, tq, tk, window, side):
 _WINDOW_BLOCKS = {"fwd": (512, 2560), "dq": (256, 2560), "bwd": (1280, 512)}
 
 
-def _flash2_blocks(kind, tq, tk, window, given=None):
+def _flash2_blocks(kind, tq, tk, window, given=None, bd=None):
     """``(block_q, block_k)`` for the grid-pipelined kernel ``kind``
     (``"fwd"``; ``"bwd"``, the kernels that walk rows a kv block: the fused
     backward, and dk/dv where a head's dq does not fit the chip; ``"dq"``,
     dq's there), fitted to the shapes: what the caller ``given`` (a pair,
     either of it ``None``) wins, then a windowed call's blocks from the
-    window and the shapes, else the full-causal sweep's."""
+    window and the shapes, else the full-causal sweep's (a block-diffusion
+    call's too, fitted to a half: its clean half is a causal walk)."""
     side = "q" if kind == "bwd" else "kv"
     bq, bk = {
         "fwd": _FLASH2_BLOCKS_FWD, "dq": _FLASH2_BLOCKS_DQ,
@@ -1057,7 +1308,7 @@ def _flash2_blocks(kind, tq, tk, window, given=None):
         if _spans_fit(wq, wk, tq, tk, window, side):
             bq, bk = wq, wk
     given = given or (None, None)
-    return _fit_blocks(given[0] or bq, given[1] or bk, tq, tk, window, side)
+    return _fit_blocks(given[0] or bq, given[1] or bk, tq, tk, window, side, bd)
 
 
 def _fit_block(block: int, t: int) -> int:
@@ -1101,6 +1352,7 @@ def flash_block_grads(
     scale: float | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ):
     """(dq, dk, dv) for one attention block given external residuals:
     per-row logsumexp ``lse`` and row correction ``delta`` [B, H, Tq],
@@ -1110,6 +1362,7 @@ def flash_block_grads(
 
     Default blocks are the fused backward's (``_FLASH2_BLOCKS_BWD``);
     explicit block args always reach the kernel."""
+    _no_block_diffusion("flash_block_grads", block_diffusion)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
@@ -1125,6 +1378,19 @@ def flash_block_grads(
     )
 
 
+def _no_block_diffusion(name: str, block_diffusion) -> None:
+    """The blockwise primitives hold one block of a sequence that other
+    devices share: the block-diffusion mask is over a whole sequence's two
+    copies, which no caller of theirs (``parallel/ring.py``, ``ulysses.py``)
+    lays out yet."""
+    if block_diffusion is not None:
+        raise NotImplementedError(
+            "%s takes no block_diffusion mask (%r): a block of a sequence that "
+            "devices share has no clean and noised half of its own"
+            % (name, block_diffusion)
+        )
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -1137,12 +1403,14 @@ def flash_with_lse(
     scale: float | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ):
     """Forward-only ``(o, lse)`` with ``lse`` as [B, H, Tq] float32 —
     the primitive blockwise/ring merging builds on. Callers own
     differentiation (ring attention defines its own VJP from
     :func:`flash_block_grads`). Default blocks are the forward's
     (``_FLASH2_BLOCKS_FWD``); explicit block args always reach the kernel."""
+    _no_block_diffusion("flash_with_lse", block_diffusion)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, h, tq, d = q.shape
@@ -1169,6 +1437,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     window: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
     """Flash attention; falls back to the reference on ragged shapes.
 
@@ -1176,28 +1445,33 @@ def flash_attention(
     every backend: off the TPU they run in the interpreter), so what a
     caller checks under this name is what a step runs. Default blocks come
     from :func:`_flash2_blocks`; explicit block args win, in the forward and
-    in the backward."""
-    _check_window(window, causal)
+    in the backward. ``window`` and ``block_diffusion`` as :func:`attention`'s."""
+    _check_window(window, causal, block_diffusion, q.shape[2], k.shape[2])
     if scale is None:
         scale = q.shape[-1] ** -0.5
     blocks = (block_q, block_k)
-    return _auto(q, k, v, causal, scale, blocks, blocks, window)
+    bd = None if block_diffusion is None else tuple(block_diffusion)
+    return _auto(q, k, v, causal, scale, blocks, blocks, window, bd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _auto(q, k, v, causal, scale, fwd_blocks=None, bwd_blocks=None,
-          window=None):
+          window=None, bd=None):
     """``fwd_blocks``/``bwd_blocks`` are optional (bq, bk) overrides for
     the kernels (hashable tuples — they ride nondiff_argnums); ``None``,
     for a pair or for either of it, means the measured defaults."""
-    return _auto_fwd(q, k, v, causal, scale, fwd_blocks, bwd_blocks, window)[0]
+    return _auto_fwd(
+        q, k, v, causal, scale, fwd_blocks, bwd_blocks, window, bd
+    )[0]
 
 
 def _auto_fwd(q, k, v, causal, scale, fwd_blocks=None, bwd_blocks=None,
-              window=None):
-    bq, bk = _flash2_blocks("fwd", q.shape[2], k.shape[2], window, fwd_blocks)
+              window=None, bd=None):
+    bq, bk = _flash2_blocks(
+        "fwd", q.shape[2], k.shape[2], window, fwd_blocks, bd
+    )
     out, lse = _flash2_forward(
-        q, k, v, causal, scale, bq, bk, _interpret(), window
+        q, k, v, causal, scale, bq, bk, _interpret(), window, bd
     )
     return _name_residuals(q, k, v, out, lse)
 
@@ -1221,32 +1495,56 @@ def _name_residuals(q, k, v, out, lse):
     return out, (q, k, v, out, lse)
 
 
-def _auto_bwd(causal, scale, fwd_blocks, bwd_blocks, window, residuals, g):
+def _auto_bwd(causal, scale, fwd_blocks, bwd_blocks, window, bd, residuals, g):
     q, k, v, o, lse = residuals
     tq, tk = q.shape[2], k.shape[2]
     kernels = lse is not None and not (causal and tq > tk)
     if kernels:
-        dq_blocks = _flash2_blocks("dq", tq, tk, window, bwd_blocks)
-        dkv_blocks = _flash2_blocks("bwd", tq, tk, window, bwd_blocks)
-        if _spans_fit(*dq_blocks, tq, tk, window, "kv") and _spans_fit(
-            *dkv_blocks, tq, tk, window, "q"
+        dq_blocks = _flash2_blocks("dq", tq, tk, window, bwd_blocks, bd)
+        dkv_blocks = _flash2_blocks("bwd", tq, tk, window, bwd_blocks, bd)
+        if _spans_fit(*dq_blocks, tq, tk, window, "kv", bd) and _spans_fit(
+            *dkv_blocks, tq, tk, window, "q", bd
         ):
             return _flash2_backward(
                 q, k, v, o, lse, g, causal, scale, *dq_blocks, _interpret(),
-                window, dkv_blocks,
+                window, dkv_blocks, bd,
             )
     obs_trace.get_tracer().note_once(
         "attn_route", tq=tq, tk=tk, window=window, side="backward",
         path="plain",
         why="lse" if lse is None else "blocks" if kernels else "shape",
+        **_mask_note(bd),
     )
     _, vjp = jax.vjp(
         lambda q, k, v: attention_reference(
-            q, k, v, causal=causal, scale=scale, window=window
+            q, k, v, causal=causal, scale=scale, window=window,
+            block_diffusion=bd,
         ),
         q, k, v,
     )
     return vjp(g)
+
+
+def _note_planned_tiles(tq, tk, bd, why):
+    """The ``attn_tiles`` census of a block-diffusion call that took the dense
+    reference (``path="plain"``, ``why`` as its ``attn_route`` note has it):
+    what the forward and the fused backward would walk at the blocks the shape
+    gets, where those tile it. A rehearsal on a CPU reads the plan there; a
+    note from the chip says by its ``path`` that no kernel walked it."""
+    for kernel, kind, side in (("flash2_fwd", "fwd", "kv"), ("flash2_bwd", "bwd", "q")):
+        blocks = _flash2_blocks(kind, tq, tk, None, None, bd)
+        if _spans_fit(*blocks, tq, tk, None, side, bd):
+            _note_tiles(
+                kernel, tq, tk, *blocks, True, None, side, bd, path="plain", why=why,
+            )
+
+
+def _mask_note(bd) -> dict:
+    """What an ``attn_route`` note says of a block-diffusion call's mask (a
+    causal or windowed call's note is what it was)."""
+    if bd is None:
+        return {}
+    return {"mask": "block_diffusion", "length": bd[0], "block": bd[1]}
 
 
 _auto.defvjp(_auto_fwd, _auto_bwd)
@@ -1259,31 +1557,43 @@ def attention(
     causal: bool = False,
     scale: float | None = None,
     window: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
     """The default entry point for every model in the tree
     (TransformerLM, the LM examples). On the TPU it is the flash kernels,
     forward and backward; off the TPU it is exactly the dense reference.
     ``flash_attention`` / ``attention_reference`` remain for callers that
-    want a specific implementation. ``window`` (with ``causal``): a query
-    sees its ``window`` newest keys, itself included; the reference takes it
-    as a mask, the kernels as spans. Which of the two a shape took
+    want a specific implementation. The mask is one of three kinds:
+    ``causal`` alone; ``window`` (with ``causal``): a query sees its
+    ``window`` newest keys, itself included; ``block_diffusion=(L, B)`` (with
+    ``causal``, over ``2 L`` positions): the block-diffusion training mask
+    over a clean copy of a sequence and its noised copy, in blocks of ``B``
+    (:func:`_sees`). The reference takes a window or a block-diffusion mask
+    as a dense mask, the kernels as spans. Which of the two a shape took
     is an ``attn_route`` note (``path``, and ``why`` where it is the plain
     form: ``backend`` here; ``lse`` / ``shape`` / ``blocks`` where a backward
-    pass on the TPU fell to the reference's)."""
-    _check_window(window, causal)
+    pass on the TPU fell to the reference's; under block diffusion also the
+    ``mask``'s kind and its ``length`` and ``block``, and off the TPU the
+    ``attn_tiles`` census the kernels would have left, as a plan:
+    :func:`_note_planned_tiles`)."""
+    _check_window(window, causal, block_diffusion, q.shape[2], k.shape[2])
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    bd = None if block_diffusion is None else tuple(block_diffusion)
     note = functools.partial(
         obs_trace.get_tracer().note_once, "attn_route", tq=q.shape[2],
-        tk=k.shape[2], window=window,
+        tk=k.shape[2], window=window, **_mask_note(bd),
     )
     if jax.default_backend() != "tpu":
         # native autodiff, not a custom_vjp around the reference: that
         # would recompute the whole forward in every backward, where plain
         # differentiation reuses the saved activations
         note(path="plain", why="backend")
+        if bd is not None:
+            _note_planned_tiles(q.shape[2], k.shape[2], bd, "backend")
         return attention_reference(
-            q, k, v, causal=causal, scale=scale, window=window
+            q, k, v, causal=causal, scale=scale, window=window,
+            block_diffusion=bd,
         )
     note(path="kernel")
-    return _auto(q, k, v, causal, scale, None, None, window)
+    return _auto(q, k, v, causal, scale, None, None, window, bd)

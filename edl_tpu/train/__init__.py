@@ -25,6 +25,7 @@ _HOME = {
     "cross_entropy_loss": "step",
     "make_cross_entropy_loss": "step",
     "make_eval_step": "step",
+    "make_block_diffusion_loss": "step",
     "make_kd_loss": "step",
     "make_masked_train_step": "step",
     "make_train_step": "step",

@@ -758,11 +758,15 @@ class ElasticTrainer:
                         ):
                             jax.block_until_ready(metrics)
                         t_synced = time.monotonic()
-                        # what the model sows (aux_loss, moe_load_max), as
+                        # what the model sows (aux_loss, moe_load_max) and
+                        # what the loss head names as its ``gauges``, as
                         # gauges: the values have just been waited for
                         sown = {
                             name: np.asarray(metrics[name])
-                            for name in state.sown if name in metrics
+                            for name in (
+                                *state.sown, *getattr(self._loss, "gauges", ())
+                            )
+                            if name in metrics
                         }
                         retired.mark(
                             steps_done - 1, t_synced, epoch=epoch, gauges=sown

@@ -236,6 +236,41 @@ def make_kd_loss(alpha: float = 0.5, temperature: float = 1.0):
     return kd_loss
 
 
+def make_block_diffusion_loss():
+    """The block-diffusion objective as a loss head for ``make_train_step``
+    (BD3-LM, arXiv:2503.09573, equation 8 under the linear schedule; the
+    model is ``TransformerLM`` under ``ArchSpec.block_diffusion``, whose
+    logits ``[B, L, vocab]`` are the noised half's).
+
+    The batch target is the pair ``(labels [B, L] int32, weights [B, L]
+    float32)`` that ``data/block_diffusion.py:noised`` yields: the clean ids,
+    and ``1 / t`` of a position's block where the position was masked, 0 where
+    it was not. Objective: ``sum(weights * CE(logits, labels)) / (B L)``, a
+    masked position predicting its own token (no shift). Metrics:
+    ``bd_masked_share`` (positions with a weight above 0 over all),
+    ``bd_masked_ce`` (the unweighted mean cross-entropy over them) and
+    ``accuracy`` over them. The first two are the head's ``gauges``: the train
+    loop publishes them as ``edl_train_<name>`` beside what the model sows."""
+
+    def bd_loss(logits: jax.Array, y) -> Tuple[jax.Array, Dict]:
+        labels, weights = y
+        ce = optax.softmax_cross_entropy(
+            logits, jax.nn.one_hot(labels, logits.shape[-1])
+        )
+        weights = weights.astype(jnp.float32)
+        scored = weights > 0
+        count = jnp.maximum(jnp.sum(scored), 1)
+        right = jnp.argmax(logits, -1) == labels
+        return jnp.sum(weights * ce) / ce.size, {
+            "accuracy": jnp.sum(scored & right) / count,
+            "bd_masked_share": jnp.mean(scored),
+            "bd_masked_ce": jnp.sum(jnp.where(scored, ce, 0.0)) / count,
+        }
+
+    bd_loss.gauges = ("bd_masked_share", "bd_masked_ce")
+    return bd_loss
+
+
 def make_train_step(
     loss_head: Callable[[jax.Array, jax.Array], Tuple[jax.Array, Dict]],
     apply_kwargs: Optional[Dict[str, Any]] = None,

@@ -224,10 +224,15 @@ def test_flash_attention_hands_on_the_callers_blocks(monkeypatch, h, h_kv, d, gi
     q = jax.ShapeDtypeStruct((2, h, 4096, d), np.float32)
     k = jax.ShapeDtypeStruct((2, h_kv, 4096, d), np.float32)
     A.flash_attention(q, k, k, causal=True, block_q=given[0], block_k=given[1])
-    assert seen == [(given, given, None)]
+    assert seen == [(given, given, None, None)]     # ..., window, block_diffusion
     seen.clear()
     A.attention(q, k, k, causal=True)
-    assert seen == [(None, None, None)]
+    assert seen == [(None, None, None, None)]
+    seen.clear()
+    wide = jax.ShapeDtypeStruct((2, h, 8192, d), np.float32)
+    narrow = jax.ShapeDtypeStruct((2, h_kv, 8192, d), np.float32)
+    A.attention(wide, narrow, narrow, causal=True, block_diffusion=[4096, 4])
+    assert seen == [(None, None, None, (4096, 4))]            # hashable: it rides nondiff_argnums
 
 
 def test_fused_backward_at_the_blocks_of_a_4096_call_matches_the_reference(monkeypatch):

@@ -2200,3 +2200,48 @@ def test_the_smallthinker_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_r
     assert doc["parameters"] == recorded["parameters"]
     assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
     assert doc["tpu_custom_calls"] == recorded["tpu_custom_calls"]
+
+
+# sdar_30b_a3b.steady's attention layers: a clean and a noised copy of 8192, GQA 32:4 x 128
+SDAR = (1, 32, 4, 8192, 128, 4)
+
+
+@pytest.mark.parametrize("kernels", ["fwd", "bwd", "dq_dkv"])
+def test_flash2_compiles_for_v5e_under_the_block_diffusion_mask(one_chip, monkeypatch, kernels):
+    """The grid-pipelined kernels under ``block_diffusion=(8192, 4)`` over
+    16,384 positions, with the blocks ``_flash2_blocks`` gives the kind: a block
+    index that jumps from the clean keys to a noised block's own (and, a kv
+    block, from the clean rows to the later noised ones), the mask from a
+    tile's corner, the fused backward's dq accumulator of a 16,384-row head
+    (8 MiB) under the limit ``_fused_bwd_vmem`` sets; and the dq / dkv pair a
+    chip of less VMEM would take."""
+    b, h, h_kv, length, d, block = SDAR
+    t, bd, scale = 2 * length, (length, block), d ** -0.5
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q, kv = sds((b, h, t, d)), sds((b, h_kv, t, d))
+    if kernels == "fwd":
+        bq, bk = A._flash2_blocks("fwd", t, t, None, None, bd)
+        fn = lambda q, k, v: A._flash2_forward(q, k, v, True, scale, bq, bk, False, None, bd)
+        args, want = (q, kv, kv), ["_flash2_kernel"]
+    else:
+        if kernels == "dq_dkv":
+            monkeypatch.setattr(A, "_vmem_capacity", lambda: 0)
+        dq, dkv = (A._flash2_blocks(kind, t, t, None, None, bd) for kind in ("dq", "bwd"))
+        fn = lambda q, k, v, g, lse, delta: A._flash2_backward_kernels(
+            q, k, v, g, lse, delta, True, scale, *dq, False, None, dkv, bd
+        )
+        row = sds((b * h, t), jnp.float32)
+        args = (q, kv, kv, q, row, row)
+        want = (
+            ["_flash2_bwd_kernel"] if kernels == "bwd"
+            else ["_flash2_bwd_dq_kernel", "_flash2_bwd_dkv_kernel"]
+        )
+    for kind, side in (("fwd", "kv"), ("dq", "kv"), ("bwd", "q")):
+        assert A._spans_fit(*A._flash2_blocks(kind, t, t, None, None, bd), t, t, None, side, bd)
+    lowered = jax.jit(fn).lower(*args)
+    assert _kernel_names(lowered.as_text()) == want
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == len(want)
