@@ -24,11 +24,13 @@ The device time of its four parts carries the names ``gdn_proj`` (both
 projections), ``gdn_conv``, ``gdn_scan`` (the L2 norms, ``beta``, ``g`` and the
 chunked rule: on a TPU backend at bfloat16, a chunk of 64, a ``T`` of whole
 lane tiles and widths in 16s its chunk-local stage is the Pallas kernels
-``gdn_inverse``, ``gdn_operands`` and ``gdn_backward``, custom calls of those
-names in a trace, between them the carry's two loops and the output stage in
-plain XLA; ``q``, ``k`` and ``v`` go to the rule as ``[B, T, H, d]``, whose
+``gdn_inverse``, ``gdn_operands`` and ``gdn_backward``, and its carry and
+output stage one walk over the chunks with the state in VMEM, ``delta_carry``
+and ``delta_carry_back``: custom calls of those names in a trace and no loop
+between them; ``q``, ``k`` and ``v`` go to the rule as ``[B, T, H, d]``, whose
 ``[B, H d, T]`` view, the steps minor as the convolution wrote them, the
-kernels read in place; everywhere else the plain form) and ``gdn_gate``
+kernels read in place; everywhere else the plain forms, the carry then two
+``lax.scan``) and ``gdn_gate``
 (``jax.named_scope``; ``obs/profile.py:step_scopes`` joins them to a trace). Into ``"metrics"`` it
 sows ``gdn_decay_mean`` (the mean of ``exp(g)`` over tokens and heads: how fast
 the state forgets), ``gdn_beta_mean`` and ``gdn_state_absmax`` (the largest
@@ -41,11 +43,12 @@ For a remat policy around the block the rule's ``o`` and final state bear the
 name ``gdn_out`` and, inside the rule, what its sequential carry leaves the
 name ``gdn_carry`` and every chunk's ``T`` ``gdn_inverse``
 (``ops/gated_delta.py:REMAT_NAMES``): a policy that saves them
-(``TransformerLM.remat_policy`` ``"save_flash"``) runs the carry's loop once
-forward and once in reverse a layer and the solve (``gdn_inverse``) once, and
-the rest of the chunk-local stage (``gdn_operands``) again when the backward
-reaches the rule; one that saves none runs the forward loop and the solve
-again when the block is recomputed.
+(``TransformerLM.remat_policy`` ``"save_flash"``) runs the carry once forward
+and once in reverse a layer (the walk's two kernels, or the plain form's two
+loops) and the solve (``gdn_inverse``) once, and the rest of the chunk-local
+stage (``gdn_operands``) again when the backward reaches the rule; one that
+saves none runs the forward carry and the solve again when the block is
+recomputed.
 The in projection's output bears the name ``mixer_in``
 (``models/mamba.py:projected``, whole and before the slices): the same policy
 hands it to the recomputation, which then runs no ``in_proj`` matmul again.
@@ -155,8 +158,8 @@ class GatedDeltaMixer(nn.Module):
             # (``gdn_carry``) and every chunk's ``T`` (``gdn_inverse``): 0.27
             # GB a layer at 8192 steps of 15 heads. Everything later products
             # read of the sequential carry is then saved, so the block's
-            # recomputation does not run the loop again and the carry's own
-            # backward reads the states: one loop forward, one in reverse,
+            # recomputation does not run the carry again and the carry's own
+            # backward reads the states: one walk forward, one in reverse,
             # one solve a layer. The rest of what the rule's backward keeps
             # (a chunk's system, ``w``, ``u``, the scores: 0.6 GB a layer) is
             # recomputed when the backward reaches the rule, and so not held
@@ -247,18 +250,19 @@ class KimiDeltaMixer(nn.Module):
     Device scopes ``kda_proj`` (the six projections in and the one out),
     ``kda_conv``, ``kda_scan`` (the L2 norms, ``beta``, the gate, the chunked
     rule: on a TPU backend at bfloat16 its chunk-local stage is the Pallas
-    kernels ``kda_inverse``, ``kda_operands`` and ``kda_backward``, custom
-    calls of those names in a trace, between them the carry's two loops and the
-    output stage in plain XLA; ``q``, ``k``, ``v`` and ``g`` go to the rule as
-    ``[B, T, H, d]``, whose ``[B, T, H d]`` view the kernels read in place) and
+    kernels ``kda_inverse``, ``kda_operands`` and ``kda_backward`` and its
+    carry and output stage the walk's ``delta_carry`` and ``delta_carry_back``,
+    custom calls of those names in a trace and no loop between them; ``q``,
+    ``k``, ``v`` and ``g`` go to the rule as ``[B, T, H, d]``, whose ``[B, T, H
+    d]`` view the kernels read in place) and
     ``kda_gate``. Sown into ``"metrics"``: ``kda_decay_mean`` (the
     mean of ``exp(g)``), ``kda_beta_mean``, ``kda_log_decay_min`` (the most
     negative ``g`` of the step: under -5.5 the rule's form before PR 51, a
     sub-block of 16 steps under one reference, was not finite) and
     ``kda_state_absmax`` (the largest magnitude in the state after the last
     step); into ``"intermediates"`` the rule's own inputs. What a remat policy saves bears the scalar rule's names
-    (``REMAT_NAMES``): under ``"save_flash"`` the carry's loop runs once forward
-    and once in reverse a layer and the solve (``kda_inverse``) once; the rest
+    (``REMAT_NAMES``): under ``"save_flash"`` the carry runs once forward and
+    once in reverse a layer and the solve (``kda_inverse``) once; the rest
     of the chunk-local stage (``kda_operands``) runs again when the backward
     reaches the rule, to remake the carry's operands. The six in projections'
     outputs bear ``mixer_in`` (``models/mamba.py:projected``; of a low-rank pair

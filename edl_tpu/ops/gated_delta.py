@@ -31,30 +31,40 @@ arithmetic is that of block forward substitution: no power of ``A`` is formed,
 so keys that repeat inside a chunk (``A`` entries near ``beta``, whose powers
 ``(I - A)(I + A^2)...`` would reach 1e18 before cancelling) cost nothing.
 
-Only the state is carried sequentially (``carried_states``: a ``lax.scan`` over
-the chunks of two matmuls a step, ``W S`` and ``K^T V_new``); the outputs of all
-chunks are then computed at once from the states the scan emits.
+Only the state is carried sequentially, and **the carry and the output stage
+have two forms under one contract** (:func:`_carried_outputs`). On a TPU
+backend, for bfloat16 operands at a chunk of 64 and widths that tile, one
+Pallas call walks a batch row's chunks in order with every head's float32
+state in VMEM and writes ``V_new``, ``o`` and the state each chunk inherits
+(``delta_carry``; ``delta_carry_back`` walks them back with ``dS`` in VMEM),
+shared by both rules: no state goes to HBM between two chunks but the copy the
+backward reads. Everywhere else, the plain form: ``carried_states``, a
+``lax.scan`` over the chunks of two matmuls a step (``W S`` and ``K^T
+V_new``), then the outputs of all chunks at once from the states the scan
+emits; it is also what the kernels are tested against.
 
-**What the carry leaves for its backward.** ``carried_states`` has a backward
-of its own: the forward keeps the float32 state every chunk inherits, the
-backward is one reverse ``lax.scan`` that takes ``jax.vjp`` of the same step
-at the saved state. The saved states, ``V_new`` and the final state bear the
-name ``gdn_carry`` (``jax.ad_checkpoint.checkpoint_name``), so a remat policy
-that saves the name (``TransformerLM.remat_policy`` ``"save_flash"``) finds
-all the later products read of the loop and does not run it a second time: the
-compiled gradient holds one forward and one reverse loop a call. jax's own
-transpose of a ``lax.scan`` takes its residuals from inside the loop, so the
-forward loop would run again whatever was saved outside it. ``T``, all its
-own backward keeps, bears the name ``gdn_inverse``: saved, the doubling's
-rounds (the costliest of the chunk-parallel parts) are not repeated either.
+**What the carry leaves for its backward.** Either form has a backward of its
+own: the forward keeps the float32 state every chunk inherits, the backward is
+one walk in reverse at the saved states (``delta_carry_back``; the plain
+form's a reverse ``lax.scan`` that takes ``jax.vjp`` of the same step). The
+saved states, ``V_new`` and the final state bear the name ``gdn_carry``
+(``jax.ad_checkpoint.checkpoint_name``), so a remat policy that saves the name
+(``TransformerLM.remat_policy`` ``"save_flash"``) finds all that is read of
+the carry and does not run it a second time: the compiled gradient holds one
+forward and one reverse walk (or loop) a call. jax's own transpose of a
+``lax.scan`` takes its residuals from inside the loop, so the forward loop
+would run again whatever was saved outside it. ``T``, all its own backward
+keeps, bears the name ``gdn_inverse``: saved, the doubling's rounds (the
+costliest of the chunk-parallel parts) are not repeated either.
 
 Precision: ``g``, its running sums, every ``exp``, ``beta``, ``A``, ``T`` (its
 rounds at ``Precision.HIGHEST``) and the carried state are float32; the other
 matmuls take their operands in ``q``'s dtype with float32 accumulation, as
 ``ssd_scan``'s do. Every ``exp`` is of a difference that is never positive, so
-nothing overflows however fast a head forgets. Plain ``jax.numpy`` / ``lax``:
-the backward is jax's, but for the inverse's (``unit_lower_inverse``) and the
-carry's (``carried_states``), and the carry's is jax's of one step.
+nothing overflows however fast a head forgets. Where the forms are plain
+``jax.numpy`` / ``lax`` the backward is jax's, but for the inverse's
+(``unit_lower_inverse``) and the carry's (``carried_states``), and the carry's
+is jax's of one step.
 
 :func:`kda_rule` is the same rule with a decay for every key channel (Kimi
 delta attention): the decay no longer factors out of the products over the key
@@ -73,14 +83,17 @@ bears ``gdn_inverse``), ``kda_operands`` (the inputs and ``T`` to ``w``, ``u``,
 layout) and ``kda_backward`` (the inputs, ``T`` and the six cotangents to
 ``dq``, ``dk``, ``dv``, ``dg``, ``dbeta``). Everywhere else, :func:`_local_plain`:
 the plain ``jax.numpy`` form with jax's backward, which is also what the
-kernels are tested against. The carry and the output stage are plain XLA under
-both. :func:`gated_delta_rule` itself is plain throughout: a decay a step is
-the kernels' case of ``g`` equal along the lanes, not moved onto them here.
+kernels are tested against. :func:`gated_delta_rule`'s chunk-local stage has
+kernels of its own, the steps along the lanes (``gdn_inverse``,
+``gdn_operands``, ``gdn_backward``). The walk after either takes ``k_out`` and
+``q_in`` where these kernels wrote them: rows whose heads lie side by side
+(``kda_operands``) or a tile a head (``gdn_operands``).
 
 ``ops/ssd.py`` (Mamba-2's scan, which has no solve) runs its own chunk-local
 stage as two kernels on the same frame and imports it from here: the call
 builder ``_chunk_call`` (a grid step a chunk, the blocks and the VMEM limit
-from a table of kinds; ``_run`` is this file's table), ``_iota``,
+from a table of kinds; ``_run`` is this file's table; ``walk``, the chunks in
+order and the scratch kept between them, is the carry's alone), ``_iota``,
 ``_running_sum`` (``axis`` 1: along the lanes), ``_column_of``, ``_over_heads``
 (``width`` heads a round) and the two transposed products. What a backward
 keeps differs: ``kda_backward`` the inputs and ``T``, ``ssd_backward`` the
@@ -242,8 +255,11 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     through the norms), so a head is ``d`` sublanes of a block wherever it
     starts: any count of heads, an odd last one by itself after the loop over
     pairs, and the cell's 15 of 96 / 192 as they are, nothing padded and
-    nothing copied. The carry (``carried_states``) and the output stage are
-    plain XLA either way.
+    nothing copied. The carry and the output stage after it
+    (:func:`_carried_outputs`) decide for themselves, from the operands they
+    are handed: the walk's two kernels (``delta_carry``, ``delta_carry_back``)
+    where they can be, on the chunk-local kernels' ``k_out`` and ``q_in`` as
+    those wrote them, a tile a head.
     """
     batch, t, h, d_k = q.shape
     d_v = v.shape[-1]
@@ -286,12 +302,14 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
             lanes(q), lanes(k), lanes(v), lanes(g.astype(f32)), lanes(beta.astype(f32)),
             interpret,
         )
-        # a tile a head as the kernels wrote them: the readers' order of axes
-        k_out, q_in = jnp.swapaxes(k_out, 2, 3), jnp.swapaxes(q_in, 2, 3)
+        # k_out and q_in a tile a head, as the kernels wrote them
         whole = whole.reshape(nc, batch, h)
     else:
         w, u, k_out, whole, q_in, scores, _ = _scalar_plain(q, k, v, g, beta, size)
-    o, state = _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t)
+        k_out, q_in = k_out.reshape(nc, batch, size, -1), q_in.reshape(batch, steps, -1)
+    o, state = _carried_outputs(
+        initial_state, w, u, k_out, whole, q_in, scores, t, interpret
+    )
     if return_final_state:
         return o, state
     return o
@@ -349,20 +367,64 @@ def _scalar_plain(q, k, v, g, beta, size):
     )
 
 
-def _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t):
-    """From the chunk-local stage's six operands (as :func:`_scalar_plain` and
-    :func:`_local_plain` lay them out) to the first ``t`` steps' ``o`` ``[B, t,
-    H, d_v]`` in the operands' dtype and the final state: the state from chunk
-    to chunk in float32 (``carried_states``), then every chunk's outputs at
-    once, what it inherits and what it wrote itself."""
+def _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t, interpret=False):
+    """From the chunk-local stage's six operands to the first ``t`` steps' ``o``
+    ``[B, t, H, d_v]`` in the operands' dtype and the final state: the state
+    from chunk to chunk in float32 and every chunk's outputs, what it inherits
+    and what it wrote itself.
+
+    ``w``, ``u``, ``whole`` and ``scores`` as :func:`_scalar_plain` and
+    :func:`_local_plain` lay them out; ``k_out`` and ``q_in`` **where their
+    producer left them**: rows whose heads lie side by side (``[n b c (h k)]``
+    and ``[b (n c) (h k)]``: the plain forms' arrays and ``kda_operands``'
+    blocks alike) or a tile a head (``[n b h c k]`` and ``[b n h c k]``, five
+    axes: ``gdn_operands``'). ``whole`` a decay a head ``[n b h]`` or a key
+    channel ``[n b h k]``.
+
+    **Two forms, one contract**, decided from the operands as the chunk-local
+    stage's is (:func:`_carry_refuses`) and noted once a shape and stage
+    (``delta_carry``: ``path`` and, where plain, ``why``): on a TPU backend,
+    for bfloat16 operands at a chunk of 64 and widths that tile, one Pallas
+    call that walks a batch row's chunks with every head's state in VMEM and
+    writes ``o`` (``delta_carry``; ``delta_carry_back`` its backward, under one
+    ``jax.custom_vjp``: :func:`_carry_kernels`); everywhere else the plain
+    form, ``carried_states`` (a ``lax.scan``) and two products over all chunks
+    at once, which is also the kernels' reference. ``interpret`` runs the
+    kernels in the Pallas interpreter."""
     nc, batch, h, size, d_k = w.shape
     d_v = u.shape[-1]
     f32, dtype = jnp.float32, w.dtype
     dot = dict(preferred_element_type=f32)
+    tiles = k_out.ndim == 5
     if initial_state is None:
         state = jnp.zeros((batch, h, d_k, d_v), f32)
     else:
         state = initial_state.astype(f32)
+    why_plain = _carry_refuses(w, u, k_out, q_in, scores, interpret)
+    obs_trace.get_tracer().note_once(
+        "delta_carry", chunk=size, chunks=nc, heads=h, d_k=d_k, d_v=d_v,
+        decay="channel" if whole.ndim == 4 else "head",
+        operands="tiles" if tiles else "rows", heads_a_step=h,
+        state_bytes=4 * h * d_k * d_v,
+        **(dict(path="kernel") if why_plain is None else dict(path="plain", why=why_plain)),
+    )
+    if why_plain is None:
+        # the kernel's one decay is a key channel's: a head's is the same along
+        # them. Its state lies [d_v, d_k], the channels along the lanes
+        if whole.ndim == 3:
+            whole = jnp.broadcast_to(whole[..., None], (*whole.shape, d_k))
+        o, state = _carry_kernels(
+            jnp.swapaxes(state, 2, 3), w, u, k_out, whole, q_in, scores, interpret
+        )
+        if tiles:
+            o = jnp.swapaxes(o, 2, 3)
+        o = o.reshape(batch, nc * size, h, d_v)[:, :t]
+        return o, jnp.swapaxes(state, 2, 3)
+    if tiles:
+        k_out, q_in = jnp.swapaxes(k_out, 2, 3), jnp.swapaxes(q_in, 2, 3)
+    else:
+        k_out = k_out.reshape(nc, batch, size, h, d_k)
+        q_in = q_in.reshape(batch, nc, size, h, d_k)
     states, new, state = carried_states(state, w, u, k_out, whole)
     # a cast of what is saved: the backward needs no copy of its own
     entering = jnp.moveaxis(states.astype(dtype), 0, 1)          # [b n h k v]
@@ -622,10 +684,12 @@ def _solve(system, rounds=None):
 
 
 def _head_of(h, width):
-    """The lanes of head ``h`` in a row of ``heads * width``."""
+    """The lanes of head ``h`` in a row of ``heads * width``; ``h`` the loop's
+    index or, in an odd tail, a number."""
     from jax.experimental import pallas as pl
 
-    return pl.ds(pl.multiple_of(h * width, 128), width)
+    start = h * width
+    return pl.ds(start if isinstance(h, int) else pl.multiple_of(start, 128), width)
 
 
 def _column_of(betas, h):
@@ -789,9 +853,12 @@ def kda_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inverse_ref,
     dbeta_ref[0] = _over_heads(heads, head, jnp.zeros(betas.shape, f32))
 
 
-def _chunk_call(kernel, grid, kinds, ins, outs, operands, interpret, scratch=()):
+def _chunk_call(kernel, grid, kinds, ins, outs, operands, interpret, scratch=(),
+                walk=False):
     """``kernel`` as one Pallas call over ``grid`` = (batch, chunks), every
-    grid step independent: ``kinds`` gives a kind of operand its shape, dtype,
+    grid step independent, or with ``walk`` a batch row's chunks one after
+    another in the grid's order (``"arbitrary"``), the ``scratch`` kept from
+    one to the next: ``kinds`` gives a kind of operand its shape, dtype,
     block and the block's place at batch ``b``, chunk ``n`` (its leading block
     indices; the rest are zeros), ``ins`` and ``outs`` name the kinds of
     ``operands`` and of the results, ``scratch`` the body's VMEM arrays (shape,
@@ -823,7 +890,7 @@ def _chunk_call(kernel, grid, kinds, ins, outs, operands, interpret, scratch=())
         out_shape=[jax.ShapeDtypeStruct(*kinds[kind][:2]) for kind in outs],
         scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel", "arbitrary" if walk else "parallel"),
             # every block twice (the pipeline's two buffers), the scratch, and
             # room for the body's own tiles
             vmem_limit_bytes=2 * sum(held(kind) for kind in (*ins, *outs))
@@ -932,6 +999,242 @@ def _kernels_refuse(q, k, v, steps, chunk, interpret, along_lanes=False):
         ("steps", steps % (_LANE_STEPS if along_lanes else chunk) == 0),
         ("heads_odd", along_lanes or q.shape[2] % 2 == 0),
         ("width", q.shape[-1] % tile == 0 and v.shape[-1] % tile == 0),
+    )
+    return next((why for why, met in conditions if not met), None)
+
+
+# -- the carry and the output stage: one walk over the chunks ----------------
+#
+# What ``carried_states`` and the two products after it do, as one Pallas call
+# forward and one backward: the grid is (batch, chunks) with the chunks in
+# order (``_chunk_call``'s ``walk``), every head's float32 state stays in a
+# VMEM scratch from one chunk to the next, and a grid step reads one chunk's
+# six operands where the chunk-local stage wrote them and writes the chunk's
+# ``o``, ``V_new`` and the state it inherited (the backward's residual). The
+# state lies transposed, ``[d_v, d_k]``: the decay of a key channel is then a
+# row along the lanes, as ``whole``'s blocks hold it, and so is its cotangent
+# ``<dS, S>``. One kernel for both rules: a decay a head comes broadcast along
+# the channels, and a head's tile is taken by the block's rank
+# (:func:`_tile_of`). The heads are walked by number, each tile at a place the
+# compiler knows (PR 62's probe: Solar's 8 heads as one round of 8 read 0.28 ms
+# a call where two rounds of 4, a head's lanes at a dynamic start, read 0.51);
+# more than ``_CARRY_HEADS`` in rounds of 8 (:func:`_over_heads`).
+
+_CARRY_HEADS = 16        # the most heads a step of the walk takes by number
+
+
+def _each_head(heads, body):
+    """``body(h)`` for every head of a step of the walk."""
+    if heads <= _CARRY_HEADS:
+        for h in range(heads):
+            body(h)
+        return
+
+    def of_a_round(group, half, carry):
+        body(8 * group + half)
+        return carry
+
+    _over_heads(heads, of_a_round, width=8)
+
+
+def _tile_of(ref, h, width):
+    """Where head ``h``'s ``[C, width]`` tile lies in a block: a tile a head
+    (five axes, ``[1, 1, H, C, width]``) or the head's lanes of rows whose
+    heads lie side by side (``[.., C, H width]``)."""
+    if len(ref.shape) == 5:
+        return 0, 0, h
+    return (0,) * (len(ref.shape) - 2) + (slice(None), _head_of(h, width))
+
+
+def delta_carry_kernel(w_ref, u_ref, k_out_ref, whole_ref, q_in_ref, scores_ref, initial_ref,
+                       o_ref, new_ref, entering_ref, final_ref, state_ref):
+    """One chunk of the walk, every head: ``V_new = U - W S`` rounded to the
+    operands' dtype, ``o = q_in S + scores V_new``, ``S <- whole o S + k_out^T
+    V_new``, with ``S`` ``[H, d_v, d_k]`` float32 in ``state_ref`` from the
+    chunk before (the initial state at the first) and rounded once for its
+    two products; the state the chunk inherits is written as it is."""
+    from jax.experimental import pallas as pl
+
+    f32, dtype = jnp.float32, w_ref.dtype
+    heads, size, d_k = w_ref.shape[2:]
+    d_v = u_ref.shape[-1]
+    n = pl.program_id(1)
+
+    @pl.when(n == 0)
+    def _():
+        state_ref[...] = initial_ref[0]
+
+    def head(h):
+        state = state_ref[h]
+        entering_ref[0, 0, h] = state
+        held = state.astype(dtype)
+        k_out, q_in = k_out_ref[_tile_of(k_out_ref, h, d_k)], q_in_ref[_tile_of(q_in_ref, h, d_k)]
+        # W S and q_in S as one product of 2 C rows against the state
+        both = _times_transposed(jnp.concatenate([w_ref[0, 0, h], q_in], axis=0), held)
+        new = (u_ref[0, 0, h] - both[:size]).astype(dtype)
+        new_ref[0, 0, h] = new
+        own = jnp.dot(scores_ref[0, 0, h], new, preferred_element_type=f32)
+        o_ref[_tile_of(o_ref, h, d_v)] = (both[size:] + own).astype(dtype)
+        state_ref[h] = whole_ref[0, 0, pl.ds(h, 1), :] * state + _transposed_times(new, k_out)
+
+    _each_head(heads, head)
+
+    @pl.when(n == pl.num_programs(1) - 1)
+    def _():
+        final_ref[0] = state_ref[...]
+
+
+def delta_carry_back_kernel(w_ref, k_out_ref, whole_ref, q_in_ref, scores_ref, entering_ref,
+                            new_ref, do_ref, dfinal_ref,
+                            dw_ref, du_ref, dk_out_ref, dwhole_ref, dq_in_ref, dscores_ref,
+                            dinitial_ref, dstate_ref):
+    """One chunk of the walk back, every head, the chunks last to first: from
+    ``d o`` and the cotangent ``dS'`` of the state the chunk left
+    (``dstate_ref``, the final state's at the first step) to the six
+    operands' cotangents and ``dS`` of the state it inherited, what
+    ``_carried_states_bwd`` and the two products' transposes return. Each
+    product's operand is rounded once to the operands' dtype; ``d u``, ``d
+    whole`` (``<dS', S>`` a channel) and ``dS`` are float32."""
+    from jax.experimental import pallas as pl
+
+    f32, dtype = jnp.float32, w_ref.dtype
+    heads, size, d_k = w_ref.shape[2:]
+    d_v = new_ref.shape[-1]
+    n = pl.program_id(1)
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+
+    @pl.when(n == 0)
+    def _():
+        dstate_ref[...] = dfinal_ref[0]
+
+    def head(h):
+        state, leaving = entering_ref[0, 0, h], dstate_ref[h]           # [d_v, d_k]
+        held, passed = state.astype(dtype), leaving.astype(dtype)
+        k_out, q_in = k_out_ref[_tile_of(k_out_ref, h, d_k)], q_in_ref[_tile_of(q_in_ref, h, d_k)]
+        new, d_o = new_ref[0, 0, h], do_ref[_tile_of(do_ref, h, d_v)]
+        # V_new fed the chunk's own outputs and the state it left
+        d_new = _transposed_times(scores_ref[0, 0, h], d_o) + _times_transposed(k_out, passed)
+        du_ref[0, 0, h] = d_new
+        dscores_ref[0, 0, h] = _times_transposed(d_o, new).astype(dtype)
+        fed = jnp.concatenate([(-d_new).astype(dtype), d_o], axis=0)    # [2 C, d_v]
+        both = dot(fed, held)                                           # d w | d q_in
+        dw_ref[0, 0, h] = both[:size].astype(dtype)
+        dq_in_ref[_tile_of(dq_in_ref, h, d_k)] = both[size:].astype(dtype)
+        dk_out_ref[_tile_of(dk_out_ref, h, d_k)] = dot(new, passed).astype(dtype)
+        dwhole_ref[0, 0, pl.ds(h, 1), :] = jnp.sum(leaving * state, axis=0, keepdims=True)
+        dstate_ref[h] = whole_ref[0, 0, pl.ds(h, 1), :] * leaving + _transposed_times(
+            fed, jnp.concatenate([w_ref[0, 0, h], q_in], axis=0)
+        )
+
+    _each_head(heads, head)
+
+    @pl.when(n == pl.num_programs(1) - 1)
+    def _():
+        dinitial_ref[0] = dstate_ref[...]
+
+
+def _carry_run(kernel, ins, outs, operands, interpret, back=False):
+    """One of the walk's two kernels on ``operands``, whose kinds ``ins`` names
+    (``outs`` those of its results): a grid step a chunk of every head, the
+    chunks first to last or, ``back``, last to first."""
+    nc, batch, h, size, d_k = operands[ins.index("w")].shape
+    d_v = operands[ins.index("new" if "new" in ins else "u")].shape[-1]
+    f32, dtype = jnp.float32, operands[ins.index("w")].dtype
+    at = (lambda n: nc - 1 - n) if back else (lambda n: n)  # noqa: E731
+    here, first = (lambda b, n: (b, at(n))), (lambda b, n: (at(n), b))  # noqa: E731
+    once = lambda b, n: (b,)  # noqa: E731 — a batch row's, whatever the chunk
+    by_head = lambda d, dt: ((nc, batch, h, size, d), dt, (1, 1, h, size, d), first)  # noqa: E731
+    if operands[ins.index("k_out")].ndim == 5:   # a tile a head
+        k_out = by_head(d_k, dtype)
+        q_in, o = (
+            ((batch, nc, h, size, d), dtype, (1, 1, h, size, d), here) for d in (d_k, d_v)
+        )
+    else:                                        # a row's heads side by side
+        k_out = ((nc, batch, size, h * d_k), dtype, (1, 1, size, h * d_k), first)
+        q_in, o = (
+            ((batch, nc * size, h * d), dtype, (1, size, h * d), here) for d in (d_k, d_v)
+        )
+    kinds = dict(
+        w=by_head(d_k, dtype), u=by_head(d_v, f32), new=by_head(d_v, dtype),
+        k_out=k_out, q_in=q_in, o=o,
+        whole=((nc, batch, h, d_k), f32, (1, 1, h, d_k), first),
+        scores=((batch, nc, h, size, size), dtype, (1, 1, h, size, size), here),
+        # the states, transposed: the ones the chunks inherit, a batch row's one
+        entering=((nc, batch, h, d_v, d_k), f32, (1, 1, h, d_v, d_k), first),
+        state=((batch, h, d_v, d_k), f32, (1, h, d_v, d_k), once),
+    )
+    return _chunk_call(
+        kernel, (batch, nc), kinds, ins, outs, operands, interpret,
+        scratch=(((h, d_v, d_k), f32),), walk=True,
+    )
+
+
+_CARRY_OPERANDS = ("w", "u", "k_out", "whole", "q_in", "scores")
+_CARRY_KEPT = ("w", "k_out", "whole", "q_in", "scores", "entering", "new")
+
+
+# jitted, as the chunk-local stage's: a step traces and lowers each body once
+@functools.partial(jax.jit, static_argnums=7)
+def _carry_call(w, u, k_out, whole, q_in, scores, state, interpret):
+    ins, outs = (*_CARRY_OPERANDS, "state"), ("o", "new", "entering", "state")
+    operands = (w, u, k_out, whole, q_in, scores, state)
+    return _carry_run(delta_carry_kernel, ins, outs, operands, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=9)
+def _carry_back_call(w, k_out, whole, q_in, scores, entering, new, d_o, d_final, interpret):
+    ins, outs = (*_CARRY_KEPT, "o", "state"), (*_CARRY_OPERANDS, "state")
+    operands = (w, k_out, whole, q_in, scores, entering, new, d_o, d_final)
+    return _carry_run(delta_carry_back_kernel, ins, outs, operands, interpret, back=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _carry_kernels(state, w, u, k_out, whole, q_in, scores, interpret):
+    """The carry and the output stage by the walk: every chunk's ``o`` (laid
+    out as ``q_in`` is, ``d_v`` wide) and the final state, from the initial
+    ``state`` ``[b h v k]`` (float32, transposed, as the final one) and the
+    six operands as :func:`_carried_outputs` takes them, ``whole`` ``[n b h
+    k]``."""
+    o, _, _, final = _carry_call(w, u, k_out, whole, q_in, scores, state, interpret)
+    return o, final
+
+
+def _carry_kernels_fwd(state, w, u, k_out, whole, q_in, scores, interpret):
+    # by name, as ``_carried_states_fwd``: with the states the chunks inherit,
+    # ``V_new`` and the final state saved (and ``o``, which the caller names),
+    # nothing is read of the walk that a recomputation would have to run it for
+    o, new, entering, final = _carry_call(w, u, k_out, whole, q_in, scores, state, interpret)
+    new, entering, final = (checkpoint_name(a, CARRY_NAME) for a in (new, entering, final))
+    return (o, final), (w, k_out, whole, q_in, scores, entering, new)
+
+
+def _carry_kernels_bwd(interpret, residuals, cotangents):
+    *d_operands, d_state = _carry_back_call(*residuals, *cotangents, interpret)
+    return (d_state, *d_operands)
+
+
+_carry_kernels.defvjp(_carry_kernels_fwd, _carry_kernels_bwd)
+
+
+def _carry_refuses(w, u, k_out, q_in, scores, interpret):
+    """Why the carry of these operands is not the walk's kernels, or None
+    where it is: the first of a TPU backend or the interpreter (``backend``),
+    bfloat16 operands beside a float32 ``u`` (``dtype``), the kernels' chunk
+    (``chunk``), widths that tile (``width``: whole lane tiles where a row's
+    heads lie side by side, a packed bfloat16 tile's 16 rows where a head has
+    a tile of its own) and every head's state, ten times over for the blocks
+    that hold it, within half of VMEM (``state``) that does not hold."""
+    _, _, h, size, d_k = w.shape
+    d_v = u.shape[-1]
+    tile = 16 if k_out.ndim == 5 else 128
+    conditions = (
+        ("backend", interpret or jax.default_backend() == "tpu"),
+        ("dtype", u.dtype == jnp.float32 and all(
+            a.dtype == jnp.bfloat16 for a in (w, k_out, q_in, scores)
+        )),
+        ("chunk", size == _KERNEL_CHUNK),
+        ("width", d_k % tile == 0 and d_v % tile == 0),
+        ("state", 40 * h * d_k * d_v <= 64 << 20),
     )
     return next((why for why, met in conditions if not met), None)
 
@@ -1281,7 +1584,7 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     and 4 strips of 16 rows for 6 whole products: ``bench_results/README.md``
     has both timed.)
 
-    ``W``, ``U``, the carry (``carried_states``, its decay a vector over
+    ``W``, ``U``, the carry (:func:`_carried_outputs`, its decay a vector over
     ``d_k``) and the outputs are the scalar rule's with ``exp(Gamma)`` a
     channel; the solve is ``unit_lower_inverse``; what a remat policy saves
     bears the same names (``REMAT_NAMES``). ``caller`` is what the caller says
@@ -1301,8 +1604,11 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
     ``jax.custom_vjp`` (``_local_kernels``); everywhere else (the CPU, float32
     operands, a ragged ``T``, another chunk) the plain form (``_local_plain``),
     which is also the kernels' reference. ``interpret`` runs the kernels in the
-    Pallas interpreter (tests on the CPU). The carry (``carried_states``) and
-    the output stage after it are plain XLA either way.
+    Pallas interpreter (tests on the CPU). The carry and the output stage
+    after it decide for themselves in the same way (:func:`_carried_outputs`:
+    the walk's ``delta_carry`` and ``delta_carry_back`` over rows whose heads
+    lie side by side, as ``kda_operands`` writes ``k_out`` and ``q_in``; an
+    odd count of heads, which the chunk-local kernels refuse, they take).
 
     The kernels hold one chunk of every head in VMEM a grid step, ``chunk``
     rows of the ``[B, T, H d]`` views of the inputs as the mixer hands them
@@ -1359,12 +1665,14 @@ def kda_rule(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
         w, u, k_out, whole, q_in, scores = _local_kernels(
             flat(q), flat(k), flat(v), flat(g.astype(f32)), beta.astype(f32), interpret
         )
-        k_out = k_out.reshape(nc, batch, size, h, d_k)
-        q_in = q_in.reshape(batch, nc, size, h, d_k)
     else:
         w, u, k_out, whole, q_in, scores, _ = _local_plain(q, k, v, g, beta, size)
+        k_out, q_in = k_out.reshape(nc, batch, size, -1), q_in.reshape(batch, steps, -1)
 
-    o, state = _carried_outputs(initial_state, w, u, k_out, whole, q_in, scores, t)
+    # k_out and q_in with a row's heads side by side, either way
+    o, state = _carried_outputs(
+        initial_state, w, u, k_out, whole, q_in, scores, t, interpret
+    )
     if return_final_state:
         return o, state
     return o

@@ -12,6 +12,7 @@ import functools
 import importlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -392,6 +393,162 @@ def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path):
     found = [e["args"] for e in tracer.to_events() if e["name"] == "kda_chunks"][before:]
     assert [e["path"] for e in found] == [path]
     assert ("kda_operands" in lowered.as_text(debug_info=True)) == (path == "kernel")
+
+
+# -- the carry and the output stage as one walk, in the Pallas interpreter -----------
+
+CARRIED = ("o", "state")
+CARRY_LEAVES = ("initial_state", "w", "u", "k_out", "whole", "q_in", "scores")
+# heads, d_k, d_v, a decay a key channel, a tile a head, an initial state, the steps kept
+CARRY_CASES = {
+    "a_decay_a_channel": (2, 128, 128, True, False, True, 128),
+    "a_decay_a_head": (2, 128, 128, False, False, False, 128),
+    "five_heads_and_a_padded_length": (5, 128, 128, True, False, True, 3 * 64 - 24),
+    "three_tiles_of_96_and_192": (3, 96, 192, False, True, True, 128),
+    "five_tiles_a_channel_and_a_padded_length": (5, 96, 192, True, True, False, 3 * 64 - 40),
+    # more than the walk takes by number: two rounds of 8 and two after them
+    "eighteen_heads": (18, 128, 128, True, False, False, 128),
+}
+
+
+def carry_operands(case):
+    """``(initial_state, w, u, k_out, whole, q_in, scores)`` as
+    ``_carried_outputs`` takes them, of the sizes a chunk-local stage leaves."""
+    h, d_k, d_v, channel, tiles, initial, t = CARRY_CASES[case]
+    nc, b, size, bf16 = -(-t // 64), 1, 64, jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 7)
+    rows = lambda key, shape, d: (jax.random.normal(key, shape) * d ** -0.5).astype(bf16)  # noqa: E731
+    w = rows(keys[0], (nc, b, h, size, d_k), d_k)
+    u = jax.random.normal(keys[1], (nc, b, h, size, d_v))
+    k_out = rows(keys[2], (nc, b, size, h, d_k), d_k)
+    whole = jax.random.uniform(keys[3], (nc, b, h, d_k) if channel else (nc, b, h), minval=0.3)
+    q_in = rows(keys[4], (b, nc, size, h, d_k), d_k)
+    scores = jnp.tril(0.2 * jax.random.normal(keys[5], (b, nc, h, size, size))).astype(bf16)
+    state = jax.random.normal(keys[6], (b, h, d_k, d_v)) if initial else None
+    if tiles:
+        k_out, q_in = jnp.swapaxes(k_out, 2, 3), jnp.swapaxes(q_in, 2, 3)
+    else:
+        k_out, q_in = k_out.reshape(nc, b, size, -1), q_in.reshape(b, nc * size, -1)
+    return state, w, u, k_out, whole, q_in, scores
+
+
+@functools.lru_cache(maxsize=None)
+def carry_both_ways(case):
+    """``(o, final state)`` and the gradients of the operands (the initial
+    state's where there is one) under a random cotangent of both, by the
+    walk's two kernels and by the plain form (``carried_states`` and the two
+    products), which the interpreter's flag alone tells apart. (One sequence:
+    at a batch of two the CPU's own dot refuses the plain form's bfloat16
+    operands beside a float32 result.)"""
+    state, *operands = carry_operands(case)
+    t = CARRY_CASES[case][-1]
+
+    def program(interpret):
+        def run(*args):
+            leaves = args if state is not None else (None, *args)
+            return G._carried_outputs(*leaves, t, interpret)
+
+        def both(*args):
+            values, pull = jax.vjp(run, *args)
+            return values, pull(weights(values, seed=22))
+
+        return jax.jit(both)
+
+    args = operands if state is None else (state, *operands)
+    return [program(interpret)(*args) for interpret in (True, False)]
+
+
+@pytest.mark.parametrize("case,what", [
+    (case, what) for case, fields in CARRY_CASES.items() for what in CARRIED + CARRY_LEAVES
+    if fields[5] or what != "initial_state"
+])
+def test_the_walks_kernels_are_the_plain_carry_and_output_stage(case, what):
+    """Values and every gradient: a decay a head and a decay a key channel,
+    rows of heads side by side and a tile a head (96 / 192 wide), with and
+    without an initial state, a length the chunk pads, odd counts of heads and
+    more heads than a step takes by number. The state, ``u`` and every accumulation are
+    float32 in both; the kernels round each product's operand once to bfloat16
+    where the plain backward on a CPU keeps ``dS`` float32, a bfloat16 step."""
+    (got, got_grads), (want, want_grads) = carry_both_ways(case)
+    if what in CARRIED:
+        a, b = got[CARRIED.index(what)], want[CARRIED.index(what)]
+    else:
+        at = CARRY_LEAVES.index(what) - (not CARRY_CASES[case][5])
+        a, b = got_grads[at], want_grads[at]
+    assert a.shape == b.shape and a.dtype == b.dtype
+    _close(a.astype(jnp.float32), b.astype(jnp.float32), tol=1.5e-2)
+
+
+@pytest.mark.parametrize("rule", ["kda_rule", "gated_delta_rule"])
+def test_a_recomputation_that_saves_the_names_traces_no_second_walk(rule):
+    """Under ``save_only_these_names(*REMAT_NAMES)``, as the mixers wrap the
+    rule: the states the chunks inherit, ``V_new`` and the final state are
+    saved by name and ``o`` is the rule's own output, so the gradient holds
+    ``delta_carry`` once and ``delta_carry_back`` once."""
+    h, d = (2, 128) if rule == "kda_rule" else (3, 32)
+    q, k, v, g, beta = bf16_args(rule_inputs(seed=9, b=1, t=128, h=h, d_k=d, d_v=d))
+    if rule == "gated_delta_rule":
+        g = jnp.mean(g, axis=-1)
+    saved = jax.checkpoint(
+        functools.partial(getattr(G, rule), chunk=64, return_final_state=True, interpret=True),
+        policy=jax.checkpoint_policies.save_only_these_names(*G.REMAT_NAMES),
+    )
+
+    def loss(*args):
+        o, state = saved(*args)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(state)
+
+    text = jax.jit(jax.grad(loss, range(5))).lower(q, k, v, g, beta).as_text(debug_info=True)
+    # the interpreter leaves no custom call, but each call its jit by name
+    calls = lambda name: len(re.findall(r"call @%s\b" % name, text))  # noqa: E731
+    assert calls("_carry_call") == 1 and calls("_carry_back_call") == 1
+
+
+@pytest.mark.parametrize("why,path", [
+    ("the_kernels_case", "kernel"), ("three_heads", "kernel"), ("no_tpu_and_no_interpreter", "plain"),
+    ("float32_operands", "plain"), ("a_chunk_of_32", "plain"), ("a_width_of_64", "plain"),
+])
+def test_the_carrys_form_is_decided_from_its_operands_and_says_so(why, path):
+    """``delta_carry`` carries ``path`` and, where plain, ``why``; an odd count
+    of heads, which the chunk-local kernels refuse, is the walk's case."""
+    d, h, chunk, interpret, narrow = 128, 2, 64, True, bf16_args
+    if why == "no_tpu_and_no_interpreter":
+        interpret = False
+    elif why == "float32_operands":
+        narrow = lambda args: args  # noqa: E731
+    elif why == "a_chunk_of_32":
+        chunk = 32
+    elif why == "a_width_of_64":
+        d = 64
+    elif why == "three_heads":
+        h = 3
+    args = narrow(rule_inputs(seed=8, b=1, t=128, h=h, d_k=d, d_v=d))
+    tracer = obs_trace.get_tracer()
+    tracer.reset_notes()
+    before = len([e for e in tracer.to_events() if e["name"] == "delta_carry"])
+    lowered = jax.jit(lambda *a: kda_rule(*a, chunk=chunk, interpret=interpret)).lower(*args)
+    (found,) = [e["args"] for e in tracer.to_events() if e["name"] == "delta_carry"][before:]
+    want = {"the_kernels_case": None, "three_heads": None, "no_tpu_and_no_interpreter": "backend",
+            "float32_operands": "dtype", "a_chunk_of_32": "chunk", "a_width_of_64": "width"}[why]
+    assert found["path"] == path and found.get("why") == want
+    assert found["heads_a_step"] == h and found["state_bytes"] == 4 * h * d * d
+    assert found["decay"] == "channel" and found["operands"] == "rows"
+    assert ("_carry_call" in lowered.as_text(debug_info=True)) == (path == "kernel")
+
+
+@pytest.mark.parametrize("heads,tiles,d,why", [
+    (16, False, 128, None), (15, True, 96, None), (15, False, 96, "width"), (3, True, 24, "width"),
+    (128, False, 128, "state"),
+])
+def test_the_walk_refuses_widths_that_do_not_tile_and_states_vmem_cannot_hold(heads, tiles, d, why):
+    """Rows whose heads lie side by side want whole lane tiles a head, a tile a
+    head a packed bfloat16 tile's 16 rows; 128 heads of 128 x 128 are 8 MB of
+    float32 state, ten times over for the blocks that hold it."""
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)  # noqa: E731
+    w, u = sds((2, 1, heads, 64, d)), sds((2, 1, heads, 64, 2 * d), jnp.float32)
+    k_out = sds((2, 1, heads, 64, d)) if tiles else sds((2, 1, 64, heads * d))
+    q_in = sds((1, 2, heads, 64, d)) if tiles else sds((1, 128, heads * d))
+    assert G._carry_refuses(w, u, k_out, q_in, sds((1, 2, heads, 64, 64)), True) == why
 
 
 # -- the flash kernels at two widths ---------------------------------------------

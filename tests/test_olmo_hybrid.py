@@ -294,6 +294,91 @@ def test_each_traced_shape_leaves_one_gdn_chunks_instant():
     )
 
 
+# -- the scalar rule's carry as the walk's kernels, in the Pallas interpreter --------
+
+# heads, d_k = d_v / 2 ... and the steps: what the chunk-local stage and the
+# carry each take, and how the carry's operands lie
+WALKS = {
+    # the cell's class: both by the kernels, a tile a head as gdn_operands wrote them
+    "the_cells_class": (3, 96, 128, True, ("kernel", None, "kernel", None, "tiles")),
+    # three chunks are no whole lane tiles of steps: the chunk-local stage is
+    # plain, and rows of whole lane tiles a head are still the walk's
+    "three_chunks_of_lane_tiles": (2, 128, 192, True, ("plain", "steps", "kernel", None, "rows")),
+    # ... and rows of heads of 96 are not
+    "three_chunks_of_96": (3, 96, 192, True, ("plain", "steps", "plain", "width", "rows")),
+    "a_length_the_chunk_pads": (2, 128, 150, True, ("plain", "steps", "kernel", None, "rows")),
+    "no_tpu_and_no_interpreter": (3, 96, 128, False, ("plain", "backend", "plain", "backend", "rows")),
+}
+
+
+def walk_inputs(case):
+    h, d, t = WALKS[case][:3]
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    unit = lambda m: m / jnp.linalg.norm(m, axis=-1, keepdims=True)  # noqa: E731
+    bf16 = jnp.bfloat16
+    q = (unit(jax.random.normal(keys[0], (1, t, h, d))) * d ** -0.5).astype(bf16)
+    k = unit(jax.random.normal(keys[1], (1, t, h, d))).astype(bf16)
+    v = jax.random.normal(keys[2], (1, t, h, 2 * d)).astype(bf16)
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (1, t, h)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(keys[4], (1, t, h)))
+    return (q, k, v, g, beta), 0.1 * jax.random.normal(keys[5], (1, h, d, 2 * d))
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_scalar_rules_carry_says_which_form_it_took(case):
+    h, d, t, interpret, (local, local_why, carry, carry_why, operands) = WALKS[case]
+    args, _ = walk_inputs(case)
+    tracer = obs_trace.get_tracer()
+    tracer.reset_notes()
+    seen = lambda name: [e["args"] for e in tracer.to_events() if e["name"] == name]  # noqa: E731
+    before = {name: len(seen(name)) for name in ("gdn_chunks", "delta_carry")}
+    jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=64, interpret=interpret), *args)
+    (chunks,), (walk,) = (seen(name)[before[name]:] for name in ("gdn_chunks", "delta_carry"))
+    assert (chunks["path"], chunks.get("why")) == (local, local_why) and chunks["carry"] == "saved"
+    assert (walk["path"], walk.get("why")) == (carry, carry_why)
+    assert walk["decay"] == "head" and walk["operands"] == operands
+    assert (walk["heads_a_step"], walk["state_bytes"]) == (h, 4 * h * d * 2 * d)
+    assert walk["chunks"] == -(-t // 64)
+
+
+@functools.lru_cache(maxsize=None)
+def walk_both_ways(case):
+    args, state = walk_inputs(case)
+
+    def program(interpret):
+        def both(state, *args):
+            values, pull = jax.vjp(lambda state, *a: gated_delta_rule(
+                *a, chunk=64, initial_state=state, return_final_state=True, interpret=interpret
+            ), state, *args)
+            keys = jax.random.split(jax.random.PRNGKey(12), 2)
+            return values, pull(tuple(
+                jax.random.normal(key, a.shape).astype(a.dtype) for key, a in zip(keys, values)
+            ))
+
+        return jax.jit(both)
+
+    return [program(interpret)(state, *args) for interpret in (True, False)]
+
+
+@pytest.mark.parametrize("case", ["three_chunks_of_lane_tiles", "a_length_the_chunk_pads"])
+@pytest.mark.parametrize(
+    "what", ["o", "state", "initial_state", "q", "k", "v", "g", "beta"]
+)
+def test_the_walk_after_a_plain_chunk_local_stage_is_the_plain_rule(what, case):
+    """A decay a head over rows whose heads lie side by side: the scalar rule
+    where only the carry is the kernels' (the cell's own case, both stages by
+    the kernels, is ``tests/test_gdn_kernels.py``'s)."""
+    (got, got_grads), (want, want_grads) = walk_both_ways(case)
+    names = ["initial_state", "q", "k", "v", "g", "beta"]
+    if what in ("o", "state"):
+        a, b = got[what == "state"], want[what == "state"]
+    else:
+        a, b = got_grads[names.index(what)], want_grads[names.index(what)]
+    assert a.shape == b.shape and a.dtype == b.dtype
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    assert np.isfinite(a).all() and np.abs(a - b).max() <= 2e-2 * np.abs(b).max()
+
+
 # -- the convolution's no-bias call ----------------------------------------------
 
 

@@ -369,7 +369,9 @@ def test_gated_delta_rule_compiles_for_v5e_at_the_hybrids_widths(one_chip, path)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == len(kernels)
     if path == "kernels":
-        assert kernels == ["gdn_backward", "gdn_inverse", "gdn_operands"] and temp < 1.2e9
+        assert kernels == [
+            "delta_carry", "delta_carry_back", "gdn_backward", "gdn_inverse", "gdn_operands"
+        ] and temp < 1.2e9
     else:
         assert kernels == [] and 1.2e9 < temp < 2e9
 
@@ -599,8 +601,10 @@ def test_a_linear_attention_step_on_the_tpu_path_runs_the_rule_as_kernels_under_
     forward (``gdn_inverse`` by name is what the recomputation reads),
     ``gdn_operands`` forward and once more where the backward reaches the rule
     under the mixer's checkpoint, and ``gdn_backward`` once; all of them under
-    ``gdn_scan``; the carry still loops once forward and once in reverse a
-    layer; and ``STEP_PARTS`` places every matmul of the compiled step."""
+    ``gdn_scan``; the carry is ``delta_carry`` once forward (its outputs saved
+    by name: the recomputation holds no second) and ``delta_carry_back`` once,
+    and no loop is left; and ``STEP_PARTS`` places every matmul of the
+    compiled step."""
     from unittest import mock
 
     import numpy as np
@@ -634,6 +638,7 @@ def test_a_linear_attention_step_on_the_tpu_path_runs_the_rule_as_kernels_under_
     tracer = obs_trace.get_tracer()
     tracer.reset_notes()
     before = len([e for e in tracer.to_events() if e["name"] == "gdn_chunks"])
+    walked = len([e for e in tracer.to_events() if e["name"] == "delta_carry"])
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         lowered = make_train_step(loss, numerics=False).lower(
             described(state), described((tokens, tokens))
@@ -644,15 +649,19 @@ def test_a_linear_attention_step_on_the_tpu_path_runs_the_rule_as_kernels_under_
     census = obs_profile.HloProgram(text).census()
     assert census["totals"]["matmuls"] > 0 and census["totals"]["unplaced_matmuls"] == 0
     linear = layers.count("linear_attention")
-    calls = {key: n for key, n in census["kernels"].items() if key.startswith("gdn_")}
+    ours = ("gdn_", "delta_carry")
+    calls = {key: n for key, n in census["kernels"].items() if key.startswith(ours)}
     assert calls == {
         "gdn_inverse/forward": linear, "gdn_operands/forward": linear,
         "gdn_operands/backward": linear, "gdn_backward/backward": linear,
+        "delta_carry/forward": linear, "delta_carry_back/backward": linear,
     }
-    assert census["totals"]["loops"] == 2 * linear
+    assert census["totals"]["loops"] == 0
+    walks = [e["args"] for e in tracer.to_events() if e["name"] == "delta_carry"][walked:]
+    assert walks and all(a["path"] == "kernel" and a["operands"] == "tiles" for a in walks)
     scopes = obs_profile.scopes_of_hlo(text, GDN_SCOPES)
-    rule = {name: scope for name, scope in scopes.items() if name.startswith("gdn_")}
-    assert len(rule) == 4 * linear and set(rule.values()) == {"gdn_scan"}
+    rule = {name: scope for name, scope in scopes.items() if name.startswith(ours)}
+    assert len(rule) == 6 * linear and set(rule.values()) == {"gdn_scan"}
 
 
 def test_a_hybrid_step_on_the_tpu_path_runs_the_conv_kernels_under_ssm_conv(one_chip):
@@ -1160,7 +1169,9 @@ def test_kda_rule_compiles_for_v5e_at_the_cells_widths(one_chip, path):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == len(kernels)
     if path == "kernels":
-        assert kernels == ["kda_backward", "kda_inverse", "kda_operands"] and temp < 1e9
+        assert kernels == [
+            "delta_carry", "delta_carry_back", "kda_backward", "kda_inverse", "kda_operands"
+        ] and temp < 1e9
     else:
         assert kernels == [] and 1e9 < temp < 3e9
 
@@ -1204,6 +1215,93 @@ def test_kda_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, ke
         assert [(a.shape, a.dtype) for a in out] == [
             (a.shape, a.dtype) for a in (rows, rows, rows, decays, beta)
         ]
+
+
+CARRY_SHAPES = {"ling": (16, 128, 128, False), "ling_published": (32, 128, 128, False),
+                "solar": (8, 128, 128, False),
+                "olmo_hybrid": (15, 96, 192, True)}
+
+
+@pytest.mark.parametrize("cell", list(CARRY_SHAPES))
+@pytest.mark.parametrize("kernel", ["delta_carry", "delta_carry_back"])
+def test_the_delta_rules_walk_compiles_for_v5e_at_the_cells_shape(one_chip, kernel, cell):
+    """The carry and the output stage as one walk over 128 chunks of 64, the
+    state of every head in VMEM (Ling 16 heads of 128 / 128: 1 MB; Solar 8;
+    OLMo-hybrid 15 of 96 / 192, a lane tile and a half of state a row): the
+    operands where the chunk-local kernels write them (a row's heads side by
+    side; OLMo-hybrid's a tile a head), the chunks ``"arbitrary"``, the scratch
+    and a VMEM limit set from the blocks are what the chip's compiler can
+    refuse."""
+    G = importlib.import_module("edl_tpu.ops.gated_delta")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    h, d_k, d_v, tiles = CARRY_SHAPES[cell]
+    nc, size, f32 = 128, 64, jnp.float32
+    by_head = lambda d, dtype=jnp.bfloat16: sds((nc, 1, h, size, d), dtype)  # noqa: E731
+    if tiles:
+        k_out, q_in, o = by_head(d_k), sds((1, nc, h, size, d_k)), sds((1, nc, h, size, d_v))
+    else:
+        k_out, q_in, o = (sds((nc, 1, size, h * d_k)), sds((1, nc * size, h * d_k)),
+                          sds((1, nc * size, h * d_v)))
+    w, u, new, whole = by_head(d_k), by_head(d_v, f32), by_head(d_v), sds((nc, 1, h, d_k), f32)
+    scores, state = sds((1, nc, h, size, size)), sds((1, h, d_v, d_k), f32)
+    entering = sds((nc, 1, h, d_v, d_k), f32)
+    call, args, want = {
+        "delta_carry": (lambda *a: G._carry_call(*a, False),
+                        (w, u, k_out, whole, q_in, scores, state), (o, new, entering, state)),
+        "delta_carry_back": (lambda *a: G._carry_back_call(*a, False),
+                             (w, k_out, whole, q_in, scores, entering, new, o, state),
+                             (w, u, k_out, whole, q_in, scores, state)),
+    }[kernel]
+    assert kernel in jax.jit(call).lower(*args).compile().as_text()
+    out = jax.eval_shape(call, *args)
+    assert [(a.shape, a.dtype) for a in out] == [(a.shape, a.dtype) for a in want]
+
+
+def test_a_chunk_call_that_is_no_walk_says_of_its_grid_what_it_said_before(one_chip):
+    """``ops/ssd.py``'s two calls and the delta rules' chunk-local ones go
+    through ``_chunk_call`` without ``walk``: both grid dimensions parallel, as
+    before the walk was there, whose own chunks are ``"arbitrary"`` (the
+    semantics lie in a call's Mosaic bytecode, so they are read where they are
+    handed over; ``benchmark/tools/lowered_step.py`` hashes Granite's,
+    Nemotron's and Mistral's whole steps against the parent's: PERF.md, PR 62)."""
+    from unittest import mock
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    S = importlib.import_module("edl_tpu.ops.ssd")
+    G = importlib.import_module("edl_tpu.ops.gated_delta")
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    # shapes no other test traces: the calls are jitted, a shape lowers once
+    f32, h, p, n, t = jnp.float32, 8, 64, 128, 768
+    xbc, dt, head = sds((1, h * p + 2 * n, t)), sds((1, h, t), f32), sds((h, 1), f32)
+    rows, decays, beta = sds((1, t, 256)), sds((1, t, 256), f32), sds((1, t, 2), f32)
+    by_head = lambda d, dtype=jnp.bfloat16: sds((t // 64, 1, 2, 64, d), dtype)  # noqa: E731
+    calls = {
+        "ssd_forward": (lambda *a: S._forward_call(*a, 256, p, n, False), (xbc, dt, head, head)),
+        "kda_inverse": (lambda *a: G._inverse_call(*a, False), (rows, decays, beta)),
+        "gdn_inverse": (lambda *a: G._scalar_inverse_call(*a, False),
+                        (sds((1, 256, t)), sds((1, 2, t), f32), sds((1, 2, t), f32))),
+        "delta_carry": (lambda *a: G._carry_call(*a, False), (
+            by_head(128), by_head(128, f32), sds((t // 64, 1, 64, 256)),
+            sds((t // 64, 1, 2, 128), f32), rows, sds((1, t // 64, 2, 64, 64)),
+            sds((1, 2, 128, 128), f32),
+        )),
+    }
+    said = {}
+    for name, (call, args) in calls.items():
+        with mock.patch.object(pltpu, "CompilerParams", wraps=pltpu.CompilerParams) as params:
+            jax.jit(call).lower(*args)
+        (made,) = params.call_args_list
+        said[name] = made.kwargs["dimension_semantics"]
+    walk = said.pop("delta_carry")
+    assert walk == ("parallel", "arbitrary")
+    assert set(said.values()) == {("parallel", "parallel")}
 
 
 def _kda_cell():
