@@ -25,6 +25,13 @@ lazily by the subpackages that need them so control-plane processes stay
 lightweight.
 """
 
+import sys as _sys
+import time as _time
+
+#: ``(time.monotonic(), len(sys.modules), jax loaded?)`` as this package's
+#: import began: where the ring's ``process_boot`` span ends (obs/trace.py)
+IMPORT_STAMP = (_time.monotonic(), len(_sys.modules), "jax" in _sys.modules)
+
 __version__ = "0.1.0"
 
 __all__ = ["__version__"]

@@ -25,6 +25,8 @@ from typing import Any, Callable, Sequence, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
+from edl_tpu.obs import trace as obs_trace
+
 ModuleDef = Any
 
 
@@ -130,16 +132,18 @@ class ResNet(nn.Module):
         block_name = getattr(self.block, "__name__", "Block")
         index = 0
         for stage, num_blocks in enumerate(self.stage_sizes):
-            for block_idx in range(num_blocks):
-                strides = 2 if stage > 0 and block_idx == 0 else 1
-                x = block(
-                    filters=self.width * 2**stage,
-                    strides=strides,
-                    conv=conv,
-                    norm=norm,
-                    name="%s_%d" % (block_name, index),
-                )(x)
-                index += 1
+            # host Python around the same calls: what a trace spends on a stage
+            with obs_trace.span("model_trace", part="stage", layer=stage):
+                for block_idx in range(num_blocks):
+                    strides = 2 if stage > 0 and block_idx == 0 else 1
+                    x = block(
+                        filters=self.width * 2**stage,
+                        strides=strides,
+                        conv=conv,
+                        norm=norm,
+                        name="%s_%d" % (block_name, index),
+                    )(x)
+                    index += 1
 
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)

@@ -231,6 +231,9 @@ LAYER_TYPES = ("attention", "sliding_attention", "mamba", "linear_attention",
                "conv", "sparse_attention", "kda", "latent_attention")
 # under ``ArchSpec.one_branch`` also: a block whose branch is the feed-forward
 FEED_FORWARD_TYPES = ("moe", "mlp")
+# the module a layer type's mixer is built under, where it is not ``attn``
+_MIXER_MODULES = {"mamba": "mamba", "linear_attention": "gdn", "kda": "kda",
+                  "conv": "sconv"}
 
 
 def _scope(name: Optional[str]):
@@ -703,6 +706,27 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
+        # host Python around the same calls, once each time a trace runs this
+        # block's Python: a second span of one layer is a second run of it
+        mixer, ffn = self._branch_names()
+        with obs_trace.span(
+            "model_trace", part="block", layer=self.name, mixer=mixer, ffn=ffn
+        ):
+            return self._branches(x, positions)
+
+    def _branch_names(self):
+        """``(mixer, ffn)``: the module names of this block's two branches, as
+        ``obs/profile.py:STEP_PARTS`` lists them; None for the one a one-branch
+        block lacks."""
+        mixer = _MIXER_MODULES.get(self.mixer, "attn")
+        ffn = "moe" if self.moe is not None or self.num_experts > 0 else "mlp"
+        if not self.arch.one_branch:
+            return mixer, ffn
+        if self.mixer in FEED_FORWARD_TYPES:
+            return None, self.mixer
+        return mixer, None
+
+    def _branches(self, x, positions):
         arch = self.arch
         if arch.post_norms not in (False, True, "only"):
             raise ValueError("unknown post_norms %r" % (arch.post_norms,))
@@ -1016,11 +1040,12 @@ class TransformerLM(nn.Module):
                 "%d layer_types for num_layers %d"
                 % (len(layer_types), self.num_layers)
             )
-        embed = nn.Embed(
-            self.vocab_size, self.d_model,
-            dtype=self.dtype, name="embed",
-        )
-        x = _times(embed(tokens), arch.embedding_multiplier)
+        with obs_trace.span("model_trace", part="embed"):
+            embed = nn.Embed(
+                self.vocab_size, self.d_model,
+                dtype=self.dtype, name="embed",
+            )
+            x = _times(embed(tokens), arch.embedding_multiplier)
         if arch.block_diffusion is not None:
             length = self._block_diffusion_length(arch, layer_types, tokens)
             if positions is None:
@@ -1055,12 +1080,13 @@ class TransformerLM(nn.Module):
             # the noised half alone is scored: the clean half was keys and
             # values, and its last layer's output is never read
             x = x[:, length:]
-        x = RMSNorm(self.norm_eps, name="ln_f")(x)
-        if arch.tie_embeddings:
-            logits = _head_matmul(x, embed.embedding.astype(x.dtype).T)
-        else:
-            head = LMHead(self.vocab_size, name="lm_head")
-            logits = head(x)
+        with obs_trace.span("model_trace", part="head"):
+            x = RMSNorm(self.norm_eps, name="ln_f")(x)
+            if arch.tie_embeddings:
+                logits = _head_matmul(x, embed.embedding.astype(x.dtype).T)
+            else:
+                head = LMHead(self.vocab_size, name="lm_head")
+                logits = head(x)
         if arch.mtp is not None and not self.decode:
             if arch.mtp.depth != 1:
                 raise ValueError(
@@ -1076,7 +1102,7 @@ class TransformerLM(nn.Module):
                 loss_weight=arch.mtp.loss_weight, vocab=self.vocab_size,
                 mixer=layer_types[-1], logit_bytes=4 * tokens.size * self.vocab_size,
             )
-            with jax.named_scope("mtp"):
+            with obs_trace.span("model_trace", part="mtp"), jax.named_scope("mtp"):
                 with jax.named_scope("mtp_join"):
                     joined = jnp.concatenate([
                         RMSNorm(self.norm_eps, name="mtp_enorm")(

@@ -406,6 +406,32 @@ class SpanTracer:
             end_wall - start_wall, **args,
         )
 
+    def record_over(
+        self, name: str, t0_mono: float, dur_s: float, children, **args
+    ) -> None:
+        """:meth:`record` a span that encloses spans already in the ring,
+        those called one of ``children``: ``worker_boot`` over the
+        ``process_boot`` and ``package_import`` spans a worker took before
+        ``init()`` opened its restage operation. Where the span is linked into
+        a live trace (:meth:`record`'s rule), the still unlinked ones become
+        its children there."""
+        ctx = current()
+        if PROPAGATION.armed and "trace_id" not in args and ctx is not None:
+            args = dict(
+                args, trace_id=ctx.trace_id, span_id=_span_id(),
+                parent_id=ctx.span_id,
+            )
+            with self._lock:
+                for ev in self._events:
+                    if ev["name"] in children and ev["ph"] == "X":
+                        linked = ev.setdefault("args", {})
+                        if "trace_id" not in linked:
+                            linked.update(
+                                trace_id=ctx.trace_id, span_id=_span_id(),
+                                parent_id=args["span_id"],
+                            )
+        self.record(name, t0_mono, dur_s, **args)
+
     def instant(self, name: str, ts_wall: Optional[float] = None, **args) -> None:
         """Zero-duration marker (drain triggered, stage published, ...).
 
@@ -539,12 +565,65 @@ def get_tracer(component: Optional[str] = None) -> SpanTracer:
         if _tracer is None:
             name = component or _default_component()
             _tracer = SpanTracer(component=name)
+            _record_process_boot(_tracer)
             if os.environ.get("EDL_TRACE_DIR"):
                 atexit.register(_tracer.export)
                 _start_periodic_export(_tracer)
         elif component and _tracer.component == "proc":
             _tracer.component = component
         return _tracer
+
+
+#: the spans of a worker's start that are taken before ``init()`` can open its
+#: restage operation (``train/context.py`` links them under ``worker_boot``)
+BOOT_SPANS = ("process_boot", "package_import")
+
+
+def process_start_mono(stat_path: str = "/proc/self/stat") -> Optional[float]:
+    """When the OS started this process, on ``time.monotonic()``'s scale:
+    field 22 of ``/proc/self/stat`` (clock ticks after the system's boot)
+    against the boot clock. None where there is no ``/proc`` to ask."""
+    try:
+        with open(stat_path) as f:
+            # the second field is the command in parentheses, spaces and all
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (
+            time.clock_gettime(time.CLOCK_BOOTTIME)
+            - ticks / os.sysconf("SC_CLK_TCK")
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.monotonic() - age
+
+
+def _record_process_boot(tracer: SpanTracer) -> None:
+    """``process_boot``, once a process: the OS's start of it -> the first
+    statement of ``edl_tpu/__init__.py``: the interpreter, ``site`` and
+    whatever the entry point imported before this package (``modules``
+    entries of ``sys.modules``; ``jax_loaded`` says whether jax was one)."""
+    import edl_tpu
+
+    stamp, modules, jax_loaded = edl_tpu.IMPORT_STAMP
+    started = process_start_mono()
+    if started is None or started > stamp:
+        return
+    tracer.record(
+        "process_boot", started, stamp - started,
+        modules=modules, jax_loaded=jax_loaded,
+    )
+
+
+@contextlib.contextmanager
+def package_import(package: str):
+    """``with package_import(__name__):`` around a package's own imports: a
+    ``package_import`` span with ``package`` and ``modules``, the entries of
+    ``sys.modules`` it added. They nest; a reader counts the outermost."""
+    before = len(sys.modules)
+    with get_tracer().span("package_import", package=package) as handle:
+        try:
+            yield
+        finally:
+            handle.args["modules"] = len(sys.modules) - before
 
 
 def _start_periodic_export(tracer: SpanTracer) -> None:

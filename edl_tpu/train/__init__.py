@@ -1,9 +1,13 @@
 """Training plane. Names resolve on first use (PEP 562), so importing a
 jax-free submodule — the launcher takes ``CacheExchange`` from
 ``edl_tpu.train.aot`` — does not import jax through this package: a
-control-plane process must never load it (see cluster/job_env.py)."""
+control-plane process must never load it (see cluster/job_env.py). What a
+name's first use imports is a ``package_import`` span in the ring (the
+package's own body imports nothing)."""
 
 import importlib
+
+from edl_tpu.obs import trace as _trace
 
 _HOME = {
     "current_env": "context",
@@ -40,8 +44,8 @@ def __getattr__(name):
         raise AttributeError(
             "module %r has no attribute %r" % (__name__, name)
         )
-    value = getattr(
-        importlib.import_module("%s.%s" % (__name__, _HOME[name])), name
-    )
+    home = "%s.%s" % (__name__, _HOME[name])
+    with _trace.package_import(home):
+        value = getattr(importlib.import_module(home), name)
     globals()[name] = value
     return value

@@ -74,6 +74,7 @@ reads as trace, lowering and load on a restage's critical path.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -306,6 +307,40 @@ def instrument_compile_spans() -> None:
 
     if _on_jax_time_span not in _monitoring.get_event_time_span_listeners():
         monitoring.register_event_time_span_listener(_on_jax_time_span)
+
+
+#: what starts one platform's runtime (``jax._src.xla_bridge``, pinned jax
+#: 0.9.0): ``backends()`` calls it once a platform, under its lock, whoever
+#: asked for a device first; a hot restage's ``_clear_backends()`` makes it
+#: run again
+JAX_BACKEND_INIT = "_init_backend"
+
+
+def instrument_backend_init() -> None:
+    """``backend_init`` into the span ring, one span a platform with
+    ``platform`` and ``devices``: the TPU runtime's start (and the CPU
+    client's), whoever triggers it. A wrapper of :data:`JAX_BACKEND_INIT`,
+    installed once (idempotent) and only ahead of the initialisation: where
+    the backends are up already, or the private name is gone, nothing is
+    installed and nothing recorded."""
+    from jax._src import xla_bridge
+
+    init = getattr(xla_bridge, JAX_BACKEND_INIT, None)
+    if (
+        init is None or getattr(init, "_edl_span", False)
+        or xla_bridge.backends_are_initialized()
+    ):
+        return
+
+    @functools.wraps(init)
+    def traced(platform):
+        with obs_trace.get_tracer().span("backend_init", platform=platform) as span:
+            backend = init(platform)
+            span.args["devices"] = backend.device_count()
+            return backend
+
+    traced._edl_span = True
+    setattr(xla_bridge, JAX_BACKEND_INIT, traced)
 
 
 def cache_event_counts() -> Dict[str, int]:

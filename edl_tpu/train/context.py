@@ -111,6 +111,7 @@ def enable_compilation_cache(path: str) -> None:
     _aot.enable_portable_cache_keys()
     _aot.instrument_compilation_cache()
     _aot.instrument_compile_spans()
+    _aot.instrument_backend_init()
 
 
 def _enable_all_rank_cache_writes() -> None:
@@ -211,10 +212,12 @@ _boot_recorded = False
 
 def _record_boot_span(obs_trace) -> None:
     """Once per process: a ``worker_boot`` restage-trace segment from the
-    launcher's spawn stamp (``EDL_SPAWN_TS``) to now — the interpreter +
-    import cold start the critical path must attribute, which no
-    in-process code can otherwise observe. Skipped on hot restages (the
-    process was not respawned, the stamp is stale)."""
+    launcher's spawn stamp (``EDL_SPAWN_TS``) to now: fork and exec, then
+    what the process itself saw of its start, the ring's ``process_boot`` (the
+    OS's start of the process -> the package's first statement) and the
+    ``package_import`` spans taken so far, which become its children in the
+    restage trace. Skipped on hot restages (the process was not respawned,
+    the stamp is stale)."""
     global _boot_recorded
     if _boot_recorded:
         return
@@ -228,8 +231,8 @@ def _record_boot_span(obs_trace) -> None:
         return
     if not 0.0 < age < 3600.0:
         return  # a clock step or an inherited stale stamp: drop it
-    obs_trace.get_tracer().record(
-        "worker_boot", time.monotonic() - age, age
+    obs_trace.get_tracer().record_over(
+        "worker_boot", time.monotonic() - age, age, obs_trace.BOOT_SPANS
     )
 
 
@@ -302,6 +305,9 @@ def init(env: Optional[WorkerEnv] = None) -> WorkerEnv:
         # the first completed step.
         from edl_tpu.obs import trace as obs_trace
 
+        # the tracer (and its process_boot) before the operation opens:
+        # worker_boot adopts what was taken before it, the root what comes after
+        obs_trace.get_tracer()
         obs_trace.begin_process_op(
             "restage", env.stage, rank=str(env.global_rank)
         )
@@ -315,6 +321,7 @@ def init(env: Optional[WorkerEnv] = None) -> WorkerEnv:
         from edl_tpu.train import aot as _aot
 
         _aot.instrument_compile_spans()
+        _aot.instrument_backend_init()
     if _distributed_up:
         return env
     if env.world_size > 1 and env.coordinator:

@@ -264,6 +264,7 @@ class ElasticTrainer:
         seed: int = 0,
         log: bool = True,
     ) -> None:
+        t_init = time.monotonic()
         self._model = model
         self._optimizer = optimizer
         self._loss = loss
@@ -282,6 +283,10 @@ class ElasticTrainer:
         self._log = log
         self._eval_step = None  # jitted once, reused across evaluate() calls
         self._masked_eval_step = None
+        obs_trace.get_tracer().record(
+            "trainer_init", t_init, time.monotonic() - t_init,
+            ckpt=bool(ckpt_dir),
+        )
 
     def _make_tx(self, overrides: Dict[str, Any]):
         if isinstance(self._optimizer, optax.GradientTransformation):

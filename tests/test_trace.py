@@ -288,9 +288,12 @@ def _write_trace(path, component, pid, spans):
 
 
 def _synthetic_restage(tmp_path, base=1000.0, with_worker=True,
-                       orphan=False):
+                       orphan=False, boot_gap=0.0, boot_parts=False):
     """A launcher + worker restage trace as two export files; returns
-    the op context."""
+    the op context. ``boot_gap``: ``worker_boot`` ends that much before
+    ``ckpt_restore`` starts (the TPU runtime's start, untraced);
+    ``boot_parts``: the worker also took ``process_boot`` under
+    ``worker_boot`` and ``backend_init`` over that gap."""
     ctx = obs_trace.op_context("restage", "synt-stage")
     root = ctx.span_id
 
@@ -315,9 +318,17 @@ def _synthetic_restage(tmp_path, base=1000.0, with_worker=True,
         _write_trace(
             tmp_path / "worker-0-200.trace.json", "worker-0", 200,
             [
-                ("worker_boot", base + 0.4, 1.0,
+                ("worker_boot", base + 0.4, 1.0 - boot_gap,
                  {"trace_id": ctx.trace_id, "span_id": seg(3),
                   "parent_id": root}),
+            ] + [
+                ("process_boot", base + 0.5, 0.3,
+                 {"trace_id": ctx.trace_id, "span_id": seg(6),
+                  "parent_id": seg(3), "modules": 700, "jax_loaded": True}),
+                ("backend_init", base + 1.4 - boot_gap, boot_gap,
+                 {"trace_id": ctx.trace_id, "span_id": seg(7),
+                  "parent_id": root, "platform": "tpu", "devices": 4}),
+            ] * boot_parts + [
                 ("ckpt_restore", base + 1.4, 0.4,
                  {"trace_id": ctx.trace_id, "span_id": seg(4),
                   "parent_id": root}),
@@ -350,6 +361,37 @@ class TestTracepath:
         gaps = [round(p.dur, 3) for p in path if p.segment is None]
         assert gaps == [0.1, 0.05, 0.1]
         assert tracepath.covered_seconds(path) == pytest.approx(1.75, abs=1e-6)
+
+    def test_a_workers_boot_in_parts(self, tmp_path):
+        """``process_boot`` is named inside ``worker_boot``'s interval and
+        ``backend_init`` where the runtime's start was an untraced gap; the
+        path covers no less for them."""
+        bare, parts = tmp_path / "bare", tmp_path / "parts"
+        covered = {}
+        for run_dir, boot_parts in ((bare, False), (parts, True)):
+            run_dir.mkdir()
+            _synthetic_restage(run_dir, boot_gap=0.25, boot_parts=boot_parts)
+            (ot,) = tracepath.extract_ops(tracepath.load_run(str(run_dir)))
+            assert not ot.orphans
+            path = tracepath.critical_path(ot)
+            covered[boot_parts] = tracepath.covered_seconds(path)
+            slices = [
+                (p.segment.name if p.segment else None, round(p.dur, 3))
+                for p in path if p.t0 >= 1000.4 - 1e-9
+            ]
+            if boot_parts:
+                assert slices == [
+                    ("worker_boot", 0.1), ("process_boot", 0.3),
+                    ("worker_boot", 0.35), ("backend_init", 0.25),
+                    ("ckpt_restore", 0.4), ("first_step", 0.2),
+                ]
+            else:
+                assert slices == [
+                    ("worker_boot", 0.75), (None, 0.25),
+                    ("ckpt_restore", 0.4), ("first_step", 0.2),
+                ]
+        assert covered[True] == pytest.approx(covered[False] + 0.25, abs=1e-6)
+        assert covered[False] == pytest.approx(1.5, abs=1e-6)
 
     def test_orphan_detection(self, tmp_path):
         _synthetic_restage(tmp_path, orphan=True)
@@ -502,6 +544,23 @@ class TestCli:
         assert doc["ops"][0]["complete"] is True
         assert edl_trace.main([str(tmp_path), "--list"]) == 0
         assert "complete" in capsys.readouterr().out
+
+    def test_edl_trace_prints_a_workers_boot_in_parts(self, tmp_path, capsys):
+        from tools import edl_trace
+
+        _synthetic_restage(tmp_path, boot_gap=0.25, boot_parts=True)
+        assert edl_trace.main([str(tmp_path), "--op", "restage"]) == 0
+        out = capsys.readouterr().out
+        names = [ln.split()[3] for ln in out.splitlines()
+                 if ln.split()[2:3] == ["worker-0"]]
+        assert names[:4] == [
+            "worker_boot", "process_boot", "worker_boot", "backend_init"]
+        assert "platform=tpu" in out and "modules=700" in out
+        assert edl_trace.main([str(tmp_path), "--json"]) == 0
+        doc = json.loads(capsys.readouterr().out)
+        assert {"process_boot", "backend_init"} <= {
+            s["name"] for s in doc["ops"][0]["path"] if s.get("name")
+        }
 
     def test_edl_trace_empty_dir(self, tmp_path, capsys):
         from tools import edl_trace
