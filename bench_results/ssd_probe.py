@@ -1,9 +1,13 @@
-"""PR 50's chip probe: ``ops/ssd.py``'s two kernels alone, and ``ssd_scan``'s
+"""The chip probe of ``ops/ssd.py``'s two kernels alone, and of ``ssd_scan``'s
 value and gradients by the kernels beside the plain form, at the two cells'
 shapes (one sequence of 8192, 64 heads of 64 over a state of 128; Granite:
-chunks of 256 in one group, Nemotron: chunks of 128 in four groups).
+chunks of 256 in one group, Nemotron: chunks of 128 in four groups). PR 50's,
+and since PR 64 of two trees in one call: ``--parent DIR`` names a checkout
+whose ``edl_tpu/ops/ssd.py`` is loaded beside this tree's and timed first
+(before PR 64: ``ssd_forward`` and ``ssd_backward`` hand the carry's operands
+to a ``lax.scan``, timed alone too, as ``_carry_out``'s value and gradients).
 
-    chiprun --chips 1 -- python3 bench_results/ssd_probe.py [heads a round ...]
+    chiprun --chips 1 -- python3 bench_results/ssd_probe.py [--parent DIR] [heads a round ...]
 
 A program is timed as the difference between one jitted function that runs it
 21 times and one that runs it once (each call's ``A`` scaled apart, so none is
@@ -14,6 +18,7 @@ call in a traced step (PERF.md, PR 48). Arguments set ``ops/ssd.py``'s
 ``_HEADS_A_ROUND`` in turn (``2 4 8``: the sweep PR 50 chose 8 by).
 """
 
+import importlib.util
 import json
 import os
 import statistics
@@ -81,7 +86,36 @@ def rel(got, want):
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
-def main(widths):
+def kernel_programs(module, local, chunk):
+    """``(name, program, operands)`` of ``module``'s kernels alone: the fused
+    pair (PR 64: the carry inside) or the chunk-local pair and the loops that
+    carried their outputs."""
+    state = jnp.zeros((1, H, P, N), jnp.float32)
+    forward = lambda *v: module._forward_call(*v, chunk, P, N, False)  # noqa: E731
+    backward = lambda *v: module._backward_call(*v, chunk, P, N, False)  # noqa: E731
+    if hasattr(module, "_scan_kernels"):
+        y, entering, final = jax.block_until_ready(jax.jit(forward)(*local, state))
+        return [("ssd_forward", forward, (*local, state)),
+                ("ssd_backward", backward, (*local, y, entering, final))]
+    y, own, whole, grown = jax.block_until_ready(jax.jit(forward)(*local))
+    nc, g = T // chunk, (local[0].shape[1] - H * P) // (2 * N)
+    r = H // g
+    c = local[0][:, H * P + g * N:].reshape(1, g, N, nc, chunk).transpose(3, 0, 1, 2, 4)
+    carried = (whole.reshape(nc, 1, g, r), own.reshape(nc, 1, g, r, P, N),
+               y.reshape(nc, 1, g, r, P, chunk), grown.reshape(nc, 1, g, r, chunk), c)
+    w = jnp.ones((1, g, r, P, T), jnp.bfloat16)
+
+    def loops(whole, own, y, grown, c):
+        run = lambda *v: module._carry_out(state.reshape(1, g, r, P, N), *v, jnp.bfloat16)[0]  # noqa: E731
+        out, vjp = jax.vjp(run, whole, own, y, grown, c)
+        return (out, *vjp(w))
+
+    return [("ssd_forward", forward, local),
+            ("ssd_backward", backward, (*local, y, own, whole, grown)),
+            ("carry loops value and gradients", loops, jax.block_until_ready(carried))]
+
+
+def main(widths, parent):
     lines = []
 
     def say(**line):
@@ -89,47 +123,52 @@ def main(widths):
         lines.append(line)
         print(json.dumps(line), flush=True)
 
+    trees = {"tree": ssd}
+    if parent:
+        spec = importlib.util.spec_from_file_location(
+            "parent_ssd", os.path.join(parent, "edl_tpu", "ops", "ssd.py")
+        )
+        trees = {"parent": importlib.util.module_from_spec(spec), **trees}
+        spec.loader.exec_module(trees["parent"])
     for width in widths or [None]:
-        if width:
-            ssd._HEADS_A_ROUND = width
-        ssd._forward_call.clear_cache()
-        ssd._backward_call.clear_cache()
-        for cell, (chunk, groups) in SHAPES.items():
-            args, w = inputs(50, groups)
-            x, dt, a, b, c, d = args
-            xbc = jnp.concatenate([v.reshape(1, T, -1) for v in (x, b, c)], axis=-1)
-            local = (xbc.swapaxes(1, 2), dt.swapaxes(1, 2), a.reshape(H, 1), d.reshape(H, 1))
-            local = jax.block_until_ready(jax.jit(lambda *v: v)(*local))
-            forward = lambda *v: ssd._forward_call(*v, chunk, P, N, False)  # noqa: E731
-            backward = lambda *v: ssd._backward_call(*v, chunk, P, N, False)  # noqa: E731
-            outs = jax.block_until_ready(jax.jit(forward)(*local))
-            for name, fn, operands in (("ssd_forward", forward, local),
-                                       ("ssd_backward", backward, (*local, *outs))):
-                say(cell=cell, program=name, chunk=chunk, groups=groups, heads_a_round=width,
-                    ms=round(a_call(fn, operands), 3))
+        for tree, module in trees.items():
+            if width:
+                module._HEADS_A_ROUND = width
+            module._forward_call.clear_cache()
+            module._backward_call.clear_cache()
+            for cell, (chunk, groups) in SHAPES.items():
+                args, w = inputs(50, groups)
+                x, dt, a, b, c, d = args
+                xbc = jnp.concatenate([v.reshape(1, T, -1) for v in (x, b, c)], axis=-1)
+                local = (xbc.swapaxes(1, 2), dt.swapaxes(1, 2), a.reshape(H, 1), d.reshape(H, 1))
+                local = jax.block_until_ready(jax.jit(lambda *v: v)(*local))
+                said = dict(tree=tree, cell=cell, chunk=chunk, groups=groups, heads_a_round=width)
+                for name, fn, operands in kernel_programs(module, local, chunk):
+                    say(program=name, ms=round(a_call(fn, operands), 3), **said)
 
-            def value_and_grads(w, *v):
-                out, vjp = jax.vjp(lambda *v: ssd.ssd_scan(*v, chunk=chunk), *v)
-                return (out, *vjp(w))
+                def value_and_grads(w, *v):
+                    out, vjp = jax.vjp(lambda *v: module.ssd_scan(*v, chunk=chunk), *v)
+                    return (out, *vjp(w))
 
-            results = {}
-            for path in ("kernel", "plain"):
-                refuse = ssd._kernels_refuse if path == "kernel" else (lambda *a: "asked")
-                with mock.patch.object(ssd, "_kernels_refuse", refuse):
-                    fn = lambda w, x, a, *v: value_and_grads(w, x, v[0], a, *v[1:])  # noqa: E731
-                    ms = a_call(fn, (w, x, a, dt, b, c, d), more=4)
-                    results[path] = jax.jit(lambda w, *v: value_and_grads(w, *v))(w, *args)
-                say(cell=cell, program="ssd_scan value and gradients", path=path, chunk=chunk,
-                    groups=groups, heads_a_round=width, ms=round(ms, 3))
-            names = ("y", "d_x", "d_dt", "d_a", "d_b", "d_c", "d_d")
-            say(cell=cell, program="kernel against plain", heads_a_round=width, **{
-                n: round(rel(g, p), 5)
-                for n, g, p in zip(names, results["kernel"], results["plain"])
-            })
+                results = {}
+                for path in ("kernel", "plain"):
+                    refuse = module._kernels_refuse if path == "kernel" else (lambda *a: "asked")
+                    with mock.patch.object(module, "_kernels_refuse", refuse):
+                        fn = lambda w, x, a, *v: value_and_grads(w, x, v[0], a, *v[1:])  # noqa: E731
+                        ms = a_call(fn, (w, x, a, dt, b, c, d), more=4)
+                        results[path] = jax.jit(lambda w, *v: value_and_grads(w, *v))(w, *args)
+                    say(program="ssd_scan value and gradients", path=path, ms=round(ms, 3), **said)
+                names = ("y", "d_x", "d_dt", "d_a", "d_b", "d_c", "d_d")
+                say(program="kernel against plain", **said, **{
+                    n: round(rel(g, p), 5)
+                    for n, g, p in zip(names, results["kernel"], results["plain"])
+                })
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "ssd_probe.jsonl"), "w") as out:
         out.writelines(json.dumps(line) + "\n" for line in lines)
 
 
 if __name__ == "__main__":
-    main([int(a) for a in sys.argv[1:]])
+    argv = sys.argv[1:]
+    parent = argv.pop(argv.index("--parent") + 1) if "--parent" in argv else None
+    main([int(a) for a in argv if a != "--parent"], parent)

@@ -23,13 +23,15 @@ read ``xBC`` in place out of the in projection's output and write it once, both
 in the model's dtype, while the float32 taps, sum, bias and SiLU stay in VMEM
 and registers; elsewhere the plain float32 form, ``causal_conv`` there.
 
-The scan's chunk-local stage is a pair of Pallas kernels on a TPU backend at
-shapes they take (``ops/ssd.py``: ``ssd_forward`` / ``ssd_backward``, time along
-the lanes as the convolution's kernels leave ``xBC``) and plain ``jax.numpy``
-elsewhere; each traced shape leaves one ``ssm_chunks`` instant in the ring
-(``chunk``, ``chunks``, ``heads``, ``groups``, ``d_head``, ``d_state``,
-``state_bytes``, ``path`` ``kernel`` / ``plain`` and, on ``plain``, ``why``),
-which ``step_plain_fallbacks`` counts.
+The scan is a pair of Pallas kernels on a TPU backend at shapes they take
+(``ops/ssd.py``: ``ssd_forward`` / ``ssd_backward``, time along the lanes as
+the convolution's kernels leave ``xBC`` and as the gate below reads ``y``; they
+carry the state from chunk to chunk themselves, in VMEM, so a layer holds no
+``lax.scan``) and plain ``jax.numpy`` around a ``lax.scan`` elsewhere; each
+traced shape leaves one ``ssm_chunks`` instant in the ring (``chunk``,
+``chunks``, ``heads``, ``groups``, ``d_head``, ``d_state``, ``state_bytes``,
+``path`` ``kernel`` / ``plain``, ``carry`` ``kernel`` / ``loop`` and, on
+``plain``, ``why``), which ``step_plain_fallbacks`` counts.
 
 The device time of its four parts carries the names ``ssm_proj`` (both
 projections), ``ssm_conv``, ``ssm_scan`` and ``ssm_gate``

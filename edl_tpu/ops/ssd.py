@@ -9,59 +9,67 @@ The recurrence, per head ``h`` with state ``S`` of ``[P, N]`` (Dao and Gu,
 is computed a chunk of ``chunk`` steps at a time. Inside a chunk the state
 never materialises: ``Y_diag = (L o C B^T)(dt x)`` with ``L_ij = exp(sum of
 dt_k A over j < k <= i)``, three matmuls. Each chunk's own final state is one
-more matmul, the states are carried from chunk to chunk by a sequential
-``lax.scan`` (``T / chunk`` steps), and what a chunk inherits reaches its
-outputs through ``Y_off = exp(cumsum) * C S_in``, which the same loop adds to
-``Y_diag`` as it passes the chunk (:func:`_carry_out`).
+more matmul, the states are carried from chunk to chunk in order (``T /
+chunk`` steps), and what a chunk inherits reaches its outputs through ``Y_off
+= exp(cumsum) * C S_in``, which is added to ``Y_diag`` as the carry passes the
+chunk.
 
 Precision: the log-decays ``dt A``, their running sums, every ``exp`` of
 them and the carried state are float32; the matmul operands (``C``, ``B``,
 ``L o C B^T``, ``dt x`` and the state a chunk reads) are in ``x``'s dtype with
-float32 accumulation. No ``exp`` is of a positive argument.
+float32 accumulation, and ``y`` is rounded once. No ``exp`` is of a positive
+argument.
 
-**The chunk-local stage**, everything between the scan's inputs and the
-carry's operands that depends on one chunk only (``Y_diag + D x``, the chunk's
-own state, ``exp`` of the chunk's whole decay and of its running sum), has two
-forms under one contract, chosen from what the call can see
+The scan has two forms under one contract, chosen from what the call can see
 (:func:`_kernels_refuse`), as ``ops/gated_delta.py:kda_rule``'s is:
 
 - On a TPU backend, for bfloat16 ``x``, ``B``, ``C``, a chunk of whole lane
   tiles (a multiple of 128) that divides ``T``, ``P`` a multiple of 16, ``N``
   of 128 and a multiple of 8 heads a group: two Pallas kernels under one
-  ``jax.custom_vjp`` (:func:`_local_kernels`), ``ssd_forward`` and
-  ``ssd_backward``, a grid step a chunk, every head of the chunk in VMEM. They
-  work on the transposed activations, ``[B, channels, T]`` with time along the
-  lanes, which is how ``causal_conv_silu`` hands ``xBC`` over and how XLA keeps
-  ``z`` and ``dt`` (PERF.md section 6, PR 30): a head is ``P`` whole sublanes, a
-  step's decay a lane, and the ``swapaxes`` around the kernels are bitcasts.
-  ``x``, ``B`` and ``C`` are read out of one array, one under another, and
-  their gradients leave as one array of its shape: the mixer hands
-  :func:`ssd_scan` three slices of what its convolution wrote, and XLA passes
-  that array whole (``tests/test_tpu_compile.py`` holds it to that).
+  ``jax.custom_vjp`` (:func:`_scan_kernels`), ``ssd_forward`` and
+  ``ssd_backward``, a grid step a chunk, every head of the chunk in VMEM,
+  **and the carry inside them**: the grid walks a batch row's chunks in order
+  (``_chunk_call``'s ``walk``; last to first in the backward) with every
+  head's float32 state in a VMEM scratch from one grid step to the next, so
+  a chunk's ``Y_diag + D x``, its own state and ``exp`` of its decays never
+  reach HBM. ``ssd_forward`` writes ``y`` (rounded once, in place), the state
+  each chunk inherits (the backward's one residual beside the inputs) and the
+  final state; ``ssd_backward`` reads ``dy`` as it is handed over and makes
+  the tile's intermediates and ``C S_in`` again in VMEM, taking the two ``dM``
+  reductions (row and column sums of ``d mixing o mixing``) on the tile; a
+  block's recomputation runs ``ssd_forward`` again.
+  They work on the transposed activations, ``[B, channels, T]`` with time
+  along the lanes, which is how ``causal_conv_silu`` hands ``xBC`` over, how
+  XLA keeps ``z`` and ``dt`` and how the mixer's gate reads ``y`` (PERF.md
+  section 6, PRs 30 and 64): a head is ``P`` whole sublanes, a step's decay a
+  lane, and the ``swapaxes`` around the kernels are bitcasts. ``x``, ``B``
+  and ``C`` are read out of one array, one under another, and their gradients
+  leave as one array of its shape: the mixer hands :func:`ssd_scan` three
+  slices of what its convolution wrote, and XLA passes that array whole
+  (``tests/test_tpu_compile.py`` holds it to that, and to no copy of ``y`` or
+  ``dy`` between the kernels and the gate).
   The ``[L, L]`` decay matrix of a head lives a ``128 x 128`` block at a time
   in registers, only the blocks on and under the causal line; the group's
   scores ``C B^T`` are made once a group in a VMEM scratch, and the products
-  whose one operand a group's heads share (their own states through ``B``)
-  are one product over the heads' rows. The backward keeps **nothing but the
-  inputs**: it makes the tile's intermediates again in VMEM and takes the two
-  ``dM`` reductions (row and column sums of ``d mixing o mixing``) on the
-  tile; a block's recomputation runs ``ssd_forward`` again.
+  whose one operand a group's heads share (their own states through ``B``,
+  what they read of the inherited states through ``C``, and both ways back)
+  are one product over the heads' rows.
 - Everywhere else (the CPU, float32 operands, a ragged ``T``, other widths),
-  :func:`_local_plain`: plain ``jax.numpy`` with jax's own backward, which is
-  also what the kernels are tested against. ``interpret`` runs the kernels in
-  the Pallas interpreter (tests on the CPU).
+  the chunk-local stage in plain ``jax.numpy`` with jax's own backward
+  (:func:`_local_plain`) and the carry as one ``lax.scan`` with a backward of
+  its own (:func:`_carry_out`: a reverse scan that keeps the state each chunk
+  inherited and nothing else; the loop rounds each chunk's outputs as it
+  passes, and the result is laid out time last behind an
+  ``optimization_barrier``, PERF.md section 6, PR 50). It is also what the
+  kernels are tested against. ``interpret`` runs the kernels in the Pallas
+  interpreter (tests on the CPU).
 
-The carry and the inherited term are plain XLA under both, one ``lax.scan``
-with a backward of its own (:func:`_carry_out`: a reverse scan that keeps the
-state each chunk inherited and nothing else). The loop rounds each chunk's
-outputs as it passes, and the result is laid out for its reader (time last)
-once, in ``x``'s dtype, behind an ``optimization_barrier``: without it XLA
-hoists the gate's float32 conversion over the layout change and makes two
-float32 passes of it (PERF.md section 6, PR 50). Each traced shape leaves one
-``ssm_chunks`` instant: ``chunk``, ``chunks``, ``heads``, ``groups``,
-``d_head``, ``d_state``, ``state_bytes``, ``path`` ``kernel`` / ``plain`` and,
-on ``plain``, ``why`` (the first of ``backend``, ``dtype``, ``chunk``,
-``steps``, ``heads``, ``width``, ``vmem`` that did not hold).
+Each traced shape leaves one ``ssm_chunks`` instant: ``chunk``, ``chunks``,
+``heads``, ``groups``, ``d_head``, ``d_state``, ``state_bytes``, ``path``
+``kernel`` / ``plain``, ``carry`` ``kernel`` / ``loop`` (who carries the state:
+the kernels, or ``_carry_out``'s ``lax.scan``) and, on ``plain``, ``why`` (the
+first of ``backend``, ``dtype``, ``chunk``, ``steps``, ``heads``, ``width``,
+``vmem`` that did not hold).
 """
 
 from __future__ import annotations
@@ -136,7 +144,7 @@ def _local_plain(x, dt, a, b, c, d, size):
     )
 
 
-# -- the chunk-local stage as Pallas kernels ---------------------------------
+# -- the scan as Pallas kernels ----------------------------------------------
 #
 # One grid step holds a chunk of every head in VMEM, time along the lanes:
 # ``xBC`` ``[B, H P + 2 G N, T]`` (a head of ``x`` is ``P`` sublanes, a group's
@@ -145,7 +153,10 @@ def _local_plain(x, dt, a, b, c, d, size):
 # (the step that wrote along the sublanes, the step that reads along the
 # lanes), so ``Y^T = (dt x)^T mixing^T`` and its two transposed products in the
 # backward are plain ones, and a step's decay is a lane of a ``[1, L]`` row; the
-# column ``decay_s`` comes out of the chunk's one ``[L, H]`` transpose.
+# column ``decay_s`` comes out of the chunk's one ``[L, H]`` transpose. The
+# grid is (batch, chunks) with a batch row's chunks one after another
+# (``_chunk_call``'s ``walk``): every head's state ``[H, P, N]`` (float32) stays
+# in a VMEM scratch from one grid step to the next, as ``delta_carry``'s does.
 
 
 def _at(i):
@@ -207,28 +218,78 @@ def _shape_of(xbc_ref, dt_ref, state_ref):
     return heads, p, n, (at_c - at_b) // n, size // _BLOCK, at_b, at_c
 
 
-def ssd_forward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
-                       y_ref, own_ref, whole_ref, grown_ref,
-                       scores_ref, decay_ref, to_end_ref, cols_ref, skip_ref, weighted_ref):
-    """One chunk of every head: ``Y_diag + D x`` (float32, transposed as the
-    inputs are), the chunk's own state, ``exp`` of its whole decay and of the
-    running sum."""
+def _first_chunk(state_ref, from_ref):
+    """At a batch row's first grid step, the walk's state from its operand."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state_ref[...] = from_ref[0]
+
+
+def _last_chunk(to_ref, state_ref):
+    """At a batch row's last grid step, the walk's state to its result."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _():
+        to_ref[0] = state_ref[...]
+
+
+def _carried(state_ref, first, whole_ref, moved):
+    """``S <- exp(whole) S + moved`` for the heads from ``first`` on (a slab's)
+    of the walk's state ``[H, P, N]``, float32: ``moved`` ``[heads P, N]`` one
+    head under another, ``whole_ref`` ``[H, N]`` a head's factor along its
+    row."""
+    from jax.experimental import pallas as pl
+
+    p = state_ref.shape[1]
+    for j in range(moved.shape[0] // p):
+        h = first + j
+        state_ref[h] = whole_ref[pl.ds(h, 1), :] * state_ref[h] + moved[j * p:(j + 1) * p]
+
+
+def ssd_forward_kernel(xbc_ref, dt_ref, a_ref, d_ref, initial_ref,
+                       y_ref, entering_ref, final_ref,
+                       state_ref, scores_ref, decay_ref, to_end_ref, cols_ref, skip_ref,
+                       weighted_ref, inherited_ref, whole_ref):
+    """One chunk of every head, the chunks in order: ``y = Y_diag + D x +
+    exp(cumsum) C S_in`` rounded once to ``x``'s dtype (transposed as the
+    inputs are, at the chunk's lanes), the state the chunk inherits as it is,
+    and ``S <- exp(whole) S + own`` in ``state_ref`` (float32, from the chunk
+    before; the initial state at the first, the final one out at the last),
+    rounded once to the operands' dtype for ``C S_in``."""
     from jax.experimental import pallas as pl
 
     f32, dtype = jnp.float32, xbc_ref.dtype
-    heads, p, n, groups, blocks, at_b, at_c = _shape_of(xbc_ref, dt_ref, own_ref)
+    heads, p, n, groups, blocks, at_b, at_c = _shape_of(xbc_ref, dt_ref, entering_ref)
     r = heads // groups
     width = _HEADS_A_ROUND
+    _first_chunk(state_ref, initial_ref)
     decay, last = _decays_of(
         dt_ref, a_ref, d_ref, decay_ref, to_end_ref, cols_ref, skip_ref
     )
-    grown_ref[0, 0] = jnp.exp(decay)
-    whole_ref[0, 0] = jnp.exp(last)
+    whole_ref[...] = jnp.broadcast_to(jnp.exp(last), whole_ref.shape)
 
     def group(g, carry):
         b_g, c_g = _rows(g, n, at_b), _rows(g, n, at_c)
         # the scores of the group's heads, [s, l]: once a group
         scores_ref[...] = _transposed_times(xbc_ref[0, b_g, :], xbc_ref[0, c_g, :])
+
+        # what the group's heads read of the states they inherit, C S_in: C is
+        # theirs together, so one product streams all their rows through it, a
+        # slab of heads at a time
+        def inherit(i, carry):
+            first = g * r // width + i
+            state = state_ref[pl.ds(first * width, width)]
+            entering_ref[0, 0, pl.ds(first * width, width)] = state
+            inherited_ref[_rows(first, width * p), :] = jnp.dot(
+                state.reshape(width * p, n).astype(dtype), xbc_ref[0, c_g, :],
+                preferred_element_type=f32,
+            )
+            return carry
+
+        carry = jax.lax.fori_loop(0, r // width, inherit, carry)
 
         def head(pair, half, carry):
             h = g * r + width * pair + half
@@ -245,7 +306,8 @@ def ssd_forward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
                     acc = acc + jnp.dot(
                         dtxb[:, _at(s)], mixing.astype(dtype), preferred_element_type=f32
                     )
-                y_ref[0, 0, rows, _at(l)] = acc
+                acc = acc + inherited_ref[rows, _at(l)] * jnp.exp(rows_h[l])
+                y_ref[0, rows, _at(l)] = acc.astype(dtype)
             weighted_ref[rows, :] = (dtx * to_end_ref[one, :]).astype(dtype)
             return carry
 
@@ -256,50 +318,71 @@ def ssd_forward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
         def slab(i, carry):
             first = g * r // width + i
             own = _times_transposed(weighted_ref[_rows(first, width * p), :], xbc_ref[0, b_g, :])
-            own_ref[0, 0, pl.ds(first * width, width)] = own.reshape(width, p, n)
+            _carried(state_ref, first * width, whole_ref, own)
             return carry
 
         return jax.lax.fori_loop(0, r // width, slab, carry)
 
     jax.lax.fori_loop(0, groups, group, 0)
+    _last_chunk(final_ref, state_ref)
 
 
-def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
-                        dy_ref, down_ref, dwhole_ref, dgrown_ref,
-                        dxbc_ref, ddt_ref, da_ref, dd_ref,
-                        scores_ref, decay_ref, to_end_ref, cols_ref, skip_ref,
-                        weighted_ref, dscores_ref, d_weighted_ref, by_row_ref, by_col_ref,
-                        d_end_ref, dt_part_ref, dd_part_ref):
-    """The cotangents of one chunk's inputs from those of the four outputs:
-    the tile's intermediates are made again in VMEM from the inputs, and every
-    step of the way back is local to the tile. ``dA`` and ``dD`` leave as the
-    chunk's own sums ``[H, 1]``."""
+def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref, dy_ref, entering_ref, dfinal_ref,
+                        dxbc_ref, ddt_ref, da_ref, dd_ref, dinitial_ref,
+                        dstate_ref, scores_ref, decay_ref, to_end_ref, cols_ref, skip_ref,
+                        weighted_ref, inherited_ref, whole_ref,
+                        dscores_ref, d_weighted_ref, d_inherited_ref, by_row_ref, by_col_ref,
+                        d_end_ref, dt_part_ref, dd_part_ref, dwhole_ref):
+    """One chunk of the walk back, every head, the chunks last to first: the
+    cotangents of the chunk's inputs from ``dy`` and the cotangent ``dS'`` of
+    the state the chunk left (``dstate_ref``, float32; the final state's at the
+    first step), and ``dS = exp(whole) dS' + (dy o grown) C^T`` of the state it
+    inherited left there (the initial state's out at the last). The tile's
+    intermediates are made again in VMEM from the inputs, ``C S_in`` from the
+    saved state the chunk inherited; ``dS'`` is the own state's cotangent,
+    ``<dS', S_in>`` the whole decay's, ``sum_p(dy o C S_in)`` the running
+    sum's, and the inherited term's ``dC = S_in^T (dy o grown)`` joins the
+    scores'. ``dA`` and ``dD`` leave as the chunk's own sums ``[H, 1]``."""
     from jax.experimental import pallas as pl
 
     f32, dtype = jnp.float32, xbc_ref.dtype
-    heads, p, n, groups, blocks, at_b, at_c = _shape_of(xbc_ref, dt_ref, down_ref)
+    heads, p, n, groups, blocks, at_b, at_c = _shape_of(xbc_ref, dt_ref, entering_ref)
     size, r = dt_ref.shape[2], heads // groups
     width = _HEADS_A_ROUND
+    _first_chunk(dstate_ref, dfinal_ref)
     decay, last = _decays_of(
         dt_ref, a_ref, d_ref, decay_ref, to_end_ref, cols_ref, skip_ref
     )
+    whole_ref[...] = jnp.broadcast_to(jnp.exp(last), whole_ref.shape)
     down = lambda a: jnp.sum(a, axis=0, keepdims=True)      # noqa: E731 — [1, L]
     across = lambda a: jnp.sum(a, axis=1, keepdims=True)    # noqa: E731 — [L, 1]
     add = lambda acc, a: a if acc is None else acc + a      # noqa: E731
+    held = lambda states: states.reshape(r * p, n).astype(dtype)  # noqa: E731 — a group's
 
     def group(g, carry):
         b_g, c_g = _rows(g, n, at_b), _rows(g, n, at_c)
         scores_ref[...] = _transposed_times(xbc_ref[0, b_g, :], xbc_ref[0, c_g, :])
         dscores_ref[...] = jnp.zeros(dscores_ref.shape, f32)
 
-        # through the own states of the group's heads, B theirs together: one
-        # product streams all their rows through it, a slab of heads at a time
+        # through the own states of the group's heads, B theirs together, and
+        # what they read of the states they inherit, C theirs together: one
+        # product each streams all their rows through it, a slab of heads at a
+        # time
         def slab(i, carry):
             first = g * r // width + i
-            d_own = down_ref[0, 0, pl.ds(first * width, width)].reshape(width * p, n)
-            d_weighted_ref[_rows(first, width * p), :] = jnp.dot(
-                d_own.astype(dtype), xbc_ref[0, b_g, :], preferred_element_type=f32
+            rows = _rows(first, width * p)
+            leaving = dstate_ref[pl.ds(first * width, width)]
+            state = entering_ref[0, 0, pl.ds(first * width, width)]
+            d_weighted_ref[rows, :] = jnp.dot(
+                leaving.reshape(width * p, n).astype(dtype), xbc_ref[0, b_g, :],
+                preferred_element_type=f32,
             )
+            inherited_ref[rows, :] = jnp.dot(
+                state.reshape(width * p, n).astype(dtype), xbc_ref[0, c_g, :],
+                preferred_element_type=f32,
+            )
+            for j in range(width):  # <dS', S_in> a head, along its row
+                dwhole_ref[pl.ds(first * width + j, 1), :] = down(leaving[j] * state[j])
             return carry
 
         jax.lax.fori_loop(0, r // width, slab, carry)
@@ -311,8 +394,8 @@ def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
             dt_h, to_end = dt_ref[0, one, :], to_end_ref[one, :]
             dtx = x32 * dt_h
             dtxb = dtx.astype(dtype)
-            dy = dy_ref[0, 0, rows, :]
-            dyb = dy.astype(dtype)
+            dyb = dy_ref[0, rows, :]
+            dy = dyb.astype(f32)
             rows_h, cols_h = _blocks_of(decay_ref, cols_ref, h, blocks)
 
             # through Y^T = (dt x)^T mixing^T, a block of the tile at a time
@@ -338,13 +421,16 @@ def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
             d_end_ref[one, :] = down(d_weighted * dtx) * to_end
             weighted_ref[rows, :] = (dtx * to_end).astype(dtype)
 
+            # through the inherited term, exp(cumsum) o C S_in
+            grown = jnp.exp(jnp.concatenate(rows_h, axis=1))
+            d_inherited_ref[rows, :] = (dy * grown).astype(dtype)
+
             skip = jnp.concatenate([skip_ref[one, :]] * blocks, axis=1)
             dxbc_ref[0, rows, :] = (d_dtx * dt_h + skip * dy).astype(dtype)
             dt_part_ref[one, :] = down(d_dtx * x32)
             dd_part_ref[one, :] = down(dy * x32)
             by_row_ref[one, :] = (
-                jnp.concatenate(by_row, axis=1)
-                + dgrown_ref[0, 0, one, :] * jnp.exp(jnp.concatenate(rows_h, axis=1))
+                jnp.concatenate(by_row, axis=1) + down(dy * inherited_ref[rows, :]) * grown
             )
             by_col = jnp.concatenate(by_col, axis=0)
             by_col_ref[...] = jnp.where(
@@ -354,11 +440,13 @@ def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
 
         carry = _over_heads(r, head, carry, width)
 
-        # the group's B and C: through the scores, and B through the own states
-        # of its heads (one product over the heads' rows)
+        # the group's B and C: through the scores, B through the own states of
+        # its heads and C through what they inherit (one product over the
+        # heads' rows each)
         b, c = xbc_ref[0, b_g, :], xbc_ref[0, c_g, :]
-        d_own = down_ref[0, 0, pl.ds(g * r, r)].reshape(r * p, n).astype(dtype)
-        db = _transposed_times(d_own, weighted_ref[_rows(g, r * p), :])
+        of_g, rows_g = pl.ds(g * r, r), _rows(g, r * p)
+        db = _transposed_times(held(dstate_ref[of_g]), weighted_ref[rows_g, :])
+        dc = _transposed_times(held(entering_ref[0, 0, of_g]), d_inherited_ref[rows_g, :])
         db_s, dc_l = [None] * blocks, [None] * blocks
         for l in range(blocks):
             for s in range(l + 1):
@@ -368,15 +456,24 @@ def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
                 )
                 db_s[s] = add(db_s[s], _times_transposed(c[:, _at(l)], d_scores))
         dxbc_ref[0, b_g, :] = (db + jnp.concatenate(db_s, axis=1)).astype(dtype)
-        dxbc_ref[0, c_g, :] = jnp.concatenate(dc_l, axis=1).astype(dtype)
-        return carry
+        dxbc_ref[0, c_g, :] = (dc + jnp.concatenate(dc_l, axis=1)).astype(dtype)
+
+        # the state's cotangent on its way back, once nothing reads dS' any more
+        def back(i, carry):
+            first = g * r // width + i
+            moved = _times_transposed(d_inherited_ref[_rows(first, width * p), :], c)
+            _carried(dstate_ref, first * width, whole_ref, moved)
+            return carry
+
+        return jax.lax.fori_loop(0, r // width, back, carry)
 
     jax.lax.fori_loop(0, groups, group, 0)
+    _last_chunk(dinitial_ref, dstate_ref)
 
     # every head's decay at once: rows less columns, the chunk's end, and back
     # through the running sum
     d_end = d_end_ref[...]
-    d_last = across(d_end) + dwhole_ref[0, 0] * jnp.exp(last)
+    d_last = across(d_end) + across(dwhole_ref[...]) * jnp.exp(last)
     d_decay = (
         by_row_ref[...] - d_end - by_col_ref[...].T
         + jnp.where(_iota(decay.shape, 1) == size - 1, d_last, 0.0)
@@ -388,87 +485,103 @@ def ssd_backward_kernel(xbc_ref, dt_ref, a_ref, d_ref,
 
 
 _INPUTS = ("packed", "steps", "head", "head")                          # xbc dt a d
-_OUTPUTS = ("local", "state", "whole", "grown")                        # y own whole grown
 
 
-def _kinds(xbc, dt, size, p, n):
+def _kinds(xbc, dt, size, p, n, back=False):
     """The kernels' kinds of operand (``ops/gated_delta.py:_chunk_call``): a
-    kind's shape, dtype, block and the block's place at batch ``b``, chunk
-    ``c``; and the scratch both kernels share."""
+    kind's shape, dtype, block and the block's place at batch ``b``, grid step
+    ``c`` (the chunks first to last or, ``back``, last to first); and the
+    scratch both kernels share, the walk's state first."""
     batch, rows, steps = xbc.shape
     h, nc = dt.shape[1], steps // size
     f32, dtype = jnp.float32, xbc.dtype
-    lanes = lambda b, c: (b, 0, c)          # noqa: E731 — a chunk's steps of [B, ., T]
-    first = lambda b, c: (c, b)             # noqa: E731 — the carry's: chunks first
+    at = (lambda c: nc - 1 - c) if back else (lambda c: c)  # noqa: E731
+    lanes = lambda b, c: (b, 0, at(c))      # noqa: E731 — a chunk's steps of [B, ., T]
+    first = lambda b, c: (at(c), b)         # noqa: E731 — chunks first
     kinds = dict(
         # every row of xBC (x, B, C one under another), as the convolution left it
         packed=((batch, rows, steps), dtype, (1, rows, size), lanes),
         steps=((batch, h, steps), f32, (1, h, size), lanes),
         head=((h, 1), f32, (h, 1), lambda b, c: (0, 0)),
-        # the carry's operands, chunks first
-        local=((nc, batch, h * p, size), f32, (1, 1, h * p, size), first),
-        grown=((nc, batch, h, size), f32, (1, 1, h, size), first),
-        state=((nc, batch, h, p, n), f32, (1, 1, h, p, n), first),
-        whole=((nc, batch, h, 1), f32, (1, 1, h, 1), first),
+        # y and its cotangent, as x lies in xBC
+        local=((batch, h * p, steps), dtype, (1, h * p, size), lanes),
+        # the states: the ones the chunks inherit, a batch row's one
+        entering=((nc, batch, h, p, n), f32, (1, 1, h, p, n), first),
+        state=((batch, h, p, n), f32, (1, h, p, n), lambda b, c: (b,)),
+        # a chunk's own sums a head (dA's and dD's parts)
+        sums=((nc, batch, h, 1), f32, (1, 1, h, 1), first),
     )
-    # the group's scores, every head's decay, exp(decay_L - decay), decay^T, D,
-    # dt x decayed to the chunk's end
-    scratch = (((size, size), f32), ((size // _BLOCK, h, _BLOCK), f32), ((h, size), f32),
-               ((size, h), f32), ((h, _BLOCK), f32), ((h * p, size), dtype))
+    # the walk's state, the group's scores, every head's decay, exp(decay_L -
+    # decay), decay^T, D, dt x decayed to the chunk's end, C S_in, exp of the
+    # whole decay along a state's rows
+    scratch = (((h, p, n), f32), ((size, size), f32), ((size // _BLOCK, h, _BLOCK), f32),
+               ((h, size), f32), ((size, h), f32), ((h, _BLOCK), f32), ((h * p, size), dtype),
+               ((h * p, size), f32), ((h, n), f32))
     return kinds, scratch
 
 
 # jitted, as ``ops/causal_conv.py``'s: a step traces and lowers each body once
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _forward_call(xbc, dt, a, d, size, p, n, interpret):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _forward_call(xbc, dt, a, d, initial, size, p, n, interpret):
+    """``(y, the states the chunks inherit, the final state)``."""
     kinds, scratch = _kinds(xbc, dt, size, p, n)
     grid = (xbc.shape[0], xbc.shape[2] // size)
     return _chunk_call(
-        ssd_forward_kernel, grid, kinds, _INPUTS, _OUTPUTS, (xbc, dt, a, d),
-        interpret, scratch,
+        ssd_forward_kernel, grid, kinds, (*_INPUTS, "state"), ("local", "entering", "state"),
+        (xbc, dt, a, d, initial), interpret, scratch, walk=True,
     )
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
-def _backward_call(xbc, dt, a, d, dy, d_own, d_whole, d_grown, size, p, n, interpret):
-    kinds, scratch = _kinds(xbc, dt, size, p, n)
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _backward_call(xbc, dt, a, d, dy, entering, d_final, size, p, n, interpret):
+    """The cotangents of ``(xbc, dt, a, d, initial)``, ``a``'s and ``d``'s a
+    chunk's own sums."""
+    kinds, scratch = _kinds(xbc, dt, size, p, n, back=True)
     grid = (xbc.shape[0], xbc.shape[2] // size)
     f32, h = jnp.float32, dt.shape[1]
     by_head = ((h, size), f32)
+    # d scores, d (dt x decayed to the end), dy o grown, the two dM reductions,
+    # the decay's gradient at the end, dt's and D's parts, <dS', S_in>
     scratch = (
-        *scratch, ((size, size), f32), ((h * p, size), f32), by_head, ((size, h), f32),
-        by_head, by_head, by_head,
+        *scratch, ((size, size), f32), ((h * p, size), f32), ((h * p, size), xbc.dtype),
+        by_head, ((size, h), f32), by_head, by_head, by_head, ((h, n), f32),
     )
-    outs = ("packed", "steps", "whole", "whole")  # dxbc ddt da dd
     return _chunk_call(
-        ssd_backward_kernel, grid, kinds, (*_INPUTS, *_OUTPUTS), outs,
-        (xbc, dt, a, d, dy, d_own, d_whole, d_grown), interpret, scratch,
+        ssd_backward_kernel, grid, kinds, (*_INPUTS, "local", "entering", "state"),
+        ("packed", "steps", "sums", "sums", "state"),
+        (xbc, dt, a, d, dy, entering, d_final), interpret, scratch, walk=True,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _local_kernels(xbc, dt, a, d, size, p, n, interpret):
-    """The chunk-local stage by the kernels, time along the lanes: ``xbc``
-    ``[B, H P + 2 G N, T]`` (``x``, ``B`` and ``C`` one under another, as the
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _scan_kernels(xbc, dt, a, d, initial, size, p, n, interpret):
+    """The whole scan by the two kernels, time along the lanes: ``xbc`` ``[B,
+    H P + 2 G N, T]`` (``x``, ``B`` and ``C`` one under another, as the
     convolution leaves them: nothing is sliced out), ``dt`` ``[B, H, T]``
-    (float32), ``a``, ``d`` ``[H, 1]`` (float32); returns, chunks first,
-    ``Y_diag + D x`` ``[n, B, H P, L]``, the own states ``[n, B, H, P, N]``,
-    ``exp`` of the chunks' whole decays ``[n, B, H, 1]`` and of the running sum
-    ``[n, B, H, L]``, float32."""
-    return _forward_call(xbc, dt, a, d, size, p, n, interpret)
+    (float32), ``a``, ``d`` ``[H, 1]`` (float32), ``initial`` ``[B, H, P, N]``
+    (float32); returns ``y`` ``[B, H P, T]`` in ``xbc``'s dtype and the final
+    state, float32."""
+    y, _, final = _forward_call(xbc, dt, a, d, initial, size, p, n, interpret)
+    return y, final
 
 
-def _local_kernels_fwd(xbc, dt, a, d, size, p, n, interpret):
-    # nothing but the inputs: the backward makes the tile again
-    return _forward_call(xbc, dt, a, d, size, p, n, interpret), (xbc, dt, a, d)
+def _scan_kernels_fwd(xbc, dt, a, d, initial, size, p, n, interpret):
+    # the inputs and the states the chunks inherit: the backward makes the
+    # tile again
+    y, entering, final = _forward_call(xbc, dt, a, d, initial, size, p, n, interpret)
+    return (y, final), (xbc, dt, a, d, entering)
 
 
-def _local_kernels_bwd(size, p, n, interpret, inputs, cotangents):
-    dxbc, ddt, da, dd = _backward_call(*inputs, *cotangents, size, p, n, interpret)
-    return dxbc, ddt, da.sum((0, 1)), dd.sum((0, 1))
+def _scan_kernels_bwd(size, p, n, interpret, residuals, cotangents):
+    *inputs, entering = residuals
+    dy, d_final = cotangents
+    dxbc, ddt, da, dd, d_initial = _backward_call(
+        *inputs, dy, entering, d_final, size, p, n, interpret
+    )
+    return dxbc, ddt, da.sum((0, 1)), dd.sum((0, 1)), d_initial
 
 
-_local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
 # -- from chunk to chunk -----------------------------------------------------
@@ -553,15 +666,19 @@ _carry_out.defvjp(_carry_out_fwd, _carry_out_bwd)
 
 
 def _kernels_refuse(dtype, h, p, g, n, steps, chunk, interpret):
-    """Why the chunk-local stage of these operands is not the kernels', or
-    None where it is: the first of a TPU backend or the interpreter
+    """Why the scan of these operands is not the kernels', or None where it
+    is: the first of a TPU backend or the interpreter
     (``backend``), bfloat16 operands (``dtype``), a chunk of whole lane tiles
     (``chunk``) that divides the length (``steps``), a group's heads in eights
     (``heads``), a head of whole bfloat16 sublane tiles over a state of whole
     lane tiles (``width``) and blocks a core's VMEM holds (``vmem``) that does
     not hold."""
-    # x, Y and dY or dx of a chunk, twice each, and the own states
-    blocks = 2 * chunk * h * p * (2 + 4 + 4) + 4 * 4 * h * p * n
+    # the backward's, the larger. Of a chunk's [H P, L]: x, dY and dx in
+    # ``dtype``, twice each (the pipeline's two buffers), and four scratch
+    # arrays, two float32 and two in ``dtype``. Of a state's [H, P, N] float32:
+    # the one the chunk inherits, the final one's cotangent and the initial
+    # one's, twice each, and the walk's own scratch
+    blocks = chunk * h * p * (2 * 3 * 2 + 2 * 4 + 2 * 2) + 4 * h * p * n * (2 * 3 + 1)
     conditions = (
         ("backend", interpret or jax.default_backend() == "tpu"),
         ("dtype", dtype == jnp.bfloat16),
@@ -604,47 +721,42 @@ def ssd_scan(x, dt, a, b, c, d=None, *, chunk: int = 256, initial_state=None,
     steps = t + pad
     nc = steps // size
     f32, dtype = jnp.float32, x.dtype
-    # once a shape and stage: which form the chunk-local stage took, and why
-    # where it is the plain one
+    # once a shape and stage: which form the scan took, who carries the state,
+    # and why where it is the plain one
     note = functools.partial(
         obs_trace.get_tracer().note_once, "ssm_chunks", chunk=size, chunks=nc,
         heads=h, groups=g, d_head=p, d_state=n, state_bytes=4 * h * p * n,
     )
     dt, a = dt.astype(f32), a.astype(f32)
+    if initial_state is None:
+        state = jnp.zeros((batch, h, p, n), f32)
+    else:
+        state = initial_state.astype(f32)
     if why_plain is None:
-        note(path="kernel")
+        note(path="kernel", carry="kernel")
         # x, B and C one under another, time last. The mixer hands over three
         # slices of the one array its convolution wrote, and XLA passes that
         # array whole: no slice of it and no concatenation is made (nor of the
         # gradient, which leaves as one array of its shape)
         xbc = jnp.concatenate([v.reshape(batch, steps, -1) for v in (x, b, c)], axis=-1)
-        # the carry's C out of the same array: its gradient then joins the
-        # kernels' in one pass over ``d xbc`` (read off ``c`` itself, XLA makes
-        # a slice of 4224 channels and a sum of its own)
-        c = xbc[..., h * p + g * n:].reshape(c.shape)
         skip = jnp.zeros((h,), f32) if d is None else d.astype(f32)
-        y, own, whole, grown = _local_kernels(
+        y, state = _scan_kernels(
             xbc.swapaxes(1, 2), dt.swapaxes(1, 2), a.reshape(h, 1), skip.reshape(h, 1),
-            size, p, n, interpret,
+            state, size, p, n, interpret,
         )
-        whole = whole[..., 0]
     else:
-        note(path="plain", why=why_plain)
+        note(path="plain", why=why_plain, carry="loop")
         y, own, whole, grown = _local_plain(
             x, dt, a, b, c, None if d is None else d.astype(f32), size
         )
-
-    # from chunk to chunk, the state in float32, and every chunk's outputs
-    if initial_state is None:
-        state = jnp.zeros((batch, g, r, p, n), f32)
-    else:
-        state = initial_state.astype(f32).reshape(batch, g, r, p, n)
-    # a chunk's C with its steps last, as the kernels take it
-    c = jnp.transpose(c.reshape(batch, nc, size, g, n), (1, 0, 3, 4, 2))
-    y, state = _carry_out(
-        state, whole.reshape(nc, batch, g, r), own.reshape(nc, batch, g, r, p, n),
-        y.reshape(nc, batch, g, r, p, size), grown.reshape(nc, batch, g, r, size), c, dtype,
-    )
+        # from chunk to chunk, the state in float32, and every chunk's outputs;
+        # a chunk's C with its steps last, as its outputs are
+        c = jnp.transpose(c.reshape(batch, nc, size, g, n), (1, 0, 3, 4, 2))
+        y, state = _carry_out(
+            state.reshape(batch, g, r, p, n), whole.reshape(nc, batch, g, r),
+            own.reshape(nc, batch, g, r, p, n), y.reshape(nc, batch, g, r, p, size),
+            grown.reshape(nc, batch, g, r, size), c, dtype,
+        )
     y = y.reshape(batch, h * p, steps).swapaxes(1, 2)[:, :t].reshape(batch, t, h, p)
     if return_final_state:
         return y, state.reshape(batch, h, p, n)

@@ -1,21 +1,27 @@
-"""``ops/ssd.py``'s chunk-local stage as Pallas kernels, in the interpreter on
-the CPU: value and the six gradients of the kernel form against the plain form
-and against the float32 sequential recurrence, at the two cells' head shape (64
-wide over a state of 128) and their chunks and groups (256 in one group, 128 in
-four); what the contract refuses takes the plain form and says why; a wrong
-program is caught at the benchmark's own limit. Mosaic's tiling is not checked
-here: ``tests/test_tpu_compile.py`` compiles the kernels for a described v5e.
+"""``ops/ssd.py``'s scan as two Pallas kernels that carry the state themselves,
+in the interpreter on the CPU: value, final state and the gradients (the six
+inputs' and the initial state's) of the kernel form against the plain form
+(``_local_plain`` and ``_carry_out``'s ``lax.scan``) and against the float32
+sequential recurrence, at the two cells' head shape (64 wide over a state of
+128), their chunks and groups (256 in one group, 128 in four) and the two
+crossed; the state between chunks is float32 (a rounded one is caught at the
+benchmark's own limit); what the contract refuses takes the plain form, whose
+carry is the loop, and says why; a wrong program is caught at the benchmark's
+own limit. Mosaic's tiling is not checked here: ``tests/test_tpu_compile.py``
+compiles the kernels for a described v5e.
 """
 
 import functools
 import importlib
+import inspect
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.families.ssm_lm import SCAN_REL_TOL
+from benchmark.families import ssm_lm
+from benchmark.families.ssm_lm import SCAN_REL_TOL, STATE_RMS_TOL, STATE_STEPS
 from benchmark.reference import ssm_lm as reference
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops import ssd_scan
@@ -48,11 +54,11 @@ def scan_inputs(chunk, groups, seed=0, heads=None, width=WIDTH, state=STATE, ste
 
 
 @functools.lru_cache(maxsize=None)
-def value_and_grads(chunk, groups, form, with_state):
+def value_and_grads(chunk, groups, form, with_state, chunks=4):
     """``(y, final state or None, the gradients)`` of ``form``: the kernels in
     the interpreter, the plain form, or the float32 recurrence. The gradients
     are the six inputs' and, ``with_state``, the initial state's."""
-    args, w, state0 = scan_inputs(chunk, groups)
+    args, w, state0 = scan_inputs(chunk, groups, steps=chunks * chunk)
     f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
 
     def fn(*a):
@@ -127,6 +133,77 @@ def test_the_initial_states_gradient_is_the_plain_forms_and_the_recurrences(chun
     assert rel(plain, want) <= SCAN_REL_TOL
 
 
+CROSSED = [(128, 1), (256, 4)]          # (chunk, groups): each cell's chunk in the other's groups
+WHAT = ("y", "final", *NAMES, "initial")
+
+
+@pytest.mark.parametrize("chunk,groups,with_state,what", [
+    pytest.param(chunk, groups, with_state, what, id="chunk%d_%dgroups-%s-%s" % (
+        chunk, groups, "state_in_and_out" if with_state else "from_zeros", what))
+    for chunk, groups in CROSSED for with_state in (False, True) for what in WHAT
+    if with_state or what not in ("final", "initial")   # no state goes in or comes out
+])
+def test_the_fused_pair_is_the_plain_stage_and_its_loop_over_three_chunks(
+        chunk, groups, with_state, what):
+    """The two kernels with the carry inside against ``_local_plain`` and
+    ``_carry_out`` (through ``ssd_scan``, which chooses between them), three
+    chunks: ``y``, the final state, each of the six gradients and the initial
+    state's, which only a scan that was handed a state has."""
+    got_y, got_state, got = value_and_grads(chunk, groups, "kernel", with_state, 3)
+    want_y, want_state, want = value_and_grads(chunk, groups, "plain", with_state, 3)
+    if what == "y":
+        assert got_y.dtype == jnp.bfloat16 and rel(got_y, want_y) <= 0.004
+    elif what == "final":
+        assert got_state.dtype == jnp.float32 and rel(got_state, want_state) <= 0.004
+    else:
+        a, b = got[WHAT.index(what) - 2], want[WHAT.index(what) - 2]
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert rel(a, b) <= 0.01
+
+
+def _rounding_its_state(kernel, name):
+    """``kernel`` with the walk's state (the scratch its signature calls
+    ``name``) rounded to bfloat16 after every chunk: the wrong program, a
+    state kept from chunk to chunk in the operands' dtype."""
+    at = list(inspect.signature(kernel).parameters).index(name)
+
+    @functools.wraps(kernel)
+    def rounded(*refs):
+        kernel(*refs)
+        refs[at][...] = refs[at][...].astype(jnp.bfloat16).astype(jnp.float32)
+
+    return rounded
+
+
+@pytest.mark.parametrize("state", ["float32", "bfloat16"])
+def test_the_state_between_chunks_is_float32(state, monkeypatch):
+    """The state after the last step in the benchmark's long-memory regime
+    (``STATE_STEPS``: a head's memory spans every chunk), sixteen chunks,
+    against the float32 recurrence at the benchmark's own limit: the kernels'
+    float32 scratch is well inside it, and the same kernel with the scratch
+    rounded to bfloat16 a chunk is outside, as the benchmark's wrong program
+    (``benchmark/tests/test_ssm_lm.py``) is on the plain path."""
+    config = dict(mamba_n_heads=8, mamba_d_head=16, mamba_n_groups=1, mamba_d_state=128)
+    args, _ = ssm_lm.scan_inputs(config, 7, 2048, STATE_STEPS)
+    args = [args[k] for k in ssm_lm.SCAN_ARGS]
+    _, want = reference.recurrence(*(v.astype(jnp.float32) for v in args))
+    if state == "bfloat16":
+        monkeypatch.setattr(
+            S, "ssd_forward_kernel", _rounding_its_state(S.ssd_forward_kernel, "state_ref")
+        )
+    S._forward_call.clear_cache()
+    try:
+        _, got = ssd_scan(*args, chunk=128, return_final_state=True, interpret=True)
+    finally:
+        S._forward_call.clear_cache()
+    assert got.dtype == jnp.float32
+    err = ssm_lm._rms_rel(got, want)
+    if state == "float32":
+        assert err <= STATE_RMS_TOL / 2
+    else:
+        assert err > STATE_RMS_TOL
+
+
 def test_a_scan_without_a_skip_has_the_kernels_too():
     args, _, _ = scan_inputs(128, 2, seed=3, width=16)
     got = ssd_scan(*args[:5], chunk=128, interpret=True)
@@ -182,20 +259,26 @@ def test_which_form_runs_is_decided_from_the_operands_and_says_so(why, path, mon
     assert (note["d_head"], note["d_state"]) == (kw["width"], kw["state"])
     assert note["state_bytes"] == 4 * kw["heads"] * kw["width"] * kw["state"]
     if path == "kernel":
-        assert note["path"] == "kernel" and "why" not in note
+        assert (note["path"], note["carry"]) == ("kernel", "kernel") and "why" not in note
     else:
-        assert (note["path"], note["why"]) == ("plain", path)
+        assert (note["path"], note["why"], note["carry"]) == ("plain", path, "loop")
     assert ("ssd_forward" in lowered.as_text(debug_info=True)) == (path == "kernel")
 
 
 def test_the_backward_keeps_nothing_but_the_inputs():
-    """What the kernels' ``custom_vjp`` saves for its backward are its four
-    operands: no ``[L, L]`` tile, no state, no second copy of ``xBC``."""
-    (x, dt, a, b, c, d), _, _ = scan_inputs(128, 2, width=16)
+    """What the kernels' ``custom_vjp`` saves for its backward are its first
+    four operands and the state every chunk inherited (the initial one its
+    first): no ``[L, L]`` tile, no ``Y_diag``, no second copy of ``xBC``."""
+    (x, dt, a, b, c, d), _, state0 = scan_inputs(128, 2, width=16)
     xbc = jnp.concatenate([v.reshape(1, v.shape[1], -1) for v in (x, b, c)], axis=-1)
     local = (xbc.swapaxes(1, 2), dt.swapaxes(1, 2), a.reshape(-1, 1), d.reshape(-1, 1))
-    _, saved = S._local_kernels_fwd(*local, 128, 16, 128, True)
-    assert len(saved) == 4 and all(s is v for s, v in zip(saved, local))
+    (y, final), saved = S._scan_kernels_fwd(*local, state0, 128, 16, 128, True)
+    assert len(saved) == 5 and all(s is v for s, v in zip(saved, local))
+    entering = saved[4]
+    assert entering.shape == (4, *state0.shape) and entering.dtype == jnp.float32
+    assert (y.shape, y.dtype) == ((1, 16 * 16, 512), jnp.bfloat16)
+    np.testing.assert_array_equal(entering[0], state0)
+    assert (final.shape, final.dtype) == (state0.shape, jnp.float32)
 
 
 def test_heads_that_the_groups_do_not_divide_are_refused():

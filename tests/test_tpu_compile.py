@@ -287,6 +287,27 @@ def _square_tiles(compiled, chunk):
     return found
 
 
+def _between_the_scans_kernels(compiled, elements=8192 * 64 * 64):
+    """What a compiled ``ssd_scan`` (value and gradients) holds outside its
+    Pallas calls that the carry inside them took away: ``(the count of its
+    while loops, the float32 arrays of y's size that XLA itself writes)``.
+    ``Y_diag + D x`` and ``dy`` were ``[n, B, H P, L]`` and ``[B, .., H P, T]``
+    float32 between the kernels and the loops."""
+    from edl_tpu.obs import profile as obs_profile
+
+    text, wide = compiled.as_text(), []
+    for line in text[text.index("ENTRY"):].splitlines():  # what a fusion holds inside is no array
+        if " = " not in line or "tpu_custom_call" in line:
+            continue
+        rest = line.split(" = ", 1)[1]
+        if re.search(r"[\])}] (get-tuple-element|bitcast|tuple)\(", rest):
+            continue        # a kernel's own result (the states the chunks inherit), handed on
+        shape = re.match(r"\(?f32\[([0-9,]*)\]", rest)
+        if shape and math.prod(int(d) for d in shape.group(1).split(",") if d) >= elements:
+            wide.append(line.strip()[:120])
+    return obs_profile.HloProgram(text).census()["totals"]["loops"], wide
+
+
 @pytest.mark.parametrize("path", ["plain", "kernels"])
 def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip, path):
     """The chunked scan at one sequence of 8192, 64 heads of 64 over a state of
@@ -294,17 +315,23 @@ def test_ssd_scan_compiles_for_v5e_at_granites_widths(one_chip, path):
     the kernels refuse), what the chip's compiler can refuse is the memory: the
     float32 decay matrices of one pass are 537 MB; value and gradients together
     must stay a small part of the 16 GB the step shares. As the chip lowers it
-    the chunk-local stage is ``ssd_forward`` and ``ssd_backward``, each once,
-    and no ``[256, 256]`` array exists outside them."""
+    the whole scan is ``ssd_forward`` and ``ssd_backward``, each once, the state
+    carried inside them: no ``[256, 256]`` array exists outside them, **no
+    ``while``** and no float32 array of ``y``'s size (the plain form has all
+    three), and the temporaries are the states the chunks inherit, ``y`` and
+    the gradients."""
     kernels, compiled = _ssd_value_and_grads(one_chip, *SSD_CELLS["granite"], path)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == len(kernels)
+    loops, wide = _between_the_scans_kernels(compiled)
     if path == "kernels":
         assert kernels == ["ssd_backward", "ssd_forward"] and temp < 1.5e9
         assert _square_tiles(compiled, 256) == []
+        assert (loops, wide) == (0, [])
     else:
         assert kernels == [] and temp < 4e9
         assert _square_tiles(compiled, 256)
+        assert loops == 2 and wide
 
 
 def test_causal_conv_kernels_compile_for_v5e_at_granites_widths(one_chip):
@@ -722,8 +749,9 @@ def test_a_hybrid_step_on_the_tpu_path_runs_the_scan_kernels_under_ssm_scan(one_
     names all of them under ``ssm_scan`` (where ``ssm_scan_ms`` and
     ``step_kernel_calls`` find them), no matmul is left unplaced, the one
     ``ssm_chunks`` instant reads ``path="kernel"`` (so ``step_plain_fallbacks``
-    counts none for it), and no float32 ``[128, 128]`` array exists outside
-    the kernels."""
+    counts none for it) and ``carry="kernel"``, the step holds no ``while``
+    (``step_loops``), and no float32 ``[128, 128]`` array exists outside the
+    kernels."""
     from unittest import mock
 
     import numpy as np
@@ -761,7 +789,7 @@ def test_a_hybrid_step_on_the_tpu_path_runs_the_scan_kernels_under_ssm_scan(one_
             described(state), described((tokens, tokens))
         )
     noted = [args for name, args in tracer.notes() if name == "ssm_chunks"]
-    assert [dict(n)["path"] for n in noted] == ["kernel"]
+    assert [(dict(n)["path"], dict(n)["carry"]) for n in noted] == [("kernel", "kernel")]
     assert {"ssd_forward", "ssd_backward"} <= set(_kernel_names(lowered.as_text()))
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -774,6 +802,7 @@ def test_a_hybrid_step_on_the_tpu_path_runs_the_scan_kernels_under_ssm_scan(one_
     assert census["kernels"]["ssd_forward/forward"] == len(layers)
     assert census["kernels"]["ssd_forward/backward"] == len(layers)
     assert census["kernels"]["ssd_backward/backward"] == len(layers)
+    assert census["totals"]["loops"] == 0       # the carry is the kernels' (three a layer before)
     assert _square_tiles(compiled, 128) == []
 
 
@@ -1261,12 +1290,13 @@ def test_the_delta_rules_walk_compiles_for_v5e_at_the_cells_shape(one_chip, kern
 
 
 def test_a_chunk_call_that_is_no_walk_says_of_its_grid_what_it_said_before(one_chip):
-    """``ops/ssd.py``'s two calls and the delta rules' chunk-local ones go
-    through ``_chunk_call`` without ``walk``: both grid dimensions parallel, as
-    before the walk was there, whose own chunks are ``"arbitrary"`` (the
-    semantics lie in a call's Mosaic bytecode, so they are read where they are
-    handed over; ``benchmark/tools/lowered_step.py`` hashes Granite's,
-    Nemotron's and Mistral's whole steps against the parent's: PERF.md, PR 62)."""
+    """The delta rules' chunk-local calls go through ``_chunk_call`` without
+    ``walk``: both grid dimensions parallel, as before the walk was there,
+    whose own chunks are ``"arbitrary"``, as are ``ops/ssd.py``'s two since
+    they carry the state themselves (PR 64) (the semantics lie in a call's
+    Mosaic bytecode, so they are read where they are handed over;
+    ``benchmark/tools/lowered_step.py`` hashes whole steps against the
+    parent's: PERF.md, PR 62)."""
     from unittest import mock
 
     from jax.experimental.pallas import tpu as pltpu
@@ -1283,7 +1313,8 @@ def test_a_chunk_call_that_is_no_walk_says_of_its_grid_what_it_said_before(one_c
     rows, decays, beta = sds((1, t, 256)), sds((1, t, 256), f32), sds((1, t, 2), f32)
     by_head = lambda d, dtype=jnp.bfloat16: sds((t // 64, 1, 2, 64, d), dtype)  # noqa: E731
     calls = {
-        "ssd_forward": (lambda *a: S._forward_call(*a, 256, p, n, False), (xbc, dt, head, head)),
+        "ssd_forward": (lambda *a: S._forward_call(*a, 256, p, n, False),
+                        (xbc, dt, head, head, sds((1, h, p, n), f32))),
         "kda_inverse": (lambda *a: G._inverse_call(*a, False), (rows, decays, beta)),
         "gdn_inverse": (lambda *a: G._scalar_inverse_call(*a, False),
                         (sds((1, 256, t)), sds((1, 2, t), f32), sds((1, 2, t), f32))),
@@ -1299,8 +1330,8 @@ def test_a_chunk_call_that_is_no_walk_says_of_its_grid_what_it_said_before(one_c
             jax.jit(call).lower(*args)
         (made,) = params.call_args_list
         said[name] = made.kwargs["dimension_semantics"]
-    walk = said.pop("delta_carry")
-    assert walk == ("parallel", "arbitrary")
+    walks = {said.pop("delta_carry"), said.pop("ssd_forward")}
+    assert walks == {("parallel", "arbitrary")}
     assert set(said.values()) == {("parallel", "parallel")}
 
 
@@ -1397,28 +1428,34 @@ def test_ssd_scan_compiles_for_v5e_in_four_groups_at_a_chunk_of_128(one_chip, pa
     16: the first cell with more than one group). Plain XLA, what the chip's
     compiler can refuse is the memory: at half Granite's chunk the float32
     decay matrices of one pass are 268 MB. As the chip lowers it, the two
-    kernels and no ``[128, 128]`` array outside them."""
+    kernels, each once, and outside them no ``[128, 128]`` array, no ``while``
+    and no float32 array of ``y``'s size."""
     kernels, compiled = _ssd_value_and_grads(one_chip, *SSD_CELLS["nemotron"], path)
     temp = compiled.memory_analysis().temp_size_in_bytes
+    loops, wide = _between_the_scans_kernels(compiled)
     if path == "kernels":
         assert kernels == ["ssd_backward", "ssd_forward"] and temp < 1.5e9
         assert _square_tiles(compiled, 128) == []
+        assert (loops, wide) == (0, [])
     else:
         assert kernels == [] and temp < 3e9
+        assert loops == 2
 
 
 @pytest.mark.parametrize("kernel", ["ssd_forward", "ssd_backward"])
 @pytest.mark.parametrize("cell", list(SSD_CELLS))
 def test_ssd_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, cell, kernel):
-    """The two kernels of the scan's chunk-local stage at one sequence of 8192
-    and 64 heads of 64 over a state of 128, a chunk of every head a grid step,
-    time along the lanes (blocks of ``[64 * 64 + 2 * groups * 128, chunk]``
-    columns of the transposed ``xBC``, a head's 64 sublanes and a group's 128
-    taken by dynamic slices inside the body's loops, a head's decay a ``[1,
-    128]`` row of a scratch a block of lanes apart, the own states of a slab of
-    heads by one product): the
-    tiling, the slices, the transposed products and the VMEM limit set from
-    the shapes are what the chip's compiler can refuse."""
+    """The scan's two kernels at one sequence of 8192 and 64 heads of 64 over a
+    state of 128, a chunk of every head a grid step, the chunks in order (last
+    to first in the backward) with every head's float32 state in a VMEM scratch
+    from one to the next, time along the lanes (blocks of ``[64 * 64 + 2 *
+    groups * 128, chunk]`` columns of the transposed ``xBC``, a head's 64
+    sublanes and a group's 128 taken by dynamic slices inside the body's
+    loops, a head's decay a ``[1, 128]`` row of a scratch a block of lanes
+    apart, the own states of a slab of heads and what they read of the states
+    they inherit by one product each, ``y`` and ``dy`` bfloat16 blocks of ``[B,
+    H P, T]``): the tiling, the slices, the transposed products and the VMEM
+    limit set from the shapes are what the chip's compiler can refuse."""
     S = importlib.import_module("edl_tpu.ops.ssd")
 
     def sds(dims, dtype=jnp.bfloat16):
@@ -1427,20 +1464,20 @@ def test_ssd_chunk_local_kernels_compile_for_v5e_at_the_cells_shape(one_chip, ce
     chunk, groups = SSD_CELLS[cell]
     t, h, p, n, f32 = 8192, 64, 64, 128, jnp.float32
     nc = t // chunk
+    state = sds((1, h, p, n), f32)
     inputs = (sds((1, h * p + 2 * groups * n, t)), sds((1, h, t), f32), sds((h, 1), f32),
               sds((h, 1), f32))
-    outputs = (sds((nc, 1, h * p, chunk), f32), sds((nc, 1, h, p, n), f32),
-               sds((nc, 1, h, 1), f32), sds((nc, 1, h, chunk), f32))
-    call, args = {
-        "ssd_forward": (lambda *a: S._forward_call(*a, chunk, p, n, False), inputs),
+    y, entering, sums = sds((1, h * p, t)), sds((nc, 1, h, p, n), f32), sds((nc, 1, h, 1), f32)
+    call, args, want = {
+        "ssd_forward": (lambda *a: S._forward_call(*a, chunk, p, n, False), (*inputs, state),
+                        (y, entering, state)),
         "ssd_backward": (lambda *a: S._backward_call(*a, chunk, p, n, False),
-                         (*inputs, *outputs)),
+                         (*inputs, y, entering, state),
+                         (inputs[0], inputs[1], sums, sums, state)),
     }[kernel]
     compiled = jax.jit(call).lower(*args).compile()
     assert kernel in compiled.as_text()
     out = jax.eval_shape(call, *args)
-    want = outputs if kernel == "ssd_forward" else (
-        inputs[0], inputs[1], outputs[2], outputs[2])
     assert [(a.shape, a.dtype) for a in out] == [(a.shape, a.dtype) for a in want]
 
 
@@ -1470,7 +1507,7 @@ def _large_under(text, scope, elements):
 
 
 @pytest.mark.parametrize("cell", list(SSD_CELLS))
-def test_a_mixer_at_the_cells_widths_passes_xbc_whole_and_moves_y_once_in_bfloat16(
+def test_a_mixer_at_the_cells_widths_passes_xbc_whole_and_moves_y_not_at_all(
         one_chip, cell):
     """One ``Mamba2Mixer`` at a cell's widths (8192 steps, 64 heads of 64 over a
     state of 128), value and gradients under ``jax.checkpoint`` as a block's
@@ -1481,10 +1518,12 @@ def test_a_mixer_at_the_cells_widths_passes_xbc_whole_and_moves_y_once_in_bfloat
     ``ssd_scan``'s laying them side by side cancel, so under ``ssm_scan`` no
     array of ``x``'s size is sliced, fused or concatenated, and the backward
     kernel writes one gradient of ``xBC``'s whole shape; **between the scan and
-    the gate** ``y`` is laid out for its reader by one bfloat16 copy a forward
-    pass (the ``optimization_barrier`` in ``_carry_out_fwd``; without it XLA
-    converts first and copies and retiles ``[8192, 4096]`` float32), and the
-    gate makes no copy of its own."""
+    the gate** nothing moves at all since PR 64: ``ssd_forward`` writes ``y``
+    in bfloat16 with time along the lanes, which is how the gate reads it, and
+    the gate's backward writes ``dy`` so for ``ssd_backward`` (before, one
+    bfloat16 copy a forward pass behind an ``optimization_barrier`` and a
+    float32 ``dy`` for the reverse loop), and the gate makes no copy of its
+    own."""
     from unittest import mock
 
     from edl_tpu.models import Mamba2Mixer, MambaSpec
@@ -1512,8 +1551,11 @@ def test_a_mixer_at_the_cells_widths_passes_xbc_whole_and_moves_y_once_in_bfloat
     text = lowered.compile().as_text()
     assert " concatenate(" not in text
     assert re.search(r"\(bf16\[1,%d,%d\]\S* [^=]*custom-call\(" % (h * p + 2 * groups * n, t), text)
-    moved = _large_under(text, "ssm_scan", t * h * p)   # a copy, or a fusion of one and a bitcast
-    assert [(kind[:4], dtype) for kind, dtype in moved] == [("copy", "bf16")] * 2
+    # under ``ssm_scan`` at most the gate's own last step backward (``dy`` rounded as it
+    # is laid out, where XLA names the fusion by its root's ``transpose``)
+    moved = _large_under(text, "ssm_scan", t * h * p)
+    assert not [(kind, dtype) for kind, dtype in moved if "copy" in kind or dtype != "bf16"]
+    assert len(moved) <= 1
     at_the_gate = _large_under(text, "ssm_gate", t * h * p)
     assert at_the_gate and not [
         kind for kind, _ in at_the_gate if "copy" in kind or "transpose" in kind
