@@ -168,11 +168,60 @@ def _top_k_kept(scores, k):
         return (checkpoint_name(values, ROUTE_NAME), idx), idx
 
     def bwd(idx, grads):
-        taken = lambda s: jnp.take_along_axis(s, idx, axis=-1)  # noqa: E731
-        return jax.linear_transpose(taken, scores)(grads[0])
+        return (_sent_home(grads[0], idx, scores.shape[-1]),)
 
     top.defvjp(fwd, bwd)
     return top(scores)
+
+
+def _chosen(idx, experts):
+    """``[.., k, E]`` bool: ``idx[.., j] == e``. Never an array in memory: the
+    one reduce that reads it takes it into its fusion."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1,) * idx.ndim + (experts,), idx.ndim)
+    return idx[..., None] == lanes
+
+
+def _sent_home(grad, idx, experts):
+    """``d scores[.., e] = sum_j where(idx[.., j] == e, grad[.., j], 0)``: each
+    chosen score's gradient back at its index, by comparison. A ``top_k``'s
+    indices are distinct a row, so a sum has at most one term and the values
+    are the scatter-add's into zeros; XLA makes it one fusion that writes
+    ``[.., E]`` once (a scatter on the TPU walks its indices). Device-side name
+    of both directions: ``picked``, under the caller's."""
+    with jax.named_scope("picked"):
+        return jnp.sum(jnp.where(_chosen(idx, experts), grad[..., None], 0), axis=-2)
+
+
+def _picked(scores, idx):
+    """``scores[.., idx[.., j]]`` (``jnp.take_along_axis`` along the last
+    axis) for a ``top_k``'s indices, read off the row by comparison:
+    ``max_e where(idx[.., j] == e, scores[.., e], -inf)``, one score among
+    ``-inf`` (a maximum and not a sum of one term among zeros: XLA folds a sum
+    of such sums, the weights' normaliser, into one reduction over ``(j, e)``
+    and adds a token's ``k`` weights in another order), so the gather's values
+    to the bit whatever stands around it, and :func:`_sent_home` their
+    gradient's way back. One fusion a direction that reads or writes
+    ``[.., E]`` once and holds no ``[.., k, E]`` array in memory: 0.16 and 0.11
+    ms a layer on the v5e at 8192 x 512 and top-22, where XLA's gather read
+    1.84 and its transpose, a scatter-add into zeros, 1.56 (PERF.md section 5,
+    PR 65)."""
+    experts = scores.shape[-1]
+
+    @jax.custom_vjp
+    def pick(scores, idx):
+        with jax.named_scope("picked"):
+            return jnp.max(
+                jnp.where(_chosen(idx, experts), scores[..., None, :], -jnp.inf), axis=-1
+            )
+
+    def fwd(scores, idx):
+        return pick(scores, idx), idx
+
+    def bwd(idx, grad):
+        return _sent_home(grad, idx, experts), None
+
+    pick.defvjp(fwd, bwd)
+    return pick(scores, idx)
 
 
 def _scalars_sorted(values, order, inverse):
@@ -415,8 +464,9 @@ class DroplessMoE(nn.Module):
     ring (``experts``, ``held``, ``top_k``, ``pairs``, ``buffer_rows``,
     ``latent``, ``width``, ``gated``, ``activation``, ``route_from``,
     ``combine_rows``: the rows a combine pass gathers on the layer's usual
-    path, and ``combine_tile``: the tokens a group of the buffer's sum, 0
-    where there is no buffer).
+    path, ``combine_tile``: the tokens a group of the buffer's sum, 0
+    where there is no buffer, and ``picked``: how a chosen score is read off
+    ``[N, E]``, ``"compare"``: :func:`_picked`).
     """
 
     num_experts: int
@@ -493,6 +543,7 @@ class DroplessMoE(nn.Module):
             activation=self.activation, route_from=self.route_from,
             # the rows a combine pass gathers, and the tokens a group of its sum
             combine_rows=buffer, combine_tile=SEGMENT_TILE if buffer < n * k else 0,
+            picked="compare",  # how a chosen score is read and its gradient sent back
         )
 
         with jax.named_scope("moe_route"):
@@ -526,7 +577,7 @@ class DroplessMoE(nn.Module):
                 weights, top_idx = _top_k_kept(probs, k)    # [N, k]
             else:  # chosen by one quantity, weighted by the scores
                 top_idx = kept(jax.lax.top_k(choice, k)[1])
-                weights = kept(jnp.take_along_axis(probs, top_idx, axis=-1))
+                weights = kept(_picked(probs, top_idx))
             if self.n_group > 1:
                 hit = jnp.any(jax.nn.one_hot(
                     top_idx // (e // self.n_group), self.n_group, dtype=bool

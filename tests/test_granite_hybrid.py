@@ -366,17 +366,18 @@ def test_the_compiled_step_names_the_mixers_scopes(scope):
 # was (``tests/test_olmoe.py`` keeps the dense LM's). Taken on PR 28's commit
 # until PR 52, which changed the expert layer's program on purpose (its
 # ``top_k`` has a gradient rule of its own, and ``save_flash`` keeps the route
-# by name); these are that PR's
+# by name), and then PR 65 (that rule sends the gradient home by comparison,
+# ``models/moe.py:_sent_home``, where it scattered); these are PR 65's
 OLMOE_STEP = {
-    True: "90e12a5f665c03141344c6095bf2d363477f03198752a5f07b0d6fe1d8d06b37",
-    False: "60a31c1717e92e06003809cebf6057a78712de6a5fd8a4bc2afda506b8f02a15",
+    True: "e26eb05911a743d09eb88df8166e547a47b3e5dba80a224489edeb721a96cba7",
+    False: "d755c4a514d09302a40ba4a3ace36f9b68083ccc40dd14b1550fc9f3aebd1bf5",
 }
 # the same step behind the attention projections' fence (PR 39): one
 # ``optimization_barrier`` a projection and half-batch; with the fence off
 # the text is still the one above
 OLMOE_STEP_FENCED = {
-    True: "435876092ba9e2b119a3a1c951e943b9d712589b71be3f8b22ea2ad9c9afa52f",
-    False: "10d894e11f274b52c4e66f090d4a0be812ad5ffec7392798452906f776c3acfa",
+    True: "7be8bd000c1463d17c3d16a421f56cdbdf6a337489249587533901dd55201d53",
+    False: "33d52245ea33f9cbcdece2aa6fd1e8cf7d794ca064bcf9892ea1170c3328373e",
 }
 
 

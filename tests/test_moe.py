@@ -2,6 +2,10 @@
 
 Net-new capability (no MoE in the reference); validated on the virtual
 8-device CPU mesh like every other sharded path.
+
+And the dropless layer's way to a token's chosen scores (``models/moe.py:
+_picked`` / ``_sent_home``, PR 65): a comparison with the chosen indices
+against the gather out of ``[N, E]`` and its transpose, to the bit.
 """
 
 import jax
@@ -10,11 +14,13 @@ import numpy as np
 import pytest
 import optax
 
-from edl_tpu.models import MOE_EP_RULES, SwitchMoE, TransformerLM
+import edl_tpu.models.moe as moe_module
+from edl_tpu.models import MOE_EP_RULES, DroplessMoE, SwitchMoE, TransformerLM
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.parallel import make_mesh, shard_batch, shard_params_by_rules
 from edl_tpu.train import create_state, cross_entropy_loss, make_train_step
 
-pytestmark = pytest.mark.slow  # compile-heavy / multi-process integration
+slow = pytest.mark.slow  # compile-heavy / multi-process integration: the three Switch classes
 
 
 B, S, D, E = 4, 16, 32, 4
@@ -27,6 +33,7 @@ def make_moe(capacity_factor=4.0):
     )
 
 
+@slow
 class TestSwitchMoE:
     def test_forward_shape_and_aux_loss(self):
         moe = make_moe()
@@ -95,6 +102,7 @@ class TestSwitchMoE:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@slow
 class TestMoETransformer:
     def test_moe_lm_trains_with_aux_loss(self):
         lm = TransformerLM(
@@ -147,6 +155,7 @@ class TestMoETransformer:
         assert wi.sharding.spec and wi.sharding.spec[0] == "ep"
 
 
+@slow
 class TestTop2Routing:
     """top_k=2 (GShard-style): each token mixes its two best experts with
     renormalized gates; 1st choices claim capacity before 2nd choices."""
@@ -247,3 +256,100 @@ class TestTop2Routing:
         )
         # and in particular: token 1 is NOT zeroed out
         assert float(jnp.abs(out[0, 1]).sum()) > 1e-6
+
+
+# -- a token's chosen scores, by comparison (PR 65) ---------------------------
+
+def gathered(scores, idx):
+    """``_picked`` as the parent had it; jax's own rule transposes it."""
+    return jnp.take_along_axis(scores, idx, axis=-1)
+
+
+def scattered(grad, idx, experts):
+    """``_sent_home`` as the parent had it: the gather's transpose, a
+    scatter-add into zeros."""
+    like = jax.ShapeDtypeStruct(idx.shape[:-1] + (experts,), grad.dtype)
+    return jax.linear_transpose(lambda s: gathered(s, idx), like)(grad)[0]
+
+
+# the six bias-routed cells' (experts, choices), and the toy layer's
+WIDTHS = [(512, 22), (512, 8), (320, 8), (128, 8), (64, 4), (8, 2)]
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward", "top_k_backward"])
+@pytest.mark.parametrize("experts,k", WIDTHS, ids=["%dx%d" % w for w in WIDTHS])
+def test_picked_is_the_gather_to_the_bit(experts, k, direction):
+    """Float32 scores of every sign and size, the ``top_k`` of scores plus a
+    bias: ``_picked`` is ``take_along_axis``, its ``jax.vjp`` the gather's, and
+    ``_top_k_kept``'s values send their gradient home as ``lax.top_k``'s do,
+    each bit for bit (a sum's one term among zeros is that term)."""
+    keys = jax.random.split(jax.random.PRNGKey(experts + k), 3)
+    scores = jax.random.normal(keys[0], (96, experts), jnp.float32) * 1e3
+    idx = jax.lax.top_k(scores + jax.random.normal(keys[1], (experts,)) * 1e3, k)[1]
+    grad = jax.random.normal(keys[2], (96, k), jnp.float32) * 1e-3
+    if direction == "forward":
+        got, want = moe_module._picked(scores, idx), gathered(scores, idx)
+        assert got.shape == (96, k) and got.dtype == jnp.float32
+    elif direction == "backward":
+        (got,) = jax.vjp(lambda s: moe_module._picked(s, idx), scores)[1](grad)
+        (want,) = jax.vjp(lambda s: gathered(s, idx), scores)[1](grad)
+        assert got.shape == (96, experts) and np.count_nonzero(got) == 96 * k
+    else:
+        (got,) = jax.vjp(lambda s: moe_module._top_k_kept(s, k)[0], scores)[1](grad)
+        (want,) = jax.vjp(lambda s: jax.lax.top_k(s, k)[0], scores)[1](grad)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+LAYERS = {
+    "scores_chosen": {},  # the first branch: ``choice is probs``
+    "bias": dict(score_func="sigmoid", bias_rate=1e-3, norm_topk_prob=True, route_scale=2.5),
+    "groups": dict(score_func="sigmoid", bias_rate=1e-3, norm_topk_prob=True,
+                   n_group=4, topk_group=2),
+    "held": dict(score_func="sigmoid", bias_rate=1e-3, norm_topk_prob=True, route_scale=2.5,
+                 held=(2, 4), shared_d_ff=16),
+}
+
+
+def _value_and_gradients(form):
+    """A toy layer's loss (its output's square and its sown losses), the
+    gradients of every parameter and of the input, and the bias it leaves.
+    Primitive by primitive: inside one jitted program the CPU's fusions contract
+    a product and a sum differently around the two forms, 1e-5 of a gradient."""
+    layer = DroplessMoE(num_experts=8, top_k=2, d_ff=24, dtype=jnp.float32, **LAYERS[form])
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32), jnp.float32)
+    variables = dict(jax.jit(layer.init)(jax.random.PRNGKey(1), x))
+    if "batch_stats" in variables:  # a bias that changes the choice
+        bias = 0.3 * jax.random.normal(jax.random.PRNGKey(2), (8,), jnp.float32)
+        variables["batch_stats"] = {"router_bias": bias}
+
+    def loss(params, x):
+        y, left = layer.apply(
+            {**variables, "params": params}, x, mutable=["losses", "metrics", "batch_stats"]
+        )
+        return jnp.sum(y * y) + sum(jax.tree.leaves(left["losses"])), left.get("batch_stats")
+
+    return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(variables["params"], x)
+
+
+@pytest.mark.parametrize("form", list(LAYERS))
+def test_the_layers_loss_and_gradients_are_the_parents_to_the_bit(monkeypatch, form):
+    """With a bias, with groups, with a held share and where the scores
+    themselves choose: the layer's loss, every gradient and the bias's step are
+    bit for bit what the parent's gather and scatter-add give."""
+    got = _value_and_gradients(form)
+    monkeypatch.setattr(moe_module, "_picked", gathered)
+    monkeypatch.setattr(moe_module, "_sent_home", scattered)
+    want = _value_and_gradients(form)
+    router = np.asarray(got[1][0]["router"]["kernel"])
+    assert np.isfinite(router).all() and np.abs(router).max() > 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_moe_shape_note_says_how_the_scores_are_picked():
+    tracer = obs_trace.get_tracer()
+    tracer.reset_notes()
+    layer = DroplessMoE(num_experts=8, top_k=2, d_ff=24, **LAYERS["held"])
+    jax.eval_shape(lambda x: layer.init(jax.random.PRNGKey(0), x), jnp.zeros((1, 16, 32)))
+    (note,) = [a for name, a in tracer.notes() if name == "moe_shape"]
+    assert note["picked"] == "compare" and note["held"] == 4

@@ -317,10 +317,13 @@ def test_the_shares_routed_parts_add_up_in_the_latent_to_the_uncut_layer(whole_l
 # PR 52 named what a remat policy keeps of the layer: against its parent's text
 # the sigmoid form's differs in the numbers of jax's private functions alone
 # (``_where_50`` is ``_where_54``), OLMoE's in its ``top_k``, whose values'
-# gradient has a rule of its own now (``models/moe.py:_top_k_kept``)
+# gradient has a rule of its own now (``models/moe.py:_top_k_kept``). PR 65's:
+# the chosen scores and their gradient go by comparison (``_picked``,
+# ``_sent_home``) where the parent's text gathers and scatters, in both forms
+# and nowhere else (``tests/test_moe.py`` holds the values to the parent's)
 GOLDEN = {
-    "olmoe": "cca6bec3d1f630fc1ccd561179cdb21f652835ab2377a13fdb0b86f896fc11c4",
-    "sigmoid_held_shared": "31ed764086d549f195c2bc1fe0adc86c70e5028e6bdc7f4d9149e5c1c8b6d907",
+    "olmoe": "8a0dd27f508fe1a26eb4e0e8d9689d9f63c665f7cbb4901f247b3eedd12ab349",
+    "sigmoid_held_shared": "51b7e1a8cc09495513f3af0246dbcfffccf40063c4dcb931ea067edf9ac10fa9",
 }
 FORMS = {
     "olmoe": {},
@@ -383,6 +386,7 @@ def test_each_traced_shape_leaves_one_moe_shape_instant():
         "experts": E, "held": 4, "top_k": K, "pairs": 64 * K, "buffer_rows": 160,
         "latent": LATENT, "width": F, "gated": False, "activation": "relu2",
         "route_from": "ff_input", "combine_rows": 160, "combine_tile": 128,
+        "picked": "compare",
     }]
 
 

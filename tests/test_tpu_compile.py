@@ -1851,10 +1851,33 @@ _WHERE = (  # first match wins: where an instruction of an expert layer runs
 )
 
 
+def _pick_computations(text):
+    """``{a fused computation's name: "forward" | "backward"}`` for those that
+    hold a reduce of ``models/moe.py:_picked`` (its scope ``picked`` under
+    ``moe_route``): the chosen scores read off ``[N, E]`` by comparison, or
+    their gradient sent back. XLA names a fusion after its root, which may be
+    a neighbour's (the weights' sum), so the fusion is known by what it holds."""
+    found, name = {}, None
+    for line in text.splitlines():
+        header = re.match(r"%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if header:
+            name = header.group(1)
+        elif line.startswith("ENTRY"):
+            name = None
+        elif name and " reduce(" in line and "/moe_route/picked/" in line:
+            found[name] = "backward" if "transpose(" in line.split('op_name="')[-1] else "forward"
+    return found
+
+
+def _called(line):
+    """The computation a ``fusion`` instruction calls."""
+    return line.split(" calls=%")[-1].split(",")[0]
+
+
 def _expert_layer_census(text):
     """``{(what, where): count}`` over a compiled step's text: ``what`` the
-    router's matmul, a ``top_k``, a sort of the route's, a fusion of the gather
-    of the chosen scores (however many XLA makes of it), a sort that carries the
+    router's matmul, a ``top_k``, a sort of the route's, a fusion that holds
+    the picks of the chosen scores (``_pick_computations``), a sort that carries the
     weights, the three Megablox kernels by the ``cond``'s branch (``buffer`` /
     ``large``), the sort and the ``tgmm`` of the buffer's sum by token
     (``segment_sort``, ``segment_sum``); ``where`` from the instruction's
@@ -1864,16 +1887,17 @@ def _expert_layer_census(text):
     import collections
 
     census = collections.Counter()
+    picks = set(_pick_computations(text))
     for line in text.splitlines():
         op_name = re.search(r'op_name="([^"]*)"', line)
         opcode = re.search(r" (dot|convolution|sort|custom-call|fusion)\(", line[:4096])
         if not op_name or not opcode or "/moe/" not in op_name.group(1):
             continue
         op_name, opcode = op_name.group(1), opcode.group(1)
-        if opcode == "fusion":  # the chosen experts' scores, gathered out of [N, E]
-            if not op_name.endswith("/moe_route/jit(take_along_axis)/gather"):
+        if opcode == "fusion":  # the chosen experts' scores, read off [N, E] by comparison
+            if _called(line) not in picks:
                 continue
-            what = "scores_gather"
+            what = "scores_picked"
         elif opcode == "custom-call":
             kernel = re.search(r"moe_experts/jit\((t?gmm)\)/|/(segment_sum)/jit\(tgmm\)/", op_name)
             if "tpu_custom_call" not in line or not kernel:
@@ -1904,7 +1928,7 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
     """A toy of one block that holds 8 of 32 experts (a buffer of 2048 of the
     4096 pairs), lowered as the chip lowers it. Under ``save_flash`` the
     policy keeps the layer's ``REMAT_NAMES`` and a block's recomputation runs
-    no router matmul, no ``top_k``, no ``argsort``, no gather of the chosen
+    no router matmul, no ``top_k``, no ``argsort``, no pick of the chosen
     scores and no Megablox call in the buffer branch: a layer's route runs once, and the buffer branch launches
     ``gmm`` three times forward (two ungated) and three times for ``d lhs``.
     The large branch keeps nothing and runs ``gate`` / ``up`` again inside its
@@ -1969,9 +1993,18 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
     conds = [line.split(" conditional(")[0] for line in text.splitlines() if " conditional(" in line]
     assert len(conds) == 3 * layers and not [c for c in conds if "[4096,256]" in c]
     census = _expert_layer_census(text)
-    gathers = {where for (what, where) in census if what == "scores_gather"}
-    assert gathers == ({"forward"} if policy else {"forward", "recomputed"})
-    census = {key: n for key, n in census.items() if key[0] != "scores_gather"}
+    # a pick forward and its gradient's way back; the recomputation is handed
+    # the weights under a policy and picks again without one
+    picked = {key: n for key, n in census.items() if key[0] == "scores_picked"}
+    assert picked == dict.fromkeys(
+        [("scores_picked", where)
+         for where in ("forward", "backward") + (() if policy else ("recomputed",))], layers,
+    )
+    assert not [
+        line for line in text.splitlines()
+        if re.search(r" (gather|scatter)\(", line[:4096]) and "/moe_route/" in line
+    ]
+    census = {key: n for key, n in census.items() if key[0] != "scores_picked"}
     top_ks = 3 if choice == "grouped" else 1
     again = 0 if policy == "save_flash" else 1  # what the recomputation repeats
     want = {
@@ -2008,6 +2041,75 @@ def test_a_held_share_step_on_the_tpu_path_decides_and_multiplies_once(
         and " gather(" in head and "bf16[4096,256]" in head
     }
     assert every_pairs >= {("moe_combine", "forward"), ("moe_experts", "backward")}
+
+
+# -- a token's chosen scores, read by comparison (PR 65) -----------------------
+
+@pytest.mark.parametrize("k,groups", [
+    pytest.param(22, {}, id="nemotron"),
+    pytest.param(8, dict(n_group=8, topk_group=4), id="ling"),
+])
+def test_the_route_picks_its_scores_by_comparison_in_one_fusion_a_direction(one_chip, k, groups):
+    """One ``DroplessMoE`` (value and gradients) that routes 8192 tokens over 512
+    experts as Nemotron's and Ling's cells do (sigmoid scores, a bias, top-22,
+    or top-8 inside the best four of eight groups; 8 experts held), compiled
+    for the described v5e: no ``gather`` and no ``scatter`` is left under
+    ``moe_route``, the picks are one fusion forward and one backward, and no
+    instruction outside a fusion writes an array of ``N k E`` elements (the
+    broadcast compare lives inside the fusions: 369 MB at top-22 otherwise)."""
+    from edl_tpu.models.moe import DroplessMoE
+
+    n, e = 8192, 512
+    layer = DroplessMoE(
+        num_experts=e, top_k=k, d_ff=128, score_func="sigmoid", bias_rate=1e-3,
+        norm_topk_prob=True, route_scale=2.5, aux_weight=0.0, z_weight=0.0, held=(0, 8),
+        **groups,
+    )
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    x = jax.ShapeDtypeStruct((1, n, 256), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype))
+    )
+
+    def value_and_gradients(variables, x):
+        def loss(params, x):
+            y, _ = layer.apply(
+                {**variables, "params": params}, x,
+                mutable=["losses", "metrics", "batch_stats"],
+            )
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(variables["params"], x)
+
+    # the experts' matmuls in their plain form (the backend is the CPU's): the
+    # route asks no backend, and no Megablox call is compiled for its sake
+    text = jax.jit(value_and_gradients).lower(
+        described(variables), described(x)
+    ).compile().as_text()
+    under_route = [line for line in text.splitlines() if "/moe_route/" in line]
+    assert under_route and not [
+        line for line in under_route if re.search(r" (gather|scatter)\(", line[:4096])
+    ]
+    picks = _pick_computations(text)
+    entry = text[text.index("ENTRY"):].splitlines()
+    calls = [
+        picks[_called(line)] for line in entry
+        if " fusion(" in line[:4096] and _called(line) in picks
+    ]
+    assert sorted(calls) == ["backward", "forward"] == sorted(picks.values())
+    wide, fused = [], False
+    for line in text.splitlines():  # what a fusion holds inside is no array
+        if re.match(r"%fused_computation[\w.\-]* \(", line):
+            fused = True
+        elif line.startswith(("%", "ENTRY")):
+            fused = False
+        shape = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[([0-9,]*)\]", line)
+        if shape and not fused and math.prod(
+                int(d) for d in shape.group(1).split(",") if d) >= n * k * e:
+            wide.append(line.strip()[:120])
+    assert not wide
 
 
 # -- what a block's recomputation runs again of a mixer's projections (PR 54) --
