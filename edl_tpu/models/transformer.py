@@ -864,7 +864,10 @@ def _remat_policy(name: Optional[str]):
     (ops/gated_delta.py), and the three values a row that a
     sparse-attention layer's select kernel found (``dsa_select``: 192 KB a
     layer; the recomputation then makes the scores and the mask again, not
-    the bisection or its row sums; ops/sparse_attention.py), and of an
+    the bisection or its row sums; ops/sparse_attention.py) with the causal
+    tiles of ``dL_I/dI`` that its indexer's loss made in its forward call
+    (``dsa_di``: 277 MB a layer at 16,384 tokens in bfloat16; neither the
+    backward nor the recomputation then runs the target kernel), and of an
     expert layer what its route decided and what its held experts'
     buffer multiplied (``models/moe.py:REMAT_NAMES``; ``moe_route``: the
     router's float32 logits ``[N, E]``, 16.8 MB a layer at 8192 tokens and

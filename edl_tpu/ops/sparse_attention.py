@@ -46,12 +46,19 @@ more than a tile of any ``[T, T]`` rectangle per head:
   online-softmax update, VMEM rule and blocks they share) under a mask that is
   an **operand**, not a function of positions. The work is the dense causal
   one: what the selection empties inside a tile is time, not work;
-- ``_target_kernel``: ``p``, the rows' KL and, for the backward, ``dL_I/dI``
-  in the indexer's compute dtype. A grid step is a score tile for all the
-  heads: they loop inside the kernel over strips of the tile's rows, their
-  sum one value a strip, and the mask is read once a tile, at the close;
-- ``_index_bwd_kernel``: ``dL_I/dI`` through the relu to ``index_q``,
-  ``index_k`` and ``index_w``, recomputing each head's products.
+- ``_target_kernel``: ``p``, the rows' KL and, where ``L_I`` is
+  differentiated, ``dL_I/dI`` in the indexer's compute dtype **from the same
+  walk**: the loss's custom-VJP forward makes ``dI`` once and keeps it as its
+  causal tiles alone (``[n (n + 1) / 2 * block, block]`` for ``n`` square
+  blocks a side: :func:`_packed_tile`), under the ``checkpoint_name``
+  ``dsa_di``, so the backward runs no second call and a block's recomputation
+  under ``save_flash`` none at all (277 MB a layer at 16,384 tokens in
+  bfloat16); a call that nothing differentiates writes the rows alone. A grid
+  step is a score tile for all the heads: they loop inside the kernel over
+  strips of the tile's rows, their sum one value a strip, and the mask is read
+  once a tile, at the close;
+- ``_index_bwd_kernel``: the kept ``dL_I/dI`` through the relu to
+  ``index_q``, ``index_k`` and ``index_w``, recomputing each head's products.
 """
 
 from __future__ import annotations
@@ -60,6 +67,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.attention import (
@@ -82,7 +90,8 @@ from edl_tpu.ops.attention import (
 )
 
 SELECT_NAME = "dsa_select"       # checkpoint_name of the select kernel's three rows
-REMAT_NAMES = (SELECT_NAME,)
+DI_NAME = "dsa_di"               # ... of dL_I/dI's causal tiles, made in the loss's forward
+REMAT_NAMES = (SELECT_NAME, DI_NAME)
 # the tile the index scores, the target and the indexer's backward are made in
 # (a configuration's q / kv chunk of 512), and the rows a selection holds
 _INDEX_BLOCKS = (512, 512)
@@ -206,6 +215,21 @@ def _last_live(qi, block_q: int, block_k: int):
     return jax.lax.div(qi * block_q + block_q - 1, block_k)
 
 
+def _packed_tile(qi, ki):
+    """The row block of causal tile ``(qi, ki)`` in an array that holds the
+    tiles at and under the diagonal alone, row of tiles after row of tiles
+    (square blocks): ``[n (n + 1) / 2 * block, block]`` where the rectangle is
+    ``[n * block, n * block]``. A dead grid step (``ki > qi``) holds the row's
+    last live tile, as ``held`` does in the rectangle."""
+    return jax.lax.div(qi * (qi + 1), 2) + jax.lax.min(ki, qi)
+
+
+def _packed_rows(t: int, block: int) -> int:
+    """Rows of the packed array of :func:`_packed_tile` for ``[t, t]``."""
+    n = t // block
+    return n * (n + 1) // 2 * block
+
+
 def _fold_lanes(x, lanes: int = 128, combine=jnp.add):
     """``[rows, n * lanes] -> [rows, lanes]``: the lane tiles summed (plain
     adds, or ``combine``; the one cross-lane step is left to the caller's
@@ -310,8 +334,9 @@ def _index_bwd_kernel(d_ref, q_ref, k_ref, w_ref, dq_ref, dw_ref, dk_ref,
 
 def _index_backward_kernels(d_scores, index_q, index_k, index_w, block_q, block_k,
                             interpret):
-    """``(d index_q, d index_k, d index_w)`` from ``d_scores [T, T]`` (zero
-    off the selection; tiles past the diagonal never read)."""
+    """``(d index_q, d index_k, d index_w)`` from ``d_scores``, the causal
+    tiles of ``dL_I/dI`` as :func:`_target_call` packs them (zero off the
+    selection)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -334,7 +359,7 @@ def _index_backward_kernels(d_scores, index_q, index_k, index_w, block_q, block_
         ],
         grid=(num_q, num_k),
         in_specs=[
-            pl.BlockSpec((block_q, block_k), lambda qi, ki: (qi, held(qi, ki))),
+            pl.BlockSpec((block_q, block_k), lambda qi, ki: (_packed_tile(qi, ki), 0)),
             pl.BlockSpec((heads, block_q, di), lambda qi, ki: (0, qi, 0)),
             pl.BlockSpec((block_k, di), lambda qi, ki: (held(qi, ki), 0)),
             pl.BlockSpec((block_q, heads), lambda qi, ki: (qi, 0)),
@@ -723,10 +748,12 @@ def _masked_flash_bwd(scale, blocks, interpret, residuals, cotangents):
     q, k, v, mask, out, lse = residuals
     g, _ = cotangents          # lse feeds the detached target alone
     h, t, d = q.shape
-    # one buffer for both of the mask's readers here (this transpose and the
-    # target's dI call): XLA otherwise fuses its pass over the scores into the
-    # transpose too, and makes the mask twice
-    mask = jax.lax.optimization_barrier(mask)
+    # the mask row-major, as its kernels read it, and this transpose a copy of
+    # 268 MB of int8. Left to itself XLA makes the mask (the forward's too)
+    # column-major so that the transpose is free, and first copies the 1.07 GB
+    # of float32 scores into that layout, twice a layer; the target's dI call
+    # in the backward used to hold the layout, and is gone
+    mask = with_layout_constraint(mask, Layout(major_to_minor=(0, 1)))
     delta = _bwd_delta(g, out, 1, h, t, d)
     dq, dk, dv = _sparse_backward_kernels(
         q, k, v, g, lse, delta, mask.T, scale, *blocks[1], interpret
@@ -819,8 +846,9 @@ def _target_vmem(h, h_kv, d, itemsize, block_q, block_k, strip_rows, grad_itemsi
 def _target_kernels(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
                     interpret, grad_dtype=None):
     """The rows' ``KL(p || softmax over the selection of I)`` ``[T]`` and, with
-    ``grad_dtype``, ``d (sum of them) / d I`` ``[T, T]`` (``softmax - p`` on
-    the selection, 0 off it; tiles past the diagonal unwritten)."""
+    ``grad_dtype``, ``d (sum of them) / d I`` (``softmax - p`` on the
+    selection, 0 off it) as its causal tiles alone, ``[n (n + 1) / 2 * block,
+    block]``: tile ``(qi, ki)`` at row block :func:`_packed_tile`."""
     return _target_call(
         q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
         _target_rows(block_q), interpret, grad_dtype,
@@ -850,8 +878,12 @@ def _target_call(q, k, lse, mask, scores, lse_index, scale, block_q, block_k,
     out_shape = [jax.ShapeDtypeStruct((t, 1), jnp.float32)]
     out_specs = [row]
     if with_grad:
-        out_shape.append(jax.ShapeDtypeStruct((t, t), grad_dtype))
-        out_specs.append(tile)
+        out_shape.append(
+            jax.ShapeDtypeStruct((_packed_rows(t, block_q), block_k), grad_dtype)
+        )
+        out_specs.append(
+            pl.BlockSpec((block_q, block_k), lambda qi, ki: (_packed_tile(qi, ki), 0))
+        )
     kernel = pl.pallas_call(
         functools.partial(
             _target_kernel, scale=scale, heads=h, group=h // h_kv, block_q=block_q,
@@ -889,34 +921,38 @@ def _index_loss(index_q, index_k, index_w, scores, mask, lse_index, q, k, lse,
     """``L_I`` of one sequence from the indexer's three operands; ``scores``
     (their ``I``), the selection, its rows' log-sum-exp of ``I`` (the select
     kernel's) and the main attention's ``q``, ``k``, ``lse`` are constants
-    here."""
-    return _index_loss_fwd(
-        index_q, index_k, index_w, scores, mask, lse_index, q, k, lse, scale,
-        blocks, interpret,
-    )[0]
+    here. Where nothing is differentiated the target kernel writes the rows'
+    KL and no ``[T, T]``-sized array."""
+    rows, _ = _target_kernels(
+        q, k, lse, mask, scores, lse_index, scale, *blocks, interpret
+    )
+    return jnp.mean(rows)
 
 
 def _index_loss_fwd(index_q, index_k, index_w, scores, mask, lse_index, q, k,
                     lse, scale, blocks, interpret):
-    rows, _ = _target_kernels(
-        q, k, lse, mask, scores, lse_index, scale, *blocks, interpret
-    )
-    return jnp.mean(rows), (
-        index_q, index_k, index_w, scores, mask, lse_index, q, k, lse
-    )
+    """The value and ``dL_I/dI`` from one walk of the score tiles: ``dI``'s
+    causal tiles are the residual, by name (``DI_NAME``), so the backward
+    calls the target kernel no second time and a block's recomputation under
+    a policy that keeps the name drops the call (both its outputs are then a
+    value nobody reads and a saved name)."""
+    from jax.ad_checkpoint import checkpoint_name
 
-
-def _index_loss_bwd(scale, blocks, interpret, residuals, g):
-    index_q, index_k, index_w, scores, mask, lse_index, q, k, lse = residuals
-    _, d_scores = _target_kernels(
+    rows, d_scores = _target_kernels(
         q, k, lse, mask, scores, lse_index, scale, *blocks, interpret,
         grad_dtype=index_q.dtype,
     )
+    d_scores = checkpoint_name(d_scores, DI_NAME)
+    return jnp.mean(rows), (index_q, index_k, index_w, d_scores)
+
+
+def _index_loss_bwd(scale, blocks, interpret, residuals, g):
+    index_q, index_k, index_w, d_scores = residuals
     with jax.named_scope("dsa_index"):  # the innermost scope counts
         dq, dk, dw = _index_backward_kernels(
             d_scores, index_q, index_k, index_w, *blocks, interpret
         )
-    g = g / scores.shape[0]    # the mean over the rows
+    g = g / index_q.shape[1]    # the mean over the rows
     return (
         (dq * g).astype(index_q.dtype), (dk * g).astype(index_k.dtype),
         (dw * g).astype(index_w.dtype), None, None, None, None, None, None,
@@ -931,12 +967,14 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 def _note_shape(tq, topk, index_heads, index_dim, select, index_lse,
                 score_bytes, mask_bytes, index_blocks, fwd_blocks, bwd_blocks,
-                select_rows, target_heads_step):
+                select_rows, target_heads_step, di_bytes):
     """One ``dsa_shape`` instant in the span ring for each shape the
     selection's kernels are traced at in a stage (``note_once``;
     ``index_lse``: where the row sums of the indexer's softmax are made;
     ``target_*``: the target kernel's tile, the heads a grid step of it takes
-    and the strip their sum is one value over)."""
+    and the strip their sum is one value over; ``target_grad``: the call that
+    makes ``dL_I/dI``, the loss's forward, and ``di_bytes`` what it keeps of
+    it for the backward, the causal tiles)."""
     obs_trace.get_tracer().note_once(
         "dsa_shape", tq=tq, topk=topk, index_heads=index_heads,
         index_dim=index_dim, select=select, index_lse=index_lse,
@@ -946,6 +984,7 @@ def _note_shape(tq, topk, index_heads, index_dim, select, index_lse,
         select_rows=select_rows, target_blocks=list(index_blocks),
         target_heads_step=target_heads_step,
         target_strip=[_target_rows(index_blocks[0]), index_blocks[1]],
+        target_grad="forward", di_bytes=di_bytes,
         path="kernel",
     )
 
@@ -953,14 +992,15 @@ def _note_shape(tq, topk, index_heads, index_dim, select, index_lse,
 def _kernel_plan(t, d, itemsize, blocks=None):
     """The blocks of every kernel at ``T = t``, or ``None`` where one of them
     cannot tile it (the caller then takes the reference): whole lane tiles of
-    keys, and no block under an int8 tile's 32 rows."""
+    keys, no block under an int8 tile's 32 rows, and square index blocks
+    (``dL_I/dI`` is kept as its causal tiles: :func:`_packed_tile`)."""
     if t % 128:
         return None
     attn = _attention_blocks(t, d, itemsize, blocks)
     index = tuple(_fit_block(b, t) for b in _INDEX_BLOCKS)
     rows = _fit_block(_SELECT_ROWS, t)
     chunk = _fit_block(_SELECT_CHUNK, t)
-    whole = all(t % b == 0 for b in index + (rows, chunk))
+    whole = index[0] == index[1] and all(t % b == 0 for b in index + (rows, chunk))
     if attn is None or not whole or min(index + attn[0] + attn[1]) < 32:
         return None
     return {"fwd": attn[0], "bwd": attn[1], "index": index, "rows": rows, "chunk": chunk}
@@ -988,6 +1028,7 @@ def _one_kernels(q, k, v, index_q, index_k, index_w, topk, scale, plan, interpre
         t, topk, index_q.shape[0], index_q.shape[2], "bisect", "select",
         t * t * 4, t * t, plan["index"], plan["fwd"], plan["bwd"], plan["rows"],
         q.shape[0],
+        _packed_rows(t, plan["index"][0]) * plan["index"][1] * index_q.dtype.itemsize,
     )
     with jax.named_scope("attn_sparse"):
         out, lse = _masked_flash(

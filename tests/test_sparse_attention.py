@@ -263,6 +263,22 @@ def test_a_traced_shape_leaves_one_dsa_shape_instant(small_tiles):
     # the target's schedule: the tile, every head in one grid step, two strips a tile
     assert event["target_blocks"] == [64, 64] and event["target_heads_step"] == H
     assert event["target_strip"] == [32, 64]
+    # dL_I/dI is made by the loss's forward call and kept as its ten causal tiles of 64 x 64
+    assert event["target_grad"] == "forward" and event["di_bytes"] == 10 * 64 * 64 * 4
+
+
+def _unpacked(packed, block=64):
+    """``dL_I/dI [T, T]`` from its causal tiles as the target kernel packs
+    them (``S._packed_tile``), zero past the diagonal's tiles."""
+    packed = np.asarray(packed)
+    n = T // block
+    assert packed.shape == (n * (n + 1) // 2 * block, block)
+    whole = np.zeros((T, T), packed.dtype)
+    for qi in range(n):
+        for ki in range(qi + 1):
+            at = int(S._packed_tile(qi, ki)) * block
+            whole[qi * block:(qi + 1) * block, ki * block:(ki + 1) * block] = packed[at:at + block]
+    return whole
 
 
 def _target_case(h, hkv, seed):
@@ -296,7 +312,7 @@ def test_the_targets_two_modes_give_the_same_rows_and_a_gradient_that_sums_to_no
     rows_again, d = run(jnp.float32)
     assert none is None
     assert np.asarray(rows).tobytes() == np.asarray(rows_again).tobytes()
-    d = np.where(np.tril(np.ones((T, T), bool)), np.asarray(d), 0.0)  # dead tiles are unwritten
+    d = _unpacked(d)                                    # dead tiles are not held at all
     assert not d[~picked].any()
     assert np.abs(d.sum(axis=1)).max() < 1e-5 and np.abs(d).max() > 1e-3
 
@@ -314,7 +330,47 @@ def test_the_target_kernel_against_the_reference_by_group_and_mode(small_tiles, 
     assert rows.shape == (T,)
     _close(jnp.mean(rows), want_kl, tol=1e-5)
     if mode == "grad":
-        _close(np.where(picked, np.asarray(d), 0.0), want_d, tol=5e-5)
+        _close(np.where(picked, _unpacked(d), 0.0), want_d, tol=5e-5)
+
+
+@pytest.mark.parametrize("n", [1, 4, 32])
+def test_the_packed_index_map_gives_every_causal_tile_a_row_block_of_its_own(n):
+    """``n (n + 1) / 2`` causal tiles fill as many row blocks with no gap, in
+    the order the grids walk them, and a dead grid step (``ki > qi``) holds
+    the row's last live tile: no block is fetched or written back for it."""
+    at = lambda qi, ki: int(S._packed_tile(qi, ki))  # noqa: E731
+    live = [at(qi, ki) for qi in range(n) for ki in range(qi + 1)]
+    assert live == list(range(n * (n + 1) // 2))
+    assert S._packed_rows(n * 64, 64) == len(live) * 64
+    for qi in range(n):
+        assert {at(qi, ki) for ki in range(qi + 1, n)} <= {at(qi, qi)}
+
+
+def test_index_blocks_that_are_not_square_take_the_plain_form(small_tiles, monkeypatch):
+    """The causal tiles of ``dL_I/dI`` are packed for square blocks: a plan
+    whose two index blocks differ is a shape the kernels do not take."""
+    assert S._kernel_plan(T, DH, 4, BLOCKS)["index"] == (64, 64)
+    monkeypatch.setattr(S, "_INDEX_BLOCKS", (64, 128))
+    assert S._kernel_plan(T, DH, 4, BLOCKS) is None
+
+
+def test_a_layer_under_the_remat_policy_gives_the_value_and_gradients_it_gives_without(small_tiles):
+    """``dL_I/dI`` kept by name and read in the backward is the array the
+    forward wrote: the value and all six gradients under ``jax.checkpoint``
+    with ``save_flash`` are those of the layer differentiated bare."""
+    def layer(*a):
+        o, kl, _, _ = S.sparse_attention(*a, TOPK, interpret=True, blocks=BLOCKS)
+        return jnp.sum(o * jnp.cos(jnp.arange(DH))) + 3.0 * kl
+
+    args = operands(10)
+    argnums = tuple(range(6))
+    want, want_grads = jax.jit(jax.value_and_grad(layer, argnums=argnums))(*args)
+    kept = jax.checkpoint(layer, policy=transformer_module._remat_policy("save_flash"))
+    got, grads = jax.jit(jax.value_and_grad(kept, argnums=argnums))(*args)
+    _close(got, want, tol=1e-6)
+    for g, w in zip(grads, want_grads):
+        assert np.abs(np.asarray(w)).max() > 0
+        _close(g, w, tol=1e-6)
 
 
 def test_an_unpicked_key_whose_exponent_passes_float32_leaves_the_target_finite(small_tiles):
@@ -684,7 +740,7 @@ def test_the_familys_start_changes_the_embedding_tables_first_values_alone(rms):
 
 
 def test_the_remat_policy_keeps_the_selections_thresholds():
-    assert S.REMAT_NAMES == ("dsa_select",)
+    assert S.REMAT_NAMES == ("dsa_select", "dsa_di")
     for name in ("save_flash", "save_flash_qkv"):
         assert transformer_module._remat_policy(name) is not None
     assert transformer_module._remat_policy("full") is None
@@ -700,15 +756,23 @@ def _equations(jaxpr, inside=()):
                 yield from _equations(sub, inside + (eqn,))
 
 
+def _live_equations(fn, *args):
+    """:func:`_equations` of ``fn(*args)``'s jaxpr after dead-code elimination."""
+    from jax._src.interpreters import partial_eval as pe
+
+    traced = jax.make_jaxpr(fn)(*args).jaxpr
+    live, _ = pe.dce_jaxpr(traced, [True] * len(traced.outvars))
+    return list(_equations(live))
+
+
 def test_under_the_remat_policy_the_bisection_runs_once_and_no_pass_of_xla_reads_the_scores_for_the_loss(small_tiles):
     """A sparse layer's value and gradient under ``jax.checkpoint`` with
     ``save_flash``, after dead-code elimination: the select kernel once (its
     three rows are saved names, so the recomputation drops it), the target
-    kernel twice (value, then ``dI``), and under ``dsa_target`` no reduction
-    of a ``[T, T]`` operand outside a kernel: the indexer's row sums are the
-    select kernel's."""
-    from jax._src.interpreters import partial_eval as pe
-
+    kernel once (the value and ``dI`` from one walk: ``dI``'s causal tiles
+    are a saved name too, so the recomputation drops that call as well), and
+    under ``dsa_target`` no reduction of a ``[T, T]`` operand outside a
+    kernel: the indexer's row sums are the select kernel's."""
     def layer(*a):
         o, kl, _, _ = S.sparse_attention(*a, TOPK, interpret=True, blocks=BLOCKS)
         return jnp.sum(o * jnp.cos(jnp.arange(DH))) + kl
@@ -717,16 +781,38 @@ def test_under_the_remat_policy_the_bisection_runs_once_and_no_pass_of_xla_reads
         jax.checkpoint(layer, policy=transformer_module._remat_policy("save_flash")),
         argnums=tuple(range(6)),
     )
-    traced = jax.make_jaxpr(step)(*operands(9)).jaxpr
-    live, _ = pe.dce_jaxpr(traced, [True] * len(traced.outvars))
     kernels, passes = [], []
-    for inside, eqn in _equations(live):
+    for inside, eqn in _live_equations(step, *operands(9)):
         scopes = "/".join(str(e.source_info.name_stack) for e in inside + (eqn,))
         if eqn.primitive.name == "pallas_call":
             kernels.append(eqn.params["jaxpr"].debug_info.func_name)
         elif "dsa_target" in scopes and eqn.primitive.name.startswith("reduce_"):
             passes += [v.aval.shape for v in eqn.invars if v.aval.shape == (T, T)]
     assert kernels.count("_select_kernel") == 1
-    assert kernels.count("_target_kernel") == 2
+    assert kernels.count("_target_kernel") == 1
+    assert kernels.count("_index_bwd_kernel") == 1
     assert kernels.count("_index_fwd_kernel") == 2      # the scores are made again, as before
     assert passes == []
+
+
+def _pallas_calls(fn, *args):
+    """``(kernel body's name, its results' shapes)`` of every Pallas call that
+    survives dead-code elimination in ``fn(*args)``."""
+    return [
+        (eqn.params["jaxpr"].debug_info.func_name, [v.aval.shape for v in eqn.outvars])
+        for _, eqn in _live_equations(fn, *args) if eqn.primitive.name == "pallas_call"
+    ]
+
+
+def test_a_forward_that_nothing_differentiates_writes_no_gradient_of_the_scores(small_tiles):
+    """The loss's primal calls the target kernel for the rows' KL alone: one
+    output, nothing the size of ``[T, T]`` or of its causal tiles; under a
+    gradient the same call has the tiles as its second output, once."""
+    layer = lambda *a: S.sparse_attention(*a, TOPK, interpret=True, blocks=BLOCKS)[:2]  # noqa: E731
+    target = [shapes for name, shapes in _pallas_calls(layer, *operands(9)) if name == "_target_kernel"]
+    assert target == [[(T, 1)]]
+    loss = lambda *a: layer(*a)[1]  # noqa: E731
+    calls = _pallas_calls(jax.grad(loss, argnums=(3, 4, 5)), *operands(9))
+    target = [shapes for name, shapes in calls if name == "_target_kernel"]
+    assert target == [[(T, 1), (10 * 64, 64)]]
+    assert [name for name, _ in calls].count("_index_bwd_kernel") == 1

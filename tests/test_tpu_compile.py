@@ -910,8 +910,9 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
     ``_kernel_plan`` gives): the int8 mask's tiles, the select kernel's row
     block and key scratch (8 MB each under a limit set from the shapes), the
     fused backward's 8 MB dq accumulator, the target's q block of all 32 heads
-    (4 MB a buffer) with the heads' loop inside the kernel, the indexer
-    backward's whole-sequence key gradient."""
+    (4 MB a buffer) with the heads' loop inside the kernel and, with a
+    gradient, ``dL_I/dI`` written as its causal tiles alone, the indexer
+    backward's read of those tiles and its whole-sequence key gradient."""
     S = importlib.import_module("edl_tpu.ops.sparse_attention")
     h, h_kv, t, d, j, di, topk = SPARSE
     plan = S._kernel_plan(t, d, 2)
@@ -925,6 +926,7 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
     f32, q, kv = jnp.float32, sds((h, t, d)), sds((h_kv, t, d))
     rect, mask, rows = sds((t, t), f32), sds((t, t), jnp.int8), sds((h, t), f32)
     iq, ik, iw = sds((j, t, di)), sds((t, di)), sds((t, j), f32)
+    tiles = (S._packed_rows(t, plan["index"][0]), plan["index"][1])
     fn, args = {
         "index_fwd": (lambda a, b, c: S._index_scores_kernels(a, b, c, *plan["index"], False),
                       (iq, ik, iw)),
@@ -941,10 +943,12 @@ def test_sparse_attention_kernels_compile_for_v5e_at_the_cells_shape(one_chip, k
             q, k, l, m, s, li, scale, *plan["index"], False, jnp.bfloat16),
             (q, kv, rows, mask, rect, sds((t,), f32))),
         "index_bwd": (lambda dd, a, b, c: S._index_backward_kernels(
-            dd, a, b, c, *plan["index"], False), (sds((t, t)), iq, ik, iw)),
+            dd, a, b, c, *plan["index"], False), (sds(tiles), iq, ik, iw)),
     }[kernel]
     lowered = jax.jit(fn).lower(*args)
     assert _kernel_names(lowered.as_text()) == [SPARSE_KERNELS[kernel]]
+    if kernel == "target_grad":   # dL_I/dI as its 528 causal tiles, what index_bwd takes
+        assert lowered.out_info[1].shape == tiles and tiles[0] * tiles[1] * 2 == 276_824_064
     compiled = lowered.compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     # nothing but a layout copy of the mask beside the kernel's own operands
@@ -992,12 +996,14 @@ def _plain_readers(entry, shape):
 
 def test_a_sparse_layers_step_reads_the_scores_outside_its_kernels_only_to_make_the_mask(one_chip):
     """One sparse-attention layer's value and gradient under ``save_flash`` at
-    the cell's shape, compiled for the described v5e: eight kernels (the select
-    kernel once), and of XLA's own operations only two take the 1.07 GB of
+    the cell's shape, compiled for the described v5e: seven kernels (the select
+    kernel once and the target kernel once: its ``dI`` is made in the forward
+    and kept by name), and of XLA's own operations only two take the 1.07 GB of
     scores, the mask's elementwise pass forward and again in the
-    recomputation: no row sum for the indexer's loss, and no second mask for
-    the masked backward's transpose (its barrier holds the two readers to one
-    buffer)."""
+    recomputation: no row sum for the indexer's loss, no second mask for the
+    masked backward's transpose, and no copy of the scores into the layout that
+    transpose would like (the backward holds the mask row-major: without that
+    XLA copies the float32 scores column-major twice a layer)."""
     from unittest import mock
 
     from edl_tpu.models.transformer import _remat_policy
@@ -1021,8 +1027,8 @@ def test_a_sparse_layers_step_reads_the_scores_outside_its_kernels_only_to_make_
             sds((1, j, t, di)), sds((1, t, di)), sds((1, t, j), jnp.float32),
         )
     kernels = _kernel_names(lowered.as_text())
-    assert len(kernels) == 8 and kernels.count("_select_kernel") == 1
-    assert kernels.count("_target_kernel") == 2
+    assert len(kernels) == 7 and kernels.count("_select_kernel") == 1
+    assert kernels.count("_target_kernel") == 1
     text = lowered.compile().as_text()
     entry = text[text.index("\nENTRY "):]
     readers = _plain_readers(entry, "f32[%d,%d]" % (t, t))
@@ -1101,8 +1107,9 @@ def test_the_sparse_cells_depth_is_the_one_its_plan_chose():
 def test_the_sparse_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorded(depth):
     """``benchmark/tools/compile_for_v5e.py`` on the cell at the depth the
     issue asked for first and at the one the rule chose (3 to 4 minutes each):
-    the step compiles with its 30 custom calls a layer, and the plan's total is
-    what the configuration's file records, to 0.1 GB."""
+    the step compiles with its 29 custom calls a layer, and the plan's total is
+    what the configuration's file records, to 0.1 GB (stale since before PR
+    66: the file's totals are PR 41's, ROADMAP S11)."""
     import json
     import subprocess
     import sys
@@ -1121,7 +1128,7 @@ def test_the_sparse_cells_whole_step_compiles_for_v5e_and_its_plan_is_as_recorde
     )
     assert doc["parameters"] == recorded["parameters"]
     assert doc["total_gb"] == pytest.approx(recorded["total_gb"], abs=0.1)
-    assert doc["tpu_custom_calls"] == 30 * depth
+    assert doc["tpu_custom_calls"] == 29 * depth
 
 
 # -- ling_3_0_flash_vl.steady: two-width flash2, the per-channel delta rule --
