@@ -27,6 +27,8 @@ hyper-parameters.
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import os
 import sys
 import threading
@@ -225,6 +227,36 @@ class _RestageRequested(Exception):
     has been superseded (hot-restage mode only)."""
 
 
+@dataclasses.dataclass(slots=True)
+class _Stage:
+    """What the parts of ``_fit_stage`` share, one frame's locals once: a
+    record its parts read and write, and nothing else."""
+
+    env: Any
+    mesh: Any
+    closing: contextlib.ExitStack  # every close of the stage, see _fit_stage
+    tracer: obs_trace.SpanTracer
+    retired: RetireClock
+    mngr: Optional[CheckpointManager] = None
+    health: Any = None  # train.context.HealthMonitor
+    mem_plane: Optional[obs_memory.MemoryPlane] = None
+    probe: Optional[obs_numerics.NumericsProbe] = None
+    step_telemetry: Optional[obs_profile.StepTelemetry] = None
+    capture: Optional[obs_profile.CaptureController] = None
+    step: Any = None  # the jitted train step
+    sharding: Any = None  # a batch's
+    state: Optional[TrainState] = None
+    start_epoch: int = 0
+    steps_done: int = 0  # stage-cumulative, drives the heartbeat
+    last_flight: float = 0.0  # throttled flight-recorder step marker
+    first_step_done: bool = False
+    ladder: Any = None  # AOT resize ladder, armed after the first step
+    census: Optional[threading.Thread] = None  # the compiled step's census
+    census_dropped: threading.Event = dataclasses.field(
+        default_factory=threading.Event
+    )
+
+
 class ElasticTrainer:
     """Drive an elastic SPMD training job end to end.
 
@@ -412,52 +444,79 @@ class ElasticTrainer:
         sys.exit(ctx.DRAINED_EXIT)
 
     def _fit_stage(
-        self,
-        data_fn: DataFn,
-        epochs: int,
-        on_epoch_end: Optional[Callable[[int, Dict], None]],
-        monitor,
+        self, data_fn: DataFn, epochs: int,
+        on_epoch_end: Optional[Callable[[int, Dict], None]], monitor,
     ) -> TrainState:
+        # `closing` owns every close of the stage: each is pushed at the line
+        # that builds its owner and they run in reverse, every one even where
+        # an earlier one raised. So the order of building is the rule of
+        # closing: the checkpoint manager is built first and closes last; the
+        # health monitor before the memory plane and the numerics probe, which
+        # hold its store client and so close before it; the ladder after the
+        # first step, so it stops before the memory plane its rungs harvest
+        # into. `leaving` owns no close and unwinds before any of them: the
+        # mesh is left, then the census thread is told to drop.
+        with contextlib.ExitStack() as closing, contextlib.ExitStack() as leaving:
+            stage = self._open_stage(closing, leaving)
+            for epoch in range(stage.start_epoch, epochs):
+                metrics, steps, t_epoch = self._run_steps(
+                    stage, epoch, data_fn, monitor
+                )
+                self._end_epoch(stage, epoch, metrics, steps, t_epoch, on_epoch_end)
+            if stage.mngr is not None:
+                stage.mngr.wait()
+            obs_goodput.close(cause="complete")
+            if stage.census is not None:
+                # bounded, and here alone: a stage that is leaving for a
+                # resize or on an exception waits for no telemetry
+                stage.census.join(timeout=CENSUS_JOIN_S)
+            return stage.state
+
+    def _open_stage(self, closing, leaving) -> _Stage:
+        """The stage from ``init()`` to a step that is ready to run: mesh,
+        planes (each one's close pushed on ``closing`` where it is built),
+        state, restore, the jitted step, the start barrier. Returns inside
+        the mesh, which ``leaving`` holds."""
         from edl_tpu.train import context as ctx
 
         env = init()
         t_setup = time.monotonic()  # train_setup trace segment starts here
+        tracer = obs_trace.get_tracer()
         # a stage traces its step anew: its shapes are noted anew
-        obs_trace.get_tracer().reset_notes()
-        mesh = make_mesh(self._mesh_axes)
-        mngr = (
-            CheckpointManager(self._ckpt_dir, async_save=self._async_save)
-            if self._ckpt_dir
-            else None
+        tracer.reset_notes()
+        stage = _Stage(
+            env=env, mesh=make_mesh(self._mesh_axes), closing=closing,
+            tracer=tracer, retired=RetireClock(tracer),
         )
+        if self._ckpt_dir:
+            stage.mngr = CheckpointManager(
+                self._ckpt_dir, async_save=self._async_save
+            )
+            closing.callback(stage.mngr.close)
         # health plane: drain-notice watch + step heartbeats. Best-effort
         # by design — a job without a store (or a store that is down right
         # now) trains exactly as before, it just cannot drain gracefully.
-        health = None
+        store = None  # its store client, which the planes below share
         if env.store_endpoint and env.job_id:
             try:
-                health = ctx.HealthMonitor(env)
+                stage.health = ctx.HealthMonitor(env)
+                closing.callback(stage.health.close)
+                store = stage.health.store_client
             except Exception as exc:  # noqa: BLE001
                 print(
                     "elastic-trainer: health monitor unavailable (%s); "
                     "continuing without graceful drain" % exc,
                     file=sys.stderr,
                 )
-        step_telemetry: Optional[obs_profile.StepTelemetry] = None
-        capture: Optional[obs_profile.CaptureController] = None
-        ladder = None  # AOT resize ladder, armed after the first step
-        census = None  # the compiled step's census, on its own thread
-        census_dropped = threading.Event()
         # memory plane: compile-time plan + census/watermarks + OOM
         # forensics, per stage
-        mem_plane: Optional[obs_memory.MemoryPlane] = None
         try:
-            mem_plane = obs_memory.MemoryPlane(
+            stage.mem_plane = obs_memory.MemoryPlane(
                 stage=env.stage, rank=env.global_rank,
-                client=health.store_client if health is not None else None,
-                job_id=env.job_id or "",
+                client=store, job_id=env.job_id or "",
                 expect_donation=True,  # make_train_step donates state
             )
+            closing.callback(stage.mem_plane.close)
         except Exception as exc:  # noqa: BLE001 — memory plane is telemetry
             print(
                 "elastic-trainer: memory plane unavailable (%s); "
@@ -467,371 +526,304 @@ class ElasticTrainer:
         # numerics plane: fused bundle + throttled host export. Shares
         # the health plane's store client for the cross-replica digest
         # exchange when one exists.
-        probe = None
         if obs_numerics.enabled():
-            probe = obs_numerics.NumericsProbe(
-                rank=env.global_rank,
-                client=health.store_client if health is not None else None,
-                job_id=env.job_id or "",
+            stage.probe = obs_numerics.NumericsProbe(
+                rank=env.global_rank, client=store, job_id=env.job_id or "",
             )
-        try:
-            with mesh:
-                # peek the checkpointed status FIRST: adjust callbacks are
-                # contractually given (restored_status_or_None, world) so
-                # e.g. epoch-aware lr schedules survive stop-resume
-                peeked = mngr.read_status() if mngr is not None else None
-                overrides = (
-                    self._adjusts.resolve(peeked, env.world_size)
-                    if self._adjusts is not None
-                    else {}
+            closing.callback(stage.probe.close)
+        leaving.callback(stage.census_dropped.set)
+        leaving.enter_context(stage.mesh)
+        # peek the checkpointed status FIRST: adjust callbacks are
+        # contractually given (restored_status_or_None, world) so e.g.
+        # epoch-aware lr schedules survive stop-resume
+        peeked = stage.mngr.read_status() if stage.mngr is not None else None
+        overrides = {}
+        if self._adjusts is not None:
+            overrides = self._adjusts.resolve(peeked, env.world_size)
+        # one jitted program whose outputs are born on the mesh: no leaf is
+        # ever committed to device 0 alone (it would clash with mesh-placed
+        # args at jit time and checkpoint restore), under fsdp the full model
+        # is on no device, and on a mesh that spans processes every process
+        # runs the same program
+        with tracer.span("state_init") as init_span:
+            stage.state = jax.block_until_ready(
+                create_state(
+                    self._model, jax.random.PRNGKey(self._seed),
+                    self._sample_input, self._make_tx(overrides),
+                    shardings=_state_shardings(stage.mesh, self._fsdp),
+                    **self._init_kwargs,
                 )
-                # one jitted program whose outputs are born on the mesh:
-                # no leaf is ever committed to device 0 alone (it would
-                # clash with mesh-placed args at jit time and checkpoint
-                # restore), under fsdp the full model is on no device,
-                # and on a mesh that spans processes every process runs
-                # the same program
-                with obs_trace.get_tracer().span("state_init") as init_span:
-                    state = jax.block_until_ready(
-                        create_state(
-                            self._model,
-                            jax.random.PRNGKey(self._seed),
-                            self._sample_input,
-                            self._make_tx(overrides),
-                            shardings=_state_shardings(mesh, self._fsdp),
-                            **self._init_kwargs,
-                        )
-                    )
-                    leaves = jax.tree.leaves(state)
-                    init_span.args = {
-                        "leaves": len(leaves),
-                        "bytes": sum(x.nbytes for x in leaves),
-                    }
-                start_epoch = 0
-                if mngr is not None:
-                    state, status = mngr.restore(state)
-                    if status and probe is not None:
-                        # arm the resume-continuity check against the
-                        # checkpoint's stamped numerics fingerprint
-                        probe.expect((status.meta or {}).get("numerics"))
-                    if status:
-                        start_epoch = status.next_epoch()
-                        if env.is_rank0 and self._log:
-                            print(
-                                "elastic-trainer: resumed at epoch %d "
-                                "(world=%d%s)"
-                                % (
-                                    start_epoch,
-                                    env.world_size,
-                                    "".join(
-                                        ", %s=%s" % kv
-                                        for kv in sorted(overrides.items())
-                                    ),
-                                )
-                            )
-                step = make_train_step(
-                    self._loss, self._apply_kwargs,
-                    numerics=obs_numerics.enabled(),
-                )
-                sharding = batch_sharding(mesh, self._batch_axis)
-                worker_barrier("elastic-trainer-start")
-                # restage-trace segment: state build + restore + stage
-                # barrier (the restore nests under it as its own span)
-                obs_trace.get_tracer().record(
-                    "train_setup", t_setup, time.monotonic() - t_setup
-                )
-                # goodput: everything from here until the first completed
-                # step is attributed to compile (jit trace + XLA compile,
-                # or persistent-cache load)
-                obs_goodput.enter("compile", cause="first_step")
-                # profiling plane: windowed MFU/roofline/HBM gauges
-                # (armed with the step's cost analysis after the first
-                # step) + store-driven on-demand jax.profiler windows.
-                # EDL_PROFILE_DIR keeps its historical meaning — ONE
-                # env-armed window for the whole fit (the reference
-                # profiles batches 100-105, train_with_fleet.py:524-534)
-                # — now riding the same controller as store requests.
-                step_telemetry = obs_profile.StepTelemetry()
-                try:
-                    capture = obs_profile.CaptureController(
-                        env, telemetry=step_telemetry
-                    )
-                    profile_dir = os.environ.get("EDL_PROFILE_DIR")
-                    if profile_dir:
-                        capture.arm_local(
-                            profile_dir, start_after=10, steps=5
-                        )
-                except Exception as exc:  # noqa: BLE001 — profiling is best-effort
-                    print(
-                        "elastic-trainer: capture plane unavailable "
-                        "(%s); continuing without it" % exc,
-                        file=sys.stderr,
-                    )
-                tracer = obs_trace.get_tracer()
-                retired = RetireClock(tracer)
-                first_step_done = False
-                steps_done = 0  # stage-cumulative, drives the heartbeat
-                last_flight = 0.0  # throttled flight-recorder step marker
-                for epoch in range(start_epoch, epochs):
-                    metrics: Dict[str, Any] = {}
-                    batches = data_fn(epoch)
-                    if self._batch_size is not None:
-                        batches = (
-                            b
-                            for b, _ in batched(
-                                batches, self._batch_size, drop_remainder=True
-                            )
-                        )
-                    step_idx = 0
-                    t_epoch = time.monotonic()
-                    t_prev = t_epoch
-                    # explicit iterator: the time blocked in next() is the
-                    # input pipeline's fault (data_wait), the dispatch
-                    # interval after it is the step's (train) — the split
-                    # the goodput ledger exists to make
-                    batch_iter = iter(prefetch_to_device(
-                        batches, depth=self._depth, sharding=sharding,
-                        epoch=epoch,
+            )
+            leaves = jax.tree.leaves(stage.state)
+            init_span.args = {
+                "leaves": len(leaves), "bytes": sum(x.nbytes for x in leaves),
+            }
+        if stage.mngr is not None:
+            stage.state, status = stage.mngr.restore(stage.state)
+            if status and stage.probe is not None:
+                # arm the resume-continuity check against the checkpoint's
+                # stamped numerics fingerprint
+                stage.probe.expect((status.meta or {}).get("numerics"))
+            if status:
+                stage.start_epoch = status.next_epoch()
+                if env.is_rank0 and self._log:
+                    print("elastic-trainer: resumed at epoch %d (world=%d%s)" % (
+                        stage.start_epoch, env.world_size,
+                        "".join(", %s=%s" % kv for kv in sorted(overrides.items())),
                     ))
-                    retired.start_epoch()
-                    while True:
-                        if first_step_done:
-                            obs_goodput.enter("data_wait")
-                        try:
-                            with tracer.span(
-                                "data_wait", epoch=epoch, step=step_idx
-                            ):
-                                device_batch = next(batch_iter)
-                        except StopIteration:
-                            break
-                        if first_step_done:
-                            obs_goodput.enter("train")
-                        if health is not None and health.drain_notice:
-                            # drain beats restage: this pod is leaving the
-                            # job, not joining the next generation
-                            self._drain_exit(
-                                health, mngr, state, epoch, steps_done, env
-                            )
-                        if monitor is not None and monitor.restage_pending:
-                            # between steps, never inside compiled code;
-                            # the in-flight step's work is simply dropped
-                            # (same loss as a stop-resume kill)
-                            raise _RestageRequested()
-                        # host cost of one dispatch: long when the
-                        # runtime's queue is full
-                        with tracer.span(
-                            "step_dispatch", epoch=epoch, step=step_idx
-                        ):
-                            if mem_plane is not None:
-                                # RESOURCE_EXHAUSTED leaves a forensics
-                                # bundle (census + device memory profile +
-                                # the plan + an fsync'd `oom` instant)
-                                # before propagating into drain/restage
-                                with mem_plane.oom_guard(
-                                    step=steps_done, epoch=epoch
-                                ):
-                                    state, metrics = step(state, device_batch)
-                            else:
-                                state, metrics = step(state, device_batch)
-                        # pop BEFORE any aggregation/printing: the bundle
-                        # is device arrays for the probe, not a scalar
-                        # metric. No host sync here — the probe fetches
-                        # on its own throttle.
-                        bundle = metrics.pop(obs_numerics.METRICS_KEY, None)
-                        if probe is not None:
-                            fetched = probe.on_step(
-                                steps_done, bundle, epoch=epoch
-                            )
-                            if fetched is not None:
-                                retired.mark(
-                                    fetched[0], fetched[1], epoch=epoch,
-                                    gauges=fetched[2],
-                                )
-                        # dispatch to dispatch: the loop runs ahead of the
-                        # device, so this is the host's interval, not the
-                        # step's (RetireClock has that)
-                        t_now = time.monotonic()
-                        dt = t_now - t_prev
-                        if probe is None:
-                            # no wait for the device inside an epoch, so
-                            # the runtime's own queue paces the dispatches
-                            _M_STEP_SECONDS.observe(dt)
-                        _M_STEPS.inc()
-                        if not first_step_done:
-                            # restage trace: the first completed step is
-                            # the operation's closing segment (jit trace
-                            # + compile or cache load), recorded while
-                            # the op context is still live so it stitches
-                            # — then the restage window ends
-                            _record_step_launch(tracer)
-                            tracer.record(
-                                "first_step", t_prev, dt, epoch=epoch
-                            )
-                            obs_trace.end_process_op()
-                        tracer.record(
-                            "train_step", t_prev, dt,
-                            epoch=epoch, step=step_idx,
-                        )
-                        if not first_step_done:
-                            # the stage's cold-start cost: jit trace +
-                            # compile (or persistent-cache load)
-                            _M_FIRST_STEP.set(dt)
-                            first_step_done = True
-                            obs_goodput.enter("train", cause="first_step")
-                            # one more jax trace of the step, shared by
-                            # what reads the program: XLA's cost analysis
-                            # arms the MFU/roofline gauges; its compile (a
-                            # persistent-cache hit, no second XLA compile)
-                            # gives the memory plane THIS stage's plan and
-                            # obs_profile.step_phases() the names. Its own
-                            # span: set-up time after `first_step` ends
-                            # (milliseconds on the chip: jax's in-process
-                            # caches hand trace, lowering and executable back)
-                            with tracer.span("step_relower") as relower:
-                                lowered, compiled = _lower_step(
-                                    step, state, device_batch,
-                                    compile=mem_plane is not None,
-                                )
-                                step_telemetry.set_cost(
-                                    obs_profile.step_cost(lowered)
-                                )
-                                plan = None
-                                if compiled is not None:
-                                    plan = mem_plane.harvest(
-                                        compiled, world=env.world_size
-                                    )
-                                    obs_profile.set_step_executable(compiled)
-                                relower.args["compiled"] = compiled is not None
-                            if compiled is not None:
-                                # the census of the compiled step: its text
-                                # and a pass over it take seconds, so on a
-                                # thread of its own (a stage that trains to
-                                # its end waits for it); the stage's notes as
-                                # they stand now, before the ladder's thread
-                                # traces other worlds
-                                census = threading.Thread(
-                                    target=_publish_step_census,
-                                    args=(tracer, tracer.notes(), plan, env,
-                                          census_dropped),
-                                    name="edl-step-census", daemon=True,
-                                )
-                                census.start()
-                            # steady state reached: speculatively compile
-                            # the N±1/N±2 neighbor worlds into the
-                            # persistent cache on a low-priority thread
-                            # (train/aot.py) so the NEXT resize re-jits
-                            # from a cache load instead of a compile
-                            if env.compile_cache_dir:
-                                ladder = self._start_ladder(
-                                    env, step, state, device_batch,
-                                    mem_plane=mem_plane,
-                                )
-                        step_telemetry.observe_step(dt)
-                        if mem_plane is not None:
-                            # throttled census + watermark sample
-                            # (EDL_MEM_CENSUS_EVERY; metadata only,
-                            # never a host sync on the step path)
-                            mem_plane.on_step(steps_done)
-                        t_prev = t_now
-                        step_idx += 1
-                        steps_done += 1
-                        if t_now - last_flight >= 1.0:
-                            # throttled black-box marker: bounds a killed
-                            # worker's open goodput interval to <= 1 s
-                            last_flight = t_now
-                            obs_events.record(
-                                "train_heartbeat", step=steps_done, epoch=epoch
-                            )
-                        if health is not None:
-                            health.heartbeat(steps_done, dt)
-                        if capture is not None:
-                            # store-driven profiler window state machine;
-                            # the sync makes the closing trace contain
-                            # the device work it claims to
-                            capture.on_step(
-                                sync=lambda m=metrics: jax.block_until_ready(m)
-                            )
-                    if first_step_done:
-                        # the epoch-end device sync below is step work,
-                        # not input wait
-                        obs_goodput.enter("train")
-                    if metrics:
-                        # the device drains: every step of the epoch has
-                        # retired when this returns
-                        with tracer.span(
-                            "epoch_sync", epoch=epoch, step=step_idx - 1
-                        ):
-                            jax.block_until_ready(metrics)
-                        t_synced = time.monotonic()
-                        # what the model sows (aux_loss, moe_load_max) and
-                        # what the loss head names as its ``gauges``, as
-                        # gauges: the values have just been waited for
-                        sown = {
-                            name: np.asarray(metrics[name])
-                            for name in (
-                                *state.sown, *getattr(self._loss, "gauges", ())
-                            )
-                            if name in metrics
-                        }
-                        retired.mark(
-                            steps_done - 1, t_synced, epoch=epoch, gauges=sown
-                        )
-                        obs_numerics.publish_sown(sown)
-                    if env.is_rank0 and self._log and metrics:
-                        print(
-                            "epoch %d %s"
-                            % (
-                                epoch,
-                                " ".join(
-                                    "%s %.4f" % (k, float(np.asarray(v)))
-                                    for k, v in sorted(metrics.items())
-                                    if np.asarray(v).ndim == 0
-                                ),
-                            )
-                        )
-                    if not metrics and env.is_rank0 and self._log:
-                        print(
-                            "epoch %d produced no full batches "
-                            "(fewer than batch_size records?)" % epoch
-                        )
-                    _M_EPOCHS.inc()
-                    tracer.record(
-                        "train_epoch", t_epoch,
-                        time.monotonic() - t_epoch,
-                        epoch=epoch, steps=step_idx,
-                    )
-                    if on_epoch_end is not None:
-                        with tracer.span("epoch_end_hook", epoch=epoch):
-                            on_epoch_end(epoch, metrics)
-                    if mngr is not None:
-                        mngr.save(
-                            state,
-                            TrainStatus(epoch=epoch, step=int(state.step)),
-                        )
-                if mngr is not None:
-                    mngr.wait()
-                obs_goodput.close(cause="complete")
-                if census is not None:
-                    # bounded, and here alone: a stage that is leaving for a
-                    # resize or on an exception waits for no telemetry
-                    census.join(timeout=CENSUS_JOIN_S)
-                return state
-        finally:
-            census_dropped.set()
+        stage.step = make_train_step(
+            self._loss, self._apply_kwargs, numerics=obs_numerics.enabled(),
+        )
+        stage.sharding = batch_sharding(stage.mesh, self._batch_axis)
+        worker_barrier("elastic-trainer-start")
+        # restage-trace segment: state build + restore + stage barrier (the
+        # restore nests under it as its own span)
+        tracer.record("train_setup", t_setup, time.monotonic() - t_setup)
+        # goodput: everything from here until the first completed step is
+        # attributed to compile (jit trace + XLA compile, or persistent-cache
+        # load)
+        obs_goodput.enter("compile", cause="first_step")
+        # profiling plane: windowed MFU/roofline/HBM gauges (armed with the
+        # step's cost analysis after the first step) + store-driven on-demand
+        # jax.profiler windows. EDL_PROFILE_DIR keeps its historical meaning —
+        # ONE env-armed window for the whole fit (the reference profiles
+        # batches 100-105, train_with_fleet.py:524-534) — now riding the same
+        # controller as store requests.
+        stage.step_telemetry = obs_profile.StepTelemetry()
+        closing.callback(stage.step_telemetry.close)
+        try:
+            stage.capture = obs_profile.CaptureController(
+                env, telemetry=stage.step_telemetry
+            )
+            closing.callback(stage.capture.close)
+            profile_dir = os.environ.get("EDL_PROFILE_DIR")
+            if profile_dir:
+                stage.capture.arm_local(profile_dir, start_after=10, steps=5)
+        except Exception as exc:  # noqa: BLE001 — profiling is best-effort
+            print(
+                "elastic-trainer: capture plane unavailable (%s); "
+                "continuing without it" % exc,
+                file=sys.stderr,
+            )
+        return stage
+
+    def _run_steps(self, stage: _Stage, epoch: int, data_fn: DataFn, monitor):
+        """One epoch's steps: ``(the last step's metrics, steps run, when the
+        epoch began)`` for ``_end_epoch``. What is in the ``while`` runs every
+        step; what a stage does once is ``_after_first_step``."""
+        tracer, probe, mem_plane = stage.tracer, stage.probe, stage.mem_plane
+        metrics: Dict[str, Any] = {}
+        batches = data_fn(epoch)
+        if self._batch_size is not None:
+            batches = (
+                b for b, _ in batched(batches, self._batch_size, drop_remainder=True)
+            )
+        step_idx = 0
+        t_epoch = time.monotonic()
+        t_prev = t_epoch
+        # explicit iterator: the time blocked in next() is the input
+        # pipeline's fault (data_wait), the dispatch interval after it is the
+        # step's (train) — the split the goodput ledger exists to make
+        batch_iter = iter(prefetch_to_device(
+            batches, depth=self._depth, sharding=stage.sharding, epoch=epoch,
+        ))
+        stage.retired.start_epoch()
+        while True:
+            if stage.first_step_done:
+                obs_goodput.enter("data_wait")
+            try:
+                with tracer.span("data_wait", epoch=epoch, step=step_idx):
+                    device_batch = next(batch_iter)
+            except StopIteration:
+                break
+            if stage.first_step_done:
+                obs_goodput.enter("train")
+            if stage.health is not None and stage.health.drain_notice:
+                # drain beats restage: this pod is leaving the job, not
+                # joining the next generation
+                self._drain_exit(
+                    stage.health, stage.mngr, stage.state, epoch,
+                    stage.steps_done, stage.env,
+                )
+            if monitor is not None and monitor.restage_pending:
+                # between steps, never inside compiled code; the in-flight
+                # step's work is simply dropped (same loss as a stop-resume
+                # kill)
+                raise _RestageRequested()
+            # host cost of one dispatch: long when the runtime's queue is full
+            with tracer.span("step_dispatch", epoch=epoch, step=step_idx):
+                # under the memory plane's guard RESOURCE_EXHAUSTED leaves a
+                # forensics bundle (census + device memory profile + the plan
+                # + an fsync'd `oom` instant) before propagating into
+                # drain/restage
+                with (
+                    mem_plane.oom_guard(step=stage.steps_done, epoch=epoch)
+                    if mem_plane is not None
+                    else contextlib.nullcontext()
+                ):
+                    stage.state, metrics = stage.step(stage.state, device_batch)
+            # pop BEFORE any aggregation/printing: the bundle is device arrays
+            # for the probe, not a scalar metric. No host sync here — the
+            # probe fetches on its own throttle.
+            bundle = metrics.pop(obs_numerics.METRICS_KEY, None)
             if probe is not None:
-                probe.close()
-            if ladder is not None:
-                ladder.close()
-            if capture is not None:
-                capture.close()
+                fetched = probe.on_step(stage.steps_done, bundle, epoch=epoch)
+                if fetched is not None:
+                    stage.retired.mark(
+                        fetched[0], fetched[1], epoch=epoch, gauges=fetched[2]
+                    )
+            # dispatch to dispatch: the loop runs ahead of the device, so this
+            # is the host's interval, not the step's (RetireClock has that)
+            t_now = time.monotonic()
+            dt = t_now - t_prev
+            if probe is None:
+                # no wait for the device inside an epoch, so the runtime's
+                # own queue paces the dispatches
+                _M_STEP_SECONDS.observe(dt)
+            _M_STEPS.inc()
+            if stage.first_step_done:
+                tracer.record("train_step", t_prev, dt, epoch=epoch, step=step_idx)
+            else:
+                self._after_first_step(
+                    stage, epoch, step_idx, t_prev, dt, device_batch
+                )
+            stage.step_telemetry.observe_step(dt)
             if mem_plane is not None:
-                mem_plane.close()
-            if step_telemetry is not None:
-                step_telemetry.close()
-            if health is not None:
-                health.close()
-            if mngr is not None:
-                mngr.close()
+                # throttled census + watermark sample (EDL_MEM_CENSUS_EVERY;
+                # metadata only, never a host sync on the step path)
+                mem_plane.on_step(stage.steps_done)
+            t_prev = t_now
+            step_idx += 1
+            stage.steps_done += 1
+            if t_now - stage.last_flight >= 1.0:
+                # throttled black-box marker: bounds a killed worker's open
+                # goodput interval to <= 1 s
+                stage.last_flight = t_now
+                obs_events.record(
+                    "train_heartbeat", step=stage.steps_done, epoch=epoch
+                )
+            if stage.health is not None:
+                stage.health.heartbeat(stage.steps_done, dt)
+            if stage.capture is not None:
+                # store-driven profiler window state machine; the sync makes
+                # the closing trace contain the device work it claims to
+                stage.capture.on_step(
+                    sync=lambda m=metrics: jax.block_until_ready(m)
+                )
+        return metrics, step_idx, t_epoch
+
+    def _after_first_step(
+        self, stage: _Stage, epoch: int, step_idx: int,
+        t_prev: float, dt: float, device_batch,
+    ) -> None:
+        """Once a stage, when its first step has returned: what the ring
+        holds of that step (its ``train_step`` in its place), then the step
+        lowered once more for the cost model and the memory plan, the census
+        thread and the AOT ladder."""
+        tracer, env, mem_plane = stage.tracer, stage.env, stage.mem_plane
+        # restage trace: the first completed step is the operation's closing
+        # segment (jit trace + compile or cache load), recorded while the op
+        # context is still live so it stitches — then the restage window ends
+        _record_step_launch(tracer)
+        tracer.record("first_step", t_prev, dt, epoch=epoch)
+        obs_trace.end_process_op()
+        tracer.record("train_step", t_prev, dt, epoch=epoch, step=step_idx)
+        # the stage's cold-start cost: jit trace + compile (or
+        # persistent-cache load)
+        _M_FIRST_STEP.set(dt)
+        stage.first_step_done = True
+        obs_goodput.enter("train", cause="first_step")
+        # one more jax trace of the step, shared by what reads the program:
+        # XLA's cost analysis arms the MFU/roofline gauges; its compile (a
+        # persistent-cache hit, no second XLA compile) gives the memory plane
+        # THIS stage's plan and obs_profile.step_phases() the names. Its own
+        # span: set-up time after `first_step` ends (milliseconds on the chip:
+        # jax's in-process caches hand trace, lowering and executable back)
+        with tracer.span("step_relower") as relower:
+            lowered, compiled = _lower_step(
+                stage.step, stage.state, device_batch,
+                compile=mem_plane is not None,
+            )
+            stage.step_telemetry.set_cost(obs_profile.step_cost(lowered))
+            plan = None
+            if compiled is not None:
+                plan = mem_plane.harvest(compiled, world=env.world_size)
+                obs_profile.set_step_executable(compiled)
+            relower.args["compiled"] = compiled is not None
+        if compiled is not None:
+            # the census of the compiled step: its text and a pass over it
+            # take seconds, so on a thread of its own (a stage that trains to
+            # its end waits for it); the stage's notes as they stand now,
+            # before the ladder's thread traces other worlds
+            stage.census = threading.Thread(
+                target=_publish_step_census,
+                args=(tracer, tracer.notes(), plan, env, stage.census_dropped),
+                name="edl-step-census", daemon=True,
+            )
+            stage.census.start()
+        # steady state reached: speculatively compile the N±1/N±2 neighbor
+        # worlds into the persistent cache on a low-priority thread
+        # (train/aot.py) so the NEXT resize re-jits from a cache load instead
+        # of a compile
+        if env.compile_cache_dir:
+            stage.ladder = self._start_ladder(
+                env, stage.step, stage.state, device_batch, mem_plane=mem_plane
+            )
+            if stage.ladder is not None:
+                stage.closing.callback(stage.ladder.close)
+
+    def _end_epoch(
+        self, stage: _Stage, epoch: int, metrics: Dict[str, Any], steps: int,
+        t_epoch: float, on_epoch_end: Optional[Callable[[int, Dict], None]],
+    ) -> None:
+        """The end of an epoch: the device drains, what was sown becomes
+        gauges, the epoch is printed and recorded, then the caller's callback
+        and the save."""
+        env, tracer = stage.env, stage.tracer
+        if stage.first_step_done:
+            # the epoch-end device sync below is step work, not input wait
+            obs_goodput.enter("train")
+        if metrics:
+            # the device drains: every step of the epoch has retired when
+            # this returns
+            with tracer.span("epoch_sync", epoch=epoch, step=steps - 1):
+                jax.block_until_ready(metrics)
+            t_synced = time.monotonic()
+            # what the model sows (aux_loss, moe_load_max) and what the loss
+            # head names as its ``gauges``, as gauges: the values have just
+            # been waited for
+            sown = {
+                name: np.asarray(metrics[name])
+                for name in (*stage.state.sown, *getattr(self._loss, "gauges", ()))
+                if name in metrics
+            }
+            stage.retired.mark(
+                stage.steps_done - 1, t_synced, epoch=epoch, gauges=sown
+            )
+            obs_numerics.publish_sown(sown)
+        if env.is_rank0 and self._log and metrics:
+            print("epoch %d %s" % (epoch, " ".join(
+                "%s %.4f" % (k, float(np.asarray(v)))
+                for k, v in sorted(metrics.items())
+                if np.asarray(v).ndim == 0
+            )))
+        if not metrics and env.is_rank0 and self._log:
+            print(
+                "epoch %d produced no full batches "
+                "(fewer than batch_size records?)" % epoch
+            )
+        _M_EPOCHS.inc()
+        tracer.record(
+            "train_epoch", t_epoch, time.monotonic() - t_epoch,
+            epoch=epoch, steps=steps,
+        )
+        if on_epoch_end is not None:
+            with tracer.span("epoch_end_hook", epoch=epoch):
+                on_epoch_end(epoch, metrics)
+        if stage.mngr is not None:
+            stage.mngr.save(
+                stage.state, TrainStatus(epoch=epoch, step=int(stage.state.step))
+            )
 
     def _start_ladder(self, env, step, state, device_batch, mem_plane=None):
         """Arm the AOT resize ladder for this stage (best-effort)."""

@@ -1413,17 +1413,18 @@ class TestRepoConformance:
     def test_full_repo_all_passes_under_budget(self):
         """ISSUE-14 satellite: ASTs + symbol table + lock-flow are
         cached on the shared context, and a full 13-pass run stays
-        under 8s on the CI rig."""
+        under 8s of this process's CPU (not of the wall clock, which
+        five busy neighbours under ``-n 6`` stretch)."""
         import time as _time
 
         from edl_tpu.analysis import repo_context, run_analysis
 
         ctx = repo_context()
-        t0 = _time.monotonic()
+        t0 = _time.process_time()
         _, counts = run_analysis(ctx)
-        elapsed = _time.monotonic() - t0
+        elapsed = _time.process_time() - t0
         assert len(counts) == 13
-        assert elapsed < 8.0, "full 13-pass run took %.1fs" % elapsed
+        assert elapsed < 8.0, "full 13-pass run took %.1fs of CPU" % elapsed
         # the cross-pass memos actually landed on the shared cache
         assert "symbol_table" in ctx.cache
         assert "lock_flow" in ctx.cache
@@ -1568,12 +1569,13 @@ class TestCli:
     def test_repo_is_clean_against_committed_baseline(self):
         """THE acceptance check: all 13 passes over edl_tpu/ + tools/,
         exit 0 against the committed baseline, within the 8s budget
-        (PR 9's 4s, relaxed for the interprocedural passes)."""
+        (PR 9's 4s, relaxed for the interprocedural passes) of the CLI
+        process's own CPU: its wall clock is the host's business."""
         out = _cli(["--json", "--baseline", ".edl_lint_baseline.json"])
         assert out.returncode == 0, out.stdout + out.stderr
         doc = json.loads(out.stdout)
         assert doc["summary"]["new"] == 0
-        assert doc["seconds"] < 8
+        assert doc["cpu_seconds"] < 8
         assert len(doc["passes"]) == 13
         names = {p["name"] for p in doc["passes"]}
         assert {

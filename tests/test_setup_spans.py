@@ -188,9 +188,11 @@ def test_backend_init_hook_is_installed_once_and_only_ahead_of_the_backends(monk
     from jax._src import xla_bridge
 
     jax.devices()
-    was = getattr(xla_bridge, aot.JAX_BACKEND_INIT)
+    found = getattr(xla_bridge, aot.JAX_BACKEND_INIT)
     aot.instrument_backend_init()
-    assert getattr(xla_bridge, aot.JAX_BACKEND_INIT) is was  # backends are up
+    assert getattr(xla_bridge, aot.JAX_BACKEND_INIT) is found  # backends are up
+    # a fit earlier in this process, ahead of the backends, left the hook in
+    was = found.__wrapped__ if getattr(found, "_edl_span", False) else found
     monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: False)
     monkeypatch.setattr(xla_bridge, aot.JAX_BACKEND_INIT, was)
     aot.instrument_backend_init()

@@ -1,6 +1,8 @@
 """ElasticTrainer: the one-call elastic loop (reference intent:
 test_train.py:28-67 PaddleState/register_adjust_function sketch)."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -254,37 +256,198 @@ def _trainer(**kwargs):
     )
 
 
-def test_every_plane_is_closed_when_the_loader_raises_mid_epoch(
-    tmp_path, monkeypatch
-):
-    """Pins: an exception out of ``data_fn``'s iterator reaches ``fit``'s
-    caller, and on the way the stage closes each plane it built exactly
-    once: checkpoint manager, memory plane, numerics probe, capture
-    controller, step telemetry."""
-    from edl_tpu.obs import memory, numerics, profile
-    from edl_tpu.train import loop
+def _steps_as_counts(names):
+    """``names`` with each run of the per-step triple (``data_wait``,
+    ``step_dispatch``, ``train_step``) as one ``("steps", n)``."""
+    triple = ["data_wait", "step_dispatch", "train_step"]
+    out, i = [], 0
+    while i < len(names):
+        if names[i:i + 3] != triple:
+            out.append(names[i])
+            i += 1
+            continue
+        if out and isinstance(out[-1], tuple):
+            out[-1] = ("steps", out[-1][1] + 1)
+        else:
+            out.append(("steps", 1))
+        i += 3
+    return out
 
-    closed = []
+
+def test_the_ring_reads_the_same_names_in_the_same_order(tmp_path, monkeypatch):
+    """Pins (taken from PR 66's loop, before PR 67 cut it into parts): what
+    the loop's own thread leaves in the ring over two epochs of three steps
+    with a save after each, name by name in the order it is recorded. jax's
+    own events and what the traced program notes of itself are not the
+    loop's; a step that leaves nothing but its triple is counted."""
+    import threading
+
+    from edl_tpu.obs import trace as obs_trace
+
+    tracer = obs_trace.SpanTracer("test")
+    monkeypatch.setattr(obs_trace, "_tracer", tracer)
+    _trainer(ckpt_dir=str(tmp_path / "ckpt")).fit(
+        lambda epoch: _records(epoch, n=24), epochs=2,
+        on_epoch_end=lambda e, m: None,
+    )
+    tid = threading.get_ident() & 0x7FFFFFFF
+    noted = {name for name, _ in tracer.notes()}
+    names = [
+        e["name"] for e in tracer.to_events()
+        if e.get("ph") in ("X", "i") and e.get("tid") == tid
+        and not e["name"].startswith("jit_") and e["name"] not in noted
+        and e["name"] not in ("cache_load", "backend_init")  # once a process
+    ]
+    epoch_end = [
+        "data_wait",  # the pull that found the end
+        "epoch_sync", "step_retired", "train_epoch", "epoch_end_hook",
+        "ckpt_stamp", "ckpt_save",
+    ]
+    assert _steps_as_counts(names) == [
+        "trainer_init", "state_init", "train_setup",
+        # the first step, which the probe fetches at once
+        "data_wait", "step_dispatch", "numerics_fetch", "step_retired",
+        "step_launch", "first_step", "train_step", "step_relower",
+        ("steps", 2), *epoch_end,
+        ("steps", 3), *epoch_end,
+        "numerics_fetch",  # the probe's closing flush
+    ]
+
+
+#: what a stage of ``_a_stage_with_every_plane`` builds and must close
+_EVERY_PLANE = [
+    "AotLadder", "CaptureController", "CheckpointManager", "HealthMonitor",
+    "MemoryPlane", "NumericsProbe", "StepTelemetry",
+]
+
+
+def _a_stage_with_every_plane(tmp_path, monkeypatch, store):
+    """``(trainer, closed)``: a trainer whose stage builds all seven things
+    it closes (a health monitor on ``store``, a stub ladder, the five the
+    toy always has), and the list their closes append ``(name, whether the
+    census thread had been told to drop)`` to."""
+    from edl_tpu.obs import memory, numerics, profile
+    from edl_tpu.train import context, loop
+
+    for key, value in (
+        ("EDL_JOB_ID", "pins"), ("EDL_POD_ID", "pod-0"),
+        ("EDL_STORE_ENDPOINT", store.endpoint),
+    ):
+        monkeypatch.setenv(key, value)
+    closed, census = [], {}
+
+    def note(name):
+        closed.append((name, census["dropped"].is_set()))
+
     for owner in (
         loop.CheckpointManager, memory.MemoryPlane, numerics.NumericsProbe,
         profile.CaptureController, profile.StepTelemetry,
+        context.HealthMonitor,
     ):
         def close(self, _real=owner.close, _name=owner.__name__):
-            closed.append(_name)
+            note(_name)
             _real(self)
 
         monkeypatch.setattr(owner, "close", close)
 
-    def torn(epoch):
-        yield from _records(epoch, n=20)
-        raise RuntimeError("the loader broke")
+    def publish(tracer, notes, plan, env, dropped, _real=loop._publish_step_census):
+        census["dropped"] = dropped
+        _real(tracer, notes, plan, env, dropped)
 
-    with pytest.raises(RuntimeError, match="the loader broke"):
-        _trainer(ckpt_dir=str(tmp_path / "ckpt")).fit(torn, epochs=1)
-    assert sorted(closed) == [
-        "CaptureController", "CheckpointManager", "MemoryPlane",
-        "NumericsProbe", "StepTelemetry",
-    ]
+    monkeypatch.setattr(loop, "_publish_step_census", publish)
+
+    def init_with_a_cache_dir(_real=loop.init):
+        # the ladder's gate, without arming jax's cache for this process
+        env = _real()
+        env.compile_cache_dir = str(tmp_path / "xla")
+        return env
+
+    monkeypatch.setattr(loop, "init", init_with_a_cache_dir)
+    monkeypatch.setattr(
+        ElasticTrainer, "_start_ladder",
+        lambda self, *args, **kwargs: types.SimpleNamespace(
+            close=lambda: note("AotLadder")
+        ),
+    )
+    return _trainer(ckpt_dir=str(tmp_path / "ckpt")), closed
+
+
+class _RestageAfter:
+    """A stage monitor whose ``restage_pending`` turns true once the loop
+    has asked ``steps`` times (it asks once before each step)."""
+
+    def __init__(self, steps):
+        self._left = steps
+
+    @property
+    def restage_pending(self):
+        self._left -= 1
+        return self._left < 0
+
+
+def _torn(epoch):
+    yield from _records(epoch, n=20)
+    raise RuntimeError("the loader broke")
+
+
+@pytest.mark.parametrize(
+    "way_out", ["trained_to_the_end", "the_loader_raised", "a_restage_was_asked_for"]
+)
+def test_the_stage_closes_what_it_built_in_an_order_its_holders_allow(
+    way_out, tmp_path, monkeypatch, store
+):
+    """Pins: whichever way a stage ends (its epochs done, an exception out
+    of ``data_fn``'s iterator that reaches ``fit``'s caller, a restage asked
+    for between steps) it closes each plane it built exactly once: the
+    census thread told to drop before the first close, the numerics probe
+    and the memory plane before the health monitor whose store client they
+    hold, the ladder before the memory plane its rungs harvest into, the
+    checkpoint manager last."""
+    from edl_tpu.train import loop
+
+    trainer, closed = _a_stage_with_every_plane(tmp_path, monkeypatch, store)
+    if way_out == "trained_to_the_end":
+        state = trainer.fit(lambda epoch: _records(epoch, n=24), epochs=1)
+        assert int(state.step) == 3
+    elif way_out == "the_loader_raised":
+        with pytest.raises(RuntimeError, match="the loader broke"):
+            trainer.fit(_torn, epochs=1)
+    else:
+        with pytest.raises(loop._RestageRequested):
+            trainer._fit_stage(
+                lambda epoch: _records(epoch, n=64), 1, None, _RestageAfter(2)
+            )
+    names = [name for name, _ in closed]
+    assert sorted(names) == _EVERY_PLANE
+    assert all(dropped for _, dropped in closed), closed
+    at = names.index
+    assert at("NumericsProbe") < at("HealthMonitor")
+    assert at("MemoryPlane") < at("HealthMonitor")
+    assert at("AotLadder") < at("MemoryPlane")
+    assert names[-1] == "CheckpointManager"
+
+
+def test_a_close_that_raises_does_not_skip_the_closes_after_it(
+    tmp_path, monkeypatch, store
+):
+    """New with PR 67's lifetime stack; fails on PR 66's loop, whose
+    ``finally`` closed plane after plane and stopped at the first close that
+    raised. Every plane is still closed once, in the same order, and the
+    exception reaches ``fit``'s caller."""
+    from edl_tpu.obs import profile
+
+    trainer, closed = _a_stage_with_every_plane(tmp_path, monkeypatch, store)
+
+    def close(self, _noting=profile.CaptureController.close):
+        _noting(self)
+        raise RuntimeError("the capture plane would not close")
+
+    monkeypatch.setattr(profile.CaptureController, "close", close)
+    with pytest.raises(RuntimeError, match="would not close"):
+        trainer.fit(lambda epoch: _records(epoch, n=24), epochs=1)
+    names = [name for name, _ in closed]
+    assert sorted(names) == _EVERY_PLANE
+    assert names[-1] == "CheckpointManager"
 
 
 def test_an_epoch_short_of_one_batch_trains_nothing_and_says_so(capsys):
