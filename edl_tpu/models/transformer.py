@@ -57,6 +57,7 @@ from edl_tpu.models.moe import REMAT_NAMES as MOE_NAMES
 from edl_tpu.models.short_conv import ShortConvMixer, ShortConvSpec
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.ops.attention import _flash2_blocks, attention
+from edl_tpu.ops.cross_entropy import rows_cross_entropy
 from edl_tpu.ops.gated_delta import REMAT_NAMES as GDN_NAMES
 from edl_tpu.ops.sparse_attention import REMAT_NAMES as DSA_NAMES
 from edl_tpu.ops.sparse_attention import sparse_attention
@@ -967,11 +968,9 @@ class LMHead(nn.Module):
 
 
 def _scored_cross_entropy(logits, labels, scored):
-    """Mean softmax cross-entropy over the positions ``scored`` marks, in the
-    arithmetic of ``train/step.py:cross_entropy_loss`` (which ``models/`` may
-    not import): ``-sum(one_hot * log_softmax(logits))`` a position."""
-    one_hot = jax.nn.one_hot(labels, logits.shape[-1])
-    ce = -jnp.sum(one_hot * jax.nn.log_softmax(logits, axis=-1), axis=-1)
+    """Mean softmax cross-entropy over the positions ``scored`` marks, by the
+    rows' one owner (``ops/cross_entropy.py``), as ``train/step.py``'s heads."""
+    ce, _ = rows_cross_entropy(logits, labels, site="mtp_head")
     return jnp.sum(ce * scored) / jnp.maximum(jnp.sum(scored), 1)
 
 

@@ -23,6 +23,7 @@ from flax import core, struct
 
 from edl_tpu.obs import numerics as obs_numerics
 from edl_tpu.obs import trace as obs_trace
+from edl_tpu.ops.cross_entropy import rows_cross_entropy
 
 
 class TrainState(struct.PyTreeNode):
@@ -174,10 +175,8 @@ def create_state(
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> Tuple[jax.Array, Dict]:
-    one_hot = jax.nn.one_hot(labels, logits.shape[-1])
-    loss = optax.softmax_cross_entropy(logits, one_hot).mean()
-    accuracy = (jnp.argmax(logits, -1) == labels).mean()
-    return loss, {"accuracy": accuracy}
+    ce, best = rows_cross_entropy(logits, labels, site="cross_entropy_loss")
+    return ce.mean(), {"accuracy": (best == labels).mean()}
 
 
 def make_cross_entropy_loss(report_top_k: Optional[int] = None):
@@ -254,13 +253,11 @@ def make_block_diffusion_loss():
 
     def bd_loss(logits: jax.Array, y) -> Tuple[jax.Array, Dict]:
         labels, weights = y
-        ce = optax.softmax_cross_entropy(
-            logits, jax.nn.one_hot(labels, logits.shape[-1])
-        )
+        ce, best = rows_cross_entropy(logits, labels, site="block_diffusion_loss")
         weights = weights.astype(jnp.float32)
         scored = weights > 0
         count = jnp.maximum(jnp.sum(scored), 1)
-        right = jnp.argmax(logits, -1) == labels
+        right = best == labels
         return jnp.sum(weights * ce) / ce.size, {
             "accuracy": jnp.sum(scored & right) / count,
             "bd_masked_share": jnp.mean(scored),

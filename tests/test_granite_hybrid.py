@@ -367,17 +367,19 @@ def test_the_compiled_step_names_the_mixers_scopes(scope):
 # until PR 52, which changed the expert layer's program on purpose (its
 # ``top_k`` has a gradient rule of its own, and ``save_flash`` keeps the route
 # by name), and then PR 65 (that rule sends the gradient home by comparison,
-# ``models/moe.py:_sent_home``, where it scattered); these are PR 65's
+# ``models/moe.py:_sent_home``, where it scattered), and then PR 68 (the loss
+# head's rows are ``ops/cross_entropy.py``'s one ``custom_vjp``, where optax's
+# ``log_softmax`` and an ``argmax`` stood); these are PR 68's
 OLMOE_STEP = {
-    True: "e26eb05911a743d09eb88df8166e547a47b3e5dba80a224489edeb721a96cba7",
-    False: "d755c4a514d09302a40ba4a3ace36f9b68083ccc40dd14b1550fc9f3aebd1bf5",
+    True: "1f46d746567c096fa0e4bce8f00771ac52e234749bea8d8916316e0fcb2e046f",
+    False: "f50465c72746811f70cf1c808a4c800e18d38d778a7c698723bef42ebb095a86",
 }
 # the same step behind the attention projections' fence (PR 39): one
 # ``optimization_barrier`` a projection and half-batch; with the fence off
 # the text is still the one above
 OLMOE_STEP_FENCED = {
-    True: "7be8bd000c1463d17c3d16a421f56cdbdf6a337489249587533901dd55201d53",
-    False: "33d52245ea33f9cbcdece2aa6fd1e8cf7d794ca064bcf9892ea1170c3328373e",
+    True: "5396a8cf40d39a541f4ddaa8b2d8433b530529eaad810a82be20b838c0f07646",
+    False: "886a3ab9dac0b1dfa51749c83a4f442da65808e571b4f3ff15f530d32ae464ef",
 }
 
 

@@ -414,17 +414,19 @@ def test_the_backward_reruns_no_down_projection_and_unsorts_no_rows(k, norm):
 # what it was. Taken on PR 26's commit. They were e3b7f7a's (5d70944e…,
 # 77d4ea6a…) until that PR gave ``LMHead`` a backward of its own, which puts
 # one convert and one optimization_barrier per pass into this text on purpose
-# (``tests/test_lm_head.py`` pins that structure)
+# (``tests/test_lm_head.py`` pins that structure), and until PR 68, which made
+# the loss head's rows ``ops/cross_entropy.py``'s one ``custom_vjp`` where
+# optax's ``log_softmax`` and an ``argmax`` stood; these are PR 68's
 DENSE_STEP = {
-    True: "230416047b58c9cb98ca0f8843911c1327930b83a45c52a8661c7062b22c13c2",
-    False: "4c3b4d092755a81112525324035758f0695c86ad670947d11ac5f32f107e1150",
+    True: "6ec74441bafa29f6303ebe05276b12ec4df51aa00d899586e63e866d9da529f9",
+    False: "1191a975c4fa2fd5e5f3d1165bdbd7bece6849da4899ae46190e75cce0818329",
 }
 # the same step behind the attention projections' fence (PR 39): one
 # ``optimization_barrier`` a projection and half-batch; with the fence off
 # the text is still the one above
 DENSE_STEP_FENCED = {
-    True: "3d8a8d6e34eeabaa8836829ce395958274c44b3d3fdeb02af58e35455ea0f905",
-    False: "dc055ac98acea674917d46d93475581ad727e5ab484df154c70ee4d0bab3e055",
+    True: "624eb3b4437a76c65b090037e14d27e4d673aa93859d8fa961ff52088c1ee801",
+    False: "06c53fe929c6d23ace6a5051d0066532bb956439337e8612798292f06045b013",
 }
 
 

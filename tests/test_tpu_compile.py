@@ -882,6 +882,67 @@ def test_trinitys_head_projections_write_their_gradient_by_a_plain_matmul(
         assert found["q"][1] and found["g"][1]
 
 
+def _entry_fusions(text):
+    """``[(name, what it writes, its operands' names)]`` of ENTRY's fusions."""
+    return [
+        (name, written, set(re.findall(r"%([\w.\-]+)", operands.split(")")[0])))
+        for name, written, operands in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) fusion\((.*)$",
+            text[text.index("\nENTRY"):], re.M,
+        )
+    ]
+
+
+def _reductions_reading(text, shape):
+    """ENTRY's fusions that hold a ``reduce`` and read an array of ``shape``
+    (a tuple's element too); a matmul's own is left out: a row's max may ride
+    it as a second output."""
+    from edl_tpu.obs import profile as obs_profile
+
+    program = obs_profile.HloProgram(text)
+    reduces = {
+        home for name, home in program.home.items() if program.opcode[name] == "reduce"
+    }
+    held = {
+        found.group(1) for found in re.finditer(
+            r"^\s*%%?([\w.\-]+) = \(?[^(]*%s" % re.escape(shape),
+            text[text.index("\nENTRY"):], re.M,
+        )
+    }
+    return [
+        name for name, _, operands in _entry_fusions(text)
+        if operands & held and program.calls[name] in reduces
+        and program.calls[name] not in program.matmuls
+    ]
+
+
+def test_the_loss_reads_trinitys_logits_in_one_reduction(one_chip):
+    """The head's matmul, ``cross_entropy_loss`` and their gradient at a
+    vocabulary slice of Trinity's (25,024 columns: 195.5 lane tiles), compiled
+    for the described v5e: ONE fusion with a reduction reads the float32
+    logits in the forward (``optax.softmax_cross_entropy`` + ``jnp.argmax``
+    made three: the max with the argmax, the sum of exponentials, the pick),
+    none in the backward, and the gradient's bfloat16 array is written once.
+    Each such pass is 4.35 ms of Granite's step (PERF.md section 6, PR 68)."""
+    from edl_tpu.models.transformer import _head_matmul
+    from edl_tpu.train import cross_entropy_loss
+
+    def loss(x, w, y):
+        return cross_entropy_loss(_head_matmul(x, w), y)
+
+    described = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip
+    )
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        described((1024, 256), jnp.bfloat16), described((256, 25024), jnp.bfloat16),
+        described((1024,), jnp.int32),
+    ).compile().as_text()
+    reads = _reductions_reading(text, "f32[1024,25024]")
+    writes = [name for name, written, _ in _entry_fusions(text) if "bf16[1024,25024]" in written]
+    assert len(reads) == 1, reads
+    assert len(writes) == 1, writes
+
+
 def test_grid_pipeline_kwargs_carry_dimension_semantics():
     """jax 0.9.0 has ``pltpu.CompilerParams(dimension_semantics=...)``: the
     flash2 family must never run without it (the old guard dropped it
